@@ -1,7 +1,7 @@
 """Interleaved A/B of BERT-base train-step variants on the real chip.
 
 Variants: f32 (round-3 config), bf16, bf16+fused(flash) attention.
-Protocol from docs/perf_r03.md: interleave variants round-robin, best-of-N
+Protocol from the r3 chip round: interleave variants round-robin, best-of-N
 windows each, report per-variant best — single measurements on the shared
 chip are not evidence.
 """

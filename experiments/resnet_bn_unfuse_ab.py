@@ -3,7 +3,7 @@
 The r5 profile (experiments/profile_model.py) showed conv fusions carrying
 BN-stat reduce epilogues running at 9-43 TF/s vs ~90-190 for clean convs —
 but the step is bandwidth-bound, so what matters is total HBM bytes, not
-in-fusion MXU rate.  Variants (result: docs/perf_r05.md):
+in-fusion MXU rate.  Variants (result: r5 chip round):
 
   base        : round-4 lowering (two-pass stats, fused into convs)   115.4 ms
   barrier     : two-pass stats behind an optimization_barrier         130.8 ms

@@ -1,11 +1,11 @@
 """Round-5 NHWC vs NCHW whole-model A/B under the fused single-pass BN.
 
 r3 measured whole-model NHWC neutral (2351 vs 2337 imgs/s) on the two-pass
-BN lowering; VERDICT r4 asks the layout question to be closed on the current
+BN lowering; r4 review asks the layout question to be closed on the current
 config.  NHWC requires the conv7 stem (s2d rearrangement is NCHW-only), so
 conv7 NCHW is included to separate stem effect from layout effect.
 
-Result (docs/perf_r05.md): NCHW+s2d 104.07, NCHW+conv7 105.00, NHWC+conv7
+Result (r5 chip round): NCHW+s2d 104.07, NCHW+conv7 105.00, NHWC+conv7
 104.35 ms/step — NHWC neutral for the third round; question closed.
 
   python experiments/resnet_nhwc_ab_r05.py [rounds] [iters]

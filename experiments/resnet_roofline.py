@@ -1,6 +1,6 @@
 """Analytic single-chip roofline for the ResNet-50 bs256 bf16 train step.
 
-Question (VERDICT r5 path): is the measured ~104 ms step near the memory
+Question (r5 review path): is the measured ~104 ms step near the memory
 roofline, i.e. is the ≥20% MFU floor reachable by software at all on one
 v5e?  Model: per conv layer, fwd+bwd cost = max(FLOP/peak, bytes/BW) with
 the fusion structure the r5 profile shows XLA already achieving:

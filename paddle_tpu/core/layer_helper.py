@@ -12,7 +12,7 @@ from .initializer import ConstantInitializer, XavierInitializer
 from .param_attr import ParamAttr
 from .program import default_main_program, default_startup_program
 
-# Mixed-precision master-weight policy (round-5 fix, docs/perf_r05.md):
+# Mixed-precision master-weight policy (r5 chip round fix):
 # trainable parameters requested in a low-precision float are CREATED as
 # float32 masters — every consuming op lowers through match_dtype, which
 # casts the master to the activation dtype inside the compiled step, so the

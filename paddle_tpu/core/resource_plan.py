@@ -74,7 +74,7 @@ __all__ = [
     # planner
     "ShapeEnv", "PlanRow", "ResourcePlan", "plan_program",
     # consumers
-    "device_hbm_limit", "precheck_program",
+    "precheck_program",
 ]
 
 # Chip model (v5e-class single chip; bench.py's V5E_BF16_PEAK is the same
@@ -613,37 +613,28 @@ def _def_type_of(block: Block, name: str) -> Optional[str]:
 # the executor's OOM pre-check
 # --------------------------------------------------------------------------
 
-def device_hbm_limit(device=None) -> Optional[int]:
-    """The device allocator's bytes_limit, or the FLAGS override; None when
-    neither is known (XLA:CPU exposes no memory_stats)."""
-    from ..flags import flag as _flag
-
-    mb = float(_flag("FLAGS_resource_hbm_limit_mb") or 0)
-    if mb > 0:
-        return int(mb * 1e6)
-    if device is None:
-        return None
-    try:
-        stats = device.memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return None
-
-
 def precheck_program(program: Program, feed_shapes, fetch_names,
-                     steps: int = 1, device=None,
+                     steps: int = 1,
                      limit_bytes: Optional[int] = None) -> Optional[ResourcePlan]:
     """The executor's compile-cache-miss OOM pre-check: plan the program
     and raise classified `ResourceError` naming the watermark ops when the
-    plan cannot fit — BEFORE XLA compiles or allocates anything.  Returns
-    the plan (or None when the check is off / no limit is known)."""
+    plan exceeds FLAGS_resource_hbm_limit_mb (or `limit_bytes`) — BEFORE
+    XLA compiles or allocates anything.  Returns the plan (or None when
+    the check is off / no limit is set).
+
+    The limit is one the user names, never the one the device reports: the
+    plan gives every op output its own buffer (no fusion, no reuse), an
+    upper bound.  Held against a v5e's own bytes_limit it refused
+    bench.py's BERT-base batch-256 step — 36.99 GB planned, 15.49 GB of
+    arguments and temporaries as XLA compiled it (PR 21) — and XLA itself
+    refuses, at compile time, what does not fit."""
     from ..flags import flag as _flag
 
     if _flag("FLAGS_resource_precheck") in ("", "off"):
         return None
-    limit = limit_bytes if limit_bytes is not None else device_hbm_limit(device)
+    limit = limit_bytes
+    if limit is None:
+        limit = int(float(_flag("FLAGS_resource_hbm_limit_mb") or 0) * 1e6)
     if not limit:
         return None
     plan = plan_program(program, feed_shapes, fetch_names, steps=steps)

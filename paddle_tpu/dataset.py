@@ -101,7 +101,7 @@ class QueueDataset(DatasetBase):
     the reference's data_feed.cc MultiSlotInMemoryDataFeed) and batches
     assemble by memcpy with the GIL released — measured 29k -> 1.4M+ ex/s
     on the DeepFM slot config vs the Python thread pool, which the GIL
-    capped below the device's consumption rate (docs/perf_r05.md).  Dense
+    capped below the device's consumption rate (r5 chip round).  Dense
     fixed-shape slots only: ragged rows raise mid-stream with guidance
     (use use_native(False) or InMemoryDataset for per-sample Python
     parsing)."""

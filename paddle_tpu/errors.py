@@ -155,8 +155,8 @@ class NumericError(TrainingError):
 
 class TransientDeviceError(TrainingError):
     """Device/runtime failure a later attempt may not reproduce: XLA
-    RESOURCE_EXHAUSTED (HBM pressure), UNAVAILABLE / ABORTED (tunnel or
-    runtime hiccup), DEADLINE_EXCEEDED.  `resource_exhausted` marks the
+    RESOURCE_EXHAUSTED (HBM pressure), UNAVAILABLE / ABORTED (a runtime
+    hiccup), DEADLINE_EXCEEDED.  `resource_exhausted` marks the
     OOM flavor so the resilient loop can also shed in-flight depth."""
 
     def __init__(self, message: str, *, code: Optional[str] = None,

@@ -14,6 +14,14 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List
 
+# the public surface (tools/print_signatures.py walks it): without this the
+# walk picks up `typing.Any`, whose signature differs between Pythons
+__all__ = [
+    "CHECKOUT_CACHE_DIR", "DEFINE_bool", "DEFINE_float", "DEFINE_int",
+    "DEFINE_string", "all_flags", "apply_compile_cache", "apply_xla_dump",
+    "flag", "get_flags", "init_from_env", "set_flags",
+]
+
 _REGISTRY: Dict[str, dict] = {}
 
 
@@ -101,7 +109,9 @@ DEFINE_string("FLAGS_compile_cache_dir", "",
               "executor.compile cost (seconds per program signature, re-paid "
               "every process) is paid once per machine — the second process "
               "running the same program loads the compiled executable from "
-              "disk.  Set before the first compile (env var or set_flags). "
+              "disk.  Set before the first compile (env var or set_flags); "
+              "ignored where JAX_COMPILATION_CACHE_DIR is set "
+              "(apply_compile_cache).  "
               "Single-process only: init_distributed force-disables it for "
               "multi-process runs (cached cross-process executables corrupt "
               "the heap on the current backend)")
@@ -137,16 +147,14 @@ DEFINE_string("FLAGS_resource_precheck", "on",
               "(paddle_tpu/core/resource_plan.py): 'on' (default) plans the "
               "program's liveness-based peak HBM and raises a classified "
               "ResourceError naming the watermark ops when the plan exceeds "
-              "the device limit — BEFORE any XLA compile or allocation; "
-              "'off' skips planning entirely.  The limit comes from "
-              "FLAGS_resource_hbm_limit_mb when set, else the device's own "
-              "memory_stats bytes_limit; with neither known (XLA:CPU "
-              "exposes no stats) the check is a no-op")
+              "FLAGS_resource_hbm_limit_mb — BEFORE any XLA compile or "
+              "allocation; 'off' skips planning entirely.  With no limit "
+              "set the check is a no-op: the plan is an upper bound (no "
+              "fusion, no buffer reuse) and is not held against the limit "
+              "the device reports")
 DEFINE_float("FLAGS_resource_hbm_limit_mb", 0.0,
              "HBM limit (MB) the resource pre-check plans against; 0 "
-             "(default) auto-detects from the device's memory_stats.  Set "
-             "explicitly to plan for a different chip than the one "
-             "attached, or to exercise the over-budget path in tests")
+             "(default) = no limit, no check")
 DEFINE_string("FLAGS_feed_validation", "shape",
               "feed-boundary validation level at DataLoader/DataFeeder "
               "(paddle_tpu/reader.py FeedSpec): 'off' trusts the caller, "
@@ -390,20 +398,39 @@ def apply_xla_dump():
         ).strip()
 
 
-def apply_compile_cache():
-    """Wire FLAGS_compile_cache_dir into jax's persistent compilation
-    cache.  The min-compile-time floor drops to 0 so every program
-    signature is cached — the framework compiles few, large programs, so
-    the cache stays small and the cold-start win applies to all of them.
-    Effective for programs compiled after the flag is set."""
-    d = flag("FLAGS_compile_cache_dir")
+# The cache of an entry point that names none: one fixed path in the
+# checkout (git-ignored).  The path is part of the cache's key, so a
+# directory that moves between runs never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def apply_compile_cache(default_dir: str = "", min_compile_secs: float = 0.0) -> str:
+    """The one rule for where jax's persistent compilation cache lives;
+    returns the directory in effect ("" = no cache).
+
+    JAX_COMPILATION_CACHE_DIR set: jax reads it itself and this code sets
+    no directory, whatever FLAGS_compile_cache_dir says — whoever runs the
+    program placed the cache.  Unset: FLAGS_compile_cache_dir, else
+    `default_dir` (the entry points chip_smoke.py, bench.py and
+    tests/conftest.py pass CHECKOUT_CACHE_DIR).  The min-compile-time floor
+    drops to `min_compile_secs` so every program signature is cached — the
+    framework compiles few, large programs; the test suite compiles
+    thousands of tiny ones and keeps a floor.  Effective for programs
+    compiled after the call."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if env_dir:
+        return env_dir
+    d = flag("FLAGS_compile_cache_dir") or default_dir
     if not d:
-        return
+        return ""
     import jax
 
     jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return d
 
 
 init_from_env()

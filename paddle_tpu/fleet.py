@@ -135,28 +135,9 @@ class Fleet:
 
     @staticmethod
     def _collective_warmup():
-        import jax
+        from jax.experimental import multihost_utils
 
-        try:
-            from jax.experimental import multihost_utils
-
-            multihost_utils.sync_global_devices("paddle_tpu.fleet.init")
-        except ImportError:
-            # fallback must still span PROCESSES (a local-only psum would
-            # leave the cross-process context unestablished): a global-mesh
-            # sum over one element per global device
-            import numpy as np
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            from .parallel.distributed import global_mesh
-
-            mesh = global_mesh()
-            x = jax.make_array_from_process_local_data(
-                NamedSharding(mesh, PartitionSpec("dp")),
-                np.ones((jax.local_device_count(), 1), "f4"))
-            out = jax.jit(lambda a: a.sum(),
-                          out_shardings=NamedSharding(mesh, PartitionSpec()))(x)
-            jax.block_until_ready(out)
+        multihost_utils.sync_global_devices("paddle_tpu.fleet.init")
 
     @property
     def watchdog(self):

@@ -1,4 +1,4 @@
-"""API-tail layers (VERDICT r3 #6): reference `paddle.fluid.layers` entries
+"""API-tail layers (r3 review #6): reference `paddle.fluid.layers` entries
 completing the audited surface.  Signatures mirror the reference API.spec;
 most wrap one op, a few compose existing ops the way the reference python
 layers do (dice_loss, npair_loss)."""

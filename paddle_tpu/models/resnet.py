@@ -10,7 +10,7 @@ data_format="NHWC" builds the whole model channels-last: every conv/pool/BN
 op carries the NHWC attr, feeds are [H,W,C], and the program contains zero
 transpose ops — XLA keeps activations in the TPU-native layout end to end
 (the round-2 per-op-transpose variant was a measured regression; this is
-the whole-model variant docs/perf_r02.md calls for).
+the whole-model variant the r2 chip round called for).
 """
 from __future__ import annotations
 
@@ -73,11 +73,11 @@ def _s2d_stem(input, is_test=False):
     the weight embedding w4[o, c*4+dy*2+dx, r, s] = w8[o, c, 2r+dy, 2s+dx]
     with w8 = 7x7 kernel zero-padded at offset (1,1) (tests/test_s2d_stem.py
     asserts exact equality).  Why: the 7x7/s2 conv on 3 channels is the
-    worst-filled MXU op in the model (docs/perf_r03.md); stride-1 on 12
+    worst-filled MXU op in the model (r3 chip round); stride-1 on 12
     channels tiles better.  Asymmetric padding (2 top/left, 1 bottom/right)
     yields exactly the 112^2 output positions of the original stem — the
     symmetric-pad-2 + slice variant was a measured regression
-    (docs/perf_r04.md)."""
+    (r4 chip round)."""
     b, c, h, w = input.shape
     x6 = layers.reshape(input, [-1, c, h // 2, 2, w // 2, 2])   # b c j dy i dx
     x6 = layers.transpose(x6, [0, 1, 3, 5, 2, 4])               # b c dy dx j i

@@ -3,7 +3,7 @@
 Reference lineage: the C++ profiler's RecordEvent/EventList
 (platform/profiler.cc) was a *profiling mode* — pay-when-on, nothing when
 off, nothing queryable in between.  This subsystem is the always-available
-replacement the perf rounds asked for (VERDICT r5): every layer of the
+replacement the perf rounds asked for (r5 review): every layer of the
 framework reports spans and counters into one process-global `Monitor`,
 and exporters (exporters.py) render the same state as a Prometheus text
 page, a JSON snapshot, a Chrome trace, or an appended JSONL stream.

@@ -59,7 +59,7 @@ def _sum(ctx, op, ins):
 
 # --- activations -----------------------------------------------------------
 
-# (r5 note, docs/perf_r05.md: an output-residual custom-vjp relu — save y
+# (r5 chip round note: an output-residual custom-vjp relu — save y
 # instead of the pre-activation for backward — measured NEUTRAL on the
 # ResNet step in an interleaved A/B (105.1 vs 105.2 ms): XLA already elides
 # the dead pre-activation buffer.  jax.nn.relu keeps higher-order autodiff.)
@@ -124,14 +124,15 @@ def _elementwise_add(ctx, op, ins):
     if not act:
         return _ew_add(ctx, op, ins)
     from ..core.selected_rows import SelectedRows
-    from .pallas_kernels import fused_bias_act, use_pallas
+    from .pallas_kernels import bias_act_shape_ok, fused_bias_act, use_pallas
 
     x = first(ins, "X")
     y = first(ins, "Y")
     if (use_pallas(ctx) and not isinstance(x, SelectedRows)
             and getattr(y, "ndim", None) == 1 and x.ndim >= 2
             and y.shape[0] == x.shape[-1]
-            and op.attr("axis", -1) in (-1, x.ndim - 1)):
+            and op.attr("axis", -1) in (-1, x.ndim - 1)
+            and bias_act_shape_ok(x.shape, x.dtype)):
         # the 1-D last-axis bias shape the kernel handles; anything else
         # (full-tensor residual adds, mid-axis broadcasts) keeps the
         # composite below

@@ -35,10 +35,10 @@ _BN_SINGLE_PASS = False
 # BN compute for bf16 activations: True keeps elementwise math in bf16 with
 # f32 reduction accumulators (TPU-kernel style); False casts the activation
 # to f32 first.  Interleaved A/B on the chip: 55.1 vs 55.6 ms ResNet-50
-# step — consistently ~1% faster, standard numerics (docs/perf_r03.md).
+# step — consistently ~1% faster, standard numerics (r3 chip round).
 _BN_BF16_COMPUTE = True
 
-# Round-5 (docs/perf_r05.md): the ResNet-50 profile showed XLA fusing the BN
+# r5 chip round: the ResNet-50 profile showed XLA fusing the BN
 # batch-stat reductions INTO the producing convolutions ("multiply_reduce_
 # fusion" convolution-fusion events at 9-43 TF/s vs 90-190 TF/s for clean
 # convs) — the reduce epilogue wrecks the conv's MXU tiling.  With
@@ -56,7 +56,7 @@ _BN_UNFUSE_CONV = False
 # activations (|mean|/std = O(1)), and for bf16 activations the input's own
 # 8-bit mantissa dominates any accumulator cancellation, so the bf16 path
 # takes the fused pass by default (interleaved A/B on the v5e: ResNet-50
-# step 103.9 vs 115.4 ms, a 10% step win — docs/perf_r05.md).  f32 stays
+# step 103.9 vs 115.4 ms, a 10% step win — r5 chip round).  f32 stays
 # two-pass unless _BN_STATS_FUSED_PASS is toggled on (keeps OpTest goldens
 # vs the reference exact); _BN_BF16_FUSED_DEFAULT=False restores the r4
 # two-pass bf16 lowering (A/B baseline).  fp16 never takes the fused pass
@@ -308,10 +308,11 @@ def _batch_norm(ctx, op, ins):
         saved_mean, saved_var = mean, var
 
     fuse_relu = op.attr("fuse_relu", False)  # core/passes.py fuse_bn_relu
-    from .pallas_kernels import use_pallas
+    from .pallas_kernels import epilogue_shape_ok, use_pallas
 
     inv = jax.lax.rsqrt(var.reshape(bshape) + eps)
-    if use_pallas(ctx) and ch_axis == 1 and not nhwc_internal and x.ndim >= 3:
+    if (use_pallas(ctx) and ch_axis == 1 and not nhwc_internal
+            and x.ndim >= 3 and epilogue_shape_ok(x.shape, x.dtype)):
         # fused epilogue kernel: the normalize/scale/shift(/relu) chain as
         # one roofline-bandwidth pass with per-channel f32 multipliers; the
         # producing conv keeps its clean MXU fusion (stats stay XLA
@@ -388,10 +389,11 @@ def _layer_norm(ctx, op, ins):
     eps = op.attr("epsilon", 1e-5)
     begin = op.attr("begin_norm_axis", 1)
     axes = tuple(range(begin, x.ndim))
-    from .pallas_kernels import fused_ln_residual, use_pallas
+    from .pallas_kernels import fused_ln_residual, ln_shape_ok, use_pallas
 
     if (use_pallas(ctx) and axes == (x.ndim - 1,)
             and scale is not None and bias is not None
+            and ln_shape_ok(x.shape, x.dtype, residual is not None)
             and not _ln_stats_consumed(ctx, op)):
         # one-VMEM-pass kernel (residual add + stats + affine); Mean/Variance
         # slots stay unset — safe because _ln_stats_consumed proved nothing
@@ -473,17 +475,18 @@ def _softmax_with_cross_entropy(ctx, op, ins):
 
     Never materializes the [N, V] log-prob tensor — at BERT's 30522 vocab
     the old log_softmax path streamed ~20 GB/step of f32 logp/softmax
-    through HBM (docs/perf_r05.md profile: ~25 ms of a 261 ms step).  All
+    through HBM (r5 chip round profile: ~25 ms of a 261 ms step).  All
     reductions accumulate in f32 even for bf16 logits; the max shift is
     stop_gradient'd (pure numerical shift, the standard logsumexp trick),
     so autodiff yields the exact softmax-minus-onehot gradient as one
     fused pass over the logits."""
     logits = first(ins, "Logits")
     label = first(ins, "Label")
-    from .pallas_kernels import fused_softmax_xent, use_pallas
+    from .pallas_kernels import fused_softmax_xent, sxe_shape_ok, use_pallas
 
     if (use_pallas(ctx) and not op.attr("soft_label", False)
             and logits.ndim >= 2
+            and sxe_shape_ok(logits.shape, logits.dtype)
             and not _outputs_consumed(ctx, op, ("Softmax",))):
         # one-VMEM-pass kernel (max + logsumexp + picked logit together;
         # bwd recomputes the softmax flash-style).  The Softmax slot stays
@@ -599,7 +602,7 @@ def _ring_attention(ctx, op, ins):
 
 
 # fused_attention: shortest kv length that routes to the Pallas flash
-# kernel on TPU.  Interleaved full-model A/Bs (docs/perf_r04.md) measured
+# kernel on TPU.  Interleaved full-model A/Bs (r4 chip round) measured
 # the Pallas kernel SLOWER than XLA's own fused attention at both seq 128
 # (398 vs 293 ms BERT step) and seq 512 (311 vs 242 ms) on v5e, so the
 # kernel is kept as a MEMORY guard only: beyond this length the [B,H,L,L]
@@ -650,7 +653,7 @@ def _fused_attention(ctx, op, ins):
         # jnp formulation below (BERT step 305 vs 275 ms; isolated
         # microbench 10.9 vs 7.9 ms/layer-fwd) — at L<=512 XLA's own
         # softmax/matmul fusion wins on this chip, extending r4's negative
-        # result for the stock streaming kernel (docs/perf_r05.md).
+        # result for the stock streaming kernel (r5 chip round).
         # bias is mask-derived in every caller, hence non-differentiable.
         from .pallas_attention import fused_sdpa
 
@@ -663,7 +666,7 @@ def _fused_attention(ctx, op, ins):
     # activation dtype for the context matmul.  The previous revision cast
     # q/k/v to f32 BEFORE the einsums, which ran the batched matmuls at the
     # f32 MXU rate and doubled score-tensor HBM traffic — profiled at
-    # 13.6 TF/s on the BERT bench (docs/perf_r05.md).
+    # 13.6 TF/s on the BERT bench (r5 chip round).
     #
     # score_dtype="bfloat16" (opt-in) additionally materializes the
     # [B,H,Lq,Lk] score tensor in bf16 — halves the dominant attention HBM

@@ -2,7 +2,7 @@
 
 Reference role: operators/fused/fused_attention ambitions + the unfused
 matmul/softmax/matmul stack in layers/nn.py multi-head attention.  The r5
-BERT profile (docs/perf_r05.md) showed the XLA formulation bandwidth-bound
+BERT profile (r5 chip round) showed the XLA formulation bandwidth-bound
 on the [B,H,L,L] f32 score tensor: ~50 ms of a 261 ms step spent streaming
 scores/probs through HBM at 12-16 TF/s.  For L <= 512 the ENTIRE score row
 block fits VMEM, so no online-softmax streaming is needed: each grid step

@@ -29,16 +29,24 @@ badly (see docs/performance.md):
     composite's intermediate never round-trips through HBM.
 
 Every kernel is an OPT-IN lowering alternative behind `FLAGS_use_pallas`
-(ops/nn_ops.py, ops/optimizer_ops.py): platform != TPU or flag off falls
-back to the XLA composite, which each kernel matches to per-dtype tolerance
-(tests/test_pallas_kernels.py runs the parity matrix in interpret mode; the
-interleaved device A/B lives in tools/opbench.py --fused).
+(ops/nn_ops.py, ops/math_ops.py, ops/optimizer_ops.py).  A call site keeps
+the XLA composite when the flag is off, when the platform is not a TPU, or
+when the kernel's `*_shape_ok` predicate refuses the shape; which of the two
+a compiled step holds is read off the step itself — each `pallas_call` is
+named, and `chip_smoke.py` prints the kernels it finds
+(`tpu_custom_call` in the compiled text).  Each kernel matches its composite
+to per-dtype tolerance: tests/test_pallas_kernels.py runs the parity matrix
+in interpret mode, tests/test_chip_compile.py compiles every kernel for a
+described v5e at BERT-base / ResNet-50 widths, chip_smoke.py runs them
+compiled; the interleaved device A/B lives in tools/opbench.py --fused.
 
 Kernel-shape contract: the last axis is the vector (lane) axis; leading
-axes flatten to rows.  Row slabs are chosen so slab * row_bytes fits the
-VMEM budget; slab counts that do not divide the row count fall back to the
-composite rather than pad (padding would re-introduce the HBM copy the
-kernel exists to remove).
+axes flatten to rows.  Row slabs are whole Mosaic tiles — a multiple of 8
+rows (16 for bf16) or the whole array — chosen so one grid step's rows fit
+the VMEM budget; per-row operands are carried as [R, 1], never as a blocked
+rank-1 array.  Row counts no such slab divides keep the composite rather
+than pad (padding would re-introduce the HBM copy the kernel exists to
+remove); the elementwise Adam update alone lets its last slab overhang.
 """
 from __future__ import annotations
 
@@ -50,17 +58,38 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+# Half of the chip's 16 MB scoped VMEM: each kernel counts the f32 rows one
+# grid step keeps live, Mosaic's double buffers of them take the other half.
 _VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def _pick_slab(n_rows: int, row_bytes: int, n_bufs: int) -> int:
-    """Largest divisor of n_rows whose working set fits the VMEM budget."""
-    per_row = max(row_bytes * n_bufs, 1)
-    slab = max(1, int(_VMEM_BUDGET // per_row))
-    slab = min(slab, n_rows)
-    while n_rows % slab:
-        slab -= 1
-    return slab
+def _sublane(*dtypes) -> int:
+    """Rows in one Mosaic tile of the narrowest operand: 8 for 4-byte
+    dtypes, 16 for bf16."""
+    return max(32 // jnp.dtype(d).itemsize for d in dtypes)
+
+
+def _lane_pad(width: int) -> int:
+    """VMEM holds the last axis in whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+def _n_rows(shape) -> int:
+    """Rows of the [R, last] view the kernels work on."""
+    return int(np.prod(shape[:-1])) if len(shape) > 1 else 1
+
+
+def _pick_slab(n_rows: int, row_bytes: int, sublane: int = 8):
+    """Largest row slab that divides n_rows, fits the VMEM budget and that
+    Mosaic can tile: the whole array, or a multiple of the sublane tile.
+    None when there is no such slab — the kernel cannot take the shape."""
+    cap = max(1, _VMEM_BUDGET // max(row_bytes, 1))
+    if n_rows <= cap:
+        return n_rows
+    for slab in range(cap - cap % sublane, 0, -sublane):
+        if n_rows % slab == 0:
+            return slab
+    return None
 
 
 def pallas_supported(platform) -> bool:
@@ -86,8 +115,7 @@ def _ln_rows(x):
     """[.., D] -> ([R, D], unflatten)."""
     D = x.shape[-1]
     lead = x.shape[:-1]
-    R = int(np.prod(lead)) if lead else 1
-    return x.reshape(R, D), lambda y: y.reshape(*lead, D)
+    return x.reshape(_n_rows(x.shape), D), lambda y: y.reshape(*lead, D)
 
 
 def _ln_fwd_kernel(eps, has_res):
@@ -160,9 +188,22 @@ def fused_ln_residual(x, res, scale, bias, eps, interpret=False):
     return out
 
 
+def _ln_slab(R, D, dtype, has_res, bwd):
+    n_f32 = (5 if bwd else 3) + bool(has_res)
+    return _pick_slab(R, _lane_pad(D) * 4 * n_f32, _sublane(dtype))
+
+
+def ln_shape_ok(shape, dtype, has_res) -> bool:
+    """The no-padding contract: the rows must split into whole slabs the
+    backward (the tighter budget) can tile, else the lowering keeps the
+    composite."""
+    return _ln_slab(_n_rows(shape), shape[-1], dtype, has_res,
+                    bwd=True) is not None
+
+
 def _ln_call(x2, res2, scale, bias, eps, interpret):
     R, D = x2.shape
-    slab = _pick_slab(R, D * 4 * (4 if res2 is not None else 3), 1)
+    slab = _ln_slab(R, D, x2.dtype, res2 is not None, bwd=False)
     row_spec = pl.BlockSpec((slab, D), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((D,), lambda i: (0,))
     args = (x2,) + ((res2,) if res2 is not None else ()) + (scale, bias)
@@ -174,6 +215,7 @@ def _ln_call(x2, res2, scale, bias, eps, interpret):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), x2.dtype),
         interpret=interpret,
+        name="ln_residual_fwd",
     )(*args)
 
 
@@ -190,7 +232,7 @@ def _ln_bwd(eps, interpret, saved, g):
     res2 = None if res is None else _ln_rows(res)[0]
     g2 = _ln_rows(g)[0]
     R, D = x2.shape
-    slab = _pick_slab(R, D * 4 * (6 if res2 is not None else 5), 1)
+    slab = _ln_slab(R, D, x2.dtype, res2 is not None, bwd=True)
     row_spec = pl.BlockSpec((slab, D), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((D,), lambda i: (0,))
     acc_spec = pl.BlockSpec((D,), lambda i: (0,))
@@ -208,6 +250,7 @@ def _ln_bwd(eps, interpret, saved, g):
             jax.ShapeDtypeStruct((D,), jnp.float32),
         ],
         interpret=interpret,
+        name="ln_residual_bwd",
     )(*args)
     dx = unflat(dx2)
     dres = None if res is None else dx.astype(res.dtype)
@@ -224,8 +267,8 @@ fused_ln_residual.defvjp(_ln_fwd, _ln_bwd)
 
 def _epilogue_fwd_kernel(relu):
     def kern(x_ref, m_ref, a_ref, o_ref):
-        y = (x_ref[...].astype(jnp.float32) * m_ref[...][:, None]
-             + a_ref[...][:, None])
+        # m/a are (slab, 1) blocks: one multiplier per row, lane-broadcast
+        y = x_ref[...].astype(jnp.float32) * m_ref[...] + a_ref[...]
         if relu:
             y = jnp.maximum(y, 0.0)
         o_ref[...] = y.astype(o_ref.dtype)
@@ -236,38 +279,54 @@ def _epilogue_fwd_kernel(relu):
 def _epilogue_bwd_kernel(relu, out_dtype):
     def kern(x_ref, m_ref, a_ref, g_ref, dx_ref, dm_ref, da_ref):
         x = x_ref[...].astype(jnp.float32)
-        mul = m_ref[...][:, None]
+        mul = m_ref[...]
         g = g_ref[...].astype(jnp.float32)
         if relu:
-            live = (x * mul + a_ref[...][:, None]) > 0.0
+            live = (x * mul + a_ref[...]) > 0.0
             g = jnp.where(live, g, 0.0)
         dx_ref[...] = (g * mul).astype(out_dtype)
         # dm/da are PER-ROW and each grid step owns a disjoint row slab
-        # (BlockSpec i -> (i,)), so a plain store is complete — unlike
+        # (BlockSpec i -> (i, 0)), so a plain store is complete — unlike
         # _ln_bwd_kernel, whose dscale/dbias block is shared across steps
         # (i -> (0,)) and genuinely accumulates.
-        dm_ref[...] = jnp.sum(g * x, axis=-1)
-        da_ref[...] = jnp.sum(g, axis=-1)
+        dm_ref[...] = jnp.sum(g * x, axis=-1, keepdims=True)
+        da_ref[...] = jnp.sum(g, axis=-1, keepdims=True)
 
     return kern
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def fused_scale_shift_relu(x, mul, add, relu=True, interpret=False):
-    """y = max(x * mul + add, 0) with PER-ROW mul/add over x:[R, W].
+    """y = max(x * mul + add, 0) with PER-ROW mul/add [R, 1] over x:[R, W].
 
     The BN-epilogue shape: callers flatten NCHW to [N*C, H*W] and tile the
-    per-channel f32 multipliers to N*C rows (ops/nn_ops.py _batch_norm).
+    per-channel f32 multipliers to N*C rows (bn_epilogue below).  The
+    per-row vectors are rank 2 because Mosaic tiles a blocked rank-1
+    operand differently from the layout XLA hands it.
     Backward masks by recomputed sign, accumulates dmul/dadd per row."""
     out, _ = _epilogue_fwd(x, mul, add, relu, interpret)
     return out
 
 
+def _epilogue_slab(R, W, dtype, bwd):
+    # each [slab, 1] per-row block occupies a whole 128-lane tile row
+    n_rows, n_vecs = (3, 4) if bwd else (2, 2)
+    return _pick_slab(R, (_lane_pad(W) * n_rows + 128 * n_vecs) * 4,
+                      _sublane(dtype))
+
+
+def epilogue_shape_ok(shape, dtype) -> bool:
+    """NC* activation -> [N*C, prod(spatial)] rows must split into whole
+    slabs, else the lowering keeps the composite."""
+    W = int(np.prod(shape[2:])) if len(shape) > 2 else 1
+    return _epilogue_slab(shape[0] * shape[1], W, dtype, True) is not None
+
+
 def _epilogue_fwd(x, mul, add, relu, interpret):
     R, W = x.shape
-    slab = _pick_slab(R, W * 4 * 2, 1)
+    slab = _epilogue_slab(R, W, x.dtype, bwd=False)
     row_spec = pl.BlockSpec((slab, W), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((slab,), lambda i: (i,))
+    vec_spec = pl.BlockSpec((slab, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         _epilogue_fwd_kernel(relu),
         grid=(R // slab,),
@@ -275,6 +334,7 @@ def _epilogue_fwd(x, mul, add, relu, interpret):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, W), x.dtype),
         interpret=interpret,
+        name="bn_epilogue_fwd",
     )(x, mul, add)
     return out, (x, mul, add)
 
@@ -282,9 +342,9 @@ def _epilogue_fwd(x, mul, add, relu, interpret):
 def _epilogue_bwd(relu, interpret, saved, g):
     x, mul, add = saved
     R, W = x.shape
-    slab = _pick_slab(R, W * 4 * 3, 1)
+    slab = _epilogue_slab(R, W, x.dtype, bwd=True)
     row_spec = pl.BlockSpec((slab, W), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((slab,), lambda i: (i,))
+    vec_spec = pl.BlockSpec((slab, 1), lambda i: (i, 0))
     dx, dm, da = pl.pallas_call(
         _epilogue_bwd_kernel(relu, x.dtype),
         grid=(R // slab,),
@@ -292,10 +352,11 @@ def _epilogue_bwd(relu, interpret, saved, g):
         out_specs=[row_spec, vec_spec, vec_spec],
         out_shape=[
             jax.ShapeDtypeStruct((R, W), x.dtype),
-            jax.ShapeDtypeStruct((R,), jnp.float32),
-            jax.ShapeDtypeStruct((R,), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="bn_epilogue_bwd",
     )(x, mul, add, g)
     return dx, dm.astype(mul.dtype), da.astype(add.dtype)
 
@@ -310,8 +371,8 @@ def bn_epilogue(x, mul, add, relu, interpret=False):
     N, C = x.shape[0], x.shape[1]
     W = int(np.prod(x.shape[2:])) if x.ndim > 2 else 1
     x2 = x.reshape(N * C, W)
-    mul_r = jnp.tile(mul.reshape(-1), N)
-    add_r = jnp.tile(add.reshape(-1), N)
+    mul_r = jnp.tile(mul.reshape(-1), N)[:, None]
+    add_r = jnp.tile(add.reshape(-1), N)[:, None]
     y = fused_scale_shift_relu(x2, mul_r, add_r, bool(relu), interpret)
     return y.reshape(x.shape)
 
@@ -362,12 +423,21 @@ def fused_adam(p, g, m, v, lr_t, beta1, beta2, eps, interpret=False):
     m2 = m.reshape(R, _ADAM_LANE)
     v2 = v.reshape(R, _ADAM_LANE)
     lr2 = jnp.asarray(lr_t, jnp.float32).reshape(1, 1)
-    slab = _pick_slab(R, _ADAM_LANE * 4 * 7, 1)
+    # The update is elementwise, so the slab need not divide R: the last
+    # grid step overhangs the array, computes on padding and its
+    # out-of-bounds rows are never written back.  (R is 2*3*3*5087 for the
+    # BERT embedding — no aligned divisor exists.)
+    # 7 blocks a step (p/m/v/g in, p/m/v out) plus temporaries: counted
+    # as 7 rows, the BERT embedding's slab took 18.2 MB of the 16 MB scoped
+    # VMEM inside the whole train step (alone it compiled); 10 leaves a
+    # fifth spare
+    cap = _VMEM_BUDGET // (_ADAM_LANE * 4 * 10)
+    slab = R if R <= cap else cap - cap % 16  # whole tiles, f32 or bf16
     row_spec = pl.BlockSpec((slab, _ADAM_LANE), lambda i: (i, 0))
     lr_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     po, mo, vo = pl.pallas_call(
         _adam_kernel(beta1, beta2, eps, p2.dtype),
-        grid=(R // slab,),
+        grid=(pl.cdiv(R, slab),),
         in_specs=[row_spec, row_spec, row_spec, row_spec, lr_spec],
         out_specs=[row_spec, row_spec, row_spec],
         out_shape=[
@@ -377,6 +447,7 @@ def fused_adam(p, g, m, v, lr_t, beta1, beta2, eps, interpret=False):
         ],
         input_output_aliases={0: 0, 1: 1, 2: 2},
         interpret=interpret,
+        name="adam_slab",
     )(p2, m2, v2, g2, lr2)
     return po.reshape(shape), mo.reshape(shape), vo.reshape(shape)
 
@@ -395,14 +466,13 @@ def fused_adam(p, g, m, v, lr_t, beta1, beta2, eps, interpret=False):
 def _sxe_fwd_kernel(ignore_index):
     def kern(x_ref, l_ref, o_ref):
         x = x_ref[...].astype(jnp.float32)
-        lab = l_ref[...].astype(jnp.int32)
+        lab = l_ref[...]  # (slab, 1) int32
         m = jnp.max(x, axis=-1, keepdims=True)
         lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True)) + m
         iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-        picked = jnp.sum(jnp.where(iota == lab[:, None], x, 0.0),
+        picked = jnp.sum(jnp.where(iota == lab, x, 0.0),
                          axis=-1, keepdims=True)
-        loss = (lse - picked)[:, 0]
-        o_ref[...] = jnp.where(lab == ignore_index, 0.0, loss)
+        o_ref[...] = jnp.where(lab == ignore_index, 0.0, lse - picked)
 
     return kern
 
@@ -410,15 +480,14 @@ def _sxe_fwd_kernel(ignore_index):
 def _sxe_bwd_kernel(ignore_index, out_dtype):
     def kern(x_ref, l_ref, g_ref, dx_ref):
         x = x_ref[...].astype(jnp.float32)
-        lab = l_ref[...].astype(jnp.int32)
+        lab = l_ref[...]  # (slab, 1) int32
         m = jnp.max(x, axis=-1, keepdims=True)
         e = jnp.exp(x - m)
         sm = e / jnp.sum(e, axis=-1, keepdims=True)
         iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-        onehot = (iota == lab[:, None]).astype(jnp.float32)
-        g = g_ref[...][:, None]
-        dx = (sm - onehot) * g
-        dx = jnp.where((lab == ignore_index)[:, None], 0.0, dx)
+        onehot = (iota == lab).astype(jnp.float32)
+        dx = (sm - onehot) * g_ref[...]
+        dx = jnp.where(lab == ignore_index, 0.0, dx)
         dx_ref[...] = dx.astype(out_dtype)
 
     return kern
@@ -436,29 +505,44 @@ def fused_softmax_xent(logits, labels, ignore_index=-100, interpret=False):
     return out
 
 
+def _sxe_slab(R, V, dtype, bwd):
+    return _pick_slab(R, _lane_pad(V) * 4 * (4 if bwd else 3),
+                      _sublane(dtype))
+
+
+def sxe_shape_ok(shape, dtype) -> bool:
+    """[.., V] logits: the rows must split into whole slabs, else the
+    lowering keeps the composite."""
+    return _sxe_slab(_n_rows(shape), shape[-1], dtype, bwd=True) is not None
+
+
 def _sxe_fwd(logits, labels, ignore_index, interpret):
+    # per-row operands (labels, loss, upstream grad) are [R, 1]: a blocked
+    # rank-1 operand must be a multiple of 128 rows, which a [slab, V]
+    # logits block at vocabulary width cannot afford
     R, V = logits.shape
-    slab = _pick_slab(R, V * 4 * 3, 1)
+    slab = _sxe_slab(R, V, logits.dtype, bwd=False)
     row_spec = pl.BlockSpec((slab, V), lambda i: (i, 0))
-    lab_spec = pl.BlockSpec((slab,), lambda i: (i,))
+    lab_spec = pl.BlockSpec((slab, 1), lambda i: (i, 0))
     loss = pl.pallas_call(
         _sxe_fwd_kernel(int(ignore_index)),
         grid=(R // slab,),
         in_specs=[row_spec, lab_spec],
         out_specs=lab_spec,
-        out_shape=jax.ShapeDtypeStruct((R,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
         interpret=interpret,
-    )(logits, labels.astype(jnp.int32))
-    return loss[:, None], (logits, labels)
+        name="softmax_xent_fwd",
+    )(logits, labels.astype(jnp.int32)[:, None])
+    return loss, (logits, labels)
 
 
 def _sxe_bwd(ignore_index, interpret, saved, g):
     logits, labels = saved
     R, V = logits.shape
-    g1 = g.reshape(R).astype(jnp.float32)
-    slab = _pick_slab(R, V * 4 * 4, 1)
+    g1 = g.reshape(R, 1).astype(jnp.float32)
+    slab = _sxe_slab(R, V, logits.dtype, bwd=True)
     row_spec = pl.BlockSpec((slab, V), lambda i: (i, 0))
-    lab_spec = pl.BlockSpec((slab,), lambda i: (i,))
+    lab_spec = pl.BlockSpec((slab, 1), lambda i: (i, 0))
     dx = pl.pallas_call(
         _sxe_bwd_kernel(int(ignore_index), logits.dtype),
         grid=(R // slab,),
@@ -466,7 +550,8 @@ def _sxe_bwd(ignore_index, interpret, saved, g):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, V), logits.dtype),
         interpret=interpret,
-    )(logits, labels.astype(jnp.int32), g1)
+        name="softmax_xent_bwd",
+    )(logits, labels.astype(jnp.int32)[:, None], g1)
     return dx, np.zeros(labels.shape, jax.dtypes.float0)
 
 
@@ -486,18 +571,41 @@ fused_softmax_xent.defvjp(_sxe_fwd, _sxe_bwd)
 _BIAS_ACTS = ("relu", "gelu")
 
 
+_ERF_ALPHA = (0.00022905065861350646, 0.0034082910107109506,
+              0.050955695062380861, 0.18520832239976145, 1.128379143519084)
+_ERF_BETA = (-1.1791602954361697e-7, 0.000023547966471313185,
+             0.0010179625278914885, 0.014070470171167667,
+             0.11098505178285362, 0.49746925110067538, 1.0)
+
+
+def _erf(x):
+    """f32 erf from mul/add/div only: Mosaic has no lowering for the erf
+    primitive.  The clamped rational x*P(x^2)/Q(x^2) XLA itself expands
+    f32 erf into, so kernel and composite agree to ~4e-7."""
+    x = jnp.clip(x, -3.832506856900711, 3.832506856900711)
+    x2 = x * x
+
+    def poly(coef):
+        acc = coef[0]
+        for c in coef[1:]:
+            acc = acc * x2 + c
+        return acc
+
+    return x * poly(_ERF_ALPHA) / poly(_ERF_BETA)
+
+
 def _act_fwd(z, act):
     if act == "relu":
         return jnp.maximum(z, 0.0)
     # exact gelu (jax.nn.gelu approximate=False): z * Phi(z)
-    return 0.5 * z * (1.0 + jax.lax.erf(z * (2.0 ** -0.5)))
+    return 0.5 * z * (1.0 + _erf(z * (2.0 ** -0.5)))
 
 
 def _act_grad(z, act):
     if act == "relu":
         return (z > 0.0).astype(jnp.float32)
     phi = jnp.exp(-0.5 * z * z) * 0.3989422804014327  # N(0,1) pdf
-    return 0.5 * (1.0 + jax.lax.erf(z * (2.0 ** -0.5))) + z * phi
+    return 0.5 * (1.0 + _erf(z * (2.0 ** -0.5))) + z * phi
 
 
 def _bias_act_fwd_kernel(act):
@@ -539,10 +647,22 @@ def fused_bias_act(x, bias, act="gelu", interpret=False):
     return out
 
 
+def _bias_act_slab(R, D, dtype):
+    # 4 f32 rows per row: in/out blocks plus the gelu polynomial's live
+    # temporaries (2 overran the chip's 16 MB scoped VMEM at [32768, 3072])
+    return _pick_slab(R, _lane_pad(D) * 4 * 4, _sublane(dtype))
+
+
+def bias_act_shape_ok(shape, dtype) -> bool:
+    """[.., D] activation: the rows must split into whole slabs, else the
+    lowering keeps the composite."""
+    return _bias_act_slab(_n_rows(shape), shape[-1], dtype) is not None
+
+
 def _bias_act_fwd(x, bias, act, interpret):
     assert act in _BIAS_ACTS, act
     R, D = x.shape
-    slab = _pick_slab(R, D * 4 * 2, 1)
+    slab = _bias_act_slab(R, D, x.dtype)
     row_spec = pl.BlockSpec((slab, D), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((D,), lambda i: (0,))
     out = pl.pallas_call(
@@ -552,6 +672,7 @@ def _bias_act_fwd(x, bias, act, interpret):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
         interpret=interpret,
+        name="bias_act_fwd",
     )(x, bias)
     return out, (x, bias)
 
@@ -559,7 +680,7 @@ def _bias_act_fwd(x, bias, act, interpret):
 def _bias_act_bwd(act, interpret, saved, g):
     x, bias = saved
     R, D = x.shape
-    slab = _pick_slab(R, D * 4 * 3, 1)
+    slab = _bias_act_slab(R, D, x.dtype)
     row_spec = pl.BlockSpec((slab, D), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((D,), lambda i: (0,))
     dx, db = pl.pallas_call(
@@ -572,6 +693,7 @@ def _bias_act_bwd(act, interpret, saved, g):
             jax.ShapeDtypeStruct((D,), jnp.float32),
         ],
         interpret=interpret,
+        name="bias_act_bwd",
     )(x, bias, g)
     return dx, db.astype(bias.dtype)
 

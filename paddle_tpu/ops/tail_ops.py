@@ -1,4 +1,4 @@
-"""API-tail op lowerings (VERDICT r3 #6 audit): the remaining reference op
+"""API-tail op lowerings (r3 review #6 audit): the remaining reference op
 families behind `paddle.fluid.layers` entries that had no lowering yet.
 Each cites its reference kernel; gradients come from autodiff.
 """

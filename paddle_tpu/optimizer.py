@@ -247,7 +247,7 @@ class AdamOptimizer(Optimizer):
             self._add_accumulator("moment2", p)
             # beta powers MUST be f32 regardless of param dtype: bf16 cannot
             # represent 0.999 (rounds to 1.0), which zeroes the bias-corrected
-            # lr and silently freezes training (docs/perf_r05.md)
+            # lr and silently freezes training (r5 chip round)
             self._add_accumulator("beta1_pow_acc", p, fill_value=self._beta1, shape=[1],
                                   dtype="float32")
             self._add_accumulator("beta2_pow_acc", p, fill_value=self._beta2, shape=[1],

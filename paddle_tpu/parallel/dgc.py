@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
-
 
 def dgc_allreduce(grads, u, v, mesh: Mesh, axis_name: str = "dp",
                   sparsity: float = 0.99, momentum: float = 0.9):
@@ -51,7 +49,7 @@ def dgc_allreduce(grads, u, v, mesh: Mesh, axis_name: str = "dp",
         dense = dense.at[all_idx.reshape(-1)].add(all_vals.reshape(-1))
         return dense[None], u_new[None], v_res[None]
 
-    shard = shard_map(
+    shard = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name), P(axis_name)),

@@ -66,10 +66,7 @@ def init_distributed(trainer_id: Optional[int] = None,
         return  # single process: nothing to bootstrap
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         # cross-process collectives on the CPU backend need gloo
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     # The persistent compilation cache (FLAGS_compile_cache_dir) corrupts
     # the heap when a cross-process executable round-trips through it on
     # this jaxlib (observed deterministically: malloc corruption / SIGSEGV

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
-
 
 def _lookup_local(ids, table_local, axis_name: str):
     n = jax.lax.psum(1, axis_name)
@@ -51,7 +49,7 @@ def sharded_lookup(ids, table, mesh: Mesh, axis_name: str = "ep"):
     """ids: int (...,) replicated; table: (V, D) row-sharded over axis_name.
     Returns (..., D) replicated embeddings."""
     fn = functools.partial(_lookup_local, axis_name=axis_name)
-    shard = shard_map(
+    shard = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), P(axis_name, None)),

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
-
 
 def _stack_params(params, n):
     return jax.tree_util.tree_map(
@@ -57,7 +55,7 @@ def local_sgd_train(step_fn, params, batches, mesh: Mesh, axis_name: str = "dp",
         pstack_out = jax.tree_util.tree_map(lambda a: a[None], p)
         return pstack_out, losses[None]
 
-    shard = shard_map(
+    shard = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name)),

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
-
 
 def _pipeline_local(params, xs, stage_id, fn: Callable, axis_name: str, S: int):
     """Per-device body: params = this stage's params (leading axis 1),
@@ -60,8 +58,8 @@ def _pipeline_local(params, xs, stage_id, fn: Callable, axis_name: str, S: int):
     _, ys = jax.lax.fori_loop(0, T, body, (jnp.zeros(mb_shape, xs.dtype), ys))
     # only the last stage's ys is meaningful; a masked psum broadcasts it
     # to the ring AND is provably replicated over axis_name, which lets
-    # replication checking (jax_compat legacy path) verify out_specs=P()
-    # where all_gather-then-index defeated the inference
+    # replication checking verify out_specs=P() where
+    # all_gather-then-index defeated the inference
     return jax.lax.psum(
         jnp.where(idx == S - 1, ys, jnp.zeros_like(ys)), axis_name)
 
@@ -83,7 +81,7 @@ def gpipe(
     """
     S = mesh.shape[axis_name]
     param_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    shard = shard_map(
+    shard = jax.shard_map(
         functools.partial(_pipeline_local, fn=fn, axis_name=axis_name, S=S),
         mesh=mesh,
         in_specs=(param_specs, P(), P(axis_name)),

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
 
 _NEG = -1e9
 
@@ -109,7 +108,7 @@ def ring_attention(
     b_ax = batch_axis if (batch_axis and batch_axis in mesh.shape) else None
     spec = P(b_ax, None, axis_name, None)
     fn = functools.partial(_ring_attention_local, axis_name=axis_name, causal=causal)
-    shard = shard_map(
+    shard = jax.shard_map(
         lambda q_, k_, v_: fn(q_, k_, v_),
         mesh=mesh,
         in_specs=(spec, spec, spec),

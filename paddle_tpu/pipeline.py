@@ -90,8 +90,7 @@ def train_loop(
     pipelines cannot OOM HBM.
 
     Note the skip trade-off: the FLAGS_check_nan_inf guard runs at
-    resolution, so non-logged steps are not NaN-checked (steps with
-    deferred host-eval side effects are always resolved; a NaN in the
+    resolution, so non-logged steps are not NaN-checked (a NaN in the
     params still surfaces at the next logged step's loss).  Passing
     `resolve_all=True` closes that window — every step pays the host
     copy + guard, which is what the resilience layer's NaN modes need to
@@ -125,10 +124,7 @@ def train_loop(
         step_i, handles = inflight.popleft()
         gauge.set(len(inflight))
         want_log = step_i % log_period == 0
-        # deferred host-eval ops (callback-less platforms) update scope
-        # accumulators at resolution — those steps must resolve even when
-        # they aren't logged, or the metric silently misses updates
-        must_resolve = want_log or resolve_all or handles[0].has_deferred_host_work
+        must_resolve = want_log or resolve_all
         t_b0 = time.perf_counter()
         with _MON.span("pipeline.host_blocked", step=step_i, logged=want_log):
             try:
@@ -182,8 +178,8 @@ def train_loop(
             except BaseException as e:
                 # a synchronous dispatch failure (hook, compile/enqueue
                 # path) belongs to this step — but OLDER steps still in
-                # flight have unresolved guards (sticky NaN check,
-                # deferred host work).  Drain them FIRST: if one fails,
+                # flight have unresolved guards (the sticky NaN
+                # check).  Drain them FIRST: if one fails,
                 # ITS error propagates and supersedes this one, because
                 # recovery must rewind to the OLDEST failure — keying
                 # recovery on the newer step would restore a snapshot

@@ -1,4 +1,4 @@
-"""API-surface audit gate (VERDICT r3 #6): every entry of the reference
+"""API-surface audit gate (r3 review #6): every entry of the reference
 /root/reference/paddle/fluid/API.spec must either resolve on paddle_tpu or
 be recorded with a rationale in API_DEVIATIONS.md — exactly one of the two."""
 import os

@@ -1,0 +1,154 @@
+"""Every `pallas_call` the main path can reach, compiled by the TPU's own
+compiler for a described (not attached) v5e at BERT-base / ResNet-50 widths,
+forward and backward.
+
+Interpret mode (tests/test_pallas_kernels.py) checks the numbers; it cannot
+see what Mosaic refuses: a block that is not a whole (8|16, 128) tile, a
+blocked rank-1 operand, a primitive with no TPU lowering, a kernel that
+overruns scoped VMEM.  These compiles can, at no chip time.  Nothing runs,
+so a pass here says nothing about results — `chip_smoke.py` does that on the
+chip.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import pallas_kernels as pk
+from paddle_tpu.ops.pallas_attention import fused_sdpa
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one device of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except (ImportError, RuntimeError, ValueError, NotImplementedError) as e:
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """An executable for a described chip is written to the persistent
+    cache but cannot be read back without the chip; every later compile
+    would warn.  Keep the cache out of these compiles."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _ln(res):
+    if res:
+        return lambda x, r, s, b: pk.fused_ln_residual(x, r, s, b, 1e-5)
+    return lambda x, s, b: pk.fused_ln_residual(x, None, s, b, 1e-5)
+
+
+def _adam(p, g, m, v, lr):
+    return pk.fused_adam(p, g, m, v, lr, 0.9, 0.999, 1e-8)
+
+
+def _flash(q, k, v):
+    from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=False, sm_scale=0.125)
+
+
+# BERT-base: batch 256 x seq 128 rows (bench.py's batch), d_model 768,
+# d_ff 3072, vocab 30522, 12 heads of 64.  ResNet-50: NCHW bf16, batch 128
+# training and the serving buckets' batch 8.
+_ROWS = 256 * 128
+# name -> (fn, [(shape, dtype)], grad argnums; () compiles forward only)
+CASES = {
+    "ln_residual_bf16": (
+        _ln(True), [((256, 128, 768), BF16), ((256, 128, 768), BF16),
+                    ((768,), F32), ((768,), F32)], (0, 1, 2, 3)),
+    "ln_plain_bf16": (
+        _ln(False), [((256, 128, 768), BF16), ((768,), F32), ((768,), F32)],
+        (0, 1, 2)),
+    "ln_plain_f32_embedding": (
+        _ln(False), [((256, 128, 768), F32), ((768,), F32), ((768,), F32)],
+        (0, 1, 2)),
+    "adam_ffn_weight": (
+        _adam, [((768, 3072), F32)] * 4 + [((), F32)], ()),
+    "adam_embedding_ragged_slab": (
+        _adam, [((30522, 768), F32)] * 4 + [((), F32)], ()),
+    "adam_bias_one_slab": (
+        _adam, [((768,), F32)] * 4 + [((), F32)], ()),
+    "softmax_xent_bf16_vocab": (
+        lambda x, y: pk.fused_softmax_xent(x, y, -100),
+        [((_ROWS, 30522), BF16), ((_ROWS,), I32)], (0,)),
+    "softmax_xent_f32_vocab": (
+        lambda x, y: pk.fused_softmax_xent(x, y, -100),
+        [((_ROWS, 30522), F32), ((_ROWS,), I32)], (0,)),
+    "bias_gelu_ffn": (
+        lambda x, b: pk.fused_bias_act(x, b, "gelu"),
+        [((_ROWS, 3072), BF16), ((3072,), F32)], (0, 1)),
+    "bias_relu_ffn": (
+        lambda x, b: pk.fused_bias_act(x, b, "relu"),
+        [((_ROWS, 3072), BF16), ((3072,), F32)], (0, 1)),
+    "bn_epilogue_stage1": (
+        lambda x, m, a: pk.bn_epilogue(x, m, a, True),
+        [((128, 64, 56, 56), BF16), ((64,), F32), ((64,), F32)], (0, 1, 2)),
+    "bn_epilogue_stage4_7x7": (
+        lambda x, m, a: pk.bn_epilogue(x, m, a, False),
+        [((128, 2048, 7, 7), BF16), ((2048,), F32), ((2048,), F32)],
+        (0, 1, 2)),
+    "bn_epilogue_stem_serving": (
+        lambda x, m, a: pk.bn_epilogue(x, m, a, True),
+        [((8, 64, 112, 112), BF16), ((64,), F32), ((64,), F32)], ()),
+    "fused_sdpa": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
+        [((256, 12, 128, 64), BF16)] * 3, (0, 1, 2)),
+    "flash_attention_seq2048": (
+        _flash, [((4, 12, 2048, 64), BF16)] * 3, (0, 1, 2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, chip):
+    fn, specs, argnums = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
+    programs = [fn]
+    if argnums:
+        programs.append(jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums))
+    for program in programs:
+        compiled = jax.jit(program).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text(), (
+            f"{name}: compiled without a Mosaic kernel")
+
+
+@pytest.mark.parametrize("kernel,shape,dtype,ok", [
+    ("ln", (256, 128, 768), BF16, True),
+    ("ln", (7, 33, 768), BF16, True),          # 231 rows: one whole-array slab
+    ("ln", (30011, 768), BF16, False),         # prime row count over budget
+    ("sxe", (_ROWS, 30522), BF16, True),
+    ("sxe", (30011, 30522), F32, False),
+    ("bias_act", (_ROWS, 3072), BF16, True),
+    ("bias_act", (30011, 3072), BF16, False),
+    ("epilogue", (128, 64, 56, 56), BF16, True),
+    ("epilogue", (30011, 1, 56, 56), BF16, False),
+])
+def test_shape_predicates_admit_only_tileable_rows(kernel, shape, dtype, ok):
+    """What a call site asks before it leaves the composite: rows that do
+    not split into aligned slabs under the VMEM budget keep the composite
+    instead of reaching the compiler with a block it refuses."""
+    got = {"ln": lambda: pk.ln_shape_ok(shape, dtype, True),
+           "sxe": lambda: pk.sxe_shape_ok(shape, dtype),
+           "bias_act": lambda: pk.bias_act_shape_ok(shape, dtype),
+           "epilogue": lambda: pk.epilogue_shape_ok(shape, dtype)}[kernel]()
+    assert got is ok

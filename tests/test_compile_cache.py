@@ -37,6 +37,7 @@ def _run_child(cache_dir):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["FLAGS_compile_cache_dir"] = cache_dir
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # it would outrank the flag
     env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(__file__))
                          + os.pathsep + env.get("PYTHONPATH", ""))
     out = subprocess.run([sys.executable, "-c", CHILD], env=env,
@@ -63,3 +64,48 @@ def test_compile_cache_flag_registered():
 
     assert fluid.get_flags("FLAGS_compile_cache_dir") == {
         "FLAGS_compile_cache_dir": ""}
+
+
+# --------------------------------------------------------------------------
+# where the cache lives: flags.apply_compile_cache is the one rule
+# --------------------------------------------------------------------------
+
+
+def _apply_recording(monkeypatch, env_dir, flag_dir):
+    """Run the rule with jax.config.update recorded instead of applied."""
+    import jax
+
+    from paddle_tpu import flags
+
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.__setitem__(k, v))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setitem(flags._REGISTRY["FLAGS_compile_cache_dir"], "value",
+                        flag_dir)
+    return flags.apply_compile_cache(flags.CHECKOUT_CACHE_DIR), calls
+
+
+def test_cache_dir_from_the_environment_is_never_overridden(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself; the program sets
+    no directory in code, flag or not."""
+    got, calls = _apply_recording(monkeypatch, "/placed/from/outside",
+                                  "/from/the/flag")
+    assert got == "/placed/from/outside"
+    assert calls == {}, f"code set cache options under the env var: {calls}"
+
+
+def test_cache_dir_defaults_to_one_fixed_path_in_the_checkout(monkeypatch):
+    """Unset: an entry point gets the fixed, git-ignored directory in the
+    checkout — the path is part of the cache key, so it must not move."""
+    from paddle_tpu import flags
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    got, calls = _apply_recording(monkeypatch, None, "")
+    assert got == flags.CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert calls["jax_compilation_cache_dir"] == got
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

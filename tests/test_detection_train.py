@@ -140,7 +140,7 @@ def test_yolov3_loss_golden():
 
 def test_yolov3_trains():
     """tiny conv head + yolov3_loss trains to decreasing loss (the e2e gate
-    VERDICT r3 asked for)."""
+    r3 review asked for)."""
     rng = np.random.RandomState(0)
     n, h, w, C = 4, 4, 4, 3
     m = len(MASK)
@@ -548,7 +548,7 @@ def test_roi_pool_argmax_golden():
 def test_ssd_end_to_end_trains():
     """multi_box_head + ssd_loss assemble a small SSD that trains to
     decreasing loss; detection_output emits padded static detections
-    (VERDICT r3 #4's end-to-end gate for the SSD path)."""
+    (r3 review #4's end-to-end gate for the SSD path)."""
     rng = np.random.RandomState(7)
     main, startup = fluid.Program(), fluid.Program()
     startup.random_seed = 13

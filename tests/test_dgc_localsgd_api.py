@@ -1,4 +1,4 @@
-"""DGC + LocalSGD reachable from the fluid API (VERDICT r3 item 5):
+"""DGC + LocalSGD reachable from the fluid API (r3 review item 5):
 fluid.optimizer.DGCMomentumOptimizer (reference optimizer.py:786) and
 CompiledProgram.with_local_sgd / DistributedStrategy.use_local_sgd
 (reference transpiler/collective.py:249)."""

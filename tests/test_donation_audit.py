@@ -1,7 +1,7 @@
 """tools/donation_audit.py: the static buffer-donation audit over compiled
 train steps, the planted-defect classes it must catch, and the bench-side
 frozen-vs-subresolution param classification it informs (ISSUE 7's
-resolution of BENCH_r05's '18/198 BERT params frozen').
+resolution of the r5 chip record's '18/198 BERT params frozen').
 
 Also covers the ratcheted bench-round gate (perf_report --check-bench) and
 the warmup-until-stable bench windowing (tools/bench_kit.timed_steps),
@@ -21,7 +21,7 @@ from tools import donation_audit as da
 
 def test_zoo_donates_every_persistable_update():
     """Zero non-donated persistable updates across the model zoo — the
-    static proof that BENCH_r05's 18 'frozen' BERT params were a probe
+    static proof that the r5 chip record's 18 'frozen' BERT params were a probe
     artifact (sub-bf16-resolution updates), not a donation drop."""
     reports = da.audit_zoo(tiny=True)
     assert sorted(reports) == ["bert", "deepfm", "mnist", "nmt", "resnet50"]
@@ -326,20 +326,6 @@ def test_check_bench_reads_round_wrapper(tmp_path):
     assert _check(tmp_path, doc) == 0
 
 
-def test_bench_r05_fails_only_on_nmt_spread(capsys):
-    """The committed BENCH_r05.json must clear the MFU floors (they were
-    set from it) and fail exactly the spread gate its NMT entry motivated."""
-    import os
-
-    from tools.perf_report import check_bench
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    assert check_bench(os.path.join(here, "BENCH_r05.json")) == 1
-    out = capsys.readouterr().out
-    assert "nmt: window spread 26.3%" in out
-    assert "fails the ratcheted floor" not in out
-
-
 # --------------------------------------------------------------------------
 # warmup-until-stable bench windowing (tools/bench_kit.timed_steps)
 # --------------------------------------------------------------------------
@@ -365,7 +351,7 @@ def _fake_clock(durations_ms):
 
 
 def test_timed_steps_extends_past_warm_in():
-    """The BENCH_r05 NMT shape: a slow first window (compile/cache warm-in)
+    """The r5 chip record NMT shape: a slow first window (compile/cache warm-in)
     must be treated as extended warmup, not evidence — windows extend until
     the trailing 3 agree, and exactly those are reported."""
     from tools.bench_kit import timed_steps

@@ -1,4 +1,4 @@
-"""Dygraph layer fill-in (VERDICT r3 #10): GroupNorm / SpectralNorm / NCE /
+"""Dygraph layer fill-in (r3 review #10): GroupNorm / SpectralNorm / NCE /
 BilinearTensorProduct / Conv3D / Conv3DTranspose — forward+backward smoke and
 static-vs-dygraph parity where a static op exists."""
 import numpy as np

@@ -90,7 +90,7 @@ def test_rng_advances_between_runs():
 
 
 def test_calc_gradient_multi_target():
-    """VERDICT weak-item regression: calc_gradient over several targets
+    """Review weak-item regression: calc_gradient over several targets
     (gradient of the summed targets, reference backward.py:672)."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):

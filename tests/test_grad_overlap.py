@@ -12,7 +12,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as fluid
-from paddle_tpu.core.jax_compat import shard_map
 from paddle_tpu.parallel import make_mesh
 from paddle_tpu.parallel.distributed import make_grad_sync, plan_buckets
 
@@ -57,9 +56,10 @@ def _sync_under_shard_map(sync, grads, mesh):
         return tuple(out[n][None] for n in names)
 
     args = [jnp.stack([dict(g)[n] for g in grads]) for n in names]
-    f = shard_map(worker, mesh=mesh,
-                  in_specs=tuple(P("dp") for _ in names),
-                  out_specs=tuple(P("dp") for _ in names))
+    f = jax.shard_map(worker, mesh=mesh,
+                      in_specs=tuple(P("dp") for _ in names),
+                      out_specs=tuple(P("dp") for _ in names),
+                      check_vma=False)
     return dict(zip(names, f(*args)))
 
 

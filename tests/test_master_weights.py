@@ -1,6 +1,6 @@
 """Mixed-precision master-weight + optimizer-state regression tests.
 
-Round-5 find (docs/perf_r05.md): bf16 models created bf16 parameters, whose
+r5 chip round find: bf16 models created bf16 parameters, whose
 bf16 Adam beta-pow accumulators rounded 0.999 -> 1.0, making the bias-
 corrected lr identically zero — bf16+Adam parameters silently never
 trained (the r4 BERT bench trained only its f32 embedding/LN params).

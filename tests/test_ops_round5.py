@@ -2,7 +2,7 @@
 
 Reference numerics: batch_norm_op.cc training mode (mean/var over N,H,W).
 The bf16 activation path now computes E[x]/E[x^2] in one fused pass with
-f32 accumulators (docs/perf_r05.md); these tests pin its accuracy against
+f32 accumulators (r5 chip round); these tests pin its accuracy against
 float64 numpy at bf16-appropriate tolerances, including a shifted-mean case
 where naive cancellation would show up first.
 """

@@ -1,4 +1,4 @@
-"""PTQ/QAT int8 inference pipeline (VERDICT r4 #8).
+"""PTQ/QAT int8 inference pipeline (r4 review #8).
 
 Reference chain being mirrored: slim QAT (fake-quant instrumentation) ->
 QuantizationFreezePass -> mkldnn_quantizer-style deployable int8 model ->

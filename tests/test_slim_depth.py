@@ -1,4 +1,4 @@
-"""Slim depth (VERDICT r3 #9): structured pruning prune-retrain,
+"""Slim depth (r3 review #9): structured pruning prune-retrain,
 distillation (L2 / FSP / soft-label over the fsp op), channel-wise QAT.
 Reference: contrib/slim/prune/pruner.py, distillation/distiller.py,
 fake_quantize_op.cc fake_channel_wise_quantize_abs_max."""

@@ -1,4 +1,4 @@
-"""API-tail batch goldens (audit VERDICT r3 #6): numpy transcriptions of the
+"""API-tail batch goldens (audit, r3 review #6): numpy transcriptions of the
 reference kernels (activation_op.h functors, smooth_l1_loss_op.h,
 teacher_student_sigmoid_loss_op.h:26, pixel_shuffle_op.h, shuffle_channel_op.h,
 temporal_shift_op.h, fsp_op.h, unfold_op.h, pool_op adaptive path, cvm_op.h,

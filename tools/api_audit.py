@@ -1,4 +1,4 @@
-"""Audit the public API against the reference's API.spec (VERDICT r3 #6).
+"""Audit the public API against the reference's API.spec (r3 review #6).
 
 For every entry in /root/reference/paddle/fluid/API.spec (936 lines), the
 name `paddle.fluid.X.y` must either RESOLVE on `paddle_tpu` (getattr chain —

@@ -21,15 +21,15 @@ def timed_steps(dispatch, K=1, n_warm=2, iters=3, windows=1,
                 spread_target=None, max_windows=12, clock=None):
     """Best-of-N timing windows, per-OPTIMIZER-step results.
 
-    The shared-chip pool shows ~±20% run-to-run throughput variance, so the
-    minimum window is the honest compute time; all windows are returned so
-    results report spread.  K = optimizer steps per dispatch (the scan
+    The r5 machine showed ~±20% run-to-run throughput variance, so the
+    minimum window was taken as the compute time; all windows are returned
+    so results report spread.  K = optimizer steps per dispatch (the scan
     length): returned dt and windows are divided by it exactly once.
 
     spread_target (percent): warmup-until-stable windowing — keep timing
     windows (up to `max_windows` total) until the LAST `windows` of them
     agree to within spread_target%, then report exactly those.  The fix for
-    BENCH_r05's NMT entry, whose first window still carried compile/cache
+    the r5 chip record's NMT entry, whose first window still carried compile/cache
     warm-in and swung the reported spread to 26% (30.3 -> 22.8 ms): the
     early windows are treated as extended warmup instead of evidence.  When
     the budget runs out before stabilizing, the trailing windows are

@@ -6,7 +6,7 @@ in place in HBM (`core/executor.py` `_CompiledStep.rw_names`,
 donate_argnums).  A persistable that is written but NOT donated-and-aliased
 is silently double-buffered: the step allocates a second copy of the buffer
 and pays an extra HBM write every step — at BERT-base scale that is ~0.5 GB
-of wasted traffic and residency per step.  BENCH_r05's `params_moved`
+of wasted traffic and residency per step.  The r5 chip record's `params_moved`
 reported 18/198 BERT params "frozen", which is either exactly this class of
 drop or a bench-probe artifact; this tool decides which, statically, for
 every program in the zoo (verdict: probe artifact — see docs/performance.md

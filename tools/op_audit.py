@@ -1,4 +1,4 @@
-"""Op-registry audit gate (VERDICT r4 #7).
+"""Op-registry audit gate (r4 review #7).
 
 Mechanically extracts the reference's operator inventory (every
 REGISTER_OPERATOR / REGISTER_OP_WITHOUT_GRADIENT / REGISTER_ELEMWISE_* /

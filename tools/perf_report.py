@@ -1462,7 +1462,7 @@ def check(path: str, steady_after: int = 2,
     return 0
 
 
-# Ratcheted analytic-MFU floors (ISSUE 7).  Set from BENCH_r05 — resnet50's
+# Ratcheted analytic-MFU floors (ISSUE 7).  Set from the r5 chip record — resnet50's
 # is EXCLUSIVE (the MFU campaign must land strictly above the level it set
 # out to beat), bert's INCLUSIVE (hold the r05 line).  Each accepted bench
 # round that clears a floor by a margin ratchets it here, in the same PR,
@@ -1472,7 +1472,7 @@ MFU_FLOORS = {
     "bert": {"floor": 0.402, "strict": False},
 }
 # Per-model window-spread ceiling: above this the round's numbers are noise
-# (BENCH_r05's NMT entry hit 26.3% from warm-in; tools/bench_kit.py
+# (the r5 chip record's NMT entry hit 26.3% from warm-in; tools/bench_kit.py
 # timed_steps(spread_target=...) now extends warmup until stable).
 MAX_SPREAD_PCT = 5.0
 # Ceiling on the per-step cross-rank skew a multi-process bench round may
