@@ -1,6 +1,7 @@
 """Round-5 interleaved A/B: unfuse BN stats reductions from convolutions.
 
-The r5 profile (experiments/profile_model.py) showed conv fusions carrying
+The r5 profile (its script since replaced by benchmark/trace_reduce.py)
+showed conv fusions carrying
 BN-stat reduce epilogues running at 9-43 TF/s vs ~90-190 for clean convs —
 but the step is bandwidth-bound, so what matters is total HBM bytes, not
 in-fusion MXU rate.  Variants (result: r5 chip round):

@@ -13,11 +13,13 @@ donated to the executable each step, so parameter updates are in-place in HBM.
 """
 from __future__ import annotations
 
+import hashlib
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Union
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 
@@ -128,6 +130,42 @@ def _lowering_flags():
             "pallas", bool(_flagv("FLAGS_use_pallas")))
 
 
+def _structure_digest(program: Program, *more) -> str:
+    """Eight hex digits of the program's STRUCTURE (op types and argument
+    names, every block) and whatever else the caller adds.  The same in
+    every process and on every rank that built the same program; never from
+    per-process identities like `program._uuid`."""
+    structure = tuple(
+        (op.type, tuple(op.input_arg_names), tuple(op.output_arg_names))
+        for blk in program.blocks for op in blk.ops)
+    return hashlib.sha1(repr((structure,) + more).encode()).hexdigest()[:8]
+
+
+# Did JAX's persistent compilation cache serve a compile?  The one event it
+# fires on a hit, counted per thread so that a compile on another thread is
+# not taken for this one's.  (`/jax/compilation_cache/cache_misses` fires
+# only when an entry is WRITTEN, which the minimum-compile-time rule
+# suppresses: a compile without the hit event is the miss.)
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_cache_hits_seen = threading.local()
+_cache_listener_on = False
+
+
+def _on_jax_event(event: str, **_):
+    if event == _CACHE_HIT_EVENT:
+        _cache_hits_seen.n = getattr(_cache_hits_seen, "n", 0) + 1
+
+
+def _listen_for_cache_hits():
+    """Registered on the first build the monitor watches.  Two threads
+    racing here register it twice at worst: a hit is then counted twice on
+    its thread, and is still a hit."""
+    global _cache_listener_on
+    if not _cache_listener_on:
+        _cache_listener_on = True
+        jax.monitoring.register_event_listener(_on_jax_event)
+
+
 class _CompiledStep:
     """One jitted executable for (program, feed sig, fetch names, state sig)."""
 
@@ -164,18 +202,11 @@ class _CompiledStep:
         self.csig = None
         if mesh is not None:
             try:
-                import hashlib
-
                 from .analysis import collective_signature
 
-                structure = tuple(
-                    (op.type, tuple(op.input_arg_names),
-                     tuple(op.output_arg_names))
-                    for blk in program.blocks for op in blk.ops)
-                self.csig = hashlib.sha1(
-                    repr((structure, collective_signature(program),
-                          tuple(sorted(dict(mesh.shape).items())))).encode()
-                ).hexdigest()[:8]
+                self.csig = _structure_digest(
+                    program, collective_signature(program),
+                    tuple(sorted(dict(mesh.shape).items())))
             except Exception:
                 self.csig = None
         self._exec = None
@@ -196,6 +227,16 @@ class _CompiledStep:
             v.name for v in program.list_vars() if v.persistable
         }
         ops = self._prune(ops, fetch_names, persistable)
+        # What the step IS, as the name of the jitted function (the `XLA
+        # Modules` line of a device trace shows `jit_<module>`), of the
+        # executor's spans (`module=`) and of its step records, so that a
+        # device event joins to the span that built and dispatched it.
+        # The name is part of JAX's persistent-cache key: it is made of
+        # the program's structure, which every process that builds the
+        # same program shares, so a warm set-up stays warm.
+        kind = ("train" if any(op.type == "backward" for op in ops)
+                else "startup" if not feed_names else "infer")
+        self.module = f"{kind}_{_structure_digest(program)}"
         read_names = set()
         written = []
         written_set = set()
@@ -477,6 +518,7 @@ class _CompiledStep:
                 fetches, new_state = smapped(state_rw, state_ro, feeds, key)
                 return fetches, new_state, jax.random.fold_in(key, max(n_steps, 1))
 
+        step.__name__ = step.__qualname__ = self.module
         if mesh is None:
             self.jfn = jax.jit(step, donate_argnums=(0,))
             self.feed_specs = None
@@ -563,6 +605,13 @@ class _CompiledStep:
             return jax.make_array_from_callback(host.shape, spec, lambda idx: host[idx])
         return jax.device_put(v, spec)
 
+    @property
+    def last_build_s(self) -> float:
+        """Seconds the last call spent lowering and compiling; 0 when it
+        ran an executable it already had."""
+        return (self.last_lower_s + self.last_compile_s
+                if self.last_recompiled else 0.0)
+
     @staticmethod
     def _state_sig(state_rw, state_ro):
         return (
@@ -606,10 +655,22 @@ class _CompiledStep:
                     return out
                 except TypeError:
                     pass
+            mon_on = _MON.enabled
+            what = dict(program=self.program_uuid, module=self.module)
             t0 = time.perf_counter()
-            lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
+            with _MON.span("executor.lower", **what):
+                lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
             t1 = time.perf_counter()
-            built = lowered.compile()
+            with _MON.span("executor.compile", **what) as compiling:
+                if mon_on:
+                    _listen_for_cache_hits()
+                    hits0 = getattr(_cache_hits_seen, "n", 0)
+                built = lowered.compile()
+                if mon_on:
+                    hit = getattr(_cache_hits_seen, "n", 0) > hits0
+                    compiling.annotate(cache_hit=hit)
+                    _MON.counter("executor.compile_cache_hit" if hit
+                                 else "executor.compile_cache_miss").inc()
             t2 = time.perf_counter()
             self._exec = built
             self._exec_by_sig[sig] = built
@@ -618,8 +679,6 @@ class _CompiledStep:
             self.last_lower_s = t1 - t0
             self.last_compile_s = t2 - t1
             self.last_recompiled = True
-        _MON.observe("executor.lower", self.last_lower_s, program=self.program_uuid)
-        _MON.observe("executor.compile", self.last_compile_s, program=self.program_uuid)
         _MON.counter("executor.recompile").inc()
         return built(state_rw, state_ro, feeds, key)
 
@@ -691,9 +750,6 @@ class _PendingFetches:
             if self._exc is not None:
                 raise self._exc
             return self._np
-        mon_on = _MON.enabled
-        if mon_on:
-            t0 = time.perf_counter()
         try:
             # the device->host copy (the NaN guard's np.asarray included)
             # is where an in-flight collective's block manifests;
@@ -703,7 +759,9 @@ class _PendingFetches:
                 Executor._check_nan_inf(self.fetch_names, self.fetches)
                 return [np.asarray(v) for v in self.fetches]
 
-            self._np = _guard_blocking(_materialize, what="executor.resolve")
+            with _MON.span("executor.fetch", program=self.program_u8):
+                self._np = _guard_blocking(_materialize,
+                                           what="executor.resolve")
         except BaseException as e:
             # route the in-flight failure through the taxonomy
             # (paddle_tpu/errors.py): an XLA RESOURCE_EXHAUSTED /
@@ -725,9 +783,6 @@ class _PendingFetches:
             self._done = True
             self.fetches = []
             self.key = None
-        if mon_on:
-            _MON.observe("executor.fetch", time.perf_counter() - t0,
-                         program=self.program_u8)
         return self._np
 
 
@@ -1006,7 +1061,7 @@ class Executor:
                         program,
                         {n: np.shape(v) for n, v in jfeeds.items()},
                         fetch_names, steps=steps)
-            with _MON.span("executor.build", program=program._uuid[:8]):
+            with _MON.span("executor.build", program=program._uuid[:8]) as building:
                 compiled = _CompiledStep(
                     program, list(jfeeds), fetch_names, scope,
                     mesh=mesh, batch_axis=batch_axis,
@@ -1015,6 +1070,7 @@ class Executor:
                     local_sgd=bool(local_sgd_every),
                     grad_overlap=grad_overlap,
                 )
+                building.annotate(module=compiled.module)
             with self._cache_lock:
                 existing = self._cache.get(cache_key)
                 if existing is not None:
@@ -1032,7 +1088,112 @@ class Executor:
                     if len(self._cache) > _flagv("FLAGS_executor_cache_capacity"):  # LRU evict
                         self._cache.pop(next(iter(self._cache)))
 
-        if mesh is None:
+        # one tail for both modes; mon_on guards only the records and the
+        # block to completion, so the disabled fast path stays branch-only
+        # (a few NULL_SPAN entries, no blocking, no records) while the
+        # monitored per-phase breakdown cannot diverge from it.
+        # Monitored, the spans of one synchronous run nest as
+        #   executor.run > executor.execute > executor.dispatch
+        #                                     > executor.feed_place
+        #                                     > executor.enqueue
+        #                                       > executor.lower, .compile
+        #                > executor.fetch
+        # and `run_async` opens `executor.dispatch` alone.
+        mon_on = _MON.enabled
+        u8 = program._uuid[:8]
+        what = dict(program=u8, module=compiled.module)
+        feed_bytes = 0
+        if mon_on:
+            feed_bytes = int(sum(getattr(v, "nbytes", 0) for v in jfeeds.values()))
+            _MON.counter("executor.feed_bytes").inc(feed_bytes)
+            # dispatch-attempt census BEFORE the (possibly collective-
+            # blocking) dispatch: the heartbeat's beat payload reads this,
+            # and it is what makes a slow-but-alive rank's lag visible
+            # while its peers sit blocked inside the collective
+            _MON.counter("executor.steps_started").inc()
+            ts_dispatch = time.time()
+            t_run0 = time.perf_counter()
+
+        def dispatch():
+            with _MON.span("executor.dispatch", **what):
+                with _MON.span("executor.feed_place", program=u8, bytes=feed_bytes):
+                    placed = self._place_feeds(compiled, jfeeds, scope, device)
+                # dispatch is watchdog-guarded: on backends whose dispatch
+                # blocks (CPU/gloo cross-process collectives), a dead peer
+                # wedges the enqueue itself — the guard turns that into
+                # PeerFailureError.  With the health layer off (every
+                # single-process run) this is a direct call behind one
+                # None-check.
+                with _MON.span("executor.enqueue", **what):
+                    fetches, new_key = _guard_blocking(
+                        lambda: compiled(scope, placed, key), what="executor.dispatch")
+                scope.set_var(RNG_STATE_VAR, new_key)
+            return fetches, new_key
+
+        def record(**phases):
+            # dispatch = what `run_async` pays on the critical path: feed
+            # placement and the enqueue, less any build
+            rec = {
+                "program": u8,
+                "module": compiled.module,
+                "steps": steps,
+                "cache_hit": cache_hit,
+                "recompiled": compiled.last_recompiled,
+                "cache_hits_total": _MON.counter("executor.cache_hit").value,
+                "cache_misses_total": _MON.counter("executor.cache_miss").value,
+                "recompiles_total": _MON.counter("executor.recompile").value,
+                "t_lower_s": compiled.last_lower_s if compiled.last_recompiled else 0.0,
+                "t_compile_s": compiled.last_compile_s if compiled.last_recompiled else 0.0,
+                **phases,
+                "ts_dispatch": ts_dispatch,
+                "feed_bytes": feed_bytes,
+            }
+            if compiled.csig is not None:
+                rec["csig"] = compiled.csig
+            _MON.record_step(rec)
+
+        if async_mode:
+            fetches, new_key = dispatch()
+            if mon_on:
+                record(**{"async": True,
+                          "t_dispatch_s": time.perf_counter() - t_run0 - compiled.last_build_s})
+            pending = _PendingFetches(fetch_names, fetches, new_key, u8)
+            return [FetchHandle(pending, i, n)
+                    for i, n in enumerate(fetch_names)]
+
+        def _fetch_out():
+            # the NaN guard's np.asarray is itself the blocking copy, so
+            # it lives inside the watchdog guard with the fetch
+            self._check_nan_inf(fetch_names, fetches)
+            return ([np.asarray(f) for f in fetches] if return_numpy
+                    else list(fetches))
+
+        with _MON.span("executor.run", **what):
+            with _MON.span("executor.execute", **what):
+                fetches, _ = dispatch()
+                if mon_on:
+                    t_dispatch = time.perf_counter() - t_run0 - compiled.last_build_s
+                    # execute additionally blocks to completion so device
+                    # compute isn't attributed to the fetch copy
+                    _guard_blocking(lambda: jax.block_until_ready(fetches),
+                                    what="executor.execute")
+                    t_execute = time.perf_counter() - t_run0 - compiled.last_build_s
+            if mon_on:
+                t_f0 = time.perf_counter()
+            with _MON.span("executor.fetch", program=u8):
+                out = _guard_blocking(_fetch_out, what="executor.fetch")
+        if mon_on:
+            t_end = time.perf_counter()
+            _MON.gauge("executor.last_step_s").set(t_execute)
+            record(t_dispatch_s=t_dispatch, t_execute_s=t_execute,
+                   t_fetch_s=t_end - t_f0, t_total_s=t_end - t_run0)
+        return out
+
+    def _place_feeds(self, compiled: _CompiledStep, jfeeds: dict, scope: Scope,
+                     device) -> dict:
+        """The feeds where the step wants them, and host-resident state
+        moved to the device: the `executor.feed_place` span."""
+        if compiled.mesh is None:
             # Single-device: pin feeds and any host-resident state.
             jfeeds = {
                 n: v if isinstance(v, jax.Array) else jax.device_put(jnp.asarray(v), device)
@@ -1048,125 +1209,23 @@ class Executor:
                     # references corrupts it in place
                     with jax.default_device(device):
                         scope.set_var(n, jnp.array(v, copy=True))
-        elif compiled.multiprocess:
+            return jfeeds
+        if compiled.multiprocess:
             # Cross-process mesh: every process contributes its LOCAL slice
             # of batch-sharded feeds (reference: per-trainer data shards in
             # NCCL2 mode); replicated feeds pass the full array everywhere.
-            jfeeds = {
+            return {
                 n: v if isinstance(v, jax.Array)
                 else jax.make_array_from_process_local_data(
                     compiled.feed_specs[n], np.asarray(v))
                 for n, v in jfeeds.items()
             }
-        else:
-            # SPMD: shard feeds up front; jit's in_shardings places state.
-            jfeeds = {
-                n: v if isinstance(v, jax.Array) and v.sharding == compiled.feed_specs[n]
-                else jax.device_put(v, compiled.feed_specs[n])
-                for n, v in jfeeds.items()
-            }
-
-        # one tail for both modes; mon_on guards only the timing hooks, so
-        # the disabled fast path stays branch-only (no blocking, no records)
-        # while the monitored per-phase breakdown cannot diverge from it.
-        # Monitored: execute is blocked to completion so device compute
-        # isn't attributed to the fetch copy; lower/compile are timed
-        # inside _dispatch when an executable is (re)built.
-        mon_on = _MON.enabled
-        if mon_on:
-            u8 = program._uuid[:8]
-            feed_bytes = int(sum(getattr(v, "nbytes", 0) for v in jfeeds.values()))
-            _MON.counter("executor.feed_bytes").inc(feed_bytes)
-            # dispatch-attempt census BEFORE the (possibly collective-
-            # blocking) dispatch: the heartbeat's beat payload reads this,
-            # and it is what makes a slow-but-alive rank's lag visible
-            # while its peers sit blocked inside the collective
-            _MON.counter("executor.steps_started").inc()
-            ts_dispatch = time.time()
-            t_run0 = time.perf_counter()
-        # dispatch is watchdog-guarded: on backends whose dispatch blocks
-        # (CPU/gloo cross-process collectives), a dead peer wedges the
-        # enqueue itself — the guard turns that into PeerFailureError.
-        # With the health layer off (every single-process run) this is a
-        # direct call behind one None-check.
-        fetches, new_key = _guard_blocking(
-            lambda: compiled(scope, jfeeds, key), what="executor.dispatch")
-        if mon_on:
-            # dispatch = enqueue-only cost (what run_async pays on the
-            # critical path); execute additionally blocks to completion so
-            # device compute isn't attributed to the fetch copy.
-            build_s = (compiled.last_lower_s + compiled.last_compile_s
-                       if compiled.last_recompiled else 0.0)
-            t_dispatch = time.perf_counter() - t_run0 - build_s
-            _MON.observe("executor.dispatch", t_dispatch, program=u8)
-        scope.set_var(RNG_STATE_VAR, new_key)
-        if async_mode:
-            pending = _PendingFetches(fetch_names, fetches, new_key,
-                                      program._uuid[:8])
-            if mon_on:
-                rec = {
-                    "program": u8,
-                    "steps": steps,
-                    "async": True,
-                    "cache_hit": cache_hit,
-                    "recompiled": compiled.last_recompiled,
-                    "cache_hits_total": _MON.counter("executor.cache_hit").value,
-                    "cache_misses_total": _MON.counter("executor.cache_miss").value,
-                    "recompiles_total": _MON.counter("executor.recompile").value,
-                    "t_lower_s": compiled.last_lower_s if compiled.last_recompiled else 0.0,
-                    "t_compile_s": compiled.last_compile_s if compiled.last_recompiled else 0.0,
-                    "t_dispatch_s": t_dispatch,
-                    "ts_dispatch": ts_dispatch,
-                    "feed_bytes": feed_bytes,
-                }
-                if compiled.csig is not None:
-                    rec["csig"] = compiled.csig
-                _MON.record_step(rec)
-            return [FetchHandle(pending, i, n)
-                    for i, n in enumerate(fetch_names)]
-        if mon_on:
-            _guard_blocking(lambda: jax.block_until_ready(fetches),
-                            what="executor.execute")
-            t_disp = time.perf_counter() - t_run0
-            t_execute = t_disp - build_s
-            _MON.observe("executor.execute", t_execute, program=u8)
-        def _fetch_out():
-            # the NaN guard's np.asarray is itself the blocking copy, so
-            # it lives inside the watchdog guard with the fetch
-            self._check_nan_inf(fetch_names, fetches)
-            return ([np.asarray(f) for f in fetches] if return_numpy
-                    else list(fetches))
-
-        if not mon_on:
-            return _guard_blocking(_fetch_out, what="executor.fetch")
-        t_f0 = time.perf_counter()
-        out = _guard_blocking(_fetch_out, what="executor.fetch")
-        t_fetch = time.perf_counter() - t_f0
-        _MON.observe("executor.fetch", t_fetch, program=u8)
-        t_total = time.perf_counter() - t_run0
-        _MON.observe(f"executor.run[{u8}]", t_total)
-        _MON.gauge("executor.last_step_s").set(t_execute)
-        rec = {
-            "program": u8,
-            "steps": steps,
-            "cache_hit": cache_hit,
-            "recompiled": compiled.last_recompiled,
-            "cache_hits_total": _MON.counter("executor.cache_hit").value,
-            "cache_misses_total": _MON.counter("executor.cache_miss").value,
-            "recompiles_total": _MON.counter("executor.recompile").value,
-            "t_lower_s": compiled.last_lower_s if compiled.last_recompiled else 0.0,
-            "t_compile_s": compiled.last_compile_s if compiled.last_recompiled else 0.0,
-            "t_dispatch_s": t_dispatch,
-            "t_execute_s": t_execute,
-            "t_fetch_s": t_fetch,
-            "t_total_s": t_total,
-            "ts_dispatch": ts_dispatch,
-            "feed_bytes": feed_bytes,
+        # SPMD: shard feeds up front; jit's in_shardings places state.
+        return {
+            n: v if isinstance(v, jax.Array) and v.sharding == compiled.feed_specs[n]
+            else jax.device_put(v, compiled.feed_specs[n])
+            for n, v in jfeeds.items()
         }
-        if compiled.csig is not None:
-            rec["csig"] = compiled.csig
-        _MON.record_step(rec)
-        return out
 
     @staticmethod
     def _check_nan_inf(fetch_names, fetches):

@@ -70,19 +70,22 @@ class LoweringContext:
 _STRUCTURAL_OPS = ("feed", "fetch", "backward")
 
 
-def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any]) -> Dict[str, Any]:
+def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
+            first: int = 0) -> Dict[str, Any]:
     """Interpret `ops` over `env` (var name -> traced jax value), in order.
 
     Op-level provenance (ISSUE 8): each op's emission is wrapped in
     `jax.named_scope("op<idx>:<type>")`, so XLA op metadata — and with it
     device profiles, HLO dumps, and the merged gang traces — maps every
-    fused region back to the ProgramDesc op(s) that produced it.  Pure
-    trace-time cost: the scope name lands in the jaxpr/HLO, nothing runs
-    per step."""
-    # per-op lower counts run at TRACE time only (this loop is the trace),
-    # so the monitor's per-program op census costs nothing at execution
+    fused region back to the ProgramDesc op(s) that produced it.  `first`
+    is the index of `ops[0]` among the interpreted ops of its block, so an
+    index names ONE op of the block: the tail after `backward` continues
+    the forward's numbering.  Pure trace-time cost: the scope name lands
+    in the jaxpr/HLO, nothing runs per step."""
+    # the op census runs at TRACE time only (this loop is the trace), so
+    # it costs nothing at execution
     mon_on = _MON.enabled
-    for idx, op in enumerate(ops):
+    for idx, op in enumerate(ops, first):
         if op.type in _STRUCTURAL_OPS:
             raise RuntimeError(
                 f"structural op {op.type!r} reached the lowering interpreter; "
@@ -92,7 +95,6 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any]) -> D
             lower_one(ctx, op, env)
         if mon_on:
             _MON.counter("lowering.ops_total").inc()
-            _MON.counter("lowering.op." + op.type).inc()
     return env
 
 
@@ -175,10 +177,17 @@ def run_block_with_backward(ctx: LoweringContext, ops: List[Operator], env: Dict
     values produced by EARLIER regions (e.g. their grads) enter later
     regions as constants (stop-gradient), matching the reference's
     grad-of-grad-free semantics.  XLA CSEs the re-interpreted prefixes.
+
+    The step says which phase an instruction belongs to: the forward
+    interpretation runs under `jax.named_scope("fwd")`, the tail after the
+    last `backward` under `"update"`, and the transposes JAX derives from
+    `fwd` are the backward phase (their `op_name` holds `transpose(`).  A
+    program without a `backward` op carries `fwd` only.
     """
     splits = [i for i, op in enumerate(ops) if op.type == "backward"]
     if not splits:
-        return run_ops(ctx, ops, env)
+        with jax.named_scope("fwd"):
+            return run_ops(ctx, ops, env)
 
     report_sparse: List[str] = []
     # every region re-interprets its op prefix FROM THE BLOCK-START env
@@ -196,7 +205,9 @@ def run_block_with_backward(ctx: LoweringContext, ops: List[Operator], env: Dict
     LAST_TRACE_REPORT.clear()
     LAST_TRACE_REPORT["sparse_grad_params"] = report_sparse
     tail_ops = ops[splits[-1] + 1:]
-    return run_ops(ctx, tail_ops, env)
+    with jax.named_scope("update"):
+        return run_ops(ctx, tail_ops, env,
+                       first=splits[-1] + 1 - len(splits))
 
 
 def _run_one_backward_region(ctx: LoweringContext, ops: List[Operator], split: int,
@@ -245,7 +256,8 @@ def _run_one_backward_region(ctx: LoweringContext, ops: List[Operator], split: i
             coll.i = 0
         e = dict(base_env)
         e.update(params)
-        e = run_ops(ctx, fwd_ops, e)
+        with jax.named_scope("fwd"):
+            e = run_ops(ctx, fwd_ops, e)
         loss = e[loss_name]
         return loss, e
 

@@ -20,10 +20,15 @@ shared null singleton, `inc`/`set` are no-ops.  `paddle_tpu.profiler` is a
 compatibility facade over this module.
 
 Instrumented out of the box: `core/executor.py` (per-run step breakdown —
-lowering / compile / execute / fetch spans, cache-hit + recompile
-counters, steps/sec EMA), `core/lowering.py` (per-op lower counts),
-`reader.py` (queue depth / wait), `fleet.py` + `dygraph/parallel.py`
-(worker lanes, collective bytes), memstats gauges (live HBM bytes).
+build / lower / compile / dispatch / execute / fetch spans, cache-hit +
+recompile counters, steps/sec EMA), `pipeline.py` (the loop's next_batch /
+dispatch / host_blocked spans, each with its step), `core/lowering.py`
+(the op census), `reader.py` (the producer's stage span, queue depth /
+wait), `serving/server.py` (the worker's batch_build / batch / split),
+`fleet.py` + `dygraph/parallel.py` (worker lanes, collective bytes),
+memstats gauges (live HBM bytes).  While enabled, every span is also a
+`jax.profiler.TraceAnnotation`: inside a profiler session the program's
+spans and the device's operations are one timeline.
 See docs/observability.md.
 """
 from __future__ import annotations

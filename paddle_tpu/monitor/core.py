@@ -25,12 +25,15 @@ the always-on recorder adds two deque appends to the hot path
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..core.locks import named_lock
 
@@ -51,6 +54,11 @@ TRACE_RING_CAP = 1024
 # and errors, retained past the trace ring's churn so a post-mortem black
 # box still carries the episodes that actually burned the SLO.
 EXEMPLAR_CAP = 64
+# The identifiers the spans of one piece of work share (choosing-metrics
+# guide, section 4): a span that does not set one takes its parent's, so
+# `executor.enqueue` under `pipeline.dispatch(step=7)` is a span of step 7
+# and a reader cuts a window by step without walking the tree.
+SHARED_IDS = ("step", "batch", "trace_id")
 
 
 class _NullSpan:
@@ -72,11 +80,17 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """Timed region.  Nesting is tracked per-thread: depth and a tid land
-    in the event buffer so the Chrome-trace exporter renders child spans
-    inside their parents."""
+    """Timed region, on two timelines at once.  Its event in the monitor's
+    buffer carries an id and its parent's id (the per-thread stack of open
+    spans; 0 is "no parent"), the tid and the nesting depth, so the
+    Chrome-trace exporter renders children inside their parents and a
+    reader computes self time.  While it is open it is also a
+    `jax.profiler.TraceAnnotation` of the same name and arguments: an event
+    on the `/host:CPU` plane of a profiler trace, on the thread that did
+    the work and on the clock of the device's `XLA Ops`.  With no profiler
+    session on, the annotation is a no-op in C++."""
 
-    __slots__ = ("mon", "name", "args", "t0", "ts")
+    __slots__ = ("mon", "name", "args", "t0", "ts", "id", "parent", "_note")
 
     def __init__(self, mon: "Monitor", name: str, args: Optional[dict]):
         self.mon = mon
@@ -84,8 +98,13 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self.ts = 0.0
+        self.id = 0
+        self.parent = 0
+        self._note = None
 
     def annotate(self, **kw):
+        """Arguments learned while the span is open land in the monitor's
+        event; the profiler's annotation keeps those it was opened with."""
         if self.args is None:
             self.args = dict(kw)
         else:
@@ -93,18 +112,31 @@ class Span:
         return self
 
     def __enter__(self):
-        tls = self.mon._tls
-        tls.depth = getattr(tls, "depth", 0) + 1
+        stack = self.mon._stack()
+        if stack:
+            above = stack[-1]
+            self.parent = above.id
+            if above.args:
+                shared = {k: above.args[k] for k in SHARED_IDS
+                          if k in above.args
+                          and not (self.args and k in self.args)}
+                if shared:
+                    self.annotate(**shared)
+        self.id = next(self.mon._span_ids)
+        stack.append(self)
+        self._note = TraceAnnotation(self.name, **(self.args or {}))
         self.ts = time.time()
         self.t0 = time.perf_counter()
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._note.__exit__(*exc)
         dur = time.perf_counter() - self.t0
-        tls = self.mon._tls
-        depth = getattr(tls, "depth", 1)
-        tls.depth = depth - 1
-        self.mon._record(self.name, self.ts, dur, depth - 1, self.args)
+        stack = self.mon._stack()
+        stack.pop()
+        self.mon._record(self.name, self.ts, dur, len(stack), self.args,
+                         self.id, self.parent)
         return False
 
 
@@ -170,9 +202,11 @@ class Monitor:
         self.enabled = False
         self._lock = named_lock("monitor.registry", rank=64, telemetry=False)
         self._tls = threading.local()
+        self._span_ids = itertools.count(1)  # next() is atomic under the GIL
         # span aggregates: name -> [calls, total_s, max_s, min_s]
         self._agg: Dict[str, list] = {}
-        # raw events for trace export: (name, ts_s, dur_s, tid, depth, args)
+        # raw events for trace export:
+        # (name, ts_s, dur_s, tid, depth, args, span id, parent's span id)
         self._events: List[tuple] = []
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
@@ -246,17 +280,30 @@ class Monitor:
             return NULL_SPAN
         return Span(self, name, args or None)
 
+    def _stack(self) -> list:
+        """This thread's open spans, outermost first."""
+        tls = self._tls
+        try:
+            return tls.stack
+        except AttributeError:
+            tls.stack = []
+            return tls.stack
+
     def observe(self, name: str, seconds: float, ts: Optional[float] = None,
                 **args):
-        """Record a completed duration without a context manager (the
-        profiler facade's record_run, and pre-measured phases)."""
+        """Record a duration that was measured elsewhere (the profiler
+        facade's record_run, a bucket's occupancy): an event in the
+        monitor's buffer, back-dated, under the span open on this thread.
+        It was not open while the work ran, so it does not enter a
+        profiler trace; a region of this program's own is a `span()`."""
         if not self.enabled:
             return
-        tls = self._tls
+        stack = self._stack()
         self._record(name, ts if ts is not None else time.time() - seconds,
-                     seconds, getattr(tls, "depth", 0), args or None)
+                     seconds, len(stack), args or None, next(self._span_ids),
+                     stack[-1].id if stack else 0)
 
-    def _record(self, name, ts, dur, depth, args):
+    def _record(self, name, ts, dur, depth, args, sid, parent):
         tid = threading.get_ident() & 0xFFFF
         with self._lock:
             a = self._agg.get(name)
@@ -269,9 +316,10 @@ class Monitor:
                     a[2] = dur
                 if dur < a[3]:
                     a[3] = dur
+            event = (name, ts, dur, tid, depth, args, sid, parent)
             if len(self._events) < EVENT_CAP:
-                self._events.append((name, ts, dur, tid, depth, args))
-            self._bb_events.append((name, ts, dur, tid, depth, args))
+                self._events.append(event)
+            self._bb_events.append(event)
 
     def span_stats(self) -> Dict[str, dict]:
         with self._lock:
@@ -405,10 +453,11 @@ class Monitor:
             exemplars = list(self._exemplars)
             events = [
                 {"name": n, "ts": ts, "dur_s": dur, "tid": tid,
-                 "depth": depth,
+                 "depth": depth, "id": sid, "parent": parent,
                  "args": ({k: str(v) for k, v in args.items()}
                           if args else None)}
-                for (n, ts, dur, tid, depth, args) in self._bb_events
+                for (n, ts, dur, tid, depth, args, sid, parent)
+                in self._bb_events
             ]
         try:
             gauges = self.gauge_values()

@@ -152,11 +152,11 @@ def chrome_trace_events(mon, pid: Optional[int] = None,
     pid = mon.lane if pid is None else pid
     events = [{"name": "process_name", "ph": "M", "pid": pid,
                "args": {"name": process_name or mon.lane_name}}]
-    for name, ts, dur, tid, depth, args in mon.events():
+    for name, ts, dur, tid, depth, args, sid, parent in mon.events():
         ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
-              "ts": ts * 1e6, "dur": dur * 1e6, "cat": "span"}
-        if args:
-            ev["args"] = {k: str(v) for k, v in args.items()}
+              "ts": ts * 1e6, "dur": dur * 1e6, "cat": "span",
+              "args": {"span": sid, "parent": parent,
+                       **{k: str(v) for k, v in (args or {}).items()}}}
         events.append(ev)
     # request-flight lanes ride the same document so one export (and the
     # trace_merge.py gang merge) carries spans AND requests

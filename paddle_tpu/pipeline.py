@@ -17,9 +17,13 @@ pieces:
     and only materializes fetches on logging steps — non-logging steps
     `wait()` for execution without paying the device->host copy.
 
-Monitor integration: `pipeline.inflight` gauge, `pipeline.host_blocked`
-span (time the host spent waiting on the device — the overlap-win
-metric), and one `kind="pipeline_step"` record per drained step that
+Monitor integration: `pipeline.inflight` gauge; the loop's three
+boundaries as spans that carry their `step`: `pipeline.next_batch` (the
+pull from the loader), `pipeline.dispatch` (`exe.run_async`; the
+`on_dispatch` hook stays outside it) and `pipeline.host_blocked` (time
+the host spent waiting on the device — the overlap-win metric); and one
+`kind="pipeline_step"` record per drained step with that step's wall
+time and what the host spent in each of the three, which
 `tools/perf_report.py` turns into a host-blocked fraction (and can gate
 on via `--check --max-host-blocked-frac`).
 """
@@ -114,14 +118,15 @@ def train_loop(
         raise ValueError(f"log_period must be >= 1, got {log_period}")
 
     stats = PipelineStats()
-    inflight: deque = deque()  # (step index, [FetchHandle, ...])
+    # (step index, [FetchHandle, ...], seconds in next(it), in run_async)
+    inflight: deque = deque()
     gauge = _MON.gauge("pipeline.inflight")
     t_wall0 = time.perf_counter()
     last_drain_t = t_wall0
 
     def drain_one():
         nonlocal last_drain_t
-        step_i, handles = inflight.popleft()
+        step_i, handles, t_next_batch, t_dispatch = inflight.popleft()
         gauge.set(len(inflight))
         want_log = step_i % log_period == 0
         must_resolve = want_log or resolve_all
@@ -146,6 +151,8 @@ def train_loop(
             _MON.record_step({
                 "kind": "pipeline_step",
                 "pipeline_step": step_i,
+                "t_next_batch_s": t_next_batch,
+                "t_dispatch_s": t_dispatch,
                 "t_host_blocked_s": now - t_b0,
                 "t_step_wall_s": now - last_drain_t,
                 "inflight": len(inflight),
@@ -163,18 +170,24 @@ def train_loop(
         while max_steps is None or stats.steps < max_steps:
             # bound checked BEFORE pulling: a shared/resumable loader must
             # not lose a batch the loop will never dispatch
+            step_i = step_offset + stats.steps
+            t_n0 = time.perf_counter()
             try:
-                feed = next(it)
+                with _MON.span("pipeline.next_batch", step=step_i):
+                    feed = next(it)
             except StopIteration:
                 break
+            t_next_batch = time.perf_counter() - t_n0
             while len(inflight) >= max_inflight:
                 drain_one()
-            step_i = step_offset + stats.steps
             try:
                 if on_dispatch is not None:
                     on_dispatch(step_i, feed)
-                handles = exe.run_async(program, feed=feed,
-                                        fetch_list=fetch_list, scope=scope)
+                t_d0 = time.perf_counter()
+                with _MON.span("pipeline.dispatch", step=step_i):
+                    handles = exe.run_async(program, feed=feed,
+                                            fetch_list=fetch_list, scope=scope)
+                t_dispatch = time.perf_counter() - t_d0
             except BaseException as e:
                 # a synchronous dispatch failure (hook, compile/enqueue
                 # path) belongs to this step — but OLDER steps still in
@@ -189,7 +202,7 @@ def train_loop(
                 while inflight:
                     drain_one()
                 raise err
-            inflight.append((step_i, handles))
+            inflight.append((step_i, handles, t_next_batch, t_dispatch))
             stats.steps += 1
             stats.max_inflight_seen = max(stats.max_inflight_seen,
                                           len(inflight))
@@ -203,7 +216,7 @@ def train_loop(
         # already landed in the scope at dispatch; resolution errors here
         # are secondary to the one propagating.
         while inflight:
-            _, handles = inflight.popleft()
+            handles = inflight.popleft()[1]
             try:
                 handles[0].wait()
             except Exception:

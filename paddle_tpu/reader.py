@@ -32,7 +32,6 @@ from __future__ import annotations
 import itertools
 import queue
 import threading
-import time
 from typing import Callable, Dict, Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -986,20 +985,27 @@ class DataLoader:
                         item = {v.name: a for v, a in zip(self.feed_vars, item)}
                     placed = {}
                     nbytes = 0
-                    for n, a in item.items():
-                        a = np.asarray(a)
-                        # FeedSpec guard: a mismatched feed dies HERE, named,
-                        # not steps later inside XLA
-                        self.feed_spec.validate(n, a, vmode)
-                        want = name_dtypes.get(n)
-                        if want is not None and a.dtype != want:
-                            a = a.astype(want)
-                        if a.dtype == np.int64:
-                            a = a.astype(np.int32)
-                        elif a.dtype == np.float64:
-                            a = a.astype(np.float32)
-                        nbytes += a.nbytes
-                        placed[n] = self._place(n, a)
+                    # one batch staged: validate, cast, hand to the device
+                    # (`device_put` returns once the copy is queued; the
+                    # runtime's own threads lay the bytes out and send
+                    # them).  This thread's busy time over the loop's time
+                    # is how close the loader's Python runs to its limit.
+                    with _MON.span("reader.stage", batch=produced) as staging:
+                        for n, a in item.items():
+                            a = np.asarray(a)
+                            # FeedSpec guard: a mismatched feed dies HERE,
+                            # named, not steps later inside XLA
+                            self.feed_spec.validate(n, a, vmode)
+                            want = name_dtypes.get(n)
+                            if want is not None and a.dtype != want:
+                                a = a.astype(want)
+                            if a.dtype == np.int64:
+                                a = a.astype(np.int32)
+                            elif a.dtype == np.float64:
+                                a = a.astype(np.float32)
+                            nbytes += a.nbytes
+                            placed[n] = self._place(n, a)
+                        staging.annotate(bytes=nbytes)
                     _MON.counter("reader.bytes_staged").inc(nbytes)
                     if not _put((placed, st)):
                         return
@@ -1023,14 +1029,11 @@ class DataLoader:
                 # checked per batch (not latched): enabling the monitor
                 # mid-run starts producing wait spans from the live iterator
                 if _MON.enabled:
-                    # consumer-side starvation: time blocked on the queue —
-                    # a deep total here means the input pipeline, not the
-                    # device step, is the bottleneck
                     _MON.gauge("reader.queue_depth").set(q.qsize())
-                    t0 = time.perf_counter()
-                    item = q.get()
-                    _MON.observe("reader.wait", time.perf_counter() - t0)
-                else:
+                # consumer-side starvation: time blocked on the queue — a
+                # deep total here means the input pipeline, not the device
+                # step, is the bottleneck
+                with _MON.span("reader.wait"):
                     item = q.get()
                 if item is END:
                     return

@@ -62,6 +62,7 @@ returning the shared NULL_TRACE, no allocation.
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 import time
 import weakref
@@ -169,6 +170,8 @@ class Server:
         # runs.  stop() is what closes the door.
         self._accepting = True
         self._inflight = 0
+        # names a batch on its worker's three spans; next() is atomic
+        self._batch_ids = itertools.count()
         # server-local exact ledger (monitor counters mirror it when the
         # monitor is enabled; admission accounting must not depend on that)
         # ledger identity (at rest): requests == completed + shed +
@@ -470,20 +473,28 @@ class Server:
         live = self._expire(picked)
         if not live:
             return
+        # the worker thread's three phases are monitor spans, so they are on
+        # a profiler trace's host plane under the device's idle gaps; the
+        # stamps t0p, tb, td, tf are the per-request trees' shared phase
+        # boundaries (serving/tracing.py)
+        batch_id = next(self._batch_ids)
         t0p = time.perf_counter()
         try:
-            # acquire ONCE per batch: a publish() swapping mid-batch never
-            # touches us — this version object stays alive until we finish
-            version = self.registry.acquire(model)
-            padded, rows, bucket, pad_rows = _bk.build_batch(
-                live, self.buckets)
+            with _MON.span("serving.batch_build", batch=batch_id) as building:
+                # acquire ONCE per batch: a publish() swapping mid-batch
+                # never touches us — this version object stays alive until
+                # we finish
+                version = self.registry.acquire(model)
+                padded, rows, bucket, pad_rows = _bk.build_batch(
+                    live, self.buckets)
+                building.annotate(bucket=bucket, rows=rows, pad_rows=pad_rows)
             tb = time.perf_counter()  # batch built (shared phase boundary)
             for r in live:
                 r.trace.phase("batch_build", t=tb)
                 r.trace.annotate(bucket=bucket, pad_rows=pad_rows,
                                  batch_rows=rows)
-            with _MON.span("serving.batch", model=model, bucket=bucket,
-                           rows=rows, pad_rows=pad_rows):
+            with _MON.span("serving.batch", batch=batch_id, model=model,
+                           bucket=bucket, rows=rows, pad_rows=pad_rows):
                 outs = version.run(padded)
             td = time.perf_counter()  # device done (dispatch+run+fetch of
             # the synchronous predictor fold into this one phase)
@@ -501,11 +512,13 @@ class Server:
                                    final="error", exemplar=True)
                 r.future.set_exception(ce)
             return
-        offsets, at = [], 0
-        for r in live:
-            offsets.append((at, at + r.rows))
-            at += r.rows
-        per_req = _bk.split_rows(outs, offsets, bucket)
+        with _MON.span("serving.split", batch=batch_id, bucket=bucket,
+                       rows=rows, pad_rows=pad_rows):
+            offsets, at = [], 0
+            for r in live:
+                offsets.append((at, at + r.rows))
+                at += r.rows
+            per_req = _bk.split_rows(outs, offsets, bucket)
         tf = time.perf_counter()  # host-side result split done
         now = time.monotonic()
         lat_max = queue_ms_max = 0.0

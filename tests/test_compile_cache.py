@@ -6,6 +6,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 CHILD = r"""
 import json
 import numpy as np
@@ -29,7 +31,11 @@ monitor.enable()
 feed = {"x": np.zeros((32, 256), "f4"), "y": np.zeros((32, 1), "f4")}
 exe.run(main_p, feed=feed, fetch_list=[loss], scope=scope)
 spans = monitor.json_snapshot()["spans"]
-print(json.dumps({"compile_s": spans["executor.compile"]["total_s"]}))
+[compiled] = [e for e in monitor.get_monitor().events() if e[0] == "executor.compile"]
+print(json.dumps({"compile_s": spans["executor.compile"]["total_s"],
+                  "program": main_p._uuid[:8], "span": compiled[5],
+                  "counters": {k: v for k, v in monitor.get_monitor().counter_values().items()
+                               if k.startswith("executor.compile_cache")}}))
 """
 
 
@@ -46,17 +52,36 @@ def _run_child(cache_dir):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_compile_cache_hits_across_processes(tmp_path):
-    cache = str(tmp_path / "xla_cache")
-    first = _run_child(cache)["compile_s"]
+@pytest.fixture(scope="module")
+def two_processes(tmp_path_factory):
+    """The same program compiled in two fresh processes over one cache."""
+    cache = str(tmp_path_factory.mktemp("xla_cache"))
+    first = _run_child(cache)
     assert os.listdir(cache), "first process wrote no cache entries"
-    second = _run_child(cache)["compile_s"]
+    return first, _run_child(cache)
+
+
+def test_compile_cache_hits_across_processes(two_processes):
+    first, second = (r["compile_s"] for r in two_processes)
     # Measured locally: 0.82s cold vs 0.055s cache hit (~15x).  Gate at 3x
     # so shared-CI timer noise can't flake the test while a broken cache
     # (second == first) still fails loudly.
     assert second < first / 3, (
         f"persistent compile cache miss: cold {first:.3f}s vs second "
         f"process {second:.3f}s (expected an order-of-magnitude drop)")
+
+
+def test_the_step_keeps_its_name_and_says_that_the_cache_served_it(two_processes):
+    """The module's name is part of the persistent cache's key: it is made
+    of the program's structure, the same in every process, and the
+    `executor.compile` span and the two counters tell a load from a compile."""
+    first, second = two_processes
+    assert first["program"] != second["program"]        # uuid4 a process
+    assert first["span"]["module"] == second["span"]["module"]
+    assert first["span"]["module"].startswith("train_")
+    assert first["span"]["cache_hit"] is False and second["span"]["cache_hit"] is True
+    assert first["counters"] == {"executor.compile_cache_miss": 1}
+    assert second["counters"] == {"executor.compile_cache_hit": 1}
 
 
 def test_compile_cache_flag_registered():
