@@ -658,8 +658,11 @@ class _CompiledStep:
             mon_on = _MON.enabled
             what = dict(program=self.program_uuid, module=self.module)
             t0 = time.perf_counter()
-            with _MON.span("executor.lower", **what):
+            fenced = _MON.counter("lowering.fenced_grads")
+            fenced0 = fenced.value
+            with _MON.span("executor.lower", **what) as lowering:
                 lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
+                lowering.annotate(fenced=fenced.value - fenced0)
             t1 = time.perf_counter()
             with _MON.span("executor.compile", **what) as compiling:
                 if mon_on:
