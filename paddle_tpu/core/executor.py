@@ -236,6 +236,7 @@ class _CompiledStep:
         # same program shares, so a warm set-up stays warm.
         kind = ("train" if any(op.type == "backward" for op in ops)
                 else "startup" if not feed_names else "infer")
+        self.moe_layers = sum(op.type == "moe_experts" for op in ops)
         self.module = f"{kind}_{_structure_digest(program)}"
         read_names = set()
         written = []
@@ -663,6 +664,9 @@ class _CompiledStep:
             with _MON.span("executor.lower", **what) as lowering:
                 lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
                 lowering.annotate(fenced=fenced.value - fenced0)
+                if self.moe_layers:
+                    _MON.counter("lowering.moe_layers").inc(self.moe_layers)
+                    lowering.annotate(moe_layers=self.moe_layers)
             t1 = time.perf_counter()
             with _MON.span("executor.compile", **what) as compiling:
                 if mon_on:

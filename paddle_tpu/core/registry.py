@@ -27,6 +27,7 @@ from typing import Callable, Dict, List, Optional
 OpLowerFn = Callable  # (ctx, op, ins) -> {slot: [values]}
 InferFn = Callable  # (op, block) -> None (sets output var shapes/dtypes)
 CostFn = Callable  # (op, block, env) -> (flops, traffic_bytes)
+StatsFn = Callable  # (step, {slot: [one value per op of the type]}) -> None
 
 
 class OpDef:
@@ -36,6 +37,7 @@ class OpDef:
         self.lower = lower
         self.infer = infer
         self.cost = cost
+        self.step_stats = None  # (slots, publish): see set_step_stats
 
 
 _REGISTRY: Dict[str, OpDef] = {}
@@ -51,6 +53,8 @@ def register_op(type: str, infer: Optional[InferFn] = None):
             d.infer = prev.infer  # re-registration keeps an attached infer
         if prev is not None and prev.cost is not None:
             d.cost = prev.cost  # re-registration keeps an attached cost rule
+        if prev is not None:
+            d.step_stats = prev.step_stats
         _REGISTRY[type] = d
         return fn
 
@@ -76,6 +80,21 @@ def set_cost(type: str, cost: CostFn):
     except KeyError:
         raise KeyError(
             f"set_cost({type!r}): op has no registered lowering"
+        ) from None
+
+
+def set_step_stats(type: str, slots, publish: StatsFn):
+    """Attach statistics of a training step to a registered op: `slots`
+    names inputs or outputs of the op whose values `pipeline.train_loop`
+    fetches with the step and reads on LOGGED steps only (when the loss is
+    read: no sync of their own); `publish(step, values)` then gets {slot:
+    [one array per op of this type, in program order]} and sets the op's
+    gauges and step records on the monitor."""
+    try:
+        _REGISTRY[type].step_stats = (tuple(slots), publish)
+    except KeyError:
+        raise KeyError(
+            f"set_step_stats({type!r}): op has no registered lowering"
         ) from None
 
 

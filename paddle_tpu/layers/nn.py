@@ -746,6 +746,77 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None,
     return out
 
 
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None, name=None):
+    """Root-mean-square norm over the axes from `begin_norm_axis` on, with a
+    learned gain (initialised to 1) and no shift: y = x / sqrt(mean(x^2) +
+    epsilon) * g.  Statistics are float32 whatever the input's dtype."""
+    helper = LayerHelper("rms_norm", name=name)
+    from ..core.initializer import ConstantInitializer
+
+    norm_size = int(np.prod(input.shape[begin_norm_axis:]))
+    gain = helper.create_parameter(param_attr, [norm_size], input.dtype,
+                                   default_initializer=ConstantInitializer(1.0))
+    out = _out(helper, input.dtype, shape=input.shape)
+    helper.append_op(
+        "rms_norm", inputs={"X": [input.name], "Scale": [gain.name]},
+        outputs={"Y": [out.name]},
+        attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
+    return _keep_lod(input, out)
+
+
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position embedding (rotate-half convention) of (B, H, L, dh)
+    queries or keys; `positions` is the (B, L) integer position of every
+    token, fed like the ids."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = _out(helper, x.dtype, shape=x.shape)
+    helper.append_op(
+        "rotary_embedding", inputs={"X": [x.name], "Positions": [positions.name]},
+        outputs={"Out": [out.name]}, attrs={"theta": float(theta)})
+    return out
+
+
+def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
+        router_attr=None, gate_attr=None, up_attr=None, down_attr=None, name=None):
+    """A layer of routed experts over (..., d): a float32 router picks
+    `top_k` of `num_experts` gated-SiLU experts of width `expert_width` for
+    every token; their outputs are summed, weighted by the router's
+    probabilities (renormalised over the chosen ones only if
+    `norm_topk_prob`).  No capacity limit: no token is dropped.
+
+    Returns (out, load_balance_loss, router_z_loss); the two [1] float32
+    losses are for the caller to weigh into the training loss.  The experts
+    are three stacked parameters, (E, d, F) gate and up and (E, F, d)
+    down."""
+    helper = LayerHelper("moe", name=name)
+    d = int(input.shape[-1])
+    lead = tuple(input.shape[:-1])
+    router = helper.create_parameter(router_attr, [d, num_experts], "float32")
+    gate = helper.create_parameter(gate_attr, [num_experts, d, expert_width], input.dtype)
+    up = helper.create_parameter(up_attr, [num_experts, d, expert_width], input.dtype)
+    down = helper.create_parameter(down_attr, [num_experts, expert_width, d], input.dtype)
+    top_p = _out(helper, "float32", shape=lead + (top_k,))
+    top_i = _out(helper, "int32", shape=lead + (top_k,))
+    load = _out(helper, "int32", shape=(num_experts,))
+    balance = _out(helper, "float32", shape=(1,))
+    z_loss = _out(helper, "float32", shape=(1,))
+    helper.append_op(
+        "moe_router", inputs={"X": [input.name], "W": [router.name]},
+        outputs={"TopKProb": [top_p.name], "TopKIndex": [top_i.name], "Load": [load.name],
+                 "LoadBalanceLoss": [balance.name], "ZLoss": [z_loss.name]},
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+    out = _out(helper, input.dtype, shape=input.shape)
+    dropped = _out(helper, "int32", shape=(1,))
+    helper.append_op(
+        "moe_experts",
+        inputs={"X": [input.name], "TopKProb": [top_p.name], "TopKIndex": [top_i.name],
+                "Load": [load.name], "WGate": [gate.name], "WUp": [up.name],
+                "WDown": [down.name]},
+        outputs={"Out": [out.name], "Dropped": [dropped.name]},
+        attrs={"num_experts": int(num_experts), "top_k": int(top_k)})
+    return _keep_lod(input, out), balance, z_loss
+
+
 def dropout_prob_check(p):
     if not 0 <= p < 1:
         raise ValueError("dropout prob must be in [0,1)")

@@ -1,9 +1,18 @@
-"""Transformer encoder / BERT-style pretraining model.
+"""Transformer blocks: the BERT-style masked-LM encoder and the causal
+decoder LM with routed experts (OLMoE's block), from one attention builder
+and one layer builder.
 
 Reference builds transformers from the same primitive layers
 (tests/unittests/dist_transformer.py; BERT-base is the BASELINE.md pod
 target).  This builder emits fc/matmul/layer_norm/softmax program ops;
 attention is plain batched matmul, which XLA maps onto the MXU.
+
+What differs between the 2018 block and today's is an argument of the shared
+builders, not a second library: the norm (`layer` / `rms`), where it sits
+(post / pre), the positions (a learned table added to the embedding /
+rotary, applied to queries and keys), q/k-norm, projection biases, and the
+feed-forward part (dense GELU / routed gated-SiLU experts).  `build_bert`
+and `build_causal_lm` pick them.
 
 `tp_rules()` returns the sharding-hint ruleset for Megatron-style tensor
 parallelism (QKV/FFN1 column-parallel, proj/FFN2 row-parallel) — a new
@@ -14,7 +23,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers, optimizer
-from ..core.initializer import NormalInitializer
+from ..core.initializer import ConstantInitializer, NormalInitializer
 from ..core.param_attr import ParamAttr
 from ..core.program import Program, program_guard
 
@@ -23,25 +32,48 @@ def _attr(name):
     return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
 
 
+def _attr_ones(name):
+    """A norm's gain in the causal LM: 1, as its sources initialise it.
+    (`build_bert` draws its layer-norm gains N(0, 0.02) like every other
+    parameter: PERF.md section 7, defect 3.)"""
+    return ParamAttr(name=name, initializer=ConstantInitializer(1.0))
+
+
 def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1, is_test=False,
                          use_ring_attention=False, causal=False, kv=None, bias=None,
-                         use_fused_attention=False, score_dtype=None):
+                         use_fused_attention=False, score_dtype=None, proj_bias=True,
+                         qk_norm_eps=None, positions=None, rope_theta=10000.0):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
     cross-attention (keys/values from another sequence); `bias` is an
     additive [b, 1, Tq, Tk] pre-softmax mask (layers.attention_bias).
-    Serves both the fixed-length BERT builder and the ragged NMT model."""
+    Serves the fixed-length BERT builder, the ragged NMT model and the
+    causal LM: `proj_bias=False` drops the four projection biases,
+    `qk_norm_eps` puts an RMS norm over the whole width of the projected
+    queries and of the keys (before the heads are split, as OLMoE has it),
+    `positions` ([b, T] integers) rotates queries and keys (`rope_theta`)."""
     d_head = d_model // n_heads
     kv_in = kv if kv is not None else x
-    q = layers.fc(x, d_model, num_flatten_dims=2, param_attr=_attr(f"{prefix}.q.w"), bias_attr=_attr(f"{prefix}.q.b"))
-    k = layers.fc(kv_in, d_model, num_flatten_dims=2, param_attr=_attr(f"{prefix}.k.w"), bias_attr=_attr(f"{prefix}.k.b"))
-    v = layers.fc(kv_in, d_model, num_flatten_dims=2, param_attr=_attr(f"{prefix}.v.w"), bias_attr=_attr(f"{prefix}.v.b"))
+
+    def project(t, name):
+        return layers.fc(t, d_model, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
+                         bias_attr=_attr(f"{prefix}.{name}.b") if proj_bias else False)
+
+    q, k, v = project(x, "q"), project(kv_in, "k"), project(kv_in, "v")
+    if qk_norm_eps is not None:
+        q = layers.rms_norm(q, begin_norm_axis=2, epsilon=qk_norm_eps,
+                            param_attr=_attr_ones(f"{prefix}.q_norm.w"))
+        k = layers.rms_norm(k, begin_norm_axis=2, epsilon=qk_norm_eps,
+                            param_attr=_attr_ones(f"{prefix}.k_norm.w"))
 
     def split_heads(t):
         t = layers.reshape(t, [0, 0, n_heads, d_head])
         return layers.transpose(t, [0, 2, 1, 3])  # (B, H, L, dh)
 
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
+    if positions is not None:
+        q = layers.rotary_embedding(q, positions, theta=rope_theta)
+        k = layers.rotary_embedding(k, positions, theta=rope_theta)
     if use_fused_attention:
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
@@ -70,28 +102,62 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         ctx = layers.matmul(attn, v)  # (B, H, L, dh)
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     ctx = layers.reshape(ctx, [0, 0, d_model])
-    return layers.fc(ctx, d_model, num_flatten_dims=2,
-                     param_attr=_attr(f"{prefix}.out.w"), bias_attr=_attr(f"{prefix}.out.b"))
+    return project(ctx, "out")
 
 
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
                   use_ring_attention=False, causal=False, use_fused_attention=False,
-                  score_dtype=None):
-    attn_out = multi_head_attention(x, seq_len, d_model, n_heads, f"{prefix}.attn",
+                  score_dtype=None, norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
+                  qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None):
+    """One transformer layer: attention and a feed-forward part, each with a
+    residual connection and a norm.
+
+    The defaults are BERT's: layer norm AFTER each residual sum, projection
+    biases, a dense GELU feed-forward of width `d_ff`.  `norm="rms"` with
+    `pre_norm=True` norms each part's INPUT instead (h = x + attn(norm(x));
+    y = h + ffn(norm(h))); `qk_norm`, `positions` and `proj_bias` go to the
+    attention.  `moe=dict(num_experts=, top_k=, norm_topk_prob=)` makes the
+    feed-forward part `d_ff`-wide routed gated-SiLU experts; its two
+    auxiliary losses are appended to `aux_losses` as (load balance, router z).
+    """
+    def normed(t, name):
+        if norm == "rms":
+            return layers.rms_norm(t, begin_norm_axis=2, epsilon=norm_eps,
+                                   param_attr=_attr_ones(f"{prefix}.{name}.w"))
+        return layers.layer_norm(t, begin_norm_axis=2, epsilon=norm_eps,
+                                 param_attr=_attr(f"{prefix}.{name}.w"),
+                                 bias_attr=_attr(f"{prefix}.{name}.b"))
+
+    def feed_forward(t):
+        if moe is not None:
+            out, balance, z_loss = layers.moe(
+                t, moe["num_experts"], d_ff, moe["top_k"],
+                norm_topk_prob=moe.get("norm_topk_prob", False),
+                router_attr=_attr(f"{prefix}.moe.router.w"), gate_attr=_attr(f"{prefix}.moe.gate.w"),
+                up_attr=_attr(f"{prefix}.moe.up.w"), down_attr=_attr(f"{prefix}.moe.down.w"))
+            aux_losses.append((balance, z_loss))
+            return out
+        ffn1 = layers.fc(t, d_ff, num_flatten_dims=2, act="gelu",
+                         param_attr=_attr(f"{prefix}.ffn1.w"), bias_attr=_attr(f"{prefix}.ffn1.b"))
+        return layers.fc(ffn1, d_model, num_flatten_dims=2,
+                         param_attr=_attr(f"{prefix}.ffn2.w"), bias_attr=_attr(f"{prefix}.ffn2.b"))
+
+    attn_out = multi_head_attention(normed(x, "ln1") if pre_norm else x,
+                                    seq_len, d_model, n_heads, f"{prefix}.attn",
                                     dropout_prob, is_test, use_ring_attention, causal,
                                     use_fused_attention=use_fused_attention,
-                                    score_dtype=score_dtype)
-    x = layers.layer_norm(layers.elementwise_add(x, attn_out), begin_norm_axis=2,
-                          param_attr=_attr(f"{prefix}.ln1.w"), bias_attr=_attr(f"{prefix}.ln1.b"))
-    ffn1 = layers.fc(x, d_ff, num_flatten_dims=2, act="gelu",
-                     param_attr=_attr(f"{prefix}.ffn1.w"), bias_attr=_attr(f"{prefix}.ffn1.b"))
-    ffn2 = layers.fc(ffn1, d_model, num_flatten_dims=2,
-                     param_attr=_attr(f"{prefix}.ffn2.w"), bias_attr=_attr(f"{prefix}.ffn2.b"))
+                                    score_dtype=score_dtype, proj_bias=proj_bias,
+                                    qk_norm_eps=norm_eps if qk_norm else None,
+                                    positions=positions, rope_theta=rope_theta)
+    x = layers.elementwise_add(x, attn_out)
+    if not pre_norm:
+        x = normed(x, "ln1")
+    ffn_out = feed_forward(normed(x, "ln2") if pre_norm else x)
     if dropout_prob and not is_test:
-        ffn2 = layers.dropout(ffn2, dropout_prob, is_test=is_test,
-                              dropout_implementation="upscale_in_train")
-    return layers.layer_norm(layers.elementwise_add(x, ffn2), begin_norm_axis=2,
-                             param_attr=_attr(f"{prefix}.ln2.w"), bias_attr=_attr(f"{prefix}.ln2.b"))
+        ffn_out = layers.dropout(ffn_out, dropout_prob, is_test=is_test,
+                                 dropout_implementation="upscale_in_train")
+    x = layers.elementwise_add(x, ffn_out)
+    return x if pre_norm else normed(x, "ln2")
 
 
 def build_bert(
@@ -147,6 +213,77 @@ def build_bert(
         if with_optimizer:
             optimizer.Adam(learning_rate=learning_rate).minimize(loss)
     return main, startup, {"ids": ids, "labels": labels, "pos_ids": pos_ids}, {"loss": loss}
+
+
+def build_causal_lm(
+    vocab_size=50304,
+    seq_len=4096,
+    d_model=2048,
+    n_layers=16,
+    n_heads=16,
+    expert_width=1024,
+    num_experts=64,
+    top_k=8,
+    norm_topk_prob=False,
+    norm_eps=1e-5,
+    rope_theta=10000.0,
+    load_balance_coef=0.01,
+    router_z_coef=0.001,
+    learning_rate=4e-4,
+    beta1=0.9,
+    beta2=0.95,
+    epsilon=1e-8,
+    with_optimizer=True,
+    use_fused_attention=True,
+    dtype="float32",
+):
+    """Decoder-only language model with routed experts in every layer: the
+    OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
+    arXiv:2409.02060): pre-norm RMSNorm, rotary positions, q/k-norm, no
+    biases, causal attention, `num_experts` gated-SiLU experts of
+    `expert_width` with `top_k` a token, an untied head.
+
+    feeds: ids, labels (the next token at every position), pos_ids, all
+    (B, L) int64.  loss = mean cross entropy + `load_balance_coef` x the
+    layers' mean load-balance loss + `router_z_coef` x their mean router
+    z-loss; fetches also hold the three parts and the logits.
+    dtype="bfloat16" as in `build_bert`: activations and matmuls in bf16
+    over float32 master weights; norms' statistics, the router and the loss
+    stay float32."""
+    main, startup = Program(), Program()
+    with program_guard(main, startup):
+        ids = layers.data("ids", [seq_len], dtype="int64")
+        labels = layers.data("labels", [seq_len], dtype="int64")
+        pos_ids = layers.data("pos_ids", [seq_len], dtype="int64")
+        x = layers.embedding(ids, size=[vocab_size, d_model], param_attr=_attr("lm.tok_emb"))
+        if dtype != "float32":
+            x = layers.cast(x, dtype)
+        aux = []
+        for i in range(n_layers):
+            x = encoder_layer(x, seq_len, d_model, n_heads, expert_width, f"lm.l{i}",
+                              dropout_prob=0.0, causal=True,
+                              use_fused_attention=use_fused_attention,
+                              norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
+                              qk_norm=True, positions=pos_ids, rope_theta=rope_theta,
+                              moe=dict(num_experts=num_experts, top_k=top_k,
+                                       norm_topk_prob=norm_topk_prob),
+                              aux_losses=aux)
+        x = layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
+                            param_attr=_attr_ones("lm.final_norm.w"))
+        logits = layers.fc(x, vocab_size, num_flatten_dims=2,
+                           param_attr=_attr("lm.head.w"), bias_attr=False)
+        ce = layers.mean(layers.softmax_with_cross_entropy(
+            layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1])))
+        balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / n_layers)
+        z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / n_layers)
+        loss = layers.sums([ce, layers.scale(balance, scale=load_balance_coef),
+                            layers.scale(z_loss, scale=router_z_coef)])
+        if with_optimizer:
+            optimizer.Adam(learning_rate=learning_rate, beta1=beta1, beta2=beta2,
+                           epsilon=epsilon).minimize(loss)
+    return (main, startup, {"ids": ids, "labels": labels, "pos_ids": pos_ids},
+            {"loss": loss, "ce": ce, "load_balance": balance, "router_z": z_loss,
+             "logits": logits})
 
 
 def tp_rules():

@@ -4,6 +4,7 @@ from . import (  # noqa: F401
     detection_ops,
     math_ops,
     misc_ops,
+    moe_ops,
     nn_ops,
     optimizer_ops,
     pipeline_ops,

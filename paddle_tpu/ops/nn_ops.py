@@ -611,6 +611,40 @@ def _ring_attention(ctx, op, ins):
 _FLASH_MIN_SEQ = 2048
 
 
+def _flash_block_sizes(block_sizes_cls, q_len, kv_len, biased):
+    """Square blocks for the stock flash kernel, forward and both backward
+    kernels: 1024 queries and keys without a bias, 512 with one (the float32
+    bias tile is a fourth operand: at 1024 the dq kernel overruns the scoped
+    VMEM, tests/test_chip_compile.py); the kernel's own default (128
+    everywhere) where the lengths are not multiples of the block.  TPU v5e,
+    (4, 16, 4096, 128) bf16, causal, no bias, forward + backward: 74.0 ms at
+    the default, 29.8 at 256, 15.5 at 512, 14.4 at 1024 (XLA's own attention
+    with the scores in HBM: 58.8 ms and 8.6 GB; PERF.md, PR 26)."""
+    b = 512 if biased else 1024
+    if q_len % b or kv_len % b:
+        return None
+    return block_sizes_cls(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
+        block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+
+
+def _flash_attention_tpu(q, k, v, bias, causal, scale):
+    """The stock Pallas online-softmax flash kernel as `fused_attention`
+    calls it.  Only this kernel needs the bias pre-broadcast to per-head and
+    in float32; fused_sdpa and the jnp path broadcast lazily (a materialized
+    [B,H,L,L] bias is H x the HBM traffic)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes, flash_attention
+
+    ab = bias
+    if ab is not None and ab.shape[1] == 1 and q.shape[1] != 1:
+        ab = jnp.broadcast_to(ab, (ab.shape[0], q.shape[1]) + ab.shape[2:])
+    ab = ab.astype(jnp.float32) if ab is not None else None
+    sizes = _flash_block_sizes(BlockSizes, q.shape[2], k.shape[2], ab is not None)
+    out = flash_attention(q, k, v, ab=ab, causal=causal, sm_scale=scale, block_sizes=sizes)
+    return out.astype(q.dtype)
+
+
 @register_op("fused_attention")
 def _fused_attention(ctx, op, ins):
     """Flash-style fused scaled-dot-product attention over (B, H, L, dh).
@@ -633,18 +667,8 @@ def _fused_attention(ctx, op, ins):
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     min_seq = op.attr("flash_min_seq", _FLASH_MIN_SEQ)
     if ctx.platform == "tpu" and k.shape[2] >= min_seq:
-        # long-sequence streaming kernel (O(L) memory): the stock online-
-        # softmax flash implementation.  Only THIS kernel needs the bias
-        # pre-broadcast to per-head; fused_sdpa and the jnp path broadcast
-        # lazily (a materialized [B,H,L,L] bias is H x the HBM traffic).
-        from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
-
-        ab = bias
-        if ab is not None and ab.shape[1] == 1 and q.shape[1] != 1:
-            ab = jnp.broadcast_to(ab, (ab.shape[0], q.shape[1]) + ab.shape[2:])
-        ab = ab.astype(jnp.float32) if ab is not None else None
-        out = flash_attention(q, k, v, ab=ab, causal=causal, sm_scale=scale)
-        return {"Out": out.astype(q.dtype)}
+        # long-sequence streaming kernel (O(L) memory)
+        return {"Out": _flash_attention_tpu(q, k, v, bias, causal, scale)}
     if (ctx.platform == "tpu" and op.attr("use_pallas_sdpa", False)
             and max(q.shape[2], k.shape[2]) <= 512):
         # moderate-L fused kernel (ops/pallas_attention.py): whole-row

@@ -25,7 +25,13 @@ the host spent waiting on the device — the overlap-win metric); and one
 `kind="pipeline_step"` record per drained step with that step's wall
 time and what the host spent in each of the three, which
 `tools/perf_report.py` turns into a host-blocked fraction (and can gate
-on via `--check --max-host-blocked-frac`).
+on via `--check --max-host-blocked-frac`).  An op may declare statistics of
+the step (`core.registry.set_step_stats`): their variables ride along as
+fetches of the step and are read on LOGGED steps only, when the loss is (no
+sync of their own), and the op publishes them.  `moe_experts` does
+(ops/moe_ops.py): every layer's tokens per expert and dropped-token count
+become the gauges `moe.load_max_over_mean`, `moe.load_min_over_mean`,
+`moe.dropped_tokens` and one `kind="moe_routing"` record per logged step.
 """
 from __future__ import annotations
 
@@ -37,6 +43,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 import numpy as np
 
 from . import errors as _errors
+from .core.registry import get_op_def_or_none
 from .monitor import MONITOR as _MON
 
 
@@ -58,6 +65,23 @@ class PipelineStats:
         near 1.0 whenever the device step dominates; the pipelined loop's
         win is exactly how far below that it lands."""
         return self.host_blocked_s / self.wall_s if self.wall_s > 0 else 0.0
+
+
+def _step_stats(program):
+    """[(publish, {slot: [variable name per op]})] for every op type of the
+    program's main block that declares step statistics
+    (`core.registry.set_step_stats`); empty for most programs."""
+    program = getattr(program, "program", program)  # a CompiledProgram wraps one
+    found = {}
+    for op in program.global_block().ops:
+        op_def = get_op_def_or_none(op.type)
+        if op_def is None or op_def.step_stats is None:
+            continue
+        slots, publish = op_def.step_stats
+        names = found.setdefault(op.type, (publish, {slot: [] for slot in slots}))[1]
+        for slot in slots:
+            names[slot].append((op.inputs.get(slot) or op.outputs[slot])[0])
+    return list(found.values())
 
 
 def train_loop(
@@ -118,6 +142,10 @@ def train_loop(
         raise ValueError(f"log_period must be >= 1, got {log_period}")
 
     stats = PipelineStats()
+    n_user = len(fetch_list)
+    step_stats = _step_stats(program)
+    fetch_list = list(fetch_list) + [name for _, names in step_stats
+                                     for slot in names.values() for name in slot]
     # (step index, [FetchHandle, ...], seconds in next(it), in run_async)
     inflight: deque = deque()
     gauge = _MON.gauge("pipeline.inflight")
@@ -159,6 +187,13 @@ def train_loop(
                 "logged": want_log,
             })
         last_drain_t = now
+        if must_resolve and step_stats:
+            if want_log and _MON.enabled:
+                extra = iter(vals[n_user:])
+                for publish, names in step_stats:
+                    publish(step_i, {slot: [next(extra) for _ in slot_names]
+                                     for slot, slot_names in names.items()})
+            vals = vals[:n_user]
         if want_log:
             if on_logged is not None:
                 on_logged(step_i, vals)

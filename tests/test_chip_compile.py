@@ -1,6 +1,7 @@
 """Every `pallas_call` the main path can reach, compiled by the TPU's own
 compiler for a described (not attached) v5e at BERT-base / ResNet-50 widths,
-forward and backward.
+forward and backward; since PR 26 also the grouped matmul and the flash kernel
+at OLMoE-1B-7B's widths.
 
 Interpret mode (tests/test_pallas_kernels.py) checks the numbers; it cannot
 see what Mosaic refuses: a block that is not a whole (8|16, 128) tile, a
@@ -61,10 +62,20 @@ def _adam(p, g, m, v, lr):
     return pk.fused_adam(p, g, m, v, lr, 0.9, 0.999, 1e-8)
 
 
-def _flash(q, k, v):
-    from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
+def _flash(causal):
+    """The stock flash kernel as the `fused_attention` op calls it on a TPU
+    (ops/nn_ops.py: its block sizes, the bias broadcast per head in float32)."""
+    from paddle_tpu.ops.nn_ops import _flash_attention_tpu
 
-    return flash_attention(q, k, v, causal=False, sm_scale=0.125)
+    def run(q, k, v, bias=None):
+        return _flash_attention_tpu(q, k, v, bias, causal, q.shape[-1] ** -0.5)
+    return run
+
+
+def _gmm(rows, weights, sizes):
+    from paddle_tpu.ops.moe_ops import grouped_matmul
+
+    return grouped_matmul(rows, weights, sizes, "tpu")
 
 
 # BERT-base: batch 256 x seq 128 rows (bench.py's batch), d_model 768,
@@ -113,8 +124,29 @@ CASES = {
     "fused_sdpa": (
         lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
         [((256, 12, 128, 64), BF16)] * 3, (0, 1, 2)),
+    # through `_flash_block_sizes`: 1024-blocks without a bias, 512 with one
+    # (1024 with a bias overruns the scoped VMEM in the dq kernel), the
+    # kernel's default where the length is a multiple of neither
     "flash_attention_seq2048": (
-        _flash, [((4, 12, 2048, 64), BF16)] * 3, (0, 1, 2)),
+        _flash(False), [((4, 12, 2048, 64), BF16)] * 3, (0, 1, 2)),
+    "flash_attention_seq2048_bias": (
+        _flash(False), [((4, 12, 2048, 64), BF16)] * 3 + [((4, 1, 2048, 2048), BF16)],
+        (0, 1, 2, 3)),
+    "flash_attention_seq2176_bias": (
+        _flash(False), [((2, 12, 2176, 64), BF16)] * 3 + [((2, 1, 2176, 2176), F32)], (0, 1, 2)),
+    # OLMoE-1B-7B: 4 x 4096 tokens x 8 experts a token = 131072 rows sorted
+    # by expert, 64 experts of 2048 x 1024 (gate, up) and 1024 x 2048 (down),
+    # 16 heads of 128 at 4096 keys
+    "flash_attention_seq4096_causal": (
+        _flash(True), [((4, 16, 4096, 128), BF16)] * 3, (0, 1, 2)),
+    "flash_attention_seq4096_causal_bias": (
+        _flash(True), [((1, 16, 4096, 128), BF16)] * 3 + [((1, 16, 4096, 4096), F32)], (0, 1, 2)),
+    "grouped_matmul_olmoe_gate": (
+        _gmm, [((131072, 2048), BF16), ((64, 2048, 1024), BF16), ((64,), I32)], (0, 1)),
+    "grouped_matmul_olmoe_down": (
+        _gmm, [((131072, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)], (0, 1)),
+    "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
+        _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
 }
 
 
