@@ -11,11 +11,19 @@ are also Mixtral's and DeepSeek-MoE's but for the shared expert:
     moe_experts       y = sum_{e in top_k} p_e . Wdown_e( silu(Wgate_e x) * (Wup_e x) )
 
 Backward comes from `jax.vjp` over these lowerings like every other op's
-(core/lowering.py).  Two transposes are written by hand, `_permute_rows`
-and `_rows_by_expert`: the derived transpose of a row gather is a
-scatter-add, and here the gather is a permutation whose inverse is known, so
-the transpose is the other gather (TPU v5e, one OLMoE layer forward and
-backward: 66.2 ms against 74.7 derived; PERF.md, PR 26).
+(core/lowering.py).  `moe_experts` makes no pass over a [rows, hidden],
+[rows, width] or [experts, ., .] array outside its grouped kernels that the
+mathematics does not need (PERF.md, PR 28).  Two row operations exist,
+`_rows_by_expert` (tokens to expert order) and `_sum_by_token` (back, summing
+each token's k rows), written as each other's transposes: the derived
+transpose of a row gather is a scatter-add, and here the permutation's inverse
+is known, so the transpose is the other gather (TPU v5e, one OLMoE layer's
+experts forward and backward: 66.2 ms against 74.7 derived; PERF.md, PR 26).
+Both promise their indices, so no fill value is selected over their result.
+The router's weights multiply the hidden rows, in expert order, so nothing
+else passes over a [rows, hidden] array, forward or backward.  The matrices
+are float32 masters: `grouped_matmul` casts each once for the forward and
+hands back `tgmm`'s float32 accumulator as its gradient.
 """
 from __future__ import annotations
 
@@ -98,103 +106,175 @@ def _moe_router(ctx, op, ins):
     }
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """x[perm] for a permutation `perm` whose inverse is `inverse`."""
-    return jnp.take(x, perm, axis=0)
+def _take_rows(x, index):
+    """x[index] along axis 0 for an `index` that is in bounds by
+    construction (an argsort's permutation, or one integer-divided): said
+    to the gather, so that it has no fill value to select in a second pass
+    over its result (PERF.md, PR 28)."""
+    return x.at[index].get(mode="promise_in_bounds")
 
 
-_permute_rows.defvjp(
-    lambda x, perm, inverse: (jnp.take(x, perm, axis=0), inverse),
-    lambda inverse, g: (jnp.take(g, inverse, axis=0), None, None))
-
+# The op's two row operations, each the other's transpose.  `order` is a
+# permutation of the tokens x k (token, slot) assignments, `inverse` its
+# inverse; row i of the expert-ordered side is assignment order[i], of
+# token order[i] // k.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _rows_by_expert(x, order, inverse, k):
-    """One row per (token, slot) assignment, in the order `order` (a
-    permutation of the tokens x k assignments, `inverse` its inverse): row i
-    is token order[i] // k.  The transpose gathers the rows' gradients back
-    into assignment order and sums each token's k, in float32."""
-    return jnp.take(x, order // k, axis=0)
+    """Tokens [T, d] -> one row per assignment [T k, d], in `order`."""
+    return _take_rows(x, order // k)
 
 
-def _rows_by_expert_bwd(k, inverse, g):
-    g = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
-    return jnp.sum(g, axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_by_token(rows, order, inverse, k):
+    """Rows [T k, d] in `order` -> tokens [T, d]: each token's k rows,
+    summed in float32."""
+    rows = _take_rows(rows, inverse).reshape(-1, k, rows.shape[-1])
+    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
 
 
 _rows_by_expert.defvjp(
-    lambda x, order, inverse, k: (jnp.take(x, order // k, axis=0), inverse),
-    _rows_by_expert_bwd)
+    lambda x, order, inverse, k: (_rows_by_expert(x, order, inverse, k), (order, inverse)),
+    lambda k, res, g: (_sum_by_token(g, *res, k), None, None))
+_sum_by_token.defvjp(
+    lambda rows, order, inverse, k: (_sum_by_token(rows, order, inverse, k), (order, inverse)),
+    lambda k, res, g: (_rows_by_expert(g, *res, k), None, None))
 
-#: (rows, contraction, columns) tile of the megablox kernel.  TPU v5e, the
+
+@jax.custom_vjp
+def _permute_scalars(v, perm, inverse):
+    """v[perm] for a vector `v` and a permutation `perm` whose inverse is
+    `inverse`, as a sort of the pairs (inverse[j], v[j]) by their key: a
+    gather of scalars runs element by element on the chip (1.07 ms for
+    131072 floats against 0.09 for the sort; PERF.md, PR 28).  The
+    transpose is the same with the two permutations exchanged."""
+    return jax.lax.sort((inverse, v), num_keys=1, is_stable=False)[1]
+
+
+_permute_scalars.defvjp(
+    lambda v, perm, inverse: (_permute_scalars(v, perm, inverse), (perm, inverse)),
+    lambda res, g: (_permute_scalars(g, res[1], res[0]), None, None))
+
+#: (rows, contraction, columns) tile of the megablox kernels.  TPU v5e, the
 #: three products of one OLMoE layer over 131072 rows, forward and backward
 #: (PERF.md, PR 26): 66.2 ms with this tile; (256, 1024, 1024) 67.7,
 #: (512, 1024, 512) 69.7, (512, 512, 1024) 71.4, (512, 512, 512) 78.4,
 #: (1024, 512, 512) 79.2, the kernel's default (128, 128, 128) 531.9;
 #: (1024, 1024, 1024) and (512, 2048, 1024) do not fit the scoped VMEM.
 _GMM_TILE = (512, 1024, 1024)
+#: `tgmm`'s, whose output block is float32 for a float32 master: two such
+#: blocks and the accumulator are 16 MB at `_GMM_TILE`, over the scoped VMEM.
+#: The same layer with float32 gradients (PERF.md, PR 28): 57.6 ms with this
+#: tile against 60.5 for bf16 gradients widened afterwards; (256, 2048, 512)
+#: 58.2, (512, 1024, 512) and (512, 512, 1024) 59.1, (1024, 1024, 512) and
+#: (1024, 512, 1024) 60.3, (1024, 512, 512) 62.4, (2048, 512, 512) 65.9.
+_TGMM_TILE = (256, 1024, 1024)
+
+
+def _tile(tile, m, k, n):
+    """`tile` cut to a product of [m, k] by [k, n] over padded rows: fewer
+    than a row tile of them are one tile."""
+    return (tile[0] if m % tile[0] == 0 else m, min(tile[1], k), min(tile[2], n))
+
+
+def _pad_rows(rows):
+    """Rows padded with zeros, which belong to no group, to a multiple of
+    the kernels' row tile."""
+    m = rows.shape[0]
+    tm = _GMM_TILE[0] if m >= _GMM_TILE[0] else 128
+    return jnp.pad(rows, ((0, -m % tm), (0, 0)))
+
+
+def _megablox():
+    """The stock kernels `gmm` and `tgmm` themselves: the package's VJP over
+    them hands back the matrices' gradient in the rows' dtype."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops
+
+    return ops.backend
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(rows, master, group_sizes, interpret):
+    return _grouped_matmul_fwd(rows, master, group_sizes, interpret)[0]
+
+
+def _grouped_matmul_fwd(rows, master, group_sizes, interpret):
+    # the one copy of the matrices the forward needs, outside the products' scope
+    weights = match_dtype(rows, master)
+    m, k = rows.shape
+    with jax.named_scope("expert_gemm"):
+        rows = _pad_rows(rows)
+        out = _megablox().gmm(rows, weights, group_sizes, rows.dtype,
+                              _tile(_GMM_TILE, rows.shape[0], k, weights.shape[2]), interpret=interpret)[:m]
+    return out, (rows, weights, group_sizes, master)  # the master for its dtype
+
+
+def _grouped_matmul_bwd(interpret, res, g):
+    """The rows' gradient is `gmm` over the transposed matrices; the
+    matrices' is `tgmm`'s float32 accumulator, written in the MASTER's dtype:
+    for a float32 master never rounded to the rows' dtype and widened again
+    (three passes over 805 MB each in an OLMoE layer; PERF.md, PR 28)."""
+    rows, weights, group_sizes, master = res
+    m, (padded, k), n = g.shape[0], rows.shape, weights.shape[2]
+    with jax.named_scope("expert_gemm"):
+        g = _pad_rows(g)
+        d_rows = _megablox().gmm(g, weights, group_sizes, rows.dtype, _tile(_GMM_TILE, padded, k, n),
+                                 transpose_rhs=True, interpret=interpret)[:m]
+        d_master = _megablox().tgmm(rows.swapaxes(0, 1), g, group_sizes, master.dtype,
+                                    _tile(_TGMM_TILE, padded, k, n), interpret=interpret)
+    return d_rows, d_master, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 def grouped_matmul(rows, weights, group_sizes, platform=None):
     """rows [M, K] sorted by group, weights [G, K, N], group_sizes [G]
     summing to M -> [M, N]: row i is multiplied by the matrix of its own
-    group.  Rows and matrices share a dtype; accumulation is float32.
+    group.  The matrices are multiplied in the rows' dtype (a float32 master
+    of bf16 rows is cast once); accumulation is float32, and the matrices'
+    gradient leaves it in THEIR dtype.
 
-    The stock Pallas grouped-matmul kernel
-    (jax.experimental.pallas.ops.tpu.megablox: forward `gmm`, its custom VJP
-    `gmm` with the matrices transposed for the rows' gradient and `tgmm` for
-    the matrices'), compiled on a TPU and interpreted elsewhere (CPU tests,
-    virtual meshes), so what the tests check is what the chip runs.  The
-    kernel wants the row count a multiple of its row tile: rows are padded
-    with zeros that belong to no group.  On the chip `jax.lax.ragged_dot`
-    read 101.9 ms against 66.2 for the layer above, and applying every expert
-    to every token (8x the arithmetic) 82.1 ms forward alone, its backward
-    30 GB (PERF.md, PR 26)."""
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-
-    m, k = rows.shape
-    n = weights.shape[2]
-    tm = _GMM_TILE[0] if m >= _GMM_TILE[0] else -(-m // 128) * 128
-    padded = -(-m // tm) * tm
-    if padded != m:
-        rows = jnp.pad(rows, ((0, padded - m), (0, 0)))
-    out = megablox.gmm(rows, weights, group_sizes, rows.dtype,
-                       (tm, min(_GMM_TILE[1], k), min(_GMM_TILE[2], n)),
-                       None, None, False, platform != "tpu")
-    return out[:m]
+    The stock Pallas grouped-matmul kernels
+    (jax.experimental.pallas.ops.tpu.megablox: forward `gmm`; backward `gmm`
+    with the matrices transposed for the rows' gradient and `tgmm` for the
+    matrices'), compiled on a TPU and interpreted elsewhere (CPU tests,
+    virtual meshes), so what the tests check is what the chip runs.  On the
+    chip `jax.lax.ragged_dot` read 101.9 ms against 66.2 for the layer above,
+    and applying every expert to every token (8x the arithmetic) 82.1 ms
+    forward alone, its backward 30 GB (PERF.md, PR 26)."""
+    return _grouped_matmul(rows, weights, group_sizes, platform != "tpu")
 
 
 @register_op("moe_experts")
 def _moe_experts(ctx, op, ins):
     """Every (token, slot) assignment is a row: the rows are sorted by
-    expert, multiplied group by group (`grouped_matmul`: 1/8 of the
-    arithmetic of applying all 64 experts to every token), weighted by
-    their router probability and summed back per token.  No capacity, so no
-    dropped token, however skewed the router: `Dropped` is the number of
-    rows the group sizes do not cover, 0 by construction."""
+    expert and multiplied group by group (`grouped_matmul`: 1/8 of the
+    arithmetic of applying all 64 experts to every token); the hidden rows
+    are weighted by their router probability (sum_k p_k Wdown(h_k) =
+    sum_k Wdown(p_k h_k): float32 into the one rounding of `hidden`), and
+    the down product's rows are summed back per token in float32.  No
+    capacity, so no dropped token, however skewed the router: `Dropped` is
+    the number of rows the group sizes do not cover, 0 by construction."""
     x = first(ins, "X")
     top_p = first(ins, "TopKProb")
     top_i = first(ins, "TopKIndex")
     load = first(ins, "Load")
-    # master weights follow the activations' dtype, outside the products' scope
-    w_gate, w_up, w_down = (match_dtype(x, first(ins, s)) for s in ("WGate", "WUp", "WDown"))
+    w_gate, w_up, w_down = (first(ins, s) for s in ("WGate", "WUp", "WDown"))
     d, k = x.shape[-1], top_i.shape[-1]
     x2 = x.reshape(-1, d)
     tokens = x2.shape[0]
     order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
     rows = _rows_by_expert(x2, order, inverse, k)
-    with jax.named_scope("expert_gemm"):
-        gate = grouped_matmul(rows, w_gate, load, ctx.platform)
-        up = grouped_matmul(rows, w_up, load, ctx.platform)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(x.dtype)
-    with jax.named_scope("expert_gemm"):
-        down = grouped_matmul(hidden, w_down, load, ctx.platform)
-    down = _permute_rows(down, inverse, order).reshape(tokens, k, d)
-    out = jnp.einsum("tkd,tk->td", down.astype(jnp.float32),
-                     top_p.reshape(tokens, k).astype(jnp.float32))
-    return {"Out": out.astype(x.dtype).reshape(x.shape),
+    # each row's router probability, in expert order
+    weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
+    gate = grouped_matmul(rows, w_gate, load, ctx.platform)
+    up = grouped_matmul(rows, w_up, load, ctx.platform)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * weight).astype(x.dtype)
+    down = grouped_matmul(hidden, w_down, load, ctx.platform)
+    out = _sum_by_token(down, order, inverse, k)
+    return {"Out": out.reshape(x.shape),
             "Dropped": (tokens * k - jnp.sum(load)).astype(jnp.int32).reshape((1,))}
 
 
@@ -292,17 +372,25 @@ def _cost_moe_router(ctx):
     return (2.0 * ws[0] + 16.0) * tokens * ws[1], ctx.io_bytes()
 
 
+#: Passes of the forward lowering over its (token, slot) rows, in arrays read
+#: or written.  [rows, hidden]: written by the gather, read by the gate and by
+#: the up product, written by the down product, read and written by the gather
+#: back to token order, read by the sum over k.  [rows, width]: written by gate
+#: and up, both read and one written by SiLU x up x weight, read by the down
+#: product.  tests/test_chip_compile.py counts them in the compiled program.
+_ROW_PASSES = {"hidden": 7, "width": 6}
+
+
 def _cost_moe_experts(ctx):
     """Useful arithmetic of the three grouped products over the (token,
     slot) rows, 2 per multiply-add, whatever a kernel pads; traffic: every
-    expert's three matrices once, the rows in and out of each product
-    (sorted copy, gate, up, hidden, down) and the combine."""
+    expert's three matrices once and `_ROW_PASSES` over the rows."""
     gate = ctx.in_shape("WGate")
     if gate is None or ctx.in_shape("TopKIndex") is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()
     rows, d, f = ctx.in_elems("TopKIndex"), gate[1], gate[2]
     item = 2 if ctx.env.dtype(ctx.in_name("X")) in ("bfloat16", "float16") else 4
-    moved = rows * (2 * d + 3 * f + 2 * d) * item
+    moved = rows * (_ROW_PASSES["hidden"] * d + _ROW_PASSES["width"] * f) * item
     return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
 
 

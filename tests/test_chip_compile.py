@@ -1,7 +1,8 @@
 """Every `pallas_call` the main path can reach, compiled by the TPU's own
 compiler for a described (not attached) v5e at BERT-base / ResNet-50 widths,
 forward and backward; since PR 26 also the grouped matmul and the flash kernel
-at OLMoE-1B-7B's widths.
+at OLMoE-1B-7B's widths, since PR 28 the whole `moe_experts` lowering there,
+with the passes over its rows that the optimised program may hold.
 
 Interpret mode (tests/test_pallas_kernels.py) checks the numbers; it cannot
 see what Mosaic refuses: a block that is not a whole (8|16, 128) tile, a
@@ -11,6 +12,8 @@ so a pass here says nothing about results — `chip_smoke.py` does that on the
 chip.
 """
 import os
+import re
+from types import SimpleNamespace
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -145,6 +148,11 @@ CASES = {
         _gmm, [((131072, 2048), BF16), ((64, 2048, 1024), BF16), ((64,), I32)], (0, 1)),
     "grouped_matmul_olmoe_down": (
         _gmm, [((131072, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)], (0, 1)),
+    # float32 masters under bf16 rows: `tgmm` writes its float32 accumulator, at a tile of its own
+    "grouped_matmul_olmoe_gate_master": (
+        _gmm, [((131072, 2048), BF16), ((64, 2048, 1024), F32), ((64,), I32)], (0, 1)),
+    "grouped_matmul_olmoe_down_master": (
+        _gmm, [((131072, 1024), BF16), ((64, 1024, 2048), F32), ((64,), I32)], (0, 1)),
     "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
 }
@@ -162,6 +170,97 @@ def test_kernel_compiles_for_v5e(name, chip):
         compiled = jax.jit(program).lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text(), (
             f"{name}: compiled without a Mosaic kernel")
+
+
+def _moe_experts(x, top_p, top_i, load, w_gate, w_up, w_down):
+    """The op's lowering as the interpreter calls it for a TPU."""
+    from paddle_tpu.core.lowering import LoweringContext
+    from paddle_tpu.core.registry import get_op_def
+
+    op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: default)
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform="tpu")
+    ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load],
+           "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
+    return get_op_def("moe_experts").lower(ctx, op, ins)["Out"]
+
+
+#: OLMoE-1B-7B's layer of experts over 4 x 4096 tokens: tokens, hidden, width, experts, experts a token
+OLMOE_EXPERTS = (4 * 4096, 2048, 1024, 64, 8)
+
+
+def _moe_experts_args(chip):
+    """`_moe_experts`' arguments at `OLMOE_EXPERTS`: bf16 activations, float32 masters."""
+    tokens, hidden, width, experts, k = OLMOE_EXPERTS
+    specs = [((tokens, hidden), BF16), ((tokens, k), F32), ((tokens, k), I32), ((experts,), I32),
+             ((experts, hidden, width), F32), ((experts, hidden, width), F32), ((experts, width, hidden), F32)]
+    return [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
+
+
+#: Temporaries of the program below as compiled here for the described v5e:
+#: 1.881 GB at the parent of PR 28 (fill-mode gathers, the weighted combine in
+#: token order), 1.614 GB without those, 1.883 GB with the matrices' gradients
+#: float32 from `tgmm` on (each 268 MB more than a bf16 one, from its kernel
+#: to the end of the program).  The bound is the last reading and a margin.
+MOE_EXPERTS_TEMP_BYTES = 1.95e9
+
+
+def test_moe_experts_at_olmoe_widths_passes_over_its_rows_no_more_than_it_must(chip):
+    """OLMoE-1B-7B's layer of experts over 4 x 4096 tokens, forward and the
+    gradients of X, TopKProb and the three float32 master matrices: outside
+    the kernels no `select` writes an [rows, hidden] array (a gather that
+    promises its indices has no fill value to select) and at most five
+    instructions write one: the gather to rows, the rows' two gradients
+    added, and the two gathers back to token order before the sums over k
+    (forward: the output; backward: X's gradient).  The masters' gradients
+    are the three `tgmm` calls' own float32 results: nothing else writes an
+    f32[experts, ., .] array (no bf16 gradient widened).  PERF.md, PR 28."""
+    tokens, hidden, width, experts, k = OLMOE_EXPERTS
+    args = _moe_experts_args(chip)
+    program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(_moe_experts(*a).astype(F32))),
+                                 argnums=(0, 1, 4, 5, 6))
+    compiled = jax.jit(program).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 9  # three products, each with its two transposes
+    rows_by_hidden = rf"= \w+\[{tokens * k},{hidden}\]\S* "
+    assert not re.findall(rows_by_hidden + r"select\(", text)
+    entry = text[text.index("ENTRY"):]
+    written = [line.split(" = ")[0].strip() for line in entry.splitlines()
+               if re.search(rows_by_hidden + r"(?!parameter|bitcast|get-tuple-element)", line)
+               and "tpu_custom_call" not in line]
+    assert len(written) <= 5, written
+    of_the_masters = [line for line in entry.splitlines()
+                      if re.search(rf"= f32\[{experts},\d+,\d+\]\S* (?!parameter)", line)]
+    assert len(of_the_masters) == 3 and all("tpu_custom_call" in line and "tgmm" in line
+                                            for line in of_the_masters), of_the_masters
+    assert compiled.memory_analysis().temp_size_in_bytes < MOE_EXPERTS_TEMP_BYTES
+
+
+def test_moe_experts_cost_row_counts_the_passes_of_the_compiled_forward(chip):
+    """`ops.moe_ops._ROW_PASSES`, which the op's cost row charges, against
+    the forward program at OLMoE's widths: every [rows, hidden] and
+    [rows, width] array an instruction of the entry computation reads or
+    writes, kernels included."""
+    from collections import Counter
+
+    from paddle_tpu.ops.moe_ops import _ROW_PASSES
+
+    tokens, hidden, width, experts, k = OLMOE_EXPERTS
+    args = _moe_experts_args(chip)
+    text = jax.jit(_moe_experts).lower(*args).compile().as_text()
+    of_rows = rf"\w+\[{tokens * k},(\d+)\]"
+    types, passes = {}, Counter()
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$", line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        types[name] = result
+        if opcode in ("parameter", "bitcast", "tuple", "get-tuple-element"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0].split("), ")[0])
+        passes.update(int(n) for t in [result] + [types.get(o, "") for o in operands]
+                      for n in re.findall(of_rows, t))
+    assert passes == {hidden: _ROW_PASSES["hidden"], width: _ROW_PASSES["width"]}, passes
 
 
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [

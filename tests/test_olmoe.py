@@ -161,20 +161,36 @@ def routing_case(name, tokens, experts, k):
             top_i[t, 1:] = [(top_i[t, 0] + j) % experts for j in range(1, k)]
     elif name == "one_expert_empty":
         top_i = np.stack([RNG.permutation(experts - 1)[:k] + 1 for _ in range(tokens)])
-    else:  # all tokens to the same k experts, slot 0 always expert 5
+    elif name == "all_tokens_to_one_expert":  # the same k experts, slot 0 always expert 5
         top_i = np.tile((5 + np.arange(k)) % experts, (tokens, 1))
+    elif name == "over_half_the_rows_to_one_expert":  # three tokens of four choose expert 2
+        top_i = np.where(np.arange(tokens) % 4 < 3, 2, RNG.randint(experts, size=tokens)).reshape(tokens, 1)
+    else:
+        top_i = np.stack([RNG.permutation(experts)[:k] for _ in range(tokens)])
     return top_i.astype("i4")
 
 
-@pytest.mark.parametrize("case", ["every_expert_hit", "one_expert_empty", "all_tokens_to_one_expert"])
+#: name -> (tokens, k); 80 rows pad to one row tile of 128, 600 to two of 512
+ROUTING_CASES = {"every_expert_hit": (40, 2), "one_expert_empty": (40, 2), "all_tokens_to_one_expert": (40, 2),
+                 "top_k_1": (40, 1), "over_half_the_rows_to_one_expert": (40, 1),
+                 "rows_no_multiple_of_the_row_tile": (300, 2)}
+
+
+@pytest.mark.parametrize("case", list(ROUTING_CASES))
 def test_moe_experts_golden_forward_and_gradient(case):
-    tokens, d, f, experts, k = 40, 16, 12, 8, 2
+    """Against every expert applied to every token in float32: the output and
+    the gradients of X, TopKProb (through the hidden rows: the weights are
+    applied in expert order), WGate, WUp and WDown."""
+    (tokens, k), d, f, experts = ROUTING_CASES[case], 16, 12, 8
     x = RNG.randn(tokens, d).astype("f4")
     top_i = routing_case(case, tokens, experts, k)
     top_p = RNG.rand(tokens, k).astype("f4") * 0.3 + 0.05
     load = np.bincount(top_i.reshape(-1), minlength=experts).astype("i4")
     assert {"every_expert_hit": load.min() > 0, "one_expert_empty": load[0] == 0,
-            "all_tokens_to_one_expert": load[5] == tokens}[case]
+            "all_tokens_to_one_expert": load[5] == tokens, "top_k_1": load.sum() == tokens,
+            "over_half_the_rows_to_one_expert": 2 * load[2] > tokens * k,
+            "rows_no_multiple_of_the_row_tile": (tokens * k) % moe_ops._GMM_TILE[0] != 0
+            and tokens * k > moe_ops._GMM_TILE[0]}[case]
     weights = [(RNG.randn(experts, d, f) * 0.3).astype("f4"), (RNG.randn(experts, d, f) * 0.3).astype("f4"),
                (RNG.randn(experts, f, d) * 0.3).astype("f4")]
 
@@ -195,6 +211,55 @@ def test_moe_experts_golden_forward_and_gradient(case):
         assert all(float(jnp.abs(g[0]).max()) == 0.0 for g in ours_g[2:])
 
 
+@pytest.mark.parametrize("tokens,k", [(12, 1), (10, 3)])
+def test_rows_by_expert_and_sum_by_token_are_each_others_transpose(tokens, k):
+    """The op's two row operations against the transposes jax derives from
+    the plain gathers (scatter-adds): each one's values are the other's
+    derived transpose, and so is each one's hand-written VJP."""
+    d = 5
+    order = RNG.permutation(tokens * k).astype("i4")
+    inverse = np.argsort(order).astype("i4")
+    x = jnp.asarray(RNG.randn(tokens, d), jnp.float32)
+    rows = jnp.asarray(RNG.randn(tokens * k, d), jnp.float32)
+    to_rows = lambda x: moe_ops._rows_by_expert(x, order, inverse, k)  # noqa: E731
+    to_tokens = lambda rows: moe_ops._sum_by_token(rows, order, inverse, k)  # noqa: E731
+    agree(to_rows(x), np.asarray(x)[order // k], tol=0)
+    scatter_add, = jax.linear_transpose(lambda x: x[order // k], x)(rows)
+    agree(to_tokens(rows), scatter_add)
+    agree(jax.vjp(to_rows, x)[1](rows)[0], scatter_add)
+    spread, = jax.linear_transpose(lambda rows: rows[inverse].reshape(tokens, k, d).sum(1), rows)(x)
+    agree(to_rows(x), spread, tol=0)
+    agree(jax.vjp(to_tokens, rows)[1](x)[0], spread, tol=0)
+    agree(jnp.vdot(to_rows(x), rows), jnp.vdot(x, to_tokens(rows)))  # <A x, r> = <x, A' r>
+
+
+def test_permute_scalars_is_the_gather_and_its_transpose_the_inverse_gather():
+    n = 37
+    perm = RNG.permutation(n).astype("i4")
+    inverse = np.argsort(perm).astype("i4")
+    v, g = (jnp.asarray(RNG.randn(n), jnp.float32) for _ in range(2))
+    agree(moe_ops._permute_scalars(v, perm, inverse), np.asarray(v)[perm], tol=0)
+    scatter, = jax.linear_transpose(lambda v: v[perm], v)(g)
+    agree(jax.vjp(lambda v: moe_ops._permute_scalars(v, perm, inverse), v)[1](g)[0], scatter, tol=0)
+    agree(scatter, np.asarray(g)[inverse], tol=0)
+
+
+def test_moe_experts_cost_row_counts_the_lowerings_passes_over_its_rows():
+    from paddle_tpu.core import resource_plan
+
+    tokens, d, f, experts, k = 40, 16, 12, 8, 2
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        out = layers.moe(layers.data("x", [d]), experts, f, k)[0]
+    plan = resource_plan.plan_program(main, {"x": (tokens, d)}, [out.name])
+    row = next(r for r in plan.rows if r.op_type == "moe_experts")
+    assert row.cost_covered and row.flops == 3 * 2 * tokens * k * d * f
+    # X and Out, TopKProb and TopKIndex, Load, the three matrices, Dropped: once each
+    once = 2 * tokens * d + 2 * tokens * k + experts + 3 * experts * d * f + 1
+    assert moe_ops._ROW_PASSES == {"hidden": 7, "width": 6}
+    assert row.traffic_bytes == 4 * (once + tokens * k * (7 * d + 6 * f))
+
+
 @pytest.mark.parametrize("rows,groups", [(256, [256, 0, 0, 0]), (200, [13, 0, 100, 87]), (640, [1, 638, 0, 1])])
 def test_grouped_matmul_golden_forward_and_gradients(rows, groups):
     """`grouped_matmul` (off the chip: the kernel the chip compiles, in
@@ -213,6 +278,32 @@ def test_grouped_matmul_golden_forward_and_gradients(rows, groups):
     for a, b in zip(jax.grad(lambda x, w: (ours(x, w) * r).sum(), (0, 1))(x, w),
                     jax.grad(lambda x, w: (golden(x, w) * r).sum(), (0, 1))(x, w)):
         agree(a, b, tol=1e-5)
+
+
+def test_grouped_matmul_gradient_for_a_float32_master_is_the_float32_accumulator():
+    """bf16 rows over a float32 master: the product runs in bf16, and the
+    master's gradient is `tgmm`'s float32 sum of exact bf16 x bf16 products,
+    so it agrees with a float32 per-group reference to float32 rounding.  At
+    the parent of PR 28 it was rounded to bf16 on its way (and widened again)."""
+    k, n, groups = 128, 256, [70, 0, 300, 142]
+    rows = sum(groups)
+    x = jnp.asarray(RNG.randn(rows, k), BF16)
+    master = jnp.asarray(RNG.randn(len(groups), k, n) * 0.1, jnp.float32)
+    sizes = jnp.asarray(groups, jnp.int32)
+    r = jnp.asarray(RNG.randn(rows, n), BF16)
+    out, vjp = jax.vjp(lambda x, w: moe_ops.grouped_matmul(x, w, sizes, platform="cpu"), x, master)
+    d_x, d_master = vjp(r)
+    assert out.dtype == d_x.dtype == BF16 and d_master.dtype == jnp.float32
+    ends = np.cumsum(groups)
+    want = jnp.stack([jnp.dot(x[e - g:e].astype(jnp.float32).T, r[e - g:e].astype(jnp.float32),
+                              precision=jax.lax.Precision.HIGHEST) for g, e in zip(groups, ends)])
+    agree(d_master, want, tol=2e-6)
+    assert float(jnp.abs(_round_to_bfloat16(want) - want).max() / jnp.abs(want).max()) > 1e-3
+    # the rows' side multiplies the master as the forward does: rounded to the rows' dtype
+    group_of_row = np.repeat(np.arange(len(groups)), groups)
+    w16 = master.astype(BF16).astype(jnp.float32)
+    agree(out, jnp.einsum("mk,mkn->mn", x.astype(jnp.float32), w16[group_of_row]), tol=4e-3)
+    agree(d_x, jnp.einsum("mn,mkn->mk", r.astype(jnp.float32), w16[group_of_row]), tol=4e-3)
 
 
 # -- (b), (c) the whole model against the benchmark's reference ----------------
@@ -369,7 +460,7 @@ def _grouped_matmul_accumulating_in_bfloat16(rows, weights, sizes, platform=None
     """The running sum of a product held in bf16, eight terms at a time."""
     def add_eight(i, acc):
         part = jax.lax.ragged_dot(jax.lax.dynamic_slice_in_dim(rows, 8 * i, 8, axis=1),
-                                  jax.lax.dynamic_slice_in_dim(weights, 8 * i, 8, axis=1),
+                                  jax.lax.dynamic_slice_in_dim(weights, 8 * i, 8, axis=1).astype(rows.dtype),
                                   sizes, preferred_element_type=jnp.float32)
         return _round_to_bfloat16(acc + part)
 
