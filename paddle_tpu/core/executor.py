@@ -116,18 +116,14 @@ def _runnable_ops(block):
 
 
 def _lowering_flags():
-    """Process-global lowering options that change generated code; they must
-    participate in the compile-cache key or toggling them would silently
-    reuse stale executables."""
+    """The process-global choice that changes generated code, for the
+    compile-cache key: toggling it must not reuse a stale executable.  One is
+    left, `FLAGS_use_pallas`: 61 tier-1 cases stand on its five kernels, so
+    it goes with a PR that brings their replacements (ROADMAP D2).  The ops'
+    lowerings read nothing else a process can set."""
     from ..flags import flag as _flagv
-    from ..ops import nn_ops
 
-    return ("nhwc", nn_ops._NHWC_LOWERING, "bn1p", nn_ops._BN_SINGLE_PASS,
-            "bnbf16", nn_ops._BN_BF16_COMPUTE,
-            "bnfused", nn_ops._BN_STATS_FUSED_PASS,
-            "bnfdef", nn_ops._BN_BF16_FUSED_DEFAULT,
-            "bnbar", nn_ops._BN_UNFUSE_CONV,
-            "pallas", bool(_flagv("FLAGS_use_pallas")))
+    return ("pallas", bool(_flagv("FLAGS_use_pallas")))
 
 
 def _structure_digest(program: Program, *more) -> str:

@@ -4,11 +4,15 @@ Reference kernels: conv_cudnn_op.cu.cc / conv_op.cc, pool_op.cc,
 batch_norm_op.cc, layer_norm_op.cc, dropout_op.cc, cross_entropy_op.cc,
 softmax_with_cross_entropy_op.cc, lookup_table_op.cc, top_k_op.cc.
 
-Conv/pool/batch_norm have three layout paths: NCHW (the public fluid
-default — XLA relayouts internally), whole-model channels-last via the
-`data_format`/`data_layout` attr (zero transposes in the program), and the
-legacy `_NHWC_LOWERING` transpose-at-op-edges toggle (measured regression;
-kept only for experiments).
+One lowering per op.  What a lowering branches on is what the op can
+observe: the platform, the dtype, the shapes and the program's own attributes
+(conv/pool/batch_norm read the layout from `data_format`/`data_layout`: NCHW,
+the public fluid default, which XLA relayouts internally, or whole-model
+channels-last with no transpose in the program).  Nothing here is a
+process-wide setting (PERF.md, PR 29, has the measurements that settled
+it).  `fused_attention` still reads two attributes that select code,
+`use_pallas_sdpa` and `score_dtype`: both beat the default on the v5e in
+PR 29 and wait for the perf_opt that makes the winner the one path.
 """
 from __future__ import annotations
 
@@ -18,56 +22,6 @@ import numpy as np
 
 from ..core.registry import register_op
 from .common import canon_dtype, first, match_dtype
-
-# When True, conv/pool/batch_norm lower with an internal NHWC layout
-# (transpose at op edges): the public program stays NCHW (fluid layout)
-# but on TPU the MXU-native layout is channels-last, and XLA folds the
-# back-to-back transposes between consecutive layers so the whole conv
-# stack runs NHWC with one transpose at each end of the network.
-_NHWC_LOWERING = False
-
-# Single-sweep BN batch stats (pilot-mean shifted E[(x-c)^2]): measured
-# SLOWER than two-pass jnp.var on v5e (62.1 vs 55.6 ms ResNet-50 step in an
-# interleaved A/B — the pilot gather breaks XLA's conv+reduce fusion), so
-# the default stays two-pass; the path is kept for other backends/shapes.
-_BN_SINGLE_PASS = False
-
-# BN compute for bf16 activations: True keeps elementwise math in bf16 with
-# f32 reduction accumulators (TPU-kernel style); False casts the activation
-# to f32 first.  Interleaved A/B on the chip: 55.1 vs 55.6 ms ResNet-50
-# step — consistently ~1% faster, standard numerics (r3 chip round).
-_BN_BF16_COMPUTE = True
-
-# r5 chip round: the ResNet-50 profile showed XLA fusing the BN
-# batch-stat reductions INTO the producing convolutions ("multiply_reduce_
-# fusion" convolution-fusion events at 9-43 TF/s vs 90-190 TF/s for clean
-# convs) — the reduce epilogue wrecks the conv's MXU tiling.  With
-# _BN_UNFUSE_CONV the training-mode lowering puts an optimization_barrier on
-# the activation so the conv materializes at full speed and the stats run as
-# a separate roofline-bandwidth reduce fusion (the barrier transposes to the
-# cotangent, unfusing the backward reductions from the dgrad convs too).
-_BN_UNFUSE_CONV = False
-
-# Single fused-pass stats: E[x]/E[x^2] as sibling reductions over the same
-# read of x (one HBM pass) instead of mean-then-centered two passes.  Unlike
-# the retired _BN_SINGLE_PASS pilot-mean variant there is no gather, so the
-# two sums fuse horizontally.  Cancellation in var = E[x^2]-mean^2 loses
-# ~2*log2(|mean|/std) mantissa bits of the f32 accumulator — fine for conv
-# activations (|mean|/std = O(1)), and for bf16 activations the input's own
-# 8-bit mantissa dominates any accumulator cancellation, so the bf16 path
-# takes the fused pass by default (interleaved A/B on the v5e: ResNet-50
-# step 103.9 vs 115.4 ms, a 10% step win — r5 chip round).  f32 stays
-# two-pass unless _BN_STATS_FUSED_PASS is toggled on (keeps OpTest goldens
-# vs the reference exact); _BN_BF16_FUSED_DEFAULT=False restores the r4
-# two-pass bf16 lowering (A/B baseline).  fp16 never takes the fused pass
-# implicitly — squaring in fp16 overflows at |x|>=256.
-_BN_STATS_FUSED_PASS = False
-_BN_BF16_FUSED_DEFAULT = True
-
-
-def enable_nhwc_lowering(on: bool = True):
-    global _NHWC_LOWERING
-    _NHWC_LOWERING = on
 
 
 @register_op("conv2d")
@@ -84,39 +38,17 @@ def _conv2d(ctx, op, ins):
         padding = [(pads[0], pads[1]), (pads[2], pads[3])]
     else:
         padding = [(pads[0], pads[0]), (pads[1], pads[1])]
-    if op.attr("data_format", "NCHW") == "NHWC":
-        # whole-model channels-last path: activations are NHWC end to end
-        # (zero transposes in the program); the filter stays OIHW so params
-        # are layout-independent — XLA's layout assignment picks the MXU
-        # layout for the filter itself.
-        out = jax.lax.conv_general_dilated(
-            x,
-            w,
-            window_strides=strides,
-            padding=padding,
-            rhs_dilation=dilations,
-            dimension_numbers=("NHWC", "OIHW", "NHWC"),
-            feature_group_count=groups,
-        )
-        return {"Output": out}
-    if _NHWC_LOWERING:
-        out = jax.lax.conv_general_dilated(
-            jnp.transpose(x, (0, 2, 3, 1)),
-            jnp.transpose(w, (2, 3, 1, 0)),  # OIHW -> HWIO
-            window_strides=strides,
-            padding=padding,
-            rhs_dilation=dilations,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=groups,
-        )
-        return {"Output": jnp.transpose(out, (0, 3, 1, 2))}
+    # NCHW, or NHWC end to end with no transpose in the program; the filter
+    # stays OIHW either way, so parameters are layout-independent and XLA's
+    # layout assignment picks the MXU's layout for it.
+    layout = "NHWC" if op.attr("data_format", "NCHW") == "NHWC" else "NCHW"
     out = jax.lax.conv_general_dilated(
         x,
         w,
         window_strides=strides,
         padding=padding,
         rhs_dilation=dilations,
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        dimension_numbers=(layout, "OIHW", layout),
         feature_group_count=groups,
     )
     return {"Output": out}
@@ -178,33 +110,29 @@ def _pool2d(ctx, op, ins):
     ksize = list(op.attr("ksize", [2, 2]))
     strides = list(op.attr("strides", [1, 1]))
     pads = list(op.attr("paddings", [0, 0]))
-    channels_last = op.attr("data_format", "NCHW") == "NHWC"
+    h = 1 if op.attr("data_format", "NCHW") == "NHWC" else 2  # the first spatial axis
+
+    def over_hw(pair, other):
+        """A 4-tuple with `pair` on the two spatial axes and `other` elsewhere."""
+        out = [other] * 4
+        out[h:h + 2] = pair
+        return tuple(out)
+
     if op.attr("global_pooling", False):
-        ksize = [x.shape[1], x.shape[2]] if channels_last else [x.shape[2], x.shape[3]]
+        ksize = [x.shape[h], x.shape[h + 1]]
         strides = [1, 1]
         pads = [0, 0]
-    nhwc = _NHWC_LOWERING and not channels_last
-    if nhwc:
-        x = jnp.transpose(x, (0, 2, 3, 1))
-    if nhwc or channels_last:
-        window = (1, ksize[0], ksize[1], 1)
-        strides4 = (1, strides[0], strides[1], 1)
-    else:
-        window = (1, 1, ksize[0], ksize[1])
-        strides4 = (1, 1, strides[0], strides[1])
+    window = over_hw(ksize, 1)
+    strides4 = over_hw(strides, 1)
     pad_hi = [pads[0], pads[1]]
     if op.attr("ceil_mode", False):
         # extra high-side padding so the window count rounds up
         for d in (0, 1):
-            in_sz = x.shape[1 + d] if (nhwc or channels_last) else x.shape[2 + d]
+            in_sz = x.shape[h + d]
             out_floor = (in_sz + 2 * pads[d] - ksize[d]) // strides[d] + 1
             out_ceil = -(-(in_sz + 2 * pads[d] - ksize[d]) // strides[d]) + 1
             pad_hi[d] += (out_ceil - out_floor) * strides[d]
-    spatial_pad = ((pads[0], pad_hi[0]), (pads[1], pad_hi[1]))
-    if nhwc or channels_last:
-        padding = ((0, 0),) + spatial_pad + ((0, 0),)
-    else:
-        padding = ((0, 0), (0, 0)) + spatial_pad
+    padding = over_hw([(pads[0], pad_hi[0]), (pads[1], pad_hi[1])], (0, 0))
     # exclusive avg pool must divide by the valid-element count whenever any
     # effective padding exists (explicit pads OR ceil-mode high padding)
     any_pad = bool(pads[0] or pads[1] or pad_hi[0] or pad_hi[1])
@@ -219,99 +147,55 @@ def _pool2d(ctx, op, ins):
             out = summed / counts
         else:
             out = summed / float(ksize[0] * ksize[1])
-    if nhwc:
-        out = jnp.transpose(out, (0, 3, 1, 2))
     return {"Out": out}
 
 
 @register_op("batch_norm")
 def _batch_norm(ctx, op, ins):
     x = first(ins, "X")
-    # normalize in fp32 regardless of activation dtype (bf16 batch stats
-    # lose too much precision); output returns to the activation dtype.
-    # _BN_BF16_COMPUTE instead keeps elementwise math in bf16 and promotes
-    # only the reduction accumulators.
-    orig_dtype = x.dtype
-    bf16_fast = _BN_BF16_COMPUTE and x.dtype in (jnp.bfloat16, jnp.float16)
-    if x.dtype in (jnp.bfloat16, jnp.float16) and not bf16_fast:
-        x = x.astype(jnp.float32)
     scale = first(ins, "Scale")
     bias = first(ins, "Bias")
     mean_in = first(ins, "Mean")
     var_in = first(ins, "Variance")
     eps = op.attr("epsilon", 1e-5)
     momentum = op.attr("momentum", 0.9)
-    is_test = op.attr("is_test", False)
-    layout = op.attr("data_layout", "NCHW")
-    nhwc_internal = _NHWC_LOWERING and layout == "NCHW" and x.ndim == 4
-    if nhwc_internal:
-        x = jnp.transpose(x, (0, 2, 3, 1))
-        ch_axis = x.ndim - 1
-    else:
-        ch_axis = 1 if layout == "NCHW" else x.ndim - 1
+    ch_axis = 1 if op.attr("data_layout", "NCHW") == "NCHW" else x.ndim - 1
     axes = tuple(i for i in range(x.ndim) if i != ch_axis)
     bshape = [1] * x.ndim
     bshape[ch_axis] = x.shape[ch_axis]
-
-    training = not (is_test or op.attr("use_global_stats", False))
-    if training and _BN_UNFUSE_CONV:
-        x = jax.lax.optimization_barrier(x)
-    # fp16 is excluded from the fused pass UNCONDITIONALLY (even under the
-    # explicit toggle): jnp.square runs in x.dtype and fp16 overflows to inf
-    # at |x| >= 256; bf16 shares f32's exponent range.
-    fused_pass = (_BN_STATS_FUSED_PASS or (
-        bf16_fast and x.dtype == jnp.bfloat16 and _BN_BF16_FUSED_DEFAULT)
-    ) and x.dtype != jnp.float16
+    # Half-width activations keep their elementwise math in their own dtype;
+    # the statistics always accumulate in float32, and HOW is chosen by dtype:
+    # bf16 sums x and x^2 over one read of x (its 8-bit mantissa outweighs the
+    # cancellation in E[x^2] - mean^2); fp16 takes the mean, then the centred
+    # variance, never the one-read form (x^2 overflows at |x| >= 256); float32
+    # is jnp.mean / jnp.var, exact against the reference's goldens.
+    half = x.dtype in (jnp.bfloat16, jnp.float16)
+    training = not (op.attr("is_test", False) or op.attr("use_global_stats", False))
     if not training:
         mean, var = mean_in, var_in
-        saved_mean, saved_var = mean_in, var_in
         mean_out, var_out = mean_in, var_in
-    elif fused_pass:
-        inv_n = 1.0 / float(np.prod([x.shape[i] for i in axes]))
-        s1 = jnp.sum(x, axis=axes, dtype=jnp.float32)
-        s2 = jnp.sum(jnp.square(x), axis=axes, dtype=jnp.float32)
-        mean = s1 * inv_n
-        var = jnp.maximum(s2 * inv_n - jnp.square(mean), 0.0)
-        mean_out = None
-    elif _BN_SINGLE_PASS:
-        # Single-sweep stats (one read of the activation instead of
-        # jnp.var's mean-then-centered-pass two; measured ~10% off the
-        # ResNet-50 train step).  Raw E[x^2]-E[x]^2 cancels catastrophically
-        # when |mean|/std is large, so shift by a cheap per-channel pilot
-        # mean c (one spatial position): var = E[(x-c)^2] - E[x-c]^2 is
-        # exact in infinite precision and the cancellation ratio drops to
-        # |mean-c|/std = O(1/sqrt(N)) for any input scale.
-        pilot_idx = tuple(
-            slice(None) if i in (0, ch_axis) else slice(0, 1) for i in range(x.ndim)
-        )
-        c = jnp.mean(x[pilot_idx], axis=tuple(i for i in range(x.ndim) if i != ch_axis))
-        xc = x - c.reshape(bshape)
-        d = jnp.mean(xc, axis=axes)
-        m2 = jnp.mean(jnp.square(xc), axis=axes)
-        mean = c + d
-        var = jnp.maximum(m2 - jnp.square(d), 0.0)
-        mean_out = var_out = saved_mean = saved_var = None  # set below
     else:
-        mean = jnp.mean(x, axis=axes, dtype=jnp.float32)
-        if bf16_fast:
-            # fp16 route (and bf16 when the fused pass is disabled): centered
-            # variance keeps the squared magnitudes small pre-accumulation
+        if x.dtype == jnp.bfloat16:
+            inv_n = 1.0 / float(np.prod([x.shape[i] for i in axes]))
+            s1 = jnp.sum(x, axis=axes, dtype=jnp.float32)
+            s2 = jnp.sum(jnp.square(x), axis=axes, dtype=jnp.float32)
+            mean = s1 * inv_n
+            var = jnp.maximum(s2 * inv_n - jnp.square(mean), 0.0)
+        elif x.dtype == jnp.float16:
+            mean = jnp.mean(x, axis=axes, dtype=jnp.float32)
             centered = x - mean.astype(x.dtype).reshape(bshape)
             var = jnp.mean(jnp.square(centered), axis=axes, dtype=jnp.float32)
         else:
+            mean = jnp.mean(x, axis=axes, dtype=jnp.float32)
             var = jnp.var(x, axis=axes)
-        mean_out = None
-    if training:
-        # shared running-stats update for both training branches
         mean_out = momentum * mean_in + (1.0 - momentum) * mean
         var_out = momentum * var_in + (1.0 - momentum) * var
-        saved_mean, saved_var = mean, var
 
     fuse_relu = op.attr("fuse_relu", False)  # core/passes.py fuse_bn_relu
     from .pallas_kernels import epilogue_shape_ok, use_pallas
 
     inv = jax.lax.rsqrt(var.reshape(bshape) + eps)
-    if (use_pallas(ctx) and ch_axis == 1 and not nhwc_internal
+    if (use_pallas(ctx) and ch_axis == 1
             and x.ndim >= 3 and epilogue_shape_ok(x.shape, x.dtype)):
         # fused epilogue kernel: the normalize/scale/shift(/relu) chain as
         # one roofline-bandwidth pass with per-channel f32 multipliers; the
@@ -324,8 +208,8 @@ def _batch_norm(ctx, op, ins):
         add_c = bias.astype(jnp.float32) - mean.reshape(-1) * mul_c
         y = bn_epilogue(x, mul_c, add_c, relu=fuse_relu)
     else:
-        if bf16_fast:
-            # per-channel multipliers computed in f32, applied in bf16
+        if half:
+            # per-channel multipliers computed in f32, applied in x's dtype
             mul = (inv * scale.astype(jnp.float32).reshape(bshape)).astype(x.dtype)
             add = (bias.astype(jnp.float32).reshape(bshape)
                    - mean.reshape(bshape) * inv * scale.astype(jnp.float32).reshape(bshape)
@@ -335,14 +219,12 @@ def _batch_norm(ctx, op, ins):
             y = (x - mean.reshape(bshape)) * inv * scale.reshape(bshape) + bias.reshape(bshape)
         if fuse_relu:
             y = jnp.maximum(y, 0.0)
-    if nhwc_internal:
-        y = jnp.transpose(y, (0, 3, 1, 2))
     return {
-        "Y": y.astype(orig_dtype),
+        "Y": y.astype(x.dtype),
         "MeanOut": mean_out,
         "VarianceOut": var_out,
-        "SavedMean": saved_mean,
-        "SavedVariance": saved_var,
+        "SavedMean": mean,
+        "SavedVariance": var,
     }
 
 
@@ -665,8 +547,7 @@ def _fused_attention(ctx, op, ins):
     scale = op.attr("scale", None)
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    min_seq = op.attr("flash_min_seq", _FLASH_MIN_SEQ)
-    if ctx.platform == "tpu" and k.shape[2] >= min_seq:
+    if ctx.platform == "tpu" and k.shape[2] >= _FLASH_MIN_SEQ:
         # long-sequence streaming kernel (O(L) memory)
         return {"Out": _flash_attention_tpu(q, k, v, bias, causal, scale)}
     if (ctx.platform == "tpu" and op.attr("use_pallas_sdpa", False)
@@ -677,7 +558,11 @@ def _fused_attention(ctx, op, ins):
         # jnp formulation below (BERT step 305 vs 275 ms; isolated
         # microbench 10.9 vs 7.9 ms/layer-fwd) — at L<=512 XLA's own
         # softmax/matmul fusion wins on this chip, extending r4's negative
-        # result for the stock streaming kernel (r5 chip round).
+        # result for the stock streaming kernel (r5 chip round).  On this
+        # tree and compiler the verdict is the other way round:
+        # bert-base.pretrain-s512 212.60 against 181.36 samples/s with this
+        # branch taken (PERF.md, PR 29), so the attribute stands until the
+        # perf_opt that makes the kernel the path by shape (ROADMAP S5(a)).
         # bias is mask-derived in every caller, hence non-differentiable.
         from .pallas_attention import fused_sdpa
 
@@ -696,6 +581,8 @@ def _fused_attention(ctx, op, ins):
     # [B,H,Lq,Lk] score tensor in bf16 — halves the dominant attention HBM
     # traffic at a documented numerics cost (pre-softmax logits quantized
     # to 8 mantissa bits; softmax max/sum still accumulate in f32).
+    # bert-base.pretrain-s512: 196.66 against 181.36 samples/s (PERF.md,
+    # PR 29); it goes or becomes the path with use_pallas_sdpa above.
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:

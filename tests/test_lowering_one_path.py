@@ -1,0 +1,168 @@
+"""One lowering per op (ISSUE 29): conv2d, pool2d, batch_norm and
+fused_attention choose by what the op can observe (platform, dtype, shapes,
+the program's own attributes) and by nothing a process can set.
+
+(a) the programs the cells and the zoo build lower, for the chip, to the text
+    they lowered to at the parent commit `7602313`, where six module globals
+    and three attention attributes still stood at their defaults: the deletion
+    changed no generated program (lowered, never compiled: nothing runs);
+(b) batch_norm's one choice, its statistics by dtype, against numpy;
+(c) the compile-cache key holds no global of an ops module.
+"""
+import hashlib
+import inspect
+import json
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import paddle_tpu as fluid  # noqa: E402
+from paddle_tpu import layers  # noqa: E402
+from paddle_tpu.core import executor as ex  # noqa: E402
+from paddle_tpu.models import resnet, transformer  # noqa: E402
+
+
+def _resnet50(batch, for_test=False, **kw):
+    """ResNet-50 at the benchmark's widths (benchmark/models/resnet.py: _build)."""
+    main, startup, _, fetches = resnet.build(
+        depth=50, class_dim=1000, learning_rate=0.1, momentum=0.9, with_optimizer=True, **kw)
+    shape = (224, 224, 3) if kw.get("data_format") == "NHWC" else (3, 224, 224)
+    feeds = {"img": jax.ShapeDtypeStruct((batch,) + shape, np.float32)}
+    if for_test:  # the clone the benchmark's reference check runs
+        return main.clone(for_test=True), startup, feeds, fetches["logits"].name
+    feeds["label"] = jax.ShapeDtypeStruct((batch, 1), np.int32)
+    return main, startup, feeds, fetches["loss"].name
+
+
+def _bert_s512():
+    """`bert-base.pretrain-s512`'s program (benchmark/models/bert.py: build)."""
+    main, startup, _, fetches = transformer.build_bert(
+        vocab_size=30522, seq_len=512, d_model=768, n_layers=12, n_heads=12, d_ff=3072,
+        dropout_prob=0.1, learning_rate=1e-4, with_optimizer=True, dtype="bfloat16",
+        use_fused_attention=True)
+    feeds = {n: jax.ShapeDtypeStruct((32, 512), np.int32) for n in ("ids", "labels", "pos_ids")}
+    return main, startup, feeds, fetches["loss"].name
+
+
+def _fp16_batch_norm():
+    """The one dtype no cell runs: a convolution and a training batch norm in float16."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", [8, 16, 16], dtype="float32")
+        h = layers.conv2d(layers.cast(x, "float16"), 16, 3, padding=1, bias_attr=False)
+        y = layers.pool2d(layers.batch_norm(h, act="relu"), pool_size=2, pool_stride=2)
+        loss = layers.mean(layers.cast(y, "float32"))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    return main, startup, {"x": jax.ShapeDtypeStruct((4, 8, 16, 16), np.float32)}, loss.name
+
+
+#: case -> (builder, module name, sha256 of the op listing, sha256 of the
+#: lowered StableHLO), recorded at the parent commit `7602313` by this test
+#: (it prints what it finds).  A PR that means to change a lowering re-records
+#: the case and says so in CHANGES.md.
+PARENTS_PROGRAMS = {
+    "resnet50-train-bf16-nchw": (lambda: _resnet50(256, dtype="bfloat16"), "train_cc732a46",
+        "b40b8617a8e43d947e7dcdd6c6b5ea2136abe6018b84b409dacbf82eadaa1b35",
+        "e437a7597c251c9f4488b4bd25aa0782721bda89de6bbeb1823bd9a16933274c"),
+    "resnet50-for_test-bf16-nchw": (lambda: _resnet50(8, for_test=True, dtype="bfloat16"), "infer_87838cf4",
+        "851aa4de6eed0242f77d2494362eb97c788109f448d2d11d66f5d487cd3c2762",
+        "87d011020c6b861070f55ffb8b357c61d33dc3628d9666aebadc404c686f38e6"),
+    "resnet50-train-f32-nchw": (lambda: _resnet50(64, dtype="float32"), "train_e29c5d91",
+        "189f0d192c4800bf280ba303e8b8e09013d5c26a596e1cd944287c15d9807a8b",
+        "697ddeb2e7bf3498a9bf54f8be1c456a19dec700b811e7967298709305a8a14f"),
+    "resnet50-train-bf16-nhwc": (lambda: _resnet50(256, dtype="bfloat16", data_format="NHWC"), "train_cc732a46",
+        "03f8097c8e4f2816fa57d4b2b45496da8d8a7e2a23de6657256a26d17ddcc3e2",
+        "73cfc133487bbb2c7e57a9846ed7f785ccd85c0c7514ed0bd6519ab192285f13"),
+    "bert-base-s512-fused": (_bert_s512, "train_e9476d18",
+        "4790802a450bc2534b4f64089c4c9b044c37d7e4de8c30169d41d35c22fb1bc5",
+        "712cc4f84b990442f3d85695e4406644b5d1b9ca2ce4b4fed6f1ea27385ad848"),
+    "batch_norm-train-fp16": (_fp16_batch_norm, "train_97080cb5",
+        "97158b65993029a94935d7280f793136a5fe07aa0458a7b0de8d003fb41fbd33",
+        "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984"),
+}
+
+
+@pytest.mark.parametrize("case", list(PARENTS_PROGRAMS))
+def test_the_step_lowers_to_the_parents_program(case):
+    build, module, ops_sha, text_sha = PARENTS_PROGRAMS[case]
+    with fluid.unique_name.guard():  # parameter names come from process-wide counters
+        main, startup, feeds, fetch = build()
+    main.random_seed = startup.random_seed = 3
+    listing = json.dumps([[op.type, op.inputs, op.outputs,
+                           {k: repr(v) for k, v in sorted(op.attrs.items())}]
+                          for op in main.global_block().ops], sort_keys=True)
+    # the state the start-up program would make, as shapes: nothing runs
+    scope = fluid.Scope()
+    for v in startup.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    step = ex._CompiledStep(main, list(feeds), [fetch], scope, platform="tpu",
+                            feed_shapes={n: s.shape for n, s in feeds.items()})
+    as_shape = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)  # noqa: E731
+    text = step.jfn.trace({n: as_shape(scope.find_var(n)) for n in step.rw_names},
+                          {n: as_shape(scope.find_var(n)) for n in step.ro_names},
+                          feeds, as_shape(jax.random.PRNGKey(0))).lower().as_text()
+    found = (step.module, hashlib.sha256(listing.encode()).hexdigest(),
+             hashlib.sha256(text.encode()).hexdigest())
+    print(f'    "{case}": {found},')
+    assert found == (module, ops_sha, text_sha)
+
+
+#: dtype -> (offset of the input, tolerance on the saved statistics, on the
+#: normalised output).  float32: two-pass numpy, exact.  bfloat16: the one
+#: read of x, within tests/test_ops_round5.py's tolerance.  float16 at
+#: |x| = 300: x * x is 90000, past fp16's 65504, so the one-read form would
+#: give inf; the centred form holds (fp16 is 0.25 apart at 300: the input's
+#: own rounding adds 0.25**2 / 12 to a variance of 1, and the output,
+#: x * mul + add in fp16, is as coarse as x: half a step either way).
+STATISTICS = {"float32": (0.0, 1e-4, 1e-4), "bfloat16": (0.0, 5e-2, 5e-2),
+              "float16": (300.0, 5e-2, 0.26)}
+
+
+@pytest.mark.parametrize("dtype", list(STATISTICS))
+def test_batch_norm_chooses_its_statistics_by_dtype(dtype):
+    offset, tol, out_tol = STATISTICS[dtype]
+    x = (np.random.RandomState(29).randn(8, 4, 6, 6) + offset).astype("float32")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        xv = layers.data("x", [4, 6, 6], dtype="float32")
+        y = layers.cast(layers.batch_norm(layers.cast(xv, dtype), is_test=False), "float32")
+    bn = next(op for op in main.global_block().ops if op.type == "batch_norm")
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    out, mean, var = (np.asarray(a, np.float64) for a in exe.run(
+        main, feed={"x": x}, scope=scope,
+        fetch_list=[y.name, bn.outputs["SavedMean"][0], bn.outputs["SavedVariance"][0]]))
+    assert np.isfinite(out).all() and np.isfinite(var).all()
+    # what the op was given: x as its dtype holds it
+    seen = np.asarray(jax.numpy.asarray(x).astype(dtype), np.float64)
+    m = seen.mean(axis=(0, 2, 3), keepdims=True)
+    v = seen.var(axis=(0, 2, 3), keepdims=True)
+    assert np.allclose(mean, m.ravel(), rtol=tol, atol=tol), np.abs(mean - m.ravel()).max()
+    assert np.allclose(var, v.ravel(), rtol=tol, atol=tol), np.abs(var - v.ravel()).max()
+    assert np.allclose(out, (seen - m) / np.sqrt(v + 1e-5), rtol=out_tol, atol=out_tol)
+
+
+def test_the_compile_cache_key_names_no_ops_module_global():
+    from paddle_tpu.ops import nn_ops
+
+    assert ex._lowering_flags() == ("pallas", False)
+    fluid.set_flags({"FLAGS_use_pallas": True})
+    try:
+        assert ex._lowering_flags() == ("pallas", True)  # a toggle is a new key, never a stale step
+    finally:
+        fluid.set_flags({"FLAGS_use_pallas": False})
+    assert "nn_ops" not in inspect.getsource(ex)
+    # and the lowerings have nothing of the kind to name: `_FLASH_MIN_SEQ` is a
+    # threshold on a shape, with cells on both sides of it
+    switches = [n for n, v in vars(nn_ops).items() if isinstance(v, (bool, int, float, str))
+                and n.startswith("_") and n.isupper()]
+    assert switches == ["_FLASH_MIN_SEQ"]
+    assert not [n for n in vars(nn_ops) if n.startswith(("enable_", "set_"))]  # nor a setter for one

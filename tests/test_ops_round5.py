@@ -65,9 +65,7 @@ def test_bn_bf16_fused_pass_shifted_mean():
 
 
 def test_bn_f32_stays_two_pass_exact():
-    # f32 default path is unchanged: exact vs the two-pass numpy reference
-    from paddle_tpu.ops import nn_ops
-    assert nn_ops._BN_STATS_FUSED_PASS is False
+    # float32 takes jnp.mean / jnp.var: exact vs the two-pass numpy reference
     rng = np.random.RandomState(2)
     x = rng.randn(4, 3, 5, 5).astype("float32")
     main, startup = Program(), Program()

@@ -1,13 +1,8 @@
-"""Shared bench/experiment dispatch builders (reference role:
-benchmark/fluid/fluid_benchmark.py model setup helpers).
+"""bench.py's dispatch builders (reference role:
+benchmark/fluid/fluid_benchmark.py model setup helpers): the single copy of
+"build model -> Executor -> device-resident feeds -> steps=K scan closure".
 
-bench.py and the experiments/*_ab_*.py scripts all need the same
-"build model -> Executor -> device-resident feeds -> steps=K scan closure"
-block; this is the single copy, so a protocol change (feed dtype, K, stem)
-cannot silently diverge between the bench and the A/Bs that justify it.
-
-Import as `from tools.bench_kit import ...` from the repo root, or with
-sys.path bootstrap from experiments/.
+Import as `from tools.bench_kit import ...` from the repo root.
 """
 from __future__ import annotations
 

@@ -3,8 +3,7 @@ paddle/fluid/operators/benchmark/op_tester.cc:1 — a standalone per-op timing
 tool fed by config files).
 
 The TPU rebuild's version packages the interleaved-A/B methodology from
-the r3 chip round into a reusable library + CLI instead of ad-hoc
-experiments/ scripts:
+the r3 chip round into a reusable library + CLI:
 
   * variants are timed round-robin (A,B,A,B,...) so throughput drift hits
     every variant equally — single measurements showed +/-20% run-to-run
@@ -12,7 +11,7 @@ experiments/ scripts:
   * each round times a window of `iters` dispatches ended by one device
     sync; per-variant stats report best / median / spread over rounds.
 
-Library use (what experiments/*_ab_*.py scripts should call):
+Library use:
 
     from tools.opbench import interleave
     stats = interleave({"conv7": dispatch_a, "s2d": dispatch_b}, rounds=5)
