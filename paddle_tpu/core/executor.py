@@ -657,9 +657,15 @@ class _CompiledStep:
             t0 = time.perf_counter()
             fenced = _MON.counter("lowering.fenced_grads")
             fenced0 = fenced.value
+            counted0 = _MON.counter_values()
             with _MON.span("executor.lower", **what) as lowering:
                 lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
                 lowering.annotate(fenced=fenced.value - fenced0)
+                # which attention each fused_attention op of this program took
+                lowering.annotate(**{
+                    name[len("lowering."):]: n - counted0.get(name, 0)
+                    for name, n in _MON.counter_values().items()
+                    if name.startswith("lowering.attention_") and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)
                     lowering.annotate(moe_layers=self.moe_layers)

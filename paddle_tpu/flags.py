@@ -200,8 +200,8 @@ DEFINE_bool("FLAGS_use_pallas", False,
             "route hot-kernel lowerings to the hand-fused Pallas TPU "
             "kernels (ops/pallas_kernels.py: LayerNorm+residual, BN "
             "scale/shift/relu epilogue, row-slab Adam, hard-label "
-            "softmax-cross-entropy, bias+relu/gelu epilogue; ops/"
-            "pallas_attention.py SDPA keeps its own use_pallas_sdpa attr). "
+            "softmax-cross-entropy, bias+relu/gelu epilogue; "
+            "fused_attention's kernels are chosen by shape, not by this). "
             "OPT-IN: off (default) or a non-TPU backend keeps the XLA "
             "composite for every kernel.  Participates in the executor "
             "compile-cache key, so toggling recompiles instead of reusing "

@@ -715,17 +715,14 @@ def space_to_depth(x, blocksize, name=None):
     return out
 
 
-def fused_attention(q, k, v, bias=None, causal=False, scale=None,
-                    score_dtype=None, name=None):
-    """Fused scaled-dot-product attention over (B, H, L, dh) tensors.
-
-    Long sequences lower to the streaming flash kernel (score matrix never
-    materialized in HBM, fwd + bwd); moderate lengths use the mixed-
-    precision XLA formulation.  `bias` is an additive pre-softmax mask,
-    (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
-    `score_dtype="bfloat16"` materializes the score tensor in bf16 (half
-    the attention HBM traffic; pre-softmax logits quantized to 8 mantissa
-    bits — softmax reductions stay f32)."""
+def fused_attention(q, k, v, bias=None, causal=False, scale=None, name=None):
+    """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
+    float32 scores and softmax whatever the operands' dtype.  On the TPU the
+    lowering takes its tiling from the shape (ops/nn_ops.py): the streaming
+    flash kernel from 2048 keys, a whole-row kernel for bf16 sequences of 384
+    to 512 (the scores never reach HBM in either, forward or backward),
+    XLA's attention otherwise.  `bias` is an additive pre-softmax mask,
+    (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh)."""
     helper = LayerHelper("fused_attention", name=name)
     out = _out(helper, q.dtype, shape=q.shape)
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
@@ -734,14 +731,6 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None,
     attrs = {"causal": causal}
     if scale is not None:
         attrs["scale"] = float(scale)
-    if score_dtype is not None:
-        sd = {"bf16": "bfloat16", "bfloat16": "bfloat16",
-              "float32": "float32", "fp32": "float32"}.get(str(score_dtype))
-        if sd is None:
-            raise ValueError(
-                f"fused_attention: score_dtype must be 'float32' or "
-                f"'bfloat16', got {score_dtype!r}")
-        attrs["score_dtype"] = sd
     helper.append_op("fused_attention", inputs=inputs, outputs={"Out": [out.name]}, attrs=attrs)
     return out
 

@@ -41,7 +41,7 @@ def _attr_ones(name):
 
 def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1, is_test=False,
                          use_ring_attention=False, causal=False, kv=None, bias=None,
-                         use_fused_attention=False, score_dtype=None, proj_bias=True,
+                         use_fused_attention=False, proj_bias=True,
                          qk_norm_eps=None, positions=None, rope_theta=10000.0):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
@@ -78,8 +78,7 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
         # goes on the attention output (same substitution as the ring path).
-        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal,
-                                     score_dtype=score_dtype)
+        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal)
         if dropout_prob and not is_test:
             ctx = layers.dropout(ctx, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -107,7 +106,7 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
 
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
                   use_ring_attention=False, causal=False, use_fused_attention=False,
-                  score_dtype=None, norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
+                  norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None):
     """One transformer layer: attention and a feed-forward part, each with a
     residual connection and a norm.
@@ -146,7 +145,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                     seq_len, d_model, n_heads, f"{prefix}.attn",
                                     dropout_prob, is_test, use_ring_attention, causal,
                                     use_fused_attention=use_fused_attention,
-                                    score_dtype=score_dtype, proj_bias=proj_bias,
+                                    proj_bias=proj_bias,
                                     qk_norm_eps=norm_eps if qk_norm else None,
                                     positions=positions, rope_theta=rope_theta)
     x = layers.elementwise_add(x, attn_out)
@@ -175,7 +174,6 @@ def build_bert(
     causal=False,
     use_fused_attention=False,
     dtype="float32",
-    attention_score_dtype=None,
 ):
     """BERT-base-style masked-LM pretraining program.
 
@@ -199,8 +197,7 @@ def build_bert(
         for i in range(n_layers):
             x = encoder_layer(x, seq_len, d_model, n_heads, d_ff, f"bert.l{i}",
                               dropout_prob, is_test, use_ring_attention, causal,
-                              use_fused_attention=use_fused_attention,
-                              score_dtype=attention_score_dtype)
+                              use_fused_attention=use_fused_attention)
         logits = layers.fc(x, vocab_size, num_flatten_dims=2,
                            param_attr=_attr("bert.lm_head.w"), bias_attr=_attr("bert.lm_head.b"))
         # bf16 logits feed the CE directly: softmax_with_cross_entropy does

@@ -10,9 +10,9 @@ observe: the platform, the dtype, the shapes and the program's own attributes
 the public fluid default, which XLA relayouts internally, or whole-model
 channels-last with no transpose in the program).  Nothing here is a
 process-wide setting (PERF.md, PR 29, has the measurements that settled
-it).  `fused_attention` still reads two attributes that select code,
-`use_pallas_sdpa` and `score_dtype`: both beat the default on the v5e in
-PR 29 and wait for the perf_opt that makes the winner the one path.
+it), and since PR 30 no attribute selects code either: `fused_attention`
+takes one of its three attentions from the platform, the two lengths, the
+head width, the dtype and the context's mesh (`_attention_path`).
 """
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.registry import register_op
+from ..monitor import MONITOR as _MON
 from .common import canon_dtype, first, match_dtype
 
 
@@ -483,14 +484,61 @@ def _ring_attention(ctx, op, ins):
     return {"Out": out}
 
 
-# fused_attention: shortest kv length that routes to the Pallas flash
-# kernel on TPU.  Interleaved full-model A/Bs (r4 chip round) measured
-# the Pallas kernel SLOWER than XLA's own fused attention at both seq 128
-# (398 vs 293 ms BERT step) and seq 512 (311 vs 242 ms) on v5e, so the
-# kernel is kept as a MEMORY guard only: beyond this length the [B,H,L,L]
-# score tensor (>=128 MB/layer at 2048) starts evicting activations, and
-# flash's O(L) memory wins regardless of kernel-vs-XLA throughput.
+# fused_attention on the TPU: three attentions, chosen by `_attention_path`
+# from what the op can observe.  Each threshold is a length, with the runs
+# that set it (TPU v5e, BERT-base's own program through benchmark.run,
+# samples/s; PERF.md, PRs 26, 29 and 30).
+#
+# From this many keys on, the stock Pallas flash kernel (online softmax, O(L)
+# memory): the [B,H,L,L] float32 scores are 128 MB a layer at 2048 and XLA's
+# attention holds them in HBM, forward and backward (OLMoE, 4 x 4096 keys:
+# 14.4 ms a layer against 58.8 ms and 8.6 GB, PR 26).
 _FLASH_MIN_SEQ = 2048
+# ... for at least this many queries: the kernel's smallest query block, and
+# its own check refuses fewer (until PR 30 a decoding step's one query
+# against 2048 keys raised there; its [B,H,1,L] scores are XLA's to keep).
+_FLASH_MIN_QUERIES = 128
+# Up to this many queries AND keys, the whole-row kernel of
+# ops/pallas_attention.py: a (query, key) score block of a whole sequence
+# fits VMEM, so the scores never reach HBM and backward recomputes them.
+# 32 x 512: 212.547, 212.544, 212.543 against 181.362, 181.361, 181.363 for
+# XLA's attention (+17.2%, PR 30; the stock flash kernel there 174.87).  At
+# 1024 the kernel's working set is 8.4 MB a pair against its 8 MB budget,
+# and no run prices the lengths in (512, 2048): they keep XLA's attention.
+_ROW_KERNEL_MAX_SEQ = 512
+# ... and from this many on: the crossing lies between 256 and 384.  At ~16k
+# tokens a step, XLA's attention | the kernel, two runs a side (PR 30):
+# 48 x 384: 288.646, 288.644 | 324.393, 324.394 (+12.4%);
+# 64 x 256: 524.733 (and 494.012 with one step of 1.4 s) | 487.019, 487.022
+# (-7.2%); 256 x 128: 1132.87 | 1003.21 (-11.45%, PR 29).  Alone the kernel
+# is the faster from 256 on (forward + backward of a layer, 1.28 against
+# 2.17 ms); in the program it costs the transposes and the dropout at its
+# edges, which XLA fuses into its own attention and a custom call cannot
+# take in, and below 384 the scores it keeps out of HBM are worth less.
+_ROW_KERNEL_MIN_SEQ = 384
+# The kernel is compiled for the v5e (tests/test_chip_compile.py) and was run
+# on it at bf16 and this head width only; lengths are whole lane tiles.
+_ROW_KERNEL_HEAD_DIM = 64
+_ROW_KERNEL_SEQ_MULTIPLE = 128
+
+
+def _attention_path(platform, mesh, q, k):
+    """Which attention `fused_attention` lowers to: "flash", "row_kernel" or
+    "xla".  Off the TPU always "xla".  A short query against long keys (a
+    decoding step) has no score block worth keeping out of HBM, hence BOTH
+    lengths in the row kernel's rule."""
+    if platform != "tpu":
+        return "xla"
+    q_len, kv_len = q.shape[2], k.shape[2]
+    if kv_len >= _FLASH_MIN_SEQ and q_len >= _FLASH_MIN_QUERIES:
+        return "flash"
+    if mesh is not None and mesh.size > 1:
+        return "xla"
+    if (all(_ROW_KERNEL_MIN_SEQ <= n <= _ROW_KERNEL_MAX_SEQ and n % _ROW_KERNEL_SEQ_MULTIPLE == 0
+            for n in (q_len, kv_len))
+            and q.shape[-1] == _ROW_KERNEL_HEAD_DIM and q.dtype == k.dtype == jnp.bfloat16):
+        return "row_kernel"
+    return "xla"
 
 
 def _flash_block_sizes(block_sizes_cls, q_len, kv_len, biased):
@@ -529,16 +577,28 @@ def _flash_attention_tpu(q, k, v, bias, causal, scale):
 
 @register_op("fused_attention")
 def _fused_attention(ctx, op, ins):
-    """Flash-style fused scaled-dot-product attention over (B, H, L, dh).
+    """Scaled-dot-product attention over (B, H, L, dh): softmax(q k^T * scale
+    + bias, causal mask) v, with the operands in their own dtype on the MXU,
+    float32 accumulation, float32 scores and softmax, and the probabilities
+    rounded to the activations' dtype for the product with v.  One
+    mathematics, three tilings, chosen by `_attention_path` and counted in
+    `lowering.attention_flash|row_kernel|xla`:
 
-    TPU-first replacement for the reference's unfused matmul/softmax/matmul
-    attention (and its fused_attention ambitions in operators/fused/): on a
-    real TPU this lowers to the Pallas flash-attention kernel — the
-    [B, H, Lq, Lk] score tensor never touches HBM, forward or backward
-    (custom VJP built into the kernel).  On CPU (tests, virtual meshes) it
-    falls back to mathematically-identical jnp attention with f32
-    softmax/accumulation, which is also what the Pallas kernel computes
-    internally, so goldens transfer across backends."""
+    * `flash`: the stock Pallas online-softmax kernel, from `_FLASH_MIN_SEQ`
+      keys on;
+    * `row_kernel`: `ops/pallas_attention.py:fused_sdpa`, queries and keys
+      both in [`_ROW_KERNEL_MIN_SEQ`, `_ROW_KERNEL_MAX_SEQ`]: a whole row of
+      scores lives in VMEM, forward and backward;
+    * `xla`: two einsums round `jax.nn.softmax`, the scores in HBM:
+      everything else on the TPU, and every other platform (CPU tests and
+      virtual meshes compute the same function, so goldens transfer).
+
+    Under a mesh of more than one device the row kernel is NOT taken: a
+    `pallas_call` is a custom call that GSPMD cannot partition, and the XLA
+    path, which it can, is correct there at no new code (no cell runs 384 to
+    512 keys on a mesh; a `shard_map` over the batch axis is the other way,
+    when one does).  The bias derives from lengths and causality in every
+    caller, so the row kernel treats it as a constant."""
     q = first(ins, "Q")
     k = first(ins, "K")
     v = first(ins, "V")
@@ -547,42 +607,16 @@ def _fused_attention(ctx, op, ins):
     scale = op.attr("scale", None)
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    if ctx.platform == "tpu" and k.shape[2] >= _FLASH_MIN_SEQ:
-        # long-sequence streaming kernel (O(L) memory)
+    path = _attention_path(ctx.platform, ctx.mesh, q, k)
+    _MON.counter(f"lowering.attention_{path}").inc()
+    if path == "flash":
         return {"Out": _flash_attention_tpu(q, k, v, bias, causal, scale)}
-    if (ctx.platform == "tpu" and op.attr("use_pallas_sdpa", False)
-            and max(q.shape[2], k.shape[2]) <= 512):
-        # moderate-L fused kernel (ops/pallas_attention.py): whole-row
-        # softmax in VMEM, scores never reach HBM fwd or bwd.  OPT-IN only:
-        # the r5 full-model A/B measured it SLOWER than the mixed-precision
-        # jnp formulation below (BERT step 305 vs 275 ms; isolated
-        # microbench 10.9 vs 7.9 ms/layer-fwd) — at L<=512 XLA's own
-        # softmax/matmul fusion wins on this chip, extending r4's negative
-        # result for the stock streaming kernel (r5 chip round).  On this
-        # tree and compiler the verdict is the other way round:
-        # bert-base.pretrain-s512 212.60 against 181.36 samples/s with this
-        # branch taken (PERF.md, PR 29), so the attribute stands until the
-        # perf_opt that makes the kernel the path by shape (ROADMAP S5(a)).
-        # bias is mask-derived in every caller, hence non-differentiable.
+    if path == "row_kernel":
         from .pallas_attention import fused_sdpa
 
         b = jax.lax.stop_gradient(bias) if bias is not None else None
         out = fused_sdpa(q, k, v, b, bool(causal), float(scale))
         return {"Out": out.astype(q.dtype)}
-    # mixed-precision fallback (standard TPU attention numerics): the
-    # einsums keep their input dtype on the MXU and ACCUMULATE in f32 via
-    # preferred_element_type; softmax runs in f32; probs return to the
-    # activation dtype for the context matmul.  The previous revision cast
-    # q/k/v to f32 BEFORE the einsums, which ran the batched matmuls at the
-    # f32 MXU rate and doubled score-tensor HBM traffic — profiled at
-    # 13.6 TF/s on the BERT bench (r5 chip round).
-    #
-    # score_dtype="bfloat16" (opt-in) additionally materializes the
-    # [B,H,Lq,Lk] score tensor in bf16 — halves the dominant attention HBM
-    # traffic at a documented numerics cost (pre-softmax logits quantized
-    # to 8 mantissa bits; softmax max/sum still accumulate in f32).
-    # bert-base.pretrain-s512: 196.66 against 181.36 samples/s (PERF.md,
-    # PR 29); it goes or becomes the path with use_pallas_sdpa above.
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -591,13 +625,7 @@ def _fused_attention(ctx, op, ins):
         Lq, Lk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)
         s = jnp.where(mask, s, -1e30)
-    if op.attr("score_dtype", "float32") == "bfloat16":
-        s = s.astype(jnp.bfloat16)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp((s - m).astype(jnp.float32))
-        p = e / jnp.sum(e, axis=-1, keepdims=True)
-    else:
-        p = jax.nn.softmax(s, axis=-1)
+    p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)
     return {"Out": out.astype(q.dtype)}

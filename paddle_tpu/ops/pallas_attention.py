@@ -1,25 +1,41 @@
-"""Fused scaled-dot-product attention Pallas kernels (moderate sequence).
+"""Whole-row scaled-dot-product attention Pallas kernels (moderate sequence).
 
 Reference role: operators/fused/fused_attention ambitions + the unfused
-matmul/softmax/matmul stack in layers/nn.py multi-head attention.  The r5
-BERT profile (r5 chip round) showed the XLA formulation bandwidth-bound
-on the [B,H,L,L] f32 score tensor: ~50 ms of a 261 ms step spent streaming
-scores/probs through HBM at 12-16 TF/s.  For L <= 512 the ENTIRE score row
-block fits VMEM, so no online-softmax streaming is needed: each grid step
-loads NB (batch*head) pairs of Q/K/V tiles, computes S = QK^T (f32 on the
-MXU), full-row softmax in VMEM, and O = PV — scores never touch HBM,
-forward or backward (the backward kernel recomputes S/P from Q/K the
-flash-attention way rather than saving them).
+matmul/softmax/matmul stack in layers/nn.py multi-head attention.  XLA's
+attention is bandwidth-bound on the [B,H,L,L] f32 score tensor, which it
+writes to HBM and reads back, forward and backward.  For L <= 512 the
+ENTIRE score row block fits VMEM, so no online-softmax streaming is needed:
+each grid step loads NB (batch*head) pairs of Q/K/V tiles, computes
+S = QK^T (f32 on the MXU), full-row softmax in VMEM, and O = PV: scores
+never touch HBM, forward or backward (the backward kernel recomputes S/P
+from Q/K the flash-attention way rather than saving them).
+
+`ops/nn_ops.py:_attention_path` sends `fused_attention` here by shape: bf16,
+64-wide heads, 384 to 512 queries and keys, no mesh.  TPU v5e, BERT-base,
+32 x 512 tokens a step: 212.54 samples/s against 181.36 with XLA's attention
+(`fused_attention` 54.4 -> 18.2 ms of the step, `peak_hbm_gb` 11.18 -> 7.24);
+at 256 x 128 it loses, 1003.2 against 1132.9, and is not taken (PERF.md,
+PRs 29 and 30).
+
+Same mathematics as the XLA path: operands in their own dtype on the MXU,
+float32 accumulation, float32 scores and softmax, the probabilities rounded
+to the operands' dtype for PV.  The backward rounds dS to the operands'
+dtype before the dQ and dK products, as XLA's transposed einsums and the
+stock flash kernel's backward do (tests/test_pallas_attention.py compares
+the three).
 
 Contracts:
   * q/k/v: [B, H, L, dh] all same dtype (bf16 or f32); out matches.
   * bias: optional additive pre-softmax bias [B, 1|H, Lq, Lk], treated as
-    NON-differentiable (it derives from lengths/causality in every caller —
-    layers.attention_bias — so its cotangent is structurally zero; the op
+    NON-differentiable (it derives from lengths/causality in every caller,
+    layers.attention_bias, so its cotangent is structurally zero; the op
     lowering stop_gradients it).
   * causal masking applied inside the kernel (no bias materialization).
-  * long-L guard: callers route L >= _FLASH_MIN_SEQ to the streaming stock
-    kernel instead (ops/nn_ops.py); this module asserts L <= 1024.
+  * long-L guard: this module asserts L <= 1024; from _FLASH_MIN_SEQ keys
+    on the lowering takes the streaming stock kernel instead.
+  * both calls carry a `cost_estimate` of their matrix products and
+    exponentials, so the step's `cost_analysis()` still counts attention's
+    arithmetic; not of their bytes (`_cost` says why).
 """
 from __future__ import annotations
 
@@ -31,6 +47,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# The working set a grid step may hold, which sets the (batch, head) pairs a
+# step (`_pick_nb`): 3 forward and 2 backward at 512 keys, 4 and 3 at 384.
+# Re-chosen on the chip in PR 30 and left alone: (32, 12, 512, 64) bf16, the
+# backward kernel alone, 1.458 ms at 2 MB (one pair a step), 1.425 at 8 MB,
+# 1.407 at 16 MB (6 and 4 pairs), 1.404 at 24 MB: 0.02 ms a layer between 8
+# and 24, a fifth of a millisecond of a 150 ms step.  The divide by the row
+# sum likewise: one reciprocal a row and a multiply a score instead reads
+# 1.431 at 8 MB (PERF.md, PR 30).
 _VMEM_BUDGET = 8 * 1024 * 1024
 
 
@@ -165,6 +189,21 @@ def _specs(B, H, L, Lk, dh, nb, bias_mode, n_io):
     return specs
 
 
+def _cost(B, H, L, Lk, dh, products):
+    """What a call computes, for the step's `cost_analysis()`: `products`
+    [L, Lk, dh] matrix products a (batch, head) pair and one exponential a
+    score.  `bytes_accessed` is left 0 on purpose.  Told the bytes (each
+    operand once, 101 MB forward and 176 MB backward at (32, 12, 512, 64)),
+    XLA's memory-space assignment prefetches across the call and stops
+    keeping the dropout fusion's output behind it in fast memory: TPU v5e,
+    `bert-base.pretrain-s512`, samples/s: no estimate 212.596, 212.603;
+    operations alone 212.546, 212.546; bytes alone 207.979, 207.977; both
+    209.028, 209.030 (PERF.md, PR 30)."""
+    pairs = B * H
+    return pl.CostEstimate(flops=2 * products * pairs * L * Lk * dh,
+                           transcendentals=pairs * L * Lk, bytes_accessed=0)
+
+
 def _flatten(q, k, v):
     B, H, L, dh = q.shape
     Lk = k.shape[2]
@@ -196,6 +235,8 @@ def _fused_sdpa_fwd(q, k, v, bias, causal, scale, interpret):
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, L, dh), q.dtype),
+        cost_estimate=_cost(B, H, L, Lk, dh, 2),
+        name="fused_sdpa_fwd",
         interpret=interpret,
     )(*args)
     out = out.reshape(B, H, L, dh)
@@ -228,6 +269,8 @@ def _fused_sdpa_bwd(causal, scale, interpret, res, g):
             jax.ShapeDtypeStruct((B * H, Lk, dh), k.dtype),
             jax.ShapeDtypeStruct((B * H, Lk, dh), v.dtype),
         ],
+        cost_estimate=_cost(B, H, L, Lk, dh, 5),
+        name="fused_sdpa_bwd",
         interpret=interpret,
     )(*args)
     dbias = None if bias is None else jnp.zeros_like(bias)

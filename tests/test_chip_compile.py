@@ -127,6 +127,29 @@ CASES = {
     "fused_sdpa": (
         lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
         [((256, 12, 128, 64), BF16)] * 3, (0, 1, 2)),
+    # every length `ops/nn_ops.py:_attention_path` sends to the whole-row
+    # kernel, at BERT-base's heads and ~16k tokens: `bert-base.pretrain-s512`'s
+    # own call, the two lengths of the crossing's runs (PERF.md, PR 30), and
+    # what else the rule admits: a mask shared by the heads or one a head,
+    # causal, queries and keys of different lengths
+    "fused_sdpa_seq512": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
+        [((32, 12, 512, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_seq384": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
+        [((48, 12, 384, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_seq256": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
+        [((64, 12, 256, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_seq512_bias_causal": (
+        lambda q, k, v, b: fused_sdpa(q, k, v, b, True, 0.125),
+        [((32, 12, 512, 64), BF16)] * 3 + [((32, 1, 512, 512), F32)], (0, 1, 2)),
+    "fused_sdpa_seq512_bias_per_head": (
+        lambda q, k, v, b: fused_sdpa(q, k, v, b, False, 0.125),
+        [((8, 12, 512, 64), BF16)] * 3 + [((8, 12, 512, 512), BF16)], (0, 1, 2)),
+    "fused_sdpa_q256_k512": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
+        [((32, 12, 256, 64), BF16)] + [((32, 12, 512, 64), BF16)] * 2, (0, 1, 2)),
     # through `_flash_block_sizes`: 1024-blocks without a bias, 512 with one
     # (1024 with a bias overruns the scoped VMEM in the dq kernel), the
     # kernel's default where the length is a multiple of neither

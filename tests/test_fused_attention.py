@@ -134,63 +134,28 @@ def test_bert_bf16_fused_builds_and_trains():
     assert losses[-1] < losses[0]  # memorizes the tiny fake batch
 
 
-def test_fused_attention_bf16_score_dtype():
-    """Opt-in bf16 score materialization: fwd + grad must match the f32
-    path within bf16-logit tolerance, including bias and causal masking
-    (fully-masked tail positions)."""
-    import jax
-    import jax.numpy as jnp
-
+def test_the_lower_span_says_which_attention_the_program_took():
+    """`lowering.attention_*` count one a `fused_attention` op where the
+    lowering decides; the `executor.lower` span of the program carries the
+    ones that moved.  Off the TPU every op takes XLA's attention (the TPU's
+    choices, shape by shape: tests/test_lowering_one_path.py)."""
     import paddle_tpu as fluid
-    from paddle_tpu import layers
-    from paddle_tpu.core.program import Program, program_guard
-
-    rng = np.random.RandomState(0)
-    B, H, L, dh = 2, 2, 8, 4
-    qv = rng.randn(B, H, L, dh).astype("f4")
-    kv = rng.randn(B, H, L, dh).astype("f4")
-    vv = rng.randn(B, H, L, dh).astype("f4")
-    bias = np.where(np.arange(L)[None, None, None, :] < 6, 0.0, -1e9).astype("f4")
-    bias = np.broadcast_to(bias, (B, 1, L, L)).copy()
-
-    outs = {}
-    grads = {}
-    for sd in (None, "bfloat16"):
-        main, startup = Program(), Program()
-        with program_guard(main, startup):
-            q = layers.data("q", [H, L, dh], dtype="float32")
-            k = layers.data("k", [H, L, dh], dtype="float32")
-            v = layers.data("v", [H, L, dh], dtype="float32")
-            b = layers.data("b", [1, L, L], dtype="float32")
-            o = layers.fused_attention(q, k, v, bias=b, causal=True,
-                                       score_dtype=sd)
-            loss = layers.mean(o)
-            g = fluid.calc_gradient(loss, [main.global_block().var("q")])[0]
-        exe = fluid.Executor(fluid.CPUPlace())
-        scope = fluid.Scope()
-        exe.run(startup, scope=scope)
-        res = exe.run(main, feed={"q": qv, "k": kv, "v": vv, "b": bias},
-                      fetch_list=[o, g], scope=scope)
-        outs[sd] = np.asarray(res[0])
-        grads[sd] = np.asarray(res[1])
-    # bf16 logits: ~2^-8 relative on scores -> small prob/ctx perturbation
-    assert np.allclose(outs[None], outs["bfloat16"], atol=2e-2), \
-        np.abs(outs[None] - outs["bfloat16"]).max()
-    assert np.allclose(grads[None], grads["bfloat16"], atol=2e-2), \
-        np.abs(grads[None] - grads["bfloat16"]).max()
-    assert np.isfinite(outs["bfloat16"]).all()
-
-
-def test_fused_attention_score_dtype_validation():
-    import pytest as _pytest
-
-    from paddle_tpu import layers
+    from paddle_tpu import layers, monitor
     from paddle_tpu.core.program import Program, program_guard
 
     main, startup = Program(), Program()
     with program_guard(main, startup):
         q = layers.data("q", [2, 8, 4], dtype="float32")
-        with _pytest.raises(ValueError, match="score_dtype"):
-            layers.fused_attention(q, q, q, score_dtype="float16")
-        # aliases normalize instead of silently no-op'ing
-        layers.fused_attention(q, q, q, score_dtype="bf16")
+        out = layers.fused_attention(layers.fused_attention(q, q, q), q, q, causal=True)
+    monitor.enable()
+    try:
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(main, feed={"q": np.ones((3, 2, 8, 4), "f4")}, fetch_list=[out])
+        seen = monitor.get_monitor().counter_values()
+        assert seen["lowering.attention_xla"] == 2
+        assert not seen.get("lowering.attention_row_kernel") and not seen.get("lowering.attention_flash")
+        lowered = [e[5] for e in monitor.get_monitor().events() if e[0] == "executor.lower"][-1]
+        assert lowered["attention_xla"] == 2 and "attention_row_kernel" not in lowered
+    finally:
+        monitor.disable()
+        monitor.reset()

@@ -1,18 +1,22 @@
-"""One lowering per op (ISSUE 29): conv2d, pool2d, batch_norm and
+"""One lowering per op (ISSUEs 29 and 30): conv2d, pool2d, batch_norm and
 fused_attention choose by what the op can observe (platform, dtype, shapes,
-the program's own attributes) and by nothing a process can set.
+the context's mesh, the program's own layout attributes) and by nothing a
+process or a program can set.
 
 (a) the programs the cells and the zoo build lower, for the chip, to the text
     they lowered to at the parent commit `7602313`, where six module globals
     and three attention attributes still stood at their defaults: the deletion
     changed no generated program (lowered, never compiled: nothing runs);
 (b) batch_norm's one choice, its statistics by dtype, against numpy;
-(c) the compile-cache key holds no global of an ops module.
+(c) the compile-cache key holds no global of an ops module;
+(d) `fused_attention`'s choice among its three attentions, shape by shape,
+    and the counters that say which a program took (ISSUE 30).
 """
 import hashlib
 import inspect
 import json
 import os
+import re
 import sys
 
 import jax
@@ -40,13 +44,22 @@ def _resnet50(batch, for_test=False, **kw):
     return main, startup, feeds, fetches["loss"].name
 
 
-def _bert_s512():
-    """`bert-base.pretrain-s512`'s program (benchmark/models/bert.py: build)."""
+def _bert(batch, seq):
+    """A BERT cell's program (benchmark/models/bert.py: build)."""
     main, startup, _, fetches = transformer.build_bert(
-        vocab_size=30522, seq_len=512, d_model=768, n_layers=12, n_heads=12, d_ff=3072,
+        vocab_size=30522, seq_len=seq, d_model=768, n_layers=12, n_heads=12, d_ff=3072,
         dropout_prob=0.1, learning_rate=1e-4, with_optimizer=True, dtype="bfloat16",
         use_fused_attention=True)
-    feeds = {n: jax.ShapeDtypeStruct((32, 512), np.int32) for n in ("ids", "labels", "pos_ids")}
+    feeds = {n: jax.ShapeDtypeStruct((batch, seq), np.int32) for n in ("ids", "labels", "pos_ids")}
+    return main, startup, feeds, fetches["loss"].name
+
+
+def _olmoe_s4096():
+    """`olmoe-1b-7b.train-s4096`'s program (benchmark/models/olmoe.py: build):
+    one layer at the published widths, 12576 vocabulary rows."""
+    main, startup, _, fetches = transformer.build_causal_lm(
+        vocab_size=12576, seq_len=4096, n_layers=1, with_optimizer=True, dtype="bfloat16")
+    feeds = {n: jax.ShapeDtypeStruct((4, 4096), np.int32) for n in ("ids", "labels", "pos_ids")}
     return main, startup, feeds, fetches["loss"].name
 
 
@@ -63,34 +76,64 @@ def _fp16_batch_norm():
 
 
 #: case -> (builder, module name, sha256 of the op listing, sha256 of the
-#: lowered StableHLO), recorded at the parent commit `7602313` by this test
-#: (it prints what it finds).  A PR that means to change a lowering re-records
-#: the case and says so in CHANGES.md.
+#: lowered StableHLO, the program's `fused_attention` ops by the attention the
+#: lowering took: row kernel, flash, XLA's), recorded at the parent commit
+#: `7602313` by this test (it prints what it finds).  A PR that means to
+#: change a lowering re-records the case and says so in CHANGES.md.  PR 30
+#: re-recorded `bert-base-s512-fused` (its twelve attentions are the whole-row
+#: kernel now) and added the two cells beside it, both the parent's programs:
+#: `bert-base-s128-fused` is `tests/test_olmoe.py`'s `BERT_TEXT_SHA`, OLMoE's
+#: is what `bb78123` lowers to.  A program that holds a TPU kernel is lowered
+#: FOR the TPU (a CPU host cannot lower a Mosaic call for itself) and hashed
+#: without the kernels' serialised bodies.
 PARENTS_PROGRAMS = {
     "resnet50-train-bf16-nchw": (lambda: _resnet50(256, dtype="bfloat16"), "train_cc732a46",
         "b40b8617a8e43d947e7dcdd6c6b5ea2136abe6018b84b409dacbf82eadaa1b35",
-        "e437a7597c251c9f4488b4bd25aa0782721bda89de6bbeb1823bd9a16933274c"),
+        "e437a7597c251c9f4488b4bd25aa0782721bda89de6bbeb1823bd9a16933274c", (0, 0, 0)),
     "resnet50-for_test-bf16-nchw": (lambda: _resnet50(8, for_test=True, dtype="bfloat16"), "infer_87838cf4",
         "851aa4de6eed0242f77d2494362eb97c788109f448d2d11d66f5d487cd3c2762",
-        "87d011020c6b861070f55ffb8b357c61d33dc3628d9666aebadc404c686f38e6"),
+        "87d011020c6b861070f55ffb8b357c61d33dc3628d9666aebadc404c686f38e6", (0, 0, 0)),
     "resnet50-train-f32-nchw": (lambda: _resnet50(64, dtype="float32"), "train_e29c5d91",
         "189f0d192c4800bf280ba303e8b8e09013d5c26a596e1cd944287c15d9807a8b",
-        "697ddeb2e7bf3498a9bf54f8be1c456a19dec700b811e7967298709305a8a14f"),
+        "697ddeb2e7bf3498a9bf54f8be1c456a19dec700b811e7967298709305a8a14f", (0, 0, 0)),
     "resnet50-train-bf16-nhwc": (lambda: _resnet50(256, dtype="bfloat16", data_format="NHWC"), "train_cc732a46",
         "03f8097c8e4f2816fa57d4b2b45496da8d8a7e2a23de6657256a26d17ddcc3e2",
-        "73cfc133487bbb2c7e57a9846ed7f785ccd85c0c7514ed0bd6519ab192285f13"),
-    "bert-base-s512-fused": (_bert_s512, "train_e9476d18",
+        "73cfc133487bbb2c7e57a9846ed7f785ccd85c0c7514ed0bd6519ab192285f13", (0, 0, 0)),
+    "bert-base-s512-fused": (lambda: _bert(32, 512), "train_e9476d18",
         "4790802a450bc2534b4f64089c4c9b044c37d7e4de8c30169d41d35c22fb1bc5",
-        "712cc4f84b990442f3d85695e4406644b5d1b9ca2ce4b4fed6f1ea27385ad848"),
+        "cedaf95cee1ad5e64a71453df109d17bbf1760c41154aa793e33f2da26cf0e6d", (12, 0, 0)),
+    "bert-base-s128-fused": (lambda: _bert(256, 128), "train_e9476d18",
+        "4790802a450bc2534b4f64089c4c9b044c37d7e4de8c30169d41d35c22fb1bc5",
+        "27af5c8d6dfb08a85dae885858d72e7874e05164773e81ec57a42e1fb1b582dd", (0, 0, 12)),
+    "olmoe-1b-7b-s4096": (_olmoe_s4096, "train_cbb6bbe7",
+        "822e9f203b8780a8ce13ae8c050fe4480b215eb43e3063bc89b21090c48146d1",
+        "a65d54648f7ec71c9881a173595184ff883b43b3cc7d035e5c364690f5fde7c7", (0, 1, 0)),
     "batch_norm-train-fp16": (_fp16_batch_norm, "train_97080cb5",
         "97158b65993029a94935d7280f793136a5fe07aa0458a7b0de8d003fb41fbd33",
-        "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984"),
+        "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984", (0, 0, 0)),
 }
 
 
+def _attention_counters():
+    from paddle_tpu.monitor import MONITOR
+
+    seen = MONITOR.counter_values()
+    return tuple(seen.get(f"lowering.attention_{path}", 0) for path in ("row_kernel", "flash", "xla"))
+
+
+@pytest.fixture
+def monitor_on():
+    from paddle_tpu import monitor
+
+    monitor.enable()
+    yield
+    monitor.disable()
+    monitor.reset()
+
+
 @pytest.mark.parametrize("case", list(PARENTS_PROGRAMS))
-def test_the_step_lowers_to_the_parents_program(case):
-    build, module, ops_sha, text_sha = PARENTS_PROGRAMS[case]
+def test_the_step_lowers_to_the_parents_program(case, monitor_on):
+    build, module, ops_sha, text_sha, attentions = PARENTS_PROGRAMS[case]
     with fluid.unique_name.guard():  # parameter names come from process-wide counters
         main, startup, feeds, fetch = build()
     main.random_seed = startup.random_seed = 3
@@ -105,9 +148,20 @@ def test_the_step_lowers_to_the_parents_program(case):
     step = ex._CompiledStep(main, list(feeds), [fetch], scope, platform="tpu",
                             feed_shapes={n: s.shape for n, s in feeds.items()})
     as_shape = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)  # noqa: E731
-    text = step.jfn.trace({n: as_shape(scope.find_var(n)) for n in step.rw_names},
-                          {n: as_shape(scope.find_var(n)) for n in step.ro_names},
-                          feeds, as_shape(jax.random.PRNGKey(0))).lower().as_text()
+    traced = step.jfn.trace({n: as_shape(scope.find_var(n)) for n in step.rw_names},
+                            {n: as_shape(scope.find_var(n)) for n in step.ro_names},
+                            feeds, as_shape(jax.random.PRNGKey(0)))
+    # one count an op, where the lowering decided
+    assert _attention_counters() == attentions
+    if attentions[0] or attentions[1]:
+        # a Mosaic kernel's serialised body names the files and lines of its
+        # call stack (this checkout's path among them): the pin is the program
+        # round the kernels and each call's name, cost and layout, not its body
+        text = re.sub(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+', r"\1",
+                      traced.lower(lowering_platforms=("tpu",)).as_text())
+        assert text.count('\\22body\\22: \\22\\22') == text.count("@tpu_custom_call") > 0
+    else:
+        text = traced.lower().as_text()
     found = (step.module, hashlib.sha256(listing.encode()).hexdigest(),
              hashlib.sha256(text.encode()).hexdigest())
     print(f'    "{case}": {found},')
@@ -160,9 +214,84 @@ def test_the_compile_cache_key_names_no_ops_module_global():
     finally:
         fluid.set_flags({"FLAGS_use_pallas": False})
     assert "nn_ops" not in inspect.getsource(ex)
-    # and the lowerings have nothing of the kind to name: `_FLASH_MIN_SEQ` is a
-    # threshold on a shape, with cells on both sides of it
+    # and the lowerings have nothing of the kind to name: what is left are
+    # thresholds on a shape, each with the chip runs that set it beside it and
+    # cells (or the crossing's runs) on both sides
     switches = [n for n, v in vars(nn_ops).items() if isinstance(v, (bool, int, float, str))
                 and n.startswith("_") and n.isupper()]
-    assert switches == ["_FLASH_MIN_SEQ"]
+    assert switches == ["_FLASH_MIN_SEQ", "_FLASH_MIN_QUERIES", "_ROW_KERNEL_MAX_SEQ", "_ROW_KERNEL_MIN_SEQ",
+                        "_ROW_KERNEL_HEAD_DIM", "_ROW_KERNEL_SEQ_MULTIPLE"]
     assert not [n for n in vars(nn_ops) if n.startswith(("enable_", "set_"))]  # nor a setter for one
+    # and no attribute of the op selects an attention
+    source = inspect.getsource(nn_ops._fused_attention) + inspect.getsource(nn_ops._attention_path)
+    assert sorted(set(re.findall(r"op\.attr\(\"(\w+)\"", source))) == ["causal", "scale"]
+
+
+#: (queries, keys) -> the attention a bf16 `fused_attention` over 64-wide
+#: heads takes on one TPU chip.  The row kernel from `_ROW_KERNEL_MIN_SEQ` to
+#: `_ROW_KERNEL_MAX_SEQ` queries AND keys, the flash kernel from
+#: `_FLASH_MIN_SEQ` keys (and `_FLASH_MIN_QUERIES` queries), XLA's attention
+#: elsewhere: 128 (the kernel loses by 11%), a decoding step's one query, the
+#: lengths no run has priced.
+ATTENTION_BY_LENGTHS = {
+    (128, 128): "xla", (256, 256): "xla", (384, 384): "row_kernel", (512, 512): "row_kernel",
+    (512, 384): "row_kernel", (512, 256): "xla", (1, 512): "xla", (128, 512): "xla", (320, 320): "xla",
+    (640, 640): "xla", (1024, 1024): "xla", (2048, 2048): "flash", (1, 2048): "xla",
+}
+#: what else the op can see: (dtype, head width) -> may the row kernel be taken
+ATTENTION_OPERANDS = {("bfloat16", 64): True, ("float32", 64): False, ("bfloat16", 128): False}
+
+
+def _trace_attention(lengths, dtype="bfloat16", head=64, bias=False, causal=False, mesh=None):
+    """The jaxpr of one `fused_attention` op as the interpreter lowers it for
+    a TPU (nothing is lowered further, nothing runs), forward and backward,
+    and the three counters' movement."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.core.lowering import LoweringContext
+    from paddle_tpu.core.registry import get_op_def
+
+    lq, lk = lengths
+    op = SimpleNamespace(type="fused_attention", attr=lambda name, default=None: {"causal": causal}.get(name, default))
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform="tpu", mesh=mesh)
+
+    def attention(q, k, v, *b):
+        ins = {"Q": [q], "K": [k], "V": [v], "Bias": list(b)}
+        return get_op_def("fused_attention").lower(ctx, op, ins)["Out"].astype(np.float32).sum()
+
+    args = [jax.ShapeDtypeStruct((2, 4, n, head), dtype) for n in (lq, lk, lk)]
+    if bias:
+        args.append(jax.ShapeDtypeStruct((2, 1, lq, lk), np.float32))
+    before = _attention_counters()
+    text = str(jax.make_jaxpr(jax.grad(attention, argnums=(0, 1, 2)))(*args))
+    moved = tuple(b - a for a, b in zip(before, _attention_counters()))
+    kernels = set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention)\b", text))
+    return kernels, moved
+
+
+KERNELS_OF = {"row_kernel": {"fused_sdpa_fwd", "fused_sdpa_bwd"}, "flash": {"flash_attention"}, "xla": set()}
+COUNTED_AS = {"row_kernel": (1, 0, 0), "flash": (0, 1, 0), "xla": (0, 0, 1)}
+
+
+@pytest.mark.parametrize("on_a_mesh", [False, True], ids=["one-chip", "mesh"])
+@pytest.mark.parametrize("bias,causal", [(False, False), (True, False), (False, True), (True, True)],
+                         ids=["plain", "bias", "causal", "bias-causal"])
+@pytest.mark.parametrize("lengths", list(ATTENTION_BY_LENGTHS), ids=lambda ls: f"{ls[0]}x{ls[1]}")
+def test_fused_attention_takes_its_attention_from_the_shape(lengths, bias, causal, on_a_mesh, monitor_on):
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("dp",)) if on_a_mesh else None
+    want = ATTENTION_BY_LENGTHS[lengths]
+    if on_a_mesh and want == "row_kernel":
+        want = "xla"  # a custom call GSPMD cannot partition: the lowering's docstring
+    kernels, moved = _trace_attention(lengths, bias=bias, causal=causal, mesh=mesh)
+    assert (kernels, moved) == (KERNELS_OF[want], COUNTED_AS[want])
+
+
+@pytest.mark.parametrize("dtype,head", list(ATTENTION_OPERANDS), ids=lambda v: str(v))
+def test_the_row_kernel_is_taken_only_for_operands_it_was_run_with(dtype, head, monitor_on):
+    want = "row_kernel" if ATTENTION_OPERANDS[(dtype, head)] else "xla"
+    kernels, moved = _trace_attention((512, 512), dtype=dtype, head=head)
+    assert (kernels, moved) == (KERNELS_OF[want], COUNTED_AS[want])
+    # off the TPU there is one attention whatever the shape
+    from paddle_tpu.ops.nn_ops import _attention_path
+    q = jax.ShapeDtypeStruct((2, 4, 512, head), dtype)
+    assert _attention_path("cpu", None, q, q) == "xla"

@@ -1,0 +1,54 @@
+"""On the chip: the three attentions of `fused_attention` against float32 on
+unit-variance q, k, v (tests/test_pallas_attention.py: attention_errors), and
+each attention's time alone at BERT-base's heads and ~16k tokens.
+
+    chiprun -- python3 tools/chip_attention_errors.py     (PERF.md, PR 30)
+"""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from tests.test_pallas_attention import attention_errors, xla_attention
+from paddle_tpu.ops.nn_ops import _flash_attention_tpu
+from paddle_tpu.ops.pallas_attention import fused_sdpa
+
+DRY = os.environ.get("DRY") == "1"  # a rehearsal on the CPU: XLA's attention only, tiny
+assert DRY or jax.devices()[0].platform == "tpu", jax.devices()
+shape = (2, 12, 512, 64) if DRY else (32, 12, 512, 64)
+scale = shape[-1] ** -0.5
+attentions = {
+    "xla": xla_attention,
+    "row_kernel": lambda q, k, v: fused_sdpa(q, k, v, None, False, scale),
+    "flash": lambda q, k, v: _flash_attention_tpu(q, k, v, None, False, scale),
+}
+if DRY:
+    attentions = {"xla": xla_attention}
+for seed in (0, 1):
+    print(json.dumps({"attention_errors": shape, "seed": seed, "device": jax.devices()[0].device_kind,
+                      "errors": attention_errors(shape, attentions, seed=seed)}), flush=True)
+
+if DRY:
+    sys.exit(0)  # a time taken on the CPU is no device number
+# each attention alone, forward + backward, ms (a microbenchmark: not the cell)
+key = jax.random.PRNGKey(0)
+for seq, batch in ((128, 128), (256, 64), (384, 48), (512, 32)):
+    q, k, v, w = (jax.random.normal(kk, (batch, 12, seq, 64), jnp.bfloat16) for kk in jax.random.split(key, 4))
+    row = {}
+    for name, f in attentions.items():
+        g = jax.jit(jax.grad(lambda q, k, v: (f(q, k, v) * w).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+        try:
+            jax.block_until_ready(g(q, k, v))
+            t = time.perf_counter()
+            for _ in range(20):
+                out = g(q, k, v)
+            jax.block_until_ready(out)
+            row[name] = round((time.perf_counter() - t) / 20 * 1e3, 3)
+        except Exception as e:  # a shape a kernel refuses
+            row[name] = repr(e)[:120]
+    print(json.dumps({"fwd_bwd_ms": row, "shape": (batch, 12, seq, 64)}), flush=True)
