@@ -715,14 +715,27 @@ def space_to_depth(x, blocksize, name=None):
     return out
 
 
-def fused_attention(q, k, v, bias=None, causal=False, scale=None, name=None):
+def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mask_block=None,
+                    name=None):
     """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
     float32 scores and softmax whatever the operands' dtype.  On the TPU the
     lowering takes its tiling from the shape (ops/nn_ops.py): the streaming
     flash kernel from 2048 keys, a whole-row kernel for bf16 sequences of 384
     to 512 (the scores never reach HBM in either, forward or backward),
     XLA's attention otherwise.  `bias` is an additive pre-softmax mask,
-    (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh)."""
+    (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
+
+    `k` and `v` may have fewer heads than `q`, a divisor of its count (grouped
+    key/value heads): query head j reads key/value head j div (Hq / Hkv).
+
+    `mask="block_diffusion"` with `mask_block=B` is the mask of
+    block-diffusion training over the 2L positions [noised ; clean] of L
+    tokens in blocks of B (`ops/masked_attention.py: block_diffusion_allowed`):
+    a noised block sees itself and the clean blocks before it, a clean block
+    the clean blocks up to itself.  The mask is two attributes of the op, not
+    a tensor: L is half the length and the rule is computed from positions,
+    so nothing of [2L, 2L] exists on the TPU, where a block-sparse kernel
+    skips the three quarters of the square the rule empties."""
     helper = LayerHelper("fused_attention", name=name)
     out = _out(helper, q.dtype, shape=q.shape)
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
@@ -731,6 +744,9 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, name=None):
     attrs = {"causal": causal}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if mask is not None:
+        attrs["mask"] = str(mask)
+        attrs["mask_block"] = int(mask_block)
     helper.append_op("fused_attention", inputs=inputs, outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
@@ -766,7 +782,7 @@ def rotary_embedding(x, positions, theta=10000.0, name=None):
 
 
 def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
-        router_attr=None, gate_attr=None, up_attr=None, down_attr=None, name=None):
+        router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None):
     """A layer of routed experts over (..., d): a float32 router picks
     `top_k` of `num_experts` gated-SiLU experts of width `expert_width` for
     every token; their outputs are summed, weighted by the router's
@@ -776,14 +792,27 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     Returns (out, load_balance_loss, router_z_loss); the two [1] float32
     losses are for the caller to weigh into the training loss.  The experts
     are three stacked parameters, (E, d, F) gate and up and (E, F, d)
-    down."""
+    down.
+
+    `held=(first, count)` is a layer that holds a share of its experts, as
+    one chip of several that split the layer does: the stacked parameters are
+    (count, d, F), (count, d, F) and (count, F, d), the experts `first` to
+    `first + count - 1`; the router keeps its `num_experts` outputs, its
+    top `top_k` and its renormalisation (over ALL the chosen, held or not),
+    and what the absent experts would have added is left out: the layers of
+    the chips that hold them add it.  Assignments to absent experts are never
+    rows of the grouped products or of a gather; no assignment to a held
+    expert is dropped.  `held=None` holds them all."""
     helper = LayerHelper("moe", name=name)
     d = int(input.shape[-1])
     lead = tuple(input.shape[:-1])
+    n_held = num_experts if held is None else int(held[1])
+    if held is not None and not (0 <= held[0] and 0 < held[1] and held[0] + held[1] <= num_experts):
+        raise ValueError(f"moe: held = {tuple(held)} is no range of the {num_experts} experts")
     router = helper.create_parameter(router_attr, [d, num_experts], "float32")
-    gate = helper.create_parameter(gate_attr, [num_experts, d, expert_width], input.dtype)
-    up = helper.create_parameter(up_attr, [num_experts, d, expert_width], input.dtype)
-    down = helper.create_parameter(down_attr, [num_experts, expert_width, d], input.dtype)
+    gate = helper.create_parameter(gate_attr, [n_held, d, expert_width], input.dtype)
+    up = helper.create_parameter(up_attr, [n_held, d, expert_width], input.dtype)
+    down = helper.create_parameter(down_attr, [n_held, expert_width, d], input.dtype)
     top_p = _out(helper, "float32", shape=lead + (top_k,))
     top_i = _out(helper, "int32", shape=lead + (top_k,))
     load = _out(helper, "int32", shape=(num_experts,))
@@ -796,13 +825,17 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
     out = _out(helper, input.dtype, shape=input.shape)
     dropped = _out(helper, "int32", shape=(1,))
+    outputs = {"Out": [out.name], "Dropped": [dropped.name]}
+    attrs = {"num_experts": int(num_experts), "top_k": int(top_k)}
+    if held is not None:
+        outputs["Held"] = [_out(helper, "int32", shape=(1,)).name]
+        attrs["held"] = [int(held[0]), int(held[1])]
     helper.append_op(
         "moe_experts",
         inputs={"X": [input.name], "TopKProb": [top_p.name], "TopKIndex": [top_i.name],
                 "Load": [load.name], "WGate": [gate.name], "WUp": [up.name],
                 "WDown": [down.name]},
-        outputs={"Out": [out.name], "Dropped": [dropped.name]},
-        attrs={"num_experts": int(num_experts), "top_k": int(top_k)})
+        outputs=outputs, attrs=attrs)
     return _keep_lod(input, out), balance, z_loss
 
 

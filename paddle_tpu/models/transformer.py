@@ -28,8 +28,10 @@ from ..core.param_attr import ParamAttr
 from ..core.program import Program, program_guard
 
 
-def _attr(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
+def _attr(name, std=0.02, seed=0):
+    """N(0, std); `seed` 0 draws from the program's own stream (its
+    `random_seed`), another pins this parameter's values whatever that is."""
+    return ParamAttr(name=name, initializer=NormalInitializer(0.0, std, seed))
 
 
 def _attr_ones(name):
@@ -42,7 +44,9 @@ def _attr_ones(name):
 def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1, is_test=False,
                          use_ring_attention=False, causal=False, kv=None, bias=None,
                          use_fused_attention=False, proj_bias=True,
-                         qk_norm_eps=None, positions=None, rope_theta=10000.0):
+                         qk_norm_eps=None, positions=None, rope_theta=10000.0,
+                         n_kv_heads=None, head_dim=None, qk_norm_per_head=False,
+                         mask=None, mask_block=None):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
     cross-attention (keys/values from another sequence); `bias` is an
@@ -50,35 +54,52 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     Serves the fixed-length BERT builder, the ragged NMT model and the
     causal LM: `proj_bias=False` drops the four projection biases,
     `qk_norm_eps` puts an RMS norm over the whole width of the projected
-    queries and of the keys (before the heads are split, as OLMoE has it),
-    `positions` ([b, T] integers) rotates queries and keys (`rope_theta`)."""
-    d_head = d_model // n_heads
+    queries and of the keys (before the heads are split, as OLMoE has it) or,
+    with `qk_norm_per_head`, over each head's features with one gain of the
+    head's width shared by the heads (after the split, as Qwen3 has it);
+    `positions` ([b, T] integers) rotates queries and keys (`rope_theta`).
+
+    `n_kv_heads` (a divisor of `n_heads`) gives keys and values fewer heads
+    than queries, and `head_dim` a head width other than d_model / n_heads:
+    q projects to n_heads x head_dim, k and v to n_kv_heads x head_dim, the
+    output from n_heads x head_dim back to d_model.  `mask` / `mask_block`
+    are `layers.fused_attention`'s structured mask (fused attention only)."""
+    d_head = head_dim or d_model // n_heads
+    n_kv_heads = n_kv_heads or n_heads
     kv_in = kv if kv is not None else x
 
-    def project(t, name):
-        return layers.fc(t, d_model, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
+    def project(t, name, width=d_model):
+        return layers.fc(t, width, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
                          bias_attr=_attr(f"{prefix}.{name}.b") if proj_bias else False)
 
-    q, k, v = project(x, "q"), project(kv_in, "k"), project(kv_in, "v")
-    if qk_norm_eps is not None:
+    q = project(x, "q", n_heads * d_head)
+    k, v = project(kv_in, "k", n_kv_heads * d_head), project(kv_in, "v", n_kv_heads * d_head)
+    if qk_norm_eps is not None and not qk_norm_per_head:
         q = layers.rms_norm(q, begin_norm_axis=2, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.q_norm.w"))
         k = layers.rms_norm(k, begin_norm_axis=2, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.k_norm.w"))
 
-    def split_heads(t):
-        t = layers.reshape(t, [0, 0, n_heads, d_head])
+    def split_heads(t, heads):
+        t = layers.reshape(t, [0, 0, heads, d_head])
         return layers.transpose(t, [0, 2, 1, 3])  # (B, H, L, dh)
 
-    q, k, v = split_heads(q), split_heads(k), split_heads(v)
+    q, k, v = split_heads(q, n_heads), split_heads(k, n_kv_heads), split_heads(v, n_kv_heads)
+    if qk_norm_eps is not None and qk_norm_per_head:
+        q = layers.rms_norm(q, begin_norm_axis=3, epsilon=qk_norm_eps,
+                            param_attr=_attr_ones(f"{prefix}.q_norm.w"))
+        k = layers.rms_norm(k, begin_norm_axis=3, epsilon=qk_norm_eps,
+                            param_attr=_attr_ones(f"{prefix}.k_norm.w"))
     if positions is not None:
         q = layers.rotary_embedding(q, positions, theta=rope_theta)
         k = layers.rotary_embedding(k, positions, theta=rope_theta)
+    if (mask is not None or n_kv_heads != n_heads) and not use_fused_attention:
+        raise ValueError("a structured mask and grouped key/value heads are fused_attention's")
     if use_fused_attention:
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
         # goes on the attention output (same substitution as the ring path).
-        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal)
+        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal, mask=mask, mask_block=mask_block)
         if dropout_prob and not is_test:
             ctx = layers.dropout(ctx, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -100,24 +121,29 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                                   dropout_implementation="upscale_in_train")
         ctx = layers.matmul(attn, v)  # (B, H, L, dh)
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
-    ctx = layers.reshape(ctx, [0, 0, d_model])
+    ctx = layers.reshape(ctx, [0, 0, n_heads * d_head])
     return project(ctx, "out")
 
 
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
                   use_ring_attention=False, causal=False, use_fused_attention=False,
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
-                  qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None):
+                  qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
+                  n_kv_heads=None, head_dim=None, attention_mask=None):
     """One transformer layer: attention and a feed-forward part, each with a
     residual connection and a norm.
 
     The defaults are BERT's: layer norm AFTER each residual sum, projection
     biases, a dense GELU feed-forward of width `d_ff`.  `norm="rms"` with
     `pre_norm=True` norms each part's INPUT instead (h = x + attn(norm(x));
-    y = h + ffn(norm(h))); `qk_norm`, `positions` and `proj_bias` go to the
-    attention.  `moe=dict(num_experts=, top_k=, norm_topk_prob=)` makes the
-    feed-forward part `d_ff`-wide routed gated-SiLU experts; its two
-    auxiliary losses are appended to `aux_losses` as (load balance, router z).
+    y = h + ffn(norm(h))); `qk_norm` (True or "width": over the projected
+    width; "head": over each head), `positions`, `proj_bias`, `n_kv_heads`,
+    `head_dim` and `attention_mask` = (kind, block length) go to the
+    attention.  `moe=dict(num_experts=, top_k=, norm_topk_prob=, held=)` makes
+    the feed-forward part `d_ff`-wide routed gated-SiLU experts (`held`: the
+    range of them this layer holds, `layers.moe`; `router_seed`: a seed of
+    the router's own, `_attr`); its two auxiliary losses
+    are appended to `aux_losses` as (load balance, router z).
     """
     def normed(t, name):
         if norm == "rms":
@@ -131,8 +157,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         if moe is not None:
             out, balance, z_loss = layers.moe(
                 t, moe["num_experts"], d_ff, moe["top_k"],
-                norm_topk_prob=moe.get("norm_topk_prob", False),
-                router_attr=_attr(f"{prefix}.moe.router.w"), gate_attr=_attr(f"{prefix}.moe.gate.w"),
+                norm_topk_prob=moe.get("norm_topk_prob", False), held=moe.get("held"),
+                router_attr=_attr(f"{prefix}.moe.router.w", seed=moe.get("router_seed", 0)),
+                gate_attr=_attr(f"{prefix}.moe.gate.w"),
                 up_attr=_attr(f"{prefix}.moe.up.w"), down_attr=_attr(f"{prefix}.moe.down.w"))
             aux_losses.append((balance, z_loss))
             return out
@@ -147,7 +174,11 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                     use_fused_attention=use_fused_attention,
                                     proj_bias=proj_bias,
                                     qk_norm_eps=norm_eps if qk_norm else None,
-                                    positions=positions, rope_theta=rope_theta)
+                                    positions=positions, rope_theta=rope_theta,
+                                    n_kv_heads=n_kv_heads, head_dim=head_dim,
+                                    qk_norm_per_head=qk_norm == "head",
+                                    mask=attention_mask and attention_mask[0],
+                                    mask_block=attention_mask and attention_mask[1])
     x = layers.elementwise_add(x, attn_out)
     if not pre_norm:
         x = normed(x, "ln1")
@@ -233,6 +264,14 @@ def build_causal_lm(
     with_optimizer=True,
     use_fused_attention=True,
     dtype="float32",
+    n_kv_heads=None,
+    head_dim=None,
+    qk_norm="width",
+    attention_mask=None,
+    experts_held=None,
+    loss_positions=None,
+    embedding_std=0.02,
+    routing_seed=0,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -246,39 +285,75 @@ def build_causal_lm(
     z-loss; fetches also hold the three parts and the logits.
     dtype="bfloat16" as in `build_bert`: activations and matmuls in bf16
     over float32 master weights; norms' statistics, the router and the loss
-    stay float32."""
+    stay float32.
+
+    What other decoders of the family change is an argument: `n_kv_heads`
+    and `head_dim` (grouped key/value heads, a head width that is not
+    d_model / n_heads), `qk_norm` ("width": OLMoE's, over the projected
+    width; "head": Qwen3's, over each head; None), `attention_mask` = (kind,
+    block length) in place of the causal mask (`layers.fused_attention`),
+    `experts_held` = (first, count), the experts each layer holds
+    (`layers.moe`), and `loss_positions` = P: the head and the loss run over
+    the first P of the `seq_len` positions only, `labels` is (B, P), and a
+    further feed `loss_weight` (B, P) float32 weighs each position's cross
+    entropy, the loss being their sum over B . P.  Block-diffusion training
+    (SDAR, BD3-LM) is `attention_mask=("block_diffusion", B)` over seq_len =
+    2L positions [noised ; clean] with `loss_positions=L` and the weight
+    1/t on the masked positions, 0 elsewhere.  An auxiliary loss whose
+    coefficient is 0 is left out of the loss.
+
+    Every weight is drawn N(0, 0.02) from the program's `random_seed` but
+    for two arguments: `embedding_std` is the token embedding's (1.0 starts
+    the residual stream at unit scale, so that a token's own embedding and
+    not the attention's average over its context is what the first routers
+    read), and `routing_seed`, where not 0, is the seed of the embedding
+    (`routing_seed`) and of layer i's router (`routing_seed` + 1 + i)
+    instead: which experts a token meets is then the same whatever the
+    program's seed, as it is for a checkpoint."""
     main, startup = Program(), Program()
     with program_guard(main, startup):
+        n_labels = loss_positions or seq_len
         ids = layers.data("ids", [seq_len], dtype="int64")
-        labels = layers.data("labels", [seq_len], dtype="int64")
+        labels = layers.data("labels", [n_labels], dtype="int64")
         pos_ids = layers.data("pos_ids", [seq_len], dtype="int64")
-        x = layers.embedding(ids, size=[vocab_size, d_model], param_attr=_attr("lm.tok_emb"))
+        x = layers.embedding(ids, size=[vocab_size, d_model],
+                             param_attr=_attr("lm.tok_emb", embedding_std, routing_seed))
         if dtype != "float32":
             x = layers.cast(x, dtype)
         aux = []
         for i in range(n_layers):
             x = encoder_layer(x, seq_len, d_model, n_heads, expert_width, f"lm.l{i}",
-                              dropout_prob=0.0, causal=True,
+                              dropout_prob=0.0, causal=attention_mask is None,
                               use_fused_attention=use_fused_attention,
                               norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
-                              qk_norm=True, positions=pos_ids, rope_theta=rope_theta,
+                              qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
                               moe=dict(num_experts=num_experts, top_k=top_k,
-                                       norm_topk_prob=norm_topk_prob),
-                              aux_losses=aux)
+                                       norm_topk_prob=norm_topk_prob, held=experts_held,
+                                       router_seed=routing_seed and routing_seed + 1 + i),
+                              aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
+                              attention_mask=attention_mask)
+        feeds = {"ids": ids, "labels": labels, "pos_ids": pos_ids}
+        if loss_positions:  # the rest of the positions are context: no logits of theirs are used
+            x = layers.slice(x, axes=[1], starts=[0], ends=[loss_positions])
         x = layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
                             param_attr=_attr_ones("lm.final_norm.w"))
         logits = layers.fc(x, vocab_size, num_flatten_dims=2,
                            param_attr=_attr("lm.head.w"), bias_attr=False)
-        ce = layers.mean(layers.softmax_with_cross_entropy(
-            layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1])))
+        ce = layers.softmax_with_cross_entropy(
+            layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1]))
+        if loss_positions:
+            feeds["loss_weight"] = layers.data("loss_weight", [loss_positions], dtype="float32")
+            ce = layers.elementwise_mul(ce, layers.reshape(feeds["loss_weight"], [-1, 1]))
+        ce = layers.mean(ce)
         balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / n_layers)
         z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / n_layers)
-        loss = layers.sums([ce, layers.scale(balance, scale=load_balance_coef),
-                            layers.scale(z_loss, scale=router_z_coef)])
+        terms = [ce] + [layers.scale(term, scale=coef) for term, coef in
+                        ((balance, load_balance_coef), (z_loss, router_z_coef)) if coef]
+        loss = layers.sums(terms) if len(terms) > 1 else ce
         if with_optimizer:
             optimizer.Adam(learning_rate=learning_rate, beta1=beta1, beta2=beta2,
                            epsilon=epsilon).minimize(loss)
-    return (main, startup, {"ids": ids, "labels": labels, "pos_ids": pos_ids},
+    return (main, startup, feeds,
             {"loss": loss, "ce": ce, "load_balance": balance, "router_z": z_loss,
              "logits": logits})
 

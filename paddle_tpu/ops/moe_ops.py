@@ -32,6 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import analysis as _A
 from ..core import resource_plan as _RP
@@ -255,7 +256,13 @@ def _moe_experts(ctx, op, ins):
     sum_k Wdown(p_k h_k): float32 into the one rounding of `hidden`), and
     the down product's rows are summed back per token in float32.  No
     capacity, so no dropped token, however skewed the router: `Dropped` is
-    the number of rows the group sizes do not cover, 0 by construction."""
+    the number of rows the group sizes do not cover, 0 by construction.
+
+    With the attribute `held` = (first, count) the three matrices are those
+    of `count` experts from `first` on, and what the absent experts would
+    have added is left out (`_held_experts`): `Held` is then the number of
+    assignments that fell on held experts, `Dropped` those of them no pass
+    covered, 0 by construction.  Without it, every expert: today's layer."""
     x = first(ins, "X")
     top_p = first(ins, "TopKProb")
     top_i = first(ins, "TopKIndex")
@@ -264,6 +271,12 @@ def _moe_experts(ctx, op, ins):
     d, k = x.shape[-1], top_i.shape[-1]
     x2 = x.reshape(-1, d)
     tokens = x2.shape[0]
+    held = op.attr("held", None)
+    if held is not None:
+        out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load,
+                                            (w_gate, w_up, w_down), tuple(held), ctx.platform)
+        return {"Out": out.reshape(x.shape), "Dropped": missed.astype(jnp.int32).reshape((1,)),
+                "Held": n_held.astype(jnp.int32).reshape((1,))}
     order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
     rows = _rows_by_expert(x2, order, inverse, k)
@@ -278,6 +291,144 @@ def _moe_experts(ctx, op, ins):
             "Dropped": (tokens * k - jnp.sum(load)).astype(jnp.int32).reshape((1,))}
 
 
+# -- a layer that holds a share of its experts ---------------------------------
+#
+# `held = (first, count)`: the three stacked matrices are those of experts
+# first .. first + count - 1 only (one chip's share of a layer split over
+# several); the router still decides over all its outputs.  Assignments to
+# absent experts are never rows: the (token, slot) assignments are sorted by
+# LOCAL expert with the absent ones last, and the row operations and grouped
+# products pass over the first `_held_rows_bound` of them, twice the share a
+# uniform router gives this chip.  What lies past the bound is a second, rarer
+# lowering in the same program (`jax.lax.cond`): the same chunk, checkpointed,
+# scanned over the rest of the order, so that no assignment to a held expert
+# is ever left out however skewed the router, and the common step neither runs
+# nor keeps anything of it.
+
+#: The bound as a multiple of the uniform share.  With weights N(0, 0.02) the
+#: masked positions of a block-diffusion batch (a quarter of all positions)
+#: carry one embedding and choose alike in the first layer: where two of
+#: their eight experts are held this chip's share is 16% against the uniform
+#: 12.5%, where five are it is 25%; a bound under the share costs the step
+#: the rare path's recomputation, one over it costs gathers over empty rows.
+_HELD_ROWS_SLACK = 2.0
+
+
+def _held_rows_bound(assignments, count, num_experts):
+    """Rows of one pass over the held assignments: `_HELD_ROWS_SLACK` x the
+    uniform share, a whole number of the kernels' row tiles, at most all."""
+    tile = _GMM_TILE[0] if assignments >= _GMM_TILE[0] else 128
+    want = int(np.ceil(_HELD_ROWS_SLACK * assignments * count / num_experts))
+    return min(-(-want // tile) * tile, -(-assignments // tile) * tile)
+
+
+@jax.custom_vjp
+def _sort_by_key(key, values):
+    """(the stable permutation that sorts `key`, `values` in that order) from
+    one sort; the transpose sorts back by the permutation, for a gather or a
+    scatter of scalars runs element by element on the chip (`_permute_scalars`)."""
+    index = jax.lax.iota(jnp.int32, key.shape[0])
+    _, order, ordered = jax.lax.sort((key, index, values), num_keys=1, is_stable=True)
+    return order, ordered
+
+
+def _sort_by_key_fwd(key, values):
+    out = _sort_by_key(key, values)
+    return out, out[0]
+
+
+_sort_by_key.defvjp(
+    _sort_by_key_fwd,
+    lambda order, g: (None, jax.lax.sort((order, g[1]), num_keys=1, is_stable=False)[1]))
+
+
+# The held path's two row operations, each the other's transpose, as
+# `_rows_by_expert` and `_sum_by_token` are.  `token` [C] is the token of each
+# row of a chunk, `target` the same with the rows no held expert owns sent
+# past the last token, where a scatter drops them.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_tokens(x, token, target, tokens):
+    """Tokens [T, d] -> the chunk's rows [C, d]."""
+    return _take_rows(x, token)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _add_to_tokens(rows, token, target, tokens):
+    """The chunk's rows [C, d] -> tokens [T, d]: each token's rows summed,
+    rows of no held expert dropped.  A token has one such row on average and
+    eight at the most, and they are summed in the rows' dtype: a float32 copy
+    of the rows for the scatter to read is 268 MB at SDAR's cell."""
+    return jnp.zeros((tokens, rows.shape[-1]), rows.dtype).at[target].add(rows, mode="drop")
+
+
+_rows_of_tokens.defvjp(
+    lambda x, token, target, tokens: (_take_rows(x, token), (token, target)),
+    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None))
+_add_to_tokens.defvjp(
+    lambda rows, token, target, tokens: (_add_to_tokens(rows, token, target, tokens), (token, target)),
+    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None))
+
+
+def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
+    """`moe_experts` over the experts `held` = (first, count): (the tokens'
+    output [T, d] in x2's dtype, the assignments that fell on held experts,
+    those of them no pass covered)."""
+    first, count = held
+    tokens, k = top_i.shape
+    assignments = tokens * k
+    bound = _held_rows_bound(assignments, count, load.shape[0])
+    chunks = -(-assignments // bound)
+    expert = top_i.reshape(-1)
+    local = jnp.where((expert >= first) & (expert < first + count), expert - first, count)
+    order, weight = _sort_by_key(local.astype(jnp.int32), top_p.reshape(-1).astype(jnp.float32))
+    pad = chunks * bound - assignments   # rows past the last assignment belong to no token
+    order = jnp.pad(order, (0, pad), constant_values=assignments)
+    weight = jnp.pad(weight, (0, pad))
+    ends = jnp.cumsum(load[first:first + count])
+    n_held = ends[-1]
+
+    def chunk(x2, weight, matrices, c):
+        """Rows [c . bound, (c + 1) . bound) of the order, as tokens' sums."""
+        lo = c * bound
+        rank = lo + jax.lax.iota(jnp.int32, bound)
+        mine = jax.lax.dynamic_slice(order, (lo,), (bound,))
+        token = jnp.minimum(mine // k, tokens - 1)
+        valid = rank < n_held
+        target = jnp.where(valid, token, tokens)
+        sizes = jnp.clip(ends, lo, lo + bound) - jnp.clip(ends - load[first:first + count], lo, lo + bound)
+        w_gate, w_up, w_down = matrices
+        rows = _rows_of_tokens(x2, token, target, tokens)
+        # a row no group covers comes out of the kernels as it lay in memory
+        keep = valid[:, None]
+        gate = checkpoint_name(grouped_matmul(rows, w_gate, sizes, platform), "expert_gate")
+        up = checkpoint_name(grouped_matmul(rows, w_up, sizes, platform), "expert_up")
+        gate, up = (jnp.where(keep, t, 0).astype(jnp.float32) for t in (gate, up))
+        w = jax.lax.dynamic_slice(weight, (lo,), (bound,))[:, None]
+        hidden = jnp.where(keep, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
+        down = grouped_matmul(hidden, w_down, sizes, platform)
+        return _add_to_tokens(down, token, target, tokens)
+
+    # the common pass keeps the two products' outputs for the backward pass and
+    # makes the rest again there (a gather, the masters' casts, one elementwise
+    # pass): 335 MB a layer at SDAR's cell that no step has to hold
+    out = jax.checkpoint(chunk, static_argnums=(3,), policy=jax.checkpoint_policies.save_only_these_names(
+        "expert_gate", "expert_up"))(x2, weight, matrices, 0)
+    covered = jnp.minimum(n_held, bound)
+    if chunks > 1:
+        def the_rest(x2, weight, matrices):
+            def step(acc, c):
+                return acc + jax.checkpoint(chunk)(x2, weight, matrices, c), None
+            return jax.lax.scan(step, jnp.zeros_like(out), jnp.arange(1, chunks, dtype=jnp.int32))[0]
+
+        out = out + jax.lax.cond(n_held > bound, the_rest,
+                                 lambda x2, weight, matrices: jnp.zeros_like(out),
+                                 x2, weight, matrices)
+        covered = n_held   # the rare path passes over every chunk there is
+    return out, n_held, n_held - covered
+
+
+
 def _publish_routing(step, values):
     """One logged step's routing statistics: per layer the busiest and the
     idlest expert's tokens over the mean (1.0 is perfect balance), the worst
@@ -289,12 +440,19 @@ def _publish_routing(step, values):
     _MON.gauge("moe.load_max_over_mean").set(max(most))
     _MON.gauge("moe.load_min_over_mean").set(min(least))
     _MON.gauge("moe.dropped_tokens").set(lost)
-    _MON.record_step({"kind": "moe_routing", "pipeline_step": step,
-                      "load_max_over_mean": most, "load_min_over_mean": least,
-                      "dropped_tokens": lost})
+    record = {"kind": "moe_routing", "pipeline_step": step,
+              "load_max_over_mean": most, "load_min_over_mean": least,
+              "dropped_tokens": lost}
+    if values.get("Held"):
+        # layers that hold a share of their experts: the share of the step's
+        # (token, slot) assignments that landed on them, per layer
+        record["held_rows_share"] = [float(np.asarray(h).sum() / v.sum())
+                                     for h, v in zip(values["Held"], loads)]
+        _MON.gauge("moe.held_rows_share").set(max(record["held_rows_share"]))
+    _MON.record_step(record)
 
 
-set_step_stats("moe_experts", ("Load", "Dropped"), _publish_routing)
+set_step_stats("moe_experts", ("Load", "Dropped", "Held"), _publish_routing)
 
 
 # -- build-time shape and dtype rules -----------------------------------------
@@ -348,7 +506,14 @@ def _infer_moe_experts(ctx):
         ctx.fail(f"experts must be WGate, WUp (E, {xs[-1]}, F) and WDown "
                  f"(E, F, {xs[-1]}), got {gate}, {up}, {down}")
     load = ctx.in_shape("Load")
-    if load is not None and tuple(load) != (gate[0],):
+    held = ctx.op.attr("held", None)
+    if held is not None:
+        first, count = held
+        if count != gate[0] or first < 0 or (load is not None and first + count > load[0]):
+            ctx.fail(f"held = {tuple(held)}: the matrices hold {gate[0]} experts and the "
+                     f"router decides over {None if load is None else load[0]}")
+        ctx.set_out("Held", (1,), "int32")
+    elif load is not None and tuple(load) != (gate[0],):
         ctx.fail(f"Load must hold one count for each of {gate[0]} experts, got {load}")
     ctx.set_out("Out", xs, ctx.in_dtype("X"))
     ctx.set_out("Dropped", (1,), "int32")
@@ -389,8 +554,15 @@ def _cost_moe_experts(ctx):
     if gate is None or ctx.in_shape("TopKIndex") is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()
     rows, d, f = ctx.in_elems("TopKIndex"), gate[1], gate[2]
+    held, load = ctx.op.attr("held", None), ctx.in_shape("Load")
+    if held is not None and load is not None:
+        # the passes are over the bound; the arithmetic is the uniform share's
+        passes = _held_rows_bound(rows, held[1], load[0])
+        rows = rows * held[1] // load[0]
+    else:
+        passes = rows
     item = 2 if ctx.env.dtype(ctx.in_name("X")) in ("bfloat16", "float16") else 4
-    moved = rows * (_ROW_PASSES["hidden"] * d + _ROW_PASSES["width"] * f) * item
+    moved = passes * (_ROW_PASSES["hidden"] * d + _ROW_PASSES["width"] * f) * item
     return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
 
 

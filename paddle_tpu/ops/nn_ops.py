@@ -522,14 +522,23 @@ _ROW_KERNEL_HEAD_DIM = 64
 _ROW_KERNEL_SEQ_MULTIPLE = 128
 
 
-def _attention_path(platform, mesh, q, k):
-    """Which attention `fused_attention` lowers to: "flash", "row_kernel" or
-    "xla".  Off the TPU always "xla".  A short query against long keys (a
-    decoding step) has no score block worth keeping out of HBM, hence BOTH
-    lengths in the row kernel's rule."""
+def _attention_path(platform, mesh, q, k, mask=None):
+    """Which attention `fused_attention` lowers to: "flash", "row_kernel",
+    "block_sparse" or "xla".  Off the TPU always "xla".  A short query against
+    long keys (a decoding step) has no score block worth keeping out of HBM,
+    hence BOTH lengths in the row kernel's rule.  Under a structured `mask`
+    (`_structured_mask`) the block-sparse kernel or, as for the row kernel,
+    XLA's attention where a custom call cannot be partitioned or the lengths
+    are no whole number of its blocks; never the other two kernels, which know
+    no mask but a causal one."""
     if platform != "tpu":
         return "xla"
     q_len, kv_len = q.shape[2], k.shape[2]
+    if mask is not None:
+        from .masked_attention import kernel_block
+
+        whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
+        return "block_sparse" if whole and (mesh is None or mesh.size == 1) else "xla"
     if kv_len >= _FLASH_MIN_SEQ and q_len >= _FLASH_MIN_QUERIES:
         return "flash"
     if mesh is not None and mesh.size > 1:
@@ -575,23 +584,51 @@ def _flash_attention_tpu(q, k, v, bias, causal, scale):
     return out.astype(q.dtype)
 
 
+def _structured_mask(op, q, k):
+    """The op's mask where it is a rule over positions: (kind, block length),
+    or None.  It is part of the mathematics, as `causal` is, and not a choice
+    among lowerings of one mathematics."""
+    kind = op.attr("mask", None)
+    if kind is None:
+        return None
+    from .masked_attention import MASKS
+
+    block = op.attr("mask_block", None)
+    positions = q.shape[2]
+    if kind not in MASKS or not block or k.shape[2] != positions or positions % (2 * block):
+        raise ValueError(f"fused_attention: mask {kind!r} with mask_block {block} over {positions} queries "
+                         f"and {k.shape[2]} keys; known masks {MASKS}, over 2L positions in blocks that divide L")
+    return kind, int(block)
+
+
 @register_op("fused_attention")
 def _fused_attention(ctx, op, ins):
     """Scaled-dot-product attention over (B, H, L, dh): softmax(q k^T * scale
     + bias, causal mask) v, with the operands in their own dtype on the MXU,
     float32 accumulation, float32 scores and softmax, and the probabilities
     rounded to the activations' dtype for the product with v.  One
-    mathematics, three tilings, chosen by `_attention_path` and counted in
-    `lowering.attention_flash|row_kernel|xla`:
+    mathematics, four tilings, chosen by `_attention_path` and counted in
+    `lowering.attention_flash|row_kernel|block_sparse|xla`:
 
     * `flash`: the stock Pallas online-softmax kernel, from `_FLASH_MIN_SEQ`
       keys on;
     * `row_kernel`: `ops/pallas_attention.py:fused_sdpa`, queries and keys
       both in [`_ROW_KERNEL_MIN_SEQ`, `_ROW_KERNEL_MAX_SEQ`]: a whole row of
       scores lives in VMEM, forward and backward;
+    * `block_sparse`: under a structured mask (the attributes `mask` and
+      `mask_block`: a rule over positions, `ops/masked_attention.py`), the
+      stock splash-attention kernel with the rule as its mask: blocks the
+      rule empties are skipped, forward and backward, the blocks it cuts read
+      the few distinct cut blocks, and no mask or score of the whole square
+      is in HBM;
     * `xla`: two einsums round `jax.nn.softmax`, the scores in HBM:
       everything else on the TPU, and every other platform (CPU tests and
-      virtual meshes compute the same function, so goldens transfer).
+      virtual meshes compute the same function, so goldens transfer); a
+      structured mask is built densely here from the same rule.
+
+    K and V may have fewer heads than Q (a divisor): query head j reads
+    key/value head j div (Hq / Hkv).  The block-sparse kernel reads them so;
+    the other three are given K and V repeated at their edge.
 
     Under a mesh of more than one device the row kernel is NOT taken: a
     `pallas_call` is a custom call that GSPMD cannot partition, and the XLA
@@ -607,8 +644,15 @@ def _fused_attention(ctx, op, ins):
     scale = op.attr("scale", None)
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    path = _attention_path(ctx.platform, ctx.mesh, q, k)
+    mask = _structured_mask(op, q, k)
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask)
     _MON.counter(f"lowering.attention_{path}").inc()
+    if path == "block_sparse":
+        from .masked_attention import block_sparse_attention
+
+        return {"Out": block_sparse_attention(q, k, v, mask[1], float(scale))}
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
     if path == "flash":
         return {"Out": _flash_attention_tpu(q, k, v, bias, causal, scale)}
     if path == "row_kernel":
@@ -623,8 +667,12 @@ def _fused_attention(ctx, op, ins):
         s = s + bias.astype(jnp.float32)
     if causal:
         Lq, Lk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)
-        s = jnp.where(mask, s, -1e30)
+        s = jnp.where(jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq), s, -1e30)
+    if mask is not None:
+        from .masked_attention import block_diffusion_allowed
+
+        at = jnp.arange(s.shape[-1], dtype=jnp.int32)
+        s = jnp.where(block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1]), s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -1397,7 +1445,12 @@ def _cost_fused_attention(ctx):
         return float(ctx.out_elems_total()), ctx.io_bytes()
     b, h, lq, dh = qs[0], qs[1], qs[2], qs[3]
     lk = ks[2]
-    return 4.0 * _elems_xs((b, h, lq, lk, dh)), ctx.io_bytes()
+    pairs = _elems_xs((lq, lk))
+    if ctx.op.attr("mask", None) is not None and ctx.op.attr("mask_block", None):
+        from .masked_attention import allowed_pairs
+
+        pairs = allowed_pairs(lq, ctx.op.attr("mask_block"))  # the pairs the rule allows
+    return 4.0 * _elems_xs((b, h, dh)) * pairs, ctx.io_bytes()
 
 
 _RP.register_cost(["fused_attention"], _cost_fused_attention)

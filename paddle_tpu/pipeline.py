@@ -80,8 +80,12 @@ def _step_stats(program):
         slots, publish = op_def.step_stats
         names = found.setdefault(op.type, (publish, {slot: [] for slot in slots}))[1]
         for slot in slots:
-            names[slot].append((op.inputs.get(slot) or op.outputs[slot])[0])
-    return list(found.values())
+            name = op.inputs.get(slot) or op.outputs.get(slot)
+            if name:
+                names[slot].append(name[0])
+    # a slot no op of the type has (a layer that holds every expert has no `Held`) is not fetched
+    return [(publish, {slot: names for slot, names in by_slot.items() if names})
+            for publish, by_slot in found.values()]
 
 
 def train_loop(

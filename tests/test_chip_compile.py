@@ -75,6 +75,12 @@ def _flash(causal):
     return run
 
 
+def _block_sparse(q, k, v):
+    from paddle_tpu.ops.masked_attention import block_sparse_attention
+
+    return block_sparse_attention(q, k, v, 4, q.shape[-1] ** -0.5)
+
+
 def _gmm(rows, weights, sizes):
     from paddle_tpu.ops.moe_ops import grouped_matmul
 
@@ -178,6 +184,13 @@ CASES = {
         _gmm, [((131072, 1024), BF16), ((64, 1024, 2048), F32), ((64,), I32)], (0, 1)),
     "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
+    # SDAR-30B-A3B-Chat's cell: 2 sequences of 8192 positions [noised ; clean], 32 query heads on
+    # 4 key/value heads of 128, under the block-diffusion mask: the stock splash kernel with the
+    # mask's rule (ops/masked_attention.py), forward, dq and dkv
+    "block_sparse_attention_sdar": (
+        _block_sparse, [((2, 32, 8192, 128), BF16)] + [((2, 4, 8192, 128), BF16)] * 2, (0, 1, 2)),
+    "block_sparse_attention_128_blocks": (  # a length that is whole in the small block only
+        _block_sparse, [((1, 8, 1280, 128), BF16)] + [((1, 8, 1280, 128), BF16)] * 2, (0, 1, 2)),
 }
 
 
@@ -284,6 +297,59 @@ def test_moe_experts_cost_row_counts_the_passes_of_the_compiled_forward(chip):
         passes.update(int(n) for t in [result] + [types.get(o, "") for o in operands]
                       for n in re.findall(of_rows, t))
     assert passes == {hidden: _ROW_PASSES["hidden"], width: _ROW_PASSES["width"]}, passes
+
+
+#: SDAR-30B-A3B-Chat's layer of experts over 2 x 8192 positions with 16 of its 128 experts held:
+#: tokens, hidden, width, router outputs, experts a token, experts held
+SDAR_EXPERTS = (2 * 8192, 2048, 768, 128, 8, 16)
+
+
+def test_no_square_of_the_positions_is_in_the_compiled_attention(chip):
+    """Forward and backward at the cell's shape: no array with 8192 x 8192
+    elements, mask or scores, in any computation of the compiled program, and
+    the three kernel calls under the lowering's scope, where the benchmark's
+    `attention_roofline_share` finds them."""
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in ((2, 32, 8192, 128), (2, 4, 8192, 128), (2, 4, 8192, 128))]
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(_block_sparse(*a).astype(F32)), argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert not re.findall(r"\[[\d,]*8192,8192\]", text)
+    assert text.count("tpu_custom_call") == 3
+    under_the_scope = re.findall(r'op_name="[^"]*block_sparse_attention[^"]*splash_mha_(fwd|dq|dkv)[^"]*/pallas_call"', text)
+    assert set(under_the_scope) == {"fwd", "dq", "dkv"}
+
+
+def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(chip):
+    """16 of 128 experts held at 16384 positions: the 131072 (token, slot)
+    assignments exist as vectors only (the sort's keys, order and weights);
+    every two-dimensional array of rows, in the common pass and in the rare
+    path's loop alike, has the bound's 32768 rows (twice the uniform share:
+    `ops.moe_ops._held_rows_bound`) or the tokens' 16384, forward and backward."""
+    from paddle_tpu.ops.moe_ops import _held_rows_bound
+
+    tokens, hidden, width, experts, k, held = SDAR_EXPERTS
+    bound = _held_rows_bound(tokens * k, held, experts)
+    assert bound == 32768
+
+    def moe(x, top_p, top_i, load, w_gate, w_up, w_down):
+        from paddle_tpu.core.lowering import LoweringContext
+        from paddle_tpu.core.registry import get_op_def
+
+        op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: {"held": [0, held]}.get(name, default))
+        ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load],
+               "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
+        return get_op_def("moe_experts").lower(LoweringContext(jax.random.PRNGKey(0), platform="tpu"), op, ins)["Out"]
+
+    specs = [((tokens, hidden), BF16), ((tokens, k), F32), ((tokens, k), I32), ((experts,), I32),
+             ((held, hidden, width), F32), ((held, hidden, width), F32), ((held, width, hidden), F32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
+    program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(moe(*a).astype(F32))), argnums=(0, 1, 4, 5, 6))
+    compiled = jax.jit(program).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 9
+    rows_of = {int(n) for n in re.findall(r"= \w+\[(\d+),(?:%d|%d)\]" % (hidden, width), text)}
+    assert max(rows_of) == bound, rows_of
+    assert not re.findall(r"\[%d,\d+" % (tokens * k), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [

@@ -1,0 +1,114 @@
+"""On the chip: the block-sparse attention of `fused_attention` under the
+block-diffusion mask (ops/masked_attention.py) priced at SDAR's cell:
+(2, 32, 8192, 128) queries over (2, 4, 8192, 128) keys and values, bf16, block
+length 4, forward + backward, by the grid's block; the same mask computed in
+the kernel from positions instead of read from its distinct cut blocks; the
+backward pass as one kernel instead of two; and, for the record, the
+stock flash kernel and the splash kernel at OLMoE's causal (4, 16, 4096, 128).
+
+    chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PR 32)
+
+A microbenchmark: a time here is a kernel's alone, not the cell's.
+"""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
+
+from paddle_tpu.ops import masked_attention as ma
+from paddle_tpu.ops.nn_ops import _flash_attention_tpu
+
+DRY = os.environ.get("DRY") == "1"  # a rehearsal on the CPU: interpreted, tiny, no time printed
+assert DRY or jax.devices()[0].platform == "tpu", jax.devices()
+POSITIONS, BLOCK = (512, 4) if DRY else (8192, 4)
+Q, KV = ((1, 4, POSITIONS, 128), (1, 2, POSITIONS, 128)) if DRY else ((2, 32, 8192, 128), (2, 4, 8192, 128))
+
+
+def ms(fn, *args, runs=5):
+    """Forward + backward of sum(fn), the median of `runs` after one that compiles."""
+    step = jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 1, 2)))
+    jax.block_until_ready(step(*args))
+    times = []
+    for _ in range(runs):
+        t = time.perf_counter()
+        jax.block_until_ready(step(*args))
+        times.append(1e3 * (time.perf_counter() - t))
+    return float(np.median(times))
+
+
+def operands(q_shape, kv_shape, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return [jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(keys, (q_shape, kv_shape, kv_shape))]
+
+
+def splash_with(mask, heads, sizes):
+    kernel = splash.make_splash_mha(mask_lib.MultiHeadMask([mask] * heads), block_sizes=sizes,
+                                    head_shards=1, q_seq_shards=1, interpret=DRY)
+    return lambda q, k, v: jax.vmap(kernel)(q * (q.shape[-1] ** -0.5), k, v)
+
+
+def report(what, **fields):
+    print(json.dumps({"what": what, "device": jax.devices()[0].device_kind, **fields}), flush=True)
+
+
+def try_ms(fn, *args):
+    try:
+        took = ms(fn, *args)
+        return None if DRY else took
+    except Exception as e:  # a block that overruns the scoped VMEM is a finding, not a failure
+        return f"{type(e).__name__}: {str(e)[:200]}"
+
+
+def sizes_of(bq, bkv, compute=None, fused=False):
+    backward = {} if fused else dict(block_q_dq=bq, block_kv_dq=bkv)
+    return splash.BlockSizes(block_q=bq, block_kv=bkv, block_kv_compute=compute or bkv, block_q_dkv=bq, block_kv_dkv=bkv,
+                             block_kv_dkv_compute=compute or bkv, use_fused_bwd_kernel=fused, **backward)
+
+
+q, k, v = operands(Q, KV)
+scale = Q[-1] ** -0.5
+
+
+class Computed(mask_lib._ComputableMask):
+    """The rule computed inside the kernel from the positions, for its price."""
+
+    def __init__(self):
+        super().__init__(shape=(POSITIONS, POSITIONS),
+                         mask_function=lambda q, kv: ma.block_diffusion_allowed(q, kv, POSITIONS // 2, BLOCK))
+
+    def __eq__(self, other):
+        return isinstance(other, type(self))
+
+    def __hash__(self):
+        return hash(type(self).__name__)
+
+
+stored = ma._mask(POSITIONS, BLOCK)
+report("block_sparse_attention", ms=try_ms(   # as `fused_attention` calls it
+    lambda q, k, v: ma.block_sparse_attention(q, k, v, BLOCK, scale, interpret=DRY), q, k, v))
+if os.environ.get("QUICK") == "1":
+    sys.exit(0)
+for b in ((128,) if DRY else (256, 512, 1024)):
+    report("two_backward_kernels", grid_block=b, ms=try_ms(splash_with(stored, Q[1], sizes_of(b, b)), q, k, v))
+    report("the_mask_computed_in_the_kernel", grid_block=b, ms=try_ms(splash_with(Computed(), Q[1], sizes_of(b, b)), q, k, v))
+if not DRY:
+    for bq, bkv, compute in ((512, 1024, 512), (1024, 512, 512), (1024, 1024, 512), (1024, 2048, 1024), (2048, 1024, 1024)):
+        report("block_sparse_attention_mixed", block_q=bq, block_kv=bkv, block_kv_compute=compute,
+               ms=try_ms(splash_with(stored, Q[1], sizes_of(bq, bkv, compute)), q, k, v))
+    for b in (512, 1024):
+        report("block_sparse_attention_fused_backward", grid_block=b,
+               ms=try_ms(splash_with(stored, Q[1], sizes_of(b, b, fused=True)), q, k, v))
+    # OLMoE's causal attention, for the follow-up PERF.md notes: the flash kernel it runs, and this one
+    oq, ok_, ov = operands((4, 16, 4096, 128), (4, 16, 4096, 128), seed=1)
+    report("flash_causal_olmoe", ms=try_ms(lambda q, k, v: _flash_attention_tpu(q, k, v, None, True, scale), oq, ok_, ov))
+    for b in (512, 1024):
+        report("splash_causal_olmoe", grid_block=b,
+               ms=try_ms(splash_with(mask_lib.CausalMask((4096, 4096)), 16, sizes_of(b, b)), oq, ok_, ov))
