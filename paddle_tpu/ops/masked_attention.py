@@ -14,33 +14,79 @@ and nothing else: a clean query never sees a noised key.  A quarter of the
 
 `block_diffusion_allowed` is the rule, over numpy or jax integers: the XLA
 attention of `fused_attention` builds the dense mask from it (tiny sizes: the
-CPU tests and goldens), and `block_sparse_attention` hands it to the stock
-splash-attention kernel (jax.experimental.pallas.ops.tpu.splash_attention) as
-a mask it can ask for any block of: the kernel's block map is made from the
-rule at trace time (numpy, a block of the grid at a time), blocks the rule
-empties are never visited nor their keys fetched, blocks it fills skip the
-mask, and the blocks it cuts read theirs from the few DISTINCT cut blocks,
-which are all of the mask that is kept on the device (three at SDAR's cell: one
-a quadrant's diagonal, 3 MB as int8).  No [2L, 2L] array exists on the device,
-forward or backward, nor on the host.  Computing the cut blocks' mask from the
-positions inside the kernel instead (the kernel's "computable" masks) was
-priced and lost by 19 ms a layer (`_BLOCKS`' table).  Key/value heads may be
-fewer than query heads: the kernel's index maps read key/value head
-j div (Hq / Hkv), nothing is repeated.
+CPU tests and goldens), and `block_sparse_attention` splits it into the term
+that has block structure and the one that has none:
+
+* the FAR term, all 2L queries against the L clean keys, through the stock
+  splash-attention kernels (jax.experimental.pallas.ops.tpu.splash_attention;
+  forward, dq and dkv) under the rule's `[2L, L]` rectangle as a mask they can
+  ask for any block of.  The block maps are made from the rule at trace time
+  (numpy, a block of the grid at a time): blocks the rule empties are never
+  visited nor their keys fetched, blocks it fills skip the mask, and the
+  blocks it cuts read theirs from the few DISTINCT cut blocks, which are all
+  of the mask that is kept on the device.  At SDAR's cell 20 of the
+  rectangle's 32 1024-blocks are visited, 12 of them whole and 8 cut (two
+  distinct ones); the noised half's keys and values enter no stock kernel.
+* the NEAR term, every noised query against the B noised keys of its own
+  block.  In the stock kernels' grid it is the diagonal of the noised
+  quadrant: a sixth of every kernel's visited blocks, each B / 1024 full,
+  for 0.1% of the allowed pairs.  No product of B x dh by dh x B fills the
+  MXU and plain jax writes 128-wide float32 score tiles to HBM for the 4 in
+  128 it needs (5.4 ms a layer, which gave back the 4.9 the far term won: my
+  chip runs, PR 33), so two kernels of this module compute it a 128-tile of
+  the diagonal at a time in VMEM (`_join_kernel`, `_own_block_backward_kernel`).
+* JOINED exactly by the log-sum-exp: lse = logaddexp(lse_f, lse_n), out =
+  exp(lse_f - lse) out_f + exp(lse_n - lse) out_n in float32, in place over
+  the far term's output (the clean rows pass through).  The first noised
+  block's queries have no far key: the stock kernel leaves them a log-sum-exp
+  of its `mask_value` -2.38e38 under a finite output, so their far weight is
+  exactly 0.  The far term's output leaves its kernel rounded to the
+  operands' dtype, so a noised row carries that rounding and the join's.
+* BACKWARD as one `custom_vjp` over the whole op: di = rowsum(do . out) from
+  the joined output, the far term's dq, dk, dv from the stock dq and dkv
+  kernels given the joined `lse` and `di`, the near term's by the same
+  formulas, added to the noised rows of dq in place.
+
+The split is taken where the rule's block is smaller than the kernels' and
+whole blocks of the rule make up a lane tile (`plan_of`: every block length
+BD3-LM or SDAR uses); a rule's block as large as the kernels' keeps one call
+over the whole square, which is the far term over all keys and no near term.
+No [2L, 2L] array exists on the device, forward or backward, nor on the host,
+and no float32 [2L, L] one.  Computing the cut blocks' mask from the positions
+inside the kernel instead (the kernel's "computable" masks) was priced and
+lost by 19 ms a layer (`_BLOCKS`' table).  Key/value heads may be fewer than
+query heads: the index maps read key/value head j div (Hq / Hkv), nothing is
+repeated.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..monitor import MONITOR as _MON
 
 MASKS = ("block_diffusion",)
 
 #: Queries and keys a block of the kernels' grids, the largest that divides the
 #: length, forward and both backward kernels, and the keys a step inside a
 #: block (`_KV_COMPUTE`).  TPU v5e, (2, 32, 8192, 128) queries over
-#: (2, 4, 8192, 128) keys and values, bf16, block length 4, forward + backward
-#: of one layer, ms (my chip runs, PR 32; tools/chip_block_attention.py):
+#: (2, 4, 8192, 128) keys and values, bf16, forward + backward of one layer, ms
+#: (my chip runs; tools/chip_block_attention.py).  The whole square against
+#: the clean keys with the own-block term joined, by the rule's block (PR 33):
+#:
+#:   rule's block           4       16      128     256
+#:   whole square           28.17   28.39   28.22   28.30   (24 of 64 1024-blocks, 12 cut at 4)
+#:   far + near             24.60   24.70   24.64   24.75   (20 of 32, 8 cut; the two forms 6e-3 apart
+#:                                                           at the most, output and gradients)
+#:
+#: no crossing below the kernels' block, so `plan_of` holds no constant.  The
+#: whole square at a rule's block of 4, by the kernels' block (PR 32):
 #:
 #:   block                  256     512     1024    1024, 512 keys a step   2048 with 1024
 #:   two backward kernels   60.99   30.51   28.29   27.44                   scoped VMEM overrun
@@ -52,11 +98,13 @@ MASKS = ("block_diffusion",)
 #: fewer, larger blocks win although more of what they hold is masked.  The
 #: fused backward (dq, dk and dv from one pass over the scores) is the fastest
 #: alone and is NOT taken: it writes dq once per block of keys, 1.07 GB a layer
-#: at 1024 and 2.15 GB at 512, and inside the cell's step its 1024-block kernel
-#: overruns the scoped VMEM (19.1 of 16 MB) that it fits alone.  So does the dq
-#: kernel at 1024 x 1024 (16.47 MB: compiled here for the described v5e, the
-#: whole step; alone it fits), which therefore takes `_KV_COMPUTE` queries a
-#: block against 1024 keys.
+#: at 1024 and 2.15 GB at 512 (half that over the clean keys alone), and inside
+#: the cell's step its 1024-block kernel overruns the scoped VMEM (19.1 of 16
+#: MB) that it fits alone.  So does the dq kernel at 1024 x 1024 (16.47 MB:
+#: compiled here for the described v5e, the whole step; alone it fits), which
+#: therefore takes `_KV_COMPUTE` queries a block against 1024 keys; and the
+#: forward kernel over the whole square where its queries are a parameter of
+#: the program and not the scaling's result (16.77 MB: PR 33).
 _BLOCKS = (1024, 512, 128)
 _KV_COMPUTE = 512
 
@@ -86,44 +134,282 @@ def kernel_block(q_len: int):
     return next((b for b in _BLOCKS if q_len % b == 0), None)
 
 
-def _mask(positions: int, block: int):
+def _rule_mask(positions: int, first_key: int, block: int):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
 
     class BlockDiffusionMask(mask_lib.Mask):
-        """The rule as a mask the kernel's block map can slice: any
-        [queries, keys] window of it, computed when asked for."""
+        """The rule over all 2L queries and the keys from `first_key` on, as a
+        mask the kernel's block map can slice: any [queries, keys] window of
+        it, computed when asked for."""
 
-        shape = (positions, positions)
+        shape = (positions, positions - first_key)
 
         def __getitem__(self, idx):
-            q, kv = (np.arange(s.start or 0, positions if s.stop is None else s.stop) for s in idx)
-            return block_diffusion_allowed(q[:, None], kv[None, :], positions // 2, block)
+            q, kv = (np.arange(s.start or 0, n if s.stop is None else s.stop) for s, n in zip(idx, self.shape))
+            return block_diffusion_allowed(q[:, None], first_key + kv[None, :], positions // 2, block)
 
         def __eq__(self, other):
             return isinstance(other, type(self)) and self.shape == other.shape
 
         def __hash__(self):
-            return hash((type(self).__name__, positions, block))
+            return hash((type(self).__name__, positions, first_key, block))
 
     return BlockDiffusionMask()
+
+
+class Plan(NamedTuple):
+    """What one attention's kernels are built from, all of it read off the
+    shapes and the rule's block."""
+    positions: int
+    heads: int
+    mask_block: int
+    block: int       # of the kernels' grids
+    first_key: int   # L where the own-block term is split off the kernels, else 0
+    interpret: bool
+
+    @property
+    def tile(self) -> int:
+        """Noised positions a tile of the own-block term: a lane tile of
+        scores, or the rule's block where that is larger."""
+        return max(self.mask_block, 128)
+
+    @property
+    def sizes(self):
+        from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+        b, inner = self.block, min(self.block, _KV_COMPUTE)
+        return splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=inner, block_q_dkv=b, block_kv_dkv=b,
+                                 block_kv_dkv_compute=inner, block_q_dq=inner, block_kv_dq=b)
+
+
+def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False) -> Plan:
+    """The own-block term leaves the stock kernels where the rule's block is
+    smaller than theirs (the noised quadrant's diagonal blocks are then cut
+    blocks mask_block / block full) and whole blocks of the rule make up the
+    own-block kernels' tile and that the stock kernels' block, which then is
+    the largest that divides L, the far keys."""
+    far, tile = kernel_block(positions // 2), max(mask_block, 128)
+    if far is not None and mask_block < far and tile % mask_block == 0 and far % tile == 0:
+        return Plan(positions, heads, mask_block, far, positions // 2, interpret)
+    return Plan(positions, heads, mask_block, kernel_block(positions), 0, interpret)
+
+
+@functools.lru_cache(maxsize=32)
+def block_maps(plan: Plan):
+    """The kernels' block maps for `plan`, forward, dq and dkv, in numpy: made
+    from the rule once a shape, a block of the grid at a time."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as info_lib
+
+    sizes = plan.sizes
+    mask = mask_lib.MultiHeadMask([_rule_mask(plan.positions, plan.first_key, plan.mask_block)] * plan.heads)
+    shards = dict(downcast_smem_data=True, head_shards=1, q_seq_shards=1)
+    return (info_lib.process_mask(mask, (sizes.block_q, sizes.block_kv), **shards)[0],
+            info_lib.process_mask(mask, (sizes.block_q_dq, sizes.block_kv_dq), **shards)[0],
+            info_lib.process_mask_dkv(mask, (sizes.block_q_dkv, sizes.block_kv_dkv), **shards)[0])
+
+
+def _block_map(plan: Plan, which: int):
+    """The stock kernels' block map `which` (forward, dq, dkv) on the device."""
+    return jax.tree.map(jnp.asarray, block_maps(plan)[which])
+
+
+def _stock_options(plan: Plan) -> dict:
+    """What the stock kernels' three calls share."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    return dict(mask_value=splash.DEFAULT_MASK_VALUE, is_mqa=False, attn_logits_soft_cap=None,
+                mask_function=None, interpret=plan.interpret)
+
+
+def _far_forward(q, k, v, plan: Plan):
+    """The kernels' term: output in the operands' dtype and float32
+    log-sum-exp (B, Hq, 2L).  A row the rule leaves no far key (the first
+    noised block's) reads the mean of a block's values, finite, under a
+    log-sum-exp of `mask_value` -2.38e38: weight exactly 0 in the join."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    info = _block_map(plan, 0)
+    out, (lse,) = jax.vmap(lambda q, k, v: splash._splash_attention_forward(
+        info, q, k, v, None, None, block_sizes=plan.sizes, residual_checkpoint_name=None, save_residuals=True,
+        **_stock_options(plan)))(q, k, v)
+    return out, lse
+
+
+def _far_backward(q, k, v, lse, do, di, plan: Plan):
+    """dq, dk, dv of the kernels' term from the stock dq and dkv kernels, given
+    the log-sum-exp and rowsum(do . out) of the WHOLE row (joined, where the
+    own-block term was split off)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    sizes, dq_info, dkv_info = plan.sizes, _block_map(plan, 1), _block_map(plan, 2)
+    options = dict(_stock_options(plan), q_layout=sizes.q_layout, k_layout=sizes.k_layout, v_layout=sizes.v_layout)
+    _, dk, dv = jax.vmap(lambda *a: splash._splash_attention_bwd_dkv(
+        *a[:3], None, None, *a[3:], bq=sizes.block_q_dkv, bkv=sizes.block_kv_dkv, bkv_compute=sizes.block_kv_dkv_compute,
+        mask_info=dkv_info, use_fused_bwd_kernel=False, **options))(q, k, v, lse, do, di)
+    dq = jax.vmap(lambda *a: splash._splash_attention_bwd_dq(
+        *a[:3], None, None, *a[3:], bq=sizes.block_q_dq, bkv=sizes.block_kv_dq, mask_info=dq_info,
+        **options))(q, k, v, lse, do, di)
+    return dq, dk, dv
+
+
+_NT = (((1,), (1,)), ((), ()))  # a b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T b
+
+
+def _own_block_scores(q, k, plan: Plan):
+    """Float32 scores [keys, queries] of a tile of the noised half's diagonal,
+    keys down the sublanes so that a query's statistics are rows [1, queries]
+    as the log-sum-exp is stored; pairs of different blocks of the rule masked."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    if plan.tile == plan.mask_block:
+        return s, None
+    own = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // plan.mask_block
+           == jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // plan.mask_block)
+    return jnp.where(own, s, splash.DEFAULT_MASK_VALUE), own
+
+
+def _join_kernel(q_ref, k_ref, v_ref, out_f_ref, lse_f_ref, out_ref, lse_ref, *, plan: Plan):
+    """A block of noised rows: their own block's keys joined to the far term.
+    lse = logaddexp(lse_f, lse_n), out = exp(lse_f - lse) out_f + p v with
+    p = exp(s - lse) rounded to the operands' dtype, all else float32."""
+    for t in range(q_ref.shape[0] // plan.tile):
+        at = pl.ds(t * plan.tile, plan.tile)
+        s, _ = _own_block_scores(q_ref[at, :], k_ref[at, :], plan)
+        m = s.max(axis=0, keepdims=True)
+        e = jnp.exp(s - m)
+        lse_f, lse_n = lse_f_ref[:, at], m + jnp.log(e.sum(axis=0, keepdims=True))
+        top = jnp.maximum(lse_f, lse_n)
+        lse = top + jnp.log(jnp.exp(lse_f - top) + jnp.exp(lse_n - top))
+        lse_ref[:, at] = lse
+        p = (e * jnp.exp(m - lse)).astype(v_ref.dtype)
+        near = jax.lax.dot_general(p, v_ref[at, :], _TN, preferred_element_type=jnp.float32)
+        # The far term's weight a query, turned from along the lanes to down the sublanes by a transpose in
+        # registers.  NOT by reading `lse_ref` back: its array is aliased to the input's, and on the chip (not
+        # interpreted) such a read returned the input's values (my chip run, PR 33).
+        far = jnp.broadcast_to(jnp.exp(lse_f - lse), s.shape).T
+        far = jnp.tile(far, (1, -(-out_ref.shape[1] // plan.tile)))[:, :out_ref.shape[1]]
+        out_ref[at, :] = (far * out_f_ref[at, :].astype(jnp.float32) + near).astype(out_ref.dtype)
+
+
+def _own_block_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_f_ref, dq_ref, dk_ref, dv_ref,
+                               dk_acc, dv_acc, *, plan: Plan, group: int):
+    """The own-block term's gradients by the stock kernels' formulas: p =
+    exp(s - lse), dv = p^T do, ds = p (do v^T - di), dq = ds k (added to the
+    far term's), dk = ds^T q; dk and dv summed over the query heads of a
+    key/value head, the grid's last axis."""
+    g = pl.program_id(3)
+
+    @pl.when(g == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    for t in range(q_ref.shape[0] // plan.tile):
+        at = pl.ds(t * plan.tile, plan.tile)
+        q, k, do = q_ref[at, :], k_ref[at, :], do_ref[at, :]
+        s, own = _own_block_scores(q, k, plan)
+        p = jnp.exp(s - lse_ref[:, at])
+        p = p if own is None else jnp.where(own, p, 0.0)
+        dv_acc[at, :] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[at, :], do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - di_ref[:, at])).astype(q.dtype)
+        dk_acc[at, :] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq = jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
+        dq_ref[at, :] = (dq_f_ref[at, :].astype(jnp.float32) + dq).astype(dq_ref.dtype)
+
+    @pl.when(g == group - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _join_own_block(q, k, v, out, lse, plan: Plan):
+    """`out` (B, Hq, 2L, dh) and `lse` (B, Hq, 2L) of the far term with the
+    noised rows' own blocks joined in, in place: the clean rows pass through."""
+    batch, heads, positions, width = q.shape
+    group, rows = heads // k.shape[1], plan.block
+    of_q = pl.BlockSpec((None, None, rows, width), lambda b, h, i: (b, h, i, 0))
+    of_kv = pl.BlockSpec((None, None, rows, width), lambda b, h, i: (b, h // group, i, 0))
+    of_lse = pl.BlockSpec((None, None, 1, rows), lambda b, h, i: (b, h, 0, i))
+    out, lse = pl.pallas_call(
+        functools.partial(_join_kernel, plan=plan), grid=(batch, heads, plan.first_key // rows),
+        in_specs=[of_q, of_kv, of_kv, of_q, of_lse], out_specs=[of_q, of_lse],
+        out_shape=[jax.ShapeDtypeStruct(out.shape, out.dtype), jax.ShapeDtypeStruct((batch, heads, 1, positions), lse.dtype)],
+        input_output_aliases={3: 0, 4: 1}, interpret=plan.interpret, name="own_block_join",
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",) * 3),
+    )(q, k, v, out, lse[:, :, None])
+    return out, lse[:, :, 0]
+
+
+def _own_block_backward(q, k, v, lse, do, di, dq, plan: Plan):
+    """`dq` (B, Hq, 2L, dh) of the far term with the own-block term's added to
+    the noised rows in place, and that term's dk, dv (B, Hkv, L, dh)."""
+    batch, heads, _, width = q.shape
+    kv_heads, rows = k.shape[1], plan.block
+    group = heads // kv_heads
+    of_q = pl.BlockSpec((None, None, rows, width), lambda b, h, i, g: (b, h * group + g, i, 0))
+    of_kv = pl.BlockSpec((None, None, rows, width), lambda b, h, i, g: (b, h, i, 0))
+    of_lse = pl.BlockSpec((None, None, 1, rows), lambda b, h, i, g: (b, h * group + g, 0, i))
+    near_kv = jax.ShapeDtypeStruct((batch, kv_heads, plan.first_key, width), k.dtype)
+    return pl.pallas_call(
+        functools.partial(_own_block_backward_kernel, plan=plan, group=group),
+        grid=(batch, kv_heads, plan.first_key // rows, group),
+        in_specs=[of_q, of_kv, of_kv, of_q, of_lse, of_lse, of_q], out_specs=[of_q, of_kv, of_kv],
+        out_shape=[jax.ShapeDtypeStruct(dq.shape, dq.dtype), near_kv, near_kv],
+        scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32)] * 2,
+        input_output_aliases={6: 0}, interpret=plan.interpret, name="own_block_backward",
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+    )(q, k, v, do, lse[:, :, None], di[:, :, None], dq)
+
+
+def _forward(q, k, v, plan: Plan):
+    seq = plan.first_key
+    out, lse = _far_forward(q, k[:, :, seq:], v[:, :, seq:], plan)
+    return _join_own_block(q, k, v, out, lse, plan) if seq else (out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attention(q, k, v, plan: Plan):
+    return _forward(q, k, v, plan)[0]
+
+
+def _attention_fwd(q, k, v, plan: Plan):
+    out, lse = _forward(q, k, v, plan)
+    return out, (q, k, v, out, lse)
+
+
+def _attention_bwd(plan: Plan, residuals, do):
+    q, k, v, out, lse = residuals
+    seq = plan.first_key
+    di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32), do.astype(jnp.float32))
+    dq, dk, dv = _far_backward(q, k[:, :, seq:], v[:, :, seq:], lse, do, di, plan)
+    if seq:
+        dq, dk_n, dv_n = _own_block_backward(q, k, v, lse, do, di, dq, plan)
+        dk, dv = jnp.concatenate([dk_n, dk], axis=2), jnp.concatenate([dv_n, dv], axis=2)
+    return dq, dk, dv
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def attention_under(plan: Plan, q, k, v, scale: float):
+    """`block_sparse_attention` with the plan given
+    (tools/chip_block_attention.py prices one the shapes would not take)."""
+    blocks = block_maps(plan)[0].block_mask
+    _MON.counter("lowering.attention_blocks_visited").inc(int(np.count_nonzero(blocks)))
+    _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
+    _MON.counter("lowering.attention_own_block_terms").inc(int(plan.first_key > 0))
+    with jax.named_scope("block_sparse_attention"):
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        return _attention(q, k, v, plan)
 
 
 def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bool = False):
     """softmax(q k^T . scale under the block-diffusion rule) v over
     (B, Hq, 2L, dh) queries and (B, Hkv, 2L, dh) keys and values, Hkv a
-    divisor of Hq: the stock splash-attention kernel, forward, dq and dkv.
-    The kernel has no scale of its own: the queries carry it."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
-
-    positions, heads = q.shape[2], q.shape[1]
-    b = kernel_block(positions)
-    inner = min(b, _KV_COMPUTE)
-    sizes = splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=inner, block_q_dkv=b, block_kv_dkv=b,
-                              block_kv_dkv_compute=inner, block_q_dq=inner, block_kv_dq=b)
-    one = _mask(positions, mask_block)
-    kernel = splash.make_splash_mha(mask_lib.MultiHeadMask([one] * heads), block_sizes=sizes,
-                                    head_shards=1, q_seq_shards=1, interpret=interpret)
-    with jax.named_scope("block_sparse_attention"):
-        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-        return jax.vmap(kernel)(q, k, v).astype(q.dtype)
+    divisor of Hq.  The kernels have no scale of their own: the queries carry
+    it."""
+    return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale)

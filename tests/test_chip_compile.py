@@ -306,16 +306,20 @@ SDAR_EXPERTS = (2 * 8192, 2048, 768, 128, 8, 16)
 
 def test_no_square_of_the_positions_is_in_the_compiled_attention(chip):
     """Forward and backward at the cell's shape: no array with 8192 x 8192
-    elements, mask or scores, in any computation of the compiled program, and
-    the three kernel calls under the lowering's scope, where the benchmark's
-    `attention_roofline_share` finds them."""
+    elements, mask or scores, in any computation of the compiled program, nor
+    a float32 one of 8192 x 4096 (the far term's scores), and the five kernel
+    calls under the lowering's scope, where the benchmark's
+    `attention_roofline_share` finds them: the stock kernel's three over the
+    clean keys and the own-block term's two."""
     args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in ((2, 32, 8192, 128), (2, 4, 8192, 128), (2, 4, 8192, 128))]
     text = jax.jit(jax.grad(lambda *a: jnp.sum(_block_sparse(*a).astype(F32)), argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
     assert not re.findall(r"\[[\d,]*8192,8192\]", text)
-    assert text.count("tpu_custom_call") == 3
-    under_the_scope = re.findall(r'op_name="[^"]*block_sparse_attention[^"]*splash_mha_(fwd|dq|dkv)[^"]*/pallas_call"', text)
-    assert set(under_the_scope) == {"fwd", "dq", "dkv"}
+    assert not re.findall(r"f32\[[\d,]*8192,4096\]", text)
+    assert text.count("tpu_custom_call") == 5
+    under_the_scope = re.findall(
+        r'op_name="[^"]*block_sparse_attention[^"]*/(?:splash_mha_)?(fwd|dq|dkv|own_block_join|own_block_backward)[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"fwd", "dq", "dkv", "own_block_join", "own_block_backward"}
 
 
 def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(chip):

@@ -114,38 +114,70 @@ def test_fused_attention_golden_forward_and_gradient_under_the_mask_and_with_gro
         agree(g, w, tol=1e-5)
 
 
-@pytest.mark.parametrize("hq,hkv,block", [(4, 2, 4), (2, 2, 8), (8, 1, 4), (4, 4, 64)])
-def test_the_block_sparse_kernel_agrees_with_the_dense_rule_forward_and_backward(hq, hkv, block):
-    """The stock kernel under the computable mask, interpreted: what the chip
-    runs but for Mosaic (tests/test_chip_compile.py compiles it).  256
-    positions in blocks of 128: blocks the rule empties, fills and cuts."""
-    positions, dh = 256, 128
+#: heads, key/value heads, the rule's block, positions -> is the own-block term split off the kernels (rule's block < kernels' 128)
+KERNEL_CASES = {(4, 2, 4, 256): True, (2, 2, 8, 256): True, (8, 1, 4, 256): True, (4, 4, 64, 256): True,
+                (2, 2, 4, 512): True, (4, 2, 128, 768): False, (2, 2, 128, 768): False, (4, 1, 384, 768): False}
+
+
+@pytest.mark.parametrize("hq,hkv,block,positions", list(KERNEL_CASES))
+def test_the_block_sparse_kernel_agrees_with_the_dense_rule_forward_and_backward(hq, hkv, block, positions):
+    """The stock kernels, interpreted: what the chip runs but for Mosaic
+    (tests/test_chip_compile.py compiles it).  Blocks of 128: blocks the rule
+    empties, fills and cuts; the own-block term joined by its log-sum-exp
+    where the rule's block is smaller than the kernels', one call over the
+    whole square where it is not; the first noised block's rows, which have
+    no far key, finite and the dense rule's in the output and every gradient."""
+    dh = 128
     q = RNG.randn(2, hq, positions, dh).astype("f4")
     k, v = (RNG.randn(2, hkv, positions, dh).astype("f4") for _ in range(2))
     weight = RNG.randn(*q.shape).astype("f4")
     mask = dense_mask(positions, block)
+    plan = masked_attention.plan_of(positions, hq, block)
+    assert (plan.first_key == positions // 2) is KERNEL_CASES[hq, hkv, block, positions] and plan.block == 128
 
     def kernel(q, k, v):
         return masked_attention.block_sparse_attention(q, k, v, block, dh ** -0.5, interpret=True)
 
-    agree(kernel(q, k, v), attention_golden(q, k, v, mask), tol=1e-5)
+    out, golden = kernel(q, k, v), attention_golden(q, k, v, mask)
+    agree(out, golden, tol=1e-5)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * weight), (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(attention_golden(*a, mask) * weight), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         agree(g, w, tol=2e-5)
+    first = slice(0, block)  # the first noised block: queries with no far key, keys no clean query sees
+    assert all(np.isfinite(np.asarray(t)).all() for t in (out, *got))
+    for g, w in zip((out, *got), (golden, *want)):
+        agree(g[:, :, first], w[:, :, first], tol=2e-5)
 
 
 def test_the_kernels_block_map_skips_what_the_rule_empties():
-    """At the cell's 8192 positions in blocks of 512: 80 of 256 blocks are
-    visited, 56 of them whole (no mask read), a row of the grid holds 9 at the
-    most, all heads share one map, and the 24 blocks the rule cuts are three
-    distinct ones (a diagonal block of each quadrant): all of the mask that
-    reaches the device."""
+    """At the cell's 8192 positions the kernels see the 4096 clean keys only:
+    20 of the rectangle's 32 1024-blocks are visited, 8 of them cut (a clean
+    block's diagonal, as the noised rows and as the clean rows see it: two
+    distinct blocks are all of the mask that reaches the device), a row of the
+    grid holds 4 at the most and all heads share one map; dq's 512 x 1024 grid
+    visits 40 with 16 cut.  The whole square in blocks of 512, which is what a
+    rule's block as large as the kernels' still takes: 80 of 256 visited, 56
+    of them whole, three distinct cut blocks (a diagonal block of each
+    quadrant)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
 
+    plan = masked_attention.plan_of(8192, 32, 4)
+    assert (plan.block, plan.first_key) == (1024, 4096)
+    forward, dq, dkv = masked_attention.block_maps(plan)
+    blocks = np.asarray(forward.block_mask)
+    assert blocks.shape == (1, 8, 4) and forward.q_sequence is None
+    assert ((blocks > 0).sum(), (blocks == 1).sum(), (blocks == 2).sum()) == (20, 8, 12)
+    assert np.asarray(forward.data_next).max() < 4096 // 1024  # no block over a noised key: there are none to fetch
+    assert np.asarray(forward.partial_mask_blocks).shape == (2, 1024, 1024)
+    assert ((np.asarray(dq.block_mask) > 0).sum(), (np.asarray(dq.block_mask) == 1).sum()) == (40, 16)
+    assert np.asarray(dkv.block_mask).shape == (1, 8, 4) and (np.asarray(dkv.block_mask) > 0).sum() == 20
+    whole = masked_attention.block_maps(plan._replace(first_key=0))[0].block_mask
+    assert ((whole > 0).sum(), (whole == 1).sum()) == (24, 12)  # what the split took off: the noised quadrant's diagonal
+
     sizes = splash.BlockSizes(block_q=512, block_kv=512)
-    kernel = splash.make_splash_mha(mask_lib.MultiHeadMask([masked_attention._mask(8192, 4)] * 32),
+    kernel = splash.make_splash_mha(mask_lib.MultiHeadMask([masked_attention._rule_mask(8192, 0, 4)] * 32),
                                     block_sizes=sizes, head_shards=1, q_seq_shards=1)
     info = kernel.fwd_mask_info
     blocks = np.asarray(info.block_mask)
@@ -157,6 +189,25 @@ def test_the_kernels_block_map_skips_what_the_rule_empties():
     diagonals = {tuple(map(int, masked_attention.block_diffusion_allowed(
         (q0 + at)[:, None], (k0 + at)[None, :], 4096, 4).sum(-1))) for q0, k0 in ((0, 0), (0, 4096), (4096, 4096))}
     assert {tuple(map(int, c.sum(-1))) for c in cut} == diagonals
+
+
+@pytest.mark.parametrize("block,counted", [(4, (20, 8, 1)), (1024, (20, 0, 0))])
+def test_the_lowering_counts_the_blocks_the_kernels_visit_and_the_own_block_terms(block, counted):
+    """An attention at the cell's shape: 20 blocks visited, 8 of them cut and
+    one own-block term; with the rule's block as large as the kernels' the
+    whole square holds 20 whole blocks, none cut, and no such term."""
+    monitor.reset()
+    monitor.enable()
+    try:
+        args = [jax.ShapeDtypeStruct((2, 32, 8192, 128), BF16)] + [jax.ShapeDtypeStruct((2, 4, 8192, 128), BF16)] * 2
+        jax.eval_shape(lambda q, k, v: lower("fused_attention", {"Q": q, "K": k, "V": v},
+                                             {"mask": "block_diffusion", "mask_block": block}, platform="tpu")["Out"], *args)
+        got = monitor.MONITOR.counter_values()
+        assert tuple(got[f"lowering.attention_{name}"] for name in ("blocks_visited", "blocks_cut", "own_block_terms")) == counted
+        assert got["lowering.attention_block_sparse"] == 1
+    finally:
+        monitor.disable()
+        monitor.reset()
 
 
 def test_a_mask_or_fewer_key_heads_that_make_no_sense_are_refused():
@@ -207,6 +258,7 @@ def test_the_lowering_counts_the_block_sparse_attention_and_names_its_kernels():
         counted = monitor.MONITOR.counter_values()
         assert counted["lowering.attention_block_sparse"] == 1 and not counted.get("lowering.attention_xla")
         assert all(f"splash_mha_{phase}" in text for phase in ("fwd", "dq", "dkv"))  # the stock kernel's three calls
+        assert "own_block_join" in text and "own_block_backward" in text  # and the own-block term's two
         assert not re.findall(r"\[(?:\d+,)*256,256\]", text)  # no array of the whole square
     finally:
         monitor.disable()

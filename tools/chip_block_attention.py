@@ -1,12 +1,17 @@
 """On the chip: the block-sparse attention of `fused_attention` under the
 block-diffusion mask (ops/masked_attention.py) priced at SDAR's cell:
 (2, 32, 8192, 128) queries over (2, 4, 8192, 128) keys and values, bf16, block
-length 4, forward + backward, by the grid's block; the same mask computed in
-the kernel from positions instead of read from its distinct cut blocks; the
-backward pass as one kernel instead of two; and, for the record, the
-stock flash kernel and the splash kernel at OLMoE's causal (4, 16, 4096, 128).
+length 4, forward + backward: the whole square through the stock kernels
+against the clean keys through them and the own-block term through its own
+(what `block_sparse_attention` takes from the rule's block), at rule's blocks
+of 4, 16, 128 and 256, with the two forms' largest difference in the output
+and the three gradients (QUICK=1 stops there; PERF.md, PR 33); then, over the
+whole square (PR 32), by the grid's block; the same mask computed in the
+kernel from positions instead of read from its distinct cut blocks; the
+backward pass as one kernel instead of two; and, for the record, the stock
+flash kernel and the splash kernel at OLMoE's causal (4, 16, 4096, 128).
 
-    chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PR 32)
+    chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PRs 32 and 33)
 
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
@@ -32,9 +37,13 @@ POSITIONS, BLOCK = (512, 4) if DRY else (8192, 4)
 Q, KV = ((1, 4, POSITIONS, 128), (1, 2, POSITIONS, 128)) if DRY else ((2, 32, 8192, 128), (2, 4, 8192, 128))
 
 
+def gradients(fn):
+    return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+
 def ms(fn, *args, runs=5):
     """Forward + backward of sum(fn), the median of `runs` after one that compiles."""
-    step = jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 1, 2)))
+    step = gradients(fn)
     jax.block_until_ready(step(*args))
     times = []
     for _ in range(runs):
@@ -91,11 +100,24 @@ class Computed(mask_lib._ComputableMask):
         return hash(type(self).__name__)
 
 
-stored = ma._mask(POSITIONS, BLOCK)
-report("block_sparse_attention", ms=try_ms(   # as `fused_attention` calls it
-    lambda q, k, v: ma.block_sparse_attention(q, k, v, BLOCK, scale, interpret=DRY), q, k, v))
+def apart(got, want):
+    """Largest difference over the largest magnitude, in float32."""
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+for rule_block in (4, 16, 128, 256):
+    split = ma.plan_of(POSITIONS, Q[1], rule_block, DRY)  # as `fused_attention` calls it
+    whole = split._replace(block=ma.kernel_block(POSITIONS), first_key=0)
+    forms = [lambda q, k, v, plan=plan: ma.attention_under(plan, q, k, v, scale) for plan in (whole, split)]
+    results = [(jax.jit(form)(q, k, v), *gradients(form)(q, k, v)) for form in forms]
+    report("whole_square_against_far_plus_near", rule_block=rule_block, split_taken=split.first_key > 0,
+           whole_square_ms=try_ms(forms[0], q, k, v), far_plus_near_ms=try_ms(forms[1], q, k, v),
+           apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))),
+           finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in results[1]))
 if os.environ.get("QUICK") == "1":
     sys.exit(0)
+stored = ma._rule_mask(POSITIONS, 0, BLOCK)
 for b in ((128,) if DRY else (256, 512, 1024)):
     report("two_backward_kernels", grid_block=b, ms=try_ms(splash_with(stored, Q[1], sizes_of(b, b)), q, k, v))
     report("the_mask_computed_in_the_kernel", grid_block=b, ms=try_ms(splash_with(Computed(), Q[1], sizes_of(b, b)), q, k, v))
