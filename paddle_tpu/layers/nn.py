@@ -782,12 +782,22 @@ def rotary_embedding(x, positions, theta=10000.0, name=None):
 
 
 def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
-        router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None):
+        router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None,
+        scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0):
     """A layer of routed experts over (..., d): a float32 router picks
     `top_k` of `num_experts` gated-SiLU experts of width `expert_width` for
     every token; their outputs are summed, weighted by the router's
-    probabilities (renormalised over the chosen ones only if
-    `norm_topk_prob`).  No capacity limit: no token is dropped.
+    scores (renormalised over the chosen ones only if `norm_topk_prob`, by
+    their sum + `norm_eps`; times `routed_scaling_factor`).  No capacity
+    limit: no token is dropped.
+
+    `scoring` "softmax" scores a token's experts by the softmax over them,
+    "sigmoid" each by its own sigmoid.  `bias_attr` (a `ParamAttr`: name and
+    initializer) gives the router a bias [num_experts] that is added to the
+    scores for the CHOICE only, the weights staying the unbiased scores.  It
+    is a persistable float32 tensor and no parameter: it has no gradient and
+    no optimizer state, and whoever balances the experts' load by it writes
+    it from outside the step (no op here does).
 
     Returns (out, load_balance_loss, router_z_loss); the two [1] float32
     losses are for the caller to weigh into the training loss.  The experts
@@ -818,11 +828,21 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     load = _out(helper, "int32", shape=(num_experts,))
     balance = _out(helper, "float32", shape=(1,))
     z_loss = _out(helper, "float32", shape=(1,))
-    helper.append_op(
-        "moe_router", inputs={"X": [input.name], "W": [router.name]},
-        outputs={"TopKProb": [top_p.name], "TopKIndex": [top_i.name], "Load": [load.name],
-                 "LoadBalanceLoss": [balance.name], "ZLoss": [z_loss.name]},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+    router_inputs = {"X": [input.name], "W": [router.name]}
+    router_outputs = {"TopKProb": [top_p.name], "TopKIndex": [top_i.name], "Load": [load.name],
+                      "LoadBalanceLoss": [balance.name], "ZLoss": [z_loss.name]}
+    router_attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
+    # what the 2024 router does not have is an attribute only where it is asked for
+    if scoring != "softmax":
+        router_attrs["scoring"] = str(scoring)
+    if routed_scaling_factor != 1.0:
+        router_attrs["routed_scaling_factor"] = float(routed_scaling_factor)
+    if norm_eps:
+        router_attrs["norm_eps"] = float(norm_eps)
+    if bias_attr is not None:
+        router_inputs["Bias"] = [_persistable_tensor(helper, bias_attr, [num_experts], "float32").name]
+        router_outputs["BiasMoved"] = [_out(helper, "int32", shape=(1,)).name]
+    helper.append_op("moe_router", inputs=router_inputs, outputs=router_outputs, attrs=router_attrs)
     out = _out(helper, input.dtype, shape=input.shape)
     dropped = _out(helper, "int32", shape=(1,))
     outputs = {"Out": [out.name], "Dropped": [dropped.name]}
@@ -837,6 +857,42 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
                 "WDown": [down.name]},
         outputs=outputs, attrs=attrs)
     return _keep_lod(input, out), balance, z_loss
+
+
+def _persistable_tensor(helper, attr, shape, dtype):
+    """A named tensor the start-up program initialises and every step reads,
+    which is NOT a parameter: no gradient, no optimizer state, not in
+    `Program.all_parameters()`."""
+    from ..core import unique_name
+    from ..core.initializer import ConstantInitializer
+    from ..core.param_attr import ParamAttr
+
+    attr = ParamAttr._to_attr(attr)
+    name = attr.name or unique_name.generate(f"{helper.name}.buffer")
+    var = helper.main_program.global_block().create_var(name, shape=shape, dtype=dtype, persistable=True)
+    var.stop_gradient = True
+    startup = helper.startup_program.global_block()
+    init = attr.initializer or ConstantInitializer(0.0)
+    init(startup.create_var(name, shape=shape, dtype=dtype, persistable=True), startup)
+    return var
+
+
+def short_conv(input, kernel_size=3, in_attr=None, filter_attr=None, out_attr=None, name=None):
+    """A gated short convolution over (b, T, d), the operator that stands
+    where attention does in most layers of a convolution-attention hybrid
+    (LFM2): [B, C, u] = split3(x W_in); y = (C * conv_K(B * u)) W_out with one
+    causal filter of `kernel_size` taps a channel (depthwise), zeros before the
+    sequence's start, no activation and no biases.  The two projections are
+    `fc` (so `mul` ops, as attention's are) and the op between them is
+    `short_conv`.  Sequences are whole: a row is one document."""
+    helper = LayerHelper("short_conv", name=name)
+    d = int(input.shape[-1])
+    projected = fc(input, 3 * d, num_flatten_dims=2, param_attr=in_attr, bias_attr=False)
+    taps = helper.create_parameter(filter_attr, [d, int(kernel_size)], "float32")
+    mixed = _out(helper, input.dtype, shape=tuple(input.shape))
+    helper.append_op("short_conv", inputs={"X": [projected.name], "Filter": [taps.name]},
+                     outputs={"Out": [mixed.name]})
+    return fc(mixed, d, num_flatten_dims=2, param_attr=out_attr, bias_attr=False)
 
 
 def dropout_prob_check(p):

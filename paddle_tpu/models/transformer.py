@@ -129,9 +129,10 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                   use_ring_attention=False, causal=False, use_fused_attention=False,
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
-                  n_kv_heads=None, head_dim=None, attention_mask=None):
-    """One transformer layer: attention and a feed-forward part, each with a
-    residual connection and a norm.
+                  n_kv_heads=None, head_dim=None, attention_mask=None,
+                  operator="attention", conv_kernel=3, ffn="gelu"):
+    """One transformer layer: a sequence operator (attention) and a
+    feed-forward part, each with a residual connection and a norm.
 
     The defaults are BERT's: layer norm AFTER each residual sum, projection
     biases, a dense GELU feed-forward of width `d_ff`.  `norm="rms"` with
@@ -139,11 +140,18 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     y = h + ffn(norm(h))); `qk_norm` (True or "width": over the projected
     width; "head": over each head), `positions`, `proj_bias`, `n_kv_heads`,
     `head_dim` and `attention_mask` = (kind, block length) go to the
-    attention.  `moe=dict(num_experts=, top_k=, norm_topk_prob=, held=)` makes
-    the feed-forward part `d_ff`-wide routed gated-SiLU experts (`held`: the
-    range of them this layer holds, `layers.moe`; `router_seed`: a seed of
-    the router's own, `_attr`); its two auxiliary losses
-    are appended to `aux_losses` as (load balance, router z).
+    attention.  `operator="conv"` puts a gated short convolution of
+    `conv_kernel` taps (`layers.short_conv`) where the attention stands.
+
+    The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
+    W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
+    `moe=dict(num_experts=, top_k=, norm_topk_prob=, held=)` makes it
+    `d_ff`-wide routed gated-SiLU experts instead (`held`: the range of them
+    this layer holds; `router_seed`: a seed of the router's own, `_attr`;
+    `scoring`, `routed_scaling_factor`, `norm_eps` and `bias` = (standard
+    deviation, seed) of a router bias that enters the choice alone:
+    `layers.moe`); its two auxiliary losses are appended to `aux_losses` as
+    (load balance, router z).
     """
     def normed(t, name):
         if norm == "rms":
@@ -155,30 +163,48 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
 
     def feed_forward(t):
         if moe is not None:
+            bias = moe.get("bias")
             out, balance, z_loss = layers.moe(
                 t, moe["num_experts"], d_ff, moe["top_k"],
                 norm_topk_prob=moe.get("norm_topk_prob", False), held=moe.get("held"),
                 router_attr=_attr(f"{prefix}.moe.router.w", seed=moe.get("router_seed", 0)),
                 gate_attr=_attr(f"{prefix}.moe.gate.w"),
-                up_attr=_attr(f"{prefix}.moe.up.w"), down_attr=_attr(f"{prefix}.moe.down.w"))
+                up_attr=_attr(f"{prefix}.moe.up.w"), down_attr=_attr(f"{prefix}.moe.down.w"),
+                scoring=moe.get("scoring", "softmax"),
+                routed_scaling_factor=moe.get("routed_scaling_factor", 1.0),
+                norm_eps=moe.get("norm_eps", 0.0),
+                bias_attr=bias and _attr(f"{prefix}.moe.router.bias", *bias))
             aux_losses.append((balance, z_loss))
             return out
+        if ffn == "gated_silu":
+            def project(u, name, width, act=None):
+                return layers.fc(u, width, num_flatten_dims=2, act=act, bias_attr=False,
+                                 param_attr=_attr(f"{prefix}.ffn.{name}.w"))
+
+            hidden = layers.elementwise_mul(project(t, "gate", d_ff, act="swish"), project(t, "up", d_ff))
+            return project(hidden, "down", d_model)
         ffn1 = layers.fc(t, d_ff, num_flatten_dims=2, act="gelu",
                          param_attr=_attr(f"{prefix}.ffn1.w"), bias_attr=_attr(f"{prefix}.ffn1.b"))
         return layers.fc(ffn1, d_model, num_flatten_dims=2,
                          param_attr=_attr(f"{prefix}.ffn2.w"), bias_attr=_attr(f"{prefix}.ffn2.b"))
 
-    attn_out = multi_head_attention(normed(x, "ln1") if pre_norm else x,
-                                    seq_len, d_model, n_heads, f"{prefix}.attn",
-                                    dropout_prob, is_test, use_ring_attention, causal,
-                                    use_fused_attention=use_fused_attention,
-                                    proj_bias=proj_bias,
-                                    qk_norm_eps=norm_eps if qk_norm else None,
-                                    positions=positions, rope_theta=rope_theta,
-                                    n_kv_heads=n_kv_heads, head_dim=head_dim,
-                                    qk_norm_per_head=qk_norm == "head",
-                                    mask=attention_mask and attention_mask[0],
-                                    mask_block=attention_mask and attention_mask[1])
+    operator_in = normed(x, "ln1") if pre_norm else x
+    if operator == "conv":
+        attn_out = layers.short_conv(operator_in, conv_kernel, in_attr=_attr(f"{prefix}.conv.in.w"),
+                                     filter_attr=_attr(f"{prefix}.conv.filter.w"),
+                                     out_attr=_attr(f"{prefix}.conv.out.w"))
+    else:
+        attn_out = multi_head_attention(operator_in,
+                                        seq_len, d_model, n_heads, f"{prefix}.attn",
+                                        dropout_prob, is_test, use_ring_attention, causal,
+                                        use_fused_attention=use_fused_attention,
+                                        proj_bias=proj_bias,
+                                        qk_norm_eps=norm_eps if qk_norm else None,
+                                        positions=positions, rope_theta=rope_theta,
+                                        n_kv_heads=n_kv_heads, head_dim=head_dim,
+                                        qk_norm_per_head=qk_norm == "head",
+                                        mask=attention_mask and attention_mask[0],
+                                        mask_block=attention_mask and attention_mask[1])
     x = layers.elementwise_add(x, attn_out)
     if not pre_norm:
         x = normed(x, "ln1")
@@ -247,7 +273,7 @@ def build_causal_lm(
     vocab_size=50304,
     seq_len=4096,
     d_model=2048,
-    n_layers=16,
+    n_layers=None,
     n_heads=16,
     expert_width=1024,
     num_experts=64,
@@ -272,6 +298,15 @@ def build_causal_lm(
     loss_positions=None,
     embedding_std=0.02,
     routing_seed=0,
+    layer_types=None,
+    conv_kernel=3,
+    num_dense_layers=0,
+    dense_width=None,
+    tie_embedding=False,
+    scoring="softmax",
+    routed_scaling_factor=1.0,
+    norm_topk_eps=0.0,
+    expert_bias=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -309,8 +344,33 @@ def build_causal_lm(
     read), and `routing_seed`, where not 0, is the seed of the embedding
     (`routing_seed`) and of layer i's router (`routing_seed` + 1 + i)
     instead: which experts a token meets is then the same whatever the
-    program's seed, as it is for a checkpoint."""
+    program's seed, as it is for a checkpoint.
+
+    A hybrid of the family (LFM2) is arguments too.  `layer_types`, one of
+    "full_attention" / "conv" a layer (its length is the depth: `n_layers`
+    is then left out or equal to it; without `layer_types` it is the number of
+    attention layers, 16 by default), puts a
+    gated short convolution of `conv_kernel` taps where a layer's attention
+    stands; the first `num_dense_layers` layers have a dense gated-SiLU
+    feed-forward of `dense_width` in place of the experts; `tie_embedding`
+    makes the head the embedding table transposed, one parameter with two
+    uses whose two gradients are one sum; `scoring` ("softmax" / "sigmoid"),
+    `routed_scaling_factor`, `norm_topk_eps` (added to the chosen scores' sum
+    before the renormalisation) and `expert_bias` = (standard deviation,
+    seed) go to the routers: layer i's bias is N(0, standard deviation) from
+    seed + i, enters its choice and not its weights, and is no parameter
+    (`layers.moe`)."""
     main, startup = Program(), Program()
+    if layer_types is not None and n_layers not in (None, len(layer_types)):
+        raise ValueError(f"build_causal_lm: n_layers={n_layers} beside {len(layer_types)} layer_types; "
+                         "layer_types alone states the depth")
+    kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
+    unknown = sorted(set(kinds) - {"full_attention", "conv"})
+    if unknown:
+        raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv")
+    if not 0 <= num_dense_layers < len(kinds) or (num_dense_layers and not dense_width):
+        raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
+                         f"among {len(kinds)} layers; the layers after them are sparse, and there is one at least")
     with program_guard(main, startup):
         n_labels = loss_positions or seq_len
         ids = layers.data("ids", [seq_len], dtype="int64")
@@ -321,32 +381,43 @@ def build_causal_lm(
         if dtype != "float32":
             x = layers.cast(x, dtype)
         aux = []
-        for i in range(n_layers):
-            x = encoder_layer(x, seq_len, d_model, n_heads, expert_width, f"lm.l{i}",
+        for i, kind in enumerate(kinds):
+            dense = i < num_dense_layers
+            experts = dict(num_experts=num_experts, top_k=top_k,
+                           norm_topk_prob=norm_topk_prob, held=experts_held,
+                           router_seed=routing_seed and routing_seed + 1 + i,
+                           scoring=scoring, routed_scaling_factor=routed_scaling_factor,
+                           norm_eps=norm_topk_eps,
+                           bias=expert_bias and (expert_bias[0], expert_bias[1] + i))
+            x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
+                              f"lm.l{i}",
                               dropout_prob=0.0, causal=attention_mask is None,
                               use_fused_attention=use_fused_attention,
                               norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
                               qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
-                              moe=dict(num_experts=num_experts, top_k=top_k,
-                                       norm_topk_prob=norm_topk_prob, held=experts_held,
-                                       router_seed=routing_seed and routing_seed + 1 + i),
+                              moe=None if dense else experts, ffn="gated_silu",
                               aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
-                              attention_mask=attention_mask)
+                              attention_mask=attention_mask,
+                              operator="conv" if kind == "conv" else "attention",
+                              conv_kernel=conv_kernel)
         feeds = {"ids": ids, "labels": labels, "pos_ids": pos_ids}
         if loss_positions:  # the rest of the positions are context: no logits of theirs are used
             x = layers.slice(x, axes=[1], starts=[0], ends=[loss_positions])
         x = layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
                             param_attr=_attr_ones("lm.final_norm.w"))
-        logits = layers.fc(x, vocab_size, num_flatten_dims=2,
-                           param_attr=_attr("lm.head.w"), bias_attr=False)
+        if tie_embedding:
+            logits = layers.matmul(x, main.global_block().var("lm.tok_emb"), transpose_y=True)
+        else:
+            logits = layers.fc(x, vocab_size, num_flatten_dims=2,
+                               param_attr=_attr("lm.head.w"), bias_attr=False)
         ce = layers.softmax_with_cross_entropy(
             layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1]))
         if loss_positions:
             feeds["loss_weight"] = layers.data("loss_weight", [loss_positions], dtype="float32")
             ce = layers.elementwise_mul(ce, layers.reshape(feeds["loss_weight"], [-1, 1]))
         ce = layers.mean(ce)
-        balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / n_layers)
-        z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / n_layers)
+        balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / len(aux))
+        z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / len(aux))
         terms = [ce] + [layers.scale(term, scale=coef) for term, coef in
                         ((balance, load_balance_coef), (z_loss, router_z_coef)) if coef]
         loss = layers.sums(terms) if len(terms) > 1 else ce

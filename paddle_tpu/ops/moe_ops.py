@@ -1,14 +1,20 @@
 """The parts of a modern decoder block that no 2019 op computes: RMSNorm,
-rotary positions, and a layer of routed experts (a router and the experts).
+rotary positions, a layer of routed experts (a router and the experts), and
+the gated short convolution that stands where attention does in most layers
+of a convolution-attention hybrid.
 
-No reference counterpart: the reference predates all three.  The equations
+No reference counterpart: the reference predates all four.  The equations
 are those of OLMoE-1B-7B (Muennighoff et al. 2024, arXiv:2409.02060), which
-are also Mixtral's and DeepSeek-MoE's but for the shared expert:
+are also Mixtral's and DeepSeek-MoE's but for the shared expert; the sigmoid
+router whose bias picks and does not weigh is DeepSeek-V3's and LFM2's, the
+short convolution LFM2's:
 
     rms_norm          y = x / sqrt(mean(x^2) + eps) * g
     rotary_embedding  y = x cos(t) + rotate_half(x) sin(t),  t = pos * theta^(-2i/dh)
     moe_router        p = softmax_f32(x Wr); (p_e, e) = top_k(p); two auxiliary losses
+                      or s = sigmoid_f32(x Wr); e = top_k(s + b); p_e = s_e (the unbiased score)
     moe_experts       y = sum_{e in top_k} p_e . Wdown_e( silu(Wgate_e x) * (Wup_e x) )
+    short_conv        y = C * conv_K(B * u),  [B, C, u] = split3(x),  conv_K causal and depthwise
 
 Backward comes from `jax.vjp` over these lowerings like every other op's
 (core/lowering.py).  `moe_experts` makes no pass over a [rows, hidden],
@@ -75,25 +81,53 @@ def _rotary_embedding(ctx, op, ins):
 
 @register_op("moe_router")
 def _moe_router(ctx, op, ins):
-    """Logits, probabilities, their top-k and both auxiliary losses in
-    float32: a routing decision made on rounded probabilities is another
-    decision.  `Load` is the number of (token, slot) assignments each
-    expert received; `moe_experts` takes it as its group sizes.
+    """Logits, scores, their top-k and both auxiliary losses in float32: a
+    routing decision made on rounded scores is another decision.  `Load` is
+    the number of (token, slot) assignments each expert received;
+    `moe_experts` takes it as its group sizes.
+
+    `scoring` "softmax" (the default): the scores are the softmax over the
+    experts.  "sigmoid": each expert's own sigmoid.  An input `Bias`
+    [experts] is added to the scores FOR THE CHOICE ONLY: the chosen experts'
+    weights are their unbiased scores, and `BiasMoved` counts the (token,
+    slot) choices that the unbiased top-k would not have made.  With
+    `norm_topk_prob` the weights are divided by (their sum + `norm_eps`);
+    `routed_scaling_factor` multiplies them.
 
     LoadBalanceLoss = E . sum_e f_e P_e with f_e the expert's share of the
-    T . k assignments (no gradient) and P_e its mean probability (1 when
-    both are uniform); ZLoss = mean_t logsumexp(logits_t)^2."""
+    T . k assignments (no gradient) and P_e its mean share of the token's
+    scores (1 when both are uniform); ZLoss = mean_t logsumexp(logits_t)^2."""
     x = first(ins, "X")
     w = first(ins, "W")
+    bias = first(ins, "Bias")
     k = op.attr("top_k")
     n_experts = w.shape[-1]
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     logits = jnp.dot(x2, w.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    top_p, top_i = jax.lax.top_k(probs, k)
+    if op.attr("scoring", "softmax") == "sigmoid":
+        _MON.counter("lowering.moe_router_sigmoid").inc()
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)   # the balance loss's shares
+    else:
+        scores = probs = jnp.exp(logits - lse[:, None])
+    outs = {}
+    if bias is None:
+        top_p, top_i = jax.lax.top_k(scores, k)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        _, unbiased = jax.lax.top_k(scores, k)
+        kept = jnp.any(top_i[:, :, None] == unbiased[:, None, :], axis=-1)
+        outs["BiasMoved"] = jnp.sum(~kept, dtype=jnp.int32).reshape((1,))
+    # an epsilon of 0 and a factor of 1 add no operation: the 2024 router's lowered text is what it was
     if op.attr("norm_topk_prob", False):
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        eps = op.attr("norm_eps", 0.0)
+        top_p = top_p / (total + eps if eps else total)
+    scaling = op.attr("routed_scaling_factor", 1.0)
+    if scaling != 1.0:
+        top_p = top_p * scaling
     load = jnp.sum(top_i[:, :, None] == jnp.arange(n_experts, dtype=top_i.dtype),
                    axis=(0, 1), dtype=jnp.int32)
     share = load.astype(jnp.float32) / float(top_i.size)
@@ -104,7 +138,77 @@ def _moe_router(ctx, op, ins):
         "Load": load,
         "LoadBalanceLoss": (n_experts * jnp.sum(share * jnp.mean(probs, axis=0))).reshape((1,)),
         "ZLoss": jnp.mean(jnp.square(lse)).reshape((1,)),
+        **outs,
     }
+
+
+def _shift_rows(t, back):
+    """t[:, s - back] over [b, T, .]: later by `back` positions with zeros
+    before the sequence's start, or for a negative `back` earlier, with zeros
+    past its end.  One `pad` of the operand itself, so that XLA reads the
+    shifted rows where it uses them (a pad of a computed product is
+    materialised, in float32: three passes more a tap)."""
+    if back == 0:
+        return t
+    return jax.lax.pad(t, jnp.zeros((), t.dtype), ((0, 0, 0), (back, -back, 0), (0, 0, 0)))
+
+
+def _short_conv_taps(x, w):
+    """(c = conv_K(B * u) in float32, each tap's shifted B * u) of the
+    in-projection x [b, T, 3d] = [B, C, u] and the filter w [d, K]: tap j
+    meets position t - (K - 1) + j."""
+    d, taps = w.shape
+    shifted = []
+    for j in range(taps):
+        rows = _shift_rows(x, taps - 1 - j)
+        shifted.append(rows[..., :d].astype(jnp.float32) * rows[..., 2 * d:].astype(jnp.float32))
+    return sum(z * w[:, j].astype(jnp.float32) for j, z in enumerate(shifted)), shifted
+
+
+@jax.custom_vjp
+def _gated_short_conv(x, w):
+    """C * conv_K(B * u), computed in float32 from x's dtype and rounded once."""
+    d = w.shape[0]
+    with jax.named_scope("gated_short_conv"):
+        return (x[..., d:2 * d].astype(jnp.float32) * _short_conv_taps(x, w)[0]).astype(x.dtype)
+
+
+def _gated_short_conv_bwd(res, g):
+    """The transpose written on shifted reads of x and g, as the forward is:
+    dc = g C; dz[s] = sum_j w_j dc[s + (K - 1) - j]; dB = dz u, du = dz B,
+    dC = g c; dw_j = sum dc . (B u)[. - (K - 1) + j].  Keeps x and the filter
+    only and makes the taps again."""
+    x, w = res
+    d, taps = w.shape
+    with jax.named_scope("gated_short_conv"):
+        c, shifted = _short_conv_taps(x, w)
+        gf = g.astype(jnp.float32)
+        dc = gf * x[..., d:2 * d].astype(jnp.float32)
+        d_w = jnp.stack([jnp.sum(dc * z, axis=(0, 1)) for z in shifted], axis=1).astype(w.dtype)
+        dz = sum(_shift_rows(g, j - (taps - 1)).astype(jnp.float32)
+                 * _shift_rows(x, j - (taps - 1))[..., d:2 * d].astype(jnp.float32)
+                 * w[:, j].astype(jnp.float32) for j in range(taps))
+        d_x = jnp.concatenate([(dz * x[..., 2 * d:].astype(jnp.float32)).astype(x.dtype),
+                               (gf * c).astype(x.dtype),
+                               (dz * x[..., :d].astype(jnp.float32)).astype(x.dtype)], axis=-1)
+    return d_x, d_w
+
+
+_gated_short_conv.defvjp(lambda x, w: (_gated_short_conv(x, w), (x, w)), _gated_short_conv_bwd)
+
+
+@register_op("short_conv")
+def _short_conv(ctx, op, ins):
+    """The gated short convolution between its two projections (which are
+    `mul` ops of the program): X [b, T, 3d] is the in-projection, split into
+    B, C and u; Out = C * conv_K(B * u) with one causal filter of K taps a
+    channel (`Filter` [d, K]; the last tap meets the current position), zeros
+    before the sequence's start.  Plain jax.numpy: K shifted multiply-adds
+    that XLA fuses into one pass forward, and a backward pass written the same
+    way (`_gated_short_conv_bwd`: the derived one pads computed products and
+    reads 2.3x the bytes; PERF.md, PR 34)."""
+    _MON.counter("lowering.short_conv_layers").inc()
+    return {"Out": _gated_short_conv(first(ins, "X"), first(ins, "Filter"))}
 
 
 def _take_rows(x, index):
@@ -301,9 +405,14 @@ def _moe_experts(ctx, op, ins):
 # products pass over the first `_held_rows_bound` of them, twice the share a
 # uniform router gives this chip.  What lies past the bound is a second, rarer
 # lowering in the same program (`jax.lax.cond`): the same chunk, checkpointed,
-# scanned over the rest of the order, so that no assignment to a held expert
-# is ever left out however skewed the router, and the common step neither runs
-# nor keeps anything of it.
+# scanned over the rest of the order `_HELD_REST_ROWS` at a time, so that no
+# assignment to a held expert is ever left out however skewed the router, and
+# the common step neither runs nor keeps anything of it.  The rest goes in
+# small passes because the step pays for a branch it never takes: XLA plans a
+# branch's temporaries into every step's heap, and `cost_analysis()` counts a
+# conditional's dearer branch (PERF.md, PR 34: at one sequence of LFM2's cell
+# the never-run branch is 50 of the 166 GB counted a step with a bound's rows a
+# pass, 27 with 2048).
 
 #: The bound as a multiple of the uniform share.  With weights N(0, 0.02) the
 #: masked positions of a block-diffusion batch (a quarter of all positions)
@@ -312,6 +421,10 @@ def _moe_experts(ctx, op, ins):
 #: 12.5%, where five are it is 25%; a bound under the share costs the step
 #: the rare path's recomputation, one over it costs gathers over empty rows.
 _HELD_ROWS_SLACK = 2.0
+
+
+#: Rows of one pass of the rare path: four of the grouped kernels' row tiles.
+_HELD_REST_ROWS = 2048
 
 
 def _held_rows_bound(assignments, count, num_experts):
@@ -378,54 +491,79 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
     tokens, k = top_i.shape
     assignments = tokens * k
     bound = _held_rows_bound(assignments, count, load.shape[0])
-    chunks = -(-assignments // bound)
+    rest = min(bound, _HELD_REST_ROWS)
+    chunks = -(-(assignments - bound) // rest)   # of the rare path
     expert = top_i.reshape(-1)
     local = jnp.where((expert >= first) & (expert < first + count), expert - first, count)
     order, weight = _sort_by_key(local.astype(jnp.int32), top_p.reshape(-1).astype(jnp.float32))
-    pad = chunks * bound - assignments   # rows past the last assignment belong to no token
+    pad = bound + chunks * rest - assignments   # rows past the last assignment belong to no token
     order = jnp.pad(order, (0, pad), constant_values=assignments)
     weight = jnp.pad(weight, (0, pad))
-    ends = jnp.cumsum(load[first:first + count])
+    sizes = load[first:first + count]
+    ends = jnp.cumsum(sizes)
     n_held = ends[-1]
 
-    def chunk(x2, weight, matrices, c):
-        """Rows [c . bound, (c + 1) . bound) of the order, as tokens' sums."""
-        lo = c * bound
-        rank = lo + jax.lax.iota(jnp.int32, bound)
-        mine = jax.lax.dynamic_slice(order, (lo,), (bound,))
+    def chunk(x2, weight, matrices, lo, n):
+        """Rows [lo, lo + n) of the order, as tokens' sums."""
+        rank = lo + jax.lax.iota(jnp.int32, n)
+        mine = jax.lax.dynamic_slice(order, (lo,), (n,))
         token = jnp.minimum(mine // k, tokens - 1)
         valid = rank < n_held
         target = jnp.where(valid, token, tokens)
-        sizes = jnp.clip(ends, lo, lo + bound) - jnp.clip(ends - load[first:first + count], lo, lo + bound)
+        groups = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - sizes, lo, lo + n)
         w_gate, w_up, w_down = matrices
         rows = _rows_of_tokens(x2, token, target, tokens)
         # a row no group covers comes out of the kernels as it lay in memory
         keep = valid[:, None]
-        gate = checkpoint_name(grouped_matmul(rows, w_gate, sizes, platform), "expert_gate")
-        up = checkpoint_name(grouped_matmul(rows, w_up, sizes, platform), "expert_up")
+        gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), "expert_gate")
+        up = checkpoint_name(grouped_matmul(rows, w_up, groups, platform), "expert_up")
         gate, up = (jnp.where(keep, t, 0).astype(jnp.float32) for t in (gate, up))
-        w = jax.lax.dynamic_slice(weight, (lo,), (bound,))[:, None]
+        w = jax.lax.dynamic_slice(weight, (lo,), (n,))[:, None]
         hidden = jnp.where(keep, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
-        down = grouped_matmul(hidden, w_down, sizes, platform)
+        down = grouped_matmul(hidden, w_down, groups, platform)
         return _add_to_tokens(down, token, target, tokens)
 
     # the common pass keeps the two products' outputs for the backward pass and
     # makes the rest again there (a gather, the masters' casts, one elementwise
     # pass): 335 MB a layer at SDAR's cell that no step has to hold
-    out = jax.checkpoint(chunk, static_argnums=(3,), policy=jax.checkpoint_policies.save_only_these_names(
-        "expert_gate", "expert_up"))(x2, weight, matrices, 0)
-    covered = jnp.minimum(n_held, bound)
-    if chunks > 1:
-        def the_rest(x2, weight, matrices):
-            def step(acc, c):
-                return acc + jax.checkpoint(chunk)(x2, weight, matrices, c), None
-            return jax.lax.scan(step, jnp.zeros_like(out), jnp.arange(1, chunks, dtype=jnp.int32))[0]
+    common = jax.checkpoint(lambda x2, weight, matrices: chunk(x2, weight, matrices, 0, bound),
+                            policy=jax.checkpoint_policies.save_only_these_names("expert_gate", "expert_up"))
+    if chunks == 0:
+        return common(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
 
-        out = out + jax.lax.cond(n_held > bound, the_rest,
-                                 lambda x2, weight, matrices: jnp.zeros_like(out),
-                                 x2, weight, matrices)
-        covered = n_held   # the rare path passes over every chunk there is
-    return out, n_held, n_held - covered
+    def the_rest(x2, weight, matrices):
+        def step(acc, lo):
+            return acc + jax.checkpoint(chunk, static_argnums=(4,))(x2, weight, matrices, lo, rest), None
+        return jax.lax.scan(step, jnp.zeros_like(x2), bound + rest * jnp.arange(chunks, dtype=jnp.int32))[0]
+
+    # Both passes' transpose is written out so that the rare one ADDS to what
+    # the common one made, inside its branch.  Derived, a conditional's two
+    # branches return the same residuals and the same cotangents, so the branch
+    # that does nothing returns zeros for them: a common step then writes the
+    # three matrices' shapes in float32 twice a layer, keeps one set from the
+    # forward pass to the backward pass and adds the other to the gradients
+    # (2.2 to 3.0 GB of the planned peak: PERF.md, PR 34).
+    def with_the_rest(out, *primals):
+        return jax.lax.cond(n_held > bound, lambda out: out + the_rest(*primals), lambda out: out, out)
+
+    @jax.custom_vjp
+    def passes(x2, weight, matrices):
+        return with_the_rest(common(x2, weight, matrices), x2, weight, matrices)
+
+    def passes_fwd(x2, weight, matrices):
+        out, pull = jax.vjp(common, x2, weight, matrices)
+        return with_the_rest(out, x2, weight, matrices), (pull, x2, weight, matrices)
+
+    def passes_bwd(res, g):
+        pull, *primals = res
+        return jax.lax.cond(
+            n_held > bound,
+            lambda grads: jax.tree.map(jnp.add, grads, jax.vjp(the_rest, *primals)[1](g)),
+            lambda grads: grads, pull(g))
+
+    passes.defvjp(passes_fwd, passes_bwd)
+    # the rare path passes over every chunk there is: no assignment is left out
+    return passes(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
 
 
 
@@ -449,10 +587,19 @@ def _publish_routing(step, values):
         record["held_rows_share"] = [float(np.asarray(h).sum() / v.sum())
                                      for h, v in zip(values["Held"], loads)]
         _MON.gauge("moe.held_rows_share").set(max(record["held_rows_share"]))
+    if values.get("BiasMoved"):
+        # routers with a bias on the choice: the share of the step's (token,
+        # slot) choices that the unbiased scores' top-k would not have made
+        record["bias_moved_share"] = [float(np.asarray(m).sum() / v.sum())
+                                      for m, v in zip(values["BiasMoved"], loads)]
+        _MON.gauge("moe.bias_moved_share").set(max(record["bias_moved_share"]))
     _MON.record_step(record)
 
 
+# one record a logged step from both ops of the layer: the experts' slots and
+# the router's `BiasMoved` reach the one `_publish_routing`
 set_step_stats("moe_experts", ("Load", "Dropped", "Held"), _publish_routing)
+set_step_stats("moe_router", ("BiasMoved",), _publish_routing)
 
 
 # -- build-time shape and dtype rules -----------------------------------------
@@ -489,6 +636,13 @@ def _infer_moe_router(ctx):
         ctx.fail(f"W must be ({xs[-1]}, experts), got {ws}")
     if not 1 <= k <= ws[1]:
         ctx.fail(f"top_k {k} of {ws[1]} experts")
+    if ctx.op.attr("scoring", "softmax") not in ("softmax", "sigmoid"):
+        ctx.fail(f"scoring {ctx.op.attr('scoring')!r} is neither softmax nor sigmoid")
+    bias = ctx.in_shape("Bias")
+    if bias is not None:
+        if tuple(bias) != (ws[1],):
+            ctx.fail(f"Bias must hold one value for each of {ws[1]} experts, got {bias}")
+        ctx.set_out("BiasMoved", (1,), "int32")
     ctx.set_out("TopKProb", tuple(xs[:-1]) + (k,), "float32")
     ctx.set_out("TopKIndex", tuple(xs[:-1]) + (k,), "int32")
     ctx.set_out("Load", (ws[1],), "int32")
@@ -519,7 +673,17 @@ def _infer_moe_experts(ctx):
     ctx.set_out("Dropped", (1,), "int32")
 
 
+def _infer_short_conv(ctx):
+    xs, ws = ctx.in_shape("X"), ctx.in_shape("Filter")
+    if xs is None or ws is None:
+        return
+    if len(xs) != 3 or xs[-1] % 3 or len(ws) != 2 or ws[0] * 3 != xs[-1] or ws[1] < 1:
+        ctx.fail(f"X must be (b, T, 3d) and Filter (d, K), got {xs} and {ws}")
+    ctx.set_out("Out", tuple(xs[:-1]) + (ws[0],), ctx.in_dtype("X"))
+
+
 _A.register_rule(["rms_norm"], _infer_rms_norm)
+_A.register_rule(["short_conv"], _infer_short_conv)
 _A.register_rule(["rotary_embedding"], _infer_rotary_embedding)
 _A.register_rule(["moe_router"], _infer_moe_router)
 _A.register_rule(["moe_experts"], _infer_moe_experts)
@@ -566,6 +730,15 @@ def _cost_moe_experts(ctx):
     return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
 
 
+def _cost_short_conv(ctx):
+    """Per output element the two gates' multiplies and K multiply-adds; the
+    traffic is the op's own: [b, T, 3d] read, [b, T, d] written."""
+    ws = ctx.in_shape("Filter")
+    taps = ws[1] if ws is not None else 3
+    return (2.0 + 2.0 * taps) * ctx.out_elems_total(), ctx.io_bytes()
+
+
+_RP.register_cost(["short_conv"], _cost_short_conv)
 _RP.register_elementwise_cost("rms_norm", flops_per_elem=6.0)
 _RP.register_elementwise_cost("rotary_embedding", flops_per_elem=6.0)
 _RP.register_cost(["moe_router"], _cost_moe_router)

@@ -78,14 +78,14 @@ def _step_stats(program):
         if op_def is None or op_def.step_stats is None:
             continue
         slots, publish = op_def.step_stats
-        names = found.setdefault(op.type, (publish, {slot: [] for slot in slots}))[1]
+        # op types that share a publish (a layer's router and its experts) make one record
+        names = found.setdefault(publish, {})
         for slot in slots:
             name = op.inputs.get(slot) or op.outputs.get(slot)
             if name:
-                names[slot].append(name[0])
-    # a slot no op of the type has (a layer that holds every expert has no `Held`) is not fetched
-    return [(publish, {slot: names for slot, names in by_slot.items() if names})
-            for publish, by_slot in found.values()]
+                names.setdefault(slot, []).append(name[0])
+    # a slot no op has (a layer that holds every expert has no `Held`) is not fetched
+    return [(publish, names) for publish, names in found.items() if names]
 
 
 def train_loop(

@@ -331,8 +331,14 @@ def held_lowering(c, x, top_p, w_gate, w_up, w_down, first=None, count=None):
 HELD_CASES = ["uniform", "all-held", "none-held", "one-expert-takes-all", "bound-met", "bound-passed-by-one"]
 
 
-@pytest.mark.parametrize("case", HELD_CASES)
-def test_held_experts_golden_forward_and_gradient_and_nothing_dropped(case):
+#: the cases past the bound with the rare path's pass cut to 128 rows: twelve passes, not three of the bound's 512
+SMALL_PASSES = ["all-held", "one-expert-takes-all", "bound-passed-by-one"]
+
+
+@pytest.mark.parametrize("case,rest_rows", [(c, None) for c in HELD_CASES] + [(c, 128) for c in SMALL_PASSES])
+def test_held_experts_golden_forward_and_gradient_and_nothing_dropped(case, rest_rows, monkeypatch):
+    if rest_rows:
+        monkeypatch.setattr(moe_ops, "_HELD_REST_ROWS", rest_rows)
     c = held_case(case)
     out = held_lowering(c, c.x, c.top_p, c.w_gate, c.w_up, c.w_down)
     held_rows = int(c.load[c.first:c.first + c.count].sum())
