@@ -37,7 +37,7 @@ class OpDef:
         self.lower = lower
         self.infer = infer
         self.cost = cost
-        self.step_stats = None  # (slots, publish): see set_step_stats
+        self.step_stats = None  # (slots, publish, attrs): see set_step_stats
 
 
 _REGISTRY: Dict[str, OpDef] = {}
@@ -83,15 +83,17 @@ def set_cost(type: str, cost: CostFn):
         ) from None
 
 
-def set_step_stats(type: str, slots, publish: StatsFn):
+def set_step_stats(type: str, slots, publish: StatsFn, attrs=()):
     """Attach statistics of a training step to a registered op: `slots`
     names inputs or outputs of the op whose values `pipeline.train_loop`
     fetches with the step and reads on LOGGED steps only (when the loss is
     read: no sync of their own); `publish(step, values)` then gets {slot:
     [one array per op of this type, in program order]} and sets the op's
-    gauges and step records on the monitor."""
+    gauges and step records on the monitor.  `attrs` names attributes of the
+    op that the statistics are read against: `values` holds them the same
+    way, [one value per op that has the attribute]."""
     try:
-        _REGISTRY[type].step_stats = (tuple(slots), publish)
+        _REGISTRY[type].step_stats = (tuple(slots), publish, tuple(attrs))
     except KeyError:
         raise KeyError(
             f"set_step_stats({type!r}): op has no registered lowering"

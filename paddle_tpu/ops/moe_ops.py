@@ -458,29 +458,78 @@ _sort_by_key.defvjp(
 # The held path's two row operations, each the other's transpose, as
 # `_rows_by_expert` and `_sum_by_token` are.  `token` [C] is the token of each
 # row of a chunk, `target` the same with the rows no held expert owns sent
-# past the last token, where a scatter drops them.
+# past the last token, where a scatter drops them, `live` the number of the
+# chunk's rows that a held expert owns (they come first).  Both cost what the
+# rows they are GIVEN cost, dropped or not (TPU v5e, 32768 rows of 2048 bf16:
+# a scatter-add 2.93 ms, a gather 0.71 in LFM2's step), and the bound is twice
+# what a uniform router sends: they are given the live rows' passes only.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of_tokens(x, token, target, tokens):
-    """Tokens [T, d] -> the chunk's rows [C, d]."""
-    return _take_rows(x, token)
+#: Passes the two row operations make over a chunk at the most.  Each count of
+#: passes is a branch of its own, compiled for its rows (80 bytes of code a
+#: row), so it is the NUMBER that is fixed and not the rows, and it is small.
+#: LFM2's cell, samples/s and warm `setup_s`, against 7.16-7.19 and 84-87 s for
+#: one pass over the bound: 16 passes (2048 rows at the step's bound of 32768)
+#: 7.47-7.51, but the step compiled in 312 s for 112 and the `for_test` clone
+#: (eight sequences: a bound of 131072, which was 64 passes) made an executable
+#: too large for the compile cache, so every run compiled it again, 355-383 s;
+#: 8 passes 7.44-7.54 and 92-95 s: the gain whole, `setup_s` 10% up, at its
+#: bound; 4 passes 7.33-7.42 and 86-89 s.  The layer alone reads the same with
+#: 2048, 4096 and 8192 rows a pass where they cover the same rows (18.36, 18.25,
+#: 18.02 ms, half the bound live); a coarser pass covers up to a pass more, at
+#: 68 ns a row and scatter-add, 20 and gather (tools/chip_held_experts.py;
+#: PERF.md, PR 35).
+_HELD_PASSES = 4
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _add_to_tokens(rows, token, target, tokens):
+def _pass_rows(n):
+    """Rows of one pass over a chunk of n: a `_HELD_PASSES`th, in whole lane tiles."""
+    return min(n, -(-n // (_HELD_PASSES * 128)) * 128)
+
+
+def _over_the_live_rows(n, live, over):
+    """`over(rows)` for the fewest whole passes' `rows` (`_pass_rows` a pass;
+    the last ends with the chunk) that hold the `live` first of a chunk's n
+    rows: one branch a count of passes, none to all.  The count is the step's
+    own, which nothing could differentiate and nothing has to: the row
+    operations' transposes are written.
+
+    One instruction over a prefix and not a loop over passes: on the chip a
+    pass of a loop costs twice a row what the one instruction does (LFM2's
+    layer forward and backward, the whole bound live: 27.0 ms with one
+    instruction, 27.1 so, 32.7 to 33.8 under a loop of 2048-row passes; half of
+    it live 19.9, 18.3, 20.0 to 20.6; tools/chip_held_experts.py)."""
+    a_pass = _pass_rows(n)
+    counts = [0] + list(range(a_pass, n, a_pass)) + [n]
+    return jax.lax.switch((live + a_pass - 1) // a_pass, [functools.partial(over, rows) for rows in counts])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of_tokens(x, token, target, live, tokens):
+    """Tokens [T, d] -> the chunk's rows [C, d]; zeros past the last pass."""
+    def over(rows):
+        return jnp.pad(_take_rows(x, token[:rows]), ((0, token.shape[0] - rows), (0, 0)))
+
+    return _over_the_live_rows(token.shape[0], live, over)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _add_to_tokens(rows, token, target, live, tokens):
     """The chunk's rows [C, d] -> tokens [T, d]: each token's rows summed,
     rows of no held expert dropped.  A token has one such row on average and
     eight at the most, and they are summed in the rows' dtype: a float32 copy
     of the rows for the scatter to read is 268 MB at SDAR's cell."""
-    return jnp.zeros((tokens, rows.shape[-1]), rows.dtype).at[target].add(rows, mode="drop")
+    def over(n):
+        return jnp.zeros((tokens, rows.shape[-1]), rows.dtype).at[target[:n]].add(rows[:n], mode="drop")
+
+    return _over_the_live_rows(token.shape[0], live, over)
 
 
 _rows_of_tokens.defvjp(
-    lambda x, token, target, tokens: (_take_rows(x, token), (token, target)),
-    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None))
+    lambda x, token, target, live, tokens: (_rows_of_tokens(x, token, target, live, tokens), (token, target, live)),
+    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None, None))
 _add_to_tokens.defvjp(
-    lambda rows, token, target, tokens: (_add_to_tokens(rows, token, target, tokens), (token, target)),
-    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None))
+    lambda rows, token, target, live, tokens: (_add_to_tokens(rows, token, target, live, tokens), (token, target, live)),
+    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None, None))
 
 
 def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
@@ -492,6 +541,7 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
     assignments = tokens * k
     bound = _held_rows_bound(assignments, count, load.shape[0])
     rest = min(bound, _HELD_REST_ROWS)
+    _MON.counter("lowering.held_row_passes").inc(-(-bound // _pass_rows(bound)))   # of a row operation, at the most
     chunks = -(-(assignments - bound) // rest)   # of the rare path
     expert = top_i.reshape(-1)
     local = jnp.where((expert >= first) & (expert < first + count), expert - first, count)
@@ -511,8 +561,9 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
         valid = rank < n_held
         target = jnp.where(valid, token, tokens)
         groups = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - sizes, lo, lo + n)
+        live = jnp.clip(n_held - lo, 0, n)
         w_gate, w_up, w_down = matrices
-        rows = _rows_of_tokens(x2, token, target, tokens)
+        rows = _rows_of_tokens(x2, token, target, live, tokens)
         # a row no group covers comes out of the kernels as it lay in memory
         keep = valid[:, None]
         gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), "expert_gate")
@@ -521,7 +572,7 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
         w = jax.lax.dynamic_slice(weight, (lo,), (n,))[:, None]
         hidden = jnp.where(keep, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
         down = grouped_matmul(hidden, w_down, groups, platform)
-        return _add_to_tokens(down, token, target, tokens)
+        return _add_to_tokens(down, token, target, live, tokens)
 
     # the common pass keeps the two products' outputs for the backward pass and
     # makes the rest again there (a gather, the masters' casts, one elementwise
@@ -584,9 +635,14 @@ def _publish_routing(step, values):
     if values.get("Held"):
         # layers that hold a share of their experts: the share of the step's
         # (token, slot) assignments that landed on them, per layer
-        record["held_rows_share"] = [float(np.asarray(h).sum() / v.sum())
-                                     for h, v in zip(values["Held"], loads)]
+        held = [int(np.asarray(h).sum()) for h in values["Held"]]
+        record["held_rows_share"] = [float(h / v.sum()) for h, v in zip(held, loads)]
         _MON.gauge("moe.held_rows_share").set(max(record["held_rows_share"]))
+        # ... and the rows the row operations' passes went over, of the bound's
+        bounds = [_held_rows_bound(int(v.sum()), count, v.size) for (_, count), v in zip(values["held"], loads)]
+        record["held_rows_passed_share"] = [
+            min(-(-h // _pass_rows(b)) * _pass_rows(b), b) / b for h, b in zip(held, bounds)]
+        _MON.gauge("moe.held_rows_passed_share").set(max(record["held_rows_passed_share"]))
     if values.get("BiasMoved"):
         # routers with a bias on the choice: the share of the step's (token,
         # slot) choices that the unbiased scores' top-k would not have made
@@ -598,7 +654,7 @@ def _publish_routing(step, values):
 
 # one record a logged step from both ops of the layer: the experts' slots and
 # the router's `BiasMoved` reach the one `_publish_routing`
-set_step_stats("moe_experts", ("Load", "Dropped", "Held"), _publish_routing)
+set_step_stats("moe_experts", ("Load", "Dropped", "Held"), _publish_routing, attrs=("held",))
 set_step_stats("moe_router", ("BiasMoved",), _publish_routing)
 
 
@@ -713,7 +769,9 @@ _ROW_PASSES = {"hidden": 7, "width": 6}
 def _cost_moe_experts(ctx):
     """Useful arithmetic of the three grouped products over the (token,
     slot) rows, 2 per multiply-add, whatever a kernel pads; traffic: every
-    expert's three matrices once and `_ROW_PASSES` over the rows."""
+    expert's three matrices once and `_ROW_PASSES` over the rows.  For a layer
+    that holds a share the rows are the bound's: an upper bound since the row
+    operations stop after the step's last live pass, which no plan can know."""
     gate = ctx.in_shape("WGate")
     if gate is None or ctx.in_shape("TopKIndex") is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()

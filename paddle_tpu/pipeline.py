@@ -68,24 +68,27 @@ class PipelineStats:
 
 
 def _step_stats(program):
-    """[(publish, {slot: [variable name per op]})] for every op type of the
-    program's main block that declares step statistics
-    (`core.registry.set_step_stats`); empty for most programs."""
+    """[(publish, {slot: [variable name per op]}, {attribute: [value per op]})]
+    for every op type of the program's main block that declares step
+    statistics (`core.registry.set_step_stats`); empty for most programs."""
     program = getattr(program, "program", program)  # a CompiledProgram wraps one
-    found = {}
+    found, constants = {}, {}
     for op in program.global_block().ops:
         op_def = get_op_def_or_none(op.type)
         if op_def is None or op_def.step_stats is None:
             continue
-        slots, publish = op_def.step_stats
+        slots, publish, attrs = op_def.step_stats
         # op types that share a publish (a layer's router and its experts) make one record
         names = found.setdefault(publish, {})
         for slot in slots:
             name = op.inputs.get(slot) or op.outputs.get(slot)
             if name:
                 names.setdefault(slot, []).append(name[0])
+        for attr in attrs:
+            if attr in op.attrs:
+                constants.setdefault(publish, {}).setdefault(attr, []).append(op.attrs[attr])
     # a slot no op has (a layer that holds every expert has no `Held`) is not fetched
-    return [(publish, names) for publish, names in found.items() if names]
+    return [(publish, names, constants.get(publish, {})) for publish, names in found.items() if names]
 
 
 def train_loop(
@@ -148,7 +151,7 @@ def train_loop(
     stats = PipelineStats()
     n_user = len(fetch_list)
     step_stats = _step_stats(program)
-    fetch_list = list(fetch_list) + [name for _, names in step_stats
+    fetch_list = list(fetch_list) + [name for _, names, _ in step_stats
                                      for slot in names.values() for name in slot]
     # (step index, [FetchHandle, ...], seconds in next(it), in run_async)
     inflight: deque = deque()
@@ -194,9 +197,9 @@ def train_loop(
         if must_resolve and step_stats:
             if want_log and _MON.enabled:
                 extra = iter(vals[n_user:])
-                for publish, names in step_stats:
-                    publish(step_i, {slot: [next(extra) for _ in slot_names]
-                                     for slot, slot_names in names.items()})
+                for publish, names, constants in step_stats:
+                    publish(step_i, {**constants, **{slot: [next(extra) for _ in slot_names]
+                                                     for slot, slot_names in names.items()}})
             vals = vals[:n_user]
         if want_log:
             if on_logged is not None:

@@ -327,8 +327,13 @@ def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(c
     assignments exist as vectors only (the sort's keys, order and weights);
     every two-dimensional array of rows, in the common pass and in the rare
     path's loop alike, has the bound's 32768 rows (twice the uniform share:
-    `ops.moe_ops._held_rows_bound`) or the tokens' 16384, forward and backward."""
-    from paddle_tpu.ops.moe_ops import _held_rows_bound
+    `ops.moe_ops._held_rows_bound`) or the tokens' 16384, forward and backward.
+    Since PR 35 the gathers write and the scatter-adds read a whole number of
+    passes, from one to four (8192 rows each over the bound, 512 over a chunk
+    of the rare path's 2048), each count a branch of a conditional that the
+    step's own count of held rows picks: rows that belong to no token cost
+    nothing past the last pass that holds a live one."""
+    from paddle_tpu.ops.moe_ops import _HELD_REST_ROWS, _held_rows_bound, _pass_rows
 
     tokens, hidden, width, experts, k, held = SDAR_EXPERTS
     bound = _held_rows_bound(tokens * k, held, experts)
@@ -354,6 +359,13 @@ def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(c
     assert max(rows_of) == bound, rows_of
     assert not re.findall(r"\[%d,\d+" % (tokens * k), text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+    shape_of = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text))
+    gathered = [shape for shape in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text) if shape.endswith(",%d" % hidden)]
+    added = [shape_of[updates] for updates in re.findall(r" scatter\(%\S+, %\S+, %([^\s,)]+)\)", text)]
+    added = [shape for shape in added if shape.endswith(",%d" % hidden)]   # the rows'; the kernels' group metadata scatters too
+    assert (_pass_rows(bound), _pass_rows(_HELD_REST_ROWS)) == (8192, 512)
+    passes = {"%d,%d" % (rows, hidden) for n in (bound, _HELD_REST_ROWS) for rows in range(_pass_rows(n), n + 1, _pass_rows(n))}
+    assert len(passes) == 8 and set(gathered) == passes and set(added) == passes, (gathered, added)
 
 
 #: LFM2-8B-A1B's cell: a sequence of 8192 positions at hidden size 2048, three taps

@@ -545,8 +545,13 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     test's own code.  Its StableHLO for the chip (the kernels' serialised
     bodies stripped) was the parent's too (sha256 1a79d422...) until
     `_held_experts`' rare path was rewritten (2048 rows a pass, its transpose
-    written out: ops/moe_ops.py); the router, the block builder and the common
-    pass lower as before, and the hash below pins the whole again."""
+    written out: ops/moe_ops.py; sha256 1f8e552e...), and that one's until PR 35
+    gave the held path's gathers and scatter-adds the live rows' passes only
+    (`_over_the_live_rows`: a conditional with one branch a count of passes,
+    four of 8192 rows at the most, where one instruction over the bound
+    stood, and `live` among the chunk's values); the op listing, the fetches, the router, the block
+    builder, the sort, the kernels and the rare path's conditional lower as
+    before, and the hash below pins the whole again."""
     import hashlib
     import re
 
@@ -565,7 +570,7 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     for v in startup.global_block().vars.values():
         if v.persistable:
             scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    fetch = [loss.name] + [n for _, names in pipeline._step_stats(main) for slot in names.values() for n in slot]
+    fetch = [loss.name] + [n for _, names, _ in pipeline._step_stats(main) for slot in names.values() for n in slot]
     assert len(fetch) == 13   # the loss, and Load, Dropped and Held of four layers
     b, length = job["batch_per_chip"], job["seq_len"]
     feeds = {"ids": jax.ShapeDtypeStruct((b, 2 * length), np.int32), "labels": jax.ShapeDtypeStruct((b, length), np.int32),
@@ -581,4 +586,4 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     found = (step.module, hashlib.sha256(listing.encode()).hexdigest(), hashlib.sha256(text.encode()).hexdigest())
     print(found)
     assert found == ("train_6de7c714", "bc7cad00c44ec7d55d9ad0458b439478849ada1e810ed87a3ceddfbb31a7734b",
-                     "1f8e552e72d78cdda7d54074e08168ebfcefc7ae703db4b94e38ad5c8396368c")
+                     "ce85b78d0623c69893abf9412e938bd0d28d803c85ca3f658fce117c254018e6")

@@ -593,8 +593,8 @@ def test_a_program_without_experts_fetches_nothing_more():
     assert pipeline._step_stats(main) == []
     lm = transformer.build_causal_lm(vocab_size=64, seq_len=8, d_model=32, n_layers=3, n_heads=2,
                                      expert_width=16, num_experts=4, top_k=2, with_optimizer=False)[0]
-    (publish, names), = pipeline._step_stats(fluid.CompiledProgram(lm))
-    assert publish is moe_ops._publish_routing
+    (publish, names, attrs), = pipeline._step_stats(fluid.CompiledProgram(lm))
+    assert publish is moe_ops._publish_routing and attrs == {}   # no layer holds a share: no `held` to read against
     assert list(names) == ["Load", "Dropped"] and len(names["Load"]) == len(names["Dropped"]) == 3
 
 

@@ -298,8 +298,9 @@ def held_case(name, tokens=1024, experts=16, k=2, first=4, count=2):
     """Routings a held layer has to get right, 2048 assignments of which the
     bound covers 512: a uniform router (an eighth of the rows held), one that
     sends every token to held experts (four times the bound: the rare path
-    runs every chunk), one that sends none, one expert taking all, and a bound
-    that is met to the row or passed by one."""
+    runs every chunk), one that sends none, one expert taking all, a bound
+    that is met to the row or passed by one, and held rows that end inside a
+    pass of 128 of the two row operations and on its edge."""
     rng = np.random.RandomState(len(name))
     if name == "uniform":
         top_i = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
@@ -310,7 +311,7 @@ def held_case(name, tokens=1024, experts=16, k=2, first=4, count=2):
     elif name == "one-expert-takes-all":
         top_i = np.tile([first + 1, 0], (tokens, 1))
     else:
-        held_rows = {"bound-met": 512, "bound-passed-by-one": 513}[name]
+        held_rows = {"bound-met": 512, "bound-passed-by-one": 513, "ends-inside-a-pass": 200, "ends-on-a-passes-edge": 256}[name]
         top_i = np.tile([0, 1], (tokens, 1))
         top_i.reshape(-1)[rng.permutation(tokens * k)[:held_rows]] = first + np.arange(held_rows) % count
     top_i = top_i.astype("int32")
@@ -333,12 +334,22 @@ HELD_CASES = ["uniform", "all-held", "none-held", "one-expert-takes-all", "bound
 
 #: the cases past the bound with the rare path's pass cut to 128 rows: twelve passes, not three of the bound's 512
 SMALL_PASSES = ["all-held", "one-expert-takes-all", "bound-passed-by-one"]
+#: the common path's row operations go over the bound's 512 rows in passes of 128 (`_pass_rows`, PR 35): in the
+#: cases above the held rows end before the first pass (none-held: no pass runs), inside the second (uniform)
+#: and with the fourth (bound-met: the bound, to the row); in these inside the second pass and on its edge
+ROW_PASSES = ["ends-inside-a-pass", "ends-on-a-passes-edge"]
+#: ... and in passes of 384: the bound is no whole number of them, and the second pass ends with it
+RAGGED_PASSES = ["bound-met", "ends-inside-a-pass"]
 
 
-@pytest.mark.parametrize("case,rest_rows", [(c, None) for c in HELD_CASES] + [(c, 128) for c in SMALL_PASSES])
-def test_held_experts_golden_forward_and_gradient_and_nothing_dropped(case, rest_rows, monkeypatch):
+@pytest.mark.parametrize("case,rest_rows,pass_rows", [(c, None, None) for c in HELD_CASES + ROW_PASSES]
+                         + [(c, 128, None) for c in SMALL_PASSES] + [(c, None, 384) for c in RAGGED_PASSES])
+def test_held_experts_golden_forward_and_gradient_and_nothing_dropped(case, rest_rows, pass_rows, monkeypatch):
     if rest_rows:
         monkeypatch.setattr(moe_ops, "_HELD_REST_ROWS", rest_rows)
+    if pass_rows:
+        monkeypatch.setattr(moe_ops, "_pass_rows", lambda n: min(n, pass_rows))
+    assert pass_rows or moe_ops._pass_rows(512) == 128
     c = held_case(case)
     out = held_lowering(c, c.x, c.top_p, c.w_gate, c.w_up, c.w_down)
     held_rows = int(c.load[c.first:c.first + c.count].sum())
@@ -354,6 +365,43 @@ def test_held_experts_golden_forward_and_gradient_and_nothing_dropped(case, rest
     want = jax.grad(lambda x, p, *w: jnp.sum(held_golden(x, p, c.top_i, *w, c.first) * weight), range(5))(*args)
     for g, w in zip(got, want):
         agree(g, w, tol=1e-5)
+    if case == "none-held":   # no token chose a held expert: no pass runs, and nothing is anything but zero
+        assert not np.asarray(out["Out"]).any() and not any(np.asarray(g).any() for g in got)
+
+
+@pytest.mark.parametrize("rows_a_pass", [8, 5, 24, 64])
+def test_the_two_row_operations_are_each_others_transpose_whatever_is_live(rows_a_pass, monkeypatch):
+    """`_rows_of_tokens` is R x and `_add_to_tokens` R^T rows for the [C, T]
+    one-hot R of the chunk's LIVE rows, for every count of them from none to
+    the chunk's 24, with passes of 8 rows, of 5 (the last ends with the
+    chunk), of the chunk's own 24 and of more: forward and `jax.vjp`, float32.
+    Rows past the live ones belong to no token: R has no row for them, a
+    gather leaves zeros past its last pass, and what it fetches between the
+    last live row and that pass's end is masked where it is used (`keep`)."""
+    monkeypatch.setattr(moe_ops, "_pass_rows", lambda n: min(n, rows_a_pass))
+    rng = np.random.RandomState(rows_a_pass)
+    chunk, tokens, d = 24, 10, 4
+    token = rng.randint(0, tokens, chunk).astype("int32")
+    x, rows = rng.randn(tokens, d).astype("f4"), rng.randn(chunk, d).astype("f4")
+    g_rows, g_tokens = rng.randn(chunk, d).astype("f4"), rng.randn(tokens, d).astype("f4")
+
+    @jax.jit
+    def both(live):
+        target = jnp.where(jnp.arange(chunk) < live, token, tokens)
+        gathered, pull_x = jax.vjp(lambda x: moe_ops._rows_of_tokens(x, token, target, live, tokens), x)
+        added, pull_rows = jax.vjp(lambda r: moe_ops._add_to_tokens(r, token, target, live, tokens), rows)
+        return gathered, pull_x(g_rows)[0], added, pull_rows(g_tokens)[0]
+
+    for live in range(chunk + 1):
+        one_hot = (np.arange(chunk)[:, None] < live) * (token[:, None] == np.arange(tokens)[None, :]).astype("f4")
+        gathered, d_x, added, d_rows = both(jnp.int32(live))
+        is_live = np.arange(chunk)[:, None] < live
+        passed = min(-(-live // min(rows_a_pass, chunk)) * min(rows_a_pass, chunk), chunk)
+        agree(np.where(is_live, gathered, 0), one_hot @ x)
+        assert not np.asarray(gathered[passed:]).any() and not np.asarray(d_rows[passed:]).any()   # zeros past the last pass
+        agree(d_x, one_hot.T @ g_rows)
+        agree(added, one_hot.T @ rows)
+        agree(np.where(is_live, d_rows, 0), one_hot @ g_tokens)
 
 
 def test_the_eight_shares_of_a_layer_add_up_to_the_layer():
@@ -419,6 +467,9 @@ def test_layers_moe_with_a_share_held_declares_its_parameters_and_refuses_no_ran
 
 
 def test_the_cost_row_charges_the_held_rows_and_the_bounds_passes():
+    """The traffic is an upper bound since PR 35: the row operations go over
+    the bound in passes and stop after the last that holds a live row, a count
+    the step's routing gives and the plan cannot know, so the row stays the bound's."""
     from paddle_tpu.core import resource_plan
 
     tokens, d, f, experts, k = 256, 16, 8, 32, 8
@@ -715,7 +766,8 @@ def test_the_stage_readings_a_precision_lower_lie_over_their_limits():
 
 # -- (f) through train_loop -----------------------------------------------------------
 
-def test_steps_through_train_loop_publish_the_share_of_the_rows_that_were_held():
+def _logged_routing(steps=6):
+    """The `moe_routing` records and the monitor's counters of `steps` steps of the tiny bf16 model through `train_loop`."""
     import itertools
 
     from paddle_tpu.core import unique_name
@@ -729,16 +781,42 @@ def test_steps_through_train_loop_publish_the_share_of_the_rows_that_were_held()
         ring = [sdar.make_batch(rng, cfg, job, 4) for _ in range(3)]
         compiled = monitor.MONITOR.counter_values().get("executor.recompile", 0)
         stats = fluid.train_loop(exe, main, itertools.cycle(ring), [loss], scope=scope,
-                                 max_inflight=2, log_period=2, max_steps=6)
-        assert stats.steps == 6 and monitor.MONITOR.counter_values()["executor.recompile"] == compiled + 1
+                                 max_inflight=2, log_period=2, max_steps=steps)
+        assert stats.steps == steps and monitor.MONITOR.counter_values()["executor.recompile"] == compiled + 1
         records = [r for r in monitor.MONITOR.step_records() if r.get("kind") == "moe_routing"]
-        assert [r["pipeline_step"] for r in records] == [0, 2, 4]
-        for r in records:
-            assert r["dropped_tokens"] == 0 and len(r["held_rows_share"]) == 2
-            assert all(0.0 < s < 1.0 for s in r["held_rows_share"])
-        assert monitor.MONITOR.gauge("moe.held_rows_share").value == max(records[-1]["held_rows_share"])
-        counted = monitor.MONITOR.counter_values()
-        assert counted["lowering.attention_xla"] >= 2 and not counted.get("lowering.attention_block_sparse")
+        gauges = {name: monitor.MONITOR.gauge(name).value for name in ("moe.held_rows_share", "moe.held_rows_passed_share")}
+        return records, monitor.MONITOR.counter_values(), gauges
     finally:
         monitor.disable()
         monitor.reset()
+
+
+def test_steps_through_train_loop_publish_the_share_of_the_rows_that_were_held():
+    records, counted, gauges = _logged_routing()
+    assert [r["pipeline_step"] for r in records] == [0, 2, 4]
+    for r in records:
+        assert r["dropped_tokens"] == 0 and len(r["held_rows_share"]) == 2
+        assert all(0.0 < s < 1.0 for s in r["held_rows_share"])
+    assert gauges["moe.held_rows_share"] == max(records[-1]["held_rows_share"])
+    assert counted["lowering.attention_xla"] >= 2 and not counted.get("lowering.attention_block_sparse")
+
+
+def test_steps_through_train_loop_publish_the_share_of_the_bound_that_the_row_passes_went_over():
+    """Beside `held_rows_share` the record holds, per layer, the rows the two
+    row operations' passes went over as a share of the bound's:
+    ceil(Held / P) P / bound, from the `Held` the step fetched already and the
+    op's own `held` attribute.  The tiny model has 512 (token, slot)
+    assignments a layer and a bound of 512 rows, so P is 128 and the share a
+    whole number of quarters, the first that covers the held rows."""
+    records, counted, gauges = _logged_routing(steps=4)
+    assignments = JOB["batch_per_chip"] * 2 * JOB["seq_len"] * TINY["num_experts_per_tok"]
+    bound = moe_ops._held_rows_bound(assignments, TINY["num_experts"], TINY["num_routed_experts"])
+    assert (assignments, bound, moe_ops._pass_rows(bound)) == (512, 512, 128) and len(records) == 2
+    for r in records:
+        held = [round(share * assignments) for share in r["held_rows_share"]]
+        assert all(0 < h < bound for h in held)
+        assert r["held_rows_passed_share"] == [-(-h // 128) * 128 / bound for h in held]
+        assert all(passed >= h / bound for passed, h in zip(r["held_rows_passed_share"], held))
+    assert gauges["moe.held_rows_passed_share"] == max(records[-1]["held_rows_passed_share"])
+    # two layers, traced once for the step: each bound makes four passes of 128 at the most
+    assert counted["lowering.held_row_passes"] % 8 == 0 and counted["lowering.held_row_passes"] >= 8
