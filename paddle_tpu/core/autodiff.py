@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
+from ..monitor import MONITOR as _MON
 from .program import Parameter, Variable
 
 GRAD_SUFFIX = "@GRAD"
@@ -33,39 +34,40 @@ def append_backward(
 ) -> List[Tuple[Variable, Variable]]:
     block = loss.block
     program = block.program
-    no_grad = set()
-    for item in no_grad_set or ():
-        no_grad.add(item.name if isinstance(item, Variable) else str(item))
+    with _MON.span("program.backward", program=program._uuid[:8]):
+        no_grad = set()
+        for item in no_grad_set or ():
+            no_grad.add(item.name if isinstance(item, Variable) else str(item))
 
-    if parameter_list is not None:
-        params = []
-        for p in parameter_list:
-            params.append(block.var(p) if isinstance(p, str) else p)
-    else:
-        params = [p for p in program.all_parameters() if p.trainable]
-    params = [p for p in params if p.name not in no_grad]
-    if not params:
-        raise ValueError("append_backward: no trainable parameters found")
+        if parameter_list is not None:
+            params = []
+            for p in parameter_list:
+                params.append(block.var(p) if isinstance(p, str) else p)
+        else:
+            params = [p for p in program.all_parameters() if p.trainable]
+        params = [p for p in params if p.name not in no_grad]
+        if not params:
+            raise ValueError("append_backward: no trainable parameters found")
 
-    param_names = [p.name for p in params]
-    grad_names = [_grad_name(n) for n in param_names]
-    grads = []
-    for p, gname in zip(params, grad_names):
-        g = block.create_var(gname, shape=p.shape, dtype=p.dtype)
-        grads.append(g)
+        param_names = [p.name for p in params]
+        grad_names = [_grad_name(n) for n in param_names]
+        grads = []
+        for p, gname in zip(params, grad_names):
+            g = block.create_var(gname, shape=p.shape, dtype=p.dtype)
+            grads.append(g)
 
-    block.append_op(
-        "backward",
-        inputs={"Loss": [loss.name]},
-        outputs={"Grads": grad_names},
-        attrs={
-            "loss_name": loss.name,
-            "param_names": param_names,
-            "grad_names": grad_names,
-            "sparse_param_names": _find_sparse_params(block, param_names),
-        },
-    )
-    return list(zip(params, grads))
+        block.append_op(
+            "backward",
+            inputs={"Loss": [loss.name]},
+            outputs={"Grads": grad_names},
+            attrs={
+                "loss_name": loss.name,
+                "param_names": param_names,
+                "grad_names": grad_names,
+                "sparse_param_names": _find_sparse_params(block, param_names),
+            },
+        )
+        return list(zip(params, grads))
 
 
 def _find_sparse_params(block, param_names) -> List[str]:

@@ -14,12 +14,10 @@ donated to the executable each step, so parameter updates are in-place in HBM.
 from __future__ import annotations
 
 import hashlib
-import threading
 import time
 from typing import Dict, List, Optional, Sequence, Union
 
 import jax
-import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 
@@ -135,31 +133,6 @@ def _structure_digest(program: Program, *more) -> str:
         (op.type, tuple(op.input_arg_names), tuple(op.output_arg_names))
         for blk in program.blocks for op in blk.ops)
     return hashlib.sha1(repr((structure,) + more).encode()).hexdigest()[:8]
-
-
-# Did JAX's persistent compilation cache serve a compile?  The one event it
-# fires on a hit, counted per thread so that a compile on another thread is
-# not taken for this one's.  (`/jax/compilation_cache/cache_misses` fires
-# only when an entry is WRITTEN, which the minimum-compile-time rule
-# suppresses: a compile without the hit event is the miss.)
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_cache_hits_seen = threading.local()
-_cache_listener_on = False
-
-
-def _on_jax_event(event: str, **_):
-    if event == _CACHE_HIT_EVENT:
-        _cache_hits_seen.n = getattr(_cache_hits_seen, "n", 0) + 1
-
-
-def _listen_for_cache_hits():
-    """Registered on the first build the monitor watches.  Two threads
-    racing here register it twice at worst: a hit is then counted twice on
-    its thread, and is still a hit."""
-    global _cache_listener_on
-    if not _cache_listener_on:
-        _cache_listener_on = True
-        jax.monitoring.register_event_listener(_on_jax_event)
 
 
 class _CompiledStep:
@@ -671,12 +644,12 @@ class _CompiledStep:
                     lowering.annotate(moe_layers=self.moe_layers)
             t1 = time.perf_counter()
             with _MON.span("executor.compile", **what) as compiling:
-                if mon_on:
-                    _listen_for_cache_hits()
-                    hits0 = getattr(_cache_hits_seen, "n", 0)
+                # did JAX's persistent cache serve it?  The monitor hears
+                # the one event JAX fires on a hit, counted per thread
+                hits0 = _MON.jax_cache_hits()
                 built = lowered.compile()
                 if mon_on:
-                    hit = getattr(_cache_hits_seen, "n", 0) > hits0
+                    hit = _MON.jax_cache_hits() > hits0
                     compiling.annotate(cache_hit=hit)
                     _MON.counter("executor.compile_cache_hit" if hit
                                  else "executor.compile_cache_miss").inc()
@@ -1037,65 +1010,69 @@ class Executor:
                 _MON.counter("executor.cache_hit").inc()
         cache_hit = compiled is not None
         if compiled is None:
-            mesh_platform = (
-                mesh.devices.flat[0].platform if mesh is not None else device.platform
-            )
-            # Static analysis ahead of lowering (FLAGS_verify_program):
-            # once per compile-cache miss, so steady state pays nothing.
-            # A malformed program raises a classified error naming the
-            # op/var/block here instead of dying inside JAX tracing.
-            from ..flags import flag as _flagv
-
-            verify_level = _flagv("FLAGS_verify_program")
-            if verify_level not in ("", "off"):
-                from .analysis import check_program
-
-                with _MON.span("analysis.verify", program=program._uuid[:8]):
-                    check_program(program, level=verify_level,
-                                  feed_names=list(jfeeds),
-                                  fetch_names=fetch_names)
-            if mesh is None:
-                # Static OOM pre-check (FLAGS_resource_precheck): the
-                # liveness plan predicts peak HBM for THIS (program, feed
-                # shapes) pair and raises classified ResourceError naming
-                # the watermark ops when it exceeds
-                # FLAGS_resource_hbm_limit_mb — before the trace/compile
-                # below allocates anything.  Mesh runs skip it: per-device
-                # residency depends on sharding, which the single-device
-                # plan would overstate.
-                from .resource_plan import precheck_program
-
-                with _MON.span("analysis.plan", program=program._uuid[:8]):
-                    precheck_program(
-                        program,
-                        {n: np.shape(v) for n, v in jfeeds.items()},
-                        fetch_names, steps=steps)
-            with _MON.span("executor.build", program=program._uuid[:8]) as building:
-                compiled = _CompiledStep(
-                    program, list(jfeeds), fetch_names, scope,
-                    mesh=mesh, batch_axis=batch_axis,
-                    feed_shapes={n: v.shape for n, v in jfeeds.items()},
-                    n_steps=steps, remat=remat, platform=mesh_platform,
-                    local_sgd=bool(local_sgd_every),
-                    grad_overlap=grad_overlap,
+            # the whole miss path is one span: what a cold call pays
+            # before its first dispatch (verify, plan, the step builder
+            # and the bookkeeping between them)
+            with _MON.span("executor.prepare", program=program._uuid[:8]):
+                mesh_platform = (
+                    mesh.devices.flat[0].platform if mesh is not None else device.platform
                 )
-                building.annotate(module=compiled.module)
-            with self._cache_lock:
-                existing = self._cache.get(cache_key)
-                if existing is not None:
-                    # a racing thread built this signature while we did:
-                    # adopt its entry so the signature keeps ONE
-                    # _CompiledStep (its _build_lock then keeps XLA
-                    # compiles single too); our duplicate build was cheap
-                    # (no trace/compile happens until _dispatch)
-                    compiled = existing
-                    cache_hit = True
-                    _MON.counter("executor.cache_hit").inc()
-                else:
-                    _MON.counter("executor.cache_miss").inc()
-                    self._cache[cache_key] = compiled
-                    if len(self._cache) > _flagv("FLAGS_executor_cache_capacity"):  # LRU evict
-                        self._cache.pop(next(iter(self._cache)))
+                # Static analysis ahead of lowering (FLAGS_verify_program):
+                # once per compile-cache miss, so steady state pays nothing.
+                # A malformed program raises a classified error naming the
+                # op/var/block here instead of dying inside JAX tracing.
+                from ..flags import flag as _flagv
+
+                verify_level = _flagv("FLAGS_verify_program")
+                if verify_level not in ("", "off"):
+                    from .analysis import check_program
+
+                    with _MON.span("analysis.verify", program=program._uuid[:8]):
+                        check_program(program, level=verify_level,
+                                      feed_names=list(jfeeds),
+                                      fetch_names=fetch_names)
+                if mesh is None:
+                    # Static OOM pre-check (FLAGS_resource_precheck): the
+                    # liveness plan predicts peak HBM for THIS (program, feed
+                    # shapes) pair and raises classified ResourceError naming
+                    # the watermark ops when it exceeds
+                    # FLAGS_resource_hbm_limit_mb — before the trace/compile
+                    # below allocates anything.  Mesh runs skip it: per-device
+                    # residency depends on sharding, which the single-device
+                    # plan would overstate.
+                    from .resource_plan import precheck_program
+
+                    with _MON.span("analysis.plan", program=program._uuid[:8]):
+                        precheck_program(
+                            program,
+                            {n: np.shape(v) for n, v in jfeeds.items()},
+                            fetch_names, steps=steps)
+                with _MON.span("executor.build", program=program._uuid[:8]) as building:
+                    compiled = _CompiledStep(
+                        program, list(jfeeds), fetch_names, scope,
+                        mesh=mesh, batch_axis=batch_axis,
+                        feed_shapes={n: v.shape for n, v in jfeeds.items()},
+                        n_steps=steps, remat=remat, platform=mesh_platform,
+                        local_sgd=bool(local_sgd_every),
+                        grad_overlap=grad_overlap,
+                    )
+                    building.annotate(module=compiled.module)
+                with self._cache_lock:
+                    existing = self._cache.get(cache_key)
+                    if existing is not None:
+                        # a racing thread built this signature while we did:
+                        # adopt its entry so the signature keeps ONE
+                        # _CompiledStep (its _build_lock then keeps XLA
+                        # compiles single too); our duplicate build was cheap
+                        # (no trace/compile happens until _dispatch)
+                        compiled = existing
+                        cache_hit = True
+                        _MON.counter("executor.cache_hit").inc()
+                    else:
+                        _MON.counter("executor.cache_miss").inc()
+                        self._cache[cache_key] = compiled
+                        if len(self._cache) > _flagv("FLAGS_executor_cache_capacity"):  # LRU evict
+                            self._cache.pop(next(iter(self._cache)))
 
         # one tail for both modes; mon_on guards only the records and the
         # block to completion, so the disabled fast path stays branch-only
@@ -1114,7 +1091,6 @@ class Executor:
         feed_bytes = 0
         if mon_on:
             feed_bytes = int(sum(getattr(v, "nbytes", 0) for v in jfeeds.values()))
-            _MON.counter("executor.feed_bytes").inc(feed_bytes)
             # dispatch-attempt census BEFORE the (possibly collective-
             # blocking) dispatch: the heartbeat's beat payload reads this,
             # and it is what makes a slow-but-alive rank's lag visible

@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..monitor import MONITOR as _MON
 from . import unique_name
 from .dtypes import canonical_dtype
 
@@ -391,20 +392,23 @@ class Program:
         in the reference (framework.py:2752 area)."""
         import uuid
 
-        p = copy.deepcopy(self)
-        p._uuid = uuid.uuid4().hex
-        if for_test:
-            for blk in p.blocks:
-                cut = None
-                for i, op in enumerate(blk.ops):
-                    if op.type == "backward":
-                        cut = i
-                        break
-                    if "is_test" in op.attrs:
-                        op.attrs["is_test"] = True
-                if cut is not None and blk.idx == 0:
-                    blk.ops = blk.ops[:cut]
-        p._bump()
+        with _MON.span("program.clone", source=self._uuid[:8],
+                       for_test=for_test) as cloning:
+            p = copy.deepcopy(self)
+            p._uuid = uuid.uuid4().hex
+            cloning.annotate(program=p._uuid[:8])
+            if for_test:
+                for blk in p.blocks:
+                    cut = None
+                    for i, op in enumerate(blk.ops):
+                        if op.type == "backward":
+                            cut = i
+                            break
+                        if "is_test" in op.attrs:
+                            op.attrs["is_test"] = True
+                    if cut is not None and blk.idx == 0:
+                        blk.ops = blk.ops[:cut]
+            p._bump()
         return p
 
     def to_dict(self) -> dict:
@@ -500,11 +504,22 @@ def program_guard(main_program: Program, startup_program: Optional[Program] = No
     _main_program = main_program
     if startup_program is not None:
         _startup_program = startup_program
-    try:
-        yield
-    finally:
-        _main_program = old_main
-        _startup_program = old_startup
+    # every layer call of a model runs under this `with`: with the monitor
+    # on it is the `program.build` span (a nested guard's is its child)
+    mon_on = _MON.enabled
+    ops0 = _op_count(main_program) if mon_on else 0
+    with _MON.span("program.build", program=main_program._uuid[:8]) as building:
+        try:
+            yield
+        finally:
+            _main_program = old_main
+            _startup_program = old_startup
+            if mon_on:
+                building.annotate(ops=_op_count(main_program) - ops0)
+
+
+def _op_count(program: Program) -> int:
+    return sum(len(blk.ops) for blk in program.blocks)
 
 
 def switch_main_program(program: Program) -> Program:

@@ -19,9 +19,13 @@ Disabled (the default) every entry point is a branch: `span()` returns a
 shared null singleton, `inc`/`set` are no-ops.  `paddle_tpu.profiler` is a
 compatibility facade over this module.
 
-Instrumented out of the box: `core/executor.py` (per-run step breakdown —
-build / lower / compile / dispatch / execute / fetch spans, cache-hit +
-recompile counters, steps/sec EMA), `pipeline.py` (the loop's next_batch /
+Instrumented out of the box: `core/program.py`, `core/autodiff.py`,
+`optimizer.py` (where a program is built: program.build / backward /
+optimize / clone spans), `core/executor.py` (per-run step breakdown —
+prepare / build / lower / compile / dispatch / execute / fetch spans,
+cache-hit + recompile counters, steps/sec EMA), every JAX trace, lowering,
+compile and cache load in the process (observed `jax.*` events under the
+span they ran in), `pipeline.py` (the loop's next_batch /
 dispatch / host_blocked spans, each with its step), `core/lowering.py`
 (the op census), `reader.py` (the producer's stage span, queue depth /
 wait), `serving/server.py` (the worker's batch_build / batch / split),

@@ -33,6 +33,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from ..core.locks import named_lock
@@ -59,6 +60,22 @@ EXEMPLAR_CAP = 64
 # `executor.enqueue` under `pipeline.dispatch(step=7)` is a span of step 7
 # and a reader cuts a window by step without walking the tree.
 SHARED_IDS = ("step", "batch", "trace_id")
+# What the monitor hears from `jax.monitoring` (this JAX: 0.9.0), each a
+# duration JAX reports when the work ENDS, recorded back-dated under the
+# span open on that thread: tracing a function to a jaxpr, lowering the
+# jaxpr to StableHLO, the backend's compile (XLA, or a load from the
+# persistent cache) and, inside that, the load from the cache alone.
+JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+# The one event JAX fires when its persistent cache served a compile.
+# (`/jax/compilation_cache/cache_misses` fires only when an entry is
+# WRITTEN, which the minimum-compile-time rule suppresses: a compile
+# without the hit event is the miss.)
+JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
 class _NullSpan:
@@ -200,6 +217,12 @@ class Monitor:
 
     def __init__(self):
         self.enabled = False
+        # (time.time(), time.perf_counter()) at the first enable() since
+        # the last reset(): the two clocks a Span reads, so that a reader
+        # places the events (time.time) against a perf_counter stamp of
+        # its own without guessing the offset
+        self.enabled_at: Optional[tuple] = None
+        self._jax_listening = False
         self._lock = named_lock("monitor.registry", rank=64, telemetry=False)
         self._tls = threading.local()
         self._span_ids = itertools.count(1)  # next() is atomic under the GIL
@@ -238,6 +261,15 @@ class Monitor:
 
     # -- lifecycle ---------------------------------------------------------
     def enable(self):
+        if self.enabled_at is None:
+            self.enabled_at = (time.time(), time.perf_counter())
+        if not self._jax_listening:
+            # JAX offers no unregister: the listeners stay for the life of
+            # the process and are one comparison an event while disabled
+            self._jax_listening = True
+            jax.monitoring.register_event_listener(self._on_jax_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_jax_duration)
         self.enabled = True
         return self
 
@@ -257,6 +289,8 @@ class Monitor:
             # a reset starts a fresh run: the one-shot dump latch re-opens
             # (the armed path survives — re-arm to change it)
             self._bb_dumped = None
+            self.enabled_at = ((time.time(), time.perf_counter())
+                               if self.enabled else None)
             for c in self._counters.values():
                 c.value = 0
             for g in self._gauges.values():
@@ -302,6 +336,48 @@ class Monitor:
         self._record(name, ts if ts is not None else time.time() - seconds,
                      seconds, len(stack), args or None, next(self._span_ids),
                      stack[-1].id if stack else 0)
+
+    # -- what JAX reports ----------------------------------------------------
+    def _on_jax_event(self, event: str, **_):
+        if not self.enabled:
+            return
+        if event == JAX_CACHE_HIT:
+            tls = self._tls
+            tls.jax_cache_hits = getattr(tls, "jax_cache_hits", 0) + 1
+
+    def _on_jax_duration(self, event: str, seconds: float, **kw):
+        if not self.enabled:
+            return
+        name = JAX_DURATIONS.get(event)
+        if name is None:
+            return
+        tls, stack = self._tls, self._stack()
+        now = time.time()
+        sid = next(self._span_ids)
+        parent = stack[-1].id if stack else 0
+        if name == "jax.cache_load":
+            # the backend-compile event that wraps this load fires after
+            # it: its id is drawn now, so that the load is its child
+            tls.jax_compile_id = parent = next(self._span_ids)
+            tls.jax_load_at = now
+        elif name == "jax.backend_compile":
+            if now - seconds <= getattr(tls, "jax_load_at", -1.0):
+                sid = tls.jax_compile_id
+            tls.jax_load_at = -1.0
+        # the work is over, so in a profiler trace the event can only be a
+        # marker where it ENDED (its seconds among the stats); the monitor's
+        # own event is back-dated to where it ran
+        with TraceAnnotation(name, seconds=seconds, **kw):
+            pass
+        self._record(name, now - seconds, seconds,
+                     len(stack) + (name == "jax.cache_load"), kw or None,
+                     sid, parent)
+
+    def jax_cache_hits(self) -> int:
+        """Compiles on THIS thread that JAX's persistent cache served while
+        the monitor was on (a compile on another thread is not taken for
+        this one's)."""
+        return getattr(self._tls, "jax_cache_hits", 0)
 
     def _record(self, name, ts, dur, depth, args, sid, parent):
         tid = threading.get_ident() & 0xFFFF

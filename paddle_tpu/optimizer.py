@@ -15,6 +15,7 @@ from .core.autodiff import append_backward
 from .core.dtypes import canonical_dtype
 from .core.program import Parameter, Program, Variable, default_main_program, default_startup_program
 from .core.regularizer import append_regularization_ops
+from .monitor import MONITOR as _MON
 
 
 class Optimizer:
@@ -138,8 +139,9 @@ class Optimizer:
 
         if _dy.enabled():
             return self._dygraph_minimize(loss, parameter_list)
-        params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
-        optimize_ops = self.apply_gradients(params_grads)
+        with _MON.span("program.optimize", program=loss.block.program._uuid[:8]):
+            params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
+            optimize_ops = self.apply_gradients(params_grads)
         return optimize_ops, params_grads
 
     # --- dygraph (eager) path --------------------------------------------
