@@ -719,10 +719,11 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
                     name=None):
     """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
     float32 scores and softmax whatever the operands' dtype.  On the TPU the
-    lowering takes its tiling from the shape (ops/nn_ops.py): the streaming
-    flash kernel from 2048 keys, a whole-row kernel for bf16 sequences of 384
-    to 512 (the scores never reach HBM in either, forward or backward),
-    XLA's attention otherwise.  `bias` is an additive pre-softmax mask,
+    lowering takes its tiling from what it can observe (ops/nn_ops.py): from
+    2048 keys the block-skipping splash kernels for a causal mask without a
+    bias and the streaming flash kernel otherwise, a whole-row kernel for bf16
+    sequences of 384 to 512 (the scores never reach HBM in any of them,
+    forward or backward), XLA's attention otherwise.  `bias` is an additive pre-softmax mask,
     (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
 
     `k` and `v` may have fewer heads than `q`, a divisor of its count (grouped

@@ -1,6 +1,15 @@
 """Attention under a mask that is a rule over positions, not a tensor.
 
-The one rule so far is block-diffusion training's (BD3-LM, Arriola et al.
+Two rules.  The CAUSAL one (`causal_allowed`: key j <= query i over equal
+lengths; `fused_attention`'s `causal` at long keys, `causal_plan`) is the stock
+splash kernels under the stock causal mask and nothing round them but the
+queries' scaling: the forward kernel and ONE backward kernel (dkv, which
+writes dq too: `Plan.fused_backward`), the cut blocks' mask computed in the
+kernel.  At 4096 keys 10 of the square's 16 1024-blocks are visited and 4 of
+them cut, at 8192 36 of 64 and 8.  What is said below of the far term's
+kernels, their block maps and grouped key/value heads holds for it; nothing of
+the own-block term does.  The other rule is block-diffusion training's
+(BD3-LM, Arriola et al.
 2025, arXiv:2503.09573; SDAR trains this way).  L tokens of data are 2L
 positions: i < L the noised copy x_t, i >= L the clean copy x_0, in blocks
 of B, blk(i) = (i mod L) div B.  Query i may see key j where
@@ -71,6 +80,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor import MONITOR as _MON
 
+#: The rules an op's `mask` attribute may name (`causal` is an attribute of its own).
 MASKS = ("block_diffusion",)
 
 #: Queries and keys a block of the kernels' grids, the largest that divides the
@@ -105,6 +115,28 @@ MASKS = ("block_diffusion",)
 #: therefore takes `_KV_COMPUTE` queries a block against 1024 keys; and the
 #: forward kernel over the whole square where its queries are a parameter of
 #: the program and not the scaling's result (16.77 MB: PR 33).
+#:
+#: Under the CAUSAL rule (PR 37; forward + backward of a layer alone, ms; the
+#: stock flash kernel as `fused_attention` called it 15.06 and 48.22):
+#:
+#:   (4, 16, 4096, 128)            1024, 1024 keys a step   1024, 512   512     2048
+#:   dq, dkv apart; cut stored     12.67                    12.35       14.09   VMEM overrun
+#:   dq, dkv apart; cut computed   12.43                    11.85       13.87
+#:   fused backward; stored        10.34                     9.96       11.52
+#:   fused backward; computed       9.99                     9.87       11.31
+#:   (2, 32 on 8, 8192, 64)
+#:   dq, dkv apart; stored | computed    40.63 | 39.62      39.49 | 38.51   47.12 | 46.64
+#:   fused backward; stored | computed   33.51 | 32.79      32.84 | 32.10   39.55 | 39.02
+#:
+#: so 1024-blocks with 512 keys a step again, the cut blocks COMPUTED (one
+#: compare a pair; with them stored the fused kernel's [keys, queries] block of
+#: the mask overran the scoped VMEM inside OLMoE's and LFM2's steps, 16.64 and
+#: 17.14 of 16 MB, which it fits alone) and the backward FUSED: in OLMoE's step
+#: 32.627 samples/s against 31.948 with dq and dkv apart and 31.207 on the
+#: flash kernel.  Its dq is a partial a block of keys, rounded to the operands'
+#: dtype before XLA sums them (4 or 8 of them: 0.27 and 1.07 GB written; dq
+#: against float32 reads 3.39e-3 for 3.33e-3 at 128-wide heads and 2.59e-3 for
+#: 2.50e-3 at 64-wide, tools/chip_attention_errors.py).
 _BLOCKS = (1024, 512, 128)
 _KV_COMPUTE = 512
 
@@ -119,6 +151,12 @@ def block_diffusion_allowed(q_ids, kv_ids, seq: int, block: int):
     return ((~q_clean & ~kv_clean & (kv_blk == q_blk))
             | (~q_clean & kv_clean & (kv_blk < q_blk))
             | (q_clean & kv_clean & (kv_blk <= q_blk)))
+
+
+def causal_allowed(q_ids, kv_ids):
+    """May query `q_ids` see key `kv_ids` under the causal rule?  Broadcasts,
+    numpy or jax, as `block_diffusion_allowed` does."""
+    return kv_ids <= q_ids
 
 
 def allowed_pairs(positions: int, block: int) -> int:
@@ -166,6 +204,7 @@ class Plan(NamedTuple):
     block: int       # of the kernels' grids
     first_key: int   # L where the own-block term is split off the kernels, else 0
     interpret: bool
+    rule: str = "block_diffusion"
 
     @property
     def tile(self) -> int:
@@ -174,12 +213,21 @@ class Plan(NamedTuple):
         return max(self.mask_block, 128)
 
     @property
+    def fused_backward(self) -> bool:
+        """dq from the dkv kernel's own pass over the scores (the stock fused
+        backward), not from a kernel of its own: under the causal rule, where
+        it is the faster alone AND in the step (`_BLOCKS`' table); never under
+        block-diffusion's, whose step it overran (ROADMAP.md S13(a))."""
+        return self.rule == "causal"
+
+    @property
     def sizes(self):
         from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
         b, inner = self.block, min(self.block, _KV_COMPUTE)
+        dq = dict(use_fused_bwd_kernel=True) if self.fused_backward else dict(block_q_dq=inner, block_kv_dq=b)
         return splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=inner, block_q_dkv=b, block_kv_dkv=b,
-                                 block_kv_dkv_compute=inner, block_q_dq=inner, block_kv_dq=b)
+                                 block_kv_dkv_compute=inner, **dq)
 
 
 def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False) -> Plan:
@@ -194,18 +242,34 @@ def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False
     return Plan(positions, heads, mask_block, kernel_block(positions), 0, interpret)
 
 
+def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
+    """The causal rule over `length` queries and as many keys: the stock
+    kernels over the whole square (the rule's block is one position, and no
+    term of it lacks block structure), their block the largest that divides
+    the length."""
+    return Plan(length, heads, 1, kernel_block(length), 0, interpret, "causal")
+
+
 @functools.lru_cache(maxsize=32)
 def block_maps(plan: Plan):
-    """The kernels' block maps for `plan`, forward, dq and dkv, in numpy: made
-    from the rule once a shape, a block of the grid at a time."""
+    """The kernels' block maps for `plan`, forward, dq (None where the dkv
+    kernel computes dq too) and dkv, in numpy: made from the rule once a
+    shape, a block of the grid at a time."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as info_lib
 
     sizes = plan.sizes
-    mask = mask_lib.MultiHeadMask([_rule_mask(plan.positions, plan.first_key, plan.mask_block)] * plan.heads)
+    if plan.rule == "causal":
+        # The stock mask of the kind the kernels COMPUTE on a cut block (`_stock_options` hands them the rule): one
+        # compare a pair, where block-diffusion's divisions lost by 19 ms, and no [keys, queries] block of the mask
+        # in the fused backward kernel's VMEM, which has no room for it (`_BLOCKS`' table).
+        rule = mask_lib.CausalMask((plan.positions, plan.positions))
+    else:
+        rule = _rule_mask(plan.positions, plan.first_key, plan.mask_block)
+    mask = mask_lib.MultiHeadMask([rule] * plan.heads)
     shards = dict(downcast_smem_data=True, head_shards=1, q_seq_shards=1)
     return (info_lib.process_mask(mask, (sizes.block_q, sizes.block_kv), **shards)[0],
-            info_lib.process_mask(mask, (sizes.block_q_dq, sizes.block_kv_dq), **shards)[0],
+            None if plan.fused_backward else info_lib.process_mask(mask, (sizes.block_q_dq, sizes.block_kv_dq), **shards)[0],
             info_lib.process_mask_dkv(mask, (sizes.block_q_dkv, sizes.block_kv_dkv), **shards)[0])
 
 
@@ -219,7 +283,7 @@ def _stock_options(plan: Plan) -> dict:
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     return dict(mask_value=splash.DEFAULT_MASK_VALUE, is_mqa=False, attn_logits_soft_cap=None,
-                mask_function=None, interpret=plan.interpret)
+                mask_function=causal_allowed if plan.rule == "causal" else None, interpret=plan.interpret)
 
 
 def _far_forward(q, k, v, plan: Plan):
@@ -239,17 +303,20 @@ def _far_forward(q, k, v, plan: Plan):
 def _far_backward(q, k, v, lse, do, di, plan: Plan):
     """dq, dk, dv of the kernels' term from the stock dq and dkv kernels, given
     the log-sum-exp and rowsum(do . out) of the WHOLE row (joined, where the
-    own-block term was split off)."""
+    own-block term was split off).  Where the plan fuses the backward pass,
+    the dkv kernel writes dq too, a partial a block of keys in the operands'
+    dtype, and XLA sums the partials."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     sizes, dq_info, dkv_info = plan.sizes, _block_map(plan, 1), _block_map(plan, 2)
     options = dict(_stock_options(plan), q_layout=sizes.q_layout, k_layout=sizes.k_layout, v_layout=sizes.v_layout)
-    _, dk, dv = jax.vmap(lambda *a: splash._splash_attention_bwd_dkv(
+    dq, dk, dv = jax.vmap(lambda *a: splash._splash_attention_bwd_dkv(
         *a[:3], None, None, *a[3:], bq=sizes.block_q_dkv, bkv=sizes.block_kv_dkv, bkv_compute=sizes.block_kv_dkv_compute,
-        mask_info=dkv_info, use_fused_bwd_kernel=False, **options))(q, k, v, lse, do, di)
-    dq = jax.vmap(lambda *a: splash._splash_attention_bwd_dq(
-        *a[:3], None, None, *a[3:], bq=sizes.block_q_dq, bkv=sizes.block_kv_dq, mask_info=dq_info,
-        **options))(q, k, v, lse, do, di)
+        mask_info=dkv_info, use_fused_bwd_kernel=plan.fused_backward, **options))(q, k, v, lse, do, di)
+    if not plan.fused_backward:
+        dq = jax.vmap(lambda *a: splash._splash_attention_bwd_dq(
+            *a[:3], None, None, *a[3:], bq=sizes.block_q_dq, bkv=sizes.block_kv_dq, mask_info=dq_info,
+            **options))(q, k, v, lse, do, di)
     return dq, dk, dv
 
 
@@ -396,8 +463,14 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 def attention_under(plan: Plan, q, k, v, scale: float):
-    """`block_sparse_attention` with the plan given
-    (tools/chip_block_attention.py prices one the shapes would not take)."""
+    """softmax(q k^T . scale under the plan's rule) v over (B, Hq, positions,
+    dh) queries and (B, Hkv, positions, dh) keys and values, Hkv a divisor of
+    Hq.  The kernels have no scale of their own: the queries carry it, rounded
+    once more to their dtype.  That costs nothing where the scale is a power
+    of two (64-wide heads); at 128-wide heads the output is 2.51e-3 from
+    float32 where the flash kernel, which scales the float32 scores, is
+    2.03e-3, most of either the output's own rounding
+    (tools/chip_attention_errors.py, PR 37)."""
     blocks = block_maps(plan)[0].block_mask
     _MON.counter("lowering.attention_blocks_visited").inc(int(np.count_nonzero(blocks)))
     _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
@@ -408,8 +481,11 @@ def attention_under(plan: Plan, q, k, v, scale: float):
 
 
 def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bool = False):
-    """softmax(q k^T . scale under the block-diffusion rule) v over
-    (B, Hq, 2L, dh) queries and (B, Hkv, 2L, dh) keys and values, Hkv a
-    divisor of Hq.  The kernels have no scale of their own: the queries carry
-    it."""
+    """`attention_under` the block-diffusion rule over 2L positions in blocks
+    of `mask_block`."""
     return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale)
+
+
+def causal_attention(q, k, v, scale: float, interpret: bool = False):
+    """`attention_under` the causal rule over equal lengths of queries and keys."""
+    return attention_under(causal_plan(q.shape[2], q.shape[1], interpret), q, k, v, scale)

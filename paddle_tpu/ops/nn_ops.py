@@ -484,7 +484,7 @@ def _ring_attention(ctx, op, ins):
     return {"Out": out}
 
 
-# fused_attention on the TPU: three attentions, chosen by `_attention_path`
+# fused_attention on the TPU: five attentions, chosen by `_attention_path`
 # from what the op can observe.  Each threshold is a length, with the runs
 # that set it (TPU v5e, BERT-base's own program through benchmark.run,
 # samples/s; PERF.md, PRs 26, 29 and 30).
@@ -522,26 +522,39 @@ _ROW_KERNEL_HEAD_DIM = 64
 _ROW_KERNEL_SEQ_MULTIPLE = 128
 
 
-def _attention_path(platform, mesh, q, k, mask=None):
-    """Which attention `fused_attention` lowers to: "flash", "row_kernel",
-    "block_sparse" or "xla".  Off the TPU always "xla".  A short query against
-    long keys (a decoding step) has no score block worth keeping out of HBM,
-    hence BOTH lengths in the row kernel's rule.  Under a structured `mask`
-    (`_structured_mask`) the block-sparse kernel or, as for the row kernel,
-    XLA's attention where a custom call cannot be partitioned or the lengths
-    are no whole number of its blocks; never the other two kernels, which know
-    no mask but a causal one."""
+def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False):
+    """Which attention `fused_attention` lowers to: "flash", "block_causal",
+    "row_kernel", "block_sparse" or "xla".  Off the TPU always "xla".  A short
+    query against long keys (a decoding step) has no score block worth keeping
+    out of HBM, hence BOTH lengths in the row kernel's rule.  Under a
+    structured `mask` (`_structured_mask`) the block-sparse kernel or, as for
+    the row kernel, XLA's attention where a custom call cannot be partitioned
+    or the lengths are no whole number of its blocks; never the other two
+    kernels, which know no mask but a causal one."""
     if platform != "tpu":
         return "xla"
-    q_len, kv_len = q.shape[2], k.shape[2]
-    if mask is not None:
-        from .masked_attention import kernel_block
+    from .masked_attention import kernel_block
 
+    q_len, kv_len = q.shape[2], k.shape[2]
+    one_device = mesh is None or mesh.size == 1
+    if mask is not None:
         whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
-        return "block_sparse" if whole and (mesh is None or mesh.size == 1) else "xla"
+        return "block_sparse" if whole and one_device else "xla"
     if kv_len >= _FLASH_MIN_SEQ and q_len >= _FLASH_MIN_QUERIES:
+        # A causal mask empties the blocks above the diagonal: the splash kernels never visit them and mask only the
+        # blocks the diagonal cuts, where the flash kernel fetches every block, masks every one it runs and is handed
+        # `di` spread to 1024 lanes in float32 and grouped key/value heads repeated.  TPU v5e, forward + backward of
+        # a layer alone, flash | splash (tools/chip_block_attention.py), and in the cell's step (PERF.md, PR 37):
+        # (4, 16, 4096, 128): 15.06 | 9.87 ms; OLMoE's step 128.2 -> 122.6 ms, 31.207 -> 32.627 samples/s (+4.55%);
+        # (2, 32 on 8, 8192, 64): 48.22 | 32.10 ms; LFM2's step 269.0 -> 254.1 ms, 7.4205 -> 7.8485 samples/s (+5.8%).
+        # What no cell or run prices keeps the flash kernel: a bias (the splash kernels take none), no causal mask
+        # (nothing to skip), queries and keys of different lengths or of no whole number of the kernels' blocks, a
+        # mesh of more than one device, operands other than bf16, a head width that is no multiple of 64.
+        if (causal and not biased and one_device and q_len == kv_len and kernel_block(q_len) is not None
+                and q.shape[-1] % 64 == 0 and q.dtype == k.dtype == jnp.bfloat16):
+            return "block_causal"
         return "flash"
-    if mesh is not None and mesh.size > 1:
+    if not one_device:
         return "xla"
     if (all(_ROW_KERNEL_MIN_SEQ <= n <= _ROW_KERNEL_MAX_SEQ and n % _ROW_KERNEL_SEQ_MULTIPLE == 0
             for n in (q_len, kv_len))
@@ -607,11 +620,18 @@ def _fused_attention(ctx, op, ins):
     + bias, causal mask) v, with the operands in their own dtype on the MXU,
     float32 accumulation, float32 scores and softmax, and the probabilities
     rounded to the activations' dtype for the product with v.  One
-    mathematics, four tilings, chosen by `_attention_path` and counted in
-    `lowering.attention_flash|row_kernel|block_sparse|xla`:
+    mathematics, five tilings, chosen by `_attention_path` and counted in
+    `lowering.attention_flash|block_causal|row_kernel|block_sparse|xla`:
 
-    * `flash`: the stock Pallas online-softmax kernel, from `_FLASH_MIN_SEQ`
-      keys on;
+    * `block_causal`: from `_FLASH_MIN_SEQ` keys on, a causal mask and no
+      bias over as many bf16 keys as queries, on one device: the stock
+      splash-attention kernels under the causal rule
+      (`ops/masked_attention.py: causal_attention`), forward and one fused
+      backward kernel, which never visit a block above the diagonal and mask
+      only the blocks it cuts;
+    * `flash`: the stock Pallas online-softmax kernel, every other attention
+      from `_FLASH_MIN_SEQ` keys on (a bias, no causal mask, a mesh): no
+      cell runs it since PR 37;
     * `row_kernel`: `ops/pallas_attention.py:fused_sdpa`, queries and keys
       both in [`_ROW_KERNEL_MIN_SEQ`, `_ROW_KERNEL_MAX_SEQ`]: a whole row of
       scores lives in VMEM, forward and backward;
@@ -627,8 +647,9 @@ def _fused_attention(ctx, op, ins):
       structured mask is built densely here from the same rule.
 
     K and V may have fewer heads than Q (a divisor): query head j reads
-    key/value head j div (Hq / Hkv).  The block-sparse kernel reads them so;
-    the other three are given K and V repeated at their edge.
+    key/value head j div (Hq / Hkv).  The splash kernels (`block_causal`,
+    `block_sparse`) read them so; the other three are given K and V repeated
+    at their edge.
 
     Under a mesh of more than one device the row kernel is NOT taken: a
     `pallas_call` is a custom call that GSPMD cannot partition, and the XLA
@@ -645,12 +666,16 @@ def _fused_attention(ctx, op, ins):
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     mask = _structured_mask(op, q, k)
-    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask)
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None)
     _MON.counter(f"lowering.attention_{path}").inc()
     if path == "block_sparse":
         from .masked_attention import block_sparse_attention
 
         return {"Out": block_sparse_attention(q, k, v, mask[1], float(scale))}
+    if path == "block_causal":
+        from .masked_attention import causal_attention
+
+        return {"Out": causal_attention(q, k, v, float(scale))}
     if k.shape[1] != q.shape[1]:
         k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
     if path == "flash":

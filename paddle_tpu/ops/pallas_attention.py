@@ -32,7 +32,7 @@ Contracts:
     lowering stop_gradients it).
   * causal masking applied inside the kernel (no bias materialization).
   * long-L guard: this module asserts L <= 1024; from _FLASH_MIN_SEQ keys
-    on the lowering takes the streaming stock kernel instead.
+    on the lowering takes a streaming stock kernel instead.
   * both calls carry a `cost_estimate` of their matrix products and
     exponentials, so the step's `cost_analysis()` still counts attention's
     arithmetic; not of their bytes (`_cost` says why).

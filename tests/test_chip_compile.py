@@ -81,6 +81,12 @@ def _block_sparse(q, k, v):
     return block_sparse_attention(q, k, v, 4, q.shape[-1] ** -0.5)
 
 
+def _block_causal(q, k, v):
+    from paddle_tpu.ops.masked_attention import causal_attention
+
+    return causal_attention(q, k, v, q.shape[-1] ** -0.5)
+
+
 def _gmm(rows, weights, sizes):
     from paddle_tpu.ops.moe_ops import grouped_matmul
 
@@ -191,6 +197,14 @@ CASES = {
         _block_sparse, [((2, 32, 8192, 128), BF16)] + [((2, 4, 8192, 128), BF16)] * 2, (0, 1, 2)),
     "block_sparse_attention_128_blocks": (  # a length that is whole in the small block only
         _block_sparse, [((1, 8, 1280, 128), BF16)] + [((1, 8, 1280, 128), BF16)] * 2, (0, 1, 2)),
+    # the same kernels under the causal rule (`_attention_path`: `block_causal`, PR 37): OLMoE's cell, and LFM2's
+    # 64-wide heads, 32 on 8 key/value heads at 8192 keys; a length in the 128-blocks
+    "block_causal_attention_olmoe": (
+        _block_causal, [((4, 16, 4096, 128), BF16)] * 3, (0, 1, 2)),
+    "block_causal_attention_lfm2": (
+        _block_causal, [((2, 32, 8192, 64), BF16)] + [((2, 8, 8192, 64), BF16)] * 2, (0, 1, 2)),
+    "block_causal_attention_128_blocks": (
+        _block_causal, [((1, 8, 2176, 64), BF16)] + [((1, 2, 2176, 64), BF16)] * 2, (0, 1, 2)),
 }
 
 
@@ -322,6 +336,28 @@ def test_no_square_of_the_positions_is_in_the_compiled_attention(chip):
     assert set(under_the_scope) == {"fwd", "dq", "dkv", "own_block_join", "own_block_backward"}
 
 
+@pytest.mark.parametrize("q,kv", [((4, 16, 4096, 128), (4, 16, 4096, 128)), ((2, 32, 8192, 64), (2, 8, 8192, 64))],
+                         ids=["olmoe", "lfm2"])
+def test_no_square_of_the_positions_is_in_the_compiled_causal_attention(q, kv, chip):
+    """Forward and backward at OLMoE's and LFM2's shapes: no array with a
+    [keys, keys] square, mask or scores, in any computation of the compiled
+    program, nor the flash path's lane-spread float32 `di` ([b, h, L, 1024]),
+    and the temporaries well under half of that path's; the stock kernels' two calls
+    (forward, and the backward kernel that writes dq, dk and dv) under the
+    lowering's scope."""
+    length = q[2]
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in (q, kv, kv)]
+    compiled = jax.jit(jax.grad(lambda *a: jnp.sum(_block_causal(*a).astype(F32)), argnums=(0, 1, 2))).lower(*args).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"\[[\d,]*%d,%d\]" % (length, length), text)
+    assert not re.findall(r"f32\[%d,%d,%d,1024\]" % q[:3], text)
+    # 0.27 and 1.34 GB here, dq's partials a block of keys among them (the flash kernel's program 1.34 and 3.36 GB: ISSUE 37)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1.5e9 if kv != q else 0.45e9)
+    assert text.count("tpu_custom_call") == 2
+    under_the_scope = re.findall(r'op_name="[^"]*block_sparse_attention[^"]*/splash_mha_(fwd|dq|dkv)[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"fwd", "dkv"}
+
+
 def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(chip):
     """16 of 128 experts held at 16384 positions: the 131072 (token, slot)
     assignments exist as vectors only (the sort's keys, order and weights);
@@ -446,7 +482,8 @@ def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip)
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.280
     text = compiled.as_text()
-    assert "flash_mha_bwd_dq" in text and "flash_mha_bwd_dkv" in text   # the one attention layer took the flash kernel
+    # the one attention layer took the splash kernels under the causal rule (PR 37; the flash kernel until then)
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
     assert text.count("/gated_short_conv/") > 0 and text.count("/expert_gemm/") > 0
 
 

@@ -159,3 +159,55 @@ def test_the_lower_span_says_which_attention_the_program_took():
     finally:
         monitor.disable()
         monitor.reset()
+
+
+#: query heads, key/value heads, length, head width -> blocks of the 128-grid visited, cut.  128-wide heads as
+#: OLMoE's, 64-wide heads on grouped key/value heads as LFM2's (32 on 8 scaled down), a length of three blocks
+CAUSAL_KERNEL_CASES = {(4, 4, 256, 128): (3, 2), (8, 2, 256, 64): (3, 2), (4, 1, 384, 64): (6, 3), (2, 2, 384, 128): (6, 3)}
+
+
+@pytest.mark.parametrize("hq,hkv,length,dh", list(CAUSAL_KERNEL_CASES))
+def test_the_causal_plans_kernels_agree_with_xlas_attention_forward_and_backward(hq, hkv, length, dh):
+    """What a TPU runs for a causal mask at long keys (`_attention_path`:
+    `block_causal`), here interpreted and in blocks of 128: the stock splash
+    kernels under the causal rule against the op's XLA attention, the output
+    and the three gradients, key/value heads read as they are (nothing
+    repeated, `dk` and `dv` of a key/value head summed over its query heads
+    by the kernel), within the tolerances of tests/test_sdar.py's kernel
+    cases; and the block map skips what the rule empties."""
+    import jax
+    import jax.numpy as jnp
+    from types import SimpleNamespace
+
+    from paddle_tpu.core.lowering import LoweringContext
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.ops import masked_attention
+
+    rng = np.random.RandomState(37)
+    q = rng.randn(2, hq, length, dh).astype("f4")
+    k, v = (rng.randn(2, hkv, length, dh).astype("f4") for _ in range(2))
+    weight = rng.randn(*q.shape).astype("f4")
+    plan = masked_attention.causal_plan(length, hq)
+    assert (plan.block, plan.first_key, plan.rule) == (128, 0, "causal")
+    blocks = masked_attention.block_maps(plan)[0].block_mask
+    assert (np.count_nonzero(blocks), np.count_nonzero(blocks == 1)) == CAUSAL_KERNEL_CASES[hq, hkv, length, dh]
+
+    def kernel(q, k, v):
+        return masked_attention.causal_attention(q, k, v, dh ** -0.5, interpret=True)
+
+    def xla(q, k, v):
+        op = SimpleNamespace(type="fused_attention", attr=lambda name, default=None: {"causal": True}.get(name, default))
+        ctx = LoweringContext(jax.random.PRNGKey(0), platform="cpu")
+        with jax.default_matmul_precision("highest"):
+            return get_op_def("fused_attention").lower(ctx, op, {"Q": [q], "K": [k], "V": [v]})["Out"]
+
+    def agree(got, want, tol):
+        got, want = np.asarray(got, "f8"), np.asarray(want, "f8")
+        assert got.shape == want.shape and np.isfinite(got).all()
+        assert np.abs(got - want).max() <= tol * np.abs(want).max(), (np.abs(got - want).max(), np.abs(want).max())
+
+    agree(kernel(q, k, v), xla(q, k, v), tol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * weight), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(xla(*a) * weight), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        agree(g, w, tol=2e-5)

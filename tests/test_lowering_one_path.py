@@ -89,28 +89,28 @@ def _fp16_batch_norm():
 PARENTS_PROGRAMS = {
     "resnet50-train-bf16-nchw": (lambda: _resnet50(256, dtype="bfloat16"), "train_cc732a46",
         "b40b8617a8e43d947e7dcdd6c6b5ea2136abe6018b84b409dacbf82eadaa1b35",
-        "e437a7597c251c9f4488b4bd25aa0782721bda89de6bbeb1823bd9a16933274c", (0, 0, 0)),
+        "e437a7597c251c9f4488b4bd25aa0782721bda89de6bbeb1823bd9a16933274c", (0, 0, 0, 0)),
     "resnet50-for_test-bf16-nchw": (lambda: _resnet50(8, for_test=True, dtype="bfloat16"), "infer_87838cf4",
         "851aa4de6eed0242f77d2494362eb97c788109f448d2d11d66f5d487cd3c2762",
-        "87d011020c6b861070f55ffb8b357c61d33dc3628d9666aebadc404c686f38e6", (0, 0, 0)),
+        "87d011020c6b861070f55ffb8b357c61d33dc3628d9666aebadc404c686f38e6", (0, 0, 0, 0)),
     "resnet50-train-f32-nchw": (lambda: _resnet50(64, dtype="float32"), "train_e29c5d91",
         "189f0d192c4800bf280ba303e8b8e09013d5c26a596e1cd944287c15d9807a8b",
-        "697ddeb2e7bf3498a9bf54f8be1c456a19dec700b811e7967298709305a8a14f", (0, 0, 0)),
+        "697ddeb2e7bf3498a9bf54f8be1c456a19dec700b811e7967298709305a8a14f", (0, 0, 0, 0)),
     "resnet50-train-bf16-nhwc": (lambda: _resnet50(256, dtype="bfloat16", data_format="NHWC"), "train_cc732a46",
         "03f8097c8e4f2816fa57d4b2b45496da8d8a7e2a23de6657256a26d17ddcc3e2",
-        "73cfc133487bbb2c7e57a9846ed7f785ccd85c0c7514ed0bd6519ab192285f13", (0, 0, 0)),
+        "73cfc133487bbb2c7e57a9846ed7f785ccd85c0c7514ed0bd6519ab192285f13", (0, 0, 0, 0)),
     "bert-base-s512-fused": (lambda: _bert(32, 512), "train_e9476d18",
         "4790802a450bc2534b4f64089c4c9b044c37d7e4de8c30169d41d35c22fb1bc5",
-        "cedaf95cee1ad5e64a71453df109d17bbf1760c41154aa793e33f2da26cf0e6d", (12, 0, 0)),
+        "cedaf95cee1ad5e64a71453df109d17bbf1760c41154aa793e33f2da26cf0e6d", (12, 0, 0, 0)),
     "bert-base-s128-fused": (lambda: _bert(256, 128), "train_e9476d18",
         "4790802a450bc2534b4f64089c4c9b044c37d7e4de8c30169d41d35c22fb1bc5",
-        "27af5c8d6dfb08a85dae885858d72e7874e05164773e81ec57a42e1fb1b582dd", (0, 0, 12)),
+        "27af5c8d6dfb08a85dae885858d72e7874e05164773e81ec57a42e1fb1b582dd", (0, 0, 12, 0)),
     "olmoe-1b-7b-s4096": (_olmoe_s4096, "train_cbb6bbe7",
         "822e9f203b8780a8ce13ae8c050fe4480b215eb43e3063bc89b21090c48146d1",
-        "a65d54648f7ec71c9881a173595184ff883b43b3cc7d035e5c364690f5fde7c7", (0, 1, 0)),
+        "02d637b2bb246f943a3b2c4babc8d1706c7df6b79d6bf7b4538e4f88398315d0", (0, 0, 0, 1)),
     "batch_norm-train-fp16": (_fp16_batch_norm, "train_97080cb5",
         "97158b65993029a94935d7280f793136a5fe07aa0458a7b0de8d003fb41fbd33",
-        "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984", (0, 0, 0)),
+        "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984", (0, 0, 0, 0)),
 }
 
 
@@ -118,7 +118,7 @@ def _attention_counters():
     from paddle_tpu.monitor import MONITOR
 
     seen = MONITOR.counter_values()
-    return tuple(seen.get(f"lowering.attention_{path}", 0) for path in ("row_kernel", "flash", "xla"))
+    return tuple(seen.get(f"lowering.attention_{path}", 0) for path in ("row_kernel", "flash", "xla", "block_causal"))
 
 
 @pytest.fixture
@@ -153,7 +153,7 @@ def test_the_step_lowers_to_the_parents_program(case, monitor_on):
                             feeds, as_shape(jax.random.PRNGKey(0)))
     # one count an op, where the lowering decided
     assert _attention_counters() == attentions
-    if attentions[0] or attentions[1]:
+    if attentions[0] or attentions[1] or attentions[3]:
         # a Mosaic kernel's serialised body names the files and lines of its
         # call stack (this checkout's path among them): the pin is the program
         # round the kernels and each call's name, cost and layout, not its body
@@ -232,12 +232,18 @@ def test_the_compile_cache_key_names_no_ops_module_global():
 #: `_ROW_KERNEL_MAX_SEQ` queries AND keys, the flash kernel from
 #: `_FLASH_MIN_SEQ` keys (and `_FLASH_MIN_QUERIES` queries), XLA's attention
 #: elsewhere: 128 (the kernel loses by 11%), a decoding step's one query, the
-#: lengths no run has priced.
+#: lengths no run has priced.  Where the flash kernel would be taken, a CAUSAL
+#: mask without a bias, over as many keys as queries in whole blocks of the
+#: splash kernels, on one device, takes those (`block_causal`, PR 37):
+#: `LONG_CAUSAL` below.
 ATTENTION_BY_LENGTHS = {
     (128, 128): "xla", (256, 256): "xla", (384, 384): "row_kernel", (512, 512): "row_kernel",
     (512, 384): "row_kernel", (512, 256): "xla", (1, 512): "xla", (128, 512): "xla", (320, 320): "xla",
-    (640, 640): "xla", (1024, 1024): "xla", (2048, 2048): "flash", (1, 2048): "xla",
+    (640, 640): "xla", (1024, 1024): "xla", (2048, 2048): "flash", (1, 2048): "xla", (8192, 8192): "flash",
+    (2048, 4096): "flash", (2176, 2176): "flash",
 }
+#: the lengths of `ATTENTION_BY_LENGTHS` that the causal rule's kernels take (2176 in their 128-blocks)
+LONG_CAUSAL = {(2048, 2048), (8192, 8192), (2176, 2176)}
 #: what else the op can see: (dtype, head width) -> may the row kernel be taken
 ATTENTION_OPERANDS = {("bfloat16", 64): True, ("float32", 64): False, ("bfloat16", 128): False}
 
@@ -265,12 +271,13 @@ def _trace_attention(lengths, dtype="bfloat16", head=64, bias=False, causal=Fals
     before = _attention_counters()
     text = str(jax.make_jaxpr(jax.grad(attention, argnums=(0, 1, 2)))(*args))
     moved = tuple(b - a for a, b in zip(before, _attention_counters()))
-    kernels = set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention)\b", text))
+    kernels = set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv)\w*\b", text))
     return kernels, moved
 
 
-KERNELS_OF = {"row_kernel": {"fused_sdpa_fwd", "fused_sdpa_bwd"}, "flash": {"flash_attention"}, "xla": set()}
-COUNTED_AS = {"row_kernel": (1, 0, 0), "flash": (0, 1, 0), "xla": (0, 0, 1)}
+KERNELS_OF = {"row_kernel": {"fused_sdpa_fwd", "fused_sdpa_bwd"}, "flash": {"flash_attention"}, "xla": set(),
+              "block_causal": {"splash_mha_fwd", "splash_mha_dkv"}}  # one backward kernel: dq from dkv's pass
+COUNTED_AS = {"row_kernel": (1, 0, 0, 0), "flash": (0, 1, 0, 0), "xla": (0, 0, 1, 0), "block_causal": (0, 0, 0, 1)}
 
 
 @pytest.mark.parametrize("on_a_mesh", [False, True], ids=["one-chip", "mesh"])
@@ -282,6 +289,8 @@ def test_fused_attention_takes_its_attention_from_the_shape(lengths, bias, causa
     want = ATTENTION_BY_LENGTHS[lengths]
     if on_a_mesh and want == "row_kernel":
         want = "xla"  # a custom call GSPMD cannot partition: the lowering's docstring
+    if want == "flash" and causal and not bias and not on_a_mesh and lengths in LONG_CAUSAL:
+        want = "block_causal"
     kernels, moved = _trace_attention(lengths, bias=bias, causal=causal, mesh=mesh)
     assert (kernels, moved) == (KERNELS_OF[want], COUNTED_AS[want])
 

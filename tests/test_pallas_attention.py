@@ -84,24 +84,27 @@ def test_fused_sdpa_cross_attention_lengths():
 # --------------------------------------------------------------------------
 
 
-def attention_errors(shape, attentions, seed=0):
+def attention_errors(shape, attentions, seed=0, causal=False, kv_heads=None):
     """{attention: {"out" | "dq" | "dk" | "dv": error}} for bf16 q, k, v of
     `shape` = (B, H, L, dh) drawn N(0, 1) and a N(0, 1) cotangent: root mean
     square of (result - reference) over root mean square of the reference,
     the reference float32 throughout on the same (bf16-rounded) inputs.
     `attentions` maps a name to f(q, k, v) -> out.  `tools/chip_attention_errors.py`
     runs this on the TPU at (32, 12, 512, 64), the stock flash kernel beside
-    the two (PERF.md, PR 30)."""
+    the two (PERF.md, PR 30), and under a causal mask with k and v on
+    `kv_heads` heads, which the reference repeats (PR 37)."""
     rng = np.random.RandomState(seed)
-    q, k, v, w = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(4))
+    kv_shape = (shape[0], kv_heads or shape[1]) + tuple(shape[2:])
+    q, k, v, w = (jnp.asarray(rng.randn(*s), jnp.bfloat16) for s in (shape, kv_shape, kv_shape, shape))
     scale = shape[-1] ** -0.5
+    group = shape[1] // kv_shape[1]
 
     def results(f, *operands):
         out, vjp = jax.vjp(f, *operands)
         return (out,) + vjp(w.astype(out.dtype))
 
     with jax.default_matmul_precision("highest"):
-        want = results(lambda q, k, v: _ref(q, k, v, None, False, scale),
+        want = results(lambda q, k, v: _ref(q, jnp.repeat(k, group, 1), jnp.repeat(v, group, 1), None, causal, scale),
                        *(t.astype(jnp.float32) for t in (q, k, v)))
     want = [np.asarray(t, np.float64) for t in want]
     errors = {}
@@ -122,6 +125,16 @@ def xla_attention(q, k, v):
     op = SimpleNamespace(type="fused_attention", attr=lambda name, default=None: default)
     ctx = LoweringContext(jax.random.PRNGKey(0), platform="cpu")
     return get_op_def("fused_attention").lower(ctx, op, {"Q": [q], "K": [k], "V": [v]})["Out"]
+
+
+def flash_causal(q, k, v):
+    """The stock flash kernel under a causal mask as `fused_attention` calls
+    it where its rule still takes it: k and v repeated at its edge (the tools'
+    yardstick for the causal rule's splash kernels, PR 37)."""
+    from paddle_tpu.ops.nn_ops import _flash_attention_tpu
+
+    k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
+    return _flash_attention_tpu(q, k, v, None, True, q.shape[-1] ** -0.5)
 
 
 def _bf16_scores_attention(q, k, v):

@@ -1,8 +1,12 @@
 """On the chip: the three attentions of `fused_attention` against float32 on
 unit-variance q, k, v (tests/test_pallas_attention.py: attention_errors), and
-each attention's time alone at BERT-base's heads and ~16k tokens.
+each attention's time alone at BERT-base's heads and ~16k tokens; then (PR 37)
+the causal attentions at long keys, the stock flash kernel and the splash
+kernels under the causal rule (`ops/masked_attention.py: causal_attention`),
+at 128-wide heads and at 64-wide heads on grouped key/value heads, which an
+interpreted run cannot vouch for (PERF.md, defect 15).  CAUSAL=1 runs those alone.
 
-    chiprun -- python3 tools/chip_attention_errors.py     (PERF.md, PR 30)
+    chiprun -- python3 tools/chip_attention_errors.py     (PERF.md, PRs 30 and 37)
 """
 import json
 import os
@@ -14,12 +18,27 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from tests.test_pallas_attention import attention_errors, xla_attention
+from tests.test_pallas_attention import attention_errors, flash_causal, xla_attention
+from paddle_tpu.ops.masked_attention import causal_attention
 from paddle_tpu.ops.nn_ops import _flash_attention_tpu
 from paddle_tpu.ops.pallas_attention import fused_sdpa
 
 DRY = os.environ.get("DRY") == "1"  # a rehearsal on the CPU: XLA's attention only, tiny
 assert DRY or jax.devices()[0].platform == "tpu", jax.devices()
+
+
+# the float32 reference holds [B, H, L, L] scores: 2048 keys, a few heads
+for causal_shape, kv_heads in (((1, 4, 256, 128), 4), ((1, 8, 256, 64), 2)) if DRY else (((2, 8, 2048, 128), 8), ((2, 16, 2048, 64), 4)):
+    causal = {"block_causal": lambda q, k, v: causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)}
+    if not DRY:
+        causal["flash"] = flash_causal
+    for seed in (0, 1):
+        print(json.dumps({"causal_attention_errors": causal_shape, "kv_heads": kv_heads, "seed": seed,
+                          "device": jax.devices()[0].device_kind,
+                          "errors": attention_errors(causal_shape, causal, seed=seed, causal=True, kv_heads=kv_heads)}),
+              flush=True)
+if os.environ.get("CAUSAL") == "1":
+    sys.exit(0)
 shape = (2, 12, 512, 64) if DRY else (32, 12, 512, 64)
 scale = shape[-1] ** -0.5
 attentions = {

@@ -8,10 +8,14 @@ of 4, 16, 128 and 256, with the two forms' largest difference in the output
 and the three gradients (QUICK=1 stops there; PERF.md, PR 33); then, over the
 whole square (PR 32), by the grid's block; the same mask computed in the
 kernel from positions instead of read from its distinct cut blocks; the
-backward pass as one kernel instead of two; and, for the record, the stock
-flash kernel and the splash kernel at OLMoE's causal (4, 16, 4096, 128).
+backward pass as one kernel instead of two.  Before all that (CAUSAL=1 stops
+after it; PR 37) the CAUSAL attentions of OLMoE's cell, (4, 16, 4096, 128), and
+LFM2's, (2, 32 on 8, 8192, 64): the stock flash kernel as `fused_attention`
+calls it (k and v repeated at its edge) against `causal_attention`, and the
+stock splash kernel by the grid's block, with the cut blocks stored or
+computed in the kernel, dq and dkv apart or the fused backward.
 
-    chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PRs 32 and 33)
+    chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PRs 32, 33 and 37)
 
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
@@ -29,7 +33,7 @@ from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_ke
 from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
 
 from paddle_tpu.ops import masked_attention as ma
-from paddle_tpu.ops.nn_ops import _flash_attention_tpu
+from tests.test_pallas_attention import flash_causal
 
 DRY = os.environ.get("DRY") == "1"  # a rehearsal on the CPU: interpreted, tiny, no time printed
 assert DRY or jax.devices()[0].platform == "tpu", jax.devices()
@@ -106,6 +110,46 @@ def apart(got, want):
     return float(jnp.abs(got - want).max() / jnp.abs(want).max())
 
 
+class StoredCausal(mask_lib.Mask):
+    """The causal rule as a mask whose cut blocks are STORED, for its price:
+    `causal_plan` takes the stock `CausalMask`, which the kernels compute."""
+
+    def __init__(self, length):
+        self.length = length
+
+    @property
+    def shape(self):
+        return (self.length, self.length)
+
+    def __getitem__(self, idx):
+        q, kv = (np.arange(s.start or 0, n if s.stop is None else s.stop) for s, n in zip(idx, self.shape))
+        return ma.causal_allowed(q[:, None], kv[None, :])
+
+    def __eq__(self, other):
+        return isinstance(other, type(self)) and self.shape == other.shape
+
+    def __hash__(self):
+        return hash((type(self).__name__, self.shape))
+
+
+for cq, ckv in (((1, 4, 256, 128), (1, 4, 256, 128)), ((1, 8, 256, 64), (1, 2, 256, 64))) if DRY else (
+        ((4, 16, 4096, 128), (4, 16, 4096, 128)), ((2, 32, 8192, 64), (2, 8, 8192, 64))):
+    cqkv = operands(cq, ckv, seed=1)
+    length, heads = cq[2], cq[1]
+    taken = lambda q, k, v: ma.causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
+    report("causal", q=cq, kv=ckv, flash_ms=None if DRY else try_ms(flash_causal, *cqkv), block_causal_ms=try_ms(taken, *cqkv))
+    if not DRY:
+        results = [(jax.jit(f)(*cqkv), *gradients(f)(*cqkv)) for f in (flash_causal, taken)]
+        report("causal_flash_against_block_causal", q=cq,
+               apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))))
+    for cut, mask in (("stored", StoredCausal(length)), ("computed", mask_lib.CausalMask((length, length)))):
+        for b, compute, fused in ((128, 128, False),) if DRY else (
+                (1024, 1024, False), (1024, 512, False), (512, 512, False), (2048, 512, False), (1024, 1024, True),
+                (1024, 512, True), (512, 512, True)):
+            report("causal_splash", q=cq, cut_blocks=cut, grid_block=b, block_kv_compute=compute, fused_backward=fused,
+                   ms=try_ms(splash_with(mask, heads, sizes_of(b, b, compute, fused=fused)), *cqkv))
+if os.environ.get("CAUSAL") == "1":
+    sys.exit(0)
 for rule_block in (4, 16, 128, 256):
     split = ma.plan_of(POSITIONS, Q[1], rule_block, DRY)  # as `fused_attention` calls it
     whole = split._replace(block=ma.kernel_block(POSITIONS), first_key=0)
@@ -128,9 +172,3 @@ if not DRY:
     for b in (512, 1024):
         report("block_sparse_attention_fused_backward", grid_block=b,
                ms=try_ms(splash_with(stored, Q[1], sizes_of(b, b, fused=True)), q, k, v))
-    # OLMoE's causal attention, for the follow-up PERF.md notes: the flash kernel it runs, and this one
-    oq, ok_, ov = operands((4, 16, 4096, 128), (4, 16, 4096, 128), seed=1)
-    report("flash_causal_olmoe", ms=try_ms(lambda q, k, v: _flash_attention_tpu(q, k, v, None, True, scale), oq, ok_, ov))
-    for b in (512, 1024):
-        report("splash_causal_olmoe", grid_block=b,
-               ms=try_ms(splash_with(mask_lib.CausalMask((4096, 4096)), 16, sizes_of(b, b)), oq, ok_, ov))
