@@ -69,7 +69,7 @@ STRUCTURAL_OPS = ("feed", "fetch", "backward")
 
 # Sub-block owners with loop semantics: body reads of body-written vars are
 # loop carries (previous iteration's value), not use-before-def.
-_LOOP_OPS = ("while", "dynamic_rnn")
+_LOOP_OPS = ("while", "dynamic_rnn", "repeat")
 
 # Compare/logical ops produce bool whatever the operand dtype.  Shared by
 # the infer registrations (ops/*) and the layer builders (math_sugar) so
@@ -596,6 +596,8 @@ def verify_structure(program: Program) -> List[Diagnostic]:
                     if op.type == "dynamic_rnn":
                         seed |= set(op.attrs.get("step_vars", []))
                         seed |= set(op.attrs.get("mem_vars", []))
+                    if op.type == "repeat":
+                        seed |= set(op.attrs.get("carry_vars", []))
                     if op.type == "pipeline":
                         seed.add(op.attrs.get("carry_in"))
                         seed |= set(op.attrs.get("canonical_params", []))

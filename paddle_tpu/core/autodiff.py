@@ -79,23 +79,27 @@ def _find_sparse_params(block, param_names) -> List[str]:
     sparse_ok: dict = {}
     program = block.program
 
-    def scan(blk):
+    def scan(blk, nested=False):
         for op in blk.ops:
             for slot, names in op.inputs.items():
                 for n in names:
                     if n not in pset:
                         continue
                     is_sparse_lookup = (
-                        op.type in ("lookup_table", "lookup_table_v2")
+                        not nested
+                        and op.type in ("lookup_table", "lookup_table_v2")
                         and slot == "W"
                         and bool(op.attrs.get("is_sparse", False))
                     )
                     sparse_ok[n] = sparse_ok.get(n, True) and is_sparse_lookup
-            # sub-block reads count too (a tied table consumed densely inside
-            # a While/cond body must stay on the dense vjp path)
+            # sub-block reads count too, and every one of them as a dense read:
+            # a table consumed inside a While/cond/Repeat body, by a sparse
+            # lookup too, stays on the dense vjp path (the taps that make a
+            # SelectedRows gradient are values of the block that holds the
+            # `backward` op, not of a loop's body)
             sub = op.attrs.get("sub_block")
             if sub is not None and program is not None:
-                scan(program.blocks[sub])
+                scan(program.blocks[sub], nested=True)
 
     scan(block)
     return sorted(n for n, ok in sparse_ok.items() if ok)

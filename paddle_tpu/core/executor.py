@@ -212,7 +212,7 @@ class _CompiledStep:
         written_set = set()
         for op in ops:
             # _effective_io folds in sub-block reads/writes (while / cond /
-            # dynamic_rnn bodies read parameters the top-level op doesn't list)
+            # dynamic_rnn / repeat bodies read parameters the top-level op may not list)
             reads, outs = self._effective_io(op)
             read_names.update(reads)
             if op.type == "backward":
@@ -533,7 +533,7 @@ class _CompiledStep:
         """(reads, writes) including sub-block effects for control flow."""
         reads = list(op.input_arg_names)
         writes = list(op.output_arg_names)
-        if op.type in ("while", "conditional_block", "dynamic_rnn"):
+        if op.type in ("while", "conditional_block", "dynamic_rnn", "repeat"):
             idx = op.attrs.get("sub_block")
             if idx is not None:
                 sub = op.block.program.blocks[idx]
@@ -634,11 +634,13 @@ class _CompiledStep:
             with _MON.span("executor.lower", **what) as lowering:
                 lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
                 lowering.annotate(fenced=fenced.value - fenced0)
-                # which attention each fused_attention op of this program took
+                # which attention each fused_attention op of this program took,
+                # and what its `repeat` ops lowered (passes, body ops, recomputed passes)
                 lowering.annotate(**{
                     name[len("lowering."):]: n - counted0.get(name, 0)
                     for name, n in _MON.counter_values().items()
-                    if name.startswith("lowering.attention_") and n != counted0.get(name, 0)})
+                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_"))
+                    and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)
                     lowering.annotate(moe_layers=self.moe_layers)

@@ -15,6 +15,7 @@ as one program.  The gradients themselves are a fusion boundary
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -92,8 +93,12 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
                 f"structural op {op.type!r} reached the lowering interpreter; "
                 "the executor must handle it"
             )
-        with jax.named_scope(f"op{idx}:{op.type}"):
-            lower_one(ctx, op, env)
+        # an op built under `fluid.name_scope` carries the path: its scope
+        # stands round the op's own, so the innermost name is still the op
+        part = op.attrs.get("op_namescope")
+        with jax.named_scope(part) if part else contextlib.nullcontext():
+            with jax.named_scope(f"op{idx}:{op.type}"):
+                lower_one(ctx, op, env)
         if mon_on:
             _MON.counter("lowering.ops_total").inc()
     return env

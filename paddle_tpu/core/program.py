@@ -282,6 +282,8 @@ class Block:
         op = Operator(self, type, _normalize_io(inputs), _normalize_io(outputs), attrs)
         if _device_guard_stage is not None and "pipeline_stage" not in op.attrs:
             op.attrs["pipeline_stage"] = _device_guard_stage
+        if unique_name.scope_path() and "op_namescope" not in op.attrs:
+            op.attrs["op_namescope"] = unique_name.scope_path()
         self.ops.append(op)
         infer_and_check(op, self)
         self.program._bump()
@@ -532,10 +534,13 @@ def switch_main_program(program: Program) -> Program:
 @contextlib.contextmanager
 def name_scope(prefix=None):
     """reference framework.name_scope: prefixes generated op/var names for
-    readability (debugging/graphviz); purely cosmetic here too.  Repeated
-    sibling scopes dedup (encoder, encoder_1) and nesting composes
-    (outer/inner); counters are NOT reset, so layers in identically-named
-    scopes never collide."""
+    readability (debugging/graphviz), and, as the reference's `op_namescope`
+    attribute does, marks the ops appended inside it: the lowering opens a
+    `jax.named_scope` of the same path round each of them
+    (core/lowering.py), so a device profile can tell a model's parts apart
+    where their op types are the same.  Repeated sibling scopes dedup
+    (encoder, encoder_1) and nesting composes (outer/inner); counters are NOT
+    reset, so layers in identically-named scopes never collide."""
     from . import unique_name
 
     if prefix:

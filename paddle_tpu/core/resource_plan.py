@@ -15,7 +15,7 @@ TPU rebuild needs the numbers OUTSIDE the compiler, before it runs:
     `tools/donation_audit.py` audits) are counted ONCE, while a written-
     but-never-read persistable costs a transient double buffer at its
     writer exactly as XLA cannot alias it.  Sub-block (while /
-    conditional_block / dynamic_rnn) temps peak inside the owning op and
+    conditional_block / dynamic_rnn / repeat) temps peak inside the owning op and
     die at loop exit; loop-carried and escaping names follow the same
     seeding rules as the verifier.  A `backward` op extends every earlier
     temp's range to itself (activations saved for the VJP) and defines
@@ -88,7 +88,7 @@ DYN = -1
 
 # Sub-block-owning op types whose body executes under the op (the same
 # vocabulary the verifier walks).
-_SUB_BLOCK_OPS = ("while", "conditional_block", "dynamic_rnn", "pipeline")
+_SUB_BLOCK_OPS = ("while", "conditional_block", "dynamic_rnn", "pipeline", "repeat")
 
 
 def _itemsize(dtype_name: Optional[str]) -> int:
@@ -475,7 +475,11 @@ def _plan_block(program: Program, block: Block, env: ShapeEnv,
             # recurse HERE, where the owner's grad factor is known: body
             # ops ahead of a parent-block `backward` are differentiated
             # too, so their rows inherit the owner's factor.  One body
-            # execution (trip counts are not static).  Loop-carried names
+            # execution where the trip count is not static; a `repeat`'s
+            # is, so its body's rows count `times` over, with one forward
+            # more where a differentiated body is recomputed, and a
+            # differentiated body that is NOT recomputed keeps every pass's
+            # temporaries until the backward.  Loop-carried names
             # need no special seeding: a body read of a not-yet-defined
             # temp starts its interval at the read, which covers the
             # whole body — the carry buffer is live across iterations
@@ -484,10 +488,16 @@ def _plan_block(program: Program, block: Block, env: ShapeEnv,
             sub_peak, _sp_op, sub_live, _sc = _plan_block(
                 program, sub_at[i], env, persistable, feeds, fetch_names,
                 rows=rows)
-            if rows is not None and gf != 1:
+            body_factor, passes_live = gf, 1
+            if op.type == "repeat":
+                times = int(op.attrs.get("times", 1))
+                recomputed = bool(op.attrs.get("recompute")) and gf != 1
+                body_factor = (gf + recomputed) * times
+                passes_live = times if gf != 1 and not recomputed else 1
+            if rows is not None and body_factor != 1:
                 for r in rows[n_rows_before:]:
-                    r.grad_factor *= gf
-            sub = (sub_peak, sub_live)
+                    r.grad_factor *= body_factor
+            sub = (sub_peak * passes_live, sub_live)
         for m in start_events.get(i, ()):
             b = env.nbytes(m)
             if b and m not in live:
@@ -649,7 +659,8 @@ def precheck_program(program: Program, feed_shapes, fetch_names,
             f"{plan.feed_bytes / 1e6:.1f} MB, live temps "
             f"{plan.peak_temp_bytes / 1e6:.1f} MB at {marks[0] if marks else '?'}); "
             f"watermark: {'; '.join(marks)} — shrink the batch, enable "
-            f"BuildStrategy.memory_optimize (remat), or shard "
+            f"BuildStrategy.memory_optimize (remat), recompute a repeated block "
+            f"(layers.Repeat(recompute=True)), or shard "
             f"(raised BEFORE any XLA compile/allocate; "
             f"FLAGS_resource_precheck=off skips this check)",
             needed_bytes=plan.peak_bytes, limit_bytes=int(limit),

@@ -36,6 +36,11 @@ def generate(key: str) -> str:
     return name
 
 
+def scope_path() -> str:
+    """The `name_scope`s open now, outermost first, joined by `/`; empty outside any."""
+    return "/".join(_scope_stack)
+
+
 @contextlib.contextmanager
 def name_scope_guard(prefix: str):
     parent = "/".join(_scope_stack)

@@ -211,7 +211,9 @@ class ResourceError(FatalError):
     while there is still a Python stack to read, instead of an opaque
     allocator RESOURCE_EXHAUSTED mid-compile.  phase="build"; never
     retried (the program itself is too big, not the run — shrink the
-    batch, enable remat/BuildStrategy.memory_optimize, or shard).
+    batch, enable remat/BuildStrategy.memory_optimize, build a block that
+    runs several times as a `layers.Repeat(recompute=True)`, which keeps
+    one pass's activations and not every pass's, or shard).
 
     Distinct from `TransientDeviceError(resource_exhausted=True)`: that is
     the RUNTIME allocator actually failing (fragmentation, co-residency),

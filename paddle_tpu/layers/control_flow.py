@@ -4,9 +4,13 @@ Switch; StaticRNN:278).
 
 `While` keeps the reference's with-block builder API; the sub-block lowers
 to one `lax.while_loop` (ops/control_flow_ops.py), so loops run on-device.
+`Repeat` is the loop that can be trained through: one sub-block run a fixed
+number of times over carried variables (`lax.scan`, which has a reverse mode
+where `lax.while_loop` has none).
 """
 from __future__ import annotations
 
+from ..core import unique_name
 from ..core.layer_helper import LayerHelper
 from ..core.program import Variable, default_main_program
 
@@ -101,6 +105,149 @@ class _WhileBlockGuard:
             outputs={},
             attrs={"sub_block": sub_idx},
         )
+        return False
+
+
+class Repeat:
+    """One sub-block run `times` times over the same outer variables, each
+    pass reading what the last one left in the carried variables: a looped
+    (weight-shared) stack of layers is `times` passes over one body.
+
+        loop = layers.Repeat(times=4, recompute=True)
+        with loop.block():
+            x = loop.carry(h0)               # h0 on the first pass, then the last pass's update
+            y = layers.fc(x, d)              # parameters and other outer variables are captured
+            loop.update(x, y)
+            loop.output(y)                   # stacked a pass
+        ys = loop()                          # [times, *y.shape]
+        last = loop.final(x)                 # the carried variable after the last pass
+
+    The program holds ONE `repeat` op with one sub-block, lowered once to a
+    `lax.scan` over the passes (ops/control_flow_ops.py), and it is
+    differentiable: a parameter the body reads gets ONE gradient, the sum of
+    its uses over the passes, accumulated in the parameter's dtype.  Shapes
+    are the same on every pass (XLA's requirement).
+
+    `recompute` is an attribute of the op, so a property of the `Program` as
+    the gradients' fusion boundary is: backward then keeps only what each pass
+    READS (the carried variables) and computes the pass's forward again, so
+    the step holds one pass's activations, not `times` passes', for a second
+    forward of the body.  `DynamicRNN` / `StaticRNN` do not serve: they scan
+    the TIME axis of a step input and mask by each row's length, where this
+    runs the same whole tensors through the body again."""
+
+    def __init__(self, times: int, recompute: bool = False, name: str = None):
+        if int(times) < 1:
+            raise ValueError(f"Repeat: times={times!r}; a loop runs once at least")
+        self.times = int(times)
+        self.recompute = bool(recompute)
+        self.helper = LayerHelper("repeat", name=name)
+        self.main = default_main_program()
+        self._carries = []   # dict(sub, init, update)
+        self._outputs = []   # sub-block Variables
+        self._sub_block = None
+        self._out_vars = None
+        self._final_vars = None
+
+    def block(self):
+        return _RepeatGuard(self)
+
+    def _require_in_block(self):
+        if self._sub_block is None or self.main.current_block() is not self._sub_block:
+            raise RuntimeError("call inside `with loop.block():`")
+
+    def carry(self, init: Variable) -> Variable:
+        """The body's view of a carried variable: `init` on the first pass,
+        afterwards what `update` named on the pass before."""
+        self._require_in_block()
+        sub = self._sub_block.create_var(unique_name.generate("repeat.carry"),
+                                         shape=init.shape, dtype=init.dtype)
+        self._carries.append({"sub": sub, "init": init, "update": None})
+        return sub
+
+    def update(self, carried: Variable, new: Variable):
+        self._require_in_block()
+        for c in self._carries:
+            if c["sub"].name == carried.name:
+                c["update"] = new
+                return
+        raise ValueError(f"{carried.name!r} is not a carried variable of this loop")
+
+    def output(self, *outputs):
+        """Body variables to keep from every pass, stacked on a new leading axis."""
+        self._require_in_block()
+        self._outputs.extend(outputs)
+
+    def final(self, carried: Variable) -> Variable:
+        """The carried variable after the last pass."""
+        if self._final_vars is None:
+            raise RuntimeError("Repeat block not finished")
+        for c, v in zip(self._carries, self._final_vars):
+            if c["sub"].name == carried.name:
+                return v
+        raise ValueError(f"{carried.name!r} is not a carried variable of this loop")
+
+    def __call__(self):
+        if self._out_vars is None:
+            raise RuntimeError("Repeat block not finished")
+        return self._out_vars[0] if len(self._out_vars) == 1 else self._out_vars
+
+    def _finalize(self, parent_block, sub_block):
+        if not self._carries:
+            raise ValueError("Repeat needs at least one carried variable: without one every pass computes the same")
+        for c in self._carries:
+            if c["update"] is None:
+                raise ValueError(f"carried variable {c['sub'].name!r} never updated")
+        own = {c["sub"].name for c in self._carries}
+        captured = [n for n in _external_reads(self.main, sub_block) if n not in own]
+        self._out_vars = [
+            parent_block.create_var(unique_name.generate("repeat.out"), dtype=o.dtype,
+                                    shape=None if o.shape is None else (self.times,) + tuple(o.shape))
+            for o in self._outputs]
+        self._final_vars = [
+            parent_block.create_var(unique_name.generate("repeat.final"), shape=c["sub"].shape, dtype=c["sub"].dtype)
+            for c in self._carries]
+        parent_block.append_op(
+            "repeat",
+            inputs={"Init": [c["init"].name for c in self._carries], "X": captured},
+            outputs={"Out": [v.name for v in self._out_vars], "Final": [v.name for v in self._final_vars]},
+            attrs={"sub_block": sub_block.idx, "times": self.times, "recompute": self.recompute,
+                   "carry_vars": [c["sub"].name for c in self._carries],
+                   "carry_updates": [c["update"].name for c in self._carries],
+                   "out_vars": [o.name for o in self._outputs]})
+
+
+def _external_reads(program, block) -> list:
+    """Names the ops of `block` (and of the sub-blocks under them) read that no
+    op of theirs wrote before: what the body captures from outside, sorted."""
+    defined, reads = set(), set()
+
+    def walk(blk):
+        for op in blk.ops:
+            reads.update(n for n in op.input_arg_names if n not in defined)
+            sub = op.attrs.get("sub_block")
+            if isinstance(sub, int):
+                walk(program.blocks[sub])
+            defined.update(op.output_arg_names)
+
+    walk(block)
+    return sorted(reads)
+
+
+class _RepeatGuard:
+    def __init__(self, loop: Repeat):
+        self.loop = loop
+
+    def __enter__(self):
+        main = self.loop.main
+        self.parent_block = main.current_block()
+        self.loop._sub_block = main.create_block()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.loop.main.rollback()
+        if exc_type is None:
+            self.loop._finalize(self.parent_block, self.loop._sub_block)
         return False
 
 

@@ -770,6 +770,27 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None, name=None)
     return _keep_lod(input, out)
 
 
+def exit_loss(ce, gate_logit, beta=0.0, name=None):
+    """The loss of a model that may leave after any of T passes (`layers.Repeat`):
+    `ce` and `gate_logit` are [T, ...], exit t's cross entropy and exit-gate
+    logit at every position.  With lam_t = sigmoid(gate_logit_t), p_t = lam_t
+    prod_{j<t} (1 - lam_j) for t < T and p_T the rest (the last gate is not
+    read), the loss is mean over positions of sum_t p_t ce_t - beta H(p): the
+    expected task loss under the learned exit distribution, held towards the
+    uniform distribution by its entropy.  float32 throughout.  Returns (loss
+    [1], p [T, ...]); the op's `ExitMass`, `Entropy` and `ExitCE` outputs are
+    published a logged step by `train_loop` as a `kind="loop_exit"` record."""
+    helper = LayerHelper("exit_loss", name=name)
+    loss = _out(helper, "float32", shape=(1,))
+    p = _out(helper, "float32", shape=ce.shape)
+    stats = {slot: _out(helper, "float32") for slot in ("ExitMass", "Entropy", "ExitCE")}
+    helper.append_op(
+        "exit_loss", inputs={"CE": [ce.name], "Gate": [gate_logit.name]},
+        outputs={"Loss": [loss.name], "P": [p.name], **{s: [v.name] for s, v in stats.items()}},
+        attrs={"beta": float(beta)})
+    return loss, p
+
+
 def rotary_embedding(x, positions, theta=10000.0, name=None):
     """Rotary position embedding (rotate-half convention) of (B, H, L, dh)
     queries or keys; `positions` is the (B, L) integer position of every

@@ -25,7 +25,7 @@ import numpy as np
 from .. import layers, optimizer
 from ..core.initializer import ConstantInitializer, NormalInitializer
 from ..core.param_attr import ParamAttr
-from ..core.program import Program, program_guard
+from ..core.program import Program, name_scope, program_guard
 
 
 def _attr(name, std=0.02, seed=0):
@@ -130,14 +130,16 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
                   n_kv_heads=None, head_dim=None, attention_mask=None,
-                  operator="attention", conv_kernel=3, ffn="gelu"):
+                  operator="attention", conv_kernel=3, ffn="gelu", post_norm=False):
     """One transformer layer: a sequence operator (attention) and a
     feed-forward part, each with a residual connection and a norm.
 
     The defaults are BERT's: layer norm AFTER each residual sum, projection
     biases, a dense GELU feed-forward of width `d_ff`.  `norm="rms"` with
     `pre_norm=True` norms each part's INPUT instead (h = x + attn(norm(x));
-    y = h + ffn(norm(h))); `qk_norm` (True or "width": over the projected
+    y = h + ffn(norm(h))); `post_norm` beside it norms each part's OUTPUT too,
+    before the residual sum (the sandwich: h = x + norm(attn(norm(x))), gains
+    `post_ln1` / `post_ln2`); `qk_norm` (True or "width": over the projected
     width; "head": over each head), `positions`, `proj_bias`, `n_kv_heads`,
     `head_dim` and `attention_mask` = (kind, block length) go to the
     attention.  `operator="conv"` puts a gated short convolution of
@@ -205,10 +207,14 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                         qk_norm_per_head=qk_norm == "head",
                                         mask=attention_mask and attention_mask[0],
                                         mask_block=attention_mask and attention_mask[1])
+    if post_norm:
+        attn_out = normed(attn_out, "post_ln1")
     x = layers.elementwise_add(x, attn_out)
     if not pre_norm:
         x = normed(x, "ln1")
     ffn_out = feed_forward(normed(x, "ln2") if pre_norm else x)
+    if post_norm:
+        ffn_out = normed(ffn_out, "post_ln2")
     if dropout_prob and not is_test:
         ffn_out = layers.dropout(ffn_out, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -307,6 +313,9 @@ def build_causal_lm(
     routed_scaling_factor=1.0,
     norm_topk_eps=0.0,
     expert_bias=None,
+    post_norm=False,
+    loop=None,
+    exit_beta=0.0,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -359,7 +368,26 @@ def build_causal_lm(
     before the renormalisation) and `expert_bias` = (standard deviation,
     seed) go to the routers: layer i's bias is N(0, standard deviation) from
     seed + i, enters its choice and not its weights, and is no parameter
-    (`layers.moe`)."""
+    (`layers.moe`).
+
+    A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
+    equal to the depth makes every layer dense: no router, and the auxiliary
+    terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
+    sandwich.  `loop` = T runs the whole stack of layers AND the final norm T
+    times over the one set of weights, as ONE `layers.Repeat` of the program
+    (its body computed again in backward: `recompute=True`, the op's attribute), each pass
+    reading the last one's normed output; the loop hands on every pass's
+    output, `hidden` [T, B, L, d_model] (h_1 .. h_T), and on it the head gives
+    each exit's logits and an exit gate (a [d_model] weight and a bias, read in
+    float32) one logit a position: one product and one pass over the T outputs,
+    outside the loop, so that they read what the loop WROTE (inside the body
+    XLA may hand a consumer in the same fusion more digits than the bf16 the
+    next pass reads, and then no check on the program's own h_t is exact).
+    The loss is then `layers.exit_loss` of the T cross entropies and gate
+    logits with `exit_beta`, and the fetches also hold `logits` [T, B, L, V]
+    and `exit_p` [T, B, L, 1] (each position's exit distribution).  A loop over
+    sparse layers or with `loss_positions` is not built: the routers'
+    auxiliary terms would have to leave the body a pass."""
     main, startup = Program(), Program()
     if layer_types is not None and n_layers not in (None, len(layer_types)):
         raise ValueError(f"build_causal_lm: n_layers={n_layers} beside {len(layer_types)} layer_types; "
@@ -368,9 +396,12 @@ def build_causal_lm(
     unknown = sorted(set(kinds) - {"full_attention", "conv"})
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv")
-    if not 0 <= num_dense_layers < len(kinds) or (num_dense_layers and not dense_width):
+    if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
         raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
-                         f"among {len(kinds)} layers; the layers after them are sparse, and there is one at least")
+                         f"among {len(kinds)} layers")
+    if loop is not None and (num_dense_layers < len(kinds) or loss_positions):
+        raise ValueError("build_causal_lm: loop= takes a stack of dense layers with a label at every position "
+                         "(a router's auxiliary terms do not leave a loop's body)")
     with program_guard(main, startup):
         n_labels = loss_positions or seq_len
         ids = layers.data("ids", [seq_len], dtype="int64")
@@ -381,52 +412,86 @@ def build_causal_lm(
         if dtype != "float32":
             x = layers.cast(x, dtype)
         aux = []
-        for i, kind in enumerate(kinds):
-            dense = i < num_dense_layers
-            experts = dict(num_experts=num_experts, top_k=top_k,
-                           norm_topk_prob=norm_topk_prob, held=experts_held,
-                           router_seed=routing_seed and routing_seed + 1 + i,
-                           scoring=scoring, routed_scaling_factor=routed_scaling_factor,
-                           norm_eps=norm_topk_eps,
-                           bias=expert_bias and (expert_bias[0], expert_bias[1] + i))
-            x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
-                              f"lm.l{i}",
-                              dropout_prob=0.0, causal=attention_mask is None,
-                              use_fused_attention=use_fused_attention,
-                              norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
-                              qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
-                              moe=None if dense else experts, ffn="gated_silu",
-                              aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
-                              attention_mask=attention_mask,
-                              operator="conv" if kind == "conv" else "attention",
-                              conv_kernel=conv_kernel)
+
+        def stack_of_layers(x):
+            for i, kind in enumerate(kinds):
+                dense = i < num_dense_layers
+                experts = dict(num_experts=num_experts, top_k=top_k,
+                               norm_topk_prob=norm_topk_prob, held=experts_held,
+                               router_seed=routing_seed and routing_seed + 1 + i,
+                               scoring=scoring, routed_scaling_factor=routed_scaling_factor,
+                               norm_eps=norm_topk_eps,
+                               bias=expert_bias and (expert_bias[0], expert_bias[1] + i))
+                x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
+                                  f"lm.l{i}",
+                                  dropout_prob=0.0, causal=attention_mask is None,
+                                  use_fused_attention=use_fused_attention,
+                                  norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
+                                  qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
+                                  moe=None if dense else experts, ffn="gated_silu",
+                                  aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
+                                  attention_mask=attention_mask,
+                                  operator="conv" if kind == "conv" else "attention",
+                                  conv_kernel=conv_kernel, post_norm=post_norm)
+            return x
+
+        def final_norm(x):
+            return layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
+                                   param_attr=_attr_ones("lm.final_norm.w"))
+
+        def head(x):
+            if tie_embedding:
+                return layers.matmul(x, main.global_block().var("lm.tok_emb"), transpose_y=True)
+            return layers.fc(x, vocab_size, num_flatten_dims=len(x.shape) - 1,
+                             param_attr=_attr("lm.head.w"), bias_attr=False)
+
         feeds = {"ids": ids, "labels": labels, "pos_ids": pos_ids}
-        if loss_positions:  # the rest of the positions are context: no logits of theirs are used
-            x = layers.slice(x, axes=[1], starts=[0], ends=[loss_positions])
-        x = layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
-                            param_attr=_attr_ones("lm.final_norm.w"))
-        if tie_embedding:
-            logits = layers.matmul(x, main.global_block().var("lm.tok_emb"), transpose_y=True)
+        fetches = {}
+        if loop is not None:
+            passes = layers.Repeat(loop, recompute=True)
+            with passes.block():
+                carried = passes.carry(x)
+                x = final_norm(stack_of_layers(carried))  # the final norm closes EVERY pass
+                passes.update(carried, x)
+                passes.output(x)
+            hidden = passes()                             # h_1 .. h_T: [T, B, L, d_model]
+            with name_scope("exit_head"):
+                logits = head(hidden)
+                # the gate reads each pass's output in float32 and off the matrix unit
+                gate = layers.reduce_sum(layers.elementwise_mul(
+                    layers.cast(hidden, "float32"),
+                    layers.create_parameter([d_model], "float32", attr=_attr("lm.exit_gate.w"))),
+                    dim=-1, keep_dim=True)
+                gate = layers.elementwise_add(gate, layers.create_parameter(
+                    [1], "float32", is_bias=True,
+                    attr=ParamAttr(name="lm.exit_gate.b", initializer=ConstantInitializer(0.0))))
+            with name_scope("exit_loss"):
+                every_exit = layers.expand(layers.reshape(labels, [1, -1, n_labels, 1]), [loop, 1, 1, 1])
+                loss, exit_p = layers.exit_loss(layers.softmax_with_cross_entropy(logits, every_exit),
+                                                gate, beta=exit_beta)
+            fetches.update(exit_p=exit_p, hidden=hidden)
         else:
-            logits = layers.fc(x, vocab_size, num_flatten_dims=2,
-                               param_attr=_attr("lm.head.w"), bias_attr=False)
-        ce = layers.softmax_with_cross_entropy(
-            layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1]))
-        if loss_positions:
-            feeds["loss_weight"] = layers.data("loss_weight", [loss_positions], dtype="float32")
-            ce = layers.elementwise_mul(ce, layers.reshape(feeds["loss_weight"], [-1, 1]))
-        ce = layers.mean(ce)
-        balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / len(aux))
-        z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / len(aux))
-        terms = [ce] + [layers.scale(term, scale=coef) for term, coef in
-                        ((balance, load_balance_coef), (z_loss, router_z_coef)) if coef]
-        loss = layers.sums(terms) if len(terms) > 1 else ce
+            x = stack_of_layers(x)
+            if loss_positions:  # the rest of the positions are context: no logits of theirs are used
+                x = layers.slice(x, axes=[1], starts=[0], ends=[loss_positions])
+            logits = head(final_norm(x))
+            ce = layers.softmax_with_cross_entropy(
+                layers.reshape(logits, [-1, vocab_size]), layers.reshape(labels, [-1, 1]))
+            if loss_positions:
+                feeds["loss_weight"] = layers.data("loss_weight", [loss_positions], dtype="float32")
+                ce = layers.elementwise_mul(ce, layers.reshape(feeds["loss_weight"], [-1, 1]))
+            loss = fetches["ce"] = layers.mean(ce)
+            if aux:  # a decoder of dense layers alone has no auxiliary term
+                balance = layers.scale(layers.sums([b for b, _ in aux]), scale=1.0 / len(aux))
+                z_loss = layers.scale(layers.sums([z for _, z in aux]), scale=1.0 / len(aux))
+                fetches.update(load_balance=balance, router_z=z_loss)
+                terms = [loss] + [layers.scale(term, scale=coef) for term, coef in
+                                  ((balance, load_balance_coef), (z_loss, router_z_coef)) if coef]
+                loss = layers.sums(terms) if len(terms) > 1 else loss
         if with_optimizer:
             optimizer.Adam(learning_rate=learning_rate, beta1=beta1, beta2=beta2,
                            epsilon=epsilon).minimize(loss)
-    return (main, startup, feeds,
-            {"loss": loss, "ce": ce, "load_balance": balance, "router_z": z_loss,
-             "logits": logits})
+    return main, startup, feeds, {"loss": loss, "logits": logits, **fetches}
 
 
 def tp_rules():

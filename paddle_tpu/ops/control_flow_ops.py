@@ -77,6 +77,58 @@ def _while(ctx, op, ins):
     return {"__env_update__": final}
 
 
+@register_op("repeat")
+def _repeat(ctx, op, ins):
+    """`layers.Repeat`: the sub-block `times` over, a pass reading the carried
+    variables the last one wrote.  What the body reads from outside (its `X`:
+    parameters among them) enters each pass as an argument, so the one
+    gradient of a captured variable is the sum over the passes, added up in
+    that variable's own dtype (float32 for a master weight: the cast to the
+    activations' dtype is an op of the body).  With `recompute` each pass is a
+    `jax.checkpoint`: backward keeps the pass's inputs and runs its forward
+    again.  The RNG key rides in the carry, so a pass's random ops draw the
+    same numbers when it is computed again.
+
+    One `lax.scan` over the passes, the body traced once.  The other form, the
+    sub-block interpreted `times` over, was timed beside it on a v5e at a
+    looped decoder's published widths (PERF.md, PR 38: four passes of eight
+    layers, one sequence of 4096): its step was 1.3% LONGER, its planned peak
+    1.0 GB lower, its compiled text three times as long and its compile 3.5
+    times; it was not kept.
+
+    Scopes: the body's ops keep their `op<idx>:<type>` scopes (numbered within
+    the body) under `loop_pass`, itself under this op's own scope."""
+    from ..core.lowering import run_ops
+    from ..monitor import MONITOR as _MON
+
+    sub_ops = _sub_block_ops(ctx, op)
+    times, recompute = int(op.attr("times")), bool(op.attr("recompute", False))
+    carry_names, update_names = op.attr("carry_vars"), op.attr("carry_updates")
+    out_names = op.attr("out_vars", [])
+    captured = dict(zip(op.input("X"), ins.get("X", [])))
+    _MON.counter("lowering.loop_passes").inc(times)
+    _MON.counter("lowering.loop_body_ops").inc(len(sub_ops))
+    _MON.counter("lowering.recomputed_segments").inc(times if recompute else 0)
+
+    def one_pass(carries, key, outer):
+        env = dict(outer)
+        env.update(zip(carry_names, carries))
+        ctx.key = key
+        with jax.named_scope("loop_pass"):
+            env = run_ops(ctx, sub_ops, env)
+        return [env[n] for n in update_names], ctx.key, [env[n] for n in out_names]
+
+    if recompute:
+        one_pass = jax.checkpoint(one_pass)
+
+    def step(carry, _):
+        new, key, outs = one_pass(carry[0], carry[1], captured)
+        return (new, key), outs
+
+    (carries, ctx.key), stacked = jax.lax.scan(step, (list(ins["Init"]), ctx.key), None, length=times)
+    return {"Out": list(stacked), "Final": list(carries)}
+
+
 @register_op("conditional_block")
 def _conditional_block(ctx, op, ins):
     from ..core.lowering import run_ops
@@ -282,10 +334,47 @@ def _infer_sub_block_op(ctx):
 _A.register_rule(["while", "conditional_block"], _infer_sub_block_op)
 
 
+def _infer_repeat(ctx):
+    """repeat: the sub-block exists, the trip count is a positive constant,
+    every carried variable has an initial value and an update of its own shape
+    and dtype, and every output is `times` of its body variable."""
+    _infer_sub_block_op(ctx)
+    attrs = ctx.op.attrs
+    times = attrs.get("times")
+    if not isinstance(times, int) or times < 1:
+        ctx.fail(f"times={times!r}: the trip count is a positive integer known when the program is built")
+    carries, updates = attrs.get("carry_vars", []), attrs.get("carry_updates", [])
+    if not (len(carries) == len(updates) == ctx.n_inputs("Init")):
+        ctx.fail(f"{len(carries)} carried variables, {len(updates)} updates and {ctx.n_inputs('Init')} initial values")
+    body = ctx.block.program.blocks[attrs["sub_block"]]
+    for i, (carried, update) in enumerate(zip(carries, updates)):
+        new = body._find_var_recursive(update)
+        if new is None:
+            ctx.fail(f"the update {update!r} of {carried!r} is declared nowhere", var=update)
+        init_shape, init_dtype = ctx.in_shape("Init", i), ctx.in_dtype("Init", i)
+        if (new.shape is not None and init_shape is not None
+                and _A.unify_shape(tuple(new.shape), tuple(init_shape)) is None) or \
+                (new.dtype is not None and init_dtype is not None and str(new.dtype) != str(init_dtype)):
+            ctx.fail(f"{carried!r} starts as {tuple(init_shape)} {init_dtype} and is updated with "
+                     f"{tuple(new.shape)} {new.dtype}: a carried variable keeps its shape and dtype", var=update)
+        ctx.set_out("Final", init_shape, init_dtype, i=i)
+    for i, name in enumerate(attrs.get("out_vars", [])):
+        v = body._find_var_recursive(name)
+        if v is None:
+            ctx.fail(f"the output {name!r} is declared nowhere", var=name)
+        ctx.set_out("Out", None if v.shape is None else (times,) + tuple(v.shape), v.dtype, i=i)
+
+
+_A.register_rule(["repeat"], _infer_repeat)
+
+
 # Static cost rules (core/resource_plan.py): sub-block owners carry only
 # their own carry/select traffic — the planner descends into the body and
-# accounts its ops (one execution; trip counts are not static).
+# accounts its ops (one execution; a while's trip count is not static).
 
 from ..core import resource_plan as _RP
 
 _RP.register_bytes_cost("while", "conditional_block", "select_input")
+# repeat's own row is its carry and stacked outputs; the planner counts the
+# body's rows `times` over, and once more forward where it is recomputed
+_RP.register_bytes_cost("repeat")

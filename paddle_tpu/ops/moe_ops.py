@@ -1,7 +1,8 @@
 """The parts of a modern decoder block that no 2019 op computes: RMSNorm,
-rotary positions, a layer of routed experts (a router and the experts), and
-the gated short convolution that stands where attention does in most layers
-of a convolution-attention hybrid.
+rotary positions, a layer of routed experts (a router and the experts), the
+gated short convolution that stands where attention does in most layers
+of a convolution-attention hybrid, and the loss of a model with an exit after
+every pass of a looped stack.
 
 No reference counterpart: the reference predates all four.  The equations
 are those of OLMoE-1B-7B (Muennighoff et al. 2024, arXiv:2409.02060), which
@@ -15,6 +16,8 @@ short convolution LFM2's:
                       or s = sigmoid_f32(x Wr); e = top_k(s + b); p_e = s_e (the unbiased score)
     moe_experts       y = sum_{e in top_k} p_e . Wdown_e( silu(Wgate_e x) * (Wup_e x) )
     short_conv        y = C * conv_K(B * u),  [B, C, u] = split3(x),  conv_K causal and depthwise
+    exit_loss         p_t = sigmoid(g_t) prod_{j<t} (1 - sigmoid(g_j)),  p_T the rest;
+                      loss = mean( sum_t p_t CE_t - beta H(p) )           (Ouro's stage-one objective)
 
 Backward comes from `jax.vjp` over these lowerings like every other op's
 (core/lowering.py).  `moe_experts` makes no pass over a [rows, hidden],
@@ -658,7 +661,70 @@ set_step_stats("moe_experts", ("Load", "Dropped", "Held"), _publish_routing, att
 set_step_stats("moe_router", ("BiasMoved",), _publish_routing)
 
 
+@register_op("exit_loss")
+def _exit_loss(ctx, op, ins):
+    """The expected task loss under a learned exit distribution, with an
+    entropy term that holds the distribution towards the uniform one.  `CE`
+    and `Gate` are [T, ...]: exit t's cross entropy and exit-gate LOGIT at
+    every position.  Everything here is float32 whatever the inputs are.  The
+    probabilities are the products as written, each pass taking its share of
+    what is LEFT (left - p_t, so they sum to 1 to float32 rounding whatever the
+    device's sigmoid rounds to); the entropy's logarithms are sums of
+    log-sigmoids, log p_t = log sigmoid(g_t) + sum_{j<t} log sigmoid(-g_j), so
+    a saturated gate gives 0 x a large number and not 0 x inf.  `P` [T, ...] is the
+    distribution; `ExitMass` [T] (the mean of p_t), `Entropy` and `ExitCE`
+    [T] (the mean cross entropy of exit t) are the step's statistics and
+    carry no gradient."""
+    ce = first(ins, "CE").astype(jnp.float32)
+    gate = first(ins, "Gate").astype(jnp.float32).reshape(ce.shape)
+    left, p = jnp.ones_like(gate[0]), []
+    for lam in jax.nn.sigmoid(gate[:-1]):            # T is small and static
+        p.append(lam * left)
+        left = left - p[-1]                           # so that the exits' probabilities sum to 1 as float32 adds
+    p = jnp.stack(p + [left])
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gate), axis=0)          # log prod_{j<=t} (1 - lam_j)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]) + before[:-1], before[-1:]], axis=0)
+    entropy = -jnp.sum(p * jnp.maximum(log_p, jnp.finfo(jnp.float32).min), axis=0)
+    loss = jnp.mean(jnp.sum(p * ce, axis=0) - op.attr("beta", 0.0) * entropy)
+    per_exit = tuple(range(1, ce.ndim))
+    stats = jax.lax.stop_gradient((jnp.mean(p, axis=per_exit), jnp.mean(entropy).reshape(1),
+                                   jnp.mean(ce, axis=per_exit)))
+    return {"Loss": loss.reshape(1), "P": p, "ExitMass": stats[0], "Entropy": stats[1], "ExitCE": stats[2]}
+
+
+def _publish_loop_exit(step, values):
+    """One logged step's exit statistics (a program holds one `exit_loss`):
+    the mean exit probability a pass, the mean entropy of the distribution and
+    each exit's mean cross entropy, as a `kind="loop_exit"` record and the
+    gauges `loop.exit_mass_last_pass` / `loop.exit_entropy`."""
+    mass = [float(v) for v in np.asarray(values["ExitMass"][0], "f8").reshape(-1)]
+    entropy = float(np.asarray(values["Entropy"][0], "f8").reshape(-1)[0])
+    _MON.gauge("loop.exit_mass_last_pass").set(mass[-1])
+    _MON.gauge("loop.exit_entropy").set(entropy)
+    _MON.record_step({"kind": "loop_exit", "pipeline_step": step, "exit_mass": mass, "entropy": entropy,
+                      "exit_ce": [float(v) for v in np.asarray(values["ExitCE"][0], "f8").reshape(-1)]})
+
+
+set_step_stats("exit_loss", ("ExitMass", "Entropy", "ExitCE"), _publish_loop_exit)
+
+
 # -- build-time shape and dtype rules -----------------------------------------
+
+def _infer_exit_loss(ctx):
+    ce, gate = ctx.in_shape("CE"), ctx.in_shape("Gate")
+    if ce is None:
+        return
+    if gate is not None and int(np.prod([max(d, 1) for d in gate])) != int(np.prod([max(d, 1) for d in ce])):
+        ctx.fail(f"Gate {gate} holds another number of logits than CE {ce} of cross entropies")
+    if len(ce) < 2 or ce[0] < 2:
+        ctx.fail(f"CE must be [exits, ...] with two exits at least, got {ce}")
+    ctx.set_out("Loss", (1,), "float32")
+    ctx.set_out("P", ce, "float32")
+    ctx.set_out("ExitMass", (ce[0],), "float32")
+    ctx.set_out("Entropy", (1,), "float32")
+    ctx.set_out("ExitCE", (ce[0],), "float32")
+
 
 def _infer_rms_norm(ctx):
     xs = ctx.in_shape("X")
@@ -738,6 +804,7 @@ def _infer_short_conv(ctx):
     ctx.set_out("Out", tuple(xs[:-1]) + (ws[0],), ctx.in_dtype("X"))
 
 
+_A.register_rule(["exit_loss"], _infer_exit_loss)
 _A.register_rule(["rms_norm"], _infer_rms_norm)
 _A.register_rule(["short_conv"], _infer_short_conv)
 _A.register_rule(["rotary_embedding"], _infer_rotary_embedding)
@@ -798,6 +865,7 @@ def _cost_short_conv(ctx):
 
 _RP.register_cost(["short_conv"], _cost_short_conv)
 _RP.register_elementwise_cost("rms_norm", flops_per_elem=6.0)
+_RP.register_elementwise_cost("exit_loss", flops_per_elem=12.0)
 _RP.register_elementwise_cost("rotary_embedding", flops_per_elem=6.0)
 _RP.register_cost(["moe_router"], _cost_moe_router)
 _RP.register_cost(["moe_experts"], _cost_moe_experts)

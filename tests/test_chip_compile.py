@@ -487,6 +487,60 @@ def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip)
     assert text.count("/gated_short_conv/") > 0 and text.count("/expert_gemm/") > 0
 
 
+def test_ouros_step_compiles_for_the_chip_as_one_loop_and_its_planned_peak_leaves_room(chip):
+    """The looped cell's whole train step (benchmark/models/ouro.py: build, at
+    the configuration's and the traffic's own sizes: one sequence of 4096
+    through four passes of eight layers) compiles for the described v5e as a
+    forward and a backward `while` (the `repeat` op's scan and its transpose),
+    the pass's forward computed again inside the backward one, the splash
+    kernels inside both, and XLA plans it under the 15.5 GB the cell allows
+    itself and over the 25% of the chip a cell has to fill (PERF.md, PR 38:
+    12.7 GB).  `cost_analysis()` counts a loop's body once, so what it counts
+    stays far under what the chip does in the step's ~0.6 s: the whole-step
+    roofline share the cell reports reads LOW, never over 100%."""
+    import paddle_tpu as fluid
+    from benchmark import manifest as mf
+    from benchmark.models import ouro
+    from paddle_tpu.core import executor as ex
+
+    cfg = mf.read_json("benchmark/configs/ouro-2.6b.json")
+    job = mf.read_json("benchmark/traffic/train-ut4-s4096.json")
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = ouro.build(cfg, job)
+    main.random_seed = startup.random_seed = 3
+    scope = fluid.Scope()
+    for v in startup.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    feeds = {n: jax.ShapeDtypeStruct((job["batch_per_chip"], job["seq_len"]), I32) for n in ouro.FEEDS}
+    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu",
+                            feed_shapes={n: s.shape for n, s in feeds.items()})
+
+    def on_chip(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+
+    compiled = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names},
+                              {n: on_chip(scope.find_var(n)) for n in step.ro_names},
+                              {n: on_chip(s) for n, s in feeds.items()},
+                              on_chip(jax.random.PRNGKey(0))).compile()
+    m = compiled.memory_analysis()
+    peak = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
+    assert m.argument_size_in_bytes == pytest.approx(3 * 4 * 461.4e6, rel=1e-3)      # masters and Adam's two moments
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.5
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 2
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    # the scopes the cell's readers find: the recomputed forward, the exits, the body's ops under the construct's
+    assert text.count("/rematted_computation/") > 0
+    # numbered where this process built a looped model before: sibling `name_scope`s of one name are
+    assert re.search(r"/exit_head(_\d+)?/", text) and re.search(r"/exit_loss(_\d+)?/", text)
+    assert re.search(r':repeat/[^"]*loop_pass/op\d+:fused_attention', text) and not re.search(r'loop_pass/[^"]*exit_head', text)
+    assert re.search(r'transpose\([^"]*:repeat/[^"]*rematted_computation/[^"]*op\d+:mul', text)
+
+
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [
     ("ln", (256, 128, 768), BF16, True),
     ("ln", (7, 33, 768), BF16, True),          # 231 rows: one whole-array slab
