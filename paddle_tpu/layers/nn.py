@@ -716,15 +716,20 @@ def space_to_depth(x, blocksize, name=None):
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mask_block=None,
-                    name=None):
+                    layout="bhld", name=None):
     """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
-    float32 scores and softmax whatever the operands' dtype.  On the TPU the
-    lowering takes its tiling from what it can observe (ops/nn_ops.py): from
-    2048 keys the block-skipping splash kernels for a causal mask without a
-    bias and the streaming flash kernel otherwise, a whole-row kernel for bf16
-    sequences of 384 to 512 (the scores never reach HBM in any of them,
-    forward or backward), XLA's attention otherwise.  `bias` is an additive pre-softmax mask,
-    (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
+    float32 scores and softmax whatever the operands' dtype.  `layout="blhd"`
+    says that `q`, `k`, `v` and the result are (B, L, H, dh) instead, the
+    layout a projection's output reshapes to for nothing: the same
+    mathematics, and no transpose in the program round the op (the whole-row
+    kernel reads that layout as it is; every other lowering transposes at its
+    own edge).  On the TPU the lowering takes its tiling from what it can
+    observe (ops/nn_ops.py): from 2048 keys the block-skipping splash kernels
+    for a causal mask without a bias and the streaming flash kernel otherwise,
+    a whole-row kernel for bf16 sequences of 256 to 512 (from 384 where the
+    operands are heads-major; the scores never reach HBM in any of them,
+    forward or backward), XLA's attention otherwise.  `bias` is an additive
+    pre-softmax mask, (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
 
     `k` and `v` may have fewer heads than `q`, a divisor of its count (grouped
     key/value heads): query head j reads key/value head j div (Hq / Hkv).
@@ -743,6 +748,8 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
     if bias is not None:
         inputs["Bias"] = [bias.name]
     attrs = {"causal": causal}
+    if layout != "bhld":
+        attrs["layout"] = layout  # the op's `infer=` rule refuses a layout it does not know
     if scale is not None:
         attrs["scale"] = float(scale)
     if mask is not None:

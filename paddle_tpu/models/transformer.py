@@ -63,7 +63,13 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     than queries, and `head_dim` a head width other than d_model / n_heads:
     q projects to n_heads x head_dim, k and v to n_kv_heads x head_dim, the
     output from n_heads x head_dim back to d_model.  `mask` / `mask_block`
-    are `layers.fused_attention`'s structured mask (fused attention only)."""
+    are `layers.fused_attention`'s structured mask (fused attention only).
+
+    With `use_fused_attention`, no per-head norm and no `positions` the heads
+    are split by a reshape alone and the attention is handed (B, L, H, dh)
+    (`layout="blhd"`): the program holds no transpose round it.  Otherwise
+    the heads are transposed to the front, (B, H, L, dh), as the per-head
+    norm, the rotary embedding and the other two attentions are written."""
     d_head = head_dim or d_model // n_heads
     n_kv_heads = n_kv_heads or n_heads
     kv_in = kv if kv is not None else x
@@ -80,12 +86,18 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         k = layers.rms_norm(k, begin_norm_axis=2, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.k_norm.w"))
 
+    # Heads-major, (B, H, L, dh), is what the per-head norm, the rotary embedding and the unfused and ring attentions
+    # are written over.  Where none of them stands between the head split and a fused attention, the projections keep
+    # their own layout, (B, L, H, dh), and `fused_attention(layout="blhd")` reads it: no `transpose2` in the program.
+    per_head_norm = qk_norm_eps is not None and qk_norm_per_head
+    heads_major = not use_fused_attention or per_head_norm or positions is not None
+
     def split_heads(t, heads):
         t = layers.reshape(t, [0, 0, heads, d_head])
-        return layers.transpose(t, [0, 2, 1, 3])  # (B, H, L, dh)
+        return layers.transpose(t, [0, 2, 1, 3]) if heads_major else t
 
     q, k, v = split_heads(q, n_heads), split_heads(k, n_kv_heads), split_heads(v, n_kv_heads)
-    if qk_norm_eps is not None and qk_norm_per_head:
+    if per_head_norm:
         q = layers.rms_norm(q, begin_norm_axis=3, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.q_norm.w"))
         k = layers.rms_norm(k, begin_norm_axis=3, epsilon=qk_norm_eps,
@@ -99,7 +111,8 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
         # goes on the attention output (same substitution as the ring path).
-        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal, mask=mask, mask_block=mask_block)
+        ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal, mask=mask, mask_block=mask_block,
+                                     layout="bhld" if heads_major else "blhd")
         if dropout_prob and not is_test:
             ctx = layers.dropout(ctx, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -120,7 +133,8 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
             attn = layers.dropout(attn, dropout_prob, is_test=is_test,
                                   dropout_implementation="upscale_in_train")
         ctx = layers.matmul(attn, v)  # (B, H, L, dh)
-    ctx = layers.transpose(ctx, [0, 2, 1, 3])
+    if heads_major:
+        ctx = layers.transpose(ctx, [0, 2, 1, 3])
     ctx = layers.reshape(ctx, [0, 0, n_heads * d_head])
     return project(ctx, "out")
 

@@ -487,7 +487,7 @@ def _ring_attention(ctx, op, ins):
 # fused_attention on the TPU: five attentions, chosen by `_attention_path`
 # from what the op can observe.  Each threshold is a length, with the runs
 # that set it (TPU v5e, BERT-base's own program through benchmark.run,
-# samples/s; PERF.md, PRs 26, 29 and 30).
+# samples/s; PERF.md, PRs 26, 29, 30 and 39).
 #
 # From this many keys on, the stock Pallas flash kernel (online softmax, O(L)
 # memory): the [B,H,L,L] float32 scores are 128 MB a layer at 2048 and XLA's
@@ -502,29 +502,45 @@ _FLASH_MIN_QUERIES = 128
 # ops/pallas_attention.py: a (query, key) score block of a whole sequence
 # fits VMEM, so the scores never reach HBM and backward recomputes them.
 # 32 x 512: 212.547, 212.544, 212.543 against 181.362, 181.361, 181.363 for
-# XLA's attention (+17.2%, PR 30; the stock flash kernel there 174.87).  At
+# XLA's attention (+17.2%, PR 30; the stock flash kernel there 174.87; 239.54
+# since the kernel reads the projections' layout, PR 39, below).  At
 # 1024 the kernel's working set is 8.4 MB a pair against its 8 MB budget,
 # and no run prices the lengths in (512, 2048): they keep XLA's attention.
 _ROW_KERNEL_MAX_SEQ = 512
-# ... and from this many on: the crossing lies between 256 and 384.  At ~16k
-# tokens a step, XLA's attention | the kernel, two runs a side (PR 30):
+# ... and from this many on: the crossing lies between 128 and 256.  At ~16k
+# tokens a step, XLA's attention | the kernel, two runs a side.  With the
+# kernel over (B, H, L, dh) and four `transpose2` ops a layer round it (PR 30):
 # 48 x 384: 288.646, 288.644 | 324.393, 324.394 (+12.4%);
 # 64 x 256: 524.733 (and 494.012 with one step of 1.4 s) | 487.019, 487.022
-# (-7.2%); 256 x 128: 1132.87 | 1003.21 (-11.45%, PR 29).  Alone the kernel
-# is the faster from 256 on (forward + backward of a layer, 1.28 against
-# 2.17 ms); in the program it costs the transposes and the dropout at its
-# edges, which XLA fuses into its own attention and a custom call cannot
-# take in, and below 384 the scores it keeps out of HBM are worth less.
-_ROW_KERNEL_MIN_SEQ = 384
+# (-7.2%); 256 x 128: 1132.87 | 1003.21 (-11.45%, PR 29): what the kernel lost
+# below 384 it lost at its edges.  With the kernel over the projections' own
+# (B, L, H, dh) and no transpose in the program (PR 39):
+# 32 x 512: 212.550, 212.541 (the parent, heads-major) | 239.540, 239.536
+# (239.148, 239.153 with each direction's call one function of the module);
+# 64 x 256: 525.369, 525.334 | 573.057, 573.066 (+9.08%, `peak_hbm_gb` 9.90 ->
+# 7.15); 256 x 128: 1135.125, 1135.129 | 1132.162, 1132.205 (-0.26%, 15.45 ->
+# 12.44 GB): the lowest length at which the step gains 1% is 256.  At 128 the
+# [B, H, 128, 128] scores are 0.2 GB a layer and XLA's attention costs the
+# same time, so the rule leaves it (the 3 GB are a batch's room, not speed).
+# ONE bound, priced on the program that hands the op (B, L, H, dh).  An op
+# handed (B, H, L, dh) at 256 to 383 keys (a rotary decoder that short: no
+# cell and no builder's default) takes the kernel unpriced: PR 30's -7.2% was
+# BERT's program with transposes that only the kernel made it pay, and alone
+# the heads-major call is the faster from 256 on (1.28 against XLA's 2.17 ms a
+# layer, PR 30); PERF.md section 7 has what would price it.
+_ROW_KERNEL_MIN_SEQ = 256
 # The kernel is compiled for the v5e (tests/test_chip_compile.py) and was run
 # on it at bf16 and this head width only; lengths are whole lane tiles.
 _ROW_KERNEL_HEAD_DIM = 64
 _ROW_KERNEL_SEQ_MULTIPLE = 128
+#: the op's `layout` -> (the heads' axis, the positions' axis) of Q, K, V and Out
+_ATTENTION_AXES = {"bhld": (1, 2), "blhd": (2, 1)}
 
 
-def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False):
+def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld"):
     """Which attention `fused_attention` lowers to: "flash", "block_causal",
-    "row_kernel", "block_sparse" or "xla".  Off the TPU always "xla".  A short
+    "row_kernel", "block_sparse" or "xla", the lengths read by the op's
+    `layout`.  Off the TPU always "xla".  A short
     query against long keys (a decoding step) has no score block worth keeping
     out of HBM, hence BOTH lengths in the row kernel's rule.  Under a
     structured `mask` (`_structured_mask`) the block-sparse kernel or, as for
@@ -535,7 +551,8 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False)
         return "xla"
     from .masked_attention import kernel_block
 
-    q_len, kv_len = q.shape[2], k.shape[2]
+    positions = _ATTENTION_AXES[layout][1]
+    q_len, kv_len = q.shape[positions], k.shape[positions]
     one_device = mesh is None or mesh.size == 1
     if mask is not None:
         whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
@@ -597,7 +614,7 @@ def _flash_attention_tpu(q, k, v, bias, causal, scale):
     return out.astype(q.dtype)
 
 
-def _structured_mask(op, q, k):
+def _structured_mask(op, q, k, layout="bhld"):
     """The op's mask where it is a rule over positions: (kind, block length),
     or None.  It is part of the mathematics, as `causal` is, and not a choice
     among lowerings of one mathematics."""
@@ -607,16 +624,38 @@ def _structured_mask(op, q, k):
     from .masked_attention import MASKS
 
     block = op.attr("mask_block", None)
-    positions = q.shape[2]
-    if kind not in MASKS or not block or k.shape[2] != positions or positions % (2 * block):
+    axis = _ATTENTION_AXES[layout][1]
+    positions, keys = q.shape[axis], k.shape[axis]
+    if kind not in MASKS or not block or keys != positions or positions % (2 * block):
         raise ValueError(f"fused_attention: mask {kind!r} with mask_block {block} over {positions} queries "
-                         f"and {k.shape[2]} keys; known masks {MASKS}, over 2L positions in blocks that divide L")
+                         f"and {keys} keys; known masks {MASKS}, over 2L positions in blocks that divide L")
     return kind, int(block)
+
+
+def _xla_attention(q, k, v, bias, causal, scale, mask):
+    """Two einsums round `jax.nn.softmax` over (B, H, L, dh), the scores in HBM."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if bias is not None:
+        s = s + bias.astype(jnp.float32)
+    if causal:
+        Lq, Lk = s.shape[-2], s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq), s, -1e30)
+    if mask is not None:
+        from .masked_attention import block_diffusion_allowed
+
+        at = jnp.arange(s.shape[-1], dtype=jnp.int32)
+        s = jnp.where(block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1]), s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
 
 
 @register_op("fused_attention")
 def _fused_attention(ctx, op, ins):
-    """Scaled-dot-product attention over (B, H, L, dh): softmax(q k^T * scale
+    """Scaled-dot-product attention over (B, H, L, dh), or over (B, L, H, dh)
+    where the op's `layout` is "blhd": softmax(q k^T * scale
     + bias, causal mask) v, with the operands in their own dtype on the MXU,
     float32 accumulation, float32 scores and softmax, and the probabilities
     rounded to the activations' dtype for the product with v.  One
@@ -651,9 +690,19 @@ def _fused_attention(ctx, op, ins):
     `block_sparse`) read them so; the other three are given K and V repeated
     at their edge.
 
+    `layout` is part of the op's signature, as `causal` is, and no switch
+    among lowerings: "blhd" hands Q, K, V over as (B, L, H, dh), what a
+    projection's output reshapes to for nothing, and takes Out back so.  The
+    row kernel reads either layout as it is (its BlockSpecs' matter); every
+    other path is written over (B, H, L, dh) and transposes a "blhd" op's
+    operands and result at its own edge, which is the device work of the
+    `transpose2` ops a program would otherwise hold.
+    `lowering.attention_layout_native` counts the ops whose path read what it
+    was handed, `lowering.attention_layout_transposed` the others.
+
     Under a mesh of more than one device the row kernel is NOT taken: a
     `pallas_call` is a custom call that GSPMD cannot partition, and the XLA
-    path, which it can, is correct there at no new code (no cell runs 384 to
+    path, which it can, is correct there at no new code (no cell runs 256 to
     512 keys on a mesh; a `shard_map` over the batch axis is the other way,
     when one does).  The bias derives from lengths and causality in every
     caller, so the row kernel treats it as a constant."""
@@ -662,46 +711,39 @@ def _fused_attention(ctx, op, ins):
     v = first(ins, "V")
     bias = first(ins, "Bias") if "Bias" in ins and ins["Bias"] else None
     causal = op.attr("causal", False)
+    layout = op.attr("layout", "bhld")
     scale = op.attr("scale", None)
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    mask = _structured_mask(op, q, k)
-    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None)
+    mask = _structured_mask(op, q, k, layout)
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout)
     _MON.counter(f"lowering.attention_{path}").inc()
+    native = layout == "bhld" or path == "row_kernel"
+    _MON.counter("lowering.attention_layout_native" if native else "lowering.attention_layout_transposed").inc()
+    if not native:
+        q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+    heads = _ATTENTION_AXES[layout][0] if native else 1
     if path == "block_sparse":
         from .masked_attention import block_sparse_attention
 
-        return {"Out": block_sparse_attention(q, k, v, mask[1], float(scale))}
-    if path == "block_causal":
+        out = block_sparse_attention(q, k, v, mask[1], float(scale))
+    elif path == "block_causal":
         from .masked_attention import causal_attention
 
-        return {"Out": causal_attention(q, k, v, float(scale))}
-    if k.shape[1] != q.shape[1]:
-        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
-    if path == "flash":
-        return {"Out": _flash_attention_tpu(q, k, v, bias, causal, scale)}
-    if path == "row_kernel":
-        from .pallas_attention import fused_sdpa
+        out = causal_attention(q, k, v, float(scale))
+    else:
+        if k.shape[heads] != q.shape[heads]:
+            k, v = (jnp.repeat(t, q.shape[heads] // t.shape[heads], axis=heads) for t in (k, v))
+        if path == "flash":
+            out = _flash_attention_tpu(q, k, v, bias, causal, scale)
+        elif path == "row_kernel":
+            from .pallas_attention import fused_sdpa
 
-        b = jax.lax.stop_gradient(bias) if bias is not None else None
-        out = fused_sdpa(q, k, v, b, bool(causal), float(scale))
-        return {"Out": out.astype(q.dtype)}
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    if bias is not None:
-        s = s + bias.astype(jnp.float32)
-    if causal:
-        Lq, Lk = s.shape[-2], s.shape[-1]
-        s = jnp.where(jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq), s, -1e30)
-    if mask is not None:
-        from .masked_attention import block_diffusion_allowed
-
-        at = jnp.arange(s.shape[-1], dtype=jnp.int32)
-        s = jnp.where(block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1]), s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return {"Out": out.astype(q.dtype)}
+            b = jax.lax.stop_gradient(bias) if bias is not None else None
+            out = fused_sdpa(q, k, v, b, bool(causal), float(scale), False, layout).astype(q.dtype)
+        else:
+            out = _xla_attention(q, k, v, bias, causal, scale, mask)
+    return {"Out": out if native else jnp.swapaxes(out, 1, 2)}
 
 
 @register_op("top_k")
@@ -1368,6 +1410,33 @@ def _infer_ring_attention(ctx):
 _A.register_rule(["ring_attention"], _infer_ring_attention)
 
 
+def _infer_fused_attention(ctx):
+    """Out is Q's shape in either layout; K and V agree with each other, with
+    Q's head width and, by the op's `layout`, hold a divisor of its heads."""
+    layout = ctx.op.attr("layout", "bhld")
+    if layout not in _ATTENTION_AXES:
+        ctx.fail(f"layout {layout!r}: (B, H, L, dh) is \"bhld\", (B, L, H, dh) is \"blhd\"")
+    qs, ks, vs = ctx.in_shape("Q"), ctx.in_shape("K"), ctx.in_shape("V")
+    if qs is None:
+        return
+    if len(qs) != 4:
+        ctx.fail(f"Q must have four axes, {layout}, got {qs}")
+    heads = _ATTENTION_AXES[layout][0]
+    for name, shape in (("K", ks), ("V", vs)):
+        if shape is None:
+            continue
+        if len(shape) != 4 or (shape[-1] != qs[-1] and _A.DYN not in (shape[-1], qs[-1])):
+            ctx.fail(f"{name} must have four axes, {layout}, and Q's head width {qs[-1]}, got {shape}")
+        if _A.DYN not in (shape[heads], qs[heads]) and (shape[heads] < 1 or qs[heads] % shape[heads]):
+            ctx.fail(f"{name}'s {shape[heads]} heads (axis {heads} of {layout}) do not divide Q's {qs[heads]}")
+    if ks is not None and vs is not None and tuple(ks) != tuple(vs):
+        ctx.fail(f"K {ks} and V {vs} differ")
+    ctx.set_out("Out", qs, ctx.in_dtype("Q"))
+
+
+_A.register_rule(["fused_attention"], _infer_fused_attention)
+
+
 # --- static cost rules (core/resource_plan.py) ------------------------------
 
 from ..core import resource_plan as _RP
@@ -1468,8 +1537,9 @@ def _cost_fused_attention(ctx):
     qs, ks = ctx.in_shape("Q"), ctx.in_shape("K")
     if qs is None or ks is None or len(qs) < 4 or len(ks) < 3:
         return float(ctx.out_elems_total()), ctx.io_bytes()
-    b, h, lq, dh = qs[0], qs[1], qs[2], qs[3]
-    lk = ks[2]
+    heads, positions = _ATTENTION_AXES[ctx.op.attr("layout", "bhld")]
+    b, h, lq, dh = qs[0], qs[heads], qs[positions], qs[3]
+    lk = ks[positions]
     pairs = _elems_xs((lq, lk))
     if ctx.op.attr("mask", None) is not None and ctx.op.attr("mask_block", None):
         from .masked_attention import allowed_pairs

@@ -162,6 +162,31 @@ CASES = {
     "fused_sdpa_q256_k512": (
         lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
         [((32, 12, 256, 64), BF16)] + [((32, 12, 512, 64), BF16)] * 2, (0, 1, 2)),
+    # the same kernel over the projections' own layout (B, L, H, dh), read as [B, L, H*dh] with the heads a grid step
+    # side by side on the lanes and each head's tile a static lane slice (PR 39): `bert-base.pretrain-s512`'s call
+    # since then, the rule's other length, the lengths `_ROW_KERNEL_MIN_SEQ`'s runs priced, a shared mask under a
+    # causal one, a mask a head, queries and keys of different lengths
+    "fused_sdpa_blhd_seq512": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125, False, "blhd"),
+        [((32, 512, 12, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_blhd_seq384": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125, False, "blhd"),
+        [((48, 384, 12, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_blhd_seq256": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125, False, "blhd"),
+        [((64, 256, 12, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_blhd_seq128": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125, False, "blhd"),
+        [((256, 128, 12, 64), BF16)] * 3, (0, 1, 2)),
+    "fused_sdpa_blhd_seq512_bias_causal": (
+        lambda q, k, v, b: fused_sdpa(q, k, v, b, True, 0.125, False, "blhd"),
+        [((32, 512, 12, 64), BF16)] * 3 + [((32, 1, 512, 512), F32)], (0, 1, 2)),
+    "fused_sdpa_blhd_seq512_bias_per_head": (
+        lambda q, k, v, b: fused_sdpa(q, k, v, b, False, 0.125, False, "blhd"),
+        [((8, 512, 12, 64), BF16)] * 3 + [((8, 12, 512, 512), BF16)], (0, 1, 2)),
+    "fused_sdpa_blhd_q384_k512": (
+        lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125, False, "blhd"),
+        [((32, 384, 12, 64), BF16)] + [((32, 512, 12, 64), BF16)] * 2, (0, 1, 2)),
     # through `_flash_block_sizes`: 1024-blocks without a bias, 512 with one
     # (1024 with a bias overruns the scoped VMEM in the dq kernel), the
     # kernel's default where the length is a multiple of neither

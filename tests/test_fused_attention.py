@@ -177,10 +177,7 @@ def test_the_causal_plans_kernels_agree_with_xlas_attention_forward_and_backward
     cases; and the block map skips what the rule empties."""
     import jax
     import jax.numpy as jnp
-    from types import SimpleNamespace
 
-    from paddle_tpu.core.lowering import LoweringContext
-    from paddle_tpu.core.registry import get_op_def
     from paddle_tpu.ops import masked_attention
 
     rng = np.random.RandomState(37)
@@ -196,10 +193,8 @@ def test_the_causal_plans_kernels_agree_with_xlas_attention_forward_and_backward
         return masked_attention.causal_attention(q, k, v, dh ** -0.5, interpret=True)
 
     def xla(q, k, v):
-        op = SimpleNamespace(type="fused_attention", attr=lambda name, default=None: {"causal": True}.get(name, default))
-        ctx = LoweringContext(jax.random.PRNGKey(0), platform="cpu")
         with jax.default_matmul_precision("highest"):
-            return get_op_def("fused_attention").lower(ctx, op, {"Q": [q], "K": [k], "V": [v]})["Out"]
+            return _lower_attention("cpu", "bhld", causal=True)(q, k, v)
 
     def agree(got, want, tol):
         got, want = np.asarray(got, "f8"), np.asarray(want, "f8")
@@ -211,3 +206,216 @@ def test_the_causal_plans_kernels_agree_with_xlas_attention_forward_and_backward
     want = jax.grad(lambda *a: jnp.sum(xla(*a) * weight), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         agree(g, w, tol=2e-5)
+
+
+# --------------------------------------------------------------------------
+# The op's `layout` (ISSUE 39): "blhd" hands Q, K, V over as (B, L, H, dh), the
+# projections' own layout, and takes Out back so.  One mathematics: every path
+# gives what it gives heads-major on the transposed operands.
+# --------------------------------------------------------------------------
+
+
+def _lower_attention(platform, layout, causal=False, mask=None, mask_block=None, mesh=None):
+    """f(q, k, v, *bias) -> Out: the op's registered lowering for `platform`, as the executor calls it."""
+    import jax
+    from types import SimpleNamespace
+
+    from paddle_tpu.core.lowering import LoweringContext
+    from paddle_tpu.core.registry import get_op_def
+
+    attrs = {"causal": causal, "layout": layout, "mask": mask, "mask_block": mask_block}
+    op = SimpleNamespace(type="fused_attention", attr=lambda name, default=None: attrs.get(name, default))
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform, mesh=mesh)
+    return lambda q, k, v, *bias: get_op_def("fused_attention").lower(ctx, op, {"Q": [q], "K": [k], "V": [v], "Bias": list(bias)})["Out"]
+
+
+#: query heads, key/value heads, causal, bias, structured mask
+LAYOUT_CASES = {"plain": (4, 4, False, False, False), "causal": (4, 4, True, False, False), "bias": (4, 4, False, True, False),
+                "causal-bias": (4, 4, True, True, False), "grouped": (4, 2, False, False, False),
+                "grouped-causal": (6, 2, True, False, False), "block-diffusion": (4, 4, False, False, True),
+                "block-diffusion-grouped": (4, 1, False, False, True)}
+
+
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_the_op_over_the_projections_layout_is_the_op_over_heads_major_on_transposed_operands(case):
+    """XLA's attention (every platform but the TPU, and the TPU's short and
+    unpriced lengths): the output and the three gradients, to the bit: the
+    lowering transposes at its own edge and runs the same einsums."""
+    import jax
+    import jax.numpy as jnp
+
+    hq, hkv, causal, biased, masked = LAYOUT_CASES[case]
+    rng = np.random.RandomState(39)
+    q = rng.randn(2, hq, 16, 8).astype("f4")
+    k, v = (rng.randn(2, hkv, 16, 8).astype("f4") for _ in range(2))
+    w = rng.randn(*q.shape).astype("f4")
+    bias = (rng.randn(2, 1, 16, 16).astype("f4"),) if biased else ()
+    mask = dict(mask="block_diffusion", mask_block=4) if masked else {}
+    swap = lambda t: jnp.swapaxes(t, 1, 2)  # noqa: E731
+
+    def results(f, w, *operands):
+        out, vjp = jax.vjp(lambda *a: f(*a, *bias), *operands)
+        return (out,) + vjp(w)
+
+    want = results(_lower_attention("cpu", "bhld", causal, **mask), w, q, k, v)
+    got = results(_lower_attention("cpu", "blhd", causal, **mask), swap(w), swap(q), swap(k), swap(v))
+    for g, t in zip(got, want):
+        assert g.shape == swap(t).shape
+        np.testing.assert_array_equal(np.asarray(swap(g)), np.asarray(t))
+
+
+#: (queries, keys), causal, structured mask -> the attention a TPU takes, the kernels in its jaxpr.  One choice
+#: whatever the layout, the row kernel's lower end too: one bound, `_ROW_KERNEL_MIN_SEQ`, 256 keys
+TPU_LAYOUT_CASES = {
+    ((128, 128), False, False): ("xla", set()), ((256, 128), False, False): ("xla", set()),
+    ((256, 256), False, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
+    ((512, 256), False, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
+    ((384, 384), False, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
+    ((512, 512), True, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
+    ((512, 384), False, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
+    ((1, 512), False, False): ("xla", set()), ((1024, 1024), False, False): ("xla", set()),
+    ((2048, 2048), False, False): ("flash", {"flash_attention"}),
+    ((2048, 2048), True, False): ("block_causal", {"splash_mha_fwd", "splash_mha_dkv"}),
+    ((2048, 2048), False, True): ("block_sparse", {"splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"}),
+}
+
+
+@pytest.mark.parametrize("lengths,causal,masked", list(TPU_LAYOUT_CASES),
+                         ids=[f"{n[0]}x{n[1]}{'-causal' * c}{'-block-diffusion' * m}" for n, c, m in TPU_LAYOUT_CASES])
+def test_on_the_tpu_the_layout_changes_no_choice_and_only_the_row_kernel_reads_it_as_it_is(lengths, causal, masked):
+    """`_attention_path` reads the lengths by the layout: the same attention
+    for (B, L, H, dh) as for (B, H, L, dh).  The row kernel's jaxpr holds no
+    transpose under either layout; every other path's holds, under "blhd",
+    the same kernels as heads-major and the transposes at its own edge
+    (four forward: q, k, v and the result); the two counters say which."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import monitor
+    from paddle_tpu.ops.nn_ops import _attention_path
+
+    (lq, lk), head = lengths, 128 if masked else 64
+    mask = dict(mask="block_diffusion", mask_block=4) if masked else {}
+    case = (lengths, causal, masked)
+    shapes = {"bhld": [(2, 4, n, head) for n in (lq, lk, lk)], "blhd": [(2, n, 4, head) for n in (lq, lk, lk)]}
+    found = {}
+    monitor.enable()
+    try:
+        for layout, operands in shapes.items():
+            path, kernels = TPU_LAYOUT_CASES[case]
+            args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in operands]
+            assert _attention_path("tpu", None, args[0], args[1], ("block_diffusion", 4) if masked else None, causal, False,
+                                   layout) == path
+            f = _lower_attention("tpu", layout, causal, **mask)
+            before = monitor.get_monitor().counter_values()
+            text = str(jax.make_jaxpr(jax.grad(lambda *a: f(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(*args))
+            moved = {n[len("lowering.attention_"):]: c - before.get(n, 0) for n, c in monitor.get_monitor().counter_values().items()
+                     if n.startswith("lowering.attention_") and c != before.get(n, 0)}
+            assert set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv)\w*\b",
+                                  text)) == kernels
+            native = layout == "bhld" or path == "row_kernel"
+            assert moved.pop(path) == 1 and moved.pop("layout_native" if native else "layout_transposed") == 1
+            assert not {n for n in moved if n.startswith("layout_") or n in ("xla", "flash", "row_kernel", "block_causal", "block_sparse")}
+            found[layout] = len(re.findall(r"\btranspose\[", text))
+    finally:
+        monitor.disable()
+        monitor.reset()
+    if TPU_LAYOUT_CASES[case][0] == "row_kernel":
+        assert found["blhd"] == found["bhld"] == 0
+    else:
+        assert found["blhd"] >= found["bhld"] + 4
+
+
+def test_the_infer_rule_reads_heads_and_lengths_by_the_layout():
+    """Out is Q's shape in either layout; K's heads must divide Q's along the
+    layout's heads axis; the rule refuses a layout it does not know when the op is appended."""
+    def attention(layout, q_shape, kv_shape):
+        main, startup = Program(), Program()
+        with program_guard(main, startup):
+            q = layers.data("q", q_shape)
+            k, v = layers.data("k", kv_shape), layers.data("v", kv_shape)
+            return layers.fused_attention(q, k, v, layout=layout), main
+
+    out, main = attention("blhd", [16, 6, 8], [24, 2, 8])  # 16 queries, 24 keys, six heads on two
+    assert tuple(out.shape)[1:] == (16, 6, 8)
+    assert [op.attrs["layout"] for op in main.global_block().ops if op.type == "fused_attention"] == ["blhd"]
+    out, main = attention("bhld", [6, 16, 8], [2, 24, 8])
+    assert tuple(out.shape)[1:] == (6, 16, 8)
+    assert ["layout" in op.attrs for op in main.global_block().ops if op.type == "fused_attention"] == [False]
+    with pytest.raises(Exception, match="heads"):
+        attention("blhd", [6, 16, 8], [2, 24, 8])  # heads-major shapes under the other layout's name: 16 heads on 24
+    with pytest.raises(Exception, match="layout 'bldh'"):
+        attention("bldh", [16, 6, 8], [24, 2, 8])
+
+
+def test_the_planners_row_counts_the_same_products_under_either_layout():
+    from paddle_tpu.core import resource_plan
+
+    def flops(layout, q_shape, kv_shape):
+        main, startup = Program(), Program()
+        with program_guard(main, startup):
+            q = layers.data("q", q_shape)
+            k, v = layers.data("k", kv_shape), layers.data("v", kv_shape)
+            layers.fused_attention(q, k, v, layout=layout)
+        feeds = {"q": (2, *q_shape), "k": (2, *kv_shape), "v": (2, *kv_shape)}
+        return [row.flops for row in resource_plan.plan_program(main, feeds).rows if row.op_type == "fused_attention"]
+
+    assert flops("blhd", [16, 6, 8], [24, 6, 8]) == flops("bhld", [6, 16, 8], [6, 24, 8]) == [4.0 * 2 * 6 * 8 * 16 * 24]
+
+
+@pytest.mark.parametrize("layout", ["bhld", "blhd"])
+def test_a_program_runs_the_op_under_either_layout(layout):
+    """Through `layers.fused_attention` and the executor, against the unfused program."""
+    rng = np.random.RandomState(5)
+    B, H, L, D = 2, 3, 16, 8
+    q, k, v = (rng.randn(B, H, L, D).astype(np.float32) for _ in range(3))
+    bias = np.where(rng.rand(B, 1, L, L) < 0.2, -1e30, 0.0).astype(np.float32)
+    at = (lambda t: t) if layout == "bhld" else (lambda t: np.ascontiguousarray(t.transpose(0, 2, 1, 3)))
+
+    def build(fused):
+        shape = [H, L, D] if layout == "bhld" or not fused else [L, H, D]
+        qv, kv, vv = (layers.data(n, shape) for n in "qkv")
+        bv = layers.data("bias", [1, L, L])
+        if fused:
+            return layers.fused_attention(qv, kv, vv, bias=bv, layout=layout)
+        return _plain_attention(qv, kv, vv, bias=bv)
+
+    fused = _run(lambda: build(True), {"q": at(q), "k": at(k), "v": at(v), "bias": bias}, "out")
+    plain = _run(lambda: build(False), {"q": q, "k": k, "v": v, "bias": bias}, "out")
+    np.testing.assert_allclose(fused, at(plain), rtol=1e-5, atol=1e-5)
+
+
+#: builder -> `transpose2` ops in the program it builds, and the layout of its `fused_attention` ops
+def _bert(**kw):
+    from paddle_tpu.models import transformer
+
+    return transformer.build_bert(vocab_size=100, seq_len=16, d_model=32, n_layers=2, n_heads=2, d_ff=64, **kw)[0]
+
+
+def _decoder(**kw):
+    from paddle_tpu.models import transformer
+
+    return transformer.build_causal_lm(vocab_size=64, seq_len=16, d_model=32, n_layers=2, n_heads=2, head_dim=16, expert_width=32,
+                                       num_experts=4, top_k=2, **kw)[0]
+
+
+TRANSPOSES = {
+    "bert-fused": (lambda: _bert(use_fused_attention=True), 0, ["blhd", "blhd"]),
+    "bert-fused-for-test": (lambda: _bert(use_fused_attention=True).clone(for_test=True), 0, ["blhd", "blhd"]),
+    "bert-unfused": (lambda: _bert(use_fused_attention=False), 8, []),
+    "rotary-decoder": (_decoder, 8, [None, None]),
+}
+
+
+@pytest.mark.parametrize("case", list(TRANSPOSES))
+def test_the_builder_transposes_only_where_something_needs_heads_major(case):
+    """BERT's builder (no rotary embedding, no per-head norm) hands the fused
+    attention the projections' own layout and its program holds no
+    `transpose2`; the unfused attention and a rotary decoder keep the four a
+    layer they had."""
+    build, transposes, layouts = TRANSPOSES[case]
+    ops = build().global_block().ops
+    assert sum(op.type == "transpose2" for op in ops) == transposes
+    assert [op.attrs.get("layout") for op in ops if op.type == "fused_attention"] == layouts

@@ -165,3 +165,105 @@ def test_the_row_kernel_is_as_close_to_float32_as_xlas_attention_on_peaked_rows(
         assert errors["row_kernel"][part] <= 1.5 * of_xla, (part, errors)
         assert errors["bf16_scores"][part] > 1.5 * of_xla, (part, errors)
         assert of_xla < 1e-2, (part, errors)
+
+
+# --------------------------------------------------------------------------
+# The projections' own layout (ISSUE 39): q, k, v and the result (B, L, H, dh),
+# read and written by the kernel as [B, L, H*dh] with `g` heads side by side on
+# the lanes of a block and each head's [L, dh] tile a static slice of them.
+# --------------------------------------------------------------------------
+
+
+def _blhd(t):
+    return jnp.swapaxes(t, 1, 2)
+
+
+def _layout_operands(lq, lk, bias_kind, seed):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(1, 12, lq, 64), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(1, 12, lk, 64), jnp.float32) for _ in range(2))
+    w = jnp.asarray(rng.randn(1, 12, lq, 64), jnp.float32)
+    bias = {None: None, "bcast": (1, 1, lq, lk), "per_head": (1, 12, lq, lk)}[bias_kind]
+    if bias is not None:
+        bias = jnp.asarray(rng.randn(*bias) * 2, jnp.float32)
+    return q, k, v, w, bias
+
+
+#: (queries, keys), causal, bias: self-attention at the kernel's two lengths under every mask, and queries against more keys
+LAYOUT_CASES = [(n, causal, bias) for n in ((384, 384), (512, 512)) for causal, bias in
+                ((False, None), (True, None), (False, "bcast"), (True, "per_head"))] + [((384, 512), False, None), ((384, 512), False, "bcast")]
+
+
+@pytest.mark.parametrize("lengths,causal,bias_kind", LAYOUT_CASES,
+                         ids=[f"{n[0]}x{n[1]}{'-causal' * c}{'-bias-' + b if b else ''}" for n, c, b in LAYOUT_CASES])
+@pytest.mark.parametrize("g", [2, 6, 12])
+def test_the_kernel_over_the_projections_layout_agrees_with_heads_major_and_with_xla(g, lengths, causal, bias_kind,
+                                                                                     monkeypatch):
+    """BERT-base's twelve 64-wide heads, one sequence: the kernel over
+    (B, L, H, dh) at `g` heads a grid step against today's heads-major call
+    (the same `_sdpa_tile` and `_sdpa_tile_bwd` a head: to rounding's last
+    place) and against XLA's attention, the output and dq, dk, dv."""
+    from paddle_tpu.ops import pallas_attention
+
+    lq, lk = lengths
+    q, k, v, w, bias = _layout_operands(lq, lk, bias_kind, seed=g + lq)
+    scale = 0.125
+
+    def results(f, *operands):
+        out, vjp = jax.vjp(f, *operands)
+        return (out,) + vjp(w if out.shape == w.shape else _blhd(w))
+
+    heads_major = results(lambda q, k, v: fused_sdpa(q, k, v, bias, causal, scale, True), q, k, v)
+    with jax.default_matmul_precision("highest"):
+        xla = results(lambda q, k, v: _ref(q, k, v, bias, causal, scale), q, k, v)
+    monkeypatch.setattr(pallas_attention, "_pick_heads", lambda *a: g)
+    native = results(lambda q, k, v: fused_sdpa(q, k, v, bias, causal, scale, True, "blhd"), _blhd(q), _blhd(k), _blhd(v))
+    for got, same, want in zip(native, heads_major, xla):
+        got = np.asarray(_blhd(got))
+        assert got.shape == want.shape and np.isfinite(got).all()
+        np.testing.assert_allclose(got, same, rtol=0, atol=1e-6 * float(np.abs(same).max()))
+        assert np.abs(got - want).max() <= 2e-4 * np.abs(want).max(), (np.abs(got - want).max(), np.abs(want).max())
+
+
+@pytest.mark.parametrize("layout,heads,length,want", [
+    ("bhld", 12, 512, (3, 2)), ("bhld", 12, 384, (4, 3)), ("bhld", 12, 128, (12, 12)), ("bhld", 5, 512, (1, 1)),
+    ("blhd", 12, 512, (12, 6)), ("blhd", 12, 384, (12, 12)), ("blhd", 12, 256, (12, 12)), ("blhd", 5, 512, (5, 5)),
+    ("blhd", 3, 128, (3, 3)),
+])
+def test_the_heads_a_grid_step_fit_the_budget_and_tile_the_lanes(layout, heads, length, want):
+    """`_pick_heads`: heads-major any divisor of H that fits (PR 30's 3 and 2
+    pairs at 512 keys, 4 and 3 at 384); in the projections' layout the score
+    buffers count once and a block's g*dh lanes are whole 128-lane tiles or
+    the whole row (five 64-wide heads: the whole row, whatever the budget);
+    a float32 bias of its own a head brings twelve heads at 512 keys down to
+    two."""
+    from paddle_tpu.ops.pallas_attention import _pick_heads
+
+    got = tuple(_pick_heads(heads, length, 64, 2, bufs, layout) for bufs in ((6, 2), (10, 3)))
+    assert got == want
+    for g in got:
+        assert heads % g == 0 and (layout == "bhld" or g == heads or (g * 64) % 128 == 0)
+    if (layout, heads, length) == ("blhd", 12, 512):
+        assert _pick_heads(heads, length, 64, 2, (6, 2), layout, head_bias=512 * 512 * 4) == 2
+
+
+@pytest.mark.parametrize("layout", ["bhld", "blhd"])
+def test_layers_of_one_signature_share_one_lowered_kernel_a_direction(layout):
+    """Six layers' attentions and their gradients, lowered for the TPU here:
+    the module holds TWO Mosaic calls, one a direction, that every layer
+    calls (the calls are `jax.jit`s of their own: a bare `pallas_call` is
+    traced and lowered layer by layer, which was `bert-base.pretrain-s512`'s
+    `setup_lower_s`, PR 39); a second signature (causal) adds its own two."""
+    shape = (2, 12, 512, 64) if layout == "bhld" else (2, 512, 12, 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def layers(causal_too):
+        def loss(q, k, v):
+            out = fused_sdpa(q, k, v, None, True, 0.125, False, layout) if causal_too else q
+            for _ in range(6):
+                out = fused_sdpa(out, k, v, None, False, 0.125, False, layout)
+            return out.astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x).lower(lowering_platforms=("tpu",)).as_text()
+
+    assert layers(False).count("@tpu_custom_call") == 2
+    assert layers(True).count("@tpu_custom_call") == 4

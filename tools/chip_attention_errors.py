@@ -44,6 +44,9 @@ scale = shape[-1] ** -0.5
 attentions = {
     "xla": xla_attention,
     "row_kernel": lambda q, k, v: fused_sdpa(q, k, v, None, False, scale),
+    # the same kernel over the projections' own layout, (B, L, H, dh), through the reference's axes (PR 39)
+    "row_kernel_blhd": lambda q, k, v: jnp.swapaxes(fused_sdpa(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)), None, False, scale,
+                                                               False, "blhd"), 1, 2),
     "flash": lambda q, k, v: _flash_attention_tpu(q, k, v, None, False, scale),
 }
 if DRY:
