@@ -732,7 +732,9 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
     pre-softmax mask, (B, 1|H, Lq, Lk).  `scale` defaults to 1/sqrt(dh).
 
     `k` and `v` may have fewer heads than `q`, a divisor of its count (grouped
-    key/value heads): query head j reads key/value head j div (Hq / Hkv).
+    key/value heads): query head j reads key/value head j div (Hq / Hkv).  `v`
+    may have another head width than `q` and `k` (latent attention's 192-wide
+    queries and keys beside 128-wide values); the result has `v`'s.
 
     `mask="block_diffusion"` with `mask_block=B` is the mask of
     block-diffusion training over the 2L positions [noised ; clean] of L
@@ -743,7 +745,7 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
     so nothing of [2L, 2L] exists on the TPU, where a block-sparse kernel
     skips the three quarters of the square the rule empties."""
     helper = LayerHelper("fused_attention", name=name)
-    out = _out(helper, q.dtype, shape=q.shape)
+    out = _out(helper, q.dtype, shape=tuple(q.shape[:-1]) + (v.shape[-1],))
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
     if bias is not None:
         inputs["Bias"] = [bias.name]
@@ -761,17 +763,20 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
 
 def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None, name=None):
     """Root-mean-square norm over the axes from `begin_norm_axis` on, with a
-    learned gain (initialised to 1) and no shift: y = x / sqrt(mean(x^2) +
-    epsilon) * g.  Statistics are float32 whatever the input's dtype."""
+    learned gain (initialised to 1; none with `param_attr=False`) and no shift:
+    y = x / sqrt(mean(x^2) + epsilon) * g.  Statistics are float32 whatever the
+    input's dtype."""
     helper = LayerHelper("rms_norm", name=name)
     from ..core.initializer import ConstantInitializer
 
     norm_size = int(np.prod(input.shape[begin_norm_axis:]))
-    gain = helper.create_parameter(param_attr, [norm_size], input.dtype,
-                                   default_initializer=ConstantInitializer(1.0))
+    inputs = {"X": [input.name]}
+    if param_attr is not False:   # False: no gain (a plain normalisation, as an L2 norm a head is)
+        inputs["Scale"] = [helper.create_parameter(param_attr, [norm_size], input.dtype,
+                                                   default_initializer=ConstantInitializer(1.0)).name]
     out = _out(helper, input.dtype, shape=input.shape)
     helper.append_op(
-        "rms_norm", inputs={"X": [input.name], "Scale": [gain.name]},
+        "rms_norm", inputs=inputs,
         outputs={"Y": [out.name]},
         attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
     return _keep_lod(input, out)
@@ -812,7 +817,8 @@ def rotary_embedding(x, positions, theta=10000.0, name=None):
 
 def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None,
-        scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0):
+        scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0,
+        shared_experts=0, shared_attrs=None):
     """A layer of routed experts over (..., d): a float32 router picks
     `top_k` of `num_experts` gated-SiLU experts of width `expert_width` for
     every token; their outputs are summed, weighted by the router's
@@ -841,7 +847,14 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     and what the absent experts would have added is left out: the layers of
     the chips that hold them add it.  Assignments to absent experts are never
     rows of the grouped products or of a gather; no assignment to a held
-    expert is dropped.  `held=None` holds them all."""
+    expert is dropped.  `held=None` holds them all.
+
+    `shared_experts=n` adds beside the routed sum what n shared experts
+    compute: one gated-SiLU feed-forward of width n x `expert_width` that EVERY
+    token passes, added once and unweighted (`shared_attrs` = the ParamAttrs of
+    its gate, up and down matrices; three `mul` ops under the scope
+    `shared_expert`).  It is outside the held path: where several chips split
+    the routed experts each computes the shared one alike."""
     helper = LayerHelper("moe", name=name)
     d = int(input.shape[-1])
     lead = tuple(input.shape[:-1])
@@ -879,12 +892,24 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     if held is not None:
         outputs["Held"] = [_out(helper, "int32", shape=(1,)).name]
         attrs["held"] = [int(held[0]), int(held[1])]
+    if shared_experts:
+        attrs["shared_experts"] = int(shared_experts)
     helper.append_op(
         "moe_experts",
         inputs={"X": [input.name], "TopKProb": [top_p.name], "TopKIndex": [top_i.name],
                 "Load": [load.name], "WGate": [gate.name], "WUp": [up.name],
                 "WDown": [down.name]},
         outputs=outputs, attrs=attrs)
+    if shared_experts:
+        from ..core.program import name_scope
+
+        gate_a, up_a, down_a = shared_attrs or (None, None, None)
+        width = int(shared_experts) * expert_width
+        with name_scope("shared_expert"):
+            hidden = elementwise_mul(
+                fc(input, width, num_flatten_dims=len(lead), act="swish", param_attr=gate_a, bias_attr=False),
+                fc(input, width, num_flatten_dims=len(lead), param_attr=up_a, bias_attr=False))
+            out = elementwise_add(out, fc(hidden, d, num_flatten_dims=len(lead), param_attr=down_a, bias_attr=False))
     return _keep_lod(input, out), balance, z_loss
 
 
@@ -906,22 +931,62 @@ def _persistable_tensor(helper, attr, shape, dtype):
     return var
 
 
-def short_conv(input, kernel_size=3, in_attr=None, filter_attr=None, out_attr=None, name=None):
+def short_conv(input, kernel_size=3, in_attr=None, filter_attr=None, out_attr=None, name=None,
+               gated=True, activation=None):
     """A gated short convolution over (b, T, d), the operator that stands
     where attention does in most layers of a convolution-attention hybrid
     (LFM2): [B, C, u] = split3(x W_in); y = (C * conv_K(B * u)) W_out with one
     causal filter of `kernel_size` taps a channel (depthwise), zeros before the
     sequence's start, no activation and no biases.  The two projections are
     `fc` (so `mul` ops, as attention's are) and the op between them is
-    `short_conv`.  Sequences are whole: a row is one document."""
+    `short_conv`.  Sequences are whole: a row is one document.
+
+    `gated=False, activation="silu"` is the op's plain mode and the taps alone,
+    with no projection round them: y = silu(conv_K(x)) over (b, T, d), as the
+    queries, keys and values of a linear-attention layer pass it (`filter_attr`
+    names the [d, K] filter)."""
     helper = LayerHelper("short_conv", name=name)
     d = int(input.shape[-1])
-    projected = fc(input, 3 * d, num_flatten_dims=2, param_attr=in_attr, bias_attr=False)
+    source = fc(input, 3 * d, num_flatten_dims=2, param_attr=in_attr, bias_attr=False) if gated else input
     taps = helper.create_parameter(filter_attr, [d, int(kernel_size)], "float32")
     mixed = _out(helper, input.dtype, shape=tuple(input.shape))
-    helper.append_op("short_conv", inputs={"X": [projected.name], "Filter": [taps.name]},
-                     outputs={"Out": [mixed.name]})
-    return fc(mixed, d, num_flatten_dims=2, param_attr=out_attr, bias_attr=False)
+    helper.append_op("short_conv", inputs={"X": [source.name], "Filter": [taps.name]},
+                     outputs={"Out": [mixed.name]},
+                     attrs=None if gated else {"gated": False, "activation": str(activation)})
+    return fc(mixed, d, num_flatten_dims=2, param_attr=out_attr, bias_attr=False) if gated else mixed
+
+
+def kda_gate(input, num_heads, a_log_attr=None, dt_bias_attr=None, name=None):
+    """The log decay of a Kimi-Delta-Attention layer from its projection
+    `input` (b, T, H . K): g = -exp(A_log[h]) . softplus(input + dt_bias),
+    float32 (b, T, H, K), with a learned `A_log` a head and `dt_bias` a channel
+    (float32 parameters)."""
+    helper = LayerHelper("kda_gate", name=name)
+    width = int(input.shape[-1])
+    a_log = helper.create_parameter(a_log_attr, [int(num_heads)], "float32")
+    dt_bias = helper.create_parameter(dt_bias_attr, [width], "float32")
+    out = _out(helper, "float32", shape=tuple(input.shape[:-1]) + (int(num_heads), width // int(num_heads)))
+    helper.append_op("kda_gate", inputs={"X": [input.name], "ALog": [a_log.name], "DtBias": [dt_bias.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def kda(q, k, v, g, beta, name=None):
+    """Kimi Delta Attention's recurrence over the sequence (`ops/
+    linear_attention_ops.py`): q, k (b, T, H, K), v (b, T, H, V), the float32
+    log decay g (b, T, H, K) a channel and the step beta (b, T, H); a head
+    keeps a float32 [K, V] state, S_t = (I - beta_t k_t k_t^T) Diag(exp g_t)
+    S_{t-1} + beta_t k_t v_t^T, and returns o_t = S_t^T q_t, (b, T, H, V) in
+    v's dtype.  Computed 64 tokens a chunk; T is a whole number of chunks (or
+    at most one).  The state starts at zero with every row: sequences are
+    whole.  The op's `Stats` (mean decay, mean step, largest |S| at the end)
+    are published a logged step by `train_loop` as a `kind="kda_state"` record."""
+    helper = LayerHelper("kda", name=name)
+    out = _out(helper, v.dtype, shape=tuple(v.shape))
+    stats = _out(helper, "float32", shape=(3,))
+    helper.append_op("kda", inputs={"Q": [q.name], "K": [k.name], "V": [v.name], "G": [g.name], "Beta": [beta.name]},
+                     outputs={"Out": [out.name], "Stats": [stats.name]})
+    return out
 
 
 def dropout_prob_check(p):

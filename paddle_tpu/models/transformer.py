@@ -23,7 +23,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers, optimizer
-from ..core.initializer import ConstantInitializer, NormalInitializer
+from ..core.initializer import ConstantInitializer, NormalInitializer, UniformInitializer
 from ..core.param_attr import ParamAttr
 from ..core.program import Program, name_scope, program_guard
 
@@ -139,12 +139,83 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     return project(ctx, "out")
 
 
+def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_dim, norm_eps=1e-5):
+    """Causal attention whose keys and values are up-projected from one normed
+    latent a token (multi-head latent attention without a rotary embedding on
+    either part, as Kimi Linear's global layers have it): q = x Wq, `n_heads`
+    heads of `nope_dim + rope_dim`; [c ; k_r] = x Wkva, `rank + rope_dim` wide;
+    [k_n ; v] = rms(c) Wkvb, a head `nope_dim + v_dim`; head h's key is
+    [k_n[h] ; k_r], the `rope_dim` part shared by all heads; softmax attention
+    at scale (nope_dim + rope_dim)^-0.5 over keys wider than the values; the
+    output from n_heads x v_dim back to d_model.  No biases.  The heads are
+    split by reshapes alone and the attention is handed (B, L, H, dh)."""
+    def project(t, name, width):
+        return layers.fc(t, width, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
+
+    with name_scope("latent_attention"):
+        qk_dim = nope_dim + rope_dim
+        q = layers.reshape(project(x, "q", n_heads * qk_dim), [0, 0, n_heads, qk_dim])
+        down = project(x, "kv_a", rank + rope_dim)
+        latent = layers.slice(down, axes=[2], starts=[0], ends=[rank])
+        k_shared = layers.slice(down, axes=[2], starts=[rank], ends=[rank + rope_dim])
+        latent = layers.rms_norm(latent, begin_norm_axis=2, epsilon=norm_eps,
+                                 param_attr=_attr_ones(f"{prefix}.kv_norm.w"))
+        up = layers.reshape(project(latent, "kv_b", n_heads * (nope_dim + v_dim)), [0, 0, n_heads, nope_dim + v_dim])
+        k_own = layers.slice(up, axes=[3], starts=[0], ends=[nope_dim])
+        v = layers.slice(up, axes=[3], starts=[nope_dim], ends=[nope_dim + v_dim])
+        k_shared = layers.expand(layers.reshape(k_shared, [0, 0, 1, rope_dim]), [1, 1, n_heads, 1])
+        k = layers.concat([k_own, k_shared], axis=3)
+        ctx = layers.fused_attention(q, k, v, causal=True, layout="blhd")
+        return project(layers.reshape(ctx, [0, 0, n_heads * v_dim]), "out", d_model)
+
+
+def kimi_delta_attention(x, d_model, n_heads, head_dim, prefix, conv_kernel=4, norm_eps=1e-5):
+    """The Kimi-Delta-Attention operator (Kimi Linear, arXiv:2510.26692) round
+    the op `kda`: q, k and v are each a projection to n_heads x head_dim, a
+    depthwise causal convolution of `conv_kernel` taps and a SiLU (`short_conv`'s
+    plain mode); q and k are L2-normalised a head (an RMS norm without a gain,
+    eps 1e-6 on the sum of squares) and q scaled by head_dim^-0.5; the log decay
+    a channel comes through a low-rank pair of width head_dim and `kda_gate`,
+    the step beta = sigmoid(x Wb) a head in float32; the recurrence's output is
+    RMS-normed a head (one gain of head_dim), gated by sigmoid of a second
+    low-rank pair (a bias on its second matrix) and projected back."""
+    width = n_heads * head_dim
+
+    def project(t, name, out, bias=False):
+        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
+                         bias_attr=ParamAttr(name=f"{prefix}.{name}.b", initializer=ConstantInitializer(0.0))
+                         if bias else False)
+
+    def mixed(name):   # projection, taps, SiLU, heads
+        t = layers.short_conv(project(x, name, width), conv_kernel, gated=False, activation="silu",
+                              filter_attr=_attr(f"{prefix}.{name}_conv.w"))
+        return layers.reshape(t, [0, 0, n_heads, head_dim])
+
+    def unit(t, scale):   # t / sqrt(sum t^2 + 1e-6) . scale = rms(t; 1e-6 / head_dim) . head_dim^-0.5 . scale
+        t = layers.rms_norm(t, begin_norm_axis=3, epsilon=1e-6 / head_dim, param_attr=False)
+        return layers.scale(t, scale=scale * head_dim ** -0.5)
+
+    with name_scope("kda"):
+        q, k, v = unit(mixed("q"), head_dim ** -0.5), unit(mixed("k"), 1.0), mixed("v")
+        g = layers.kda_gate(
+            project(project(x, "f_a", head_dim), "f_b", width), n_heads,
+            a_log_attr=ParamAttr(name=f"{prefix}.a_log", initializer=UniformInitializer(0.0, float(np.log(16.0)))),
+            dt_bias_attr=ParamAttr(name=f"{prefix}.dt_bias",
+                                   initializer=UniformInitializer(float(np.log(1e-3)), float(np.log(1e-1)))))
+        beta = layers.sigmoid(layers.cast(project(x, "b", n_heads), "float32"))
+        o = layers.rms_norm(layers.kda(q, k, v, g, beta), begin_norm_axis=3, epsilon=norm_eps,
+                            param_attr=_attr_ones(f"{prefix}.o_norm.w"))
+        gate = layers.sigmoid(project(project(x, "g_a", head_dim), "g_b", width, bias=True))
+        o = layers.elementwise_mul(o, layers.reshape(gate, [0, 0, n_heads, head_dim]))
+        return project(layers.reshape(o, [0, 0, width]), "out", d_model)
+
+
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
                   use_ring_attention=False, causal=False, use_fused_attention=False,
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
                   n_kv_heads=None, head_dim=None, attention_mask=None,
-                  operator="attention", conv_kernel=3, ffn="gelu", post_norm=False):
+                  operator="attention", conv_kernel=3, ffn="gelu", post_norm=False, operator_args=None):
     """One transformer layer: a sequence operator (attention) and a
     feed-forward part, each with a residual connection and a norm.
 
@@ -157,7 +228,12 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     width; "head": over each head), `positions`, `proj_bias`, `n_kv_heads`,
     `head_dim` and `attention_mask` = (kind, block length) go to the
     attention.  `operator="conv"` puts a gated short convolution of
-    `conv_kernel` taps (`layers.short_conv`) where the attention stands.
+    `conv_kernel` taps (`layers.short_conv`) where the attention stands,
+    `operator="kda"` a Kimi-Delta-Attention operator (`kimi_delta_attention`;
+    `operator_args` = dict(n_heads=, head_dim=)) and
+    `operator="latent_attention"` attention over latent keys and values
+    (`latent_attention`; `operator_args` = dict(rank=, nope_dim=, rope_dim=,
+    v_dim=)).
 
     The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
     W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
@@ -165,9 +241,10 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `d_ff`-wide routed gated-SiLU experts instead (`held`: the range of them
     this layer holds; `router_seed`: a seed of the router's own, `_attr`;
     `scoring`, `routed_scaling_factor`, `norm_eps` and `bias` = (standard
-    deviation, seed) of a router bias that enters the choice alone:
-    `layers.moe`); its two auxiliary losses are appended to `aux_losses` as
-    (load balance, router z).
+    deviation, seed) of a router bias that enters the choice alone;
+    `shared_experts`: how many shared experts every token passes beside the
+    routed ones: `layers.moe`); its two auxiliary losses are appended to
+    `aux_losses` as (load balance, router z).
     """
     def normed(t, name):
         if norm == "rms":
@@ -189,7 +266,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                 scoring=moe.get("scoring", "softmax"),
                 routed_scaling_factor=moe.get("routed_scaling_factor", 1.0),
                 norm_eps=moe.get("norm_eps", 0.0),
-                bias_attr=bias and _attr(f"{prefix}.moe.router.bias", *bias))
+                bias_attr=bias and _attr(f"{prefix}.moe.router.bias", *bias),
+                shared_experts=moe.get("shared_experts", 0),
+                shared_attrs=tuple(_attr(f"{prefix}.moe.shared.{n}.w") for n in ("gate", "up", "down")))
             aux_losses.append((balance, z_loss))
             return out
         if ffn == "gated_silu":
@@ -209,6 +288,12 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         attn_out = layers.short_conv(operator_in, conv_kernel, in_attr=_attr(f"{prefix}.conv.in.w"),
                                      filter_attr=_attr(f"{prefix}.conv.filter.w"),
                                      out_attr=_attr(f"{prefix}.conv.out.w"))
+    elif operator == "kda":
+        attn_out = kimi_delta_attention(operator_in, d_model, prefix=f"{prefix}.kda", conv_kernel=conv_kernel,
+                                        norm_eps=norm_eps, **operator_args)
+    elif operator == "latent_attention":
+        attn_out = latent_attention(operator_in, d_model, n_heads, f"{prefix}.attn", norm_eps=norm_eps,
+                                    **operator_args)
     else:
         attn_out = multi_head_attention(operator_in,
                                         seq_len, d_model, n_heads, f"{prefix}.attn",
@@ -330,6 +415,10 @@ def build_causal_lm(
     post_norm=False,
     loop=None,
     exit_beta=0.0,
+    shared_experts=0,
+    kda_heads=None,
+    kda_head_dim=None,
+    latent=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -384,6 +473,14 @@ def build_causal_lm(
     seed + i, enters its choice and not its weights, and is no parameter
     (`layers.moe`).
 
+    A hybrid of linear and latent attention (Kimi Linear) is arguments too:
+    `layer_types` may hold "kda" (a Kimi-Delta-Attention operator of `kda_heads`
+    heads of `kda_head_dim`, its three convolutions of `conv_kernel` taps) and
+    "latent_attention" (`latent` = dict(rank=, nope_dim=, rope_dim=, v_dim=):
+    keys and values from one normed latent a token, no rotary embedding), and
+    `shared_experts` = n gives every sparse layer n shared experts that every
+    token passes, beside the routed ones and outside `experts_held`.
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -407,9 +504,14 @@ def build_causal_lm(
         raise ValueError(f"build_causal_lm: n_layers={n_layers} beside {len(layer_types)} layer_types; "
                          "layer_types alone states the depth")
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
-    unknown = sorted(set(kinds) - {"full_attention", "conv"})
+    operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention"}
+    operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent}
+    unknown = sorted(set(kinds) - set(operators))
     if unknown:
-        raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv")
+        raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
+                         "kda or latent_attention")
+    if ("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent):
+        raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer latent=")
     if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
         raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
                          f"among {len(kinds)} layers")
@@ -435,7 +537,8 @@ def build_causal_lm(
                                router_seed=routing_seed and routing_seed + 1 + i,
                                scoring=scoring, routed_scaling_factor=routed_scaling_factor,
                                norm_eps=norm_topk_eps,
-                               bias=expert_bias and (expert_bias[0], expert_bias[1] + i))
+                               bias=expert_bias and (expert_bias[0], expert_bias[1] + i),
+                               shared_experts=shared_experts)
                 x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
                                   f"lm.l{i}",
                                   dropout_prob=0.0, causal=attention_mask is None,
@@ -445,7 +548,7 @@ def build_causal_lm(
                                   moe=None if dense else experts, ffn="gated_silu",
                                   aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
                                   attention_mask=attention_mask,
-                                  operator="conv" if kind == "conv" else "attention",
+                                  operator=operators[kind], operator_args=operator_args.get(kind),
                                   conv_kernel=conv_kernel, post_norm=post_norm)
             return x
 

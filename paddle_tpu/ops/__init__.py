@@ -2,6 +2,7 @@
 from . import (  # noqa: F401
     control_flow_ops,
     detection_ops,
+    linear_attention_ops,
     math_ops,
     misc_ops,
     moe_ops,

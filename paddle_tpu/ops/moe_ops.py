@@ -200,6 +200,50 @@ def _gated_short_conv_bwd(res, g):
 _gated_short_conv.defvjp(lambda x, w: (_gated_short_conv(x, w), (x, w)), _gated_short_conv_bwd)
 
 
+def _plain_short_conv_taps(x, w, ahead=0):
+    """(c = conv_K(x) in float32, each tap's shifted x) of x [b, T, d] and the
+    filter w [d, K], as `_short_conv_taps` has them for B * u; `ahead`: c of the
+    row that many after each, zeros past the sequence's end."""
+    taps = w.shape[1]
+    shifted = [_shift_rows(x, taps - 1 - j - ahead).astype(jnp.float32) for j in range(taps)]
+    return sum(z * w[:, j].astype(jnp.float32) for j, z in enumerate(shifted)), shifted
+
+
+def _silu_slope(c):
+    s = jax.nn.sigmoid(c)
+    return s * (1.0 + c * (1.0 - s))
+
+
+@jax.custom_vjp
+def _plain_short_conv(x, w):
+    """silu(conv_K(x)), computed in float32 from x's dtype and rounded once."""
+    with jax.named_scope("plain_short_conv"):
+        return jax.nn.silu(_plain_short_conv_taps(x, w)[0]).astype(x.dtype)
+
+
+def _plain_short_conv_bwd(res, g):
+    """The transpose on shifted reads of x and g, in the gated form's shape:
+    dc = g silu'(c); dw_j = sum dc . x[. - (K - 1) + j]; dx[s] = sum_j w_j
+    dc[s + (K - 1) - j], where dc at a later row is made again from the rows of
+    x it reads, so that every `pad` is of an operand.  Keeps x and the filter."""
+    x, w = res
+    taps = w.shape[1]
+    with jax.named_scope("plain_short_conv"):
+        c, shifted = _plain_short_conv_taps(x, w)
+        dc = g.astype(jnp.float32) * _silu_slope(c)
+        d_w = jnp.stack([jnp.sum(dc * z, axis=(0, 1)) for z in shifted], axis=1).astype(w.dtype)
+
+        def dc_ahead(ahead):   # dc[s + ahead], zero past the sequence's end
+            return _shift_rows(g, -ahead).astype(jnp.float32) * _silu_slope(_plain_short_conv_taps(x, w, ahead)[0])
+
+        d_x = sum((dc if j == taps - 1 else dc_ahead(taps - 1 - j)) * w[:, j].astype(jnp.float32)
+                  for j in range(taps)).astype(x.dtype)
+    return d_x, d_w
+
+
+_plain_short_conv.defvjp(lambda x, w: (_plain_short_conv(x, w), (x, w)), _plain_short_conv_bwd)
+
+
 @register_op("short_conv")
 def _short_conv(ctx, op, ins):
     """The gated short convolution between its two projections (which are
@@ -209,7 +253,13 @@ def _short_conv(ctx, op, ins):
     before the sequence's start.  Plain jax.numpy: K shifted multiply-adds
     that XLA fuses into one pass forward, and a backward pass written the same
     way (`_gated_short_conv_bwd`: the derived one pads computed products and
-    reads 2.3x the bytes; PERF.md, PR 34)."""
+    reads 2.3x the bytes; PERF.md, PR 34).  With the attributes `gated=False,
+    activation="silu"` X is [b, T, d] and Out = silu(conv_K(X)): the taps alone."""
+    if not op.attr("gated", True):
+        # the plain mode (attributes gated=False, activation="silu"): X [b, T, d] itself passes the taps and a
+        # SiLU, as a linear-attention layer's q, k and v do; `_shift_rows` and the transpose's shape are shared
+        _MON.counter("lowering.short_conv_plain_layers").inc()
+        return {"Out": _plain_short_conv(first(ins, "X"), first(ins, "Filter"))}
     _MON.counter("lowering.short_conv_layers").inc()
     return {"Out": _gated_short_conv(first(ins, "X"), first(ins, "Filter"))}
 
@@ -379,6 +429,8 @@ def _moe_experts(ctx, op, ins):
     x2 = x.reshape(-1, d)
     tokens = x2.shape[0]
     held = op.attr("held", None)
+    if op.attr("shared_experts", 0):   # the layer's builder computes them beside this op, every token, once
+        _MON.counter("lowering.shared_expert_layers").inc()
     if held is not None:
         out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load,
                                             (w_gate, w_up, w_down), tuple(held), ctx.platform)
@@ -799,8 +851,12 @@ def _infer_short_conv(ctx):
     xs, ws = ctx.in_shape("X"), ctx.in_shape("Filter")
     if xs is None or ws is None:
         return
-    if len(xs) != 3 or xs[-1] % 3 or len(ws) != 2 or ws[0] * 3 != xs[-1] or ws[1] < 1:
-        ctx.fail(f"X must be (b, T, 3d) and Filter (d, K), got {xs} and {ws}")
+    gated, activation = ctx.op.attr("gated", True), ctx.op.attr("activation", None)
+    if activation != (None if gated else "silu"):
+        ctx.fail(f"gated={gated} with activation={activation!r}: the gated form has none, the plain form a silu")
+    fold = 3 if gated else 1
+    if len(xs) != 3 or len(ws) != 2 or ws[0] * fold != xs[-1] or ws[1] < 1:
+        ctx.fail(f"X must be (b, T, {'3d' if gated else 'd'}) and Filter (d, K), got {xs} and {ws}")
     ctx.set_out("Out", tuple(xs[:-1]) + (ws[0],), ctx.in_dtype("X"))
 
 
@@ -860,7 +916,8 @@ def _cost_short_conv(ctx):
     traffic is the op's own: [b, T, 3d] read, [b, T, d] written."""
     ws = ctx.in_shape("Filter")
     taps = ws[1] if ws is not None else 3
-    return (2.0 + 2.0 * taps) * ctx.out_elems_total(), ctx.io_bytes()
+    edge = 2.0 if ctx.op.attr("gated", True) else 4.0   # the two gates, or the SiLU
+    return (edge + 2.0 * taps) * ctx.out_elems_total(), ctx.io_bytes()
 
 
 _RP.register_cost(["short_conv"], _cost_short_conv)

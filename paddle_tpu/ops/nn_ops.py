@@ -537,7 +537,7 @@ _ROW_KERNEL_SEQ_MULTIPLE = 128
 _ATTENTION_AXES = {"bhld": (1, 2), "blhd": (2, 1)}
 
 
-def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld"):
+def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld", v_width=None):
     """Which attention `fused_attention` lowers to: "flash", "block_causal",
     "row_kernel", "block_sparse" or "xla", the lengths read by the op's
     `layout`.  Off the TPU always "xla".  A short
@@ -554,9 +554,12 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
     positions = _ATTENTION_AXES[layout][1]
     q_len, kv_len = q.shape[positions], k.shape[positions]
     one_device = mesh is None or mesh.size == 1
+    # values of another width than queries and keys (latent attention: 192-wide q, k beside 128-wide v): the splash
+    # kernels take the widths as they are; the flash, row and block-diffusion kernels were never given any
+    one_width = v_width in (None, q.shape[-1])
     if mask is not None:
         whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
-        return "block_sparse" if whole and one_device else "xla"
+        return "block_sparse" if whole and one_device and one_width else "xla"
     if kv_len >= _FLASH_MIN_SEQ and q_len >= _FLASH_MIN_QUERIES:
         # A causal mask empties the blocks above the diagonal: the splash kernels never visit them and mask only the
         # blocks the diagonal cuts, where the flash kernel fetches every block, masks every one it runs and is handed
@@ -568,14 +571,14 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
         # (nothing to skip), queries and keys of different lengths or of no whole number of the kernels' blocks, a
         # mesh of more than one device, operands other than bf16, a head width that is no multiple of 64.
         if (causal and not biased and one_device and q_len == kv_len and kernel_block(q_len) is not None
-                and q.shape[-1] % 64 == 0 and q.dtype == k.dtype == jnp.bfloat16):
+                and q.shape[-1] % 64 == 0 and (v_width or q.shape[-1]) % 64 == 0 and q.dtype == k.dtype == jnp.bfloat16):
             return "block_causal"
-        return "flash"
+        return "flash" if one_width else "xla"
     if not one_device:
         return "xla"
     if (all(_ROW_KERNEL_MIN_SEQ <= n <= _ROW_KERNEL_MAX_SEQ and n % _ROW_KERNEL_SEQ_MULTIPLE == 0
             for n in (q_len, kv_len))
-            and q.shape[-1] == _ROW_KERNEL_HEAD_DIM and q.dtype == k.dtype == jnp.bfloat16):
+            and q.shape[-1] == _ROW_KERNEL_HEAD_DIM and one_width and q.dtype == k.dtype == jnp.bfloat16):
         return "row_kernel"
     return "xla"
 
@@ -716,8 +719,10 @@ def _fused_attention(ctx, op, ins):
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     mask = _structured_mask(op, q, k, layout)
-    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout)
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout, v.shape[-1])
     _MON.counter(f"lowering.attention_{path}").inc()
+    if v.shape[-1] != q.shape[-1]:
+        _MON.counter("lowering.latent_attention_layers").inc()
     native = layout == "bhld" or path == "row_kernel"
     _MON.counter("lowering.attention_layout_native" if native else "lowering.attention_layout_transposed").inc()
     if not native:
@@ -1411,8 +1416,9 @@ _A.register_rule(["ring_attention"], _infer_ring_attention)
 
 
 def _infer_fused_attention(ctx):
-    """Out is Q's shape in either layout; K and V agree with each other, with
-    Q's head width and, by the op's `layout`, hold a divisor of its heads."""
+    """Out is Q's shape in either layout but for V's head width; K has Q's
+    head width, V may have another (latent attention), K and V agree in the
+    rest and, by the op's `layout`, hold a divisor of Q's heads."""
     layout = ctx.op.attr("layout", "bhld")
     if layout not in _ATTENTION_AXES:
         ctx.fail(f"layout {layout!r}: (B, H, L, dh) is \"bhld\", (B, L, H, dh) is \"blhd\"")
@@ -1425,13 +1431,13 @@ def _infer_fused_attention(ctx):
     for name, shape in (("K", ks), ("V", vs)):
         if shape is None:
             continue
-        if len(shape) != 4 or (shape[-1] != qs[-1] and _A.DYN not in (shape[-1], qs[-1])):
-            ctx.fail(f"{name} must have four axes, {layout}, and Q's head width {qs[-1]}, got {shape}")
+        if len(shape) != 4 or (name == "K" and shape[-1] != qs[-1] and _A.DYN not in (shape[-1], qs[-1])):
+            ctx.fail(f"{name} must have four axes, {layout}, and K Q's head width {qs[-1]}, got {shape}")
         if _A.DYN not in (shape[heads], qs[heads]) and (shape[heads] < 1 or qs[heads] % shape[heads]):
             ctx.fail(f"{name}'s {shape[heads]} heads (axis {heads} of {layout}) do not divide Q's {qs[heads]}")
-    if ks is not None and vs is not None and tuple(ks) != tuple(vs):
-        ctx.fail(f"K {ks} and V {vs} differ")
-    ctx.set_out("Out", qs, ctx.in_dtype("Q"))
+    if ks is not None and vs is not None and tuple(ks[:3]) != tuple(vs[:3]):
+        ctx.fail(f"K {ks} and V {vs} differ in more than the head width")
+    ctx.set_out("Out", qs if vs is None else tuple(qs[:3]) + (vs[3],), ctx.in_dtype("Q"))
 
 
 _A.register_rule(["fused_attention"], _infer_fused_attention)
@@ -1539,13 +1545,15 @@ def _cost_fused_attention(ctx):
         return float(ctx.out_elems_total()), ctx.io_bytes()
     heads, positions = _ATTENTION_AXES[ctx.op.attr("layout", "bhld")]
     b, h, lq, dh = qs[0], qs[heads], qs[positions], qs[3]
+    vs = ctx.in_shape("V")
+    dv = vs[3] if vs is not None and len(vs) == 4 else dh   # QK^T over dh, PV over V's width
     lk = ks[positions]
     pairs = _elems_xs((lq, lk))
     if ctx.op.attr("mask", None) is not None and ctx.op.attr("mask_block", None):
         from .masked_attention import allowed_pairs
 
         pairs = allowed_pairs(lq, ctx.op.attr("mask_block"))  # the pairs the rule allows
-    return 4.0 * _elems_xs((b, h, dh)) * pairs, ctx.io_bytes()
+    return 2.0 * _elems_xs((b, h)) * (dh + dv) * pairs, ctx.io_bytes()
 
 
 _RP.register_cost(["fused_attention"], _cost_fused_attention)

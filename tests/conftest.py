@@ -22,6 +22,10 @@ from paddle_tpu.flags import CHECKOUT_CACHE_DIR, apply_compile_cache  # noqa: E4
 apply_compile_cache(CHECKOUT_CACHE_DIR, min_compile_secs=0.5)
 
 
+def pytest_configure(config):
+    config.addinivalue_line("markers", "slow: minutes of one compile; left out of tier-1 (`-m 'not slow'`), run by name")
+
+
 @pytest.fixture(autouse=True)
 def _fresh_programs():
     """Each test builds into fresh default programs and a fresh scope."""

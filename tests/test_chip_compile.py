@@ -566,6 +566,54 @@ def test_ouros_step_compiles_for_the_chip_as_one_loop_and_its_planned_peak_leave
     assert re.search(r'transpose\([^"]*:repeat/[^"]*rematted_computation/[^"]*op\d+:mul', text)
 
 
+@pytest.mark.slow   # 3 to 4.5 minutes of one compile on every core: run by name (`-m slow`), PERF.md PR 42 has its readings
+def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip):
+    """Kimi Linear's cell's whole train step (benchmark/models/kimi_linear.py:
+    build, at the configuration's and the traffic's own sizes: one sequence of
+    4096 through four KDA layers and a latent attention) compiles for the
+    described v5e, and XLA plans it under the 15.5 GB the cell allows itself
+    and over the 25% of the chip a cell has to fill (PERF.md, PR 42, has the
+    planned peaks that chose the batch).  The latent attention took the splash
+    kernels with its two widths as they are; the scans are XLA's (no kernel of
+    their own) under the scope their roofline share reads, forward and
+    backward; the state and Adam's moments are 12 bytes of the 16 a parameter."""
+    import paddle_tpu as fluid
+    from benchmark import manifest as mf
+    from benchmark.models import kimi_linear
+    from paddle_tpu.core import executor as ex
+
+    cfg = mf.read_json("benchmark/configs/kimi-linear-48b-a3b.json")
+    job = mf.read_json("benchmark/traffic/train-kda-s4096.json")
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = kimi_linear.build(cfg, job)
+    main.random_seed = startup.random_seed = 3
+    scope = fluid.Scope()
+    for v in startup.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    feeds = {n: jax.ShapeDtypeStruct((job["batch_per_chip"], job["seq_len"]), I32) for n in kimi_linear.FEEDS}
+    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu",
+                            feed_shapes={n: s.shape for n, s in feeds.items()})
+
+    def on_chip(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+
+    compiled = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names},
+                              {n: on_chip(scope.find_var(n)) for n in step.ro_names},
+                              {n: on_chip(s) for n, s in feeds.items()},
+                              on_chip(jax.random.PRNGKey(0))).compile()
+    m = compiled.memory_analysis()
+    peak = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
+    assert m.argument_size_in_bytes == pytest.approx(3 * 4 * cfg["parameters"], rel=1e-3)    # masters and Adam's two moments
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    scans = re.findall(r'op_name="([^"]*/kda_chunk_scan/[^"]*)"', text)
+    assert any("transpose(" in name for name in scans) and any("transpose(" not in name for name in scans)
+    assert re.search(r"/kda(_\d+)?/op\d+:kda/kda_chunk_scan/", text) and re.search(r"/latent_attention(_\d+)?/op\d+:fused_attention", text)
+    assert re.search(r"/shared_expert(_\d+)?/op\d+:mul", text) and text.count("/plain_short_conv/") > 0
+
+
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [
     ("ln", (256, 128, 768), BF16, True),
     ("ln", (7, 33, 768), BF16, True),          # 231 rows: one whole-array slab

@@ -1,0 +1,190 @@
+"""Prices, on the chip, the two lowerings Kimi Linear's cell chose by a reading
+(run through `chiprun -- python3 tools/chip_kimi_kernels.py`, ~5 min):
+
+  * the chunked KDA op alone at the cell's shape (1, 4096, 32, 128), forward
+    and forward + backward, at each precision of its float32 products and with
+    the chunks' terms made a group or a whole row at a time, and how far each
+    lies from the token-by-token float32 recurrence;
+  * the causal attention at latent attention's widths (192-wide queries and
+    keys, 128-wide values, 32 heads, 4096 keys): the stock splash kernels with
+    the widths as they are, with q and k padded to 256 by zeros, and XLA's.
+
+Prints one JSON line a reading.  ONLY=kda or ONLY=attention runs one half."""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.getcwd())
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark.models import kimi_linear
+from paddle_tpu.ops import linear_attention_ops as lao
+from paddle_tpu.ops import masked_attention, nn_ops
+
+RUNS = 5
+PRECISION, GROUP = lao._KDA_PRECISION, lao._KDA_GROUP   # the op's own
+
+
+def say(**fields):
+    print(json.dumps(fields, default=float), flush=True)
+
+
+def timed(fn, *args):
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(RUNS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / RUNS, out
+
+
+def kda_inputs(seed, b=1, T=4096, H=32, K=128):
+    r = np.random.RandomState(seed)
+    unit = lambda t: t / np.linalg.norm(t, axis=-1, keepdims=True)
+    q, k = unit(r.randn(b, T, H, K)) * K ** -0.5, unit(r.randn(b, T, H, K))
+    v = 0.5 * r.randn(b, T, H, K)
+    g = -(r.uniform(1, 16, (1, 1, H, 1)) * np.exp(r.uniform(np.log(1e-3), np.log(1e-1), (b, T, H, K))))
+    beta = 1 / (1 + np.exp(-r.randn(b, T, H)))
+    low = lambda t: jnp.asarray(t, jnp.bfloat16)
+    return low(q), low(k), low(v), jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32)
+
+
+def bf16(t):
+    """float32 holding bf16's eight bits; not a pair of casts, which XLA may take out."""
+    return jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
+
+
+def bf16_states(phi, B):
+    """`linear_attention_ops._states` with the state rounded to bf16 at every chunk boundary."""
+    def step(S, term):
+        return bf16(lao._mm("hkj,hjv->hkv", term[0], S) + term[1]), S
+    final, starts = jax.lax.scan(step, jnp.zeros(B.shape[1:], jnp.float32), (phi, B))
+    return starts, final
+
+
+def kda():
+    args = kda_inputs(int(os.environ.get("SEED", "1")))
+
+    def op(q, k, v, g, beta):
+        return lao.chunked_kda(q, k, v, g, beta[..., None])[0]
+
+    def both(*a):
+        out, pull = jax.vjp(op, *a)
+        return pull(out)
+
+    def errors(out):   # as the cell's KDA stage reads them
+        found = kimi_linear.kda_errors([args + (out,)])
+        return {"error": found["kda_error"], "error_unrounded": found["kda_error_unrounded"],
+                "recurrence_bf16_state": found["kda_error_bf16_state"]}
+
+    for precision in ("HIGHEST", "HIGH"):
+        for group in (8, 4, 16):
+            lao._KDA_PRECISION, lao._KDA_GROUP = getattr(jax.lax.Precision, precision), group
+            fwd_ms, out = timed(jax.jit(lambda *a: op(*a)), *args)
+            both_ms, _ = timed(jax.jit(lambda *a: both(*a)), *args)
+            say(reading="kda_op", precision=precision, group=group, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms, **errors(out))
+    lao._KDA_PRECISION = jax.lax.Precision.DEFAULT
+    say(reading="kda_op", precision="DEFAULT", **errors(jax.jit(lambda *a: op(*a))(*args)))
+    lao._KDA_PRECISION, lao._KDA_GROUP = PRECISION, GROUP
+    # the faults the stage's limit has to refuse, put into the OP
+    states, cumulative = lao._states, lao._cumulative
+
+    lao._states = bf16_states
+    say(reading="kda_op_bf16_state", **errors(jax.jit(lambda *a: op(*a))(*args)))
+    lao._states = states
+    lao._cumulative = lambda g: bf16(cumulative(g))
+    say(reading="kda_op_bf16_cumulative_decay", **errors(jax.jit(lambda *a: op(*a))(*args)))
+    lao._cumulative = cumulative
+    say(reading="kda_op_no_decay", **errors(jax.jit(lambda q, k, v, g, b: op(q, k, v, 0 * g, b))(*args)))
+    # the rarer lowering of the blocks' own keys, forced: a channel that dies in one token
+    strong = args[3].at[:, ::97, :, 5].set(-100.0)
+    hard = args[:3] + (strong,) + args[4:]
+    found = kimi_linear.kda_errors([hard + (jax.jit(lambda *a: op(*a))(*hard),)])
+    say(reading="kda_op_by_differences", error=found["kda_error"], error_unrounded=found["kda_error_unrounded"])
+
+
+def attention():
+    r = np.random.RandomState(2)
+    b, h, L, dqk, dv = 1, 32, 4096, 192, 128
+    q, k = (jnp.asarray(r.randn(b, h, L, dqk), jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(r.randn(b, h, L, dv), jnp.bfloat16)
+    scale = dqk ** -0.5
+
+    def splash(q, k, v):
+        return masked_attention.causal_attention(q, k, v, scale)
+
+    def padded(q, k, v):
+        wide = lambda t: jnp.pad(t, ((0, 0), (0, 0), (0, 0), (0, 256 - dqk)))
+        return masked_attention.causal_attention(wide(q), wide(k), v, scale)
+
+    def xla(q, k, v):
+        return nn_ops._xla_attention(q, k, v, None, True, scale, None)
+
+    def both(fn):
+        def run(q, k, v):
+            out, pull = jax.vjp(fn, q, k, v)
+            return out, pull(out)
+        return jax.jit(run)
+
+    want = None
+    for name, fn in (("xla", xla), ("splash_192_128", splash), ("splash_padded_256", padded)):
+        ms, (out, _) = timed(both(fn), q, k, v)
+        fwd_ms, _ = timed(jax.jit(fn), q, k, v)
+        want = np.asarray(out, "f4") if want is None else want
+        say(reading="causal_attention", kernel=name, fwd_ms=fwd_ms, fwd_bwd_ms=ms,
+            max_error_from_xla=float(np.abs(np.asarray(out, "f4") - want).max() / np.abs(want).max()))
+
+
+def profile():
+    """Where the op's forward + backward spends its time: own device time by
+    HLO instruction, and the compiled text to look each up in, written under
+    chiprun_out/."""
+    from benchmark.metrics.recompute_ms_per_step import own_times
+
+    args = kda_inputs(1)
+
+    def both(q, k, v, g, beta):
+        out, pull = jax.vjp(lambda *a: lao.chunked_kda(*a[:4], a[4][..., None])[0], q, k, v, g, beta)
+        return pull(out)
+
+    fn = jax.jit(both)
+    jax.block_until_ready(fn(*args))
+    os.makedirs("chiprun_out", exist_ok=True)
+    open("chiprun_out/kda_both.hlo", "w").write(fn.lower(*args).compile().as_text())
+    jax.profiler.start_trace("chiprun_out/kda_trace")
+    for _ in range(3):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    found = [os.path.join(base, f) for base, _, files in os.walk("chiprun_out/kda_trace") for f in files
+             if f.endswith(".xplane.pb")]
+    data = jax.profiler.ProfileData.from_file(max(found, key=os.path.getmtime))
+    spent = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            events = [(e.name, e.start_ns, e.duration_ns) for e in line.events]
+            for name, ns in own_times(events, (min(e[1] for e in events), max(e[1] + e[2] for e in events))):
+                spent[name] = spent.get(name, 0.0) + ns / 3e6
+    json.dump(sorted(spent.items(), key=lambda kv: -kv[1]), open("chiprun_out/kda_profile.json", "w"))
+    say(reading="kda_profile", own_ms_a_run=sum(spent.values()), instructions=len(spent))
+    import shutil
+    shutil.rmtree("chiprun_out/kda_trace")
+
+
+if __name__ == "__main__":
+    say(device=jax.devices()[0].device_kind, platform=jax.devices()[0].platform)
+    only = os.environ.get("ONLY")
+    if only == "profile":
+        profile()
+    if only in (None, "kda"):
+        kda()
+    if only in (None, "attention"):
+        attention()
