@@ -635,11 +635,12 @@ class _CompiledStep:
                 lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
                 lowering.annotate(fenced=fenced.value - fenced0)
                 # which attention each fused_attention op of this program took,
-                # and what its `repeat` ops lowered (passes, body ops, recomputed passes)
+                # what its `repeat` ops lowered (passes, body ops, recomputed passes),
+                # and how many of its `kda` ops took the kernels
                 lowering.annotate(**{
                     name[len("lowering."):]: n - counted0.get(name, 0)
                     for name, n in _MON.counter_values().items()
-                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_"))
+                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_"))
                     and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)

@@ -54,6 +54,7 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_step_stats
 from ..monitor import MONITOR as _MON
+from . import kda_kernels
 from .common import first
 
 #: Tokens a chunk (the published kernels') and a block of its rows for the
@@ -246,28 +247,63 @@ def _over_rows(fn, *rows):
     return jax.lax.map(lambda row: fn(*row), rows)
 
 
-def _chunked_kda(q, k, v, g, beta, chunk, sub):
+def _kernel_seams():
+    """What the kernels take from this module and their own where the
+    `jax.numpy` form reads its globals: the products' precision, the cumulative
+    decay's function and the carried state's (static arguments of the kernels'
+    `jax.jit`s, so a control that patches one is traced anew:
+    tools/chip_kimi_controls.py)."""
+    return _KDA_PRECISION, kda_kernels.cumulative, kda_kernels.carried
+
+
+def _kda_path(platform, mesh, q, v, chunk):
+    """How the op is lowered: "kernels" (`ops/kda_kernels.py`: a group of heads'
+    chunk in VMEM, the state carried there, forward and transposed) on the TPU
+    where a head of keys and of values is a whole number of lane tiles, a chunk
+    is the published 64 tokens and the program runs on one device (a
+    `pallas_call` cannot be partitioned: `nn_ops._attention_path`'s rule), else
+    "xla", this module's `jax.numpy` form: the CPU's path and what the tests
+    hold the kernels to.  TPU v5e, (1, 4096, 32, 128), forward | backward of the
+    op alone: PERF.md, PR 44.  A 64-wide head stays "xla" until someone prices a
+    kernel for it."""
+    one_device = mesh is None or mesh.size == 1
+    whole = q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0 and chunk == _KDA_CHUNK and q.shape[1] % chunk == 0
+    return "kernels" if platform == "tpu" and one_device and whole else "xla"
+
+
+def _chunked_kda(q, k, v, g, beta, chunk, sub, kernels):
     with jax.named_scope("kda_chunk_scan"):
+        if kernels:
+            return kda_kernels.scan(q, k, v, g, beta[..., 0], chunk, sub, _KDA_SAFE, _kernel_seams(), False, kernels == "interpret")
         return _over_rows(functools.partial(_row_forward, chunk=chunk, sub=sub), q, k, v, g, beta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def chunked_kda(q, k, v, g, beta, chunk=_KDA_CHUNK, sub=_KDA_SUB):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def chunked_kda(q, k, v, g, beta, chunk=_KDA_CHUNK, sub=_KDA_SUB, kernels=None):
     """(o [b, T, H, V] in v's dtype, the state after the last token [b, H, K, V]
     float32) of the recurrence above over q, k [b, T, H, K], v [b, T, H, V], the
     log decay g [b, T, H, K] and beta [b, T, H, 1], `chunk` tokens at a time,
-    a chunk's rows in blocks of `sub` (`_blocks_of`).
+    a chunk's rows in blocks of `sub` (`_blocks_of`).  `kernels`: None for the
+    `jax.numpy` form, "tpu" for the Pallas kernels of `ops/kda_kernels.py`
+    (`_kda_path` says when), "interpret" for those interpreted (the tests').
     The final state is for statistics: backward takes no cotangent for it."""
-    return _chunked_kda(q, k, v, g, beta, chunk, sub)
+    return _chunked_kda(q, k, v, g, beta, chunk, sub, kernels)
 
 
-def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub):
-    return _chunked_kda(q, k, v, g, beta, chunk, sub), (q, k, v, g, beta)
+def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub, kernels):
+    return _chunked_kda(q, k, v, g, beta, chunk, sub, kernels), (q, k, v, g, beta)
 
 
-def _chunked_kda_bwd(chunk, sub, inputs, cotangents):
+def _chunked_kda_bwd(chunk, sub, kernels, inputs, cotangents):
     with jax.named_scope("kda_chunk_scan"):
-        return _over_rows(functools.partial(_row_backward, chunk=chunk, sub=sub), *inputs, cotangents[0])
+        if not kernels:
+            return _over_rows(functools.partial(_row_backward, chunk=chunk, sub=sub), *inputs, cotangents[0])
+        # the boundary states made again (nothing is kept from forward), then the chunks in reverse
+        _MON.counter("lowering.kda_kernel_transposed_calls").inc()
+        operands, how = inputs[:4] + (inputs[4][..., 0],), (chunk, sub, _KDA_SAFE, _kernel_seams())
+        starts = kda_kernels.scan(*operands, *how, True, kernels == "interpret")
+        *d_inputs, d_beta = kda_kernels.scan_transposed(*operands, cotangents[0], starts, *how, kernels == "interpret")
+        return (*d_inputs, d_beta[..., None].astype(inputs[4].dtype))
 
 
 chunked_kda.defvjp(_chunked_kda_fwd, _chunked_kda_bwd)
@@ -284,10 +320,19 @@ def _kda(ctx, op, ins):
     chunk = min(_KDA_CHUNK, T)
     if T % chunk:
         raise ValueError(f"kda: {T} positions are no whole number of chunks of {chunk} tokens")
+    kernels = "tpu" if _kda_path(ctx.platform, ctx.mesh, q, v, chunk) == "kernels" else None
     _MON.counter("lowering.kda_layers").inc()
     _MON.counter("lowering.kda_chunks").inc(T // chunk)
+    _MON.counter("lowering.kda_kernel_calls").inc(1 if kernels else 0)
     g = g.astype(jnp.float32)
-    out, final = chunked_kda(q, k, v, g, beta[..., None], chunk, _blocks_of(chunk))
+    if kernels:
+        # The kernels read the VARIABLES.  Without the barrier XLA hands a custom call whose operands it has to lay out
+        # anew ([b, T, H . 128]) a second run of their producers' fusion, which rounds at other places than the one
+        # that made the variables (excess precision): the first KDA layer of Kimi Linear's clone then read 4.71e-3
+        # against the recurrence on its FETCHED q, k, v, g, beta where the same kernels on those arrays read 5.9e-4
+        # (my chip runs, PR 44: `correct` false by `KDA_RTOL` for arithmetic that was sound).
+        q, k, v, g, beta = jax.lax.optimization_barrier((q, k, v, g, beta))
+    out, final = chunked_kda(q, k, v, g, beta[..., None], chunk, _blocks_of(chunk), kernels)
     stats = jnp.stack([jnp.mean(jnp.exp(g)), jnp.mean(beta.astype(jnp.float32)), jnp.max(jnp.abs(final))])
     return {"Out": out, "Stats": jax.lax.stop_gradient(stats)}
 

@@ -574,8 +574,10 @@ def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_roo
     described v5e, and XLA plans it under the 15.5 GB the cell allows itself
     and over the 25% of the chip a cell has to fill (PERF.md, PR 42, has the
     planned peaks that chose the batch).  The latent attention took the splash
-    kernels with its two widths as they are; the scans are XLA's (no kernel of
-    their own) under the scope their roofline share reads, forward and
+    kernels with its two widths as they are; the scans are the kernels of
+    `ops/kda_kernels.py` since PR 44 (forward, the start states again and the
+    transpose, four heads a grid step: they fit their VMEM inside the step, not
+    only alone) under the scope their roofline share reads, forward and
     backward; the state and Adam's moments are 12 bytes of the 16 a parameter."""
     import paddle_tpu as fluid
     from benchmark import manifest as mf
@@ -610,6 +612,9 @@ def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_roo
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
     scans = re.findall(r'op_name="([^"]*/kda_chunk_scan/[^"]*)"', text)
     assert any("transpose(" in name for name in scans) and any("transpose(" not in name for name in scans)
+    assert all(any(name.endswith(f"/{kernel}/pallas_call") for name in scans)
+               for kernel in ("kda_scan", "kda_scan_starts", "kda_scan_transposed"))
+    assert not re.search(r"kda_chunk_scan/[^\"]*while", text)          # no `lax.scan` is left in the op
     assert re.search(r"/kda(_\d+)?/op\d+:kda/kda_chunk_scan/", text) and re.search(r"/latent_attention(_\d+)?/op\d+:fused_attention", text)
     assert re.search(r"/shared_expert(_\d+)?/op\d+:mul", text) and text.count("/plain_short_conv/") > 0
 

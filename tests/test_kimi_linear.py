@@ -5,6 +5,8 @@
     of the recurrence, at several chunk counts, at mild and at strong decay
     (g = -20 a token: finite everywhere), and the faults the benchmark's stage
     has to refuse; `kda_gate`; `infer=`, the planner rows, `analysis.verify`;
+    since ISSUE 44 the Pallas kernels of `ops/kda_kernels.py`, interpreted,
+    against both, the rule that takes them and the counter that says so;
 (b) `short_conv`'s plain mode against four shifted multiply-adds, forward and
     gradients, and the gated mode's lowered text unchanged;
 (c) `fused_attention` with values of another width than queries and keys
@@ -39,6 +41,7 @@ from paddle_tpu import layers, monitor  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import kda_kernels  # noqa: E402
 from paddle_tpu.ops import linear_attention_ops as lao  # noqa: E402
 from paddle_tpu.ops import nn_ops  # noqa: E402
 
@@ -133,6 +136,122 @@ def test_no_exponent_is_positive_in_a_channel_that_dies_in_one_token():
     want, want_state = recurrence_with_state(q, k, v, g, beta)
     agree(out, want, tol=2e-5)
     agree(state, want_state, tol=2e-5)
+
+
+def test_no_exponent_is_positive_in_the_kernels_either():
+    """The same dying channel through the kernels, which take the block's own
+    pairs from their differences for the (heads, chunk) that hold it and the
+    carried-back products for the others: output, state and every gradient
+    finite, and the `jax.numpy` form's and the recurrence's."""
+    q, k, v, g, beta = scan_inputs(7, 1, 128, 2, 8, 8, 0.05)
+    g = g.at[:, 5, :, 3].set(-100.0).at[:, 37, 0, :2].set(-60.0)        # chunk 1 is mild in both heads
+    weigh = jnp.asarray(np.random.RandomState(1).randn(*v.shape).astype("f4"))
+
+    def through(kernels):
+        op = lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, kernels)
+        return op(q, k, v, g, beta), jax.grad(lambda *a: jnp.sum(op(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    (out, state), grads = through("interpret")
+    (plain, plain_state), plain_grads = through(None)
+    want, want_state = recurrence_with_state(q, k, v, g, beta)
+    for mine, theirs in ((out, want), (state, want_state), (out, plain), (state, plain_state)) + tuple(zip(grads, plain_grads)):
+        assert np.isfinite(np.asarray(mine)).all()
+        agree(mine, theirs, tol=3e-5)
+
+
+KERNEL_CASES = [  # rows, length, heads, dtype, decay, beta
+    (1, 64, 2, "float32", 0.1, None), (2, 128, 3, "float32", 1.0, None), (1, 256, 4, "float32", 0.02, None),
+    (2, 64, 3, "bfloat16", 0.3, None), (1, 128, 2, "bfloat16", 0.05, None), (1, 128, 2, "float32", 20.0, None),
+    (1, 64, 3, "float32", 0.5, 0.0), (2, 128, 2, "float32", 0.2, 0.999), (1, 256, 8, "bfloat16", 0.1, None)]
+
+
+@pytest.mark.parametrize("rows,length,heads,dtype,decay,beta", KERNEL_CASES)
+def test_the_kernels_are_the_jax_numpy_form_and_the_recurrence(rows, length, heads, dtype, decay, beta):
+    """`ops/kda_kernels.py`, interpreted: a step's terms (Phi, B, Qe, P U) are
+    `_chunk_terms`'; the op through the kernels (one, two, four heads a grid
+    step; the state carried in scratch; the chunks in reverse for backward)
+    gives the `jax.numpy` form's output, final state and five gradients and
+    the token-by-token recurrence's (`benchmark/models/kimi_linear.py:
+    kda_recurrence`), at float32, from float32 and from bf16 inputs, at a mild
+    decay and at g = -20 a token (every output finite), beta drawn, 0 and near 1."""
+    q, k, v, g, b = scan_inputs(length + heads, rows, length, heads, 8, 8, decay)
+    b = b if beta is None else jnp.full_like(b, beta)
+    q, k, v = (t.astype(dtype) for t in (q, k, v))
+    # (a) the terms, as the kernels make them in VMEM (plain jax.numpy outside a kernel), chunk 0 of row 0
+    chunks = kda_kernels._Chunks([tuple(t[0, :64, h].astype(jnp.float32) for t in (q, k, v, g)) + (b[0, :64, h, None],)
+                                  for h in range(heads)], 16, lao._KDA_SAFE, lao._kernel_seams())
+    want = lao._chunk_terms(q[0, :64], k[0, :64], v[0, :64], g[0, :64], b[0, :64, :, None], 64, 16)
+    for mine, theirs in zip((chunks.phi, chunks.B, chunks.q_eff, chunks.own_out), want):
+        # exp of two float32 sums of 64 terms, each summed in its own order; a term that cancels to 1e-6 (beta near 1) by its parts' size
+        agree(jnp.stack(mine), theirs[0], tol=1e-4, floor=1e-3)
+
+    # (b) the op
+    def through(kernels):
+        return lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, kernels)
+
+    out, state = through("interpret")(q, k, v, g, b)
+    plain, plain_state = through(None)(q, k, v, g, b)
+    floats = tuple(t.astype(jnp.float32) for t in (q, k, v)) + (g, b)
+    recurred = kimi_linear.kda_recurrence(*floats)
+    assert out.dtype == v.dtype and state.dtype == jnp.float32
+    assert np.isfinite(np.asarray(out, "f4")).all() and np.isfinite(np.asarray(state)).all()
+    agree(state, plain_state, tol=5e-5)
+    agree(state, recurrence_with_state(*floats)[1], tol=5e-5)
+    agree(out, plain, tol=5e-5 if dtype == "float32" else 8e-3)      # a bf16 output rounds once, either way
+    agree(out, recurred, tol=5e-5 if dtype == "float32" else 8e-3)
+    # (c) the gradients, of float32 inputs (a bf16 cotangent rounds each form's sum at another place)
+    weigh = jnp.asarray(np.random.RandomState(1).randn(*out.shape).astype("f4"))
+    grads = [jax.grad(lambda *a: jnp.sum(fn(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*floats)
+             for fn in (through("interpret"), through(None), recurrence_with_state)]
+    for name, mine, theirs, recurrences in zip("q k v g beta".split(), *grads):
+        assert np.isfinite(np.asarray(mine)).all(), name
+        # as above: lost to underflow on either side; the kernels' x . dx - k . dk leaves float32's rounding of two O(1) terms
+        floor = 1e-2 if decay >= 20 and name == "g" else 1e-12
+        agree(mine, theirs, tol=5e-5, floor=floor)
+        agree(mine, recurrences, tol=5e-5, floor=floor)
+
+
+@pytest.mark.parametrize("platform,devices,width,v_width,length,path", [
+    ("tpu", 1, 128, 128, 4096, "kernels"), ("tpu", None, 128, 128, 64, "kernels"), ("tpu", 1, 256, 128, 128, "kernels"),
+    ("cpu", 1, 128, 128, 4096, "xla"), (None, None, 128, 128, 4096, "xla"), ("tpu", 1, 64, 64, 4096, "xla"),
+    ("tpu", 1, 128, 64, 4096, "xla"), ("tpu", 1, 128, 128, 32, "xla"), ("tpu", 4, 128, 128, 4096, "xla")])
+def test_the_rule_takes_the_kernels_on_one_tpu_at_whole_lane_tiles_and_nowhere_else(platform, devices, width, v_width, length, path):
+    """`_kda_path` reads the platform, the mesh, the two head widths and the
+    chunk, and nothing else: no flag, environment variable or attribute."""
+    q, v = jax.ShapeDtypeStruct((1, length, 2, width), jnp.bfloat16), jax.ShapeDtypeStruct((1, length, 2, v_width), jnp.bfloat16)
+    mesh = None if devices is None else SimpleNamespace(size=devices)
+    assert lao._kda_path(platform, mesh, q, v, min(lao._KDA_CHUNK, length)) == path
+    import inspect
+    assert not re.search(r"environ|getenv|FLAGS|\.attr\(", inspect.getsource(lao._kda_path) + inspect.getsource(lao._kda))
+
+
+def test_the_counter_says_which_kda_ops_took_the_kernels():
+    """`lowering.kda_kernel_calls` counts, at trace time, the `kda` ops whose
+    lowering took the kernels (`lowering.kda_kernel_transposed_calls` their
+    backward): one on the TPU at 128-wide heads, none off it, where the op's
+    numbers are the `jax.numpy` form's to the bit."""
+    q, k, v, g, beta = scan_inputs(5, 1, 64, 2, 128, 128, 0.1)
+    ins = {n: [jnp.asarray(t)] for n, t in zip(("Q", "K", "V", "G", "Beta"), (q, k, v, g, beta))}
+    op = SimpleNamespace(type="kda", attr=lambda n, d=None: d)
+
+    def lowered(platform):
+        ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
+        return lambda ins: get_op_def("kda").lower(ctx, op, ins)["Out"]
+
+    counter = lambda name: monitor.get_monitor().counter_values().get(name, 0)
+    monitor.reset()
+    monitor.enable()
+    try:
+        traced = jax.make_jaxpr(jax.grad(lambda ins: jnp.sum(lowered("tpu")(ins))))(ins)       # traced for the TPU, not run
+        assert counter("lowering.kda_kernel_calls") == 1 and counter("lowering.kda_kernel_transposed_calls") == 1
+        assert counter("lowering.kda_layers") == 1
+        assert str(traced).count("pallas_call") == 3 and "kda_scan_transposed" in str(traced)      # o, the start states, the transpose
+        out = lowered("cpu")(ins)
+        assert counter("lowering.kda_kernel_calls") == 1 and counter("lowering.kda_layers") == 2
+    finally:
+        monitor.disable()
+        monitor.reset()
+    assert (np.asarray(out) == np.asarray(lao.chunked_kda(q, k, v, g, beta[..., None])[0])).all()
 
 
 def test_the_benchmarks_recurrence_is_the_same_and_a_bf16_state_is_not():

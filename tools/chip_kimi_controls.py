@@ -4,8 +4,11 @@ program's check rows against the float32 reference, sound and then with a
 fault put in, one at a time, so that each limit this PR brings has a reading
 it must refuse beside the sound one (PERF.md, section 6, PR 42).  The faults
 are put into THE PROGRAM (the op's module is patched and the check rows run
-again through a new executor), but for the last, which is a weight zeroed in
-the reference (the errors are differences):
+again through a new executor; since PR 44 the scan's faults go into BOTH its
+lowerings, the kernels of `ops/kda_kernels.py` that the chip runs, through the
+seams their `jax.jit`s take as static arguments, and the `jax.numpy` form that
+`DRY=1` runs), but for the last, which is a weight zeroed in the reference
+(the errors are differences):
 
   * `kda_bf16_state`: the KDA state rounded to bf16 at every chunk boundary:
     `KDA_RTOL`;
@@ -42,10 +45,11 @@ import numpy as np
 import paddle_tpu as fluid
 from benchmark import manifest as mf
 from benchmark.models import kimi_linear, lfm2
+from paddle_tpu.ops import kda_kernels
 from paddle_tpu.ops import linear_attention_ops as lao
 from paddle_tpu.ops import moe_ops
 
-from chip_kimi_kernels import bf16, bf16_states   # beside this script
+from chip_kimi_kernels import bf16, bf16_cumulative_in_kernel, bf16_states, in_kernel_bf16   # beside this script
 
 CHECK_ROWS = 8  # as benchmark/runners/train.py
 DRY = os.environ.get("DRY") == "1"
@@ -61,13 +65,16 @@ LIMITS = {"logit_error": "REFERENCE_RTOL", "loss_error": "REFERENCE_RTOL", "kda_
 
 
 @contextlib.contextmanager
-def patched(module, name, value):
-    sound = getattr(module, name)
-    setattr(module, name, value)
+def patched(*seams):
+    """`seams`: (module, name, value), each set for the length of the block."""
+    sound = [getattr(module, name) for module, name, _ in seams]
+    for module, name, value in seams:
+        setattr(module, name, value)
     try:
         yield
     finally:
-        setattr(module, name, sound)
+        for (module, name, _), value in zip(seams, sound):
+            setattr(module, name, value)
 
 
 def conv_in_bf16(x, w):
@@ -81,11 +88,13 @@ def conv_in_bf16(x, w):
 def faults():
     cumulative, scan = lao._cumulative, lao.chunked_kda
     return {
-        "kda_bf16_state": lambda: patched(lao, "_states", bf16_states),
-        "kda_bf16_cumulative_decay": lambda: patched(lao, "_cumulative", lambda g: bf16(cumulative(g))),
-        "kda_no_decay": lambda: patched(lao, "chunked_kda", lambda q, k, v, g, *rest: scan(q, k, v, 0 * g, *rest)),
-        "kda_default_precision": lambda: patched(lao, "_KDA_PRECISION", jax.lax.Precision.DEFAULT),
-        "conv_in_bf16": lambda: patched(moe_ops, "_plain_short_conv", conv_in_bf16),
+        "kda_bf16_state": lambda: patched((lao, "_states", bf16_states), (kda_kernels, "carried", in_kernel_bf16)),
+        "kda_bf16_cumulative_decay": lambda: patched(
+            (lao, "_cumulative", lambda g: bf16(cumulative(g))),
+            (kda_kernels, "cumulative", bf16_cumulative_in_kernel)),
+        "kda_no_decay": lambda: patched((lao, "chunked_kda", lambda q, k, v, g, *rest: scan(q, k, v, 0 * g, *rest))),
+        "kda_default_precision": lambda: patched((lao, "_KDA_PRECISION", jax.lax.Precision.DEFAULT)),   # the kernels' too
+        "conv_in_bf16": lambda: patched((moe_ops, "_plain_short_conv", conv_in_bf16)),
     }
 
 

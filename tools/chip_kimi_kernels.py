@@ -2,14 +2,18 @@
 (run through `chiprun -- python3 tools/chip_kimi_kernels.py`, ~5 min):
 
   * the chunked KDA op alone at the cell's shape (1, 4096, 32, 128), forward
-    and forward + backward, at each precision of its float32 products and with
-    the chunks' terms made a group or a whole row at a time, and how far each
-    lies from the token-by-token float32 recurrence;
+    and forward + backward, through the Pallas kernels (`ops/kda_kernels.py`:
+    what the op's rule takes on the chip, PR 44) and in the `jax.numpy` form,
+    and how far each lies from the token-by-token float32 recurrence rounded as
+    the op rounds; with SWEEP=1 the `jax.numpy` form at each precision of its
+    float32 products and with the chunks' terms made 4, 8 or 16 chunks at a time;
   * the causal attention at latent attention's widths (192-wide queries and
     keys, 128-wide values, 32 heads, 4096 keys): the stock splash kernels with
     the widths as they are, with q and k padded to 256 by zeros, and XLA's.
 
-Prints one JSON line a reading.  ONLY=kda or ONLY=attention runs one half."""
+Prints one JSON line a reading.  ONLY=kda or ONLY=attention runs one half;
+ONLY=profile writes the op's own device time by HLO instruction (the kernels,
+the state's `while`s, what is left in XLA's fusions) under chiprun_out/."""
 import json
 import os
 import sys
@@ -22,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.models import kimi_linear
+from paddle_tpu.ops import kda_kernels
 from paddle_tpu.ops import linear_attention_ops as lao
 from paddle_tpu.ops import masked_attention, nn_ops
 
@@ -66,45 +71,91 @@ def bf16_states(phi, B):
     return starts, final
 
 
-def kda():
-    args = kda_inputs(int(os.environ.get("SEED", "1")))
-
+def op_of(kernels):
+    """The op as `_kda` calls it: through the kernels ("tpu") or in the `jax.numpy` form (None)."""
     def op(q, k, v, g, beta):
-        return lao.chunked_kda(q, k, v, g, beta[..., None])[0]
+        return lao.chunked_kda(q, k, v, g, beta[..., None], lao._KDA_CHUNK, lao._KDA_SUB, kernels)[0]
+    return op
 
+
+def both_of(op):
     def both(*a):
         out, pull = jax.vjp(op, *a)
         return pull(out)
+    return both
 
-    def errors(out):   # as the cell's KDA stage reads them
-        found = kimi_linear.kda_errors([args + (out,)])
+
+def in_kernel_bf16(t):
+    """bf16's eight bits inside a Pallas kernel: a pair of casts, of which Mosaic takes none out."""
+    return t.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+cumulative_in_kernel = kda_kernels.cumulative
+
+
+def bf16_cumulative_in_kernel(g, lower):
+    return in_kernel_bf16(cumulative_in_kernel(g, lower))
+
+
+def kda():
+    args = kda_inputs(int(os.environ.get("SEED", "1")))
+
+    def errors(out, inputs=args):   # as the cell's KDA stage reads them
+        found = kimi_linear.kda_errors([inputs + (out,)])
         return {"error": found["kda_error"], "error_unrounded": found["kda_error_unrounded"],
                 "recurrence_bf16_state": found["kda_error_bf16_state"]}
 
-    for precision in ("HIGHEST", "HIGH"):
-        for group in (8, 4, 16):
-            lao._KDA_PRECISION, lao._KDA_GROUP = getattr(jax.lax.Precision, precision), group
-            fwd_ms, out = timed(jax.jit(lambda *a: op(*a)), *args)
-            both_ms, _ = timed(jax.jit(lambda *a: both(*a)), *args)
-            say(reading="kda_op", precision=precision, group=group, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms, **errors(out))
-    lao._KDA_PRECISION = jax.lax.Precision.DEFAULT
-    say(reading="kda_op", precision="DEFAULT", **errors(jax.jit(lambda *a: op(*a))(*args)))
-    lao._KDA_PRECISION, lao._KDA_GROUP = PRECISION, GROUP
-    # the faults the stage's limit has to refuse, put into the OP
-    states, cumulative = lao._states, lao._cumulative
-
-    lao._states = bf16_states
-    say(reading="kda_op_bf16_state", **errors(jax.jit(lambda *a: op(*a))(*args)))
-    lao._states = states
-    lao._cumulative = lambda g: bf16(cumulative(g))
-    say(reading="kda_op_bf16_cumulative_decay", **errors(jax.jit(lambda *a: op(*a))(*args)))
-    lao._cumulative = cumulative
-    say(reading="kda_op_no_decay", **errors(jax.jit(lambda q, k, v, g, b: op(q, k, v, 0 * g, b))(*args)))
+    outs = {}
+    weigh = jnp.asarray(np.random.RandomState(2).randn(*args[2].shape), jnp.bfloat16)
+    heads_a_step = kda_kernels._HEADS
+    forms = [("kernels", "tpu", heads_a_step), ("jax.numpy", None, None)]
+    forms += [("kernels", "tpu", h) for h in (1, 2, 4, 8) if h != heads_a_step] if os.environ.get("HEADS") == "1" else []
+    for form, kernels, heads in forms:
+        kda_kernels._HEADS = heads or heads_a_step      # read when a kernel is traced
+        jax.clear_caches()
+        op = op_of(kernels)
+        fwd_ms, out = timed(jax.jit(op), *args)
+        bwd_ms, _ = timed(jax.jit(lambda *a: jax.vjp(op, *a)[1](weigh)), *args)      # forward's call is dead code there
+        both_ms, grads = timed(jax.jit(both_of(op)), *args)
+        say(reading="kda_op", form=form, heads_a_grid_step=heads, precision="HIGHEST", fwd_ms=fwd_ms, bwd_ms=bwd_ms,
+            fwd_bwd_ms=both_ms, **errors(out))
+        if form not in outs:
+            outs[form], outs[form + ".grads"] = out, grads
+    kda_kernels._HEADS = heads_a_step
+    jax.clear_caches()
+    rms = lambda t: float(np.sqrt(np.mean(np.square(np.asarray(t, "f4")))))
+    say(reading="kda_kernels_against_jax_numpy", out=rms(outs["kernels"].astype(jnp.float32) - outs["jax.numpy"].astype(jnp.float32)) / rms(outs["jax.numpy"]),
+        **{"d" + name: rms(mine.astype(jnp.float32) - theirs.astype(jnp.float32)) / max(rms(theirs), 1e-30)
+           for name, mine, theirs in zip("q k v g beta".split(), outs["kernels.grads"], outs["jax.numpy.grads"])})
+    if os.environ.get("SWEEP") == "1":
+        for precision in ("HIGHEST", "HIGH"):
+            for group in (8, 4, 16):
+                lao._KDA_PRECISION, lao._KDA_GROUP = getattr(jax.lax.Precision, precision), group
+                fwd_ms, out = timed(jax.jit(op_of(None)), *args)
+                both_ms, _ = timed(jax.jit(both_of(op_of(None))), *args)
+                say(reading="kda_op", form="jax.numpy", precision=precision, group=group, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms, **errors(out))
+        lao._KDA_PRECISION = jax.lax.Precision.DEFAULT
+        say(reading="kda_op", form="jax.numpy", precision="DEFAULT", **errors(jax.jit(op_of(None))(*args)))
+        lao._KDA_PRECISION, lao._KDA_GROUP = PRECISION, GROUP
+    # the faults the stage's limit has to refuse, put into the OP as the chip runs it (the kernels)
+    # (a new function a fault: `jax.jit` keeps what it traced by the function it was given)
+    carried = kda_kernels.carried
+    kda_kernels.carried = in_kernel_bf16
+    say(reading="kda_op_bf16_state", **errors(jax.jit(op_of("tpu"))(*args)))
+    kda_kernels.carried = carried
+    kda_kernels.cumulative = bf16_cumulative_in_kernel
+    say(reading="kda_op_bf16_cumulative_decay", **errors(jax.jit(op_of("tpu"))(*args)))
+    kda_kernels.cumulative = cumulative_in_kernel
+    say(reading="kda_op_no_decay", **errors(jax.jit(lambda q, k, v, g, b: op_of("tpu")(q, k, v, 0 * g, b))(*args)))
     # the rarer lowering of the blocks' own keys, forced: a channel that dies in one token
     strong = args[3].at[:, ::97, :, 5].set(-100.0)
     hard = args[:3] + (strong,) + args[4:]
-    found = kimi_linear.kda_errors([hard + (jax.jit(lambda *a: op(*a))(*hard),)])
-    say(reading="kda_op_by_differences", error=found["kda_error"], error_unrounded=found["kda_error_unrounded"])
+    for form, kernels in (("kernels", "tpu"), ("jax.numpy", None)):
+        fwd_ms, out = timed(jax.jit(op_of(kernels)), *hard)
+        both_ms, _ = timed(jax.jit(both_of(op_of(kernels))), *hard)
+        found = errors(out, hard)
+        say(reading="kda_op_by_differences", form=form, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms, error=found["error"],
+            error_unrounded=found["error_unrounded"])
 
 
 def attention():
@@ -147,11 +198,7 @@ def profile():
 
     args = kda_inputs(1)
 
-    def both(q, k, v, g, beta):
-        out, pull = jax.vjp(lambda *a: lao.chunked_kda(*a[:4], a[4][..., None])[0], q, k, v, g, beta)
-        return pull(out)
-
-    fn = jax.jit(both)
+    fn = jax.jit(both_of(op_of(None if os.environ.get("FORM") == "jax.numpy" else "tpu")))
     jax.block_until_ready(fn(*args))
     os.makedirs("chiprun_out", exist_ok=True)
     open("chiprun_out/kda_both.hlo", "w").write(fn.lower(*args).compile().as_text())
@@ -174,7 +221,11 @@ def profile():
             for name, ns in own_times(events, (min(e[1] for e in events), max(e[1] + e[2] for e in events))):
                 spent[name] = spent.get(name, 0.0) + ns / 3e6
     json.dump(sorted(spent.items(), key=lambda kv: -kv[1]), open("chiprun_out/kda_profile.json", "w"))
-    say(reading="kda_profile", own_ms_a_run=sum(spent.values()), instructions=len(spent))
+    kinds = {"kernels": 0.0, "while": 0.0, "fusions": 0.0}
+    for name, ms in spent.items():      # a Pallas kernel's instruction carries the call's name
+        kinds["kernels" if name.startswith("kda_scan") else "while" if name.startswith("while") else "fusions"] += ms
+    say(reading="kda_profile", own_ms_a_run=sum(spent.values()), instructions=len(spent), own_ms_by_kind=kinds,
+        top=sorted(spent.items(), key=lambda kv: -kv[1])[:12])
     import shutil
     shutil.rmtree("chiprun_out/kda_trace")
 
