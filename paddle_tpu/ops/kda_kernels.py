@@ -11,10 +11,15 @@ B, Qe and the chunk's own output P U, and carries the float32 state S [K, V] a
 head from chunk to chunk in VMEM scratch (the chunk axis is "arbitrary"):
 
   * `scan`: o = P U + Qe S in v's dtype and S' = Phi S + B; the state after the
-    last token out.  With `keep_starts` the state every chunk STARTS from
-    instead of o: what backward reads (nothing is kept from forward);
-  * `scan_transposed`: the chunks in REVERSE order, the five inputs, d o and the
-    chunks' start states in; the terms made again in VMEM, the state's
+    last token out.  With `keep` (the op's forward where it is differentiated:
+    `linear_attention_ops._chunked_kda_fwd`) what backward reads too, all that
+    is kept beside the inputs: the state every chunk STARTS from, [n, b, H, K,
+    V] float32 from the scratch as the grid step finds it, and T, whose ten
+    dependent products are a sixth of the transposed kernel's time where it
+    makes them again (`_unit_lower_inverses`), [n, b, H, C / 2, 2 C] (its
+    upper rows beside its lower: `_halves_side_by_side`);
+  * `scan_transposed`: the chunks in REVERSE order, the five inputs, d o and
+    what forward kept in; the other terms made again in VMEM, the state's
     cotangent lambda [K, V] a head carried in scratch (lambda_c = Phi_c^T
     lambda_{c+1} + Qe_c^T dO_c), and the transpose of the chunk's own terms
     written out by hand (a dozen products; T's transpose is a product with
@@ -89,9 +94,9 @@ class _Chunks:
     """The terms of a grid step's heads, one chunk each, in VMEM: from lists of
     float32 q, k [C, K], v [C, V], g [C, K] and beta [C, 1] a head; `sub` rows a
     block, `seams` = (the products' precision, the cumulative decay's function,
-    the carried state's)."""
+    the carried state's); `T`: the heads' (I + beta M)^-1 where forward kept them."""
 
-    def __init__(self, heads, sub, safe, seams):
+    def __init__(self, heads, sub, safe, seams, T=None):
         self.precision, cumulative_fn, self.carried = seams
         self.heads = range(len(heads))
         self.q, self.k, self.v, _, self.beta = (list(t) for t in zip(*heads))
@@ -122,9 +127,8 @@ class _Chunks:
         grams = self._grams()
         self.M = [jnp.where(self.strict, M, 0.0) for M, _ in grams]
         self.P = [jnp.where(self.lower, P, 0.0) for _, P in grams]
-        self.T = self._unit_lower_inverses([beta * M for beta, M in zip(self.beta, self.M)])
-        self.X = [self.dot(T, jnp.concatenate([beta * k * e, beta * v], axis=1))            # [W | U]
-                  for T, beta, k, e, v in zip(self.T, self.beta, self.k, self.from_start, self.v)]
+        self.T = self._unit_lower_inverses([beta * M for beta, M in zip(self.beta, self.M)]) if T is None else T
+        self.X = self._solved()                                                              # [W | U]
         # Phi [K, K], B [K, V], Qe [C, K], the chunk's own output P U [C, V]
         eye_K = self.eye_K = _iota((K, K), 0) == _iota((K, K), 1)
         ends = [self.dot(k_end, X, _TN) for k_end, X in zip(self.k_end, self.X)]             # [K, K + V]
@@ -193,6 +197,11 @@ class _Chunks:
             m *= 2
         return inverses
 
+    def _solved(self):
+        """[W | U] = T [beta k exp(G) | beta v], [C, K + V] a head."""
+        return [self.dot(T, jnp.concatenate([beta * k * e, beta * v], axis=1))
+                for T, beta, k, e, v in zip(self.T, self.beta, self.k, self.from_start, self.v)]
+
     def transposed(self, d_phi, d_b, d_qe, d_own):
         """Lists a head of (dq, dk, dv, dg [C, .], dbeta [C, 1]) from lists of the
         four outputs' cotangents."""
@@ -259,6 +268,20 @@ class _Chunks:
         return out
 
 
+def _halves_side_by_side(t):
+    """[C, C] as [C / 2, 2 C], the upper rows beside the lower: how T is kept (a
+    whole lane tile wide at C = 64; 64 lanes wide it is padded to twice its
+    bytes in HBM: `peak_hbm_gb` 13.47 | 13.33 at the same rate, my chip runs, PR 45)."""
+    half = t.shape[0] // 2
+    return jnp.concatenate([t[:half], t[half:]], axis=1)
+
+
+def _halves_stacked(t):
+    """`_halves_side_by_side`'s inverse."""
+    half = t.shape[1] // 2
+    return jnp.concatenate([t[:, :half], t[:, half:]], axis=0)
+
+
 def _heads_of(refs, heads, widths, group):
     """A list, a head of the grid step's group, of float32 (q, k, v, g [C, .],
     beta [C, 1]) from the step's blocks: q, k, v, g `[1, C, heads . width]` of
@@ -270,10 +293,11 @@ def _heads_of(refs, heads, widths, group):
             + (jnp.sum(jnp.where(lane == group * heads + j, beta, 0.0), axis=1, keepdims=True),) for j in range(heads)]
 
 
-def _scan_kernel(heads, sub, safe, seams, keep_starts, q_ref, k_ref, v_ref, g_ref, beta_ref, *rest):
+def _scan_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, final_ref, *rest):
+    """`rest`: the blocks of the chunk's start states and T where they are kept, and the state's scratch."""
     K, V = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
     chunks = _Chunks(_heads_of((q_ref, k_ref, v_ref, g_ref, beta_ref), heads, (K, K, V, K), pl.program_id(1)), sub, safe, seams)
-    state = rest[-1]
+    *kept, state = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -281,21 +305,22 @@ def _scan_kernel(heads, sub, safe, seams, keep_starts, q_ref, k_ref, v_ref, g_re
 
     for h in chunks.heads:
         S = state[h]
-        if keep_starts:
-            rest[0][0, 0, h] = S
-        else:
-            rest[0][0, :, h * V:(h + 1) * V] = (chunks.own_out[h] + chunks.dot(chunks.q_eff[h], S)).astype(rest[0].dtype)
+        if kept:
+            kept[0][0, 0, h] = S
+            kept[1][0, 0, h] = _halves_side_by_side(chunks.T[h])
+        o_ref[0, :, h * V:(h + 1) * V] = (chunks.own_out[h] + chunks.dot(chunks.q_eff[h], S)).astype(o_ref.dtype)
         state[h] = chunks.carried(chunks.dot(chunks.phi[h], S) + chunks.B[h])
-    if not keep_starts:
-        @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-        def _():
-            rest[1][0] = state[...]
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        final_ref[0] = state[...]
 
 
-def _transposed_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, d_o_ref, starts_ref,
+def _transposed_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, d_o_ref, starts_ref, t_ref,
                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, after):
     K, V = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
-    chunks = _Chunks(_heads_of((q_ref, k_ref, v_ref, g_ref, beta_ref), heads, (K, K, V, K), pl.program_id(1)), sub, safe, seams)
+    chunks = _Chunks(_heads_of((q_ref, k_ref, v_ref, g_ref, beta_ref), heads, (K, K, V, K), pl.program_id(1)), sub, safe, seams,
+                     T=[_halves_stacked(t_ref[0, 0, h]) for h in range(heads)])
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -339,54 +364,60 @@ _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "
 
 
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
-def scan(q, k, v, g, beta, chunk, sub, safe, seams, keep_starts, interpret):
+def scan(q, k, v, g, beta, chunk, sub, safe, seams, keep, interpret):
     """(o [b, T, H, V] in v's dtype, the state after the last token [b, H, K, V]
     float32) of q, k [b, T, H, K], v [b, T, H, V], g [b, T, H, K] float32 and
-    beta [b, T, H]; with `keep_starts` the state every chunk starts from
-    [n, b, H, K, V] instead."""
+    beta [b, T, H]; with `keep` the state every chunk starts from [n, b, H, K,
+    V] and T [n, b, H, C / 2, 2 C] (`_halves_side_by_side`), float32, after
+    them (the same kernel with two more output blocks a grid step: o and the
+    final state are the plain call's)."""
     (b, T, H, K), V = k.shape, v.shape[-1]
     n, heads = T // chunk, _heads_a_step(H)
 
     def tokens(width):      # [b, T, H . width]: the chunk's rows, the group's lanes
         return pl.BlockSpec((1, chunk, heads * width), lambda i, h, c: (i, c, h))
 
-    if keep_starts:
-        out_specs = [pl.BlockSpec((1, 1, heads, K, V), lambda i, h, c: (c, i, h, 0, 0))]
-        out_shape = [jax.ShapeDtypeStruct((n, b, H, K, V), F32)]
-    else:
-        out_specs = [tokens(V), pl.BlockSpec((1, heads, K, V), lambda i, h, c: (i, h, 0, 0))]
-        out_shape = [jax.ShapeDtypeStruct((b, T, H * V), v.dtype), jax.ShapeDtypeStruct((b, H, K, V), F32)]
-    out = pl.pallas_call(
-        functools.partial(_scan_kernel, heads, sub, safe, seams, keep_starts), grid=(b, H // heads, n),
+    out_specs = [tokens(V), pl.BlockSpec((1, heads, K, V), lambda i, h, c: (i, h, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, T, H * V), v.dtype), jax.ShapeDtypeStruct((b, H, K, V), F32)]
+    for rows, width in ((K, V), (chunk // 2, 2 * chunk)) if keep else ():
+        out_specs.append(pl.BlockSpec((1, 1, heads, rows, width), lambda i, h, c: (c, i, h, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((n, b, H, rows, width), F32))
+    o, *rest = pl.pallas_call(
+        functools.partial(_scan_kernel, heads, sub, safe, seams), grid=(b, H // heads, n),
         in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, c, 0))],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=[pltpu.VMEM((heads, K, V), F32)],
-        compiler_params=_SEMANTICS, cost_estimate=_cost(b, T, H, K, V, chunk, 1, 4 * b * H * K * V * (n if keep_starts else 1)),
-        name="kda_scan_starts" if keep_starts else "kda_scan", interpret=interpret,
+        compiler_params=_SEMANTICS, cost_estimate=_cost(b, T, H, K, V, chunk, 1, 4 * b * H * (K * V + n * keep * (K * V + chunk * chunk))),
+        name="kda_scan", interpret=interpret,
     )(_flat(q), _flat(k), _flat(v), _flat(g), beta)
-    return out[0] if keep_starts else (out[0].reshape(v.shape), out[1])
+    return (o.reshape(v.shape), *rest)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
-def scan_transposed(q, k, v, g, beta, d_o, starts, chunk, sub, safe, seams, interpret):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
+def scan_transposed(q, k, v, g, beta, d_o, starts, inverses, chunk, sub, safe, seams, interpret):
     """(dq, dk, dv, dg, dbeta) in the five inputs' shapes (dq, dk, dv in theirs'
-    dtypes, dg and dbeta float32) of d o [b, T, H, V], the chunks' start states
-    [n, b, H, K, V] (`scan(keep_starts=True)`) beside the inputs."""
+    dtypes, dg and dbeta float32) of d o [b, T, H, V] and what `scan(keep=True)`
+    kept from forward, the chunks' start states [n, b, H, K, V] and T
+    [n, b, H, C / 2, 2 C], beside the inputs."""
     (b, T, H, K), V = k.shape, v.shape[-1]
     n, heads = T // chunk, _heads_a_step(H)
 
     def tokens(width):      # the chunks in reverse order
         return pl.BlockSpec((1, chunk, heads * width), lambda i, h, c: (i, n - 1 - c, h))
 
+    def kept(rows, width):
+        return pl.BlockSpec((1, 1, heads, rows, width), lambda i, h, c: (n - 1 - c, i, h, 0, 0))
+
     dq, dk, dv, dg, dbeta = pl.pallas_call(
         functools.partial(_transposed_kernel, heads, sub, safe, seams), grid=(b, H // heads, n),
         in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, n - 1 - c, 0)),
-                  tokens(V), pl.BlockSpec((1, 1, heads, K, V), lambda i, h, c: (n - 1 - c, i, h, 0, 0))],
+                  tokens(V), kept(K, V), kept(chunk // 2, 2 * chunk)],
         out_specs=[tokens(K), tokens(K), tokens(V), tokens(K),
                    pl.BlockSpec((1, 1, 1, heads, chunk), lambda i, h, c: (i, n - 1 - c, h, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(_flat(t).shape, t.dtype) for t in (q, k, v, g)]
         + [jax.ShapeDtypeStruct((b, n, H // heads, heads, chunk), F32)],
         scratch_shapes=[pltpu.VMEM((heads, K, V), F32)], compiler_params=_SEMANTICS,
-        cost_estimate=_cost(b, T, H, K, V, chunk, 3, 4 * b * H * K * V * n), name="kda_scan_transposed", interpret=interpret,
-    )(_flat(q), _flat(k), _flat(v), _flat(g), beta, _flat(d_o), starts)
+        cost_estimate=_cost(b, T, H, K, V, chunk, 3, 4 * b * H * n * (K * V + chunk * chunk)), name="kda_scan_transposed",
+        interpret=interpret,
+    )(_flat(q), _flat(k), _flat(v), _flat(g), beta, _flat(d_o), starts, inverses)
     dbeta = dbeta.reshape(b, n, H, chunk).swapaxes(2, 3).reshape(b, T, H)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), dg.reshape(g.shape), dbeta

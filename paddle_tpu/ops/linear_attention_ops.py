@@ -35,12 +35,20 @@ keys of their own block either carried back to that row, over at most
 (`_decayed_grams`).  G, the state, T and every product that reads them are
 float32 at `_KDA_PRECISION`.
 
-Backward is written out (`jax.custom_vjp`): it keeps the op's five inputs and
-nothing else, makes the chunks' terms and the boundary states again, runs the
-state's recurrence TRANSPOSED (lambda_c = Phi_c^T lambda_{c+1} + Qe_c^T dO_c,
-one reverse scan), and hands the cotangents of (Phi, B, Qe, P U) to the
-transpose of the chunks' own terms, which no scan is part of.  Nothing of
-[T, T] and no [chunks, C, C, K] array is kept from forward to backward.
+Backward is written out (`jax.custom_vjp`): it makes the chunks' terms again,
+runs the state's recurrence TRANSPOSED (lambda_c = Phi_c^T lambda_{c+1} +
+Qe_c^T dO_c, one reverse scan), and hands the cotangents of (Phi, B, Qe, P U)
+to the transpose of the chunks' own terms, which no scan is part of.  What
+forward keeps for it is the op's five inputs and, on the kernels' path, two
+things a (head, chunk) that the forward kernel has in VMEM anyway and writes
+out where the op is differentiated (`_chunked_kda_fwd`; a plain call writes
+neither): the state the chunk STARTS from ([n, b, H, K, V] float32, 134 MB a
+layer at the cell's shape; backward made it again with a second forward call
+until PR 45) and T ([C, C], 33.5 MB a layer: ten dependent products that were
+a sixth of the transposed kernel's time).  So backward is the transposed
+kernel and nothing else.  The `jax.numpy` form keeps the five inputs only and
+makes the boundary states again.  Nothing of [T, T] and no [chunks, C, C, K]
+array is kept from forward to backward.
 """
 from __future__ import annotations
 
@@ -271,10 +279,13 @@ def _kda_path(platform, mesh, q, v, chunk):
     return "kernels" if platform == "tpu" and one_device and whole else "xla"
 
 
-def _chunked_kda(q, k, v, g, beta, chunk, sub, kernels):
+def _chunked_kda(q, k, v, g, beta, chunk, sub, kernels, keep=False):
+    """(o, the final state) and, of the kernels with `keep`, what backward
+    reads after them: the chunks' start states and T."""
     with jax.named_scope("kda_chunk_scan"):
         if kernels:
-            return kda_kernels.scan(q, k, v, g, beta[..., 0], chunk, sub, _KDA_SAFE, _kernel_seams(), False, kernels == "interpret")
+            return kda_kernels.scan(q, k, v, g, beta[..., 0], chunk, sub, _KDA_SAFE, _kernel_seams(), keep,
+                                    kernels == "interpret")
         return _over_rows(functools.partial(_row_forward, chunk=chunk, sub=sub), q, k, v, g, beta)
 
 
@@ -291,18 +302,25 @@ def chunked_kda(q, k, v, g, beta, chunk=_KDA_CHUNK, sub=_KDA_SUB, kernels=None):
 
 
 def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub, kernels):
-    return _chunked_kda(q, k, v, g, beta, chunk, sub, kernels), (q, k, v, g, beta)
+    """The op where it is differentiated: the kernels keep the chunks' start
+    states and T beside the five inputs (the `jax.numpy` form the inputs alone)."""
+    inputs = (q, k, v, g, beta)
+    if not kernels:
+        return _chunked_kda(*inputs, chunk, sub, None), (inputs, ())
+    _MON.counter("lowering.kda_starts_kept").inc()
+    o, final, *kept = _chunked_kda(*inputs, chunk, sub, kernels, keep=True)
+    return (o, final), (inputs, tuple(kept))
 
 
-def _chunked_kda_bwd(chunk, sub, kernels, inputs, cotangents):
+def _chunked_kda_bwd(chunk, sub, kernels, residuals, cotangents):
+    inputs, kept = residuals
     with jax.named_scope("kda_chunk_scan"):
         if not kernels:
             return _over_rows(functools.partial(_row_backward, chunk=chunk, sub=sub), *inputs, cotangents[0])
-        # the boundary states made again (nothing is kept from forward), then the chunks in reverse
+        # the chunks in reverse, each from the start state and with the T that forward kept
         _MON.counter("lowering.kda_kernel_transposed_calls").inc()
-        operands, how = inputs[:4] + (inputs[4][..., 0],), (chunk, sub, _KDA_SAFE, _kernel_seams())
-        starts = kda_kernels.scan(*operands, *how, True, kernels == "interpret")
-        *d_inputs, d_beta = kda_kernels.scan_transposed(*operands, cotangents[0], starts, *how, kernels == "interpret")
+        *d_inputs, d_beta = kda_kernels.scan_transposed(*inputs[:4], inputs[4][..., 0], cotangents[0], *kept, chunk, sub,
+                                                        _KDA_SAFE, _kernel_seams(), kernels == "interpret")
         return (*d_inputs, d_beta[..., None].astype(inputs[4].dtype))
 
 

@@ -1,21 +1,29 @@
 """Prices, on the chip, the two lowerings Kimi Linear's cell chose by a reading
 (run through `chiprun -- python3 tools/chip_kimi_kernels.py`, ~5 min):
 
-  * the chunked KDA op alone at the cell's shape (1, 4096, 32, 128), forward
-    and forward + backward, through the Pallas kernels (`ops/kda_kernels.py`:
-    what the op's rule takes on the chip, PR 44) and in the `jax.numpy` form,
-    and how far each lies from the token-by-token float32 recurrence rounded as
-    the op rounds; with SWEEP=1 the `jax.numpy` form at each precision of its
+  * the chunked KDA op alone at the cell's shape (1, 4096, 32, 128) as the step
+    runs it: the plain forward (the `for_test` clone's), the forward that is
+    differentiated (which keeps what backward reads: the chunks' start states
+    and T on the kernels' path, PR 45), the backward that reads it, and both
+    together, through the Pallas kernels (`ops/kda_kernels.py`: what the op's
+    rule takes on the chip, PR 44) and in the `jax.numpy` form, and how far
+    each lies from the token-by-token float32 recurrence rounded as the op
+    rounds; with SWEEP=1 the `jax.numpy` form at each precision of its
     float32 products and with the chunks' terms made 4, 8 or 16 chunks at a time;
   * the causal attention at latent attention's widths (192-wide queries and
     keys, 128-wide values, 32 heads, 4096 keys): the stock splash kernels with
     the widths as they are, with q and k padded to 256 by zeros, and XLA's.
 
 Prints one JSON line a reading.  ONLY=kda or ONLY=attention runs one half;
-ONLY=profile writes the op's own device time by HLO instruction (the kernels,
-the state's `while`s, what is left in XLA's fusions) under chiprun_out/."""
+ONLY=profile writes the own device time by HLO instruction (the kernels, the
+state's `while`s, what is left in XLA's fusions) of the op's three programs,
+the plain forward, the forward that keeps and the backward that reads, under
+chiprun_out/; ONLY=terms prices what keeping one more of a chunk's terms from
+forward could save (`terms`: the kernels with that term's products cut out,
+an upper bound)."""
 import json
 import os
+import shutil
 import sys
 import time
 
@@ -58,6 +66,11 @@ def kda_inputs(seed, b=1, T=4096, H=32, K=128):
     return low(q), low(k), low(v), jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32)
 
 
+def cotangent_of(args):
+    """A d o for `kda_inputs`' v."""
+    return jnp.asarray(np.random.RandomState(2).randn(*args[2].shape), jnp.bfloat16)
+
+
 def bf16(t):
     """float32 holding bf16's eight bits; not a pair of casts, which XLA may take out."""
     return jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
@@ -85,6 +98,23 @@ def both_of(op):
     return both
 
 
+def kept_of(kernels):
+    """The op's forward where it is differentiated (`custom_vjp`'s rule): ((o, the final state), what it MAKES for
+    backward: the chunks' start states and T on the kernels' path, nothing in the `jax.numpy` form; the five inputs it
+    keeps too are the caller's own arrays)."""
+    def forward(q, k, v, g, beta):
+        out, (_, kept) = lao._chunked_kda_fwd(q, k, v, g, beta[..., None], lao._KDA_CHUNK, lao._KDA_SUB, kernels)
+        return out, kept
+    return forward
+
+
+def backward_of(kernels):
+    """The op's backward alone: the five gradients of the inputs, what forward made for it and d o."""
+    def backward(q, k, v, g, beta, kept, d_o):
+        return lao._chunked_kda_bwd(lao._KDA_CHUNK, lao._KDA_SUB, kernels, ((q, k, v, g, beta[..., None]), kept), (d_o, None))
+    return backward
+
+
 def in_kernel_bf16(t):
     """bf16's eight bits inside a Pallas kernel: a pair of casts, of which Mosaic takes none out."""
     return t.astype(jnp.bfloat16).astype(jnp.float32)
@@ -106,7 +136,7 @@ def kda():
                 "recurrence_bf16_state": found["kda_error_bf16_state"]}
 
     outs = {}
-    weigh = jnp.asarray(np.random.RandomState(2).randn(*args[2].shape), jnp.bfloat16)
+    weigh = cotangent_of(args)
     heads_a_step = kda_kernels._HEADS
     forms = [("kernels", "tpu", heads_a_step), ("jax.numpy", None, None)]
     forms += [("kernels", "tpu", h) for h in (1, 2, 4, 8) if h != heads_a_step] if os.environ.get("HEADS") == "1" else []
@@ -115,10 +145,11 @@ def kda():
         jax.clear_caches()
         op = op_of(kernels)
         fwd_ms, out = timed(jax.jit(op), *args)
-        bwd_ms, _ = timed(jax.jit(lambda *a: jax.vjp(op, *a)[1](weigh)), *args)      # forward's call is dead code there
+        kept_ms, (_, kept) = timed(jax.jit(kept_of(kernels)), *args)
+        bwd_ms, _ = timed(jax.jit(backward_of(kernels)), *args, kept, weigh)
         both_ms, grads = timed(jax.jit(both_of(op)), *args)
-        say(reading="kda_op", form=form, heads_a_grid_step=heads, precision="HIGHEST", fwd_ms=fwd_ms, bwd_ms=bwd_ms,
-            fwd_bwd_ms=both_ms, **errors(out))
+        say(reading="kda_op", form=form, heads_a_grid_step=heads, precision="HIGHEST", fwd_ms=fwd_ms, fwd_kept_ms=kept_ms,
+            bwd_ms=bwd_ms, fwd_bwd_ms=both_ms, kept_mb=sum(t.nbytes for t in kept) / 1e6, **errors(out))
         if form not in outs:
             outs[form], outs[form + ".grads"] = out, grads
     kda_kernels._HEADS = heads_a_step
@@ -190,25 +221,21 @@ def attention():
             max_error_from_xla=float(np.abs(np.asarray(out, "f4") - want).max() / np.abs(want).max()))
 
 
-def profile():
-    """Where the op's forward + backward spends its time: own device time by
-    HLO instruction, and the compiled text to look each up in, written under
-    chiprun_out/."""
+def own_ms_by_instruction(name, fn, *args, runs=3):
+    """{HLO instruction: own device ms a run} of `fn(*args)`, from a trace of
+    `runs` runs; the compiled text to look each up in goes to chiprun_out/."""
     from benchmark.metrics.recompute_ms_per_step import own_times
 
-    args = kda_inputs(1)
-
-    fn = jax.jit(both_of(op_of(None if os.environ.get("FORM") == "jax.numpy" else "tpu")))
     jax.block_until_ready(fn(*args))
     os.makedirs("chiprun_out", exist_ok=True)
-    open("chiprun_out/kda_both.hlo", "w").write(fn.lower(*args).compile().as_text())
-    jax.profiler.start_trace("chiprun_out/kda_trace")
-    for _ in range(3):
+    open(f"chiprun_out/kda_{name}.hlo", "w").write(fn.lower(*args).compile().as_text())
+    where = f"chiprun_out/kda_trace_{name}"
+    jax.profiler.start_trace(where)
+    for _ in range(runs):
         out = fn(*args)
     jax.block_until_ready(out)
     jax.profiler.stop_trace()
-    found = [os.path.join(base, f) for base, _, files in os.walk("chiprun_out/kda_trace") for f in files
-             if f.endswith(".xplane.pb")]
+    found = [os.path.join(base, f) for base, _, files in os.walk(where) for f in files if f.endswith(".xplane.pb")]
     data = jax.profiler.ProfileData.from_file(max(found, key=os.path.getmtime))
     spent = {}
     for plane in data.planes:
@@ -218,16 +245,68 @@ def profile():
             if line.name != "XLA Ops":
                 continue
             events = [(e.name, e.start_ns, e.duration_ns) for e in line.events]
-            for name, ns in own_times(events, (min(e[1] for e in events), max(e[1] + e[2] for e in events))):
-                spent[name] = spent.get(name, 0.0) + ns / 3e6
-    json.dump(sorted(spent.items(), key=lambda kv: -kv[1]), open("chiprun_out/kda_profile.json", "w"))
-    kinds = {"kernels": 0.0, "while": 0.0, "fusions": 0.0}
-    for name, ms in spent.items():      # a Pallas kernel's instruction carries the call's name
-        kinds["kernels" if name.startswith("kda_scan") else "while" if name.startswith("while") else "fusions"] += ms
-    say(reading="kda_profile", own_ms_a_run=sum(spent.values()), instructions=len(spent), own_ms_by_kind=kinds,
-        top=sorted(spent.items(), key=lambda kv: -kv[1])[:12])
-    import shutil
-    shutil.rmtree("chiprun_out/kda_trace")
+            for instruction, ns in own_times(events, (min(e[1] for e in events), max(e[1] + e[2] for e in events))):
+                spent[instruction] = spent.get(instruction, 0.0) + ns / (runs * 1e6)
+    shutil.rmtree(where)
+    return spent
+
+
+def profile():
+    """Where the op spends its time as the step runs it: own device time by HLO
+    instruction of the plain forward, of the forward that keeps what backward
+    reads, and of the backward that reads it."""
+    args = kda_inputs(1)
+    kernels = None if os.environ.get("FORM") == "jax.numpy" else "tpu"
+    weigh = cotangent_of(args)
+    keeps = jax.jit(kept_of(kernels))
+    programs = (("forward", jax.jit(op_of(kernels)), args), ("forward_kept", keeps, args),
+                ("backward", jax.jit(backward_of(kernels)), (*args, keeps(*args)[1], weigh)))
+    whole = {}
+    for name, fn, operands in programs:
+        spent = whole[name] = own_ms_by_instruction(name, fn, *operands)
+        kinds = {"kernels": 0.0, "while": 0.0, "fusions": 0.0}
+        for instruction, ms in spent.items():      # a Pallas kernel's instruction carries the call's name
+            kind = "kernels" if instruction.startswith("kda_scan") else "while" if instruction.startswith("while") else "fusions"
+            kinds[kind] += ms
+        say(reading="kda_profile", program=name, own_ms_a_run=sum(spent.values()), instructions=len(spent), own_ms_by_kind=kinds,
+            top=sorted(spent.items(), key=lambda kv: -kv[1])[:8])
+    json.dump({name: sorted(spent.items(), key=lambda kv: -kv[1]) for name, spent in whole.items()},
+              open("chiprun_out/kda_profile.json", "w"))
+
+
+def terms():
+    """What a chunk's term costs where a kernel makes it, so what keeping it from
+    forward could save: the forward that keeps and the backward that reads, as
+    they are and with the term's products cut out of `_Chunks` (other numbers,
+    the same shapes and every other product).  The difference is an UPPER
+    bound of the saving: reading the term back is a DMA more a grid step.  T's
+    chain priced so (1.36 ms a layer of backward) is why forward keeps T since
+    PR 45, and reads no difference in backward since."""
+    args = kda_inputs(1)
+    weigh = cotangent_of(args)
+    kept = jax.jit(kept_of("tpu"))(*args)[1]
+
+    def no_chain(self, As):         # T := I - the 2-blocks of beta M
+        pairs = self.row // 2 == self.col // 2
+        return [self.eye.astype(jnp.float32) - jnp.where(pairs, a, 0.0) for a in As]
+
+    def no_product(self):           # [W | U] := the right-hand side itself
+        return [jnp.concatenate([beta * k * e, beta * v], axis=1)
+                for beta, k, e, v in zip(self.beta, self.k, self.from_start, self.v)]
+
+    def price():
+        jax.clear_caches()          # `_Chunks`' methods are read when a kernel is traced
+        return {"fwd_kept_ms": timed(jax.jit(kept_of("tpu")), *args)[0],
+                "bwd_ms": timed(jax.jit(backward_of("tpu")), *args, kept, weigh)[0]}
+
+    found = {"as_it_is": price()}
+    for term, method, without in (("T", "_unit_lower_inverses", no_chain), ("W|U", "_solved", no_product)):
+        made = getattr(kda_kernels._Chunks, method)
+        setattr(kda_kernels._Chunks, method, without)
+        found["without_the_products_of_" + term] = price()
+        setattr(kda_kernels._Chunks, method, made)
+    found["as_it_is_again"] = price()
+    say(reading="kda_terms_priced", **found)
 
 
 if __name__ == "__main__":
@@ -235,6 +314,8 @@ if __name__ == "__main__":
     only = os.environ.get("ONLY")
     if only == "profile":
         profile()
+    if only == "terms":
+        terms()
     if only in (None, "kda"):
         kda()
     if only in (None, "attention"):
