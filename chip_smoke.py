@@ -9,19 +9,19 @@ One process, through the entry points a user calls (`fluid.Program` ->
 
   trainer        BERT-base (vocab 30522, d_model 768, 12 layers, 12 heads,
                  d_ff 3072, seq 128), bf16, fused attention, Adam, batch 256
-                 and steps=2 per dispatch as bench.py runs it.  Loss finite
-                 and lower at the end, parameters moved, state on the chip,
-                 int64 feeds narrowed without a warning.
+                 and steps=2 per dispatch.  Loss finite and lower at the
+                 end, parameters moved, state on the chip, int64 feeds
+                 narrowed without a warning.
   server         ResNet-50 (bf16, 1000 classes, 224x224) saved with
                  io.save_inference_model and served by serving.Server over
                  buckets (1, 8): sizes that hit and that pad to a bucket
                  agree with a direct Executor run, no compile after warm.
   kernels        the BERT program cut to 2 layers, the same steps with
-                 default flags and with FLAGS_use_pallas (what bench.py
-                 turns on): the loss series agree to the parity tests' bf16
-                 tolerance, and the Mosaic kernels found in each compiled
-                 step are named.  Then every registered fused kernel alone,
-                 compiled, against its composite.
+                 default flags and with FLAGS_use_pallas: the loss series
+                 agree to the parity tests' bf16 tolerance, and the Mosaic
+                 kernels found in each compiled step are named.  Then every
+                 registered fused kernel alone, compiled, against its
+                 composite.
   host callback  a small TPUPlace program with a py_func op in mid-graph:
                  jax.pure_callback works on this runtime.
   --chips 4      BERT-base widths on a dp=2 x tp=2 mesh
@@ -146,8 +146,7 @@ def bert_feed(bert: dict, batch: int, k: int) -> dict:
 
 def start_bert(bert: dict, dropout: float):
     """(main, loss, scope, exe) of a seeded BERT train program after
-    `startup`: bf16 compute on f32 master weights, fused attention, Adam —
-    bench.py's BERT arm (tools/bench_kit.make_bert_dispatch)."""
+    `startup`: bf16 compute on f32 master weights, fused attention, Adam."""
     import paddle_tpu as fluid
     from paddle_tpu.models import transformer
 
@@ -159,6 +158,42 @@ def start_bert(bert: dict, dropout: float):
     exe = fluid.Executor(fluid.TPUPlace(0))
     exe.run(startup, scope=scope)
     return main, fetches["loss"], scope, exe
+
+
+# First-order optimizer accumulators per param ({param}_moment1_0 /
+# _moment_0 / _velocity_0 ...: optimizer.py _add_accumulator naming).
+# _mean_grad_0 LAST: rmsprop only updates it under centered=True, so
+# _momentum_0 is the live accumulator there and must win the tie.
+_MOMENT_SUFFIXES = ("_moment1_0", "_moment_0", "_velocity_0", "_momentum_0",
+                    "_avg_squared_grad_0", "_squared_0", "_mean_grad_0")
+
+
+def _params(main, scope) -> dict:
+    """{param: f8 snapshot} of EVERY trainable parameter, so that a partial
+    optimizer freeze (bf16 + Adam once froze every encoder parameter while
+    the f32 embeddings kept moving) cannot pass by luck of program order."""
+    found = ((p.name, scope.find_var(p.name)) for p in main.all_parameters())
+    snap = {n: np.asarray(v).astype("f8") for n, v in found if v is not None}
+    if not snap:
+        raise RuntimeError("no parameters in scope")
+    return snap
+
+
+def _first_moments(main, scope) -> dict:
+    """{param: f8 snapshot of its first moment}: the tie-breaker when a
+    parameter's snapshot does not move.  A LIVE moment means the optimizer
+    ran and the update rounded away below the parameter's resolution; a dead
+    moment beside a dead parameter is a dropped update, the class
+    tools/donation_audit.py pins statically."""
+    names = set(scope.var_names())
+    snap = {}
+    for p in main.all_parameters():
+        for suffix in _MOMENT_SUFFIXES:
+            if p.name + suffix in names:
+                snap[p.name] = np.asarray(
+                    scope.find_var(p.name + suffix)).astype("f8")
+                break
+    return snap
 
 
 def run_steps(exe, program, feed, loss, scope, k, dispatches):
@@ -186,16 +221,13 @@ def phase_trainer(bert: dict = BERT_BASE, batch: int = 256, k: int = 2,
                   dispatches: int = 4) -> None:
     import jax
 
-    from tools.bench_kit import attach_param_probe
-
-    cut = ("no cut from bench.py's configuration"
+    cut = ("no cut"
            if (bert, batch, k) == (BERT_BASE, 256, 2) else
            "CUT from BERT-base at batch 256, steps=2")
     say(f"trainer: BERT {bert}, bf16, fused attention, Adam, batch {batch}, "
         f"steps={k} per dispatch, {dispatches} dispatches ({cut})")
     main, loss, scope, exe = start_bert(bert, dropout=0.1)
-    probe = attach_param_probe(lambda: None, main, scope)
-    before = probe.probe_param()
+    before = _params(main, scope)
     feed = bert_feed(bert, batch, k)
 
     c0 = compile_seconds()
@@ -220,8 +252,8 @@ def phase_trainer(bert: dict = BERT_BASE, batch: int = 256, k: int = 2,
             f"{losses[0].tolist()}, last {losses[-1].tolist()}")
     say(f"trainer: loss per step {np.round(losses.reshape(-1), 4).tolist()}")
 
-    after = probe.probe_param()
-    moments = probe.probe_moments()
+    after = _params(main, scope)
+    moments = _first_moments(main, scope)
     still = [n for n in before if not np.abs(after[n] - before[n]).max() > 0]
     dead = [n for n in still
             if not np.abs(moments.get(n, np.zeros(1))).max() > 0]

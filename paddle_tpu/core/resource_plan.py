@@ -30,8 +30,8 @@ TPU rebuild needs the numbers OUTSIDE the compiler, before it runs:
     an analytic roofline step time — per op, time = max(flops/peak_flops,
     bytes/hbm_bandwidth); ops ahead of a `backward` count 3x (fwd + 2x
     bwd) — and a `predicted_mfu`: the MFU this program could reach at
-    roofline, the yardstick `perf_report --check-bench` holds measured
-    MFU against.
+    roofline, a yardstick beside the benchmark's measured
+    `model_flops_util` (PERF.md).
 
 Consumers: the executor pre-checks every compile-cache miss and raises
 classified `errors.ResourceError` (phase=build) naming the watermark ops
@@ -77,9 +77,9 @@ __all__ = [
     "precheck_program",
 ]
 
-# Chip model (v5e-class single chip; bench.py's V5E_BF16_PEAK is the same
-# peak).  The roofline is a yardstick, not a simulator: one dense-unit
-# peak, one HBM stream.
+# Chip model (v5e-class single chip; benchmark/peaks.py's "TPU v5 lite" row,
+# held equal by tests/test_resource_plan.py: the library may not import the
+# benchmark).  A yardstick, not a simulator: one dense peak, one HBM stream.
 CHIP_PEAK_FLOPS = 197e12     # bf16 dense peak, FLOP/s
 CHIP_HBM_BANDWIDTH = 819e9   # bytes/s
 CHIP_HBM_BYTES = 16e9        # HBM capacity
@@ -635,7 +635,7 @@ def precheck_program(program: Program, feed_shapes, fetch_names,
     The limit is one the user names, never the one the device reports: the
     plan gives every op output its own buffer (no fusion, no reuse), an
     upper bound.  Held against a v5e's own bytes_limit it refused
-    bench.py's BERT-base batch-256 step — 36.99 GB planned, 15.49 GB of
+    the BERT-base batch-256 step — 36.99 GB planned, 15.49 GB of
     arguments and temporaries as XLA compiled it (PR 21) — and XLA itself
     refuses, at compile time, what does not fit."""
     from ..flags import flag as _flag

@@ -412,7 +412,7 @@ def apply_compile_cache(default_dir: str = "", min_compile_secs: float = 0.0) ->
     JAX_COMPILATION_CACHE_DIR set: jax reads it itself and this code sets
     no directory, whatever FLAGS_compile_cache_dir says — whoever runs the
     program placed the cache.  Unset: FLAGS_compile_cache_dir, else
-    `default_dir` (the entry points chip_smoke.py, bench.py and
+    `default_dir` (the entry points chip_smoke.py and
     tests/conftest.py pass CHECKOUT_CACHE_DIR).  The min-compile-time floor
     drops to `min_compile_secs` so every program signature is cached — the
     framework compiles few, large programs; the test suite compiles

@@ -22,8 +22,8 @@ badly (see docs/performance.md):
     `input_output_aliases` pinning the update in place.
   * `fused_softmax_xent` — hard-label softmax-cross-entropy (max, logsumexp
     and the picked logit in one VMEM pass; backward recomputes the softmax
-    flash-style).  Named by the ISSUE-17 roofline gap ranking
-    (tools/resource_plan.py --gap-rank): the composite is pure HBM traffic.
+    flash-style).  The composite is pure HBM traffic; PERF.md section 5
+    has its measured share of a step (the ledger's `breakdown`).
   * `fused_bias_act` — y = act(x + bias[D]) for relu/gelu, the FFN bias
     epilogue (core/passes.py fuse_bias_act folds the add->act pair); the
     composite's intermediate never round-trips through HBM.

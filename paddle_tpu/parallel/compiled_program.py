@@ -93,7 +93,7 @@ class CompiledProgram:
         boundary is the final (smallest) bucket.
 
         mode="serial" keeps ONE flat all-reduce after the whole backward —
-        the A/B baseline `bench.py --overlap` compares against; both modes
+        the baseline tests/test_grad_overlap.py compares with; both modes
         are element-wise identical (bucketing never changes what each grad
         element is summed with), so final params stay bit-identical.
 

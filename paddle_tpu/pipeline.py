@@ -50,7 +50,7 @@ from .monitor import MONITOR as _MON
 @dataclass
 class PipelineStats:
     """What `train_loop` hands back: per-logged-step fetch values plus the
-    overlap accounting bench.py / perf tooling report."""
+    overlap accounting tools/perf_report.py's gates read."""
 
     steps: int = 0
     logged: List[Tuple[int, List[np.ndarray]]] = field(default_factory=list)

@@ -7,8 +7,8 @@
     immediately with `ServingError(reason="overload")`: under sustained
     overload the queue depth (and therefore queueing latency) stays
     constant and the overflow is an explicit, counted signal
-    (`serving.shed`) instead of an unbounded latency ramp.  The
-    `bench.py --serve` overload arm proves p99 stays bounded this way.
+    (`serving.shed`) instead of an unbounded latency ramp.  The burst in
+    tests/test_serving.py's served stream holds p99 bounded this way.
 
   * per-request deadlines — `submit(deadline_ms=...)` (default
     FLAGS_serving_default_deadline_ms; 0 = none).  A request still
@@ -189,7 +189,7 @@ class Server:
         self._qwin: collections.deque = collections.deque(maxlen=4096)
         # per-bucket attribution ledger: bucket -> batches/requests/rows/
         # pad_rows/queue_s/total_s/infer_s (exact, unconditional; the
-        # pad_frac gauges and bench.py's bucket_attribution read it)
+        # pad_frac gauges and bucket_attribution() read it)
         self._bucket_attr: Dict[int, dict] = {}
         # gauges close over a WEAK ref (the global monitor must not keep a
         # dead server — queue, latency window, registry — alive forever)
@@ -637,7 +637,7 @@ class Server:
     def queue_wait_frac(self) -> float:
         """Lifetime queue-wait fraction: of all the wall time completed
         requests spent in the server, the share spent QUEUED (the
-        gauge's sliding-window cousin; bench.py embeds this one)."""
+        gauge's sliding-window cousin; stats() reports this one)."""
         with self._cv:
             q = sum(a["queue_s"] for a in self._bucket_attr.values())
             t = sum(a["total_s"] for a in self._bucket_attr.values())
@@ -646,8 +646,8 @@ class Server:
     def bucket_attribution(self) -> Dict[int, dict]:
         """Per-bucket latency/pad attribution from the exact server-local
         ledger: where each bucket's wall time went (queued vs on device)
-        and how much of its compute was pad waste.  The `bench.py
-        --serve` record embeds this."""
+        and how much of its compute was pad waste.  The gates' pad and
+        queue-wait fractions are these, summed over buckets."""
         with self._cv:
             attr = {b: dict(a) for b, a in self._bucket_attr.items()}
         out = {}

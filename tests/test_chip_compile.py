@@ -93,7 +93,7 @@ def _gmm(rows, weights, sizes):
     return grouped_matmul(rows, weights, sizes, "tpu")
 
 
-# BERT-base: batch 256 x seq 128 rows (bench.py's batch), d_model 768,
+# BERT-base: batch 256 x seq 128 rows (the `pretrain-s128` cell's), d_model 768,
 # d_ff 3072, vocab 30522, 12 heads of 64.  ResNet-50: NCHW bf16, batch 128
 # training and the serving buckets' batch 8.
 _ROWS = 256 * 128

@@ -1,8 +1,7 @@
 """Backward-overlapped dp gradient all-reduce (ISSUE 7):
 `parallel.distributed.make_grad_sync` bucketing + the
 `CompiledProgram.with_grad_overlap` end-to-end path on the virtual CPU
-mesh.  The real 2-process A/B lives in `bench.py --overlap`
-(tests/dist_worker_overlap.py); the micro A/B in
+mesh.  The micro A/B of the two modes is
 tools/collective_bench.py --overlap."""
 import numpy as np
 import pytest

@@ -348,3 +348,21 @@ def test_perf_report_render_and_check(tmp_path):
 
     r = _run_perf_report("--check", str(tmp_path / "missing.jsonl"))
     assert r.returncode == 1
+
+
+def test_perf_report_reads_streams_and_no_bench_round():
+    """The tool gates a run's metrics stream and nothing else: speed is the
+    benchmark's to state (`python3 -m benchmark.run`, PERF.md, the ledger),
+    so a bench-round gate is refused, and every gate that
+    tests/test_gate_zero_evidence.py audits is an option of the CLI."""
+    from test_gate_zero_evidence import GATES
+
+    r = _run_perf_report("--check-bench", "round.json")
+    assert r.returncode == 2 and "unrecognized arguments" in r.stderr
+    r = _run_perf_report("--help")
+    assert r.returncode == 0, r.stderr
+    for name, *_ in GATES:
+        assert "--" + name.replace("_", "-") in r.stdout, name
+    for gone in ("--max-spread-pct", "--min-roofline-frac",
+                 "--require-overlap"):
+        assert gone not in r.stdout, gone

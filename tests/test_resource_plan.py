@@ -1,5 +1,5 @@
 """Static resource planner (paddle_tpu/core/resource_plan.py): liveness
-peak-HBM + op cost model, and its four consumers.
+peak-HBM + op cost model, and its three consumers.
 
 Acceptance contract (ISSUE 12):
   * planted-defect tests per planner class — leaked live range,
@@ -405,7 +405,7 @@ def test_unbudgeted_load_is_counted_and_evented(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# consumers 3+4: CLI gate (tier-1 wiring) + bench roofline column
+# consumer 3: CLI gate (tier-1 wiring)
 # --------------------------------------------------------------------------
 
 def _run_cli(*args, timeout=780):
@@ -439,103 +439,33 @@ def test_cli_coverage_gate_trips_when_floor_unreachable():
     assert "coverage" in r.stdout
 
 
-def test_cli_bench_zero_evidence_fails(tmp_path):
-    """The PR-8/PR-10 gate-hardening precedent: a BENCH file with no model
-    records must FAIL the roofline comparison, not gate green."""
-    p = tmp_path / "empty_bench.json"
-    p.write_text(json.dumps({"metric": "nothing_useful", "value": 1}))
-    r = _run_cli("--bench", str(p), timeout=120)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "zero evidence" in r.stdout
-
-
-def test_cli_bench_renders_predicted_vs_measured(tmp_path):
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({
-        "metric": "resnet50_train_imgs_per_sec_per_chip", "value": 2704.0,
-        "mfu_bf16_analytic": 0.168, "mfu_predicted_roofline": 0.196,
-        "extra": {"models": {"bert": {"metric": "bert_...",
-                                      "mfu_bf16_analytic": 0.402,
-                                      "mfu_predicted_roofline": 0.368}}}}))
-    r = _run_cli("--bench", str(p), timeout=120)
+def test_cli_json_is_one_document_of_plans_and_coverage():
+    """`--json` is what another tool reads: one JSON document on stdout, a
+    plan a program and the coverage of the cost rules; and the CLI plans
+    programs, it reads no bench round and ranks nothing."""
+    r = _run_cli("--json", "--program", "mnist", timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "achieved_frac" in r.stdout and "0.86" in r.stdout
+    doc = json.loads(r.stdout)
+    assert sorted(doc) == ["_coverage", "mnist"]
+    plan = doc["mnist"]["plan"]
+    assert plan["peak_bytes"] >= plan["persistable_bytes"] > 0
+    assert plan["roofline_step_s"] > 0 and plan["watermark"]
+    assert doc["_coverage"]["frac"] == 1.0 and not doc["_coverage"]["missing_types"]
+    for gone in ("--gap-rank", "--bench"):
+        r = _run_cli(gone, "x.json", timeout=120)
+        assert r.returncode == 2 and "unrecognized arguments" in r.stderr, gone
 
 
-def test_cli_gap_rank_check_tiny_zoo():
-    """ISSUE 17 tier-1 wiring: the gap ranking renders over the whole
-    zoo with every cost row covered by a real FLOPs/traffic rule — an
-    uncovered row (default 1-flop/elem model) would poison the ranking
-    the kernel campaign walks, so --check fails on any."""
-    r = _run_cli("--gap-rank", "--check")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "CHECK OK" in r.stdout and "zero uncovered" in r.stdout
-    # the campaign's own top targets from GAP_RANK.md stay in the table
-    assert "matmul" in r.stdout and "op_type" in r.stdout
+def test_the_planner_and_the_benchmark_hold_one_chip_model():
+    """The library may not import the benchmark, so the planner keeps a
+    second copy of the chip's peaks; this holds it to the benchmark's row,
+    so that a roofline and a `model_flops_util` are shares of one chip."""
+    from benchmark.peaks import PEAKS
 
-
-def test_cli_gap_rank_scales_by_bench_and_writes_artifact(tmp_path):
-    """--bench supplies the measured side: op times scale by each model's
-    predicted/measured MFU ratio, the scaling is disclosed in the render,
-    and --out writes the committed artifact."""
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({
-        "metric": "resnet50_train_imgs_per_sec_per_chip", "value": 2704.0,
-        "mfu_bf16_analytic": 0.168, "mfu_predicted_roofline": 0.196}))
-    out = tmp_path / "gap_rank.md"
-    r = _run_cli("--gap-rank", "--program", "resnet50", "--bench", str(p),
-                 "--out", str(out), timeout=300)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "time scaling (predicted/measured MFU)" in r.stdout
-    assert "resnet50=" in r.stdout
-    text = out.read_text()
-    assert text.startswith("# roofline gap ranking")
-    assert "scaled by bench.json" in text
-
-
-def test_cli_gap_rank_zero_rows_fails(tmp_path):
-    """Zero-evidence precedent: a ranking rendered from zero cost rows
-    (nothing planned) must FAIL --check, not gate green."""
-    r = _run_cli("--gap-rank", "--check", "--program", "no_such_model",
-                 timeout=120)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "zero cost rows" in r.stdout
-
-
-def test_cli_gap_rank_bench_without_mfu_warns_unscaled(tmp_path):
-    """A bench file with no usable measured MFU must not silently render
-    as if it were evidence-scaled."""
-    p = tmp_path / "no_mfu.json"
-    p.write_text(json.dumps({"metric": "x", "value": 1.0}))
-    r = _run_cli("--gap-rank", "--program", "mnist", "--bench", str(p),
-                 timeout=300)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "no usable measured MFU" in r.stdout
-
-
-def test_perf_report_check_bench_names_roofline_gap(tmp_path):
-    """perf_report --check-bench prints the predicted-MFU column and
-    --min-roofline-frac turns a deep gap into a hard failure."""
-    rec = {"metric": "resnet50_train_imgs_per_sec_per_chip", "value": 2704.0,
-           "mfu_bf16_analytic": 0.169, "mfu_predicted_roofline": 0.9,
-           "windows_ms": [10.0, 10.1], "spread_pct": 1.0,
-           "extra": {"models": {"bert": {
-               "metric": "bert_base_train_seqs_per_sec_per_chip",
-               "mfu_bf16_analytic": 0.41, "spread_pct": 1.0}}}}
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps(rec))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    base = [sys.executable, os.path.join(REPO, "tools", "perf_report.py"),
-            "--check-bench", str(p)]
-    r = subprocess.run(base, capture_output=True, text=True, env=env,
-                       cwd=REPO, timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "vs static roofline 0.9" in r.stdout
-    r2 = subprocess.run(base + ["--min-roofline-frac", "0.5"],
-                        capture_output=True, text=True, env=env, cwd=REPO,
-                        timeout=120)
-    assert r2.returncode == 1, r2.stdout + r2.stderr
-    assert "static roofline" in r2.stdout
+    row = PEAKS["TPU v5 lite"]
+    assert rp.CHIP_PEAK_FLOPS == row["bf16_flops_per_s"]
+    assert rp.CHIP_HBM_BANDWIDTH == row["hbm_bytes_per_s"]
+    assert rp.CHIP_HBM_BYTES == row["hbm_bytes"]
 
 
 # --------------------------------------------------------------------------

@@ -14,8 +14,8 @@
         exists, contains step records, and that the recompile count stayed
         FLAT across steady-state steps (index >= N, default 2).  A rising
         recompile count in steady state is the compile-cache-thrash
-        signature behind NMT-style run-to-run variance (BENCH r5: 26.3%
-        spread); exit 1 names the offending steps.
+        signature behind NMT-style run-to-run variance; exit 1 names the
+        offending steps.
 
     python tools/perf_report.py --check metrics.jsonl --max-host-blocked-frac 0.5
         Additionally gate the pipelined loop's steady-state host-blocked
@@ -82,14 +82,15 @@
     python tools/perf_report.py --check metrics.jsonl --max-p99-ms 50
         Gate the serving tail: p99 request latency from the newest
         snapshot's serving.p99_ms gauge (lat_ms_max over serving_batch
-        records as fallback).  The SLO number the overload arm of
-        `bench.py --serve` must hold WITH shedding active — bounded-queue
-        admission is what keeps it flat while load climbs.
+        records as fallback).  The SLO number a server over capacity
+        must hold WITH shedding active — bounded-queue admission is what
+        keeps it flat while load climbs.
 
     python tools/perf_report.py --check metrics.jsonl --require-quant-parity
-        Gate a quantized-serving round (bench.py --serve --quant): the
-        file must carry at least one `quant_parity` serving_event — the
-        publish ladder's accuracy gate over a quantized snapshot
+        Gate a quantized-serving run (a save_quantized_inference_model
+        directory published through serving.publish): the file must
+        carry at least one `quant_parity` serving_event — the publish
+        ladder's accuracy gate over a quantized snapshot
         (FLAGS_serving_quant_atol vs the fp32 parent's outputs) — with
         max_abs_diff within its recorded atol, and no quant-parity
         publish rejection.  A file with no quant evidence FAILS (zero
@@ -125,20 +126,6 @@
         failure's minimal repro lives in the campaign's
         CHAOS_REPRO.json.  A file with no chaos evidence at all FAILS
         the gate — zero evidence must not gate green.
-
-    python tools/perf_report.py --check-bench BENCH_rNN.json
-        Ratcheted bench-round gate (ISSUE 7): analytic MFU must clear the
-        MFU_FLOORS landed with the last accepted round (resnet50's floor
-        is EXCLUSIVE — a new round must beat it, not tie it), window
-        spread must sit under MAX_SPREAD_PCT per model (the NMT warm-in
-        fix makes that honest), no model may report a genuinely frozen
-        param (dead optimizer state — the donation-drop class
-        tools/donation_audit.py pins statically), and an embedded overlap
-        A/B record must confirm the bucketed all-reduce beats serial at
-        bit parity.  Accepts a raw bench.py JSON line or the round
-        wrapper ({"tail": ...}).  When a round ratchets a floor, edit
-        MFU_FLOORS in the same PR — that is the "never regress silently"
-        contract.
 """
 from __future__ import annotations
 
@@ -1208,9 +1195,9 @@ def check(path: str, steady_after: int = 2,
             failures.append(
                 f"--require-quant-parity given but {path} carries no "
                 f"quant_parity serving_event — no quantized snapshot "
-                f"went through the publish ladder's parity gate (was "
-                f"`bench.py --serve --quant` the producer, with the "
-                f"monitor enabled?); zero evidence must not gate green")
+                f"went through the publish ladder's parity gate (did "
+                f"the run quantize a model, with the monitor "
+                f"enabled?); zero evidence must not gate green")
         else:
             worst = max(float(r.get("max_abs_diff", 0.0) or 0.0)
                         for r in qevs)
@@ -1462,252 +1449,6 @@ def check(path: str, steady_after: int = 2,
     return 0
 
 
-# Ratcheted analytic-MFU floors (ISSUE 7).  Set from the r5 chip record — resnet50's
-# is EXCLUSIVE (the MFU campaign must land strictly above the level it set
-# out to beat), bert's INCLUSIVE (hold the r05 line).  Each accepted bench
-# round that clears a floor by a margin ratchets it here, in the same PR,
-# so MFU can never regress silently.
-MFU_FLOORS = {
-    "resnet50": {"floor": 0.168, "strict": True},
-    "bert": {"floor": 0.402, "strict": False},
-}
-# Per-model window-spread ceiling: above this the round's numbers are noise
-# (the r5 chip record's NMT entry hit 26.3% from warm-in; tools/bench_kit.py
-# timed_steps(spread_target=...) now extends warmup until stable).
-MAX_SPREAD_PCT = 5.0
-# Ceiling on the per-step cross-rank skew a multi-process bench round may
-# embed (bench.py gangs compute it from worker telemetry via
-# tools/trace_merge.py): mean arrival skew above one full mean step time
-# means a rank spent every step waiting for a straggler — the round's
-# gang numbers measure the straggler, not the framework.
-MAX_BENCH_STEP_SKEW_FRAC = 1.0
-
-
-def _bench_records(path):
-    """{model: record} from a bench.py JSON line or a BENCH_rNN.json round
-    wrapper ({"tail": "...last line is the record..."})."""
-    with open(path) as f:
-        doc = json.load(f)
-    if "tail" in doc and "metric" not in doc:
-        rec = None
-        for line in doc["tail"].splitlines():
-            line = line.strip()
-            if line.startswith("{"):
-                try:
-                    cand = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if "metric" in cand:
-                    rec = cand
-        if rec is None:
-            raise ValueError(f"{path}: no bench JSON line in 'tail'")
-        doc = rec
-    out = {}
-    extra = doc.get("extra", {})
-    if doc.get("metric", "").startswith("resnet50"):
-        out["resnet50"] = {**doc, **{k: v for k, v in extra.items()
-                                     if k != "models"}}
-    elif "metric" in doc:
-        # a non-resnet50 anchor (e.g. a serving round's quant A/B) keys
-        # itself in alongside any records riding its extra.models
-        out[doc["metric"].split("_")[0]] = doc
-    for name, rec in extra.get("models", {}).items():
-        out[name] = rec
-    return out
-
-
-def check_bench(path, floors=None, max_spread_pct=None,
-                require_overlap=False, min_roofline_frac=None) -> int:
-    """Ratcheted bench-round gate: MFU floors, spread ceiling, zero frozen
-    params, overlap A/B confirmation, and the predicted-MFU column — every
-    record carrying the program's own static roofline prediction
-    (mfu_predicted_roofline, stamped by bench.py from
-    core/resource_plan.py) is printed as measured-vs-predicted so a
-    measured MFU far under the program's roofline is NAMED, not averaged
-    away; `min_roofline_frac` turns that naming into a hard gate.
-    0 healthy / 1 failed, diagnosis printed either way.  `require_overlap`
-    fails rounds that do not embed a dp_grad_overlap record (fresh-round
-    acceptance; historical rounds predate the overlap path and check
-    without it).
-
-    A serving-only round (every record metric starts with "serving", e.g.
-    BENCH_r06) skips the training MFU floors with a loud NOTE; the
-    measured-vs-predicted roofline line, the off-device honesty contract
-    (`throughput_claim`), and the quant parity ledger still gate it —
-    a dirty ledger or a quant A/B whose publish ladder never recorded
-    its `quant_parity` event FAILS."""
-    floors = MFU_FLOORS if floors is None else floors
-    max_spread = MAX_SPREAD_PCT if max_spread_pct is None else max_spread_pct
-    try:
-        recs = _bench_records(path)
-    except (FileNotFoundError, ValueError, json.JSONDecodeError) as e:
-        print(f"perf_report --check-bench: cannot read {path}: {e}")
-        return 1
-    if not recs:
-        print(f"perf_report --check-bench: no model records in {path}")
-        return 1
-    failures = []
-    # a serving round carries no training records for the floors to hold
-    # against — skipping them silently would look like a green training
-    # gate, so say it; the serving-specific gates below still apply
-    serving_only = all(
-        isinstance(r, dict)
-        and str(r.get("metric", "")).startswith("serving")
-        for r in recs.values())
-    if serving_only:
-        print("perf_report --check-bench: serving-only round — training "
-              "MFU floors skipped (roofline line, throughput-claim "
-              "honesty, and the quant parity ledger still gate it)")
-    for model, gate in ([] if serving_only else floors.items()):
-        rec = recs.get(model)
-        if rec is None or "error" in rec:
-            failures.append(f"{model}: no bench record to hold its MFU "
-                            f"floor against (errored or missing)")
-            continue
-        mfu = rec.get("mfu_bf16_analytic")
-        if mfu is None:
-            failures.append(f"{model}: record carries no "
-                            f"mfu_bf16_analytic")
-            continue
-        ok = mfu > gate["floor"] if gate["strict"] else mfu >= gate["floor"]
-        cmp = ">" if gate["strict"] else ">="
-        if not ok:
-            failures.append(
-                f"{model}: analytic MFU {mfu} fails the ratcheted floor "
-                f"(needs {cmp} {gate['floor']}) — a kernel/donation/"
-                f"overlap regression landed; bisect with tools/opbench.py "
-                f"--fused and tools/donation_audit.py --check")
-        else:
-            print(f"perf_report --check-bench: {model} MFU {mfu} {cmp} "
-                  f"floor {gate['floor']}")
-    for model, rec in sorted(recs.items()):
-        if not isinstance(rec, dict) or "error" in rec:
-            continue
-        # predicted-MFU column: the program's own static roofline
-        # (core/resource_plan.py) is the denominator that makes a low
-        # measured MFU attributable — "leaving 3x on the table" vs "this
-        # program is bandwidth-bound and 0.2 IS its roofline"
-        mfu = rec.get("mfu_bf16_analytic")
-        pred = rec.get("mfu_predicted_roofline")
-        if mfu is not None and pred:
-            frac = mfu / pred
-            print(f"perf_report --check-bench: {model} measured MFU {mfu} "
-                  f"vs static roofline {pred} ({frac:.2f}x of predicted)")
-            if min_roofline_frac is not None and frac < min_roofline_frac:
-                failures.append(
-                    f"{model}: measured MFU {mfu} is only {frac:.2f}x of "
-                    f"the program's own static roofline {pred} (floor "
-                    f"{min_roofline_frac}) — the gap is in the compiled "
-                    f"step (fusion/layout/overlap), not the hardware; "
-                    f"tools/resource_plan.py --bench names the per-model "
-                    f"gaps")
-            elif frac < 0.1:
-                print(f"perf_report --check-bench: NOTE: {model} runs at "
-                      f"{frac:.2f}x of its own static roofline — large "
-                      f"compiled-step factors on the table")
-        elif mfu is not None and min_roofline_frac is not None:
-            # gating on a ratio no record carries would be a green gate
-            # with no data (the PR-8/PR-10 class) — fail, don't skip
-            failures.append(
-                f"{model}: --min-roofline-frac set but the record carries "
-                f"no mfu_predicted_roofline to hold measured MFU against "
-                f"(bench.py stamps it; its roofline prediction failed or "
-                f"the round predates it)")
-        spread = rec.get("spread_pct")
-        if spread is not None and spread > max_spread:
-            failures.append(
-                f"{model}: window spread {spread}% exceeds "
-                f"{max_spread}% — the round's numbers are noise; rerun "
-                f"with timed_steps(spread_target=...) warm-until-stable")
-        pm = rec.get("params_moved")
-        if pm and "subresolution" in pm and pm.get("frozen", 0):
-            failures.append(
-                f"{model}: {pm['frozen']} param(s) with DEAD optimizer "
-                f"state (dropped-update class) — run tools/"
-                f"donation_audit.py --program {model}")
-        sk = rec.get("step_skew_frac")
-        if sk is not None and sk > MAX_BENCH_STEP_SKEW_FRAC:
-            failures.append(
-                f"{model}: embedded gang skew record reports mean "
-                f"per-step cross-rank skew {sk} > "
-                f"{MAX_BENCH_STEP_SKEW_FRAC} (straggler rank "
-                f"{rec.get('straggler_rank')}) — the round's gang "
-                f"numbers measure a straggler, not the framework; rerun "
-                f"on healthy workers (tools/trace_merge.py names the "
-                f"offender)")
-        elif sk is not None:
-            print(f"perf_report --check-bench: {model} gang skew frac "
-                  f"{sk} <= {MAX_BENCH_STEP_SKEW_FRAC}")
-        if rec.get("throughput_claim") == "parity_only_off_device":
-            print(f"perf_report --check-bench: NOTE: {model} ran "
-                  f"off-device (device={rec.get('device')}) — parity "
-                  f"evidence only; no throughput or MFU floor may "
-                  f"ratchet from this record")
-        par = rec.get("parity")
-        if isinstance(par, dict) and "within_atol" in par:
-            # a quant A/B is a speedup claim with no accuracy evidence
-            # unless both halves of its ledger hold: the publish ladder's
-            # own gate event ran, and the recorded drift sits inside atol
-            if not par.get("gate_event_recorded", True):
-                failures.append(
-                    f"{model}: quant A/B but the publish ladder recorded "
-                    f"no quant_parity event — the accuracy gate never ran "
-                    f"on this snapshot (FLAGS_serving_quant_atol=0 "
-                    f"disables it); an ungated quant round cannot land")
-            if not par["within_atol"]:
-                failures.append(
-                    f"{model}: quant parity ledger DIRTY — max|diff| "
-                    f"{par.get('max_abs_diff')} past atol "
-                    f"{par.get('atol')}; the quantized snapshot drifted "
-                    f"from its fp32 parent and the A/B's throughput is "
-                    f"not evidence")
-            elif par.get("gate_event_recorded", True):
-                print(f"perf_report --check-bench: {model} quant parity "
-                      f"ledger clean (max|diff| "
-                      f"{par.get('max_abs_diff'):.2e} <= atol "
-                      f"{par.get('atol'):g}, gate event recorded)")
-    ov = next((r for r in recs.values() if isinstance(r, dict)
-               and r.get("metric", "").startswith("dp_grad_overlap")), None)
-    if ov is None:
-        # a silent skip here would let an overlap regression through on any
-        # round assembled without `bench.py --overlap`'s record — say so
-        msg = ("no dp_grad_overlap record embedded — overlap gates "
-               "skipped; embed the `bench.py --overlap` record under "
-               "extra.models to hold the round to them")
-        if require_overlap:
-            failures.append(msg)
-        else:
-            print(f"perf_report --check-bench: NOTE: {msg}")
-    if ov is not None:
-        if not ov.get("overlap_confirmed"):
-            # off-device (CPU gloo) records are parity evidence only —
-            # overlap_confirmed stays false there by design, so an
-            # unconfirmed record fails the gate only under
-            # --require-overlap; without it the parity checks below still
-            # hold the record and the gap is said out loud
-            msg = (
-                f"overlap A/B: bucketed all-reduce did not beat serial "
-                f"({ov.get('speedup_vs_serial')}x) — either the backward "
-                f"overlap regressed or the record is from an off-device "
-                f"round (parity evidence only); a device round must "
-                f"confirm overlap")
-            if require_overlap:
-                failures.append(msg)
-            else:
-                print(f"perf_report --check-bench: NOTE: {msg}")
-        if not ov.get("bit_parity_serial_vs_bucketed", True):
-            failures.append("overlap A/B: serial and bucketed arms ended "
-                            "with different params — bucketing changed "
-                            "numerics, which it must never do")
-    if failures:
-        for f_ in failures:
-            print(f"perf_report --check-bench: {f_}")
-        return 1
-    print(f"perf_report --check-bench: OK — {sorted(recs)} hold the "
-          f"ratcheted floors")
-    return 0
-
-
 def postmortem(root: str, last_n: int = 30) -> int:
     """Render a merged post-mortem from a gang's harvested telemetry
     (`perf_report --postmortem <telemetry_root>`): every rank's
@@ -1829,23 +1570,6 @@ def main(argv=None):
     ap.add_argument("--postmortem-last-n", type=int, default=30,
                     metavar="N",
                     help="--postmortem: merged-timeline depth (default 30)")
-    ap.add_argument("--check-bench", metavar="BENCH_JSON",
-                    help="ratcheted bench-round gate (MFU_FLOORS, spread "
-                         "ceiling, zero frozen params, overlap A/B) over a "
-                         "bench.py JSON line or BENCH_rNN.json wrapper")
-    ap.add_argument("--max-spread-pct", type=float, default=None,
-                    metavar="PCT",
-                    help="--check-bench: override the per-model window-"
-                         f"spread ceiling (default {MAX_SPREAD_PCT})")
-    ap.add_argument("--min-roofline-frac", type=float, default=None,
-                    help="--check-bench: fail any model whose measured MFU "
-                         "is below this fraction of its own static roofline "
-                         "prediction (mfu_predicted_roofline, stamped by "
-                         "bench.py from core/resource_plan.py); without it "
-                         "the gap is printed/NOTEd, never averaged away")
-    ap.add_argument("--require-overlap", action="store_true",
-                    help="--check-bench: fail rounds that do not embed a "
-                         "dp_grad_overlap record (fresh-round acceptance)")
     ap.add_argument("--steady-after", type=int, default=2,
                     help="steps to skip before the recompile-flat gate "
                          "(default 2: startup + first real step)")
@@ -1908,8 +1632,8 @@ def main(argv=None):
                          "ladder's accuracy gate over a quantized "
                          "snapshot, paddle_tpu/serving/publisher.py) "
                          "with max_abs_diff within its atol, and no "
-                         "quant-parity publish rejection — the "
-                         "`bench.py --serve --quant` round's metrics "
+                         "quant-parity publish rejection — a "
+                         "quantized-serving run's metrics "
                          "gate.  Fails on a file with no quant evidence "
                          "at all (zero evidence must not gate green)")
     ap.add_argument("--max-lock-wait-frac", type=float, default=None,
@@ -2020,11 +1744,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.postmortem:
         return postmortem(args.postmortem, last_n=args.postmortem_last_n)
-    if args.check_bench:
-        return check_bench(args.check_bench,
-                           min_roofline_frac=args.min_roofline_frac,
-                           max_spread_pct=args.max_spread_pct,
-                           require_overlap=args.require_overlap)
     if args.check:
         return check(args.check, args.steady_after,
                      args.max_host_blocked_frac, args.max_retry_frac,

@@ -23,14 +23,7 @@
             CALIBRATION_RATIO_HI] on any zoo program (the stated-tolerance
             contract from docs/static_analysis.md — also a ratchet).
 
-    python tools/resource_plan.py --bench BENCH_rNN.json
-        Predicted-vs-measured roofline: for every model record carrying
-        mfu_bf16_analytic, print measured MFU next to the program's own
-        static roofline prediction and the fraction achieved.  A BENCH
-        file with NO model records fails loudly (zero-evidence files must
-        not gate green — the PR-8/PR-10 hardening precedent).
-
-Exit codes: 0 clean, 1 gate failure / zero evidence.
+Exit codes: 0 clean, 1 gate failure.
 """
 from __future__ import annotations
 
@@ -187,148 +180,6 @@ def render(tiny=True, only=None, calibrate=False):
     return "\n".join(parts), results
 
 
-def _bench_measured_mfu(bench_path):
-    """{model: measured mfu_bf16_analytic} from a BENCH round file — the
-    measured side of the gap ranking's time scaling.  Per-op timers don't
-    exist off-device, so each program's static per-op roofline is scaled
-    by the program-level measured/predicted ratio instead; that keeps the
-    ranking evidence-based without pretending to per-op truth."""
-    from tools.perf_report import _bench_records
-
-    measured = {}
-    try:
-        recs = _bench_records(bench_path)
-    except (FileNotFoundError, ValueError, json.JSONDecodeError) as e:
-        print(f"resource_plan --gap-rank: cannot read {bench_path}: {e}",
-              file=sys.stderr)
-        return {}
-    for model, rec in recs.items():
-        if isinstance(rec, dict) and rec.get("mfu_bf16_analytic"):
-            measured[model] = rec["mfu_bf16_analytic"]
-    return measured
-
-
-def gap_rank(tiny=True, only=None, bench=None):
-    """(text, data) — rank op types by roofline-gap x estimated time over
-    the zoo.  Per op row the roofline time is max(t_flops, t_traffic)
-    (core/resource_plan.py's own formula); the GAP is the traffic-bound
-    fraction 1 - t_flops/t_roof — the share of the op's time the compute
-    units sit idle waiting on HBM, exactly what kernel fusion and narrower
-    dtypes recover (Williams et al.).  Estimated time scales each
-    program's roofline by its measured/predicted MFU ratio when a --bench
-    round supplies one.  data: {"ranking": [...], "uncovered_rows": N,
-    "total_rows": N, "bench": path|None, "programs": [...]}"""
-    from paddle_tpu.core import resource_plan as rp
-
-    measured = _bench_measured_mfu(bench) if bench else {}
-    plans = zoo_plans(tiny=tiny, only=only)
-    agg = {}          # op_type -> aggregate dict
-    scales = {}       # model -> predicted/measured MFU ratio actually used
-    uncovered = 0
-    total_rows = 0
-    for name, _, plan in plans:
-        # measured step time = roofline time * (predicted / measured MFU);
-        # the prediction is the plan's own (same formula as the per-op rows)
-        scale = 1.0
-        if measured.get(name) and plan.predicted_mfu:
-            scale = plan.predicted_mfu / measured[name]
-            scales[name] = round(scale, 4)
-        for r in plan.rows:
-            total_rows += 1
-            t_flops = r.flops * r.grad_factor / rp.CHIP_PEAK_FLOPS
-            t_traffic = (r.traffic_bytes * r.grad_factor
-                         / rp.CHIP_HBM_BANDWIDTH)
-            t_roof = max(t_flops, t_traffic)
-            gap_frac = (1.0 - t_flops / t_roof) if t_roof > 0 else 0.0
-            t_est = t_roof * scale
-            a = agg.setdefault(r.op_type, {
-                "op_type": r.op_type, "count": 0, "time_s": 0.0,
-                "gap_time_s": 0.0, "uncovered": 0, "programs": set()})
-            a["count"] += 1
-            a["time_s"] += t_est
-            a["gap_time_s"] += gap_frac * t_est
-            a["programs"].add(name)
-            if not r.cost_covered:
-                a["uncovered"] += 1
-                uncovered += 1
-    ranking = sorted(agg.values(), key=lambda a: -a["gap_time_s"])
-    total_time = sum(a["time_s"] for a in ranking) or 1.0
-    rows = []
-    for a in ranking:
-        a["programs"] = sorted(a["programs"])
-        a["gap_frac"] = a["gap_time_s"] / a["time_s"] if a["time_s"] else 0.0
-        a["time_share"] = a["time_s"] / total_time
-        rows.append((a["op_type"], a["count"],
-                     f"{a['gap_time_s'] * 1e6:.1f}",
-                     f"{a['gap_frac']:.2f}",
-                     f"{a['time_share']:.3f}",
-                     ",".join(a["programs"]),
-                     a["uncovered"] or ""))
-    parts = ["# roofline gap ranking  (zoo, %s configs%s)"
-             % ("tiny" if tiny else "full",
-                f", scaled by {os.path.basename(bench)}" if bench else
-                ", unscaled roofline"),
-             "",
-             "score = traffic-bound fraction x estimated op time, summed "
-             "over every zoo step.",
-             "The top of this table is where the next fused kernel or "
-             "narrower dtype pays.",
-             "",
-             _fmt_table(rows, ["op_type", "rows", "gap_us", "gap_frac",
-                               "time_share", "programs", "uncov"])]
-    if scales:
-        parts.append("\ntime scaling (predicted/measured MFU): "
-                     + ", ".join(f"{m}={s:.2f}"
-                                 for m, s in sorted(scales.items())))
-    elif bench:
-        parts.append("\nWARNING: --bench file supplied but carried no "
-                     "usable measured MFU — ranking is unscaled roofline "
-                     "only")
-    data = {"ranking": [{k: v for k, v in a.items()} for a in ranking],
-            "uncovered_rows": uncovered, "total_rows": total_rows,
-            "bench": bench,
-            "bench_scales": scales,
-            "programs": sorted({n for n, _, _ in plans})}
-    return "\n".join(parts), data
-
-
-def check_bench(path) -> int:
-    """Predicted-vs-measured roofline over a BENCH round file.  Uses
-    perf_report's record reader; a file with zero model records FAILS
-    (zero evidence must not gate green)."""
-    from tools.perf_report import _bench_records
-
-    try:
-        recs = _bench_records(path)
-    except (FileNotFoundError, ValueError, json.JSONDecodeError) as e:
-        print(f"resource_plan --bench: cannot read {path}: {e}")
-        return 1
-    rows = []
-    for model, rec in sorted(recs.items()):
-        if not isinstance(rec, dict):
-            continue
-        mfu = rec.get("mfu_bf16_analytic")
-        pred = rec.get("mfu_predicted_roofline")
-        if mfu is None:
-            continue
-        frac = (mfu / pred) if pred else None
-        rows.append((model, mfu, pred if pred is not None else "-",
-                     f"{frac:.2f}" if frac is not None else "-"))
-    if not rows:
-        print(f"resource_plan --bench: {path} carries no model records with "
-              f"measured MFU — zero evidence, failing (embed bench.py model "
-              f"records, which stamp mfu_predicted_roofline)")
-        return 1
-    print(_fmt_table(rows, ["model", "measured_MFU", "predicted_roofline_MFU",
-                            "achieved_frac"]))
-    for model, mfu, pred, frac in rows:
-        if frac != "-" and float(frac) < 0.1:
-            print(f"NOTE: {model} runs at {frac} of its own static roofline "
-                  f"— the compiled step leaves large factors on the table "
-                  f"(kernel fusion / layout / overlap), not the hardware")
-    return 0
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -341,17 +192,6 @@ def main(argv=None):
                     help="full-size model configs (default: CI-size tiny)")
     ap.add_argument("--program", default=None,
                     help="plan one zoo program (mnist|resnet50|bert|nmt|deepfm)")
-    ap.add_argument("--bench", default=None, metavar="BENCH.json",
-                    help="predicted-vs-measured roofline over a bench round "
-                         "(with --gap-rank: scale op times by each model's "
-                         "measured/predicted MFU ratio)")
-    ap.add_argument("--gap-rank", action="store_true",
-                    help="rank op types by roofline-gap x time across the "
-                         "zoo (with --check: gate on zero uncovered cost "
-                         "rows; zero rows = zero evidence = FAIL)")
-    ap.add_argument("--out", default=None, metavar="PATH",
-                    help="with --gap-rank: also write the rendered ranking "
-                         "to PATH (the committed artifact)")
     ap.add_argument("--min-coverage", type=float, default=COST_COVERAGE_FLOOR,
                     help=f"cost-rule coverage floor for --check "
                          f"(default {COST_COVERAGE_FLOOR})")
@@ -359,40 +199,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if args.gap_rank:
-        try:
-            text, data = gap_rank(tiny=not args.full, only=args.program,
-                                  bench=args.bench)
-        except Exception as e:
-            print(f"resource_plan --gap-rank: ranking FAILED: "
-                  f"{type(e).__name__}: {e}")
-            return 1
-        if args.json:
-            print(json.dumps(data, default=str))
-        else:
-            print(text)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(text + "\n")
-            print(f"\nwrote {args.out}")
-        if args.check:
-            if data["total_rows"] == 0:
-                print("\nCHECK FAILED: gap ranking rendered zero cost rows "
-                      "— zero evidence must not gate green")
-                return 1
-            if data["uncovered_rows"]:
-                bad = [a["op_type"] for a in data["ranking"]
-                       if a["uncovered"]]
-                print(f"\nCHECK FAILED: {data['uncovered_rows']} cost rows "
-                      f"over the zoo use the default 1-flop/elem model "
-                      f"(op types: {', '.join(bad)}) — the ranking cannot "
-                      f"be trusted with uncovered rows in it")
-                return 1
-            print(f"\nCHECK OK: {data['total_rows']} cost rows ranked, "
-                  f"zero uncovered")
-        return 0
-    if args.bench:
-        return check_bench(args.bench)
     # NOTE: no persistent XLA compile cache here, deliberately — a
     # cache-deserialized executable's memory_analysis() loses alias_size
     # (donation), which silently inflates the calibration's "measured"

@@ -262,8 +262,7 @@ def correlate(per_rank: Dict[int, List[dict]], steady_after: int = 2) -> dict:
 
 
 def skew_from_dir(root: str) -> Optional[dict]:
-    """Skew report over every rank's metrics stream under `root` (used by
-    bench.py to embed skew records in multi-process rounds); None when
+    """Skew report over every rank's metrics stream under `root`; None when
     fewer than two ranks left telemetry."""
     files = find_rank_files(root)
     if len(files["metrics"]) < 2:
