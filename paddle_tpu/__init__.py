@@ -26,6 +26,7 @@ from .core.program import (  # noqa: F401
     device_guard,
     name_scope,
     program_guard,
+    recompute_scope,
 )
 from .core.scope import Scope, global_scope, scope_guard  # noqa: F401
 from . import parallel  # noqa: F401

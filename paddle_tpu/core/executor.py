@@ -255,7 +255,10 @@ class _CompiledStep:
 
         def step(state_rw: Dict[str, jnp.ndarray], state_ro: Dict[str, jnp.ndarray],
                  feeds: Dict[str, jnp.ndarray], key):
-            ctx = LoweringContext(key, mesh=mesh, platform=self.platform)
+            # LocalSGD and the overlapped all-reduce trace the step INSIDE a shard_map of their own: no batch axis is
+            # left for an op to split over
+            manual = local_sgd or grad_overlap is not None
+            ctx = LoweringContext(key, mesh=mesh, platform=self.platform, batch_axis=None if manual else batch_axis)
             ctx.remat = self.remat
             ctx.grad_sync = self._grad_sync
             ctx.fetch_names = tuple(self.fetch_names)
@@ -640,7 +643,8 @@ class _CompiledStep:
                 lowering.annotate(**{
                     name[len("lowering."):]: n - counted0.get(name, 0)
                     for name, n in _MON.counter_values().items()
-                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_"))
+                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_",
+                                        "lowering.selective_scan_", "lowering.kernels_under_"))
                     and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)
@@ -682,7 +686,31 @@ class _CompiledStep:
         fetches, new_state, new_key = self._dispatch(state_rw, state_ro, feeds, key)
         for n, v in new_state.items():
             scope.set_var(n, v)
+        if self.mesh is not None and self.last_recompiled:
+            self._count_state({**state_ro, **state_rw, **new_state})
         return fetches, new_key
+
+    def _count_state(self, state):
+        """At placement (a program's first run on its mesh): the bytes of its
+        persistables that a device holds, those split by a hint and those held
+        whole, as two gauges of the program's last placement and in the
+        `executor.state_placed` step record."""
+        sharded = replicated = 0
+        for v in state.values():
+            shards = getattr(v, "addressable_shards", None)
+            if not shards:
+                continue
+            held = int(shards[0].data.nbytes)
+            if held < v.nbytes:
+                sharded += held
+            else:
+                replicated += held
+        _MON.gauge("executor.state_bytes_sharded").set(sharded)
+        _MON.gauge("executor.state_bytes_replicated").set(replicated)
+        if _MON.enabled:
+            _MON.record_step({"kind": "state_placed", "program": self.program_uuid, "module": self.module,
+                              "devices": int(self.mesh.size), "bytes_sharded_per_device": sharded,
+                              "bytes_replicated_per_device": replicated})
 
 
 class _PendingFetches:
@@ -899,6 +927,20 @@ class Executor:
                 bucket_mb = float(getattr(program, "grad_overlap_bucket_mb", 0.0))
                 grad_overlap = (ov_mode, int(bucket_mb * 1e6))
             program = program.program
+            hinted = getattr(program, "sharding_mesh", None)
+            if mesh is not None and hinted is not None and (mesh != hinted or batch_axis != program.sharding_batch_axis):
+                # the start-up program placed the state over `hinted`; a step over other devices, another order or
+                # another batch axis would read every persistable through a reshard, silently
+                raise ValueError(
+                    f"CompiledProgram.with_mesh({mesh.axis_names}, batch_axis={batch_axis!r}) is not the mesh the "
+                    f"program's sharding hints were given ({hinted.axis_names}, batch_axis="
+                    f"{program.sharding_batch_axis!r}: `parallel.shard_parameters(mesh=)`): the same devices in "
+                    f"the same order, and the same batch axis")
+        elif getattr(program, "sharding_hints", None) and getattr(program, "sharding_mesh", None) is not None:
+            # state that is born sharded: a program that carries hints AND the mesh they name (`parallel.
+            # shard_parameters(mesh=)`) is placed by them whoever runs it: the start-up program's draws come out split
+            # (`out_shardings`), a `for_test` clone reads them where they lie.  A program without both is placed as ever
+            mesh, batch_axis = program.sharding_mesh, program.sharding_batch_axis
         if local_sgd_every:
             if steps == 1:
                 steps = local_sgd_every  # one dispatch = one LocalSGD round

@@ -133,6 +133,31 @@ class NumpyArrayInitializer(Initializer):
         )
 
 
+class SoftplusInverseLogUniformInitializer(Initializer):
+    """b with softplus(b) log-uniform on [low, high]: a state-space layer's step
+    bias as Mamba initialises it (dt = exp(U(ln low, ln high)), b = dt +
+    ln(1 - exp(-dt)), the inverse of the softplus the layer applies).  Ops of
+    the start-up program: one draw and five elementwise ops on it."""
+
+    def __init__(self, low: float = 1e-3, high: float = 1e-1, seed: int = 0):
+        self.low, self.high, self.seed = float(low), float(high), seed
+
+    def __call__(self, var, block):
+        from . import unique_name
+
+        def step(kind, source, **attrs):
+            out = block.create_var(unique_name.generate(f"{var.name}.init"), shape=var.shape, dtype=var.dtype)
+            block.append_op(kind, inputs={"X": [source.name]}, outputs={"Out": [out.name]}, attrs=attrs)
+            return out
+
+        drawn = block.create_var(unique_name.generate(f"{var.name}.init"), shape=var.shape, dtype=var.dtype)
+        UniformInitializer(float(np.log(self.low)), float(np.log(self.high)), self.seed)(drawn, block)
+        dt = step("exp", drawn)
+        tail = step("log", step("scale", step("exp", step("scale", dt, scale=-1.0)), scale=-1.0, bias=1.0))
+        return block.append_op("elementwise_add", inputs={"X": [dt.name], "Y": [tail.name]},
+                               outputs={"Out": [var.name]}, attrs={"axis": -1})
+
+
 # reference-style aliases (initializer.py exports these names)
 Constant = ConstantInitializer
 Uniform = UniformInitializer

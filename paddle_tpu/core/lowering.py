@@ -35,10 +35,14 @@ class LoweringContext:
     the scope, so randomness advances across `Executor.run` calls.
     """
 
-    def __init__(self, key, is_test: bool = False, mesh=None, platform: Optional[str] = None):
+    def __init__(self, key, is_test: bool = False, mesh=None, platform: Optional[str] = None,
+                 batch_axis: Optional[str] = None):
         self.key = key
         self.is_test = is_test
         self.mesh = mesh
+        # the mesh axis the executor splits the feeds' rows over (None off a mesh): a lowering whose kernel GSPMD
+        # cannot partition asks `ops.common.batch_shards` whether its operands are split over it alone
+        self.batch_axis = batch_axis if mesh is not None else None
         # target backend ("tpu"/"cpu"); lowerings that have a Pallas TPU
         # kernel (fused_attention) pick it here and fall back to plain jnp
         # math elsewhere so CPU tests and virtual meshes still run
@@ -73,7 +77,7 @@ _STRUCTURAL_OPS = ("feed", "fetch", "backward")
 
 
 def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
-            first: int = 0) -> Dict[str, Any]:
+            first: int = 0, segments: bool = True) -> Dict[str, Any]:
     """Interpret `ops` over `env` (var name -> traced jax value), in order.
 
     Op-level provenance (ISSUE 8): each op's emission is wrapped in
@@ -83,11 +87,26 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
     is the index of `ops[0]` among the interpreted ops of its block, so an
     index names ONE op of the block: the tail after `backward` continues
     the forward's numbering.  Pure trace-time cost: the scope name lands
-    in the jaxpr/HLO, nothing runs per step."""
+    in the jaxpr/HLO, nothing runs per step.
+
+    A run of ops that `recompute_scope` marked as one segment is lowered as one
+    `jax.checkpoint` (`_run_recomputed`, which calls back with `segments`
+    off)."""
     # the op census runs at TRACE time only (this loop is the trace), so
     # it costs nothing at execution
     mon_on = _MON.enabled
-    for idx, op in enumerate(ops, first):
+    segment_end = 0
+    for at, op in enumerate(ops):
+        idx = first + at
+        if at < segment_end:
+            continue    # an op of a recomputed segment, lowered with the segment's first
+        segment = op.attrs.get("recompute_segment") if segments else None
+        if segment is not None:
+            segment_end = at + 1
+            while segment_end < len(ops) and ops[segment_end].attrs.get("recompute_segment") == segment:
+                segment_end += 1
+            _run_recomputed(ctx, ops[at:segment_end], env, idx)
+            continue
         if op.type in _STRUCTURAL_OPS:
             raise RuntimeError(
                 f"structural op {op.type!r} reached the lowering interpreter; "
@@ -102,6 +121,28 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
         if mon_on:
             _MON.counter("lowering.ops_total").inc()
     return env
+
+
+def _run_recomputed(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any], first: int) -> None:
+    """The ops of one `recompute_scope` as a `jax.checkpoint`: where the trace
+    is differentiated, backward keeps what the segment reads from `env` and the
+    RNG key, and runs its ops again (the key rides through, so a random op
+    draws the same numbers the second time).  Everything the ops write goes
+    back into `env`, as if they had run one by one."""
+    reads, written = [], set()
+    for op in ops:
+        reads += [n for n in op.input_arg_names if n not in written and n in env and n not in reads]
+        written.update(op.output_arg_names)
+
+    def segment(values, key):
+        inner = dict(zip(reads, values))
+        ctx.key = key
+        run_ops(ctx, ops, inner, first, segments=False)
+        return {n: inner[n] for n in sorted(written) if n in inner}, ctx.key
+
+    made, ctx.key = jax.checkpoint(segment)([env[n] for n in reads], ctx.key)
+    env.update(made)
+    _MON.counter("lowering.recomputed_segments").inc()
 
 
 def lower_one(ctx: LoweringContext, op: Operator, env: Dict[str, Any]) -> None:

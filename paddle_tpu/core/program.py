@@ -284,6 +284,8 @@ class Block:
             op.attrs["pipeline_stage"] = _device_guard_stage
         if unique_name.scope_path() and "op_namescope" not in op.attrs:
             op.attrs["op_namescope"] = unique_name.scope_path()
+        if _recompute_segment is not None and self.idx == 0:
+            op.attrs["recompute_segment"] = _recompute_segment
         self.ops.append(op)
         infer_and_check(op, self)
         self.program._bump()
@@ -339,8 +341,13 @@ class Program:
         self.current_block_idx = 0
         self.random_seed: Optional[int] = None
         self.version = 0
-        # sharding hints attached by the parallel layer (mesh axis -> dim)
+        # sharding hints attached by the parallel layer (mesh axis -> dim), and
+        # the mesh whose axes they name (`parallel.shard_parameters(mesh=)`):
+        # with both, `Executor.run` of this program places its state as hinted
         self.sharding_hints: Dict[str, Any] = {}
+        self.sharding_mesh = None
+        self.sharding_batch_axis = "dp"
+        self._recompute_segments = 0   # `recompute_scope`s opened on this program
         self._seed_counter = 0
 
     def _bump(self):
@@ -396,7 +403,8 @@ class Program:
 
         with _MON.span("program.clone", source=self._uuid[:8],
                        for_test=for_test) as cloning:
-            p = copy.deepcopy(self)
+            # the mesh is devices, not description: the clone refers to the same one (the memo hands it on as it is)
+            p = copy.deepcopy(self, {id(self.sharding_mesh): self.sharding_mesh})
             p._uuid = uuid.uuid4().hex
             cloning.annotate(program=p._uuid[:8])
             if for_test:
@@ -529,6 +537,34 @@ def switch_main_program(program: Program) -> Program:
     old = _main_program
     _main_program = program
     return old
+
+
+_recompute_segment: Optional[int] = None
+
+
+@contextlib.contextmanager
+def recompute_scope():
+    """Marks the ops appended to the main block inside it as ONE segment that
+    backward computes again: the lowering runs the segment as a
+    `jax.checkpoint` (core/lowering.py: `run_ops`), so backward keeps what the
+    segment READS and nothing it makes (a decoder layer's activations are
+    made again from the layer's input).  The ops stay ops of the block, with
+    their own scopes, statistics and fetchable outputs; a `for_test` clone,
+    which has no backward, runs them as they are.  Nested scopes are one
+    segment, the outermost.  A segment's number is its program's own (the
+    n-th scope opened on it), so a program built twice carries the same
+    attributes whatever else the process built before."""
+    global _recompute_segment
+    if _recompute_segment is not None:
+        yield
+        return
+    program = default_main_program()
+    program._recompute_segments += 1
+    _recompute_segment = program._recompute_segments
+    try:
+        yield
+    finally:
+        _recompute_segment = None
 
 
 @contextlib.contextmanager

@@ -932,7 +932,7 @@ def _persistable_tensor(helper, attr, shape, dtype):
 
 
 def short_conv(input, kernel_size=3, in_attr=None, filter_attr=None, out_attr=None, name=None,
-               gated=True, activation=None):
+               gated=True, activation=None, bias_attr=None):
     """A gated short convolution over (b, T, d), the operator that stands
     where attention does in most layers of a convolution-attention hybrid
     (LFM2): [B, C, u] = split3(x W_in); y = (C * conv_K(B * u)) W_out with one
@@ -944,13 +944,18 @@ def short_conv(input, kernel_size=3, in_attr=None, filter_attr=None, out_attr=No
     `gated=False, activation="silu"` is the op's plain mode and the taps alone,
     with no projection round them: y = silu(conv_K(x)) over (b, T, d), as the
     queries, keys and values of a linear-attention layer pass it (`filter_attr`
-    names the [d, K] filter)."""
+    names the [d, K] filter).  `bias_attr` (the plain mode's) adds a float32
+    bias a channel before the SiLU, y = silu(conv_K(x) + b): the op's optional
+    input `Bias`, which a state-space mixer's convolution has."""
     helper = LayerHelper("short_conv", name=name)
     d = int(input.shape[-1])
     source = fc(input, 3 * d, num_flatten_dims=2, param_attr=in_attr, bias_attr=False) if gated else input
     taps = helper.create_parameter(filter_attr, [d, int(kernel_size)], "float32")
     mixed = _out(helper, input.dtype, shape=tuple(input.shape))
-    helper.append_op("short_conv", inputs={"X": [source.name], "Filter": [taps.name]},
+    inputs = {"X": [source.name], "Filter": [taps.name]}
+    if bias_attr:
+        inputs["Bias"] = [helper.create_parameter(bias_attr, [d], "float32", is_bias=True).name]
+    helper.append_op("short_conv", inputs=inputs,
                      outputs={"Out": [mixed.name]},
                      attrs=None if gated else {"gated": False, "activation": str(activation)})
     return fc(mixed, d, num_flatten_dims=2, param_attr=out_attr, bias_attr=False) if gated else mixed
@@ -985,6 +990,32 @@ def kda(q, k, v, g, beta, name=None):
     out = _out(helper, v.dtype, shape=tuple(v.shape))
     stats = _out(helper, "float32", shape=(3,))
     helper.append_op("kda", inputs={"Q": [q.name], "K": [k.name], "V": [v.name], "G": [g.name], "Beta": [beta.name]},
+                     outputs={"Out": [out.name], "Stats": [stats.name]})
+    return out
+
+
+def selective_scan(x, dt, b, c, a_log_attr=None, d_attr=None, dt_bias_attr=None, name=None):
+    """A Mamba-1 mixer's selective scan over the sequence (`ops/ssm_ops.py`):
+    x and the step's projection dt (b, T, d), the input and output matrices b,
+    c (b, T, N) a token; a channel keeps a float32 state of N, h_t = exp(dt_t
+    A) h_{t-1} + dt_t x_t B_t with dt_t = softplus(dt + dt_bias) and A =
+    -exp(A_log), and returns y_t = h_t C_t + D x_t, (b, T, d) in x's dtype.
+    `A_log` [d, N], `D` and `dt_bias` [d] are float32 parameters of the op
+    (`a_log_attr`, `d_attr`, `dt_bias_attr`).  Computed a chunk of tokens at a
+    time, each chunk made again in backward; any T.
+    The state starts at zero with every row: sequences are whole.  The op's
+    `Stats` (mean decay, mean step, largest |h| at the end) are published a
+    logged step by `train_loop` as a `kind="ssm_state"` record."""
+    helper = LayerHelper("selective_scan", name=name)
+    d, n = int(x.shape[-1]), int(b.shape[-1])
+    a_log = helper.create_parameter(a_log_attr, [d, n], "float32")
+    d_skip = helper.create_parameter(d_attr, [d], "float32")
+    dt_bias = helper.create_parameter(dt_bias_attr, [d], "float32", is_bias=True)
+    out = _out(helper, x.dtype, shape=tuple(x.shape))
+    stats = _out(helper, "float32", shape=(3,))
+    helper.append_op("selective_scan",
+                     inputs={"X": [x.name], "Dt": [dt.name], "ALog": [a_log.name], "B": [b.name], "C": [c.name],
+                             "D": [d_skip.name], "DtBias": [dt_bias.name]},
                      outputs={"Out": [out.name], "Stats": [stats.name]})
     return out
 

@@ -20,12 +20,16 @@ capability vs the reference (SURVEY.md §2c: TP absent in 2019).
 """
 from __future__ import annotations
 
+import contextlib
+import re
+
 import numpy as np
 
 from .. import layers, optimizer
-from ..core.initializer import ConstantInitializer, NormalInitializer, UniformInitializer
+from ..core.initializer import (ConstantInitializer, NormalInitializer, NumpyArrayInitializer,
+                                SoftplusInverseLogUniformInitializer, UniformInitializer)
 from ..core.param_attr import ParamAttr
-from ..core.program import Program, name_scope, program_guard
+from ..core.program import Program, name_scope, program_guard, recompute_scope
 
 
 def _attr(name, std=0.02, seed=0):
@@ -210,6 +214,46 @@ def kimi_delta_attention(x, d_model, n_heads, head_dim, prefix, conv_kernel=4, n
         return project(layers.reshape(o, [0, 0, width]), "out", d_model)
 
 
+def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kernel=4, norm_eps=1e-6):
+    """The Mamba-1 mixer as Jamba has it (Lieber et al. 2024, arXiv:2403.19887;
+    Gu & Dao 2023) round the op `selective_scan`: [xs, z] = split(x W_in), each
+    `expand` x d_model wide; xs = silu(conv(xs)), a depthwise causal convolution
+    of `conv_kernel` taps with a bias a channel (`short_conv`'s plain mode); [dt,
+    B, C] = split(xs W_x), `dt_rank`, `state` and `state` wide, each RMS-normed
+    with a gain (Jamba's three inner norms); the step's projection dt W_dt back
+    to the channels (its bias and the softplus are the op's, float32); the
+    recurrence; y * silu(z), projected back.  No other biases.  A_log[c, n] =
+    ln(n + 1), D = 1 and the step's bias the inverse softplus of a log-uniform
+    draw on [1e-3, 1e-1], as Mamba initialises them."""
+    inner = expand * d_model
+    dt_rank = dt_rank or -(-d_model // 16)
+
+    def project(t, name, out):
+        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
+
+    def part(t, name, lo, hi):
+        return layers.rms_norm(layers.slice(t, axes=[2], starts=[lo], ends=[hi]), begin_norm_axis=2, epsilon=norm_eps,
+                               param_attr=_attr_ones(f"{prefix}.{name}_norm.w"))
+
+    with name_scope("mamba"):
+        both = project(x, "in", 2 * inner)
+        xs = layers.slice(both, axes=[2], starts=[0], ends=[inner])
+        z = layers.slice(both, axes=[2], starts=[inner], ends=[2 * inner])
+        xs = layers.short_conv(xs, conv_kernel, gated=False, activation="silu", filter_attr=_attr(f"{prefix}.conv.w"),
+                               bias_attr=ParamAttr(name=f"{prefix}.conv.b", initializer=ConstantInitializer(0.0)))
+        low = project(xs, "x", dt_rank + 2 * state)
+        dt = project(part(low, "dt", 0, dt_rank), "dt", inner)
+        b, c = part(low, "b", dt_rank, dt_rank + state), part(low, "c", dt_rank + state, dt_rank + 2 * state)
+        with name_scope("selective_scan"):
+            y = layers.selective_scan(
+                xs, dt, b, c,
+                a_log_attr=ParamAttr(name=f"{prefix}.a_log", initializer=NumpyArrayInitializer(
+                    np.tile(np.log(np.arange(1, state + 1, dtype="float32")), (inner, 1)))),
+                d_attr=ParamAttr(name=f"{prefix}.d", initializer=ConstantInitializer(1.0)),
+                dt_bias_attr=ParamAttr(name=f"{prefix}.dt.b", initializer=SoftplusInverseLogUniformInitializer(1e-3, 1e-1)))
+        return project(layers.elementwise_mul(y, layers.swish(z)), "out", d_model)
+
+
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
                   use_ring_attention=False, causal=False, use_fused_attention=False,
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
@@ -233,7 +277,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `operator_args` = dict(n_heads=, head_dim=)) and
     `operator="latent_attention"` attention over latent keys and values
     (`latent_attention`; `operator_args` = dict(rank=, nope_dim=, rope_dim=,
-    v_dim=)).
+    v_dim=)) and `operator="mamba"` a Mamba-1 mixer (`mamba_mixer`;
+    `operator_args` = dict(expand=, state=, dt_rank=), its convolution
+    of `conv_kernel` taps).
 
     The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
     W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
@@ -294,6 +340,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     elif operator == "latent_attention":
         attn_out = latent_attention(operator_in, d_model, n_heads, f"{prefix}.attn", norm_eps=norm_eps,
                                     **operator_args)
+    elif operator == "mamba":
+        attn_out = mamba_mixer(operator_in, d_model, f"{prefix}.mamba", conv_kernel=conv_kernel, norm_eps=norm_eps,
+                               **operator_args)
     else:
         attn_out = multi_head_attention(operator_in,
                                         seq_len, d_model, n_heads, f"{prefix}.attn",
@@ -419,6 +468,9 @@ def build_causal_lm(
     kda_heads=None,
     kda_head_dim=None,
     latent=None,
+    mamba=None,
+    rotary=True,
+    recompute_layers=False,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -481,6 +533,15 @@ def build_causal_lm(
     `shared_experts` = n gives every sparse layer n shared experts that every
     token passes, beside the routed ones and outside `experts_held`.
 
+    A hybrid of state-space layers and attention (Jamba) is arguments too:
+    `layer_types` may hold "mamba" (a Mamba-1 mixer, `mamba` = dict(expand=,
+    state=, dt_rank=), its convolution of `conv_kernel` taps);
+    `num_dense_layers` equal to the depth puts the dense feed-forward after
+    EVERY operator; `rotary=False` leaves positions out of the attention
+    altogether (the state-space layers carry the order; `pos_ids` is then no
+    feed); and `recompute_layers` makes every layer a `recompute_scope`:
+    backward keeps a layer's input and computes the layer again.
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -504,14 +565,17 @@ def build_causal_lm(
         raise ValueError(f"build_causal_lm: n_layers={n_layers} beside {len(layer_types)} layer_types; "
                          "layer_types alone states the depth")
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
-    operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention"}
-    operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent}
+    operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention",
+                 "mamba": "mamba"}
+    operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba}
     unknown = sorted(set(kinds) - set(operators))
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
-                         "kda or latent_attention")
-    if ("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent):
-        raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer latent=")
+                         "kda or latent_attention, or mamba")
+    if (("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent)
+            or ("mamba" in kinds and not mamba)):
+        raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer "
+                         "latent=, a mamba layer mamba=")
     if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
         raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
                          f"among {len(kinds)} layers")
@@ -522,7 +586,7 @@ def build_causal_lm(
         n_labels = loss_positions or seq_len
         ids = layers.data("ids", [seq_len], dtype="int64")
         labels = layers.data("labels", [n_labels], dtype="int64")
-        pos_ids = layers.data("pos_ids", [seq_len], dtype="int64")
+        pos_ids = layers.data("pos_ids", [seq_len], dtype="int64") if rotary else None
         x = layers.embedding(ids, size=[vocab_size, d_model],
                              param_attr=_attr("lm.tok_emb", embedding_std, routing_seed))
         if dtype != "float32":
@@ -539,17 +603,18 @@ def build_causal_lm(
                                norm_eps=norm_topk_eps,
                                bias=expert_bias and (expert_bias[0], expert_bias[1] + i),
                                shared_experts=shared_experts)
-                x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
-                                  f"lm.l{i}",
-                                  dropout_prob=0.0, causal=attention_mask is None,
-                                  use_fused_attention=use_fused_attention,
-                                  norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
-                                  qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
-                                  moe=None if dense else experts, ffn="gated_silu",
-                                  aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
-                                  attention_mask=attention_mask,
-                                  operator=operators[kind], operator_args=operator_args.get(kind),
-                                  conv_kernel=conv_kernel, post_norm=post_norm)
+                with recompute_scope() if recompute_layers else contextlib.nullcontext():
+                    x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
+                                      f"lm.l{i}",
+                                      dropout_prob=0.0, causal=attention_mask is None,
+                                      use_fused_attention=use_fused_attention,
+                                      norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
+                                      qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
+                                      moe=None if dense else experts, ffn="gated_silu",
+                                      aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
+                                      attention_mask=attention_mask,
+                                      operator=operators[kind], operator_args=operator_args.get(kind),
+                                      conv_kernel=conv_kernel, post_norm=post_norm)
             return x
 
         def final_norm(x):
@@ -562,7 +627,7 @@ def build_causal_lm(
             return layers.fc(x, vocab_size, num_flatten_dims=len(x.shape) - 1,
                              param_attr=_attr("lm.head.w"), bias_attr=False)
 
-        feeds = {"ids": ids, "labels": labels, "pos_ids": pos_ids}
+        feeds = {"ids": ids, "labels": labels, **({"pos_ids": pos_ids} if rotary else {})}
         fetches = {}
         if loop is not None:
             passes = layers.Repeat(loop, recompute=True)
@@ -609,6 +674,28 @@ def build_causal_lm(
             optimizer.Adam(learning_rate=learning_rate, beta1=beta1, beta2=beta2,
                            epsilon=epsilon).minimize(loss)
     return main, startup, feeds, {"loss": loss, "logits": logits, **fetches}
+
+
+def fsdp_rules(program, axis="dp", ways=None):
+    """Sharding hints that split every matrix of `program` (a
+    `build_causal_lm` program, by the names it gives: `lm.tok_emb`, `lm.head.w`
+    and the layers' `lm.l<i>.<part>.w`) `ways` ways along its first dimension
+    that `ways` divides, over the mesh axis `axis` that also splits the batch:
+    ZeRO-3 as GSPMD states it.  The optimizer's accumulators take a parameter's
+    hint (`Optimizer._add_accumulator`), so master, gradient and moments lie
+    split alike and each chip updates its own rows; GSPMD gathers a matrix where
+    a product reads it and scatters its gradient's sum.  Vectors (norm gains,
+    biases, D) and what no dimension divides stay whole.  `ways` None takes any
+    dimension of 2 and more rows."""
+    rules = {}
+    for v in program.global_block().all_parameters():
+        shape = tuple(v.shape or ())
+        if len(shape) < 2 or not re.fullmatch(r"lm\.(tok_emb|head\.w|exit_gate\.w|l\d+\..*)", v.name):
+            continue
+        dim = next((i for i, n in enumerate(shape) if n > 1 and (ways is None or n % ways == 0)), None)
+        if dim is not None:
+            rules[re.escape(v.name)] = tuple(axis if i == dim else None for i in range(len(shape)))
+    return rules
 
 
 def tp_rules():

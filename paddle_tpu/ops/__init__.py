@@ -10,6 +10,7 @@ from . import (  # noqa: F401
     optimizer_ops,
     pipeline_ops,
     sequence_ops,
+    ssm_ops,
     tail_ops,
     tensor_ops,
 )

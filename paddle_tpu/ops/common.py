@@ -14,6 +14,39 @@ def first(ins, slot, default=None):
     return vals[0]
 
 
+def batch_shards(mesh, batch_axis, batch) -> int:
+    """How `mesh` (a lowering context's) splits an op whose operands lead with
+    a batch axis of `batch` rows: 1 on one device; n where `batch_axis` (the
+    executor's, `ctx.batch_axis`) is its ONLY axis of more than one device and cuts
+    the rows n ways evenly: the op's operands are then split over the batch
+    alone, and a chip's share of the op is the whole op on its own rows
+    (`over_batch_shards`); 0 under any other mesh, where a lowering keeps the
+    forms GSPMD can partition by itself."""
+    if mesh is None or mesh.size == 1:
+        return 1
+    n = dict(mesh.shape).get(batch_axis, 1)
+    return n if n == mesh.size and batch is not None and batch > 0 and batch % n == 0 else 0
+
+
+def over_batch_shards(ctx, fn, batched, whole=()):
+    """`fn(*batched, *whole)` run by every chip on its own rows: a `shard_map`
+    over the mesh's batch axis, `batched` (and every output) split along the
+    leading axis, `whole` handed to every chip entire.  What a `pallas_call`
+    needs under a mesh (a custom call that GSPMD cannot partition and would
+    run on all the rows on every chip), and what keeps a `lax.scan` over the
+    sequence from being split any other way.  `fn` sees per-chip shapes; a
+    reduction over the batch inside it names the axis `ctx.batch_axis`."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..monitor import MONITOR
+
+    MONITOR.counter("lowering.kernels_under_shard_map").inc()
+    rows = P(ctx.batch_axis)
+    return jax.shard_map(lambda b, w: fn(*b, *w), mesh=ctx.mesh, in_specs=(rows, P()), out_specs=rows,
+                         check_vma=False)(tuple(batched), tuple(whole))
+
+
 def bcast_y_to_x(x, y, axis: int):
     """Fluid elementwise broadcasting (reference: operators/elementwise/
     elementwise_op_function.h): Y's dims align to X starting at `axis`

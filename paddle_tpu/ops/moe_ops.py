@@ -200,13 +200,15 @@ def _gated_short_conv_bwd(res, g):
 _gated_short_conv.defvjp(lambda x, w: (_gated_short_conv(x, w), (x, w)), _gated_short_conv_bwd)
 
 
-def _plain_short_conv_taps(x, w, ahead=0):
+def _plain_short_conv_taps(x, w, ahead=0, bias=None):
     """(c = conv_K(x) in float32, each tap's shifted x) of x [b, T, d] and the
     filter w [d, K], as `_short_conv_taps` has them for B * u; `ahead`: c of the
-    row that many after each, zeros past the sequence's end."""
+    row that many after each, zeros past the sequence's end; `bias` [d] is
+    added to c where there is one."""
     taps = w.shape[1]
     shifted = [_shift_rows(x, taps - 1 - j - ahead).astype(jnp.float32) for j in range(taps)]
-    return sum(z * w[:, j].astype(jnp.float32) for j, z in enumerate(shifted)), shifted
+    c = sum(z * w[:, j].astype(jnp.float32) for j, z in enumerate(shifted))
+    return (c if bias is None else c + bias.astype(jnp.float32)), shifted
 
 
 def _silu_slope(c):
@@ -215,10 +217,10 @@ def _silu_slope(c):
 
 
 @jax.custom_vjp
-def _plain_short_conv(x, w):
-    """silu(conv_K(x)), computed in float32 from x's dtype and rounded once."""
+def _plain_short_conv(x, w, bias=None):
+    """silu(conv_K(x) + bias), computed in float32 from x's dtype and rounded once."""
     with jax.named_scope("plain_short_conv"):
-        return jax.nn.silu(_plain_short_conv_taps(x, w)[0]).astype(x.dtype)
+        return jax.nn.silu(_plain_short_conv_taps(x, w, bias=bias)[0]).astype(x.dtype)
 
 
 def _plain_short_conv_bwd(res, g):
@@ -226,22 +228,23 @@ def _plain_short_conv_bwd(res, g):
     dc = g silu'(c); dw_j = sum dc . x[. - (K - 1) + j]; dx[s] = sum_j w_j
     dc[s + (K - 1) - j], where dc at a later row is made again from the rows of
     x it reads, so that every `pad` is of an operand.  Keeps x and the filter."""
-    x, w = res
+    x, w, bias = res
     taps = w.shape[1]
     with jax.named_scope("plain_short_conv"):
-        c, shifted = _plain_short_conv_taps(x, w)
+        c, shifted = _plain_short_conv_taps(x, w, bias=bias)
         dc = g.astype(jnp.float32) * _silu_slope(c)
         d_w = jnp.stack([jnp.sum(dc * z, axis=(0, 1)) for z in shifted], axis=1).astype(w.dtype)
 
-        def dc_ahead(ahead):   # dc[s + ahead], zero past the sequence's end
-            return _shift_rows(g, -ahead).astype(jnp.float32) * _silu_slope(_plain_short_conv_taps(x, w, ahead)[0])
+        def dc_ahead(ahead):   # dc[s + ahead], zero past the sequence's end (where g is zero, whatever the bias)
+            return (_shift_rows(g, -ahead).astype(jnp.float32)
+                    * _silu_slope(_plain_short_conv_taps(x, w, ahead, bias)[0]))
 
         d_x = sum((dc if j == taps - 1 else dc_ahead(taps - 1 - j)) * w[:, j].astype(jnp.float32)
                   for j in range(taps)).astype(x.dtype)
-    return d_x, d_w
+    return d_x, d_w, None if bias is None else jnp.sum(dc, axis=(0, 1)).astype(bias.dtype)
 
 
-_plain_short_conv.defvjp(lambda x, w: (_plain_short_conv(x, w), (x, w)), _plain_short_conv_bwd)
+_plain_short_conv.defvjp(lambda x, w, bias=None: (_plain_short_conv(x, w, bias), (x, w, bias)), _plain_short_conv_bwd)
 
 
 @register_op("short_conv")
@@ -254,12 +257,14 @@ def _short_conv(ctx, op, ins):
     that XLA fuses into one pass forward, and a backward pass written the same
     way (`_gated_short_conv_bwd`: the derived one pads computed products and
     reads 2.3x the bytes; PERF.md, PR 34).  With the attributes `gated=False,
-    activation="silu"` X is [b, T, d] and Out = silu(conv_K(X)): the taps alone."""
+    activation="silu"` X is [b, T, d] and Out = silu(conv_K(X)): the taps alone,
+    plus the optional input `Bias` [d] before the SiLU where the op has one."""
     if not op.attr("gated", True):
         # the plain mode (attributes gated=False, activation="silu"): X [b, T, d] itself passes the taps and a
         # SiLU, as a linear-attention layer's q, k and v do; `_shift_rows` and the transpose's shape are shared
         _MON.counter("lowering.short_conv_plain_layers").inc()
-        return {"Out": _plain_short_conv(first(ins, "X"), first(ins, "Filter"))}
+        bias = first(ins, "Bias")   # absent in every program that stood before the state-space mixer's
+        return {"Out": _plain_short_conv(first(ins, "X"), first(ins, "Filter"), *(() if bias is None else (bias,)))}
     _MON.counter("lowering.short_conv_layers").inc()
     return {"Out": _gated_short_conv(first(ins, "X"), first(ins, "Filter"))}
 
@@ -857,6 +862,9 @@ def _infer_short_conv(ctx):
     fold = 3 if gated else 1
     if len(xs) != 3 or len(ws) != 2 or ws[0] * fold != xs[-1] or ws[1] < 1:
         ctx.fail(f"X must be (b, T, {'3d' if gated else 'd'}) and Filter (d, K), got {xs} and {ws}")
+    bias = ctx.in_shape("Bias")
+    if bias is not None and (gated or tuple(bias) != (ws[0],)):
+        ctx.fail(f"Bias is the plain form's, one value for each of Filter's {ws[0]} channels, got {bias} with gated={gated}")
     ctx.set_out("Out", tuple(xs[:-1]) + (ws[0],), ctx.in_dtype("X"))
 
 

@@ -22,7 +22,7 @@ import numpy as np
 
 from ..core.registry import register_op
 from ..monitor import MONITOR as _MON
-from .common import canon_dtype, first, match_dtype
+from .common import batch_shards, canon_dtype, first, match_dtype, over_batch_shards
 
 
 @register_op("conv2d")
@@ -537,7 +537,8 @@ _ROW_KERNEL_SEQ_MULTIPLE = 128
 _ATTENTION_AXES = {"bhld": (1, 2), "blhd": (2, 1)}
 
 
-def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld", v_width=None):
+def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld", v_width=None,
+                    batch_axis=None):
     """Which attention `fused_attention` lowers to: "flash", "block_causal",
     "row_kernel", "block_sparse" or "xla", the lengths read by the op's
     `layout`.  Off the TPU always "xla".  A short
@@ -546,14 +547,22 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
     structured `mask` (`_structured_mask`) the block-sparse kernel or, as for
     the row kernel, XLA's attention where a custom call cannot be partitioned
     or the lengths are no whole number of its blocks; never the other two
-    kernels, which know no mask but a causal one."""
+    kernels, which know no mask but a causal one.
+
+    A chip runs the op whole (`one_device`) on one device, and also under a
+    mesh whose `batch_axis` splits the operands' rows and nothing else
+    (`ops.common.batch_shards`): `_fused_attention` then runs the chosen kernel
+    in a `shard_map` over that axis, each chip on its own rows, and the choice
+    reads what a chip sees (lengths, widths and dtype are the same; only the
+    rows are fewer).  Under any other mesh (heads or positions split too) the
+    forms GSPMD partitions by itself stand, as before."""
     if platform != "tpu":
         return "xla"
     from .masked_attention import kernel_block
 
     positions = _ATTENTION_AXES[layout][1]
     q_len, kv_len = q.shape[positions], k.shape[positions]
-    one_device = mesh is None or mesh.size == 1
+    one_device = batch_shards(mesh, batch_axis, q.shape[0]) >= 1
     # values of another width than queries and keys (latent attention: 192-wide q, k beside 128-wide v): the splash
     # kernels take the widths as they are; the flash, row and block-diffusion kernels were never given any
     one_width = v_width in (None, q.shape[-1])
@@ -569,7 +578,7 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
         # (2, 32 on 8, 8192, 64): 48.22 | 32.10 ms; LFM2's step 269.0 -> 254.1 ms, 7.4205 -> 7.8485 samples/s (+5.8%).
         # What no cell or run prices keeps the flash kernel: a bias (the splash kernels take none), no causal mask
         # (nothing to skip), queries and keys of different lengths or of no whole number of the kernels' blocks, a
-        # mesh of more than one device, operands other than bf16, a head width that is no multiple of 64.
+        # mesh that splits more than the rows, operands other than bf16, a head width that is no multiple of 64.
         if (causal and not biased and one_device and q_len == kv_len and kernel_block(q_len) is not None
                 and q.shape[-1] % 64 == 0 and (v_width or q.shape[-1]) % 64 == 0 and q.dtype == k.dtype == jnp.bfloat16):
             return "block_causal"
@@ -703,11 +712,16 @@ def _fused_attention(ctx, op, ins):
     `lowering.attention_layout_native` counts the ops whose path read what it
     was handed, `lowering.attention_layout_transposed` the others.
 
-    Under a mesh of more than one device the row kernel is NOT taken: a
-    `pallas_call` is a custom call that GSPMD cannot partition, and the XLA
-    path, which it can, is correct there at no new code (no cell runs 256 to
-    512 keys on a mesh; a `shard_map` over the batch axis is the other way,
-    when one does).  The bias derives from lengths and causality in every
+    A `pallas_call` is a custom call that GSPMD cannot partition: under a mesh
+    it would run on every chip over ALL the rows.  Where the mesh's batch axis
+    splits the operands' rows and nothing else, every kernel path therefore
+    runs in a `shard_map` over that axis, each chip on its own rows
+    (`ops.common.over_batch_shards`, counted in
+    `lowering.kernels_under_shard_map`; the four-chip Jamba cell's causal
+    attention at 8192 keys takes the splash kernels so).  Under a mesh that
+    splits heads or positions too, the kernels step aside for the XLA path,
+    which GSPMD partitions at no new code, and the stock flash kernel runs
+    replicated as before.  The bias derives from lengths and causality in every
     caller, so the row kernel treats it as a constant."""
     q = first(ins, "Q")
     k = first(ins, "K")
@@ -719,36 +733,47 @@ def _fused_attention(ctx, op, ins):
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     mask = _structured_mask(op, q, k, layout)
-    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout, v.shape[-1])
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout, v.shape[-1],
+                           ctx.batch_axis)
     _MON.counter(f"lowering.attention_{path}").inc()
     if v.shape[-1] != q.shape[-1]:
         _MON.counter("lowering.latent_attention_layers").inc()
     native = layout == "bhld" or path == "row_kernel"
     _MON.counter("lowering.attention_layout_native" if native else "lowering.attention_layout_transposed").inc()
-    if not native:
-        q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
-    heads = _ATTENTION_AXES[layout][0] if native else 1
-    if path == "block_sparse":
-        from .masked_attention import block_sparse_attention
 
-        out = block_sparse_attention(q, k, v, mask[1], float(scale))
-    elif path == "block_causal":
-        from .masked_attention import causal_attention
+    def attend(q, k, v, bias=None):
+        if not native:
+            q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+        heads = _ATTENTION_AXES[layout][0] if native else 1
+        if path == "block_sparse":
+            from .masked_attention import block_sparse_attention
 
-        out = causal_attention(q, k, v, float(scale))
-    else:
-        if k.shape[heads] != q.shape[heads]:
-            k, v = (jnp.repeat(t, q.shape[heads] // t.shape[heads], axis=heads) for t in (k, v))
-        if path == "flash":
-            out = _flash_attention_tpu(q, k, v, bias, causal, scale)
-        elif path == "row_kernel":
-            from .pallas_attention import fused_sdpa
+            out = block_sparse_attention(q, k, v, mask[1], float(scale))
+        elif path == "block_causal":
+            from .masked_attention import causal_attention
 
-            b = jax.lax.stop_gradient(bias) if bias is not None else None
-            out = fused_sdpa(q, k, v, b, bool(causal), float(scale), False, layout).astype(q.dtype)
+            out = causal_attention(q, k, v, float(scale))
         else:
-            out = _xla_attention(q, k, v, bias, causal, scale, mask)
-    return {"Out": out if native else jnp.swapaxes(out, 1, 2)}
+            if k.shape[heads] != q.shape[heads]:
+                k, v = (jnp.repeat(t, q.shape[heads] // t.shape[heads], axis=heads) for t in (k, v))
+            if path == "flash":
+                out = _flash_attention_tpu(q, k, v, bias, causal, scale)
+            elif path == "row_kernel":
+                from .pallas_attention import fused_sdpa
+
+                b = jax.lax.stop_gradient(bias) if bias is not None else None
+                out = fused_sdpa(q, k, v, b, bool(causal), float(scale), False, layout).astype(q.dtype)
+            else:
+                out = _xla_attention(q, k, v, bias, causal, scale, mask)
+        return out if native else jnp.swapaxes(out, 1, 2)
+
+    if path != "xla" and batch_shards(ctx.mesh, ctx.batch_axis, q.shape[0]) > 1:
+        # a kernel under a mesh that splits the rows alone: each chip on its own rows (a bias that has the rows' axis
+        # is split with them, one that is broadcast over the rows is handed over whole)
+        rows = bias is not None and bias.shape[0] == q.shape[0]
+        batched, whole = ((q, k, v, bias), ()) if rows else ((q, k, v), () if bias is None else (bias,))
+        return {"Out": over_batch_shards(ctx, attend, batched, whole)}
+    return {"Out": attend(q, k, v, bias)}
 
 
 @register_op("top_k")

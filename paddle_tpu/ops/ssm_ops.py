@@ -1,0 +1,215 @@
+"""The selective scan of a Mamba-1 mixer (Gu & Dao 2023, arXiv:2312.00752): a
+diagonal state-space recurrence whose step, input matrix and output matrix are
+functions of the INPUT, computed chunk by chunk.
+
+A channel c of `d` keeps a float32 state h[c, :] in R^N (N = 16), h_0 = 0:
+
+    dt_t   = softplus(Dt_t + DtBias)                         [d]     the step a channel, float32
+    h_t    = exp(dt_t[:, None] * A) * h_{t-1} + (dt_t * x_t)[:, None] * B_t[None, :],    A = -exp(ALog)  [d, N]
+    y_t    = h_t C_t + D * x_t                               [d]
+
+`selective_scan` takes x and the step's projection Dt [b, T, d], B and C
+[b, T, N], the float32 parameters ALog [d, N], D [d] and DtBias [d]; the
+convolution, the projections, the three inner norms and the output gate round it
+are ops of the program.  The bias and the softplus are the op's (the published
+kernel's `delta_bias` / `delta_softplus`), so that they and everything after
+them are float32 whatever the activations' dtype.
+
+The form (every platform; no kernel yet): ONE `lax.scan` over chunks of
+`chunk` tokens that carries the state [b, N, d]; inside a chunk the recurrence
+is a `jax.lax.associative_scan` over the pairs (a_t, u_t) = (exp(dt_t A), dt_t
+x_t B_t) under (a, u) . (a', u') = (a a', a' u + u'), which is stable however
+strong the decay (no exponent is positive), and the chunk's start state enters
+through the cumulative products the same scan returns.  Every chunk is a
+`jax.checkpoint`: backward keeps the chunks' inputs and start states
+([chunks, b, N, d] float32: 335 MB a layer at 8192 tokens of 5120 channels in
+chunks of 8, an eighth of the states) and makes a chunk's [chunk, N, d] arrays
+again; nothing of [T, d, N] (1.34 GB a sequence of 4096 a layer) outlives a
+chunk.  The state lies [N, d], channels
+last: an array that ends in 16 is tiled to 128 lanes and wastes seven eighths
+of them (PERF.md, PR 42).  T need not be a whole number of chunks: the tail is
+padded with steps of zero (dt = 0: a = 1, u = 0), which leave the state alone.
+
+Under a mesh whose batch axis splits the rows and nothing else the whole op
+runs in a `shard_map` over that axis (`ops.common.over_batch_shards`): a chip
+scans its own rows, and GSPMD is not asked how to split a loop over the
+sequence.
+"""
+from __future__ import annotations
+
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core import analysis as _A
+from ..core import resource_plan as _RP
+from ..core.registry import register_op, set_step_stats
+from ..monitor import MONITOR as _MON
+from .common import batch_shards, first, over_batch_shards
+
+#: Tokens a chunk.  A chunk's arrays are
+#: [b, chunk, N, d] float32 and the associative scan makes log2(chunk) levels of
+#: them, which XLA keeps in one fusion only while they are small.  Measured on
+#: a v5e at (1, 8192, 5120) x 16, ms forward | forward and backward
+#: (tools/chip_jamba_scan.py; PERF.md section 6, PR 47): 8 tokens 18.4 | 42.8,
+#: 16: 20.5 | 45.8, 32: 19.7 | 110.2, 64: 18.6 | 160.4, 128: 125.7 | 331.4.
+#: Shorter chunks keep more start states for backward (2 tokens: 9.2 | 30.0, and
+#: half of [T, N, d]).
+_SSM_CHUNK = 8
+#: What the padded tail's step projection holds: softplus of it is exactly 0 in
+#: float32 and so is its slope.
+_NO_STEP = -1e4
+
+
+def _combine(left, right):
+    """(a, u) then (a', u'): h -> a' (a h + u) + u'."""
+    return left[0] * right[0], right[0] * left[1] + right[1]
+
+
+def _step_of(dt, dt_bias):
+    """The float32 step softplus(Dt + DtBias) (a function of its own so that a
+    control can round it: tests/test_jamba.py, tools/chip_jamba_controls.py)."""
+    return jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+
+
+def _carried(h):
+    """The state handed from a chunk to the next: float32 (a seam for the
+    controls, as `_step_of`)."""
+    return h
+
+
+def _chunk(h0, x, dt, b_t, c_t, a_t, dt_bias):
+    """One chunk from the state h0 [b, N, d]: (the state after its last token,
+    y without the skip term [b, C, d] float32, the sum of its decays) of x, dt
+    [b, C, d] and B, C [b, C, N]; `a_t` = A transposed, [N, d]."""
+    step = _step_of(dt, dt_bias)
+    decay = jnp.exp(step[:, :, None, :] * a_t)                                   # [b, C, N, d]
+    enters = (step * x.astype(jnp.float32))[:, :, None, :] * b_t.astype(jnp.float32)[:, :, :, None]
+    through, own = jax.lax.associative_scan(_combine, (decay, enters), axis=1)
+    h = own + through * h0[:, None]
+    y = jnp.sum(h * c_t.astype(jnp.float32)[:, :, :, None], axis=2)
+    return _carried(h[:, -1]), y, (jnp.sum(decay), jnp.sum(step))
+
+
+def chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk=_SSM_CHUNK):
+    """(y [b, T, d] in x's dtype, the state after the last token [b, N, d]
+    float32, (the mean decay, the mean step)) of the recurrence in the module's
+    docstring, `chunk` tokens at a time."""
+    batch, T, d = x.shape
+    chunk = min(int(chunk), T)
+    n = -(-T // chunk)
+    pad = n * chunk - T
+    a_t = -jnp.exp(a_log.astype(jnp.float32)).T                                  # [N, d]
+    dt_bias = dt_bias.astype(jnp.float32)
+
+    def chunks(t, fill=0.0):   # [b, T, .] -> [n, b, C, .]
+        if pad:
+            t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)), constant_values=fill)
+        return t.reshape(batch, n, chunk, t.shape[-1]).swapaxes(0, 1)
+
+    @jax.checkpoint
+    def body(h, part):
+        h, y, sums = _chunk(h, *part, a_t, dt_bias)
+        return h, (y, sums)
+
+    with jax.named_scope("selective_scan"):
+        h0 = jnp.zeros((batch, a_t.shape[0], d), jnp.float32)
+        final, (y, (decays, steps)) = jax.lax.scan(body, h0, (chunks(x), chunks(dt, _NO_STEP), chunks(b_t), chunks(c_t)))
+        y = y.swapaxes(0, 1).reshape(batch, n * chunk, d)[:, :T]
+        y = (y + d_skip.astype(jnp.float32) * x.astype(jnp.float32)).astype(x.dtype)
+        real = float(batch * T * d)
+        # the padded steps decay by exactly 1 and step by exactly 0
+        means = (jnp.sum(decays) - float(batch * pad * d * a_t.shape[0])) / (real * a_t.shape[0]), jnp.sum(steps) / real
+    return y, final, means
+
+
+@register_op("selective_scan")
+def _selective_scan(ctx, op, ins):
+    """The chunked recurrence over X, Dt [b, T, d], B, C [b, T, N] with ALog
+    [d, N], D and DtBias [d] (float32).  `Stats` [3] is the step's health, read
+    on logged steps: the mean decay exp(dt A), the mean step dt and the largest
+    |h| of the state after the last token."""
+    x, dt, a_log, b_t, c_t, d_skip, dt_bias = (first(ins, s) for s in ("X", "Dt", "ALog", "B", "C", "D", "DtBias"))
+    _MON.counter("lowering.selective_scan_ops").inc()
+    _MON.counter("lowering.selective_scan_chunks").inc(-(-x.shape[1] // min(_SSM_CHUNK, x.shape[1])))
+    shards = batch_shards(ctx.mesh, ctx.batch_axis, x.shape[0])
+
+    def scan(x, dt, b_t, c_t, a_log, d_skip, dt_bias):
+        y, final, (decay, step) = chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias)
+        decay, step, largest = jax.lax.stop_gradient((decay, step, jnp.max(jnp.abs(final))))
+        if shards > 1:   # a chip's rows: the means of equal shares, the largest of all
+            decay, step = (jax.lax.pmean(t, ctx.batch_axis) for t in (decay, step))
+            largest = jax.lax.pmax(largest, ctx.batch_axis)
+        return y, jnp.broadcast_to(jnp.stack([decay, step, largest]), (x.shape[0], 3))
+
+    # The scan reads the VARIABLES.  Without the barrier XLA fuses the operands' producers into the chunks' layout and
+    # the scan reads xs before its rounding to bf16 (excess precision): sound arithmetic that a stage check on the
+    # FETCHED xs, dt, B, C cannot tell from a fault (the cell's first run read 2.78e-3 against the recurrence where
+    # the op alone on such arrays reads 2e-5: PERF.md section 6, PR 47, and defect 19 for `kda`'s kernels)
+    x, dt, b_t, c_t = jax.lax.optimization_barrier((x, dt, b_t, c_t))
+    batched, whole = (x, dt, b_t, c_t), (a_log, d_skip, dt_bias)
+    y, stats = over_batch_shards(ctx, scan, batched, whole) if shards > 1 else scan(*batched, *whole)
+    return {"Out": y, "Stats": stats[0]}
+
+
+def _publish_ssm_state(step, values):
+    """One logged step's `ssm_state` record: per layer the mean decay exp(dt A),
+    the mean step dt and the largest |h| after the last token; the layers' mean
+    decay and the worst layer's state as gauges.  A health check (a decay at 1
+    forgets nothing and the state grows with the sequence; at 0 the layer reads
+    one token): no lever on the step's time."""
+    stats = np.stack([np.asarray(s, "f8").reshape(3) for s in values["Stats"]])
+    record = {"kind": "ssm_state", "pipeline_step": step, "decay_mean": stats[:, 0].tolist(),
+              "dt_mean": stats[:, 1].tolist(), "state_abs_max": stats[:, 2].tolist(),
+              "worst_layer": int(np.argmax(stats[:, 2]))}
+    _MON.gauge("ssm.decay_mean").set(float(stats[:, 0].mean()))
+    _MON.gauge("ssm.state_abs_max").set(float(stats[:, 2].max()))
+    _MON.record_step(record)
+
+
+set_step_stats("selective_scan", ("Stats",), _publish_ssm_state)
+
+
+def _infer_selective_scan(ctx):
+    x, dt, a_log, b_t, c_t = (ctx.in_shape(s) for s in ("X", "Dt", "ALog", "B", "C"))
+    if x is None or a_log is None:
+        return
+    if len(x) != 3 or len(a_log) != 2 or a_log[0] != x[-1]:
+        ctx.fail(f"X must be (b, T, d) and ALog (d, N), got {x} and {a_log}")
+    if dt is not None and tuple(dt) != tuple(x):
+        ctx.fail(f"Dt holds one step for each of X's {tuple(x)} channels, got {dt}")
+    for name, t in (("B", b_t), ("C", c_t)):
+        if t is not None and tuple(t) != tuple(x[:2]) + (a_log[1],):
+            ctx.fail(f"{name} must be (b, T, N) = {tuple(x[:2]) + (a_log[1],)}, got {t}")
+    for name in ("D", "DtBias"):
+        t = ctx.in_shape(name)
+        if t is not None and tuple(t) != (x[-1],):
+            ctx.fail(f"{name} must be (d,) = ({x[-1]},), got {t}")
+    ctx.set_out("Out", x, ctx.in_dtype("X"))
+    ctx.set_out("Stats", (3,), "float32")
+
+
+_A.register_rule(["selective_scan"], _infer_selective_scan)
+
+#: Elementwise operations a state element a token, forward: the decay's product
+#: and exp (counted as one each), the input's product, the recurrence's multiply
+#: and add, the output's multiply and add.
+SCAN_FLOPS_PER_STATE_ELEMENT = 7.0
+
+
+def selective_scan_flops(tokens, channels, state):
+    """Elementwise operations of the recurrence's forward over `tokens`
+    positions, `SCAN_FLOPS_PER_STATE_ELEMENT` a state element (an exp counted
+    as one), and per channel the softplus, the step's product and the skip."""
+    return float(tokens) * channels * (SCAN_FLOPS_PER_STATE_ELEMENT * state + 6.0)
+
+
+def _cost_selective_scan(ctx):
+    x, a_log = ctx.in_shape("X"), ctx.in_shape("ALog")
+    if x is None or a_log is None or len(x) != 3:
+        return float(ctx.out_elems_total()), ctx.io_bytes()
+    return selective_scan_flops(x[0] * x[1], x[2], a_log[1]), ctx.io_bytes()
+
+
+_RP.register_cost(["selective_scan"], _cost_selective_scan)

@@ -15,6 +15,11 @@ from ..core.registry import register_op
 from .common import canon_dtype, first, np_dtype
 
 
+#: Elements from which `fill_constant` hands on a zero-strided view of its value instead of a filled array (the small
+#: ones stay filled: every accepted cell's step and clone lower to the text they had).
+_FILL_AS_VIEW = 1 << 20
+
+
 @register_op("fill_constant")
 def _fill_constant(ctx, op, ins):
     shape = tuple(op.attr("shape", []))
@@ -22,7 +27,11 @@ def _fill_constant(ctx, op, ins):
     value = op.attr("value", 0.0)
     # host-side constant: stays concrete through the trace so tensor-array
     # indices built from constants remain static; jnp coerces on use and
-    # XLA constant-folds either way
+    # XLA constant-folds either way.  A large one (an optimizer's moment of a table's shape) is a VIEW of one value
+    # with strides of zero, which JAX lowers as a broadcast: `np.full` wrote 12.8 GB on the host for the moments of
+    # 1.6 G parameters, 86 s of a start-up program's trace here and four times that on the chip machine's host
+    if int(np.prod(shape, dtype=np.int64)) >= _FILL_AS_VIEW:
+        return {"Out": np.broadcast_to(np.asarray(value, dtype=dtype), shape)}
     return {"Out": np.full(shape, value, dtype=dtype)}
 
 

@@ -67,6 +67,12 @@ class Optimizer:
             outputs={"Out": [var_name]},
             attrs={"shape": shape, "dtype": dtype, "value": float(fill_value)},
         )
+        # an accumulator of the parameter's shape lies where the parameter lies: a hinted parameter's moments take
+        # its hint, in the main and in the start-up program (`parallel.shard_parameters`)
+        for program in (default_main_program(), default_startup_program()):
+            hint = program.sharding_hints.get(param.name)
+            if hint is not None and tuple(shape) == tuple(param.shape):
+                program.sharding_hints[var_name] = hint
         self._accumulators.setdefault(name, {})[param.name] = v
         return v
 

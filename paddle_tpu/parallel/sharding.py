@@ -25,12 +25,22 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 
-def shard_parameters(program, rules: Dict[str, Tuple[Optional[str], ...]]):
+def shard_parameters(program, rules: Dict[str, Tuple[Optional[str], ...]], mesh=None, batch_axis: str = "dp"):
     """Attach sharding hints by param-name regex.
 
     rules: {name_regex: partition_spec_tuple}, e.g.
         {r".*ffn1.w.*": (None, "tp"), r".*ffn2.w.*": ("tp", None)}
     First matching rule wins.  Returns the number of params annotated.
+
+    With `mesh` the program also carries the mesh the hints refer to (and the
+    axis that splits its feeds' rows, `batch_axis`): `Executor.run` of the
+    program itself, not only of a `CompiledProgram.with_mesh` round it, then
+    places every persistable as its hint says.  Hint the START-UP program too
+    and its draws are born split: no chip ever holds a hinted parameter whole.
+    An optimizer built afterwards gives its accumulators their parameter's hint,
+    in both programs.  A later `CompiledProgram.with_mesh` has to name the same
+    devices in the same order (`make_mesh`'s default order) and the same batch
+    axis: `Executor.run` refuses another.
     """
     count = 0
     compiled = [(re.compile(pat), spec) for pat, spec in rules.items()]
@@ -42,6 +52,8 @@ def shard_parameters(program, rules: Dict[str, Tuple[Optional[str], ...]]):
                 program.sharding_hints[v.name] = tuple(spec)
                 count += 1
                 break
+    if mesh is not None:
+        program.sharding_mesh, program.sharding_batch_axis = mesh, batch_axis
     program._bump()
     return count
 
