@@ -15,7 +15,19 @@ are ops of the program.  The bias and the softplus are the op's (the published
 kernel's `delta_bias` / `delta_softplus`), so that they and everything after
 them are float32 whatever the activations' dtype.
 
-The form (every platform; no kernel yet): ONE `lax.scan` over chunks of
+Two paths, one rule (`_scan_path`, from what the lowering observes: the
+platform, the mesh, the shapes; nothing a process or a program can set).
+
+"kernels", on the TPU where the channels are whole registers a state index:
+the two Pallas kernels of `ops/ssm_kernels.py`, which run the recurrence above
+token by token on a block of channels' state held in VMEM, forward and, under a
+`jax.custom_vjp` (`kernel_selective_scan`), transposed: backward keeps the
+state every chunk of 64 tokens STARTS from ([T / 64, b, N, d] float32: 42 MB a
+row of 8192 tokens of 5120 channels) and the transposed kernel makes a chunk's
+states again in VMEM; nothing of [T, N, d] goes to HBM.
+
+"xla", everywhere else (the CPU, odd shapes, a mesh that splits more than the
+batch; what the tests hold the kernels to): ONE `lax.scan` over chunks of
 `chunk` tokens that carries the state [b, N, d]; inside a chunk the recurrence
 is a `jax.lax.associative_scan` over the pairs (a_t, u_t) = (exp(dt_t A), dt_t
 x_t B_t) under (a, u) . (a', u') = (a a', a' u + u'), which is stable however
@@ -27,16 +39,18 @@ chunks of 8, an eighth of the states) and makes a chunk's [chunk, N, d] arrays
 again; nothing of [T, d, N] (1.34 GB a sequence of 4096 a layer) outlives a
 chunk.  The state lies [N, d], channels
 last: an array that ends in 16 is tiled to 128 lanes and wastes seven eighths
-of them (PERF.md, PR 42).  T need not be a whole number of chunks: the tail is
-padded with steps of zero (dt = 0: a = 1, u = 0), which leave the state alone.
+of them (PERF.md, PR 42).  In both paths T need not be a whole number of chunks:
+the tail is padded with steps of zero (dt = 0: a = 1, u = 0), which leave the
+state alone.
 
 Under a mesh whose batch axis splits the rows and nothing else the whole op
 runs in a `shard_map` over that axis (`ops.common.over_batch_shards`): a chip
-scans its own rows, and GSPMD is not asked how to split a loop over the
-sequence.
+scans its own rows by either path (a `pallas_call` cannot be partitioned), and
+GSPMD is not asked how to split a loop over the sequence.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +60,12 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_step_stats
 from ..monitor import MONITOR as _MON
+from . import ssm_kernels
 from .common import batch_shards, first, over_batch_shards
 
-#: Tokens a chunk.  A chunk's arrays are
+#: Tokens a chunk of the XLA form (`_scan_path`: the CPU's and the odd shapes';
+#: the kernels' chunk is `ssm_kernels.CHUNK`, and their state is `_carried`
+#: after as many tokens as here).  A chunk's arrays are
 #: [b, chunk, N, d] float32 and the associative scan makes log2(chunk) levels of
 #: them, which XLA keeps in one fusion only while they are small.  Measured on
 #: a v5e at (1, 8192, 5120) x 16, ms forward | forward and backward
@@ -124,6 +141,91 @@ def chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk=_SSM_C
     return y, final, means
 
 
+def _kernel_seams():
+    """The kernels' own two seams, `ssm_kernels.step_of` and `carried`, where the
+    `jax.numpy` form reads this module's `_step_of` and `_carried` (static
+    arguments of the kernels' `jax.jit`s, so a control that patches one is
+    traced anew: tools/chip_jamba_controls.py patches both pairs)."""
+    return ssm_kernels.step_of, ssm_kernels.carried
+
+
+def _scan_path(platform, mesh, x, a_log, batch_axis=None):
+    """How the op is lowered: "kernels" (`ops/ssm_kernels.py`: a block of
+    channels' state in VMEM, token by token, forward and transposed) on the TPU
+    where the channels are a whole number of the kernels' `UNIT` (whole
+    registers a state index), the state a whole number of sublane tiles, and
+    `batch_shards` is not 0: no mesh, one device, or a mesh that splits the
+    batch alone, where the kernels run on a chip's rows inside the `shard_map`
+    `over_batch_shards` opens (a `pallas_call` cannot be partitioned); else
+    "xla", `chunked_selective_scan`: the CPU's path, the odd shapes' and what
+    the tests hold the kernels to.  TPU v5e, (1, 8192, 5120) x 16, forward |
+    forward + backward of the op alone: PERF.md, PR 48."""
+    whole = x.shape[-1] % ssm_kernels.UNIT == 0 and a_log.shape[-1] % ssm_kernels.GROUP == 0
+    return "kernels" if platform == "tpu" and whole and batch_shards(mesh, batch_axis, x.shape[0]) else "xla"
+
+
+def _kernel_operands(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk, block):
+    """(the kernels' seven operands: x, dt, B, C padded to a whole number of
+    chunks, the tail stepping by exactly 0 (`_NO_STEP`), A transposed [N, d], D
+    and DtBias float32; their static arguments: the chunk, the block, the seams;
+    the padded tokens)."""
+    T, d = x.shape[1:]
+    chunk = min(int(chunk), -(-T // 16) * 16)
+    pad = -T % chunk
+    if pad:
+        x, b_t, c_t = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (x, b_t, c_t))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)), constant_values=_NO_STEP)
+    a_t = -jnp.exp(a_log.astype(jnp.float32)).T
+    return ((x, dt, b_t, c_t, a_t, d_skip.astype(jnp.float32), dt_bias.astype(jnp.float32)),
+            (chunk, block or ssm_kernels.block_of(d), _kernel_seams()), pad)
+
+
+def _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, keep):
+    """(`chunked_selective_scan`'s three results, what backward reads: the
+    chunks' start states where they are kept) of the kernels."""
+    batch, T, d = x.shape
+    with jax.named_scope("selective_scan"):
+        operands, static, pad = _kernel_operands(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk, block)
+        y, final, decays, steps, *kept = ssm_kernels.scan(*operands, *static, keep, kernels == "interpret")
+        real, state = float(batch * T * d), a_log.shape[1]
+        means = (jnp.sum(decays) - float(batch * pad * d * state)) / (real * state), jnp.sum(steps) / real
+    return (y[:, :T], ssm_kernels.channels_last(final), means), tuple(kept)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels="tpu", chunk=ssm_kernels.CHUNK, block=None):
+    """`chunked_selective_scan`'s results from the Pallas kernels of
+    `ops/ssm_kernels.py`, `chunk` tokens and `block` channels a grid step
+    (`ssm_kernels.block_of` the row's unless given; `_scan_path` says when
+    the op comes here); `kernels`: "tpu", or "interpret" for those
+    interpreted (the tests').  The final state and the means are for
+    statistics: backward takes no cotangent for them."""
+    return _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, False)[0]
+
+
+def _kernel_scan_fwd(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block):
+    """The op where it is differentiated: forward keeps the chunks' start states beside the seven inputs."""
+    _MON.counter("lowering.selective_scan_starts_kept").inc()
+    out, kept = _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, True)
+    return out, ((x, dt, a_log, b_t, c_t, d_skip, dt_bias), kept)
+
+
+def _kernel_scan_bwd(kernels, chunk, block, residuals, cotangents):
+    (x, dt, a_log, b_t, c_t, d_skip, dt_bias), (starts,) = residuals
+    _MON.counter("lowering.selective_scan_kernel_transposed_calls").inc()
+    T = x.shape[1]
+    with jax.named_scope("selective_scan"):
+        operands, static, pad = _kernel_operands(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk, block)
+        d_y = jnp.pad(cotangents[0], ((0, 0), (0, pad), (0, 0))) if pad else cotangents[0]
+        dx, ddt, db, dc, da_t, dskip, dbias = ssm_kernels.scan_transposed(*operands, d_y, starts, *static, kernels == "interpret")
+        d_a_log = (da_t * operands[4]).T                                           # A = -exp(ALog): dA / dALog = A
+    return (dx[:, :T], ddt[:, :T], d_a_log.astype(a_log.dtype), db[:, :T].astype(b_t.dtype), dc[:, :T].astype(c_t.dtype),
+            dskip.astype(d_skip.dtype), dbias.astype(dt_bias.dtype))
+
+
+kernel_selective_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+
+
 @register_op("selective_scan")
 def _selective_scan(ctx, op, ins):
     """The chunked recurrence over X, Dt [b, T, d], B, C [b, T, N] with ALog
@@ -134,9 +236,15 @@ def _selective_scan(ctx, op, ins):
     _MON.counter("lowering.selective_scan_ops").inc()
     _MON.counter("lowering.selective_scan_chunks").inc(-(-x.shape[1] // min(_SSM_CHUNK, x.shape[1])))
     shards = batch_shards(ctx.mesh, ctx.batch_axis, x.shape[0])
+    # "interpret" is the tests': the kernels interpreted where no chip is
+    kernels = {"kernels": "tpu", "interpret": "interpret"}.get(_scan_path(ctx.platform, ctx.mesh, x, a_log, ctx.batch_axis))
+    _MON.counter("lowering.selective_scan_kernel_calls").inc(1 if kernels else 0)
 
     def scan(x, dt, b_t, c_t, a_log, d_skip, dt_bias):
-        y, final, (decay, step) = chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias)
+        if kernels:
+            y, final, (decay, step) = kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels)
+        else:
+            y, final, (decay, step) = chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias)
         decay, step, largest = jax.lax.stop_gradient((decay, step, jnp.max(jnp.abs(final))))
         if shards > 1:   # a chip's rows: the means of equal shares, the largest of all
             decay, step = (jax.lax.pmean(t, ctx.batch_axis) for t in (decay, step))

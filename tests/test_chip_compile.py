@@ -623,6 +623,54 @@ def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_roo
     assert re.search(r"/shared_expert(_\d+)?/op\d+:mul", text) and text.count("/plain_short_conv/") > 0
 
 
+def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recomputed_and_backward(chip):
+    """A small Jamba (the configuration's period cut to four layers, 512 wide:
+    1024 channels a mixer and a state of 16, which `_scan_path` sends to the
+    kernels on the TPU; 64 tokens) trained one step, compiled for the described
+    v5e: every call of the two kernels of `ops/ssm_kernels.py`, forward, made
+    again under the layer's `recompute_scope` and transposed (the one in the
+    `custom_vjp`'s backward), carries an `op_name` that the benchmark's readers
+    `ssm_scan_roofline_share` and `ssm_ms_per_step` match (their own `SCOPE`s,
+    imported), the recomputed ones `recompute_ms_per_step`'s too; no `while` is
+    left under the op's scope."""
+    import paddle_tpu as fluid
+    from benchmark import manifest as mf
+    from benchmark.metrics import recompute_ms_per_step, ssm_ms_per_step, ssm_scan_roofline_share
+    from benchmark.models import jamba
+    from paddle_tpu.core import executor as ex
+
+    cfg = dict(mf.read_json("benchmark/configs/ai21-jamba2-3b.json"), hidden_size=512, intermediate_size=96, mamba_dt_rank=4,
+               num_attention_heads=4, num_key_value_heads=1, vocab_size=96, num_hidden_layers=4, attn_layer_period=4,
+               attn_layer_offset=2)
+    cfg["layer_types"] = jamba.layer_types(cfg)
+    job = dict(mf.read_json("benchmark/traffic/train-ssm-fsdp4.json"), seq_len=64, batch_per_chip=1)
+    del job["mesh_shape"], job["mesh_axes"]
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = jamba.build(cfg, job)
+    scope = fluid.Scope()
+    for v in startup.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    feeds = {n: jax.ShapeDtypeStruct((1, 64), I32) for n in jamba.FEEDS}
+    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu", feed_shapes={n: s.shape for n, s in feeds.items()})
+
+    def on_chip(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+
+    text = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names}, {n: on_chip(scope.find_var(n)) for n in step.ro_names},
+                          {n: on_chip(s) for n, s in feeds.items()}, on_chip(jax.random.PRNGKey(0))).compile().as_text()
+    kernels = sorted({name for name in recompute_ms_per_step.op_names(text).values()      # a call's operands' copies carry its name too
+                      if name.endswith(("/selective_scan/pallas_call", "/selective_scan_transposed/pallas_call"))})
+    assert all(ssm_scan_roofline_share.SCOPE.search(name) and ssm_ms_per_step.SCOPE.search(name) for name in kernels), kernels
+    transposed = [name for name in kernels if name.endswith("/selective_scan_transposed/pallas_call")]
+    again = [name for name in kernels if recompute_ms_per_step.SCOPE in name]
+    forward = [name for name in kernels if name not in transposed and name not in again]
+    assert len(forward) == len(again) == len(transposed) == 3, kernels             # the three Mamba layers, each way
+    assert all("transpose(" in name for name in transposed) and not any("transpose(" in name for name in forward)
+    assert all(name.endswith("/selective_scan/pallas_call") for name in again)
+    assert not re.search(r'op_name="[^"]*op\d+:selective_scan/[^"]*while', text)
+
+
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [
     ("ln", (256, 128, 768), BF16, True),
     ("ln", (7, 33, 768), BF16, True),          # 231 rows: one whole-array slab

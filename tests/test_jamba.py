@@ -4,7 +4,12 @@
     recurrence: forward, the final state and `jax.grad` of every input, at
     several chunk lengths (one that does not divide the sequence among them) and
     both dtypes, at mild and at strong steps; its statistics; `infer=`, the
-    planner row, `analysis.verify`;
+    planner row, `analysis.verify`; since ISSUE 48 every case also by the Pallas
+    kernels of `ops/ssm_kernels.py`, INTERPRETED (their gradients, which are a
+    kernel of their own, against `jax.grad` of the XLA form), the start states
+    they keep, their two seams, `_scan_path`'s rule, the three counters, the
+    whole operands' gradients under the four-device batch mesh; (f) has a whole
+    train step through them;
 (b) `short_conv`'s optional bias against four shifted multiply-adds, forward
     and gradients, and a program without one lowering what it lowered;
 (c) `recompute_scope`: the marked ops are ops of the block, one
@@ -40,7 +45,7 @@ from paddle_tpu import layers, monitor  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
-from paddle_tpu.ops import nn_ops, ssm_ops  # noqa: E402
+from paddle_tpu.ops import nn_ops, ssm_kernels, ssm_ops  # noqa: E402
 from paddle_tpu.ops.common import batch_shards  # noqa: E402
 
 
@@ -87,40 +92,159 @@ def recurrence_with_state(x, dt, b, c, a_log, d_skip, dt_bias):
 SCAN_CASES = [(2, 50, 16, "float32", 0.0), (2, 50, 7, "float32", 0.0), (1, 64, 64, "float32", 0.0),
               (1, 33, 1, "float32", 0.0), (2, 40, 128, "float32", 0.0), (1, 96, 32, "float32", 6.0),
               (2, 50, 16, "bfloat16", 0.0), (1, 45, 8, "bfloat16", 0.0)]
+#: the interpreted kernels' channels a grid step, of 16: every case has a row of two channel blocks; the chunk is the case's, in
+#: whole groups of eight tokens (50 tokens in chunks of 16 or 8: four and seven chunks, the last with a padded tail)
+KERNEL_BLOCK = 8
 
 
+def scan_of(path, chunk):
+    """`chunked_selective_scan`'s results by the XLA form or by the interpreted
+    kernels, as a function of `scan_inputs`' seven arrays."""
+    if path == "xla":
+        return lambda x, dt, b, c, al, ds, bias: ssm_ops.chunked_selective_scan(x, dt, al, b, c, ds, bias, chunk)
+    chunk = -(-chunk // ssm_kernels.GROUP) * ssm_kernels.GROUP
+    return lambda x, dt, b, c, al, ds, bias: ssm_ops.kernel_selective_scan(x, dt, al, b, c, ds, bias, "interpret", chunk, KERNEL_BLOCK)
+
+
+def through(fn):
+    return lambda *a: jnp.sum(jnp.sin(jnp.asarray(fn(*a)[0], jnp.float32)))
+
+
+@pytest.mark.parametrize("path", ["xla", "interpret"])
 @pytest.mark.parametrize("rows,length,chunk,dtype,step_bias", SCAN_CASES)
-def test_the_chunked_scan_is_the_recurrence_forward_and_backward(rows, length, chunk, dtype, step_bias):
-    inputs = scan_inputs(1, rows, length, 8, 4, dtype, step_bias)
+def test_the_chunked_scan_is_the_recurrence_forward_and_backward(rows, length, chunk, dtype, step_bias, path):
+    """Both forms against the token-by-token recurrence: the output, the final
+    state, the statistics; the XLA form's seven gradients against `jax.grad` of
+    the recurrence, the kernels' (their own transposed kernel) against
+    `jax.grad` of the XLA form."""
+    channels, state = (8, 4) if path == "xla" else (16, 8)
+    inputs = scan_inputs(1, rows, length, channels, state, dtype, step_bias)
     want, want_state = recurrence_with_state(*inputs)
-    got, state, (decay, step) = ssm_ops.chunked_selective_scan(*inputs[:2], inputs[4], *inputs[2:4], *inputs[5:], chunk)
-    assert got.dtype == jnp.dtype(dtype) and got.shape == (rows, length, 8)
+    got, final, (decay, step) = scan_of(path, chunk)(*inputs)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (rows, length, channels)
     agree(got, want, tol=1e-5 if dtype == "float32" else 1e-2)
-    agree(state.swapaxes(1, 2), want_state, tol=2e-5)                     # the op's state lies [N, d], channels last
+    agree(final.swapaxes(1, 2), want_state, tol=2e-5)                     # the op's state lies [N, d], channels last
     soft = jax.nn.softplus(jnp.asarray(inputs[1], jnp.float32) + inputs[6])
     agree(step, soft.mean(), tol=1e-5)                                    # the padded tail counts for nothing
     agree(decay, jnp.exp(-soft[..., None] * jnp.exp(inputs[4])).mean(), tol=1e-5)
 
-    def through(fn):
-        return lambda *a: jnp.sum(jnp.sin(jnp.asarray(fn(*a), jnp.float32)))
-
-    mine = jax.grad(through(lambda x, dt, b, c, al, ds, bias: ssm_ops.chunked_selective_scan(x, dt, al, b, c, ds, bias, chunk)[0]),
-                    argnums=tuple(range(7)))(*inputs)
-    theirs = jax.grad(through(lambda *a: recurrence_with_state(*a)[0].astype(dtype)), argnums=tuple(range(7)))(*inputs)
+    mine = jax.grad(through(scan_of(path, chunk)), argnums=tuple(range(7)))(*inputs)
+    if path == "xla":
+        theirs = jax.grad(through(lambda *a: (recurrence_with_state(*a)[0].astype(dtype),)), argnums=tuple(range(7)))(*inputs)
+    else:
+        theirs = jax.grad(through(scan_of("xla", chunk)), argnums=tuple(range(7)))(*inputs)
     for g, w in zip(mine, theirs):
-        assert np.isfinite(np.asarray(g, "f4")).all()
+        assert g.dtype == w.dtype and np.isfinite(np.asarray(g, "f4")).all()
         agree(g, w, tol=2e-5 if dtype == "float32" else 5e-2)
 
 
-def test_a_step_of_sixty_nats_a_token_overflows_nothing():
+@pytest.mark.parametrize("path", ["xla", "interpret"])
+def test_a_step_of_sixty_nats_a_token_overflows_nothing(path):
     """A strong step (dt ~ 60, A down to -4: the decay underflows to 0) is
     finite forward and backward: no exponent in the op is positive."""
     inputs = scan_inputs(2, 1, 70, 8, 4, step_bias=60.0)
-    y, state, _ = ssm_ops.chunked_selective_scan(*inputs[:2], inputs[4], *inputs[2:4], *inputs[5:], 16)
-    grads = jax.grad(lambda x, dt: ssm_ops.chunked_selective_scan(x, dt, inputs[4], *inputs[2:4], *inputs[5:], 16)[0].sum(),
-                     argnums=(0, 1))(*inputs[:2])
+    y, state, _ = scan_of(path, 16)(*inputs)
+    grads = jax.grad(lambda *a: scan_of(path, 16)(*a)[0].sum(), argnums=tuple(range(7)))(*inputs)
     assert all(np.isfinite(np.asarray(t)).all() for t in (y, state, *grads))
     agree(y, recurrence_with_state(*inputs)[0], tol=1e-5)
+
+
+def test_the_kernels_keep_the_state_every_chunk_starts_from():
+    """What forward keeps where the op is differentiated: the XLA form's
+    carried state at every chunk boundary (its final state on the tokens before
+    it), in the kernels' own tiles."""
+    inputs = scan_inputs(4, 2, 50, 16, 8)
+    x, dt, b, c, a_log, d_skip, bias = inputs
+    (y, final, _), (starts,) = ssm_ops._kernel_scan(x, dt, a_log, b, c, d_skip, bias, "interpret", 16, KERNEL_BLOCK, True)
+    assert starts.shape == (4, 2, 2, 8, ssm_kernels.GROUP, 1)               # 50 tokens: three chunks of 16 and a padded tail
+    starts = ssm_kernels.channels_last(starts)
+    assert not np.asarray(starts[0]).any()
+    for k in (1, 2, 3):
+        before = ssm_ops.chunked_selective_scan(x[:, :16 * k], dt[:, :16 * k], a_log, b[:, :16 * k], c[:, :16 * k], d_skip, bias)[1]
+        agree(starts[k], before, tol=1e-6)
+    agree(final, ssm_ops.chunked_selective_scan(x, dt, a_log, b, c, d_skip, bias)[1], tol=1e-6)
+
+
+@pytest.mark.parametrize("seam", ["step_of", "carried"])
+def test_the_seams_bite_in_the_kernels(seam, monkeypatch):
+    """`ssm_kernels.step_of` and `carried`, patched as
+    tools/chip_jamba_controls.py patches them beside `ssm_ops`' pair, change what
+    the interpreted kernels give (they are static arguments of the kernels'
+    `jax.jit`s: a patched one is traced anew), by what the XLA form's change it."""
+    inputs = scan_inputs(5, 1, 48, 16, 8)
+    sound = scan_of("interpret", 16)(*inputs)[0]
+    low = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    step, step_in_kernel = ssm_ops._step_of, ssm_kernels.step_of
+    faults = {"step_of": lambda of: (lambda dt, bias: low(of(dt, bias))), "carried": lambda of: low}[seam]
+    monkeypatch.setattr(ssm_ops, "_" + seam, faults(step))
+    monkeypatch.setattr(ssm_kernels, seam, faults(step_in_kernel))
+    faulty, faulty_xla = scan_of("interpret", 16)(*inputs)[0], scan_of("xla", ssm_ops._SSM_CHUNK)(*inputs)[0]
+    moved = float(jnp.abs(faulty - sound).max() / jnp.abs(sound).max())
+    assert moved > 1e-4, moved
+    agree(faulty, faulty_xla, tol=2e-5)                                   # the state is handed on every eight tokens in both
+
+
+MESH4 = SimpleNamespace(size=4, shape={"dp": 4})
+MESH22 = SimpleNamespace(size=4, shape={"dp": 2, "tp": 2})
+
+
+@pytest.mark.parametrize("platform,mesh,axis,shape,state,path", [
+    ("tpu", None, None, (1, 8192, 5120), 16, "kernels"),
+    ("tpu", SimpleNamespace(size=1, shape={"dp": 1}), "dp", (4, 8192, 5120), 16, "kernels"),
+    ("tpu", MESH4, "dp", (4, 8192, 5120), 16, "kernels"),     # the rows split four ways and nothing else: a chip scans its own
+    ("tpu", MESH4, "dp", (8, 4096, 1024), 8, "kernels"),
+    ("cpu", None, None, (1, 8192, 5120), 16, "xla"),
+    ("tpu", None, None, (1, 8192, 100), 16, "xla"),           # no whole number of the kernels' channel blocks
+    ("tpu", None, None, (1, 8192, 5120 + 128), 16, "xla"),
+    ("tpu", None, None, (1, 8192, 5120), 4, "xla"),           # a state that is no whole sublane tile
+    ("tpu", MESH22, "dp", (4, 8192, 5120), 16, "xla"),        # channels may be split too: GSPMD's form
+    ("tpu", MESH4, None, (4, 8192, 5120), 16, "xla"),         # no batch axis known
+    ("tpu", MESH4, "dp", (6, 8192, 5120), 16, "xla"),         # rows that 4 does not divide
+])
+def test_the_scans_rule_reads_the_platform_the_mesh_and_the_shapes(platform, mesh, axis, shape, state, path):
+    x, a_log = jax.ShapeDtypeStruct(shape, jnp.bfloat16), jax.ShapeDtypeStruct((shape[-1], state), jnp.float32)
+    assert ssm_ops._scan_path(platform, mesh, x, a_log, axis) == path
+
+
+KERNEL_COUNTERS = tuple(f"lowering.selective_scan_{n}" for n in ("kernel_calls", "kernel_transposed_calls", "starts_kept"))
+
+
+def scan_op_gradients(ctx, rows):
+    """(Out, the gradients of sum(sin(Out)) by ALog, D and DtBias) of the op
+    lowered under `ctx` on `rows` rows of 24 tokens, 16 channels, a state of 8."""
+    inputs = scan_inputs(6, rows, 24, 16, 8)
+    op = SimpleNamespace(type="selective_scan", attr=lambda n, d=None: d)
+
+    def out(*arrays):
+        return ssm_ops._selective_scan(ctx, op, {k: [v] for k, v in zip(("X", "Dt", "B", "C", "ALog", "D", "DtBias"), arrays)})["Out"]
+
+    return jax.jit(out)(*inputs), jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(out(*a))), argnums=(4, 5, 6)))(*inputs)
+
+
+def test_under_a_batch_mesh_the_kernels_run_on_a_chips_rows_and_the_whole_operands_gradients_are_summed_once(monkeypatch):
+    """The `custom_vjp` stands inside the `shard_map`: ALog's, D's and DtBias'
+    gradients under the four-device batch mesh are one device's on the same
+    rows (each chip's share summed over the chips once), and the counters count
+    the op's two kernels and the kept starts, which the CPU's path leaves at 0."""
+    mesh = fluid.parallel.make_mesh((4,), ("dp",))
+    monitor.reset()
+    monitor.enable()
+    try:
+        xla = scan_op_gradients(LoweringContext(jax.random.PRNGKey(0)), 4)
+        assert [monitor.counter(n).value for n in KERNEL_COUNTERS] == [0, 0, 0]
+        monkeypatch.setattr(ssm_ops, "_scan_path", lambda *a, **k: "interpret")
+        alone = scan_op_gradients(LoweringContext(jax.random.PRNGKey(0)), 4)
+        assert [monitor.counter(n).value for n in KERNEL_COUNTERS] == [2, 1, 1]      # lowered twice: Out, and Out's gradients
+        split = scan_op_gradients(LoweringContext(jax.random.PRNGKey(0), mesh=mesh, platform="cpu", batch_axis="dp"), 4)
+        assert [monitor.counter(n).value for n in KERNEL_COUNTERS] == [4, 2, 2]
+        assert monitor.counter("lowering.kernels_under_shard_map").value == 2
+    finally:
+        monitor.disable()
+        monitor.reset()
+    agree(split[0], alone[0], tol=1e-6)
+    for mine, one_device, xla_form in zip(split[1], alone[1], xla[1]):
+        agree(mine, one_device, tol=1e-5)
+        agree(mine, xla_form, tol=2e-5)
 
 
 def test_the_op_publishes_its_state_and_takes_any_length():
@@ -561,10 +685,6 @@ def test_the_rules_name_what_build_causal_lm_names():
     assert fluid.parallel.shard_parameters(main, rules) == len(rules) and main.sharding_mesh is None
 
 
-MESH4 = SimpleNamespace(size=4, shape={"dp": 4})
-MESH22 = SimpleNamespace(size=4, shape={"dp": 2, "tp": 2})
-
-
 @pytest.mark.parametrize("mesh,axis,rows,path", [
     (None, None, 4, "block_causal"),
     (MESH4, "dp", 4, "block_causal"),       # the rows split four ways and nothing else: what a chip sees decides
@@ -629,7 +749,6 @@ def test_steps_through_train_loop_publish_the_ssm_state_and_the_counters_count_t
         batches = [jamba.make_batch(rng, cfg, job, 4) for _ in range(4)]
         mesh = fluid.parallel.make_mesh(tuple(job["mesh_shape"]), tuple(job["mesh_axes"]))
         program = fluid.CompiledProgram(main).with_mesh(mesh, batch_axis="dp")
-        lowered0 = {n: monitor.counter(n).value for n in ("lowering.selective_scan_ops", "lowering.selective_scan_chunks")}
         fluid.train_loop(exe, program, iter(batches), [loss], scope=scope, log_period=2)
         records = [r for r in monitor.step_records() if r.get("kind") == "ssm_state"]
         placed = [r for r in monitor.step_records() if r.get("kind") == "state_placed"]
@@ -642,3 +761,28 @@ def test_steps_through_train_loop_publish_the_ssm_state_and_the_counters_count_t
         assert all(0.2 < d < 1.0 for d in r["decay_mean"]) and all(1e-3 < s < 1.0 for s in r["dt_mean"])
         assert all(np.isfinite(s) and s > 0 for s in r["state_abs_max"])
     assert placed and all(r["devices"] == 4 and r["bytes_sharded_per_device"] > r["bytes_replicated_per_device"] > 0 for r in placed)
+
+
+def test_a_step_through_the_interpreted_kernels_is_the_xla_forms_step_and_the_counters_count_the_layers(float32_run, monkeypatch):
+    """The tiny model's train step with the op's path forced to the kernels,
+    interpreted (three Mamba layers, each inside a `recompute_scope`): the loss
+    and every parameter's gradient (Adam's first moment) are the XLA form's
+    step's from the same seed and batch; the three counters count one a layer
+    (the op's kernel call, the start states kept where it is differentiated, the
+    transposed call), beside `selective_scan_ops`, which keeps counting."""
+    r = float32_run
+    monkeypatch.setattr(ssm_ops, "_scan_path", lambda *a, **k: "interpret")
+    monitor.reset()
+    monitor.enable()
+    try:
+        with jax.default_matmul_precision("highest"):
+            cfg, job, main, loss, names, scope, exe = tiny_model("float32")
+            step_loss, = exe.run(main, feed=jamba.make_batch(np.random.RandomState(4), cfg, job, 4), fetch_list=[loss], scope=scope)
+        counted = [monitor.counter(n).value for n in ("lowering.selective_scan_ops",) + KERNEL_COUNTERS]
+    finally:
+        monitor.disable()
+        monitor.reset()
+    assert counted == [3, 3, 3, 3]
+    assert abs(float(np.asarray(step_loss).reshape(-1)[0]) - r.step_loss) < 1e-6 * r.step_loss
+    for name in PARAMS:
+        agree(np.asarray(scope.find_var(name + "_moment1_0")), r.moments[name], tol=2e-5)

@@ -6,10 +6,11 @@ it must refuse beside the sound one (PERF.md, section 6, PR 47).  Two faults
 go into THE PROGRAM (the op's module is patched and the check rows run again
 through a new executor), two into THE REFERENCE (the errors are differences):
 
-  * `scan_bf16_state`: the scan's state rounded to bf16 at every chunk
-    boundary (`ssm_ops._carried`): `SCAN_RTOL`;
+  * `scan_bf16_state`: the scan's state rounded to bf16 where eight tokens
+    hand it to the next (`ssm_ops._carried` in the XLA form, `ssm_kernels.carried`
+    in the kernels: both are patched, the cell's path reads its own): `SCAN_RTOL`;
   * `scan_bf16_step`: the step softplus(dt + b_dt), and with it the decay's
-    exponent, rounded to bf16 (`ssm_ops._step_of`): `SCAN_RTOL`;
+    exponent, rounded to bf16 (`ssm_ops._step_of`, `ssm_kernels.step_of`): `SCAN_RTOL`;
   * `reference_default_precision`: the reference's float32 products at the
     chip's default precision (bf16 operands), the nearest precision below the
     one the reference states: `REFERENCE_RTOL` / `QK_RTOL` / `INNER_RTOL`;
@@ -39,7 +40,7 @@ import numpy as np
 import paddle_tpu as fluid
 from benchmark import manifest as mf
 from benchmark.models import jamba, lfm2
-from paddle_tpu.ops import ssm_ops
+from paddle_tpu.ops import ssm_kernels, ssm_ops
 
 CHECK_ROWS = 8  # as benchmark/runners/train.py
 TINY = (dict(hidden_size=64, intermediate_size=96, mamba_dt_rank=4, num_attention_heads=4, vocab_size=96,
@@ -52,17 +53,24 @@ LIMITS = {"logit_error": "REFERENCE_RTOL", "loss_error": "REFERENCE_RTOL", "scan
 
 
 @contextlib.contextmanager
-def patched(module, name, value):
-    sound = getattr(module, name)
-    setattr(module, name, value)
+def patched(*seams):
+    """Each (module, name, value) set for the block."""
+    sound = [getattr(module, name) for module, name, _ in seams]
+    for module, name, value in seams:
+        setattr(module, name, value)
     try:
         yield
     finally:
-        setattr(module, name, sound)
+        for (module, name, _), value in zip(seams, sound):
+            setattr(module, name, value)
 
 
 def low(t):
     return jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)   # XLA takes a pair of casts out
+
+
+def low_in_kernel(t):
+    return t.astype(jax.numpy.bfloat16).astype(jax.numpy.float32)          # Mosaic takes none out, and has no `reduce_precision`
 
 
 def main(seed: int, only=()):
@@ -109,13 +117,14 @@ def main(seed: int, only=()):
 
     want, sound = reference(), check_rows()
     report("sound", sound, want)
-    step = ssm_ops._step_of
-    program_faults = {"scan_bf16_state": (ssm_ops, "_carried", low),
-                      "scan_bf16_step": (ssm_ops, "_step_of", lambda dt, bias: low(step(dt, bias)))}
-    for name, seam in program_faults.items():
+    step, step_in_kernel = ssm_ops._step_of, ssm_kernels.step_of
+    program_faults = {"scan_bf16_state": ((ssm_ops, "_carried", low), (ssm_kernels, "carried", low_in_kernel)),
+                      "scan_bf16_step": ((ssm_ops, "_step_of", lambda dt, bias: low(step(dt, bias))),
+                                         (ssm_kernels, "step_of", lambda dt, bias: low_in_kernel(step_in_kernel(dt, bias))))}
+    for name, seams in program_faults.items():
         if only and name not in only:
             continue
-        with patched(*seam):
+        with patched(*seams):
             report(name, check_rows(), want)
     if not only or "no_inner_norms" in only:
         report("no_inner_norms", sound, reference(inner_norms=False))
