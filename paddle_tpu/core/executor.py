@@ -639,12 +639,12 @@ class _CompiledStep:
                 lowering.annotate(fenced=fenced.value - fenced0)
                 # which attention each fused_attention op of this program took,
                 # what its `repeat` ops lowered (passes, body ops, recomputed passes),
-                # and how many of its `kda` ops took the kernels
+                # how many of its `kda` ops took the kernels, and of its `moe_experts` ops' ways back to token order
                 lowering.annotate(**{
                     name[len("lowering."):]: n - counted0.get(name, 0)
                     for name, n in _MON.counter_values().items()
                     if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_",
-                                        "lowering.selective_scan_", "lowering.kernels_under_"))
+                                        "lowering.selective_scan_", "lowering.kernels_under_", "lowering.token_sum_"))
                     and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)

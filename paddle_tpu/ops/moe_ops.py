@@ -28,7 +28,14 @@ each token's k rows), written as each other's transposes: the derived
 transpose of a row gather is a scatter-add, and here the permutation's inverse
 is known, so the transpose is the other gather (TPU v5e, one OLMoE layer's
 experts forward and backward: 66.2 ms against 74.7 derived; PERF.md, PR 26).
-Both promise their indices, so no fill value is selected over their result.
+`_rows_by_expert` is XLA's gather, which promises its indices (no fill value
+is selected over its result) and reads and writes its 604 MB at the HBM's speed
+(0.8 ms): nothing for a kernel to win.  `_sum_by_token` is a Pallas kernel on
+one TPU device (`ops/moe_kernels.py`, `_token_sum_path`): as XLA's gather and
+sum it wrote [tokens, k, hidden] at a third of the HBM's speed and read it
+again, 5.4 ms a call, where the kernel brings a block of tokens' rows into VMEM
+run by run and sums them there, 1.56 ms (PERF.md, PR 49); XLA's form stays the
+CPU's, a mesh's and the odd shapes' path, and what the tests hold the kernel to.
 The router's weights multiply the hidden rows, in expert order, so nothing
 else passes over a [rows, hidden] array, forward or backward.  The matrices
 are float32 masters: `grouped_matmul` casts each once for the forward and
@@ -47,6 +54,7 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_step_stats
 from ..monitor import MONITOR as _MON
+from . import moe_kernels
 from .common import first, match_dtype
 
 
@@ -277,31 +285,50 @@ def _take_rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-# The op's two row operations, each the other's transpose.  `order` is a
-# permutation of the tokens x k (token, slot) assignments, `inverse` its
-# inverse; row i of the expert-ordered side is assignment order[i], of
-# token order[i] // k.
+# The op's two row operations, each the other's transpose.  `route` = (order,
+# inverse, expert): `order` is the stable permutation that sorts the tokens x k
+# (token, slot) assignments by expert, `inverse` its inverse, `expert` [T, k]
+# each assignment's expert; row i of the expert-ordered side is assignment
+# order[i], of token order[i] // k.  `kernel`: None for the `jax.numpy` form of
+# the way back, else `_token_sum_path`'s (experts, interpreted).
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_by_expert(x, order, inverse, k):
+def _token_sum_path(platform, mesh, x, k, experts):
+    """How `_sum_by_token` is lowered for tokens `x` [T, d] of k rows each:
+    "kernel" (`ops/moe_kernels.py`: a block of tokens' rows brought into VMEM
+    run by run and summed there by a 0/1 product) on the TPU, on one device (a
+    `pallas_call` cannot be partitioned: `nn_ops._attention_path`'s rule), where
+    `moe_kernels.fits`: a row is whole lane tiles, the tokens whole blocks, bf16
+    or float32, and the two buffers fit; else "xla", the gather and the sum
+    below: the CPU's path, a mesh's, the odd shapes', and what the tests hold
+    the kernel to.  TPU v5e, (131072, 2048) bf16 rows, k = 8: PERF.md, PR 49."""
+    one_device = mesh is None or mesh.size == 1
+    return "kernel" if platform == "tpu" and one_device and moe_kernels.fits(*x.shape, k, x.dtype, experts) else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rows_by_expert(x, route, k, kernel=None):
     """Tokens [T, d] -> one row per assignment [T k, d], in `order`."""
-    return _take_rows(x, order // k)
+    return _take_rows(x, route[0] // k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_by_token(rows, order, inverse, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _sum_by_token(rows, route, k, kernel=None):
     """Rows [T k, d] in `order` -> tokens [T, d]: each token's k rows,
     summed in float32."""
+    _, inverse, expert = route
+    if kernel:
+        _MON.counter("lowering.token_sum_kernel_calls").inc()
+        return moe_kernels.token_sum(rows, inverse.reshape(-1, k), expert.reshape(-1, k), *kernel)
     rows = _take_rows(rows, inverse).reshape(-1, k, rows.shape[-1])
     return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
 
 
 _rows_by_expert.defvjp(
-    lambda x, order, inverse, k: (_rows_by_expert(x, order, inverse, k), (order, inverse)),
-    lambda k, res, g: (_sum_by_token(g, *res, k), None, None))
+    lambda x, route, k, kernel=None: (_rows_by_expert(x, route, k, kernel), route),
+    lambda k, kernel, route, g: (_sum_by_token(g, route, k, kernel), None))
 _sum_by_token.defvjp(
-    lambda rows, order, inverse, k: (_sum_by_token(rows, order, inverse, k), (order, inverse)),
-    lambda k, res, g: (_rows_by_expert(g, *res, k), None, None))
+    lambda rows, route, k, kernel=None: (_sum_by_token(rows, route, k, kernel), route),
+    lambda k, kernel, route, g: (_rows_by_expert(g, route, k, kernel), None))
 
 
 @jax.custom_vjp
@@ -443,14 +470,18 @@ def _moe_experts(ctx, op, ins):
                 "Held": n_held.astype(jnp.int32).reshape((1,))}
     order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
-    rows = _rows_by_expert(x2, order, inverse, k)
+    route = (order, inverse, top_i.reshape(-1, k).astype(jnp.int32))
+    experts = load.shape[0]
+    # "interpret" is the tests': the kernel interpreted where no chip is
+    kernel = {"kernel": (experts, False), "interpret": (experts, True)}.get(_token_sum_path(ctx.platform, ctx.mesh, x2, k, experts))
+    rows = _rows_by_expert(x2, route, k, kernel)
     # each row's router probability, in expert order
     weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
     gate = grouped_matmul(rows, w_gate, load, ctx.platform)
     up = grouped_matmul(rows, w_up, load, ctx.platform)
     hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * weight).astype(x.dtype)
     down = grouped_matmul(hidden, w_down, load, ctx.platform)
-    out = _sum_by_token(down, order, inverse, k)
+    out = _sum_by_token(down, route, k, kernel)
     return {"Out": out.reshape(x.shape),
             "Dropped": (tokens * k - jnp.sum(load)).astype(jnp.int32).reshape((1,))}
 
@@ -890,32 +921,40 @@ def _cost_moe_router(ctx):
 
 #: Passes of the forward lowering over its (token, slot) rows, in arrays read
 #: or written.  [rows, hidden]: written by the gather, read by the gate and by
-#: the up product, written by the down product, read and written by the gather
-#: back to token order, read by the sum over k.  [rows, width]: written by gate
-#: and up, both read and one written by SiLU x up x weight, read by the down
-#: product.  tests/test_chip_compile.py counts them in the compiled program.
-_ROW_PASSES = {"hidden": 7, "width": 6}
+#: the up product, written by the down product, and on the way back to token
+#: order read once by the kernel (`_token_sum_path`); "hidden_xla": where the way
+#: back is XLA's, the gather reads and writes them and the sum over k reads them
+#: again.  [rows, width]: written by gate and up, both read and one written by
+#: SiLU x up x weight, read by the down product.  tests/test_chip_compile.py
+#: counts them in the compiled program.
+_ROW_PASSES = {"hidden": 5, "hidden_xla": 7, "width": 6}
 
 
 def _cost_moe_experts(ctx):
     """Useful arithmetic of the three grouped products over the (token,
     slot) rows, 2 per multiply-add, whatever a kernel pads; traffic: every
-    expert's three matrices once and `_ROW_PASSES` over the rows.  For a layer
-    that holds a share the rows are the bound's: an upper bound since the row
-    operations stop after the step's last live pass, which no plan can know."""
+    expert's three matrices once and `_ROW_PASSES` over the rows, the way back
+    the kernel's where the shapes are ones it takes (the plan is the chip's).
+    For a layer that holds a share the rows are the bound's: an upper bound
+    since the row operations stop after the step's last live pass, which no plan
+    can know."""
     gate = ctx.in_shape("WGate")
     if gate is None or ctx.in_shape("TopKIndex") is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()
     rows, d, f = ctx.in_elems("TopKIndex"), gate[1], gate[2]
     held, load = ctx.op.attr("held", None), ctx.in_shape("Load")
+    dtype = ctx.env.dtype(ctx.in_name("X"))
+    hidden = _ROW_PASSES["hidden_xla"]
     if held is not None and load is not None:
         # the passes are over the bound; the arithmetic is the uniform share's
         passes = _held_rows_bound(rows, held[1], load[0])
         rows = rows * held[1] // load[0]
     else:
-        passes = rows
-    item = 2 if ctx.env.dtype(ctx.in_name("X")) in ("bfloat16", "float16") else 4
-    moved = passes * (_ROW_PASSES["hidden"] * d + _ROW_PASSES["width"] * f) * item
+        passes, k = rows, ctx.in_shape("TopKIndex")[-1]
+        if held is None and moe_kernels.fits(rows // k, d, k, dtype, gate[0]):
+            hidden = _ROW_PASSES["hidden"]
+    item = 2 if dtype in ("bfloat16", "float16") else 4
+    moved = passes * (hidden * d + _ROW_PASSES["width"] * f) * item
     return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
 
 

@@ -93,6 +93,13 @@ def _gmm(rows, weights, sizes):
     return grouped_matmul(rows, weights, sizes, "tpu")
 
 
+def _token_sum(experts):
+    """The routed experts' way back to token order as `_sum_by_token` calls it on a TPU (ops/moe_kernels.py)."""
+    from paddle_tpu.ops.moe_kernels import token_sum
+
+    return lambda rows, index, expert: token_sum(rows, index, expert, experts)
+
+
 # BERT-base: batch 256 x seq 128 rows (the `pretrain-s128` cell's), d_model 768,
 # d_ff 3072, vocab 30522, 12 heads of 64.  ResNet-50: NCHW bf16, batch 128
 # training and the serving buckets' batch 8.
@@ -213,6 +220,12 @@ CASES = {
         _gmm, [((131072, 2048), BF16), ((64, 2048, 1024), F32), ((64,), I32)], (0, 1)),
     "grouped_matmul_olmoe_down_master": (
         _gmm, [((131072, 1024), BF16), ((64, 1024, 2048), F32), ((64,), I32)], (0, 1)),
+    # the down product's rows back to token order, each token's 8 summed in VMEM (PR 49): OLMoE's cell, and float32
+    # rows of 128 experts (the 0/1 product at the highest precision; twice the buffers)
+    "token_sum_olmoe": (
+        _token_sum(64), [((131072, 2048), BF16), ((16384, 8), I32), ((16384, 8), I32)], ()),
+    "token_sum_float32_rows": (
+        _token_sum(128), [((32768, 1024), F32), ((4096, 8), I32), ((4096, 8), I32)], ()),
     "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
     # SDAR-30B-A3B-Chat's cell: 2 sequences of 8192 positions [noised ; clean], 32 query heads on
@@ -275,7 +288,9 @@ def _moe_experts_args(chip):
 #: 1.881 GB at the parent of PR 28 (fill-mode gathers, the weighted combine in
 #: token order), 1.614 GB without those, 1.883 GB with the matrices' gradients
 #: float32 from `tgmm` on (each 268 MB more than a bf16 one, from its kernel
-#: to the end of the program).  The bound is the last reading and a margin.
+#: to the end of the program), 1.891 GB since the way back to token order is a
+#: kernel (PR 49: the [tokens, 8, hidden] arrays it took away were never live
+#: at the peak).  The bound is the last reading and a margin.
 MOE_EXPERTS_TEMP_BYTES = 1.95e9
 
 
@@ -283,26 +298,31 @@ def test_moe_experts_at_olmoe_widths_passes_over_its_rows_no_more_than_it_must(c
     """OLMoE-1B-7B's layer of experts over 4 x 4096 tokens, forward and the
     gradients of X, TopKProb and the three float32 master matrices: outside
     the kernels no `select` writes an [rows, hidden] array (a gather that
-    promises its indices has no fill value to select) and at most five
-    instructions write one: the gather to rows, the rows' two gradients
-    added, and the two gathers back to token order before the sums over k
-    (forward: the output; backward: X's gradient).  The masters' gradients
-    are the three `tgmm` calls' own float32 results: nothing else writes an
-    f32[experts, ., .] array (no bf16 gradient widened).  PERF.md, PR 28."""
+    promises its indices has no fill value to select) and at most three
+    instructions write one: the gather to rows and the rows' two gradients
+    added (the third is room for one relayout); the two ways back to token
+    order (forward: the output; backward: X's gradient) are two calls of the
+    `token_sum` kernel, which write [tokens, hidden] and nothing of [tokens,
+    8, hidden] (PR 49; two gathers and two sums until then).  The masters'
+    gradients are the three `tgmm` calls' own float32 results: nothing else
+    writes an f32[experts, ., .] array (no bf16 gradient widened).  PERF.md,
+    PR 28."""
     tokens, hidden, width, experts, k = OLMOE_EXPERTS
     args = _moe_experts_args(chip)
     program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(_moe_experts(*a).astype(F32))),
                                  argnums=(0, 1, 4, 5, 6))
     compiled = jax.jit(program).lower(*args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 9  # three products, each with its two transposes
+    assert text.count("tpu_custom_call") >= 11  # three products, each with its two transposes, and the two ways back
+    assert len(re.findall(r'custom_call_target="tpu_custom_call".*token_sum', text)) == 2
+    assert not re.findall(rf"= \w+\[{tokens},{k},{hidden}\]", text)
     rows_by_hidden = rf"= \w+\[{tokens * k},{hidden}\]\S* "
     assert not re.findall(rows_by_hidden + r"select\(", text)
     entry = text[text.index("ENTRY"):]
     written = [line.split(" = ")[0].strip() for line in entry.splitlines()
                if re.search(rows_by_hidden + r"(?!parameter|bitcast|get-tuple-element)", line)
                and "tpu_custom_call" not in line]
-    assert len(written) <= 5, written
+    assert len(written) <= 3, written
     of_the_masters = [line for line in entry.splitlines()
                       if re.search(rf"= f32\[{experts},\d+,\d+\]\S* (?!parameter)", line)]
     assert len(of_the_masters) == 3 and all("tpu_custom_call" in line and "tgmm" in line

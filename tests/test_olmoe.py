@@ -211,18 +211,25 @@ def test_moe_experts_golden_forward_and_gradient(case):
         assert all(float(jnp.abs(g[0]).max()) == 0.0 for g in ours_g[2:])
 
 
-@pytest.mark.parametrize("tokens,k", [(12, 1), (10, 3)])
-def test_rows_by_expert_and_sum_by_token_are_each_others_transpose(tokens, k):
+def route_of(top_i):
+    """`moe_experts`' route of a [T, k] choice: (order, inverse, expert)."""
+    order = np.argsort(top_i.reshape(-1), kind="stable").astype("i4")
+    return jnp.asarray(order), jnp.asarray(np.argsort(order).astype("i4")), jnp.asarray(top_i, jnp.int32)
+
+
+@pytest.mark.parametrize("tokens,k,d,kernel", [(12, 1, 5, None), (10, 3, 5, None), (128, 1, 128, (4, True)), (256, 3, 128, (4, True))],
+                         ids=["xla-12-1", "xla-10-3", "kernel-128-1", "kernel-256-3"])
+def test_rows_by_expert_and_sum_by_token_are_each_others_transpose(tokens, k, d, kernel):
     """The op's two row operations against the transposes jax derives from
     the plain gathers (scatter-adds): each one's values are the other's
-    derived transpose, and so is each one's hand-written VJP."""
-    d = 5
-    order = RNG.permutation(tokens * k).astype("i4")
-    inverse = np.argsort(order).astype("i4")
+    derived transpose, and so is each one's hand-written VJP; the way back
+    as XLA's gather and sum, and as the kernel (interpreted)."""
+    route = order, inverse, _ = route_of(RNG.randint(4, size=(tokens, k)))
+    order, inverse = np.asarray(order), np.asarray(inverse)
     x = jnp.asarray(RNG.randn(tokens, d), jnp.float32)
     rows = jnp.asarray(RNG.randn(tokens * k, d), jnp.float32)
-    to_rows = lambda x: moe_ops._rows_by_expert(x, order, inverse, k)  # noqa: E731
-    to_tokens = lambda rows: moe_ops._sum_by_token(rows, order, inverse, k)  # noqa: E731
+    to_rows = lambda x: moe_ops._rows_by_expert(x, route, k, kernel)  # noqa: E731
+    to_tokens = lambda rows: moe_ops._sum_by_token(rows, route, k, kernel)  # noqa: E731
     agree(to_rows(x), np.asarray(x)[order // k], tol=0)
     scatter_add, = jax.linear_transpose(lambda x: x[order // k], x)(rows)
     agree(to_tokens(rows), scatter_add)
@@ -230,7 +237,113 @@ def test_rows_by_expert_and_sum_by_token_are_each_others_transpose(tokens, k):
     spread, = jax.linear_transpose(lambda rows: rows[inverse].reshape(tokens, k, d).sum(1), rows)(x)
     agree(to_rows(x), spread, tol=0)
     agree(jax.vjp(to_tokens, rows)[1](x)[0], spread, tol=0)
-    agree(jnp.vdot(to_rows(x), rows), jnp.vdot(x, to_tokens(rows)))  # <A x, r> = <x, A' r>
+    agree(jnp.vdot(to_rows(x), rows), jnp.vdot(x, to_tokens(rows)), tol=1e-4)  # <A x, r> = <x, A' r>, two float32 sums of all
+
+
+def token_sum_routing(name, tokens, experts, k):
+    if name == "a_quarter_of_all_rows_to_one_expert":   # of four slots, the first is always expert 3
+        rest = np.stack([RNG.permutation(np.delete(np.arange(experts), 3))[:k - 1] for _ in range(tokens)])
+        return np.concatenate([np.full((tokens, 1), 3), rest], axis=1).astype("i4")
+    return routing_case(name, tokens, experts, k)
+
+
+#: name -> (tokens, experts a token, hidden, experts, dtype, router): one block of the kernel's tokens and several, one
+#: buffer chunk and several, fewer experts than tiles and more
+TOKEN_SUM_CASES = {
+    "k1_f32": (128, 1, 128, 8, "float32", "uniform"),
+    "k1_bf16_three_blocks": (384, 1, 256, 4, "bfloat16", "uniform"),
+    "k8_f32": (128, 8, 128, 16, "float32", "uniform"),
+    "k8_bf16_64_experts": (256, 8, 256, 64, "bfloat16", "uniform"),
+    "k2_bf16_an_expert_with_no_rows": (256, 2, 128, 8, "bfloat16", "one_expert_empty"),
+    "k2_f32_an_expert_with_no_rows": (128, 2, 384, 8, "float32", "one_expert_empty"),
+    "k4_bf16_a_quarter_to_one_expert": (256, 4, 128, 16, "bfloat16", "a_quarter_of_all_rows_to_one_expert"),
+    "k4_f32_a_quarter_to_one_expert": (256, 4, 128, 16, "float32", "a_quarter_of_all_rows_to_one_expert"),
+    "k2_bf16_all_tokens_to_one_expert": (256, 2, 128, 8, "bfloat16", "all_tokens_to_one_expert"),
+}
+
+
+@pytest.mark.parametrize("case", list(TOKEN_SUM_CASES))
+def test_the_token_sum_kernel_is_the_gather_and_the_float32_sum(case):
+    """`moe_kernels.token_sum`, interpreted, against `_sum_by_token`'s
+    `jax.numpy` form on the same rows: float32 rows to 1e-6 of the largest
+    sum, bf16 rows to one bf16 ulp (the same float32 sums in another order,
+    rounded once)."""
+    from paddle_tpu.ops import moe_kernels
+
+    tokens, k, d, experts, dtype, router = TOKEN_SUM_CASES[case]
+    assert moe_kernels.fits(tokens, d, k, dtype, experts)
+    top_i = token_sum_routing(router, tokens, experts, k)
+    load = np.bincount(top_i.reshape(-1), minlength=experts)
+    assert {"uniform": lambda: True, "one_expert_empty": lambda: load[0] == 0, "all_tokens_to_one_expert": lambda: load[5] == tokens,
+            "a_quarter_of_all_rows_to_one_expert": lambda: 4 * load[3] == tokens * k}[router]()
+    route = route_of(top_i)
+    rows = jnp.asarray(RNG.randn(tokens * k, d), dtype)
+    want = np.asarray(moe_ops._sum_by_token(rows, route, k), "f8")
+    got = moe_ops._sum_by_token(rows, route, k, (experts, True))
+    assert got.dtype == rows.dtype and got.shape == (tokens, d)
+    off = np.abs(np.asarray(got, "f8") - want)
+    if dtype == "float32":
+        assert off.max() <= 1e-6 * np.abs(want).max(), off.max()
+    else:
+        assert (off <= 2.0 ** -7 * np.maximum(np.abs(want), 1e-3)).all(), off.max()
+
+
+@pytest.mark.parametrize("platform,devices,tokens,d,dtype,experts,path", [
+    ("tpu", None, 16384, 2048, "bfloat16", 64, "kernel"), ("tpu", 1, 128, 128, "float32", 8, "kernel"),
+    ("tpu", 1, 16384, 2048, "bfloat16", 128, "kernel"),
+    ("cpu", None, 16384, 2048, "bfloat16", 64, "xla"), (None, None, 16384, 2048, "bfloat16", 64, "xla"),
+    ("tpu", 4, 16384, 2048, "bfloat16", 64, "xla"), ("tpu", None, 16384, 2000, "bfloat16", 64, "xla"),
+    ("tpu", None, 16320, 2048, "bfloat16", 64, "xla"), ("tpu", None, 16384, 2048, "float16", 64, "xla"),
+    ("tpu", None, 16384, 8192, "float32", 384, "xla")])
+def test_the_token_sum_kernel_is_taken_on_one_tpu_at_whole_tiles_and_blocks_and_nowhere_else(platform, devices, tokens, d, dtype,
+                                                                                             experts, path):
+    """`_token_sum_path` reads the platform, the mesh, the tokens' shape and
+    dtype, the rows a token and the number of experts, and nothing else: no flag, environment
+    variable or attribute.  The last case: two buffers that do not fit."""
+    import inspect
+    import re
+
+    k = 8
+    mesh = None if devices is None else SimpleNamespace(size=devices)
+    assert moe_ops._token_sum_path(platform, mesh, jax.ShapeDtypeStruct((tokens, d), dtype), k, experts) == path
+    assert not re.search(r"environ|getenv|FLAGS|\.attr\(", inspect.getsource(moe_ops._token_sum_path))
+
+
+def _count_token_sum_kernel_calls(trace):
+    monitor.reset()
+    monitor.enable()
+    try:
+        traced = trace()
+        return monitor.get_monitor().counter_values().get("lowering.token_sum_kernel_calls", 0), str(traced)
+    finally:
+        monitor.disable()
+        monitor.reset()
+
+
+@pytest.mark.parametrize("platform,devices,d,calls", [("tpu", None, 128, 2), ("cpu", None, 128, 0), ("tpu", None, 120, 0),
+                                                      ("tpu", 4, 128, 0)])
+def test_the_counter_says_which_layers_took_the_token_sum_kernel(platform, devices, d, calls):
+    """`lowering.token_sum_kernel_calls` counts, at trace time, the calls of
+    `_sum_by_token` that took the kernel: two a differentiated layer on one
+    TPU device (forward's, and the transpose of `_rows_by_expert`), one a plain
+    call; on a mesh, on the CPU and at a hidden size that is no whole number of
+    lane tiles the layer keeps XLA's form and the counter stays 0."""
+    tokens, f, experts, k = 128, 16, 8, 2
+    top_i = routing_case("uniform", tokens, experts, k)
+    ins = {"X": RNG.randn(tokens, d).astype("f4"), "TopKProb": RNG.rand(tokens, k).astype("f4"), "TopKIndex": top_i,
+           "Load": np.bincount(top_i.reshape(-1), minlength=experts).astype("i4"),
+           "WGate": RNG.randn(experts, d, f).astype("f4"), "WUp": RNG.randn(experts, d, f).astype("f4"),
+           "WDown": RNG.randn(experts, f, d).astype("f4")}
+    op = SimpleNamespace(type="moe_experts", attr=lambda n, default=None: default)
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform, mesh=None if devices is None else SimpleNamespace(size=devices))
+
+    def layer(x):
+        return jnp.sum(get_op_def("moe_experts").lower(ctx, op, {n: [jnp.asarray(v)] for n, v in {**ins, "X": x}.items()})["Out"])
+
+    found, text = _count_token_sum_kernel_calls(lambda: jax.make_jaxpr(jax.grad(layer))(ins["X"]))   # traced, not run
+    assert found == calls and text.count("name=token_sum") == 2 * calls  # each one `jax.jit` and the kernel's call inside it
+    found, _ = _count_token_sum_kernel_calls(lambda: jax.make_jaxpr(layer)(ins["X"]))
+    assert found == calls // 2
 
 
 def test_permute_scalars_is_the_gather_and_its_transpose_the_inverse_gather():
@@ -256,7 +369,8 @@ def test_moe_experts_cost_row_counts_the_lowerings_passes_over_its_rows():
     assert row.cost_covered and row.flops == 3 * 2 * tokens * k * d * f
     # X and Out, TopKProb and TopKIndex, Load, the three matrices, Dropped: once each
     once = 2 * tokens * d + 2 * tokens * k + experts + 3 * experts * d * f + 1
-    assert moe_ops._ROW_PASSES == {"hidden": 7, "width": 6}
+    # a hidden size of 16 is no whole lane tile: the way back is XLA's gather and sum, seven passes over [rows, hidden]
+    assert moe_ops._ROW_PASSES == {"hidden": 5, "hidden_xla": 7, "width": 6}
     assert row.traffic_bytes == 4 * (once + tokens * k * (7 * d + 6 * f))
 
 
