@@ -639,12 +639,14 @@ class _CompiledStep:
                 lowering.annotate(fenced=fenced.value - fenced0)
                 # which attention each fused_attention op of this program took,
                 # what its `repeat` ops lowered (passes, body ops, recomputed passes),
-                # how many of its `kda` ops took the kernels, and of its `moe_experts` ops' ways back to token order
+                # how many of its `kda` ops took the kernels, of its `moe_experts` ops' ways back to token order, what
+                # its window attentions visited and allowed, and how many ops read a tensor another layer kept
                 lowering.annotate(**{
                     name[len("lowering."):]: n - counted0.get(name, 0)
                     for name, n in _MON.counter_values().items()
                     if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_",
-                                        "lowering.selective_scan_", "lowering.kernels_under_", "lowering.token_sum_"))
+                                        "lowering.selective_scan_", "lowering.kernels_under_", "lowering.token_sum_",
+                                        "lowering.window_", "lowering.kept_"))
                     and n != counted0.get(name, 0)})
                 if self.moe_layers:
                     _MON.counter("lowering.moe_layers").inc(self.moe_layers)

@@ -716,7 +716,7 @@ def space_to_depth(x, blocksize, name=None):
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mask_block=None,
-                    layout="bhld", name=None):
+                    layout="bhld", name=None, kept_kv=False):
     """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
     float32 scores and softmax whatever the operands' dtype.  `layout="blhd"`
     says that `q`, `k`, `v` and the result are (B, L, H, dh) instead, the
@@ -743,7 +743,18 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
     the clean blocks up to itself.  The mask is two attributes of the op, not
     a tensor: L is half the length and the rule is computed from positions,
     so nothing of [2L, 2L] exists on the TPU, where a block-sparse kernel
-    skips the three quarters of the square the rule empties."""
+    skips the three quarters of the square the rule empties.
+
+    `mask="sliding_window"` with `mask_block=W` (for this rule the attribute is
+    the WINDOW, in keys) lets query i see the keys j with i - W < j <= i, its
+    own position the last of W, over as many keys as queries
+    (`ops/masked_attention.py: window_allowed`).  On the TPU the same kernels
+    visit only the blocks the band touches; a window as long as the sequence
+    is the causal mask.
+
+    `kept_kv` says that `k` and `v` are tensors ANOTHER layer projected and
+    kept (a cross-decoder's attention on the self-decoder's keys and values):
+    the same mathematics, counted in `lowering.kept_tensor_readers`."""
     helper = LayerHelper("fused_attention", name=name)
     out = _out(helper, q.dtype, shape=tuple(q.shape[:-1]) + (v.shape[-1],))
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
@@ -757,6 +768,8 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
     if mask is not None:
         attrs["mask"] = str(mask)
         attrs["mask_block"] = int(mask_block)
+    if kept_kv:
+        attrs["kept_kv"] = True
     helper.append_op("fused_attention", inputs=inputs, outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
@@ -1016,6 +1029,20 @@ def selective_scan(x, dt, b, c, a_log_attr=None, d_attr=None, dt_bias_attr=None,
     helper.append_op("selective_scan",
                      inputs={"X": [x.name], "Dt": [dt.name], "ALog": [a_log.name], "B": [b.name], "C": [c.name],
                              "D": [d_skip.name], "DtBias": [dt_bias.name]},
+                     outputs={"Out": [out.name], "Stats": [stats.name]})
+    return out
+
+
+def memory_gate(gate, memory, name=None):
+    """A Gated Memory Unit's gate (`ops/ssm_ops.py: memory_gate`): silu(gate) *
+    memory over (b, T, d), `memory` a tensor another layer kept (a state-space
+    scan's output before that layer's own gate), in `memory`'s dtype.  The op's
+    `Stats` (mean |memory|, mean gate, all finite) are published a logged step
+    by `train_loop` as a `kind="gmu_memory"` record."""
+    helper = LayerHelper("memory_gate", name=name)
+    out = _out(helper, memory.dtype, shape=tuple(memory.shape))
+    stats = _out(helper, "float32", shape=(3,))
+    helper.append_op("memory_gate", inputs={"Gate": [gate.name], "Memory": [memory.name]},
                      outputs={"Out": [out.name], "Stats": [stats.name]})
     return out
 

@@ -50,7 +50,7 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                          use_fused_attention=False, proj_bias=True,
                          qk_norm_eps=None, positions=None, rope_theta=10000.0,
                          n_kv_heads=None, head_dim=None, qk_norm_per_head=False,
-                         mask=None, mask_block=None):
+                         mask=None, mask_block=None, keep=None, kept_kv=None):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
     cross-attention (keys/values from another sequence); `bias` is an
@@ -73,7 +73,15 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     are split by a reshape alone and the attention is handed (B, L, H, dh)
     (`layout="blhd"`): the program holds no transpose round it.  Otherwise
     the heads are transposed to the front, (B, H, L, dh), as the per-head
-    norm, the rotary embedding and the other two attentions are written."""
+    norm, the rotary embedding and the other two attentions are written.
+
+    A layer may hand its keys and values on, and another read them (SambaY's
+    cross-decoder, Ren et al. 2025): `keep` is a dict into which this layer
+    puts `keep["kv"]` = (k, v), each (B, L, n_kv_heads, head_dim) as projected
+    (bias included); `kept_kv` is such a pair, and the layer then has NO key or
+    value weights: its own queries attend to the kept tensors and its own out
+    projection follows.  (`kv=` is something else: another SEQUENCE projected
+    with THIS layer's weights.)  Both take the fused attention's (B, L, H, dh)."""
     d_head = head_dim or d_model // n_heads
     n_kv_heads = n_kv_heads or n_heads
     kv_in = kv if kv is not None else x
@@ -83,7 +91,8 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                          bias_attr=_attr(f"{prefix}.{name}.b") if proj_bias else False)
 
     q = project(x, "q", n_heads * d_head)
-    k, v = project(kv_in, "k", n_kv_heads * d_head), project(kv_in, "v", n_kv_heads * d_head)
+    if kept_kv is None:
+        k, v = project(kv_in, "k", n_kv_heads * d_head), project(kv_in, "v", n_kv_heads * d_head)
     if qk_norm_eps is not None and not qk_norm_per_head:
         q = layers.rms_norm(q, begin_norm_axis=2, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.q_norm.w"))
@@ -95,12 +104,18 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     # their own layout, (B, L, H, dh), and `fused_attention(layout="blhd")` reads it: no `transpose2` in the program.
     per_head_norm = qk_norm_eps is not None and qk_norm_per_head
     heads_major = not use_fused_attention or per_head_norm or positions is not None
+    if (keep is not None or kept_kv is not None) and (heads_major or qk_norm_eps is not None):
+        raise ValueError("keys and values are kept, and kept ones read, as the fused attention's (B, L, H, dh): "
+                         "no q/k-norm and no rotary positions stand between the projection and the attention")
 
     def split_heads(t, heads):
         t = layers.reshape(t, [0, 0, heads, d_head])
         return layers.transpose(t, [0, 2, 1, 3]) if heads_major else t
 
-    q, k, v = split_heads(q, n_heads), split_heads(k, n_kv_heads), split_heads(v, n_kv_heads)
+    q = split_heads(q, n_heads)
+    k, v = kept_kv if kept_kv is not None else (split_heads(k, n_kv_heads), split_heads(v, n_kv_heads))
+    if keep is not None:
+        keep["kv"] = (k, v)
     if per_head_norm:
         q = layers.rms_norm(q, begin_norm_axis=3, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.q_norm.w"))
@@ -116,7 +131,7 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         # can't run inside the fused kernel; the equivalent regularization
         # goes on the attention output (same substitution as the ring path).
         ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal, mask=mask, mask_block=mask_block,
-                                     layout="bhld" if heads_major else "blhd")
+                                     layout="bhld" if heads_major else "blhd", kept_kv=kept_kv is not None)
         if dropout_prob and not is_test:
             ctx = layers.dropout(ctx, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -214,17 +229,29 @@ def kimi_delta_attention(x, d_model, n_heads, head_dim, prefix, conv_kernel=4, n
         return project(layers.reshape(o, [0, 0, width]), "out", d_model)
 
 
-def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kernel=4, norm_eps=1e-6):
-    """The Mamba-1 mixer as Jamba has it (Lieber et al. 2024, arXiv:2403.19887;
-    Gu & Dao 2023) round the op `selective_scan`: [xs, z] = split(x W_in), each
-    `expand` x d_model wide; xs = silu(conv(xs)), a depthwise causal convolution
-    of `conv_kernel` taps with a bias a channel (`short_conv`'s plain mode); [dt,
-    B, C] = split(xs W_x), `dt_rank`, `state` and `state` wide, each RMS-normed
-    with a gain (Jamba's three inner norms); the step's projection dt W_dt back
-    to the channels (its bias and the softplus are the op's, float32); the
-    recurrence; y * silu(z), projected back.  No other biases.  A_log[c, n] =
-    ln(n + 1), D = 1 and the step's bias the inverse softplus of a log-uniform
-    draw on [1e-3, 1e-1], as Mamba initialises them."""
+def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kernel=4, norm_eps=1e-6,
+                inner_norms=True, keep=None, taps_bound=None):
+    """The Mamba-1 mixer (Gu & Dao 2023) round the op `selective_scan`: [xs, z]
+    = split(x W_in), each `expand` x d_model wide; xs = silu(conv(xs)), a
+    depthwise causal convolution of `conv_kernel` taps with a bias a channel
+    (`short_conv`'s plain mode); [dt, B, C] = split(xs W_x), `dt_rank`, `state`
+    and `state` wide; the step's projection dt W_dt back to the channels (its
+    bias and the softplus are the op's, float32); the recurrence; y * silu(z),
+    projected back.  No other biases.  A_log[c, n] = ln(n + 1), D = 1 and the
+    step's bias the inverse softplus of a log-uniform draw on [1e-3, 1e-1], as
+    Mamba initialises them.
+
+    `inner_norms` (the default, as Jamba has it: Lieber et al. 2024,
+    arXiv:2403.19887) RMS-norms dt, B and C each with a gain before they are
+    used; False is the plain mixer, which has no such parameters.  `taps_bound`
+    = b draws the convolution's taps U(-b, b) and not N(0, 0.02) with the other
+    weights: Mamba's own code leaves them at `nn.Conv1d`'s default, b =
+    conv_kernel^-0.5, and WITHOUT the inner norms it is the taps' size that
+    decides how much of the output the state carries (at N(0, 0.02) B and C
+    are of the weights' order and h C is ~5e-4 of the skip D x).  `keep` is a
+    dict into which the layer puts `keep["memory"]` = y, the scan's output
+    (b, T, `expand` x d_model) BEFORE the gate silu(z) and the out projection:
+    what a later layer's Gated Memory Unit reads (`gated_memory_unit`)."""
     inner = expand * d_model
     dt_rank = dt_rank or -(-d_model // 16)
 
@@ -232,14 +259,18 @@ def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kerne
         return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
 
     def part(t, name, lo, hi):
-        return layers.rms_norm(layers.slice(t, axes=[2], starts=[lo], ends=[hi]), begin_norm_axis=2, epsilon=norm_eps,
-                               param_attr=_attr_ones(f"{prefix}.{name}_norm.w"))
+        t = layers.slice(t, axes=[2], starts=[lo], ends=[hi])
+        if not inner_norms:
+            return t
+        return layers.rms_norm(t, begin_norm_axis=2, epsilon=norm_eps, param_attr=_attr_ones(f"{prefix}.{name}_norm.w"))
 
     with name_scope("mamba"):
         both = project(x, "in", 2 * inner)
         xs = layers.slice(both, axes=[2], starts=[0], ends=[inner])
         z = layers.slice(both, axes=[2], starts=[inner], ends=[2 * inner])
-        xs = layers.short_conv(xs, conv_kernel, gated=False, activation="silu", filter_attr=_attr(f"{prefix}.conv.w"),
+        taps = _attr(f"{prefix}.conv.w") if taps_bound is None else ParamAttr(
+            name=f"{prefix}.conv.w", initializer=UniformInitializer(-float(taps_bound), float(taps_bound)))
+        xs = layers.short_conv(xs, conv_kernel, gated=False, activation="silu", filter_attr=taps,
                                bias_attr=ParamAttr(name=f"{prefix}.conv.b", initializer=ConstantInitializer(0.0)))
         low = project(xs, "x", dt_rank + 2 * state)
         dt = project(part(low, "dt", 0, dt_rank), "dt", inner)
@@ -251,7 +282,22 @@ def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kerne
                     np.tile(np.log(np.arange(1, state + 1, dtype="float32")), (inner, 1)))),
                 d_attr=ParamAttr(name=f"{prefix}.d", initializer=ConstantInitializer(1.0)),
                 dt_bias_attr=ParamAttr(name=f"{prefix}.dt.b", initializer=SoftplusInverseLogUniformInitializer(1e-3, 1e-1)))
+        if keep is not None:
+            keep["memory"] = y
         return project(layers.elementwise_mul(y, layers.swish(z)), "out", d_model)
+
+
+def gated_memory_unit(x, memory, d_model, prefix):
+    """A Gated Memory Unit (SambaY, Ren et al. 2025, arXiv:2507.06607): out =
+    (silu(x W1) * m) W2, m the scan output (b, T, width) that an earlier
+    state-space layer kept (`mamba_mixer(keep=)`), W1 d_model x width, W2 width
+    x d_model, no biases: the layer reads that layer's memory through a gate of
+    its own input, for two products and no scan."""
+    def project(t, name, out):
+        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
+
+    with name_scope("gmu"):
+        return project(layers.memory_gate(project(x, "in", int(memory.shape[-1])), memory), "out", d_model)
 
 
 def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, is_test=False,
@@ -259,7 +305,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                   norm="layer", norm_eps=1e-5, pre_norm=False, proj_bias=True,
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
                   n_kv_heads=None, head_dim=None, attention_mask=None,
-                  operator="attention", conv_kernel=3, ffn="gelu", post_norm=False, operator_args=None):
+                  operator="attention", conv_kernel=3, ffn="gelu", post_norm=False, operator_args=None,
+                  unit_norms=False, keep=None, kept=None):
     """One transformer layer: a sequence operator (attention) and a
     feed-forward part, each with a residual connection and a norm.
 
@@ -278,8 +325,21 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `operator="latent_attention"` attention over latent keys and values
     (`latent_attention`; `operator_args` = dict(rank=, nope_dim=, rope_dim=,
     v_dim=)) and `operator="mamba"` a Mamba-1 mixer (`mamba_mixer`;
-    `operator_args` = dict(expand=, state=, dt_rank=), its convolution
-    of `conv_kernel` taps).
+    `operator_args` = dict(expand=, state=, dt_rank=, inner_norms=, taps_bound=), its
+    convolution of `conv_kernel` taps).  `unit_norms` starts a layer norm at
+    gain 1 and bias 0, as a decoder's sources do (BERT's are drawn: PERF.md
+    section 7, defect 3).
+
+    A layer may hand on more than the residual stream.  `keep` is a dict the
+    layer WRITES what it makes into: a Mamba layer its scan output
+    (`keep["memory"]`), an attention layer its projected keys and values
+    (`keep["kv"]`).  `kept` is such a dict a later layer READS:
+    `operator="gmu"` is a Gated Memory Unit on `kept["memory"]`
+    (`gated_memory_unit`), `operator="cross_attention"` causal attention of the
+    layer's own queries on `kept["kv"]`, with no key or value weights
+    (`multi_head_attention(kept_kv=)`).  Attention under a sliding window
+    (`attention_mask=("sliding_window", W)`) stands in the scope
+    `sliding_attention`, on kept keys and values in `cross_attention`.
 
     The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
     W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
@@ -296,6 +356,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         if norm == "rms":
             return layers.rms_norm(t, begin_norm_axis=2, epsilon=norm_eps,
                                    param_attr=_attr_ones(f"{prefix}.{name}.w"))
+        if unit_norms:
+            return layers.layer_norm(t, begin_norm_axis=2, epsilon=norm_eps, param_attr=_attr_ones(f"{prefix}.{name}.w"),
+                                     bias_attr=ParamAttr(name=f"{prefix}.{name}.b", initializer=ConstantInitializer(0.0)))
         return layers.layer_norm(t, begin_norm_axis=2, epsilon=norm_eps,
                                  param_attr=_attr(f"{prefix}.{name}.w"),
                                  bias_attr=_attr(f"{prefix}.{name}.b"))
@@ -342,19 +405,26 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                     **operator_args)
     elif operator == "mamba":
         attn_out = mamba_mixer(operator_in, d_model, f"{prefix}.mamba", conv_kernel=conv_kernel, norm_eps=norm_eps,
-                               **operator_args)
+                               keep=keep, **operator_args)
+    elif operator == "gmu":
+        attn_out = gated_memory_unit(operator_in, kept["memory"], d_model, f"{prefix}.gmu")
     else:
-        attn_out = multi_head_attention(operator_in,
-                                        seq_len, d_model, n_heads, f"{prefix}.attn",
-                                        dropout_prob, is_test, use_ring_attention, causal,
-                                        use_fused_attention=use_fused_attention,
-                                        proj_bias=proj_bias,
-                                        qk_norm_eps=norm_eps if qk_norm else None,
-                                        positions=positions, rope_theta=rope_theta,
-                                        n_kv_heads=n_kv_heads, head_dim=head_dim,
-                                        qk_norm_per_head=qk_norm == "head",
-                                        mask=attention_mask and attention_mask[0],
-                                        mask_block=attention_mask and attention_mask[1])
+        crossing = operator == "cross_attention"
+        scope = ("cross_attention" if crossing
+                 else "sliding_attention" if attention_mask and attention_mask[0] == "sliding_window" else None)
+        with name_scope(scope) if scope else contextlib.nullcontext():
+            attn_out = multi_head_attention(operator_in,
+                                            seq_len, d_model, n_heads, f"{prefix}.attn",
+                                            dropout_prob, is_test, use_ring_attention, causal,
+                                            use_fused_attention=use_fused_attention,
+                                            proj_bias=proj_bias,
+                                            qk_norm_eps=norm_eps if qk_norm else None,
+                                            positions=positions, rope_theta=rope_theta,
+                                            n_kv_heads=n_kv_heads, head_dim=head_dim,
+                                            qk_norm_per_head=qk_norm == "head",
+                                            mask=attention_mask and attention_mask[0],
+                                            mask_block=attention_mask and attention_mask[1],
+                                            keep=keep, kept_kv=kept["kv"] if crossing else None)
     if post_norm:
         attn_out = normed(attn_out, "post_ln1")
     x = layers.elementwise_add(x, attn_out)
@@ -471,6 +541,11 @@ def build_causal_lm(
     mamba=None,
     rotary=True,
     recompute_layers=False,
+    norm="rms",
+    proj_bias=False,
+    sliding_window=None,
+    memory_layer=None,
+    kv_layer=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -542,6 +617,23 @@ def build_causal_lm(
     feed); and `recompute_layers` makes every layer a `recompute_scope`:
     backward keeps a layer's input and computes the layer again.
 
+    A decoder whose later layers read what earlier ones made (SambaY: Ren et
+    al. 2025, arXiv:2507.06607) is arguments too.  `layer_types` may hold
+    "sliding_attention" (softmax over the `sliding_window` keys that end at the
+    query's own: `layers.fused_attention(mask="sliding_window")`), "gmu" (a
+    Gated Memory Unit, `gated_memory_unit`, on the scan output that the Mamba
+    layer at index `memory_layer` hands on) and "cross_attention" (the layer's
+    own queries, causally, on the keys and values that the attention layer at
+    index `kv_layer` projected and hands on; no key or value weights).  A "gmu"
+    or "cross_attention" at or before the layer that makes what it reads, or
+    with no such layer, is refused here, with its index.  With
+    `recompute_layers` the kept tensors leave their layer's segment as outputs
+    and enter each reader's as inputs, and backward sums their gradients over
+    the readers.  `norm="layer"` makes every norm, the final one too, a
+    LayerNorm with gain 1 and bias 0 at the start; `proj_bias` gives the four
+    attention projections biases; `mamba` may hold `inner_norms=False` (the
+    plain Mamba-1 mixer) and `taps_bound=` (its taps' own initialisation).
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -566,16 +658,33 @@ def build_causal_lm(
                          "layer_types alone states the depth")
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
     operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention",
-                 "mamba": "mamba"}
+                 "mamba": "mamba", "sliding_attention": "attention", "gmu": "gmu", "cross_attention": "cross_attention"}
     operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba}
     unknown = sorted(set(kinds) - set(operators))
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
-                         "kda or latent_attention, or mamba")
+                         "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention")
     if (("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent)
             or ("mamba" in kinds and not mamba)):
         raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer "
                          "latent=, a mamba layer mamba=")
+    if "sliding_attention" in kinds and not (sliding_window and sliding_window >= 1):
+        raise ValueError("build_causal_lm: a sliding_attention layer needs sliding_window=, its width in keys")
+    # what a layer reads of another: (the reading kind, the argument that names the maker, the kinds that make it)
+    for reader, argument, maker, makers in (("gmu", "memory_layer", memory_layer, ("mamba",)),
+                                            ("cross_attention", "kv_layer", kv_layer,
+                                             ("full_attention", "sliding_attention"))):
+        if maker is not None and not (0 <= maker < len(kinds) and kinds[maker] in makers):
+            raise ValueError(f"build_causal_lm: {argument}={maker} names no layer of kind {' / '.join(makers)} "
+                             f"among {len(kinds)} layers")
+        for i, kind in enumerate(kinds):
+            if kind == reader and (maker is None or maker >= i):
+                raise ValueError(f"build_causal_lm: layer {i} is a {reader} layer and reads what layer {argument}="
+                                 f"{maker} hands on, which has to be a layer before it")
+    if loop is not None and (memory_layer is not None or kv_layer is not None):
+        raise ValueError("build_causal_lm: loop= with memory_layer= or kv_layer=: a kept tensor does not leave a pass")
+    if norm not in ("rms", "layer"):
+        raise ValueError(f"build_causal_lm: norm={norm!r}; \"rms\" or \"layer\"")
     if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
         raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
                          f"among {len(kinds)} layers")
@@ -594,7 +703,9 @@ def build_causal_lm(
         aux = []
 
         def stack_of_layers(x):
+            kept = {}   # what a layer handed on: "memory" (a scan's output), "kv" (keys and values)
             for i, kind in enumerate(kinds):
+                window = ("sliding_window", sliding_window) if kind == "sliding_attention" else None
                 dense = i < num_dense_layers
                 experts = dict(num_experts=num_experts, top_k=top_k,
                                norm_topk_prob=norm_topk_prob, held=experts_held,
@@ -606,18 +717,22 @@ def build_causal_lm(
                 with recompute_scope() if recompute_layers else contextlib.nullcontext():
                     x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
                                       f"lm.l{i}",
-                                      dropout_prob=0.0, causal=attention_mask is None,
+                                      dropout_prob=0.0, causal=(window or attention_mask) is None,
                                       use_fused_attention=use_fused_attention,
-                                      norm="rms", norm_eps=norm_eps, pre_norm=True, proj_bias=False,
+                                      norm=norm, unit_norms=True, norm_eps=norm_eps, pre_norm=True, proj_bias=proj_bias,
                                       qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
                                       moe=None if dense else experts, ffn="gated_silu",
                                       aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
-                                      attention_mask=attention_mask,
+                                      attention_mask=window or attention_mask,
                                       operator=operators[kind], operator_args=operator_args.get(kind),
-                                      conv_kernel=conv_kernel, post_norm=post_norm)
+                                      conv_kernel=conv_kernel, post_norm=post_norm,
+                                      keep=kept if i in (memory_layer, kv_layer) else None, kept=kept)
             return x
 
         def final_norm(x):
+            if norm == "layer":
+                return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_eps, param_attr=_attr_ones("lm.final_norm.w"),
+                                         bias_attr=ParamAttr(name="lm.final_norm.b", initializer=ConstantInitializer(0.0)))
             return layers.rms_norm(x, begin_norm_axis=2, epsilon=norm_eps,
                                    param_attr=_attr_ones("lm.final_norm.w"))
 

@@ -1,6 +1,13 @@
 """Attention under a mask that is a rule over positions, not a tensor.
 
-Two rules.  The CAUSAL one (`causal_allowed`: key j <= query i over equal
+Three rules.  The SLIDING-WINDOW one (`window_allowed`: key j for query i where
+i - window < j <= i; `fused_attention`'s `mask="sliding_window"`, `window_plan`)
+is the causal rule's kernels under the stock local mask: the block maps hold
+only the blocks the band touches (at 8192 keys and a window of 512, 31 of the
+256 512-blocks, every one of them cut), the cut blocks' mask is computed in the
+kernel from the positions (two compares a pair), and a window that reaches the
+sequence's start from every query IS the causal rule and takes its plan.  The
+CAUSAL one (`causal_allowed`: key j <= query i over equal
 lengths; `fused_attention`'s `causal` at long keys, `causal_plan`) is the stock
 splash kernels under the stock causal mask and nothing round them but the
 queries' scaling: the forward kernel and ONE backward kernel (dkv, which
@@ -81,7 +88,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..monitor import MONITOR as _MON
 
 #: The rules an op's `mask` attribute may name (`causal` is an attribute of its own).
-MASKS = ("block_diffusion",)
+MASKS = ("block_diffusion", "sliding_window")
 
 #: Queries and keys a block of the kernels' grids, the largest that divides the
 #: length, forward and both backward kernels, and the keys a step inside a
@@ -159,6 +166,20 @@ def causal_allowed(q_ids, kv_ids):
     return kv_ids <= q_ids
 
 
+def window_allowed(q_ids, kv_ids, window: int):
+    """May query `q_ids` see key `kv_ids` under a sliding window of `window`
+    keys, the query's own position the last of them?  Broadcasts, numpy or
+    jax, as `block_diffusion_allowed` does."""
+    return (kv_ids <= q_ids) & (kv_ids > q_ids - window)
+
+
+def window_pairs(length: int, window: int) -> int:
+    """(query, key) pairs the window rule allows among `length` positions: the
+    causal triangle where the window reaches the start from every query."""
+    w = min(window, length)
+    return w * (w + 1) // 2 + (length - w) * w
+
+
 def allowed_pairs(positions: int, block: int) -> int:
     """(query, key) pairs the rule allows among `positions` = 2L positions."""
     seq = positions // 2
@@ -217,7 +238,18 @@ class Plan(NamedTuple):
         """dq from the dkv kernel's own pass over the scores (the stock fused
         backward), not from a kernel of its own: under the causal rule, where
         it is the faster alone AND in the step (`_BLOCKS`' table); never under
-        block-diffusion's, whose step it overran (ROADMAP.md S13(a))."""
+        block-diffusion's, whose step it overran (ROADMAP.md S13(a)); nor under
+        the window rule.  The fused kernel writes dq as one partial a block of
+        KEYS, [L / block, Hq, L, dh], and the stock code gives it a dkv grid that
+        is NOT shrunk to the blocks the rule leaves (a shrunk grid's query index
+        is no longer the query block: the partials land in the wrong rows and
+        the rest is never written, NaN interpreted), so every (key block, query
+        block) pair is a grid step that writes zeros where the band is not, 16 x
+        the band's own at 8192 keys in 512-blocks, which XLA then sums.  The
+        causal rule leaves half of the square, the window 31 of 256 blocks: two
+        kernels over the band's blocks alone, each computing its scores again,
+        take 8.17 ms where the one over the square takes 11.72
+        (`_WINDOW_BLOCKS`' table)."""
         return self.rule == "causal"
 
     @property
@@ -250,6 +282,46 @@ def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
     return Plan(length, heads, 1, kernel_block(length), 0, interpret, "causal")
 
 
+#: The kernels' block under the window rule: the smallest block of `_WINDOW_BLOCKS` that holds a whole window.  A
+#: block of b visits 1 + ceil((window - 1) / b) blocks a block of queries, so about (b + window) / window times the
+#: pairs the rule allows: smaller blocks compute fewer masked pairs and pay for it in grid steps, larger ones the
+#: other way.  TPU v5e, (1, 40 on 20, 8192, 64) bf16, window 512, forward + backward of a layer alone, ms (my chip
+#: run, PR 50; `WINDOW=1 python3 tools/chip_block_attention.py`; the causal rule over the same operands 20.61):
+#:
+#:   block (keys a step)           128      256      512 (512)   512 (256)   1024 (512)   1024 (1024)
+#:   pairs visited / allowed       1.29     1.55     2.06        2.06        4.13         4.13
+#:   dq and dkv apart              22.82    11.37     8.17        8.66       11.46        11.63
+#:   fused backward                -        22.71    11.72        -          11.56
+#:
+#: so a block of the window's own length, `_KV_COMPUTE` keys a step, dq a kernel of its own (`window_attention` as the
+#: op calls it: 8.36).  The output and gradients against dense float32 at (1, 8 on 4, 2048, 64): 3.5e-3, 4.4e-3,
+#: 2.7e-3, 3.8e-3 of the largest, the causal rule's readings.  Only a window of 512 was priced.
+_WINDOW_BLOCKS = (128, 256, 512, 1024)
+
+
+def window_block(length: int, window: int):
+    """The grid's block for the window rule over `length` positions, None where
+    no block of `_WINDOW_BLOCKS` divides the length."""
+    fitting = [b for b in _WINDOW_BLOCKS if length % b == 0]
+    return next((b for b in fitting if b >= window), fitting[-1] if fitting else None)
+
+
+def window_plan(length: int, heads: int, window: int, interpret: bool = False) -> Plan:
+    """The window rule over `length` queries and as many keys.  A window that
+    reaches the sequence's start from every query allows what the causal rule
+    allows: that plan is the causal one, block maps and all."""
+    if window >= length:
+        return causal_plan(length, heads, interpret)
+    return Plan(length, heads, window, window_block(length, window), 0, interpret, "sliding_window")
+
+
+@functools.lru_cache(maxsize=8)
+def _window_rule(window: int):
+    """`window_allowed` at one window, the one function a window: the kernels
+    keep what they traced by the function they were handed."""
+    return functools.partial(window_allowed, window=window)
+
+
 @functools.lru_cache(maxsize=32)
 def block_maps(plan: Plan):
     """The kernels' block maps for `plan`, forward, dq (None where the dkv
@@ -264,6 +336,9 @@ def block_maps(plan: Plan):
         # compare a pair, where block-diffusion's divisions lost by 19 ms, and no [keys, queries] block of the mask
         # in the fused backward kernel's VMEM, which has no room for it (`_BLOCKS`' table).
         rule = mask_lib.CausalMask((plan.positions, plan.positions))
+    elif plan.rule == "sliding_window":
+        # the stock local mask, `mask_block` keys wide with the query's own the last: computed on a cut block as well
+        rule = mask_lib.LocalMask((plan.positions, plan.positions), (plan.mask_block - 1, 0), 0)
     else:
         rule = _rule_mask(plan.positions, plan.first_key, plan.mask_block)
     mask = mask_lib.MultiHeadMask([rule] * plan.heads)
@@ -282,8 +357,10 @@ def _stock_options(plan: Plan) -> dict:
     """What the stock kernels' three calls share."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
+    computed = (causal_allowed if plan.rule == "causal"
+                else _window_rule(plan.mask_block) if plan.rule == "sliding_window" else None)
     return dict(mask_value=splash.DEFAULT_MASK_VALUE, is_mqa=False, attn_logits_soft_cap=None,
-                mask_function=causal_allowed if plan.rule == "causal" else None, interpret=plan.interpret)
+                mask_function=computed, interpret=plan.interpret)
 
 
 def _far_forward(q, k, v, plan: Plan):
@@ -484,6 +561,20 @@ def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bo
     """`attention_under` the block-diffusion rule over 2L positions in blocks
     of `mask_block`."""
     return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale)
+
+
+def window_attention(q, k, v, window: int, scale: float, interpret: bool = False):
+    """`attention_under` the sliding-window rule over equal lengths of queries
+    and keys.  Counted at trace time: the op, the pairs inside the blocks its
+    forward block map visits and the pairs the rule allows, over rows and heads."""
+    plan = window_plan(q.shape[2], q.shape[1], window, interpret)
+    blocks = block_maps(plan)[0].block_mask      # [heads, or 1 where every head has the one mask; query blocks; key blocks]
+    visited = int(np.count_nonzero(blocks)) * (q.shape[1] // blocks.shape[0]) * plan.block * plan.block
+    _MON.counter("lowering.window_attention_ops").inc()
+    _MON.counter("lowering.window_pairs_visited").inc(q.shape[0] * visited)
+    _MON.counter("lowering.window_pairs_allowed").inc(q.shape[0] * q.shape[1] * window_pairs(q.shape[2], window))
+    with jax.named_scope("window_attention"):
+        return attention_under(plan, q, k, v, scale)
 
 
 def causal_attention(q, k, v, scale: float, interpret: bool = False):

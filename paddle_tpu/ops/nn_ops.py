@@ -544,10 +544,15 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
     `layout`.  Off the TPU always "xla".  A short
     query against long keys (a decoding step) has no score block worth keeping
     out of HBM, hence BOTH lengths in the row kernel's rule.  Under a
-    structured `mask` (`_structured_mask`) the block-sparse kernel or, as for
-    the row kernel, XLA's attention where a custom call cannot be partitioned
-    or the lengths are no whole number of its blocks; never the other two
-    kernels, which know no mask but a causal one.
+    structured `mask` (`_structured_mask`: block diffusion's rule or a sliding
+    window) "block_sparse", the splash kernels under that rule's block maps,
+    or, as for the row kernel, XLA's attention where a custom call cannot be
+    partitioned or the lengths are no whole number of the kernels' blocks;
+    never the flash or the row kernel, which know no mask but a causal one.
+    The head width the kernels were given differs by rule: block diffusion's
+    own-block term was written for multiples of 128 and never given another;
+    the window rule is the causal rule's kernels with fewer blocks and takes
+    what they take (bf16 operands, multiples of 64).
 
     A chip runs the op whole (`one_device`) on one device, and also under a
     mesh whose `batch_axis` splits the operands' rows and nothing else
@@ -567,7 +572,18 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
     # kernels take the widths as they are; the flash, row and block-diffusion kernels were never given any
     one_width = v_width in (None, q.shape[-1])
     if mask is not None:
-        whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
+        if mask[0] == "sliding_window":
+            # Phi-4-mini-flash's window layer, (1, 40 on 20, 8192, 64) under a window of 512: XLA's attention would
+            # hold [1, 40, 8192, 8192] float32 scores, 10.7 GB, for the 1/8 of the causal triangle the rule allows;
+            # the splash kernels over the band's blocks 8.36 ms forward + backward alone, the causal rule's 20.61
+            # (my chip run, PR 50; `ops/masked_attention.py: _WINDOW_BLOCKS` has the runs by block).  What no run
+            # prices keeps XLA's: operands other than bf16, a head width that is no multiple of 64
+            from .masked_attention import window_block
+
+            whole = (window_block(q_len, mask[1]) is not None and q.shape[-1] % 64 == 0
+                     and q.dtype == k.dtype == jnp.bfloat16)
+        else:
+            whole = kernel_block(q_len) is not None and q.shape[-1] % 128 == 0
         return "block_sparse" if whole and one_device and one_width else "xla"
     if kv_len >= _FLASH_MIN_SEQ and q_len >= _FLASH_MIN_QUERIES:
         # A causal mask empties the blocks above the diagonal: the splash kernels never visit them and mask only the
@@ -638,7 +654,10 @@ def _structured_mask(op, q, k, layout="bhld"):
     block = op.attr("mask_block", None)
     axis = _ATTENTION_AXES[layout][1]
     positions, keys = q.shape[axis], k.shape[axis]
-    if kind not in MASKS or not block or keys != positions or positions % (2 * block):
+    if kind not in MASKS or not block or block < 1 or keys != positions:
+        raise ValueError(f"fused_attention: mask {kind!r} with mask_block {block} over {positions} queries "
+                         f"and {keys} keys; known masks {MASKS}, over as many keys as queries")
+    if kind == "block_diffusion" and positions % (2 * block):
         raise ValueError(f"fused_attention: mask {kind!r} with mask_block {block} over {positions} queries "
                          f"and {keys} keys; known masks {MASKS}, over 2L positions in blocks that divide L")
     return kind, int(block)
@@ -654,10 +673,14 @@ def _xla_attention(q, k, v, bias, causal, scale, mask):
         Lq, Lk = s.shape[-2], s.shape[-1]
         s = jnp.where(jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq), s, -1e30)
     if mask is not None:
-        from .masked_attention import block_diffusion_allowed
+        from .masked_attention import block_diffusion_allowed, window_allowed
 
         at = jnp.arange(s.shape[-1], dtype=jnp.int32)
-        s = jnp.where(block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1]), s, -1e30)
+        if mask[0] == "sliding_window":
+            allowed = window_allowed(at[:, None], at[None, :], mask[1])
+        else:
+            allowed = block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1])
+        s = jnp.where(allowed, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -690,8 +713,9 @@ def _fused_attention(ctx, op, ins):
       `mask_block`: a rule over positions, `ops/masked_attention.py`), the
       stock splash-attention kernel with the rule as its mask: blocks the
       rule empties are skipped, forward and backward, the blocks it cuts read
-      the few distinct cut blocks, and no mask or score of the whole square
-      is in HBM;
+      the few distinct cut blocks (block diffusion) or compute the rule from
+      the positions (a sliding window, `mask_block` its width in keys), and
+      no mask or score of the whole square is in HBM;
     * `xla`: two einsums round `jax.nn.softmax`, the scores in HBM:
       everything else on the TPU, and every other platform (CPU tests and
       virtual meshes compute the same function, so goldens transfer); a
@@ -738,6 +762,8 @@ def _fused_attention(ctx, op, ins):
     _MON.counter(f"lowering.attention_{path}").inc()
     if v.shape[-1] != q.shape[-1]:
         _MON.counter("lowering.latent_attention_layers").inc()
+    if op.attr("kept_kv", False):
+        _MON.counter("lowering.kept_tensor_readers").inc()
     native = layout == "bhld" or path == "row_kernel"
     _MON.counter("lowering.attention_layout_native" if native else "lowering.attention_layout_transposed").inc()
 
@@ -746,9 +772,10 @@ def _fused_attention(ctx, op, ins):
             q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
         heads = _ATTENTION_AXES[layout][0] if native else 1
         if path == "block_sparse":
-            from .masked_attention import block_sparse_attention
+            from .masked_attention import block_sparse_attention, window_attention
 
-            out = block_sparse_attention(q, k, v, mask[1], float(scale))
+            under = window_attention if mask[0] == "sliding_window" else block_sparse_attention
+            out = under(q, k, v, mask[1], float(scale))
         elif path == "block_causal":
             from .masked_attention import causal_attention
 
@@ -1575,9 +1602,10 @@ def _cost_fused_attention(ctx):
     lk = ks[positions]
     pairs = _elems_xs((lq, lk))
     if ctx.op.attr("mask", None) is not None and ctx.op.attr("mask_block", None):
-        from .masked_attention import allowed_pairs
+        from .masked_attention import allowed_pairs, window_pairs
 
-        pairs = allowed_pairs(lq, ctx.op.attr("mask_block"))  # the pairs the rule allows
+        count = window_pairs if ctx.op.attr("mask") == "sliding_window" else allowed_pairs
+        pairs = count(lq, ctx.op.attr("mask_block"))  # the pairs the rule allows
     return 2.0 * _elems_xs((b, h)) * (dh + dv) * pairs, ctx.io_bytes()
 
 

@@ -321,3 +321,52 @@ def _cost_selective_scan(ctx):
 
 
 _RP.register_cost(["selective_scan"], _cost_selective_scan)
+
+
+@register_op("memory_gate")
+def _memory_gate(ctx, op, ins):
+    """A Gated Memory Unit's gate (SambaY, Ren et al. 2025, arXiv:2507.06607):
+    Out = silu(Gate) * Memory over (b, T, d), Gate this layer's projection of
+    its own input and Memory a tensor ANOTHER layer kept (a state-space scan's
+    output, before that layer's own gate and out-projection); the SiLU in
+    float32, the product rounded once to Memory's dtype.  `Stats` [3], read on
+    logged steps: the mean |Memory|, the mean gate silu(Gate), and 1 where
+    every value of both is finite."""
+    gate, memory = first(ins, "Gate"), first(ins, "Memory")
+    _MON.counter("lowering.kept_tensor_readers").inc()
+    # the gate reads the VARIABLES, as the scan does: fused with its projection it would read the product before its
+    # rounding to bf16, which a stage check on the fetched operands cannot tell from a fault
+    gate, memory = jax.lax.optimization_barrier((gate, memory))
+    opened = jax.nn.silu(gate.astype(jnp.float32))
+    kept = memory.astype(jnp.float32)
+    stats = jax.lax.stop_gradient(jnp.stack([
+        jnp.mean(jnp.abs(kept)), jnp.mean(opened),
+        (jnp.all(jnp.isfinite(kept)) & jnp.all(jnp.isfinite(opened))).astype(jnp.float32)]))
+    return {"Out": (opened * kept).astype(memory.dtype), "Stats": stats}
+
+
+def _publish_gmu_memory(step, values):
+    """One logged step's `gmu_memory` record: per Gated Memory Unit the mean
+    |m| of the kept scan output it read, the mean of its gate silu(x W1) and
+    whether every value was finite.  A health check as `ssm_state` is: a memory
+    that has died (|m| near 0) or a gate that has closed leaves the layer its
+    feed-forward part alone."""
+    stats = np.stack([np.asarray(s, "f8").reshape(3) for s in values["Stats"]])
+    _MON.gauge("gmu.memory_abs_mean").set(float(stats[:, 0].mean()))
+    _MON.record_step({"kind": "gmu_memory", "pipeline_step": step, "memory_abs_mean": stats[:, 0].tolist(),
+                      "gate_mean": stats[:, 1].tolist(), "finite": bool(np.all(stats[:, 2] == 1.0))})
+
+
+set_step_stats("memory_gate", ("Stats",), _publish_gmu_memory)
+
+
+def _infer_memory_gate(ctx):
+    gate, memory = ctx.in_shape("Gate"), ctx.in_shape("Memory")
+    if gate is not None and memory is not None and tuple(gate) != tuple(memory):
+        ctx.fail(f"Gate {gate} and Memory {memory} must have one shape")
+    ctx.set_out("Out", memory if memory is not None else gate, ctx.in_dtype("Memory"))
+    ctx.set_out("Stats", (3,), "float32")
+
+
+_A.register_rule(["memory_gate"], _infer_memory_gate)
+_RP.register_cost(["memory_gate"], lambda ctx: (6.0 * ctx.out_elems_total(), ctx.io_bytes()))

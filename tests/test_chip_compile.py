@@ -87,6 +87,12 @@ def _block_causal(q, k, v):
     return causal_attention(q, k, v, q.shape[-1] ** -0.5)
 
 
+def _window(q, k, v):
+    from paddle_tpu.ops.masked_attention import window_attention
+
+    return window_attention(q, k, v, 512, q.shape[-1] ** -0.5)
+
+
 def _gmm(rows, weights, sizes):
     from paddle_tpu.ops.moe_ops import grouped_matmul
 
@@ -243,6 +249,10 @@ CASES = {
         _block_causal, [((2, 32, 8192, 64), BF16)] + [((2, 8, 8192, 64), BF16)] * 2, (0, 1, 2)),
     "block_causal_attention_128_blocks": (
         _block_causal, [((1, 8, 2176, 64), BF16)] + [((1, 2, 2176, 64), BF16)] * 2, (0, 1, 2)),
+    # the same kernels under the sliding-window rule (PR 50): Phi-4-mini-flash's window layer, 40 query heads on 20
+    # key/value heads of 64 at 8192 keys under a window of 512; forward, and dq and dkv each a kernel of its own
+    "window_attention_phi4flash": (
+        _window, [((1, 40, 8192, 64), BF16)] + [((1, 20, 8192, 64), BF16)] * 2, (0, 1, 2)),
 }
 
 
@@ -483,6 +493,24 @@ def test_the_short_convolution_is_passes_over_the_activations_dtype(chip):
     # forward and backward under the scope the benchmark's `short_conv_roofline_share` reads
     scoped = re.findall(r'op_name="[^"]*/gated_short_conv/[^"]*"', text)
     assert any("transpose(" in name for name in scoped) and any("transpose(" not in name for name in scoped)
+
+
+def test_no_square_of_the_positions_is_in_the_compiled_window_attention(chip):
+    """Forward and backward at Phi-4-mini-flash's window layer's shape: no array
+    with an [8192, 8192] square, mask or scores, in any computation of the
+    compiled program (XLA's attention would hold 10.7 GB of float32 scores
+    there), temporaries under 0.4 GB (0.29 here), and the stock kernels' three
+    calls (forward, dq, dkv) under the rule's own scope, where the benchmark's
+    `window_attention_roofline_share` finds them."""
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in ((1, 40, 8192, 64), (1, 20, 8192, 64), (1, 20, 8192, 64))]
+    compiled = jax.jit(jax.grad(lambda *a: jnp.sum(_window(*a).astype(F32)), argnums=(0, 1, 2))).lower(*args).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"\[[\d,]*8192,8192\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+    assert text.count("tpu_custom_call") == 3
+    under_the_scope = re.findall(
+        r'op_name="[^"]*window_attention\)*/block_sparse_attention[^"]*/splash_mha_(fwd|dq|dkv)[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"fwd", "dq", "dkv"}
 
 
 def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip):

@@ -495,7 +495,7 @@ def test_the_tiny_model_has_these_layers_parameters_and_no_other(float32_run):
                       "lm.l0.mamba.dt.w": (4, 128), "lm.l0.mamba.a_log": (128, 16), "lm.l0.mamba.dt_norm.w": (4,),
                       "lm.l0.mamba.b_norm.w": (16,), "lm.l2.attn.q.w": (64, 64), "lm.l2.attn.k.w": (64, 16),
                       "lm.l0.ffn.gate.w": (64, 96)}
-    with pytest.raises(ValueError, match="latent_attention, or mamba"):
+    with pytest.raises(ValueError, match="latent_attention, mamba, sliding_attention, gmu or cross_attention"):
         transformer.build_causal_lm(vocab_size=8, seq_len=4, d_model=8, n_heads=2, layer_types=["conv", "scan"])
     with pytest.raises(ValueError, match="a mamba layer mamba="):
         transformer.build_causal_lm(vocab_size=8, seq_len=4, d_model=8, n_heads=2, layer_types=["mamba"])

@@ -265,9 +265,11 @@ def test_the_compile_cache_key_names_no_ops_module_global():
                         "_ROW_KERNEL_HEAD_DIM", "_ROW_KERNEL_SEQ_MULTIPLE"]
     assert not [n for n in vars(nn_ops) if n.startswith(("enable_", "set_"))]  # nor a setter for one
     # and no attribute of the op selects an attention: `causal`, `scale` and (PR 39) `layout`, which says where the
-    # heads lie in the operands it was handed, are its mathematics and its signature
+    # heads lie in the operands it was handed, are its mathematics and its signature; `kept_kv` (PR 50) says whose keys
+    # and values the operands are and is read by a counter alone (`lowering.kept_tensor_readers`), outside the rule
     source = inspect.getsource(nn_ops._fused_attention) + inspect.getsource(nn_ops._attention_path)
-    assert sorted(set(re.findall(r"op\.attr\(\"(\w+)\"", source))) == ["causal", "layout", "scale"]
+    assert sorted(set(re.findall(r"op\.attr\(\"(\w+)\"", source))) == ["causal", "kept_kv", "layout", "scale"]
+    assert "kept_kv" not in inspect.getsource(nn_ops._attention_path)
 
 
 #: (queries, keys) -> the attention a bf16 `fused_attention` over 64-wide

@@ -17,6 +17,14 @@ computed in the kernel, dq and dkv apart or the fused backward.
 
     chiprun -- python3 tools/chip_block_attention.py       (PERF.md, PRs 32, 33 and 37)
 
+WINDOW=1 prices the SLIDING-WINDOW rule alone and stops (PR 50): Phi-4-mini-flash's
+window layer, (1, 40 on 20, 8192, 64) under a window of 512, `window_attention`
+as `fused_attention` calls it against `causal_attention` over the same
+operands (8x the pairs), the stock splash kernel under the stock local mask by
+the grid's block, dq and dkv apart (grids shrunk to the band) or the fused
+backward (its dkv grid unshrunk), and at 2048 positions the taken form's output
+and gradients against dense float32.
+
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
 import json
@@ -132,6 +140,40 @@ class StoredCausal(mask_lib.Mask):
         return hash((type(self).__name__, self.shape))
 
 
+if os.environ.get("WINDOW") == "1":
+    wq, wkv, window = ((1, 4, 512, 64), (1, 2, 512, 64), 130) if DRY else ((1, 40, 8192, 64), (1, 20, 8192, 64), 512)
+    wqkv = operands(wq, wkv, seed=2)
+    length, heads = wq[2], wq[1]
+    taken = lambda q, k, v: ma.window_attention(q, k, v, window, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
+    causal = lambda q, k, v: ma.causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
+    plan = ma.window_plan(length, heads, window, DRY)
+    report("window", q=wq, kv=wkv, window=window, taken_block=plan.block, taken_fused_backward=plan.fused_backward,
+           window_ms=try_ms(taken, *wqkv), causal_ms=try_ms(causal, *wqkv),
+           pairs_allowed_over_causal=ma.window_pairs(length, window) / (length * (length + 1) / 2))
+    local = mask_lib.LocalMask((length, length), (window - 1, 0), 0)
+    for b, compute, fused in ((128, 128, False), (128, 128, True)) if DRY else (
+            (128, 128, False), (256, 256, False), (512, 512, False), (512, 256, False), (1024, 512, False), (1024, 1024, False),
+            (256, 256, True), (512, 512, True), (1024, 512, True)):
+        blocks = 1 + -(-(window - 1) // b)
+        report("window_splash", q=wq, grid_block=b, block_kv_compute=compute, fused_backward=fused,
+               pairs_visited_over_allowed=round(blocks * b * length / ma.window_pairs(length, window), 3),
+               ms=try_ms(splash_with(local, heads, sizes_of(b, b, compute, fused=fused)), *wqkv))
+    sq, skv = ((1, 4, 512, 64), (1, 2, 512, 64)) if DRY else ((1, 8, 2048, 64), (1, 4, 2048, 64))
+    sqkv = operands(sq, skv, seed=3)
+
+    def dense(q, k, v):   # float32 scores of the whole square under the rule
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
+        at = jnp.arange(q.shape[2])
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * q.shape[-1] ** -0.5
+        p = jax.nn.softmax(jnp.where(ma.window_allowed(at[:, None], at[None, :], window), s, -jnp.inf), -1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+    results = [(jax.jit(f)(*sqkv), *gradients(f)(*sqkv)) for f in (dense, taken)]
+    report("window_against_dense_float32", q=sq, window=window,
+           apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))),
+           finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in results[1]))
+    sys.exit(0)
 for cq, ckv in (((1, 4, 256, 128), (1, 4, 256, 128)), ((1, 8, 256, 64), (1, 2, 256, 64))) if DRY else (
         ((4, 16, 4096, 128), (4, 16, 4096, 128)), ((2, 32, 8192, 64), (2, 8, 8192, 64))):
     cqkv = operands(cq, ckv, seed=1)
