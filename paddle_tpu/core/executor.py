@@ -25,7 +25,7 @@ from ..dist_resilience import guard_blocking as _guard_blocking
 from ..monitor import MONITOR as _MON
 from . import locks
 from .dtypes import as_np_dtype
-from .lowering import LoweringContext, run_block_with_backward
+from .lowering import LoweringContext, plan_kept, run_block_with_backward
 from .program import Program, Variable, default_main_program
 from .scope import RNG_STATE_VAR, Scope, global_scope
 
@@ -264,6 +264,8 @@ class _CompiledStep:
             ctx.fetch_names = tuple(self.fetch_names)
             env = dict(state_ro)
             env.update(state_rw)
+            # what the recomputed segments keep for backward, from the shapes and the room the state leaves on a chip
+            plan_kept(ctx, ops, {n: v.shape for n, v in feeds.items()}, self._held_bytes(env, whole=manual))
             env.update(feeds)
             env = run_block_with_backward(ctx, ops, env)
             new_state = {n: env[n] for n in written if n in env}
@@ -691,6 +693,15 @@ class _CompiledStep:
         if self.mesh is not None and self.last_recompiled:
             self._count_state({**state_ro, **state_rw, **new_state})
         return fetches, new_key
+
+    def _held_bytes(self, state, whole=False) -> int:
+        """Bytes of the step's state (traced values by name) that ONE chip
+        holds: a value's own where there is no mesh or the step is traced inside
+        a `shard_map` of its own (`whole`), else its shard's under the
+        program's hints."""
+        specs = {} if whole or self.mesh is None else self.state_specs
+        return sum(int(np.prod(specs[n].shard_shape(v.shape) if n in specs else v.shape)) * v.dtype.itemsize
+                   for n, v in state.items())
 
     def _count_state(self, state):
         """At placement (a program's first run on its mesh): the bytes of its

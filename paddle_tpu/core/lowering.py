@@ -16,14 +16,15 @@ as one program.  The gradients themselves are a fusion boundary
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..monitor import MONITOR as _MON
 from .program import Block, Operator
-from .registry import get_op_def
+from .registry import get_op_def, get_op_def_or_none
 
 
 class LoweringContext:
@@ -66,6 +67,12 @@ class LoweringContext:
         # BuildStrategy.memory_optimize: rematerialize the forward during
         # backward (jax.checkpoint) instead of keeping activations
         self.remat = False
+        # `plan_kept`'s choice: a `recompute_scope` segment's number -> the names of the values that segment keeps
+        # for backward instead of making them again (none: the segment keeps what it reads and nothing it makes) ...
+        self.kept_by_segment: Dict[int, Set[str]] = {}
+        # ... and those of the segment being lowered (`_run_recomputed`): a lowering whose kernel has residuals that
+        # only its forward makes gives them their name (`registry.set_kept`) where it is in here
+        self.keep: Set[str] = set()
 
     def next_key(self):
         self.key, sub = jax.random.split(self.key)
@@ -91,7 +98,8 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
 
     A run of ops that `recompute_scope` marked as one segment is lowered as one
     `jax.checkpoint` (`_run_recomputed`, which calls back with `segments`
-    off)."""
+    off and `ctx.keep` set: an output variable that the segment's policy saves
+    is given its own name as it is made)."""
     # the op census runs at TRACE time only (this loop is the trace), so
     # it costs nothing at execution
     mon_on = _MON.enabled
@@ -118,6 +126,9 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
         with jax.named_scope(part) if part else contextlib.nullcontext():
             with jax.named_scope(f"op{idx}:{op.type}"):
                 lower_one(ctx, op, env)
+        if ctx.keep:
+            for n in ctx.keep.intersection(op.output_arg_names):
+                env[n] = checkpoint_name(env[n], n)
         if mon_on:
             _MON.counter("lowering.ops_total").inc()
     return env
@@ -125,24 +136,126 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
 
 def _run_recomputed(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any], first: int) -> None:
     """The ops of one `recompute_scope` as a `jax.checkpoint`: where the trace
-    is differentiated, backward keeps what the segment reads from `env` and the
-    RNG key, and runs its ops again (the key rides through, so a random op
-    draws the same numbers the second time).  Everything the ops write goes
-    back into `env`, as if they had run one by one."""
+    is differentiated, backward keeps what the segment reads from `env`, the
+    RNG key and the values `plan_kept` chose for this segment
+    (`ctx.kept_by_segment`: products' outputs, kernels' residuals, as far as
+    the chip has room), and runs the other ops again (the key rides through, so
+    a random op draws the same numbers the second time).  With nothing chosen
+    the checkpoint has no policy: nothing the segment makes is kept.
+    Everything the ops write goes back into `env`, as if they had run one by
+    one."""
     reads, written = [], set()
     for op in ops:
         reads += [n for n in op.input_arg_names if n not in written and n in env and n not in reads]
         written.update(op.output_arg_names)
+    keep = ctx.kept_by_segment.get(ops[0].attrs["recompute_segment"], set())
 
     def segment(values, key):
         inner = dict(zip(reads, values))
-        ctx.key = key
-        run_ops(ctx, ops, inner, first, segments=False)
+        ctx.key, ctx.keep = key, keep
+        try:
+            run_ops(ctx, ops, inner, first, segments=False)
+        finally:
+            ctx.keep = set()
         return {n: inner[n] for n in sorted(written) if n in inner}, ctx.key
 
-    made, ctx.key = jax.checkpoint(segment)([env[n] for n in reads], ctx.key)
+    policy = jax.checkpoint_policies.save_only_these_names(*sorted(keep)) if keep else None
+    made, ctx.key = jax.checkpoint(segment, policy=policy)([env[n] for n in reads], ctx.key)
     env.update(made)
     _MON.counter("lowering.recomputed_segments").inc()
+
+
+#: Of the chip's memory that the step's state does not hold, the share that the values kept for backward may take
+#: (`plan_kept`); the rest is the room of the step's own temporaries: the gradients, the head's logits, a layer's
+#: activations while it is made again.  PERF.md section 6, PR 51, has the two cells' plans behind it.
+KEPT_SHARE = 0.5
+
+#: Ops whose transposes read neither operand: a value that reaches nothing in its segment but through them (a layer's
+#: last product, which the bias and the residual stream are added to) is read by nothing in that segment's backward.
+_SUMS = ("elementwise_add", "elementwise_sub", "sum")
+
+
+class Kept(NamedTuple):
+    """A value a recomputed segment can keep for backward: what a chip pays to
+    hold it and what it pays to make it again."""
+    segment: int    # the `recompute_scope`'s number
+    name: str       # what the lowering calls the value: `jax.checkpoint`'s policy saves by name
+    nbytes: int     # a chip's
+    flops: float    # of the op that makes it, a chip's
+
+
+def choose_kept(candidates: Sequence[Kept], budget: float) -> List[Kept]:
+    """The candidates a block keeps, in program order: greedily by the
+    operations a kept byte saves, the dearest first and ties in program order,
+    each taken if what is left of `budget` bytes holds it.  A pure function:
+    the same candidates and budget give the same set."""
+    chosen, left = [], budget
+    for at in sorted(range(len(candidates)), key=lambda i: (-candidates[i].flops / max(candidates[i].nbytes, 1), i)):
+        if candidates[at].nbytes <= left:
+            chosen.append(at)
+            left -= candidates[at].nbytes
+    return [candidates[at] for at in sorted(chosen)]
+
+
+def kept_candidates(ctx: LoweringContext, ops: List[Operator], shapes) -> List[Kept]:
+    """What the recomputed segments among `ops` could keep, in program order:
+    for every op inside a `recompute_scope` whose type has a `registry.set_kept`
+    rule (a matrix product's output, a kernel's residuals), the value's bytes
+    from the variables' static shapes (`shapes`, a `resource_plan.ShapeEnv`) and
+    the operations of the op's cost rule, both divided by the chips the mesh
+    splits the batch over.  An output variable that no op of its segment reads
+    but through sums (`_SUMS`) is left out: the segment's backward reads it
+    nowhere, and what leaves the segment is the next one's to keep."""
+    from ..ops.common import batch_shards
+    from .resource_plan import op_cost
+
+    shards = max(batch_shards(ctx.mesh, ctx.batch_axis, shapes.batch), 1)
+    readers: Dict[tuple, List[Operator]] = {}
+    for op in ops:
+        for n in op.input_arg_names:
+            readers.setdefault((op.attrs.get("recompute_segment"), n), []).append(op)
+
+    def read_in_backward(segment, name):
+        return any(op.type not in _SUMS or any(read_in_backward(segment, n) for n in op.output_arg_names)
+                   for op in readers.get((segment, name), ()))
+
+    found = []
+    for op in ops:
+        segment = op.attrs.get("recompute_segment")
+        rule = None if segment is None else getattr(get_op_def_or_none(op.type), "kept", None)
+        value = rule(ctx, op, shapes) if rule is not None else None
+        if value is not None and (value[0] not in op.output_arg_names or read_in_backward(segment, value[0])):
+            found.append(Kept(segment, value[0], int(value[1]) // shards, op_cost(op, op.block, shapes)[0] / shards))
+    return found
+
+
+def plan_kept(ctx: LoweringContext, ops: List[Operator], feed_shapes: Dict[str, tuple], held_bytes: int) -> None:
+    """Choose, once a trace and before any op of it is lowered, what the
+    block's `recompute_scope` segments keep for backward
+    (`ctx.kept_by_segment`), from what can be observed: the candidates and
+    their prices from the program's shapes (`kept_candidates`), the budget from
+    the device: its memory (`memory_stats()["bytes_limit"]`, else the chip
+    model's `resource_plan.CHIP_HBM_BYTES`) less `held_bytes`, the state a chip
+    holds for the step, times `KEPT_SHARE`.  A block with no `backward` op (a
+    `for_test` clone) or no segment chooses nothing; a chip that is full keeps
+    nothing and the program is the plain `jax.checkpoint`'s.  Counted:
+    `lowering.recomputed_kept_values`, `_kept_bytes` (a chip's) and, of all the
+    candidates, `_candidates_bytes`."""
+    ctx.kept_by_segment = {}
+    if not (any(op.type == "backward" for op in ops) and any("recompute_segment" in op.attrs for op in ops)):
+        return
+    from ..monitor.memstats import device_bytes_limit
+    from .resource_plan import CHIP_HBM_BYTES, ShapeEnv
+
+    candidates = kept_candidates(ctx, ops, ShapeEnv(ops[0].block.program, feed_shapes))
+    limit = device_bytes_limit()
+    budget = KEPT_SHARE * max((CHIP_HBM_BYTES if limit is None else limit) - held_bytes, 0)
+    chosen = choose_kept(candidates, budget)
+    for value in chosen:
+        ctx.kept_by_segment.setdefault(value.segment, set()).add(value.name)
+    _MON.counter("lowering.recomputed_kept_values").inc(len(chosen))
+    _MON.counter("lowering.recomputed_kept_bytes").inc(sum(value.nbytes for value in chosen))
+    _MON.counter("lowering.recomputed_candidates_bytes").inc(sum(value.nbytes for value in candidates))
 
 
 def lower_one(ctx: LoweringContext, op: Operator, env: Dict[str, Any]) -> None:

@@ -547,8 +547,13 @@ def recompute_scope():
     """Marks the ops appended to the main block inside it as ONE segment that
     backward computes again: the lowering runs the segment as a
     `jax.checkpoint` (core/lowering.py: `run_ops`), so backward keeps what the
-    segment READS and nothing it makes (a decoder layer's activations are
-    made again from the layer's input).  The ops stay ops of the block, with
+    segment READS and, of what it makes, only what is dear to make again and
+    the chip has room for: matrix products' outputs and kernels' residuals,
+    chosen once a trace from the program's shapes and the memory the step's
+    state leaves free (core/lowering.py: `plan_kept`; nothing where the chip
+    is full).  Everything else, the elementwise work, norms, gates, is made
+    again from the layer's input and the kept values: the same numbers either
+    way.  The ops stay ops of the block, with
     their own scopes, statistics and fetchable outputs; a `for_test` clone,
     which has no backward, runs them as they are.  Nested scopes are one
     segment, the outermost.  A segment's number is its program's own (the

@@ -38,6 +38,7 @@ class OpDef:
         self.infer = infer
         self.cost = cost
         self.step_stats = None  # (slots, publish, attrs): see set_step_stats
+        self.kept = None  # what a recomputed segment may keep of the op: see set_kept
 
 
 _REGISTRY: Dict[str, OpDef] = {}
@@ -55,6 +56,7 @@ def register_op(type: str, infer: Optional[InferFn] = None):
             d.cost = prev.cost  # re-registration keeps an attached cost rule
         if prev is not None:
             d.step_stats = prev.step_stats
+            d.kept = prev.kept
         _REGISTRY[type] = d
         return fn
 
@@ -81,6 +83,23 @@ def set_cost(type: str, cost: CostFn):
         raise KeyError(
             f"set_cost({type!r}): op has no registered lowering"
         ) from None
+
+
+def set_kept(type: str, rule):
+    """Say what a `recompute_scope` segment may KEEP of a registered op instead
+    of making it again in backward (core/lowering.py: `plan_kept`): a value that
+    is dear to make (a matrix product, a kernel call) and that the op's lowering
+    can name with `jax.ad_checkpoint.checkpoint_name`.  `rule(ctx, op, shapes)`
+    (the lowering's context, the op, a `resource_plan.ShapeEnv`) returns (the
+    name, the value's bytes over the whole batch), or None where this op makes
+    no such value (its kernel is not the path taken).  A name that is one of the
+    op's output variables is given by the lowering itself; any other (a kernel's
+    residuals) by the op's own lowering, where it finds the name in `ctx.keep`:
+    a program that keeps nothing holds no name."""
+    try:
+        _REGISTRY[type].kept = rule
+    except KeyError:
+        raise KeyError(f"set_kept({type!r}): op has no registered lowering") from None
 
 
 def set_step_stats(type: str, slots, publish: StatsFn, attrs=()):

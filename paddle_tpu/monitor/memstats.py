@@ -39,19 +39,30 @@ def live_array_count() -> int:
     return n
 
 
-def device_bytes_in_use(device_index: int = 0) -> float:
-    """PJRT allocator's bytes_in_use for one device; NaN where the backend
-    (e.g. XLA:CPU) exposes no memory_stats."""
+def _device_stat(device_index: int, key: str):
+    """One number of a device's PJRT `memory_stats()`; None where the backend
+    (e.g. XLA:CPU) exposes none."""
     import jax
 
     try:
-        dev = jax.local_devices()[device_index]
-        stats = dev.memory_stats()
-        if stats:
-            return float(stats.get("bytes_in_use", float("nan")))
+        stats = jax.local_devices()[device_index].memory_stats()
+        return stats.get(key) if stats else None
     except Exception:
-        pass
-    return float("nan")
+        return None
+
+
+def device_bytes_in_use(device_index: int = 0) -> float:
+    """PJRT allocator's bytes_in_use for one device; NaN where the backend
+    exposes no memory_stats."""
+    in_use = _device_stat(device_index, "bytes_in_use")
+    return float("nan") if in_use is None else float(in_use)
+
+
+def device_bytes_limit(device_index: int = 0):
+    """PJRT allocator's bytes_limit for one device: the memory a program on it
+    can have; None where the backend exposes no memory_stats."""
+    limit = _device_stat(device_index, "bytes_limit")
+    return int(limit) if limit else None
 
 
 def register_memory_gauges(mon):

@@ -47,6 +47,29 @@ def over_batch_shards(ctx, fn, batched, whole=()):
                          check_vma=False)(tuple(batched), tuple(whole))
 
 
+def operand_of(shapes, name):
+    """The variable `name` as a lowering will see it, shape and dtype alone
+    (`shapes`: a `resource_plan.ShapeEnv`): what a `registry.set_kept` rule
+    hands the op's own path rule before anything is traced."""
+    import jax
+
+    return jax.ShapeDtypeStruct(shapes.shape(name), canon_dtype(shapes.dtype(name)))
+
+
+def residuals_name(op):
+    """What an op whose kernel has residuals that only its forward makes calls
+    them, for its `registry.set_kept` rule and for its lowering alike."""
+    return op.output("Out")[0] + "@residuals"
+
+
+def kept_residuals(ctx, op):
+    """`residuals_name(op)` where the recomputed segment being lowered keeps
+    them (`ctx.keep`), else None: the name the op's kernel gives them.  Where
+    nothing is kept the op is not asked for its names (the tests' stand-ins
+    have none)."""
+    return residuals_name(op) if ctx.keep and residuals_name(op) in ctx.keep else None
+
+
 def bcast_y_to_x(x, y, axis: int):
     """Fluid elementwise broadcasting (reference: operators/elementwise/
     elementwise_op_function.h): Y's dims align to X starting at `axis`

@@ -82,6 +82,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -515,17 +516,22 @@ def _forward(q, k, v, plan: Plan):
     return _join_own_block(q, k, v, out, lse, plan) if seq else (out, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention(q, k, v, plan: Plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attention(q, k, v, plan: Plan, keep=None):
     return _forward(q, k, v, plan)[0]
 
 
-def _attention_fwd(q, k, v, plan: Plan):
+def _attention_fwd(q, k, v, plan: Plan, keep):
+    """`keep` names the two residuals that only the forward kernels make: a
+    `jax.checkpoint` round the op whose policy saves the name
+    (`core/lowering.py: plan_kept`) then runs no second forward."""
     out, lse = _forward(q, k, v, plan)
+    if keep:
+        out, lse = checkpoint_name(out, keep), checkpoint_name(lse, keep)
     return out, (q, k, v, out, lse)
 
 
-def _attention_bwd(plan: Plan, residuals, do):
+def _attention_bwd(plan: Plan, keep, residuals, do):
     q, k, v, out, lse = residuals
     seq = plan.first_key
     di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32), do.astype(jnp.float32))
@@ -539,7 +545,7 @@ def _attention_bwd(plan: Plan, residuals, do):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def attention_under(plan: Plan, q, k, v, scale: float):
+def attention_under(plan: Plan, q, k, v, scale: float, keep=None):
     """softmax(q k^T . scale under the plan's rule) v over (B, Hq, positions,
     dh) queries and (B, Hkv, positions, dh) keys and values, Hkv a divisor of
     Hq.  The kernels have no scale of their own: the queries carry it, rounded
@@ -547,23 +553,24 @@ def attention_under(plan: Plan, q, k, v, scale: float):
     of two (64-wide heads); at 128-wide heads the output is 2.51e-3 from
     float32 where the flash kernel, which scales the float32 scores, is
     2.03e-3, most of either the output's own rounding
-    (tools/chip_attention_errors.py, PR 37)."""
+    (tools/chip_attention_errors.py, PR 37).  `keep`: the name under which a
+    recomputed segment keeps the output and the log-sum-exp for backward."""
     blocks = block_maps(plan)[0].block_mask
     _MON.counter("lowering.attention_blocks_visited").inc(int(np.count_nonzero(blocks)))
     _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
     _MON.counter("lowering.attention_own_block_terms").inc(int(plan.first_key > 0))
     with jax.named_scope("block_sparse_attention"):
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-        return _attention(q, k, v, plan)
+        return _attention(q, k, v, plan, keep)
 
 
-def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bool = False):
+def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the block-diffusion rule over 2L positions in blocks
     of `mask_block`."""
-    return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale)
+    return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale, keep)
 
 
-def window_attention(q, k, v, window: int, scale: float, interpret: bool = False):
+def window_attention(q, k, v, window: int, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the sliding-window rule over equal lengths of queries
     and keys.  Counted at trace time: the op, the pairs inside the blocks its
     forward block map visits and the pairs the rule allows, over rows and heads."""
@@ -574,9 +581,9 @@ def window_attention(q, k, v, window: int, scale: float, interpret: bool = False
     _MON.counter("lowering.window_pairs_visited").inc(q.shape[0] * visited)
     _MON.counter("lowering.window_pairs_allowed").inc(q.shape[0] * q.shape[1] * window_pairs(q.shape[2], window))
     with jax.named_scope("window_attention"):
-        return attention_under(plan, q, k, v, scale)
+        return attention_under(plan, q, k, v, scale, keep)
 
 
-def causal_attention(q, k, v, scale: float, interpret: bool = False):
+def causal_attention(q, k, v, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the causal rule over equal lengths of queries and keys."""
-    return attention_under(causal_plan(q.shape[2], q.shape[1], interpret), q, k, v, scale)
+    return attention_under(causal_plan(q.shape[2], q.shape[1], interpret), q, k, v, scale, keep)

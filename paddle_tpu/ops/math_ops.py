@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.registry import register_op
+from ..core.registry import register_op, set_kept
 from .common import bcast_y_to_x, first, match_dtype, normalize_axes
 
 
@@ -615,3 +615,13 @@ def _elems_of(shape):
 
 _RP.register_cost(["mul"], _cost_mul)
 _RP.register_cost(["matmul"], _cost_matmul)
+
+
+def _kept_product(ctx, op, shapes):
+    """A product's output is dear to make again and the lowering names it."""
+    out = op.output("Out")[0]
+    return out, shapes.nbytes(out)
+
+
+set_kept("mul", _kept_product)
+set_kept("matmul", _kept_product)

@@ -22,7 +22,7 @@ import numpy as np
 
 from ..core.registry import register_op
 from ..monitor import MONITOR as _MON
-from .common import batch_shards, canon_dtype, first, match_dtype, over_batch_shards
+from .common import batch_shards, canon_dtype, first, match_dtype, kept_residuals, operand_of, over_batch_shards, residuals_name
 
 
 @register_op("conv2d")
@@ -766,6 +766,7 @@ def _fused_attention(ctx, op, ins):
         _MON.counter("lowering.kept_tensor_readers").inc()
     native = layout == "bhld" or path == "row_kernel"
     _MON.counter("lowering.attention_layout_native" if native else "lowering.attention_layout_transposed").inc()
+    keep = kept_residuals(ctx, op)
 
     def attend(q, k, v, bias=None):
         if not native:
@@ -775,11 +776,11 @@ def _fused_attention(ctx, op, ins):
             from .masked_attention import block_sparse_attention, window_attention
 
             under = window_attention if mask[0] == "sliding_window" else block_sparse_attention
-            out = under(q, k, v, mask[1], float(scale))
+            out = under(q, k, v, mask[1], float(scale), keep=keep)
         elif path == "block_causal":
             from .masked_attention import causal_attention
 
-            out = causal_attention(q, k, v, float(scale))
+            out = causal_attention(q, k, v, float(scale), keep=keep)
         else:
             if k.shape[heads] != q.shape[heads]:
                 k, v = (jnp.repeat(t, q.shape[heads] // t.shape[heads], axis=heads) for t in (k, v))
@@ -1497,6 +1498,7 @@ _A.register_rule(["fused_attention"], _infer_fused_attention)
 
 # --- static cost rules (core/resource_plan.py) ------------------------------
 
+from ..core import registry as _REG
 from ..core import resource_plan as _RP
 
 _RP.register_elementwise_cost("square_error_cost", "label_smooth",
@@ -1611,3 +1613,23 @@ def _cost_fused_attention(ctx):
 
 _RP.register_cost(["fused_attention"], _cost_fused_attention)
 _RP.register_cost(["ring_attention"], _cost_fused_attention)
+
+
+def _kept_attention(ctx, op, shapes):
+    """Where the op takes the splash kernels of `ops/masked_attention.py`
+    (`block_causal`, `block_sparse`): the output and the float32 log-sum-exp a
+    query and head, which only the forward kernels make.  The other paths name
+    nothing: no cell runs the flash kernel, the row kernel keeps nothing but its
+    output, XLA's attention is XLA's to make again."""
+    q, k, v = (operand_of(shapes, op.input(slot)[0]) for slot in ("Q", "K", "V"))
+    layout = op.attr("layout", "bhld")
+    path = _attention_path(ctx.platform, ctx.mesh, q, k, _structured_mask(op, q, k, layout), op.attr("causal", False),
+                           bool(op.input("Bias")), layout, v.shape[-1], ctx.batch_axis)
+    if path not in ("block_causal", "block_sparse"):
+        return None
+    heads, positions = _ATTENTION_AXES[layout]
+    lse_bytes = 4 * q.shape[0] * q.shape[heads] * q.shape[positions]
+    return residuals_name(op), shapes.nbytes(op.output("Out")[0]) + lse_bytes
+
+
+_REG.set_kept("fused_attention", _kept_attention)

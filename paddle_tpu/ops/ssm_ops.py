@@ -55,13 +55,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import analysis as _A
 from ..core import resource_plan as _RP
-from ..core.registry import register_op, set_step_stats
+from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import ssm_kernels
-from .common import batch_shards, first, over_batch_shards
+from .common import batch_shards, first, kept_residuals, operand_of, over_batch_shards, residuals_name
 
 #: Tokens a chunk of the XLA form (`_scan_path`: the CPU's and the odd shapes';
 #: the kernels' chunk is `ssm_kernels.CHUNK`, and their state is `_carried`
@@ -164,13 +165,18 @@ def _scan_path(platform, mesh, x, a_log, batch_axis=None):
     return "kernels" if platform == "tpu" and whole and batch_shards(mesh, batch_axis, x.shape[0]) else "xla"
 
 
+def _kernel_chunk(tokens, chunk=ssm_kernels.CHUNK):
+    """Tokens a grid step of the kernels: `chunk`, or a short row rounded up to whole sublane tiles."""
+    return min(int(chunk), -(-tokens // 16) * 16)
+
+
 def _kernel_operands(x, dt, a_log, b_t, c_t, d_skip, dt_bias, chunk, block):
     """(the kernels' seven operands: x, dt, B, C padded to a whole number of
     chunks, the tail stepping by exactly 0 (`_NO_STEP`), A transposed [N, d], D
     and DtBias float32; their static arguments: the chunk, the block, the seams;
     the padded tokens)."""
     T, d = x.shape[1:]
-    chunk = min(int(chunk), -(-T // 16) * 16)
+    chunk = _kernel_chunk(T, chunk)
     pad = -T % chunk
     if pad:
         x, b_t, c_t = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (x, b_t, c_t))
@@ -192,8 +198,9 @@ def _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block,
     return (y[:, :T], ssm_kernels.channels_last(final), means), tuple(kept)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels="tpu", chunk=ssm_kernels.CHUNK, block=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels="tpu", chunk=ssm_kernels.CHUNK, block=None,
+                          keep=None):
     """`chunked_selective_scan`'s results from the Pallas kernels of
     `ops/ssm_kernels.py`, `chunk` tokens and `block` channels a grid step
     (`ssm_kernels.block_of` the row's unless given; `_scan_path` says when
@@ -203,14 +210,19 @@ def kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels="tpu"
     return _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, False)[0]
 
 
-def _kernel_scan_fwd(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block):
-    """The op where it is differentiated: forward keeps the chunks' start states beside the seven inputs."""
+def _kernel_scan_fwd(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, keep):
+    """The op where it is differentiated: forward keeps the chunks' start states beside the seven inputs.  `keep`
+    names the two values that only the forward kernel makes and backward reads, the output (the mixer's gate reads
+    it) and the start states: a `jax.checkpoint` round the op whose policy saves the name (`core/lowering.py:
+    plan_kept`) then runs no second forward."""
     _MON.counter("lowering.selective_scan_starts_kept").inc()
-    out, kept = _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, True)
-    return out, ((x, dt, a_log, b_t, c_t, d_skip, dt_bias), kept)
+    (y, final, means), (starts,) = _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, chunk, block, True)
+    if keep:
+        y, starts = checkpoint_name(y, keep), checkpoint_name(starts, keep)
+    return (y, final, means), ((x, dt, a_log, b_t, c_t, d_skip, dt_bias), (starts,))
 
 
-def _kernel_scan_bwd(kernels, chunk, block, residuals, cotangents):
+def _kernel_scan_bwd(kernels, chunk, block, keep, residuals, cotangents):
     (x, dt, a_log, b_t, c_t, d_skip, dt_bias), (starts,) = residuals
     _MON.counter("lowering.selective_scan_kernel_transposed_calls").inc()
     T = x.shape[1]
@@ -239,10 +251,11 @@ def _selective_scan(ctx, op, ins):
     # "interpret" is the tests': the kernels interpreted where no chip is
     kernels = {"kernels": "tpu", "interpret": "interpret"}.get(_scan_path(ctx.platform, ctx.mesh, x, a_log, ctx.batch_axis))
     _MON.counter("lowering.selective_scan_kernel_calls").inc(1 if kernels else 0)
+    keep = kept_residuals(ctx, op)
 
     def scan(x, dt, b_t, c_t, a_log, d_skip, dt_bias):
         if kernels:
-            y, final, (decay, step) = kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels)
+            y, final, (decay, step) = kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, kernels, keep=keep)
         else:
             y, final, (decay, step) = chunked_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias)
         decay, step, largest = jax.lax.stop_gradient((decay, step, jnp.max(jnp.abs(final))))
@@ -321,6 +334,20 @@ def _cost_selective_scan(ctx):
 
 
 _RP.register_cost(["selective_scan"], _cost_selective_scan)
+
+
+def _kept_scan(ctx, op, shapes):
+    """Where the op takes the kernels: its output and the float32 state every
+    chunk starts from, [chunks, b, N, d]."""
+    x, a_log = operand_of(shapes, op.input("X")[0]), operand_of(shapes, op.input("ALog")[0])
+    if _scan_path(ctx.platform, ctx.mesh, x, a_log, ctx.batch_axis) == "xla":
+        return None
+    (batch, tokens, channels), state = x.shape, a_log.shape[1]
+    starts_bytes = 4 * -(-tokens // _kernel_chunk(tokens)) * batch * state * channels
+    return residuals_name(op), shapes.nbytes(op.output("Out")[0]) + starts_bytes
+
+
+set_kept("selective_scan", _kept_scan)
 
 
 @register_op("memory_gate")

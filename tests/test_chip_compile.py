@@ -30,16 +30,20 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one device of a described v5e 2x2 host."""
+def host():
+    """A described v5e 2x2 host: four devices, none attached."""
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except (ImportError, RuntimeError, ValueError, NotImplementedError) as e:
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip(host):
+    """Sharding on one device of the described host."""
+    return SingleDeviceSharding(host.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -671,7 +675,13 @@ def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_roo
     assert re.search(r"/shared_expert(_\d+)?/op\d+:mul", text) and text.count("/plain_short_conv/") > 0
 
 
-def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recomputed_and_backward(chip):
+#: What a v5e reports as `memory_stats()["bytes_limit"]` (my chip run, PR 51, call 1): the limit `plan_kept` reads on
+#: the chip, given to it here, where the CPU reports none, so that the step compiled here is the step the chip compiles.
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+@pytest.mark.parametrize("limit,made_again", [(0, 3), (V5E_BYTES_LIMIT, 0)], ids=["a-full-chip", "a-v5es-room"])
+def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recomputed_and_backward(chip, monkeypatch, limit, made_again):
     """A small Jamba (the configuration's period cut to four layers, 512 wide:
     1024 channels a mixer and a state of 16, which `_scan_path` sends to the
     kernels on the TPU; 64 tokens) trained one step, compiled for the described
@@ -680,13 +690,17 @@ def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recompu
     `custom_vjp`'s backward), carries an `op_name` that the benchmark's readers
     `ssm_scan_roofline_share` and `ssm_ms_per_step` match (their own `SCOPE`s,
     imported), the recomputed ones `recompute_ms_per_step`'s too; no `while` is
-    left under the op's scope."""
+    left under the op's scope.  The forward kernel is made again where the chip
+    has no room for what `plan_kept` would keep (the cell's own thirteen at its
+    size), and not at all where it has (this small model on a v5e: PR 51)."""
     import paddle_tpu as fluid
     from benchmark import manifest as mf
     from benchmark.metrics import recompute_ms_per_step, ssm_ms_per_step, ssm_scan_roofline_share
     from benchmark.models import jamba
     from paddle_tpu.core import executor as ex
+    from paddle_tpu.monitor import memstats
 
+    monkeypatch.setattr(memstats, "device_bytes_limit", lambda *a: limit)
     cfg = dict(mf.read_json("benchmark/configs/ai21-jamba2-3b.json"), hidden_size=512, intermediate_size=96, mamba_dt_rank=4,
                num_attention_heads=4, num_key_value_heads=1, vocab_size=96, num_hidden_layers=4, attn_layer_period=4,
                attn_layer_offset=2)
@@ -713,10 +727,110 @@ def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recompu
     transposed = [name for name in kernels if name.endswith("/selective_scan_transposed/pallas_call")]
     again = [name for name in kernels if recompute_ms_per_step.SCOPE in name]
     forward = [name for name in kernels if name not in transposed and name not in again]
-    assert len(forward) == len(again) == len(transposed) == 3, kernels             # the three Mamba layers, each way
+    assert len(forward) == len(transposed) == 3 and len(again) == made_again, kernels   # the three Mamba layers, each way
     assert all("transpose(" in name for name in transposed) and not any("transpose(" in name for name in forward)
     assert all(name.endswith("/selective_scan/pallas_call") for name in again)
     assert not re.search(r'op_name="[^"]*op\d+:selective_scan/[^"]*while', text)
+
+
+def _kept_step(module, config, traffic, devices, monkeypatch):
+    """(the compiled train step of a cell at its configuration's and traffic's
+    own sizes, for the described chip or mesh, with what `plan_kept` chose at
+    the chip's own memory limit; the `lowering.recomputed_*` counters of its
+    trace)."""
+    import importlib
+
+    import paddle_tpu as fluid
+    from benchmark import manifest as mf
+    from paddle_tpu import monitor
+    from paddle_tpu.core import executor as ex
+    from paddle_tpu.monitor import memstats
+
+    model = importlib.import_module(f"benchmark.models.{module}")
+    cfg, job = mf.read_json(f"benchmark/configs/{config}.json"), mf.read_json(f"benchmark/traffic/{traffic}.json")
+    monkeypatch.setattr(memstats, "device_bytes_limit", lambda *a: V5E_BYTES_LIMIT)
+    mesh, make_mesh = None, fluid.parallel.make_mesh
+    if "mesh_shape" in job:    # the builder's mesh over the described devices, not the CPU's
+        monkeypatch.setattr(fluid.parallel, "make_mesh", lambda sizes, names, _=None: make_mesh(sizes, names, list(devices)))
+        mesh = fluid.parallel.make_mesh(tuple(job["mesh_shape"]), tuple(job["mesh_axes"]))
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = model.build(cfg, job)
+    main.random_seed = startup.random_seed = 3
+    scope = fluid.Scope()
+    for v in startup.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    rows = job["batch_per_chip"] * (mesh.size if mesh is not None else 1)
+    feeds = {n: jax.ShapeDtypeStruct((rows, job["seq_len"]), I32) for n in model.FEEDS}
+    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, mesh=mesh, batch_axis=job.get("mesh_axes", ["dp"])[0],
+                            platform="tpu", feed_shapes={n: s.shape for n, s in feeds.items()})
+    one = SingleDeviceSharding(devices[0])
+
+    def placed(v, sharding):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one if mesh is None else sharding)
+
+    monitor.reset()
+    monitor.enable()
+    try:
+        lowered = step.jfn.lower(
+            {n: placed(scope.find_var(n), mesh and step.state_specs[n]) for n in step.rw_names},
+            {n: placed(scope.find_var(n), mesh and step.state_specs[n]) for n in step.ro_names},
+            {n: placed(s, mesh and step.feed_specs[n]) for n, s in feeds.items()},
+            placed(jax.random.PRNGKey(0), mesh and step.key_spec))
+        counted = {k[len("lowering.recomputed_"):]: v for k, v in monitor.MONITOR.counter_values().items()
+                   if k.startswith("lowering.recomputed_")}
+    finally:
+        monitor.disable()
+        monitor.reset()
+    return lowered.compile(), counted
+
+
+def _planned_peak(compiled):
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+
+
+def _made_again(text):
+    """The names of the instructions of the compiled step that stand in a
+    rematerialised computation, as `recompute_ms_per_step` finds them."""
+    from benchmark.metrics import recompute_ms_per_step
+
+    return [name for name in recompute_ms_per_step.op_names(text).values() if recompute_ms_per_step.SCOPE in name]
+
+
+def test_phi4_mini_flashs_step_keeps_every_product_and_kernel_residual_and_its_planned_peak_leaves_room(host, monkeypatch):
+    """`phi-4-mini-flash-reasoning.train-sambay-s8192`'s whole step at the
+    published widths, compiled for the described v5e with what `plan_kept`
+    chooses at the chip's memory limit: all 37 candidates of the six segments
+    (3.45 GB of the 4.27 the state leaves the kept values), planned under the
+    14.5 GB the issue allows and over the parent's 10.5; in the rematerialised
+    computations no product and no kernel call is left (ISSUE 51)."""
+    compiled, counted = _kept_step("phi4flash", "phi-4-mini-flash-reasoning", "train-sambay-s8192", host.devices, monkeypatch)
+    assert counted == {"segments": 6, "kept_values": 37, "kept_bytes": 3449552896, "candidates_bytes": 3449552896}
+    peak = _planned_peak(compiled)
+    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 12.5e9 <= peak <= 14.5e9, peak
+    again = _made_again(compiled.as_text())
+    assert again and not [name for name in again if name.endswith(("/dot_general", "/pallas_call"))]
+
+
+@pytest.mark.slow   # one compile for four devices, ~3 minutes here: run by name (`-m slow`); PERF.md, PR 51, has its readings
+def test_jamba2s_step_on_the_2x2_host_keeps_what_a_chips_room_holds_and_its_planned_peak_leaves_room(host, monkeypatch):
+    """`ai21-jamba2-3b.train-ssm-fsdp4`'s whole step at the published widths
+    on the described 2x2 host, ZeRO-3 over `dp`: of 98 candidates (9.38 GB a
+    chip) the budget (half of what 4.80 GB of state leave of the chip) holds
+    68, 6.04 GB: the attention's residuals and every product but the 13 step
+    projections and the last layer's `up`; the 13 scans' forward kernels are
+    still made again (their output and start states come last by operations a
+    byte).  Planned under 14.5 GB a chip."""
+    compiled, counted = _kept_step("jamba", "ai21-jamba2-3b", "train-ssm-fsdp4", host.devices, monkeypatch)
+    assert counted == {"segments": 14, "kept_values": 68, "kept_bytes": 6035210240, "candidates_bytes": 9382264832}
+    peak = _planned_peak(compiled)
+    print(f"planned peak {peak / 1e9:.3f} GB a chip")
+    assert 11e9 <= peak <= 14.5e9, peak
+    again = _made_again(compiled.as_text())
+    assert sum(name.endswith("/selective_scan/pallas_call") for name in again) >= 13
+    assert not [name for name in again if name.endswith("/pallas_call") and "selective_scan" not in name]   # the attention's is kept
 
 
 @pytest.mark.parametrize("kernel,shape,dtype,ok", [
