@@ -25,7 +25,7 @@ from ..dist_resilience import guard_blocking as _guard_blocking
 from ..monitor import MONITOR as _MON
 from . import locks
 from .dtypes import as_np_dtype
-from .lowering import LoweringContext, plan_kept, run_block_with_backward
+from .lowering import LoweringContext, jaxpr_size, phase, plan_kept, profiled, run_block_with_backward
 from .program import Program, Variable, default_main_program
 from .scope import RNG_STATE_VAR, Scope, global_scope
 
@@ -265,7 +265,8 @@ class _CompiledStep:
             env = dict(state_ro)
             env.update(state_rw)
             # what the recomputed segments keep for backward, from the shapes and the room the state leaves on a chip
-            plan_kept(ctx, ops, {n: v.shape for n, v in feeds.items()}, self._held_bytes(env, whole=manual))
+            with phase("plan_kept"):
+                plan_kept(ctx, ops, {n: v.shape for n, v in feeds.items()}, self._held_bytes(env, whole=manual))
             env.update(feeds)
             env = run_block_with_backward(ctx, ops, env)
             new_state = {n: env[n] for n in written if n in env}
@@ -633,26 +634,25 @@ class _CompiledStep:
             mon_on = _MON.enabled
             what = dict(program=self.program_uuid, module=self.module)
             t0 = time.perf_counter()
-            fenced = _MON.counter("lowering.fenced_grads")
-            fenced0 = fenced.value
-            counted0 = _MON.counter_values()
-            with _MON.span("executor.lower", **what) as lowering:
-                lowered = self.jfn.trace(state_rw, state_ro, feeds, key).lower()
-                lowering.annotate(fenced=fenced.value - fenced0)
-                # which attention each fused_attention op of this program took,
-                # what its `repeat` ops lowered (passes, body ops, recomputed passes),
-                # how many of its `kda` ops took the kernels, of its `moe_experts` ops' ways back to token order, what
-                # its window attentions visited and allowed, and how many ops read a tensor another layer kept
-                lowering.annotate(**{
-                    name[len("lowering."):]: n - counted0.get(name, 0)
-                    for name, n in _MON.counter_values().items()
-                    if name.startswith(("lowering.attention_", "lowering.loop_", "lowering.recomputed_", "lowering.kda_",
-                                        "lowering.selective_scan_", "lowering.kernels_under_", "lowering.token_sum_",
-                                        "lowering.window_", "lowering.kept_"))
-                    and n != counted0.get(name, 0)})
-                if self.moe_layers:
-                    _MON.counter("lowering.moe_layers").inc(self.moe_layers)
-                    lowering.annotate(moe_layers=self.moe_layers)
+            counted0 = _MON.counter_values() if mon_on else {}
+            with _MON.span("executor.lower", **what) as lowering, profiled(**what) as profile:
+                # the trace's phases are spans of their own under this one (`lowering.TraceProfile`)
+                with phase("trace"):
+                    traced = self.jfn.trace(state_rw, state_ro, feeds, key)
+                with phase("to_hlo") as to_hlo:
+                    if profile is not None:
+                        to_hlo.annotate(**jaxpr_size(traced.jaxpr))
+                    lowered = traced.lower()
+                if profile is not None:
+                    if self.moe_layers:
+                        _MON.counter("lowering.moe_layers").inc(self.moe_layers)
+                    # every `lowering.` counter that moved during this span (which attention each fused_attention op of
+                    # this program took, what its `repeat` ops lowered, what its recomputed segments keep, ...), the
+                    # gradients fenced under the name they have had, and where the trace's Python went
+                    moved = {name[len("lowering."):]: n - counted0.get(name, 0)
+                             for name, n in _MON.counter_values().items()
+                             if name.startswith("lowering.") and n != counted0.get(name, 0)}
+                    lowering.annotate(fenced=moved.pop("fenced_grads", 0), by_op=profile.by_op(), **moved)
             t1 = time.perf_counter()
             with _MON.span("executor.compile", **what) as compiling:
                 # did JAX's persistent cache serve it?  The monitor hears

@@ -16,13 +16,15 @@ as one program.  The gradients themselves are a fusion boundary
 from __future__ import annotations
 
 import contextlib
+import threading
+import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..monitor import MONITOR as _MON
+from ..monitor import MONITOR as _MON, NULL_SPAN
 from .program import Block, Operator
 from .registry import get_op_def, get_op_def_or_none
 
@@ -82,6 +84,130 @@ class LoweringContext:
 # Ops handled by the executor itself, not by a registered lowering.
 _STRUCTURAL_OPS = ("feed", "fetch", "backward")
 
+#: The rows of a profile's table that leave the trace by name (`TraceProfile.by_op`); the rest are summed as `other`.
+BY_OP_ROWS = 12
+#: What a run of ops that `recompute_scope` marked as one segment is called in the table: the `jax.checkpoint` round
+#: them (JAX's second pass over the segment's jaxpr), less the ops lowered inside it.
+SEGMENT = "recompute_scope"
+
+_TRACING = threading.local()    # .profile: the TraceProfile of the trace this thread is making (`profiled`)
+_UNTIMED = contextlib.nullcontext()
+
+
+class TraceProfile:
+    """Where the Python of ONE trace went, while the monitor is on: the phases
+    of the trace as spans under the executor's `executor.lower` (`phase`), and
+    inside them the seconds and calls of every op's lowering by `(phase, op
+    type)` (`timed`).  A row holds SELF time: `run_ops` nests (a `repeat`'s
+    body, a recomputed segment, a `conditional_block`), and an op's seconds are
+    its own, less those of the ops lowered inside it, so a phase's rows never
+    add up to more than its span.  The program's own `jax.custom_vjp` rules are
+    timed the same way under their op's type (`ops.common.counted_rules`): JAX
+    calls a backward rule during `lowering.transpose`, and a forward rule again
+    wherever it differentiates a recomputed segment.  What is left of a phase
+    is JAX's own (linearisation, `backward_pass`): its span's `ops_s` says how
+    much the rows hold."""
+
+    __slots__ = ("what", "rows", "phases", "inside")
+
+    def __init__(self, **what):
+        self.what = what                        # `program=` and `module=`: every phase span carries its parent's
+        self.rows: Dict[tuple, list] = {}       # (phase, op type) -> [self seconds, calls]
+        self.phases: List[str] = []             # the open phase spans, innermost last
+        self.inside: List[float] = []           # per open timed call, the seconds of the timed calls inside it
+
+    @contextlib.contextmanager
+    def timed(self, op_type: str):
+        row = self.rows.setdefault((self.phases[-1] if self.phases else "", op_type), [0.0, 0])
+        self.inside.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            took = time.perf_counter() - t0
+            row[0] += took - self.inside.pop()
+            row[1] += 1
+            if self.inside:
+                self.inside[-1] += took
+
+    def seconds_in(self, phase_name: str) -> float:
+        return sum(row[0] for (at, _), row in self.rows.items() if at == phase_name)
+
+    def by_op(self) -> Dict[str, list]:
+        """The table as it leaves the trace: {"<phase>:<op type>": [self
+        seconds, calls]} of the `BY_OP_ROWS` dearest rows, and `other`."""
+        dearest = sorted(self.rows.items(), key=lambda kv: -kv[1][0])
+        table = {f"{at}:{op_type}": list(row) for (at, op_type), row in dearest[:BY_OP_ROWS]}
+        rest = [row for _, row in dearest[BY_OP_ROWS:]]
+        table["other"] = [sum(row[0] for row in rest), sum(row[1] for row in rest)]
+        return table
+
+
+def open_profile() -> Optional[TraceProfile]:
+    """The profile of the trace this thread is making, or None."""
+    return getattr(_TRACING, "profile", None)
+
+
+@contextlib.contextmanager
+def profiled(**what):
+    """Round one trace (the executor's miss path, inside its `executor.lower`
+    span): a `TraceProfile` for this thread while the monitor is on, else None:
+    no table is built and `phase` opens nothing."""
+    if not _MON.enabled:
+        yield None
+        return
+    profile = _TRACING.profile = TraceProfile(**what)
+    try:
+        yield profile
+    finally:
+        _TRACING.profile = None
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """One phase of the trace being profiled, as the span `lowering.<name>`
+    with the profile's `program=` and `module=`; the ops timed while it is the
+    innermost open phase are its rows, and their seconds its `ops_s`."""
+    profile = open_profile()
+    if profile is None:
+        yield NULL_SPAN
+        return
+    with _MON.span("lowering." + name, **profile.what) as span:
+        held = profile.seconds_in(name)
+        profile.phases.append(name)
+        try:
+            yield span
+        finally:
+            profile.phases.pop()
+            span.annotate(ops_s=profile.seconds_in(name) - held)
+
+
+def jaxpr_size(jaxpr) -> Dict[str, int]:
+    """`jaxpr_eqns` and `pallas_calls` of a traced jaxpr by one walk, the
+    sub-jaxprs in its equations' parameters included once a call site (a
+    kernel's body, a loop's, a `jit`'s): what `lowering.to_hlo` goes over, and
+    the two numbers behind "a Pallas kernel's set-up is its body's size times
+    its call sites"."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    sizes: Dict[int, tuple] = {}
+
+    def size(j) -> tuple:
+        j = getattr(j, "jaxpr", j)
+        if id(j) not in sizes:
+            eqns, pallas = len(j.eqns), sum(eqn.primitive.name == "pallas_call" for eqn in j.eqns)
+            for eqn in j.eqns:
+                for value in eqn.params.values():
+                    for sub in value if isinstance(value, (tuple, list)) else (value,):
+                        if isinstance(sub, (Jaxpr, ClosedJaxpr)):
+                            inner = size(sub)
+                            eqns, pallas = eqns + inner[0], pallas + inner[1]
+            sizes[id(j)] = (eqns, pallas)
+        return sizes[id(j)]
+
+    eqns, pallas = size(jaxpr)
+    return {"jaxpr_eqns": eqns, "pallas_calls": pallas}
+
 
 def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
             first: int = 0, segments: bool = True) -> Dict[str, Any]:
@@ -103,6 +229,7 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
     # the op census runs at TRACE time only (this loop is the trace), so
     # it costs nothing at execution
     mon_on = _MON.enabled
+    profile = open_profile() if mon_on else None
     segment_end = 0
     for at, op in enumerate(ops):
         idx = first + at
@@ -113,7 +240,8 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
             segment_end = at + 1
             while segment_end < len(ops) and ops[segment_end].attrs.get("recompute_segment") == segment:
                 segment_end += 1
-            _run_recomputed(ctx, ops[at:segment_end], env, idx)
+            with profile.timed(SEGMENT) if profile is not None else _UNTIMED:
+                _run_recomputed(ctx, ops[at:segment_end], env, idx)
             continue
         if op.type in _STRUCTURAL_OPS:
             raise RuntimeError(
@@ -124,7 +252,7 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
         # stands round the op's own, so the innermost name is still the op
         part = op.attrs.get("op_namescope")
         with jax.named_scope(part) if part else contextlib.nullcontext():
-            with jax.named_scope(f"op{idx}:{op.type}"):
+            with jax.named_scope(f"op{idx}:{op.type}"), profile.timed(op.type) if profile is not None else _UNTIMED:
                 lower_one(ctx, op, env)
         if ctx.keep:
             for n in ctx.keep.intersection(op.output_arg_names):
@@ -345,6 +473,11 @@ def run_block_with_backward(ctx: LoweringContext, ops: List[Operator], env: Dict
     chip and on a mesh, whatever the optimizer: it is a property of the
     `Program` and of nothing else.
 
+    The trace says where its seconds go (`TraceProfile`): the probe for the
+    sparse tables, the forward with its linearisation, the transpose and the
+    tail are the spans `lowering.sparse_probe`, `.forward`, `.transpose` and
+    `.update`, once a region, under the executor's `lowering.trace`.
+
     The step says which phase an instruction belongs to: the forward
     interpretation runs under `jax.named_scope("fwd")`, the tail after the
     last `backward` under `"update"`, and the transposes JAX derives from
@@ -353,7 +486,7 @@ def run_block_with_backward(ctx: LoweringContext, ops: List[Operator], env: Dict
     """
     splits = [i for i, op in enumerate(ops) if op.type == "backward"]
     if not splits:
-        with jax.named_scope("fwd"):
+        with phase("forward"), jax.named_scope("fwd"):
             return run_ops(ctx, ops, env)
 
     report_sparse: List[str] = []
@@ -372,7 +505,7 @@ def run_block_with_backward(ctx: LoweringContext, ops: List[Operator], env: Dict
     LAST_TRACE_REPORT.clear()
     LAST_TRACE_REPORT["sparse_grad_params"] = report_sparse
     tail_ops = ops[splits[-1] + 1:]
-    with jax.named_scope("update"):
+    with phase("update"), jax.named_scope("update"):
         return run_ops(ctx, tail_ops, env,
                        first=splits[-1] + 1 - len(splits))
 
@@ -413,7 +546,8 @@ def _run_one_backward_region(ctx: LoweringContext, ops: List[Operator], split: i
             run_ops(ctx, fwd_ops, e)
             return 0
 
-        jax.eval_shape(probe, {p: env[p] for p in param_names})
+        with phase("sparse_probe"):
+            jax.eval_shape(probe, {p: env[p] for p in param_names})
         ctx.key = saved_key
         coll.mode = "inject"
 
@@ -435,8 +569,10 @@ def _run_one_backward_region(ctx: LoweringContext, ops: List[Operator], split: i
             deltas0[f"__tap{i}"] = jnp.zeros(shape, dtype)
 
     fwd_fn = jax.checkpoint(fwd) if ctx.remat else fwd
-    loss, vjp_fn, env_after = jax.vjp(fwd_fn, primal_params, deltas0, has_aux=True)
-    (grads, dtaps) = vjp_fn(jnp.ones_like(loss))
+    with phase("forward"):      # the forward's interpretation and JAX's linearisation of it
+        loss, vjp_fn, env_after = jax.vjp(fwd_fn, primal_params, deltas0, has_aux=True)
+    with phase("transpose"):    # JAX's `backward_pass`, which calls the program's own `custom_vjp` backward rules
+        (grads, dtaps) = vjp_fn(jnp.ones_like(loss))
 
     # merge the region's fresh intermediates over the incoming env so
     # earlier regions' grads survive for downstream consumers

@@ -1,10 +1,13 @@
 """Shared helpers for op lowerings."""
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.dtypes import as_np_dtype
+from ..core.lowering import open_profile
 
 
 def first(ins, slot, default=None):
@@ -45,6 +48,27 @@ def over_batch_shards(ctx, fn, batched, whole=()):
     rows = P(ctx.batch_axis)
     return jax.shard_map(lambda b, w: fn(*b, *w), mesh=ctx.mesh, in_specs=(rows, P()), out_specs=rows,
                          check_vma=False)(tuple(batched), tuple(whole))
+
+
+def counted_rules(op_type, *rules):
+    """An op's `jax.custom_vjp` rules (`f.defvjp(*counted_rules("kda", fwd,
+    bwd))`), each counted in the lowering's profile under `op_type` and the
+    phase of the trace that is open when JAX calls it
+    (`core.lowering.TraceProfile`): a backward rule during `lowering.transpose`,
+    a forward rule wherever JAX differentiates the op, inside its own lowering
+    or, for an op of a recomputed segment, after it.  With no trace being
+    profiled (the monitor off) a rule runs as it is."""
+    def counted(rule):
+        @functools.wraps(rule)
+        def timed(*args):
+            profile = open_profile()
+            if profile is None:
+                return rule(*args)
+            with profile.timed(op_type):
+                return rule(*args)
+        return timed
+
+    return tuple(counted(rule) for rule in rules)
 
 
 def operand_of(shapes, name):

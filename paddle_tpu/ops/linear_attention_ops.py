@@ -63,7 +63,7 @@ from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import kda_kernels
-from .common import first
+from .common import counted_rules, first
 
 #: Tokens a chunk (the published kernels') and a block of its rows for the
 #: decayed Grams (`_decayed_grams`), and the largest decay inside a block, in
@@ -324,7 +324,7 @@ def _chunked_kda_bwd(chunk, sub, kernels, residuals, cotangents):
         return (*d_inputs, d_beta[..., None].astype(inputs[4].dtype))
 
 
-chunked_kda.defvjp(_chunked_kda_fwd, _chunked_kda_bwd)
+chunked_kda.defvjp(*counted_rules("kda", _chunked_kda_fwd, _chunked_kda_bwd))
 
 
 @register_op("kda")

@@ -87,6 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor import MONITOR as _MON
+from .common import counted_rules
 
 #: The rules an op's `mask` attribute may name (`causal` is an attribute of its own).
 MASKS = ("block_diffusion", "sliding_window")
@@ -542,7 +543,7 @@ def _attention_bwd(plan: Plan, keep, residuals, do):
     return dq, dk, dv
 
 
-_attention.defvjp(_attention_fwd, _attention_bwd)
+_attention.defvjp(*counted_rules("fused_attention", _attention_fwd, _attention_bwd))
 
 
 def attention_under(plan: Plan, q, k, v, scale: float, keep=None):

@@ -55,7 +55,7 @@ from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import moe_kernels
-from .common import first, match_dtype
+from .common import counted_rules, first, match_dtype
 
 
 @register_op("rms_norm")
@@ -205,7 +205,7 @@ def _gated_short_conv_bwd(res, g):
     return d_x, d_w
 
 
-_gated_short_conv.defvjp(lambda x, w: (_gated_short_conv(x, w), (x, w)), _gated_short_conv_bwd)
+_gated_short_conv.defvjp(*counted_rules("short_conv", lambda x, w: (_gated_short_conv(x, w), (x, w)), _gated_short_conv_bwd))
 
 
 def _plain_short_conv_taps(x, w, ahead=0, bias=None):
@@ -252,7 +252,8 @@ def _plain_short_conv_bwd(res, g):
     return d_x, d_w, None if bias is None else jnp.sum(dc, axis=(0, 1)).astype(bias.dtype)
 
 
-_plain_short_conv.defvjp(lambda x, w, bias=None: (_plain_short_conv(x, w, bias), (x, w, bias)), _plain_short_conv_bwd)
+_plain_short_conv.defvjp(*counted_rules(
+    "short_conv", lambda x, w, bias=None: (_plain_short_conv(x, w, bias), (x, w, bias)), _plain_short_conv_bwd))
 
 
 @register_op("short_conv")
@@ -323,12 +324,14 @@ def _sum_by_token(rows, route, k, kernel=None):
     return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
 
 
-_rows_by_expert.defvjp(
+_rows_by_expert.defvjp(*counted_rules(
+    "moe_experts",
     lambda x, route, k, kernel=None: (_rows_by_expert(x, route, k, kernel), route),
-    lambda k, kernel, route, g: (_sum_by_token(g, route, k, kernel), None))
-_sum_by_token.defvjp(
+    lambda k, kernel, route, g: (_sum_by_token(g, route, k, kernel), None)))
+_sum_by_token.defvjp(*counted_rules(
+    "moe_experts",
     lambda rows, route, k, kernel=None: (_sum_by_token(rows, route, k, kernel), route),
-    lambda k, kernel, route, g: (_rows_by_expert(g, route, k, kernel), None))
+    lambda k, kernel, route, g: (_rows_by_expert(g, route, k, kernel), None)))
 
 
 @jax.custom_vjp
@@ -341,9 +344,10 @@ def _permute_scalars(v, perm, inverse):
     return jax.lax.sort((inverse, v), num_keys=1, is_stable=False)[1]
 
 
-_permute_scalars.defvjp(
+_permute_scalars.defvjp(*counted_rules(
+    "moe_experts",
     lambda v, perm, inverse: (_permute_scalars(v, perm, inverse), (perm, inverse)),
-    lambda res, g: (_permute_scalars(g, res[1], res[0]), None, None))
+    lambda res, g: (_permute_scalars(g, res[1], res[0]), None, None)))
 
 #: (rows, contraction, columns) tile of the megablox kernels.  TPU v5e, the
 #: three products of one OLMoE layer over 131072 rows, forward and backward
@@ -415,7 +419,7 @@ def _grouped_matmul_bwd(interpret, res, g):
     return d_rows, d_master, None
 
 
-_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+_grouped_matmul.defvjp(*counted_rules("moe_experts", _grouped_matmul_fwd, _grouped_matmul_bwd))
 
 
 def grouped_matmul(rows, weights, group_sizes, platform=None):
@@ -541,9 +545,10 @@ def _sort_by_key_fwd(key, values):
     return out, out[0]
 
 
-_sort_by_key.defvjp(
+_sort_by_key.defvjp(*counted_rules(
+    "moe_experts",
     _sort_by_key_fwd,
-    lambda order, g: (None, jax.lax.sort((order, g[1]), num_keys=1, is_stable=False)[1]))
+    lambda order, g: (None, jax.lax.sort((order, g[1]), num_keys=1, is_stable=False)[1])))
 
 
 # The held path's two row operations, each the other's transpose, as
@@ -615,12 +620,14 @@ def _add_to_tokens(rows, token, target, live, tokens):
     return _over_the_live_rows(token.shape[0], live, over)
 
 
-_rows_of_tokens.defvjp(
+_rows_of_tokens.defvjp(*counted_rules(
+    "moe_experts",
     lambda x, token, target, live, tokens: (_rows_of_tokens(x, token, target, live, tokens), (token, target, live)),
-    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None, None))
-_add_to_tokens.defvjp(
+    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None, None)))
+_add_to_tokens.defvjp(*counted_rules(
+    "moe_experts",
     lambda rows, token, target, live, tokens: (_add_to_tokens(rows, token, target, live, tokens), (token, target, live)),
-    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None, None))
+    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None, None)))
 
 
 def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
@@ -703,7 +710,7 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
             lambda grads: jax.tree.map(jnp.add, grads, jax.vjp(the_rest, *primals)[1](g)),
             lambda grads: grads, pull(g))
 
-    passes.defvjp(passes_fwd, passes_bwd)
+    passes.defvjp(*counted_rules("moe_experts", passes_fwd, passes_bwd))
     # the rare path passes over every chunk there is: no assignment is left out
     return passes(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
 

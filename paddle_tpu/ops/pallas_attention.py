@@ -64,6 +64,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import counted_rules
+
 # The working set a grid step may hold, which sets the heads a step
 # (`_pick_heads`).  Re-chosen on the chip in PR 30 for the heads-major call and
 # left alone: (32, 12, 512, 64) bf16, the backward kernel alone, 1.458 ms at
@@ -302,7 +304,7 @@ def _fused_sdpa_bwd(causal, scale, interpret, layout, res, g_out):
     return _bwd_call(q, k, v, bias, g_out, causal, scale, interpret, layout, g) + (dbias,)
 
 
-fused_sdpa.defvjp(_fused_sdpa_fwd, _fused_sdpa_bwd)
+fused_sdpa.defvjp(*counted_rules("fused_attention", _fused_sdpa_fwd, _fused_sdpa_bwd))
 
 
 # The two calls are `jax.jit`s of their own inside the step's: a model's layers

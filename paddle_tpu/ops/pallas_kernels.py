@@ -58,6 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .common import counted_rules
+
 # Half of the chip's 16 MB scoped VMEM: each kernel counts the f32 rows one
 # grid step keeps live, Mosaic's double buffers of them take the other half.
 _VMEM_BUDGET = 8 * 1024 * 1024
@@ -257,7 +259,7 @@ def _ln_bwd(eps, interpret, saved, g):
     return (dx, dres, ds.astype(scale.dtype), db.astype(bias.dtype))
 
 
-fused_ln_residual.defvjp(_ln_fwd, _ln_bwd)
+fused_ln_residual.defvjp(*counted_rules("layer_norm", _ln_fwd, _ln_bwd))
 
 
 # --------------------------------------------------------------------------
@@ -361,7 +363,7 @@ def _epilogue_bwd(relu, interpret, saved, g):
     return dx, dm.astype(mul.dtype), da.astype(add.dtype)
 
 
-fused_scale_shift_relu.defvjp(_epilogue_fwd, _epilogue_bwd)
+fused_scale_shift_relu.defvjp(*counted_rules("batch_norm", _epilogue_fwd, _epilogue_bwd))
 
 
 def bn_epilogue(x, mul, add, relu, interpret=False):
@@ -555,7 +557,7 @@ def _sxe_bwd(ignore_index, interpret, saved, g):
     return dx, np.zeros(labels.shape, jax.dtypes.float0)
 
 
-fused_softmax_xent.defvjp(_sxe_fwd, _sxe_bwd)
+fused_softmax_xent.defvjp(*counted_rules("softmax_with_cross_entropy", _sxe_fwd, _sxe_bwd))
 
 
 # --------------------------------------------------------------------------
@@ -698,7 +700,7 @@ def _bias_act_bwd(act, interpret, saved, g):
     return dx, db.astype(bias.dtype)
 
 
-fused_bias_act.defvjp(_bias_act_fwd, _bias_act_bwd)
+fused_bias_act.defvjp(*counted_rules("elementwise_add", _bias_act_fwd, _bias_act_bwd))
 
 
 # --------------------------------------------------------------------------

@@ -62,7 +62,7 @@ from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import ssm_kernels
-from .common import batch_shards, first, kept_residuals, operand_of, over_batch_shards, residuals_name
+from .common import batch_shards, counted_rules, first, kept_residuals, operand_of, over_batch_shards, residuals_name
 
 #: Tokens a chunk of the XLA form (`_scan_path`: the CPU's and the odd shapes';
 #: the kernels' chunk is `ssm_kernels.CHUNK`, and their state is `_carried`
@@ -235,7 +235,7 @@ def _kernel_scan_bwd(kernels, chunk, block, keep, residuals, cotangents):
             dskip.astype(d_skip.dtype), dbias.astype(dt_bias.dtype))
 
 
-kernel_selective_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+kernel_selective_scan.defvjp(*counted_rules("selective_scan", _kernel_scan_fwd, _kernel_scan_bwd))
 
 
 @register_op("selective_scan")
