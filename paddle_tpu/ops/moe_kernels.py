@@ -25,7 +25,15 @@ started before this block's product (two buffers).
 
 A buffer row that no token of the block owns (the rest of a run's first and last
 tile, the rest of the last chunk) meets a 0: the buffers start as zeros and hold
-only rows of `rows` afterwards.
+only rows of `rows` afterwards.  So every row of a tile that a run touches must
+be FINITE, whoever owns it: 0 x NaN is a NaN for the block's 128 tokens.
+
+A slot may own NO row (a layer that holds a share of its experts: the slot's
+expert is another chip's): its `group` is `groups` and its `index` negative.  It
+is then in no run's count and no run's first, no run's shift is added to its
+place, which stays negative, and no buffer column equals it: its line of the 0/1
+matrix is zeros.  `rows` may then be fewer than T k, and the kernel copies the
+tiles of the owned rows only, however many it is given.
 """
 from __future__ import annotations
 
@@ -88,7 +96,8 @@ def plan(index, group, groups, tokens=TOKENS):
     """(runs [T / tokens, 1, 2 groups + 1] int32: each (block, group)'s first
     tile, then its number of tiles, then the block's tiles in all; place [T, k]
     int32: the row of the block's buffer that holds each assignment's row) of
-    `index`, `group` [T, k]."""
+    `index`, `group` [T, k].  A slot of group `groups` (which owns no row: its
+    `index` is negative) is no run's, and its place is its `index`."""
     T, k = index.shape
     blocks = T // tokens
     index, group = (t.reshape(blocks, tokens * k, 1) for t in (index, group))
@@ -167,8 +176,11 @@ def token_sum(rows, index, group, groups, interpret=False, tokens=TOKENS, chunk=
     """out [T, d] in the rows' dtype: out[t] = sum_j rows[index[t, j]] in float32,
     of rows [R, d] and `index` [T, k] int32, the place of each assignment in a
     stable sort by `group` [T, k] (values in [0, groups)); `fits(T, d, k, dtype,
-    groups)`.  `tokens` a grid step and `chunk` rows a product are the module's
-    unless given (tools/chip_token_sum.py prices others)."""
+    groups)`.  A slot with `group == groups` and a negative `index` owns no row
+    and adds nothing; R is then whatever the owned slots need, and every row of
+    an 8-row tile that holds an owned row must be finite.  `tokens` a grid step
+    and `chunk` rows a product are the module's unless given
+    (tools/chip_token_sum.py prices others)."""
     (T, k), d = index.shape, rows.shape[1]
     blocks = T // tokens
     runs, place = plan(index, group, groups, tokens)
@@ -182,7 +194,8 @@ def token_sum(rows, index, group, groups, interpret=False, tokens=TOKENS, chunk=
         scratch_shapes=[pltpu.VMEM((2, buffer_rows(k, groups, tokens, chunk), d), rows.dtype), pltpu.VMEM((tokens, d), F32),
                         pltpu.SemaphoreType.DMA((2,))],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
-        # what the sum needs: every row read once, the tokens written once; the product is the kernel's way, not work
-        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0, bytes_accessed=int((T * k + T) * d * rows.dtype.itemsize)),
+        # what the sum needs: every row it is given read once (T k of them where every slot owns one), the tokens written
+        # once; the product is the kernel's way, not work
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0, bytes_accessed=int((rows.shape[0] + T) * d * rows.dtype.itemsize)),
         name="token_sum", interpret=interpret,
     )(runs, runs, place, rows)

@@ -306,6 +306,14 @@ def _token_sum_path(platform, mesh, x, k, experts):
     return "kernel" if platform == "tpu" and one_device and moe_kernels.fits(*x.shape, k, x.dtype, experts) else "xla"
 
 
+def _token_sum_kernel(ctx, x, k, groups):
+    """`_sum_by_token`'s and `_add_to_tokens`' `kernel` for tokens `x` of k slots
+    each over `groups` groups (a layer's experts, or the ones it holds): None
+    where `_token_sum_path` says XLA's form, else (groups, interpreted).
+    "interpret" is the tests': the kernel interpreted where no chip is."""
+    return {"kernel": (groups, False), "interpret": (groups, True)}.get(_token_sum_path(ctx.platform, ctx.mesh, x, k, groups))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _rows_by_expert(x, route, k, kernel=None):
     """Tokens [T, d] -> one row per assignment [T k, d], in `order`."""
@@ -468,16 +476,14 @@ def _moe_experts(ctx, op, ins):
     if op.attr("shared_experts", 0):   # the layer's builder computes them beside this op, every token, once
         _MON.counter("lowering.shared_expert_layers").inc()
     if held is not None:
-        out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load,
-                                            (w_gate, w_up, w_down), tuple(held), ctx.platform)
+        out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load, (w_gate, w_up, w_down),
+                                            tuple(held), ctx.platform, _token_sum_kernel(ctx, x2, k, held[1]))
         return {"Out": out.reshape(x.shape), "Dropped": missed.astype(jnp.int32).reshape((1,)),
                 "Held": n_held.astype(jnp.int32).reshape((1,))}
     order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
     route = (order, inverse, top_i.reshape(-1, k).astype(jnp.int32))
-    experts = load.shape[0]
-    # "interpret" is the tests': the kernel interpreted where no chip is
-    kernel = {"kernel": (experts, False), "interpret": (experts, True)}.get(_token_sum_path(ctx.platform, ctx.mesh, x2, k, experts))
+    kernel = _token_sum_kernel(ctx, x2, k, load.shape[0])
     rows = _rows_by_expert(x2, route, k, kernel)
     # each row's router probability, in expert order
     weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
@@ -559,6 +565,23 @@ _sort_by_key.defvjp(*counted_rules(
 # rows they are GIVEN cost, dropped or not (TPU v5e, 32768 rows of 2048 bf16:
 # a scatter-add 2.93 ms, a gather 0.71 in LFM2's step), and the bound is twice
 # what a uniform router sends: they are given the live rows' passes only.
+#
+# `kernel`: None for XLA's scatter-add, else `_token_sum_kernel`'s (held experts,
+# interpreted): the way back is then `moe_kernels.token_sum` with the slots no
+# held expert owns left out, and `target` is what THAT reads, (each slot's place
+# among the chunk's rows [T, k], negative where it owns none; its local expert,
+# `count` there).  A stable sort by local expert left every expert's rows in
+# token order, which is what the kernel rests on.  It copies the live runs'
+# tiles only, whatever the bound, so it has no switch over prefixes; the gather,
+# at 20 ns a row, stays XLA's and keeps its own.  TPU v5e, ms a call alone, half
+# | all of the bound live (my chip runs, PR 53, tools/chip_held_experts.py):
+# SDAR's 32768 rows of 2048 bf16 behind 16384 tokens of 8 slots, 16 held: the
+# scatter-add 2.00 | 3.09, the kernel 0.50 | 0.55; LFM2's (4 slots, 8 held)
+# 1.99 | 3.08 and 0.43 | 0.48; Kimi Linear's 2048 rows of 2304 behind 4096
+# tokens 0.54 | 0.64 and 0.22 | 0.23.  The layer forward and backward, at the
+# share the cell reads: SDAR's 16.9 -> 13.2, LFM2's 21.6 -> 17.8, Kimi
+# Linear's 4.4 -> 3.4; the cells +4.0 to +5.3%, +5.0 to +5.5% and +3.8%
+# (PERF.md, PR 53).
 
 #: Passes the two row operations make over a chunk at the most.  Each count of
 #: passes is a branch of its own, compiled for its rows (80 bytes of code a
@@ -599,21 +622,53 @@ def _over_the_live_rows(n, live, over):
     return jax.lax.switch((live + a_pass - 1) // a_pass, [functools.partial(over, rows) for rows in counts])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_tokens(x, token, target, live, tokens):
-    """Tokens [T, d] -> the chunk's rows [C, d]; zeros past the last pass."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _rows_of_tokens(x, token, target, live, tokens, kernel=None):
+    """Tokens [T, d] -> the chunk's rows [C, d]; zeros past the last pass.
+    XLA's gather whatever `kernel`, which is its transpose's."""
     def over(rows):
         return jnp.pad(_take_rows(x, token[:rows]), ((0, token.shape[0] - rows), (0, 0)))
 
     return _over_the_live_rows(token.shape[0], live, over)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _add_to_tokens(rows, token, target, live, tokens):
+def _zeros_from(rows, live):
+    """`rows` with zeros from row `live` to the end of its tile of `GRANULE`.
+    Rows past the live ones come out of the grouped kernels as they lay in
+    memory, the kernel copies whole tiles, and its 0/1 product makes a NaN of
+    0 x NaN for a whole block of tokens: the live rows' last tile is the only
+    one it copies that can hold such rows, and eight rows are written in place
+    (into the grouped kernel's output: XLA copies nothing) where a `where` over
+    all of them is a pass over the bound: SDAR's layer forward and backward
+    13.2 ms so, 13.2 with nothing zeroed, 13.7 with the `where` (my chip run,
+    PR 53, tools/chip_held_experts.py); a kernel that zeroed its own buffer
+    could win nothing and would change the unmasked call's text."""
+    tile = moe_kernels.GRANULE
+    at = jnp.minimum(live // tile * tile, rows.shape[0] - tile)
+    dead = (at + jax.lax.iota(jnp.int32, tile) >= live)[:, None]
+    last = jnp.where(dead, 0, jax.lax.dynamic_slice(rows, (at, 0), (tile, rows.shape[1])))
+    return jax.lax.dynamic_update_slice(rows, last, (at, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _add_to_tokens(rows, token, target, live, tokens, kernel=None):
     """The chunk's rows [C, d] -> tokens [T, d]: each token's rows summed,
     rows of no held expert dropped.  A token has one such row on average and
-    eight at the most, and they are summed in the rows' dtype: a float32 copy
-    of the rows for the scatter to read is 268 MB at SDAR's cell."""
+    eight at the most.  XLA's form sums them in the rows' dtype (a float32 copy
+    of the rows for the scatter to read is 268 MB at SDAR's cell), the kernel in
+    float32 with one rounding."""
+    if kernel:
+        _MON.counter("lowering.held_token_sum_calls").inc()
+    return _add_to_tokens_as_traced(rows, token, target, live, tokens, kernel)
+
+
+def _add_to_tokens_as_traced(rows, token, target, live, tokens, kernel):
+    """`_add_to_tokens` itself, and its forward rule's: the common pass is a
+    `jax.checkpoint`, whose body JAX traces as written and then once more through
+    the rules, and a call is counted where it is written."""
+    if kernel:
+        return moe_kernels.token_sum(_zeros_from(rows, live), *target, *kernel)
+
     def over(n):
         return jnp.zeros((tokens, rows.shape[-1]), rows.dtype).at[target[:n]].add(rows[:n], mode="drop")
 
@@ -622,18 +677,20 @@ def _add_to_tokens(rows, token, target, live, tokens):
 
 _rows_of_tokens.defvjp(*counted_rules(
     "moe_experts",
-    lambda x, token, target, live, tokens: (_rows_of_tokens(x, token, target, live, tokens), (token, target, live)),
-    lambda tokens, res, g: (_add_to_tokens(g, *res, tokens), None, None, None)))
+    lambda x, token, target, live, tokens, kernel=None: (_rows_of_tokens(x, token, target, live, tokens, kernel), (token, target, live)),
+    lambda tokens, kernel, res, g: (_add_to_tokens(g, *res, tokens, kernel), None, None, None)))
 _add_to_tokens.defvjp(*counted_rules(
     "moe_experts",
-    lambda rows, token, target, live, tokens: (_add_to_tokens(rows, token, target, live, tokens), (token, target, live)),
-    lambda tokens, res, g: (_rows_of_tokens(g, *res, tokens), None, None, None)))
+    lambda rows, token, target, live, tokens, kernel=None: (_add_to_tokens_as_traced(rows, token, target, live, tokens, kernel), (token, target, live)),
+    lambda tokens, kernel, res, g: (_rows_of_tokens(g, *res, tokens, kernel), None, None, None)))
 
 
-def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
+def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None):
     """`moe_experts` over the experts `held` = (first, count): (the tokens'
     output [T, d] in x2's dtype, the assignments that fell on held experts,
-    those of them no pass covered)."""
+    those of them no pass covered).  `kernel`: the common pass's way back to
+    token order (`_add_to_tokens`); the rare path, which no step runs and every
+    step's compile pays for, keeps XLA's."""
     first, count = held
     tokens, k = top_i.shape
     assignments = tokens * k
@@ -644,6 +701,8 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
     expert = top_i.reshape(-1)
     local = jnp.where((expert >= first) & (expert < first + count), expert - first, count)
     order, weight = _sort_by_key(local.astype(jnp.int32), top_p.reshape(-1).astype(jnp.float32))
+    if kernel:   # each assignment's place in the order: one more sort of scalars, as `_sort_by_key`'s transpose is
+        place = jax.lax.sort((order, jax.lax.iota(jnp.int32, assignments)), num_keys=1, is_stable=False)[1].reshape(tokens, k)
     pad = bound + chunks * rest - assignments   # rows past the last assignment belong to no token
     order = jnp.pad(order, (0, pad), constant_values=assignments)
     weight = jnp.pad(weight, (0, pad))
@@ -651,17 +710,21 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
     ends = jnp.cumsum(sizes)
     n_held = ends[-1]
 
-    def chunk(x2, weight, matrices, lo, n):
+    def chunk(x2, weight, matrices, lo, n, kernel=None):
         """Rows [lo, lo + n) of the order, as tokens' sums."""
         rank = lo + jax.lax.iota(jnp.int32, n)
         mine = jax.lax.dynamic_slice(order, (lo,), (n,))
         token = jnp.minimum(mine // k, tokens - 1)
         valid = rank < n_held
-        target = jnp.where(valid, token, tokens)
+        if kernel:   # a slot owns a row of this chunk where its place is one of the chunk's live rows'
+            owned = (place >= lo) & (place < jnp.minimum(lo + n, n_held))
+            target = jnp.where(owned, place - lo, -1), jnp.where(owned, local.reshape(tokens, k), count).astype(jnp.int32)
+        else:
+            target = jnp.where(valid, token, tokens)
         groups = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - sizes, lo, lo + n)
         live = jnp.clip(n_held - lo, 0, n)
         w_gate, w_up, w_down = matrices
-        rows = _rows_of_tokens(x2, token, target, live, tokens)
+        rows = _rows_of_tokens(x2, token, target, live, tokens, kernel)
         # a row no group covers comes out of the kernels as it lay in memory
         keep = valid[:, None]
         gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), "expert_gate")
@@ -670,12 +733,12 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform):
         w = jax.lax.dynamic_slice(weight, (lo,), (n,))[:, None]
         hidden = jnp.where(keep, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
         down = grouped_matmul(hidden, w_down, groups, platform)
-        return _add_to_tokens(down, token, target, live, tokens)
+        return _add_to_tokens(down, token, target, live, tokens, kernel)
 
     # the common pass keeps the two products' outputs for the backward pass and
     # makes the rest again there (a gather, the masters' casts, one elementwise
     # pass): 335 MB a layer at SDAR's cell that no step has to hold
-    common = jax.checkpoint(lambda x2, weight, matrices: chunk(x2, weight, matrices, 0, bound),
+    common = jax.checkpoint(lambda x2, weight, matrices: chunk(x2, weight, matrices, 0, bound, kernel),
                             policy=jax.checkpoint_policies.save_only_these_names("expert_gate", "expert_up"))
     if chunks == 0:
         return common(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
@@ -929,11 +992,12 @@ def _cost_moe_router(ctx):
 #: Passes of the forward lowering over its (token, slot) rows, in arrays read
 #: or written.  [rows, hidden]: written by the gather, read by the gate and by
 #: the up product, written by the down product, and on the way back to token
-#: order read once by the kernel (`_token_sum_path`); "hidden_xla": where the way
-#: back is XLA's, the gather reads and writes them and the sum over k reads them
-#: again.  [rows, width]: written by gate and up, both read and one written by
-#: SiLU x up x weight, read by the down product.  tests/test_chip_compile.py
-#: counts them in the compiled program.
+#: order read once by the kernel (`_token_sum_path`; a layer that holds a share
+#: too, over its bound's rows); "hidden_xla": where the way back is XLA's, the
+#: gather reads and writes them and the sum over k reads them again.  [rows,
+#: width]: written by gate and up, both read and one written by SiLU x up x
+#: weight, read by the down product.  tests/test_chip_compile.py counts them in
+#: the compiled program.
 _ROW_PASSES = {"hidden": 5, "hidden_xla": 7, "width": 6}
 
 
@@ -950,16 +1014,14 @@ def _cost_moe_experts(ctx):
         return float(ctx.out_elems_total()), ctx.io_bytes()
     rows, d, f = ctx.in_elems("TopKIndex"), gate[1], gate[2]
     held, load = ctx.op.attr("held", None), ctx.in_shape("Load")
-    dtype = ctx.env.dtype(ctx.in_name("X"))
-    hidden = _ROW_PASSES["hidden_xla"]
+    dtype, k = ctx.env.dtype(ctx.in_name("X")), ctx.in_shape("TopKIndex")[-1]
+    # the matrices are the experts the layer computes with, all or the held ones: the kernel's groups either way
+    hidden = _ROW_PASSES["hidden" if moe_kernels.fits(rows // k, d, k, dtype, gate[0]) else "hidden_xla"]
+    passes = rows
     if held is not None and load is not None:
         # the passes are over the bound; the arithmetic is the uniform share's
         passes = _held_rows_bound(rows, held[1], load[0])
         rows = rows * held[1] // load[0]
-    else:
-        passes, k = rows, ctx.in_shape("TopKIndex")[-1]
-        if held is None and moe_kernels.fits(rows // k, d, k, dtype, gate[0]):
-            hidden = _ROW_PASSES["hidden"]
     item = 2 if dtype in ("bfloat16", "float16") else 4
     moved = passes * (hidden * d + _ROW_PASSES["width"] * f) * item
     return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)

@@ -236,6 +236,14 @@ CASES = {
         _token_sum(64), [((131072, 2048), BF16), ((16384, 8), I32), ((16384, 8), I32)], ()),
     "token_sum_float32_rows": (
         _token_sum(128), [((32768, 1024), F32), ((4096, 8), I32), ((4096, 8), I32)], ()),
+    # ... and given the held experts' rows alone, a quarter or a sixteenth of the slots' (PR 53): SDAR's, LFM2's and Kimi
+    # Linear's calls, the last with rows of 2304 = 18 lane tiles
+    "token_sum_held_sdar": (
+        _token_sum(16), [((32768, 2048), BF16), ((16384, 8), I32), ((16384, 8), I32)], ()),
+    "token_sum_held_lfm2": (
+        _token_sum(8), [((32768, 2048), BF16), ((16384, 4), I32), ((16384, 4), I32)], ()),
+    "token_sum_held_kimi_linear": (
+        _token_sum(8), [((2048, 2304), BF16), ((4096, 8), I32), ((4096, 8), I32)], ()),
     "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
     # SDAR-30B-A3B-Chat's cell: 2 sequences of 8192 positions [noised ; clean], 32 query heads on
@@ -417,22 +425,33 @@ def test_no_square_of_the_positions_is_in_the_compiled_causal_attention(q, kv, c
     assert set(under_the_scope) == {"fwd", "dkv"}
 
 
-def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(chip):
+#: Kimi-Linear-48B-A3B's: one sequence of 4096 positions, hidden 2304 (18 lane tiles), 8 of 256 experts held
+KIMI_EXPERTS = (4096, 2304, 1024, 256, 8, 8)
+
+
+@pytest.mark.parametrize("cell,shape,bound", [("sdar", SDAR_EXPERTS, 32768), ("kimi-linear", KIMI_EXPERTS, 2048)])
+def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(cell, shape, bound, chip):
     """16 of 128 experts held at 16384 positions: the 131072 (token, slot)
-    assignments exist as vectors only (the sort's keys, order and weights);
+    assignments exist as vectors only (the sort's keys, order and weights, and
+    since PR 53 each slot's place and group, [tokens, k]);
     every two-dimensional array of rows, in the common pass and in the rare
     path's loop alike, has the bound's 32768 rows (twice the uniform share:
     `ops.moe_ops._held_rows_bound`) or the tokens' 16384, forward and backward.
-    Since PR 35 the gathers write and the scatter-adds read a whole number of
+    Since PR 35 the gathers write a whole number of
     passes, from one to four (8192 rows each over the bound, 512 over a chunk
     of the rare path's 2048), each count a branch of a conditional that the
     step's own count of held rows picks: rows that belong to no token cost
-    nothing past the last pass that holds a live one."""
+    nothing past the last pass that holds a live one.  Since PR 53 the common
+    pass's way back is the `token_sum` kernel, forward's call and the transpose
+    of the gather (`lowering.held_token_sum_calls` reads two): the only
+    scatter-adds of rows left stand in the rare path's loop.  The same at Kimi
+    Linear's widths, where Mosaic meets rows of 2304 = 18 lane tiles and the
+    bound's passes are the rare path's."""
+    from paddle_tpu import monitor
     from paddle_tpu.ops.moe_ops import _HELD_REST_ROWS, _held_rows_bound, _pass_rows
 
-    tokens, hidden, width, experts, k, held = SDAR_EXPERTS
-    bound = _held_rows_bound(tokens * k, held, experts)
-    assert bound == 32768
+    tokens, hidden, width, experts, k, held = shape
+    assert _held_rows_bound(tokens * k, held, experts) == bound
 
     def moe(x, top_p, top_i, load, w_gate, w_up, w_down):
         from paddle_tpu.core.lowering import LoweringContext
@@ -447,20 +466,35 @@ def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(c
              ((held, hidden, width), F32), ((held, hidden, width), F32), ((held, width, hidden), F32)]
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
     program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(moe(*a).astype(F32))), argnums=(0, 1, 4, 5, 6))
-    compiled = jax.jit(program).lower(*args).compile()
+    monitor.reset()
+    monitor.enable()
+    try:
+        compiled = jax.jit(program).lower(*args).compile()
+        assert monitor.get_monitor().counter_values().get("lowering.held_token_sum_calls") == 2
+    finally:
+        monitor.disable()
+        monitor.reset()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 9
+    assert text.count("tpu_custom_call") >= 11
+    assert len(re.findall(r'custom_call_target="tpu_custom_call".*jit\(token_sum\)', text)) == 2
     rows_of = {int(n) for n in re.findall(r"= \w+\[(\d+),(?:%d|%d)\]" % (hidden, width), text)}
-    assert max(rows_of) == bound, rows_of
+    assert max(rows_of) == max(bound, tokens), rows_of
     assert not re.findall(r"\[%d,\d+" % (tokens * k), text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
     shape_of = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text))
     gathered = [shape for shape in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text) if shape.endswith(",%d" % hidden)]
-    added = [shape_of[updates] for updates in re.findall(r" scatter\(%\S+, %\S+, %([^\s,)]+)\)", text)]
-    added = [shape for shape in added if shape.endswith(",%d" % hidden)]   # the rows'; the kernels' group metadata scatters too
-    assert (_pass_rows(bound), _pass_rows(_HELD_REST_ROWS)) == (8192, 512)
-    passes = {"%d,%d" % (rows, hidden) for n in (bound, _HELD_REST_ROWS) for rows in range(_pass_rows(n), n + 1, _pass_rows(n))}
-    assert len(passes) == 8 and set(gathered) == passes and set(added) == passes, (gathered, added)
+    # the rows'; the kernels' group metadata scatters too
+    added = [(shape_of[updates], where) for updates, where in re.findall(r' scatter\(%\S+, %\S+, %([^\s,)]+)\).*op_name="([^"]*)"', text)
+             if shape_of[updates].endswith(",%d" % hidden)]
+    assert _pass_rows(bound) == bound // 4 and _pass_rows(_HELD_REST_ROWS) == 512
+
+    def passes(n):
+        return {"%d,%d" % (rows, hidden) for rows in range(_pass_rows(n), n + 1, _pass_rows(n))}
+
+    assert len(passes(bound) | passes(_HELD_REST_ROWS)) == (8 if cell == "sdar" else 4)
+    assert set(gathered) == passes(bound) | passes(_HELD_REST_ROWS), gathered
+    assert {shape for shape, _ in added} == passes(_HELD_REST_ROWS) and len(added) == 8, added   # four counts of passes, forward and backward
+    assert all("/while/body/" in where for _, where in added), added   # the rare path's loop; none in the common pass
 
 
 #: LFM2-8B-A1B's cell: a sequence of 8192 positions at hidden size 2048, three taps

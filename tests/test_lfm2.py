@@ -551,7 +551,11 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     four of 8192 rows at the most, where one instruction over the bound
     stood, and `live` among the chunk's values); the op listing, the fetches, the router, the block
     builder, the sort, the kernels and the rare path's conditional lower as
-    before, and the hash below pins the whole again."""
+    before, and the hash below pins the whole again.  That one stood (sha256
+    ce85b78d...) until PR 53 sent the common pass's `_add_to_tokens` to the
+    `token_sum` kernel with the slots no held expert owns left out: two kernel
+    calls a layer and one more sort of scalars where two five-branch
+    conditionals of scatter-adds stood; the rare path keeps XLA's form."""
     import hashlib
     import re
 
@@ -586,4 +590,4 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     found = (step.module, hashlib.sha256(listing.encode()).hexdigest(), hashlib.sha256(text.encode()).hexdigest())
     print(found)
     assert found == ("train_6de7c714", "bc7cad00c44ec7d55d9ad0458b439478849ada1e810ed87a3ceddfbb31a7734b",
-                     "ce85b78d0623c69893abf9412e938bd0d28d803c85ca3f658fce117c254018e6")
+                     "aff8883d16d19a0c7f09d8a6d91b4aff24e094cd3278bbca9ba7f6b05a9fd51f")

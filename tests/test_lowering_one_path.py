@@ -116,6 +116,12 @@ def _fp16_batch_norm():
 #: `token_sum` kernel (`ops/moe_kernels.py`: the experts' rows back to token
 #: order) where it held two gathers and two sums over k; the op listing's hash
 #: is what it was, and the nine other programs lower to what they lowered to.
+#: PR 53 re-recorded `lfm2-8b-a1b-s8192` and only that (sha256 516ab54d... at
+#: the parent `14276a2`): its four sparse layers' common pass comes back to
+#: token order through two calls of the same kernel with the slots no held
+#: expert owns left out, where two five-branch conditionals of scatter-adds
+#: stood; the op listing's hash is what it was, and OLMoE's text with the
+#: unmasked call is the parent's to the byte.
 PARENTS_PROGRAMS = {
     "resnet50-train-bf16-nchw": (lambda: _resnet50(256, dtype="bfloat16"), "train_cc732a46",
         "b40b8617a8e43d947e7dcdd6c6b5ea2136abe6018b84b409dacbf82eadaa1b35",
@@ -140,7 +146,7 @@ PARENTS_PROGRAMS = {
         "9438d63e71a0118f9fadadf412dcdcc95f213c835c55b6a1e42cee787e96d655", (0, 0, 0, 1)),
     "lfm2-8b-a1b-s8192": (lambda: _cell("lfm2", "lfm2-8b-a1b", "train-s8192"), "train_b5740440",
         "94c5da17ec8b9b45b8a6e3c57b80081513f0cdc288a7212598cece8733215c98",
-        "516ab54d965d95e11e410aa8d1765765daf08c5ef42137b5efeabb3a89f4d712", (0, 0, 0, 1)),
+        "4a13169bb57d3fe31cacddb6b40691ddf36b4dbd97dbcf0de5edae7c5aeb8b0e", (0, 0, 0, 1)),
     "ouro-2.6b-ut4-s4096": (lambda: _cell("ouro", "ouro-2.6b", "train-ut4-s4096"), "train_3a3d8d40",
         "de4588fb1ac19708384a3c0cc4e3b98109602dd8aa2103815510ee3782ecdf3a",
         "d0474d1a373d8c91b3730ae74079615afa75aaecb439c0e1151e4a2d5145dc90", (0, 0, 0, 8)),
