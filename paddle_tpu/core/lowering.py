@@ -291,6 +291,8 @@ def _run_recomputed(ctx: LoweringContext, ops: List[Operator], env: Dict[str, An
     made, ctx.key = jax.checkpoint(segment, policy=policy)([env[n] for n in reads], ctx.key)
     env.update(made)
     _MON.counter("lowering.recomputed_segments").inc()
+    if any(op.type == "moe_experts" for op in ops):   # a sparse layer that backward makes again, routing and all
+        _MON.counter("lowering.recomputed_sparse_segments").inc()
 
 
 #: Of the chip's memory that the step's state does not hold, the share that the values kept for backward may take

@@ -816,15 +816,25 @@ def exit_loss(ce, gate_logit, beta=0.0, name=None):
     return loss, p
 
 
-def rotary_embedding(x, positions, theta=10000.0, name=None):
-    """Rotary position embedding (rotate-half convention) of (B, H, L, dh)
-    queries or keys; `positions` is the (B, L) integer position of every
-    token, fed like the ids."""
+def rotary_embedding(x, positions, theta=10000.0, name=None, layout="bhld", interleave=False):
+    """Rotary position embedding of (B, H, L, dh) queries or keys, or with
+    `layout="blhd"` of (B, L, H, dh) as a projection's reshape leaves them;
+    `positions` is the (B, L) integer position of every token, fed like the
+    ids.  Feature i turns with feature i + dh/2 (the rotate-half convention),
+    or with `interleave` feature 2i with 2i + 1.  The angles are float32.  A
+    default is no attribute of the op: the programs that stood keep their text."""
+    if layout not in ("bhld", "blhd"):
+        raise ValueError(f"rotary_embedding: layout={layout!r}; \"bhld\" (B, H, L, dh) or \"blhd\" (B, L, H, dh)")
     helper = LayerHelper("rotary_embedding", name=name)
     out = _out(helper, x.dtype, shape=x.shape)
+    attrs = {"theta": float(theta)}
+    if layout != "bhld":
+        attrs["layout"] = layout
+    if interleave:
+        attrs["interleave"] = True
     helper.append_op(
         "rotary_embedding", inputs={"X": [x.name], "Positions": [positions.name]},
-        outputs={"Out": [out.name]}, attrs={"theta": float(theta)})
+        outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
 

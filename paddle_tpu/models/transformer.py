@@ -105,8 +105,9 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     per_head_norm = qk_norm_eps is not None and qk_norm_per_head
     heads_major = not use_fused_attention or per_head_norm or positions is not None
     if (keep is not None or kept_kv is not None) and (heads_major or qk_norm_eps is not None):
-        raise ValueError("keys and values are kept, and kept ones read, as the fused attention's (B, L, H, dh): "
-                         "no q/k-norm and no rotary positions stand between the projection and the attention")
+        raise ValueError("keep= / kept_kv=: keys and values are kept, and kept ones read, as the fused attention's "
+                         "(B, L, H, dh): use_fused_attention=True, and no q/k-norm and no rotary positions (qk_norm_eps=, "
+                         "positions=) stand between the projection and the attention")
 
     def split_heads(t, heads):
         t = layers.reshape(t, [0, 0, heads, d_head])
@@ -125,7 +126,8 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         q = layers.rotary_embedding(q, positions, theta=rope_theta)
         k = layers.rotary_embedding(k, positions, theta=rope_theta)
     if (mask is not None or n_kv_heads != n_heads) and not use_fused_attention:
-        raise ValueError("a structured mask and grouped key/value heads are fused_attention's")
+        raise ValueError("mask= and n_kv_heads= (a structured mask, grouped key/value heads) are fused_attention's: "
+                         "use_fused_attention=True")
     if use_fused_attention:
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
@@ -158,18 +160,30 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     return project(ctx, "out")
 
 
-def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_dim, norm_eps=1e-5):
+def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_dim, norm_eps=1e-5,
+                     positions=None, rope_theta=10000.0, rope_interleave=False):
     """Causal attention whose keys and values are up-projected from one normed
-    latent a token (multi-head latent attention without a rotary embedding on
-    either part, as Kimi Linear's global layers have it): q = x Wq, `n_heads`
-    heads of `nope_dim + rope_dim`; [c ; k_r] = x Wkva, `rank + rope_dim` wide;
+    latent a token (multi-head latent attention): q = x Wq, `n_heads` heads of
+    `nope_dim + rope_dim`; [c ; k_r] = x Wkva, `rank + rope_dim` wide;
     [k_n ; v] = rms(c) Wkvb, a head `nope_dim + v_dim`; head h's key is
     [k_n[h] ; k_r], the `rope_dim` part shared by all heads; softmax attention
     at scale (nope_dim + rope_dim)^-0.5 over keys wider than the values; the
     output from n_heads x v_dim back to d_model.  No biases.  The heads are
-    split by reshapes alone and the attention is handed (B, L, H, dh)."""
+    split by reshapes alone and the attention is handed (B, L, H, dh).
+
+    Without `positions` neither part carries a rotary embedding (Kimi Linear's
+    global layers: `mla_use_nope`).  With `positions` ([b, T] integers) the
+    decoupled rotary embedding of DeepSeek-V2/V3's family stands between the
+    projections and the attention, in the scope `latent_attention/rotary`: each
+    head's `rope_dim`-wide q_r and the ONE k_r a token are rotated
+    (`rope_theta`; `rope_interleave` pairs feature 2i with 2i + 1 and not i
+    with i + rope_dim / 2; float32 angles), and k_r is spread over the heads
+    AFTER its rotation, so that it is rotated once and not `n_heads` times."""
     def project(t, name, width):
         return layers.fc(t, width, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
+
+    def rotated(t):
+        return layers.rotary_embedding(t, positions, theta=rope_theta, layout="blhd", interleave=rope_interleave)
 
     with name_scope("latent_attention"):
         qk_dim = nope_dim + rope_dim
@@ -182,7 +196,14 @@ def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_di
         up = layers.reshape(project(latent, "kv_b", n_heads * (nope_dim + v_dim)), [0, 0, n_heads, nope_dim + v_dim])
         k_own = layers.slice(up, axes=[3], starts=[0], ends=[nope_dim])
         v = layers.slice(up, axes=[3], starts=[nope_dim], ends=[nope_dim + v_dim])
-        k_shared = layers.expand(layers.reshape(k_shared, [0, 0, 1, rope_dim]), [1, 1, n_heads, 1])
+        k_shared = layers.reshape(k_shared, [0, 0, 1, rope_dim])
+        if positions is None:
+            k_shared = layers.expand(k_shared, [1, 1, n_heads, 1])
+        else:
+            with name_scope("rotary"):
+                q = layers.concat([layers.slice(q, axes=[3], starts=[0], ends=[nope_dim]),
+                                   rotated(layers.slice(q, axes=[3], starts=[nope_dim], ends=[qk_dim]))], axis=3)
+                k_shared = layers.expand(rotated(k_shared), [1, 1, n_heads, 1])
         k = layers.concat([k_own, k_shared], axis=3)
         ctx = layers.fused_attention(q, k, v, causal=True, layout="blhd")
         return project(layers.reshape(ctx, [0, 0, n_heads * v_dim]), "out", d_model)
@@ -324,7 +345,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `operator_args` = dict(n_heads=, head_dim=)) and
     `operator="latent_attention"` attention over latent keys and values
     (`latent_attention`; `operator_args` = dict(rank=, nope_dim=, rope_dim=,
-    v_dim=)) and `operator="mamba"` a Mamba-1 mixer (`mamba_mixer`;
+    v_dim=), with `rope=True` the layer's rotary embedding on `positions` at
+    `rope_theta`, and `rope_interleave=`) and `operator="mamba"` a Mamba-1 mixer (`mamba_mixer`;
     `operator_args` = dict(expand=, state=, dt_rank=, inner_norms=, taps_bound=), its
     convolution of `conv_kernel` taps).  `unit_norms` starts a layer norm at
     gain 1 and bias 0, as a decoder's sources do (BERT's are drawn: PERF.md
@@ -401,8 +423,10 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         attn_out = kimi_delta_attention(operator_in, d_model, prefix=f"{prefix}.kda", conv_kernel=conv_kernel,
                                         norm_eps=norm_eps, **operator_args)
     elif operator == "latent_attention":
+        latent = dict(operator_args)
         attn_out = latent_attention(operator_in, d_model, n_heads, f"{prefix}.attn", norm_eps=norm_eps,
-                                    **operator_args)
+                                    positions=positions if latent.pop("rope", False) else None, rope_theta=rope_theta,
+                                    **latent)
     elif operator == "mamba":
         attn_out = mamba_mixer(operator_in, d_model, f"{prefix}.mamba", conv_kernel=conv_kernel, norm_eps=norm_eps,
                                keep=keep, **operator_args)
@@ -604,7 +628,10 @@ def build_causal_lm(
     `layer_types` may hold "kda" (a Kimi-Delta-Attention operator of `kda_heads`
     heads of `kda_head_dim`, its three convolutions of `conv_kernel` taps) and
     "latent_attention" (`latent` = dict(rank=, nope_dim=, rope_dim=, v_dim=):
-    keys and values from one normed latent a token, no rotary embedding), and
+    keys and values from one normed latent a token; no rotary embedding unless
+    the dict also says `rope=True`, which hands `pos_ids` and `rope_theta` to
+    the layer's decoupled rotary embedding, and `rope_interleave=True` for the
+    pairing (2i, 2i + 1) of DeepSeek-V3's family: `latent_attention`), and
     `shared_experts` = n gives every sparse layer n shared experts that every
     token passes, beside the routed ones and outside `experts_held`.
 
@@ -615,7 +642,14 @@ def build_causal_lm(
     EVERY operator; `rotary=False` leaves positions out of the attention
     altogether (the state-space layers carry the order; `pos_ids` is then no
     feed); and `recompute_layers` makes every layer a `recompute_scope`:
-    backward keeps a layer's input and computes the layer again.
+    backward keeps a layer's input and computes the layer again.  A SPARSE
+    layer is such a segment like any other: its router decides again on the
+    same input (the same choice, bit for bit), the held path's conditional and
+    the `token_sum` way back are differentiated inside the segment, what
+    `plan_kept` finds room for (the expert products' outputs, the router's
+    logits, the shared experts' products) is kept, and the routing's
+    statistics, the auxiliary terms and every fetchable output leave the
+    segment as they leave the layer.
 
     A decoder whose later layers read what earlier ones made (SambaY: Ren et
     al. 2025, arXiv:2507.06607) is arguments too.  `layer_types` may hold
@@ -686,11 +720,15 @@ def build_causal_lm(
     if norm not in ("rms", "layer"):
         raise ValueError(f"build_causal_lm: norm={norm!r}; \"rms\" or \"layer\"")
     if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
-        raise ValueError(f"build_causal_lm: {num_dense_layers} leading dense layers of width {dense_width} "
-                         f"among {len(kinds)} layers")
+        raise ValueError(f"build_causal_lm: num_dense_layers={num_dense_layers} leading dense layers of "
+                         f"dense_width={dense_width} among {len(kinds)} layers")
+    if latent and latent.get("rope") and not rotary:
+        raise ValueError("build_causal_lm: latent=dict(rope=True) rotates by pos_ids, which rotary=False leaves out of "
+                         "the feeds")
     if loop is not None and (num_dense_layers < len(kinds) or loss_positions):
-        raise ValueError("build_causal_lm: loop= takes a stack of dense layers with a label at every position "
-                         "(a router's auxiliary terms do not leave a loop's body)")
+        raise ValueError("build_causal_lm: loop= takes a stack of dense layers (num_dense_layers= the depth) with a "
+                         "label at every position (no loss_positions=): a router's auxiliary terms do not leave a "
+                         "loop's body")
     with program_guard(main, startup):
         n_labels = loss_positions or seq_len
         ids = layers.data("ids", [seq_len], dtype="int64")

@@ -11,7 +11,7 @@ router whose bias picks and does not weigh is DeepSeek-V3's and LFM2's, the
 short convolution LFM2's:
 
     rms_norm          y = x / sqrt(mean(x^2) + eps) * g
-    rotary_embedding  y = x cos(t) + rotate_half(x) sin(t),  t = pos * theta^(-2i/dh)
+    rotary_embedding  y = x cos(t) + rotate_half(x) sin(t),  t = pos * theta^(-2i/dh)  (or pairs (2i, 2i+1))
     moe_router        p = softmax_f32(x Wr); (p_e, e) = top_k(p); two auxiliary losses
                       or s = sigmoid_f32(x Wr); e = top_k(s + b); p_e = s_e (the unbiased score)
     moe_experts       y = sum_{e in top_k} p_e . Wdown_e( silu(Wgate_e x) * (Wup_e x) )
@@ -52,10 +52,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..core import analysis as _A
 from ..core import resource_plan as _RP
-from ..core.registry import register_op, set_step_stats
+from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import moe_kernels
-from .common import counted_rules, first, match_dtype
+from .common import counted_rules, first, kept_residuals, match_dtype, residuals_name
 
 
 @register_op("rms_norm")
@@ -76,18 +76,44 @@ def _rms_norm(ctx, op, ins):
 
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, op, ins):
-    """Rotate-half rotary positions over (B, H, L, dh); `Positions` is
-    (B, L) integers, an input so that packed or offset sequences bring
-    their own.  Angles, sines and the rotation are float32."""
+    """Rotary positions over (B, H, L, dh), or with the attribute
+    `layout="blhd"` over (B, L, H, dh) as a projection's reshape leaves the
+    heads (the latent attention's layout: H is 1 for the key part its heads
+    share); `Positions` is (B, L) integers, an input so that packed or offset
+    sequences bring their own.  Feature i turns with feature i + dh/2
+    (rotate-half), or with the attribute `interleave` feature 2i with 2i + 1
+    (the pairing of the original rotary embedding, which DeepSeek-V3's family
+    keeps).  Angles, sines and the rotation are float32 whatever X's dtype: at
+    position 16383 a bf16 angle is off by whole turns."""
     x = first(ins, "X")
     pos = first(ins, "Positions")
     half = x.shape[-1] // 2
     inv_freq = op.attr("theta", 10000.0) ** (-np.arange(half, dtype=np.float32) / half)
-    angle = pos.astype(jnp.float32)[:, None, :, None] * inv_freq  # (B, 1, L, dh/2)
+    by_position = op.attr("layout", "bhld") == "blhd"
+    if by_position:
+        _MON.counter("lowering.latent_rotary_ops").inc()
+    pos = pos.astype(jnp.float32)
+    angle = (pos[:, :, None, None] if by_position else pos[:, None, :, None]) * inv_freq  # dh/2 angles a position
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if op.attr("interleave", False):
+        # A pair's other member, signed, (-x[2i+1], x[2i]), as a product with a constant matrix of 0 and +-1 (exact in
+        # any dtype: one term a sum) and not as strided slices: those leave arrays whose last axis is 2, which the chip
+        # tiles to (8, 128) and copies (the 8-row clone compiled for the described v5e: PERF.md, section 6, PR 54).
+        swap = np.zeros((2 * half, 2 * half), np.float32)
+        swap[np.arange(1, 2 * half, 2), np.arange(0, 2 * half, 2)] = -1.0
+        swap[np.arange(0, 2 * half, 2), np.arange(1, 2 * half, 2)] = 1.0
+        other = jnp.einsum("...d,de->...e", x, swap.astype(x.dtype), precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+        out = x.astype(jnp.float32) * jnp.repeat(cos, 2, axis=-1) + other * jnp.repeat(sin, 2, axis=-1)
+    else:
+        x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return {"Out": out.astype(x.dtype)}
+
+
+def _router_logits_name(op):
+    """What a recomputed segment calls a router's float32 logits (`registry.set_kept`)."""
+    return op.output("TopKProb")[0] + "@logits"
 
 
 @register_op("moe_router")
@@ -115,6 +141,8 @@ def _moe_router(ctx, op, ins):
     n_experts = w.shape[-1]
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     logits = jnp.dot(x2, w.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    if ctx.keep and _router_logits_name(op) in ctx.keep:   # a recomputed segment keeps them: a float32 product at six passes
+        logits = checkpoint_name(logits, _router_logits_name(op))
     lse = jax.nn.logsumexp(logits, axis=-1)
     if op.attr("scoring", "softmax") == "sigmoid":
         _MON.counter("lowering.moe_router_sigmoid").inc()
@@ -475,9 +503,10 @@ def _moe_experts(ctx, op, ins):
     held = op.attr("held", None)
     if op.attr("shared_experts", 0):   # the layer's builder computes them beside this op, every token, once
         _MON.counter("lowering.shared_expert_layers").inc()
+    keep = kept_residuals(ctx, op)   # the name under which a recomputed segment keeps the gate and up products' outputs
     if held is not None:
         out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load, (w_gate, w_up, w_down),
-                                            tuple(held), ctx.platform, _token_sum_kernel(ctx, x2, k, held[1]))
+                                            tuple(held), ctx.platform, _token_sum_kernel(ctx, x2, k, held[1]), keep)
         return {"Out": out.reshape(x.shape), "Dropped": missed.astype(jnp.int32).reshape((1,)),
                 "Held": n_held.astype(jnp.int32).reshape((1,))}
     order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
@@ -489,6 +518,8 @@ def _moe_experts(ctx, op, ins):
     weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
     gate = grouped_matmul(rows, w_gate, load, ctx.platform)
     up = grouped_matmul(rows, w_up, load, ctx.platform)
+    if keep:
+        gate, up = checkpoint_name(gate, keep), checkpoint_name(up, keep)
     hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * weight).astype(x.dtype)
     down = grouped_matmul(hidden, w_down, load, ctx.platform)
     out = _sum_by_token(down, route, k, kernel)
@@ -685,12 +716,14 @@ _add_to_tokens.defvjp(*counted_rules(
     lambda tokens, kernel, res, g: (_rows_of_tokens(g, *res, tokens, kernel), None, None, None)))
 
 
-def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None):
+def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None, keep=None):
     """`moe_experts` over the experts `held` = (first, count): (the tokens'
     output [T, d] in x2's dtype, the assignments that fell on held experts,
     those of them no pass covered).  `kernel`: the common pass's way back to
     token order (`_add_to_tokens`); the rare path, which no step runs and every
-    step's compile pays for, keeps XLA's."""
+    step's compile pays for, keeps XLA's.  `keep`: the name under which a
+    `recompute_scope` round the layer keeps the common pass's two products'
+    outputs (`_kept_experts`); the rare path's are made again whatever it says."""
     first, count = held
     tokens, k = top_i.shape
     assignments = tokens * k
@@ -710,13 +743,24 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None)
     ends = jnp.cumsum(sizes)
     n_held = ends[-1]
 
-    def chunk(x2, weight, matrices, lo, n, kernel=None):
+    # What the passes read of the routing, handed to them as an ARGUMENT: a `jax.custom_vjp` that closes over values
+    # of the trace it stands in is lowered with them as inputs nobody passes once that trace is a `jax.checkpoint`'s
+    # (a sparse layer inside a `recompute_scope`), so `passes` below closes over nothing that is traced.
+    route = (order, n_held) + ((place, local) if kernel else ()) + (ends, sizes)
+    # the names of the two products' outputs that the common pass keeps for its own backward pass; a `recompute_scope`
+    # round the layer that keeps them too saves by `keep`, so they carry that one name: a policy by name reaches through
+    # this pass's own `jax.checkpoint`, and the value this pass saves has to be the one the outer policy saves
+    kept = (keep, keep) if keep else ("expert_gate", "expert_up")
+
+    def chunk(route, x2, weight, matrices, lo, n, kernel=None):
         """Rows [lo, lo + n) of the order, as tokens' sums."""
+        order, n_held, *slots, ends, sizes = route
         rank = lo + jax.lax.iota(jnp.int32, n)
         mine = jax.lax.dynamic_slice(order, (lo,), (n,))
         token = jnp.minimum(mine // k, tokens - 1)
         valid = rank < n_held
         if kernel:   # a slot owns a row of this chunk where its place is one of the chunk's live rows'
+            place, local = slots
             owned = (place >= lo) & (place < jnp.minimum(lo + n, n_held))
             target = jnp.where(owned, place - lo, -1), jnp.where(owned, local.reshape(tokens, k), count).astype(jnp.int32)
         else:
@@ -726,26 +770,26 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None)
         w_gate, w_up, w_down = matrices
         rows = _rows_of_tokens(x2, token, target, live, tokens, kernel)
         # a row no group covers comes out of the kernels as it lay in memory
-        keep = valid[:, None]
-        gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), "expert_gate")
-        up = checkpoint_name(grouped_matmul(rows, w_up, groups, platform), "expert_up")
-        gate, up = (jnp.where(keep, t, 0).astype(jnp.float32) for t in (gate, up))
+        covered = valid[:, None]
+        gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), kept[0])
+        up = checkpoint_name(grouped_matmul(rows, w_up, groups, platform), kept[1])
+        gate, up = (jnp.where(covered, t, 0).astype(jnp.float32) for t in (gate, up))
         w = jax.lax.dynamic_slice(weight, (lo,), (n,))[:, None]
-        hidden = jnp.where(keep, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
+        hidden = jnp.where(covered, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
         down = grouped_matmul(hidden, w_down, groups, platform)
         return _add_to_tokens(down, token, target, live, tokens, kernel)
 
     # the common pass keeps the two products' outputs for the backward pass and
     # makes the rest again there (a gather, the masters' casts, one elementwise
     # pass): 335 MB a layer at SDAR's cell that no step has to hold
-    common = jax.checkpoint(lambda x2, weight, matrices: chunk(x2, weight, matrices, 0, bound, kernel),
-                            policy=jax.checkpoint_policies.save_only_these_names("expert_gate", "expert_up"))
+    common = jax.checkpoint(lambda route, x2, weight, matrices: chunk(route, x2, weight, matrices, 0, bound, kernel),
+                            policy=jax.checkpoint_policies.save_only_these_names(*kept))
     if chunks == 0:
-        return common(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
+        return common(route, x2, weight, matrices), n_held, jnp.zeros_like(n_held)
 
-    def the_rest(x2, weight, matrices):
+    def the_rest(x2, weight, matrices, route):
         def step(acc, lo):
-            return acc + jax.checkpoint(chunk, static_argnums=(4,))(x2, weight, matrices, lo, rest), None
+            return acc + jax.checkpoint(chunk, static_argnums=(5,))(route, x2, weight, matrices, lo, rest), None
         return jax.lax.scan(step, jnp.zeros_like(x2), bound + rest * jnp.arange(chunks, dtype=jnp.int32))[0]
 
     # Both passes' transpose is written out so that the rare one ADDS to what
@@ -755,27 +799,28 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None)
     # three matrices' shapes in float32 twice a layer, keeps one set from the
     # forward pass to the backward pass and adds the other to the gradients
     # (2.2 to 3.0 GB of the planned peak: PERF.md, PR 34).
-    def with_the_rest(out, *primals):
-        return jax.lax.cond(n_held > bound, lambda out: out + the_rest(*primals), lambda out: out, out)
+    def with_the_rest(out, x2, weight, matrices, route):
+        return jax.lax.cond(route[1] > bound, lambda out: out + the_rest(x2, weight, matrices, route), lambda out: out, out)
 
     @jax.custom_vjp
-    def passes(x2, weight, matrices):
-        return with_the_rest(common(x2, weight, matrices), x2, weight, matrices)
+    def passes(x2, weight, matrices, route):
+        return with_the_rest(common(route, x2, weight, matrices), x2, weight, matrices, route)
 
-    def passes_fwd(x2, weight, matrices):
-        out, pull = jax.vjp(common, x2, weight, matrices)
-        return with_the_rest(out, x2, weight, matrices), (pull, x2, weight, matrices)
+    def passes_fwd(x2, weight, matrices, route):
+        out, pull = jax.vjp(functools.partial(common, route), x2, weight, matrices)
+        return with_the_rest(out, x2, weight, matrices, route), (pull, x2, weight, matrices, route)
 
     def passes_bwd(res, g):
-        pull, *primals = res
-        return jax.lax.cond(
-            n_held > bound,
-            lambda grads: jax.tree.map(jnp.add, grads, jax.vjp(the_rest, *primals)[1](g)),
+        pull, *primals, route = res
+        grads = jax.lax.cond(
+            route[1] > bound,
+            lambda grads: jax.tree.map(jnp.add, grads, jax.vjp(lambda *p: the_rest(*p, route), *primals)[1](g)),
             lambda grads: grads, pull(g))
+        return (*grads, None)    # the routing is whole numbers: nothing flows back into it
 
     passes.defvjp(*counted_rules("moe_experts", passes_fwd, passes_bwd))
     # the rare path passes over every chunk there is: no assignment is left out
-    return passes(x2, weight, matrices), n_held, jnp.zeros_like(n_held)
+    return passes(x2, weight, matrices, route), n_held, jnp.zeros_like(n_held)
 
 
 
@@ -900,10 +945,14 @@ def _infer_rotary_embedding(ctx):
     xs, ps = ctx.in_shape("X"), ctx.in_shape("Positions")
     if xs is None:
         return
+    layout = ctx.op.attr("layout", "bhld")
+    if layout not in ("bhld", "blhd"):
+        ctx.fail(f"layout must be \"bhld\" or \"blhd\", got {layout!r}")
+    at = 1 if layout == "blhd" else 2    # where X holds the positions
     if len(xs) != 4 or xs[-1] % 2:
-        ctx.fail(f"X must be (B, H, L, dh) with an even dh, got {xs}")
-    if ps is not None and (len(ps) != 2 or (ps[1] != xs[2] and _A.DYN not in (ps[1], xs[2]))):
-        ctx.fail(f"Positions must be (B, L) with L = {xs[2]}, got {ps}")
+        ctx.fail(f"X must be {'(B, L, H, dh)' if at == 1 else '(B, H, L, dh)'} with an even dh, got {xs}")
+    if ps is not None and (len(ps) != 2 or (ps[1] != xs[at] and _A.DYN not in (ps[1], xs[at]))):
+        ctx.fail(f"Positions must be (B, L) with L = {xs[at]}, got {ps}")
     ctx.set_out("Out", xs, ctx.in_dtype("X"))
 
 
@@ -1042,3 +1091,31 @@ _RP.register_elementwise_cost("exit_loss", flops_per_elem=12.0)
 _RP.register_elementwise_cost("rotary_embedding", flops_per_elem=6.0)
 _RP.register_cost(["moe_router"], _cost_moe_router)
 _RP.register_cost(["moe_experts"], _cost_moe_experts)
+
+
+# -- what a `recompute_scope` round a sparse layer may keep (core/lowering.py: plan_kept) ----------
+
+def _kept_experts(ctx, op, shapes):
+    """The gate and the up product's outputs, [rows, width] each in the rows'
+    dtype, which backward reads (the down product's it does not): over every
+    (token, slot) row, or for a layer that holds a share over its bound's rows
+    (the common pass's; the rare path makes its own again).  Priced by the op's
+    cost rule, as a `mul`'s output is by its own."""
+    gate, index = shapes.shape(op.input("WGate")[0]), shapes.shape(op.input("TopKIndex")[0])
+    rows = int(np.prod(index))
+    held = op.attr("held", None)
+    if held is not None:
+        rows = _held_rows_bound(rows, held[1], shapes.shape(op.input("Load")[0])[0])
+    return residuals_name(op), 2 * rows * gate[-1] * _RP._itemsize(shapes.dtype(op.input("X")[0]))
+
+
+def _kept_router(ctx, op, shapes):
+    """The float32 logits [tokens, experts]: 8 MB where the product that makes
+    them runs at the highest precision, six passes of the matrix unit."""
+    experts = shapes.shape(op.input("W")[0])[-1]
+    tokens = int(np.prod(shapes.shape(op.output("TopKProb")[0])[:-1]))
+    return _router_logits_name(op), 4 * tokens * experts
+
+
+set_kept("moe_experts", _kept_experts)
+set_kept("moe_router", _kept_router)

@@ -848,6 +848,31 @@ def test_phi4_mini_flashs_step_keeps_every_product_and_kernel_residual_and_its_p
     assert again and not [name for name in again if name.endswith(("/dot_general", "/pallas_call"))]
 
 
+@pytest.mark.slow   # one compile of ~70 s on every core: run by name (`-m slow`); PERF.md, PR 54, has its readings
+def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_planned_peak_leaves_room(host, monkeypatch):
+    """`kanana-2-30b-a3b.train-mla-s16384`'s whole step at the published widths
+    and 16384 tokens, compiled for the described v5e with what `plan_kept`
+    chooses at the chip's memory limit: every candidate of the five segments,
+    four of them sparse (the expert products' outputs and the routers' logits
+    among them), planned under the 15.5 GB a cell allows itself and over 25% of
+    the chip; the latent attention took the splash kernels at (192, 128) over
+    16384 keys, the ten rotations stand under `latent_attention/rotary`, and in
+    the rematerialised computations no product, no attention kernel and no
+    grouped product of the held path's COMMON pass is left (ISSUE 54)."""
+    compiled, counted = _kept_step("kanana", "kanana-2-30b-a3b", "train-mla-s16384", host.devices, monkeypatch)
+    assert counted["segments"] == 5 and counted["sparse_segments"] == 4
+    assert counted["kept_bytes"] == counted["candidates_bytes"] > 4e9
+    peak = _planned_peak(compiled)
+    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    assert len(set(re.findall(r"/(latent_attention(?:_\d+)?)/rotary/op\d+:rotary_embedding", text))) == 5
+    # (the rare path makes its own again, and a rotation's pair swap is a product with a constant, no kept matrix's)
+    again = [name for name in _made_again(text) if "/cond/branch_" not in name and ":rotary_embedding/" not in name]
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]
+
+
 @pytest.mark.slow   # one compile for four devices, ~3 minutes here: run by name (`-m slow`); PERF.md, PR 51, has its readings
 def test_jamba2s_step_on_the_2x2_host_keeps_what_a_chips_room_holds_and_its_planned_peak_leaves_room(host, monkeypatch):
     """`ai21-jamba2-3b.train-ssm-fsdp4`'s whole step at the published widths
