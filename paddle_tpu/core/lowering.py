@@ -75,6 +75,10 @@ class LoweringContext:
         # ... and those of the segment being lowered (`_run_recomputed`): a lowering whose kernel has residuals that
         # only its forward makes gives them their name (`registry.set_kept`) where it is in here
         self.keep: Set[str] = set()
+        # `plan_latent_operands`' finding: the `id` of every op of a latent attention's chain, and of the attention,
+        # -> the unit they are lowered as (`ops/latent_operands.py`); `run_ops` lowers the unit where it meets the
+        # attention and none of the chain's ops on its own
+        self.latent_units: Dict[int, Any] = {}
 
     def next_key(self):
         self.key, sub = jax.random.split(self.key)
@@ -225,12 +229,17 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
     A run of ops that `recompute_scope` marked as one segment is lowered as one
     `jax.checkpoint` (`_run_recomputed`, which calls back with `segments`
     off and `ctx.keep` set: an output variable that the segment's policy saves
-    is given its own name as it is made)."""
+    is given its own name as it is made).
+
+    An op of a latent attention's chain (`ctx.latent_units`) is passed over and
+    lowered with its attention, as one unit whose passes stand under the
+    scopes of the ops they stand for (`ops/latent_operands.py: lower`)."""
     # the op census runs at TRACE time only (this loop is the trace), so
     # it costs nothing at execution
     mon_on = _MON.enabled
     profile = open_profile() if mon_on else None
     segment_end = 0
+    met: Dict[int, int] = {}    # the index of each op of a unit's chain, by the op's `id`
     for at, op in enumerate(ops):
         idx = first + at
         if at < segment_end:
@@ -248,12 +257,23 @@ def run_ops(ctx: LoweringContext, ops: List[Operator], env: Dict[str, Any],
                 f"structural op {op.type!r} reached the lowering interpreter; "
                 "the executor must handle it"
             )
-        # an op built under `fluid.name_scope` carries the path: its scope
-        # stands round the op's own, so the innermost name is still the op
-        part = op.attrs.get("op_namescope")
-        with jax.named_scope(part) if part else contextlib.nullcontext():
-            with jax.named_scope(f"op{idx}:{op.type}"), profile.timed(op.type) if profile is not None else _UNTIMED:
-                lower_one(ctx, op, env)
+        unit = ctx.latent_units.get(id(op)) if ctx.latent_units else None
+        if unit is not None:
+            met[id(op)] = idx
+            if op is not unit.attention:
+                continue
+            from ..ops.latent_operands import lower as lower_unit
+
+            with profile.timed("latent_operands") if profile is not None else _UNTIMED:
+                lower_unit(ctx, unit, env, lambda o: "/".join(
+                    filter(None, (o.attrs.get("op_namescope"), f"op{met[id(o)]}:{o.type}"))))
+        else:
+            # an op built under `fluid.name_scope` carries the path: its scope
+            # stands round the op's own, so the innermost name is still the op
+            part = op.attrs.get("op_namescope")
+            with jax.named_scope(part) if part else contextlib.nullcontext():
+                with jax.named_scope(f"op{idx}:{op.type}"), profile.timed(op.type) if profile is not None else _UNTIMED:
+                    lower_one(ctx, op, env)
         if ctx.keep:
             for n in ctx.keep.intersection(op.output_arg_names):
                 env[n] = checkpoint_name(env[n], n)
@@ -386,6 +406,17 @@ def plan_kept(ctx: LoweringContext, ops: List[Operator], feed_shapes: Dict[str, 
     _MON.counter("lowering.recomputed_kept_values").inc(len(chosen))
     _MON.counter("lowering.recomputed_kept_bytes").inc(sum(value.nbytes for value in chosen))
     _MON.counter("lowering.recomputed_candidates_bytes").inc(sum(value.nbytes for value in candidates))
+
+
+def plan_latent_operands(ctx: LoweringContext, ops: List[Operator]) -> None:
+    """Choose, once a trace and after `plan_kept`, which latent attentions
+    among `ops` are lowered with the chain of ops between their projections and
+    their kernels as one unit (`ctx.latent_units`; `ops/latent_operands.py` has
+    the rule and the counters).  A block without a `fused_attention` whose
+    values are narrower than its queries chooses nothing."""
+    from ..ops.latent_operands import plan
+
+    plan(ctx, ops)
 
 
 def lower_one(ctx: LoweringContext, op: Operator, env: Dict[str, Any]) -> None:

@@ -94,6 +94,16 @@ def kept_residuals(ctx, op):
     return residuals_name(op) if ctx.keep and residuals_name(op) in ctx.keep else None
 
 
+def rotary_angles(pos, half: int, theta: float, by_position: bool):
+    """(cos, sin) float32 of `half` angles a position, pos . theta^(-i / half),
+    over (B, L, 1, half) where the heads follow the positions (`by_position`)
+    or (B, 1, L, half): at position 16383 a bf16 angle is off by whole turns."""
+    inv_freq = theta ** (-np.arange(half, dtype=np.float32) / half)
+    pos = pos.astype(jnp.float32)
+    angle = (pos[:, :, None, None] if by_position else pos[:, None, :, None]) * inv_freq
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def bcast_y_to_x(x, y, axis: int):
     """Fluid elementwise broadcasting (reference: operators/elementwise/
     elementwise_op_function.h): Y's dims align to X starting at `axis`

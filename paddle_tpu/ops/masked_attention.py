@@ -77,7 +77,7 @@ repeated.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -546,11 +546,12 @@ def _attention_bwd(plan: Plan, keep, residuals, do):
 _attention.defvjp(*counted_rules("fused_attention", _attention_fwd, _attention_bwd))
 
 
-def attention_under(plan: Plan, q, k, v, scale: float, keep=None):
+def attention_under(plan: Plan, q, k, v, scale: Optional[float], keep=None):
     """softmax(q k^T . scale under the plan's rule) v over (B, Hq, positions,
     dh) queries and (B, Hkv, positions, dh) keys and values, Hkv a divisor of
     Hq.  The kernels have no scale of their own: the queries carry it, rounded
-    once more to their dtype.  That costs nothing where the scale is a power
+    once more to their dtype (`scale` None: they carry it already, as the
+    latent attention's assembled queries do).  That costs nothing where the scale is a power
     of two (64-wide heads); at 128-wide heads the output is 2.51e-3 from
     float32 where the flash kernel, which scales the float32 scores, is
     2.03e-3, most of either the output's own rounding
@@ -561,7 +562,8 @@ def attention_under(plan: Plan, q, k, v, scale: float, keep=None):
     _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
     _MON.counter("lowering.attention_own_block_terms").inc(int(plan.first_key > 0))
     with jax.named_scope("block_sparse_attention"):
-        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        if scale is not None:
+            q = (q.astype(jnp.float32) * scale).astype(q.dtype)
         return _attention(q, k, v, plan, keep)
 
 

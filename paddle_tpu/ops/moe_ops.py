@@ -55,7 +55,7 @@ from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import moe_kernels
-from .common import counted_rules, first, kept_residuals, match_dtype, residuals_name
+from .common import counted_rules, first, kept_residuals, match_dtype, residuals_name, rotary_angles
 
 
 @register_op("rms_norm")
@@ -88,13 +88,10 @@ def _rotary_embedding(ctx, op, ins):
     x = first(ins, "X")
     pos = first(ins, "Positions")
     half = x.shape[-1] // 2
-    inv_freq = op.attr("theta", 10000.0) ** (-np.arange(half, dtype=np.float32) / half)
     by_position = op.attr("layout", "bhld") == "blhd"
     if by_position:
         _MON.counter("lowering.latent_rotary_ops").inc()
-    pos = pos.astype(jnp.float32)
-    angle = (pos[:, :, None, None] if by_position else pos[:, None, :, None]) * inv_freq  # dh/2 angles a position
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    cos, sin = rotary_angles(pos, half, op.attr("theta", 10000.0), by_position)  # dh/2 angles a position
     if op.attr("interleave", False):
         # A pair's other member, signed, (-x[2i+1], x[2i]), as a product with a constant matrix of 0 and +-1 (exact in
         # any dtype: one term a sum) and not as strided slices: those leave arrays whose last axis is 2, which the chip

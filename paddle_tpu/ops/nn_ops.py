@@ -747,15 +747,27 @@ def _fused_attention(ctx, op, ins):
     which GSPMD partitions at no new code, and the stock flash kernel runs
     replicated as before.  The bias derives from lengths and causality in every
     caller, so the row kernel treats it as a constant."""
-    q = first(ins, "Q")
-    k = first(ins, "K")
-    v = first(ins, "V")
     bias = first(ins, "Bias") if "Bias" in ins and ins["Bias"] else None
-    causal = op.attr("causal", False)
-    layout = op.attr("layout", "bhld")
+    return {"Out": attention(ctx, op, first(ins, "Q"), first(ins, "K"), first(ins, "V"), bias)}
+
+
+def attention_scale(op, width: int) -> float:
+    """The scores' scale: the op's attribute, else the queries' width^-0.5."""
     scale = op.attr("scale", None)
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    return 1.0 / float(np.sqrt(width)) if scale is None else scale
+
+
+def attention(ctx, op, q, k, v, bias=None, assembled=False):
+    """`fused_attention`'s lowering on its operands.  `assembled` (the latent
+    attention's unit, `ops/latent_operands.py`): q, k, v and the result are
+    heads-major whatever the op's `layout`, and the queries carry the scale, so
+    that no path transposes at its edge and none scales."""
+    causal = op.attr("causal", False)
+    layout = "bhld" if assembled else op.attr("layout", "bhld")
+    # assembled queries carry the scale: the splash kernels, which scale the queries at their edge, are told so (None),
+    # and every other path scales its scores by 1
+    scale = 1.0 if assembled else attention_scale(op, q.shape[-1])
+    carried = None if assembled else float(scale)
     mask = _structured_mask(op, q, k, layout)
     path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout, v.shape[-1],
                            ctx.batch_axis)
@@ -776,11 +788,11 @@ def _fused_attention(ctx, op, ins):
             from .masked_attention import block_sparse_attention, window_attention
 
             under = window_attention if mask[0] == "sliding_window" else block_sparse_attention
-            out = under(q, k, v, mask[1], float(scale), keep=keep)
+            out = under(q, k, v, mask[1], carried, keep=keep)
         elif path == "block_causal":
             from .masked_attention import causal_attention
 
-            out = causal_attention(q, k, v, float(scale), keep=keep)
+            out = causal_attention(q, k, v, carried, keep=keep)
         else:
             if k.shape[heads] != q.shape[heads]:
                 k, v = (jnp.repeat(t, q.shape[heads] // t.shape[heads], axis=heads) for t in (k, v))
@@ -800,8 +812,8 @@ def _fused_attention(ctx, op, ins):
         # is split with them, one that is broadcast over the rows is handed over whole)
         rows = bias is not None and bias.shape[0] == q.shape[0]
         batched, whole = ((q, k, v, bias), ()) if rows else ((q, k, v), () if bias is None else (bias,))
-        return {"Out": over_batch_shards(ctx, attend, batched, whole)}
-    return {"Out": attend(q, k, v, bias)}
+        return over_batch_shards(ctx, attend, batched, whole)
+    return attend(q, k, v, bias)
 
 
 @register_op("top_k")

@@ -811,8 +811,9 @@ def _kept_step(module, config, traffic, devices, monkeypatch):
             {n: placed(scope.find_var(n), mesh and step.state_specs[n]) for n in step.ro_names},
             {n: placed(s, mesh and step.feed_specs[n]) for n, s in feeds.items()},
             placed(jax.random.PRNGKey(0), mesh and step.key_spec))
+        # (the counters that moved: `monitor.reset()` keeps the names an earlier test of this process counted under)
         counted = {k[len("lowering.recomputed_"):]: v for k, v in monitor.MONITOR.counter_values().items()
-                   if k.startswith("lowering.recomputed_")}
+                   if k.startswith("lowering.recomputed_") and v}
     finally:
         monitor.disable()
         monitor.reset()
@@ -848,6 +849,42 @@ def test_phi4_mini_flashs_step_keeps_every_product_and_kernel_residual_and_its_p
     assert again and not [name for name in again if name.endswith(("/dot_general", "/pallas_call"))]
 
 
+def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
+    """ONE latent attention layer at Kanana-2's widths (H 32, 192 / 128) over
+    2048 positions, forward and backward through `_CompiledStep`, compiled for
+    the described v5e (ISSUE 55): the chain of ops between the projections and
+    the attention went into the unit's four kernels (`ops/latent_kernels.py`),
+    which write the arrays the attention's kernels and the projections' backward
+    read and nothing else: q, k, v forward and again, dq and d_up backward.
+    Beside them no instruction under the layer's scope that is no product, no
+    kernel call and not the partials' sum writes 30 MB x (2048 / 16384) or more
+    but the kept output's copy in its two layouts and the output's way back to
+    (B, L, H, 128), forward and again; no `dot_general` stands under `/rotary/`
+    (the rotation is a rotation of lanes inside a pass, not a product with a
+    0/+-1 matrix of its own)."""
+    from tools import chip_latent_edges as edge
+
+    positions = 2048
+    compiled, counted = edge.one_layer_step(host.devices, positions)
+    assert counted["lowering.latent_operands_assembled"] == 1 and not counted.get("lowering.latent_operands_fallback")
+    assert counted["lowering.attention_block_causal"] == 1 and counted["lowering.latent_rotary_ops"] == 2
+    text = compiled.as_text()
+    found = edge.edges(text, floor=edge.FLOOR * positions / 16384)
+    mb = 2 * positions * 32 / 1e6       # of a (B, L, H, 1) slab in bf16
+    kernels = sorted((way, kind, round(size / mb)) for way, kind, size, _, _ in found if kind.startswith("kernel:"))
+    assert kernels == sorted([("forward", "kernel:latent_queries", 192), ("forward", "kernel:latent_keys_values", 192 + 128),
+                              ("again", "kernel:latent_queries", 192), ("again", "kernel:latent_keys_values", 192 + 128),
+                              ("backward", "kernel:latent_queries_back", 192), ("backward", "kernel:latent_up_back", 256 + 4)]), kernels   # + the float32 sum over the heads
+    # (a projection's own cast of its weights, 16.8 MB whatever the positions, is no array of the edge's)
+    rest = sorted((way, kind, round(size / mb)) for way, kind, size, _, name in found
+                  if not kind.startswith("kernel:") and ":mul/" not in name)
+    assert rest == [("again", "transpose", 128), ("forward", "reduce_precision", 256), ("forward", "transpose", 128)], rest
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/rotary/" in name for name in names)
+    assert not [name for name in names if "/rotary/" in name and name.endswith("/dot_general")]
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+
+
 @pytest.mark.slow   # one compile of ~70 s on every core: run by name (`-m slow`); PERF.md, PR 54, has its readings
 def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_planned_peak_leaves_room(host, monkeypatch):
     """`kanana-2-30b-a3b.train-mla-s16384`'s whole step at the published widths
@@ -864,10 +901,17 @@ def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_plan
     assert counted["kept_bytes"] == counted["candidates_bytes"] > 4e9
     peak = _planned_peak(compiled)
     print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
+    assert 0.25 * 16.9e9 <= peak <= 14.9e9, peak
     text = compiled.as_text()
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
     assert len(set(re.findall(r"/(latent_attention(?:_\d+)?)/rotary/op\d+:rotary_embedding", text))) == 5
+    # the edge of a sparse layer's latent attention, the unit's own kernels with it: 2.6 GB written or less, from 4.1
+    # before the chain was lowered as one unit (ISSUE 55; `tools/chip_latent_edges.py` prints the table)
+    from tools import chip_latent_edges as edge
+
+    written = sum(size for _, _, size, _, _ in edge.edges(text, edge.LAYER))
+    print(f"a sparse layer's edge writes {written / 1e3:.3f} GB")
+    assert 1.5e3 <= written <= 2.6e3, written
     # (the rare path makes its own again, and a rotation's pair swap is a product with a constant, no kept matrix's)
     again = [name for name in _made_again(text) if "/cond/branch_" not in name and ":rotary_embedding/" not in name]
     assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]

@@ -273,7 +273,9 @@ def test_the_compile_cache_key_names_no_ops_module_global():
     # and no attribute of the op selects an attention: `causal`, `scale` and (PR 39) `layout`, which says where the
     # heads lie in the operands it was handed, are its mathematics and its signature; `kept_kv` (PR 50) says whose keys
     # and values the operands are and is read by a counter alone (`lowering.kept_tensor_readers`), outside the rule
-    source = inspect.getsource(nn_ops._fused_attention) + inspect.getsource(nn_ops._attention_path)
+    # (`attention` is the op's lowering on its operands, which the latent attention's unit shares: PR 55)
+    source = "".join(inspect.getsource(f) for f in (nn_ops._fused_attention, nn_ops.attention, nn_ops.attention_scale,
+                                                    nn_ops._attention_path))
     assert sorted(set(re.findall(r"op\.attr\(\"(\w+)\"", source))) == ["causal", "kept_kv", "layout", "scale"]
     assert "kept_kv" not in inspect.getsource(nn_ops._attention_path)
 
