@@ -332,16 +332,19 @@ class Kept(NamedTuple):
     name: str       # what the lowering calls the value: `jax.checkpoint`'s policy saves by name
     nbytes: int     # a chip's
     flops: float    # of the op that makes it, a chip's
+    must: bool = False   # kept whatever the room: made again it could come out another value (a choice from scores)
 
 
 def choose_kept(candidates: Sequence[Kept], budget: float) -> List[Kept]:
     """The candidates a block keeps, in program order: greedily by the
     operations a kept byte saves, the dearest first and ties in program order,
-    each taken if what is left of `budget` bytes holds it.  A pure function:
-    the same candidates and budget give the same set."""
+    each taken if what is left of `budget` bytes holds it; those that MUST be
+    kept first and whatever is left.  A pure function: the same candidates and
+    budget give the same set."""
     chosen, left = [], budget
-    for at in sorted(range(len(candidates)), key=lambda i: (-candidates[i].flops / max(candidates[i].nbytes, 1), i)):
-        if candidates[at].nbytes <= left:
+    for at in sorted(range(len(candidates)),
+                     key=lambda i: (not candidates[i].must, -candidates[i].flops / max(candidates[i].nbytes, 1), i)):
+        if candidates[at].must or candidates[at].nbytes <= left:
             chosen.append(at)
             left -= candidates[at].nbytes
     return [candidates[at] for at in sorted(chosen)]
@@ -375,7 +378,8 @@ def kept_candidates(ctx: LoweringContext, ops: List[Operator], shapes) -> List[K
         rule = None if segment is None else getattr(get_op_def_or_none(op.type), "kept", None)
         value = rule(ctx, op, shapes) if rule is not None else None
         if value is not None and (value[0] not in op.output_arg_names or read_in_backward(segment, value[0])):
-            found.append(Kept(segment, value[0], int(value[1]) // shards, op_cost(op, op.block, shapes)[0] / shards))
+            found.append(Kept(segment, value[0], int(value[1]) // shards, op_cost(op, op.block, shapes)[0] / shards,
+                              len(value) > 2 and bool(value[2])))
     return found
 
 

@@ -92,7 +92,9 @@ def set_kept(type: str, rule):
     can name with `jax.ad_checkpoint.checkpoint_name`.  `rule(ctx, op, shapes)`
     (the lowering's context, the op, a `resource_plan.ShapeEnv`) returns (the
     name, the value's bytes over the whole batch), or None where this op makes
-    no such value (its kernel is not the path taken).  A name that is one of the
+    no such value (its kernel is not the path taken); a third value True says
+    that the value MUST be kept, whatever the room (made again it could come
+    out another value: a choice from scores).  A name that is one of the
     op's output variables is given by the lowering itself; any other (a kernel's
     residuals) by the op's own lowering, where it finds the name in `ctx.keep`:
     a program that keeps nothing holds no name."""

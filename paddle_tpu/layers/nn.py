@@ -716,7 +716,7 @@ def space_to_depth(x, blocksize, name=None):
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mask_block=None,
-                    layout="bhld", name=None, kept_kv=False):
+                    layout="bhld", name=None, kept_kv=False, picks=None, picks_topk=None, keep=None):
     """Fused scaled-dot-product attention over (B, H, L, dh) tensors, with
     float32 scores and softmax whatever the operands' dtype.  `layout="blhd"`
     says that `q`, `k`, `v` and the result are (B, L, H, dh) instead, the
@@ -754,7 +754,18 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
 
     `kept_kv` says that `k` and `v` are tensors ANOTHER layer projected and
     kept (a cross-decoder's attention on the self-decoder's keys and values):
-    the same mathematics, counted in `lowering.kept_tensor_readers`."""
+    the same mathematics, counted in `lowering.kept_tensor_readers`.
+
+    `picks` is a mask that is DATA beside the masks that are rules: int32 (B,
+    Lq, Lk / 32), bit j of word w of a query set where the query holds key
+    32 w + j, as `layers.sparse_index` chooses them every step.  The softmax
+    runs over a query's picks and no other pair has weight (with `causal`,
+    none above the diagonal either); on the TPU the splash kernels read the
+    mask a block at a time and skip a block that holds no chosen pair.
+    `picks_topk`, the most keys a query holds, prices the op for the planner.
+    Under `picks` the op also hands on each query's float32 log-sum-exp over
+    its keys, (B, H, Lq): `keep`, a dict, receives it as `keep["lse"]`
+    (`index_alignment` steadies its own softmax by it)."""
     helper = LayerHelper("fused_attention", name=name)
     out = _out(helper, q.dtype, shape=tuple(q.shape[:-1]) + (v.shape[-1],))
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
@@ -770,7 +781,61 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=None, mask=None, mas
         attrs["mask_block"] = int(mask_block)
     if kept_kv:
         attrs["kept_kv"] = True
-    helper.append_op("fused_attention", inputs=inputs, outputs={"Out": [out.name]}, attrs=attrs)
+    if picks is not None:
+        inputs["Picks"] = [picks.name]
+        if picks_topk:
+            attrs["picks_topk"] = int(picks_topk)
+    outputs = {"Out": [out.name]}
+    if picks is not None:
+        heads, positions = (1, 2) if layout == "bhld" else (2, 1)
+        lse = _out(helper, "float32", shape=(q.shape[0], q.shape[heads], q.shape[positions]))
+        outputs["Lse"] = [lse.name]
+        if keep is not None:
+            keep["lse"] = lse
+    helper.append_op("fused_attention", inputs=inputs, outputs=outputs, attrs=attrs)
+    return out
+
+
+def sparse_index(q_index, k_index, weights, topk, name=None):
+    """The learned choice of keys of DeepSeek Sparse Attention's indexer
+    (`ops/sparse_index_ops.py`): from the indexer's queries `q_index` (B, L, Hi,
+    Di), its ONE key a token `k_index` (B, L, 1, Di) and a float32 weight a
+    query and index head `weights` (B, L, Hi), the scores I[t, s] = sum_j w[t,
+    j] Hi^-0.5 Di^-0.5 relu(qI[t, j] . kI[s]) in float32 over s <= t, and for
+    every query the min(`topk`, t + 1) keys of the largest scores, the lower
+    index first among equals.  Returns the picks, int32 (B, L, L / 32): bit j
+    of word w of query t set where t holds key 32 w + j; `fused_attention(picks=)`
+    and `index_alignment` read them.  No gradient passes the choice, and a
+    `recompute_scope` round the op keeps it: the forward made again reads it.
+    `train_loop` publishes a `kind="sparse_index"` record a logged step."""
+    helper = LayerHelper("sparse_index", name=name)
+    batch, length = q_index.shape[0], int(q_index.shape[1])
+    picks = _out(helper, "int32", shape=(batch, length, length // 32))
+    stats = _out(helper, "int32", shape=(5,))
+    picks.stop_gradient = stats.stop_gradient = True
+    helper.append_op("sparse_index", inputs={"QI": [q_index.name], "KI": [k_index.name], "W": [weights.name]},
+                     outputs={"Picks": [picks.name], "Stats": [stats.name]}, attrs={"topk": int(topk)})
+    return picks
+
+
+def index_alignment(q_index, k_index, weights, picks, q, k, lse, scale=None, name=None):
+    """The loss that trains `sparse_index`'s indexer: the mean over rows and
+    queries of KL(p_t || softmax over the query's picks of its index scores),
+    p_t the main attention's probabilities over the picks summed over its
+    heads, a constant.  `q` (B, Hq, L, dh) and `k` (B, Hkv, L, dh) are that
+    attention's operands, heads-major, `scale` its scores' scale (dh^-0.5),
+    `lse` (B, Hq, L) its log-sum-exp a query (`fused_attention(keep=)`): the
+    target's softmax is the op's own and `lse` only steadies it.  Returns [1]
+    float32; its gradient reaches `q_index`, `k_index` and `weights` and
+    nothing else."""
+    helper = LayerHelper("index_alignment", name=name)
+    out = _out(helper, "float32", shape=(1,))
+    rows = _out(helper, "float32", shape=(q_index.shape[0],))     # each row's own term: the op's output `Rows`
+    attrs = {} if scale is None else {"scale": float(scale)}
+    helper.append_op("index_alignment",
+                     inputs={"QI": [q_index.name], "KI": [k_index.name], "W": [weights.name], "Picks": [picks.name],
+                             "Q": [q.name], "K": [k.name], "Lse": [lse.name]},
+                     outputs={"Out": [out.name], "Rows": [rows.name]}, attrs=attrs)
     return out
 
 

@@ -36,6 +36,16 @@ def cast(x, dtype):
     return out
 
 
+def stop_gradient(x, name=None):
+    """`x` as a constant: the identity forward, and no gradient reaches what
+    made `x` through the result."""
+    helper = LayerHelper("stop_gradient", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    out.stop_gradient = True
+    helper.append_op("stop_gradient", inputs={"X": [x.name]}, outputs={"Out": [out.name]})
+    return out
+
+
 def concat(input, axis=0, name=None):
     helper = LayerHelper("concat", name=name)
     shape = None

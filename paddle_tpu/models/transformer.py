@@ -50,7 +50,7 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                          use_fused_attention=False, proj_bias=True,
                          qk_norm_eps=None, positions=None, rope_theta=10000.0,
                          n_kv_heads=None, head_dim=None, qk_norm_per_head=False,
-                         mask=None, mask_block=None, keep=None, kept_kv=None):
+                         mask=None, mask_block=None, keep=None, kept_kv=None, sparse_index=None, index_losses=None):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
     cross-attention (keys/values from another sequence); `bias` is an
@@ -81,7 +81,25 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     (bias included); `kept_kv` is such a pair, and the layer then has NO key or
     value weights: its own queries attend to the kept tensors and its own out
     projection follows.  (`kv=` is something else: another SEQUENCE projected
-    with THIS layer's weights.)  Both take the fused attention's (B, L, H, dh)."""
+    with THIS layer's weights.)  Both take the fused attention's (B, L, H, dh).
+
+    `sparse_index` = dict(heads=, head_dim=, topk=) makes the mask DATA
+    (DeepSeek Sparse Attention): an indexer of its own beside the projections
+    chooses, every step, the `topk` keys each query may see, and the attention
+    runs over those alone (`layers.sparse_index`, `fused_attention(picks=)`).
+    The indexer reads the layer's input DETACHED (`layers.stop_gradient`): qI =
+    x WqI, `heads` of `head_dim`; kI = LayerNorm(x WkI), ONE head of `head_dim`
+    with a gain and a bias; w = x Ww, a float32 weight an index head; qI and kI
+    carry the layer's rotary embedding over their whole width.  What trains it
+    is `layers.index_alignment`, the divergence between the attention's
+    head-summed probabilities over the picks and the softmax of the index scores
+    over them.  `index_losses` receives a CALL that appends that op and returns
+    the term [1]: the caller makes it where the term should stand
+    (`build_causal_lm`: after the layer's `recompute_scope`, so that the term,
+    whose forward pass makes its gradients too, is no part of what backward
+    makes again) and adds it to the loss.  The indexer's ops stand in the scope
+    `sparse_index`.  It takes the fused
+    attention over heads-major operands (a per-head norm or `positions`)."""
     d_head = head_dim or d_model // n_heads
     n_kv_heads = n_kv_heads or n_heads
     kv_in = kv if kv is not None else x
@@ -128,12 +146,41 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     if (mask is not None or n_kv_heads != n_heads) and not use_fused_attention:
         raise ValueError("mask= and n_kv_heads= (a structured mask, grouped key/value heads) are fused_attention's: "
                          "use_fused_attention=True")
+    picks = None
+    if sparse_index is not None:
+        if not (use_fused_attention and heads_major) or kv is not None or kept_kv is not None or mask is not None:
+            raise ValueError("sparse_index=: the chosen keys are fused_attention's (use_fused_attention=True) over "
+                             "heads-major operands of the layer's own sequence (a per-head norm or positions=; no kv=, "
+                             "kept_kv= or mask=)")
+        index_heads, index_dim = sparse_index["heads"], sparse_index["head_dim"]
+        with name_scope("sparse_index"):
+            detached = layers.stop_gradient(x)      # the indexer trains on its own loss: nothing of it reaches x
+            q_index = layers.reshape(project(detached, "index.q", index_heads * index_dim), [0, 0, index_heads, index_dim])
+            k_index = layers.layer_norm(
+                project(detached, "index.k", index_dim), begin_norm_axis=2, epsilon=1e-6,
+                param_attr=_attr_ones(f"{prefix}.index.k_norm.w"),
+                bias_attr=ParamAttr(name=f"{prefix}.index.k_norm.b", initializer=ConstantInitializer(0.0)))
+            k_index = layers.reshape(k_index, [0, 0, 1, index_dim])
+            weights = layers.fc(layers.cast(detached, "float32"), index_heads, num_flatten_dims=2,
+                                param_attr=_attr(f"{prefix}.index.w.w"), bias_attr=False)
+            if positions is not None:
+                q_index = layers.rotary_embedding(q_index, positions, theta=rope_theta, layout="blhd")
+                k_index = layers.rotary_embedding(k_index, positions, theta=rope_theta, layout="blhd")
+            picks = layers.sparse_index(q_index, k_index, weights, sparse_index["topk"])
     if use_fused_attention:
         # Pallas flash kernel: scores never hit HBM.  Attention-prob dropout
         # can't run inside the fused kernel; the equivalent regularization
         # goes on the attention output (same substitution as the ring path).
+        handed = {}
         ctx = layers.fused_attention(q, k, v, bias=bias, causal=causal, mask=mask, mask_block=mask_block,
-                                     layout="bhld" if heads_major else "blhd", kept_kv=kept_kv is not None)
+                                     layout="bhld" if heads_major else "blhd", kept_kv=kept_kv is not None,
+                                     picks=picks, picks_topk=sparse_index and sparse_index["topk"], keep=handed)
+        if picks is not None:
+            def alignment_term():
+                with name_scope("sparse_index"):
+                    return layers.index_alignment(q_index, k_index, weights, picks, q, k, handed["lse"])
+
+            index_losses.append(alignment_term)
         if dropout_prob and not is_test:
             ctx = layers.dropout(ctx, dropout_prob, is_test=is_test,
                                  dropout_implementation="upscale_in_train")
@@ -327,7 +374,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
                   n_kv_heads=None, head_dim=None, attention_mask=None,
                   operator="attention", conv_kernel=3, ffn="gelu", post_norm=False, operator_args=None,
-                  unit_norms=False, keep=None, kept=None):
+                  unit_norms=False, keep=None, kept=None, sparse_index=None, index_losses=None):
     """One transformer layer: a sequence operator (attention) and a
     feed-forward part, each with a residual connection and a norm.
 
@@ -362,6 +409,10 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     (`multi_head_attention(kept_kv=)`).  Attention under a sliding window
     (`attention_mask=("sliding_window", W)`) stands in the scope
     `sliding_attention`, on kept keys and values in `cross_attention`.
+    `sparse_index` = dict(heads=, head_dim=, topk=) gives the attention a
+    learned indexer that chooses each query's keys, and `index_losses` the list
+    that receives the call which makes its alignment term
+    (`multi_head_attention(sparse_index=)`).
 
     The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
     W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
@@ -448,7 +499,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                             qk_norm_per_head=qk_norm == "head",
                                             mask=attention_mask and attention_mask[0],
                                             mask_block=attention_mask and attention_mask[1],
-                                            keep=keep, kept_kv=kept["kv"] if crossing else None)
+                                            keep=keep, kept_kv=kept["kv"] if crossing else None,
+                                            sparse_index=sparse_index, index_losses=index_losses)
     if post_norm:
         attn_out = normed(attn_out, "post_ln1")
     x = layers.elementwise_add(x, attn_out)
@@ -570,6 +622,7 @@ def build_causal_lm(
     sliding_window=None,
     memory_layer=None,
     kv_layer=None,
+    sparse_index=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -668,6 +721,20 @@ def build_causal_lm(
     attention projections biases; `mamba` may hold `inner_norms=False` (the
     plain Mamba-1 mixer) and `taps_bound=` (its taps' own initialisation).
 
+    Attention whose mask is DATA (DeepSeek Sparse Attention) is an argument
+    too: `layer_types` may hold "sparse_attention", the block's own attention
+    (grouped heads, q/k-norm, rotary positions as the other arguments say) over
+    the keys that a learned indexer chooses for every query, every step;
+    `sparse_index` = dict(heads=, head_dim=, topk=) is the indexer
+    (`multi_head_attention(sparse_index=)`).  The layers' alignment terms, each
+    the mean over rows and queries, are averaged over those layers into
+    `fetches["index_kl"]` and added to the loss where the routers' auxiliary
+    terms are (L = L_LM + L_I): the language-model loss trains the main
+    weights through the chosen pairs, this term the indexers, and neither the
+    other's.  With `recompute_layers` a layer's choice is KEPT: the forward
+    that backward makes again reads it (`ops/sparse_index_ops.py`); the
+    alignment op stands after the layer's segment and is made once.
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -692,16 +759,24 @@ def build_causal_lm(
                          "layer_types alone states the depth")
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
     operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention",
-                 "mamba": "mamba", "sliding_attention": "attention", "gmu": "gmu", "cross_attention": "cross_attention"}
+                 "mamba": "mamba", "sliding_attention": "attention", "gmu": "gmu", "cross_attention": "cross_attention",
+                 "sparse_attention": "attention"}
     operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba}
     unknown = sorted(set(kinds) - set(operators))
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
-                         "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention")
+                         "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention, or sparse_attention")
     if (("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent)
             or ("mamba" in kinds and not mamba)):
         raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer "
                          "latent=, a mamba layer mamba=")
+    if "sparse_attention" in kinds and not (sparse_index and all(sparse_index.get(n, 0) >= 1
+                                                                 for n in ("heads", "head_dim", "topk"))):
+        raise ValueError("build_causal_lm: a sparse_attention layer needs sparse_index=dict(heads=, head_dim=, topk=), "
+                         "its indexer")
+    if "sparse_attention" in kinds and (loop is not None or attention_mask is not None):
+        raise ValueError("build_causal_lm: a sparse_attention layer under loop= or attention_mask=: the alignment terms "
+                         "do not leave a loop's body, and the chosen keys are the layer's mask")
     if "sliding_attention" in kinds and not (sliding_window and sliding_window >= 1):
         raise ValueError("build_causal_lm: a sliding_attention layer needs sliding_window=, its width in keys")
     # what a layer reads of another: (the reading kind, the argument that names the maker, the kinds that make it)
@@ -738,7 +813,7 @@ def build_causal_lm(
                              param_attr=_attr("lm.tok_emb", embedding_std, routing_seed))
         if dtype != "float32":
             x = layers.cast(x, dtype)
-        aux = []
+        aux, index_terms = [], []
 
         def stack_of_layers(x):
             kept = {}   # what a layer handed on: "memory" (a scan's output), "kv" (keys and values)
@@ -752,6 +827,7 @@ def build_causal_lm(
                                norm_eps=norm_topk_eps,
                                bias=expert_bias and (expert_bias[0], expert_bias[1] + i),
                                shared_experts=shared_experts)
+                pending = []        # the calls that make a sparse-attention layer's alignment term, AFTER its segment
                 with recompute_scope() if recompute_layers else contextlib.nullcontext():
                     x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
                                       f"lm.l{i}",
@@ -764,7 +840,10 @@ def build_causal_lm(
                                       attention_mask=window or attention_mask,
                                       operator=operators[kind], operator_args=operator_args.get(kind),
                                       conv_kernel=conv_kernel, post_norm=post_norm,
-                                      keep=kept if i in (memory_layer, kv_layer) else None, kept=kept)
+                                      keep=kept if i in (memory_layer, kv_layer) else None, kept=kept,
+                                      sparse_index=sparse_index if kind == "sparse_attention" else None,
+                                      index_losses=pending)
+                index_terms.extend(make() for make in pending)
             return x
 
         def final_norm(x):
@@ -823,6 +902,9 @@ def build_causal_lm(
                 terms = [loss] + [layers.scale(term, scale=coef) for term, coef in
                                   ((balance, load_balance_coef), (z_loss, router_z_coef)) if coef]
                 loss = layers.sums(terms) if len(terms) > 1 else loss
+            if index_terms:  # the indexers' own loss, beside the language model's
+                fetches["index_kl"] = layers.scale(layers.sums(index_terms), scale=1.0 / len(index_terms))
+                loss = layers.sums([loss, fetches["index_kl"]])
         if with_optimizer:
             optimizer.Adam(learning_rate=learning_rate, beta1=beta1, beta2=beta2,
                            epsilon=epsilon).minimize(loss)

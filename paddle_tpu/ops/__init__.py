@@ -10,6 +10,7 @@ from . import (  # noqa: F401
     optimizer_ops,
     pipeline_ops,
     sequence_ops,
+    sparse_index_ops,
     ssm_ops,
     tail_ops,
     tensor_ops,

@@ -1,4 +1,6 @@
-"""Attention under a mask that is a rule over positions, not a tensor.
+"""Attention under a mask that is a rule over positions, not a tensor, and,
+at the module's end (`selected_attention`), under one that IS a tensor of the
+step: each query's own chosen keys, through the same stock kernels.
 
 Three rules.  The SLIDING-WINDOW one (`window_allowed`: key j for query i where
 i - window < j <= i; `fused_attention`'s `mask="sliding_window"`, `window_plan`)
@@ -228,6 +230,7 @@ class Plan(NamedTuple):
     first_key: int   # L where the own-block term is split off the kernels, else 0
     interpret: bool
     rule: str = "block_diffusion"
+    causal: bool = False   # under the rule "selected": the causal rule laid over the picks too
 
     @property
     def tile(self) -> int:
@@ -259,6 +262,9 @@ class Plan(NamedTuple):
         from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
         b, inner = self.block, min(self.block, _KV_COMPUTE)
+        if self.rule == "selected":   # a stored block of the mask a grid step: [512, b] bytes, never [b, b]
+            return splash.BlockSizes(block_q=inner, block_kv=b, block_kv_compute=inner, block_q_dkv=inner, block_kv_dkv=b,
+                                     block_kv_dkv_compute=inner, block_q_dq=inner, block_kv_dq=b)
         dq = dict(use_fused_bwd_kernel=True) if self.fused_backward else dict(block_q_dq=inner, block_kv_dq=b)
         return splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=inner, block_q_dkv=b, block_kv_dkv=b,
                                  block_kv_dkv_compute=inner, **dq)
@@ -360,7 +366,7 @@ def _stock_options(plan: Plan) -> dict:
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     computed = (causal_allowed if plan.rule == "causal"
-                else _window_rule(plan.mask_block) if plan.rule == "sliding_window" else None)
+                else _window_rule(plan.mask_block) if plan.rule == "sliding_window" else None)   # stored: the other rules
     return dict(mask_value=splash.DEFAULT_MASK_VALUE, is_mqa=False, attn_logits_soft_cap=None,
                 mask_function=computed, interpret=plan.interpret)
 
@@ -590,3 +596,122 @@ def window_attention(q, k, v, window: int, scale: float, interpret: bool = False
 def causal_attention(q, k, v, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the causal rule over equal lengths of queries and keys."""
     return attention_under(causal_plan(q.shape[2], q.shape[1], interpret), q, k, v, scale, keep)
+
+
+# -- a mask that is DATA: each query's own chosen keys ------------------------------------------------------------------
+#
+# The same stock kernels once more, their block maps made on the device from the step's own mask (the stock
+# `process_dynamic_mask`): every block of the grid brings its [queries, keys] block of the mask from HBM, one byte a
+# pair, a block no query of which chose a key is skipped (`block_mask` 0: its keys are not fetched), and nothing is
+# computed from positions.  The rows are taken one at a time (`lax.map`): a row's mask is a row's own block maps, which
+# the kernels read from scalar memory.
+
+#: The selected attention's grid block: the largest of these that divides the length.  At 1024 the fused backward
+#: kernel's [keys, queries] block of the stored mask overran the scoped VMEM inside two cells' steps (`_BLOCKS`' table);
+#: dq and dkv are kernels of their own here, as under block diffusion's rule, each over 512 queries or keys a step.
+_SELECTED_BLOCKS = (1024, 512, 256, 128)
+
+
+def selected_block(length: int):
+    """The grid's block for `length` positions under a mask that is data, None
+    where the kernels are not taken."""
+    return next((b for b in _SELECTED_BLOCKS if length % b == 0), None)
+
+
+def selected_plan(length: int, heads: int, causal: bool = False, interpret: bool = False) -> Plan:
+    return Plan(length, heads, 1, selected_block(length), 0, interpret, "selected", causal)
+
+
+def _row_mask(picks, plan: Plan):
+    """bool [L, L] of one row's picks [L, L / 32]."""
+    from .sparse_index_ops import unpack_bits
+
+    allowed = unpack_bits(picks, plan.positions)
+    if plan.causal:
+        allowed &= causal_allowed(jax.lax.broadcasted_iota(jnp.int32, allowed.shape, 0),
+                                  jax.lax.broadcasted_iota(jnp.int32, allowed.shape, 1))
+    return allowed
+
+
+def _selected_map(picks, plan: Plan, queries: int, keys: int, dkv: bool = False):
+    """One kernel's block map, (`queries`, `keys`) a block, of ONE row's picks
+    [L, L / 32], as arrays of the step (`dkv`: the dkv kernel's, its stored
+    blocks [keys, queries])."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as info_lib
+
+    process = info_lib.process_dynamic_mask_dkv if dkv else info_lib.process_dynamic_mask
+    info = process(_row_mask(picks, plan)[None], (queries, keys))[0]
+    # the stock kernels read a dynamic mask's blocks by one index
+    return info._replace(partial_mask_blocks=info.partial_mask_blocks.reshape((-1,) + info.partial_mask_blocks.shape[-2:]))
+
+
+def _selected_forward(q, k, v, picks, plan: Plan):
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    def row(operands):
+        q, k, v, picks = operands
+        info = _selected_map(picks, plan, plan.sizes.block_q, plan.sizes.block_kv)
+        out, (lse,) = splash._splash_attention_forward(
+            info, q, k, v, None, None, block_sizes=plan.sizes, residual_checkpoint_name=None, save_residuals=True,
+            **_stock_options(plan))
+        return out, lse
+
+    return jax.lax.map(row, (q, k, v, picks))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _selected(q, k, v, picks, plan: Plan, keep=None):
+    return _selected_forward(q, k, v, picks, plan)
+
+
+def _selected_fwd(q, k, v, picks, plan: Plan, keep):
+    out, lse = _selected_forward(q, k, v, picks, plan)
+    if keep:
+        out, lse = checkpoint_name(out, keep), checkpoint_name(lse, keep)
+    return (out, lse), (q, k, v, picks, out, lse)
+
+
+def _selected_bwd(plan: Plan, keep, residuals, cotangents):
+    """The stock dq and dkv kernels a row, each on its own block maps of the
+    row's mask; the picks are whole numbers and take no gradient.  The
+    log-sum-exp is an OUTPUT here (the alignment term reads it): ds = p (dp -
+    di) + p dlse, so its cotangent goes in as di - dlse."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    q, k, v, picks, out, lse = residuals
+    do, dlse = cotangents
+    di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32), do.astype(jnp.float32)) - dlse.astype(jnp.float32)
+    sizes = plan.sizes
+    options = dict(_stock_options(plan), q_layout=sizes.q_layout, k_layout=sizes.k_layout, v_layout=sizes.v_layout)
+
+    def row(operands):
+        q, k, v, picks, lse, do, di = operands
+        _, dk, dv = splash._splash_attention_bwd_dkv(
+            q, k, v, None, None, lse, do, di, bq=sizes.block_q_dkv, bkv=sizes.block_kv_dkv,
+            bkv_compute=sizes.block_kv_dkv_compute, use_fused_bwd_kernel=False,
+            mask_info=_selected_map(picks, plan, sizes.block_q_dkv, sizes.block_kv_dkv, dkv=True), **options)
+        dq = splash._splash_attention_bwd_dq(
+            q, k, v, None, None, lse, do, di, bq=sizes.block_q_dq, bkv=sizes.block_kv_dq,
+            mask_info=_selected_map(picks, plan, sizes.block_q_dq, sizes.block_kv_dq), **options)
+        return dq, dk, dv
+
+    return (*jax.lax.map(row, (q, k, v, picks, lse, do, di)), None)
+
+
+_selected.defvjp(*counted_rules("fused_attention", _selected_fwd, _selected_bwd))
+
+
+def selected_attention(q, k, v, picks, scale: Optional[float], causal: bool = False, interpret: bool = False, keep=None):
+    """(out, the float32 log-sum-exp of each query's scores over its keys (B,
+    Hq, L)) of softmax(q k^T . scale over the keys `picks` holds for each query) v
+    over (B, Hq, L, dh) queries and (B, Hkv, L, dh) keys and values: `picks` int32 (B, L, L / 32), bit j
+    of word w of query t set where t holds key 32 w + j (`ops/sparse_index_ops.py: pack_bits`).  No
+    pair outside the picks has weight: the kernels mask every block from the
+    stored mask, whatever the positions.  A query that holds no key reads the
+    stock kernels' finite output under a log-sum-exp of `mask_value`; the
+    indexer's choice always holds the query's own position."""
+    plan = selected_plan(q.shape[2], q.shape[1], causal, interpret)
+    with jax.named_scope("selected_attention"):
+        if scale is not None:
+            q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        return _selected(q, k, v, picks, plan, keep)

@@ -538,10 +538,15 @@ _ATTENTION_AXES = {"bhld": (1, 2), "blhd": (2, 1)}
 
 
 def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False, layout="bhld", v_width=None,
-                    batch_axis=None):
+                    batch_axis=None, picked=False):
     """Which attention `fused_attention` lowers to: "flash", "block_causal",
-    "row_kernel", "block_sparse" or "xla", the lengths read by the op's
-    `layout`.  Off the TPU always "xla".  A short
+    "row_kernel", "block_sparse", "selected" or "xla", the lengths read by the
+    op's `layout`.  Off the TPU always "xla".  Under a mask that is DATA
+    (`picked`: the op's input `Picks`) "selected", the splash kernels on block
+    maps made on the device from the picks (`ops/masked_attention.py:
+    selected_attention`), where the other kernels' conditions hold (one device,
+    bf16, as many keys as queries in whole blocks, one head width, a multiple
+    of 64), else XLA's attention under the dense mask.  A short
     query against long keys (a decoding step) has no score block worth keeping
     out of HBM, hence BOTH lengths in the row kernel's rule.  Under a
     structured `mask` (`_structured_mask`: block diffusion's rule or a sliding
@@ -571,6 +576,12 @@ def _attention_path(platform, mesh, q, k, mask=None, causal=False, biased=False,
     # values of another width than queries and keys (latent attention: 192-wide q, k beside 128-wide v): the splash
     # kernels take the widths as they are; the flash, row and block-diffusion kernels were never given any
     one_width = v_width in (None, q.shape[-1])
+    if picked:
+        from .masked_attention import selected_block
+
+        whole = (q_len == kv_len and selected_block(q_len) is not None and q.shape[-1] % 64 == 0
+                 and q.dtype == k.dtype == jnp.bfloat16 and mask is None and not biased)
+        return "selected" if whole and one_device and one_width else "xla"
     if mask is not None:
         if mask[0] == "sliding_window":
             # Phi-4-mini-flash's window layer, (1, 40 on 20, 8192, 64) under a window of 512: XLA's attention would
@@ -663,8 +674,9 @@ def _structured_mask(op, q, k, layout="bhld"):
     return kind, int(block)
 
 
-def _xla_attention(q, k, v, bias, causal, scale, mask):
-    """Two einsums round `jax.nn.softmax` over (B, H, L, dh), the scores in HBM."""
+def _xla_attention(q, k, v, bias, causal, scale, mask, allowed=None):
+    """Two einsums round `jax.nn.softmax` over (B, H, L, dh), the scores in HBM.
+    `allowed` bool (B, Lq, Lk): a mask that is data, each query's own keys."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -677,13 +689,17 @@ def _xla_attention(q, k, v, bias, causal, scale, mask):
 
         at = jnp.arange(s.shape[-1], dtype=jnp.int32)
         if mask[0] == "sliding_window":
-            allowed = window_allowed(at[:, None], at[None, :], mask[1])
+            by_rule = window_allowed(at[:, None], at[None, :], mask[1])
         else:
-            allowed = block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1])
-        s = jnp.where(allowed, s, -1e30)
+            by_rule = block_diffusion_allowed(at[:, None], at[None, :], s.shape[-1] // 2, mask[1])
+        s = jnp.where(by_rule, s, -1e30)
+    if allowed is not None:
+        s = jnp.where(allowed[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)
+    if allowed is not None:   # ... and each query's log-sum-exp over its keys, the op's output `Lse`
+        return out.astype(q.dtype), jax.nn.logsumexp(s, axis=-1)
     return out.astype(q.dtype)
 
 
@@ -694,8 +710,8 @@ def _fused_attention(ctx, op, ins):
     + bias, causal mask) v, with the operands in their own dtype on the MXU,
     float32 accumulation, float32 scores and softmax, and the probabilities
     rounded to the activations' dtype for the product with v.  One
-    mathematics, five tilings, chosen by `_attention_path` and counted in
-    `lowering.attention_flash|block_causal|row_kernel|block_sparse|xla`:
+    mathematics, six tilings, chosen by `_attention_path` and counted in
+    `lowering.attention_flash|block_causal|row_kernel|block_sparse|selected|xla`:
 
     * `block_causal`: from `_FLASH_MIN_SEQ` keys on, a causal mask and no
       bias over as many bf16 keys as queries, on one device: the stock
@@ -716,6 +732,13 @@ def _fused_attention(ctx, op, ins):
       the few distinct cut blocks (block diffusion) or compute the rule from
       the positions (a sliding window, `mask_block` its width in keys), and
       no mask or score of the whole square is in HBM;
+    * `selected`: under a mask that is DATA (the input `Picks`, int32 (B, Lq,
+      Lk / 32): bit j of word w of a query set where it holds key 32 w + j;
+      `sparse_index` makes it), the same stock kernels on block maps made on
+      the device from the picks: every block brings its stored block of the
+      mask, a block that holds no chosen pair is skipped, and no pair outside
+      the picks has weight (with `causal`, none above the diagonal either).
+      Counted in `lowering.selected_attention_ops`;
     * `xla`: two einsums round `jax.nn.softmax`, the scores in HBM:
       everything else on the TPU, and every other platform (CPU tests and
       virtual meshes compute the same function, so goldens transfer); a
@@ -748,7 +771,11 @@ def _fused_attention(ctx, op, ins):
     replicated as before.  The bias derives from lengths and causality in every
     caller, so the row kernel treats it as a constant."""
     bias = first(ins, "Bias") if "Bias" in ins and ins["Bias"] else None
-    return {"Out": attention(ctx, op, first(ins, "Q"), first(ins, "K"), first(ins, "V"), bias)}
+    picks = first(ins, "Picks") if "Picks" in ins and ins["Picks"] else None
+    out = attention(ctx, op, first(ins, "Q"), first(ins, "K"), first(ins, "V"), bias, picks=picks)
+    # under picks also each query's float32 log-sum-exp over its keys, (B, H, Lq): what `index_alignment` steadies its own
+    # softmax by (an op that declares no `Lse` drops it)
+    return {"Out": out} if picks is None else {"Out": out[0], "Lse": out[1]}
 
 
 def attention_scale(op, width: int) -> float:
@@ -757,11 +784,12 @@ def attention_scale(op, width: int) -> float:
     return 1.0 / float(np.sqrt(width)) if scale is None else scale
 
 
-def attention(ctx, op, q, k, v, bias=None, assembled=False):
+def attention(ctx, op, q, k, v, bias=None, assembled=False, picks=None):
     """`fused_attention`'s lowering on its operands.  `assembled` (the latent
     attention's unit, `ops/latent_operands.py`): q, k, v and the result are
     heads-major whatever the op's `layout`, and the queries carry the scale, so
-    that no path transposes at its edge and none scales."""
+    that no path transposes at its edge and none scales.  Under `picks` the
+    result is (out, log-sum-exp (B, H, Lq) float32)."""
     causal = op.attr("causal", False)
     layout = "bhld" if assembled else op.attr("layout", "bhld")
     # assembled queries carry the scale: the splash kernels, which scale the queries at their edge, are told so (None),
@@ -770,8 +798,10 @@ def attention(ctx, op, q, k, v, bias=None, assembled=False):
     carried = None if assembled else float(scale)
     mask = _structured_mask(op, q, k, layout)
     path = _attention_path(ctx.platform, ctx.mesh, q, k, mask, causal, bias is not None, layout, v.shape[-1],
-                           ctx.batch_axis)
+                           ctx.batch_axis, picks is not None)
     _MON.counter(f"lowering.attention_{path}").inc()
+    if picks is not None:
+        _MON.counter("lowering.selected_attention_ops").inc()
     if v.shape[-1] != q.shape[-1]:
         _MON.counter("lowering.latent_attention_layers").inc()
     if op.attr("kept_kv", False):
@@ -784,7 +814,11 @@ def attention(ctx, op, q, k, v, bias=None, assembled=False):
         if not native:
             q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
         heads = _ATTENTION_AXES[layout][0] if native else 1
-        if path == "block_sparse":
+        if path == "selected":
+            from .masked_attention import selected_attention
+
+            out = selected_attention(q, k, v, picks, carried, bool(causal), keep=keep)
+        elif path == "block_sparse":
             from .masked_attention import block_sparse_attention, window_attention
 
             under = window_attention if mask[0] == "sliding_window" else block_sparse_attention
@@ -803,8 +837,14 @@ def attention(ctx, op, q, k, v, bias=None, assembled=False):
 
                 b = jax.lax.stop_gradient(bias) if bias is not None else None
                 out = fused_sdpa(q, k, v, b, bool(causal), float(scale), False, layout).astype(q.dtype)
+            elif picks is not None:
+                from .sparse_index_ops import unpack_bits
+
+                out = _xla_attention(q, k, v, bias, causal, scale, mask, unpack_bits(picks, k.shape[2]))
             else:
                 out = _xla_attention(q, k, v, bias, causal, scale, mask)
+        if picks is not None:
+            return (out[0] if native else jnp.swapaxes(out[0], 1, 2)), out[1]
         return out if native else jnp.swapaxes(out, 1, 2)
 
     if path != "xla" and batch_shards(ctx.mesh, ctx.batch_axis, q.shape[0]) > 1:
@@ -1502,7 +1542,15 @@ def _infer_fused_attention(ctx):
             ctx.fail(f"{name}'s {shape[heads]} heads (axis {heads} of {layout}) do not divide Q's {qs[heads]}")
     if ks is not None and vs is not None and tuple(ks[:3]) != tuple(vs[:3]):
         ctx.fail(f"K {ks} and V {vs} differ in more than the head width")
+    picks = ctx.in_shape("Picks")
+    if picks is not None and ks is not None:
+        positions = _ATTENTION_AXES[layout][1]
+        want = (qs[positions], -(-ks[positions] // 32))
+        if len(picks) != 3 or (_A.DYN not in tuple(picks[1:]) + want and tuple(picks[1:]) != want):
+            ctx.fail(f"Picks must be (B, {want[0]}, {want[1]}): a word of 32 keys' bits a query (sparse_index's), got {picks}")
     ctx.set_out("Out", qs if vs is None else tuple(qs[:3]) + (vs[3],), ctx.in_dtype("Q"))
+    if picks is not None:
+        ctx.set_out("Lse", (qs[0], qs[heads], qs[_ATTENTION_AXES[layout][1]]), "float32")
 
 
 _A.register_rule(["fused_attention"], _infer_fused_attention)
@@ -1620,6 +1668,9 @@ def _cost_fused_attention(ctx):
 
         count = window_pairs if ctx.op.attr("mask") == "sliding_window" else allowed_pairs
         pairs = count(lq, ctx.op.attr("mask_block"))  # the pairs the rule allows
+    if ctx.op.attr("picks_topk", None):
+        topk = min(ctx.op.attr("picks_topk"), lk)   # a query holds min(topk, its position + 1) keys
+        pairs = topk * (topk + 1) // 2 + max(lq - topk, 0) * topk
     return 2.0 * _elems_xs((b, h)) * (dh + dv) * pairs, ctx.io_bytes()
 
 
@@ -1629,15 +1680,15 @@ _RP.register_cost(["ring_attention"], _cost_fused_attention)
 
 def _kept_attention(ctx, op, shapes):
     """Where the op takes the splash kernels of `ops/masked_attention.py`
-    (`block_causal`, `block_sparse`): the output and the float32 log-sum-exp a
+    (`block_causal`, `block_sparse`, `selected`): the output and the float32 log-sum-exp a
     query and head, which only the forward kernels make.  The other paths name
     nothing: no cell runs the flash kernel, the row kernel keeps nothing but its
     output, XLA's attention is XLA's to make again."""
     q, k, v = (operand_of(shapes, op.input(slot)[0]) for slot in ("Q", "K", "V"))
     layout = op.attr("layout", "bhld")
     path = _attention_path(ctx.platform, ctx.mesh, q, k, _structured_mask(op, q, k, layout), op.attr("causal", False),
-                           bool(op.input("Bias")), layout, v.shape[-1], ctx.batch_axis)
-    if path not in ("block_causal", "block_sparse"):
+                           bool(op.input("Bias")), layout, v.shape[-1], ctx.batch_axis, bool(op.input("Picks")))
+    if path not in ("block_causal", "block_sparse", "selected"):
         return None
     heads, positions = _ATTENTION_AXES[layout]
     lse_bytes = 4 * q.shape[0] * q.shape[heads] * q.shape[positions]

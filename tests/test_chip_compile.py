@@ -767,11 +767,12 @@ def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recompu
     assert not re.search(r'op_name="[^"]*op\d+:selective_scan/[^"]*while', text)
 
 
-def _kept_step(module, config, traffic, devices, monkeypatch):
+def _kept_step(module, config, traffic, devices, monkeypatch, check_rows=None):
     """(the compiled train step of a cell at its configuration's and traffic's
     own sizes, for the described chip or mesh, with what `plan_kept` chose at
     the chip's own memory limit; the `lowering.recomputed_*` counters of its
-    trace)."""
+    trace).  `check_rows`: the cell's `for_test` clone on that many rows with
+    the variables its reference check fetches, instead of the step."""
     import importlib
 
     import paddle_tpu as fluid
@@ -788,15 +789,16 @@ def _kept_step(module, config, traffic, devices, monkeypatch):
         monkeypatch.setattr(fluid.parallel, "make_mesh", lambda sizes, names, _=None: make_mesh(sizes, names, list(devices)))
         mesh = fluid.parallel.make_mesh(tuple(job["mesh_shape"]), tuple(job["mesh_axes"]))
     with fluid.unique_name.guard():
-        main, startup, _, loss, _ = model.build(cfg, job)
+        main, startup, _, loss, compared = model.build(cfg, job)
     main.random_seed = startup.random_seed = 3
+    program, fetched = (main, [loss.name]) if check_rows is None else (main.clone(for_test=True), list(compared))
     scope = fluid.Scope()
     for v in startup.global_block().vars.values():
         if v.persistable:
             scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    rows = job["batch_per_chip"] * (mesh.size if mesh is not None else 1)
+    rows = check_rows or job["batch_per_chip"] * (mesh.size if mesh is not None else 1)
     feeds = {n: jax.ShapeDtypeStruct((rows, job["seq_len"]), I32) for n in model.FEEDS}
-    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, mesh=mesh, batch_axis=job.get("mesh_axes", ["dp"])[0],
+    step = ex._CompiledStep(program, list(feeds), fetched, scope, mesh=mesh, batch_axis=job.get("mesh_axes", ["dp"])[0],
                             platform="tpu", feed_shapes={n: s.shape for n, s in feeds.items()})
     one = SingleDeviceSharding(devices[0])
 
@@ -915,6 +917,69 @@ def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_plan
     # (the rare path makes its own again, and a rotation's pair swap is a product with a constant, no kept matrix's)
     again = [name for name in _made_again(text) if "/cond/branch_" not in name and ":rotary_embedding/" not in name]
     assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]
+
+
+def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):
+    """The splash kernels on block maps made from the step's own picks
+    (`ops/masked_attention.py: selected_attention`), forward, dq and dkv, at (1,
+    32 on 4, 16384, 128) bf16 with the picks as int32 words: every grid step's
+    stored [512, 1024] block of the mask fits the scoped VMEM beside its
+    operands (the fused backward's [1024, 1024] did not: `_BLOCKS`' table), and
+    no byte mask of the whole square outlives the row it was unpacked for
+    (three block layouts of 268 MB each and the unpacking's own temporaries:
+    under 4 GB)."""
+    from paddle_tpu.ops import masked_attention as ma
+
+    def gradients(q, k, v, picks):
+        def loss(q, k, v):
+            out, lse = ma.selected_attention(q, k, v, picks, 128 ** -0.5, causal=True)
+            return out.astype(F32).sum() + lse.sum()
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+        ((1, 32, 16384, 128), BF16), ((1, 4, 16384, 128), BF16), ((1, 4, 16384, 128), BF16), ((1, 16384, 512), I32))]
+    compiled = jax.jit(gradients).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9, compiled.memory_analysis().temp_size_in_bytes
+    assert ma.selected_plan(16384, 32).sizes.block_q == 512 and ma.selected_plan(16384, 32).sizes.block_kv == 1024
+
+
+@pytest.mark.slow   # two compiles, ~115 and ~80 s on every core: run by name (`-m slow`); PERF.md, PR 56, has their readings
+def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
+    """`keye-vl-2.0-30b-a3b.train-dsa-s16384`'s whole step at the published
+    widths and 16384 tokens, compiled for the described v5e with what
+    `plan_kept` chooses at the chip's memory limit: every candidate of the four
+    segments, the four layers' picks among them (33.5 MB each, kept whatever the
+    room), planned over 25% of the chip and under the 15.5 GB a cell allows
+    itself; the attention took the splash kernels under the stored mask, no
+    `reduce-window` spans a row of keys, and in the rematerialised computations
+    no `top_k` of the indexer, no attention kernel and no product is left.  The 8-row
+    `for_test` clone of the reference check, the tightest program of a
+    16384-token cell (PERF.md, PR 54), plans with the optimizer's two moments
+    beside it under the 16.9 GB the chip's runtime gives (ISSUE 56)."""
+    compiled, counted = _kept_step("keye", "keye-vl-2.0-30b-a3b", "train-dsa-s16384", host.devices, monkeypatch)
+    assert counted["segments"] == counted["sparse_segments"] == 4
+    assert counted["kept_bytes"] == counted["candidates_bytes"] > 2e9
+    peak = _planned_peak(compiled)
+    print(f"the step's planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 0.25 * 16.9e9 <= peak <= 15.5e9, f"the step plans {peak / 1e9:.3f} GB"
+    text = compiled.as_text()
+    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
+    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:sparse_index/index_select/", text))) == 4
+    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/", text))) == 4
+    windows = [int(n) for n in re.findall(r"reduce-window\([^\n]*window=\{size=[0-9x]*?x?(\d+) pad", text)]
+    assert max(windows, default=0) < 2048, max(windows)      # no row's statistic is spread as one window over the row
+    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
+    # (the router's own top-8 is made again with its layer, the same choice bit for bit: ISSUE 54; the INDEXER's never)
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name
+                          or (name.endswith("/top_k") and "moe_router" not in name)
+                          or "index_select" in name or "index_alignment" in name]
+    clone, _ = _kept_step("keye", "keye-vl-2.0-30b-a3b", "train-dsa-s16384", host.devices, monkeypatch, check_rows=8)
+    moments = 2 * 4 * 465_391_104
+    beside = _planned_peak(clone) + moments
+    print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
+    assert beside <= 16.9e9, f"the clone plans {_planned_peak(clone) / 1e9:.3f} GB beside {moments / 1e9:.3f} GB of moments"
 
 
 @pytest.mark.slow   # one compile for four devices, ~3 minutes here: run by name (`-m slow`); PERF.md, PR 51, has its readings

@@ -1,0 +1,749 @@
+"""Keye-VL-2.0-30B-A3B's parts and the whole, tiny on the CPU (ISSUE 56).
+
+(a) `sparse_index`: every query holds min(topk, t + 1) keys, none after itself,
+    equals broken by the lower index, over one chunk and over several chunks
+    and bands; the picks as bits; the `infer=` rules, the planner rows,
+    `analysis.verify`;
+(b) `fused_attention(picks=)`: with topk >= the length the selected attention
+    IS the causal one, to the last bit; its gradient is dense attention's under
+    the same fixed mask; the splash kernels on block maps made from the picks
+    (interpreted here) against the dense form, output and gradients, a block
+    with no chosen pair skipped;
+(c) `index_alignment` against the reference's function, value and gradients,
+    over several chunks; nothing of it reaches the attention's operands;
+(d) the sectioned rotation (`mrope_section`) equals the plain one for equal
+    streams, and does not for unequal ones;
+(e) the held shares of this block's router add up to the uncut layer;
+(f) a tiny `build_causal_lm` of two sparse-attention layers in float32 against
+    the benchmark's reference (benchmark/models/keye.py) on seeded weights: both
+    loss terms, logits, every stage, every parameter's gradient (the main
+    weights' from L_LM alone, the indexer's from L_I alone); gradients with and
+    without `recompute_scope` equal to the last bit, the choice KEPT and never
+    made again; in bf16 within the benchmark's tolerances; and the faults the
+    comparison has to refuse.
+
+One compiled tiny model serves (f): `float32_run`.
+"""
+import os
+import sys
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import paddle_tpu as fluid  # noqa: E402
+from benchmark import manifest as mf  # noqa: E402
+from benchmark.models import keye  # noqa: E402
+from paddle_tpu import layers, monitor  # noqa: E402
+from paddle_tpu.core import lowering  # noqa: E402
+from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
+from paddle_tpu.core.registry import get_op_def  # noqa: E402
+from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import sparse_index_ops as sio  # noqa: E402
+
+
+def lower(op_type, ins, attrs=None):
+    """One op's lowering called as the interpreter calls it."""
+    attrs = attrs or {}
+    op = SimpleNamespace(type=op_type, attr=lambda n, d=None: attrs.get(n, d), input=lambda s: [], output=lambda s: [])
+    ctx = LoweringContext(jax.random.PRNGKey(0))
+    return get_op_def(op_type).lower(ctx, op, {k: [jnp.asarray(v)] for k, v in ins.items()})
+
+
+def agree(got, want, tol=1e-5, floor=1e-12):
+    got, want = np.asarray(got, "f8"), np.asarray(want, "f8")
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), floor), \
+        (np.abs(got - want).max(), np.abs(want).max())
+
+
+@pytest.fixture(autouse=True)
+def float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def indexer_operands(rng, rows, length, heads, width):
+    return (rng.randn(rows, length, heads, width).astype("f4"), rng.randn(rows, length, 1, width).astype("f4"),
+            rng.randn(rows, length, heads).astype("f4"))
+
+
+def scores_float64(qi, ki, w):
+    """I [rows, L, L] of the module's docstring in float64 numpy, every pair."""
+    qi, ki, w = (np.asarray(t, "f8") for t in (qi, ki, w))
+    products = np.einsum("bthd,bsd->bths", qi, ki[:, :, 0])
+    return np.einsum("bths,bth->bts", np.maximum(products, 0.0), w) * qi.shape[2] ** -0.5 * qi.shape[3] ** -0.5
+
+
+def top_keys(scores, topk):
+    """bool [rows, L, L]: float64's choice, the lower index first among equals."""
+    length = scores.shape[-1]
+    causal = np.tril(np.ones((length, length), bool))
+    order = np.argsort(-np.where(causal, scores, -np.inf), -1, kind="stable")
+    want = np.zeros(scores.shape, bool)
+    np.put_along_axis(want, order[..., :topk], True, -1)
+    return want & causal
+
+
+# -- (a) the choice --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("length,topk,heads,width", [(32, 8, 4, 16), (1024, 48, 2, 8), (4096, 96, 2, 8)],
+                         ids=["one-chunk", "two-chunks", "two-bands-of-four-chunks"])
+def test_every_query_holds_its_top_keys_none_after_itself(length, topk, heads, width):
+    rng = np.random.RandomState(56)
+    qi, ki, w = indexer_operands(rng, 2, length, heads, width)
+    out = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk})
+    picks = keye.unpack(np.asarray(out["Picks"]), length)
+    assert np.asarray(out["Picks"]).dtype == np.int32 and np.asarray(out["Picks"]).shape == (2, length, length // 32)
+    held = np.minimum(topk, np.arange(length) + 1)
+    assert (picks.sum(-1) == held).all()                                     # min(topk, t + 1) keys a query
+    assert not (picks & ~np.tril(np.ones((length, length), bool))).any()     # none after itself
+    want = top_keys(scores_float64(qi, ki, w), topk)
+    differ = (picks != want).sum() / 2 / want.sum()
+    assert differ < 2e-4, differ                                             # float32 against float64 at the threshold
+    chunk, bands = sio.chunking(length)
+    assert (chunk, len(bands)) == {32: (32, 1), 1024: (512, 1), 4096: (512, 2)}[length]
+    stats = np.asarray(out["Stats"])
+    assert stats[0] == 2 * held.sum() and stats[3] == 2 * length and stats[4] == 2 * sio.chunk_pairs(length)
+    recent = sum((picks[r] & (np.arange(length)[None, :] > np.arange(length)[:, None] - topk)).sum() for r in range(2))
+    assert stats[1] == recent and 0 < stats[2] <= stats[4]
+
+
+def test_equal_scores_are_broken_by_the_lower_index():
+    """A key's scores all zero (every ReLU shut) tie at 0: of the equals the
+    lowest indices are held, as `lax.top_k` orders them."""
+    length, topk = 32, 8
+    qi = np.ones((1, length, 2, 4), "f4")
+    ki = -np.ones((1, length, 1, 4), "f4")        # every product negative: I = 0 everywhere
+    ki[0, 20:24] = 1.0                            # but four keys, which every later query holds first
+    w = np.ones((1, length, 2), "f4")
+    picks = keye.unpack(np.asarray(lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk})["Picks"]), length)[0]
+    assert picks[31].nonzero()[0].tolist() == [0, 1, 2, 3, 20, 21, 22, 23]
+    assert picks[21].nonzero()[0].tolist() == [0, 1, 2, 3, 4, 5, 20, 21]
+    assert picks[12].nonzero()[0].tolist() == list(range(8)) and picks[5].nonzero()[0].tolist() == list(range(6))
+
+
+def test_the_picks_are_words_of_32_keys_bits():
+    rng = np.random.RandomState(1)
+    chosen = rng.rand(3, 5, 96) < 0.3
+    packed = sio.pack_bits(jnp.asarray(chosen))
+    assert packed.dtype == jnp.int32 and packed.shape == (3, 5, 3)
+    np.testing.assert_array_equal(np.asarray(sio.unpack_bits(packed, 96)), chosen)
+    np.testing.assert_array_equal(keye.unpack(np.asarray(packed), 96), chosen)
+    word = np.asarray(packed).view(np.uint32)[0, 0, 1]
+    assert [bool(word >> j & 1) for j in range(32)] == chosen[0, 0, 32:64].tolist()      # bit j of word w is key 32 w + j
+
+
+def test_the_ops_have_infer_rules_planner_rows_and_pass_verify():
+    from paddle_tpu.core import analysis, resource_plan
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        qi, ki = layers.data("qi", [64, 2, 8]), layers.data("ki", [64, 1, 8])
+        w, q, k = layers.data("w", [64, 2]), layers.data("q", [4, 64, 16]), layers.data("k", [2, 64, 16])
+        picks = layers.sparse_index(qi, ki, w, topk=16)
+        handed = {}
+        out = layers.fused_attention(q, k, k, causal=True, picks=picks, picks_topk=16, keep=handed)
+        term = layers.index_alignment(qi, ki, w, picks, q, k, handed["lse"])
+        still = layers.stop_gradient(out)
+        with pytest.raises(analysis.ShapeInferenceError, match="ONE key a token"):
+            layers.sparse_index(qi, layers.data("ki2", [64, 2, 8]), w, topk=16)
+        with pytest.raises(analysis.ShapeInferenceError, match="words of 32 keys"):
+            layers.sparse_index(layers.data("qi3", [48, 2, 8]), layers.data("ki3", [48, 1, 8]), layers.data("w3", [48, 2]), 4)
+        with pytest.raises(analysis.ShapeInferenceError, match="Picks must be"):
+            layers.fused_attention(q, k, k, picks=layers.data("p4", [64, 3], dtype="int32"))
+    assert tuple(picks.shape) == (-1, 64, 2) and picks.dtype == "int32"
+    assert tuple(out.shape) == (-1, 4, 64, 16) and tuple(term.shape) == (1,) and tuple(still.shape) == tuple(out.shape)
+    assert tuple(handed["lse"].shape) == (-1, 4, 64) and handed["lse"].dtype == "float32"
+    block = main.global_block()
+    env = resource_plan.ShapeEnv(main, {n: (3,) + tuple(block.var(n).shape[1:]) for n in ("qi", "ki", "w", "q", "k")})
+    cost = {}
+    for op in block.ops:       # the first of each type: the refused ones stand after them
+        cost.setdefault(op.type, resource_plan.op_cost(op, block, env))
+    triangle = 3 * 64 * 65 / 2
+    assert cost["sparse_index"][0] == triangle * 2 * (2 * 8 + 3)
+    assert cost["fused_attention"][0] == 2.0 * 3 * 4 * (16 + 16) * (16 * 17 // 2 + 48 * 16)       # the CHOSEN pairs
+    assert cost["index_alignment"][0] == triangle * (3 * 2 * (2 * 8 + 3) + 4 * (2 * 16 + 4))
+    assert cost["stop_gradient"][0] == 0.0
+
+
+# -- (b) the selected attention ---------------------------------------------------------------
+
+def attention_operands(rng, rows, heads, kv_heads, length, width, dtype="f4"):
+    return tuple(jnp.asarray(rng.randn(rows, h, length, width), dtype) for h in (heads, kv_heads, kv_heads))
+
+
+def dense_attention(q, k, v, allowed):
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(allowed[:, None], s, -1e30), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v, preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def test_with_topk_at_least_the_length_the_selected_attention_is_the_causal_one_to_the_last_bit():
+    rng = np.random.RandomState(2)
+    length = 64
+    qi, ki, w = indexer_operands(rng, 2, length, 2, 8)
+    q, k, v = attention_operands(rng, 2, 4, 2, length, 16)
+    for topk in (length, 4 * length):
+        picks = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk})["Picks"]
+        np.testing.assert_array_equal(keye.unpack(np.asarray(picks), length),
+                                      np.broadcast_to(np.tril(np.ones((length, length), bool)), (2, length, length)))
+        selected = lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {"causal": True})["Out"]
+        causal = lower("fused_attention", {"Q": q, "K": k, "V": v}, {"causal": True})["Out"]
+        np.testing.assert_array_equal(np.asarray(selected), np.asarray(causal))
+        unmarked = lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {})["Out"]     # the picks ARE the mask
+        np.testing.assert_array_equal(np.asarray(unmarked), np.asarray(causal))
+
+
+def test_the_selected_attentions_gradient_is_dense_attentions_under_the_same_fixed_mask():
+    rng = np.random.RandomState(3)
+    length = 64
+    qi, ki, w = indexer_operands(rng, 2, length, 2, 8)
+    q, k, v = attention_operands(rng, 2, 4, 2, length, 16)
+    picks = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": 8})["Picks"]
+    allowed = jnp.asarray(keye.unpack(np.asarray(picks), length))
+    weight = jnp.asarray(rng.randn(2, 4, length, 16), "f4")
+
+    def selected(q, k, v):
+        return jnp.sum(lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {"causal": True})["Out"] * weight)
+
+    def dense(q, k, v):
+        return jnp.sum(dense_attention(q, k, v, allowed) * weight)
+
+    agree(selected(q, k, v), dense(q, k, v), 1e-6)
+    for mine, theirs in zip(jax.grad(selected, (0, 1, 2))(q, k, v), jax.grad(dense, (0, 1, 2))(q, k, v)):
+        agree(mine, theirs, 1e-5)
+    # a key that no query holds has no weight and takes no gradient: rows 48 on hold keys among 0..47 and their own
+    outside = ~np.asarray(allowed).any(axis=(0, 1))
+    if outside.any():
+        assert not np.asarray(jax.grad(selected, 1)(q, k, v))[:, :, outside].any()
+
+
+def test_the_kernels_under_block_maps_made_from_the_picks_are_the_dense_form():
+    """The TPU's path (`ops/masked_attention.py: selected_attention`), its
+    kernels interpreted: output and the three gradients against the dense form
+    in bf16, with a block of the grid that holds no chosen pair (skipped: its
+    `block_mask` is 0) and the causal rule laid over picks that break it."""
+    from paddle_tpu.ops import masked_attention as ma
+
+    rng = np.random.RandomState(4)
+    rows, heads, kv_heads, length, width = 1, 4, 2, 512, 128
+    q, k, v = attention_operands(rng, rows, heads, kv_heads, length, width, jnp.bfloat16)
+    allowed = (rng.rand(rows, length, length) < 0.3) | np.eye(length, dtype=bool)
+    allowed[:, 256:, :128] = False                                   # a [256, 128] block of the grid with no pair
+    picks = sio.pack_bits(jnp.asarray(allowed))                      # ... and pairs ABOVE the diagonal, which `causal` cuts
+    causal = allowed & np.tril(np.ones((length, length), bool))
+    weight = jnp.asarray(rng.randn(rows, heads, length, width), "f4")
+    assert ma.selected_block(length) == 512 and ma.selected_block(16384) == 1024 and ma.selected_block(100) is None
+
+    def kernels(q, k, v):
+        out, lse = ma.selected_attention(q, k, v, picks, width ** -0.5, causal=True, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * weight) + jnp.sum(lse * lse_weight), (out, lse)
+
+    def dense(q, k, v):
+        out = dense_attention(q, k, v, jnp.asarray(causal))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, heads // kv_heads, 1), preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(jnp.where(jnp.asarray(causal)[:, None], s * width ** -0.5, -jnp.inf), -1)
+        return jnp.sum(out.astype(jnp.float32) * weight) + jnp.sum(lse * lse_weight), (out, lse)
+
+    lse_weight = jnp.asarray(rng.randn(rows, heads, length), "f4")       # the log-sum-exp is an output: its cotangent counts
+    (_, (mine, mine_lse)), grads = jax.value_and_grad(kernels, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, (theirs, their_lse)), want = jax.value_and_grad(dense, (0, 1, 2), has_aux=True)(q, k, v)
+    agree(mine.astype(jnp.float32), theirs.astype(jnp.float32), 2e-2)
+    agree(mine_lse, their_lse, 5e-3)
+    for g, t in zip(grads, want):
+        agree(g.astype(jnp.float32), t.astype(jnp.float32), 2e-2)
+    unruled, _ = ma.selected_attention(q, k, v, picks, width ** -0.5, causal=False, interpret=True)
+    agree(unruled.astype(jnp.float32), dense_attention(q, k, v, jnp.asarray(allowed)).astype(jnp.float32), 2e-2)
+
+
+def test_the_path_is_the_kernels_where_their_conditions_hold_and_xlas_elsewhere():
+    from paddle_tpu.ops.nn_ops import _attention_path
+
+    def path(length=16384, dtype=jnp.bfloat16, width=128, platform="tpu", **more):
+        q = jax.ShapeDtypeStruct((1, 32, length, width), dtype)
+        k = jax.ShapeDtypeStruct((1, 4, more.pop("keys", length), width), dtype)
+        return _attention_path(platform, None, q, k, None, True, False, "bhld", width, None, **more)
+
+    assert path(picked=True) == "selected" and path() == "block_causal"
+    assert path(picked=True, platform="cpu") == path(picked=True, dtype=jnp.float32) == "xla"
+    assert path(picked=True, length=16384 + 32) == path(picked=True, keys=8192) == path(picked=True, width=96) == "xla"
+
+
+# -- (c) the alignment term --------------------------------------------------------------------
+
+@pytest.mark.parametrize("length,topk", [(32, 8), (1024, 48)], ids=["one-chunk", "two-chunks"])
+def test_the_alignment_term_and_its_gradients_are_the_references(length, topk):
+    rng = np.random.RandomState(5)
+    rows, heads, kv_heads, width = 2, 4, 2, 16
+    qi, ki, w = indexer_operands(rng, rows, length, 2, 8)
+    q, k, v = attention_operands(rng, rows, heads, kv_heads, length, width)
+    picks = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk})["Picks"]
+    allowed = jnp.asarray(keye.unpack(np.asarray(picks), length))
+    lse = lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {"causal": True})["Lse"]
+    assert lse.shape == (rows, heads, length) and lse.dtype == jnp.float32
+
+    def mine(qi, ki, w, q, k, lse=lse):
+        out = lower("index_alignment", {"QI": qi, "KI": ki, "W": w, "Picks": picks, "Q": q, "K": k, "Lse": lse})
+        return out["Out"][0], out["Rows"]
+
+    def theirs(qi, ki, w, q, k):
+        terms = [keye.sparse_attention(q[r], k[r], jnp.zeros_like(k[r]), qi[r].transpose(1, 0, 2), ki[r, :, 0],
+                                       w[r] * 2 ** -0.5 * 8 ** -0.5, topk, picks=allowed[r])[1] / length for r in range(rows)]
+        return jnp.mean(jnp.stack(terms)), jnp.stack(terms)
+
+    operands = tuple(jnp.asarray(t) for t in (qi, ki, w)) + (q, k)
+    (value, each), grads = jax.value_and_grad(mine, (0, 1, 2, 3, 4), has_aux=True)(*operands)
+    (want, want_each), want_grads = jax.value_and_grad(theirs, (0, 1, 2, 3, 4), has_aux=True)(*operands)
+    agree(value, want, 2e-5)
+    agree(each, want_each, 2e-5)
+    assert float(value) > 0
+    for g, t in zip(grads[:3], want_grads[:3]):
+        agree(g, t, 5e-5)
+    assert not np.asarray(grads[3]).any() and not np.asarray(grads[4]).any()        # the target is a constant
+    assert not np.asarray(want_grads[3]).any() and not np.asarray(want_grads[4]).any()
+    # the forward pass alone (a `for_test` clone) is the same number; the log-sum-exp only steadies the op's own softmax:
+    # handed another (every row's off by 3), the term is the same
+    agree(jax.jit(lambda *o: mine(*o)[0])(*operands), value, 1e-6)
+    agree(mine(*operands, lse=lse + 3.0)[0], value, 1e-5)
+
+
+# -- (d) the sectioned rotation ------------------------------------------------------------------
+
+def test_the_sectioned_rotation_is_the_plain_one_for_equal_streams_and_not_for_unequal_ones():
+    rng = np.random.RandomState(6)
+    length, theta, sections = 96, 1e7, [16, 24, 24]
+    x = rng.randn(2, 3, length, 128).astype("f4")                               # (B, H, L, dh)
+    positions = np.stack([np.arange(length), rng.randint(0, 16384, length)])
+    plain = np.asarray(lower("rotary_embedding", {"X": x, "Positions": positions}, {"theta": theta})["Out"])
+    # (a float32 angle at position 16383 is itself 1e-3 of a turn from float64's, in the op and in the reference alike)
+    for row, tol in ((0, 1e-5), (1, 1e-3)):
+        streams = jnp.asarray(np.stack([positions[row]] * 3))
+        agree(keye.rotate_sections(jnp.asarray(x[row]), streams, theta, sections), plain[row], tol)
+        agree(keye.rotate_sections(jnp.asarray(x[row]), streams, theta), plain[row], tol)            # one stream: the indexer's
+    # an image token's streams differ (temporal, height, width): another rotation, in the sections' angles alone
+    streams = np.stack([positions[0], positions[0] + 3, positions[0] + 7])
+    other = np.asarray(keye.rotate_sections(jnp.asarray(x[0]), jnp.asarray(streams), theta, sections))
+    same = np.isclose(other, plain[0], atol=1e-5).all(axis=(0, 1))
+    assert same[:16].all() and same[64:80].all()                                # the temporal section's features, both halves
+    assert not same[16:64].all() and not same[80:].all()
+    assert sum(sections) == 64 and mf.read_json("benchmark/configs/keye-vl-2.0-30b-a3b.json")["rope_scaling"]["mrope_section"] == sections
+
+
+# -- (e) the held shares ---------------------------------------------------------------------------
+
+def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Eight chips hold 16 of 128 experts each behind THIS block's router
+    (softmax over 128, top 8 renormalised over all eight, no shared expert):
+    their parts, summed, are the uncut layer's output as the equations write it."""
+    rng = np.random.RandomState(56)
+    tokens, experts, k, d, f = 64, 128, 8, 16, 8
+    x = rng.randn(tokens, d).astype("f4")
+    router = rng.randn(d, experts).astype("f4") / 2
+    gate, up = (rng.randn(experts, d, f).astype("f4") / 4 for _ in range(2))
+    down = rng.randn(experts, f, d).astype("f4") / 4
+    routed = lower("moe_router", {"X": x, "W": router}, {"top_k": k, "norm_topk_prob": True})
+
+    def share(first, count):
+        ins = {"X": x, "TopKProb": routed["TopKProb"], "TopKIndex": routed["TopKIndex"], "Load": routed["Load"],
+               "WGate": gate[first:first + count], "WUp": up[first:first + count], "WDown": down[first:first + count]}
+        return lower("moe_experts", ins, {"held": [first, count]})
+
+    shares = [share(first, 16) for first in range(0, experts, 16)]
+    assert sum(int(np.asarray(s["Held"])[0]) for s in shares) == tokens * k
+    assert all(int(np.asarray(s["Dropped"])[0]) == 0 for s in shares)
+    logits = x.astype("f8") @ router.astype("f8")
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    chosen = np.argsort(-probs, -1)[:, :k]
+    weights = np.take_along_axis(probs, chosen, -1)
+    weights /= weights.sum(-1, keepdims=True)
+    want = np.zeros((tokens, d))
+    for t in range(tokens):
+        for e, g_e in zip(chosen[t], weights[t]):
+            h = x[t].astype("f8") @ gate[e]
+            want[t] += g_e * ((h / (1 + np.exp(-h)) * (x[t].astype("f8") @ up[e])) @ down[e])
+    agree(sum(np.asarray(s["Out"], "f8") for s in shares), want, tol=1e-5)
+    assert np.abs(np.asarray(shares[0]["Out"], "f8") - want).max() > 0.1 * np.abs(want).max()     # one share is not the layer
+
+
+# -- (f) the whole model ---------------------------------------------------------------------------
+
+TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16, moe_intermediate_size=32,
+            num_experts=2, num_routed_experts=8, experts_held_first=2, num_experts_per_tok=2, vocab_size=96,
+            num_hidden_layers=2, rope_scaling=dict(mrope_section=[2, 3, 3]),
+            sa_config=dict(indexer_head_dim=16, indexer_num_heads=4, indexer_num_kv_heads=1, kv_chunk_size=512,
+                           q_chunk_size=512, topk=8))
+JOB = dict(seq_len=32, batch_per_chip=4)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def every_position_is_sampled():
+    from benchmark.models import lfm2
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(lfm2, "LOGIT_SAMPLE", 32)
+        patch.setattr(lfm2, "ATTENTION_SAMPLE", 32)
+        yield
+
+
+def tiny_model(dtype, **job):
+    from paddle_tpu.core import unique_name
+
+    cfg = dict(mf.read_json("benchmark/configs/keye-vl-2.0-30b-a3b.json"), compute_dtype=dtype, **TINY)
+    job = dict(mf.read_json("benchmark/traffic/train-dsa-s16384.json"), **JOB, **job)
+    with unique_name.guard():
+        main, startup, feeds, loss, names = keye.build(cfg, job)
+    main.random_seed = startup.random_seed = 3
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup, scope=scope)
+    return cfg, job, main, loss, names, scope, exe
+
+
+def params_of(main, scope):
+    return {p.name: np.asarray(scope.find_var(p.name)) for p in main.all_parameters()}
+
+
+def reference_of(cfg, params, rows):
+    return [np.asarray(w) for w in jax.jit(lambda p, b: keye.reference(p, b, cfg))(params, rows)]
+
+
+def one_step(main, loss, scope, exe, batch):
+    """(the step's loss, Adam's first moments, the logged step's `sparse_index`
+    record, the trace's `lowering.` counters) of one step through `train_loop`."""
+    losses = []
+    monitor.reset()
+    monitor.enable()
+    try:
+        fluid.train_loop(exe, main, iter([batch]), [loss], scope=scope, log_period=1,
+                         on_logged=lambda i, vals: losses.append(float(np.asarray(vals[0]).reshape(-1)[0])))
+        records = [r for r in monitor.get_monitor().step_records() if r.get("kind") == "sparse_index"]
+        counters = {k: v for k, v in monitor.get_monitor().counter_values().items() if k.startswith("lowering.") and v}
+    finally:
+        monitor.disable()
+        monitor.reset()
+    moments = {p.name: np.asarray(scope.find_var(p.name + "_moment1_0")) for p in main.all_parameters()}
+    return losses.pop(), moments, records, counters
+
+
+@pytest.fixture(scope="module")
+def float32_run():
+    """The tiny model built twice from the same seed, every layer a
+    `recompute_scope` (as the cell builds it) and none, one step each on the
+    same batch; the recomputed one's `for_test` clone against the reference."""
+    with jax.default_matmul_precision("highest"):
+        cfg, job, main, loss, names, scope, exe = tiny_model("float32")
+        rows = keye.make_batch(np.random.RandomState(3), cfg, job, 8)
+        got = exe.run(main.clone(for_test=True), feed=rows, fetch_list=list(names), scope=scope)
+        before = params_of(main, scope)
+        want = reference_of(cfg, before, rows)
+        batch = keye.make_batch(np.random.RandomState(4), cfg, job, 4)
+
+        def term(which):
+            return jax.jit(jax.value_and_grad(lambda p: keye.reference(p, batch, cfg)[which]))(before)
+
+        (ref_loss, ref_grads), (_, lm_grads), (_, index_grads) = term(0), term(2), term(3)
+        step_loss, moments, records, counters = one_step(main, loss, scope, exe, batch)
+        after = params_of(main, scope)
+        _, _, plain_main, plain_loss, _, plain_scope, plain_exe = tiny_model("float32", recompute_layers=False)
+        plain = one_step(plain_main, plain_loss, plain_scope, plain_exe, batch)
+        ops = [op.type for op in main.global_block().ops]
+    as_numpy = lambda grads: {k: np.asarray(v) for k, v in grads.items()}   # noqa: E731
+    return SimpleNamespace(cfg=cfg, job=job, main=main, loss=loss, got=got, want=want, ops=ops, before=before, after=after,
+                           moments=moments, records=records, counters=counters, plain=plain,
+                           plain_segments=[op.attrs.get("recompute_segment") for op in plain_main.global_block().ops],
+                           ref_loss=float(ref_loss), step_loss=step_loss, ref_grads=as_numpy(ref_grads),
+                           lm_grads=as_numpy(lm_grads), index_grads=as_numpy(index_grads))
+
+
+def test_float32_both_loss_terms_logits_and_every_stage_agree_with_the_reference(float32_run):
+    found = keye.compare(float32_run.got, float32_run.want)
+    assert found["left_out"] == found["routed_differently"] == found["routed_differently_above_margin"] == 0
+    assert found["loss_error"] < 1e-5 and found["ce_error"] < 1e-5 and found["index_kl_error"] < 1e-5, found
+    assert found["logit_error"] < 2e-5 and found["index_kl"] > 1e-3, found
+    assert found["picks_differ"] == found["picks_gap"] == found["picks_miscounted"] == found["picks_after_query"] == 0
+    assert max(found["router_prob_error"], found["experts_error"], found["attention_error"], found["alignment_error"],
+               found["qk_error"]) < 2e-5, found
+    assert found["attention_error_dense"] > 0.1                               # what the attention stage has to refuse
+    assert found["reference_self_error"] < 1e-5 and keye.failed_limits(found) == []
+    assert keye.reference_error(float32_run.got, float32_run.want) < 2e-5
+    assert abs(float32_run.step_loss - float32_run.ref_loss) < 1e-5 * float32_run.ref_loss
+    got = float32_run.got
+    assert np.asarray(got[1]).shape == (32, 8, 96)                                             # the sampled logits
+    assert np.asarray(got[4]).shape == (8, 32, 2) and np.asarray(got[5]).shape == (keye.STAGE_ROWS, 32, 64)
+    staged = got[4 + 4 * 2:]
+    assert [np.asarray(t).shape for t in staged[:9]] == [(1, 32, 4, 16), (1, 32, 1, 16), (1, 32, 4), (1, 32, 1),
+                                                          (1, 4, 32, 16), (1, 2, 32, 16), (1, 2, 32, 16), (1, 4, 32, 16), (8,)]
+    assert len(staged) == 18                                                                     # the first and the last layer
+
+
+PARAMS = sorted(
+    ["lm.tok_emb", "lm.head.w", "lm.final_norm.w"]
+    + [f"lm.l{i}.{n}" for i in range(2) for n in ("ln1.w", "ln2.w")]
+    + [f"lm.l{i}.attn.{n}.w" for i in range(2) for n in ("q", "k", "v", "out", "q_norm", "k_norm")]
+    + [f"lm.l{i}.moe.{n}.w" for i in range(2) for n in ("router", "gate", "up", "down")])
+INDEXER = sorted(f"lm.l{i}.attn.index.{n}" for i in range(2) for n in ("q.w", "k.w", "w.w", "k_norm.w", "k_norm.b"))
+
+
+def test_the_tiny_model_has_these_layers_parameters_and_no_other(float32_run):
+    r = float32_run
+    assert sorted(r.before) == sorted(PARAMS + INDEXER)
+    assert r.ops.count("fused_attention") == r.ops.count("sparse_index") == r.ops.count("index_alignment") == 2
+    assert r.ops.count("stop_gradient") == 2 and r.ops.count("rotary_embedding") == 8 and r.ops.count("moe_experts") == 2
+    shapes = {n: r.before[n].shape for n in ("lm.l0.attn.q.w", "lm.l0.attn.k.w", "lm.l0.attn.index.q.w",
+                                            "lm.l0.attn.index.k.w", "lm.l0.attn.index.w.w", "lm.l0.attn.index.k_norm.b",
+                                            "lm.l1.moe.gate.w", "lm.l1.moe.router.w")}
+    assert shapes == {"lm.l0.attn.q.w": (64, 64), "lm.l0.attn.k.w": (64, 32), "lm.l0.attn.index.q.w": (64, 64),
+                      "lm.l0.attn.index.k.w": (64, 16), "lm.l0.attn.index.w.w": (64, 4), "lm.l0.attn.index.k_norm.b": (16,),
+                      "lm.l1.moe.gate.w": (2, 64, 32), "lm.l1.moe.router.w": (64, 8)}
+    block = r.main.global_block()
+    segments = [op.attrs.get("recompute_segment") for op in block.ops]
+    assert sorted(set(segments) - {None}) == [1, 2] and set(r.plain_segments) == {None}
+    # the alignment op stands AFTER its layer's segment: its forward pass makes its gradients too, once
+    after = [(op.type, op.attrs.get("recompute_segment")) for op in block.ops if op.type in ("index_alignment", "sparse_index")]
+    assert after == [("sparse_index", 1), ("index_alignment", None), ("sparse_index", 2), ("index_alignment", None)]
+    # the indexer stands in the scope `sparse_index`, its three ops read the DETACHED input, and the attention takes the picks
+    scoped = [op.type for op in block.ops if "sparse_index" in (op.attrs.get("op_namescope") or "")]
+    assert scoped.count("sparse_index") == scoped.count("index_alignment") == scoped.count("stop_gradient") == 2
+    assert scoped.count("layer_norm") == 2 and scoped.count("rotary_embedding") == 4 and "fused_attention" not in scoped
+    first = next(op for op in block.ops if op.type == "fused_attention")
+    choice = next(op for op in block.ops if op.type == "sparse_index")
+    assert first.inputs["Picks"] == choice.outputs["Picks"] and first.attrs["picks_topk"] == 8 and first.attrs["causal"]
+
+
+@pytest.mark.parametrize("name", PARAMS + INDEXER)
+def test_float32_gradient_and_adam_step_agree_with_the_reference(float32_run, name):
+    """Adam's first moment after one step is 0.1 x the gradient; the parameter
+    moves by the warm-up's first rate.  The main weights' gradient is the
+    language-model term's alone and the indexer's the alignment term's alone:
+    the top-k passes none, the indexer reads its input detached and the target
+    is a constant."""
+    r = float32_run
+    agree(r.moments[name] / (1 - 0.9), r.ref_grads[name], tol=2e-4)
+    mine, other = (r.index_grads, r.lm_grads) if name in INDEXER else (r.lm_grads, r.index_grads)
+    agree(r.moments[name] / (1 - 0.9), mine[name], tol=2e-4)
+    assert not other[name].any()
+    moved = np.abs(r.after[name] - r.before[name]).max()
+    assert 0.5e-6 < moved < 4e-6, moved
+
+
+@pytest.mark.parametrize("name", PARAMS + INDEXER)
+def test_a_recomputed_layers_gradient_is_the_plain_layers_to_the_last_bit(float32_run, name):
+    np.testing.assert_array_equal(float32_run.moments[name], float32_run.plain[1][name])
+
+
+def test_a_recomputed_segment_publishes_what_the_plain_layer_publishes_and_counts_what_it_lowered(float32_run):
+    r = float32_run
+    plain_loss, _, plain_records, plain_counters = r.plain
+    assert r.step_loss == plain_loss and len(r.records) == len(plain_records) == 1
+
+    def said(record):
+        return {k: v for k, v in record.items() if k not in ("ts", "step", "lane")}
+
+    assert said(r.records[0]) == said(plain_records[0])
+    record = r.records[0]
+    held = int(np.minimum(8, np.arange(32) + 1).sum())
+    assert record["picks"] == [4 * held] * 2 and record["queries"] == [4 * 32] * 2
+    assert record["picks_per_query"] == [held / 32] * 2 and record["chunk_pairs_touched_share"] == [1.0, 1.0]
+    assert all(0.3 < share <= 1.0 for share in record["recent_share"]) and all(kl > 0 for kl in record["index_kl"])
+    assert r.counters["lowering.sparse_index_ops"] == r.counters["lowering.selected_attention_ops"] == 2
+    assert r.counters["lowering.attention_xla"] == 2 and r.counters["lowering.recomputed_segments"] == 2
+    assert plain_counters["lowering.sparse_index_ops"] == 2 and not plain_counters.get("lowering.recomputed_segments")
+    # the CPU reports no memory limit, so the chip model's stands in and everything offered is kept: the picks among it
+    assert r.counters["lowering.recomputed_kept_bytes"] == r.counters["lowering.recomputed_candidates_bytes"] > 0
+
+
+def primitives_of(jaxpr, counted=None):
+    """{primitive: how often it stands in `jaxpr`, its sub-computations included}."""
+    counted = {} if counted is None else counted
+    for eqn in jaxpr.eqns:
+        counted[eqn.primitive.name] = counted.get(eqn.primitive.name, 0) + 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    primitives_of(inner, counted)
+    return counted
+
+
+def traced_step(run):
+    from paddle_tpu.core import executor as ex
+
+    scope = fluid.Scope()
+    for v in run.main.global_block().vars.values():
+        if v.persistable:
+            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
+    feeds = {n: jax.ShapeDtypeStruct((4, 32), np.int32) for n in keye.FEEDS}
+    step = ex._CompiledStep(run.main, list(feeds), [run.loss.name], scope, feed_shapes={n: s.shape for n, s in feeds.items()})
+
+    def as_shape(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype)
+
+    return step.jfn.trace({n: as_shape(scope.find_var(n)) for n in step.rw_names},
+                          {n: as_shape(scope.find_var(n)) for n in step.ro_names}, feeds, as_shape(jax.random.PRNGKey(0)))
+
+
+def test_the_choice_is_offered_as_one_that_must_be_kept_and_is_kept_on_a_full_chip(float32_run):
+    from paddle_tpu.core import resource_plan
+
+    block = float32_run.main.global_block()
+    ops = [op for op in block.ops if op.type not in ("feed", "fetch")]
+    shapes = resource_plan.ShapeEnv(float32_run.main, {n: (4, 32) for n in keye.FEEDS})
+    offered = lowering.kept_candidates(LoweringContext(jax.random.PRNGKey(0)), ops, shapes)
+    picks = [v for v in offered if v.must]
+    names = [op.outputs["Picks"][0] for op in ops if op.type == "sparse_index"]
+    assert [v.name for v in picks] == names and all(v.nbytes == 4 * 32 * 1 * 4 for v in picks)
+    # a budget that holds nothing keeps the choice and nothing else; one that holds everything, everything
+    assert lowering.choose_kept(offered, 0) == picks
+    assert lowering.choose_kept(offered, sum(v.nbytes for v in offered)) == offered
+    assert not any(v.must for v in offered if v.name not in names)
+
+
+@pytest.mark.parametrize("share", [0.5, 0.0], ids=["room-for-all", "a-full-chip"])
+def test_the_forward_made_again_reads_the_kept_choice_and_never_chooses_again(float32_run, share, monkeypatch):
+    """The traced step holds ONE `top_k` of the indexer a layer (and the
+    router's, forward and again): the second forward that backward makes has
+    none of the indexer's, whether the chip has room for the other candidates
+    or is full.  Without the rule that keeps the choice it would hold two a
+    layer: the test's own control."""
+    monkeypatch.setattr(lowering, "KEPT_SHARE", share)
+    kept = primitives_of(traced_step(float32_run).jaxpr.jaxpr)
+    monkeypatch.setattr(get_op_def("sparse_index"), "kept", None)
+    again = primitives_of(traced_step(float32_run).jaxpr.jaxpr)
+    assert again["top_k"] - kept["top_k"] == 2, (kept["top_k"], again["top_k"])
+
+
+SOUND = dict(routed_differently_above_margin=0, left_out=58, tokens=1000, logit_error_left_out=0.037, router_choice_differs=0,
+             router_prob_error=5.5e-6, experts_error=4.8e-3, index_kl_error=3e-5, picks_miscounted=0, picks_after_query=0,
+             picks_differ=0.0, picks_gap=0.0, attention_error=4.1e-3, alignment_error=2e-7, qk_error=1.21e-2, ce_error=2.2e-6,
+             logit_error=1.21e-2, reference_self_error=2e-7)
+
+
+@pytest.mark.parametrize("reading,limit", [
+    (dict(routed_differently_above_margin=1), "ROUTING_MARGIN"), (dict(left_out=265), "LEFT_OUT_MAX"),
+    (dict(logit_error_left_out=float("nan")), "LEFT_OUT_LOGIT_MAX"), (dict(router_choice_differs=5), "ROUTER_TIE"),
+    (dict(router_prob_error=1.3e-3), "ROUTER_RTOL"), (dict(experts_error=3.1e-2), "EXPERTS_RTOL"),
+    (dict(index_kl_error=0.165), "INDEX_KL_RTOL"), (dict(picks_miscounted=1), "picks_count"),
+    (dict(picks_after_query=1), "picks_count"), (dict(picks_differ=8.7e-4), "PICKS_DIFFER_MAX"),
+    (dict(picks_gap=1.5e-3), "PICKS_GAP_MAX"), (dict(attention_error=5.8e-2), "ATTENTION_RTOL"),
+    (dict(alignment_error=7.4e-6), "ALIGNMENT_RTOL"), (dict(qk_error=7.06e-2), "QK_RTOL"),
+    (dict(logit_error=7.25e-2), "REFERENCE_RTOL"), (dict(ce_error=float("nan")), "REFERENCE_RTOL"),
+    (dict(reference_self_error=2.17e-3), "REFERENCE_SELF_RTOL")])
+def test_every_limit_refuses_the_least_faulty_reading_the_chip_gave(reading, limit):
+    """`failed_limits` on the chip's sound readings (my chip runs, PR 56: the
+    most seen of each) names nothing, and with the least reading a fault gave
+    there (tools/chip_keye_controls.py) the limit that reading belongs to."""
+    assert keye.failed_limits(SOUND) == []
+    assert keye.failed_limits({**SOUND, **reading}) == [limit]
+
+
+def test_bfloat16_agrees_within_the_benchmarks_tolerances():
+    cfg, job, main, loss, names, scope, exe = tiny_model("bfloat16")
+    rows = keye.make_batch(np.random.RandomState(3), cfg, job, 8)
+    got = exe.run(main.clone(for_test=True), feed=rows, fetch_list=list(names), scope=scope)
+    want = reference_of(cfg, params_of(main, scope), rows)
+    found = keye.compare(got, want)
+    assert found["tokens"] == 8 * 32 and found["routed_differently_above_margin"] == 0
+    assert 1e-4 < found["logit_error"] < keye.REFERENCE_RTOL and found["ce_error"] < 1e-3
+    assert found["router_prob_error"] < keye.ROUTER_RTOL and found["experts_error"] < keye.EXPERTS_RTOL
+    assert found["picks_miscounted"] == found["picks_after_query"] == 0 and found["picks_gap"] <= keye.PICKS_GAP_MAX
+    assert found["picks_differ"] <= keye.PICKS_DIFFER_MAX                       # on its OWN bf16 operands the choice is float64's
+    assert found["attention_error"] < keye.ATTENTION_RTOL < found["attention_error_dense"]
+    assert found["alignment_error"] < keye.ALIGNMENT_RTOL and found["qk_error"] < keye.QK_RTOL
+    assert found["reference_self_error"] < keye.REFERENCE_SELF_RTOL
+
+
+def patched_attr(op, attrs):
+    return SimpleNamespace(type=op.type, attr=lambda n, d=None: attrs.get(n, op.attr(n, d)), input=op.input, output=op.output,
+                           inputs=op.inputs, outputs=op.outputs, attrs=op.attrs)
+
+
+@pytest.mark.parametrize("fault", ["half_the_picks", "a_key_after_the_query", "no_relu", "no_weights", "dense_attention",
+                                   "target_of_one_head", "plain_rotation_of_other_streams"])
+def test_the_reference_check_fails_on(fault, monkeypatch):
+    """A program that computes something else under the same names is not
+    correct: half the picks, a key after the query chosen, index scores without
+    the ReLU or without the weights, dense causal attention where the selected
+    one belongs, an alignment target of one head, another rotation."""
+    stage, limit = {"half_the_picks": ("picks_miscounted", 0), "a_key_after_the_query": ("picks_after_query", 0),
+                    "no_relu": ("picks_differ", keye.PICKS_DIFFER_MAX), "no_weights": ("picks_differ", keye.PICKS_DIFFER_MAX),
+                    "dense_attention": ("attention_error", keye.ATTENTION_RTOL),
+                    "target_of_one_head": ("alignment_error", keye.ALIGNMENT_RTOL),
+                    "plain_rotation_of_other_streams": ("qk_error", keye.QK_RTOL)}[fault]
+    if fault == "half_the_picks":
+        real = get_op_def("sparse_index").lower
+        monkeypatch.setattr(get_op_def("sparse_index"), "lower",
+                            lambda ctx, op, ins: real(ctx, patched_attr(op, {"topk": 4}), ins))
+    elif fault == "a_key_after_the_query":
+        real = sio.choose
+
+        def wrong(scores, first_query, topk):
+            return real(scores, first_query, topk).at[:, -1].set(True)       # every query holds the last key
+
+        monkeypatch.setattr(sio, "choose", wrong)
+    elif fault in ("no_relu", "no_weights"):
+        def wrong(qi, ki, w):
+            products = jnp.einsum("chd,kd->hck", qi, ki, preferred_element_type=jnp.float32)
+            products = products if fault == "no_relu" else jax.nn.relu(products)
+            return jnp.sum(products * (jnp.transpose(w)[:, :, None] if fault == "no_relu" else 1.0), axis=0)
+
+        real_select = sio._select_row       # the choice alone is faulty: the alignment term keeps the sound scores
+        monkeypatch.setattr(sio, "_select_row", lambda *a: _with(sio, "index_scores", wrong, real_select, *a))
+    elif fault == "dense_attention":
+        real = get_op_def("fused_attention").lower
+        monkeypatch.setattr(get_op_def("fused_attention"), "lower", lambda ctx, op, ins: {
+            **real(ctx, op, ins), "Out": real(ctx, op, {k: v for k, v in ins.items() if k != "Picks"})["Out"]})
+    elif fault == "target_of_one_head":
+        real = sio.attention_target
+        monkeypatch.setattr(sio, "attention_target", lambda q, k, lse, allowed, scale: real(q[:1], k[:1], lse[:1], allowed, scale))
+    else:
+        real = get_op_def("rotary_embedding").lower
+
+        def wrong(ctx, op, ins):       # the main attention's queries and keys turn by twice the position
+            if op.attr("layout", "bhld") == "bhld":
+                return real(ctx, op, {**ins, "Positions": [2 * ins["Positions"][0]]})
+            return real(ctx, op, ins)
+
+        monkeypatch.setattr(get_op_def("rotary_embedding"), "lower", wrong)
+    cfg, job, main, loss, names, scope, exe = tiny_model("float32")
+    for p in main.all_parameters():        # N(0, 0.02) keeps every score near 0 and the softmax flat: draw q, k larger
+        if p.name.endswith((".attn.q.w", ".attn.k.w", ".index.q.w", ".index.k.w", ".index.w.w")):
+            scope.set_var(p.name, jnp.asarray(np.asarray(scope.find_var(p.name)) * 10.0))
+    rows = keye.make_batch(np.random.RandomState(3), cfg, job, 8)
+    got = exe.run(main.clone(for_test=True), feed=rows, fetch_list=list(names), scope=scope)
+    want = reference_of(cfg, params_of(main, scope), rows)
+    found = keye.compare(got, want)
+    assert found[stage] > limit, found
+    assert not keye.reference_error(got, want) <= keye.REFERENCE_RTOL
+
+
+def _with(module, name, replacement, function, *args):
+    """`function(*args)` with `module.name` replaced while it is traced."""
+    real = getattr(module, name)
+    setattr(module, name, replacement)
+    try:
+        return function(*args)
+    finally:
+        setattr(module, name, real)
+
+
+def test_build_causal_lm_names_what_a_sparse_attention_layer_needs():
+    with pytest.raises(ValueError, match="sparse_index=dict"):
+        transformer.build_causal_lm(layer_types=["sparse_attention"], with_optimizer=False)
+    with pytest.raises(ValueError, match="sparse_attention"):
+        transformer.build_causal_lm(layer_types=["sparse_attentoin"], with_optimizer=False)
+    with pytest.raises(ValueError, match="the chosen keys are the layer's mask"):
+        transformer.build_causal_lm(layer_types=["sparse_attention"], sparse_index=dict(heads=2, head_dim=8, topk=4),
+                                    attention_mask=("block_diffusion", 4), with_optimizer=False)
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        with pytest.raises(ValueError, match="heads-major"):      # no per-head norm and no positions: the projections' layout
+            transformer.multi_head_attention(layers.data("x", [32, 64]), 32, 64, 4, "a", use_fused_attention=True,
+                                             sparse_index=dict(heads=2, head_dim=8, topk=4))
