@@ -9,8 +9,8 @@ index head w (B, L, Hi) float32,
 
 products in the operands' dtype into float32, the ReLU, the weights and the sum
 over the index heads in float32; query t then holds S_t, the min(topk, t + 1)
-keys of the largest I[t, .], the lower index first among equals (`lax.top_k`'s
-order).  What leaves the op is ONE tensor, `Picks` int32 (B, L, L / 32): bit j
+keys of the largest I[t, .], the lower index first among equals (a stable
+sort's order).  What leaves the op is ONE tensor, `Picks` int32 (B, L, L / 32): bit j
 of word w of query t is set where t holds key 32 w + j (`pack_bits`; 33.5 MB a
 row at 16384 tokens, where the picks as indices (B, L, 2048) int32 would be
 134 MB and a [L, L] byte mask 268 MB).  `fused_attention` takes it as its input
@@ -20,8 +20,14 @@ choice is whole numbers: no gradient passes it.
 The scores are made by query chunk (`CHUNK` queries against the keys up to
 their band's end, `BAND` queries a band, so that the work follows the causal
 triangle in steps): no [L, L] float32 array of a whole layer is in HBM.  The
-k-th largest of a row and its index are `lax.top_k`'s last column, and the
-chosen keys those above it, and those equal to it up to that index.
+chosen keys of a row are those above its `topk`-th largest score and, of those
+equal to it, the ones up to the index `last`.  Both are found by COUNTING, not
+by sorting the row (`ops/sparse_index_kernels.py`): the scores as whole numbers
+in the scores' order, the threshold a bit at a time by 32 passes that compare
+and count, then its equals: exact, ties included.  On the TPU, where a chunk is
+whole (8, 128) tiles, a Pallas kernel makes every pass on a block of rows held
+in VMEM (`select`); anywhere else the same function is plain `jax.numpy`
+(`kth_and_last`).  The platform and the shape choose, nothing else can.
 
 A `recompute_scope` round the layer KEEPS `Picks` (`registry.set_kept`, marked
 as one that must be kept whatever the room): the forward that backward makes
@@ -56,6 +62,7 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
+from . import sparse_index_kernels
 from .common import counted_rules, first
 
 #: Queries a chunk of the scores (`sa_config.q_chunk_size`, read as tiling) and queries a band: a band's chunks see the
@@ -111,9 +118,10 @@ def scaled_weights(w, heads: int, width: int):
     return w.astype(jnp.float32) * np.float32(heads ** -0.5 * width ** -0.5)
 
 
-def choose(scores, first_query, topk: int):
+def choose(scores, first_query, topk: int, select=sparse_index_kernels.kth_and_last):
     """bool [C, K]: the keys each of a chunk's queries (`first_query` on)
-    holds, from its float32 scores against keys 0 to K - 1."""
+    holds, from its float32 scores against keys 0 to K - 1; `select` finds a
+    row's threshold (one of `sparse_index_kernels`' two forms)."""
     chunk, keys = scores.shape
     at = first_query + jax.lax.broadcasted_iota(jnp.int32, (chunk, keys), 0)
     key = jax.lax.broadcasted_iota(jnp.int32, (chunk, keys), 1)
@@ -121,8 +129,7 @@ def choose(scores, first_query, topk: int):
     if keys <= topk:
         return causal
     masked = jnp.where(causal, scores, -jnp.inf)
-    values, indices = jax.lax.top_k(masked, topk)
-    kth, last = values[:, -1:], indices[:, -1:]
+    kth, last = select(masked, topk)
     return causal & ((masked > kth) | ((masked == kth) & (key <= last)))
 
 
@@ -137,7 +144,7 @@ def _by_chunk(length: int, body):
     return jax.tree.map(lambda *parts: jnp.concatenate(parts, axis=0), *found)
 
 
-def _select_row(qi, ki, w, topk: int):
+def _select_row(qi, ki, w, topk: int, select=sparse_index_kernels.kth_and_last):
     """One row: (picks int32 [L, L / 32], `Stats`' five counts of the row)."""
     length = qi.shape[0]
     chunk = chunking(length)[0]
@@ -145,7 +152,7 @@ def _select_row(qi, ki, w, topk: int):
     def body(start, keys):
         scores = index_scores(jax.lax.dynamic_slice_in_dim(qi, start, chunk, 0), ki[:keys],
                               jax.lax.dynamic_slice_in_dim(w, start, chunk, 0))
-        chosen = choose(scores, start, topk)
+        chosen = choose(scores, start, topk, select)
         at = start + jax.lax.broadcasted_iota(jnp.int32, chosen.shape, 0)
         recent = chosen & (jax.lax.broadcasted_iota(jnp.int32, chosen.shape, 1) > at - topk)
         touched = jnp.any(chosen.reshape(chunk, keys // chunk, chunk), axis=(0, 2))
@@ -169,9 +176,15 @@ def _sparse_index(ctx, op, ins):
     qi, ki, w = first(ins, "QI"), first(ins, "KI"), first(ins, "W")
     ki = ki.reshape(ki.shape[0], ki.shape[1], ki.shape[-1])
     w = scaled_weights(jax.lax.stop_gradient(w), qi.shape[2], qi.shape[3])
+    topk = op.attr("topk")
+    chunk, bands = chunking(qi.shape[1])
+    selecting = [keys for _, keys in bands if keys > topk]       # the bands whose chunks have a threshold to find
+    kernel = ctx.platform == "tpu" and bool(selecting) and all(sparse_index_kernels.fits(chunk, keys) for keys in selecting)
     _MON.counter("lowering.sparse_index_ops").inc()
+    _MON.counter("lowering.index_select_kernel_calls").inc(1 if kernel else 0)
+    select = sparse_index_kernels.select if kernel else sparse_index_kernels.kth_and_last
     with jax.named_scope("index_select"):
-        picks, stats = jax.lax.map(lambda row: _select_row(*row, op.attr("topk")),
+        picks, stats = jax.lax.map(lambda row: _select_row(*row, topk, select),
                                    (jax.lax.stop_gradient(qi), jax.lax.stop_gradient(ki), w))
     return {"Picks": picks, "Stats": jnp.sum(stats, axis=0)}
 
@@ -373,8 +386,11 @@ def _triangle(length: int) -> float:
 
 def _cost_sparse_index(ctx):
     """The triangle's scores, Hi heads of Di, 2 per multiply-add, and a ReLU,
-    a weight and a sum a head and pair; the choosing is compares over the same
-    pairs.  Traffic: the op's own operands and the picks."""
+    a weight and a sum a head and pair.  The choosing is compares over the same
+    pairs, 34 a pair where no scores tie (`sparse_index_kernels`: 32 counting
+    passes for the threshold's bits, two for its equals): 1.6% of the scores'
+    2096 operations a pair at 16 heads of 64, which the row leaves out.
+    Traffic: the op's own operands and the picks."""
     qi = ctx.in_shape("QI")
     if qi is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()

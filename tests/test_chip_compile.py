@@ -945,6 +945,22 @@ def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):
     assert ma.selected_plan(16384, 32).sizes.block_q == 512 and ma.selected_plan(16384, 32).sizes.block_kv == 1024
 
 
+@pytest.mark.parametrize("keys", [4096, 16384])
+def test_the_indexers_choice_of_a_chunk_compiles_to_the_counting_kernel_with_no_sort(keys, chip):
+    """`sparse_index_ops.choose` as the op calls it on the TPU, on a chunk of
+    Keye-VL-2.0's cell, [512, keys] float32 scores, 2048 picks a query: the
+    kernel that counts (`ops/sparse_index_kernels.py: select`; its block of 2 MB
+    of scores twice and of keys once fits the scoped VMEM) and no sort of the
+    row (`lax.top_k` was one, 399 ms a step: PERF.md, PR 57)."""
+    from paddle_tpu.ops import sparse_index_kernels as sik
+    from paddle_tpu.ops import sparse_index_ops as sio
+
+    scores = jax.ShapeDtypeStruct((512, keys), F32, sharding=chip)
+    text = jax.jit(lambda s: sio.pack_bits(sio.choose(s, keys - 512, 2048, sik.select))).lower(scores).compile().as_text()
+    assert "kth_by_counting" in text and "tpu_custom_call" in text
+    assert " sort(" not in text and "TopK" not in text and " while(" not in text
+
+
 @pytest.mark.slow   # two compiles, ~115 and ~80 s on every core: run by name (`-m slow`); PERF.md, PR 56, has their readings
 def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
     """`keye-vl-2.0-30b-a3b.train-dsa-s16384`'s whole step at the published

@@ -2,8 +2,11 @@
 
 (a) `sparse_index`: every query holds min(topk, t + 1) keys, none after itself,
     equals broken by the lower index, over one chunk and over several chunks
-    and bands; the picks as bits; the `infer=` rules, the planner rows,
-    `analysis.verify`;
+    and bands; the threshold found by counting (`ops/sparse_index_kernels.py`,
+    plain and the kernel interpreted) against a stable sort, the op's picks
+    against a `lax.top_k` oracle's, word for word, and which form the platform
+    and the shape choose; the picks as bits; the `infer=` rules, the planner
+    rows, `analysis.verify`;
 (b) `fused_attention(picks=)`: with topk >= the length the selected attention
     IS the causal one, to the last bit; its gradient is dense attention's under
     the same fixed mask; the splash kernels on block maps made from the picks
@@ -24,6 +27,7 @@
 
 One compiled tiny model serves (f): `float32_run`.
 """
+import functools
 import os
 import sys
 from types import SimpleNamespace
@@ -44,6 +48,7 @@ from paddle_tpu.core import lowering  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import sparse_index_kernels as sik  # noqa: E402
 from paddle_tpu.ops import sparse_index_ops as sio  # noqa: E402
 
 
@@ -126,6 +131,118 @@ def test_equal_scores_are_broken_by_the_lower_index():
     assert picks[31].nonzero()[0].tolist() == [0, 1, 2, 3, 20, 21, 22, 23]
     assert picks[21].nonzero()[0].tolist() == [0, 1, 2, 3, 4, 5, 20, 21]
     assert picks[12].nonzero()[0].tolist() == list(range(8)) and picks[5].nonzero()[0].tolist() == list(range(6))
+
+
+def _rows_of(case, rng, rows, keys, topk):
+    """float32 [rows, keys] scores of one kind of trouble."""
+    x = rng.randn(rows, keys).astype("f4")
+    if case == "a-whole-row-tied":
+        x[:] = 0.0
+        x[1] = -3.5
+    elif case == "ties-straddle-the-threshold":     # a row's threshold value stands topk / 2 times before it and as often after
+        for row in x:
+            at = np.argsort(-row, kind="stable")
+            row[at[topk // 2:topk + topk // 2]] = row[at[topk - 1]]
+        x[0, rng.permutation(keys)[:keys // 2]] = x[0].max() + 1.0        # ... and above it, where nothing is counted
+    elif case == "both-zeros":                      # -0.0 and +0.0 are one value: the lower INDEX is first, whichever zero
+        x = np.where(rng.rand(rows, keys) < 0.5, np.float32(-0.0), np.float32(0.0))
+        x[::2, ::5] = rng.randn(*x[::2, ::5].shape).astype("f4")
+    elif case == "beyond-the-causal-edge":          # row r sees keys 0 .. 3 r + 2: fewer than topk in the first rows
+        x = np.where(np.arange(keys)[None, :] <= 3 * np.arange(rows)[:, None] + 2, x, -np.inf).astype("f4")
+    return x
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("rows,keys,topk", [(8, 256, 48), (16, 128, 127), (24, 1024, 200)],
+                         ids=["48-of-256", "all-but-one-of-128", "200-of-1024"])
+@pytest.mark.parametrize("case", ["no-ties", "a-whole-row-tied", "ties-straddle-the-threshold", "both-zeros",
+                                  "beyond-the-causal-edge"])
+def test_the_threshold_by_counting_is_a_stable_sorts(case, rows, keys, topk, form):
+    """Both forms against numpy's stable sort of the row, descending: the value
+    at place `topk` and that place's index (the kernel interpreted here)."""
+    x = _rows_of(case, np.random.RandomState(57), rows, keys, topk)
+    place = np.argsort(-x, -1, kind="stable")[:, topk - 1:topk]       # -x keeps -0.0 and +0.0 equal, as `>` and `==` do
+    assert sik.fits(rows, keys)
+    select = jax.jit(sik.kth_and_last, static_argnums=1) if form == "plain" else functools.partial(sik.select, interpret=True)
+    kth, last = (np.asarray(t) for t in select(jnp.asarray(x), topk))
+    assert kth.dtype == np.float32 and last.dtype == np.int32 and kth.shape == last.shape == (rows, 1)
+    np.testing.assert_array_equal(last, place)
+    np.testing.assert_array_equal(kth, np.take_along_axis(x, place, -1))     # equal as VALUES: either zero is the zero
+
+
+def test_the_plain_form_takes_any_shape_and_the_kernel_whole_tiles():
+    x = np.random.RandomState(3).randn(5, 37).astype("f4")
+    kth, last = sik.kth_and_last(jnp.asarray(x), 36)
+    place = np.argsort(-x, -1, kind="stable")[:, 35:36]
+    np.testing.assert_array_equal(np.asarray(last), place)
+    np.testing.assert_array_equal(np.asarray(kth), np.take_along_axis(x, place, -1))
+    assert not sik.fits(5, 128) and not sik.fits(8, 37) and sik.fits(512, 16384) and not sik.fits(8, 2 ** 17)
+    assert [sik._rows(512, keys) for keys in (4096, 8192, 16384, 65536)] == [128, 64, 32, 8]
+
+
+def test_the_keys_are_whole_numbers_in_the_scores_order():
+    x = np.array([-np.inf, -3.0e38, -1.5, -1e-30, -0.0, 0.0, 1e-30, 2.5, 3.0e38, np.inf], "f4")
+    keys = np.asarray(sik.ordered(jnp.asarray(x)))
+    assert keys.dtype == np.int32 and (np.diff(keys.astype("i8")) > 0).sum() == len(x) - 2 and keys[4] == keys[5]
+    back = np.asarray(sik.score_of(jnp.asarray(keys)))
+    np.testing.assert_array_equal(back, x)                 # -0.0 comes back as +0.0: equal as values
+    assert not np.signbit(back[4])
+
+
+def lowering_counters(lowered):
+    """(what `lowered()` returns, the `lowering.` counters it moved)."""
+    monitor.reset()
+    monitor.enable()
+    try:
+        return lowered(), {k: v for k, v in monitor.get_monitor().counter_values().items() if k.startswith("lowering.")}
+    finally:
+        monitor.disable()
+        monitor.reset()
+
+
+def _top_k_oracle(masked, topk):
+    """What `choose` read before it counted: the last column of `lax.top_k`."""
+    values, indices = jax.lax.top_k(masked, topk)
+    return values[:, -1:], indices[:, -1:]
+
+
+@pytest.mark.parametrize("length,topk,heads,width", [(32, 8, 4, 16), (1024, 48, 2, 8), (4096, 96, 2, 8)],
+                         ids=["one-chunk", "two-chunks", "two-bands-of-four-chunks"])
+def test_the_picks_are_a_top_k_oracles_word_for_word(length, topk, heads, width, monkeypatch):
+    rng = np.random.RandomState(57)
+    qi, ki, w = indexer_operands(rng, 2, length, heads, width)
+    ki[0, length // 2:length // 2 + 40] = ki[0, 3]          # equal keys score equal: ties at every query after them
+    qi[1, :, :, :] = np.abs(qi[1])
+    ki[1, ::3] = -np.abs(ki[1, ::3])                        # a third of a row's scores exactly 0
+    mine, counted = lowering_counters(lambda: lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk}))
+    assert counted["lowering.sparse_index_ops"] == 1 and counted["lowering.index_select_kernel_calls"] == 0    # the CPU
+    monkeypatch.setattr(sik, "kth_and_last", _top_k_oracle)
+    theirs = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": topk})
+    np.testing.assert_array_equal(np.asarray(mine["Picks"]), np.asarray(theirs["Picks"]))
+    np.testing.assert_array_equal(np.asarray(mine["Stats"]), np.asarray(theirs["Stats"]))
+
+
+def test_on_the_tpu_the_select_goes_to_the_kernel_where_the_chunks_are_whole_tiles(monkeypatch):
+    """What `_sparse_index` hands `_select_row`, by the platform and the shape
+    alone, and what `lowering.index_select_kernel_calls` counts."""
+    def seen(qi, ki, w, topk, select):
+        chosen.append(select)
+        return jnp.zeros((qi.shape[0], qi.shape[0] // 32), jnp.int32), jnp.zeros((5,), jnp.int32)
+
+    def selects(platform, length, topk):
+        op = SimpleNamespace(type="sparse_index", attr=lambda n, d=None: {"topk": topk}.get(n, d))
+        ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
+        ins = {k: [jnp.asarray(v)] for k, v in zip(("QI", "KI", "W"), indexer_operands(np.random.RandomState(0), 1, length, 2, 8))}
+        _, counted = lowering_counters(lambda: get_op_def("sparse_index").lower(ctx, op, ins))
+        return chosen[-1], counted["lowering.index_select_kernel_calls"]
+
+    chosen = []
+    monkeypatch.setattr(sio, "_select_row", seen)
+    assert selects("tpu", 4096, 96) == (sik.select, 1)
+    assert selects("tpu", 1024, 48) == (sik.select, 1)               # one band, two chunks of 512 x 1024 keys
+    assert selects("cpu", 4096, 96) == (sik.kth_and_last, 0)
+    assert selects("tpu", 96, 8) == (sik.kth_and_last, 0)            # one chunk of 96 keys: no whole tile
+    assert selects("tpu", 1024, 1024) == (sik.kth_and_last, 0)       # nothing to select: every causal key is held
 
 
 def test_the_picks_are_words_of_32_keys_bits():
@@ -609,16 +726,19 @@ def test_the_choice_is_offered_as_one_that_must_be_kept_and_is_kept_on_a_full_ch
 
 @pytest.mark.parametrize("share", [0.5, 0.0], ids=["room-for-all", "a-full-chip"])
 def test_the_forward_made_again_reads_the_kept_choice_and_never_chooses_again(float32_run, share, monkeypatch):
-    """The traced step holds ONE `top_k` of the indexer a layer (and the
-    router's, forward and again): the second forward that backward makes has
-    none of the indexer's, whether the chip has room for the other candidates
+    """The traced step holds ONE select of the indexer a layer (its marker is
+    the `cond` by which `sparse_index_kernels._search` asks whether a row's
+    equals are all held; the chip's kernel holds it too): the second forward that
+    backward makes has none, whether the chip has room for the other candidates
     or is full.  Without the rule that keeps the choice it would hold two a
-    layer: the test's own control."""
+    layer: the test's own control.  The router's `top_k`s (forward and again)
+    are as many either way, and the indexer has none."""
     monkeypatch.setattr(lowering, "KEPT_SHARE", share)
     kept = primitives_of(traced_step(float32_run).jaxpr.jaxpr)
     monkeypatch.setattr(get_op_def("sparse_index"), "kept", None)
     again = primitives_of(traced_step(float32_run).jaxpr.jaxpr)
-    assert again["top_k"] - kept["top_k"] == 2, (kept["top_k"], again["top_k"])
+    assert again["cond"] - kept["cond"] == 2, (kept["cond"], again["cond"])
+    assert again["top_k"] == kept["top_k"] == 4, (kept["top_k"], again["top_k"])       # two sparse layers' routers, twice
 
 
 SOUND = dict(routed_differently_above_margin=0, left_out=58, tokens=1000, logit_error_left_out=0.037, router_choice_differs=0,
@@ -666,15 +786,17 @@ def patched_attr(op, attrs):
                            inputs=op.inputs, outputs=op.outputs, attrs=op.attrs)
 
 
-@pytest.mark.parametrize("fault", ["half_the_picks", "a_key_after_the_query", "no_relu", "no_weights", "dense_attention",
-                                   "target_of_one_head", "plain_rotation_of_other_streams"])
+@pytest.mark.parametrize("fault", ["half_the_picks", "a_key_after_the_query", "no_relu", "no_weights", "threshold_from_16_bits",
+                                   "dense_attention", "target_of_one_head", "plain_rotation_of_other_streams"])
 def test_the_reference_check_fails_on(fault, monkeypatch):
     """A program that computes something else under the same names is not
     correct: half the picks, a key after the query chosen, index scores without
-    the ReLU or without the weights, dense causal attention where the selected
-    one belongs, an alignment target of one head, another rotation."""
+    the ReLU or without the weights, a threshold from the scores' upper 16 bits,
+    dense causal attention where the selected one belongs, an alignment target
+    of one head, another rotation."""
     stage, limit = {"half_the_picks": ("picks_miscounted", 0), "a_key_after_the_query": ("picks_after_query", 0),
                     "no_relu": ("picks_differ", keye.PICKS_DIFFER_MAX), "no_weights": ("picks_differ", keye.PICKS_DIFFER_MAX),
+                    "threshold_from_16_bits": ("picks_differ", keye.PICKS_DIFFER_MAX),
                     "dense_attention": ("attention_error", keye.ATTENTION_RTOL),
                     "target_of_one_head": ("alignment_error", keye.ALIGNMENT_RTOL),
                     "plain_rotation_of_other_streams": ("qk_error", keye.QK_RTOL)}[fault]
@@ -685,10 +807,18 @@ def test_the_reference_check_fails_on(fault, monkeypatch):
     elif fault == "a_key_after_the_query":
         real = sio.choose
 
-        def wrong(scores, first_query, topk):
-            return real(scores, first_query, topk).at[:, -1].set(True)       # every query holds the last key
+        def wrong(scores, first_query, topk, select):
+            return real(scores, first_query, topk, select).at[:, -1].set(True)       # every query holds the last key
 
         monkeypatch.setattr(sio, "choose", wrong)
+    elif fault == "threshold_from_16_bits":
+        real = sio.choose
+
+        def upper_half(masked, topk):       # bf16's order of the scores: the float32 threshold's place among them is lost
+            bits = jax.lax.bitcast_convert_type(masked, jnp.int32) & jnp.int32(-65536)
+            return sik.kth_and_last(jax.lax.bitcast_convert_type(bits, jnp.float32), topk)
+
+        monkeypatch.setattr(sio, "choose", lambda scores, first_query, topk, select: real(scores, first_query, topk, upper_half))
     elif fault in ("no_relu", "no_weights"):
         def wrong(qi, ki, w):
             products = jnp.einsum("chd,kd->hck", qi, ki, preferred_element_type=jnp.float32)

@@ -2,7 +2,7 @@
 harness's own `benchmark.models.keye.compare` / `failed_limits` on the program's
 check rows against the float32 reference, sound and then with a fault put in,
 one at a time, so that each limit this PR brings has a reading it must refuse
-beside the sound one (PERF.md, section 6, PR 56).  Eight faults go into THE
+beside the sound one (PERF.md, section 6, PRs 56 and 57).  Nine faults go into THE
 PROGRAM (a lowering or a seam of `ops/sparse_index_ops.py` is wrapped and the
 check rows run again through a new executor), two are read on the program's own
 fetched tensors of the stage row, one is the reference a precision lower:
@@ -15,6 +15,10 @@ fetched tensors of the stage row, one is the reference a precision lower:
     `PICKS_GAP_MAX`;
   * `index_scores_in_bf16`: each index head's products rounded to bf16 before
     the ReLU, the weights and the sum: `PICKS_DIFFER_MAX`, `PICKS_GAP_MAX`;
+  * `threshold_from_16_bits` (PR 57): the select made WRONG: a row's threshold
+    and `last` from scores whose low 16 bits are dropped (bf16's order; the
+    picks then compare the float32 scores with it): `PICKS_DIFFER_MAX`,
+    `PICKS_GAP_MAX`, `picks_count`;
   * `dense_attention`: dense causal attention where the selected one belongs:
     `ATTENTION_RTOL`.  At the cell's size its 8-row clone holds BOTH attentions
     (the selected one still makes the log-sum-exp) and does not load beside the
@@ -32,7 +36,7 @@ fetched tensors of the stage row, one is the reference a precision lower:
     bf16, and how far the attention's dq under the second mask lies from dq
     under the first (`gradient_change`).  The sound program reads 0 and 0.0: the
     choice is KEPT (`registry.set_kept`, must), and tests/test_keye.py holds the
-    traced step to one `top_k` a layer;
+    traced step to one select a layer;
   * `target_not_detached`: the alignment term's gradient to the attention's
     queries, on the stage row's first chunk: the op's own is 0.0 exactly (its
     backward rule returns none), the same term differentiated WITHOUT the
@@ -124,8 +128,14 @@ def faults(cfg):
     def halved(real, ctx, op, ins):
         return real(ctx, with_attrs(op, topk=cfg["sa_config"]["topk"] // 2), ins)
 
-    def after(real, scores, first_query, topk):
-        return real(scores, first_query, topk).at[:, -1].set(True)
+    def after(real, scores, first_query, topk, select):
+        return real(scores, first_query, topk, select).at[:, -1].set(True)
+
+    def from_16_bits(real, scores, first_query, topk, select):
+        def upper_half(masked, topk):
+            bits = jax.lax.bitcast_convert_type(masked, jnp.int32) & jnp.int32(-65536)
+            return select(jax.lax.bitcast_convert_type(bits, jnp.float32), topk)
+        return real(scores, first_query, topk, upper_half)
 
     def dense(real, ctx, op, ins):
         return {**real(ctx, op, ins), "Out": real(ctx, op, {k: v for k, v in ins.items() if k != "Picks"})["Out"]}
@@ -139,6 +149,7 @@ def faults(cfg):
         "no_relu": scores_of("no_relu"),
         "no_weights": scores_of("no_weights"),
         "index_scores_in_bf16": scores_of("index_scores_in_bf16"),
+        "threshold_from_16_bits": lambda: seam("choose", from_16_bits),
         "dense_attention": lambda: lowered_as("fused_attention", dense),
         "alignment_scores_in_bf16": scores_of("index_scores_in_bf16", "_alignment_row"),
         "target_of_one_head": lambda: seam("attention_target", one_head),
