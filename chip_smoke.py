@@ -16,12 +16,6 @@ One process, through the entry points a user calls (`fluid.Program` ->
                  io.save_inference_model and served by serving.Server over
                  buckets (1, 8): sizes that hit and that pad to a bucket
                  agree with a direct Executor run, no compile after warm.
-  kernels        the BERT program cut to 2 layers, the same steps with
-                 default flags and with FLAGS_use_pallas: the loss series
-                 agree to the parity tests' bf16 tolerance, and the Mosaic
-                 kernels found in each compiled step are named.  Then every
-                 registered fused kernel alone, compiled, against its
-                 composite.
   host callback  a small TPUPlace program with a py_func op in mid-graph:
                  jax.pure_callback works on this runtime.
   --chips 4      BERT-base widths on a dp=2 x tp=2 mesh
@@ -51,8 +45,8 @@ import numpy as np
 
 PLATFORM = "tpu"
 SEED = 21
-# tests/test_pallas_kernels.py's bf16 tolerance (ln_residual, softmax_xent,
-# bias_act): what two bf16 formulations of the same step may differ by
+# what two bf16 formulations of the same step may differ by (a bucket's
+# padding, one chip against the 2x2 mesh)
 BF16_TOL = 5e-2
 
 BERT_BASE = dict(vocab_size=30522, seq_len=128, d_model=768, n_layers=12,
@@ -358,115 +352,6 @@ def phase_server(depth: int = 50, image: int = 224, class_dim: int = 1000,
 
 
 # --------------------------------------------------------------------------
-# kernels
-# --------------------------------------------------------------------------
-
-
-def phase_kernels(bert: dict = BERT_BASE_2L, batch: int = 32,
-                  steps: int = 4) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.ops import pallas_kernels as pk
-
-    say(f"kernels: BERT {bert} (depth CUT to {bert['n_layers']} layers: "
-        f"two arms share one compile budget), batch {batch}, {steps} steps, "
-        f"default flags against FLAGS_use_pallas")
-    main, loss, scope, exe = start_bert(bert, dropout=0.1)
-    start = host_copy(scope)
-    feed = bert_feed(bert, batch, 1)
-
-    # off the chip (the CPU rehearsal) use_pallas keeps the composite and
-    # the registry kernels below run in Pallas interpret mode
-    compiled_kernels = PLATFORM == "tpu"
-    series = {}
-    for arm, flag in (("composite", False), ("pallas", True)):
-        restore(scope, start)
-        fluid.set_flags({"FLAGS_use_pallas": flag})
-        try:
-            losses, _ = run_steps(exe, main, feed, loss, scope, 1, steps)
-        finally:
-            fluid.set_flags({"FLAGS_use_pallas": False})
-        series[arm] = losses.reshape(-1)
-        found = kernels_in(exe)  # both arms' executables by now
-        say(f"kernels: {arm}: loss {np.round(series[arm], 4).tolist()}, "
-            f"kernels in the compiled step: {found or 'none'}")
-        if arm == "composite" and found:
-            raise AssertionError(f"kernels: default flags compiled {found}")
-    if not np.isfinite(series["pallas"]).all():
-        raise AssertionError("kernels: non-finite loss with FLAGS_use_pallas")
-    err = float(np.abs(series["pallas"] - series["composite"]).max())
-    if not err <= BF16_TOL:
-        raise AssertionError(
-            f"kernels: loss series differ by {err:.3e} > {BF16_TOL}")
-    if compiled_kernels:
-        missing = {"ln_residual_fwd", "ln_residual_bwd", "softmax_xent_fwd",
-                   "softmax_xent_bwd", "adam_slab"} - set(found)
-        if missing:
-            raise AssertionError(
-                f"kernels: the FLAGS_use_pallas step ran the composite for "
-                f"{sorted(missing)}")
-        n_adam = sum(op.type == "adam" for op in main.global_block().ops)
-        say(f"kernels: loss series agree to {err:.2e} (tolerance "
-            f"{BF16_TOL}); adam_slab on {found['adam_slab']} of {n_adam} "
-            f"parameters, the rest keep the composite (element count not a "
-            f"multiple of {pk._ADAM_LANE})")
-    exe.close()
-    interpret = not compiled_kernels
-
-    # every registered kernel alone, compiled, forward and gradient,
-    # against its composite at the parity tests' tolerances; one run names
-    # every mismatch
-    bad = []
-    for name in pk.registered_fused_kernels():
-        spec = pk.FUSED_KERNELS[name]
-        for dtype in ("float32", "bfloat16"):
-            args = spec["example"](jnp.dtype(dtype))
-            got = jax.jit(lambda a: spec["fused"](a, interpret=interpret))(args)
-            want = spec["reference"](args)
-            err = max(float(jnp.max(jnp.abs(g.astype(jnp.float32)
-                                            - w.astype(jnp.float32))))
-                      for g, w in zip(jax.tree.leaves(got),
-                                      jax.tree.leaves(want)))
-            if not err <= spec["tol"][dtype]:
-                bad.append(f"{name} {dtype} forward: {err:.3e} > "
-                           f"{spec['tol'][dtype]}")
-        argnums = tuple(i for i in spec["grad_argnums"])
-        if argnums:
-            args = spec["example"](jnp.float32)
-
-            def loss(fn):
-                return lambda *a: jnp.sum(jnp.square(fn(a).astype(jnp.float32)))
-
-            gf = jax.jit(jax.grad(loss(
-                lambda a: spec["fused"](a, interpret=interpret)),
-                argnums=argnums))(*args)
-            gr = jax.grad(loss(spec["reference"]), argnums=argnums)(*args)
-            for i, (a, b) in enumerate(zip(gf, gr)):
-                err = float(jnp.max(jnp.abs(a - b)))
-                tol = 1e-4 * (1.0 + float(jnp.max(jnp.abs(b))))
-                if not err <= tol:
-                    bad.append(f"{name} d(arg{i}): {err:.3e} > {tol:.1e}")
-    # the ragged last slab of the Adam kernel (the BERT embedding's shape
-    # class: no aligned slab divides its rows)
-    args = pk._adam_example(jnp.float32, shape=(30522, 128))
-    got = jax.jit(lambda a: pk.fused_adam(*a, 1e-3, 0.9, 0.999, 1e-8,
-                                          interpret))(args)
-    want = pk._adam_reference(*args)
-    err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
-    if not err <= pk.FUSED_KERNELS["adam_slab"]["tol"]["float32"]:
-        bad.append(f"adam_slab with a ragged last slab: {err:.3e}")
-    if bad:
-        raise AssertionError(
-            f"kernels: differ from their composites: {'; '.join(bad)}")
-    say(f"kernels: {pk.registered_fused_kernels()} alone, "
-        f"{'compiled' if compiled_kernels else 'interpreted'}: forward "
-        f"f32+bf16 and f32 gradients match the composites, the ragged "
-        f"last Adam slab included")
-
-
-# --------------------------------------------------------------------------
 # host callback
 # --------------------------------------------------------------------------
 
@@ -591,8 +476,7 @@ def main(argv=None) -> None:
 
     t0 = time.perf_counter()
     phases = ([phase_mesh] if args.chips == 4 else
-              [phase_trainer, phase_server, phase_kernels,
-               phase_host_callback])
+              [phase_trainer, phase_server, phase_host_callback])
     for phase in phases:
         t1 = time.perf_counter()
         phase()

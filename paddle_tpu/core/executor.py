@@ -113,17 +113,6 @@ def _runnable_ops(block):
     return [op for op in block.ops if op.type not in ("feed", "fetch")]
 
 
-def _lowering_flags():
-    """The process-global choice that changes generated code, for the
-    compile-cache key: toggling it must not reuse a stale executable.  One is
-    left, `FLAGS_use_pallas`: 61 tier-1 cases stand on its five kernels, so
-    it goes with a PR that brings their replacements (ROADMAP D2).  The ops'
-    lowerings read nothing else a process can set."""
-    from ..flags import flag as _flagv
-
-    return ("pallas", bool(_flagv("FLAGS_use_pallas")))
-
-
 def _structure_digest(program: Program, *more) -> str:
     """Eight hex digits of the program's STRUCTURE (op types and argument
     names, every block) and whatever else the caller adds.  The same in
@@ -1056,7 +1045,6 @@ class Executor:
             remat,
             local_sgd_every,
             grad_overlap,
-            _lowering_flags(),
         )
         # the bookkeeping lock covers only the dict operations: a HIT (the
         # serving steady state) never waits behind a concurrent miss's

@@ -61,10 +61,9 @@ class LoweringContext:
         # _run_one_backward_region applies it to the assembled grads so the
         # optimizer segment consumes globally-reduced gradients
         self.grad_sync = None
-        # fetch targets of the step being traced (set by the executor):
-        # lowerings that can skip optional output slots on a fused path
-        # (e.g. layer_norm Mean/Variance under FLAGS_use_pallas) consult
-        # this so a fetched slot keeps the composite that populates it
+        # fetch targets of the step being traced (set by the executor): a
+        # lowering that fuses a chain of ops (ops/latent_operands.py: plan)
+        # leaves a chain alone whose inner values are fetched
         self.fetch_names = ()
         # BuildStrategy.memory_optimize: rematerialize the forward during
         # backward (jax.checkpoint) instead of keeping activations

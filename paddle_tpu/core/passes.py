@@ -5,15 +5,18 @@ Reference: framework/ir/ — `ir::Graph` + `Pass` registry + ~60 passes
 
 TPU-first: XLA owns fusion/layout/scheduling, so the reference's kernel-
 fusion passes have no residue to produce — the passes that REMAIN useful
-are program-level rewrites ahead of lowering: dead-op pruning, identity
-elimination, algebraic folds, and structural rewrites (PipelineOptimizer's
-stage cut is morally one of these).  The IR the passes walk is the Program
-itself (op/var lists) — the redesign collapsed the separate ir::Graph; a
-pass is any callable Program -> None mutating in place.
+are program-level rewrites ahead of lowering that mean the same on every
+backend: dead-op pruning, identity elimination, algebraic folds, and
+structural rewrites (PipelineOptimizer's stage cut is morally one of
+these).  A chain of ops that one kernel should take is fused at LOWERING
+time (ops/latent_operands.py: plan), where the fetch targets, the kept
+values and the mesh are visible; no pass here rewrites for a kernel.  The
+IR the passes walk is the Program itself (op/var lists) — the redesign
+collapsed the separate ir::Graph; a pass is any callable Program -> None
+mutating in place.
 """
 from __future__ import annotations
 
-import bisect
 from typing import Callable, Dict, List, Optional, Sequence
 
 _PASS_REGISTRY: Dict[str, Callable] = {}
@@ -172,43 +175,11 @@ def fold_scale_chains(program):
     program._bump()
 
 
-def _reader_counts(block):
-    """name -> number of ops in `block` reading it."""
-    counts: Dict[str, int] = {}
-    for op in block.ops:
-        for n in op.input_arg_names:
-            counts[n] = counts.get(n, 0) + 1
-    return counts
-
-
-def _rw_positions(block):
-    """(writes, reads): name -> ascending list of op indices writing/reading
-    it — fuels the O(log n) intervening-access hazard checks below."""
-    writes: Dict[str, list] = {}
-    reads: Dict[str, list] = {}
-    for i, op in enumerate(block.ops):
-        for n in op.input_arg_names:
-            reads.setdefault(n, []).append(i)
-        for n in op.output_arg_names:
-            writes.setdefault(n, []).append(i)
-    return writes, reads
-
-
-def _accessed_between(positions, name, lo, hi):
-    """True if `name` appears in `positions` at an op index strictly between
-    lo and hi (exclusive both ends)."""
-    idxs = positions.get(name)
-    if not idxs:
-        return False
-    j = bisect.bisect_right(idxs, lo)
-    return j < len(idxs) and idxs[j] < hi
-
-
 def _outside_reads(program):
     """Per-block sets of names read by any op OUTSIDE that block (sub-block
     capture), aligned with program.blocks: one pass over the program instead
     of an O(blocks^2) rescan of every other block's op list per block.
-    Shared by remove_identity_ops and the fusion passes."""
+    Read by remove_identity_ops."""
     block_reads = []
     n_blocks_reading: Dict[str, int] = {}
     for b in program.blocks:
@@ -221,173 +192,6 @@ def _outside_reads(program):
     return [{n for n, c in n_blocks_reading.items()
              if c > (1 if n in reads else 0)}
             for reads in block_reads]
-
-
-@register_pass("fuse_bn_relu")
-def fuse_bn_relu(program, keep=()):
-    """Merge `batch_norm` -> `relu` pairs into batch_norm(fuse_relu=True)
-    (reference: conv_bn_fuse / fuse_relu_depthwise_conv ir passes; here the
-    relu folds into the BN epilogue so the Pallas scale/shift/relu kernel —
-    or the XLA composite's fused maximum — applies it in the same pass over
-    the activation).
-
-    Safe only when the BN's Y is read by exactly that relu and nowhere else
-    (any other reader still needs the pre-relu value); `keep` names fetch
-    targets that must stay written."""
-    keep = set(keep)
-    for block, outside in zip(program.blocks, _outside_reads(program)):
-        readers = _reader_counts(block)
-        writes, reads = _rw_positions(block)
-        by_out = {}
-        for i, op in enumerate(block.ops):
-            if op.type == "batch_norm" and not op.attrs.get("fuse_relu"):
-                by_out[op.output("Y")[0]] = (op, i)
-        kept = []
-        for i, op in enumerate(block.ops):
-            if op.type == "relu":
-                src = op.input_arg_names[0]
-                bn, bn_i = by_out.get(src, (None, -1))
-                # by_out keeps the LAST batch_norm writing each Y name — it
-                # must also PRECEDE this relu (a later writer is a different
-                # def; pairing across it would miscompile)
-                if bn is not None and bn_i >= i:
-                    bn = None
-                out_name = op.output("Out")[0] if bn is not None else None
-                # snapshot semantics: fusing moves the write of Out from the
-                # relu's position up to the BN's — any op between that reads
-                # Out (old value) or writes Out, or that writes Y (so the
-                # relu never saw the BN's value), makes the move observable
-                hazard = bn is not None and (
-                    _accessed_between(writes, src, bn_i, i)
-                    or _accessed_between(writes, out_name, bn_i, i)
-                    or _accessed_between(reads, out_name, bn_i, i))
-                if (bn is not None and not hazard
-                        and readers.get(src, 0) == 1
-                        and src not in keep and src not in outside):
-                    v = block._find_var_recursive(src)
-                    if v is None or not v.persistable:
-                        # BN now writes the relu's output var directly
-                        bn.outputs["Y"] = [op.output("Out")[0]]
-                        bn.attrs["fuse_relu"] = True
-                        continue
-            kept.append(op)
-        block.ops = kept
-    program._bump()
-
-
-@register_pass("fuse_ln_residual")
-def fuse_ln_residual(program, keep=()):
-    """Fold `elementwise_add(X, Y)` -> `layer_norm` chains into
-    layer_norm(X, Residual=Y) (reference: operators/fused/
-    fused_layernorm_residual_dropout_bias).  The pre-norm residual sum then
-    never materializes as its own HBM tensor on the Pallas path
-    (ops/pallas_kernels.py fused_ln_residual); the composite lowering adds
-    it inline.
-
-    Conditions: the add's output feeds exactly the layer_norm (no other
-    readers, not fetched via `keep`, not captured by another block, not
-    persistable), shapes match exactly (no broadcasting), default axis."""
-    keep = set(keep)
-    for block, outside in zip(program.blocks, _outside_reads(program)):
-        readers = _reader_counts(block)
-        writes, _ = _rw_positions(block)
-        adds = {}
-        for i, op in enumerate(block.ops):
-            if (op.type == "elementwise_add"
-                    and op.attrs.get("axis", -1) in (-1,)
-                    and len(op.input("X")) == 1 and len(op.input("Y")) == 1):
-                xv = block._find_var_recursive(op.input("X")[0])
-                yv = block._find_var_recursive(op.input("Y")[0])
-                if (xv is not None and yv is not None
-                        and xv.shape is not None
-                        and tuple(xv.shape) == tuple(yv.shape or ())):
-                    adds[op.output("Out")[0]] = (op, i)
-        fused_adds = []
-        for i, op in enumerate(block.ops):
-            if op.type != "layer_norm" or op.inputs.get("Residual"):
-                continue
-            src = op.input("X")[0]
-            add, add_i = adds.get(src, (None, -1))
-            # adds keeps the LAST elementwise_add writing each Out name — it
-            # must also PRECEDE this layer_norm (a later writer is a
-            # different def; fusing across it would normalize the wrong sum)
-            if (add is None or add_i >= i or readers.get(src, 0) != 1
-                    or src in keep or src in outside):
-                continue
-            # snapshot semantics: fusing moves the reads of the add's X/Y
-            # from the add's position down to the layer_norm's — an op
-            # between that writes either input (t = a + b; b += 1; ln(t))
-            # or re-writes src makes the LN observe the mutation
-            if (_accessed_between(writes, add.input("X")[0], add_i, i)
-                    or _accessed_between(writes, add.input("Y")[0], add_i, i)
-                    or _accessed_between(writes, src, add_i, i)):
-                continue
-            v = block._find_var_recursive(src)
-            if v is not None and v.persistable:
-                continue
-            op.inputs["X"] = [add.input("X")[0]]
-            op.inputs["Residual"] = [add.input("Y")[0]]
-            fused_adds.append(add)
-        if fused_adds:
-            dead = set(id(a) for a in fused_adds)
-            block.ops = [o for o in block.ops if id(o) not in dead]
-    program._bump()
-
-
-@register_pass("fuse_bias_act")
-def fuse_bias_act(program, keep=()):
-    """Merge `elementwise_add` -> `relu`/`gelu` pairs into
-    elementwise_add(fuse_act=<act>) (reference: the fc_fuse / conv+bias+act
-    family of ir passes; here the activation folds into the add so the
-    Pallas bias-act epilogue — or XLA's own fused maximum/erf chain —
-    applies it in the same pass over the activation, ISSUE-17 gap ranking's
-    top unfused elementwise pair).
-
-    Safe only when the add's Out is read by exactly that activation and
-    nowhere else (any other reader still needs the pre-activation value);
-    `keep` names fetch targets that must stay written."""
-    keep = set(keep)
-    for block, outside in zip(program.blocks, _outside_reads(program)):
-        readers = _reader_counts(block)
-        writes, reads = _rw_positions(block)
-        by_out = {}
-        for i, op in enumerate(block.ops):
-            if (op.type == "elementwise_add"
-                    and not op.attrs.get("fuse_act")
-                    and len(op.input("X")) == 1 and len(op.input("Y")) == 1):
-                by_out[op.output("Out")[0]] = (op, i)
-        kept = []
-        for i, op in enumerate(block.ops):
-            if op.type in ("relu", "gelu"):
-                src = op.input_arg_names[0]
-                add, add_i = by_out.get(src, (None, -1))
-                # by_out keeps the LAST add writing each Out name — it must
-                # also PRECEDE this activation (a later writer is a
-                # different def; pairing across it would miscompile)
-                if add is not None and add_i >= i:
-                    add = None
-                out_name = op.output("Out")[0] if add is not None else None
-                # snapshot semantics: fusing moves the write of Out from the
-                # activation's position up to the add's — any op between
-                # that reads Out (old value) or writes Out, or that writes
-                # the add's Out (so the activation never saw the add's
-                # value), makes the move observable
-                hazard = add is not None and (
-                    _accessed_between(writes, src, add_i, i)
-                    or _accessed_between(writes, out_name, add_i, i)
-                    or _accessed_between(reads, out_name, add_i, i))
-                if (add is not None and not hazard
-                        and readers.get(src, 0) == 1
-                        and src not in keep and src not in outside):
-                    v = block._find_var_recursive(src)
-                    if v is None or not v.persistable:
-                        # the add now writes the activation's output var
-                        add.outputs["Out"] = [op.output("Out")[0]]
-                        add.attrs["fuse_act"] = op.type
-                        continue
-            kept.append(op)
-        block.ops = kept
-    program._bump()
 
 
 @register_pass("prune_dead_ops")

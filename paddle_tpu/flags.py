@@ -196,17 +196,6 @@ DEFINE_float("FLAGS_dist_bootstrap_timeout_s", 120.0,
              "role): a gang whose worker never dials in raises "
              "CollectiveTimeoutError instead of blocking the others at "
              "startup")
-DEFINE_bool("FLAGS_use_pallas", False,
-            "route hot-kernel lowerings to the hand-fused Pallas TPU "
-            "kernels (ops/pallas_kernels.py: LayerNorm+residual, BN "
-            "scale/shift/relu epilogue, row-slab Adam, hard-label "
-            "softmax-cross-entropy, bias+relu/gelu epilogue; "
-            "fused_attention's kernels are chosen by shape, not by this). "
-            "OPT-IN: off (default) or a non-TPU backend keeps the XLA "
-            "composite for every kernel.  Participates in the executor "
-            "compile-cache key, so toggling recompiles instead of reusing "
-            "stale executables.  Parity: tests/test_pallas_kernels.py; "
-            "device A/B: tools/opbench.py --fused")
 DEFINE_float("FLAGS_dp_bucket_mb", 4.0,
              "gradient-bucket size cap (MB) for the backward-overlapped "
              "data-parallel all-reduce (parallel/distributed.py "

@@ -327,6 +327,10 @@ def _refused(ctx, unit: Unit, ops, at: Dict[int, int], readers) -> Optional[str]
     return None
 
 
+# This is where a chain of ops is fused, and how the next fused edge is to be built (PR 58 settled it: the
+# program-level fusion passes went with the kernels nothing priced): at LOWERING time, over the ops the step really
+# lowers, where `ctx.fetch_names`, the kept values and the mesh are visible and a refusal falls back op by op.
+# `core/passes.py` holds only rewrites of the program that mean the same on every backend.
 def plan(ctx, ops) -> None:
     """Find, once a trace and before any op of it is lowered, the latent
     attentions among `ops` (the ops the step lowers: the executor's, after its

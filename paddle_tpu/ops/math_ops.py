@@ -36,6 +36,7 @@ def _ew(fn):
 
 
 for _name, _fn in {
+    "elementwise_add": jnp.add,
     "elementwise_sub": jnp.subtract,
     "elementwise_mul": jnp.multiply,
     "elementwise_div": jnp.divide,
@@ -108,41 +109,6 @@ def _unary(fn):
 
 for _name, _fn in _UNARY.items():
     register_op(_name)(_unary(_fn))
-
-
-_ew_add = _ew(jnp.add)
-
-
-@register_op("elementwise_add")
-def _elementwise_add(ctx, op, ins):
-    """Plain add, or — after core/passes.py fuse_bias_act folded a
-    relu/gelu consumer into the op (attr fuse_act) — the fused bias-act
-    epilogue.  On the Pallas path the pre-activation never round-trips
-    through HBM (ops/pallas_kernels.py fused_bias_act); the composite
-    applies the activation inline and XLA fuses the chain."""
-    act = op.attr("fuse_act", None)
-    if not act:
-        return _ew_add(ctx, op, ins)
-    from ..core.selected_rows import SelectedRows
-    from .pallas_kernels import bias_act_shape_ok, fused_bias_act, use_pallas
-
-    x = first(ins, "X")
-    y = first(ins, "Y")
-    if (use_pallas(ctx) and not isinstance(x, SelectedRows)
-            and getattr(y, "ndim", None) == 1 and x.ndim >= 2
-            and y.shape[0] == x.shape[-1]
-            and op.attr("axis", -1) in (-1, x.ndim - 1)
-            and bias_act_shape_ok(x.shape, x.dtype)):
-        # the 1-D last-axis bias shape the kernel handles; anything else
-        # (full-tensor residual adds, mid-axis broadcasts) keeps the
-        # composite below
-        out = fused_bias_act(x.reshape(-1, x.shape[-1]), y, act)
-        return {"Out": out.reshape(x.shape)}
-    out = _ew_add(ctx, op, ins)["Out"]
-    fn = _UNARY[act]
-    if isinstance(out, SelectedRows):
-        return {"Out": SelectedRows(out.rows, fn(out.values), out.height)}
-    return {"Out": fn(out)}
 
 
 @register_op("hard_shrink")

@@ -192,34 +192,16 @@ def _batch_norm(ctx, op, ins):
         mean_out = momentum * mean_in + (1.0 - momentum) * mean
         var_out = momentum * var_in + (1.0 - momentum) * var
 
-    fuse_relu = op.attr("fuse_relu", False)  # core/passes.py fuse_bn_relu
-    from .pallas_kernels import epilogue_shape_ok, use_pallas
-
     inv = jax.lax.rsqrt(var.reshape(bshape) + eps)
-    if (use_pallas(ctx) and ch_axis == 1
-            and x.ndim >= 3 and epilogue_shape_ok(x.shape, x.dtype)):
-        # fused epilogue kernel: the normalize/scale/shift(/relu) chain as
-        # one roofline-bandwidth pass with per-channel f32 multipliers; the
-        # producing conv keeps its clean MXU fusion (stats stay XLA
-        # reductions above)
-        from .pallas_kernels import bn_epilogue
-
-        sf = scale.astype(jnp.float32)
-        mul_c = inv.reshape(-1) * sf
-        add_c = bias.astype(jnp.float32) - mean.reshape(-1) * mul_c
-        y = bn_epilogue(x, mul_c, add_c, relu=fuse_relu)
+    if half:
+        # per-channel multipliers computed in f32, applied in x's dtype
+        mul = (inv * scale.astype(jnp.float32).reshape(bshape)).astype(x.dtype)
+        add = (bias.astype(jnp.float32).reshape(bshape)
+               - mean.reshape(bshape) * inv * scale.astype(jnp.float32).reshape(bshape)
+               ).astype(x.dtype)
+        y = x * mul + add
     else:
-        if half:
-            # per-channel multipliers computed in f32, applied in x's dtype
-            mul = (inv * scale.astype(jnp.float32).reshape(bshape)).astype(x.dtype)
-            add = (bias.astype(jnp.float32).reshape(bshape)
-                   - mean.reshape(bshape) * inv * scale.astype(jnp.float32).reshape(bshape)
-                   ).astype(x.dtype)
-            y = x * mul + add
-        else:
-            y = (x - mean.reshape(bshape)) * inv * scale.reshape(bshape) + bias.reshape(bshape)
-        if fuse_relu:
-            y = jnp.maximum(y, 0.0)
+        y = (x - mean.reshape(bshape)) * inv * scale.reshape(bshape) + bias.reshape(bshape)
     return {
         "Y": y.astype(x.dtype),
         "MeanOut": mean_out,
@@ -229,70 +211,20 @@ def _batch_norm(ctx, op, ins):
     }
 
 
-def _outputs_consumed(ctx, op, slots):
-    """True when any of `op`'s outputs in `slots` is read by any op or
-    fetched — a fused kernel that does not materialize those slots must
-    then yield to the composite lowering.
-
-    The program-wide read-name set is memoized on the LoweringContext (one
-    scan per trace, not one per op — a deep transformer would otherwise
-    rescan every op per candidate on every compile-cache miss).  An op
-    never reads its own outputs (def-before-use), so the union over ALL
-    ops matches the per-op exclusion it replaces."""
-    names = {n for slot in slots for n in op.outputs.get(slot, [])}
-    if not names:
-        return False
-    if names & set(getattr(ctx, "fetch_names", ()) or ()):
-        return True
-    read = getattr(ctx, "_program_read_names", None)
-    if read is None:
-        read = set()
-        for b in op.block.program.blocks:
-            for o in b.ops:
-                read.update(o.input_arg_names)
-        ctx._program_read_names = read
-    return bool(names & read)
-
-
-def _ln_stats_consumed(ctx, op):
-    """True when this layer_norm's Mean/Variance outputs are read or
-    fetched — the fused kernel does not materialize them."""
-    return _outputs_consumed(ctx, op, ("Mean", "Variance"))
-
-
 @register_op("layer_norm")
 def _layer_norm(ctx, op, ins):
     x = first(ins, "X")
     scale = first(ins, "Scale")
     bias = first(ins, "Bias")
-    # optional fused residual input (core/passes.py fuse_ln_residual): the
-    # residual add that fed this LN has been folded into the op, so the
-    # pre-norm sum never becomes a standalone HBM tensor on the fused path
-    residual = first(ins, "Residual") if ins.get("Residual") else None
     eps = op.attr("epsilon", 1e-5)
     begin = op.attr("begin_norm_axis", 1)
     axes = tuple(range(begin, x.ndim))
-    from .pallas_kernels import fused_ln_residual, ln_shape_ok, use_pallas
-
-    if (use_pallas(ctx) and axes == (x.ndim - 1,)
-            and scale is not None and bias is not None
-            and ln_shape_ok(x.shape, x.dtype, residual is not None)
-            and not _ln_stats_consumed(ctx, op)):
-        # one-VMEM-pass kernel (residual add + stats + affine); Mean/Variance
-        # slots stay unset — safe because _ln_stats_consumed proved nothing
-        # reads or fetches them (a consumer keeps the composite below)
-        y = fused_ln_residual(x, residual, scale, bias, float(eps))
-        return {"Y": y}
-    if residual is not None:
-        x = x + match_dtype(x, residual)
     # standard TPU LN numerics: stats/normalize in f32 even for bf16
     # activations (bf16's 8-bit mantissa loses the mean under cancellation)
     xf = x.astype(jnp.float32) if x.dtype in (jnp.bfloat16, jnp.float16) else x
     mean = jnp.mean(xf, axis=axes, keepdims=True)
     var = jnp.var(xf, axis=axes, keepdims=True)
     y = ((xf - mean) * jax.lax.rsqrt(var + eps)).astype(x.dtype)
-    import numpy as _np
-
     norm_shape = (1,) * begin + tuple(x.shape[begin:])
     if scale is not None:
         y = y * match_dtype(y, scale).reshape(norm_shape)
@@ -365,24 +297,6 @@ def _softmax_with_cross_entropy(ctx, op, ins):
     fused pass over the logits."""
     logits = first(ins, "Logits")
     label = first(ins, "Label")
-    from .pallas_kernels import fused_softmax_xent, sxe_shape_ok, use_pallas
-
-    if (use_pallas(ctx) and not op.attr("soft_label", False)
-            and logits.ndim >= 2
-            and sxe_shape_ok(logits.shape, logits.dtype)
-            and not _outputs_consumed(ctx, op, ("Softmax",))):
-        # one-VMEM-pass kernel (max + logsumexp + picked logit together;
-        # bwd recomputes the softmax flash-style).  The Softmax slot stays
-        # unset — safe because _outputs_consumed proved nothing reads or
-        # fetches it (a consumer keeps the composite below).
-        lab = label
-        if lab.ndim == logits.ndim and lab.shape[-1] == 1:
-            lab = lab[..., 0]
-        lead = logits.shape[:-1]
-        loss = fused_softmax_xent(
-            logits.reshape(-1, logits.shape[-1]), lab.reshape(-1),
-            int(op.attr("ignore_index", -100)))
-        return {"Loss": loss.reshape(lead + (1,))}
     m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
     shifted = (logits - m).astype(jnp.float32)
     sumexp = jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True)

@@ -146,23 +146,6 @@ def _adam(ctx, op, ins):
                 "Beta1PowOut": (b1p * beta1).reshape((1,)),
                 "Beta2PowOut": (b2p * beta2).reshape((1,)),
             }
-    from .pallas_kernels import adam_shape_ok, fused_adam, use_pallas
-
-    if use_pallas(ctx) and adam_shape_ok(p.shape):
-        # row-slab fused update: p/m/v read+written in ONE kernel pass with
-        # input_output_aliases, instead of the composite's separate m, v,
-        # sqrt, div, sub HBM round-trips.  The bias-corrected step size and
-        # the beta-pow advance stay outside (scalars).
-        lr_t = lr * jnp.sqrt(1.0 - b2p) / (1.0 - b1p)
-        p_new, m1n, m2n = fused_adam(p, g, m1, m2, lr_t, float(beta1),
-                                     float(beta2), float(eps))
-        return {
-            "ParamOut": p_new,
-            "Moment1Out": m1n,
-            "Moment2Out": m2n,
-            "Beta1PowOut": (b1p * beta1).reshape((1,)),
-            "Beta2PowOut": (b2p * beta2).reshape((1,)),
-        }
     m1n = beta1 * m1 + (1.0 - beta1) * g
     m2n = beta2 * m2 + (1.0 - beta2) * jnp.square(g)
     lr_t = lr * jnp.sqrt(1.0 - b2p) / (1.0 - b1p)

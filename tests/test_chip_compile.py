@@ -4,7 +4,7 @@ forward and backward; since PR 26 also the grouped matmul and the flash kernel
 at OLMoE-1B-7B's widths, since PR 28 the whole `moe_experts` lowering there,
 with the passes over its rows that the optimised program may hold.
 
-Interpret mode (tests/test_pallas_kernels.py) checks the numbers; it cannot
+Interpret mode (each kernel's own test file) checks the numbers; it cannot
 see what Mosaic refuses: a block that is not a whole (8|16, 128) tile, a
 blocked rank-1 operand, a primitive with no TPU lowering, a kernel that
 overruns scoped VMEM.  These compiles can, at no chip time.  Nothing runs,
@@ -23,7 +23,6 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops.pallas_attention import fused_sdpa
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -59,16 +58,6 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _ln(res):
-    if res:
-        return lambda x, r, s, b: pk.fused_ln_residual(x, r, s, b, 1e-5)
-    return lambda x, s, b: pk.fused_ln_residual(x, None, s, b, 1e-5)
-
-
-def _adam(p, g, m, v, lr):
-    return pk.fused_adam(p, g, m, v, lr, 0.9, 0.999, 1e-8)
-
-
 def _flash(causal):
     """The stock flash kernel as the `fused_attention` op calls it on a TPU
     (ops/nn_ops.py: its block sizes, the bias broadcast per head in float32)."""
@@ -97,6 +86,34 @@ def _window(q, k, v):
     return window_attention(q, k, v, 512, q.shape[-1] ** -0.5)
 
 
+def _kda(q, k, v, g, beta):
+    """The chunked KDA op as `kda`'s lowering calls it on a TPU (ops/linear_attention_ops.py): `kda_scan` forward,
+    `kda_scan_transposed` under `jax.grad`."""
+    from paddle_tpu.ops.linear_attention_ops import chunked_kda
+
+    return chunked_kda(q, k, v, g, beta, kernels="tpu")[0]
+
+
+def _ssm(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
+    """The selective scan as `selective_scan`'s lowering calls it on a TPU (ops/ssm_ops.py): `ssm_kernels.scan`
+    forward, `scan_transposed` under `jax.grad`."""
+    from paddle_tpu.ops.ssm_ops import kernel_selective_scan
+
+    return kernel_selective_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias)[0]
+
+
+def _backward(fn, argnums):
+    """`fn`'s gradient as a case of its own: the transposed kernel counts beside the forward one."""
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums)
+
+
+def _latent(name, **static):
+    """One of the latent attention's four edge kernels (ops/latent_kernels.py) as `ops/latent_operands.py` calls it."""
+    from paddle_tpu.ops import latent_kernels
+
+    return lambda *a: getattr(latent_kernels, name)(*a, **static)
+
+
 def _gmm(rows, weights, sizes):
     from paddle_tpu.ops.moe_ops import grouped_matmul
 
@@ -116,43 +133,6 @@ def _token_sum(experts):
 _ROWS = 256 * 128
 # name -> (fn, [(shape, dtype)], grad argnums; () compiles forward only)
 CASES = {
-    "ln_residual_bf16": (
-        _ln(True), [((256, 128, 768), BF16), ((256, 128, 768), BF16),
-                    ((768,), F32), ((768,), F32)], (0, 1, 2, 3)),
-    "ln_plain_bf16": (
-        _ln(False), [((256, 128, 768), BF16), ((768,), F32), ((768,), F32)],
-        (0, 1, 2)),
-    "ln_plain_f32_embedding": (
-        _ln(False), [((256, 128, 768), F32), ((768,), F32), ((768,), F32)],
-        (0, 1, 2)),
-    "adam_ffn_weight": (
-        _adam, [((768, 3072), F32)] * 4 + [((), F32)], ()),
-    "adam_embedding_ragged_slab": (
-        _adam, [((30522, 768), F32)] * 4 + [((), F32)], ()),
-    "adam_bias_one_slab": (
-        _adam, [((768,), F32)] * 4 + [((), F32)], ()),
-    "softmax_xent_bf16_vocab": (
-        lambda x, y: pk.fused_softmax_xent(x, y, -100),
-        [((_ROWS, 30522), BF16), ((_ROWS,), I32)], (0,)),
-    "softmax_xent_f32_vocab": (
-        lambda x, y: pk.fused_softmax_xent(x, y, -100),
-        [((_ROWS, 30522), F32), ((_ROWS,), I32)], (0,)),
-    "bias_gelu_ffn": (
-        lambda x, b: pk.fused_bias_act(x, b, "gelu"),
-        [((_ROWS, 3072), BF16), ((3072,), F32)], (0, 1)),
-    "bias_relu_ffn": (
-        lambda x, b: pk.fused_bias_act(x, b, "relu"),
-        [((_ROWS, 3072), BF16), ((3072,), F32)], (0, 1)),
-    "bn_epilogue_stage1": (
-        lambda x, m, a: pk.bn_epilogue(x, m, a, True),
-        [((128, 64, 56, 56), BF16), ((64,), F32), ((64,), F32)], (0, 1, 2)),
-    "bn_epilogue_stage4_7x7": (
-        lambda x, m, a: pk.bn_epilogue(x, m, a, False),
-        [((128, 2048, 7, 7), BF16), ((2048,), F32), ((2048,), F32)],
-        (0, 1, 2)),
-    "bn_epilogue_stem_serving": (
-        lambda x, m, a: pk.bn_epilogue(x, m, a, True),
-        [((8, 64, 112, 112), BF16), ((64,), F32), ((64,), F32)], ()),
     "fused_sdpa": (
         lambda q, k, v: fused_sdpa(q, k, v, None, False, 0.125),
         [((256, 12, 128, 64), BF16)] * 3, (0, 1, 2)),
@@ -244,6 +224,48 @@ CASES = {
         _token_sum(8), [((32768, 2048), BF16), ((16384, 4), I32), ((16384, 4), I32)], ()),
     "token_sum_held_kimi_linear": (
         _token_sum(8), [((2048, 2304), BF16), ((4096, 8), I32), ((4096, 8), I32)], ()),
+    # Kimi-Linear-48B-A3B's KDA layers: one sequence of 4096 positions, 32 heads of 128-wide keys and values in
+    # chunks of 64 (tools/chip_kimi_kernels.py times them on the chip; the whole step's compile is `-m slow`)
+    "kda_scan_kimi_linear": (
+        _kda, [((1, 4096, 32, 128), BF16)] * 3 + [((1, 4096, 32, 128), F32), ((1, 4096, 32, 1), F32)], ()),
+    "kda_scan_transposed_kimi_linear": (
+        _backward(_kda, (0, 1, 2, 3, 4)),
+        [((1, 4096, 32, 128), BF16)] * 3 + [((1, 4096, 32, 128), F32), ((1, 4096, 32, 1), F32)], ()),
+    # the selective scan at Jamba2-3B's and Phi-4-mini-flash's widths (the same in both cells: one row of 8192
+    # positions a chip, 5120 channels, a state of 16): x and dt in bf16, A, B, C and the two channel vectors float32
+    "selective_scan_jamba2_phi4flash": (
+        _ssm, [((1, 8192, 5120), BF16)] * 2 + [((5120, 16), F32)] + [((1, 8192, 16), F32)] * 2 + [((5120,), F32)] * 2, ()),
+    "selective_scan_transposed_jamba2_phi4flash": (
+        _backward(_ssm, (0, 1, 2, 3, 4, 5, 6)),
+        [((1, 8192, 5120), BF16)] * 2 + [((5120, 16), F32)] + [((1, 8192, 16), F32)] * 2 + [((5120,), F32)] * 2, ()),
+    "selective_scan_phi4flash_check_rows": (   # what the reference check's clone hands the op: 8 rows a call
+        _ssm, [((8, 8192, 5120), BF16)] * 2 + [((5120, 16), F32)] + [((8, 8192, 16), F32)] * 2 + [((5120,), F32)] * 2, ()),
+    # Kanana-2-30B-A3B's latent attention: one sequence of 16384 positions, 32 heads of 128 + 64 (keys) and 128
+    # (values), the rotary pairs interleaved (`shift` 1); the layer's own compile stands at 2048 positions below
+    "latent_queries_kanana2": (
+        _latent("queries", heads=32, nope=128, scale=192 ** -0.5, shift=1),
+        [((1, 16384, 32 * 192), BF16), ((1, 16384, 128), F32), ((1, 16384, 128), F32)], ()),
+    "latent_queries_back_kanana2": (
+        _latent("queries_back", heads=32, nope=128, scale=192 ** -0.5, shift=1),
+        [((1, 32, 16384, 192), BF16), ((1, 16384, 128), F32), ((1, 16384, 128), F32)], ()),
+    "latent_keys_values_kanana2": (
+        _latent("keys_values", heads=32, nope=128), [((1, 16384, 32 * 256), BF16), ((1, 16384, 64), BF16)], ()),
+    "latent_up_back_kanana2": (
+        _latent("up_back"), [((1, 32, 16384, 192), BF16), ((1, 32, 16384, 128), BF16)], ()),
+    # the held experts' products in the five sparse cells whose shapes stood in no case: the bound's rows
+    # (`ops.moe_ops._held_rows_bound`: twice the uniform share of tokens x k slots) on the held experts' float32
+    # masters, gate | up and down.  SDAR and Keye-VL-2.0: 16 of 128 held, 2048 x 768; LFM2: 8 of 32, 2048 x 1792;
+    # Kimi Linear: 8 of 256, 2304 x 1024; Kanana-2: 8 of 128, 2048 x 768 at 16384 positions x 6
+    "grouped_matmul_sdar_gate": (
+        _gmm, [((32768, 2048), BF16), ((16, 2048, 768), F32), ((16,), I32)], (0, 1)),
+    "grouped_matmul_keye_vl2_down": (
+        _gmm, [((32768, 768), BF16), ((16, 768, 2048), F32), ((16,), I32)], (0, 1)),
+    "grouped_matmul_lfm2_gate": (
+        _gmm, [((32768, 2048), BF16), ((8, 2048, 1792), F32), ((8,), I32)], (0, 1)),
+    "grouped_matmul_kimi_linear_gate": (
+        _gmm, [((2048, 2304), BF16), ((8, 2304, 1024), F32), ((8,), I32)], (0, 1)),
+    "grouped_matmul_kanana2_down": (
+        _gmm, [((12288, 768), BF16), ((8, 768, 2048), F32), ((8,), I32)], (0, 1)),
     "grouped_matmul_ragged_rows": (  # 1000 rows: padded to the kernel's row tile
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
     # SDAR-30B-A3B-Chat's cell: 2 sequences of 8192 positions [noised ; clean], 32 query heads on
@@ -274,8 +296,7 @@ def test_kernel_compiles_for_v5e(name, chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
     programs = [fn]
     if argnums:
-        programs.append(jax.grad(
-            lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums))
+        programs.append(_backward(fn, argnums))
     for program in programs:
         compiled = jax.jit(program).lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text(), (
@@ -1015,25 +1036,3 @@ def test_jamba2s_step_on_the_2x2_host_keeps_what_a_chips_room_holds_and_its_plan
     again = _made_again(compiled.as_text())
     assert sum(name.endswith("/selective_scan/pallas_call") for name in again) >= 13
     assert not [name for name in again if name.endswith("/pallas_call") and "selective_scan" not in name]   # the attention's is kept
-
-
-@pytest.mark.parametrize("kernel,shape,dtype,ok", [
-    ("ln", (256, 128, 768), BF16, True),
-    ("ln", (7, 33, 768), BF16, True),          # 231 rows: one whole-array slab
-    ("ln", (30011, 768), BF16, False),         # prime row count over budget
-    ("sxe", (_ROWS, 30522), BF16, True),
-    ("sxe", (30011, 30522), F32, False),
-    ("bias_act", (_ROWS, 3072), BF16, True),
-    ("bias_act", (30011, 3072), BF16, False),
-    ("epilogue", (128, 64, 56, 56), BF16, True),
-    ("epilogue", (30011, 1, 56, 56), BF16, False),
-])
-def test_shape_predicates_admit_only_tileable_rows(kernel, shape, dtype, ok):
-    """What a call site asks before it leaves the composite: rows that do
-    not split into aligned slabs under the VMEM budget keep the composite
-    instead of reaching the compiler with a block it refuses."""
-    got = {"ln": lambda: pk.ln_shape_ok(shape, dtype, True),
-           "sxe": lambda: pk.sxe_shape_ok(shape, dtype),
-           "bias_act": lambda: pk.bias_act_shape_ok(shape, dtype),
-           "epilogue": lambda: pk.epilogue_shape_ok(shape, dtype)}[kernel]()
-    assert got is ok

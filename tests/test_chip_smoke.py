@@ -52,8 +52,6 @@ PHASES = {
         bert=TINY_BERT, batch=8, k=2, dispatches=4),
     "server": lambda: chip_smoke.phase_server(
         depth=18, image=32, class_dim=10, buckets=(1, 4), sizes=(1, 4, 3)),
-    "kernels": lambda: chip_smoke.phase_kernels(
-        bert=TINY_BERT, batch=8, steps=3),
     "host_callback": lambda: chip_smoke.phase_host_callback(),
     "mesh_2x2": lambda: chip_smoke.phase_mesh(
         bert=TINY_BERT, batch=8, steps=3),

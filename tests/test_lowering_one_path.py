@@ -12,6 +12,7 @@ process or a program can set.
 (d) `fused_attention`'s choice among its three attentions, shape by shape,
     and the counters that say which a program took (ISSUE 30).
 """
+import glob
 import hashlib
 import inspect
 import json
@@ -156,6 +157,24 @@ PARENTS_PROGRAMS = {
 }
 
 
+#: cell -> (module name, sha256 of the step as lowered for the TPU, the kernels' bodies stripped): the five one-chip
+#: cells `PARENTS_PROGRAMS` lacks, through `tools/lowered_hash.py`'s function (the recipe every `perf_opt` since PR 51
+#: rests "the other cells cannot move" on), recorded at the parent commit `41636dc` of PR 58 by that tool in a
+#: `git archive` of it.  A PR that means to change one of these programs re-records its line and says so.
+PARENTS_CELLS = {
+    "sdar-30b-a3b-chat.train-blockdiff-s4096": ("train_6de7c714",
+        "3a0761cf88eedd3d35378d5928192cfe3234650ff0046226fe0d6bb9c78b8195"),
+    "kimi-linear-48b-a3b.train-kda-s4096": ("train_f85463d4",
+        "2c60a2d6d7f1e9754d535dd8a9864daaf187b0dc7550fe6e06d6e42aa60dd4eb"),
+    "phi-4-mini-flash-reasoning.train-sambay-s8192": ("train_d4522e44",
+        "5d27410c095290ca3ba3084360cf6fddf735c135027655eff2962967ccd84a7a"),
+    "kanana-2-30b-a3b.train-mla-s16384": ("train_9752db24",
+        "c11c680130484415415ebbd16816934a956b91a9a77afa06c1fe569d39a6c118"),
+    "keye-vl-2.0-30b-a3b.train-dsa-s16384": ("train_21d207fd",
+        "002087c445cc081e70d1c1a3e8cf4cf18caecc5127ae5dd417baf06ab6812882"),
+}
+
+
 def _attention_counters():
     from paddle_tpu.monitor import MONITOR
 
@@ -174,14 +193,24 @@ def monitor_on():
     monitor.reset()
 
 
-@pytest.mark.parametrize("case", list(PARENTS_PROGRAMS))
+@pytest.mark.parametrize("case", list(PARENTS_PROGRAMS) + list(PARENTS_CELLS))
 def test_the_step_lowers_to_the_parents_program(case, monitor_on, monkeypatch):
     from collections import defaultdict
 
-    build, module, ops_sha, text_sha, attentions = PARENTS_PROGRAMS[case]
     # a `name_scope` met a second time in a process is numbered ("exit_head_1") and the ops carry it: a table of this
     # test's own, so that the listing does not depend on what was built before and nothing built after sees this
     monkeypatch.setattr(fluid.unique_name, "_scope_children", defaultdict(lambda: defaultdict(int)))
+    # nor on what it traced: an inner `jax.jit` found again in JAX's caches shares one traced object with its other
+    # callers, which lower to one private function, and the text's numbering moves
+    jax.clear_caches()
+    if case in PARENTS_CELLS:
+        from tools import lowered_hash
+
+        found = lowered_hash.lowered(case)
+        print(f'    "{case}": {found},')
+        assert found == PARENTS_CELLS[case]
+        return
+    build, module, ops_sha, text_sha, attentions = PARENTS_PROGRAMS[case]
     with fluid.unique_name.guard():  # parameter names come from process-wide counters
         main, startup, feeds, fetch = build()
     main.random_seed = startup.random_seed = 3
@@ -255,13 +284,20 @@ def test_batch_norm_chooses_its_statistics_by_dtype(dtype):
 def test_the_compile_cache_key_names_no_ops_module_global():
     from paddle_tpu.ops import nn_ops
 
-    assert ex._lowering_flags() == ("pallas", False)
-    fluid.set_flags({"FLAGS_use_pallas": True})
-    try:
-        assert ex._lowering_flags() == ("pallas", True)  # a toggle is a new key, never a stale step
-    finally:
-        fluid.set_flags({"FLAGS_use_pallas": False})
+    # the key has no process-global element (the last, the flag between two lowerings of five ops, went with PR 58):
+    # every element of the tuple is a name the run was called with or made from them
+    elements = re.search(r"cache_key = \((.*?)\n        \)", inspect.getsource(ex.Executor._run_impl), re.S).group(1)
+    assert [e.strip().rstrip(",") for e in elements.strip().splitlines()] == [
+        "program._uuid", "program.version", "tuple(sorted((n, v.shape, str(v.dtype)) for n, v in jfeeds.items()))",
+        "tuple(fetch_names)", "scope._uuid", "(tuple(mesh.shape.items()), batch_axis) if mesh is not None else None",
+        "steps", "remat", "local_sgd_every", "grad_overlap"]
     assert "nn_ops" not in inspect.getsource(ex)
+    # and nothing under `paddle_tpu/ops/` nor the interpreter asks for a flag: a lowering is chosen from what the op
+    # can observe (platform, dtype, shape, the context's mesh), never from what a process can set
+    sources = sorted(glob.glob(os.path.join(REPO, "paddle_tpu", "ops", "*.py"))) + [os.path.join(REPO, "paddle_tpu", "core", "lowering.py")]
+    assert len(sources) > 20
+    asking = [os.path.relpath(path, REPO) for path in sources if re.search(r"\bflag\(|\bflags\b", open(path).read())]
+    assert not asking, asking
     # and the lowerings have nothing of the kind to name: what is left are
     # thresholds on a shape, each with the chip runs that set it beside it and
     # cells (or the crossing's runs) on both sides
@@ -278,6 +314,34 @@ def test_the_compile_cache_key_names_no_ops_module_global():
                                                     nn_ops._attention_path))
     assert sorted(set(re.findall(r"op\.attr\(\"(\w+)\"", source))) == ["causal", "kept_kv", "layout", "scale"]
     assert "kept_kv" not in inspect.getsource(nn_ops._attention_path)
+
+
+def test_a_process_that_still_exports_the_deleted_flag_runs_and_setting_it_raises():
+    """An operator's shell may still carry `FLAGS_use_pallas=1` (PR 58 deleted the flag): the environment is read for
+    the registered flags alone, so such a process imports the package and runs a step; `set_flags` of the name fails
+    as any unknown flag does and lists the known ones."""
+    import subprocess
+
+    script = (
+        "import numpy as np, paddle_tpu as fluid\n"
+        "main, startup = fluid.Program(), fluid.Program()\n"
+        "with fluid.program_guard(main, startup):\n"
+        "    x = fluid.layers.data('x', [4])\n"
+        "    loss = fluid.layers.mean(fluid.layers.fc(fluid.layers.layer_norm(x), 1))\n"
+        "    fluid.optimizer.Adam(0.1).minimize(loss)\n"
+        "exe = fluid.Executor(fluid.CPUPlace())\n"
+        "exe.run(startup)\n"
+        "print('loss', float(exe.run(main, feed={'x': np.ones((2, 4), 'float32')}, fetch_list=[loss])[0]))\n"
+        "try:\n"
+        "    fluid.set_flags({'FLAGS_use_pallas': True})\n"
+        "except Exception as e:\n"
+        "    print('refused', type(e).__name__, e)\n")
+    out = subprocess.run([sys.executable, "-c", script], cwd=REPO, capture_output=True, text=True, timeout=300,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu", FLAGS_use_pallas="1"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "loss " in out.stdout
+    refused = next(line for line in out.stdout.splitlines() if line.startswith("refused"))
+    assert "FLAGS_use_pallas" in refused and "FLAGS_dp_bucket_mb" in refused, refused    # the known flags are listed
 
 
 #: (queries, keys) -> the attention a bf16 `fused_attention` over 64-wide

@@ -21,14 +21,6 @@ CLI use (single-op timing through the real program/executor path):
     python tools/opbench.py --op relu --input X=256x1024 --grad
     python tools/opbench.py --op conv2d --input Input=64x64x56x56 \
         --input Filter=64x64x3x3 --attr strides=1,1 --attr paddings=1,1
-
-Fused-kernel A/B (ISSUE 7): each registered Pallas kernel
-(ops/pallas_kernels.py FUSED_KERNELS) timed interleaved against the XLA
-composite it replaces, after a parity check at the registry tolerance:
-
-    python tools/opbench.py --fused                       # all kernels
-    python tools/opbench.py --fused ln_residual --grad    # fwd+bwd arm
-    python tools/opbench.py --fused --interpret           # CPU/CI parity
 """
 from __future__ import annotations
 
@@ -173,133 +165,6 @@ def _probe_output_slots(op_type: str):
 
 
 # --------------------------------------------------------------------------
-# fused-kernel A/B (ops/pallas_kernels.py registry)
-# --------------------------------------------------------------------------
-
-def build_fused_dispatches(kernel: str, dtype: str = "float32",
-                           interpret: bool = False, grad: bool = False):
-    """(dispatches, tol) for one registered fused kernel: `pallas` (the
-    hand-fused kernel; `interpret=True` runs it through the Pallas
-    interpreter — the CPU/CI mode, which validates semantics but not
-    speed) vs `xla` (the composite lowering the kernel replaces), both
-    jitted over the registry's example shapes.  With grad=True both arms
-    differentiate sum(out**2) over the kernel's grad_argnums, so the
-    window times fwd+bwd — the training-path shape."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas_kernels import FUSED_KERNELS
-
-    spec = FUSED_KERNELS[kernel]
-    args = spec["example"](jnp.dtype(dtype))
-    tol = spec["tol"][dtype]
-    if grad:
-        if not spec["grad_argnums"]:
-            raise ValueError(f"--grad: fused kernel {kernel!r} is a state "
-                             f"update, not a differentiable layer")
-
-        def _loss(fn):
-            def wrapped(*a):
-                out = fn(a)
-                leaves = out if isinstance(out, (list, tuple)) else [out]
-                return sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
-                           for l in leaves)
-            return wrapped
-
-        # argnums restricted to non-None example args, ORIGINAL positions
-        # kept — dropping Nones from the tuple would shift every later
-        # arg under the registry lambdas' positional indexing
-        argnums = tuple(i for i in spec["grad_argnums"]
-                        if args[i] is not None)
-        fused = jax.jit(jax.grad(
-            _loss(lambda a: spec["fused"](a, interpret=interpret)),
-            argnums=argnums))
-        ref = jax.jit(jax.grad(_loss(spec["reference"]), argnums=argnums))
-    else:
-        fused = jax.jit(lambda *a: spec["fused"](a, interpret=interpret))
-        ref = jax.jit(lambda *a: spec["reference"](a))
-    live = list(args)  # full example tuple, None placeholders included
-
-    # parity before timing: an A/B between divergent kernels is meaningless
-    def _flat(out):
-        leaves = out if isinstance(out, (list, tuple)) else [out]
-        return [np.asarray(l.astype(jnp.float32)) for l in leaves]
-
-    for got, want in zip(_flat(fused(*live)), _flat(ref(*live))):
-        err = float(np.max(np.abs(got - want))) if got.size else 0.0
-        # scale-aware on the grad arm: reduced grads (dscale/dmul row-sums)
-        # carry accumulation-order noise proportional to their magnitude,
-        # same bound as tests/test_pallas_kernels.py test_grad_parity_fp32
-        scale = 1.0 + (float(np.max(np.abs(want))) if grad and want.size
-                       else 0.0)
-        if err > tol * scale:
-            raise AssertionError(
-                f"fused kernel {kernel!r} ({dtype}, grad={grad}) diverged "
-                f"from its composite: max|d|={err:.3e} > "
-                f"tol={tol:.0e}*{scale:.1f}")
-    return {"pallas": lambda: fused(*live), "xla": lambda: ref(*live)}, tol
-
-
-def run_fused_ab(kernels=None, dtypes=("float32",), interpret=False,
-                 grad=False, rounds=4, iters=8):
-    """[{kernel, dtype, grad, parity_tol, pallas: stats, xla: stats,
-    speedup}] — one interleaved A/B per (kernel, dtype)."""
-    from paddle_tpu.ops.pallas_kernels import (FUSED_KERNELS,
-                                               registered_fused_kernels)
-
-    recs = []
-    for kernel in (kernels or registered_fused_kernels()):
-        if grad and not FUSED_KERNELS[kernel]["grad_argnums"]:
-            # announced, not silent: `--fused adam_slab --grad` printing
-            # nothing and exiting 0 would be indistinguishable from a
-            # harness bug (unknown kernels/dtypes still raise loudly)
-            print(f"opbench --fused: skipping {kernel!r} under --grad "
-                  f"(state update, not a differentiable layer)",
-                  file=sys.stderr)
-            continue
-        for dtype in dtypes:
-            dispatches, tol = build_fused_dispatches(
-                kernel, dtype, interpret=interpret, grad=grad)
-            stats = interleave(dispatches, rounds=rounds, iters=iters)
-            rec = {
-                "kernel": kernel, "dtype": dtype, "grad": grad,
-                "interpret": interpret, "parity_tol": tol,
-                "pallas": stats["pallas"], "xla": stats["xla"],
-                "speedup": round(stats["xla"]["best_ms"]
-                                 / stats["pallas"]["best_ms"], 4)
-                if stats["pallas"]["best_ms"] else None,
-            }
-            rec.update(_roofline_frac(kernel, dtype, grad, stats))
-            recs.append(rec)
-    return recs
-
-
-def _roofline_frac(kernel, dtype, grad, stats):
-    """roofline_ms + per-arm roofline_frac from the registry's analytic
-    (flops, bytes) for the example shapes — the kernel's cost-rule-units
-    roofline time divided by measured time, so an A/B win is stated in the
-    same units the MFU floors ratchet in (ISSUE-17).  Forward arm only:
-    the analytic model prices one fwd pass, and a fwd/bwd window would
-    flatter the frac by ~the grad factor."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.core.resource_plan import (CHIP_HBM_BANDWIDTH,
-                                               CHIP_PEAK_FLOPS)
-    from paddle_tpu.ops.pallas_kernels import FUSED_KERNELS
-
-    ana = FUSED_KERNELS[kernel].get("analytic")
-    if ana is None or grad:
-        return {}
-    flops, bts = ana(FUSED_KERNELS[kernel]["example"](jnp.dtype(dtype)))
-    t_ms = max(flops / CHIP_PEAK_FLOPS, bts / CHIP_HBM_BANDWIDTH) * 1e3
-    out = {"roofline_ms": round(t_ms, 6), "roofline_frac": {}}
-    for arm in ("pallas", "xla"):
-        best = stats[arm]["best_ms"]
-        out["roofline_frac"][arm] = round(t_ms / best, 4) if best else None
-    return out
-
-
-# --------------------------------------------------------------------------
 # CLI
 # --------------------------------------------------------------------------
 
@@ -355,43 +220,10 @@ def main(argv=None):
     p.add_argument("--cpu", action="store_true", help="run on CPUPlace")
     p.add_argument("--rounds", type=int, default=4)
     p.add_argument("--iters", type=int, default=8)
-    p.add_argument("--fused", nargs="?", const="all", default=None,
-                   metavar="KERNEL",
-                   help="interleaved Pallas-vs-XLA A/B over the fused-"
-                        "kernel registry (ops/pallas_kernels.py); optional "
-                        "KERNEL narrows to one, default all.  One JSON "
-                        "line per (kernel, dtype) with both arms' stats, "
-                        "after a parity check at the registry tolerance")
-    p.add_argument("--dtype", default="float32,bfloat16",
-                   help="--fused dtypes (comma-separated)")
-    p.add_argument("--interpret", action="store_true",
-                   help="--fused: run the Pallas arm in interpret mode "
-                        "(the CPU/CI path — validates semantics, not "
-                        "speed; timing numbers are NOT kernel evidence)")
     args = p.parse_args(argv)
 
-    if args.fused:
-        import jax
-
-        from paddle_tpu.ops.pallas_kernels import (pallas_supported,
-                                                   registered_fused_kernels)
-
-        interpret = args.interpret
-        if not interpret and not pallas_supported(jax.default_backend()):
-            print(f"opbench --fused: backend {jax.default_backend()!r} has "
-                  f"no Pallas support; forcing --interpret (parity evidence "
-                  f"only — time the real kernels on TPU)", file=sys.stderr)
-            interpret = True
-        kernels = (registered_fused_kernels() if args.fused == "all"
-                   else [args.fused])
-        recs = run_fused_ab(kernels, dtypes=args.dtype.split(","),
-                            interpret=interpret, grad=args.grad,
-                            rounds=args.rounds, iters=args.iters)
-        for rec in recs:
-            print(json.dumps(rec))
-        return
     if not args.op:
-        p.error("--op is required unless --fused is given")
+        p.error("--op is required")
 
     import paddle_tpu as fluid
 

@@ -984,7 +984,7 @@ def check(path: str, steady_after: int = 2,
             failures.append(
                 f"recompile count moved in steady state (started at {base}): "
                 f"steps {bad[:10]} — the executor is re-tracing; check feed "
-                f"shape/dtype churn and _lowering_flags toggles")
+                f"shape/dtype churn")
         else:
             print(f"perf_report --check: recompile count flat at {base} "
                   f"across {len(steady)} steady-state steps")
