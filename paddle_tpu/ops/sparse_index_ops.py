@@ -49,6 +49,21 @@ qI, kI and w and nothing else; it is computed WITH the value, chunk by chunk
 (the value is linear in its cotangent), so that backward holds three small
 arrays and no [L, L] one; a builder therefore puts the op AFTER its layer's
 `recompute_scope` (`build_causal_lm` does), where it is made once.
+
+A chunk's value is `index_scores` as `sparse_index` runs it (XLA fuses the ReLU,
+the weights and the heads' sum into the product: I [C, K] leaves and no
+per-head array), the softmax over the allowed keys and the term.  Its gradients
+are NOT `jax.vjp`'s through `index_scores`, which kept the per-head products
+[Hi, C, K] float32 for backward and handed two more einsums a d_products of
+that shape: the term's gradient to the scores is written out, dI = r T - target
+over the held pairs (T the row's sum of the held target), and the three
+gradients are made from dI with the products made AGAIN where they are used
+(`ops/index_alignment_kernels.py`: on the TPU, where a chunk and its band are
+whole tiles, one Pallas kernel a chunk that holds a head's products for a block
+of keys in VMEM; anywhere else the same sums in `jax.numpy`; the platform and
+the shape choose, nothing else can).  So the index scores are computed four
+times a pair, once for the value, once more for the ReLU's mask and d_w, and
+the two gradient products, and nothing [Hi, C, K] reaches HBM.
 """
 from __future__ import annotations
 
@@ -62,7 +77,7 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
-from . import sparse_index_kernels
+from . import index_alignment_kernels, sparse_index_kernels
 from .common import counted_rules, first
 
 #: Queries a chunk of the scores (`sa_config.q_chunk_size`, read as tiling) and queries a band: a band's chunks see the
@@ -219,19 +234,41 @@ def attention_target(q, k, lse, allowed, scale: float):
     return jnp.sum(jax.lax.map(of_group, grouped), axis=0) / heads
 
 
-def chunk_divergence(qi, ki, w, target, allowed):
-    """sum over a chunk's queries of KL(target_t || softmax over the allowed
-    keys of I[t, .]), float32; `target` is a constant."""
-    scores = jnp.where(allowed, index_scores(qi, ki, w), -jnp.inf)
+def _divergence_of(scores, target, allowed):
+    """(the chunk's term, which pairs it holds, log r [C, K]) of the index
+    scores I [C, K]: r the softmax of I over the allowed keys."""
+    scores = jnp.where(allowed, scores, -jnp.inf)
     top = _whole(jax.lax.stop_gradient(jnp.max(scores, axis=-1)))
     log_r = scores - (top + jnp.log(_whole(jnp.sum(jnp.exp(scores - top[:, None]), axis=-1))))[:, None]
     held = allowed & (target > 0)
-    return jnp.sum(jnp.where(held, target * (jnp.log(jnp.where(held, target, 1.0)) - jnp.where(held, log_r, 0.0)), 0.0))
+    term = jnp.sum(jnp.where(held, target * (jnp.log(jnp.where(held, target, 1.0)) - jnp.where(held, log_r, 0.0)), 0.0))
+    return term, held, log_r
 
 
-def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, with_gradients: bool):
-    """One row's summed divergence and, `with_gradients`, its gradients to qI
-    [L, Hi, Di], kI [L, Di] and w [L, Hi] (the SCALED weights')."""
+def chunk_divergence(qi, ki, w, target, allowed):
+    """sum over a chunk's queries of KL(target_t || softmax over the allowed
+    keys of I[t, .]), float32; `target` is a constant."""
+    return _divergence_of(index_scores(qi, ki, w), target, allowed)[0]
+
+
+def chunk_divergence_and_gradients(qi, ki, w, target, allowed, gradients=index_alignment_kernels.gradients_plain):
+    """(`chunk_divergence`, its gradients to qI [C, Hi, Di], kI [K, Di] and w
+    [C, Hi], float32).  The value is `chunk_divergence`'s own expressions; its
+    gradient to the scores is written out, dI = r T - target over the held
+    pairs (T the row's sum of the held target; zero where a key is not
+    allowed), and `gradients` (one of `index_alignment_kernels`' two forms)
+    makes the three from it with the per-head products made again: nothing
+    [Hi, C, K] is kept of the forward pass."""
+    term, held, log_r = _divergence_of(index_scores(qi, ki, w), target, allowed)
+    target = jnp.where(held, target, 0.0)
+    d_scores = jnp.exp(log_r) * _whole(jnp.sum(target, axis=-1))[:, None] - target
+    return (term,) + tuple(gradients(qi, ki, w, d_scores))
+
+
+def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, gradients):
+    """One row's summed divergence and, with `gradients` (one of
+    `index_alignment_kernels`' two forms; None for the value alone), its
+    gradients to qI [L, Hi, Di], kI [L, Di] and w [L, Hi] (the SCALED weights')."""
     length = qi.shape[0]
     chunk = chunking(length)[0]
 
@@ -244,38 +281,38 @@ def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, with_gradients: bo
             target = attention_target(jax.lax.dynamic_slice_in_dim(q, start, chunk, 1), k[:, :keys],
                                       jax.lax.dynamic_slice_in_dim(lse, start, chunk, 1), allowed, scale)
         operands = (rows(qi), ki[:keys], rows(w))
-        if not with_gradients:
+        if gradients is None:
             return (chunk_divergence(*operands, target, allowed),)
-        value, pull = jax.vjp(lambda *o: chunk_divergence(*o, target, allowed), *operands)
-        d_qi, d_ki, d_w = pull(jnp.ones((), jnp.float32))
-        return value, d_qi, jnp.pad(d_ki.astype(jnp.float32), ((0, length - keys), (0, 0))), d_w
+        value, d_qi, d_ki, d_w = chunk_divergence_and_gradients(*operands, target, allowed, gradients)
+        # qI's gradient in qI's dtype here, as `jax.vjp` gave it: the chunks stack up to half the bytes
+        return value, d_qi.astype(qi.dtype), jnp.pad(d_ki, ((0, length - keys), (0, 0))), d_w
 
     found = _by_chunk(length, body)
-    if not with_gradients:
+    if gradients is None:
         return (jnp.sum(found[0]),)
     value, d_qi, d_ki, d_w = found
     return jnp.sum(value), d_qi.reshape(qi.shape), jnp.sum(d_ki, axis=0), d_w.reshape(w.shape)
 
 
-def _alignment(operands, scale: float, with_gradients: bool):
+def _alignment(operands, scale: float, gradients):
     """The rows one at a time: (each row's mean divergence a query [B], then
     that mean's gradients a row)."""
     qi = operands[0]
-    found = jax.lax.map(lambda row: _alignment_row(*row, scale, with_gradients), operands)
+    found = jax.lax.map(lambda row: _alignment_row(*row, scale, gradients), operands)
     return tuple(t / qi.shape[1] for t in found)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _divergence(qi, ki, w, q, k, lse, picks, scale):
-    return _alignment((qi, ki, w, q, k, lse, picks), scale, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _divergence(qi, ki, w, q, k, lse, picks, scale, gradients):
+    return _alignment((qi, ki, w, q, k, lse, picks), scale, None)[0]
 
 
-def _divergence_fwd(qi, ki, w, q, k, lse, picks, scale):
-    rows, *gradients = _alignment((qi, ki, w, q, k, lse, picks), scale, True)
-    return rows, tuple(g.astype(t.dtype) for g, t in zip(gradients, (qi, ki, w)))
+def _divergence_fwd(qi, ki, w, q, k, lse, picks, scale, gradients):
+    rows, *made = _alignment((qi, ki, w, q, k, lse, picks), scale, gradients)
+    return rows, tuple(g.astype(t.dtype) for g, t in zip(made, (qi, ki, w)))
 
 
-def _divergence_bwd(scale, gradients, cotangent):
+def _divergence_bwd(scale, form, gradients, cotangent):
     """A row's term is linear in its cotangent: the gradients made with it, times that."""
     d_qi, d_ki, d_w = (cotangent.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype) * g for g in gradients)
     return d_qi, d_ki, d_w, None, None, None, None
@@ -295,8 +332,13 @@ def _index_alignment(ctx, op, ins):
     ki = ki.reshape(ki.shape[0], ki.shape[1], ki.shape[-1])
     scale = op.attr("scale", None)
     scale = float(q.shape[-1]) ** -0.5 if scale is None else float(scale)
+    chunk, bands = chunking(qi.shape[1])
+    kernel = ctx.platform == "tpu" and all(index_alignment_kernels.fits(chunk, keys, *qi.shape[2:]) for _, keys in bands)
+    _MON.counter("lowering.index_alignment_ops").inc()
+    _MON.counter("lowering.index_alignment_kernel_calls").inc(1 if kernel else 0)
+    gradients = index_alignment_kernels.gradients if kernel else index_alignment_kernels.gradients_plain
     rows = _divergence(qi, ki, scaled_weights(w, qi.shape[2], qi.shape[3]), q, k, lse.astype(jnp.float32),
-                       first(ins, "Picks"), scale)
+                       first(ins, "Picks"), scale, gradients)
     return {"Out": jnp.mean(rows).reshape(1), "Rows": rows}
 
 
@@ -398,13 +440,16 @@ def _cost_sparse_index(ctx):
 
 
 def _cost_index_alignment(ctx):
-    """The index scores once more and twice for their gradients, and the main
-    attention's scores over the triangle for the target."""
+    """The index scores FOUR times (once more for the value, once again for the
+    ReLU's mask and the weights' gradient where the gradients are made, and the
+    two products of the gradients to qI and kI:
+    `index_alignment_kernels`), and the main attention's scores over the
+    triangle for the target."""
     qi, q = ctx.in_shape("QI"), ctx.in_shape("Q")
     if qi is None or q is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()
     pairs = qi[0] * _triangle(qi[1])
-    return pairs * (3.0 * qi[2] * (2.0 * qi[3] + 3.0) + q[1] * (2.0 * q[3] + 4.0)), ctx.io_bytes()
+    return pairs * (4.0 * qi[2] * (2.0 * qi[3] + 3.0) + q[1] * (2.0 * q[3] + 4.0)), ctx.io_bytes()
 
 
 _RP.register_cost(["sparse_index"], _cost_sparse_index)

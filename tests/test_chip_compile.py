@@ -982,6 +982,27 @@ def test_the_indexers_choice_of_a_chunk_compiles_to_the_counting_kernel_with_no_
     assert " sort(" not in text and "TopK" not in text and " while(" not in text
 
 
+@pytest.mark.parametrize("keys", [2048, 16384])
+def test_the_alignment_gradients_of_a_chunk_compile_to_one_kernel_inside_its_vmem(keys, chip):
+    """`index_alignment_kernels.gradients` as the op calls it on the TPU, on a
+    chunk of Keye-VL-2.0's cell: 512 queries of 16 index heads of 64 in bf16
+    against a band's keys, dI [512, keys] float32.  One Mosaic kernel (under a
+    second here: its body is one group of 128 lanes' heads, looped), inside the
+    32 MB of VMEM it asks for, and no [16, 512, keys] array beside it: what the
+    program holds besides its operands and results is kI laid at each head's
+    lanes, [2, keys, 128] bf16 (PERF.md, PR 59)."""
+    from paddle_tpu.ops import index_alignment_kernels as iak
+
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+        ((512, 16, 64), BF16), ((keys, 64), BF16), ((512, 16), F32), ((512, keys), F32))]
+    assert iak.fits(512, keys, 16, 64) and iak._VMEM_LIMIT <= 32 * 2 ** 20
+    compiled = jax.jit(lambda *operands: iak.gradients(*operands)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "index_alignment_gradients" in text
+    assert not re.search(rf"\[16,512,{keys}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * keys * 128 * 2 + 2 * 512 * 1024 * 4
+
+
 @pytest.mark.slow   # two compiles, ~115 and ~80 s on every core: run by name (`-m slow`); PERF.md, PR 56, has their readings
 def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
     """`keye-vl-2.0-30b-a3b.train-dsa-s16384`'s whole step at the published
@@ -1005,6 +1026,8 @@ def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(hos
     assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:sparse_index/index_select/", text))) == 4
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/", text))) == 4
+    # the alignment's gradients are the kernel's in every layer (PR 59)
+    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/[^\"]*index_alignment_gradients", text))) == 4
     windows = [int(n) for n in re.findall(r"reduce-window\([^\n]*window=\{size=[0-9x]*?x?(\d+) pad", text)]
     assert max(windows, default=0) < 2048, max(windows)      # no row's statistic is spread as one window over the row
     again = [name for name in _made_again(text) if "/cond/branch_" not in name]

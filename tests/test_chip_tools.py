@@ -48,6 +48,7 @@ def test_lowered_hash_prints_the_pinned_program_of_berts_s128_cell(capsys):
 REHEARSED = {
     "chip_block_attention": (),
     "chip_held_experts": (),
+    "chip_index_alignment": (),
     "chip_index_select": (),
     "chip_lfm2_controls": ("1",),
     "chip_phi4flash_controls": ("1",),

@@ -13,7 +13,11 @@
     (interpreted here) against the dense form, output and gradients, a block
     with no chosen pair skipped;
 (c) `index_alignment` against the reference's function, value and gradients,
-    over several chunks; nothing of it reaches the attention's operands;
+    over several chunks; nothing of it reaches the attention's operands; its
+    gradients, made from dI with the products made again (ISSUE 59:
+    `ops/index_alignment_kernels.py`, plain and the kernel interpreted),
+    against `jax.grad` through `index_scores`; which form the platform and the
+    shape choose; backward holds three small arrays and scales them;
 (d) the sectioned rotation (`mrope_section`) equals the plain one for equal
     streams, and does not for unequal ones;
 (e) the held shares of this block's router add up to the uncut layer;
@@ -48,6 +52,7 @@ from paddle_tpu.core import lowering  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import index_alignment_kernels as iak  # noqa: E402
 from paddle_tpu.ops import sparse_index_kernels as sik  # noqa: E402
 from paddle_tpu.ops import sparse_index_ops as sio  # noqa: E402
 
@@ -285,7 +290,7 @@ def test_the_ops_have_infer_rules_planner_rows_and_pass_verify():
     triangle = 3 * 64 * 65 / 2
     assert cost["sparse_index"][0] == triangle * 2 * (2 * 8 + 3)
     assert cost["fused_attention"][0] == 2.0 * 3 * 4 * (16 + 16) * (16 * 17 // 2 + 48 * 16)       # the CHOSEN pairs
-    assert cost["index_alignment"][0] == triangle * (3 * 2 * (2 * 8 + 3) + 4 * (2 * 16 + 4))
+    assert cost["index_alignment"][0] == triangle * (4 * 2 * (2 * 8 + 3) + 4 * (2 * 16 + 4))      # the index scores four times
     assert cost["stop_gradient"][0] == 0.0
 
 
@@ -430,6 +435,170 @@ def test_the_alignment_term_and_its_gradients_are_the_references(length, topk):
     # handed another (every row's off by 3), the term is the same
     agree(jax.jit(lambda *o: mine(*o)[0])(*operands), value, 1e-6)
     agree(mine(*operands, lse=lse + 3.0)[0], value, 1e-5)
+
+
+def by_autodiff(qi, ki, w, q, k, lse, picks, scale):
+    """A row's mean term as the op chunks it, every chunk `chunk_divergence`
+    on a constant target, for `jax.grad`: what `_alignment_row` took `jax.vjp`
+    of until PR 59."""
+    length = qi.shape[0]
+    chunk, bands = sio.chunking(length)
+    total = 0.0
+    for lo, hi in bands:
+        for start in range(lo, hi, chunk):
+            allowed = sio.unpack_bits(picks[start:start + chunk, :hi // 32], hi)
+            target = jax.lax.stop_gradient(sio.attention_target(q[:, start:start + chunk], k[:, :hi], lse[:, start:start + chunk],
+                                                                allowed, scale))
+            total = total + sio.chunk_divergence(qi[start:start + chunk], ki[:hi], w[start:start + chunk], target, allowed)
+    return total / length
+
+
+@pytest.mark.parametrize("length,topk", [(32, 8), (1024, 48), (4096, 96), (96, 16)],
+                         ids=["one-chunk", "two-chunks", "two-bands-of-four-chunks", "a-band-of-no-whole-tiles"])
+def test_the_ops_gradients_made_from_d_scores_are_jax_grads_of_the_plain_term(length, topk):
+    """The rule that makes the products again (`index_alignment_kernels.
+    gradients_plain` on the CPU) against `jax.grad` through `index_scores`."""
+    rng = np.random.RandomState(59)
+    heads, kv_heads, width, index_heads, index_width = 4, 2, 16, 2, 8
+    qi, ki, w = (jnp.asarray(t[0]) for t in indexer_operands(rng, 1, length, index_heads, index_width))
+    q, k, v = attention_operands(rng, 1, heads, kv_heads, length, width)
+    picks = lower("sparse_index", {"QI": qi[None], "KI": ki[None], "W": w[None]}, {"topk": topk})["Picks"]
+    lse = lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {"causal": True})["Lse"]
+
+    def mine(qi, ki, w):
+        return lower("index_alignment", {"QI": qi[None], "KI": ki[None], "W": w[None], "Picks": picks, "Q": q, "K": k, "Lse": lse})["Out"][0]
+
+    def theirs(qi, ki, w):
+        scaled = sio.scaled_weights(w, index_heads, index_width)
+        return by_autodiff(qi, ki[:, 0], scaled, q[0], k[0], lse[0], picks[0], width ** -0.5)
+
+    value, grads = jax.value_and_grad(mine, (0, 1, 2))(qi, ki, w)
+    want, want_grads = jax.jit(jax.value_and_grad(theirs, (0, 1, 2)))(qi, ki, w)
+    agree(value, want, 1e-6)
+    for g, t in zip(grads, want_grads):
+        assert np.abs(np.asarray(t)).max() > 0
+        agree(g, t, 5e-5)
+
+
+def chunk_operands(rng, chunk, keys, heads=2, width=8):
+    qi, ki, w = rng.randn(chunk, heads, width), rng.randn(keys, width), rng.randn(chunk, heads) * heads ** -0.5 * width ** -0.5
+    allowed = np.arange(keys) <= keys - chunk + np.arange(chunk)[:, None]
+    target = np.where(allowed, rng.exponential(size=(chunk, keys)), 0.0)
+    return [jnp.asarray(t, "f4") for t in (qi, ki, w)], target, allowed
+
+
+@pytest.mark.parametrize("case", ["every-causal-key-held", "no-held-key-but-the-diagonal", "a-target-of-zero-on-allowed-keys",
+                                  "keys-of-no-whole-tile"])
+def test_a_chunks_term_and_gradients_are_jax_value_and_grads(case):
+    rng = np.random.RandomState(60)
+    chunk, keys = (24, 72) if case == "keys-of-no-whole-tile" else (32, 96)
+    operands, target, allowed = chunk_operands(rng, chunk, keys)
+    if case == "no-held-key-but-the-diagonal":        # half the rows: the target is the diagonal's alone, others are allowed
+        own = np.arange(keys) == keys - chunk + np.arange(chunk)[:, None]
+        target = np.where((np.arange(chunk) % 2 == 0)[:, None], own * 1.0, target)
+        allowed = allowed & ((np.arange(chunk) % 4 != 0)[:, None] | own)      # ... and a quarter allow the diagonal alone
+    if case == "a-target-of-zero-on-allowed-keys":
+        target = np.where(rng.rand(chunk, keys) < 0.4, 0.0, target)
+        target[np.arange(chunk), keys - chunk + np.arange(chunk)] += 0.1
+    target = jnp.asarray(target / target.sum(-1, keepdims=True), "f4")
+    allowed = jnp.asarray(allowed)
+    value, d_qi, d_ki, d_w = jax.jit(sio.chunk_divergence_and_gradients)(*operands, target, allowed)
+    want, (want_qi, want_ki, want_w) = jax.jit(jax.value_and_grad(
+        lambda *o: sio.chunk_divergence(*o, target, allowed), (0, 1, 2)))(*operands)
+    assert float(want) > 0 and all(np.isfinite(np.asarray(g)).all() for g in (d_qi, d_ki, d_w))
+    agree(value, want, 1e-6)
+    agree(d_w, want_w, 1e-6)
+    agree(d_qi, want_qi, 5e-5)
+    agree(d_ki, want_ki, 5e-5)
+    if case == "no-held-key-but-the-diagonal":        # r is 1 where one key is allowed and the target is 1 there: no gradient
+        alone = np.arange(chunk) % 4 == 0
+        assert not np.asarray(d_qi)[alone].any() and not np.asarray(d_w)[alone].any()
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 4e-3)])    # the kernel rounds G to bf16, as the chip does
+@pytest.mark.parametrize("keys", [2048, 4096])
+def test_the_gradients_kernel_interpreted_is_the_plain_form(keys, dtype, tol):
+    """`index_alignment_kernels.gradients` at a chunk of the cell's shape, 512
+    queries of 16 heads of 64 against two and four blocks of keys, with a dI
+    that is zero on most pairs as the loss's is."""
+    rng = np.random.RandomState(61)
+    chunk, heads, width = 512, 16, 64
+    assert iak.fits(chunk, keys, heads, width) and iak._block(keys) == 1024
+    qi, ki = jnp.asarray(rng.randn(chunk, heads, width), dtype), jnp.asarray(rng.randn(keys, width), dtype)
+    w = jnp.asarray(rng.randn(chunk, heads) / 32, "f4")
+    d_scores = jnp.asarray(rng.randn(chunk, keys) * (rng.rand(chunk, keys) < 0.25), "f4")
+    got = iak.gradients(qi, ki, w, d_scores, interpret=True)
+    want = jax.jit(iak.gradients_plain)(qi, ki, w, d_scores)
+    for g, t in zip(got, want):
+        assert g.shape == t.shape and g.dtype == t.dtype == jnp.float32
+        agree(g, t, tol)
+
+
+def test_the_gradients_kernel_takes_whole_tiles_of_no_more_rows_than_a_tile_holds():
+    assert iak.fits(512, 2048, 16, 64) and iak.fits(512, 16384, 16, 64) and iak.fits(128, 128, 2, 64) and iak.fits(32, 256, 16, 8)
+    assert not iak.fits(96, 96, 16, 8)             # keys that are no whole tile
+    assert not iak.fits(1024, 1024, 16, 8)         # more rows than a head's tile in VMEM holds
+    assert not iak.fits(512, 2048, 3, 64)          # half of 128 lanes without a head
+    assert not iak.fits(512, 2048, 4, 48)          # a head across two tiles of lanes
+    assert [iak._block(keys) for keys in (2048, 4096, 1536, 384)] == [1024, 1024, 512, 128]
+
+
+def test_on_the_tpu_the_gradients_go_to_the_kernel_where_the_chunks_are_whole_tiles(monkeypatch):
+    """What `_index_alignment` hands `_alignment_row`, by the platform and the
+    shape alone, and what `lowering.index_alignment_kernel_calls` counts."""
+    def seen(qi, ki, w, q, k, lse, picks, scale, gradients):
+        chosen.append(gradients)
+        return (jnp.zeros((), jnp.float32),) + ((jnp.zeros_like(qi), jnp.zeros_like(ki), jnp.zeros_like(w)) if gradients else ())
+
+    def forms(platform, length, index_heads, index_width):
+        rng = np.random.RandomState(0)
+        qi, ki, w = indexer_operands(rng, 1, length, index_heads, index_width)
+        q, k, _ = attention_operands(rng, 1, 2, 1, length, 8)
+        ins = {"QI": qi, "KI": ki, "W": w, "Q": q, "K": k, "Lse": np.zeros((1, 2, length), "f4"),
+               "Picks": np.zeros((1, length, length // 32), "i4")}
+        op = SimpleNamespace(type="index_alignment", attr=lambda n, d=None: d)
+        ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
+
+        def run(*operands):
+            return get_op_def("index_alignment").lower(ctx, op, {**{n: [jnp.asarray(v)] for n, v in ins.items()},
+                                                                 **dict(zip(("QI", "KI", "W"), ([o] for o in operands)))})["Out"][0]
+
+        _, counted = lowering_counters(lambda: jax.grad(run, (0, 1, 2))(*(jnp.asarray(t) for t in (qi, ki, w))))
+        assert counted["lowering.index_alignment_ops"] == 1
+        return chosen[-1], counted["lowering.index_alignment_kernel_calls"]
+
+    chosen = []
+    monkeypatch.setattr(sio, "_alignment_row", seen)
+    assert forms("tpu", 4096, 2, 64) == (iak.gradients, 1)
+    assert forms("tpu", 1024, 16, 8) == (iak.gradients, 1)              # one band, two chunks of 512 x 1024 keys
+    assert forms("cpu", 4096, 2, 64) == (iak.gradients_plain, 0)
+    assert forms("tpu", 96, 2, 64) == (iak.gradients_plain, 0)          # one chunk of 96 keys: no whole tile
+    assert forms("tpu", 1280, 2, 64) == (iak.gradients_plain, 0)        # one chunk of 1280 queries: over a tile's rows
+    assert forms("tpu", 1024, 2, 8) == (iak.gradients_plain, 0)         # 16 of 128 lanes: no whole tile of qI
+
+
+def test_backward_holds_three_small_arrays_and_only_scales_them():
+    """The op's forward rule makes the three gradients with the value; what
+    backward holds is those three, of qI's, kI's and w's shapes, and no array
+    of a query and a key; `_divergence_bwd` is a multiplication by the row's
+    cotangent and nothing else."""
+    rng = np.random.RandomState(62)
+    rows, length = 2, 64
+    qi, ki, w = (jnp.asarray(t) for t in indexer_operands(rng, rows, length, 2, 8))
+    q, k, v = attention_operands(rng, rows, 4, 2, length, 16)
+    picks = lower("sparse_index", {"QI": qi, "KI": ki, "W": w}, {"topk": 16})["Picks"]
+    lse = lower("fused_attention", {"Q": q, "K": k, "V": v, "Picks": picks}, {"causal": True})["Lse"]
+
+    def term(qi, ki, w):
+        return sio._divergence(qi, ki, w, q, k, lse, picks, 0.25, iak.gradients_plain)
+
+    ki = ki[:, :, 0]
+    _, pull = jax.vjp(term, qi, ki, w)
+    backward = jax.make_jaxpr(pull)(jnp.ones((rows,), jnp.float32))
+    assert sorted(c.shape for c in backward.consts) == sorted([qi.shape, ki.shape, w.shape])
+    assert set(primitives_of(backward.jaxpr)) <= {"mul", "reshape", "broadcast_in_dim", "convert_element_type", "pjit", "jit"}
+    shapes = [v.aval.shape for eqn in backward.jaxpr.eqns for v in eqn.outvars]
+    assert not [s for s in shapes if sum(d == length for d in s) > 1]
 
 
 # -- (d) the sectioned rotation ------------------------------------------------------------------

@@ -2,9 +2,9 @@
 harness's own `benchmark.models.keye.compare` / `failed_limits` on the program's
 check rows against the float32 reference, sound and then with a fault put in,
 one at a time, so that each limit this PR brings has a reading it must refuse
-beside the sound one (PERF.md, section 6, PRs 56 and 57).  Nine faults go into THE
+beside the sound one (PERF.md, section 6, PRs 56, 57 and 59).  Nine faults go into THE
 PROGRAM (a lowering or a seam of `ops/sparse_index_ops.py` is wrapped and the
-check rows run again through a new executor), two are read on the program's own
+check rows run again through a new executor), three are read on the program's own
 fetched tensors of the stage row, one is the reference a precision lower:
 
   * `half_the_picks`: 1024 picks a query for 2048: `picks_count`;
@@ -41,6 +41,13 @@ fetched tensors of the stage row, one is the reference a precision lower:
     queries, on the stage row's first chunk: the op's own is 0.0 exactly (its
     backward rule returns none), the same term differentiated WITHOUT the
     stop_gradient reads `alignment_gradient_to_q` > 0;
+  * `alignment_gradient_without_relu_mask` (PR 59): the benchmark's `correct`
+    reads the alignment TERM and not its gradients, so the gradients have a
+    comparison of their own (`tools/chip_index_alignment.py: differences`,
+    `GRADIENT_RTOL`): on the stage row's first band's last chunk, the form the
+    platform and the shape choose (`index_alignment_kernels`) against `jax.vjp`
+    through `index_scores` (`alignment_gradient_error`, the largest of d_qI,
+    d_kI and d_w), sound, and with G formed without `[P_h > 0]`;
   * `reference_default_precision`: the reference's float32 products at the
     chip's default precision (bf16 operands): `REFERENCE_SELF_RTOL`.
 
@@ -69,7 +76,10 @@ from benchmark import manifest as mf
 from benchmark.models import keye, lfm2
 from benchmark.runners.train import CHECK_ROWS
 from paddle_tpu.core.registry import get_op_def
+from paddle_tpu.ops import index_alignment_kernels as iak
 from paddle_tpu.ops import sparse_index_ops as sio
+
+import chip_index_alignment as alone      # tools/ is this script's directory
 
 TINY = (dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16, moe_intermediate_size=32, num_experts=2,
              num_routed_experts=8, experts_held_first=2, num_experts_per_tok=2, vocab_size=96, num_hidden_layers=2,
@@ -198,29 +208,53 @@ def choice_again(got, cfg):
             "gradient_change_same_choice": float(jnp.abs(one - dq(first)).max() / jnp.abs(one).max())}
 
 
-def target_not_detached(got, cfg):
-    """See `target_not_detached` above: the first chunk of the stage row."""
+def chunk_of_the_stage_row(got, cfg, last=False):
+    """(qI, kI, the scaled w, q, k, allowed, `lse_of`, the attention's scale) of
+    the first chunk of the stage row's first band, or the band's `last`: its
+    queries against the band's keys."""
     qi, ki, w, picks, q, k, _ = on_the_stage_row(got, cfg, cfg["num_hidden_layers"])
     chunk = min(sio.CHUNK, q.shape[1])
     keys = min(sio.BAND, q.shape[1])
-    allowed = sio.unpack_bits(picks[:chunk], q.shape[1])[:, :keys]
-    weights = sio.scaled_weights(w, qi.shape[1], qi.shape[2])[:chunk]
+    rows = slice(keys - chunk, keys) if last else slice(0, chunk)
+    allowed = sio.unpack_bits(picks[rows], q.shape[1])[:, :keys]
+    weights = sio.scaled_weights(w, qi.shape[1], qi.shape[2])[rows]
     scale = q.shape[-1] ** -0.5
 
     def lse_of(q):
-        s = jnp.einsum("hcd,hkd->hck", q[:, :chunk], jnp.repeat(k[:, :keys], q.shape[0] // k.shape[0], 0),
+        s = jnp.einsum("hcd,hkd->hck", q, jnp.repeat(k[:, :keys], q.shape[0] // k.shape[0], 0),
                        preferred_element_type=jnp.float32) * scale
         return jax.nn.logsumexp(jnp.where(allowed, s, -jnp.inf), -1)
 
+    return qi[rows], ki[:keys, 0], weights, q[:, rows], k[:, :keys], allowed, lse_of, scale
+
+
+def target_not_detached(got, cfg):
+    """See `target_not_detached` above."""
+    qi, ki, weights, q, k, allowed, lse_of, scale = chunk_of_the_stage_row(got, cfg)
+
     def term(q, detached):
-        target = sio.attention_target(q[:, :chunk], k[:, :keys], jax.lax.stop_gradient(lse_of(q)), allowed, scale)
+        target = sio.attention_target(q, k, jax.lax.stop_gradient(lse_of(q)), allowed, scale)
         target = jax.lax.stop_gradient(target) if detached else target
-        return sio.chunk_divergence(qi[:chunk], ki[:keys, 0], weights, target, allowed) / chunk
+        return sio.chunk_divergence(qi, ki, weights, target, allowed) / q.shape[1]
 
     sound = jax.jit(jax.grad(lambda q: term(q, True)))(q)
     faulty = jax.jit(jax.grad(lambda q: term(q, False)))(q)
     return {"alignment_gradient_to_q": float(jnp.abs(sound.astype(jnp.float32)).max()),
             "alignment_gradient_to_q_not_detached": float(jnp.abs(faulty.astype(jnp.float32)).max())}
+
+
+def gradient_without_relu_mask(got, cfg):
+    """See `alignment_gradient_without_relu_mask` above."""
+    qi, ki, weights, q, k, allowed, lse_of, scale = chunk_of_the_stage_row(got, cfg, last=True)
+    operands = (qi, ki, weights, sio.attention_target(q, k, lse_of(q), allowed, scale), allowed)
+    name = "kernel" if jax.default_backend() == "tpu" and iak.fits(qi.shape[0], ki.shape[0], *qi.shape[1:]) else "plain"
+    want = jax.jit(alone.by_vjp)(*operands)
+    sound = alone.differences(jax.jit(alone.FORMS[name])(*operands), want)
+    with alone.without_relu_mask():
+        faulty = alone.differences(jax.jit(alone.FORMS[name])(*operands), want)
+    return {"form": name, "limit": alone.GRADIENT_RTOL,
+            "alignment_gradient_error": max(sound[n] for n in alone.NAMES[1:]), "sound": sound,
+            "alignment_gradient_error_without_relu_mask": max(faulty[n] for n in alone.NAMES[1:]), "faulty": faulty}
 
 
 def main(seed: int, only=()):
@@ -261,6 +295,9 @@ def main(seed: int, only=()):
         print(json.dumps({"control": "choice_again_from_bf16_scores", "seed": seed, **choice_again(sound, cfg)}), flush=True)
     if not only or "target_not_detached" in only:
         print(json.dumps({"control": "target_not_detached", "seed": seed, **target_not_detached(sound, cfg)}), flush=True)
+    if not only or "alignment_gradient_without_relu_mask" in only:
+        print(json.dumps({"control": "alignment_gradient_without_relu_mask", "seed": seed, **gradient_without_relu_mask(sound, cfg)}),
+              flush=True)
     if not only or "reference_default_precision" in only:
         report("reference_default_precision", sound, reference(precision="default"))
     for name, fault in faults(cfg).items():
