@@ -906,13 +906,23 @@ def rotary_embedding(x, positions, theta=10000.0, name=None, layout="bhld", inte
 def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None,
         scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0,
-        shared_experts=0, shared_attrs=None):
+        shared_experts=0, shared_attrs=None, activation="silu", gated=True, latent_size=None, latent_attrs=None,
+        shared_width=None):
     """A layer of routed experts over (..., d): a float32 router picks
-    `top_k` of `num_experts` gated-SiLU experts of width `expert_width` for
-    every token; their outputs are summed, weighted by the router's
-    scores (renormalised over the chosen ones only if `norm_topk_prob`, by
-    their sum + `norm_eps`; times `routed_scaling_factor`).  No capacity
-    limit: no token is dropped.
+    `top_k` of `num_experts` experts of width `expert_width` for every token;
+    their outputs are summed, weighted by the router's scores (renormalised
+    over the chosen ones only if `norm_topk_prob`, by their sum + `norm_eps`;
+    times `routed_scaling_factor`).  No capacity limit: no token is dropped.
+
+    The experts' form is two attributes of the one op `moe_experts`: `gated`
+    (the default) with `activation="silu"` is W_down(silu(W_gate x) * (W_up x)),
+    three matrices an expert; `gated=False` is W_down(act(W_up x)), TWO matrices
+    and no gate; `activation="relu2"` is relu(.)^2.  `latent_size=L` puts the
+    experts in a latent: the layer projects the token into L dimensions ONCE (u
+    = x W_in, `latent_attrs[0]`), the router still reads x, every expert's
+    matrices are (L, F) and (F, L), and the weighted sum is projected back once
+    (r W_out, `latent_attrs[1]`): once a token, not once a slot.  The two
+    projections and the op stand in the scope `latent_experts`.
 
     `scoring` "softmax" scores a token's experts by the softmax over them,
     "sigmoid" each by its own sigmoid.  `bias_attr` (a `ParamAttr`: name and
@@ -924,8 +934,8 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
 
     Returns (out, load_balance_loss, router_z_loss); the two [1] float32
     losses are for the caller to weigh into the training loss.  The experts
-    are three stacked parameters, (E, d, F) gate and up and (E, F, d)
-    down.
+    are stacked parameters, (E, d, F) gate (where gated) and up and (E, F, d)
+    down, d the latent's width where there is one.
 
     `held=(first, count)` is a layer that holds a share of its experts, as
     one chip of several that split the layer does: the stacked parameters are
@@ -938,19 +948,62 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     expert is dropped.  `held=None` holds them all.
 
     `shared_experts=n` adds beside the routed sum what n shared experts
-    compute: one gated-SiLU feed-forward of width n x `expert_width` that EVERY
-    token passes, added once and unweighted (`shared_attrs` = the ParamAttrs of
-    its gate, up and down matrices; three `mul` ops under the scope
-    `shared_expert`).  It is outside the held path: where several chips split
-    the routed experts each computes the shared one alike."""
+    compute: one feed-forward of the experts' form (gate and activation) and of
+    width n x `expert_width`, or `shared_width` where given, that EVERY token
+    passes at the layer's OWN width (never in the latent), added once and
+    unweighted (`shared_attrs` = the ParamAttrs of its gate, up and down
+    matrices; `mul` ops under the scope `shared_expert`).  It is outside the
+    held path: where several chips split the routed experts each computes the
+    shared one alike."""
+    import contextlib
+
+    from ..core.program import name_scope
+
     helper = LayerHelper("moe", name=name)
+    if activation not in ("silu", "relu2"):
+        raise ValueError(f"moe: activation={activation!r}; \"silu\" or \"relu2\"")
+    hidden_in, lead = input, tuple(input.shape[:-1])
+    in_latent = name_scope("latent_experts") if latent_size else contextlib.nullcontext()
+    with in_latent:
+        if latent_size:
+            input = fc(hidden_in, int(latent_size), num_flatten_dims=len(lead), bias_attr=False,
+                       param_attr=(latent_attrs or (None, None))[0])
+        out, balance, z_loss = _routed_experts(
+            helper, hidden_in, input, num_experts, expert_width, top_k, norm_topk_prob, router_attr, gate_attr, up_attr,
+            down_attr, held, scoring, bias_attr, routed_scaling_factor, norm_eps, shared_experts, activation, gated)
+        if latent_size:
+            out = fc(out, int(hidden_in.shape[-1]), num_flatten_dims=len(lead), bias_attr=False,
+                     param_attr=(latent_attrs or (None, None))[1])
+    if shared_experts:
+        gate_a, up_a, down_a = shared_attrs or (None, None, None)
+        width = int(shared_width or int(shared_experts) * expert_width)
+
+        def project(t, size, attr, act=None):
+            return fc(t, size, num_flatten_dims=len(lead), act=act, param_attr=attr, bias_attr=False)
+
+        with name_scope("shared_expert"):
+            act = {"silu": "swish", "relu2": "relu"}[activation]
+            hidden = project(hidden_in, width, gate_a if gated else up_a, act=act)
+            if activation == "relu2":
+                hidden = square(hidden)
+            if gated:
+                hidden = elementwise_mul(hidden, project(hidden_in, width, up_a))
+            out = elementwise_add(out, project(hidden, int(hidden_in.shape[-1]), down_a))
+    return _keep_lod(hidden_in, out), balance, z_loss
+
+
+def _routed_experts(helper, routed_on, input, num_experts, expert_width, top_k, norm_topk_prob, router_attr, gate_attr,
+                    up_attr, down_attr, held, scoring, bias_attr, routed_scaling_factor, norm_eps, shared_experts,
+                    activation, gated):
+    """`moe`'s two ops: the router on `routed_on` (the layer's input) and the
+    experts on `input` (the same, or its projection into the latent)."""
     d = int(input.shape[-1])
     lead = tuple(input.shape[:-1])
     n_held = num_experts if held is None else int(held[1])
     if held is not None and not (0 <= held[0] and 0 < held[1] and held[0] + held[1] <= num_experts):
         raise ValueError(f"moe: held = {tuple(held)} is no range of the {num_experts} experts")
-    router = helper.create_parameter(router_attr, [d, num_experts], "float32")
-    gate = helper.create_parameter(gate_attr, [n_held, d, expert_width], input.dtype)
+    router = helper.create_parameter(router_attr, [int(routed_on.shape[-1]), num_experts], "float32")
+    gate = helper.create_parameter(gate_attr, [n_held, d, expert_width], input.dtype) if gated else None
     up = helper.create_parameter(up_attr, [n_held, d, expert_width], input.dtype)
     down = helper.create_parameter(down_attr, [n_held, expert_width, d], input.dtype)
     top_p = _out(helper, "float32", shape=lead + (top_k,))
@@ -958,7 +1011,7 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     load = _out(helper, "int32", shape=(num_experts,))
     balance = _out(helper, "float32", shape=(1,))
     z_loss = _out(helper, "float32", shape=(1,))
-    router_inputs = {"X": [input.name], "W": [router.name]}
+    router_inputs = {"X": [routed_on.name], "W": [router.name]}
     router_outputs = {"TopKProb": [top_p.name], "TopKIndex": [top_i.name], "Load": [load.name],
                       "LoadBalanceLoss": [balance.name], "ZLoss": [z_loss.name]}
     router_attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
@@ -982,23 +1035,18 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         attrs["held"] = [int(held[0]), int(held[1])]
     if shared_experts:
         attrs["shared_experts"] = int(shared_experts)
+    # what the 2024 experts do not have is an attribute only where it is asked for, as the router's are
+    if activation != "silu":
+        attrs["activation"] = str(activation)
+    if not gated:
+        attrs["gated"] = False
     helper.append_op(
         "moe_experts",
         inputs={"X": [input.name], "TopKProb": [top_p.name], "TopKIndex": [top_i.name],
-                "Load": [load.name], "WGate": [gate.name], "WUp": [up.name],
+                "Load": [load.name], **({"WGate": [gate.name]} if gated else {}), "WUp": [up.name],
                 "WDown": [down.name]},
         outputs=outputs, attrs=attrs)
-    if shared_experts:
-        from ..core.program import name_scope
-
-        gate_a, up_a, down_a = shared_attrs or (None, None, None)
-        width = int(shared_experts) * expert_width
-        with name_scope("shared_expert"):
-            hidden = elementwise_mul(
-                fc(input, width, num_flatten_dims=len(lead), act="swish", param_attr=gate_a, bias_attr=False),
-                fc(input, width, num_flatten_dims=len(lead), param_attr=up_a, bias_attr=False))
-            out = elementwise_add(out, fc(hidden, d, num_flatten_dims=len(lead), param_attr=down_a, bias_attr=False))
-    return _keep_lod(input, out), balance, z_loss
+    return out, balance, z_loss
 
 
 def _persistable_tensor(helper, attr, shape, dtype):
@@ -1105,6 +1153,37 @@ def selective_scan(x, dt, b, c, a_log_attr=None, d_attr=None, dt_bias_attr=None,
                      inputs={"X": [x.name], "Dt": [dt.name], "ALog": [a_log.name], "B": [b.name], "C": [c.name],
                              "D": [d_skip.name], "DtBias": [dt_bias.name]},
                      outputs={"Out": [out.name], "Stats": [stats.name]})
+    return out
+
+
+def ssd_scan(x, dt, b, c, heads, groups=1, chunk=128, a_log_attr=None, d_attr=None, dt_bias_attr=None, name=None):
+    """A Mamba-2 mixer's scalar-decay state-space scan over the sequence
+    (`ops/ssd_ops.py`): x (b, T, heads x P), the step's projection dt (b, T,
+    heads), the input and output matrices b, c (b, T, groups x N) a token; a head
+    keeps a float32 state [P, N], h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t
+    with dt_t = softplus(dt + dt_bias) and A = -exp(A_log) ONE scalar a head,
+    head h reading the B and C of group h // (heads / groups), and returns y_t =
+    h_t C_t + D x_t, (b, T, heads x P) in x's dtype.  `A_log`, `D` and `dt_bias`
+    [heads] are float32 parameters of the op (`a_log_attr`, `d_attr`,
+    `dt_bias_attr`).  Computed `chunk` tokens at a time as matrix products, the
+    state carried chunk to chunk; any T.  The state starts at zero with every
+    row: sequences are whole.  The op's other outputs: `State`, the float32
+    state after the last token, (b, heads, P, N), and `Stats` (mean decay, mean
+    step, largest |h| at the end), published a logged step by `train_loop` as a
+    `kind="ssd_state"` record."""
+    helper = LayerHelper("ssd_scan", name=name)
+    a_log = helper.create_parameter(a_log_attr, [int(heads)], "float32")
+    d_skip = helper.create_parameter(d_attr, [int(heads)], "float32")
+    dt_bias = helper.create_parameter(dt_bias_attr, [int(heads)], "float32", is_bias=True)
+    out = _out(helper, x.dtype, shape=tuple(x.shape))
+    state = _out(helper, "float32",
+                 shape=(x.shape[0], int(heads), int(x.shape[-1]) // int(heads), int(b.shape[-1]) // int(groups)))
+    stats = _out(helper, "float32", shape=(3,))
+    helper.append_op("ssd_scan",
+                     inputs={"X": [x.name], "Dt": [dt.name], "ALog": [a_log.name], "B": [b.name], "C": [c.name],
+                             "D": [d_skip.name], "DtBias": [dt_bias.name]},
+                     outputs={"Out": [out.name], "State": [state.name], "Stats": [stats.name]},
+                     attrs={"groups": int(groups), "chunk": int(chunk)})
     return out
 
 

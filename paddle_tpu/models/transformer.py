@@ -355,6 +355,55 @@ def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kerne
         return project(layers.elementwise_mul(y, layers.swish(z)), "out", d_model)
 
 
+def mamba2_mixer(x, d_model, prefix, heads, head_dim, state, groups=1, conv_kernel=4, chunk=128, norm_eps=1e-5,
+                 taps_bound=None):
+    """The Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060) round the op
+    `ssd_scan`: ONE in-projection [z, xBC, dt] = split(x W_in), `heads` x
+    `head_dim` (the inner width), the inner width + 2 x `groups` x `state`, and
+    `heads` wide; xBC = silu(conv(xBC) + b), a depthwise causal convolution of
+    `conv_kernel` taps over xs, B and C TOGETHER (`short_conv`'s plain mode with
+    its bias); [xs, B, C] = split(xBC); the recurrence with one scalar decay a
+    head (the step's bias and its softplus are the op's, float32), `chunk`
+    tokens at a time; then the GATED norm, which gates before it norms and norms
+    by group: rms(y * silu(z)) over each of the `groups` groups of inner /
+    groups channels, one gain a channel; projected back.  No other biases.  A =
+    1 .. 16 evenly over the heads (A_log its logarithm: Mamba-2 draws A
+    U(1, 16)), D = 1 and the step's bias the inverse softplus of a log-uniform
+    draw on [1e-3, 1e-1], as Mamba-2 initialises them.  `taps_bound` = b draws
+    the convolution's taps U(-b, b) and not N(0, 0.02) with the other weights,
+    as `mamba_mixer`'s does: Mamba-2's own code leaves them at `nn.Conv1d`'s
+    default, b = conv_kernel^-0.5, and it is the taps' size that decides how
+    much of the output the state carries beside the skip D x."""
+    inner = heads * head_dim
+    wide = groups * state
+
+    def project(t, name, out):
+        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"), bias_attr=False)
+
+    def part(t, lo, hi):
+        return layers.slice(t, axes=[2], starts=[lo], ends=[hi])
+
+    with name_scope("mamba2"):
+        both = project(x, "in", 2 * inner + 2 * wide + heads)
+        z, dt = part(both, 0, inner), part(both, 2 * inner + 2 * wide, 2 * inner + 2 * wide + heads)
+        taps = _attr(f"{prefix}.conv.w") if taps_bound is None else ParamAttr(
+            name=f"{prefix}.conv.w", initializer=UniformInitializer(-float(taps_bound), float(taps_bound)))
+        xbc = layers.short_conv(part(both, inner, 2 * inner + 2 * wide), conv_kernel, gated=False, activation="silu",
+                                filter_attr=taps,
+                                bias_attr=ParamAttr(name=f"{prefix}.conv.b", initializer=ConstantInitializer(0.0)))
+        y = layers.ssd_scan(
+            part(xbc, 0, inner), dt, part(xbc, inner, inner + wide), part(xbc, inner + wide, inner + 2 * wide),
+            heads, groups=groups, chunk=chunk,
+            a_log_attr=ParamAttr(name=f"{prefix}.a_log", initializer=NumpyArrayInitializer(
+                np.log(np.linspace(1.0, 16.0, heads)).astype("float32"))),
+            d_attr=ParamAttr(name=f"{prefix}.d", initializer=ConstantInitializer(1.0)),
+            dt_bias_attr=ParamAttr(name=f"{prefix}.dt_bias", initializer=SoftplusInverseLogUniformInitializer(1e-3, 1e-1)))
+        gated = layers.reshape(layers.elementwise_mul(y, layers.swish(z)), [0, 0, groups, inner // groups])
+        normed = layers.reshape(layers.rms_norm(gated, begin_norm_axis=3, epsilon=norm_eps, param_attr=False), [0, 0, inner])
+        normed = layers.elementwise_mul(normed, layers.create_parameter([inner], x.dtype, attr=_attr_ones(f"{prefix}.norm.w")))
+        return project(normed, "out", d_model)
+
+
 def gated_memory_unit(x, memory, d_model, prefix):
     """A Gated Memory Unit (SambaY, Ren et al. 2025, arXiv:2507.06607): out =
     (silu(x W1) * m) W2, m the scan output (b, T, width) that an earlier
@@ -395,7 +444,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     v_dim=), with `rope=True` the layer's rotary embedding on `positions` at
     `rope_theta`, and `rope_interleave=`) and `operator="mamba"` a Mamba-1 mixer (`mamba_mixer`;
     `operator_args` = dict(expand=, state=, dt_rank=, inner_norms=, taps_bound=), its
-    convolution of `conv_kernel` taps).  `unit_norms` starts a layer norm at
+    convolution of `conv_kernel` taps) and `operator="mamba2"` a Mamba-2 mixer
+    (`mamba2_mixer`; `operator_args` = dict(heads=, head_dim=, state=, groups=,
+    chunk=, taps_bound=)).  `unit_norms` starts a layer norm at
     gain 1 and bias 0, as a decoder's sources do (BERT's are drawn: PERF.md
     section 7, defect 3).
 
@@ -414,6 +465,11 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     that receives the call which makes its alignment term
     (`multi_head_attention(sparse_index=)`).
 
+    A layer of ONE part (a pre-norm layer only: y = x + part(norm(x))):
+    `ffn=None` is an operator with no feed-forward part (its norm `ln1`),
+    `operator=None` a feed-forward part with no operator (its norm `ln2`); a
+    layer with neither is refused.
+
     The feed-forward part: `ffn="gelu"` is BERT's biased pair, `"gated_silu"`
     W2(silu(W1 x) * (W3 x)) without biases, both `d_ff` wide;
     `moe=dict(num_experts=, top_k=, norm_topk_prob=, held=)` makes it
@@ -422,9 +478,16 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `scoring`, `routed_scaling_factor`, `norm_eps` and `bias` = (standard
     deviation, seed) of a router bias that enters the choice alone;
     `shared_experts`: how many shared experts every token passes beside the
-    routed ones: `layers.moe`); its two auxiliary losses are appended to
+    routed ones; `activation`, `gated`, `latent_size`, `shared_width`: the
+    experts' form, the latent they live in and the shared expert's own width:
+    `layers.moe`); its two auxiliary losses are appended to
     `aux_losses` as (load balance, router z).
     """
+    if (operator is None or ffn is None) and not pre_norm:
+        raise ValueError("encoder_layer: a layer of one part (operator=None or ffn=None) is a pre-norm layer, "
+                         "y = x + part(norm(x))")
+    if operator is None and ffn is None and moe is None:
+        raise ValueError("encoder_layer: operator=None and ffn=None leave the layer no part")
     def normed(t, name):
         if norm == "rms":
             return layers.rms_norm(t, begin_norm_axis=2, epsilon=norm_eps,
@@ -450,9 +513,13 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                 norm_eps=moe.get("norm_eps", 0.0),
                 bias_attr=bias and _attr(f"{prefix}.moe.router.bias", *bias),
                 shared_experts=moe.get("shared_experts", 0),
-                shared_attrs=tuple(_attr(f"{prefix}.moe.shared.{n}.w") for n in ("gate", "up", "down")))
+                shared_attrs=tuple(_attr(f"{prefix}.moe.shared.{n}.w") for n in ("gate", "up", "down")),
+                **{n: moe[n] for n in ("activation", "gated", "latent_size", "shared_width") if n in moe},
+                **({"latent_attrs": tuple(_attr(f"{prefix}.moe.latent_{n}.w") for n in ("in", "out"))}
+                   if moe.get("latent_size") else {}))
             aux_losses.append((balance, z_loss))
             return out
+
         if ffn == "gated_silu":
             def project(u, name, width, act=None):
                 return layers.fc(u, width, num_flatten_dims=2, act=act, bias_attr=False,
@@ -465,6 +532,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         return layers.fc(ffn1, d_model, num_flatten_dims=2,
                          param_attr=_attr(f"{prefix}.ffn2.w"), bias_attr=_attr(f"{prefix}.ffn2.b"))
 
+    if operator is None:   # a feed-forward part with no operator
+        return layers.elementwise_add(x, feed_forward(normed(x, "ln2")))
     operator_in = normed(x, "ln1") if pre_norm else x
     if operator == "conv":
         attn_out = layers.short_conv(operator_in, conv_kernel, in_attr=_attr(f"{prefix}.conv.in.w"),
@@ -481,6 +550,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     elif operator == "mamba":
         attn_out = mamba_mixer(operator_in, d_model, f"{prefix}.mamba", conv_kernel=conv_kernel, norm_eps=norm_eps,
                                keep=keep, **operator_args)
+    elif operator == "mamba2":
+        attn_out = mamba2_mixer(operator_in, d_model, f"{prefix}.mamba2", conv_kernel=conv_kernel, norm_eps=norm_eps,
+                                **operator_args)
     elif operator == "gmu":
         attn_out = gated_memory_unit(operator_in, kept["memory"], d_model, f"{prefix}.gmu")
     else:
@@ -504,6 +576,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     if post_norm:
         attn_out = normed(attn_out, "post_ln1")
     x = layers.elementwise_add(x, attn_out)
+    if ffn is None and moe is None:   # an operator with no feed-forward part
+        return x
     if not pre_norm:
         x = normed(x, "ln1")
     ffn_out = feed_forward(normed(x, "ln2") if pre_norm else x)
@@ -623,6 +697,8 @@ def build_causal_lm(
     memory_layer=None,
     kv_layer=None,
     sparse_index=None,
+    mamba2=None,
+    expert_form=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -735,6 +811,20 @@ def build_causal_lm(
     that backward makes again reads it (`ops/sparse_index_ops.py`); the
     alignment op stands after the layer's segment and is made once.
 
+    A stack whose layers have ONE part each (a hybrid of the Nemotron-H kind: y
+    = x + part(norm(x)), the part a state-space mixer, an attention or the
+    experts) is arguments too.  `layer_types` may hold "mamba2" (a Mamba-2
+    mixer, `mamba2` = dict(heads=, head_dim=, state=, groups=, chunk=): a
+    scalar-decay scan as matrix products, `mamba2_mixer`, its convolution of
+    `conv_kernel` taps) and "feed_forward" (NO operator: the layer is its
+    feed-forward part alone, dense or the experts as `num_dense_layers` says);
+    a stack that holds a "feed_forward" layer is such a stack, and every layer of
+    it that HAS an operator has no feed-forward part.  `expert_form` = dict(activation="relu2", gated=False,
+    latent_size=, shared_width=) gives the experts' own form where it is not
+    the gated-SiLU one at the model's width (`layers.moe`: two matrices an
+    expert with relu(.)^2 between, a latent the layer projects into and out of
+    once a token, a shared expert of its own width).
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -760,16 +850,21 @@ def build_causal_lm(
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
     operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention",
                  "mamba": "mamba", "sliding_attention": "attention", "gmu": "gmu", "cross_attention": "cross_attention",
-                 "sparse_attention": "attention"}
-    operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba}
+                 "sparse_attention": "attention", "mamba2": "mamba2", "feed_forward": None}
+    operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba,
+                     "mamba2": mamba2}
     unknown = sorted(set(kinds) - set(operators))
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
-                         "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention, or sparse_attention")
+                         "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention, or sparse_attention, "
+                         "mamba2 or feed_forward")
     if (("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent)
-            or ("mamba" in kinds and not mamba)):
+            or ("mamba" in kinds and not mamba) or ("mamba2" in kinds and not mamba2)):
         raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer "
-                         "latent=, a mamba layer mamba=")
+                         "latent=, a mamba layer mamba=, a mamba2 layer mamba2=")
+    one_part = "feed_forward" in kinds
+    if loop is not None and one_part:
+        raise ValueError("build_causal_lm: loop= with a feed_forward layer: a looped stack's layers have both parts")
     if "sparse_attention" in kinds and not (sparse_index and all(sparse_index.get(n, 0) >= 1
                                                                  for n in ("heads", "head_dim", "topk"))):
         raise ValueError("build_causal_lm: a sparse_attention layer needs sparse_index=dict(heads=, head_dim=, topk=), "
@@ -826,7 +921,8 @@ def build_causal_lm(
                                scoring=scoring, routed_scaling_factor=routed_scaling_factor,
                                norm_eps=norm_topk_eps,
                                bias=expert_bias and (expert_bias[0], expert_bias[1] + i),
-                               shared_experts=shared_experts)
+                               shared_experts=shared_experts, **(expert_form or {}))
+                has_ffn = not (one_part and operators[kind] is not None)   # a layer of one part with an operator has none
                 pending = []        # the calls that make a sparse-attention layer's alignment term, AFTER its segment
                 with recompute_scope() if recompute_layers else contextlib.nullcontext():
                     x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
@@ -835,7 +931,7 @@ def build_causal_lm(
                                       use_fused_attention=use_fused_attention,
                                       norm=norm, unit_norms=True, norm_eps=norm_eps, pre_norm=True, proj_bias=proj_bias,
                                       qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
-                                      moe=None if dense else experts, ffn="gated_silu",
+                                      moe=experts if has_ffn and not dense else None, ffn="gated_silu" if has_ffn else None,
                                       aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
                                       attention_mask=window or attention_mask,
                                       operator=operators[kind], operator_args=operator_args.get(kind),

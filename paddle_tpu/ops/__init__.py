@@ -11,6 +11,7 @@ from . import (  # noqa: F401
     pipeline_ops,
     sequence_ops,
     sparse_index_ops,
+    ssd_ops,
     ssm_ops,
     tail_ops,
     tensor_ops,

@@ -15,6 +15,7 @@ short convolution LFM2's:
     moe_router        p = softmax_f32(x Wr); (p_e, e) = top_k(p); two auxiliary losses
                       or s = sigmoid_f32(x Wr); e = top_k(s + b); p_e = s_e (the unbiased score)
     moe_experts       y = sum_{e in top_k} p_e . Wdown_e( silu(Wgate_e x) * (Wup_e x) )
+                      or, `gated=False, activation="relu2"`:  p_e . Wdown_e( relu(Wup_e x)^2 ), two matrices an expert
     short_conv        y = C * conv_K(B * u),  [B, C, u] = split3(x),  conv_K causal and depthwise
     exit_loss         p_t = sigmoid(g_t) prod_{j<t} (1 - sigmoid(g_j)),  p_T the rest;
                       loss = mean( sum_t p_t CE_t - beta H(p) )           (Ouro's stage-one objective)
@@ -35,7 +36,11 @@ one TPU device (`ops/moe_kernels.py`, `_token_sum_path`): as XLA's gather and
 sum it wrote [tokens, k, hidden] at a third of the HBM's speed and read it
 again, 5.4 ms a call, where the kernel brings a block of tokens' rows into VMEM
 run by run and sums them there, 1.56 ms (PERF.md, PR 49); XLA's form stays the
-CPU's, a mesh's and the odd shapes' path, and what the tests hold the kernel to.
+CPU's and the odd shapes' path, and what the tests hold the kernel to.  Under a
+mesh whose batch axis splits the rows and nothing else the whole of `moe_experts`
+(the sort, the grouped products, the way back) runs on a chip's own rows inside
+`over_batch_shards`, the matrices handed in whole (ZeRO-3's gather): a
+`pallas_call` GSPMD cannot partition would run ALL rows on every chip.
 The router's weights multiply the hidden rows, in expert order, so nothing
 else passes over a [rows, hidden] array, forward or backward.  The matrices
 are float32 masters: `grouped_matmul` casts each once for the forward and
@@ -55,7 +60,8 @@ from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import moe_kernels
-from .common import counted_rules, first, kept_residuals, match_dtype, residuals_name, rotary_angles
+from .common import (batch_shards, counted_rules, first, kept_residuals, match_dtype, over_batch_shards, residuals_name,
+                     rotary_angles)
 
 
 @register_op("rms_norm")
@@ -318,25 +324,30 @@ def _take_rows(x, index):
 # order[i], of token order[i] // k.  `kernel`: None for the `jax.numpy` form of
 # the way back, else `_token_sum_path`'s (experts, interpreted).
 
-def _token_sum_path(platform, mesh, x, k, experts):
+def _token_sum_path(platform, mesh, x, k, experts, on_own_rows=False):
     """How `_sum_by_token` is lowered for tokens `x` [T, d] of k rows each:
     "kernel" (`ops/moe_kernels.py`: a block of tokens' rows brought into VMEM
-    run by run and summed there by a 0/1 product) on the TPU, on one device (a
-    `pallas_call` cannot be partitioned: `nn_ops._attention_path`'s rule), where
+    run by run and summed there by a 0/1 product) on the TPU, where a chip has
+    its rows to itself (a `pallas_call` cannot be partitioned: `nn_ops.
+    _attention_path`'s rule): on one device, or `on_own_rows`, inside the
+    `shard_map` that `moe_experts` opens under a mesh which splits the rows and
+    nothing else (`x` is then the chip's own tokens); and where
     `moe_kernels.fits`: a row is whole lane tiles, the tokens whole blocks, bf16
     or float32, and the two buffers fit; else "xla", the gather and the sum
-    below: the CPU's path, a mesh's, the odd shapes', and what the tests hold
-    the kernel to.  TPU v5e, (131072, 2048) bf16 rows, k = 8: PERF.md, PR 49."""
-    one_device = mesh is None or mesh.size == 1
-    return "kernel" if platform == "tpu" and one_device and moe_kernels.fits(*x.shape, k, x.dtype, experts) else "xla"
+    below: the CPU's path, any other mesh's (GSPMD partitions it by itself), the
+    odd shapes', and what the tests hold the kernel to.  TPU v5e, (131072, 2048)
+    bf16 rows, k = 8: PERF.md, PR 49."""
+    to_itself = mesh is None or mesh.size == 1 or on_own_rows
+    return "kernel" if platform == "tpu" and to_itself and moe_kernels.fits(*x.shape, k, x.dtype, experts) else "xla"
 
 
-def _token_sum_kernel(ctx, x, k, groups):
+def _token_sum_kernel(ctx, x, k, groups, on_own_rows=False):
     """`_sum_by_token`'s and `_add_to_tokens`' `kernel` for tokens `x` of k slots
     each over `groups` groups (a layer's experts, or the ones it holds): None
     where `_token_sum_path` says XLA's form, else (groups, interpreted).
     "interpret" is the tests': the kernel interpreted where no chip is."""
-    return {"kernel": (groups, False), "interpret": (groups, True)}.get(_token_sum_path(ctx.platform, ctx.mesh, x, k, groups))
+    path = _token_sum_path(ctx.platform, ctx.mesh, x, k, groups, on_own_rows)
+    return {"kernel": (groups, False), "interpret": (groups, True)}.get(path)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -473,6 +484,20 @@ def grouped_matmul(rows, weights, group_sizes, platform=None):
     return _grouped_matmul(rows, weights, group_sizes, platform != "tpu")
 
 
+def _activated(products, weight, activation):
+    """An expert's hidden rows in float32 from its first products' outputs: the
+    activation of the first (`"silu"`, or `"relu2"`: relu(.)^2), times the
+    second where the expert is gated, times the row's router weight."""
+    def wide(t):   # float32, each where it is first read
+        return t if t.dtype == jnp.float32 else t.astype(jnp.float32)
+
+    opened, *gated_by = products
+    hidden = jax.nn.silu(wide(opened)) if activation == "silu" else jnp.square(jax.nn.relu(wide(opened)))
+    for other in gated_by:
+        hidden = hidden * wide(other)
+    return hidden * weight
+
+
 @register_op("moe_experts")
 def _moe_experts(ctx, op, ins):
     """Every (token, slot) assignment is a row: the rows are sorted by
@@ -484,44 +509,75 @@ def _moe_experts(ctx, op, ins):
     capacity, so no dropped token, however skewed the router: `Dropped` is
     the number of rows the group sizes do not cover, 0 by construction.
 
-    With the attribute `held` = (first, count) the three matrices are those
+    The experts' form is two attributes: `gated` (the default: inputs WGate,
+    WUp, WDown, hidden = act(gate) * up) or not (WUp and WDown alone, hidden =
+    act(up)), and `activation`, "silu" (the default) or "relu2", relu(.)^2.
+
+    With the attribute `held` = (first, count) the matrices are those
     of `count` experts from `first` on, and what the absent experts would
     have added is left out (`_held_experts`): `Held` is then the number of
     assignments that fell on held experts, `Dropped` those of them no pass
-    covered, 0 by construction.  Without it, every expert: today's layer."""
+    covered, 0 by construction.  Without it, every expert: today's layer.
+
+    Under a mesh whose batch axis splits the rows and nothing else
+    (`batch_shards`) all of this runs on a chip's own rows inside
+    `over_batch_shards`: the group sizes are the chip's own counts, the matrices
+    are handed in whole, and `Held` and `Dropped` are summed over the axis."""
     x = first(ins, "X")
     top_p = first(ins, "TopKProb")
     top_i = first(ins, "TopKIndex")
     load = first(ins, "Load")
-    w_gate, w_up, w_down = (first(ins, s) for s in ("WGate", "WUp", "WDown"))
+    gated, activation = op.attr("gated", True), op.attr("activation", "silu")
+    matrices = tuple(first(ins, s) for s in (("WGate",) if gated else ()) + ("WUp", "WDown"))
     d, k = x.shape[-1], top_i.shape[-1]
-    x2 = x.reshape(-1, d)
-    tokens = x2.shape[0]
     held = op.attr("held", None)
     if op.attr("shared_experts", 0):   # the layer's builder computes them beside this op, every token, once
         _MON.counter("lowering.shared_expert_layers").inc()
-    keep = kept_residuals(ctx, op)   # the name under which a recomputed segment keeps the gate and up products' outputs
-    if held is not None:
-        out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load, (w_gate, w_up, w_down),
-                                            tuple(held), ctx.platform, _token_sum_kernel(ctx, x2, k, held[1]), keep)
-        return {"Out": out.reshape(x.shape), "Dropped": missed.astype(jnp.int32).reshape((1,)),
-                "Held": n_held.astype(jnp.int32).reshape((1,))}
-    order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
-    route = (order, inverse, top_i.reshape(-1, k).astype(jnp.int32))
-    kernel = _token_sum_kernel(ctx, x2, k, load.shape[0])
-    rows = _rows_by_expert(x2, route, k, kernel)
-    # each row's router probability, in expert order
-    weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
-    gate = grouped_matmul(rows, w_gate, load, ctx.platform)
-    up = grouped_matmul(rows, w_up, load, ctx.platform)
-    if keep:
-        gate, up = checkpoint_name(gate, keep), checkpoint_name(up, keep)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * weight).astype(x.dtype)
-    down = grouped_matmul(hidden, w_down, load, ctx.platform)
-    out = _sum_by_token(down, route, k, kernel)
-    return {"Out": out.reshape(x.shape),
-            "Dropped": (tokens * k - jnp.sum(load)).astype(jnp.int32).reshape((1,))}
+    keep = kept_residuals(ctx, op)   # the name under which a recomputed segment keeps the first products' outputs
+    shards = batch_shards(ctx.mesh, ctx.batch_axis, x.shape[0])
+    own_rows = shards > 1
+
+    def experts(x, top_p, top_i, load, *matrices):
+        """(out, the assignments no pass covered, those that fell on held experts) of a chip's rows."""
+        x2 = x.reshape(-1, d)
+        tokens = x2.shape[0]
+        if own_rows:   # the group sizes are this chip's own counts, not the mesh's
+            load = jnp.sum(top_i.reshape(-1, k)[:, :, None] == jnp.arange(load.shape[0], dtype=top_i.dtype),
+                           axis=(0, 1), dtype=jnp.int32)
+        if held is not None:
+            out, n_held, missed = _held_experts(x2, top_p.reshape(-1, k), top_i.reshape(-1, k), load, matrices,
+                                                tuple(held), ctx.platform,
+                                                _token_sum_kernel(ctx, x2, k, held[1], own_rows), keep, activation)
+            return out.reshape(x.shape), missed.astype(jnp.int32).reshape((1,)), n_held.astype(jnp.int32).reshape((1,))
+        *openers, w_down = matrices
+        order = jnp.argsort(top_i.reshape(-1), stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        route = (order, inverse, top_i.reshape(-1, k).astype(jnp.int32))
+        kernel = _token_sum_kernel(ctx, x2, k, load.shape[0], own_rows)
+        rows = _rows_by_expert(x2, route, k, kernel)
+        # each row's router probability, in expert order
+        weight = _permute_scalars(top_p.reshape(-1).astype(jnp.float32), order, inverse)[:, None]
+        products = [grouped_matmul(rows, w, load, ctx.platform) for w in openers]
+        if keep:
+            products = [checkpoint_name(t, keep) for t in products]
+        hidden = _activated(products, weight, activation).astype(x.dtype)
+        down = grouped_matmul(hidden, w_down, load, ctx.platform)
+        out = _sum_by_token(down, route, k, kernel)
+        return out.reshape(x.shape), (tokens * k - jnp.sum(load)).astype(jnp.int32).reshape((1,)), None
+
+    if own_rows:
+        _MON.counter("lowering.moe_experts_under_shard_map").inc()
+
+        def on_a_chip(*operands):   # the two counts whole over the mesh, a row a row of x so that every output is split alike
+            out, *counts = experts(*operands)
+            return (out,) + tuple(jnp.broadcast_to(jax.lax.psum(n, ctx.batch_axis), (out.shape[0], 1))
+                                  for n in counts if n is not None)
+
+        out, *counts = over_batch_shards(ctx, on_a_chip, (x, top_p, top_i), (load,) + matrices)
+        counts = [n[0] for n in counts]
+    else:
+        out, *counts = experts(x, top_p, top_i, load, *matrices)
+    return {"Out": out, "Dropped": counts[0], **({"Held": counts[1]} if held is not None else {})}
 
 
 # -- a layer that holds a share of its experts ---------------------------------
@@ -713,14 +769,16 @@ _add_to_tokens.defvjp(*counted_rules(
     lambda tokens, kernel, res, g: (_rows_of_tokens(g, *res, tokens, kernel), None, None, None)))
 
 
-def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None, keep=None):
+def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None, keep=None, activation="silu"):
     """`moe_experts` over the experts `held` = (first, count): (the tokens'
     output [T, d] in x2's dtype, the assignments that fell on held experts,
     those of them no pass covered).  `kernel`: the common pass's way back to
     token order (`_add_to_tokens`); the rare path, which no step runs and every
     step's compile pays for, keeps XLA's.  `keep`: the name under which a
-    `recompute_scope` round the layer keeps the common pass's two products'
-    outputs (`_kept_experts`); the rare path's are made again whatever it says."""
+    `recompute_scope` round the layer keeps the common pass's first products'
+    outputs (`_kept_experts`); the rare path's are made again whatever it says.
+    `matrices` and `activation`: the experts' form (`_activated`): (gate, up,
+    down), or (up, down) for experts with no gate."""
     first, count = held
     tokens, k = top_i.shape
     assignments = tokens * k
@@ -747,10 +805,15 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None,
     # the names of the two products' outputs that the common pass keeps for its own backward pass; a `recompute_scope`
     # round the layer that keeps them too saves by `keep`, so they carry that one name: a policy by name reaches through
     # this pass's own `jax.checkpoint`, and the value this pass saves has to be the one the outer policy saves
-    kept = (keep, keep) if keep else ("expert_gate", "expert_up")
+    kept = ((keep, keep) if keep else ("expert_gate", "expert_up"))[3 - len(matrices):]
+    # ... and under such a segment the rare path's carry names of their own, which no policy saves: under the segment's
+    # name the outer policy would keep every one of its passes' products too, [passes, rows, width] a layer that no step
+    # ever makes, and the branch that does nothing would hand backward as many zeros (0.85 GB a layer at 22 of 512 over
+    # 8192 tokens: 20.6 GB planned a chip for 13.5)
+    unkept = tuple(name + "@rest" for name in kept) if keep else kept
 
-    def chunk(route, x2, weight, matrices, lo, n, kernel=None):
-        """Rows [lo, lo + n) of the order, as tokens' sums."""
+    def chunk(route, x2, weight, matrices, lo, n, kernel=None, kept=kept):
+        """Rows [lo, lo + n) of the order, as tokens' sums; `kept`: the names of its first products' outputs."""
         order, n_held, *slots, ends, sizes = route
         rank = lo + jax.lax.iota(jnp.int32, n)
         mine = jax.lax.dynamic_slice(order, (lo,), (n,))
@@ -764,15 +827,14 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None,
             target = jnp.where(valid, token, tokens)
         groups = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - sizes, lo, lo + n)
         live = jnp.clip(n_held - lo, 0, n)
-        w_gate, w_up, w_down = matrices
+        *openers, w_down = matrices
         rows = _rows_of_tokens(x2, token, target, live, tokens, kernel)
         # a row no group covers comes out of the kernels as it lay in memory
         covered = valid[:, None]
-        gate = checkpoint_name(grouped_matmul(rows, w_gate, groups, platform), kept[0])
-        up = checkpoint_name(grouped_matmul(rows, w_up, groups, platform), kept[1])
-        gate, up = (jnp.where(covered, t, 0).astype(jnp.float32) for t in (gate, up))
+        products = [checkpoint_name(grouped_matmul(rows, w, groups, platform), name) for w, name in zip(openers, kept)]
+        products = [jnp.where(covered, t, 0).astype(jnp.float32) for t in products]
         w = jax.lax.dynamic_slice(weight, (lo,), (n,))[:, None]
-        hidden = jnp.where(covered, jax.nn.silu(gate) * up * w, 0).astype(x2.dtype)
+        hidden = jnp.where(covered, _activated(products, w, activation), 0).astype(x2.dtype)
         down = grouped_matmul(hidden, w_down, groups, platform)
         return _add_to_tokens(down, token, target, live, tokens, kernel)
 
@@ -784,9 +846,11 @@ def _held_experts(x2, top_p, top_i, load, matrices, held, platform, kernel=None,
     if chunks == 0:
         return common(route, x2, weight, matrices), n_held, jnp.zeros_like(n_held)
 
+    a_rest_pass = functools.partial(chunk, kept=unkept)   # ONE function, so that a second trace of the scan finds the first's
+
     def the_rest(x2, weight, matrices, route):
         def step(acc, lo):
-            return acc + jax.checkpoint(chunk, static_argnums=(5,))(route, x2, weight, matrices, lo, rest), None
+            return acc + jax.checkpoint(a_rest_pass, static_argnums=(5,))(route, x2, weight, matrices, lo, rest), None
         return jax.lax.scan(step, jnp.zeros_like(x2), bound + rest * jnp.arange(chunks, dtype=jnp.int32))[0]
 
     # Both passes' transpose is written out so that the rare one ADDS to what
@@ -978,13 +1042,20 @@ def _infer_moe_router(ctx):
 
 def _infer_moe_experts(ctx):
     xs = ctx.in_shape("X")
+    gated = ctx.op.attr("gated", True)
     gate, up, down = (ctx.in_shape(s) for s in ("WGate", "WUp", "WDown"))
+    if ctx.op.attr("activation", "silu") not in ("silu", "relu2"):
+        ctx.fail(f"activation {ctx.op.attr('activation')!r} is neither silu nor relu2")
+    if not gated:   # two matrices an expert: no WGate
+        if gate is not None:
+            ctx.fail("gated=False: experts of two matrices have no WGate")
+        gate = up
     if xs is None or gate is None or up is None or down is None:
         return
     if len(gate) != 3 or gate[1] != xs[-1] or tuple(up) != tuple(gate) \
             or tuple(down) != (gate[0], gate[2], gate[1]):
-        ctx.fail(f"experts must be WGate, WUp (E, {xs[-1]}, F) and WDown "
-                 f"(E, F, {xs[-1]}), got {gate}, {up}, {down}")
+        ctx.fail(f"experts must be {'WGate, ' if gated else ''}WUp (E, {xs[-1]}, F) and WDown "
+                 f"(E, F, {xs[-1]}), got {gate if gated else ''}, {up}, {down}")
     load = ctx.in_shape("Load")
     held = ctx.op.attr("held", None)
     if held is not None:
@@ -1048,14 +1119,14 @@ _ROW_PASSES = {"hidden": 5, "hidden_xla": 7, "width": 6}
 
 
 def _cost_moe_experts(ctx):
-    """Useful arithmetic of the three grouped products over the (token,
+    """Useful arithmetic of the grouped products (three, or two without a gate) over the (token,
     slot) rows, 2 per multiply-add, whatever a kernel pads; traffic: every
     expert's three matrices once and `_ROW_PASSES` over the rows, the way back
     the kernel's where the shapes are ones it takes (the plan is the chip's).
     For a layer that holds a share the rows are the bound's: an upper bound
     since the row operations stop after the step's last live pass, which no plan
     can know."""
-    gate = ctx.in_shape("WGate")
+    gate, products = ctx.in_shape("WUp"), 3.0 if ctx.op.attr("gated", True) else 2.0
     if gate is None or ctx.in_shape("TopKIndex") is None:
         return float(ctx.out_elems_total()), ctx.io_bytes()
     rows, d, f = ctx.in_elems("TopKIndex"), gate[1], gate[2]
@@ -1070,7 +1141,7 @@ def _cost_moe_experts(ctx):
         rows = rows * held[1] // load[0]
     item = 2 if dtype in ("bfloat16", "float16") else 4
     moved = passes * (hidden * d + _ROW_PASSES["width"] * f) * item
-    return 3.0 * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
+    return products * 2.0 * rows * d * f, float(ctx.io_bytes() + moved)
 
 
 def _cost_short_conv(ctx):
@@ -1093,17 +1164,19 @@ _RP.register_cost(["moe_experts"], _cost_moe_experts)
 # -- what a `recompute_scope` round a sparse layer may keep (core/lowering.py: plan_kept) ----------
 
 def _kept_experts(ctx, op, shapes):
-    """The gate and the up product's outputs, [rows, width] each in the rows'
+    """The gate and the up product's outputs (the one first product's where the
+    experts have no gate), [rows, width] each in the rows'
     dtype, which backward reads (the down product's it does not): over every
     (token, slot) row, or for a layer that holds a share over its bound's rows
     (the common pass's; the rare path makes its own again).  Priced by the op's
     cost rule, as a `mul`'s output is by its own."""
-    gate, index = shapes.shape(op.input("WGate")[0]), shapes.shape(op.input("TopKIndex")[0])
+    gate, index = shapes.shape(op.input("WUp")[0]), shapes.shape(op.input("TopKIndex")[0])
     rows = int(np.prod(index))
     held = op.attr("held", None)
     if held is not None:
         rows = _held_rows_bound(rows, held[1], shapes.shape(op.input("Load")[0])[0])
-    return residuals_name(op), 2 * rows * gate[-1] * _RP._itemsize(shapes.dtype(op.input("X")[0]))
+    firsts = 2 if op.attr("gated", True) else 1
+    return residuals_name(op), firsts * rows * gate[-1] * _RP._itemsize(shapes.dtype(op.input("X")[0]))
 
 
 def _kept_router(ctx, op, shapes):

@@ -158,8 +158,9 @@ def _scan_path(platform, mesh, x, a_log, batch_axis=None):
     `batch_shards` is not 0: no mesh, one device, or a mesh that splits the
     batch alone, where the kernels run on a chip's rows inside the `shard_map`
     `over_batch_shards` opens (a `pallas_call` cannot be partitioned); else
-    "xla", `chunked_selective_scan`: the CPU's path, the odd shapes' and what
-    the tests hold the kernels to.  TPU v5e, (1, 8192, 5120) x 16, forward |
+    "xla", `chunked_selective_scan`: the CPU's path, the odd shapes', any mesh's
+    that splits more than the rows (GSPMD partitions the plain form by itself),
+    and what the tests hold the kernels to.  TPU v5e, (1, 8192, 5120) x 16, forward |
     forward + backward of the op alone: PERF.md, PR 48."""
     whole = x.shape[-1] % ssm_kernels.UNIT == 0 and a_log.shape[-1] % ssm_kernels.GROUP == 0
     return "kernels" if platform == "tpu" and whole and batch_shards(mesh, batch_axis, x.shape[0]) else "xla"

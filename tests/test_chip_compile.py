@@ -19,6 +19,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -1059,3 +1060,87 @@ def test_jamba2s_step_on_the_2x2_host_keeps_what_a_chips_room_holds_and_its_plan
     again = _made_again(compiled.as_text())
     assert sum(name.endswith("/selective_scan/pallas_call") for name in again) >= 13
     assert not [name for name in again if name.endswith("/pallas_call") and "selective_scan" not in name]   # the attention's is kept
+
+
+# -- ISSUE 60: the scalar-decay scan and the latent experts under the (4,) mesh, at Nemotron-3-Super's widths ------------
+
+#: one row of 8192 positions a chip: 128 heads of 64, a state of 128 in 8 groups, chunks of 128 (x, B, C bf16; dt bf16)
+SSD_SPECS = [((1, 8192, 8192), BF16), ((1, 8192, 128), BF16), ((128,), F32), ((1, 8192, 1024), BF16), ((1, 8192, 1024), BF16),
+             ((128,), F32), ((128,), F32)]
+
+
+def _ssd(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
+    from paddle_tpu.ops.ssd_ops import chunked_ssd_scan
+
+    return chunked_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, 8, 128)[0]
+
+
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_the_scalar_decay_scan_compiles_for_v5e_at_nemotron3s_widths(way, chip):
+    """`ssd_scan`'s chunked form (plain `jax.numpy`: no Mosaic kernel) for one
+    described chip: the 64 chunks' carried state is ONE `while` of 64 steps
+    forward (its transpose a second one backward), the intra-chunk work batched
+    products, and what it plans beside its operands stays under 4 GB a row."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in SSD_SPECS]
+    program = _ssd if way == "forward" else _backward(_ssd, (0, 1, 2, 3, 4, 5, 6))
+    compiled = jax.jit(program).lower(*args).compile()
+    text = compiled.as_text()
+    whiles = len(re.findall(r"= [^\n]* while\(", text))
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    print(f"ssd_scan {way}: {whiles} while(s), temporaries {temporaries / 1e9:.3f} GB")
+    assert 1 <= whiles <= (1 if way == "forward" else 3), whiles
+    assert temporaries < (2.5e9 if way == "forward" else 4.5e9), temporaries
+    assert "tpu_custom_call" not in text
+
+
+def test_the_latent_experts_under_the_rows_only_mesh_compile_for_the_2x2_host_with_the_kernels_on_a_chips_own_rows(host):
+    """`moe_experts` at the cell's widths (a row of 8192 tokens a chip in the
+    latent of 1024, 22 of 512 a token, experts 0-31 held as [32, 1024, 2688] and
+    [32, 2688, 1024] float32 stacks split four ways along their first dimension)
+    under the described host's (4,) mesh: the op runs in a `shard_map` over
+    `dp`, the grouped products and the way back are Mosaic kernels on a chip's
+    own rows, and the stacks are gathered whole (ZeRO-3's gather)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.lowering import LoweringContext
+    from paddle_tpu.core.registry import get_op_def
+
+    mesh = Mesh(np.array(host.devices), ("dp",))
+    attrs = {"held": [0, 32], "gated": False, "activation": "relu2", "num_experts": 512, "top_k": 22}
+    op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: attrs.get(name, default))
+
+    def layer(x, top_p, top_i, load, w_up, w_down):
+        ctx = LoweringContext(jax.random.PRNGKey(0), platform="tpu", mesh=mesh, batch_axis="dp")
+        ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load], "WUp": [w_up], "WDown": [w_down]}
+        outs = get_op_def("moe_experts").lower(ctx, op, ins)
+        return outs["Out"], outs["Held"], outs["Dropped"]
+
+    rows, whole = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+    specs = [((4, 8192, 1024), BF16, rows), ((4, 8192, 22), F32, rows), ((4, 8192, 22), I32, rows), ((512,), I32, whole),
+             ((32, 1024, 2688), F32, rows), ((32, 2688, 1024), F32, rows)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in specs]
+    for program in (layer, jax.grad(lambda *a: jnp.sum(layer(*a)[0].astype(F32)), argnums=(0, 4, 5))):
+        text = jax.jit(program).lower(*args).compile().as_text()
+        assert text.count("tpu_custom_call") >= 3, "the grouped products and the way back are kernels on a chip's rows"
+        assert "all-gather" in text
+    assert "reduce-scatter" in text or "all-reduce" in text      # the stacks' gradients, summed over the chips
+
+
+@pytest.mark.slow   # two compiles for four devices, ~6 minutes here: run by name (`-m slow -k nemotron3`); PERF.md, PR 60, has its readings
+def test_nemotron3_supers_step_and_its_check_rows_on_the_2x2_host_leave_room(host, monkeypatch):
+    """`nemotron-3-super-120b-a12b.train-ssd-fsdp4`'s whole step at the published
+    widths on the described 2x2 host, ZeRO-3 over `dp`, 32 experts held a layer
+    (7.49 GB a chip of state), and the 8-row `for_test` clone its reference
+    check runs beside that state: both planned under the chip's 16.9 GB."""
+    compiled, counted = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch)
+    peak = _planned_peak(compiled)
+    print(f"step: planned peak {peak / 1e9:.3f} GB a chip, kept {counted}")
+    assert counted["segments"] == 11
+    assert 7.49e9 <= peak <= 15.5e9, peak
+    text = compiled.as_text()
+    assert text.count("all-gather") and "tpu_custom_call" in text
+    clone, _ = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch, check_rows=8)
+    moments = 2 * 4 * 1871531904 / 4     # Adam's two float32 moments lie beside the clone's own arguments, split four ways
+    beside = _planned_peak(clone) + moments
+    print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
+    assert beside <= 16.9e9, beside

@@ -51,6 +51,7 @@ REHEARSED = {
     "chip_index_alignment": (),
     "chip_index_select": (),
     "chip_lfm2_controls": ("1",),
+    "chip_nemotron_controls": ("1", "scan_wrong_group", "relu_for_relu2"),   # the sound run, a fault in the program, one in the reference
     "chip_phi4flash_controls": ("1",),
     "chip_row_attention": (),
     "chip_sdar_routing": ("1", "2"),

@@ -309,7 +309,7 @@ def test_the_counter_says_which_held_layers_took_the_kernel(platform, devices, d
     tokens, experts, k, f, held = 256, 16, 4, 16, 4
     top_i = held_routing("uniform", tokens, experts, k, held)
     load = np.bincount(top_i.reshape(-1), minlength=experts).astype("i4")
-    lowered = held_layer(top_i, load, held, platform, None if devices is None else SimpleNamespace(size=devices))
+    lowered = held_layer(top_i, load, held, platform, None if devices is None else SimpleNamespace(size=devices, shape={"dp": 2, "tp": devices // 2}))
     x, *rest = layer_operands(tokens, k, d, f, held)
     x = jnp.asarray(x, dtype)
 

@@ -335,7 +335,9 @@ def test_the_counter_says_which_layers_took_the_token_sum_kernel(platform, devic
            "WGate": RNG.randn(experts, d, f).astype("f4"), "WUp": RNG.randn(experts, d, f).astype("f4"),
            "WDown": RNG.randn(experts, f, d).astype("f4")}
     op = SimpleNamespace(type="moe_experts", attr=lambda n, default=None: default)
-    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform, mesh=None if devices is None else SimpleNamespace(size=devices))
+    # the mesh splits more than the rows (under one that splits the rows alone the op runs on a chip's own rows: tests/test_nemotron_h.py)
+    mesh = None if devices is None else SimpleNamespace(size=devices, shape={"dp": 2, "tp": devices // 2})
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform, mesh=mesh)
 
     def layer(x):
         return jnp.sum(get_op_def("moe_experts").lower(ctx, op, {n: [jnp.asarray(v)] for n, v in {**ins, "X": x}.items()})["Out"])
