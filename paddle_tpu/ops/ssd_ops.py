@@ -38,27 +38,60 @@ a float32 operand is rounded to bf16 first, eight bits of a decay or of the
 state.  T need not be a whole number of chunks: the tail is padded with steps
 of zero (dt = 0: decay 1, nothing enters), which leave the state alone.
 
-Backward is `jax.vjp` over this lowering like every other op's
-(core/lowering.py): each product's two transposes are products of the same
-shapes, and what backward reads of a chunk ([chunks, H, Q, Q] float32: the
-decayed scores) is dearer to hold than to make, so a `recompute_scope` round the
-layer keeps nothing of the op (`_kept_ssd`).
+Two paths, one rule (`_scan_path`, from what the lowering observes: the
+platform, the mesh, the shapes; nothing a process or a program can set).
+
+"kernels", on the TPU where the chunk and the state are whole lane tiles and
+a group's heads whole slabs of 128 channels (Nemotron-3-Super's: chunks of 128,
+N 128, sixteen heads of 64 a group): the two Pallas kernels of
+`ops/ssd_kernels.py` under a `jax.custom_vjp` (`kernel_ssd_scan`).  A grid
+step is a (row, GROUP, chunk): C . B is made once and shared by the group's
+heads, a head's [Q, Q] decays and decayed scores live and die in VMEM, the
+states are carried in VMEM scratch from chunk to chunk, and x, B, C and y are
+read and written as the program lays them out.  Of the three products with a
+float32 operand the kernels know which operand is bf16 EXACTLY (x, B, C,
+backward's d y) and send the float32 one to the matrix unit as its three bf16
+pieces: `_float32_product`'s six passes less the three that multiply zeros.
+What the kernels read in place of Dt, ALog and DtBias is the step and the
+cumulative decay, [b, T, H] float32, made by XLA with this module's own lines
+(`_decays`), and backward hands XLA their cotangents to transpose.  Forward
+where it is differentiated KEEPS the seven inputs and the float32 state every
+chunk starts from ([chunks, b, H, P, N]: 268 MB a row of 8192 tokens of 128
+heads), and the transposed kernel makes a chunk's scores and decays again from
+them, the chunks in reverse, the state's cotangent in VMEM.  A
+`recompute_scope` round the layer is offered the output and those start states
+under one name (`_kept_ssd`, as `selective_scan`'s): where `plan_kept`'s budget
+holds them the segment runs no second forward.
+
+"xla", everywhere else (the CPU, odd shapes, a mesh that splits more than the
+rows; what the tests and `tools/chip_nemotron_controls.py`'s copy hold the
+kernels to): `chunked_ssd_scan`, the chunked form above in plain `jax.numpy`.
+Backward is `jax.vjp` over it like every other op's (core/lowering.py): each
+product's two transposes are products of the same shapes, and what backward
+reads of a chunk ([chunks, H, Q, Q] float32: the decayed scores) is dearer to
+hold than to make, so a `recompute_scope` round the layer keeps nothing of it.
 
 Under a mesh whose batch axis splits the rows and nothing else the whole op runs
 in a `shard_map` over that axis (`ops.common.over_batch_shards`), as
-`selective_scan` does: GSPMD is not asked how to split a loop over the sequence.
+`selective_scan` does: a chip scans its own rows by either path (a
+`pallas_call` cannot be partitioned), and GSPMD is not asked how to split a
+loop over the sequence.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
-from .common import batch_shards, first, over_batch_shards
+from . import ssd_kernels
+from .common import batch_shards, counted_rules, first, kept_residuals, operand_of, over_batch_shards, residuals_name
 
 #: Tokens a chunk where the op's attribute gives none (Mamba-2's `chunk_size`).
 CHUNK = 128
@@ -132,6 +165,108 @@ def chunked_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk=CHUN
     return y, final.reshape(batch, heads, P, N), means
 
 
+def _scan_path(platform, mesh, x, a_log, b_t, groups, chunk, batch_axis=None):
+    """How the op is lowered: "kernels" (`ops/ssd_kernels.py`: a group's scores
+    and its heads' states in VMEM, forward and transposed) on the TPU where the
+    chunk (the row's own length where that is shorter) and the state N are whole
+    lane tiles, the heads a whole number of groups and a group's heads a whole
+    number of the slabs whose channels fill a lane tile
+    (`ssd_kernels.heads_a_slab`: P a multiple of the sublane tile that divides
+    128, or a multiple of 128), and `batch_shards` is not 0: no mesh, one
+    device, or a mesh that splits the rows alone, where the kernels run on a
+    chip's rows inside the `shard_map` `over_batch_shards` opens (a
+    `pallas_call` cannot be partitioned); else "xla", `chunked_ssd_scan`: the
+    CPU's path, the odd shapes', any mesh's that splits more than the rows
+    (GSPMD partitions the plain form by itself), and what the tests and
+    `tools/chip_nemotron_controls.py`'s copy hold the kernels to.  From what the
+    lowering observes alone: nothing a process or a program can set."""
+    heads, G = a_log.shape[0], int(groups)
+    if platform != "tpu" or heads % G or x.shape[-1] % heads or b_t.shape[-1] % G:
+        return "xla"
+    P, N, Q = x.shape[-1] // heads, b_t.shape[-1] // G, min(int(chunk), x.shape[1])
+    whole = Q % ssd_kernels.LANES == 0 and N % ssd_kernels.LANES == 0 and P % 8 == 0 and ssd_kernels.heads_a_slab(heads // G, P)
+    return "kernels" if whole and batch_shards(mesh, batch_axis, x.shape[0]) else "xla"
+
+
+def _decays(dt, a_log, dt_bias, pad, chunk):
+    """(the float32 step softplus(Dt + DtBias) and the decay's logarithm summed
+    along each chunk, [b, T + pad, H]; the decay's logarithm a token) as
+    `chunked_ssd_scan` makes them, the padded tail stepping by exactly 0: what
+    the kernels read in place of Dt, ALog and DtBias."""
+    batch, T, heads = dt.shape
+    if pad:
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)), constant_values=_NO_STEP)
+    step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+    log_decay = step * -jnp.exp(a_log.astype(jnp.float32))
+    cum = jnp.cumsum(log_decay.reshape(batch, -1, chunk, heads), axis=2).reshape(step.shape)
+    return step, cum, log_decay
+
+
+def _kernel_operands(x, b_t, c_t, chunk):
+    """(x, B, C padded to a whole number of chunks; `_decays` as a function of
+    Dt, ALog and DtBias; the chunk; the padded tokens)."""
+    T = x.shape[1]
+    Q = min(int(chunk), T)
+    pad = -T % Q
+    if pad:
+        x, b_t, c_t = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (x, b_t, c_t))
+    return (x, b_t, c_t), functools.partial(_decays, pad=pad, chunk=Q), Q, pad
+
+
+def _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk, kernels, keep):
+    """(`chunked_ssd_scan`'s three results, what backward reads: the chunks'
+    start states where they are kept) of the kernels."""
+    batch, T, _ = x.shape
+    heads = a_log.shape[0]
+    with jax.named_scope("ssd_scan"):
+        (xs, bs, cs), decays, Q, pad = _kernel_operands(x, b_t, c_t, chunk)
+        step, cum, log_decay = decays(dt, a_log, dt_bias)
+        y, final, *kept = ssd_kernels.scan(xs, bs, cs, step, cum, d_skip.astype(jnp.float32), Q, int(groups), keep, kernels == "interpret")
+        real = float(batch * T * heads)
+        # the padded steps decay by exactly 1 and step by exactly 0
+        means = ((jnp.sum(jnp.exp(log_decay)) - float(batch * pad * heads)) / real, jnp.sum(step) / real)
+    return (y[:, :T], ssd_kernels.heads_first(final, x.shape[-1] // heads), means), tuple(kept)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def kernel_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk=CHUNK, kernels="tpu", keep=None):
+    """`chunked_ssd_scan`'s results from the Pallas kernels of
+    `ops/ssd_kernels.py` (`_scan_path` says when the op comes here); `kernels`:
+    "tpu", or "interpret" for those interpreted (the tests').  The final state
+    and the means are for statistics: backward takes no cotangent for them."""
+    return _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk, kernels, False)[0]
+
+
+def _kernel_scan_fwd(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk, kernels, keep):
+    """The op where it is differentiated: forward keeps the chunks' start states ([chunks, b, H, P, N] float32 in the
+    kernels' own tiles) beside the seven inputs.  `keep` names the two values that only the forward kernel makes and
+    backward reads, the output (the mixer's gate reads it) and the start states: a `jax.checkpoint` round the op whose
+    policy saves the name (`core/lowering.py: plan_kept`) then runs no second forward."""
+    _MON.counter("lowering.ssd_scan_starts_kept").inc()
+    (y, final, means), (starts,) = _kernel_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk, kernels, True)
+    if keep:
+        y, starts = checkpoint_name(y, keep), checkpoint_name(starts, keep)
+    return (y, final, means), ((x, dt, a_log, b_t, c_t, d_skip, dt_bias), (starts,))
+
+
+def _kernel_scan_bwd(groups, chunk, kernels, keep, residuals, cotangents):
+    (x, dt, a_log, b_t, c_t, d_skip, dt_bias), (starts,) = residuals
+    _MON.counter("lowering.ssd_scan_kernel_transposed_calls").inc()
+    T = x.shape[1]
+    with jax.named_scope("ssd_scan"):
+        (xs, bs, cs), decays, Q, pad = _kernel_operands(x, b_t, c_t, chunk)
+        (step, cum, _), transpose = jax.vjp(decays, dt, a_log, dt_bias)
+        d_y = jnp.pad(cotangents[0], ((0, 0), (0, pad), (0, 0))) if pad else cotangents[0]
+        dx, db, dc, dstep, dcum, dskip = ssd_kernels.scan_transposed(
+            xs, bs, cs, step, cum, d_skip.astype(jnp.float32), d_y, starts, Q, int(groups), kernels == "interpret")
+        # the step's softplus, the chunk's sum and A = -exp(ALog) are plain lines of 4 MB: XLA transposes them
+        ddt, d_a_log, dbias = transpose((dstep, dcum, jnp.zeros_like(step)))
+    return dx[:, :T], ddt, d_a_log, db[:, :T], dc[:, :T], dskip.astype(d_skip.dtype), dbias
+
+
+kernel_ssd_scan.defvjp(*counted_rules("ssd_scan", _kernel_scan_fwd, _kernel_scan_bwd))
+
+
 @register_op("ssd_scan")
 def _ssd_scan(ctx, op, ins):
     """The chunked recurrence over X [b, T, H P], Dt [b, T, H], B, C [b, T, G N]
@@ -146,9 +281,17 @@ def _ssd_scan(ctx, op, ins):
     _MON.counter("lowering.ssd_scan_ops").inc()
     _MON.counter("lowering.ssd_scan_chunks").inc(-(-x.shape[1] // min(chunk, x.shape[1])))
     shards = batch_shards(ctx.mesh, ctx.batch_axis, x.shape[0])
+    # "interpret" is the tests': the kernels interpreted where no chip is
+    kernels = {"kernels": "tpu", "interpret": "interpret"}.get(
+        _scan_path(ctx.platform, ctx.mesh, x, a_log, b_t, groups, chunk, ctx.batch_axis))
+    _MON.counter("lowering.ssd_scan_kernel_calls").inc(1 if kernels else 0)
+    keep = kept_residuals(ctx, op)
 
     def scan(x, dt, b_t, c_t, a_log, d_skip, dt_bias):
-        y, final, (decay, step) = chunked_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk)
+        if kernels:
+            y, final, (decay, step) = kernel_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk, kernels, keep)
+        else:
+            y, final, (decay, step) = chunked_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, groups, chunk)
         decay, step, largest = jax.lax.stop_gradient((decay, step, jnp.max(jnp.abs(final))))
         if shards > 1:   # a chip's rows: the means of equal shares, the largest of all
             decay, step = (jax.lax.pmean(t, ctx.batch_axis) for t in (decay, step))
@@ -233,11 +376,19 @@ _RP.register_cost(["ssd_scan"], _cost_ssd_scan)
 
 
 def _kept_ssd(ctx, op, shapes):
-    """Nothing: backward reads the chunks' decayed scores ([b, chunks, H, Q, Q]
-    float32, 0.5 GB a row of 8192 tokens of 128 heads), which are dearer to hold
-    than to make from the op's inputs, and the op's output is no part of its own
-    transpose."""
-    return None
+    """Where the op takes the kernels: its output and the float32 state every
+    chunk starts from, [chunks, b, H, P, N] (what only the forward kernel makes
+    and the transposed one reads; the chunk's scores and decays are made again
+    in VMEM from the op's inputs).  The plain form offers nothing: backward
+    reads its chunks' decayed scores ([b, chunks, H, Q, Q] float32, 0.5 GB a row
+    of 8192 tokens of 128 heads), which are dearer to hold than to make."""
+    x, a_log, b_t = (operand_of(shapes, op.input(slot)[0]) for slot in ("X", "ALog", "B"))
+    groups, chunk = op.attr("groups", 1), op.attr("chunk", CHUNK)
+    if _scan_path(ctx.platform, ctx.mesh, x, a_log, b_t, groups, chunk, ctx.batch_axis) == "xla":
+        return None
+    batch, tokens, width = x.shape
+    starts_bytes = 4 * -(-tokens // min(chunk, tokens)) * batch * width * (b_t.shape[-1] // groups)
+    return residuals_name(op), shapes.nbytes(op.output("Out")[0]) + starts_bytes
 
 
 set_kept("ssd_scan", _kept_ssd)

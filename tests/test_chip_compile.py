@@ -1077,7 +1077,9 @@ def _ssd(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
 
 @pytest.mark.parametrize("way", ["forward", "backward"])
 def test_the_scalar_decay_scan_compiles_for_v5e_at_nemotron3s_widths(way, chip):
-    """`ssd_scan`'s chunked form (plain `jax.numpy`: no Mosaic kernel) for one
+    """`ssd_scan`'s chunked form (plain `jax.numpy`: no Mosaic kernel on THAT
+    path, the CPU's and the odd shapes'; the chip's own path at these widths is
+    the next test's) for one
     described chip: the 64 chunks' carried state is ONE `while` of 64 steps
     forward (its transpose a second one backward), the intra-chunk work batched
     products, and what it plans beside its operands stays under 4 GB a row."""
@@ -1091,6 +1093,38 @@ def test_the_scalar_decay_scan_compiles_for_v5e_at_nemotron3s_widths(way, chip):
     assert 1 <= whiles <= (1 if way == "forward" else 3), whiles
     assert temporaries < (2.5e9 if way == "forward" else 4.5e9), temporaries
     assert "tpu_custom_call" not in text
+
+
+def _ssd_kernels(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
+    from paddle_tpu.ops import ssd_ops
+
+    assert ssd_ops._scan_path("tpu", None, x, a_log, b_t, 8, 128) == "kernels"
+    return ssd_ops.kernel_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, 8, 128, "tpu")[0]
+
+
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_the_scalar_decay_scans_kernels_compile_for_v5e_at_nemotron3s_widths(way, chip):
+    """What `_scan_path` takes on the chip at these widths (ISSUE 61): the two
+    kernels of `ops/ssd_kernels.py`, a group's sixteen heads a grid step in
+    eight slabs of two.  One Mosaic call forward, two backward (the forward
+    that keeps the chunks' start states, the transposed one), no `while` round
+    the chunks (the chunk axis is the kernels' grid), and beside its operands
+    the op plans only what it hands on: nothing forward, the start states
+    ([64 chunks, 128 heads, 64, 128] float32, 0.27 GB) and the kernels' small
+    operands backward, where the plain form plans 2.0 | 3.5 GB.  The kernels fit
+    the scoped VMEM they ask for or Mosaic would refuse them here."""
+    from paddle_tpu.ops import ssd_kernels
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in SSD_SPECS]
+    program = _ssd_kernels if way == "forward" else _backward(_ssd_kernels, (0, 1, 2, 3, 4, 5, 6))
+    compiled = jax.jit(program).lower(*args).compile()
+    text = compiled.as_text()
+    calls, whiles = text.count("tpu_custom_call"), len(re.findall(r"= [^\n]* while\(", text))
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    print(f"ssd_scan's kernels {way}: {calls} Mosaic call(s), {whiles} while(s), temporaries {temporaries / 1e9:.3f} GB")
+    assert (calls, whiles) == ((1, 0) if way == "forward" else (2, 0)), (calls, whiles)
+    assert temporaries < (0.1e9 if way == "forward" else 1e9), temporaries
+    assert ssd_kernels._SEMANTICS.vmem_limit_bytes <= 100 * 2 ** 20       # of the v5e's 128 MiB
 
 
 def test_the_latent_experts_under_the_rows_only_mesh_compile_for_the_2x2_host_with_the_kernels_on_a_chips_own_rows(host):
@@ -1131,14 +1165,19 @@ def test_nemotron3_supers_step_and_its_check_rows_on_the_2x2_host_leave_room(hos
     """`nemotron-3-super-120b-a12b.train-ssd-fsdp4`'s whole step at the published
     widths on the described 2x2 host, ZeRO-3 over `dp`, 32 experts held a layer
     (7.49 GB a chip of state), and the 8-row `for_test` clone its reference
-    check runs beside that state: both planned under the chip's 16.9 GB."""
+    check runs beside that state: both planned under the chip's 16.9 GB.  Since
+    PR 61 the five scans are kernels whose residuals (the output and the
+    chunks' start states, 0.40 GB a layer) `plan_kept` holds with every other
+    candidate: 34 values, 4.89 GB a chip (29 and 2.88 with the plain form, which
+    offered nothing), planned 14.47 GB (13.46), and no scan is made again."""
     compiled, counted = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch)
     peak = _planned_peak(compiled)
     print(f"step: planned peak {peak / 1e9:.3f} GB a chip, kept {counted}")
-    assert counted["segments"] == 11
-    assert 7.49e9 <= peak <= 15.5e9, peak
+    assert counted == {"segments": 11, "sparse_segments": 5, "kept_values": 34, "kept_bytes": 4891082752, "candidates_bytes": 4891082752}
+    assert 14.2e9 <= peak <= 14.8e9, peak
     text = compiled.as_text()
     assert text.count("all-gather") and "tpu_custom_call" in text
+    assert not [name for name in _made_again(text) if "ssd_scan" in name and name.endswith("/pallas_call")]
     clone, _ = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch, check_rows=8)
     moments = 2 * 4 * 1871531904 / 4     # Adam's two float32 moments lie beside the clone's own arguments, split four ways
     beside = _planned_peak(clone) + moments

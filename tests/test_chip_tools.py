@@ -70,6 +70,21 @@ def test_the_tool_still_runs_at_tiny_sizes_with_its_kernels_interpreted(tool):
     assert not [r for r in readings if r.get("error")], readings
 
 
+def test_chip_nemotron_controls_op_alone_mode_rehearses_with_the_kernels_interpreted():
+    """`ONLY=profile python3 tools/chip_nemotron_controls.py` (ISSUE 61: the scan alone, its kernels beside the plain
+    form): tiny and interpreted it times nothing, and its two readings of how far the kernels lie from the plain form
+    and both from the recurrence are float32's rounding."""
+    out = subprocess.run([sys.executable, os.path.join("tools", "chip_nemotron_controls.py")], cwd=REPO, capture_output=True, text=True,
+                         timeout=600, stdin=subprocess.DEVNULL, env=dict(os.environ, DRY="1", ONLY="profile", JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    readings = {r["reading"] + r.get("form", ""): r for r in map(json.loads, filter(lambda line: line.startswith("{"), out.stdout.splitlines()))}
+    apart = readings["ssd_kernels_from_plain"]
+    assert max(apart["y"], apart["state"], apart["y_kept"], *apart["means"]) < 1e-6 and max(apart["d_a_log"], apart["d_d"], apart["d_dt_bias"]) < 1e-4
+    assert max(apart[k] for k in ("d_x", "d_dt", "d_b", "d_c")) < 1e-2                   # bf16 gradients: a step of theirs
+    for form in ("kernels", "plain"):
+        assert readings["ssd_against_the_recurrence" + form]["scan_state_error"] < 1e-5
+
+
 def test_chip_kimi_kernels_ops_agree_at_tiny_sizes_with_the_kernels_interpreted():
     """`tools/chip_kimi_kernels.py` has no rehearsal of its own, but its pieces take their sizes as arguments: the op
     through the kernels (interpreted) against the `jax.numpy` form, forward, and the backward it times alone

@@ -15,8 +15,13 @@ REFERENCE (the errors are differences):
   * `scan_default_precision`: the three products with a float32 operand at the
     matrix unit's default precision, bf16 operands;
   * `scan_wrong_group`: every group's heads read their neighbour group's B;
-    (the four through `faulty_ssd_scan`, this file's copy of the op's chunked
-    form, which with no fault is the op bit for bit: `copy_differs`)
+    (the four through `faulty_ssd_scan`, this file's copy of the op's PLAIN
+    chunked form, `ssd_ops.chunked_ssd_scan`, which with no fault is that form
+    bit for bit.  Since PR 61 the cell's sound run goes through the Pallas
+    kernels of `ops/ssd_kernels.py` and the faults through the copy:
+    `copy_differs` is how far the kernels' output and last state lie from the
+    plain form's on the program's own operands, float32's rounding of the state
+    and a step of the output's bf16 at most)
   * `attention_mask_shifted`: a query also sees the key after it;
   * `attention_wrong_kv_head`: query head j reads key/value head j mod 2, not
     j // 16: `ATTENTION_RTOL` (both in float32 numpy on the program's q, k, v,
@@ -30,6 +35,14 @@ REFERENCE (the errors are differences):
     reads as a sound run does: the program's own products are bf16.)
 
     chiprun --chips 4 --timeout 3400 -- python3 tools/chip_nemotron_controls.py 3600000701 3600000702 3600000703      (PERF.md, PR 60)
+
+`ONLY=profile python3 tools/chip_nemotron_controls.py` (one chip, ~2 min) is the
+op ALONE at a chip's shapes in the cell, no program round it: own device ms of
+the forward kernel, of the forward that keeps the chunks' start states, of the
+transposed kernel and of the plain form forward and through `jax.vjp`, how far
+the kernels' results and seven gradients lie from the plain form's, and both
+forms against the recurrence as the scan stage reads them: the split that
+`ssd_ms_per_step` and `ssd_scan_roofline_share` do not give (PERF.md, PR 61).
 
 Every control runs on the first seed; on each further seed the scan's controls
 alone, against the scan stage's own limits (no reference is computed there).  Names
@@ -244,6 +257,108 @@ def main(seeds, only=()):
         gc.collect()
 
 
+def scan_alone_inputs(seed, rows, tokens, heads, width, groups, state):
+    """The op's seven operands at a chip's shapes, bf16 activations: xs, B, C as
+    a silu of unit normals leaves them, A = 1 .. 16 over the heads, the step's
+    bias the inverse softplus of a log-uniform draw on [1e-3, 1e-1] (the
+    mixer's own initialisation)."""
+    r = np.random.RandomState(seed)
+    silu = lambda t: t / (1.0 + np.exp(-t))                                               # noqa: E731
+    step = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), heads))
+    activations = (silu(r.randn(rows, tokens, heads * width)), 0.5 * r.randn(rows, tokens, heads),
+                   silu(r.randn(rows, tokens, groups * state)), silu(r.randn(rows, tokens, groups * state)))
+    x, dt, b_t, c_t = (jnp.asarray(t, jnp.bfloat16) for t in activations)
+    parameters = (np.log(np.linspace(1.0, 16.0, heads)), np.ones(heads), np.log(np.expm1(step)))
+    a_log, d_skip, dt_bias = (jnp.asarray(t, jnp.float32) for t in parameters)
+    return x, dt, a_log, b_t, c_t, d_skip, dt_bias
+
+
+def own_ms(fn, *args, runs=3):
+    """{HLO instruction: own device ms a run} of the jitted `fn(*args)`, from a
+    trace of `runs` runs."""
+    import shutil
+
+    from benchmark.metrics.recompute_ms_per_step import own_times
+
+    jax.block_until_ready(fn(*args))
+    where = "chiprun_out/ssd_trace"
+    os.makedirs(where, exist_ok=True)
+    jax.profiler.start_trace(where)
+    for _ in range(runs):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    found = [os.path.join(base, f) for base, _, files in os.walk(where) for f in files if f.endswith(".xplane.pb")]
+    spent = {}
+    for plane in jax.profiler.ProfileData.from_file(max(found, key=os.path.getmtime)).planes:
+        for line in plane.lines if plane.name.startswith("/device:TPU:0") else ():
+            if line.name == "XLA Ops":
+                events = [(e.name, e.start_ns, e.duration_ns) for e in line.events]
+                for instruction, ns in own_times(events, (min(e[1] for e in events), max(e[1] + e[2] for e in events))):
+                    spent[instruction] = spent.get(instruction, 0.0) + ns / (runs * 1e6)
+    shutil.rmtree(where)
+    return spent
+
+
+def profile():
+    """`ONLY=profile`: the op alone on one chip at a chip's shapes in the cell
+    (a row of 8192 tokens, 128 heads of 64, a state of 128 in 8 groups, chunks
+    of 128): own device ms of the forward kernel, of the forward that keeps the
+    chunks' start states, of the transposed kernel, and of the plain form
+    forward and through `jax.vjp`, each with its largest instructions; how far
+    the kernels' results and seven gradients lie from the plain form's, and
+    both forms' errors against the recurrence as the cell's scan stage reads
+    them (on the first two groups' first 2048 tokens)."""
+    from paddle_tpu.ops import ssd_ops
+
+    rows, tokens, heads, width, groups, state, chunk = (2, 64, 8, 8, 2, 16, 16) if DRY else (1, 8192, 128, 64, 8, 128, 128)
+    kernels = "interpret" if DRY else "tpu"
+    args = scan_alone_inputs(1, rows, tokens, heads, width, groups, state)
+    weigh = jnp.asarray(np.random.RandomState(2).randn(rows, tokens, heads * width), jnp.bfloat16)
+    static = (groups, chunk, kernels, None)
+    programs = {
+        "kernel_forward": (jax.jit(lambda *a: ssd_ops.kernel_ssd_scan(*a, groups, chunk, kernels)), args),
+        "kernel_forward_kept": (jax.jit(lambda *a: ssd_ops._kernel_scan_fwd(*a, *static)), args),
+        "plain_forward": (jax.jit(lambda *a: ssd_ops.chunked_ssd_scan(*a, groups, chunk)), args),
+        "plain_both": (jax.jit(lambda w, *a: jax.vjp(lambda *o: ssd_ops.chunked_ssd_scan(*o, groups, chunk)[0], *a)[1](w)), (weigh, *args)),
+    }
+    kept = programs["kernel_forward_kept"][0](*args)[1]
+    programs["kernel_transposed"] = (jax.jit(lambda kept, w: ssd_ops._kernel_scan_bwd(*static, kept, (w, None, None))), (kept, weigh))
+    results = {name: fn(*operands) for name, (fn, operands) in programs.items()}
+    if not DRY:
+        for name, (fn, operands) in programs.items():
+            spent = own_ms(fn, *operands)
+            print(json.dumps({"reading": "ssd_profile", "program": name, "own_ms_a_run": sum(spent.values()), "instructions": len(spent),
+                              "top": sorted(spent.items(), key=lambda kv: -kv[1])[:6]}, default=float), flush=True)
+
+    def apart(mine, theirs):
+        mine, theirs = np.asarray(mine, "f4"), np.asarray(theirs, "f4")
+        return float(np.sqrt(np.mean((mine - theirs) ** 2)) / max(np.sqrt(np.mean(theirs ** 2)), 1e-30))
+
+    (y, final, means), (y_plain, final_plain, means_plain) = results["kernel_forward"], results["plain_forward"]
+    names = ("x", "dt", "a_log", "b", "c", "d", "dt_bias")
+    print(json.dumps({"reading": "ssd_kernels_from_plain", "y": apart(y, y_plain), "state": apart(final, final_plain),
+                      "means": [apart(m, p) for m, p in zip(means, means_plain)],
+                      "y_kept": apart(results["kernel_forward_kept"][0][0], y),
+                      **{"d_" + n: apart(g, w) for n, g, w in zip(names, results["kernel_transposed"], results["plain_both"])}}), flush=True)
+    x, dt, a_log, b_t, c_t, d_skip, dt_bias = args
+    per, P = heads // groups, width
+    cut = dict(t=min(tokens, 2048), h=min(heads, 2 * per), g=min(groups, 2))
+    for name, (out, last) in {"kernels": (y, final), "plain": (y_plain, final_plain)}.items():
+        stage = [np.asarray(t[:, :cut["t"], :w].astype(jnp.float32)) for t, w in
+                 ((x, cut["h"] * P), (dt, cut["h"]), (b_t, cut["g"] * state), (c_t, cut["g"] * state))]
+        form = ssd_ops.chunked_ssd_scan if name == "plain" else (lambda *a: ssd_ops.kernel_ssd_scan(*a, kernels=kernels))
+        out, last, _ = jax.jit(form, static_argnums=(7, 8))(x[:, :cut["t"], :cut["h"] * P], dt[:, :cut["t"], :cut["h"]], a_log[:cut["h"]],
+                                                            b_t[:, :cut["t"], :cut["g"] * state], c_t[:, :cut["t"], :cut["g"] * state],
+                                                            d_skip[:cut["h"]], dt_bias[:cut["h"]], cut["g"], chunk)
+        found = nemotron_h.scan_errors([[*stage, np.asarray(out), np.asarray(last)]], *(np.asarray(t[:cut["h"]])[None] for t in (a_log, d_skip, dt_bias)), state)
+        print(json.dumps({"reading": "ssd_against_the_recurrence", "form": name,
+                          **{k: found[k] for k in ("scan_state_error", "scan_error", "scan_error_unrounded")}}), flush=True)
+
+
 if __name__ == "__main__":
     given = sys.argv[1:] or ["1"]
-    main([int(a) for a in given if a.isdigit()], tuple(a for a in given if not a.isdigit()))
+    if os.environ.get("ONLY") == "profile":
+        profile()
+    else:
+        main([int(a) for a in given if a.isdigit()], tuple(a for a in given if not a.isdigit()))
