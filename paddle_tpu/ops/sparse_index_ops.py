@@ -44,6 +44,11 @@ queries and keys itself).  The target is made under the scope
 pairs), by query chunk as the scores are, with a softmax of its own so that it
 sums to 1 whatever kernel made the attention's output; that attention's
 log-sum-exp (`fused_attention`'s output `Lse`) only steadies the exponentials.
+On the TPU, where a chunk and its band are whole tiles and the heads 128 lanes
+wide, one Pallas kernel a chunk makes it (`ops/alignment_target_kernels.py`: a
+head's scores and exponentials live for a block of keys in VMEM); anywhere else
+`attention_target`'s plain form, whose [group, C, K] float32 exponentials pass
+through HBM; the platform and the shape choose, nothing else can.
 Its gradient reaches
 qI, kI and w and nothing else; it is computed WITH the value, chunk by chunk
 (the value is linear in its cotangent), so that backward holds three small
@@ -77,7 +82,7 @@ from ..core import analysis as _A
 from ..core import resource_plan as _RP
 from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
-from . import index_alignment_kernels, sparse_index_kernels
+from . import alignment_target_kernels, index_alignment_kernels, sparse_index_kernels
 from .common import counted_rules, first
 
 #: Queries a chunk of the scores (`sa_config.q_chunk_size`, read as tiling) and queries a band: a band's chunks see the
@@ -213,14 +218,20 @@ def _whole(reduced):
     return jax.lax.optimization_barrier(reduced)
 
 
-def attention_target(q, k, lse, allowed, scale: float):
+def attention_target(q, k, lse, allowed, scale: float, kernel=None):
     """p [C, K] float32: the main attention's probabilities of a chunk's
     queries q [Hq, C, dh] over the keys k [Hkv, K, dh] that `allowed` holds,
     each head's softmax over those keys, summed over the heads and divided by
     their number.  The softmax is this function's own (it sums to 1 whatever
     made the attention's output); `lse` [Hq, C], that attention's log-sum-exp,
     only steadies the exponentials, so no pass looks for a row's largest score.
-    A key/value head's group of query heads at a time."""
+    A key/value head's group of query heads at a time.  Both forms pass here:
+    `kernel` is `alignment_target_kernels.target` where `_index_alignment`
+    found the platform and the shapes fit (a group's scores live for a block
+    of keys in VMEM only); without it the plain form below, whose [group, C, K]
+    float32 exponentials are whole in HBM before the row's sum divides them."""
+    if kernel is not None:
+        return kernel(q, k, lse, allowed, scale)
     heads, kv_heads = q.shape[0], k.shape[0]
     group = heads // kv_heads
 
@@ -265,10 +276,11 @@ def chunk_divergence_and_gradients(qi, ki, w, target, allowed, gradients=index_a
     return (term,) + tuple(gradients(qi, ki, w, d_scores))
 
 
-def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, gradients):
+def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, gradients, target_kernel=None):
     """One row's summed divergence and, with `gradients` (one of
     `index_alignment_kernels`' two forms; None for the value alone), its
-    gradients to qI [L, Hi, Di], kI [L, Di] and w [L, Hi] (the SCALED weights')."""
+    gradients to qI [L, Hi, Di], kI [L, Di] and w [L, Hi] (the SCALED weights');
+    `target_kernel` is `attention_target`'s, None for its plain form."""
     length = qi.shape[0]
     chunk = chunking(length)[0]
 
@@ -279,7 +291,7 @@ def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, gradients):
         allowed = unpack_bits(rows(picks)[:, :keys // 32], keys)
         with jax.named_scope("selected_attention"):
             target = attention_target(jax.lax.dynamic_slice_in_dim(q, start, chunk, 1), k[:, :keys],
-                                      jax.lax.dynamic_slice_in_dim(lse, start, chunk, 1), allowed, scale)
+                                      jax.lax.dynamic_slice_in_dim(lse, start, chunk, 1), allowed, scale, target_kernel)
         operands = (rows(qi), ki[:keys], rows(w))
         if gradients is None:
             return (chunk_divergence(*operands, target, allowed),)
@@ -294,25 +306,25 @@ def _alignment_row(qi, ki, w, q, k, lse, picks, scale: float, gradients):
     return jnp.sum(value), d_qi.reshape(qi.shape), jnp.sum(d_ki, axis=0), d_w.reshape(w.shape)
 
 
-def _alignment(operands, scale: float, gradients):
+def _alignment(operands, scale: float, gradients, target_kernel):
     """The rows one at a time: (each row's mean divergence a query [B], then
     that mean's gradients a row)."""
     qi = operands[0]
-    found = jax.lax.map(lambda row: _alignment_row(*row, scale, gradients), operands)
+    found = jax.lax.map(lambda row: _alignment_row(*row, scale, gradients, target_kernel), operands)
     return tuple(t / qi.shape[1] for t in found)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _divergence(qi, ki, w, q, k, lse, picks, scale, gradients):
-    return _alignment((qi, ki, w, q, k, lse, picks), scale, None)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _divergence(qi, ki, w, q, k, lse, picks, scale, gradients, target_kernel=None):
+    return _alignment((qi, ki, w, q, k, lse, picks), scale, None, target_kernel)[0]
 
 
-def _divergence_fwd(qi, ki, w, q, k, lse, picks, scale, gradients):
-    rows, *made = _alignment((qi, ki, w, q, k, lse, picks), scale, gradients)
+def _divergence_fwd(qi, ki, w, q, k, lse, picks, scale, gradients, target_kernel):
+    rows, *made = _alignment((qi, ki, w, q, k, lse, picks), scale, gradients, target_kernel)
     return rows, tuple(g.astype(t.dtype) for g, t in zip(made, (qi, ki, w)))
 
 
-def _divergence_bwd(scale, form, gradients, cotangent):
+def _divergence_bwd(scale, form, target_kernel, gradients, cotangent):
     """A row's term is linear in its cotangent: the gradients made with it, times that."""
     d_qi, d_ki, d_w = (cotangent.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype) * g for g in gradients)
     return d_qi, d_ki, d_w, None, None, None, None
@@ -337,8 +349,11 @@ def _index_alignment(ctx, op, ins):
     _MON.counter("lowering.index_alignment_ops").inc()
     _MON.counter("lowering.index_alignment_kernel_calls").inc(1 if kernel else 0)
     gradients = index_alignment_kernels.gradients if kernel else index_alignment_kernels.gradients_plain
+    target_fits = ctx.platform == "tpu" and all(alignment_target_kernels.fits(chunk, keys, q.shape[1], k.shape[1], q.shape[3])
+                                                for _, keys in bands)
+    _MON.counter("lowering.alignment_target_kernel_calls").inc(1 if target_fits else 0)
     rows = _divergence(qi, ki, scaled_weights(w, qi.shape[2], qi.shape[3]), q, k, lse.astype(jnp.float32),
-                       first(ins, "Picks"), scale, gradients)
+                       first(ins, "Picks"), scale, gradients, alignment_target_kernels.target if target_fits else None)
     return {"Out": jnp.mean(rows).reshape(1), "Rows": rows}
 
 

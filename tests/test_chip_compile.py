@@ -1004,6 +1004,28 @@ def test_the_alignment_gradients_of_a_chunk_compile_to_one_kernel_inside_its_vme
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * keys * 128 * 2 + 2 * 512 * 1024 * 4
 
 
+@pytest.mark.parametrize("keys", [2048, 16384])
+def test_the_alignment_target_of_a_chunk_compiles_to_one_kernel_and_no_per_head_array(keys, chip):
+    """`sparse_index_ops.attention_target` as the op calls it on the TPU, on a
+    chunk of Keye-VL-2.0's cell: 512 queries of 32 heads of 128 in bf16 over 4
+    key/value heads against a band's keys.  One Mosaic kernel
+    (`ops/alignment_target_kernels.py: target`), and no [8, 512, keys] array of
+    a group's scores or exponentials beside it, where the plain form's program
+    holds them in float32 (PERF.md, PR 62)."""
+    from paddle_tpu.ops import alignment_target_kernels as atk
+    from paddle_tpu.ops import sparse_index_ops as sio
+
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+        ((32, 512, 128), BF16), ((4, keys, 128), BF16), ((32, 512), F32), ((512, keys), jnp.bool_))]
+    assert atk.fits(512, keys, 32, 4, 128)
+    compiled = jax.jit(lambda *operands: sio.attention_target(*operands, 128 ** -0.5, atk.target)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "alignment_target" in text
+    assert not re.search(rf"\[(4,)?8,512,{keys}\]", text)
+    plain = jax.jit(lambda *operands: sio.attention_target(*operands, 128 ** -0.5)).lower(*shapes).compile()
+    assert re.search(rf"f32\[(4,)?8,512,{keys}\]", plain.as_text())
+
+
 @pytest.mark.slow   # two compiles, ~115 and ~80 s on every core: run by name (`-m slow`); PERF.md, PR 56, has their readings
 def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
     """`keye-vl-2.0-30b-a3b.train-dsa-s16384`'s whole step at the published
@@ -1027,8 +1049,13 @@ def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(hos
     assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:sparse_index/index_select/", text))) == 4
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/", text))) == 4
-    # the alignment's gradients are the kernel's in every layer (PR 59)
+    # the alignment's gradients are the kernel's in every layer (PR 59), and its target's (PR 62): a call a chunk loop's body,
+    # and no float32 array of a group's scores or exponentials under the op
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/[^\"]*index_alignment_gradients", text))) == 4
+    targets = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*/(sparse_index(?:_\d+)?)/op\d+:index_alignment/[^\"\n]*selected_attention/[^\"\n]*alignment_target", text)
+    assert len(set(targets)) == 4 and len(targets) == 4 * 8, (len(set(targets)), len(targets))          # eight bands a layer, a call a chunk
+    widths = "|".join(str(keys) for keys in range(2048, 16385, 2048))
+    assert not [line for line in text.splitlines() if "index_alignment" in line and re.search(rf"f32\[(4,)?8,512,({widths})\]", line)]
     windows = [int(n) for n in re.findall(r"reduce-window\([^\n]*window=\{size=[0-9x]*?x?(\d+) pad", text)]
     assert max(windows, default=0) < 2048, max(windows)      # no row's statistic is spread as one window over the row
     again = [name for name in _made_again(text) if "/cond/branch_" not in name]

@@ -46,6 +46,7 @@ def test_lowered_hash_prints_the_pinned_program_of_berts_s128_cell(capsys):
 #: tool -> its arguments in a rehearsal (a seed; seeds and steps).  Each reads `DRY=1` as it is imported (its sizes
 #: are module constants), so each is a process of its own.
 REHEARSED = {
+    "chip_alignment_target": (),
     "chip_block_attention": (),
     "chip_held_experts": (),
     "chip_index_alignment": (),

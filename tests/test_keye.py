@@ -17,7 +17,10 @@
     gradients, made from dI with the products made again (ISSUE 59:
     `ops/index_alignment_kernels.py`, plain and the kernel interpreted),
     against `jax.grad` through `index_scores`; which form the platform and the
-    shape choose; backward holds three small arrays and scales them;
+    shape choose; backward holds three small arrays and scales them; its
+    TARGET's kernel (ISSUE 62: `ops/alignment_target_kernels.py`) interpreted
+    against the plain form `attention_target`, where it fits, and which of the
+    two the platform and the shape choose;
 (d) the sectioned rotation (`mrope_section`) equals the plain one for equal
     streams, and does not for unequal ones;
 (e) the held shares of this block's router add up to the uncut layer;
@@ -52,6 +55,7 @@ from paddle_tpu.core import lowering  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import alignment_target_kernels as atk  # noqa: E402
 from paddle_tpu.ops import index_alignment_kernels as iak  # noqa: E402
 from paddle_tpu.ops import sparse_index_kernels as sik  # noqa: E402
 from paddle_tpu.ops import sparse_index_ops as sio  # noqa: E402
@@ -543,38 +547,119 @@ def test_the_gradients_kernel_takes_whole_tiles_of_no_more_rows_than_a_tile_hold
     assert [iak._block(keys) for keys in (2048, 4096, 1536, 384)] == [1024, 1024, 512, 128]
 
 
-def test_on_the_tpu_the_gradients_go_to_the_kernel_where_the_chunks_are_whole_tiles(monkeypatch):
-    """What `_index_alignment` hands `_alignment_row`, by the platform and the
-    shape alone, and what `lowering.index_alignment_kernel_calls` counts."""
-    def seen(qi, ki, w, q, k, lse, picks, scale, gradients):
-        chosen.append(gradients)
+def forms_handed_to_a_row(monkeypatch, platform, length, index_heads=2, index_width=8, heads=2, kv_heads=1, width=8):
+    """((gradients, target_kernel) that `_index_alignment` hands `_alignment_row` where the op is differentiated, the
+    `lowering.` counters that lowering moved)."""
+    def seen(qi, ki, w, q, k, lse, picks, scale, gradients, target_kernel=None):
+        chosen.append((gradients, target_kernel))
         return (jnp.zeros((), jnp.float32),) + ((jnp.zeros_like(qi), jnp.zeros_like(ki), jnp.zeros_like(w)) if gradients else ())
-
-    def forms(platform, length, index_heads, index_width):
-        rng = np.random.RandomState(0)
-        qi, ki, w = indexer_operands(rng, 1, length, index_heads, index_width)
-        q, k, _ = attention_operands(rng, 1, 2, 1, length, 8)
-        ins = {"QI": qi, "KI": ki, "W": w, "Q": q, "K": k, "Lse": np.zeros((1, 2, length), "f4"),
-               "Picks": np.zeros((1, length, length // 32), "i4")}
-        op = SimpleNamespace(type="index_alignment", attr=lambda n, d=None: d)
-        ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
-
-        def run(*operands):
-            return get_op_def("index_alignment").lower(ctx, op, {**{n: [jnp.asarray(v)] for n, v in ins.items()},
-                                                                 **dict(zip(("QI", "KI", "W"), ([o] for o in operands)))})["Out"][0]
-
-        _, counted = lowering_counters(lambda: jax.grad(run, (0, 1, 2))(*(jnp.asarray(t) for t in (qi, ki, w))))
-        assert counted["lowering.index_alignment_ops"] == 1
-        return chosen[-1], counted["lowering.index_alignment_kernel_calls"]
 
     chosen = []
     monkeypatch.setattr(sio, "_alignment_row", seen)
+    rng = np.random.RandomState(0)
+    qi, ki, w = indexer_operands(rng, 1, length, index_heads, index_width)
+    q, k, _ = attention_operands(rng, 1, heads, kv_heads, length, width)
+    ins = {"QI": qi, "KI": ki, "W": w, "Q": q, "K": k, "Lse": np.zeros((1, heads, length), "f4"),
+           "Picks": np.zeros((1, length, length // 32), "i4")}
+    op = SimpleNamespace(type="index_alignment", attr=lambda n, d=None: d)
+    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
+
+    def run(*operands):
+        return get_op_def("index_alignment").lower(ctx, op, {**{n: [jnp.asarray(v)] for n, v in ins.items()},
+                                                             **dict(zip(("QI", "KI", "W"), ([o] for o in operands)))})["Out"][0]
+
+    _, counted = lowering_counters(lambda: jax.grad(run, (0, 1, 2))(*(jnp.asarray(t) for t in (qi, ki, w))))
+    assert counted["lowering.index_alignment_ops"] == 1
+    return chosen[-1], counted
+
+
+def test_on_the_tpu_the_gradients_go_to_the_kernel_where_the_chunks_are_whole_tiles(monkeypatch):
+    """What `_index_alignment` hands `_alignment_row`, by the platform and the
+    shape alone, and what `lowering.index_alignment_kernel_calls` counts."""
+    def forms(platform, length, index_heads, index_width):
+        (gradients, _), counted = forms_handed_to_a_row(monkeypatch, platform, length, index_heads, index_width)
+        return gradients, counted["lowering.index_alignment_kernel_calls"]
+
     assert forms("tpu", 4096, 2, 64) == (iak.gradients, 1)
     assert forms("tpu", 1024, 16, 8) == (iak.gradients, 1)              # one band, two chunks of 512 x 1024 keys
     assert forms("cpu", 4096, 2, 64) == (iak.gradients_plain, 0)
     assert forms("tpu", 96, 2, 64) == (iak.gradients_plain, 0)          # one chunk of 96 keys: no whole tile
     assert forms("tpu", 1280, 2, 64) == (iak.gradients_plain, 0)        # one chunk of 1280 queries: over a tile's rows
     assert forms("tpu", 1024, 2, 8) == (iak.gradients_plain, 0)         # 16 of 128 lanes: no whole tile of qI
+
+
+def target_operands(rng, heads, kv_heads, chunk, keys, first_query, dtype, held=None):
+    """A chunk's (q, k, lse, allowed) of `attention_target`: the queries `first_query` on see keys up to themselves;
+    with `held`, each holds that many of them at random (its own among them), else all.  `lse` is the allowed scores'
+    log-sum-exp moved by a third, as another kernel's rounding moves it."""
+    q, k = jnp.asarray(rng.randn(heads, chunk, 128), dtype), jnp.asarray(rng.randn(kv_heads, keys, 128), dtype)
+    at = first_query + np.arange(chunk)[:, None]
+    allowed = np.arange(keys) <= at
+    if held is not None:
+        drawn = np.where(allowed, rng.rand(chunk, keys), 2.0)
+        allowed = (drawn < np.sort(drawn, axis=-1)[:, held - 1:held]) | (np.arange(keys) == at)
+    s = jnp.einsum("ghcd,gkd->ghck", q.reshape(kv_heads, -1, chunk, 128), k, preferred_element_type=jnp.float32) * 128 ** -0.5
+    lse = jax.nn.logsumexp(jnp.where(allowed, s, -jnp.inf), axis=-1).reshape(heads, chunk) + 1 / 3
+    return q, k, lse, jnp.asarray(allowed)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case,keys,heads,kv_heads,first_query,held", [
+    ("rows-that-hold-one-key", 2048, 8, 1, 1536, 1),
+    ("every-key-up-to-the-diagonal", 2048, 8, 1, 1536, None),
+    ("a-band-wider-than-its-diagonal", 4096, 8, 1, 2048, 200),
+    ("two-groups-of-eight", 2048, 16, 2, 0, 64)])
+def test_the_target_kernel_interpreted_is_the_plain_form(case, keys, heads, kv_heads, first_query, held, dtype):
+    """`alignment_target_kernels.target` at a chunk of the cell's shape, 512
+    queries of groups of 8 heads of 128 against two and four thousand keys: rows
+    that hold ONE key (the target is 1 there), rows that hold every key up to
+    the diagonal, a band's first chunk (its last blocks of keys hold no allowed
+    pair: the kernel's sums pass zeros) and two key/value groups.  Float32
+    differences at rounding's size, each row's target summing to 1."""
+    q, k, lse, allowed = target_operands(np.random.RandomState(62), heads, kv_heads, 512, keys, first_query, dtype, held)
+    assert atk.fits(512, keys, heads, kv_heads, 128)
+    got = atk.target(q, k, lse, allowed, 128 ** -0.5, interpret=True)
+    want = jax.jit(sio.attention_target, static_argnums=4)(q, k, lse, allowed, 128 ** -0.5)
+    assert got.shape == want.shape == (512, keys) and got.dtype == want.dtype == jnp.float32
+    agree(got, want, 1e-6)
+    assert not np.asarray(got)[~np.asarray(allowed)].any()
+    np.testing.assert_allclose(np.asarray(got).sum(-1), 1.0, atol=2e-6)
+    if held == 1:
+        assert np.array_equal(np.asarray(got)[np.asarray(allowed)], np.ones(512, "f4"))
+    # the seam hands the kernel what it was given and nothing else
+    through = sio.attention_target(q, k, lse, allowed, 128 ** -0.5, functools.partial(atk.target, interpret=True))
+    assert np.array_equal(np.asarray(through), np.asarray(got))
+
+
+@pytest.mark.parametrize("shape,fits", [
+    *(((512, keys, 32, 4, 128), True) for keys in range(2048, 16385, 2048)),       # the cell's eight bands
+    ((128, 256, 8, 8, 128), True), ((512, 2048, 1, 1, 128), True),                   # groups of one head (the one-head control's)
+    ((96, 96, 8, 2, 128), False),          # a chunk that is no whole tile of the mask's bytes, keys that are no whole tile
+    ((1024, 1024, 8, 2, 128), False),      # more rows than a call takes
+    ((512, 2048, 8, 2, 64), False),        # a head of half a tile's lanes
+    ((512, 2048, 64, 4, 128), False)],     # twice the heads whose chunk of q the kernel holds in VMEM
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_the_target_kernel_takes_whole_tiles_of_heads_128_lanes_wide(shape, fits):
+    assert atk.fits(*shape) is fits
+
+
+@pytest.mark.parametrize("platform,length,heads,kv_heads,width,kernel", [
+    ("tpu", 4096, 8, 2, 128, True),
+    ("tpu", 1024, 2, 2, 128, True),         # one band, two chunks of 512 x 1024 keys, groups of one head
+    ("cpu", 4096, 8, 2, 128, False),
+    ("tpu", 96, 8, 2, 128, False),          # one chunk of 96 keys: no whole tile
+    ("tpu", 1280, 8, 2, 128, False),        # one chunk of 1280 queries: over a call's rows
+    ("tpu", 4096, 8, 2, 64, False)])        # heads of half a tile's lanes
+def test_on_the_tpu_the_target_goes_to_the_kernel_where_the_chunks_are_whole_tiles(platform, length, heads, kv_heads, width, kernel,
+                                                                                    monkeypatch):
+    """What `_index_alignment` hands `_alignment_row` for the target, by the
+    platform and the attention's shapes alone (the indexer's decide the
+    gradients' form, not this one), and what
+    `lowering.alignment_target_kernel_calls` counts."""
+    (gradients, target_kernel), counted = forms_handed_to_a_row(monkeypatch, platform, length, heads=heads, kv_heads=kv_heads, width=width)
+    assert gradients is iak.gradients_plain and counted["lowering.index_alignment_kernel_calls"] == 0     # 8 of 128 lanes of qI
+    assert counted["lowering.alignment_target_kernel_calls"] == (1 if kernel else 0)
+    assert target_kernel is (atk.target if kernel else None)
 
 
 def test_backward_holds_three_small_arrays_and_only_scales_them():
@@ -1002,7 +1087,8 @@ def test_the_reference_check_fails_on(fault, monkeypatch):
             **real(ctx, op, ins), "Out": real(ctx, op, {k: v for k, v in ins.items() if k != "Picks"})["Out"]})
     elif fault == "target_of_one_head":
         real = sio.attention_target
-        monkeypatch.setattr(sio, "attention_target", lambda q, k, lse, allowed, scale: real(q[:1], k[:1], lse[:1], allowed, scale))
+        monkeypatch.setattr(sio, "attention_target",
+                            lambda q, k, lse, allowed, scale, *kernel: real(q[:1], k[:1], lse[:1], allowed, scale, *kernel))
     else:
         real = get_op_def("rotary_embedding").lower
 

@@ -170,10 +170,10 @@ PARENTS_CELLS = {
         "5d27410c095290ca3ba3084360cf6fddf735c135027655eff2962967ccd84a7a"),
     "kanana-2-30b-a3b.train-mla-s16384": ("train_9752db24",
         "c11c680130484415415ebbd16816934a956b91a9a77afa06c1fe569d39a6c118"),
-    # re-recorded by PR 59, which means to change this program (`index_alignment`'s gradients: `ops/index_alignment_kernels.py`);
-    # the parent's line was 002087c4...12882, and the four above are the parent's still
+    # re-recorded by PR 62, which means to change this program (`index_alignment`'s target: `ops/alignment_target_kernels.py`),
+    # as PR 59 did for the op's gradients; the parent's line was bd1e3b97...545f0, and the four above are the parent's still
     "keye-vl-2.0-30b-a3b.train-dsa-s16384": ("train_21d207fd",
-        "bd1e3b97dc87e2983ca41ffb459f253febce8b000e5aa2471a7a12dfd37545f0"),
+        "89cf2a5715956162615cb03e3226f7bfe673601c7be3b465bf505928e582ea4e"),
 }
 
 

@@ -150,8 +150,8 @@ def faults(cfg):
     def dense(real, ctx, op, ins):
         return {**real(ctx, op, ins), "Out": real(ctx, op, {k: v for k, v in ins.items() if k != "Picks"})["Out"]}
 
-    def one_head(real, q, k, lse, allowed, scale):
-        return real(q[:1], k[:1], lse[:1], allowed, scale)
+    def one_head(real, q, k, lse, allowed, scale, *kernel):      # through the seam both forms pass: the chip's kernel takes the one head
+        return real(q[:1], k[:1], lse[:1], allowed, scale, *kernel)
 
     return {
         "half_the_picks": lambda: lowered_as("sparse_index", halved),
