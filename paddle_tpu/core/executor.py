@@ -25,7 +25,8 @@ from ..dist_resilience import guard_blocking as _guard_blocking
 from ..monitor import MONITOR as _MON
 from . import locks
 from .dtypes import as_np_dtype
-from .lowering import LoweringContext, jaxpr_size, phase, plan_kept, plan_latent_operands, profiled, run_block_with_backward
+from .lowering import (LoweringContext, count_layer_forms, jaxpr_size, phase, plan_kept, plan_latent_operands, profiled,
+                       run_block_with_backward)
 from .program import Program, Variable, default_main_program
 from .scope import RNG_STATE_VAR, Scope, global_scope
 
@@ -257,6 +258,7 @@ class _CompiledStep:
             with phase("plan_kept"):
                 plan_kept(ctx, ops, {n: v.shape for n, v in feeds.items()}, self._held_bytes(env, whole=manual))
                 plan_latent_operands(ctx, ops)
+                count_layer_forms(ops)
             env.update(feeds)
             env = run_block_with_backward(ctx, ops, env)
             new_state = {n: env[n] for n in written if n in env}

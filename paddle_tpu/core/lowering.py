@@ -411,6 +411,34 @@ def plan_kept(ctx: LoweringContext, ops: List[Operator], feed_shapes: Dict[str, 
     _MON.counter("lowering.recomputed_candidates_bytes").inc(sum(value.nbytes for value in candidates))
 
 
+def count_layer_forms(ops: List[Operator]) -> None:
+    """Counted once a trace of a block with a backward pass, beside
+    `plan_kept`'s counters and from the program's own ops:
+    `lowering.routers_before_attention`, the `moe_router` ops between which and
+    the `moe_experts` that reads their choice a `fused_attention` stands (a
+    router that reads its layer's input ahead of the attention:
+    `layers.moe(router_input=)`), and `lowering.attention_layers_without_positions`,
+    the `fused_attention` ops whose queries no `rotary_embedding` reaches
+    between their projection and the attention."""
+    if not any(op.type == "backward" for op in ops):
+        return
+    made_by = {name: op for op in ops for name in op.output_arg_names}
+
+    def rotated(name, depth=3):   # a rotation between this value and the product that projected it
+        op = made_by.get(name)
+        if op is None or op.type in ("mul", "matmul") or not depth:
+            return False
+        return op.type == "rotary_embedding" or any(rotated(n, depth - 1) for n in op.input_arg_names)
+
+    chosen_at = {op.output("TopKIndex")[0]: i for i, op in enumerate(ops) if op.type == "moe_router"}
+    attentions = [i for i, op in enumerate(ops) if op.type == "fused_attention"]
+    ahead = sum(any(chosen_at[op.input("TopKIndex")[0]] < a < i for a in attentions)
+                for i, op in enumerate(ops) if op.type == "moe_experts" and op.input("TopKIndex")[0] in chosen_at)
+    _MON.counter("lowering.routers_before_attention").inc(ahead)
+    _MON.counter("lowering.attention_layers_without_positions").inc(
+        sum(not rotated(ops[a].input("Q")[0]) for a in attentions))
+
+
 def plan_latent_operands(ctx: LoweringContext, ops: List[Operator]) -> None:
     """Choose, once a trace and after `plan_kept`, which latent attentions
     among `ops` are lowered with the chain of ops between their projections and

@@ -907,7 +907,7 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None,
         scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0,
         shared_experts=0, shared_attrs=None, activation="silu", gated=True, latent_size=None, latent_attrs=None,
-        shared_width=None):
+        shared_width=None, router_input=None):
     """A layer of routed experts over (..., d): a float32 router picks
     `top_k` of `num_experts` experts of width `expert_width` for every token;
     their outputs are summed, weighted by the router's scores (renormalised
@@ -917,7 +917,9 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     The experts' form is two attributes of the one op `moe_experts`: `gated`
     (the default) with `activation="silu"` is W_down(silu(W_gate x) * (W_up x)),
     three matrices an expert; `gated=False` is W_down(act(W_up x)), TWO matrices
-    and no gate; `activation="relu2"` is relu(.)^2.  `latent_size=L` puts the
+    and no gate; `activation` is one of three: "silu", "relu" (with the gate:
+    W_down(relu(W_gate x) * (W_up x)), the gated ReLU) and "relu2", relu(.)^2.
+    `latent_size=L` puts the
     experts in a latent: the layer projects the token into L dimensions ONCE (u
     = x W_in, `latent_attrs[0]`), the router still reads x, every expert's
     matrices are (L, F) and (F, L), and the weighted sum is projected back once
@@ -960,17 +962,21 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     from ..core.program import name_scope
 
     helper = LayerHelper("moe", name=name)
-    if activation not in ("silu", "relu2"):
-        raise ValueError(f"moe: activation={activation!r}; \"silu\" or \"relu2\"")
+    if activation not in ("silu", "relu", "relu2"):
+        raise ValueError(f"moe: activation={activation!r}; \"silu\", \"relu\" or \"relu2\"")
     hidden_in, lead = input, tuple(input.shape[:-1])
+    routed_on = hidden_in if router_input is None else router_input
+    if tuple(routed_on.shape[:-1]) != lead:
+        raise ValueError(f"moe: router_input {tuple(routed_on.shape)} beside an input {tuple(input.shape)}: a choice a token")
     in_latent = name_scope("latent_experts") if latent_size else contextlib.nullcontext()
     with in_latent:
         if latent_size:
             input = fc(hidden_in, int(latent_size), num_flatten_dims=len(lead), bias_attr=False,
                        param_attr=(latent_attrs or (None, None))[0])
         out, balance, z_loss = _routed_experts(
-            helper, hidden_in, input, num_experts, expert_width, top_k, norm_topk_prob, router_attr, gate_attr, up_attr,
-            down_attr, held, scoring, bias_attr, routed_scaling_factor, norm_eps, shared_experts, activation, gated)
+            helper, routed_on, input, num_experts, expert_width, top_k, norm_topk_prob, router_attr, gate_attr, up_attr,
+            down_attr, held, scoring, bias_attr, routed_scaling_factor, norm_eps, shared_experts, activation, gated,
+            ahead=routed_on is not hidden_in)
         if latent_size:
             out = fc(out, int(hidden_in.shape[-1]), num_flatten_dims=len(lead), bias_attr=False,
                      param_attr=(latent_attrs or (None, None))[1])
@@ -982,7 +988,7 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
             return fc(t, size, num_flatten_dims=len(lead), act=act, param_attr=attr, bias_attr=False)
 
         with name_scope("shared_expert"):
-            act = {"silu": "swish", "relu2": "relu"}[activation]
+            act = {"silu": "swish", "relu": "relu", "relu2": "relu"}[activation]
             hidden = project(hidden_in, width, gate_a if gated else up_a, act=act)
             if activation == "relu2":
                 hidden = square(hidden)
@@ -994,9 +1000,11 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
 
 def _routed_experts(helper, routed_on, input, num_experts, expert_width, top_k, norm_topk_prob, router_attr, gate_attr,
                     up_attr, down_attr, held, scoring, bias_attr, routed_scaling_factor, norm_eps, shared_experts,
-                    activation, gated):
+                    activation, gated, ahead=False):
     """`moe`'s two ops: the router on `routed_on` (the layer's input) and the
-    experts on `input` (the same, or its projection into the latent)."""
+    experts on `input` (the same, or its projection into the latent).  `ahead`:
+    `routed_on` was made earlier than the experts' input, and the router's op
+    stands where it is first read."""
     d = int(input.shape[-1])
     lead = tuple(input.shape[:-1])
     n_held = num_experts if held is None else int(held[1])
@@ -1026,6 +1034,9 @@ def _routed_experts(helper, routed_on, input, num_experts, expert_width, top_k, 
         router_inputs["Bias"] = [_persistable_tensor(helper, bias_attr, [num_experts], "float32").name]
         router_outputs["BiasMoved"] = [_out(helper, "int32", shape=(1,)).name]
     helper.append_op("moe_router", inputs=router_inputs, outputs=router_outputs, attrs=router_attrs)
+    if ahead:   # before the first op that reads the router's input (the layer's norm): nothing it reads is made later
+        ops = helper.main_block.ops
+        ops.insert(next(i for i, op in enumerate(ops) if routed_on.name in op.input_arg_names), ops.pop())
     out = _out(helper, input.dtype, shape=input.shape)
     dropped = _out(helper, "int32", shape=(1,))
     outputs = {"Out": [out.name], "Dropped": [dropped.name]}

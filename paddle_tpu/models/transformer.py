@@ -479,9 +479,11 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     deviation, seed) of a router bias that enters the choice alone;
     `shared_experts`: how many shared experts every token passes beside the
     routed ones; `activation`, `gated`, `latent_size`, `shared_width`: the
-    experts' form, the latent they live in and the shared expert's own width:
-    `layers.moe`); its two auxiliary losses are appended to
-    `aux_losses` as (load balance, router z).
+    experts' form, the latent they live in and the shared expert's own width;
+    `router_ahead`: the router reads the LAYER's input x, before the input norm
+    and the operator, and not the normed h the experts read, and its op stands
+    ahead of the operator's: `layers.moe(router_input=)`); its two auxiliary
+    losses are appended to `aux_losses` as (load balance, router z).
     """
     if (operator is None or ffn is None) and not pre_norm:
         raise ValueError("encoder_layer: a layer of one part (operator=None or ffn=None) is a pre-norm layer, "
@@ -503,7 +505,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         if moe is not None:
             bias = moe.get("bias")
             out, balance, z_loss = layers.moe(
-                t, moe["num_experts"], d_ff, moe["top_k"],
+                t, moe["num_experts"], d_ff, moe["top_k"], router_input=layer_input if moe.get("router_ahead") else None,
                 norm_topk_prob=moe.get("norm_topk_prob", False), held=moe.get("held"),
                 router_attr=_attr(f"{prefix}.moe.router.w", seed=moe.get("router_seed", 0)),
                 gate_attr=_attr(f"{prefix}.moe.gate.w"),
@@ -532,6 +534,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
         return layers.fc(ffn1, d_model, num_flatten_dims=2,
                          param_attr=_attr(f"{prefix}.ffn2.w"), bias_attr=_attr(f"{prefix}.ffn2.b"))
 
+    layer_input = x   # what a router ahead of the attention reads: the stream as it enters the layer, before any norm
     if operator is None:   # a feed-forward part with no operator
         return layers.elementwise_add(x, feed_forward(normed(x, "ln2")))
     operator_in = normed(x, "ln1") if pre_norm else x
@@ -825,6 +828,20 @@ def build_causal_lm(
     expert with relu(.)^2 between, a latent the layer projects into and out of
     once a token, a shared expert of its own width).
 
+    A decoder that chooses its experts AHEAD of the attention and mixes layers
+    with and without positions (SmallThinker: arXiv:2507.20984) is arguments
+    too.  `expert_form` may hold `router_ahead=True`: every sparse layer's
+    router reads the layer's input itself, before the input norm and the
+    attention, and its `moe_router` op stands ahead of the attention's ops
+    (`encoder_layer`, `layers.moe(router_input=)`); the experts read the normed
+    post-attention stream as ever; and `activation="relu"` with the gate is the
+    gated ReLU.  `rotary` is a statement a LAYER where it is no single boolean:
+    a sequence of booleans, one a layer as `layer_types` is, or a dict from a
+    layer's kind to one (a kind it does not name rotates): a "full_attention"
+    layer that attends to every earlier key without any position then stands
+    beside "sliding_attention" layers whose queries and keys carry the rotary
+    embedding.  `pos_ids` is a feed where any layer rotates.
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -892,7 +909,16 @@ def build_causal_lm(
     if not 0 <= num_dense_layers <= len(kinds) or (num_dense_layers and not dense_width):
         raise ValueError(f"build_causal_lm: num_dense_layers={num_dense_layers} leading dense layers of "
                          f"dense_width={dense_width} among {len(kinds)} layers")
-    if latent and latent.get("rope") and not rotary:
+    if isinstance(rotary, dict):
+        rotates = [bool(rotary.get(kind, True)) for kind in kinds]
+    elif isinstance(rotary, (list, tuple)):
+        if len(rotary) != len(kinds):
+            raise ValueError(f"build_causal_lm: rotary states {len(rotary)} layers beside {len(kinds)} layer_types")
+        rotates = [bool(r) for r in rotary]
+    else:
+        rotates = [bool(rotary)] * len(kinds)
+    rotary = any(rotates)
+    if latent and latent.get("rope") and not all(r for r, kind in zip(rotates, kinds) if kind == "latent_attention"):
         raise ValueError("build_causal_lm: latent=dict(rope=True) rotates by pos_ids, which rotary=False leaves out of "
                          "the feeds")
     if loop is not None and (num_dense_layers < len(kinds) or loss_positions):
@@ -930,7 +956,7 @@ def build_causal_lm(
                                       dropout_prob=0.0, causal=(window or attention_mask) is None,
                                       use_fused_attention=use_fused_attention,
                                       norm=norm, unit_norms=True, norm_eps=norm_eps, pre_norm=True, proj_bias=proj_bias,
-                                      qk_norm=qk_norm, positions=pos_ids, rope_theta=rope_theta,
+                                      qk_norm=qk_norm, positions=pos_ids if rotates[i] else None, rope_theta=rope_theta,
                                       moe=experts if has_ffn and not dense else None, ffn="gated_silu" if has_ffn else None,
                                       aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
                                       attention_mask=window or attention_mask,

@@ -303,7 +303,21 @@ def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
 #:
 #: so a block of the window's own length, `_KV_COMPUTE` keys a step, dq a kernel of its own (`window_attention` as the
 #: op calls it: 8.36).  The output and gradients against dense float32 at (1, 8 on 4, 2048, 64): 3.5e-3, 4.4e-3,
-#: 2.7e-3, 3.8e-3 of the largest, the causal rule's readings.  Only a window of 512 was priced.
+#: 2.7e-3, 3.8e-3 of the largest, the causal rule's readings.
+#:
+#: A window LONGER than the largest block takes that block, 1024.  TPU v5e, (1, 28 on 4, 16384, 128) bf16, window 4096
+#: (SmallThinker's window layer), forward + backward of a layer alone, ms (my chip run, PR 63; `WINDOW=4096 python3
+#: tools/chip_block_attention.py`; the causal rule over the same operands 49.59, the band 43.7% of its pairs):
+#:
+#:   block (keys a step)           512 (512)   1024 (512)   1024 (1024)   2048 (512)
+#:   pairs visited / allowed       1.12        1.25         1.25          overruns the scoped VMEM
+#:   dq and dkv apart              34.26       30.96        31.87
+#:   fused backward                39.62       29.50
+#:   as the op calls it            34.28       31.60
+#:
+#: so 1024, which `window_block` already took: a block of 512 visits 10% fewer pairs and loses 8% to its grid steps.
+#: The fused backward is 1.5 ms a layer ahead alone at 1024 and is NOT taken: its partial dq, [16, 28, 16384, 128] a
+#: layer, is 1.9 GB of the step's memory.  Against dense float32 at (1, 7 on 1, 6144, 128): 2.4e-3, 4.0e-3, 5.2e-3, 3.8e-3.
 _WINDOW_BLOCKS = (128, 256, 512, 1024)
 
 

@@ -486,13 +486,15 @@ def grouped_matmul(rows, weights, group_sizes, platform=None):
 
 def _activated(products, weight, activation):
     """An expert's hidden rows in float32 from its first products' outputs: the
-    activation of the first (`"silu"`, or `"relu2"`: relu(.)^2), times the
-    second where the expert is gated, times the row's router weight."""
+    activation of the first (`"silu"`, `"relu"`, or `"relu2"`: relu(.)^2), times
+    the second where the expert is gated, times the row's router weight."""
     def wide(t):   # float32, each where it is first read
         return t if t.dtype == jnp.float32 else t.astype(jnp.float32)
 
     opened, *gated_by = products
-    hidden = jax.nn.silu(wide(opened)) if activation == "silu" else jnp.square(jax.nn.relu(wide(opened)))
+    hidden = jax.nn.silu(wide(opened)) if activation == "silu" else jax.nn.relu(wide(opened))
+    if activation == "relu2":
+        hidden = jnp.square(hidden)
     for other in gated_by:
         hidden = hidden * wide(other)
     return hidden * weight
@@ -511,7 +513,8 @@ def _moe_experts(ctx, op, ins):
 
     The experts' form is two attributes: `gated` (the default: inputs WGate,
     WUp, WDown, hidden = act(gate) * up) or not (WUp and WDown alone, hidden =
-    act(up)), and `activation`, "silu" (the default) or "relu2", relu(.)^2.
+    act(up)), and `activation`, "silu" (the default), "relu" or "relu2",
+    relu(.)^2.
 
     With the attribute `held` = (first, count) the matrices are those
     of `count` experts from `first` on, and what the absent experts would
@@ -1044,8 +1047,8 @@ def _infer_moe_experts(ctx):
     xs = ctx.in_shape("X")
     gated = ctx.op.attr("gated", True)
     gate, up, down = (ctx.in_shape(s) for s in ("WGate", "WUp", "WDown"))
-    if ctx.op.attr("activation", "silu") not in ("silu", "relu2"):
-        ctx.fail(f"activation {ctx.op.attr('activation')!r} is neither silu nor relu2")
+    if ctx.op.attr("activation", "silu") not in ("silu", "relu", "relu2"):
+        ctx.fail(f"activation {ctx.op.attr('activation')!r} is none of silu, relu and relu2")
     if not gated:   # two matrices an expert: no WGate
         if gate is not None:
             ctx.fail("gated=False: experts of two matrices have no WGate")

@@ -873,6 +873,37 @@ def test_phi4_mini_flashs_step_keeps_every_product_and_kernel_residual_and_its_p
     assert again and not [name for name in again if name.endswith(("/dot_general", "/pallas_call"))]
 
 
+def test_smallthinkers_step_compiles_for_the_chip_with_its_routers_ahead_and_a_window_of_4096(host, monkeypatch):
+    """`smallthinker-21b-a3b.train-nope-swa-s16384`'s whole step at the published
+    widths and 16384 tokens, compiled for the described v5e with what
+    `plan_kept` chooses at the chip's memory limit: all 28 candidates of the
+    four sparse segments (every product's output, the kernels' residuals, the
+    expert products' outputs and the routers' logits: 1.74 GB), planned over the
+    25% of the chip a cell has to fill and under 9 GB; the full layer took the
+    causal splash kernels and the three window layers the window rule's, in
+    blocks of 1024 at (28 on 4, 128) inside the scoped VMEM; every router's
+    scope stands AHEAD of its layer's attention; and in the rematerialised
+    computations no product and no attention kernel is left (ISSUE 63)."""
+    compiled, counted = _kept_step("smallthinker", "smallthinker-21b-a3b", "train-nope-swa-s16384", host.devices, monkeypatch)
+    assert counted == {"segments": 4, "sparse_segments": 4, "kept_values": 28, "kept_bytes": 1735393280,
+                       "candidates_bytes": 1735393280}
+    peak = _planned_peak(compiled)
+    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 0.25 * 16.9e9 <= peak <= 9.0e9, peak
+    text = compiled.as_text()
+    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    window = {re.search(r"/(sliding_attention(?:_\d+)?)/", n).group(1) for n in names if "/window_attention/" in n}
+    assert len(window) == 3                        # the three rotary layers, each under its own numbered scope
+    assert any(re.search(r"/op\d+:fused_attention/block_sparse_attention/", n) for n in names)      # the full layer: no window scope
+    routers = sorted({int(i) for n in names for i in re.findall(r"/op(\d+):moe_router", n)})
+    attentions = sorted({int(i) for n in names for i in re.findall(r"/op(\d+):fused_attention", n)})
+    assert len(routers) == len(attentions) == 4 and all(r < a for r, a in zip(routers, attentions))
+    assert all(a < r for a, r in zip(attentions, routers[1:]))          # router, attention, router, attention, ...
+    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]
+
+
 def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
     """ONE latent attention layer at Kanana-2's widths (H 32, 192 / 128) over
     2048 positions, forward and backward through `_CompiledStep`, compiled for

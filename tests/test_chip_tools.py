@@ -56,6 +56,7 @@ REHEARSED = {
     "chip_phi4flash_controls": ("1",),
     "chip_row_attention": (),
     "chip_sdar_routing": ("1", "2"),
+    "chip_smallthinker_controls": ("1", "router_in_bf16", "window_of_17"),   # the sound run, a wrapped lowering, a program built again
     "chip_token_sum": (),
 }
 

@@ -1,0 +1,596 @@
+"""SmallThinker-21BA3B's parts and the whole, tiny on the CPU (ISSUE 63).
+
+(a) `layers.moe(router_input=)`: handed the experts' own input it is today's
+    program, op for op and lowered text for text; handed the layer's input the
+    router's op stands ahead of the attention, in the layer's segment; the
+    gated ReLU is the third activation, and the three are named where another
+    is refused;
+(b) the router's two forms are one number: the softmax over the six chosen
+    logits, and the softmax over all 64 renormalised over the six;
+(c) the eight shares of one sparse layer add up to the uncut layer;
+(d) positions a layer: a full layer without positions does not read `pos_ids`,
+    a rotary window layer does; a query at position 5000 under a window of 4096
+    does not see key 904 and sees key 905, in the rule, in the kernels' block
+    maps and through the op;
+(e) a two-period toy (8 layers, window 8 of 32 positions, 8 experts top 2,
+    every expert held) in float32 against the benchmark's reference
+    (benchmark/models/smallthinker.py) on seeded weights: loss, logits,
+    routing, every stage, every parameter's gradient, with and without
+    `recompute_layers` to the last bit; the two lowering counters; in bf16
+    within the benchmark's tolerances; and the faults the comparison refuses.
+
+One compiled tiny model serves (e): `float32_run`.
+"""
+import os
+import sys
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import paddle_tpu as fluid  # noqa: E402
+from benchmark import manifest as mf  # noqa: E402
+from benchmark.models import smallthinker  # noqa: E402
+from paddle_tpu import layers, monitor  # noqa: E402
+from paddle_tpu.core import unique_name  # noqa: E402
+from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
+from paddle_tpu.core.registry import get_op_def  # noqa: E402
+from paddle_tpu.models import transformer  # noqa: E402
+from paddle_tpu.ops import masked_attention  # noqa: E402
+
+
+def lower(op_type, ins, attrs=None):
+    """One op's lowering called as the interpreter calls it."""
+    attrs = attrs or {}
+    op = SimpleNamespace(type=op_type, attr=lambda n, d=None: attrs.get(n, d))
+    ctx = LoweringContext(jax.random.PRNGKey(0))
+    return get_op_def(op_type).lower(ctx, op, {k: [jnp.asarray(v)] for k, v in ins.items()})
+
+
+def agree(got, want, tol=1e-5, floor=1e-12):
+    got, want = np.asarray(got, "f8"), np.asarray(want, "f8")
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), floor), \
+        (np.abs(got - want).max(), np.abs(want).max())
+
+
+@pytest.fixture(autouse=True)
+def float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# -- (a) the router's input is an argument of its own -----------------------------------------
+
+def sparse_layer(router_input, **kw):
+    """x -> rms -> a product (standing for an attention) -> moe, the router on `router_input(x, h)`."""
+    with unique_name.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = layers.data("x", [6, 16], dtype="float32")
+            h = layers.elementwise_add(x, layers.fc(layers.rms_norm(x, begin_norm_axis=2), 16, num_flatten_dims=2))
+            out, _, _ = layers.moe(layers.rms_norm(h, begin_norm_axis=2), 8, 4, 2, norm_topk_prob=True,
+                                   router_input=router_input(x, h), **kw)
+    return main, startup, out
+
+
+def test_router_input_that_is_the_experts_own_input_is_todays_program_op_for_op():
+    """`router_input=None` and `router_input=` the very tensor the experts read
+    build the same ops in the same order with the same inputs and attributes
+    (the lowering reads nothing else: the same listing is the same lowered
+    text), and run to the same bits."""
+    plain = sparse_layer(lambda x, h: None)
+    # the experts' input is made inside `sparse_layer`: hand `moe` its own `input` by building it again by hand
+    with unique_name.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = layers.data("x", [6, 16], dtype="float32")
+            h = layers.elementwise_add(x, layers.fc(layers.rms_norm(x, begin_norm_axis=2), 16, num_flatten_dims=2))
+            m = layers.rms_norm(h, begin_norm_axis=2)
+            out, _, _ = layers.moe(m, 8, 4, 2, norm_topk_prob=True, router_input=m)
+    same = (main, startup, out)
+
+    def listing(program):
+        return [(op.type, dict(op.inputs), dict(op.outputs), dict(op.attrs)) for op in program.global_block().ops]
+
+    assert listing(plain[0]) == listing(same[0]) and listing(plain[1]) == listing(same[1])
+    feed = {"x": np.random.RandomState(0).randn(2, 6, 16).astype("f4")}
+    outs = []
+    for program, start, fetched in (plain, same):
+        program.random_seed = start.random_seed = 7
+        scope, exe = fluid.Scope(), fluid.Executor(fluid.TPUPlace(0))
+        exe.run(start, scope=scope)
+        outs.append(np.asarray(exe.run(program, feed=feed, fetch_list=[fetched], scope=scope)[0]))
+    np.testing.assert_array_equal(*outs)
+
+
+def test_a_router_ahead_stands_before_the_first_reader_of_its_input_and_reads_that_tensor():
+    main, _, _ = sparse_layer(lambda x, h: x)
+    ops = main.global_block().ops
+    kinds = [op.type for op in ops]
+    router, experts = kinds.index("moe_router"), kinds.index("moe_experts")
+    assert router == 0 and kinds[1] == "rms_norm" and experts == len(ops) - 1       # ahead of the norm and the product
+    assert ops[router].inputs["X"] == ["x"] and ops[experts].inputs["X"] != ["x"]
+    assert ops[experts].inputs["TopKIndex"] == ops[router].outputs["TopKIndex"]
+    beside, _, _ = sparse_layer(lambda x, h: None)
+    kinds = [op.type for op in beside.global_block().ops]
+    assert kinds.index("moe_router") == kinds.index("moe_experts") - 1
+    with pytest.raises(ValueError, match="a choice a token"):
+        sparse_layer(lambda x, h: layers.reshape(x, [0, 3, 32]))
+
+
+def test_the_gated_relu_is_the_third_activation_and_a_fourth_is_refused_by_name():
+    rng = np.random.RandomState(63)
+    tokens, experts, k, d, f = 24, 4, 2, 8, 6
+    x = rng.randn(tokens, d).astype("f4")
+    gate, up = (rng.randn(experts, d, f).astype("f4") / 2 for _ in range(2))
+    down = rng.randn(experts, f, d).astype("f4") / 2
+    routed = lower("moe_router", {"X": x, "W": rng.randn(d, experts).astype("f4")}, {"top_k": k, "norm_topk_prob": True})
+    ins = {"X": x, "TopKProb": routed["TopKProb"], "TopKIndex": routed["TopKIndex"], "Load": routed["Load"],
+           "WGate": gate, "WUp": up, "WDown": down}
+    outs = {act: np.asarray(lower("moe_experts", ins, {"activation": act} if act != "silu" else {})["Out"], "f8")
+            for act in ("silu", "relu", "relu2")}
+    want = np.zeros((tokens, d))
+    top_i, top_p = np.asarray(routed["TopKIndex"]), np.asarray(routed["TopKProb"], "f8")
+    for t in range(tokens):
+        for e, g in zip(top_i[t], top_p[t]):
+            want[t] += g * ((np.maximum(x[t].astype("f8") @ gate[e], 0) * (x[t].astype("f8") @ up[e])) @ down[e])
+    agree(outs["relu"], want, tol=1e-5)
+    assert np.abs(outs["relu"] - outs["silu"]).max() > 1e-2 and np.abs(outs["relu"] - outs["relu2"]).max() > 1e-2
+    with pytest.raises(ValueError, match='"silu", "relu" or "relu2"'):
+        sparse_layer(lambda x, h: None, activation="gelu")
+    with pytest.raises(Exception, match="none of silu, relu and relu2"):
+        with unique_name.guard(), fluid.program_guard(fluid.Program(), fluid.Program()):
+            x = layers.data("x", [6, 16], dtype="float32")
+            out, _, _ = layers.moe(x, 8, 4, 2)
+            experts_op = fluid.default_main_program().global_block().ops[-1]
+            fluid.default_main_program().global_block().append_op(
+                "moe_experts", inputs=experts_op.inputs, outputs=experts_op.outputs, attrs={**experts_op.attrs, "activation": "gelu"})
+
+
+# -- (b) the router's two forms -----------------------------------------------------------------
+
+def test_softmax_over_the_chosen_logits_is_softmax_over_all_renormalised_over_the_chosen():
+    """`moe_primary_router_apply_softmax` over the six chosen logits, which the
+    reference writes, and the framework's router (softmax over all 64, top 6,
+    `norm_topk_prob`) give the same six weights and the same six experts."""
+    rng = np.random.RandomState(6)
+    x, w = rng.randn(512, 32).astype("f4"), rng.randn(32, 64).astype("f4")
+    routed = lower("moe_router", {"X": x, "W": w}, {"top_k": 6, "norm_topk_prob": True})
+    logits = x.astype("f8") @ w.astype("f8")
+    order, gates = smallthinker._softmax_top(logits, 6)
+    np.testing.assert_array_equal(np.sort(np.asarray(routed["TopKIndex"]), -1), np.sort(order, -1))
+    mine = np.take_along_axis(np.asarray(routed["TopKProb"], "f8"), np.argsort(np.asarray(routed["TopKIndex"]), -1), -1)
+    agree(mine, np.take_along_axis(gates, np.argsort(order, -1), -1), tol=1e-5)
+    everyone = np.exp(logits - logits.max(-1, keepdims=True))
+    everyone /= everyone.sum(-1, keepdims=True)
+    chosen = np.take_along_axis(everyone, order, -1)
+    agree(chosen / chosen.sum(-1, keepdims=True), gates, tol=1e-12)
+
+
+# -- (c) the shares --------------------------------------------------------------------------------
+
+def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Eight chips hold 8 of 64 experts each behind THIS router (64 logits on
+    the layer's input, the top 6, the softmax over those six).  Nothing is
+    computed alike on every chip (no shared expert): the eight parts, summed,
+    are the uncut layer's output as the plain equations write it."""
+    rng = np.random.RandomState(63)
+    tokens, experts, k, d, f = 96, 64, 6, 16, 8
+    x, m = rng.randn(tokens, d).astype("f4"), rng.randn(tokens, d).astype("f4")   # the router's input and the experts'
+    router = rng.randn(d, experts).astype("f4") / 2
+    gate, up = (rng.randn(experts, d, f).astype("f4") / 4 for _ in range(2))
+    down = rng.randn(experts, f, d).astype("f4") / 4
+    routed = lower("moe_router", {"X": x, "W": router}, {"top_k": k, "norm_topk_prob": True})
+
+    def share(first, count):
+        ins = {"X": m, "TopKProb": routed["TopKProb"], "TopKIndex": routed["TopKIndex"], "Load": routed["Load"],
+               "WGate": gate[first:first + count], "WUp": up[first:first + count], "WDown": down[first:first + count]}
+        return lower("moe_experts", ins, {"held": [first, count], "activation": "relu"})
+
+    shares = [share(first, 8) for first in range(0, experts, 8)]
+    assert len(shares) == 8 and sum(int(np.asarray(s["Held"])[0]) for s in shares) == tokens * k
+    assert all(int(np.asarray(s["Dropped"])[0]) == 0 for s in shares)
+    chosen, gates = smallthinker._softmax_top(x.astype("f8") @ router.astype("f8"), k)
+    want = np.zeros((tokens, d))
+    for t in range(tokens):
+        for e, g_e in zip(chosen[t], gates[t]):
+            want[t] += g_e * ((np.maximum(m[t].astype("f8") @ gate[e], 0) * (m[t].astype("f8") @ up[e])) @ down[e])
+    agree(sum(np.asarray(s["Out"], "f8") for s in shares), want, tol=1e-5)
+    assert min(np.abs(np.asarray(s["Out"])).max() for s in shares) > 1e-3 * np.abs(want).max()   # every chip adds something
+
+
+# -- (d) positions a layer, and the window's edge --------------------------------------------------
+
+def two_layers(rotary):
+    with unique_name.guard():
+        main, startup, feeds, fetches = transformer.build_causal_lm(
+            vocab_size=32, seq_len=16, d_model=16, n_heads=2, n_kv_heads=1, head_dim=8, qk_norm=None,
+            layer_types=["full_attention", "sliding_attention"], sliding_window=4, rotary=rotary,
+            expert_width=8, num_experts=4, top_k=2, norm_topk_prob=True,
+            expert_form=dict(activation="relu", router_ahead=True), load_balance_coef=0.0, router_z_coef=0.0,
+            with_optimizer=False, use_fused_attention=True)
+    main.random_seed = startup.random_seed = 5
+    return main, startup, feeds, fetches
+
+
+def test_a_full_layer_without_positions_ignores_pos_ids_and_a_rotary_window_layer_reads_them():
+    main, startup, feeds, fetches = two_layers([False, True])
+    ops = main.global_block().ops
+    assert [op.type for op in ops].count("rotary_embedding") == 2            # q and k of the window layer alone
+    attentions = [op for op in ops if op.type == "fused_attention"]
+    assert attentions[0].attr("layout", "bhld") == "blhd" and attentions[0].attr("mask", None) is None
+    assert attentions[1].attr("layout", "bhld") == "bhld" and attentions[1].attr("mask") == "sliding_window"
+    first_out = next(op for op in ops if op.type == "moe_experts").outputs["Out"][0]       # of layer 0
+    layer0 = next(op.outputs["Out"][0] for op in ops if op.type == "elementwise_add" and first_out in op.input_arg_names)
+    scope, exe = fluid.Scope(), fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup, scope=scope)
+    rng = np.random.RandomState(1)
+    ids = rng.randint(0, 32, (2, 16)).astype("int64")
+    runs = [exe.run(main.clone(for_test=True), fetch_list=[layer0, fetches["logits"].name], scope=scope,
+                    feed={"ids": ids, "labels": ids, "pos_ids": pos})
+            for pos in (np.tile(np.arange(16), (2, 1)), np.tile(np.arange(16) * 3 + 5, (2, 1)))]
+    np.testing.assert_array_equal(np.asarray(runs[0][0]), np.asarray(runs[1][0]))          # no position reaches layer 0
+    assert np.abs(np.asarray(runs[0][1]) - np.asarray(runs[1][1])).max() > 1e-4            # the window layer turns by them
+    # stated by kind, the same program; a stack none of whose layers rotates has no `pos_ids` at all
+    by_kind = two_layers({"full_attention": False})[0]
+    assert [op.type for op in by_kind.global_block().ops] == [op.type for op in ops]
+    assert "pos_ids" not in two_layers(False)[2] and "pos_ids" in feeds
+    with pytest.raises(ValueError, match="rotary states 3 layers beside 2"):
+        two_layers([True, False, True])
+
+
+def test_a_query_at_5000_does_not_see_key_904_and_sees_key_905():
+    """i - 4096 < j <= i: the rule as the kernels compute it, as the reference
+    writes it, in the block maps at the cell's shape, and through the op."""
+    for rule in (lambda q, k: masked_attention.window_allowed(q, k, 4096), lambda q, k: smallthinker.allowed(q, k, 4096)):
+        assert not rule(np.int64(5000), np.int64(904)) and rule(np.int64(5000), np.int64(905))
+        assert rule(np.int64(5000), np.int64(5000)) and not rule(np.int64(5000), np.int64(5001))
+    assert smallthinker.allowed(np.int64(5000), np.int64(0), None) and not smallthinker.allowed(np.int64(5), np.int64(6), None)
+    assert masked_attention.window_pairs(16384, 4096) == 58722304 and masked_attention.window_block(16384, 4096) == 1024
+    assert masked_attention.window_block(8192, 512) == 512                      # Phi-4-mini-flash's stays what it was
+    plan = masked_attention.window_plan(16384, 28, 4096)
+    visited = masked_attention.block_maps(plan)[0]
+    # query block 4 (4096 .. 5119) reaches back to key 1: key blocks 0 to 4, five of them; block 5 (5120 ..) not block 0
+    assert np.count_nonzero(visited.block_mask[0, 4]) == 5 and np.count_nonzero(visited.block_mask[0, 5]) == 5
+    assert np.count_nonzero(visited.block_mask) * 1024 * 1024 / masked_attention.window_pairs(16384, 4096) == pytest.approx(1.25, abs=1e-3)
+    rng = np.random.RandomState(0)
+    q, k = rng.randn(1, 1, 5120, 8).astype("f4"), rng.randn(1, 1, 5120, 8).astype("f4")
+    v = rng.randn(1, 1, 5120, 8).astype("f4")
+    attrs = {"causal": False, "mask": "sliding_window", "mask_block": 4096}
+
+    def at_5000(values):
+        return np.asarray(lower("fused_attention", {"Q": q, "K": k, "V": values}, attrs)["Out"])[0, 0, 5000]
+
+    base = at_5000(v)
+    for key, seen in ((904, False), (905, True)):
+        moved = v.copy()
+        moved[0, 0, key] += 100.0
+        assert (np.abs(at_5000(moved) - base).max() > 1e-3) == seen, key
+
+
+# -- (e) the whole model --------------------------------------------------------------------------------
+
+TINY = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=16, moe_ffn_hidden_size=16,
+            moe_num_primary_experts=8, num_routed_experts=8, experts_held_first=0, moe_num_active_primary_experts=2,
+            vocab_size=64, num_hidden_layers=8, sliding_window_size=8, rope_layout=[0, 1, 1, 1] * 2,
+            sliding_window_layout=[0, 1, 1, 1] * 2, layer_types=["full_attention"] + ["sliding_attention"] * 3
+            + ["full_attention"] + ["sliding_attention"] * 3)
+JOB = dict(seq_len=32, batch_per_chip=4)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def every_position_is_sampled():
+    from benchmark.models import lfm2
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(lfm2, "LOGIT_SAMPLE", 32)
+        patch.setattr(lfm2, "ATTENTION_SAMPLE", 32)
+        yield
+
+
+def tiny_model(dtype, cfg_over=None, **job):
+    cfg = dict(mf.read_json("benchmark/configs/smallthinker-21b-a3b.json"), compute_dtype=dtype, **{**TINY, **(cfg_over or {})})
+    job = dict(mf.read_json("benchmark/traffic/train-nope-swa-s16384.json"), **JOB, **job)
+    with unique_name.guard():
+        main, startup, feeds, loss, names = smallthinker.build(cfg, job)
+    main.random_seed = startup.random_seed = 3
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup, scope=scope)
+    return cfg, job, main, loss, names, scope, exe
+
+
+def params_of(main, scope):
+    return {p.name: np.asarray(scope.find_var(p.name)) for p in main.all_parameters()}
+
+
+def reference_of(cfg, params, rows, **kw):
+    return [np.asarray(w) for w in jax.jit(lambda p, b: smallthinker.reference(p, b, cfg, **kw))(params, rows)]
+
+
+def one_step(main, loss, scope, exe, batch):
+    """(the step's loss, Adam's first moments, the trace's `lowering.` counters) of one step through `train_loop`."""
+    losses = []
+    monitor.reset()
+    monitor.enable()
+    try:
+        before = {k: v for k, v in monitor.get_monitor().counter_values().items() if k.startswith("lowering.")}
+        fluid.train_loop(exe, main, iter([batch]), [loss], scope=scope, log_period=1,
+                         on_logged=lambda i, vals: losses.append(float(np.asarray(vals[0]).reshape(-1)[0])))
+        counters = {k: v - before.get(k, 0) for k, v in monitor.get_monitor().counter_values().items()
+                    if k.startswith("lowering.")}
+    finally:
+        monitor.disable()
+        monitor.reset()
+    moments = {p.name: np.asarray(scope.find_var(p.name + "_moment1_0")) for p in main.all_parameters()}
+    return losses.pop(), moments, counters
+
+
+@pytest.fixture(scope="module")
+def float32_run():
+    """The toy built twice from the same seed, every layer a `recompute_scope`
+    (as the cell builds it) and none, one step each on the same batch; the
+    recomputed one's `for_test` clone against the reference."""
+    with jax.default_matmul_precision("highest"):
+        cfg, job, main, loss, names, scope, exe = tiny_model("float32")
+        rows = smallthinker.make_batch(np.random.RandomState(3), cfg, job, 8)
+        got = exe.run(main.clone(for_test=True), feed=rows, fetch_list=list(names), scope=scope)
+        before = params_of(main, scope)
+        want = reference_of(cfg, before, rows)
+        batch = smallthinker.make_batch(np.random.RandomState(4), cfg, job, 4)
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(lambda p: smallthinker.reference(p, batch, cfg)[0]))(before)
+        step_loss, moments, counters = one_step(main, loss, scope, exe, batch)
+        _, _, plain_main, plain_loss, _, plain_scope, plain_exe = tiny_model("float32", recompute_layers=False)
+        plain = one_step(plain_main, plain_loss, plain_scope, plain_exe, batch)
+    return SimpleNamespace(cfg=cfg, job=job, main=main, got=got, want=want, before=before, rows=rows, names=names,
+                           moments=moments, counters=counters, plain=plain, scope=scope,
+                           plain_segments=[op.attrs.get("recompute_segment") for op in plain_main.global_block().ops],
+                           ref_loss=float(ref_loss), step_loss=step_loss,
+                           ref_grads={k: np.asarray(v) for k, v in ref_grads.items()})
+
+
+def test_float32_loss_logits_routing_and_every_stage_agree_with_the_reference(float32_run):
+    found = smallthinker.compare(float32_run.got, float32_run.want)
+    assert found["left_out"] == found["routed_differently"] == found["routed_differently_above_margin"] == 0
+    assert found["router_choice_differs"] == 0
+    assert found["loss_error"] < 1e-5 and found["logit_error"] < 2e-5, found
+    assert max(found["router_prob_error"], found["experts_error"], found["attention_error"], found["qk_error"]) < 2e-5, found
+    assert found["experts_error_silu"] > 0.1 and found["attention_error_other_grouping"] > 0.1      # what they refuse
+    assert abs(found["window_edge_missing"]) < 1e-4 and abs(found["window_edge_extra"]) < 1e-4    # the rule as stated
+    assert found["reference_self_error"] < 1e-5 and smallthinker.failed_limits(found) == []
+    assert smallthinker.reference_error(float32_run.got, float32_run.want) < 2e-5
+    assert abs(float32_run.step_loss - float32_run.ref_loss) < 1e-5 * float32_run.ref_loss
+    assert found["held_rows_share"] == [1.0] * 8                                      # every expert held
+    assert np.asarray(float32_run.got[1]).shape == (32, 8, 64)
+    assert np.asarray(float32_run.got[2]).shape == (8, 32, 2)                                     # the choice: every row
+    assert np.asarray(float32_run.got[3]).shape == (smallthinker.STAGE_ROWS, 32, 32)              # the layer's input: the stage rows
+    assert np.asarray(float32_run.got[-1]).shape == (smallthinker.STAGE_ROWS, 32, 4, 16)          # the sampled queries' outputs
+    assert np.asarray(float32_run.got[-3]).shape == (smallthinker.STAGE_ROWS, 32, 2, 16)          # every key, grouped heads
+
+
+PARAMS = sorted(["lm.tok_emb", "lm.head.w", "lm.final_norm.w"]
+                + [f"lm.l{i}.{n}" for i in range(8) for n in ("ln1.w", "ln2.w")]
+                + [f"lm.l{i}.attn.{n}.w" for i in range(8) for n in ("q", "k", "v", "out")]
+                + [f"lm.l{i}.moe.{n}.w" for i in range(8) for n in ("router", "gate", "up", "down")])
+
+
+def test_the_toy_has_these_layers_parameters_and_no_other_and_its_routers_stand_ahead(float32_run):
+    r = float32_run
+    assert sorted(r.before) == PARAMS
+    ops = r.main.global_block().ops
+    kinds = [op.type for op in ops]
+    assert kinds.count("fused_attention") == 8 and kinds.count("rotary_embedding") == 12      # q and k of six window layers
+    assert kinds.count("moe_router") == kinds.count("moe_experts") == 8
+    order = [i for i, kind in enumerate(kinds) if kind in ("moe_router", "fused_attention", "moe_experts")]
+    assert [kinds[i] for i in order] == ["moe_router", "fused_attention", "moe_experts"] * 8
+    segments = [op.attrs.get("recompute_segment") for op in ops]
+    assert [segments[i] for i in order] == [n for n in range(1, 9) for _ in range(3)]        # each in its layer's segment
+    assert set(r.plain_segments) == {None}
+    routers = [op for op in ops if op.type == "moe_router"]
+    norms = [op for op in ops if op.type == "rms_norm" and op.inputs["Scale"][0].endswith(".ln1.w")]
+    assert [op.inputs["X"] for op in routers] == [op.inputs["X"] for op in norms]            # the layer's input ITSELF
+    assert all(op.attr("activation") == "relu" for op in ops if op.type == "moe_experts")
+    assert r.before["lm.l0.attn.k.w"].shape == (32, 32) and r.before["lm.l0.attn.q.w"].shape == (32, 64)
+    assert r.before["lm.l0.moe.router.w"].shape == (32, 8) and r.before["lm.l0.moe.gate.w"].shape == (8, 32, 16)
+
+
+def test_the_two_counters_are_counted_once_a_trace_of_the_step(float32_run):
+    """Eight routers ahead of their attentions, two full layers without
+    positions, in the step's trace; a program whose routers stand beside their
+    experts and whose layers all rotate counts neither."""
+    assert float32_run.counters["lowering.routers_before_attention"] == 8
+    assert float32_run.counters["lowering.attention_layers_without_positions"] == 2
+    from paddle_tpu.core.lowering import count_layer_forms
+
+    with unique_name.guard():
+        main, _, _, fetches = transformer.build_causal_lm(
+            vocab_size=32, seq_len=16, d_model=16, n_layers=2, n_heads=2, expert_width=8, num_experts=4, top_k=2,
+            load_balance_coef=0.0, router_z_coef=0.0, with_optimizer=True, use_fused_attention=True)
+    monitor.reset()
+    monitor.enable()
+    try:
+        count_layer_forms(main.global_block().ops)
+        counted = monitor.get_monitor().counter_values()
+        assert counted.get("lowering.routers_before_attention", 0) == 0
+        assert counted.get("lowering.attention_layers_without_positions", 0) == 0
+        count_layer_forms(main.clone(for_test=True).global_block().ops)        # no backward: nothing is counted
+        count_layer_forms(float32_run.main.global_block().ops)
+        counted = monitor.get_monitor().counter_values()
+        assert counted["lowering.routers_before_attention"] == 8
+        assert counted["lowering.attention_layers_without_positions"] == 2
+    finally:
+        monitor.disable()
+        monitor.reset()
+
+
+@pytest.mark.parametrize("name", PARAMS)
+def test_float32_gradient_agrees_with_the_reference(float32_run, name):
+    """Adam's first moment after one step is 0.1 x the gradient.  The program
+    differentiated here makes every layer again in backward, its routing with it."""
+    agree(float32_run.moments[name] / (1 - 0.9), float32_run.ref_grads[name], tol=2e-4, floor=1e-7)
+
+
+@pytest.mark.parametrize("name", PARAMS)
+def test_a_recomputed_layers_gradient_is_the_plain_layers_to_the_last_bit(float32_run, name):
+    plain_loss, plain_moments, _ = float32_run.plain
+    assert plain_loss == float32_run.step_loss
+    np.testing.assert_array_equal(float32_run.moments[name], plain_moments[name])
+
+
+def test_the_counted_parameters_of_the_cells_program_are_370_5_million():
+    """The program as the cell builds it, at the published widths (built, not
+    lowered): 370.5 M parameters, what the configuration file states."""
+    cfg = mf.read_json("benchmark/configs/smallthinker-21b-a3b.json")
+    job = mf.read_json("benchmark/traffic/train-nope-swa-s16384.json")
+    with unique_name.guard():
+        main = smallthinker.build(cfg, job)[0]
+    shapes = {p.name: tuple(p.shape) for p in main.all_parameters()}
+    counted = sum(int(np.prod(s)) for s in shapes.values())
+    assert counted == cfg["parameters"] == 370547200
+    assert shapes["lm.l0.attn.q.w"] == (2560, 3584) and shapes["lm.l0.attn.k.w"] == (2560, 512)
+    assert shapes["lm.l0.moe.router.w"] == (2560, 64) and shapes["lm.l3.moe.gate.w"] == (8, 2560, 768)
+    assert shapes["lm.tok_emb"] == shapes["lm.head.w"][::-1] == (18992, 2560)
+    per_layer = sum(int(np.prod(s)) for n, s in shapes.items() if n.startswith("lm.l2."))
+    assert per_layer == 68326400
+    kinds = [op.type for op in main.global_block().ops]
+    assert kinds.count("rotary_embedding") == 6 and kinds.count("fused_attention") == 4
+    windows = [op.attr("mask_block", None) for op in main.global_block().ops if op.type == "fused_attention"]
+    assert windows == [None, 4096, 4096, 4096]
+    assert smallthinker.flops_per_sample(cfg, job) > 0
+
+
+def test_the_counted_operations_are_the_issues():
+    """4.45 TFLOP of attention forward (1.92 full, 0.84 a window layer), the
+    window 43.7% of the triangle; the functions count the allowed pairs."""
+    cfg = mf.read_json("benchmark/configs/smallthinker-21b-a3b.json")
+    job = mf.read_json("benchmark/traffic/train-nope-swa-s16384.json")
+    full, band = smallthinker._pairs(16384, None), smallthinker._pairs(16384, 4096)
+    assert full == 16384 * 16385 // 2 and band == masked_attention.window_pairs(16384, 4096)
+    assert band / full == pytest.approx(0.437, abs=1e-3)
+    assert smallthinker.causal_attention_flops(cfg, job) == 6 * 2.0 * 28 * 128 * full
+    assert smallthinker.window_attention_flops(cfg, job) == 3 * 6 * 2.0 * 28 * 128 * band
+    assert smallthinker.causal_attention_flops(cfg, job) / 3 == pytest.approx(1.92e12, rel=5e-3)
+    assert smallthinker.window_attention_bytes(cfg, job) == 3 * smallthinker.causal_attention_bytes(cfg, job)
+    assert smallthinker.causal_attention_bytes(cfg, job) == 2 * 2 * 64 * 128 * 16384
+    assert smallthinker.flops_per_sample(cfg, job) == pytest.approx(3 * 9.37e12, rel=2e-2)
+
+
+@pytest.mark.parametrize("limit,sound,faulty", [
+    ("REFERENCE_RTOL", 7.43e-3, 3.90e-2),          # a router on the post-attention stream, the least fault to the stream (6.17e-2 at the first seed)
+    ("LEFT_OUT_MAX", 0.0165, 0.085),               # a SiLU for the ReLU
+    ("ROUTER_RTOL", 5.31e-6, 8.32e-3),             # the router's matrix in bf16
+    ("EXPERTS_RTOL", 4.60e-3, 3.2e-2),             # the running sums in bf16 (numpy); a SiLU in the program 0.293
+    ("ATTENTION_RTOL", 4.12e-3, 1.14),             # the other grouping of the heads
+    ("WINDOW_EDGE_MAX", 5.3e-3, 0.9992),           # a window of 4097; of 4095 0.99998; the most under another control
+    ("QK_RTOL", 7.00e-3, 1.82),                    # no rotation in layer 1
+    ("REFERENCE_SELF_RTOL", 1.4e-6, 3.31e-3),      # the reference's attention at the chip's default precision
+])
+def test_every_limit_lies_between_the_readings_the_chip_gave(limit, sound, faulty):
+    """My chip runs, PR 63 (PERF.md section 6): the most any of eight sound runs
+    read, and the least a fault this limit has to refuse read, with room on both
+    sides."""
+    value = getattr(smallthinker, limit)
+    assert 1.9 * sound < value < faulty / 1.9, (limit, sound, value, faulty)
+
+
+def test_bfloat16_agrees_within_the_benchmarks_tolerances():
+    cfg, job, main, _, names, scope, exe = tiny_model("bfloat16")
+    rows = smallthinker.make_batch(np.random.RandomState(3), cfg, job, 8)
+    got = exe.run(main.clone(for_test=True), feed=rows, fetch_list=list(names), scope=scope)
+    found = smallthinker.compare(got, reference_of(cfg, params_of(main, scope), rows))
+    assert found["routed_differently_above_margin"] == 0 and found["router_choice_differs"] == 0
+    assert found["router_prob_error"] <= smallthinker.ROUTER_RTOL and found["experts_error"] <= smallthinker.EXPERTS_RTOL
+    assert found["attention_error"] <= smallthinker.ATTENTION_RTOL and found["reference_self_error"] <= smallthinker.REFERENCE_SELF_RTOL
+    assert max(found["loss_error"], found["logit_error"]) <= smallthinker.REFERENCE_RTOL, found
+
+
+FAULTS = {
+    "rotation_in_the_full_layer": (dict(rope_layout=[1, 1, 1, 1] * 2), "QK_RTOL"),
+    "no_rotation_in_the_first_window_layer": (dict(rope_layout=[0, 0, 1, 1] * 2), "QK_RTOL"),
+    "a_window_of_7": (dict(sliding_window_size=7), "WINDOW_EDGE_MAX"),
+    "a_window_of_9": (dict(sliding_window_size=9), "WINDOW_EDGE_MAX"),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_the_comparison_refuses_a_program_with(fault, float32_run):
+    """A program built with the fault on the sound program's parameters (the
+    names are the same) against the sound reference."""
+    over, limit = FAULTS[fault]
+    _, _, main, _, names, _, exe = tiny_model("float32", over)
+    got = exe.run(main.clone(for_test=True), feed=float32_run.rows, fetch_list=list(names), scope=float32_run.scope)
+    found = smallthinker.compare(got, float32_run.want)
+    refused = smallthinker.failed_limits(found)
+    assert limit in refused, refused
+    if limit == "WINDOW_EDGE_MAX":   # the stage's rule is the configuration's 8 keys: the program's own lacks one or has one more
+        edge = found["window_edge_missing"] if over["sliding_window_size"] == 7 else found["window_edge_extra"]
+        assert edge == pytest.approx(1.0, abs=1e-3), found
+
+
+@pytest.mark.parametrize("reads", ["the_normed_input", "the_post_attention_stream"])
+def test_the_comparison_refuses_a_router_that_reads(reads, float32_run, monkeypatch):
+    """The router's stage reads THE LAYER'S INPUT whatever the op was handed: a
+    router on the normed input, or on the stream after the attention, chooses
+    other experts than float64 on the layer's input does, or (the norm's gains
+    all 1: a token's logits are scaled alike and their order stays) the same
+    experts at other weights."""
+    real = layers.moe
+
+    def wrong(input, *a, router_input=None, **kw):
+        ops = fluid.default_main_program().global_block().ops
+        if reads == "the_normed_input":
+            other = next(op for op in ops if op.type == "rms_norm" and op.inputs["X"] == [router_input.name])
+            name = other.outputs["Y"][0]
+        else:
+            name = next(op for op in ops if input.name in op.output_arg_names).inputs["X"][0]
+        return real(input, *a, router_input=fluid.default_main_program().global_block().var(name), **kw)
+
+    monkeypatch.setattr(layers, "moe", wrong)
+    _, _, main, _, names, _, exe = tiny_model("float32")
+    got = exe.run(main.clone(for_test=True), feed=float32_run.rows, fetch_list=list(names), scope=float32_run.scope)
+    found = smallthinker.compare(got, float32_run.want)
+    refused = smallthinker.failed_limits(found)
+    if reads == "the_normed_input":
+        assert found["router_prob_error"] > 0.01 and "ROUTER_RTOL" in refused, found
+    else:
+        assert found["router_choice_differs"] > 0 and {"ROUTER_TIE", "ROUTER_RTOL"} <= set(refused), found
+
+
+def test_the_reference_at_default_precision_in_its_attention_is_what_it_says():
+    """Off the chip "default" is float32 too: the argument reaches the two
+    products and changes nothing here; on the chip it is bf16 operands, which
+    `REFERENCE_SELF_RTOL` refuses (tools/chip_smallthinker_controls.py)."""
+    cfg, job, main, _, _, scope, _ = tiny_model("float32")
+    rows = smallthinker.make_batch(np.random.RandomState(3), cfg, job, 2)
+    a = reference_of(cfg, params_of(main, scope), rows)
+    b = reference_of(cfg, params_of(main, scope), rows, attention_precision="default")
+    agree(a[1], b[1], tol=1e-5)
+
+
+def test_every_control_of_the_chip_tool_is_refused_in_its_rehearsal_and_the_sound_program_is_not():
+    """`DRY=1 python3 tools/chip_smallthinker_controls.py`: tiny and on the CPU
+    the eight faults put into the program each fail a committed limit, the one
+    the tool's docstring names; the reference's attention at "default"
+    precision is float32 here and passes (the chip refuses it)."""
+    import json
+    import subprocess
+
+    out = subprocess.run([sys.executable, os.path.join("tools", "chip_smallthinker_controls.py"), "5"], cwd=REPO,
+                         capture_output=True, text=True, timeout=900, stdin=subprocess.DEVNULL,
+                         env=dict(os.environ, DRY="1", JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    readings = {r["control"]: r for r in map(json.loads, (line for line in out.stdout.splitlines() if line.startswith("{")))}
+    assert readings["sound"]["correct"] and readings["sound"]["refused_by"] == []
+    expected = {"router_in_bf16": "ROUTER_RTOL", "router_reads_the_normed_input": "ROUTER_RTOL",
+                "router_reads_the_post_attention_stream": "ROUTER_TIE", "rotation_in_layer_0": "QK_RTOL",
+                "no_rotation_in_layer_1": "QK_RTOL", "window_of_15": "WINDOW_EDGE_MAX", "window_of_17": "WINDOW_EDGE_MAX",
+                "silu_for_relu": "EXPERTS_RTOL"}
+    assert set(readings) == set(expected) | {"sound", "attention_at_default_precision"}
+    for control, limit in expected.items():
+        assert not readings[control]["correct"] and limit in readings[control]["refused_by"], (control, readings[control]["refused_by"])

@@ -23,7 +23,10 @@ as `fused_attention` calls it against `causal_attention` over the same
 operands (8x the pairs), the stock splash kernel under the stock local mask by
 the grid's block, dq and dkv apart (grids shrunk to the band) or the fused
 backward (its dkv grid unshrunk), and at 2048 positions the taken form's output
-and gradients against dense float32.
+and gradients against dense float32.  WINDOW=4096 (PR 63) is the same at
+SmallThinker's window layer, (1, 28 on 4, 16384, 128) under a window of 4096:
+the op's own call at a block of 512 and of 1024 first, then the stock kernel by
+block, and the taken form against dense float32 at 6144 positions.
 
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
@@ -140,8 +143,12 @@ class StoredCausal(mask_lib.Mask):
         return hash((type(self).__name__, self.shape))
 
 
-if os.environ.get("WINDOW") == "1":
-    wq, wkv, window = ((1, 4, 512, 64), (1, 2, 512, 64), 130) if DRY else ((1, 40, 8192, 64), (1, 20, 8192, 64), 512)
+if os.environ.get("WINDOW") in ("1", "4096"):
+    wide = os.environ["WINDOW"] == "4096"
+    if wide:
+        wq, wkv, window = ((1, 14, 1024, 128), (1, 2, 1024, 128), 256) if DRY else ((1, 28, 16384, 128), (1, 4, 16384, 128), 4096)
+    else:
+        wq, wkv, window = ((1, 4, 512, 64), (1, 2, 512, 64), 130) if DRY else ((1, 40, 8192, 64), (1, 20, 8192, 64), 512)
     wqkv = operands(wq, wkv, seed=2)
     length, heads = wq[2], wq[1]
     taken = lambda q, k, v: ma.window_attention(q, k, v, window, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
@@ -150,8 +157,14 @@ if os.environ.get("WINDOW") == "1":
     report("window", q=wq, kv=wkv, window=window, taken_block=plan.block, taken_fused_backward=plan.fused_backward,
            window_ms=try_ms(taken, *wqkv), causal_ms=try_ms(causal, *wqkv),
            pairs_allowed_over_causal=ma.window_pairs(length, window) / (length * (length + 1) / 2))
+    if wide:   # the op's own call at each block that could be taken: dq a kernel of its own, `_KV_COMPUTE` keys a step
+        for b in (128, 256) if DRY else (512, 1024):
+            at_block = lambda q, k, v, b=b: ma.attention_under(plan._replace(block=b), q, k, v, q.shape[-1] ** -0.5)  # noqa: E731
+            report("window_as_the_op_calls_it", q=wq, window=window, grid_block=b, ms=try_ms(at_block, *wqkv))
     local = mask_lib.LocalMask((length, length), (window - 1, 0), 0)
     for b, compute, fused in ((128, 128, False), (128, 128, True)) if DRY else (
+            (512, 512, False), (1024, 512, False), (1024, 1024, False), (2048, 512, False),
+            (512, 512, True), (1024, 512, True)) if wide else (
             (128, 128, False), (256, 256, False), (512, 512, False), (512, 256, False), (1024, 512, False), (1024, 1024, False),
             (256, 256, True), (512, 512, True), (1024, 512, True)):
         blocks = 1 + -(-(window - 1) // b)
@@ -159,6 +172,8 @@ if os.environ.get("WINDOW") == "1":
                pairs_visited_over_allowed=round(blocks * b * length / ma.window_pairs(length, window), 3),
                ms=try_ms(splash_with(local, heads, sizes_of(b, b, compute, fused=fused)), *wqkv))
     sq, skv = ((1, 4, 512, 64), (1, 2, 512, 64)) if DRY else ((1, 8, 2048, 64), (1, 4, 2048, 64))
+    if wide:   # the taken form against dense float32 where a window of 4096 is no causal rule: 6144 positions
+        sq, skv = ((1, 14, 1024, 128), (1, 2, 1024, 128)) if DRY else ((1, 7, 6144, 128), (1, 1, 6144, 128))
     sqkv = operands(sq, skv, seed=3)
 
     def dense(q, k, v):   # float32 scores of the whole square under the rule
