@@ -11,13 +11,14 @@ kernel from the positions (two compares a pair), and a window that reaches the
 sequence's start from every query IS the causal rule and takes its plan.  The
 CAUSAL one (`causal_allowed`: key j <= query i over equal
 lengths; `fused_attention`'s `causal` at long keys, `causal_plan`) is the stock
-splash kernels under the stock causal mask and nothing round them but the
-queries' scaling: the forward kernel and ONE backward kernel (dkv, which
-writes dq too: `Plan.fused_backward`), the cut blocks' mask computed in the
-kernel.  At 4096 keys 10 of the square's 16 1024-blocks are visited and 4 of
-them cut, at 8192 36 of 64 and 8.  What is said below of the far term's
-kernels, their block maps and grouped key/value heads holds for it; nothing of
-the own-block term does.  The other rule is block-diffusion training's
+splash forward kernel under the stock causal mask and nothing round it but the
+queries' scaling.  BACKWARD under these two rules is ONE kernel of our own
+(`ops/attention_backward_kernels.py`, `Plan.backward`): dq, dk and dv from one
+pass over the scores of the (key block, query block) pairs the rule leaves, dq
+summed in VMEM, the cut blocks' mask computed in the kernel.  At 4096 keys 10 of
+the square's 16 1024-blocks are visited and 4 of them cut, at 8192 36 of 64 and
+8.  What is said below of the far term's forward kernel, its block maps and
+grouped key/value heads holds for it; nothing of the own-block term does.  The other rule is block-diffusion training's
 (BD3-LM, Arriola et al.
 2025, arXiv:2503.09573; SDAR trains this way).  L tokens of data are 2L
 positions: i < L the noised copy x_t, i >= L the clean copy x_0, in blocks
@@ -148,6 +149,33 @@ MASKS = ("block_diffusion", "sliding_window")
 #: dtype before XLA sums them (4 or 8 of them: 0.27 and 1.07 GB written; dq
 #: against float32 reads 3.39e-3 for 3.33e-3 at 128-wide heads and 2.59e-3 for
 #: 2.50e-3 at 64-wide, tools/chip_attention_errors.py).
+#:
+#: Since PR 64 backward under the causal rule is ONE kernel of our own that sums
+#: dq in VMEM (`Plan.backward`, `ops/attention_backward_kernels.py`), its grid the
+#: (key block, query block) pairs under the diagonal.  Forward + backward of a
+#: layer alone, ms (my chip run, PR 64, call 1; the stock rows read again in the
+#: same call, cut blocks computed):
+#:
+#:   block (keys a pass)                  1024 (1024)     1024 (512)      512 (512)
+#:   (4, 16, 4096, 128), OLMoE's
+#:   fused, dq on the chip                 9.34            9.47           10.63
+#:   stock fused backward                 10.05            9.76           11.23
+#:   (2, 32 on 8, 8192, 64), LFM2's       dk and dv of a group summed in VMEM | outside in float32
+#:   fused, dq on the chip                29.46 | 30.28   29.74 | 30.56   33.19 | 34.18
+#:   stock fused backward                 32.82           32.06           38.84
+#:   (1, 32, 16384, 192 | 128), Kanana-2's latent attention
+#:   fused, dq on the chip                79.76           80.30           88.75
+#:   stock fused backward | the pair                      91.31 | 102.57
+#:   (1, 28 on 4, 16384, 128), SmallThinker's full layer
+#:   fused, dq on the chip                                44.82 | 45.73
+#:   stock fused backward                                 49.15
+#:
+#: so ours at every shape, 1024-blocks, a block's keys ONE pass (the kernel has
+#: no inner loop: 0.5 to 2% over 512 keys a pass now that the VMEM is ours), a
+#: group's dk and dv summed in VMEM where a key/value head's rows fit.  dq is
+#: rounded once: against float32 3.34e-3 for the stock fused kernel's 3.49e-3 at
+#: (1, 4, 8192, 128), 8 partials; 2.50e-3 for 2.59e-3 at 64-wide heads; dk and dv
+#: to the sixth digit the stock kernel's (tools/chip_attention_errors.py).
 _BLOCKS = (1024, 512, 128)
 _KV_COMPUTE = 512
 
@@ -231,6 +259,7 @@ class Plan(NamedTuple):
     interpret: bool
     rule: str = "block_diffusion"
     causal: bool = False   # under the rule "selected": the causal rule laid over the picks too
+    widths: tuple = (128, 128)   # of a head's queries and keys, and of its values
 
     @property
     def tile(self) -> int:
@@ -239,23 +268,25 @@ class Plan(NamedTuple):
         return max(self.mask_block, 128)
 
     @property
-    def fused_backward(self) -> bool:
-        """dq from the dkv kernel's own pass over the scores (the stock fused
-        backward), not from a kernel of its own: under the causal rule, where
-        it is the faster alone AND in the step (`_BLOCKS`' table); never under
-        block-diffusion's, whose step it overran (ROADMAP.md S13(a)); nor under
-        the window rule.  The fused kernel writes dq as one partial a block of
-        KEYS, [L / block, Hq, L, dh], and the stock code gives it a dkv grid that
-        is NOT shrunk to the blocks the rule leaves (a shrunk grid's query index
-        is no longer the query block: the partials land in the wrong rows and
-        the rest is never written, NaN interpreted), so every (key block, query
-        block) pair is a grid step that writes zeros where the band is not, 16 x
-        the band's own at 8192 keys in 512-blocks, which XLA then sums.  The
-        causal rule leaves half of the square, the window 31 of 256 blocks: two
-        kernels over the band's blocks alone, each computing its scores again,
-        take 8.17 ms where the one over the square takes 11.72
-        (`_WINDOW_BLOCKS`' table)."""
-        return self.rule == "causal"
+    def backward(self) -> str:
+        """Which backward form the plan takes, read off the rule, the widths and
+        the length alone.  `"onchip_dq"`: the one kernel of
+        `ops/attention_backward_kernels.py`, dq, dk and dv from one pass over the
+        scores of the blocks the rule leaves, dq summed in VMEM; under the two
+        rules whose cut blocks are computed from positions, wherever a head's
+        whole dq fits the kernel's VMEM.  `"stock_pair"`: the stock dq and dkv
+        kernels, each computing its scores again; under the rules whose cut
+        blocks are STORED (block diffusion's, the selected one: the kernel reads
+        no stored block yet), and where a head's dq does not fit.  The stock
+        FUSED kernel, which the causal rule took until PR 64, is no form any
+        more: it writes dq as one partial a block of keys, [L / block, Hq, L, dh]
+        rounded to the operands' dtype for XLA to sum, over a dkv grid that is
+        not shrunk to the rule's blocks, and lost to the kernel of our own at
+        every shape priced (`_BLOCKS`' and `_WINDOW_BLOCKS`' tables)."""
+        from . import attention_backward_kernels as onchip
+
+        computed = self.rule in ("causal", "sliding_window")
+        return "onchip_dq" if computed and onchip.vmem_bytes(self.positions, self.widths, False) <= onchip.VMEM_LIMIT else "stock_pair"
 
     @property
     def sizes(self):
@@ -265,7 +296,7 @@ class Plan(NamedTuple):
         if self.rule == "selected":   # a stored block of the mask a grid step: [512, b] bytes, never [b, b]
             return splash.BlockSizes(block_q=inner, block_kv=b, block_kv_compute=inner, block_q_dkv=inner, block_kv_dkv=b,
                                      block_kv_dkv_compute=inner, block_q_dq=inner, block_kv_dq=b)
-        dq = dict(use_fused_bwd_kernel=True) if self.fused_backward else dict(block_q_dq=inner, block_kv_dq=b)
+        dq = {} if self.backward == "onchip_dq" else dict(block_q_dq=inner, block_kv_dq=b)
         return splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=inner, block_q_dkv=b, block_kv_dkv=b,
                                  block_kv_dkv_compute=inner, **dq)
 
@@ -282,12 +313,11 @@ def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False
     return Plan(positions, heads, mask_block, kernel_block(positions), 0, interpret)
 
 
-def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
-    """The causal rule over `length` queries and as many keys: the stock
-    kernels over the whole square (the rule's block is one position, and no
-    term of it lacks block structure), their block the largest that divides
-    the length."""
-    return Plan(length, heads, 1, kernel_block(length), 0, interpret, "causal")
+def causal_plan(length: int, heads: int, interpret: bool = False, widths=(128, 128)) -> Plan:
+    """The causal rule over `length` queries and as many keys: the kernels
+    over the whole square (the rule's block is one position, and no term of it
+    lacks block structure), their block the largest that divides the length."""
+    return Plan(length, heads, 1, kernel_block(length), 0, interpret, "causal", widths=tuple(widths))
 
 
 #: The kernels' block under the window rule: the smallest block of `_WINDOW_BLOCKS` that holds a whole window.  A
@@ -302,8 +332,16 @@ def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
 #:   fused backward                -        22.71    11.72        -          11.56
 #:
 #: so a block of the window's own length, `_KV_COMPUTE` keys a step, dq a kernel of its own (`window_attention` as the
-#: op calls it: 8.36).  The output and gradients against dense float32 at (1, 8 on 4, 2048, 64): 3.5e-3, 4.4e-3,
-#: 2.7e-3, 3.8e-3 of the largest, the causal rule's readings.
+#: op called it: 8.36).  The output and gradients against dense float32 at (1, 8 on 4, 2048, 64): 3.5e-3, 4.4e-3,
+#: 2.7e-3, 3.8e-3 of the largest, the causal rule's readings.  Since PR 64 backward is the ONE kernel that sums dq in
+#: VMEM over the band's blocks alone (my chip run, PR 64, call 1; the pair read again in the same call; dk and dv of a
+#: group summed in VMEM | outside in float32):
+#:
+#:   block (keys a pass)           256 (256)       512 (512)       512 (256)       1024 (512)
+#:   fused, dq on the chip         9.82 | 10.23    7.20 | 7.35     7.51 | 7.91     9.48 | 9.80
+#:   dq and dkv apart              11.45           8.42            8.70            11.44
+#:
+#: the same block, 14% under the pair (`window_attention` as the op calls it: 6.94).
 #:
 #: A window LONGER than the largest block takes that block, 1024.  TPU v5e, (1, 28 on 4, 16384, 128) bf16, window 4096
 #: (SmallThinker's window layer), forward + backward of a layer alone, ms (my chip run, PR 63; `WINDOW=4096 python3
@@ -316,8 +354,15 @@ def causal_plan(length: int, heads: int, interpret: bool = False) -> Plan:
 #:   as the op calls it            34.28       31.60
 #:
 #: so 1024, which `window_block` already took: a block of 512 visits 10% fewer pairs and loses 8% to its grid steps.
-#: The fused backward is 1.5 ms a layer ahead alone at 1024 and is NOT taken: its partial dq, [16, 28, 16384, 128] a
-#: layer, is 1.9 GB of the step's memory.  Against dense float32 at (1, 7 on 1, 6144, 128): 2.4e-3, 4.0e-3, 5.2e-3, 3.8e-3.
+#: The stock fused backward was 1.5 ms a layer ahead alone at 1024 and was NOT taken: its partial dq, [16, 28, 16384,
+#: 128] a layer, is 1.9 GB of the step's memory.  Since PR 64 (my chip run, PR 64, call 1; the stock rows read again in
+#: the same call: 34.25, 30.87, 31.78 apart and 39.44, 29.34 fused; dk and dv of a group summed in VMEM | outside):
+#:
+#:   block (keys a pass)           512 (512)       1024 (512)      1024 (1024)
+#:   fused, dq on the chip         25.34 | 26.35   24.23 | 25.04   23.67 | 24.85
+#:
+#: 70 steps a head for the pair's 80 + 80 and the stock fused kernel's 256: 23% under the pair.  Against dense float32
+#: at (1, 7 on 1, 6144, 128): 2.4e-3, 4.0e-3, 5.2e-3, 3.8e-3.
 _WINDOW_BLOCKS = (128, 256, 512, 1024)
 
 
@@ -328,13 +373,13 @@ def window_block(length: int, window: int):
     return next((b for b in fitting if b >= window), fitting[-1] if fitting else None)
 
 
-def window_plan(length: int, heads: int, window: int, interpret: bool = False) -> Plan:
+def window_plan(length: int, heads: int, window: int, interpret: bool = False, widths=(128, 128)) -> Plan:
     """The window rule over `length` queries and as many keys.  A window that
     reaches the sequence's start from every query allows what the causal rule
     allows: that plan is the causal one, block maps and all."""
     if window >= length:
-        return causal_plan(length, heads, interpret)
-    return Plan(length, heads, window, window_block(length, window), 0, interpret, "sliding_window")
+        return causal_plan(length, heads, interpret, widths)
+    return Plan(length, heads, window, window_block(length, window), 0, interpret, "sliding_window", widths=tuple(widths))
 
 
 @functools.lru_cache(maxsize=8)
@@ -346,17 +391,18 @@ def _window_rule(window: int):
 
 @functools.lru_cache(maxsize=32)
 def block_maps(plan: Plan):
-    """The kernels' block maps for `plan`, forward, dq (None where the dkv
-    kernel computes dq too) and dkv, in numpy: made from the rule once a
-    shape, a block of the grid at a time."""
+    """The kernels' block maps for `plan`, forward, dq (None where one kernel
+    computes dq, dk and dv: it walks the dkv map, `data_next` the query block of
+    a step whether the map was shrunk to the rule's blocks, as the window's
+    is, or not) and dkv, in numpy: made from the rule once a shape, a block
+    of the grid at a time."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as info_lib
 
     sizes = plan.sizes
     if plan.rule == "causal":
         # The stock mask of the kind the kernels COMPUTE on a cut block (`_stock_options` hands them the rule): one
-        # compare a pair, where block-diffusion's divisions lost by 19 ms, and no [keys, queries] block of the mask
-        # in the fused backward kernel's VMEM, which has no room for it (`_BLOCKS`' table).
+        # compare a pair, where block-diffusion's divisions lost by 19 ms (`_BLOCKS`' table).
         rule = mask_lib.CausalMask((plan.positions, plan.positions))
     elif plan.rule == "sliding_window":
         # the stock local mask, `mask_block` keys wide with the query's own the last: computed on a cut block as well
@@ -366,13 +412,21 @@ def block_maps(plan: Plan):
     mask = mask_lib.MultiHeadMask([rule] * plan.heads)
     shards = dict(downcast_smem_data=True, head_shards=1, q_seq_shards=1)
     return (info_lib.process_mask(mask, (sizes.block_q, sizes.block_kv), **shards)[0],
-            None if plan.fused_backward else info_lib.process_mask(mask, (sizes.block_q_dq, sizes.block_kv_dq), **shards)[0],
+            None if sizes.block_q_dq is None else info_lib.process_mask(mask, (sizes.block_q_dq, sizes.block_kv_dq), **shards)[0],
             info_lib.process_mask_dkv(mask, (sizes.block_q_dkv, sizes.block_kv_dkv), **shards)[0])
 
 
 def _block_map(plan: Plan, which: int):
     """The stock kernels' block map `which` (forward, dq, dkv) on the device."""
     return jax.tree.map(jnp.asarray, block_maps(plan)[which])
+
+
+@functools.lru_cache(maxsize=32)
+def _steps(plan: Plan):
+    """The (key block, query block) pairs the one backward kernel steps through."""
+    from . import attention_backward_kernels as onchip
+
+    return onchip.steps_of(block_maps(plan)[2])
 
 
 def _stock_options(plan: Plan) -> dict:
@@ -400,22 +454,24 @@ def _far_forward(q, k, v, plan: Plan):
 
 
 def _far_backward(q, k, v, lse, do, di, plan: Plan):
-    """dq, dk, dv of the kernels' term from the stock dq and dkv kernels, given
-    the log-sum-exp and rowsum(do . out) of the WHOLE row (joined, where the
-    own-block term was split off).  Where the plan fuses the backward pass,
-    the dkv kernel writes dq too, a partial a block of keys in the operands'
-    dtype, and XLA sums the partials."""
+    """dq, dk, dv of the kernels' term given the log-sum-exp and rowsum(do .
+    out) of the WHOLE row (joined, where the own-block term was split off): from
+    the one kernel that keeps dq on the chip, or from the stock dq and dkv
+    kernels (`Plan.backward`)."""
+    if plan.backward == "onchip_dq":
+        from . import attention_backward_kernels as onchip
+
+        return onchip.backward(q, k, v, lse, do, di, _steps(plan), _stock_options(plan)["mask_function"], plan.block, plan.interpret)
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     sizes, dq_info, dkv_info = plan.sizes, _block_map(plan, 1), _block_map(plan, 2)
     options = dict(_stock_options(plan), q_layout=sizes.q_layout, k_layout=sizes.k_layout, v_layout=sizes.v_layout)
-    dq, dk, dv = jax.vmap(lambda *a: splash._splash_attention_bwd_dkv(
+    _, dk, dv = jax.vmap(lambda *a: splash._splash_attention_bwd_dkv(
         *a[:3], None, None, *a[3:], bq=sizes.block_q_dkv, bkv=sizes.block_kv_dkv, bkv_compute=sizes.block_kv_dkv_compute,
-        mask_info=dkv_info, use_fused_bwd_kernel=plan.fused_backward, **options))(q, k, v, lse, do, di)
-    if not plan.fused_backward:
-        dq = jax.vmap(lambda *a: splash._splash_attention_bwd_dq(
-            *a[:3], None, None, *a[3:], bq=sizes.block_q_dq, bkv=sizes.block_kv_dq, mask_info=dq_info,
-            **options))(q, k, v, lse, do, di)
+        mask_info=dkv_info, use_fused_bwd_kernel=False, **options))(q, k, v, lse, do, di)
+    dq = jax.vmap(lambda *a: splash._splash_attention_bwd_dq(
+        *a[:3], None, None, *a[3:], bq=sizes.block_q_dq, bkv=sizes.block_kv_dq, mask_info=dq_info,
+        **options))(q, k, v, lse, do, di)
     return dq, dk, dv
 
 
@@ -581,6 +637,7 @@ def attention_under(plan: Plan, q, k, v, scale: Optional[float], keep=None):
     _MON.counter("lowering.attention_blocks_visited").inc(int(np.count_nonzero(blocks)))
     _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
     _MON.counter("lowering.attention_own_block_terms").inc(int(plan.first_key > 0))
+    _MON.counter("lowering.attention_backward_onchip_dq").inc(int(plan.backward == "onchip_dq"))
     with jax.named_scope("block_sparse_attention"):
         if scale is not None:
             q = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -597,7 +654,7 @@ def window_attention(q, k, v, window: int, scale: float, interpret: bool = False
     """`attention_under` the sliding-window rule over equal lengths of queries
     and keys.  Counted at trace time: the op, the pairs inside the blocks its
     forward block map visits and the pairs the rule allows, over rows and heads."""
-    plan = window_plan(q.shape[2], q.shape[1], window, interpret)
+    plan = window_plan(q.shape[2], q.shape[1], window, interpret, (q.shape[-1], v.shape[-1]))
     blocks = block_maps(plan)[0].block_mask      # [heads, or 1 where every head has the one mask; query blocks; key blocks]
     visited = int(np.count_nonzero(blocks)) * (q.shape[1] // blocks.shape[0]) * plan.block * plan.block
     _MON.counter("lowering.window_attention_ops").inc()
@@ -609,7 +666,7 @@ def window_attention(q, k, v, window: int, scale: float, interpret: bool = False
 
 def causal_attention(q, k, v, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the causal rule over equal lengths of queries and keys."""
-    return attention_under(causal_plan(q.shape[2], q.shape[1], interpret), q, k, v, scale, keep)
+    return attention_under(causal_plan(q.shape[2], q.shape[1], interpret, (q.shape[-1], v.shape[-1])), q, k, v, scale, keep)
 
 
 # -- a mask that is DATA: each query's own chosen keys ------------------------------------------------------------------

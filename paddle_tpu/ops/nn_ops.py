@@ -629,10 +629,11 @@ def _fused_attention(ctx, op, ins):
 
     * `block_causal`: from `_FLASH_MIN_SEQ` keys on, a causal mask and no
       bias over as many bf16 keys as queries, on one device: the stock
-      splash-attention kernels under the causal rule
-      (`ops/masked_attention.py: causal_attention`), forward and one fused
-      backward kernel, which never visit a block above the diagonal and mask
-      only the blocks it cuts;
+      splash-attention forward kernel under the causal rule
+      (`ops/masked_attention.py: causal_attention`) and ONE backward kernel of
+      our own that keeps dq on the chip (`ops/attention_backward_kernels.py`),
+      which never visit a block above the diagonal and mask only the blocks
+      it cuts;
     * `flash`: the stock Pallas online-softmax kernel, every other attention
       from `_FLASH_MIN_SEQ` keys on (a bias, no causal mask, a mesh): no
       cell runs it since PR 37;
@@ -644,7 +645,8 @@ def _fused_attention(ctx, op, ins):
       stock splash-attention kernel with the rule as its mask: blocks the
       rule empties are skipped, forward and backward, the blocks it cuts read
       the few distinct cut blocks (block diffusion) or compute the rule from
-      the positions (a sliding window, `mask_block` its width in keys), and
+      the positions (a sliding window, `mask_block` its width in keys: its
+      backward is the causal rule's one kernel over the band's blocks), and
       no mask or score of the whole square is in HBM;
     * `selected`: under a mask that is DATA (the input `Picks`, int32 (B, Lq,
       Lk / 32): bit j of word w of a query set where it holds key 32 w + j;

@@ -81,10 +81,14 @@ def _block_causal(q, k, v):
     return causal_attention(q, k, v, q.shape[-1] ** -0.5)
 
 
-def _window(q, k, v):
+def _window(q, k, v, window=512):
     from paddle_tpu.ops.masked_attention import window_attention
 
-    return window_attention(q, k, v, 512, q.shape[-1] ** -0.5)
+    return window_attention(q, k, v, window, q.shape[-1] ** -0.5)
+
+
+def _window_4096(q, k, v):
+    return _window(q, k, v, 4096)
 
 
 def _kda(q, k, v, g, beta):
@@ -288,6 +292,16 @@ CASES = {
     # key/value heads of 64 at 8192 keys under a window of 512; forward, and dq and dkv each a kernel of its own
     "window_attention_phi4flash": (
         _window, [((1, 40, 8192, 64), BF16)] + [((1, 20, 8192, 64), BF16)] * 2, (0, 1, 2)),
+    # the ONE backward kernel that keeps dq on the chip (ops/attention_backward_kernels.py, PR 64) where its VMEM is
+    # largest: SmallThinker's window layer and its full layer, 28 query heads on 4 key/value heads of 128 at 16384
+    # keys (a head's float32 dq 8 MB, a key/value head's dk and dv rows 16 MB more), and Kanana-2's latent
+    # attention, 32 heads of 192-wide queries and keys beside 128-wide values (dq's rows padded to 256 lanes: 16 MB)
+    "window_attention_smallthinker": (
+        _window_4096, [((1, 28, 16384, 128), BF16)] + [((1, 4, 16384, 128), BF16)] * 2, (0, 1, 2)),
+    "block_causal_attention_smallthinker": (
+        _block_causal, [((1, 28, 16384, 128), BF16)] + [((1, 4, 16384, 128), BF16)] * 2, (0, 1, 2)),
+    "block_causal_attention_kanana2_192_128": (
+        _block_causal, [((1, 32, 16384, 192), BF16)] * 2 + [((1, 32, 16384, 128), BF16)], (0, 1, 2)),
 }
 
 
@@ -431,20 +445,23 @@ def test_no_square_of_the_positions_is_in_the_compiled_causal_attention(q, kv, c
     """Forward and backward at OLMoE's and LFM2's shapes: no array with a
     [keys, keys] square, mask or scores, in any computation of the compiled
     program, nor the flash path's lane-spread float32 `di` ([b, h, L, 1024]),
-    and the temporaries well under half of that path's; the stock kernels' two calls
-    (forward, and the backward kernel that writes dq, dk and dv) under the
-    lowering's scope."""
+    and the temporaries well under half of that path's; two kernel calls (the
+    stock forward, and the ONE backward kernel of `ops/attention_backward_kernels.py`,
+    which sums dq in VMEM: no partial a block of keys) under the lowering's scope."""
     length = q[2]
     args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in (q, kv, kv)]
     compiled = jax.jit(jax.grad(lambda *a: jnp.sum(_block_causal(*a).astype(F32)), argnums=(0, 1, 2))).lower(*args).compile()
     text = compiled.as_text()
     assert not re.findall(r"\[[\d,]*%d,%d\]" % (length, length), text)
     assert not re.findall(r"f32\[%d,%d,%d,1024\]" % q[:3], text)
-    # 0.27 and 1.34 GB here, dq's partials a block of keys among them (the flash kernel's program 1.34 and 3.36 GB: ISSUE 37)
-    assert compiled.memory_analysis().temp_size_in_bytes < (1.5e9 if kv != q else 0.45e9)
+    # 0.27 and 0.81 GB here (the stock fused backward's program, dq's partials a block of keys among them, 0.27 and
+    # 1.34 GB; the flash kernel's 1.34 and 3.36 GB: ISSUE 37)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1.0e9 if kv != q else 0.45e9)
+    assert not re.findall(r"\[%d,%d,%d,%d,%d\]" % ((length // 1024,) + q), text)         # dq's partials a block of keys
     assert text.count("tpu_custom_call") == 2
-    under_the_scope = re.findall(r'op_name="[^"]*block_sparse_attention[^"]*/splash_mha_(fwd|dq|dkv)[^"/]*/pallas_call"', text)
-    assert set(under_the_scope) == {"fwd", "dkv"}
+    under_the_scope = re.findall(
+        r'op_name="[^"]*block_sparse_attention[^"]*/(splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv)[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"splash_mha_fwd", "attention_dq_dk_dv"}
 
 
 #: Kimi-Linear-48B-A3B's: one sequence of 4096 positions, hidden 2304 (18 lane tiles), 8 of 256 experts held
@@ -559,18 +576,20 @@ def test_no_square_of_the_positions_is_in_the_compiled_window_attention(chip):
     """Forward and backward at Phi-4-mini-flash's window layer's shape: no array
     with an [8192, 8192] square, mask or scores, in any computation of the
     compiled program (XLA's attention would hold 10.7 GB of float32 scores
-    there), temporaries under 0.4 GB (0.29 here), and the stock kernels' three
-    calls (forward, dq, dkv) under the rule's own scope, where the benchmark's
-    `window_attention_roofline_share` finds them."""
+    there), temporaries under 0.4 GB (0.38 here), and the two kernel calls
+    (the stock forward and the one backward kernel of
+    `ops/attention_backward_kernels.py`) under the rule's own scope, where the
+    benchmark's `window_attention_roofline_share` finds them."""
     args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in ((1, 40, 8192, 64), (1, 20, 8192, 64), (1, 20, 8192, 64))]
     compiled = jax.jit(jax.grad(lambda *a: jnp.sum(_window(*a).astype(F32)), argnums=(0, 1, 2))).lower(*args).compile()
     text = compiled.as_text()
     assert not re.findall(r"\[[\d,]*8192,8192\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     under_the_scope = re.findall(
-        r'op_name="[^"]*window_attention\)*/block_sparse_attention[^"]*/splash_mha_(fwd|dq|dkv)[^"/]*/pallas_call"', text)
-    assert set(under_the_scope) == {"fwd", "dq", "dkv"}
+        r'op_name="[^"]*window_attention\)*/block_sparse_attention[^"]*/(splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv)'
+        r'[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"splash_mha_fwd", "attention_dq_dk_dv"}
 
 
 def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip):
@@ -616,7 +635,7 @@ def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip)
     assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.280
     text = compiled.as_text()
     # the one attention layer took the splash kernels under the causal rule (PR 37; the flash kernel until then)
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
     assert text.count("/gated_short_conv/") > 0 and text.count("/expert_gemm/") > 0
 
 
@@ -665,7 +684,7 @@ def test_ouros_step_compiles_for_the_chip_as_one_loop_and_its_planned_peak_leave
     assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.5
     text = compiled.as_text()
     assert len(re.findall(r" while\(", text)) == 2
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
     # the scopes the cell's readers find: the recomputed forward, the exits, the body's ops under the construct's
     assert text.count("/rematted_computation/") > 0
     # numbered where this process built a looped model before: sibling `name_scope`s of one name are
@@ -720,7 +739,7 @@ def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_roo
     assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
     assert m.argument_size_in_bytes == pytest.approx(3 * 4 * cfg["parameters"], rel=1e-3)    # masters and Adam's two moments
     text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
     scans = re.findall(r'op_name="([^"]*/kda_chunk_scan/[^"]*)"', text)
     assert any("transpose(" in name for name in scans) and any("transpose(" not in name for name in scans)
     assert all(any(name.endswith(f"/{kernel}/pallas_call") for name in scans) for kernel in ("kda_scan", "kda_scan_transposed"))
@@ -891,7 +910,10 @@ def test_smallthinkers_step_compiles_for_the_chip_with_its_routers_ahead_and_a_w
     print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
     assert 0.25 * 16.9e9 <= peak <= 9.0e9, peak
     text = compiled.as_text()
-    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
+    assert "splash_mha_fwd" in text and "splash_mha_d" not in text and "flash_mha" not in text
+    # ONE backward kernel an attention layer (ISSUE 64), and no partial dq a block of keys
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*/attention_dq_dk_dv["/]', text)) == 4
+    assert not re.findall(r"\[16,28,16384,128\]", text)
     names = re.findall(r'op_name="([^"]*)"', text)
     window = {re.search(r"/(sliding_attention(?:_\d+)?)/", n).group(1) for n in names if "/window_attention/" in n}
     assert len(window) == 3                        # the three rotary layers, each under its own numbered scope
@@ -901,7 +923,7 @@ def test_smallthinkers_step_compiles_for_the_chip_with_its_routers_ahead_and_a_w
     assert len(routers) == len(attentions) == 4 and all(r < a for r, a in zip(routers, attentions))
     assert all(a < r for a, r in zip(attentions, routers[1:]))          # router, attention, router, attention, ...
     again = [name for name in _made_again(text) if "/cond/branch_" not in name]
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
 
 
 def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
@@ -922,7 +944,7 @@ def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
     positions = 2048
     compiled, counted = edge.one_layer_step(host.devices, positions)
     assert counted["lowering.latent_operands_assembled"] == 1 and not counted.get("lowering.latent_operands_fallback")
-    assert counted["lowering.attention_block_causal"] == 1 and counted["lowering.latent_rotary_ops"] == 2
+    assert counted["lowering.attention_block_causal"] == counted["lowering.attention_backward_onchip_dq"] == 1 and counted["lowering.latent_rotary_ops"] == 2
     text = compiled.as_text()
     found = edge.edges(text, floor=edge.FLOOR * positions / 16384)
     mb = 2 * positions * 32 / 1e6       # of a (B, L, H, 1) slab in bf16
@@ -937,7 +959,7 @@ def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
     names = re.findall(r'op_name="([^"]*)"', text)
     assert any("/rotary/" in name for name in names)
     assert not [name for name in names if "/rotary/" in name and name.endswith("/dot_general")]
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text
 
 
 @pytest.mark.slow   # one compile of ~70 s on every core: run by name (`-m slow`); PERF.md, PR 54, has its readings
@@ -958,7 +980,7 @@ def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_plan
     print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
     assert 0.25 * 16.9e9 <= peak <= 14.9e9, peak
     text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "flash_mha" not in text
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
     assert len(set(re.findall(r"/(latent_attention(?:_\d+)?)/rotary/op\d+:rotary_embedding", text))) == 5
     # the edge of a sparse layer's latent attention, the unit's own kernels with it: 2.6 GB written or less, from 4.1
     # before the chain was lowered as one unit (ISSUE 55; `tools/chip_latent_edges.py` prints the table)
@@ -969,7 +991,7 @@ def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_plan
     assert 1.5e3 <= written <= 2.6e3, written
     # (the rare path makes its own again, and a rotation's pair swap is a product with a constant, no kept matrix's)
     again = [name for name in _made_again(text) if "/cond/branch_" not in name and ":rotary_embedding/" not in name]
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "/expert_gemm/" in name]
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
 
 
 def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):

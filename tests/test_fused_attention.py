@@ -208,6 +208,102 @@ def test_the_causal_plans_kernels_agree_with_xlas_attention_forward_and_backward
         agree(g, w, tol=2e-5)
 
 
+def backward_agrees_with_dense_float32(plan, allowed, hq, hkv, widths):
+    """The ONE backward kernel of `ops/attention_backward_kernels.py` as
+    `attention_under` reaches it under `plan`, INTERPRETED, against dense
+    float32 attention under `allowed(q_ids, kv_ids)`: dq, dk and dv within the
+    kernel cases' tolerance, `dk` and `dv` of a key/value head summed over its
+    query heads by the kernel (tests/test_sambay.py has the window rule's cases)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import masked_attention
+
+    length, (width, v_width) = plan.positions, widths
+    rng = np.random.RandomState(64)
+    q, k = rng.randn(2, hq, length, width).astype("f4"), rng.randn(2, hkv, length, width).astype("f4")
+    v, weight = rng.randn(2, hkv, length, v_width).astype("f4"), rng.randn(2, hq, length, v_width).astype("f4")
+    assert plan.backward == "onchip_dq"
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
+        at = jnp.arange(length)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * width ** -0.5
+        p = jax.nn.softmax(jnp.where(allowed(at[:, None], at[None, :]), s, -jnp.inf), -1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+    got = jax.grad(lambda *a: jnp.sum(masked_attention.attention_under(plan, *a, width ** -0.5) * weight), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * weight), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, "f8"), np.asarray(w, "f8")
+        assert g.shape == w.shape and np.isfinite(g).all()
+        assert np.abs(g - w).max() <= 2e-5 * np.abs(w).max(), (np.abs(g - w).max(), np.abs(w).max())
+
+
+#: query heads, key/value heads, blocks of 128 in the length, widths of (queries and keys, values): every head
+#: grouping with every width and both lengths once (a group of 7 on 1 as SmallThinker's 28 on 4, 192 | 128 as Kanana-2's)
+ONCHIP_DQ_CASES = [(4, 4, 4, (64, 64)), (4, 4, 8, (128, 128)), (8, 2, 4, (128, 128)), (8, 2, 8, (192, 128)),
+                   (7, 1, 4, (192, 128)), (7, 1, 8, (64, 64)), (4, 4, 4, (192, 128)), (8, 2, 4, (64, 64)), (7, 1, 4, (128, 128))]
+
+
+@pytest.mark.parametrize("hq,hkv,blocks,widths", ONCHIP_DQ_CASES)
+def test_the_one_backward_kernel_agrees_with_dense_float32_under_the_causal_rule(hq, hkv, blocks, widths):
+    from paddle_tpu.ops import masked_attention
+
+    plan = masked_attention.causal_plan(128 * blocks, hq, True, widths)._replace(block=128)
+    steps = masked_attention._steps(plan)
+    assert steps.q_block.size == blocks * (blocks + 1) // 2 and (steps.q_block >= steps.kv_block).all()   # the triangle alone
+    backward_agrees_with_dense_float32(plan, masked_attention.causal_allowed, hq, hkv, widths)
+
+
+def _backward_jaxpr(plan, q_shape, kv_heads, v_width=None, picks=False):
+    """Backward alone under `plan` as a TPU would run it, traced and not run:
+    the jaxpr of the op's backward rule on its residuals' shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import masked_attention
+
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((q_shape[0], kv_heads) + q_shape[2:], jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(k.shape[:3] + (v_width or q_shape[3],), jnp.bfloat16)
+    out = jax.ShapeDtypeStruct(q_shape[:3] + v.shape[3:], jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct(q_shape[:3], jnp.float32)
+    if picks:
+        chosen = jax.ShapeDtypeStruct((q_shape[0], q_shape[2], q_shape[2] // 32), jnp.int32)
+        return str(jax.make_jaxpr(lambda *a: masked_attention._selected_bwd(plan, None, a[:6], a[6:]))(q, k, v, chosen, out, lse, out, lse))
+    return str(jax.make_jaxpr(lambda *a: masked_attention._attention_bwd(plan, None, a[:5], a[5]))(q, k, v, out, lse, out))
+
+
+def test_the_causal_plans_backward_is_one_kernel_and_holds_no_partial_dq():
+    """ONE `pallas_call`, the kernel that sums dq in VMEM, and no array a block
+    of keys by the queries' shape (`[L / block, Hq, L, dh]`: the stock fused
+    kernel's partials); the op's counter says so at trace time."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import monitor
+    from paddle_tpu.ops import masked_attention
+
+    plan = masked_attention.causal_plan(4096, 8, widths=(64, 64))
+    assert (plan.block, plan.backward) == (1024, "onchip_dq")
+    text = _backward_jaxpr(plan, (1, 8, 4096, 64), 2)
+    assert text.count("pallas_call[") == 1 and "name=attention_dq_dk_dv" in text and "splash_mha" not in text
+    assert not re.findall(r"\[4,(?:1,)?8,4096,64\]", text)
+    monitor.reset()
+    monitor.enable()
+    try:
+        q = jax.ShapeDtypeStruct((1, 8, 4096, 64), jnp.bfloat16)
+        jax.eval_shape(lambda q: masked_attention.causal_attention(q, q[:, :2], q[:, :2], 0.125), q)
+        seen = monitor.get_monitor().counter_values()
+    finally:
+        monitor.disable()
+        monitor.reset()
+    assert seen["lowering.attention_backward_onchip_dq"] == 1 and not seen.get("lowering.attention_dq_partials_bytes")
+
+
 # --------------------------------------------------------------------------
 # The op's `layout` (ISSUE 39): "blhd" hands Q, K, V over as (B, L, H, dh), the
 # projections' own layout, and takes Out back so.  One mathematics: every path
@@ -275,7 +371,7 @@ TPU_LAYOUT_CASES = {
     ((512, 384), False, False): ("row_kernel", {"fused_sdpa_fwd", "fused_sdpa_bwd"}),
     ((1, 512), False, False): ("xla", set()), ((1024, 1024), False, False): ("xla", set()),
     ((2048, 2048), False, False): ("flash", {"flash_attention"}),
-    ((2048, 2048), True, False): ("block_causal", {"splash_mha_fwd", "splash_mha_dkv"}),
+    ((2048, 2048), True, False): ("block_causal", {"splash_mha_fwd", "attention_dq_dk_dv"}),   # ONE backward kernel, of our own
     ((2048, 2048), False, True): ("block_sparse", {"splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"}),
 }
 
@@ -313,7 +409,7 @@ def test_on_the_tpu_the_layout_changes_no_choice_and_only_the_row_kernel_reads_i
             text = str(jax.make_jaxpr(jax.grad(lambda *a: f(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(*args))
             moved = {n[len("lowering.attention_"):]: c - before.get(n, 0) for n, c in monitor.get_monitor().counter_values().items()
                      if n.startswith("lowering.attention_") and c != before.get(n, 0)}
-            assert set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv)\w*\b",
+            assert set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv)\w*\b",
                                   text)) == kernels
             native = layout == "bhld" or path == "row_kernel"
             assert moved.pop(path) == 1 and moved.pop("layout_native" if native else "layout_transposed") == 1

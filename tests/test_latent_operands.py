@@ -243,6 +243,7 @@ def test_inside_a_recomputed_segment_the_kept_names_are_the_op_by_op_lowerings(c
     per_op, said_per_op = _names_kept(main, loss, [loss.name, _an_intermediate(main, "V")], 2048, 64)
     assert said["latent_operands_assembled"] == 1 and said_per_op["latent_operands_fallback_fetched"] == 1
     assert said["attention_block_causal"] == said_per_op["attention_block_causal"] == 1
+    assert said["attention_backward_onchip_dq"] == said_per_op["attention_backward_onchip_dq"] == 1   # 192 | 128 widths take the one backward kernel
     assert unit == per_op and any(n.endswith("@residuals") for n in unit) and len(unit) == 4   # q, kv_a, kv_b; out, lse
     assert said["recomputed_kept_values"] == said_per_op["recomputed_kept_values"] == len(unit)
     assert said["recomputed_kept_bytes"] == said_per_op["recomputed_kept_bytes"]

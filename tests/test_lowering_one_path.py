@@ -142,15 +142,17 @@ PARENTS_PROGRAMS = {
     "bert-base-s128-fused": (lambda: _bert(256, 128), "train_44acd386",
         "ad604c402ea6916dc1d33a8b1ffffa1099b7f41e51e8f94b14007955a5978ded",
         "0a22ade36687defbab16d2d7aefcbd8952f2a23b7713bb567c0863cad4efa10c", (0, 0, 12, 0)),
+    # OLMoE's, LFM2's and Ouro's text re-recorded by PR 64 (the causal rule's backward: `ops/attention_backward_kernels.py`);
+    # their listings of ops are the parent's
     "olmoe-1b-7b-s4096": (_olmoe_s4096, "train_cbb6bbe7",
         "822e9f203b8780a8ce13ae8c050fe4480b215eb43e3063bc89b21090c48146d1",
-        "9438d63e71a0118f9fadadf412dcdcc95f213c835c55b6a1e42cee787e96d655", (0, 0, 0, 1)),
+        "66ffe85c3d6308031ea0876d0aa1567725e5e6ce3e0d925ab9c191c69a99c016", (0, 0, 0, 1)),
     "lfm2-8b-a1b-s8192": (lambda: _cell("lfm2", "lfm2-8b-a1b", "train-s8192"), "train_b5740440",
         "94c5da17ec8b9b45b8a6e3c57b80081513f0cdc288a7212598cece8733215c98",
-        "4a13169bb57d3fe31cacddb6b40691ddf36b4dbd97dbcf0de5edae7c5aeb8b0e", (0, 0, 0, 1)),
+        "2013734ce6ab07a3ee242fadf3fd2d9709f15094f554a0d302e78a4669fb7f17", (0, 0, 0, 1)),
     "ouro-2.6b-ut4-s4096": (lambda: _cell("ouro", "ouro-2.6b", "train-ut4-s4096"), "train_3a3d8d40",
         "de4588fb1ac19708384a3c0cc4e3b98109602dd8aa2103815510ee3782ecdf3a",
-        "d0474d1a373d8c91b3730ae74079615afa75aaecb439c0e1151e4a2d5145dc90", (0, 0, 0, 8)),
+        "33533526eb79b0e32a69b24490454067c8362c3d670e9a1941477eb339d1c5b7", (0, 0, 0, 8)),
     "batch_norm-train-fp16": (_fp16_batch_norm, "train_97080cb5",
         "97158b65993029a94935d7280f793136a5fe07aa0458a7b0de8d003fb41fbd33",
         "c51ed89d771c7584243bbd025643a313dbcaafe3ab0d33c7761134fc04f57984", (0, 0, 0, 0)),
@@ -162,14 +164,18 @@ PARENTS_PROGRAMS = {
 #: rests "the other cells cannot move" on), recorded at the parent commit `41636dc` of PR 58 by that tool in a
 #: `git archive` of it.  A PR that means to change one of these programs re-records its line and says so.
 PARENTS_CELLS = {
+    # Kimi Linear's, Phi-4-mini-flash's and Kanana-2's lines re-recorded by PR 64, which means to change these programs:
+    # the causal and the window rule's backward is one kernel of `ops/attention_backward_kernels.py`, not the stock
+    # fused kernel or the stock pair (the parent's lines were 2c60a2d6...dd4eb, 5d27410c...84a7a, c11c6801...6c118);
+    # SDAR's (block diffusion's rule) and Keye-VL-2.0's (the selected rule) are the parent's still
     "sdar-30b-a3b-chat.train-blockdiff-s4096": ("train_6de7c714",
         "3a0761cf88eedd3d35378d5928192cfe3234650ff0046226fe0d6bb9c78b8195"),
     "kimi-linear-48b-a3b.train-kda-s4096": ("train_f85463d4",
-        "2c60a2d6d7f1e9754d535dd8a9864daaf187b0dc7550fe6e06d6e42aa60dd4eb"),
+        "f90a348c6f9460dfea90bb984d9dd4d084abf11d37de553a04e55d0bbe4d49a2"),
     "phi-4-mini-flash-reasoning.train-sambay-s8192": ("train_d4522e44",
-        "5d27410c095290ca3ba3084360cf6fddf735c135027655eff2962967ccd84a7a"),
+        "6b601724f5a394da300cf9409359a264175e2f75310435adb903a6eb3e5c3b7b"),
     "kanana-2-30b-a3b.train-mla-s16384": ("train_9752db24",
-        "c11c680130484415415ebbd16816934a956b91a9a77afa06c1fe569d39a6c118"),
+        "9d96882e36a8fbccc6318cac72f400183a5635f2f44212ed1b17e268251d1a0f"),
     # re-recorded by PR 62, which means to change this program (`index_alignment`'s target: `ops/alignment_target_kernels.py`),
     # as PR 59 did for the op's gradients; the parent's line was bd1e3b97...545f0, and the four above are the parent's still
     "keye-vl-2.0-30b-a3b.train-dsa-s16384": ("train_21d207fd",
@@ -394,12 +400,12 @@ def _trace_attention(lengths, dtype="bfloat16", head=64, bias=False, causal=Fals
     before = _attention_counters()
     text = str(jax.make_jaxpr(jax.grad(attention, argnums=(0, 1, 2)))(*args))
     moved = tuple(b - a for a, b in zip(before, _attention_counters()))
-    kernels = set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv)\w*\b", text))
+    kernels = set(re.findall(r"name=(fused_sdpa_fwd|fused_sdpa_bwd|flash_attention|splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv)\w*\b", text))
     return kernels, moved
 
 
 KERNELS_OF = {"row_kernel": {"fused_sdpa_fwd", "fused_sdpa_bwd"}, "flash": {"flash_attention"}, "xla": set(),
-              "block_causal": {"splash_mha_fwd", "splash_mha_dkv"}}  # one backward kernel: dq from dkv's pass
+              "block_causal": {"splash_mha_fwd", "attention_dq_dk_dv"}}  # one backward kernel, dq summed on the chip
 COUNTED_AS = {"row_kernel": (1, 0, 0, 0), "flash": (0, 1, 0, 0), "xla": (0, 0, 1, 0), "block_causal": (0, 0, 0, 1)}
 
 

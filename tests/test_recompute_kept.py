@@ -93,7 +93,8 @@ def kernels_interpreted(monkeypatch):
     causal_plan = masked_attention.causal_plan
     monkeypatch.setattr(ssm_ops, "_scan_path", lambda *a, **k: "interpret")
     monkeypatch.setattr(nn_ops, "_attention_path", lambda *a, **k: "block_causal")
-    monkeypatch.setattr(masked_attention, "causal_plan", lambda length, heads, interpret=False: causal_plan(length, heads, True))
+    monkeypatch.setattr(masked_attention, "causal_plan",
+                        lambda length, heads, interpret=False, widths=(128, 128): causal_plan(length, heads, True, widths))
 
 
 def batch(seed=5):

@@ -38,6 +38,7 @@ from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
 from paddle_tpu.ops import masked_attention, nn_ops  # noqa: E402
+from test_fused_attention import ONCHIP_DQ_CASES, _backward_jaxpr, backward_agrees_with_dense_float32  # noqa: E402
 
 
 def agree(got, want, tol=1e-5, floor=1e-12):
@@ -83,10 +84,10 @@ def test_the_windows_block_maps_hold_the_bands_blocks_and_a_whole_window_is_the_
             assert (mine is None and theirs is None) or all(
                 (a is None and b is None) or np.array_equal(a, b) for a, b in zip(mine, theirs))
         return
-    assert plan.rule == "sliding_window" and plan.mask_block == window and not plan.fused_backward
+    assert plan.rule == "sliding_window" and plan.mask_block == window and plan.backward == "onchip_dq"
     assert plan.block == {1: 128, 8: 128, 64: 128, 130: 256}[window]             # the smallest block that holds a window
     forward, dq, dkv = masked_attention.block_maps(plan)
-    assert np.count_nonzero(dq.block_mask) >= np.count_nonzero(forward.block_mask)      # dq's blocks of queries are smaller
+    assert dq is None                                                          # one backward kernel, which walks the dkv map
     at = np.arange(length)
     dense = masked_attention.window_allowed(at[:, None], at[None, :], window)
     n = length // plan.block
@@ -97,6 +98,12 @@ def test_the_windows_block_maps_hold_the_bands_blocks_and_a_whole_window_is_the_
         assert ((state > 0) == touched).all() and ((state == 2) == whole).all()
     assert np.count_nonzero(state) == touched.sum() <= 2 * n - 1                 # the diagonal and one block before it
     assert np.count_nonzero(dkv.block_mask) == np.count_nonzero(state)
+    steps = masked_attention._steps(plan)                                      # ... a step a touched block, a key block after the other
+    assert steps.q_block.size == touched.sum() and (np.diff(steps.kv_block) >= 0).all()
+    assert touched[steps.q_block, steps.kv_block].all()
+    first = np.r_[True, np.diff(steps.kv_block) > 0]                          # marked: a key block's first and last step
+    assert ((steps.marks & 1 > 0) == first).all() and ((steps.marks & 2 > 0) == np.r_[first[1:], True]).all()
+    assert len(set(zip(steps.q_block.tolist(), steps.kv_block.tolist()))) == steps.q_block.size
 
 
 WINDOW_KERNEL_CASES = [(4, 2, 384, 64, 130), (2, 2, 256, 128, 64), (4, 1, 256, 64, 1)]
@@ -131,7 +138,7 @@ def test_the_window_plans_kernels_agree_with_xlas_attention_forward_and_backward
         monitor.reset()
     plan = masked_attention.window_plan(length, hq, window)
     blocks = np.count_nonzero(masked_attention.block_maps(plan)[0].block_mask[0])
-    assert seen["lowering.window_attention_ops"] == 1
+    assert seen["lowering.window_attention_ops"] == seen["lowering.attention_backward_onchip_dq"] == 1
     assert seen["lowering.window_pairs_allowed"] == 2 * hq * masked_attention.window_pairs(length, window)
     assert seen["lowering.window_pairs_visited"] == 2 * hq * blocks * plan.block ** 2 >= seen["lowering.window_pairs_allowed"]
     assert seen["lowering.attention_blocks_visited"] >= blocks
@@ -139,6 +146,44 @@ def test_the_window_plans_kernels_agree_with_xlas_attention_forward_and_backward
     want = jax.grad(lambda *a: jnp.sum(xla(*a) * weight), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         agree(g, w, tol=2e-5, floor=1e-1)      # under a window of 1 the queries' and keys' gradients are exactly 0
+
+
+#: the causal rule's cases (tests/test_fused_attention.py) under a window SHORTER than the kernels' block of 128, of a
+#: block and of FOUR blocks: a key block then meets up to five query blocks, the first and the last of them cut
+ONCHIP_DQ_WINDOW_CASES = [(hq, hkv, blocks, widths, window)
+                          for (hq, hkv, blocks, widths), window in zip(ONCHIP_DQ_CASES, (100, 512, 128, 512, 100, 128, 128, 100, 128))]
+
+
+@pytest.mark.parametrize("hq,hkv,blocks,widths,window", ONCHIP_DQ_WINDOW_CASES)
+def test_the_one_backward_kernel_agrees_with_dense_float32_under_the_window_rule(hq, hkv, blocks, widths, window):
+    length = 128 * blocks
+    plan = masked_attention.window_plan(length, hq, window, True, widths)._replace(block=128)
+    assert plan.rule == "sliding_window"
+    steps = masked_attention._steps(plan)
+    reach = 1 + -(-(window - 1) // 128)                        # query blocks a key block meets where the sequence does not end
+    assert steps.q_block.size == sum(min(reach, blocks - j) for j in range(blocks)) < blocks * (blocks + 1) // 2
+    backward_agrees_with_dense_float32(plan, masked_attention._window_rule(window), hq, hkv, widths)
+
+
+def test_the_window_plans_backward_is_one_kernel_and_holds_no_partial_dq():
+    import re
+
+    plan = masked_attention.window_plan(8192, 8, 2048)
+    assert (plan.block, plan.backward) == (1024, "onchip_dq")
+    text = _backward_jaxpr(plan, (1, 8, 8192, 128), 2)
+    assert text.count("pallas_call[") == 1 and "name=attention_dq_dk_dv" in text and "splash_mha" not in text
+    assert not re.findall(r"\[8,(?:1,)?8,8192,128\]", text)
+
+
+@pytest.mark.parametrize("rule", ["block_diffusion", "selected"])
+def test_a_stored_masks_plan_still_lowers_to_the_stock_pair(rule):
+    """The rules whose cut blocks are STORED keep the stock dq and dkv kernels,
+    each a call of its own, and no kernel of ours (ISSUE 64 leaves them to an
+    issue of their own)."""
+    plan = masked_attention.selected_plan(1024, 4) if rule == "selected" else masked_attention.plan_of(1024, 4, 4)
+    assert plan.backward == "stock_pair"
+    text = _backward_jaxpr(plan, (1, 4, 1024, 128), 2, picks=rule == "selected")
+    assert "name=splash_mha_dq" in text and "name=splash_mha_dkv" in text and "attention_dq_dk_dv" not in text
 
 
 def test_a_window_as_long_as_the_sequence_is_the_causal_attention():

@@ -5,8 +5,12 @@ the causal attentions at long keys, the stock flash kernel and the splash
 kernels under the causal rule (`ops/masked_attention.py: causal_attention`),
 at 128-wide heads and at 64-wide heads on grouped key/value heads, which an
 interpreted run cannot vouch for (PERF.md, defect 15).  CAUSAL=1 runs those alone.
+Since PR 64 `causal_attention`'s backward is the one kernel that sums dq in VMEM and
+rounds it ONCE (`ops/attention_backward_kernels.py`); beside it the stock fused
+backward, whose dq is a partial a block of keys rounded to bf16 before XLA sums
+them (2 partials at 2048 keys, 8 at 8192: the third shape).
 
-    chiprun -- python3 tools/chip_attention_errors.py     (PERF.md, PRs 30 and 37)
+    chiprun -- python3 tools/chip_attention_errors.py     (PERF.md, PRs 30, 37 and 64)
 """
 import json
 import os
@@ -27,9 +31,25 @@ DRY = os.environ.get("DRY") == "1"  # a rehearsal on the CPU: XLA's attention on
 assert DRY or jax.devices()[0].platform == "tpu", jax.devices()
 
 
-# the float32 reference holds [B, H, L, L] scores: 2048 keys, a few heads
-for causal_shape, kv_heads in (((1, 4, 256, 128), 4), ((1, 8, 256, 64), 2)) if DRY else (((2, 8, 2048, 128), 8), ((2, 16, 2048, 64), 4)):
-    causal = {"block_causal": lambda q, k, v: causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)}
+def stock_fused(q, k, v):
+    """The stock splash kernels under the causal rule with the stock FUSED
+    backward: what `causal_attention` ran until PR 64."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
+
+    b = 128 if DRY else 1024
+    sizes = splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=min(b, 512), block_q_dkv=b, block_kv_dkv=b,
+                              block_kv_dkv_compute=min(b, 512), use_fused_bwd_kernel=True)
+    mask = mask_lib.MultiHeadMask([mask_lib.CausalMask((q.shape[2], q.shape[2]))] * q.shape[1])
+    kernel = splash.make_splash_mha(mask, block_sizes=sizes, head_shards=1, q_seq_shards=1, interpret=DRY)
+    return jax.vmap(kernel)((q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype), k, v)
+
+
+# the float32 reference holds [B, H, L, L] scores: 2048 keys, a few heads; 8192 keys on 4 heads (1 GB an array)
+for causal_shape, kv_heads in (((1, 4, 256, 128), 4), ((1, 8, 256, 64), 2)) if DRY else (
+        ((2, 8, 2048, 128), 8), ((2, 16, 2048, 64), 4), ((1, 4, 8192, 128), 4)):
+    causal = {"block_causal": lambda q, k, v: causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY),
+              "stock_fused_backward": stock_fused}
     if not DRY:
         causal["flash"] = flash_causal
     for seed in (0, 1):

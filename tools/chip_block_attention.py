@@ -34,6 +34,7 @@ import json
 import os
 import sys
 import time
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -43,6 +44,7 @@ import numpy as np
 from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as mask_lib
 
+from paddle_tpu.ops import attention_backward_kernels as onchip_kernels
 from paddle_tpu.ops import masked_attention as ma
 from tests.test_pallas_attention import flash_causal
 
@@ -68,9 +70,23 @@ def ms(fn, *args, runs=5):
     return float(np.median(times))
 
 
-def operands(q_shape, kv_shape, seed=0):
+def operands(q_shape, kv_shape, seed=0, v_width=None):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    return [jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(keys, (q_shape, kv_shape, kv_shape))]
+    v_shape = kv_shape[:-1] + (v_width or kv_shape[-1],)
+    return [jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(keys, (q_shape, kv_shape, v_shape))]
+
+
+def onchip(plan, kv_rows=None):
+    """`attention_under(plan, ...)` as the op calls it; `kv_rows` False: dk and dv
+    a query head in float32 and a group's summed outside, what the one backward
+    kernel (ops/attention_backward_kernels.py) falls back to where a key/value
+    head's whole rows do not fit its VMEM (None: as the kernel takes it from
+    the shapes)."""
+    def attend(q, k, v):
+        with mock.patch.object(onchip_kernels, "kv_rows_fit", (lambda *a: kv_rows) if kv_rows is not None else onchip_kernels.kv_rows_fit):
+            return ma.attention_under(plan, q, k, v, q.shape[-1] ** -0.5)
+    onchip_kernels.backward.clear_cache()      # the kernel's call is a `jax.jit` of its own: a trace under another patch is not this one's
+    return attend
 
 
 def splash_with(mask, heads, sizes):
@@ -153,14 +169,29 @@ if os.environ.get("WINDOW") in ("1", "4096"):
     length, heads = wq[2], wq[1]
     taken = lambda q, k, v: ma.window_attention(q, k, v, window, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
     causal = lambda q, k, v: ma.causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
-    plan = ma.window_plan(length, heads, window, DRY)
-    report("window", q=wq, kv=wkv, window=window, taken_block=plan.block, taken_fused_backward=plan.fused_backward,
+    plan = ma.window_plan(length, heads, window, DRY, (wq[-1], wkv[-1]))
+    report("window", q=wq, kv=wkv, window=window, taken_block=plan.block, taken_backward=plan.backward,
            window_ms=try_ms(taken, *wqkv), causal_ms=try_ms(causal, *wqkv),
            pairs_allowed_over_causal=ma.window_pairs(length, window) / (length * (length + 1) / 2))
     if wide:   # the op's own call at each block that could be taken: dq a kernel of its own, `_KV_COMPUTE` keys a step
         for b in (128, 256) if DRY else (512, 1024):
             at_block = lambda q, k, v, b=b: ma.attention_under(plan._replace(block=b), q, k, v, q.shape[-1] ** -0.5)  # noqa: E731
             report("window_as_the_op_calls_it", q=wq, window=window, grid_block=b, ms=try_ms(at_block, *wqkv))
+    # the one backward kernel of our own, dq summed in VMEM, its grid the band's blocks alone, a block's keys one pass: by block, and under
+    # grouped key/value heads with dk and dv summed over the group in VMEM (`kv_rows`) or outside in float32
+    for b in (128,) if DRY else (512, 1024) if wide else (256, 512, 1024):
+        for kv_rows in (True, False):
+            report("window_fused_dq_on_the_chip", q=wq, window=window, grid_block=b, dk_dv_summed_in_vmem=kv_rows,
+                   steps_a_head=int(ma._steps(plan._replace(block=b)).q_block.size),
+                   ms=try_ms(onchip(plan._replace(block=b), kv_rows), *wqkv))
+    if wide:   # the full layer's: the causal rule over the same operands, ours against the stock fused backward
+        full = ma.causal_plan(length, heads, DRY, (wq[-1], wkv[-1]))
+        for kv_rows in (True, False):
+            report("causal_fused_dq_on_the_chip", q=wq, grid_block=full.block, dk_dv_summed_in_vmem=kv_rows,
+                   ms=try_ms(onchip(full, kv_rows), *wqkv))
+        if not DRY:
+            report("causal_splash", q=wq, cut_blocks="computed", grid_block=1024, block_kv_compute=512, fused_backward=True,
+                   ms=try_ms(splash_with(mask_lib.CausalMask((length, length)), heads, sizes_of(1024, 1024, 512, fused=True)), *wqkv))
     local = mask_lib.LocalMask((length, length), (window - 1, 0), 0)
     for b, compute, fused in ((128, 128, False), (128, 128, True)) if DRY else (
             (512, 512, False), (1024, 512, False), (1024, 1024, False), (2048, 512, False),
@@ -189,20 +220,29 @@ if os.environ.get("WINDOW") in ("1", "4096"):
            apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))),
            finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in results[1]))
     sys.exit(0)
-for cq, ckv in (((1, 4, 256, 128), (1, 4, 256, 128)), ((1, 8, 256, 64), (1, 2, 256, 64))) if DRY else (
-        ((4, 16, 4096, 128), (4, 16, 4096, 128)), ((2, 32, 8192, 64), (2, 8, 8192, 64))):
-    cqkv = operands(cq, ckv, seed=1)
+for cq, ckv, v_width in (((1, 4, 256, 128), (1, 4, 256, 128), 128), ((1, 8, 256, 64), (1, 2, 256, 64), 64),
+                         ((1, 2, 256, 192), (1, 2, 256, 192), 128)) if DRY else (
+        ((4, 16, 4096, 128), (4, 16, 4096, 128), 128), ((2, 32, 8192, 64), (2, 8, 8192, 64), 64),
+        ((1, 32, 16384, 192), (1, 32, 16384, 192), 128)):          # OLMoE's, LFM2's, Kanana-2's latent attention (192 | 128)
+    cqkv = operands(cq, ckv, seed=1, v_width=v_width)
     length, heads = cq[2], cq[1]
     taken = lambda q, k, v: ma.causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
-    report("causal", q=cq, kv=ckv, flash_ms=None if DRY else try_ms(flash_causal, *cqkv), block_causal_ms=try_ms(taken, *cqkv))
-    if not DRY:
+    one_width = v_width == cq[-1]      # the flash kernel was never given two widths
+    report("causal", q=cq, kv=ckv, v_width=v_width, flash_ms=try_ms(flash_causal, *cqkv) if one_width and not DRY else None,
+           block_causal_ms=try_ms(taken, *cqkv), taken_backward=ma.causal_plan(length, heads, DRY, (cq[-1], v_width)).backward)
+    if one_width and not DRY:
         results = [(jax.jit(f)(*cqkv), *gradients(f)(*cqkv)) for f in (flash_causal, taken)]
         report("causal_flash_against_block_causal", q=cq,
                apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))))
+    for b in (128,) if DRY else (1024, 512):
+        for kv_rows in (True, False) if cq[1] != ckv[1] else (None,):
+            plan = ma.causal_plan(length, heads, DRY, (cq[-1], v_width))._replace(block=b)
+            report("causal_fused_dq_on_the_chip", q=cq, grid_block=b, dk_dv_summed_in_vmem=kv_rows,
+                   steps_a_head=int(ma._steps(plan).q_block.size), ms=try_ms(onchip(plan, kv_rows), *cqkv))
     for cut, mask in (("stored", StoredCausal(length)), ("computed", mask_lib.CausalMask((length, length)))):
         for b, compute, fused in ((128, 128, False),) if DRY else (
                 (1024, 1024, False), (1024, 512, False), (512, 512, False), (2048, 512, False), (1024, 1024, True),
-                (1024, 512, True), (512, 512, True)):
+                (1024, 512, True), (512, 512, True)) if one_width else ((1024, 512, False), (1024, 512, True)):
             report("causal_splash", q=cq, cut_blocks=cut, grid_block=b, block_kv_compute=compute, fused_backward=fused,
                    ms=try_ms(splash_with(mask, heads, sizes_of(b, b, compute, fused=fused)), *cqkv))
 if os.environ.get("CAUSAL") == "1":
