@@ -16,6 +16,7 @@ as one program.  The gradients themselves are a fusion boundary
 from __future__ import annotations
 
 import contextlib
+import re
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Set
@@ -419,7 +420,15 @@ def count_layer_forms(ops: List[Operator]) -> None:
     router that reads its layer's input ahead of the attention:
     `layers.moe(router_input=)`), and `lowering.attention_layers_without_positions`,
     the `fused_attention` ops whose queries no `rotary_embedding` reaches
-    between their projection and the attention."""
+    between their projection and the attention;
+    `lowering.gated_attention_layers`, the attention layers whose output passes a
+    sigmoid gate a head (`multi_head_attention(head_gate=)`: the `sigmoid` ops in
+    a scope `attention_gate`); `lowering.rotary_tables`, the DISTINCT rotary
+    descriptions among the `rotary_embedding` ops (theta, how much of a head
+    turns, the pairing, a table of frequencies, a factor: 2 where stretched
+    half-rotary layers stand beside plain ones); and
+    `lowering.query_heads_by_layer.<i>`, the query heads of the i-th
+    `fused_attention` op, a counter a layer so that the list can be read."""
     if not any(op.type == "backward" for op in ops):
         return
     made_by = {name: op for op in ops for name in op.output_arg_names}
@@ -437,6 +446,16 @@ def count_layer_forms(ops: List[Operator]) -> None:
     _MON.counter("lowering.routers_before_attention").inc(ahead)
     _MON.counter("lowering.attention_layers_without_positions").inc(
         sum(not rotated(ops[a].input("Q")[0]) for a in attentions))
+    _MON.counter("lowering.gated_attention_layers").inc(
+        sum(op.type == "sigmoid" and bool(re.search(r"(^|/)attention_gate(_\d+)?(/|$)", op.attrs.get("op_namescope") or ""))
+            for op in ops))
+    _MON.counter("lowering.rotary_tables").inc(len({
+        tuple(op.attr(n, None) for n in ("theta", "interleave", "rotary_dim", "inv_freq", "scale"))
+        for op in ops if op.type == "rotary_embedding"}))
+    for layer, a in enumerate(attentions):
+        queries = ops[a].block.var(ops[a].input("Q")[0]).shape
+        _MON.counter(f"lowering.query_heads_by_layer.{layer}").inc(
+            int(queries[2 if ops[a].attr("layout", "bhld") == "blhd" else 1]))
 
 
 def plan_latent_operands(ctx: LoweringContext, ops: List[Operator]) -> None:

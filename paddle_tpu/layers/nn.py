@@ -25,7 +25,10 @@ def _keep_lod(src, out):
     return out
 
 
-def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None, act=None, name=None):
+def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None, act=None, name=None, precision=None):
+    """`precision` ("highest"): the product's precision where the chip's default,
+    one pass over bf16-rounded operands, is not enough for a float32 input (a
+    gate or a router read in float32); None is no attribute of the op."""
     helper = LayerHelper("fc", name=name, act=act)
     inputs = input if isinstance(input, (list, tuple)) else [input]
     mul_results = []
@@ -38,7 +41,7 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None, act=Non
             "mul",
             inputs={"X": [inp.name], "Y": [w.name]},
             outputs={"Out": [out.name]},
-            attrs={"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1},
+            attrs={"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1, **({"precision": precision} if precision else {})},
         )
         mul_results.append(out)
     if len(mul_results) == 1:
@@ -881,15 +884,30 @@ def exit_loss(ce, gate_logit, beta=0.0, name=None):
     return loss, p
 
 
-def rotary_embedding(x, positions, theta=10000.0, name=None, layout="bhld", interleave=False):
+def rotary_embedding(x, positions, theta=10000.0, name=None, layout="bhld", interleave=False, rotary_dim=None,
+                     inv_freq=None, scale=1.0):
     """Rotary position embedding of (B, H, L, dh) queries or keys, or with
     `layout="blhd"` of (B, L, H, dh) as a projection's reshape leaves them;
     `positions` is the (B, L) integer position of every token, fed like the
     ids.  Feature i turns with feature i + dh/2 (the rotate-half convention),
-    or with `interleave` feature 2i with 2i + 1.  The angles are float32.  A
-    default is no attribute of the op: the programs that stood keep their text."""
+    or with `interleave` feature 2i with 2i + 1.  The angles are float32.
+
+    `rotary_dim` = r < dh turns the LEADING r features of a head alone (i with
+    i + r/2) and passes the other dh - r as they are (a partial rotary
+    embedding); `inv_freq`, r/2 frequencies, takes the place of theta^(-2i/r)
+    (a stretched table: YaRN's blend, `models.transformer.yarn_frequencies`);
+    `scale` multiplies cos and sin (YaRN's attention factor: the turned
+    features grow by it, the passed ones do not).  A default is no attribute of
+    the op: the programs that stood keep their text."""
     if layout not in ("bhld", "blhd"):
         raise ValueError(f"rotary_embedding: layout={layout!r}; \"bhld\" (B, H, L, dh) or \"blhd\" (B, L, H, dh)")
+    width = int(x.shape[-1])
+    turned = width if rotary_dim is None else int(rotary_dim)
+    if not 0 < turned <= width or turned % 2:
+        raise ValueError(f"rotary_embedding: rotary_dim={rotary_dim} of a head {width} wide; an even number of leading "
+                         "features, at most the head")
+    if inv_freq is not None and len(inv_freq) != turned // 2:
+        raise ValueError(f"rotary_embedding: inv_freq holds {len(inv_freq)} frequencies for {turned // 2} pairs")
     helper = LayerHelper("rotary_embedding", name=name)
     out = _out(helper, x.dtype, shape=x.shape)
     attrs = {"theta": float(theta)}
@@ -897,6 +915,12 @@ def rotary_embedding(x, positions, theta=10000.0, name=None, layout="bhld", inte
         attrs["layout"] = layout
     if interleave:
         attrs["interleave"] = True
+    if turned != width:
+        attrs["rotary_dim"] = turned
+    if inv_freq is not None:
+        attrs["inv_freq"] = tuple(float(f) for f in inv_freq)
+    if float(scale) != 1.0:
+        attrs["scale"] = float(scale)
     helper.append_op(
         "rotary_embedding", inputs={"X": [x.name], "Positions": [positions.name]},
         outputs={"Out": [out.name]}, attrs=attrs)

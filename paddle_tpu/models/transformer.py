@@ -50,7 +50,8 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                          use_fused_attention=False, proj_bias=True,
                          qk_norm_eps=None, positions=None, rope_theta=10000.0,
                          n_kv_heads=None, head_dim=None, qk_norm_per_head=False,
-                         mask=None, mask_block=None, keep=None, kept_kv=None, sparse_index=None, index_losses=None):
+                         mask=None, mask_block=None, keep=None, kept_kv=None, sparse_index=None, index_losses=None,
+                         head_gate=False):
     """Self- or cross-attention over [b, T, d] (T may be dynamic: head
     split/merge uses fluid's 0-copy-dim reshape).  `kv` switches to
     cross-attention (keys/values from another sequence); `bias` is an
@@ -61,7 +62,19 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     queries and of the keys (before the heads are split, as OLMoE has it) or,
     with `qk_norm_per_head`, over each head's features with one gain of the
     head's width shared by the heads (after the split, as Qwen3 has it);
-    `positions` ([b, T] integers) rotates queries and keys (`rope_theta`).
+    `positions` ([b, T] integers) rotates queries and keys; `rope_theta` is
+    the base, or the embedding's whole description, dict(theta=, rotary_dim=,
+    inv_freq=, scale=) as `layers.rotary_embedding` takes them: how many of a
+    head's leading features turn, a table of frequencies where they are not
+    theta's own (`yarn_frequencies`), a factor on cos and sin.
+
+    `head_gate` gates each head's output before the out projection (the
+    head-wise gated attention of Qiu et al. 2025, arXiv:2505.06708): g =
+    sigmoid(x Wg), Wg [d_model, n_heads], ONE number a head a token, read from
+    the layer's own (normed) input in float32; the attention's output, in the
+    layout the attention left it, is multiplied by it in float32 and rounded
+    once.  The projection, the sigmoid and the product stand in the scope
+    `attention_gate`.
 
     `n_kv_heads` (a divisor of `n_heads`) gives keys and values fewer heads
     than queries, and `head_dim` a head width other than d_model / n_heads:
@@ -140,9 +153,10 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
                             param_attr=_attr_ones(f"{prefix}.q_norm.w"))
         k = layers.rms_norm(k, begin_norm_axis=3, epsilon=qk_norm_eps,
                             param_attr=_attr_ones(f"{prefix}.k_norm.w"))
+    rope = dict(rope_theta) if isinstance(rope_theta, dict) else {"theta": rope_theta}
     if positions is not None:
-        q = layers.rotary_embedding(q, positions, theta=rope_theta)
-        k = layers.rotary_embedding(k, positions, theta=rope_theta)
+        q = layers.rotary_embedding(q, positions, **rope)
+        k = layers.rotary_embedding(k, positions, **rope)
     if (mask is not None or n_kv_heads != n_heads) and not use_fused_attention:
         raise ValueError("mask= and n_kv_heads= (a structured mask, grouped key/value heads) are fused_attention's: "
                          "use_fused_attention=True")
@@ -152,6 +166,10 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
             raise ValueError("sparse_index=: the chosen keys are fused_attention's (use_fused_attention=True) over "
                              "heads-major operands of the layer's own sequence (a per-head norm or positions=; no kv=, "
                              "kept_kv= or mask=)")
+        if set(rope) != {"theta"}:
+            raise ValueError("sparse_index=: the indexer's heads have a width of their own and turn whole, by theta alone "
+                             "(rope_theta= a number)")
+        rope_theta = rope["theta"]
         index_heads, index_dim = sparse_index["heads"], sparse_index["head_dim"]
         with name_scope("sparse_index"):
             detached = layers.stop_gradient(x)      # the indexer trains on its own loss: nothing of it reaches x
@@ -201,10 +219,52 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
             attn = layers.dropout(attn, dropout_prob, is_test=is_test,
                                   dropout_implementation="upscale_in_train")
         ctx = layers.matmul(attn, v)  # (B, H, L, dh)
+    if head_gate:   # in the layout the attention left: the one transpose that follows feeds the out projection as before
+        with name_scope("attention_gate"):
+            ctx = _head_gate(x, ctx, n_heads, f"{prefix}.gate.w", heads_major)
     if heads_major:
         ctx = layers.transpose(ctx, [0, 2, 1, 3])
     ctx = layers.reshape(ctx, [0, 0, n_heads * d_head])
     return project(ctx, "out")
+
+
+def _head_gate(x, ctx, n_heads, name, heads_major):
+    """ctx, (B, L, H, dh) or `heads_major` (B, H, L, dh), times sigmoid(x Wg)
+    (B, L, H), one number a head a token: the projection reads x in float32 at
+    the highest precision, as a router's does (the chip's default rounds the
+    float32 matrix to bf16: 4e-3 of a small gate, a bf16 gate's own error), the
+    sigmoid and the product are float32 and the result is rounded once, to
+    ctx's dtype."""
+    gate = layers.sigmoid(layers.fc(layers.cast(x, "float32"), n_heads, num_flatten_dims=2, param_attr=_attr(name),
+                                    bias_attr=False, precision="highest"))
+    gate = (layers.reshape(layers.transpose(gate, [0, 2, 1]), [0, n_heads, 0, 1]) if heads_major
+            else layers.reshape(gate, [0, 0, n_heads, 1]))
+    return layers.cast(layers.elementwise_mul(layers.cast(ctx, "float32"), gate), ctx.dtype)
+
+
+def yarn_frequencies(theta, rotary_dim, factor, original_max_position_embeddings, beta_fast=32.0, beta_slow=1.0):
+    """The `rotary_dim / 2` frequencies of a rotary embedding stretched by YaRN
+    (Peng et al. 2023, arXiv:2309.00071), as a tuple for
+    `layers.rotary_embedding(inv_freq=)`: pair i keeps theta's own frequency
+    theta^(-2i / rotary_dim) where it turns more than `beta_fast` times over the
+    original context, takes a `factor`-th of it where it turns fewer than
+    `beta_slow` times, and a linear blend between:
+
+        f_i = (1 - r_i) theta^(-2i/r) + r_i theta^(-2i/r) / factor,   r_i = clip((i - low) / (high - low), 0, 1),
+        low = floor(d(beta_fast)), high = ceil(d(beta_slow)),   d(n) = r ln(original / (2 pi n)) / (2 ln theta),
+
+    both bounds held within [0, r - 1], as the public implementation of
+    `rope_type` "yarn" holds them.  The factor on cos and sin that goes with it
+    (0.1 ln factor + 1 by YaRN's rule) is `rotary_embedding`'s `scale`."""
+    pairs = rotary_dim // 2
+    own = float(theta) ** (-np.arange(pairs, dtype=np.float64) / pairs)
+
+    def turns(n):   # the pair that makes n turns over the original context
+        return rotary_dim * np.log(original_max_position_embeddings / (n * 2 * np.pi)) / (2 * np.log(float(theta)))
+
+    low, high = max(np.floor(turns(beta_fast)), 0), min(np.ceil(turns(beta_slow)), rotary_dim - 1)
+    ramp = np.clip((np.arange(pairs, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return tuple(float(f) for f in own * (1.0 - ramp) + own / factor * ramp)
 
 
 def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_dim, norm_eps=1e-5,
@@ -423,7 +483,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                   qk_norm=False, positions=None, rope_theta=10000.0, moe=None, aux_losses=None,
                   n_kv_heads=None, head_dim=None, attention_mask=None,
                   operator="attention", conv_kernel=3, ffn="gelu", post_norm=False, operator_args=None,
-                  unit_norms=False, keep=None, kept=None, sparse_index=None, index_losses=None):
+                  unit_norms=False, keep=None, kept=None, sparse_index=None, index_losses=None, head_gate=False):
     """One transformer layer: a sequence operator (attention) and a
     feed-forward part, each with a residual connection and a norm.
 
@@ -433,8 +493,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     y = h + ffn(norm(h))); `post_norm` beside it norms each part's OUTPUT too,
     before the residual sum (the sandwich: h = x + norm(attn(norm(x))), gains
     `post_ln1` / `post_ln2`); `qk_norm` (True or "width": over the projected
-    width; "head": over each head), `positions`, `proj_bias`, `n_kv_heads`,
-    `head_dim` and `attention_mask` = (kind, block length) go to the
+    width; "head": over each head), `positions`, `rope_theta` (a number or the
+    rotary embedding's description), `proj_bias`, `n_kv_heads`, `head_dim`,
+    `head_gate` and `attention_mask` = (kind, block length) go to the
     attention.  `operator="conv"` puts a gated short convolution of
     `conv_kernel` taps (`layers.short_conv`) where the attention stands,
     `operator="kda"` a Kimi-Delta-Attention operator (`kimi_delta_attention`;
@@ -575,7 +636,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                                             mask=attention_mask and attention_mask[0],
                                             mask_block=attention_mask and attention_mask[1],
                                             keep=keep, kept_kv=kept["kv"] if crossing else None,
-                                            sparse_index=sparse_index, index_losses=index_losses)
+                                            sparse_index=sparse_index, index_losses=index_losses,
+                                            head_gate=head_gate)
     if post_norm:
         attn_out = normed(attn_out, "post_ln1")
     x = layers.elementwise_add(x, attn_out)
@@ -702,6 +764,7 @@ def build_causal_lm(
     sparse_index=None,
     mamba2=None,
     expert_form=None,
+    attention_gate=False,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -842,6 +905,22 @@ def build_causal_lm(
     beside "sliding_attention" layers whose queries and keys carry the rotary
     embedding.  `pos_ids` is a feed where any layer rotates.
 
+    A decoder whose attention layers differ by KIND in more than their mask
+    (Laguna: poolside/Laguna-XS.2) is arguments too.  `n_heads` is a number or
+    a statement a layer kind, a dict from a layer's kind to its count of QUERY
+    heads (a kind it does not name takes the default, 16): 48 "full_attention"
+    heads beside 64 "sliding_attention" heads on the same `n_kv_heads` key/value
+    heads of `head_dim`, so the two kinds' q, out and gate matrices have widths
+    of their own.  `rope_theta` is a number or a statement a layer kind as well:
+    a dict from a kind to the base, or to the rotary embedding's whole
+    description dict(theta=, rotary_dim=, inv_freq=, scale=)
+    (`layers.rotary_embedding`; a kind it does not name takes the default,
+    10000): a half-rotary embedding stretched by YaRN (`yarn_frequencies`, its
+    attention factor as `scale`) on the full layers beside a plain whole one on
+    the windows.  `attention_gate` gives every attention layer a sigmoid gate a
+    head on its output (`multi_head_attention(head_gate=)`, the scope
+    `attention_gate`).
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -918,6 +997,12 @@ def build_causal_lm(
     else:
         rotates = [bool(rotary)] * len(kinds)
     rotary = any(rotates)
+    # a statement a layer kind (a dict from the kind) or one value for the stack; an unnamed kind takes the default
+    heads = [n_heads.get(kind, 16) if isinstance(n_heads, dict) else n_heads for kind in kinds]
+    ropes = [rope_theta.get(kind, 10000.0) if isinstance(rope_theta, dict) else rope_theta for kind in kinds]
+    if any(isinstance(rope, dict) and kind == "latent_attention" for rope, kind in zip(ropes, kinds)):
+        raise ValueError("build_causal_lm: a latent_attention layer's decoupled rotary embedding turns by theta alone "
+                         "(rope_theta= a number for that kind)")
     if latent and latent.get("rope") and not all(r for r, kind in zip(rotates, kinds) if kind == "latent_attention"):
         raise ValueError("build_causal_lm: latent=dict(rope=True) rotates by pos_ids, which rotary=False leaves out of "
                          "the feeds")
@@ -951,12 +1036,12 @@ def build_causal_lm(
                 has_ffn = not (one_part and operators[kind] is not None)   # a layer of one part with an operator has none
                 pending = []        # the calls that make a sparse-attention layer's alignment term, AFTER its segment
                 with recompute_scope() if recompute_layers else contextlib.nullcontext():
-                    x = encoder_layer(x, seq_len, d_model, n_heads, dense_width if dense else expert_width,
+                    x = encoder_layer(x, seq_len, d_model, heads[i], dense_width if dense else expert_width,
                                       f"lm.l{i}",
                                       dropout_prob=0.0, causal=(window or attention_mask) is None,
                                       use_fused_attention=use_fused_attention,
                                       norm=norm, unit_norms=True, norm_eps=norm_eps, pre_norm=True, proj_bias=proj_bias,
-                                      qk_norm=qk_norm, positions=pos_ids if rotates[i] else None, rope_theta=rope_theta,
+                                      qk_norm=qk_norm, positions=pos_ids if rotates[i] else None, rope_theta=ropes[i],
                                       moe=experts if has_ffn and not dense else None, ffn="gated_silu" if has_ffn else None,
                                       aux_losses=aux, n_kv_heads=n_kv_heads, head_dim=head_dim,
                                       attention_mask=window or attention_mask,
@@ -964,7 +1049,8 @@ def build_causal_lm(
                                       conv_kernel=conv_kernel, post_norm=post_norm,
                                       keep=kept if i in (memory_layer, kv_layer) else None, kept=kept,
                                       sparse_index=sparse_index if kind == "sparse_attention" else None,
-                                      index_losses=pending)
+                                      index_losses=pending,
+                                      head_gate=attention_gate and operators[kind] in ("attention", "cross_attention"))
                 index_terms.extend(make() for make in pending)
             return x
 

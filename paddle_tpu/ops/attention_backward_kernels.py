@@ -51,11 +51,15 @@ block are `masked_attention._BLOCKS`' and `_WINDOW_BLOCKS`'):
   (2, 32 on 8, 8192, 64), causal          29.46   32.06         38.70
   (4, 16, 4096, 128), causal               9.34    9.76         11.96
   (1, 40 on 20, 8192, 64), window 512      7.20   11.71          8.42
+  (1, 64 on 8, 16384, 128), window 512    18.39   50.24         22.61      (my chip run, PR 65, call 1)
+  (1, 48 on 8, 16384, 128), causal        74.06   82.95                    (groups of SIX: `rem(head, 6)`)
 
 A group's dk and dv summed in VMEM (`kv_rows`) against a query head's written in
 float32 and summed by XLA: 24.23 | 25.04 at 28 on 4 x 16384 (0.94 GB a layer
 written and read back), 29.74 | 30.56 at 32 on 8 x 8192, 7.20 | 7.35 at 40 on
-20: in VMEM wherever the rows fit.  The call is a `jax.jit` of its own so that a
+20, 18.39 | 18.33 at 64 on 8 x 16384 under a window of 512 and 74.06 | 74.03 at
+48 on 8 under the causal rule (PR 65: level, where a band 512 wide or groups of six
+leave the sum outside little to move): in VMEM wherever the rows fit.  The call is a `jax.jit` of its own so that a
 model's layers share one lowering (`setup_s` is end to end).
 """
 from __future__ import annotations

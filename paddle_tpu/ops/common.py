@@ -94,13 +94,20 @@ def kept_residuals(ctx, op):
     return residuals_name(op) if ctx.keep and residuals_name(op) in ctx.keep else None
 
 
-def rotary_angles(pos, half: int, theta: float, by_position: bool):
-    """(cos, sin) float32 of `half` angles a position, pos . theta^(-i / half),
-    over (B, L, 1, half) where the heads follow the positions (`by_position`)
-    or (B, 1, L, half): at position 16383 a bf16 angle is off by whole turns."""
-    inv_freq = theta ** (-np.arange(half, dtype=np.float32) / half)
+def rotary_angles(pos, half: int, theta: float, by_position: bool, inv_freq=None, scale: float = 1.0):
+    """(cos, sin) float32 of `half` angles a position, pos . theta^(-i / half)
+    (or pos . `inv_freq`[i], a table of `half` frequencies), each times
+    `scale`, over (B, L, 1, half) where the heads follow the positions
+    (`by_position`) or (B, 1, L, half): at position 16383 a bf16 angle is off
+    by whole turns."""
+    if inv_freq is None:
+        inv_freq = theta ** (-np.arange(half, dtype=np.float32) / half)
+    else:
+        inv_freq = np.asarray(inv_freq, np.float32)
     pos = pos.astype(jnp.float32)
     angle = (pos[:, :, None, None] if by_position else pos[:, None, :, None]) * inv_freq
+    if scale != 1.0:
+        return jnp.cos(angle) * np.float32(scale), jnp.sin(angle) * np.float32(scale)
     return jnp.cos(angle), jnp.sin(angle)
 
 

@@ -343,6 +343,23 @@ def causal_plan(length: int, heads: int, interpret: bool = False, widths=(128, 1
 #:
 #: the same block, 14% under the pair (`window_attention` as the op calls it: 6.94).
 #:
+#: The same window on 128-wide heads in groups of eight at twice the keys: TPU v5e, (1, 64 on 8, 16384, 128) bf16, window 512
+#: (Laguna-XS.2's window layer), forward + backward of a layer alone, ms (my chip run, PR 65, call 1; `WINDOW=512x128
+#: python3 tools/chip_block_attention.py`; the causal rule over the same operands 98.49, the band 6.15% of its pairs; dk and
+#: dv of a group summed in VMEM | outside in float32):
+#:
+#:   block (keys a pass)           256 (256)        512 (512)        1024 (512)
+#:   pairs visited / allowed       1.52             2.03             4.06
+#:   fused, dq on the chip         26.13 | 26.13    18.39 | 18.33    26.69 | 26.67
+#:   stock: dq and dkv apart       31.13            22.61            33.63
+#:   stock: fused backward         -                50.24
+#:   as the op calls it            26.39            18.23            26.70
+#:
+#: so the block of the window's own length again, which `window_block` already took: 63 steps a head where 256 keys a step
+#: are 189 and lose 42% to the grid, and 1024 compute twice the masked pairs; a group's eight heads' dk and dv fit the VMEM
+#: beside a head's dq (`kv_rows_fit`) and cost the same summed outside.  Against dense float32 at (1, 16 on 2, 2048, 128):
+#: 3.4e-3, 6.9e-3, 3.8e-3, 2.2e-3.
+#:
 #: A window LONGER than the largest block takes that block, 1024.  TPU v5e, (1, 28 on 4, 16384, 128) bf16, window 4096
 #: (SmallThinker's window layer), forward + backward of a layer alone, ms (my chip run, PR 63; `WINDOW=4096 python3
 #: tools/chip_block_attention.py`; the causal rule over the same operands 49.59, the band 43.7% of its pairs):
@@ -439,18 +456,21 @@ def _stock_options(plan: Plan) -> dict:
                 mask_function=computed, interpret=plan.interpret)
 
 
-def _far_forward(q, k, v, plan: Plan):
+def _far_forward(q, k, v, plan: Plan, residuals: bool = True):
     """The kernels' term: output in the operands' dtype and float32
     log-sum-exp (B, Hq, 2L).  A row the rule leaves no far key (the first
     noised block's) reads the mean of a block's values, finite, under a
-    log-sum-exp of `mask_value` -2.38e38: weight exactly 0 in the join."""
+    log-sum-exp of `mask_value` -2.38e38: weight exactly 0 in the join.
+    `residuals` False: the output alone, None for the log-sum-exp (the kernel
+    writes it 128 lanes wide in float32, twice the output's bytes at 128-wide
+    heads: a forward that nothing differentiates and nothing joins leaves it)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     info = _block_map(plan, 0)
-    out, (lse,) = jax.vmap(lambda q, k, v: splash._splash_attention_forward(
-        info, q, k, v, None, None, block_sizes=plan.sizes, residual_checkpoint_name=None, save_residuals=True,
+    made = jax.vmap(lambda q, k, v: splash._splash_attention_forward(
+        info, q, k, v, None, None, block_sizes=plan.sizes, residual_checkpoint_name=None, save_residuals=residuals,
         **_stock_options(plan)))(q, k, v)
-    return out, lse
+    return (made[0], made[1][0]) if residuals else (made, None)
 
 
 def _far_backward(q, k, v, lse, do, di, plan: Plan):
@@ -587,15 +607,15 @@ def _own_block_backward(q, k, v, lse, do, di, dq, plan: Plan):
     )(q, k, v, do, lse[:, :, None], di[:, :, None], dq)
 
 
-def _forward(q, k, v, plan: Plan):
+def _forward(q, k, v, plan: Plan, residuals: bool = True):
     seq = plan.first_key
-    out, lse = _far_forward(q, k[:, :, seq:], v[:, :, seq:], plan)
+    out, lse = _far_forward(q, k[:, :, seq:], v[:, :, seq:], plan, residuals or bool(seq))   # the join reads the log-sum-exp
     return _join_own_block(q, k, v, out, lse, plan) if seq else (out, lse)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _attention(q, k, v, plan: Plan, keep=None):
-    return _forward(q, k, v, plan)[0]
+    return _forward(q, k, v, plan, residuals=False)[0]     # nothing differentiates this call: a `for_test` clone's
 
 
 def _attention_fwd(q, k, v, plan: Plan, keep):

@@ -177,7 +177,8 @@ def _clip_by_norm(ctx, op, ins):
 @register_op("mul")
 def _mul(ctx, op, ins):
     """reference operators/mul_op.cc: flatten x to 2-D at x_num_col_dims,
-    y at y_num_col_dims, then GEMM."""
+    y at y_num_col_dims, then GEMM.  The attribute `precision` ("highest") asks
+    more of a float32 product than the chip's one pass over bf16 operands."""
     x = first(ins, "X")
     y = first(ins, "Y")
     xd = op.attr("x_num_col_dims", 1)
@@ -188,7 +189,7 @@ def _mul(ctx, op, ins):
     xs, ys = x.shape, y.shape
     x2 = x if x.ndim == 2 else jnp.reshape(x, (int(_np.prod(xs[:xd])), int(_np.prod(xs[xd:]))))
     y2 = y if y.ndim == 2 else jnp.reshape(y, (int(_np.prod(ys[:yd])), int(_np.prod(ys[yd:]))))
-    out = jnp.matmul(x2, y2)
+    out = jnp.matmul(x2, y2, precision=op.attr("precision", None))   # None: the platform's default, as ever
     out_shape = xs[:xd] + ys[yd:]
     return {"Out": jnp.reshape(out, out_shape)}
 

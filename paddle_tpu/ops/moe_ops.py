@@ -89,15 +89,22 @@ def _rotary_embedding(ctx, op, ins):
     sequences bring their own.  Feature i turns with feature i + dh/2
     (rotate-half), or with the attribute `interleave` feature 2i with 2i + 1
     (the pairing of the original rotary embedding, which DeepSeek-V3's family
-    keeps).  Angles, sines and the rotation are float32 whatever X's dtype: at
+    keeps).  With `rotary_dim` = r the leading r features turn among
+    themselves and the rest pass, bit for bit; `inv_freq` (r/2 numbers) are the
+    frequencies in place of theta's own; `scale` multiplies cos and sin.
+    Angles, sines and the rotation are float32 whatever X's dtype: at
     position 16383 a bf16 angle is off by whole turns."""
     x = first(ins, "X")
     pos = first(ins, "Positions")
+    turned, passed = op.attr("rotary_dim", x.shape[-1]), None
+    if turned != x.shape[-1]:
+        x, passed = x[..., :turned], x[..., turned:]
     half = x.shape[-1] // 2
     by_position = op.attr("layout", "bhld") == "blhd"
     if by_position:
         _MON.counter("lowering.latent_rotary_ops").inc()
-    cos, sin = rotary_angles(pos, half, op.attr("theta", 10000.0), by_position)  # dh/2 angles a position
+    cos, sin = rotary_angles(pos, half, op.attr("theta", 10000.0), by_position,   # dh/2 angles a position
+                             op.attr("inv_freq", None), op.attr("scale", 1.0))
     if op.attr("interleave", False):
         # A pair's other member, signed, (-x[2i+1], x[2i]), as a product with a constant matrix of 0 and +-1 (exact in
         # any dtype: one term a sum) and not as strided slices: those leave arrays whose last axis is 2, which the chip
@@ -111,7 +118,8 @@ def _rotary_embedding(ctx, op, ins):
     else:
         x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
         out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return {"Out": out.astype(x.dtype)}
+    out = out.astype(x.dtype)
+    return {"Out": out if passed is None else jnp.concatenate([out, passed], axis=-1)}
 
 
 def _router_logits_name(op):
@@ -1015,6 +1023,12 @@ def _infer_rotary_embedding(ctx):
     at = 1 if layout == "blhd" else 2    # where X holds the positions
     if len(xs) != 4 or xs[-1] % 2:
         ctx.fail(f"X must be {'(B, L, H, dh)' if at == 1 else '(B, H, L, dh)'} with an even dh, got {xs}")
+    turned = ctx.op.attr("rotary_dim", xs[-1])
+    if not 0 < turned <= xs[-1] or turned % 2:
+        ctx.fail(f"rotary_dim must be an even number of leading features, at most dh = {xs[-1]}, got {turned}")
+    table = ctx.op.attr("inv_freq", None)
+    if table is not None and len(table) != turned // 2:
+        ctx.fail(f"inv_freq must hold {turned // 2} frequencies, one a pair, got {len(table)}")
     if ps is not None and (len(ps) != 2 or (ps[1] != xs[at] and _A.DYN not in (ps[1], xs[at]))):
         ctx.fail(f"Positions must be (B, L) with L = {xs[at]}, got {ps}")
     ctx.set_out("Out", xs, ctx.in_dtype("X"))

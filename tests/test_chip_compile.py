@@ -302,6 +302,13 @@ CASES = {
         _block_causal, [((1, 28, 16384, 128), BF16)] + [((1, 4, 16384, 128), BF16)] * 2, (0, 1, 2)),
     "block_causal_attention_kanana2_192_128": (
         _block_causal, [((1, 32, 16384, 192), BF16)] * 2 + [((1, 32, 16384, 128), BF16)], (0, 1, 2)),
+    # Laguna-XS.2's two attentions (PR 65): a window of 512 under 64 query heads on 8 key/value heads of 128 at 16384
+    # keys (groups of eight, a head's whole dq and a key/value head's dk and dv rows in VMEM for a band 512 wide), and
+    # the causal rule under 48 on 8: groups of SIX
+    "window_attention_laguna": (
+        _window, [((1, 64, 16384, 128), BF16)] + [((1, 8, 16384, 128), BF16)] * 2, (0, 1, 2)),
+    "block_causal_attention_laguna": (
+        _block_causal, [((1, 48, 16384, 128), BF16)] + [((1, 8, 16384, 128), BF16)] * 2, (0, 1, 2)),
 }
 
 
@@ -922,6 +929,38 @@ def test_smallthinkers_step_compiles_for_the_chip_with_its_routers_ahead_and_a_w
     attentions = sorted({int(i) for n in names for i in re.findall(r"/op(\d+):fused_attention", n)})
     assert len(routers) == len(attentions) == 4 and all(r < a for r, a in zip(routers, attentions))
     assert all(a < r for a, r in zip(attentions, routers[1:]))          # router, attention, router, attention, ...
+    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
+
+
+def test_lagunas_step_compiles_for_the_chip_with_its_gates_its_two_head_counts_and_a_window_of_512(host, monkeypatch):
+    """`laguna-xs.2.train-gated-swa-s16384`'s whole step at the published widths
+    and 16384 tokens, compiled for the described v5e with what `plan_kept`
+    chooses at the chip's memory limit: all 48 candidates of the five segments
+    (4.0 GB), planned over the 25% of the chip a cell has to fill and under
+    12.5 GB (11.77 with 16 experts held; 32 held planned 14.76 and its 8-row
+    clone did not fit beside the moments: the configuration's `deployment`); the
+    two full layers took the causal splash kernels at 48 heads on 8 and the
+    three window layers the window rule's at 64 on 8, blocks of 512, ONE
+    backward kernel a layer; five `attention_gate` scopes, each under its
+    layer's; and in the rematerialised computations no product and no attention
+    kernel is left (ISSUE 65)."""
+    compiled, counted = _kept_step("laguna", "laguna-xs.2", "train-gated-swa-s16384", host.devices, monkeypatch)
+    assert counted == {"segments": 5, "sparse_segments": 4, "kept_values": 48, "kept_bytes": 3997171712,
+                       "candidates_bytes": 3997171712}
+    peak = _planned_peak(compiled)
+    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 0.25 * 16.9e9 <= peak <= 12.5e9, peak
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_d" not in text and "flash_mha" not in text
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*/attention_dq_dk_dv["/]', text)) == 5
+    assert re.findall(r"bf16\[1,48,16384,128\]", text) and re.findall(r"bf16\[1,64,16384,128\]", text)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    window = {re.search(r"/(sliding_attention(?:_\d+)?)/", n).group(1) for n in names if "/window_attention/" in n}
+    assert len(window) == 3                        # the three window layers, each under its own numbered scope
+    assert any(re.search(r"/op\d+:fused_attention/block_sparse_attention/", n) for n in names)      # the full layers: no window scope
+    gates = {m.group(1) for n in names for m in [re.search(r"/((?:sliding_attention(?:_\d+)?/)?attention_gate(?:_\d+)?)/", n)] if m}
+    assert len(gates) == 5 and sum(g.startswith("sliding_attention") for g in gates) == 3, gates
     again = [name for name in _made_again(text) if "/cond/branch_" not in name]
     assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
 

@@ -51,6 +51,7 @@ REHEARSED = {
     "chip_held_experts": (),
     "chip_index_alignment": (),
     "chip_index_select": (),
+    "chip_laguna_controls": ("1", "gate_in_bf16", "window_of_17"),   # the sound run, a lowering wrapped by its name scope, a program built again
     "chip_lfm2_controls": ("1",),
     "chip_nemotron_controls": ("1", "scan_wrong_group", "relu_for_relu2"),   # the sound run, a fault in the program, one in the reference
     "chip_phi4flash_controls": ("1",),

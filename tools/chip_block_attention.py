@@ -26,7 +26,12 @@ backward (its dkv grid unshrunk), and at 2048 positions the taken form's output
 and gradients against dense float32.  WINDOW=4096 (PR 63) is the same at
 SmallThinker's window layer, (1, 28 on 4, 16384, 128) under a window of 4096:
 the op's own call at a block of 512 and of 1024 first, then the stock kernel by
-block, and the taken form against dense float32 at 6144 positions.
+block, and the taken form against dense float32 at 6144 positions.  WINDOW=512x128
+(PR 65) is the same at Laguna-XS.2's window layer, (1, 64 on 8, 16384, 128)
+under a window of 512 (128-wide heads in groups of eight, a band 1/32 of the
+sequence), and its full layer's causal rule at (1, 48 on 8, 16384, 128), groups
+of SIX: the one backward kernel with a group's dk and dv summed in VMEM against
+outside, beside the stock fused backward.
 
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
@@ -159,10 +164,13 @@ class StoredCausal(mask_lib.Mask):
         return hash((type(self).__name__, self.shape))
 
 
-if os.environ.get("WINDOW") in ("1", "4096"):
+if os.environ.get("WINDOW") in ("1", "4096", "512x128"):
     wide = os.environ["WINDOW"] == "4096"
+    narrow_band = os.environ["WINDOW"] == "512x128"     # Laguna-XS.2's: Phi's window on SmallThinker's kind of heads
     if wide:
         wq, wkv, window = ((1, 14, 1024, 128), (1, 2, 1024, 128), 256) if DRY else ((1, 28, 16384, 128), (1, 4, 16384, 128), 4096)
+    elif narrow_band:
+        wq, wkv, window = ((1, 16, 1024, 128), (1, 2, 1024, 128), 130) if DRY else ((1, 64, 16384, 128), (1, 8, 16384, 128), 512)
     else:
         wq, wkv, window = ((1, 4, 512, 64), (1, 2, 512, 64), 130) if DRY else ((1, 40, 8192, 64), (1, 20, 8192, 64), 512)
     wqkv = operands(wq, wkv, seed=2)
@@ -173,8 +181,8 @@ if os.environ.get("WINDOW") in ("1", "4096"):
     report("window", q=wq, kv=wkv, window=window, taken_block=plan.block, taken_backward=plan.backward,
            window_ms=try_ms(taken, *wqkv), causal_ms=try_ms(causal, *wqkv),
            pairs_allowed_over_causal=ma.window_pairs(length, window) / (length * (length + 1) / 2))
-    if wide:   # the op's own call at each block that could be taken: dq a kernel of its own, `_KV_COMPUTE` keys a step
-        for b in (128, 256) if DRY else (512, 1024):
+    if wide or narrow_band:   # the op's own call at each block that could be taken
+        for b in (128, 256) if DRY else (512, 1024) if wide else (256, 512, 1024):
             at_block = lambda q, k, v, b=b: ma.attention_under(plan._replace(block=b), q, k, v, q.shape[-1] ** -0.5)  # noqa: E731
             report("window_as_the_op_calls_it", q=wq, window=window, grid_block=b, ms=try_ms(at_block, *wqkv))
     # the one backward kernel of our own, dq summed in VMEM, its grid the band's blocks alone, a block's keys one pass: by block, and under
@@ -184,18 +192,24 @@ if os.environ.get("WINDOW") in ("1", "4096"):
             report("window_fused_dq_on_the_chip", q=wq, window=window, grid_block=b, dk_dv_summed_in_vmem=kv_rows,
                    steps_a_head=int(ma._steps(plan._replace(block=b)).q_block.size),
                    ms=try_ms(onchip(plan._replace(block=b), kv_rows), *wqkv))
-    if wide:   # the full layer's: the causal rule over the same operands, ours against the stock fused backward
-        full = ma.causal_plan(length, heads, DRY, (wq[-1], wkv[-1]))
+    if wide or narrow_band:   # the full layer's: the causal rule, ours against the stock fused backward
+        fq = wq if wide else ((1, 12, 1024, 128) if DRY else (1, 48, 16384, 128))     # Laguna's full layer: groups of SIX
+        fqkv = wqkv if wide else operands(fq, wkv, seed=4)
+        full = ma.causal_plan(length, fq[1], DRY, (wq[-1], wkv[-1]))
         for kv_rows in (True, False):
-            report("causal_fused_dq_on_the_chip", q=wq, grid_block=full.block, dk_dv_summed_in_vmem=kv_rows,
-                   ms=try_ms(onchip(full, kv_rows), *wqkv))
+            report("causal_fused_dq_on_the_chip", q=fq, grid_block=full.block, dk_dv_summed_in_vmem=kv_rows,
+                   steps_a_head=int(ma._steps(full).q_block.size), ms=try_ms(onchip(full, kv_rows), *fqkv))
+        if narrow_band:
+            report("causal_as_the_op_calls_it", q=fq, grid_block=full.block, taken_backward=full.backward,
+                   ms=try_ms(causal, *fqkv))
         if not DRY:
-            report("causal_splash", q=wq, cut_blocks="computed", grid_block=1024, block_kv_compute=512, fused_backward=True,
-                   ms=try_ms(splash_with(mask_lib.CausalMask((length, length)), heads, sizes_of(1024, 1024, 512, fused=True)), *wqkv))
+            report("causal_splash", q=fq, cut_blocks="computed", grid_block=1024, block_kv_compute=512, fused_backward=True,
+                   ms=try_ms(splash_with(mask_lib.CausalMask((length, length)), fq[1], sizes_of(1024, 1024, 512, fused=True)), *fqkv))
     local = mask_lib.LocalMask((length, length), (window - 1, 0), 0)
     for b, compute, fused in ((128, 128, False), (128, 128, True)) if DRY else (
             (512, 512, False), (1024, 512, False), (1024, 1024, False), (2048, 512, False),
             (512, 512, True), (1024, 512, True)) if wide else (
+            (256, 256, False), (512, 512, False), (1024, 512, False), (512, 512, True)) if narrow_band else (
             (128, 128, False), (256, 256, False), (512, 512, False), (512, 256, False), (1024, 512, False), (1024, 1024, False),
             (256, 256, True), (512, 512, True), (1024, 512, True)):
         blocks = 1 + -(-(window - 1) // b)
@@ -205,6 +219,8 @@ if os.environ.get("WINDOW") in ("1", "4096"):
     sq, skv = ((1, 4, 512, 64), (1, 2, 512, 64)) if DRY else ((1, 8, 2048, 64), (1, 4, 2048, 64))
     if wide:   # the taken form against dense float32 where a window of 4096 is no causal rule: 6144 positions
         sq, skv = ((1, 14, 1024, 128), (1, 2, 1024, 128)) if DRY else ((1, 7, 6144, 128), (1, 1, 6144, 128))
+    if narrow_band:   # groups of eight 128-wide heads
+        sq, skv = ((1, 16, 1024, 128), (1, 2, 1024, 128)) if DRY else ((1, 16, 2048, 128), (1, 2, 2048, 128))
     sqkv = operands(sq, skv, seed=3)
 
     def dense(q, k, v):   # float32 scores of the whole square under the rule
