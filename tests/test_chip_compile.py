@@ -1,8 +1,13 @@
 """Every `pallas_call` the main path can reach, compiled by the TPU's own
 compiler for a described (not attached) v5e at BERT-base / ResNet-50 widths,
 forward and backward; since PR 26 also the grouped matmul and the flash kernel
-at OLMoE-1B-7B's widths, since PR 28 the whole `moe_experts` lowering there,
-with the passes over its rows that the optimised program may hold.
+at OLMoE-1B-7B's widths, and since then every cell's own kernels at its shapes.
+This file is the kernels alone, a kernel or one op's kernels a case; the ops'
+whole lowerings (`moe_experts`, `short_conv`, `ssd_scan`) stand in
+`tests/test_chip_compile_ops.py` and the cells' whole steps in
+`tests/test_chip_compile_steps.py`, which take the described chip (`host`,
+`chip`) and `_no_persistent_cache` from here (ISSUE 66: `--dist loadfile` makes
+a file the unit of balance; `docs/tier1_durations.md` has each file's seconds).
 
 Interpret mode (each kernel's own test file) checks the numbers; it cannot
 see what Mosaic refuses: a block that is not a whole (8|16, 128) tile, a
@@ -13,13 +18,11 @@ chip.
 """
 import os
 import re
-from types import SimpleNamespace
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -325,109 +328,6 @@ def test_kernel_compiles_for_v5e(name, chip):
             f"{name}: compiled without a Mosaic kernel")
 
 
-def _moe_experts(x, top_p, top_i, load, w_gate, w_up, w_down):
-    """The op's lowering as the interpreter calls it for a TPU."""
-    from paddle_tpu.core.lowering import LoweringContext
-    from paddle_tpu.core.registry import get_op_def
-
-    op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: default)
-    ctx = LoweringContext(jax.random.PRNGKey(0), platform="tpu")
-    ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load],
-           "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
-    return get_op_def("moe_experts").lower(ctx, op, ins)["Out"]
-
-
-#: OLMoE-1B-7B's layer of experts over 4 x 4096 tokens: tokens, hidden, width, experts, experts a token
-OLMOE_EXPERTS = (4 * 4096, 2048, 1024, 64, 8)
-
-
-def _moe_experts_args(chip):
-    """`_moe_experts`' arguments at `OLMOE_EXPERTS`: bf16 activations, float32 masters."""
-    tokens, hidden, width, experts, k = OLMOE_EXPERTS
-    specs = [((tokens, hidden), BF16), ((tokens, k), F32), ((tokens, k), I32), ((experts,), I32),
-             ((experts, hidden, width), F32), ((experts, hidden, width), F32), ((experts, width, hidden), F32)]
-    return [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
-
-
-#: Temporaries of the program below as compiled here for the described v5e:
-#: 1.881 GB at the parent of PR 28 (fill-mode gathers, the weighted combine in
-#: token order), 1.614 GB without those, 1.883 GB with the matrices' gradients
-#: float32 from `tgmm` on (each 268 MB more than a bf16 one, from its kernel
-#: to the end of the program), 1.891 GB since the way back to token order is a
-#: kernel (PR 49: the [tokens, 8, hidden] arrays it took away were never live
-#: at the peak).  The bound is the last reading and a margin.
-MOE_EXPERTS_TEMP_BYTES = 1.95e9
-
-
-def test_moe_experts_at_olmoe_widths_passes_over_its_rows_no_more_than_it_must(chip):
-    """OLMoE-1B-7B's layer of experts over 4 x 4096 tokens, forward and the
-    gradients of X, TopKProb and the three float32 master matrices: outside
-    the kernels no `select` writes an [rows, hidden] array (a gather that
-    promises its indices has no fill value to select) and at most three
-    instructions write one: the gather to rows and the rows' two gradients
-    added (the third is room for one relayout); the two ways back to token
-    order (forward: the output; backward: X's gradient) are two calls of the
-    `token_sum` kernel, which write [tokens, hidden] and nothing of [tokens,
-    8, hidden] (PR 49; two gathers and two sums until then).  The masters'
-    gradients are the three `tgmm` calls' own float32 results: nothing else
-    writes an f32[experts, ., .] array (no bf16 gradient widened).  PERF.md,
-    PR 28."""
-    tokens, hidden, width, experts, k = OLMOE_EXPERTS
-    args = _moe_experts_args(chip)
-    program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(_moe_experts(*a).astype(F32))),
-                                 argnums=(0, 1, 4, 5, 6))
-    compiled = jax.jit(program).lower(*args).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 11  # three products, each with its two transposes, and the two ways back
-    assert len(re.findall(r'custom_call_target="tpu_custom_call".*token_sum', text)) == 2
-    assert not re.findall(rf"= \w+\[{tokens},{k},{hidden}\]", text)
-    rows_by_hidden = rf"= \w+\[{tokens * k},{hidden}\]\S* "
-    assert not re.findall(rows_by_hidden + r"select\(", text)
-    entry = text[text.index("ENTRY"):]
-    written = [line.split(" = ")[0].strip() for line in entry.splitlines()
-               if re.search(rows_by_hidden + r"(?!parameter|bitcast|get-tuple-element)", line)
-               and "tpu_custom_call" not in line]
-    assert len(written) <= 3, written
-    of_the_masters = [line for line in entry.splitlines()
-                      if re.search(rf"= f32\[{experts},\d+,\d+\]\S* (?!parameter)", line)]
-    assert len(of_the_masters) == 3 and all("tpu_custom_call" in line and "tgmm" in line
-                                            for line in of_the_masters), of_the_masters
-    assert compiled.memory_analysis().temp_size_in_bytes < MOE_EXPERTS_TEMP_BYTES
-
-
-def test_moe_experts_cost_row_counts_the_passes_of_the_compiled_forward(chip):
-    """`ops.moe_ops._ROW_PASSES`, which the op's cost row charges, against
-    the forward program at OLMoE's widths: every [rows, hidden] and
-    [rows, width] array an instruction of the entry computation reads or
-    writes, kernels included."""
-    from collections import Counter
-
-    from paddle_tpu.ops.moe_ops import _ROW_PASSES
-
-    tokens, hidden, width, experts, k = OLMOE_EXPERTS
-    args = _moe_experts_args(chip)
-    text = jax.jit(_moe_experts).lower(*args).compile().as_text()
-    of_rows = rf"\w+\[{tokens * k},(\d+)\]"
-    types, passes = {}, Counter()
-    for line in text[text.index("ENTRY"):].splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$", line)
-        if not m:
-            continue
-        name, result, opcode, rest = m.groups()
-        types[name] = result
-        if opcode in ("parameter", "bitcast", "tuple", "get-tuple-element"):
-            continue
-        operands = re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0].split("), ")[0])
-        passes.update(int(n) for t in [result] + [types.get(o, "") for o in operands]
-                      for n in re.findall(of_rows, t))
-    assert passes == {hidden: _ROW_PASSES["hidden"], width: _ROW_PASSES["width"]}, passes
-
-
-#: SDAR-30B-A3B-Chat's layer of experts over 2 x 8192 positions with 16 of its 128 experts held:
-#: tokens, hidden, width, router outputs, experts a token, experts held
-SDAR_EXPERTS = (2 * 8192, 2048, 768, 128, 8, 16)
-
-
 def test_no_square_of_the_positions_is_in_the_compiled_attention(chip):
     """Forward and backward at the cell's shape: no array with 8192 x 8192
     elements, mask or scores, in any computation of the compiled program, nor
@@ -471,114 +371,6 @@ def test_no_square_of_the_positions_is_in_the_compiled_causal_attention(q, kv, c
     assert set(under_the_scope) == {"splash_mha_fwd", "attention_dq_dk_dv"}
 
 
-#: Kimi-Linear-48B-A3B's: one sequence of 4096 positions, hidden 2304 (18 lane tiles), 8 of 256 experts held
-KIMI_EXPERTS = (4096, 2304, 1024, 256, 8, 8)
-
-
-@pytest.mark.parametrize("cell,shape,bound", [("sdar", SDAR_EXPERTS, 32768), ("kimi-linear", KIMI_EXPERTS, 2048)])
-def test_moe_experts_with_a_share_held_passes_over_no_more_rows_than_its_bound(cell, shape, bound, chip):
-    """16 of 128 experts held at 16384 positions: the 131072 (token, slot)
-    assignments exist as vectors only (the sort's keys, order and weights, and
-    since PR 53 each slot's place and group, [tokens, k]);
-    every two-dimensional array of rows, in the common pass and in the rare
-    path's loop alike, has the bound's 32768 rows (twice the uniform share:
-    `ops.moe_ops._held_rows_bound`) or the tokens' 16384, forward and backward.
-    Since PR 35 the gathers write a whole number of
-    passes, from one to four (8192 rows each over the bound, 512 over a chunk
-    of the rare path's 2048), each count a branch of a conditional that the
-    step's own count of held rows picks: rows that belong to no token cost
-    nothing past the last pass that holds a live one.  Since PR 53 the common
-    pass's way back is the `token_sum` kernel, forward's call and the transpose
-    of the gather (`lowering.held_token_sum_calls` reads two): the only
-    scatter-adds of rows left stand in the rare path's loop.  The same at Kimi
-    Linear's widths, where Mosaic meets rows of 2304 = 18 lane tiles and the
-    bound's passes are the rare path's."""
-    from paddle_tpu import monitor
-    from paddle_tpu.ops.moe_ops import _HELD_REST_ROWS, _held_rows_bound, _pass_rows
-
-    tokens, hidden, width, experts, k, held = shape
-    assert _held_rows_bound(tokens * k, held, experts) == bound
-
-    def moe(x, top_p, top_i, load, w_gate, w_up, w_down):
-        from paddle_tpu.core.lowering import LoweringContext
-        from paddle_tpu.core.registry import get_op_def
-
-        op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: {"held": [0, held]}.get(name, default))
-        ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load],
-               "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
-        return get_op_def("moe_experts").lower(LoweringContext(jax.random.PRNGKey(0), platform="tpu"), op, ins)["Out"]
-
-    specs = [((tokens, hidden), BF16), ((tokens, k), F32), ((tokens, k), I32), ((experts,), I32),
-             ((held, hidden, width), F32), ((held, hidden, width), F32), ((held, width, hidden), F32)]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in specs]
-    program = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(moe(*a).astype(F32))), argnums=(0, 1, 4, 5, 6))
-    monitor.reset()
-    monitor.enable()
-    try:
-        compiled = jax.jit(program).lower(*args).compile()
-        assert monitor.get_monitor().counter_values().get("lowering.held_token_sum_calls") == 2
-    finally:
-        monitor.disable()
-        monitor.reset()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 11
-    assert len(re.findall(r'custom_call_target="tpu_custom_call".*jit\(token_sum\)', text)) == 2
-    rows_of = {int(n) for n in re.findall(r"= \w+\[(\d+),(?:%d|%d)\]" % (hidden, width), text)}
-    assert max(rows_of) == max(bound, tokens), rows_of
-    assert not re.findall(r"\[%d,\d+" % (tokens * k), text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
-    shape_of = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text))
-    gathered = [shape for shape in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text) if shape.endswith(",%d" % hidden)]
-    # the rows'; the kernels' group metadata scatters too
-    added = [(shape_of[updates], where) for updates, where in re.findall(r' scatter\(%\S+, %\S+, %([^\s,)]+)\).*op_name="([^"]*)"', text)
-             if shape_of[updates].endswith(",%d" % hidden)]
-    assert _pass_rows(bound) == bound // 4 and _pass_rows(_HELD_REST_ROWS) == 512
-
-    def passes(n):
-        return {"%d,%d" % (rows, hidden) for rows in range(_pass_rows(n), n + 1, _pass_rows(n))}
-
-    assert len(passes(bound) | passes(_HELD_REST_ROWS)) == (8 if cell == "sdar" else 4)
-    assert set(gathered) == passes(bound) | passes(_HELD_REST_ROWS), gathered
-    assert {shape for shape, _ in added} == passes(_HELD_REST_ROWS) and len(added) == 8, added   # four counts of passes, forward and backward
-    assert all("/while/body/" in where for _, where in added), added   # the rare path's loop; none in the common pass
-
-
-#: LFM2-8B-A1B's cell: a sequence of 8192 positions at hidden size 2048, three taps
-LFM2_CONV = (1, 8192, 2048, 3)
-
-
-def test_the_short_convolution_is_passes_over_the_activations_dtype(chip):
-    """`short_conv` at the cell's shape, forward and backward: plain jax.numpy
-    that XLA fuses.  No float32 copy of the [b, T, 3d] in-projection and no
-    padded copy of a product exists in the compiled program (the derived
-    backward made both), and the temporaries stay under three of the op's own
-    [b, T, d] float32 arrays."""
-    from paddle_tpu.ops.moe_ops import _gated_short_conv
-
-    b, t, d, taps = LFM2_CONV
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in
-            (((b, t, 3 * d), BF16), ((d, taps), F32), ((b, t, d), BF16))]
-
-    def forward(x, w):   # as the executor differentiates it: the scopes are opened inside
-        with jax.named_scope("fwd"):
-            return _gated_short_conv(x, w)
-
-    def step(x, w, g):
-        out, vjp = jax.vjp(forward, x, w)
-        return (out,) + vjp(g)
-
-    compiled = jax.jit(step).lower(*args).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" not in text               # no kernel: the op is XLA's
-    entry = text[text.index("ENTRY"):]                 # what exists in memory: the entry computation's results
-    assert not re.findall(r"= [^=]*f32\[%d,%d,%d\][^=]* fusion\(" % (b, t, 3 * d), entry)
-    assert not re.findall(r"= [^=]*f32\[%d,%d,%d\][^=]* fusion\(" % (b, t - 1, d), entry)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 3 * b * t * d * 4
-    # forward and backward under the scope the benchmark's `short_conv_roofline_share` reads
-    scoped = re.findall(r'op_name="[^"]*/gated_short_conv/[^"]*"', text)
-    assert any("transpose(" in name for name in scoped) and any("transpose(" not in name for name in scoped)
-
-
 def test_no_square_of_the_positions_is_in_the_compiled_window_attention(chip):
     """Forward and backward at Phi-4-mini-flash's window layer's shape: no array
     with an [8192, 8192] square, mask or scores, in any computation of the
@@ -597,440 +389,6 @@ def test_no_square_of_the_positions_is_in_the_compiled_window_attention(chip):
         r'op_name="[^"]*window_attention\)*/block_sparse_attention[^"]*/(splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv)'
         r'[^"/]*/pallas_call"', text)
     assert set(under_the_scope) == {"splash_mha_fwd", "attention_dq_dk_dv"}
-
-
-def test_lfm2s_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip):
-    """The cell's whole train step (benchmark/models/lfm2.py: build, at the
-    configuration's and the traffic's own sizes: two sequences) compiles for
-    the described v5e, and XLA plans it under the 15.5 GB the cell allows
-    itself and over the 12 GB it promises to fill (PERF.md, PR 34, has the
-    three planned peaks: a third sequence plans 15.60).  What `cost_analysis()`
-    counts for the step stays under what the HBM moves in 280 ms, the step's
-    time on the chip: the whole-step roofline share the cell reports reads
-    under 100% (it read 121.6% while the held experts' never-run branch was
-    a bound's rows a pass)."""
-    import paddle_tpu as fluid
-    from benchmark import manifest as mf
-    from benchmark.models import lfm2
-    from paddle_tpu.core import executor as ex
-
-    cfg = mf.read_json("benchmark/configs/lfm2-8b-a1b.json")
-    job = mf.read_json("benchmark/traffic/train-s8192.json")
-    with fluid.unique_name.guard():
-        main, startup, _, loss, _ = lfm2.build(cfg, job)
-    main.random_seed = startup.random_seed = 3
-    scope = fluid.Scope()
-    for v in startup.global_block().vars.values():
-        if v.persistable:
-            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    feeds = {n: jax.ShapeDtypeStruct((job["batch_per_chip"], job["seq_len"]), I32) for n in lfm2.FEEDS}
-    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu",
-                            feed_shapes={n: s.shape for n, s in feeds.items()})
-
-    def on_chip(v):
-        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
-
-    compiled = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names},
-                              {n: on_chip(scope.find_var(n)) for n in step.ro_names},
-                              {n: on_chip(s) for n, s in feeds.items()},
-                              on_chip(jax.random.PRNGKey(0))).compile()
-    m = compiled.memory_analysis()
-    peak = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
-    assert 12e9 <= peak <= 15.5e9, peak
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-    assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.280
-    text = compiled.as_text()
-    # the one attention layer took the splash kernels under the causal rule (PR 37; the flash kernel until then)
-    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    assert text.count("/gated_short_conv/") > 0 and text.count("/expert_gemm/") > 0
-
-
-def test_ouros_step_compiles_for_the_chip_as_one_loop_and_its_planned_peak_leaves_room(chip):
-    """The looped cell's whole train step (benchmark/models/ouro.py: build, at
-    the configuration's and the traffic's own sizes: one sequence of 4096
-    through four passes of eight layers) compiles for the described v5e as a
-    forward and a backward `while` (the `repeat` op's scan and its transpose),
-    the pass's forward computed again inside the backward one, the splash
-    kernels inside both, and XLA plans it under the 15.5 GB the cell allows
-    itself and over the 25% of the chip a cell has to fill (PERF.md, PR 38:
-    12.7 GB).  `cost_analysis()` counts a loop's body once, so what it counts
-    stays far under what the chip does in the step's ~0.6 s: the whole-step
-    roofline share the cell reports reads LOW, never over 100%."""
-    import paddle_tpu as fluid
-    from benchmark import manifest as mf
-    from benchmark.models import ouro
-    from paddle_tpu.core import executor as ex
-
-    cfg = mf.read_json("benchmark/configs/ouro-2.6b.json")
-    job = mf.read_json("benchmark/traffic/train-ut4-s4096.json")
-    with fluid.unique_name.guard():
-        main, startup, _, loss, _ = ouro.build(cfg, job)
-    main.random_seed = startup.random_seed = 3
-    scope = fluid.Scope()
-    for v in startup.global_block().vars.values():
-        if v.persistable:
-            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    feeds = {n: jax.ShapeDtypeStruct((job["batch_per_chip"], job["seq_len"]), I32) for n in ouro.FEEDS}
-    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu",
-                            feed_shapes={n: s.shape for n, s in feeds.items()})
-
-    def on_chip(v):
-        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
-
-    compiled = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names},
-                              {n: on_chip(scope.find_var(n)) for n in step.ro_names},
-                              {n: on_chip(s) for n, s in feeds.items()},
-                              on_chip(jax.random.PRNGKey(0))).compile()
-    m = compiled.memory_analysis()
-    peak = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
-    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
-    assert m.argument_size_in_bytes == pytest.approx(3 * 4 * 461.4e6, rel=1e-3)      # masters and Adam's two moments
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-    assert max(cost["bytes accessed"] / 819e9, cost["flops"] / 197e12) < 0.5
-    text = compiled.as_text()
-    assert len(re.findall(r" while\(", text)) == 2
-    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    # the scopes the cell's readers find: the recomputed forward, the exits, the body's ops under the construct's
-    assert text.count("/rematted_computation/") > 0
-    # numbered where this process built a looped model before: sibling `name_scope`s of one name are
-    assert re.search(r"/exit_head(_\d+)?/", text) and re.search(r"/exit_loss(_\d+)?/", text)
-    assert re.search(r':repeat/[^"]*loop_pass/op\d+:fused_attention', text) and not re.search(r'loop_pass/[^"]*exit_head', text)
-    assert re.search(r'transpose\([^"]*:repeat/[^"]*rematted_computation/[^"]*op\d+:mul', text)
-
-
-@pytest.mark.slow   # 3 to 4.5 minutes of one compile on every core: run by name (`-m slow`), PERF.md PR 42 has its readings
-def test_kimi_linears_step_compiles_for_the_chip_and_its_planned_peak_leaves_room(chip):
-    """Kimi Linear's cell's whole train step (benchmark/models/kimi_linear.py:
-    build, at the configuration's and the traffic's own sizes: one sequence of
-    4096 through four KDA layers and a latent attention) compiles for the
-    described v5e, and XLA plans it under the 15.5 GB the cell allows itself
-    and over the 25% of the chip a cell has to fill (PERF.md, PR 42, has the
-    planned peaks that chose the batch).  The latent attention took the splash
-    kernels with its two widths as they are; the scans are the kernels of
-    `ops/kda_kernels.py` since PR 44, two calls a layer since PR 45 (forward,
-    which in the step writes the chunks' start states and T beside o, 0.17 GB
-    a layer kept until backward, and the transpose, which reads them: no call
-    makes the states again; four heads a grid step: they fit their VMEM inside the
-    step, not only alone) under the scope their roofline share reads, forward
-    and backward; the state and Adam's moments are 12 bytes of the 16 a
-    parameter.  PERF.md, PR 45, has the planned peak."""
-    import paddle_tpu as fluid
-    from benchmark import manifest as mf
-    from benchmark.models import kimi_linear
-    from paddle_tpu.core import executor as ex
-
-    cfg = mf.read_json("benchmark/configs/kimi-linear-48b-a3b.json")
-    job = mf.read_json("benchmark/traffic/train-kda-s4096.json")
-    with fluid.unique_name.guard():
-        main, startup, _, loss, _ = kimi_linear.build(cfg, job)
-    main.random_seed = startup.random_seed = 3
-    scope = fluid.Scope()
-    for v in startup.global_block().vars.values():
-        if v.persistable:
-            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    feeds = {n: jax.ShapeDtypeStruct((job["batch_per_chip"], job["seq_len"]), I32) for n in kimi_linear.FEEDS}
-    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu",
-                            feed_shapes={n: s.shape for n, s in feeds.items()})
-
-    def on_chip(v):
-        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
-
-    compiled = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names},
-                              {n: on_chip(scope.find_var(n)) for n in step.ro_names},
-                              {n: on_chip(s) for n, s in feeds.items()},
-                              on_chip(jax.random.PRNGKey(0))).compile()
-    m = compiled.memory_analysis()
-    peak = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
-    assert 0.25 * 16.9e9 <= peak <= 15.5e9, peak
-    assert m.argument_size_in_bytes == pytest.approx(3 * 4 * cfg["parameters"], rel=1e-3)    # masters and Adam's two moments
-    text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    scans = re.findall(r'op_name="([^"]*/kda_chunk_scan/[^"]*)"', text)
-    assert any("transpose(" in name for name in scans) and any("transpose(" not in name for name in scans)
-    assert all(any(name.endswith(f"/{kernel}/pallas_call") for name in scans) for kernel in ("kda_scan", "kda_scan_transposed"))
-    assert "kda_scan_starts" not in text
-    print(f"planned peak {peak / 1e9:.3f} GB, temporaries {m.temp_size_in_bytes / 1e9:.3f} GB")     # shown by `-s`
-    assert not re.search(r"kda_chunk_scan/[^\"]*while", text)          # no `lax.scan` is left in the op
-    assert re.search(r"/kda(_\d+)?/op\d+:kda/kda_chunk_scan/", text) and re.search(r"/latent_attention(_\d+)?/op\d+:fused_attention", text)
-    assert re.search(r"/shared_expert(_\d+)?/op\d+:mul", text) and text.count("/plain_short_conv/") > 0
-
-
-#: What a v5e reports as `memory_stats()["bytes_limit"]` (my chip run, PR 51, call 1): the limit `plan_kept` reads on
-#: the chip, given to it here, where the CPU reports none, so that the step compiled here is the step the chip compiles.
-V5E_BYTES_LIMIT = 16_909_336_064
-
-
-@pytest.mark.parametrize("limit,made_again", [(0, 3), (V5E_BYTES_LIMIT, 0)], ids=["a-full-chip", "a-v5es-room"])
-def test_the_benchmarks_readers_find_the_selective_scans_kernels_forward_recomputed_and_backward(chip, monkeypatch, limit, made_again):
-    """A small Jamba (the configuration's period cut to four layers, 512 wide:
-    1024 channels a mixer and a state of 16, which `_scan_path` sends to the
-    kernels on the TPU; 64 tokens) trained one step, compiled for the described
-    v5e: every call of the two kernels of `ops/ssm_kernels.py`, forward, made
-    again under the layer's `recompute_scope` and transposed (the one in the
-    `custom_vjp`'s backward), carries an `op_name` that the benchmark's readers
-    `ssm_scan_roofline_share` and `ssm_ms_per_step` match (their own `SCOPE`s,
-    imported), the recomputed ones `recompute_ms_per_step`'s too; no `while` is
-    left under the op's scope.  The forward kernel is made again where the chip
-    has no room for what `plan_kept` would keep (the cell's own thirteen at its
-    size), and not at all where it has (this small model on a v5e: PR 51)."""
-    import paddle_tpu as fluid
-    from benchmark import manifest as mf
-    from benchmark.metrics import recompute_ms_per_step, ssm_ms_per_step, ssm_scan_roofline_share
-    from benchmark.models import jamba
-    from paddle_tpu.core import executor as ex
-    from paddle_tpu.monitor import memstats
-
-    monkeypatch.setattr(memstats, "device_bytes_limit", lambda *a: limit)
-    cfg = dict(mf.read_json("benchmark/configs/ai21-jamba2-3b.json"), hidden_size=512, intermediate_size=96, mamba_dt_rank=4,
-               num_attention_heads=4, num_key_value_heads=1, vocab_size=96, num_hidden_layers=4, attn_layer_period=4,
-               attn_layer_offset=2)
-    cfg["layer_types"] = jamba.layer_types(cfg)
-    job = dict(mf.read_json("benchmark/traffic/train-ssm-fsdp4.json"), seq_len=64, batch_per_chip=1)
-    del job["mesh_shape"], job["mesh_axes"]
-    with fluid.unique_name.guard():
-        main, startup, _, loss, _ = jamba.build(cfg, job)
-    scope = fluid.Scope()
-    for v in startup.global_block().vars.values():
-        if v.persistable:
-            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    feeds = {n: jax.ShapeDtypeStruct((1, 64), I32) for n in jamba.FEEDS}
-    step = ex._CompiledStep(main, list(feeds), [loss.name], scope, platform="tpu", feed_shapes={n: s.shape for n, s in feeds.items()})
-
-    def on_chip(v):
-        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
-
-    text = step.jfn.lower({n: on_chip(scope.find_var(n)) for n in step.rw_names}, {n: on_chip(scope.find_var(n)) for n in step.ro_names},
-                          {n: on_chip(s) for n, s in feeds.items()}, on_chip(jax.random.PRNGKey(0))).compile().as_text()
-    kernels = sorted({name for name in recompute_ms_per_step.op_names(text).values()      # a call's operands' copies carry its name too
-                      if name.endswith(("/selective_scan/pallas_call", "/selective_scan_transposed/pallas_call"))})
-    assert all(ssm_scan_roofline_share.SCOPE.search(name) and ssm_ms_per_step.SCOPE.search(name) for name in kernels), kernels
-    transposed = [name for name in kernels if name.endswith("/selective_scan_transposed/pallas_call")]
-    again = [name for name in kernels if recompute_ms_per_step.SCOPE in name]
-    forward = [name for name in kernels if name not in transposed and name not in again]
-    assert len(forward) == len(transposed) == 3 and len(again) == made_again, kernels   # the three Mamba layers, each way
-    assert all("transpose(" in name for name in transposed) and not any("transpose(" in name for name in forward)
-    assert all(name.endswith("/selective_scan/pallas_call") for name in again)
-    assert not re.search(r'op_name="[^"]*op\d+:selective_scan/[^"]*while', text)
-
-
-def _kept_step(module, config, traffic, devices, monkeypatch, check_rows=None):
-    """(the compiled train step of a cell at its configuration's and traffic's
-    own sizes, for the described chip or mesh, with what `plan_kept` chose at
-    the chip's own memory limit; the `lowering.recomputed_*` counters of its
-    trace).  `check_rows`: the cell's `for_test` clone on that many rows with
-    the variables its reference check fetches, instead of the step."""
-    import importlib
-
-    import paddle_tpu as fluid
-    from benchmark import manifest as mf
-    from paddle_tpu import monitor
-    from paddle_tpu.core import executor as ex
-    from paddle_tpu.monitor import memstats
-
-    model = importlib.import_module(f"benchmark.models.{module}")
-    cfg, job = mf.read_json(f"benchmark/configs/{config}.json"), mf.read_json(f"benchmark/traffic/{traffic}.json")
-    monkeypatch.setattr(memstats, "device_bytes_limit", lambda *a: V5E_BYTES_LIMIT)
-    mesh, make_mesh = None, fluid.parallel.make_mesh
-    if "mesh_shape" in job:    # the builder's mesh over the described devices, not the CPU's
-        monkeypatch.setattr(fluid.parallel, "make_mesh", lambda sizes, names, _=None: make_mesh(sizes, names, list(devices)))
-        mesh = fluid.parallel.make_mesh(tuple(job["mesh_shape"]), tuple(job["mesh_axes"]))
-    with fluid.unique_name.guard():
-        main, startup, _, loss, compared = model.build(cfg, job)
-    main.random_seed = startup.random_seed = 3
-    program, fetched = (main, [loss.name]) if check_rows is None else (main.clone(for_test=True), list(compared))
-    scope = fluid.Scope()
-    for v in startup.global_block().vars.values():
-        if v.persistable:
-            scope.set_var(v.name, jax.ShapeDtypeStruct(tuple(v.shape), v.dtype))
-    rows = check_rows or job["batch_per_chip"] * (mesh.size if mesh is not None else 1)
-    feeds = {n: jax.ShapeDtypeStruct((rows, job["seq_len"]), I32) for n in model.FEEDS}
-    step = ex._CompiledStep(program, list(feeds), fetched, scope, mesh=mesh, batch_axis=job.get("mesh_axes", ["dp"])[0],
-                            platform="tpu", feed_shapes={n: s.shape for n, s in feeds.items()})
-    one = SingleDeviceSharding(devices[0])
-
-    def placed(v, sharding):
-        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one if mesh is None else sharding)
-
-    monitor.reset()
-    monitor.enable()
-    try:
-        lowered = step.jfn.lower(
-            {n: placed(scope.find_var(n), mesh and step.state_specs[n]) for n in step.rw_names},
-            {n: placed(scope.find_var(n), mesh and step.state_specs[n]) for n in step.ro_names},
-            {n: placed(s, mesh and step.feed_specs[n]) for n, s in feeds.items()},
-            placed(jax.random.PRNGKey(0), mesh and step.key_spec))
-        # (the counters that moved: `monitor.reset()` keeps the names an earlier test of this process counted under)
-        counted = {k[len("lowering.recomputed_"):]: v for k, v in monitor.MONITOR.counter_values().items()
-                   if k.startswith("lowering.recomputed_") and v}
-    finally:
-        monitor.disable()
-        monitor.reset()
-    return lowered.compile(), counted
-
-
-def _planned_peak(compiled):
-    m = compiled.memory_analysis()
-    return m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
-
-
-def _made_again(text):
-    """The names of the instructions of the compiled step that stand in a
-    rematerialised computation, as `recompute_ms_per_step` finds them."""
-    from benchmark.metrics import recompute_ms_per_step
-
-    return [name for name in recompute_ms_per_step.op_names(text).values() if recompute_ms_per_step.SCOPE in name]
-
-
-def test_phi4_mini_flashs_step_keeps_every_product_and_kernel_residual_and_its_planned_peak_leaves_room(host, monkeypatch):
-    """`phi-4-mini-flash-reasoning.train-sambay-s8192`'s whole step at the
-    published widths, compiled for the described v5e with what `plan_kept`
-    chooses at the chip's memory limit: all 37 candidates of the six segments
-    (3.45 GB of the 4.27 the state leaves the kept values), planned under the
-    14.5 GB the issue allows and over the parent's 10.5; in the rematerialised
-    computations no product and no kernel call is left (ISSUE 51)."""
-    compiled, counted = _kept_step("phi4flash", "phi-4-mini-flash-reasoning", "train-sambay-s8192", host.devices, monkeypatch)
-    assert counted == {"segments": 6, "kept_values": 37, "kept_bytes": 3449552896, "candidates_bytes": 3449552896}
-    peak = _planned_peak(compiled)
-    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 12.5e9 <= peak <= 14.5e9, peak
-    again = _made_again(compiled.as_text())
-    assert again and not [name for name in again if name.endswith(("/dot_general", "/pallas_call"))]
-
-
-def test_smallthinkers_step_compiles_for_the_chip_with_its_routers_ahead_and_a_window_of_4096(host, monkeypatch):
-    """`smallthinker-21b-a3b.train-nope-swa-s16384`'s whole step at the published
-    widths and 16384 tokens, compiled for the described v5e with what
-    `plan_kept` chooses at the chip's memory limit: all 28 candidates of the
-    four sparse segments (every product's output, the kernels' residuals, the
-    expert products' outputs and the routers' logits: 1.74 GB), planned over the
-    25% of the chip a cell has to fill and under 9 GB; the full layer took the
-    causal splash kernels and the three window layers the window rule's, in
-    blocks of 1024 at (28 on 4, 128) inside the scoped VMEM; every router's
-    scope stands AHEAD of its layer's attention; and in the rematerialised
-    computations no product and no attention kernel is left (ISSUE 63)."""
-    compiled, counted = _kept_step("smallthinker", "smallthinker-21b-a3b", "train-nope-swa-s16384", host.devices, monkeypatch)
-    assert counted == {"segments": 4, "sparse_segments": 4, "kept_values": 28, "kept_bytes": 1735393280,
-                       "candidates_bytes": 1735393280}
-    peak = _planned_peak(compiled)
-    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 0.25 * 16.9e9 <= peak <= 9.0e9, peak
-    text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    # ONE backward kernel an attention layer (ISSUE 64), and no partial dq a block of keys
-    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*/attention_dq_dk_dv["/]', text)) == 4
-    assert not re.findall(r"\[16,28,16384,128\]", text)
-    names = re.findall(r'op_name="([^"]*)"', text)
-    window = {re.search(r"/(sliding_attention(?:_\d+)?)/", n).group(1) for n in names if "/window_attention/" in n}
-    assert len(window) == 3                        # the three rotary layers, each under its own numbered scope
-    assert any(re.search(r"/op\d+:fused_attention/block_sparse_attention/", n) for n in names)      # the full layer: no window scope
-    routers = sorted({int(i) for n in names for i in re.findall(r"/op(\d+):moe_router", n)})
-    attentions = sorted({int(i) for n in names for i in re.findall(r"/op(\d+):fused_attention", n)})
-    assert len(routers) == len(attentions) == 4 and all(r < a for r, a in zip(routers, attentions))
-    assert all(a < r for a, r in zip(attentions, routers[1:]))          # router, attention, router, attention, ...
-    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
-
-
-def test_lagunas_step_compiles_for_the_chip_with_its_gates_its_two_head_counts_and_a_window_of_512(host, monkeypatch):
-    """`laguna-xs.2.train-gated-swa-s16384`'s whole step at the published widths
-    and 16384 tokens, compiled for the described v5e with what `plan_kept`
-    chooses at the chip's memory limit: all 48 candidates of the five segments
-    (4.0 GB), planned over the 25% of the chip a cell has to fill and under
-    12.5 GB (11.77 with 16 experts held; 32 held planned 14.76 and its 8-row
-    clone did not fit beside the moments: the configuration's `deployment`); the
-    two full layers took the causal splash kernels at 48 heads on 8 and the
-    three window layers the window rule's at 64 on 8, blocks of 512, ONE
-    backward kernel a layer; five `attention_gate` scopes, each under its
-    layer's; and in the rematerialised computations no product and no attention
-    kernel is left (ISSUE 65)."""
-    compiled, counted = _kept_step("laguna", "laguna-xs.2", "train-gated-swa-s16384", host.devices, monkeypatch)
-    assert counted == {"segments": 5, "sparse_segments": 4, "kept_values": 48, "kept_bytes": 3997171712,
-                       "candidates_bytes": 3997171712}
-    peak = _planned_peak(compiled)
-    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 0.25 * 16.9e9 <= peak <= 12.5e9, peak
-    text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*/attention_dq_dk_dv["/]', text)) == 5
-    assert re.findall(r"bf16\[1,48,16384,128\]", text) and re.findall(r"bf16\[1,64,16384,128\]", text)
-    names = re.findall(r'op_name="([^"]*)"', text)
-    window = {re.search(r"/(sliding_attention(?:_\d+)?)/", n).group(1) for n in names if "/window_attention/" in n}
-    assert len(window) == 3                        # the three window layers, each under its own numbered scope
-    assert any(re.search(r"/op\d+:fused_attention/block_sparse_attention/", n) for n in names)      # the full layers: no window scope
-    gates = {m.group(1) for n in names for m in [re.search(r"/((?:sliding_attention(?:_\d+)?/)?attention_gate(?:_\d+)?)/", n)] if m}
-    assert len(gates) == 5 and sum(g.startswith("sliding_attention") for g in gates) == 3, gates
-    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
-
-
-def test_one_latent_attention_layer_writes_each_kernel_operand_once(host):
-    """ONE latent attention layer at Kanana-2's widths (H 32, 192 / 128) over
-    2048 positions, forward and backward through `_CompiledStep`, compiled for
-    the described v5e (ISSUE 55): the chain of ops between the projections and
-    the attention went into the unit's four kernels (`ops/latent_kernels.py`),
-    which write the arrays the attention's kernels and the projections' backward
-    read and nothing else: q, k, v forward and again, dq and d_up backward.
-    Beside them no instruction under the layer's scope that is no product, no
-    kernel call and not the partials' sum writes 30 MB x (2048 / 16384) or more
-    but the kept output's copy in its two layouts and the output's way back to
-    (B, L, H, 128), forward and again; no `dot_general` stands under `/rotary/`
-    (the rotation is a rotation of lanes inside a pass, not a product with a
-    0/+-1 matrix of its own)."""
-    from tools import chip_latent_edges as edge
-
-    positions = 2048
-    compiled, counted = edge.one_layer_step(host.devices, positions)
-    assert counted["lowering.latent_operands_assembled"] == 1 and not counted.get("lowering.latent_operands_fallback")
-    assert counted["lowering.attention_block_causal"] == counted["lowering.attention_backward_onchip_dq"] == 1 and counted["lowering.latent_rotary_ops"] == 2
-    text = compiled.as_text()
-    found = edge.edges(text, floor=edge.FLOOR * positions / 16384)
-    mb = 2 * positions * 32 / 1e6       # of a (B, L, H, 1) slab in bf16
-    kernels = sorted((way, kind, round(size / mb)) for way, kind, size, _, _ in found if kind.startswith("kernel:"))
-    assert kernels == sorted([("forward", "kernel:latent_queries", 192), ("forward", "kernel:latent_keys_values", 192 + 128),
-                              ("again", "kernel:latent_queries", 192), ("again", "kernel:latent_keys_values", 192 + 128),
-                              ("backward", "kernel:latent_queries_back", 192), ("backward", "kernel:latent_up_back", 256 + 4)]), kernels   # + the float32 sum over the heads
-    # (a projection's own cast of its weights, 16.8 MB whatever the positions, is no array of the edge's)
-    rest = sorted((way, kind, round(size / mb)) for way, kind, size, _, name in found
-                  if not kind.startswith("kernel:") and ":mul/" not in name)
-    assert rest == [("again", "transpose", 128), ("forward", "reduce_precision", 256), ("forward", "transpose", 128)], rest
-    names = re.findall(r'op_name="([^"]*)"', text)
-    assert any("/rotary/" in name for name in names)
-    assert not [name for name in names if "/rotary/" in name and name.endswith("/dot_general")]
-    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text
-
-
-@pytest.mark.slow   # one compile of ~70 s on every core: run by name (`-m slow`); PERF.md, PR 54, has its readings
-def test_kanana2s_step_keeps_every_candidate_of_its_sparse_segments_and_its_planned_peak_leaves_room(host, monkeypatch):
-    """`kanana-2-30b-a3b.train-mla-s16384`'s whole step at the published widths
-    and 16384 tokens, compiled for the described v5e with what `plan_kept`
-    chooses at the chip's memory limit: every candidate of the five segments,
-    four of them sparse (the expert products' outputs and the routers' logits
-    among them), planned under the 15.5 GB a cell allows itself and over 25% of
-    the chip; the latent attention took the splash kernels at (192, 128) over
-    16384 keys, the ten rotations stand under `latent_attention/rotary`, and in
-    the rematerialised computations no product, no attention kernel and no
-    grouped product of the held path's COMMON pass is left (ISSUE 54)."""
-    compiled, counted = _kept_step("kanana", "kanana-2-30b-a3b", "train-mla-s16384", host.devices, monkeypatch)
-    assert counted["segments"] == 5 and counted["sparse_segments"] == 4
-    assert counted["kept_bytes"] == counted["candidates_bytes"] > 4e9
-    peak = _planned_peak(compiled)
-    print(f"planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 0.25 * 16.9e9 <= peak <= 14.9e9, peak
-    text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_d" not in text and "flash_mha" not in text
-    assert len(set(re.findall(r"/(latent_attention(?:_\d+)?)/rotary/op\d+:rotary_embedding", text))) == 5
-    # the edge of a sparse layer's latent attention, the unit's own kernels with it: 2.6 GB written or less, from 4.1
-    # before the chain was lowered as one unit (ISSUE 55; `tools/chip_latent_edges.py` prints the table)
-    from tools import chip_latent_edges as edge
-
-    written = sum(size for _, _, size, _, _ in edge.edges(text, edge.LAYER))
-    print(f"a sparse layer's edge writes {written / 1e3:.3f} GB")
-    assert 1.5e3 <= written <= 2.6e3, written
-    # (the rare path makes its own again, and a rotation's pair swap is a product with a constant, no kept matrix's)
-    again = [name for name in _made_again(text) if "/cond/branch_" not in name and ":rotary_embedding/" not in name]
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name]
 
 
 def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):
@@ -1116,189 +474,3 @@ def test_the_alignment_target_of_a_chunk_compiles_to_one_kernel_and_no_per_head_
     assert not re.search(rf"\[(4,)?8,512,{keys}\]", text)
     plain = jax.jit(lambda *operands: sio.attention_target(*operands, 128 ** -0.5)).lower(*shapes).compile()
     assert re.search(rf"f32\[(4,)?8,512,{keys}\]", plain.as_text())
-
-
-@pytest.mark.slow   # two compiles, ~115 and ~80 s on every core: run by name (`-m slow`); PERF.md, PR 56, has their readings
-def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
-    """`keye-vl-2.0-30b-a3b.train-dsa-s16384`'s whole step at the published
-    widths and 16384 tokens, compiled for the described v5e with what
-    `plan_kept` chooses at the chip's memory limit: every candidate of the four
-    segments, the four layers' picks among them (33.5 MB each, kept whatever the
-    room), planned over 25% of the chip and under the 15.5 GB a cell allows
-    itself; the attention took the splash kernels under the stored mask, no
-    `reduce-window` spans a row of keys, and in the rematerialised computations
-    no `top_k` of the indexer, no attention kernel and no product is left.  The 8-row
-    `for_test` clone of the reference check, the tightest program of a
-    16384-token cell (PERF.md, PR 54), plans with the optimizer's two moments
-    beside it under the 16.9 GB the chip's runtime gives (ISSUE 56)."""
-    compiled, counted = _kept_step("keye", "keye-vl-2.0-30b-a3b", "train-dsa-s16384", host.devices, monkeypatch)
-    assert counted["segments"] == counted["sparse_segments"] == 4
-    assert counted["kept_bytes"] == counted["candidates_bytes"] > 2e9
-    peak = _planned_peak(compiled)
-    print(f"the step's planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
-    assert 0.25 * 16.9e9 <= peak <= 15.5e9, f"the step plans {peak / 1e9:.3f} GB"
-    text = compiled.as_text()
-    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
-    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:sparse_index/index_select/", text))) == 4
-    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/", text))) == 4
-    # the alignment's gradients are the kernel's in every layer (PR 59), and its target's (PR 62): a call a chunk loop's body,
-    # and no float32 array of a group's scores or exponentials under the op
-    assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/[^\"]*index_alignment_gradients", text))) == 4
-    targets = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*/(sparse_index(?:_\d+)?)/op\d+:index_alignment/[^\"\n]*selected_attention/[^\"\n]*alignment_target", text)
-    assert len(set(targets)) == 4 and len(targets) == 4 * 8, (len(set(targets)), len(targets))          # eight bands a layer, a call a chunk
-    widths = "|".join(str(keys) for keys in range(2048, 16385, 2048))
-    assert not [line for line in text.splitlines() if "index_alignment" in line and re.search(rf"f32\[(4,)?8,512,({widths})\]", line)]
-    windows = [int(n) for n in re.findall(r"reduce-window\([^\n]*window=\{size=[0-9x]*?x?(\d+) pad", text)]
-    assert max(windows, default=0) < 2048, max(windows)      # no row's statistic is spread as one window over the row
-    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
-    # (the router's own top-8 is made again with its layer, the same choice bit for bit: ISSUE 54; the INDEXER's never)
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name
-                          or (name.endswith("/top_k") and "moe_router" not in name)
-                          or "index_select" in name or "index_alignment" in name]
-    clone, _ = _kept_step("keye", "keye-vl-2.0-30b-a3b", "train-dsa-s16384", host.devices, monkeypatch, check_rows=8)
-    moments = 2 * 4 * 465_391_104
-    beside = _planned_peak(clone) + moments
-    print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
-    assert beside <= 16.9e9, f"the clone plans {_planned_peak(clone) / 1e9:.3f} GB beside {moments / 1e9:.3f} GB of moments"
-
-
-@pytest.mark.slow   # one compile for four devices, ~3 minutes here: run by name (`-m slow`); PERF.md, PR 51, has its readings
-def test_jamba2s_step_on_the_2x2_host_keeps_what_a_chips_room_holds_and_its_planned_peak_leaves_room(host, monkeypatch):
-    """`ai21-jamba2-3b.train-ssm-fsdp4`'s whole step at the published widths
-    on the described 2x2 host, ZeRO-3 over `dp`: of 98 candidates (9.38 GB a
-    chip) the budget (half of what 4.80 GB of state leave of the chip) holds
-    68, 6.04 GB: the attention's residuals and every product but the 13 step
-    projections and the last layer's `up`; the 13 scans' forward kernels are
-    still made again (their output and start states come last by operations a
-    byte).  Planned under 14.5 GB a chip."""
-    compiled, counted = _kept_step("jamba", "ai21-jamba2-3b", "train-ssm-fsdp4", host.devices, monkeypatch)
-    assert counted == {"segments": 14, "kept_values": 68, "kept_bytes": 6035210240, "candidates_bytes": 9382264832}
-    peak = _planned_peak(compiled)
-    print(f"planned peak {peak / 1e9:.3f} GB a chip")
-    assert 11e9 <= peak <= 14.5e9, peak
-    again = _made_again(compiled.as_text())
-    assert sum(name.endswith("/selective_scan/pallas_call") for name in again) >= 13
-    assert not [name for name in again if name.endswith("/pallas_call") and "selective_scan" not in name]   # the attention's is kept
-
-
-# -- ISSUE 60: the scalar-decay scan and the latent experts under the (4,) mesh, at Nemotron-3-Super's widths ------------
-
-#: one row of 8192 positions a chip: 128 heads of 64, a state of 128 in 8 groups, chunks of 128 (x, B, C bf16; dt bf16)
-SSD_SPECS = [((1, 8192, 8192), BF16), ((1, 8192, 128), BF16), ((128,), F32), ((1, 8192, 1024), BF16), ((1, 8192, 1024), BF16),
-             ((128,), F32), ((128,), F32)]
-
-
-def _ssd(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
-    from paddle_tpu.ops.ssd_ops import chunked_ssd_scan
-
-    return chunked_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, 8, 128)[0]
-
-
-@pytest.mark.parametrize("way", ["forward", "backward"])
-def test_the_scalar_decay_scan_compiles_for_v5e_at_nemotron3s_widths(way, chip):
-    """`ssd_scan`'s chunked form (plain `jax.numpy`: no Mosaic kernel on THAT
-    path, the CPU's and the odd shapes'; the chip's own path at these widths is
-    the next test's) for one
-    described chip: the 64 chunks' carried state is ONE `while` of 64 steps
-    forward (its transpose a second one backward), the intra-chunk work batched
-    products, and what it plans beside its operands stays under 4 GB a row."""
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in SSD_SPECS]
-    program = _ssd if way == "forward" else _backward(_ssd, (0, 1, 2, 3, 4, 5, 6))
-    compiled = jax.jit(program).lower(*args).compile()
-    text = compiled.as_text()
-    whiles = len(re.findall(r"= [^\n]* while\(", text))
-    temporaries = compiled.memory_analysis().temp_size_in_bytes
-    print(f"ssd_scan {way}: {whiles} while(s), temporaries {temporaries / 1e9:.3f} GB")
-    assert 1 <= whiles <= (1 if way == "forward" else 3), whiles
-    assert temporaries < (2.5e9 if way == "forward" else 4.5e9), temporaries
-    assert "tpu_custom_call" not in text
-
-
-def _ssd_kernels(x, dt, a_log, b_t, c_t, d_skip, dt_bias):
-    from paddle_tpu.ops import ssd_ops
-
-    assert ssd_ops._scan_path("tpu", None, x, a_log, b_t, 8, 128) == "kernels"
-    return ssd_ops.kernel_ssd_scan(x, dt, a_log, b_t, c_t, d_skip, dt_bias, 8, 128, "tpu")[0]
-
-
-@pytest.mark.parametrize("way", ["forward", "backward"])
-def test_the_scalar_decay_scans_kernels_compile_for_v5e_at_nemotron3s_widths(way, chip):
-    """What `_scan_path` takes on the chip at these widths (ISSUE 61): the two
-    kernels of `ops/ssd_kernels.py`, a group's sixteen heads a grid step in
-    eight slabs of two.  One Mosaic call forward, two backward (the forward
-    that keeps the chunks' start states, the transposed one), no `while` round
-    the chunks (the chunk axis is the kernels' grid), and beside its operands
-    the op plans only what it hands on: nothing forward, the start states
-    ([64 chunks, 128 heads, 64, 128] float32, 0.27 GB) and the kernels' small
-    operands backward, where the plain form plans 2.0 | 3.5 GB.  The kernels fit
-    the scoped VMEM they ask for or Mosaic would refuse them here."""
-    from paddle_tpu.ops import ssd_kernels
-
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in SSD_SPECS]
-    program = _ssd_kernels if way == "forward" else _backward(_ssd_kernels, (0, 1, 2, 3, 4, 5, 6))
-    compiled = jax.jit(program).lower(*args).compile()
-    text = compiled.as_text()
-    calls, whiles = text.count("tpu_custom_call"), len(re.findall(r"= [^\n]* while\(", text))
-    temporaries = compiled.memory_analysis().temp_size_in_bytes
-    print(f"ssd_scan's kernels {way}: {calls} Mosaic call(s), {whiles} while(s), temporaries {temporaries / 1e9:.3f} GB")
-    assert (calls, whiles) == ((1, 0) if way == "forward" else (2, 0)), (calls, whiles)
-    assert temporaries < (0.1e9 if way == "forward" else 1e9), temporaries
-    assert ssd_kernels._SEMANTICS.vmem_limit_bytes <= 100 * 2 ** 20       # of the v5e's 128 MiB
-
-
-def test_the_latent_experts_under_the_rows_only_mesh_compile_for_the_2x2_host_with_the_kernels_on_a_chips_own_rows(host):
-    """`moe_experts` at the cell's widths (a row of 8192 tokens a chip in the
-    latent of 1024, 22 of 512 a token, experts 0-31 held as [32, 1024, 2688] and
-    [32, 2688, 1024] float32 stacks split four ways along their first dimension)
-    under the described host's (4,) mesh: the op runs in a `shard_map` over
-    `dp`, the grouped products and the way back are Mosaic kernels on a chip's
-    own rows, and the stacks are gathered whole (ZeRO-3's gather)."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from paddle_tpu.core.lowering import LoweringContext
-    from paddle_tpu.core.registry import get_op_def
-
-    mesh = Mesh(np.array(host.devices), ("dp",))
-    attrs = {"held": [0, 32], "gated": False, "activation": "relu2", "num_experts": 512, "top_k": 22}
-    op = SimpleNamespace(type="moe_experts", attr=lambda name, default=None: attrs.get(name, default))
-
-    def layer(x, top_p, top_i, load, w_up, w_down):
-        ctx = LoweringContext(jax.random.PRNGKey(0), platform="tpu", mesh=mesh, batch_axis="dp")
-        ins = {"X": [x], "TopKProb": [top_p], "TopKIndex": [top_i], "Load": [load], "WUp": [w_up], "WDown": [w_down]}
-        outs = get_op_def("moe_experts").lower(ctx, op, ins)
-        return outs["Out"], outs["Held"], outs["Dropped"]
-
-    rows, whole = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
-    specs = [((4, 8192, 1024), BF16, rows), ((4, 8192, 22), F32, rows), ((4, 8192, 22), I32, rows), ((512,), I32, whole),
-             ((32, 1024, 2688), F32, rows), ((32, 2688, 1024), F32, rows)]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in specs]
-    for program in (layer, jax.grad(lambda *a: jnp.sum(layer(*a)[0].astype(F32)), argnums=(0, 4, 5))):
-        text = jax.jit(program).lower(*args).compile().as_text()
-        assert text.count("tpu_custom_call") >= 3, "the grouped products and the way back are kernels on a chip's rows"
-        assert "all-gather" in text
-    assert "reduce-scatter" in text or "all-reduce" in text      # the stacks' gradients, summed over the chips
-
-
-@pytest.mark.slow   # two compiles for four devices, ~6 minutes here: run by name (`-m slow -k nemotron3`); PERF.md, PR 60, has its readings
-def test_nemotron3_supers_step_and_its_check_rows_on_the_2x2_host_leave_room(host, monkeypatch):
-    """`nemotron-3-super-120b-a12b.train-ssd-fsdp4`'s whole step at the published
-    widths on the described 2x2 host, ZeRO-3 over `dp`, 32 experts held a layer
-    (7.49 GB a chip of state), and the 8-row `for_test` clone its reference
-    check runs beside that state: both planned under the chip's 16.9 GB.  Since
-    PR 61 the five scans are kernels whose residuals (the output and the
-    chunks' start states, 0.40 GB a layer) `plan_kept` holds with every other
-    candidate: 34 values, 4.89 GB a chip (29 and 2.88 with the plain form, which
-    offered nothing), planned 14.47 GB (13.46), and no scan is made again."""
-    compiled, counted = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch)
-    peak = _planned_peak(compiled)
-    print(f"step: planned peak {peak / 1e9:.3f} GB a chip, kept {counted}")
-    assert counted == {"segments": 11, "sparse_segments": 5, "kept_values": 34, "kept_bytes": 4891082752, "candidates_bytes": 4891082752}
-    assert 14.2e9 <= peak <= 14.8e9, peak
-    text = compiled.as_text()
-    assert text.count("all-gather") and "tpu_custom_call" in text
-    assert not [name for name in _made_again(text) if "ssd_scan" in name and name.endswith("/pallas_call")]
-    clone, _ = _kept_step("nemotron_h", "nemotron-3-super-120b-a12b", "train-ssd-fsdp4", host.devices, monkeypatch, check_rows=8)
-    moments = 2 * 4 * 1871531904 / 4     # Adam's two float32 moments lie beside the clone's own arguments, split four ways
-    beside = _planned_peak(clone) + moments
-    print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
-    assert beside <= 16.9e9, beside

@@ -6,8 +6,11 @@ PR 51: its printed line for BERT's `pretrain-s128` cell is held to what
 written for one `chiprun` call and no test imported them: each is run here the
 way its own docstring says to rehearse it (`DRY=1`: tiny sizes, the kernels
 interpreted, no time worth reading), so that a PR which breaks a tool finds out
-before it spends a chip call on it.  `chip_smoke.py` has `tests/test_chip_smoke.py`,
-the controls of Ouro, Kimi Linear, Jamba, Kanana-2 and Keye-VL-2.0 and
+before it spends a chip call on it.  This file has the kernels' tools; the
+`chip_*_controls` tools that have no other rehearsal are in
+`tests/test_chip_controls.py`, a file of their own so that two workers share the
+subprocesses (ISSUE 66).  `chip_smoke.py` has `tests/test_chip_smoke.py`, the
+controls of Ouro, Kimi Linear, Jamba, Kanana-2 and Keye-VL-2.0 and
 `chip_latent_edges.py` have their cells' own test files.
 """
 import hashlib
@@ -43,49 +46,35 @@ def test_lowered_hash_prints_the_pinned_program_of_berts_s128_cell(capsys):
     assert sha == "b782cbe3abb7d3330242baf72181dda127cee798d7a53bd66c3b90f2107ec9bd" != pinned_sha
 
 
-#: tool -> its arguments in a rehearsal (a seed; seeds and steps).  Each reads `DRY=1` as it is imported (its sizes
-#: are module constants), so each is a process of its own.
+def rehearsed(tool, *arguments, **environment):
+    """The JSON lines `DRY=1 python3 tools/<tool>.py <arguments>` prints.  Each tool reads `DRY=1` as it is imported (its
+    sizes are module constants), so each is a process of its own."""
+    out = subprocess.run([sys.executable, os.path.join("tools", tool + ".py"), *arguments], cwd=REPO, capture_output=True,
+                         text=True, timeout=600, stdin=subprocess.DEVNULL,
+                         env=dict(os.environ, DRY="1", JAX_PLATFORMS="cpu", **environment))
+    assert out.returncode == 0, out.stderr[-3000:]
+    readings = [json.loads(line) for line in out.stdout.splitlines() if line.startswith("{")]
+    assert readings, out.stdout[-2000:]                  # every one of them reports in JSON lines
+    return readings
+
+
+#: tool -> its arguments in a rehearsal (seeds)
 REHEARSED = {
     "chip_alignment_target": (),
     "chip_block_attention": (),
     "chip_held_experts": (),
     "chip_index_alignment": (),
     "chip_index_select": (),
-    "chip_laguna_controls": ("1", "gate_in_bf16", "window_of_17"),   # the sound run, a lowering wrapped by its name scope, a program built again
-    "chip_lfm2_controls": ("1",),
-    "chip_nemotron_controls": ("1", "scan_wrong_group", "relu_for_relu2"),   # the sound run, a fault in the program, one in the reference
-    "chip_phi4flash_controls": ("1",),
     "chip_row_attention": (),
     "chip_sdar_routing": ("1", "2"),
-    "chip_smallthinker_controls": ("1", "router_in_bf16", "window_of_17"),   # the sound run, a wrapped lowering, a program built again
     "chip_token_sum": (),
 }
 
 
 @pytest.mark.parametrize("tool", sorted(REHEARSED))
 def test_the_tool_still_runs_at_tiny_sizes_with_its_kernels_interpreted(tool):
-    out = subprocess.run([sys.executable, os.path.join("tools", tool + ".py"), *REHEARSED[tool]], cwd=REPO, capture_output=True,
-                         text=True, timeout=600, stdin=subprocess.DEVNULL,
-                         env=dict(os.environ, DRY="1", JAX_PLATFORMS="cpu"))
-    assert out.returncode == 0, out.stderr[-3000:]
-    readings = [json.loads(line) for line in out.stdout.splitlines() if line.startswith("{")]
-    assert readings, out.stdout[-2000:]                  # every one of them reports in JSON lines
+    readings = rehearsed(tool, *REHEARSED[tool])
     assert not [r for r in readings if r.get("error")], readings
-
-
-def test_chip_nemotron_controls_op_alone_mode_rehearses_with_the_kernels_interpreted():
-    """`ONLY=profile python3 tools/chip_nemotron_controls.py` (ISSUE 61: the scan alone, its kernels beside the plain
-    form): tiny and interpreted it times nothing, and its two readings of how far the kernels lie from the plain form
-    and both from the recurrence are float32's rounding."""
-    out = subprocess.run([sys.executable, os.path.join("tools", "chip_nemotron_controls.py")], cwd=REPO, capture_output=True, text=True,
-                         timeout=600, stdin=subprocess.DEVNULL, env=dict(os.environ, DRY="1", ONLY="profile", JAX_PLATFORMS="cpu"))
-    assert out.returncode == 0, out.stderr[-3000:]
-    readings = {r["reading"] + r.get("form", ""): r for r in map(json.loads, filter(lambda line: line.startswith("{"), out.stdout.splitlines()))}
-    apart = readings["ssd_kernels_from_plain"]
-    assert max(apart["y"], apart["state"], apart["y_kept"], *apart["means"]) < 1e-6 and max(apart["d_a_log"], apart["d_d"], apart["d_dt_bias"]) < 1e-4
-    assert max(apart[k] for k in ("d_x", "d_dt", "d_b", "d_c")) < 1e-2                   # bf16 gradients: a step of theirs
-    for form in ("kernels", "plain"):
-        assert readings["ssd_against_the_recurrence" + form]["scan_state_error"] < 1e-5
 
 
 def test_chip_kimi_kernels_ops_agree_at_tiny_sizes_with_the_kernels_interpreted():

@@ -158,12 +158,18 @@ def test_watchdog_expiry_blackbox_and_live_straggler_naming(tmp_path):
     # must clear a cold XLA compile (~3s, worse on a loaded box): the
     # watchdog guards EVERY blocking dispatch, compiles included, and a
     # deadline under compile time fires before the stall even happens.
+    # Whether it did is read from the run's own record, not from a clock:
+    # the expiry names the train step it blocked at, and only one AT the
+    # stall's step is the incident under test (an earlier one, a compile
+    # that outlasted the deadline on a loaded box, proves nothing).
+    stall_step = 2
     res, tel = _retry(
-        tmp_path, "stall", "stall_worker@2:1:20",
+        tmp_path, "stall", f"stall_worker@{stall_step}:1:20",
         extra={"FLAGS_dist_watchdog_timeout_s": "8",
                "FLAGS_dist_heartbeat_interval_s": "0.1",
                "FLAGS_dist_heartbeat_miss_factor": "150"},
-        fired=lambda r: "exceeded watchdog deadline" in _worker_stderr(r))
+        fired=lambda r: "exceeded watchdog deadline" in _worker_stderr(r)
+        and f"[step={stall_step}, phase=collective]" in _worker_stderr(r))
     assert not res.ok
     boxes = _blackboxes(tel)
     assert 0 in boxes, sorted(boxes)

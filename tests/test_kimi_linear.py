@@ -1,14 +1,10 @@
 """Kimi-Linear-48B-A3B's parts and the whole, tiny on the CPU (ISSUE 42).
 
-(a) the op `kda`, chunk by chunk, against the token-by-token recurrence:
-    forward, the final state and the hand-written backward against `jax.grad`
-    of the recurrence, at several chunk counts, at mild and at strong decay
-    (g = -20 a token: finite everywhere), and the faults the benchmark's stage
-    has to refuse; `kda_gate`; `infer=`, the planner rows, `analysis.verify`;
-    since ISSUE 44 the Pallas kernels of `ops/kda_kernels.py`, interpreted,
-    against both, the rule that takes them and the counter that says so;
-    since ISSUE 45 the chunks' start states that the differentiated forward
-    keeps for backward, the two kernel calls of a gradient and their counter;
+(a) the op `kda`: its `jax.numpy` form against the token-by-token recurrence,
+    its stage's faults, `kda_gate`, `infer=`, the planner rows and
+    `analysis.verify` stand in `tests/test_kda_op.py`, its Pallas kernels
+    (`ops/kda_kernels.py`, interpreted) in `tests/test_kda_kernels.py`; this file
+    keeps `lower`, `agree` and the float32 products they take;
 (b) `short_conv`'s plain mode against four shifted multiply-adds, forward and
     gradients, and the gated mode's lowered text unchanged;
 (c) `fused_attention` with values of another width than queries and keys
@@ -43,7 +39,6 @@ from paddle_tpu import layers, monitor  # noqa: E402
 from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
-from paddle_tpu.ops import kda_kernels  # noqa: E402
 from paddle_tpu.ops import linear_attention_ops as lao  # noqa: E402
 from paddle_tpu.ops import nn_ops  # noqa: E402
 
@@ -67,390 +62,6 @@ def agree(got, want, tol=1e-5, floor=1e-12):
 def float32_products():
     with jax.default_matmul_precision("highest"):
         yield
-
-
-# -- (a) the chunked recurrence ---------------------------------------------------------
-
-def scan_inputs(seed, rows, length, heads, width, v_width, decay):
-    """q, k unit a head, v, a log decay of `decay` x |N(0, 1)| a channel (or
-    exactly -`decay` a token where `decay` >= 20) and beta in (0, 1)."""
-    r = np.random.RandomState(seed)
-    q, k = (r.randn(rows, length, heads, width).astype("f4") for _ in range(2))
-    q, k = (t / np.linalg.norm(t, axis=-1, keepdims=True) for t in (q, k))
-    v = r.randn(rows, length, heads, v_width).astype("f4")
-    g = -decay * (np.ones_like(q) if decay >= 20 else np.abs(r.randn(rows, length, heads, width))).astype("f4")
-    beta = (1 / (1 + np.exp(-r.randn(rows, length, heads)))).astype("f4")
-    return tuple(jnp.asarray(t) for t in (q, k, v, g, beta))
-
-
-def recurrence_with_state(q, k, v, g, beta):
-    """(o, the state after the last token) of the recurrence, a token at a time."""
-    def step(S, token):
-        q_t, k_t, v_t, g_t, beta_t = token
-        S = S * jnp.exp(g_t)[..., None]
-        S = S + (beta_t[..., None] * k_t)[..., None] * (v_t - jnp.einsum("rhkv,rhk->rhv", S, k_t))[..., None, :]
-        return S, jnp.einsum("rhkv,rhk->rhv", S, q_t)
-
-    tokens = tuple(t.swapaxes(0, 1) for t in (q, k, v, g, beta))
-    S, o = jax.lax.scan(step, jnp.zeros(k.shape[:1] + k.shape[2:] + v.shape[-1:]), tokens)
-    return o.swapaxes(0, 1), S
-
-
-CASES = [  # rows, length, chunk, decay
-    (2, 64, 64, 0.1), (1, 128, 32, 1.0), (2, 256, 64, 0.02), (1, 64, 16, 20.0), (1, 128, 64, 20.0),
-    (1, 48, 24, 3.0), (1, 1024, 64, 0.3), (2, 16, 16, 0.5), (1, 4, 4, 0.5)]
-
-
-@pytest.mark.parametrize("rows,length,chunk,decay", CASES)
-def test_the_chunked_recurrence_is_the_recurrence_forward_and_backward(rows, length, chunk, decay):
-    """The chunked form, whatever the chunk (one chunk, many, a group of chunks
-    at a time from 16 chunks on, blocks of 16, 4 and 1 or fewer levels), gives
-    the token-by-token recurrence's output and final state, and its hand-written
-    backward `jax.grad` of the recurrence, for all five inputs.  At g = -20 a
-    token (alpha = 2e-9) everything is finite and still the recurrence."""
-    args = scan_inputs(length + chunk, rows, length, 3, 8, 5, decay)
-    blocks = lao._blocks_of(chunk)
-
-    def op(*a):
-        return lao.chunked_kda(*a[:4], a[4][..., None], chunk, blocks)
-
-    out, state = op(*args)
-    want, want_state = recurrence_with_state(*args)
-    assert np.isfinite(np.asarray(out)).all() and np.isfinite(np.asarray(state)).all()
-    agree(out, want, tol=2e-5)
-    agree(state, want_state, tol=2e-5)
-    weigh = jnp.asarray(np.random.RandomState(1).randn(*out.shape).astype("f4"))
-    got = jax.grad(lambda *a: jnp.sum(op(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
-    ref = jax.grad(lambda *a: jnp.sum(recurrence_with_state(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
-    for name, mine, theirs in zip("q k v g beta".split(), got, ref):
-        assert np.isfinite(np.asarray(mine)).all(), name
-        # at alpha = 2e-9 the decay's own gradient is of the order of 1e-9 and lost to underflow on either side
-        agree(mine, theirs, tol=5e-5, floor=1e-3 if decay >= 20 and name == "g" else 1e-12)
-
-
-def test_no_exponent_is_positive_in_a_channel_that_dies_in_one_token():
-    """One token forgets a channel outright (g = -100 there) between mild
-    decays: the pairs on either side of it are still exact, where a Gram
-    factored about the chunk's start would meet exp(100)."""
-    q, k, v, g, beta = scan_inputs(7, 1, 64, 2, 8, 8, 0.05)
-    g = g.at[:, 5, :, 3].set(-100.0).at[:, 37, :, :2].set(-60.0)
-    out, state = lao.chunked_kda(q, k, v, g, beta[..., None], 64, lao._blocks_of(64))
-    want, want_state = recurrence_with_state(q, k, v, g, beta)
-    agree(out, want, tol=2e-5)
-    agree(state, want_state, tol=2e-5)
-
-
-def dying_channel_inputs():
-    """Two heads, two chunks, and a channel that one token of chunk 0 forgets outright."""
-    q, k, v, g, beta = scan_inputs(7, 1, 128, 2, 8, 8, 0.05)
-    return q, k, v, g.at[:, 5, :, 3].set(-100.0).at[:, 37, 0, :2].set(-60.0), beta
-
-
-def test_no_exponent_is_positive_in_the_kernels_either():
-    """The same dying channel through the kernels, which take the block's own
-    pairs from their differences for the (heads, chunk) that hold it and the
-    carried-back products for the others: output, state and every gradient
-    finite, and the `jax.numpy` form's and the recurrence's."""
-    q, k, v, g, beta = dying_channel_inputs()                             # chunk 1 is mild in both heads
-    weigh = jnp.asarray(np.random.RandomState(1).randn(*v.shape).astype("f4"))
-
-    def through(kernels):
-        op = lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, kernels)
-        return op(q, k, v, g, beta), jax.grad(lambda *a: jnp.sum(op(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
-
-    (out, state), grads = through("interpret")
-    (plain, plain_state), plain_grads = through(None)
-    want, want_state = recurrence_with_state(q, k, v, g, beta)
-    for mine, theirs in ((out, want), (state, want_state), (out, plain), (state, plain_state)) + tuple(zip(grads, plain_grads)):
-        assert np.isfinite(np.asarray(mine)).all()
-        agree(mine, theirs, tol=3e-5)
-
-
-KERNEL_CASES = [  # rows, length, heads, dtype, decay, beta
-    (1, 64, 2, "float32", 0.1, None), (2, 128, 3, "float32", 1.0, None), (1, 256, 4, "float32", 0.02, None),
-    (2, 64, 3, "bfloat16", 0.3, None), (1, 128, 2, "bfloat16", 0.05, None), (1, 128, 2, "float32", 20.0, None),
-    (1, 64, 3, "float32", 0.5, 0.0), (2, 128, 2, "float32", 0.2, 0.999), (1, 256, 8, "bfloat16", 0.1, None)]
-
-
-@pytest.mark.parametrize("rows,length,heads,dtype,decay,beta", KERNEL_CASES)
-def test_the_kernels_are_the_jax_numpy_form_and_the_recurrence(rows, length, heads, dtype, decay, beta):
-    """`ops/kda_kernels.py`, interpreted: a step's terms (Phi, B, Qe, P U) are
-    `_chunk_terms`'; the op through the kernels (one, two, four heads a grid
-    step; the state carried in scratch; the chunks in reverse for backward)
-    gives the `jax.numpy` form's output, final state and five gradients and
-    the token-by-token recurrence's (`benchmark/models/kimi_linear.py:
-    kda_recurrence`), at float32, from float32 and from bf16 inputs, at a mild
-    decay and at g = -20 a token (every output finite), beta drawn, 0 and near 1."""
-    q, k, v, g, b = scan_inputs(length + heads, rows, length, heads, 8, 8, decay)
-    b = b if beta is None else jnp.full_like(b, beta)
-    q, k, v = (t.astype(dtype) for t in (q, k, v))
-    # (a) the terms, as the kernels make them in VMEM (plain jax.numpy outside a kernel), chunk 0 of row 0
-    chunks = kda_kernels._Chunks([tuple(t[0, :64, h].astype(jnp.float32) for t in (q, k, v, g)) + (b[0, :64, h, None],)
-                                  for h in range(heads)], 16, lao._KDA_SAFE, lao._kernel_seams())
-    want = lao._chunk_terms(q[0, :64], k[0, :64], v[0, :64], g[0, :64], b[0, :64, :, None], 64, 16)
-    for mine, theirs in zip((chunks.phi, chunks.B, chunks.q_eff, chunks.own_out), want):
-        # exp of two float32 sums of 64 terms, each summed in its own order; a term that cancels to 1e-6 (beta near 1) by its parts' size
-        agree(jnp.stack(mine), theirs[0], tol=1e-4, floor=1e-3)
-
-    # (b) the op
-    def through(kernels):
-        return lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, kernels)
-
-    out, state = through("interpret")(q, k, v, g, b)
-    plain, plain_state = through(None)(q, k, v, g, b)
-    floats = tuple(t.astype(jnp.float32) for t in (q, k, v)) + (g, b)
-    recurred = kimi_linear.kda_recurrence(*floats)
-    assert out.dtype == v.dtype and state.dtype == jnp.float32
-    assert np.isfinite(np.asarray(out, "f4")).all() and np.isfinite(np.asarray(state)).all()
-    agree(state, plain_state, tol=5e-5)
-    agree(state, recurrence_with_state(*floats)[1], tol=5e-5)
-    agree(out, plain, tol=5e-5 if dtype == "float32" else 8e-3)      # a bf16 output rounds once, either way
-    agree(out, recurred, tol=5e-5 if dtype == "float32" else 8e-3)
-    # (c) the gradients, of float32 inputs (a bf16 cotangent rounds each form's sum at another place)
-    weigh = jnp.asarray(np.random.RandomState(1).randn(*out.shape).astype("f4"))
-    grads = [jax.grad(lambda *a: jnp.sum(fn(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*floats)
-             for fn in (through("interpret"), through(None), recurrence_with_state)]
-    for name, mine, theirs, recurrences in zip("q k v g beta".split(), *grads):
-        assert np.isfinite(np.asarray(mine)).all(), name
-        # as above: lost to underflow on either side; the kernels' x . dx - k . dk leaves float32's rounding of two O(1) terms
-        floor = 1e-2 if decay >= 20 and name == "g" else 1e-12
-        agree(mine, theirs, tol=5e-5, floor=floor)
-        agree(mine, recurrences, tol=5e-5, floor=floor)
-
-
-@pytest.mark.parametrize("platform,devices,width,v_width,length,path", [
-    ("tpu", 1, 128, 128, 4096, "kernels"), ("tpu", None, 128, 128, 64, "kernels"), ("tpu", 1, 256, 128, 128, "kernels"),
-    ("cpu", 1, 128, 128, 4096, "xla"), (None, None, 128, 128, 4096, "xla"), ("tpu", 1, 64, 64, 4096, "xla"),
-    ("tpu", 1, 128, 64, 4096, "xla"), ("tpu", 1, 128, 128, 32, "xla"), ("tpu", 4, 128, 128, 4096, "xla")])
-def test_the_rule_takes_the_kernels_on_one_tpu_at_whole_lane_tiles_and_nowhere_else(platform, devices, width, v_width, length, path):
-    """`_kda_path` reads the platform, the mesh, the two head widths and the
-    chunk, and nothing else: no flag, environment variable or attribute."""
-    q, v = jax.ShapeDtypeStruct((1, length, 2, width), jnp.bfloat16), jax.ShapeDtypeStruct((1, length, 2, v_width), jnp.bfloat16)
-    mesh = None if devices is None else SimpleNamespace(size=devices)
-    assert lao._kda_path(platform, mesh, q, v, min(lao._KDA_CHUNK, length)) == path
-    import inspect
-    assert not re.search(r"environ|getenv|FLAGS|\.attr\(", inspect.getsource(lao._kda_path) + inspect.getsource(lao._kda))
-
-
-KEPT_CASES = [KERNEL_CASES[1], KERNEL_CASES[3], KERNEL_CASES[5], KERNEL_CASES[7], KERNEL_CASES[8], "a_channel_dies"]
-
-
-@pytest.mark.parametrize("case", KEPT_CASES, ids=lambda c: c if isinstance(c, str) else "-".join(map(str, c)))
-def test_the_differentiated_forward_keeps_the_chunks_start_states_and_is_the_plain_call(case):
-    """Under `jax.vjp` the kernels' forward (`_chunked_kda_fwd`: ONE `kda_scan`
-    call with two more outputs) gives the plain call's o and final state to
-    the bit, and what it keeps beside the five inputs is the state every chunk
-    starts from, [n, b, H, K, V] float32: zero, then Phi_c S_c + B_c of
-    `_chunk_terms` chunk after chunk up to the final state, the `jax.numpy`
-    form's `_states`; and T, unit lower triangular, the inverse of I + beta M,
-    laid out [n, b, H, C / 2, 2 C] (its upper rows beside its lower: a whole
-    lane tile wide).  Backward reads them and makes neither."""
-    if case == "a_channel_dies":
-        q, k, v, g, b = dying_channel_inputs()
-    else:
-        rows, length, heads, dtype, decay, beta = case
-        q, k, v, g, b = scan_inputs(length + heads, rows, length, heads, 8, 8, decay)
-        b = b if beta is None else jnp.full_like(b, beta)
-        q, k, v = (t.astype(dtype) for t in (q, k, v))
-    op = lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, "interpret")  # noqa: E731
-    out, final = op(q, k, v, g, b)
-    (under_vjp, final_under_vjp), _ = jax.vjp(op, q, k, v, g, b)
-    (kept_out, kept_final), (inputs, (starts, inverses)) = lao._chunked_kda_fwd(q, k, v, g, b[..., None], 64, 16, "interpret")
-    for mine, plain in ((under_vjp, out), (final_under_vjp, final), (kept_out, out), (kept_final, final)):
-        assert mine.dtype == plain.dtype and (np.asarray(mine, "f4") == np.asarray(plain, "f4")).all()
-    assert len(inputs) == 5 and all(kept is given for kept, given in zip(inputs[:4], (q, k, v, g)))
-    (rows, length, heads, width), n = k.shape, k.shape[1] // 64
-    assert starts.shape == (n, rows, heads, width, v.shape[-1]) and starts.dtype == jnp.float32
-    assert inverses.shape == (n, rows, heads, 32, 128) and inverses.dtype == jnp.float32
-    assert np.isfinite(np.asarray(starts)).all() and not np.asarray(starts[0]).any()
-    for row in range(rows):
-        phi, B, _, _ = lao._chunk_terms(q[row], k[row], v[row], g[row], b[row, :, :, None], 64, 16)
-        follows = jnp.concatenate([starts[1:, row], final[None, row]])          # what each chunk hands on
-        scale = max(float(jnp.abs(follows).max()), 1e-12)
-        for c in range(n):
-            assert float(jnp.abs(lao._mm("hkj,hjv->hkv", phi[c], starts[c, row]) + B[c] - follows[c]).max()) <= 5e-5 * scale
-        plain_starts, plain_final = lao._states(phi, B)
-        agree(starts[:, row], plain_starts, tol=5e-5, floor=1e-6)
-        agree(final[row], plain_final, tol=5e-5)
-        for c in range(n):
-            at = slice(64 * c, 64 * (c + 1))
-            terms = kda_kernels._Chunks([tuple(t[row, at, h].astype(jnp.float32) for t in (q, k, v, g)) + (b[row, at, h, None],)
-                                         for h in range(heads)], 16, lao._KDA_SAFE, lao._kernel_seams())
-            for h in range(heads):
-                T = np.asarray(kda_kernels._halves_stacked(inverses[c, row, h]), "f8")
-                assert (np.asarray(kda_kernels._halves_side_by_side(T)) == np.asarray(inverses[c, row, h])).all()
-                assert (np.triu(T, 1) == 0).all() and (np.diag(T) == 1).all()
-                agree((np.eye(64) + np.asarray(terms.beta[h] * terms.M[h], "f8")) @ T, np.eye(64), tol=2e-5)
-    # the xla path keeps the five inputs alone
-    assert lao._chunked_kda_fwd(q, k, v, g, b[..., None], 64, 16, None)[1][1] == ()
-
-
-def pallas_calls(jaxpr):
-    """(name, outputs' shapes) of every `pallas_call` of a jaxpr, its sub-jaxprs' too."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], [v.aval.shape for v in eqn.outvars]))
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            found += pallas_calls(inner)
-    return found
-
-
-@pytest.mark.parametrize("rows,length,heads", [(1, 128, 2), (2, 256, 4), (1, 64, 3)])
-def test_the_gradient_is_two_kernel_calls_and_the_plain_op_one_with_two_outputs(rows, length, heads):
-    """What is traced for the TPU: the op's gradient holds ONE `kda_scan` (o,
-    the final state, the chunks' start states and T) and ONE
-    `kda_scan_transposed`, and no call that makes the start states again; the
-    plain op ONE `kda_scan` that writes o and the final state and keeps nothing."""
-    q, k, v, g, b = scan_inputs(3, rows, length, heads, 128, 128, 0.1)
-    op = lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, "tpu")  # noqa: E731
-    n, o, final = length // 64, (rows, length, heads * 128), (rows, heads, 128, 128)
-    assert pallas_calls(jax.make_jaxpr(op)(q, k, v, g, b).jaxpr) == [("kda_scan", [o, final])]
-    grad = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(op(*a)[0]), argnums=(0, 1, 2, 3, 4)))(q, k, v, g, b)
-    calls = pallas_calls(grad.jaxpr)
-    assert [name for name, _ in calls] == ["kda_scan", "kda_scan_transposed"] and "kda_scan_starts" not in str(grad)
-    assert calls[0][1] == [o, final, (n, rows, heads, 128, 128), (n, rows, heads, 32, 128)]
-    assert calls[1][1] == [o, o, o, o, (rows, n, -(-heads // kda_kernels._heads_a_step(heads)), kda_kernels._heads_a_step(heads), 64)]
-
-
-def kda_lowered(platform):
-    """The op `kda`'s lowering for `platform`, as a function of its inputs."""
-    op = SimpleNamespace(type="kda", attr=lambda n, d=None: d)
-    ctx = LoweringContext(jax.random.PRNGKey(0), platform=platform)
-    return lambda ins: get_op_def("kda").lower(ctx, op, ins)["Out"]
-
-
-@pytest.mark.parametrize("platform,differentiated,kept", [("tpu", True, 1), ("tpu", False, 0), ("cpu", True, 0), ("cpu", False, 0)])
-def test_the_counter_says_whose_forward_kept_its_start_states(platform, differentiated, kept):
-    """`lowering.kda_starts_kept` counts, at trace time, the `kda` ops whose
-    forward wrote the chunks' start states for backward: the kernels' where the
-    op is differentiated (`custom_vjp`'s forward rule), and no other: not a
-    plain call (the `for_test` clone, inference), not the `jax.numpy` form."""
-    q, k, v, g, beta = scan_inputs(5, 1, 64, 2, 128, 128, 0.1)
-    ins = {n: [jnp.asarray(t)] for n, t in zip(("Q", "K", "V", "G", "Beta"), (q, k, v, g, beta))}
-    fn = lambda ins: jnp.sum(kda_lowered(platform)(ins))  # noqa: E731
-    monitor.reset()
-    monitor.enable()
-    try:
-        jax.make_jaxpr(jax.grad(fn) if differentiated else fn)(ins)                # traced for the platform, not run
-        counters = monitor.get_monitor().counter_values()
-    finally:
-        monitor.disable()
-        monitor.reset()
-    assert counters.get("lowering.kda_starts_kept", 0) == kept
-    assert counters.get("lowering.kda_kernel_calls", 0) == (platform == "tpu")
-    assert counters.get("lowering.kda_kernel_transposed_calls", 0) == (platform == "tpu" and differentiated)
-    assert counters["lowering.kda_layers"] == 1
-
-
-def test_the_counter_says_which_kda_ops_took_the_kernels():
-    """`lowering.kda_kernel_calls` counts, at trace time, the `kda` ops whose
-    lowering took the kernels (`lowering.kda_kernel_transposed_calls` their
-    backward, `lowering.kda_starts_kept` the forwards that kept the chunks'
-    start states for it): one on the TPU at 128-wide heads, none off it, where
-    the op's numbers are the `jax.numpy` form's to the bit."""
-    q, k, v, g, beta = scan_inputs(5, 1, 64, 2, 128, 128, 0.1)
-    ins = {n: [jnp.asarray(t)] for n, t in zip(("Q", "K", "V", "G", "Beta"), (q, k, v, g, beta))}
-    lowered = kda_lowered
-    counter = lambda name: monitor.get_monitor().counter_values().get(name, 0)
-    monitor.reset()
-    monitor.enable()
-    try:
-        traced = jax.make_jaxpr(jax.grad(lambda ins: jnp.sum(lowered("tpu")(ins))))(ins)       # traced for the TPU, not run
-        assert counter("lowering.kda_kernel_calls") == 1 and counter("lowering.kda_kernel_transposed_calls") == 1
-        assert counter("lowering.kda_layers") == 1 and counter("lowering.kda_starts_kept") == 1
-        assert str(traced).count("pallas_call") == 2 and "kda_scan_transposed" in str(traced)      # o with what is kept, the transpose
-        out = lowered("cpu")(ins)
-        assert counter("lowering.kda_kernel_calls") == 1 and counter("lowering.kda_layers") == 2
-        assert counter("lowering.kda_starts_kept") == 1
-    finally:
-        monitor.disable()
-        monitor.reset()
-    assert (np.asarray(out) == np.asarray(lao.chunked_kda(q, k, v, g, beta[..., None])[0])).all()
-
-
-def test_the_benchmarks_recurrence_is_the_same_and_a_bf16_state_is_not():
-    args = scan_inputs(3, 2, 96, 2, 8, 8, 0.2)
-    want, _ = recurrence_with_state(*args)
-    agree(kimi_linear.kda_recurrence(*args), want, tol=1e-6)
-    low = kimi_linear.kda_recurrence(*args, bf16_state=True)
-    assert np.abs(np.asarray(low) - np.asarray(want)).max() > 1e-3 * np.abs(np.asarray(want)).max()
-
-
-@pytest.mark.parametrize("fault", ["bf16_state", "bf16_cumulative_decay", "no_decay", "bf16_output_only"])
-def test_the_kda_stage_tells_the_faults_apart(fault, monkeypatch):
-    """The benchmark's KDA stage (`kimi_linear.kda_errors`: the op's output
-    against the recurrence on the op's own inputs) reads the sound op at its
-    output's rounding and each fault above it: the state kept in bf16 from
-    chunk to chunk, the cumulative decay rounded to bf16, Diag(alpha) dropped.
-    (tools/chip_kimi_controls.py shows the same at the published widths against
-    the limit `KDA_RTOL`, which two chip readings set.)"""
-    q, k, v, g, beta = scan_inputs(11, 2, 512, 2, 16, 16, 0.3)
-    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
-    if fault == "bf16_state":
-        def rounded(phi, B):
-            def step(S, term):
-                return jax.lax.reduce_precision(lao._mm("hkj,hjv->hkv", term[0], S) + term[1], 8, 7), S
-            final, starts = jax.lax.scan(step, jnp.zeros(B.shape[1:], jnp.float32), (phi, B))
-            return starts, final
-        monkeypatch.setattr(lao, "_states", rounded)
-    elif fault == "bf16_cumulative_decay":
-        real = lao._cumulative
-        monkeypatch.setattr(lao, "_cumulative", lambda g: jax.lax.reduce_precision(real(g), 8, 7))
-    out = lower("kda", {"Q": q, "K": k, "V": v, "G": 0 * g if fault == "no_decay" else g, "Beta": beta})["Out"]
-    assert out.dtype == jnp.bfloat16
-    found = kimi_linear.kda_errors([(q, k, v, g, beta, out)])
-    if fault == "bf16_output_only":
-        # against the float32 recurrence the output's own rounding is all there is to see (2^-9 / sqrt(3) and more);
-        # against the recurrence rounded alike, only the elements whose last float32 bits cross a rounding boundary
-        assert 0.2 * 2.0 ** -9 < found["kda_error_unrounded"] < 2.0 ** -9, found
-        assert found["kda_error"] < 1e-4 < 3e-4 < found["kda_error_bf16_state"], found
-    else:
-        assert found["kda_error"] > 3e-4, found
-
-
-def test_kda_publishes_its_state_and_kda_gate_is_the_published_decay():
-    q, k, v, g, beta = scan_inputs(5, 1, 32, 2, 8, 8, 0.1)
-    outs = lower("kda", {"Q": q, "K": k, "V": v, "G": g, "Beta": beta})
-    _, state = recurrence_with_state(q, k, v, g, beta)
-    agree(outs["Stats"], [np.exp(np.asarray(g)).mean(), np.asarray(beta).mean(), np.abs(np.asarray(state)).max()], tol=1e-5)
-    x = np.random.RandomState(2).randn(2, 6, 3 * 4).astype("f4")
-    a_log, dt_bias = np.log([1.0, 4.0, 16.0]).astype("f4"), np.random.RandomState(3).randn(12).astype("f4")
-    got = lower("kda_gate", {"X": jnp.asarray(x).astype(jnp.bfloat16), "ALog": a_log, "DtBias": dt_bias})["Out"]
-    assert got.dtype == jnp.float32 and got.shape == (2, 6, 3, 4)
-    rounded = np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
-    agree(got, -np.exp(a_log)[:, None] * np.log1p(np.exp(rounded + dt_bias)).reshape(2, 6, 3, 4), tol=1e-5)
-    assert (np.asarray(got) < 0).all()
-
-
-def test_the_new_ops_have_infer_rules_planner_rows_and_pass_verify():
-    from paddle_tpu.core import analysis, resource_plan
-
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = layers.data("x", [128, 24], dtype="float32")
-        y = transformer.kimi_delta_attention(x, 24, n_heads=2, head_dim=8, prefix="t.kda")
-        z = transformer.latent_attention(y, 24, 2, "t.attn", rank=12, nope_dim=8, rope_dim=4, v_dim=8)
-    assert tuple(y.shape)[1:] == (128, 24) and tuple(z.shape)[1:] == (128, 24)
-    assert [d for d in analysis.verify_program(main, level="full") if d.severity == "error"] == []
-    shapes = {op.type: tuple(main.global_block().var(op.outputs["Out"][0]).shape)[1:]
-              for op in main.global_block().ops if op.type in ("kda", "kda_gate", "short_conv", "fused_attention")}
-    assert shapes == {"kda": (128, 2, 8), "kda_gate": (128, 2, 8), "short_conv": (128, 16), "fused_attention": (128, 2, 8)}
-    plan = resource_plan.plan_program(main, feed_shapes={"x": (2, 128, 24)})
-    rows = {r.op_type: r for r in plan.rows}
-    assert rows["kda"].flops == lao.kda_chunk_flops(2 * 128, 2, 8, 8) == kimi_linear._chunk_flops(2 * 128, 2, 8, 8)
-    assert rows["kda"].traffic_bytes == 4 * (4 * 2 * 128 * 16 + 2 * 128 * 2 + 2 * 128 * 16 + 3)
-    assert rows["fused_attention"].flops == 2.0 * 2 * 2 * (12 + 8) * 128 * 128   # QK^T over 12, PV over 8
-    assert rows["short_conv"].flops == (4 + 2 * 4) * 2 * 128 * 16                 # the SiLU and four taps
-    # shapes the rules refuse
-    for bad in (dict(G=(2, 128, 2, 4)), dict(Beta=(2, 128, 1)), dict(K=(2, 128, 2, 4))):
-        with pytest.raises(Exception, match="kda|Beta|log decay|Q and K"):
-            with fluid.program_guard(fluid.Program(), fluid.Program()):
-                shapes = {**dict(Q=(2, 128, 2, 8), K=(2, 128, 2, 8), V=(2, 128, 2, 8), G=(2, 128, 2, 8), Beta=(2, 128, 2)), **bad}
-                ins = {n: layers.data(n, list(s[1:]), dtype="float32") for n, s in shapes.items()}
-                layers.kda(*(ins[n] for n in ("Q", "K", "V", "G", "Beta")))
-                problems = [d for d in analysis.verify_program(fluid.default_main_program(), level="full") if d.severity == "error"]
-                assert not problems, f"kda: {problems}"
 
 
 # -- (b) the plain short convolution ---------------------------------------------------

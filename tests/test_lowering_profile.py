@@ -4,6 +4,8 @@ span's `by_op`, and nothing of either while the monitor is off.  Tiny programs
 on the CPU: (a) a `backward` and an optimizer, (b) an `is_sparse` table, (c) a
 `recompute_scope` segment, (d) a `layers.Repeat` body, (e) no `backward` at
 all, (f) an op with a `custom_vjp` of the program's own."""
+import re
+
 import numpy as np
 import pytest
 
@@ -233,7 +235,8 @@ def test_the_steps_compiled_text_is_the_same_with_the_monitor_on_and_off(case):
     for on in (False, True):
         monitor.enable() if on else monitor.disable()
         _, step = _run(build)
-        texts.append(step._exec.as_text())
+        # the program, not where a line of Python stands: the monitor's branch of `counted_rules`' wrapper is another line
+        texts.append(re.sub(r" line=\d+ end_line=\d+ column=\d+ end_column=\d+", "", step._exec.as_text()))
     assert texts[0] == texts[1]
 
 

@@ -21,6 +21,7 @@
 
 One compiled tiny model serves (e): `float32_run`.
 """
+import contextlib
 import os
 import sys
 from types import SimpleNamespace
@@ -42,6 +43,7 @@ from paddle_tpu.core.lowering import LoweringContext  # noqa: E402
 from paddle_tpu.core.registry import get_op_def  # noqa: E402
 from paddle_tpu.models import transformer  # noqa: E402
 from paddle_tpu.ops import masked_attention  # noqa: E402
+from tools import chip_smallthinker_controls as controls  # noqa: E402
 
 
 def lower(op_type, ins, attrs=None):
@@ -510,11 +512,25 @@ def test_bfloat16_agrees_within_the_benchmarks_tolerances():
     assert max(found["loss_error"], found["logit_error"]) <= smallthinker.REFERENCE_RTOL, found
 
 
+def _router_in_bf16(real, ctx, op, ins):
+    return real(ctx, op, {**ins, "W": [jax.lax.reduce_precision(ins["W"][0], 8, 7)]})
+
+
+def _silu_for_relu(real, ctx, op, ins):
+    return real(ctx, controls.with_attrs(op, activation="silu"), ins)
+
+
+#: fault -> (the configuration built with it, the op whose registered lowering is wrapped and by what, the limit that
+#: refuses it): every control of `tools/chip_smallthinker_controls.py` that goes into the program has its case here or
+#: in `test_the_comparison_refuses_a_router_that_reads`, so that tier-1 holds which limit refuses which without the
+#: tool's ten builds (its whole rehearsal is `-m slow`; `tests/test_chip_controls.py` rehearses it on one fault)
 FAULTS = {
-    "rotation_in_the_full_layer": (dict(rope_layout=[1, 1, 1, 1] * 2), "QK_RTOL"),
-    "no_rotation_in_the_first_window_layer": (dict(rope_layout=[0, 0, 1, 1] * 2), "QK_RTOL"),
-    "a_window_of_7": (dict(sliding_window_size=7), "WINDOW_EDGE_MAX"),
-    "a_window_of_9": (dict(sliding_window_size=9), "WINDOW_EDGE_MAX"),
+    "rotation_in_the_full_layer": (dict(rope_layout=[1, 1, 1, 1] * 2), None, "QK_RTOL"),
+    "no_rotation_in_the_first_window_layer": (dict(rope_layout=[0, 0, 1, 1] * 2), None, "QK_RTOL"),
+    "a_window_of_7": (dict(sliding_window_size=7), None, "WINDOW_EDGE_MAX"),
+    "a_window_of_9": (dict(sliding_window_size=9), None, "WINDOW_EDGE_MAX"),
+    "the_routers_matrix_in_bf16": ({}, ("moe_router", _router_in_bf16), "ROUTER_RTOL"),
+    "a_silu_for_the_relu": ({}, ("moe_experts", _silu_for_relu), "EXPERTS_RTOL"),
 }
 
 
@@ -522,9 +538,10 @@ FAULTS = {
 def test_the_comparison_refuses_a_program_with(fault, float32_run):
     """A program built with the fault on the sound program's parameters (the
     names are the same) against the sound reference."""
-    over, limit = FAULTS[fault]
-    _, _, main, _, names, _, exe = tiny_model("float32", over)
-    got = exe.run(main.clone(for_test=True), feed=float32_run.rows, fetch_list=list(names), scope=float32_run.scope)
+    over, wrapped, limit = FAULTS[fault]
+    with controls.lowered_as(*wrapped) if wrapped else contextlib.nullcontext():     # read when the clone is traced
+        _, _, main, _, names, _, exe = tiny_model("float32", over)
+        got = exe.run(main.clone(for_test=True), feed=float32_run.rows, fetch_list=list(names), scope=float32_run.scope)
     found = smallthinker.compare(got, float32_run.want)
     refused = smallthinker.failed_limits(found)
     assert limit in refused, refused
@@ -573,6 +590,7 @@ def test_the_reference_at_default_precision_in_its_attention_is_what_it_says():
     agree(a[1], b[1], tol=1e-5)
 
 
+@pytest.mark.slow   # one subprocess of ten builds, 68 to 122 s: run by name (`-m slow`); `FAULTS` above holds which limit refuses which
 def test_every_control_of_the_chip_tool_is_refused_in_its_rehearsal_and_the_sound_program_is_not():
     """`DRY=1 python3 tools/chip_smallthinker_controls.py`: tiny and on the CPU
     the eight faults put into the program each fail a committed limit, the one

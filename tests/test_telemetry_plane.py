@@ -300,21 +300,36 @@ def test_perf_report_skew_gate_counters_only(tmp_path):
 
 # --- the monitor-overhead guard (tier-1 satellite) ---------------------------
 
-def _per_call(fn, n):
-    t0 = time.perf_counter()
-    for _ in range(n):
-        fn()
-    return (time.perf_counter() - t0) / n
+def _empty():
+    pass
+
+
+def _in_empty_calls(fn, n, batches=10):
+    """What a call of `fn` costs in calls of an empty function: both timed in
+    the same loop, batch about, the least of `batches` readings each.  The
+    wall clock of a loaded box reads anything; the least of ten readings
+    beside the same empty loop's does not (ISSUE 66: 22 | 2 | 5 disabled and
+    140 | 39 | 70 enabled, the same alone and beside six busy workers)."""
+    least = {fn: float("inf"), _empty: float("inf")}
+    for _ in range(batches):
+        for timed in (_empty, fn):
+            t0 = time.perf_counter()
+            for _ in range(n // batches):
+                timed()
+            least[timed] = min(least[timed], time.perf_counter() - t0)
+    return least[fn] / least[_empty]
 
 
 def test_monitor_hot_path_overhead_bounded(tmp_path):
     """The always-on flight recorder must not tax the dispatch path: a
     DISABLED monitor's span/counter entry points stay within a few
     hundred ns (branch + singleton), and an ENABLED monitor with the
-    recorder armed stays within tens of µs per call.  Bounds are ~20x
-    above observed cost so a loaded CI box cannot flake them, while a
-    regression to per-call allocation/IO (the class of bug this guards
-    against) still lands orders of magnitude above."""
+    recorder armed stays within tens of µs per call.  The bounds are the
+    old ones in µs (5 | 2 | 5 disabled, 100 | 50 | 500 enabled, ~20x the
+    observed cost) at the 30 ns an empty call takes here, so a loaded CI
+    box cannot flake them, while a regression to per-call allocation/IO
+    (the class of bug this guards against) still lands orders of
+    magnitude above."""
     n = 20000
     monitor.disable()
     c = monitor.counter("guard.c")
@@ -323,9 +338,9 @@ def test_monitor_hot_path_overhead_bounded(tmp_path):
         with monitor.span("guard.s", step=1):
             pass
 
-    assert _per_call(disabled_span, n) < 5e-6
-    assert _per_call(lambda: c.inc(), n) < 2e-6
-    assert _per_call(lambda: monitor.gauge("guard.g").set(1.0), n) < 5e-6
+    assert _in_empty_calls(disabled_span, n) < 150
+    assert _in_empty_calls(lambda: c.inc(), n) < 60
+    assert _in_empty_calls(lambda: monitor.gauge("guard.g").set(1.0), n) < 150
 
     monitor.enable()
     monitor.arm_flight_recorder(str(tmp_path / "bb.json"), 0)
@@ -334,11 +349,11 @@ def test_monitor_hot_path_overhead_bounded(tmp_path):
         with monitor.span("guard.s", step=1):
             pass
 
-    assert _per_call(enabled_span, n) < 1e-4
-    assert _per_call(lambda: c.inc(), n) < 5e-5
-    assert _per_call(
+    assert _in_empty_calls(enabled_span, n) < 3000
+    assert _in_empty_calls(lambda: c.inc(), n) < 1500
+    assert _in_empty_calls(
         lambda: monitor.record_step({"kind": "pipeline_step", "x": 1}),
-        2000) < 5e-4
+        2000) < 15000
     # the armed ring stayed bounded through all of it
     assert len(MONITOR._bb_events) <= FLIGHT_RECORDER_CAP
     assert len(MONITOR._bb_steps) <= FLIGHT_RECORDER_CAP
