@@ -657,6 +657,10 @@ class _CompiledStep:
                     _MON.counter("executor.compile_cache_hit" if hit
                                  else "executor.compile_cache_miss").inc()
             t2 = time.perf_counter()
+            if mon_on and self.mesh is not None and self.mesh.size > 1:
+                # what the step communicates: a walk over the compiled text, a span of its own beside the compile's
+                from ..parallel import collectives
+                collectives.publish(built, self.mesh, **what)
             self._exec = built
             self._exec_by_sig[sig] = built
             if len(self._exec_by_sig) > 8:

@@ -51,8 +51,10 @@ def _model():
 FEED = {"x": np.ones((4, 8), "f4"), "y": np.ones((4, 1), "f4")}
 
 
-def _train(steps=STEPS, before_loop=lambda: None):
+def _train(steps=STEPS, before_loop=lambda: None, uuid8=None):
     main, startup, feeds, loss = _model()
+    if uuid8 is not None:
+        main._uuid = uuid8 + main._uuid[8:]
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     exe.run(startup, scope=scope)
@@ -65,7 +67,28 @@ def _train(steps=STEPS, before_loop=lambda: None):
     return exe, main
 
 
-def test_every_span_of_a_training_step_is_in_the_profiler_trace(tmp_path):
+def same_program(stat, uuid8: str) -> bool:
+    """Is a trace event's `program=` stat the program whose `_uuid[:8]` is
+    `uuid8`?  By this rule: the profiler hands a stat that READS as a number
+    back as that number, and eight hexadecimal characters do so about once in
+    twenty-seven draws: all digits ((10/16)**8, one in forty-three: an int, its
+    leading zeros lost, so `len(stat)` is a TypeError) or digits round one `e`
+    (`12e45678`: a float, and `inf` at that, which nothing turns back into the
+    id).  So a stat that is text is compared as text, and one that is a number
+    with the id read the same way.  `module=` starts with a word and is the key
+    to join a trace event to a span or a record on (docs/observability.md, "One
+    timeline")."""
+    if isinstance(stat, str):
+        return stat == uuid8
+    try:
+        return float(uuid8) == stat
+    except ValueError:
+        return False
+
+
+@pytest.mark.parametrize("uuid8", [None, "01234567", "12e45678", "0123abcd"],
+                         ids=["as_drawn", "all_digits", "digits_round_an_e", "hexadecimal"])
+def test_every_span_of_a_training_step_is_in_the_profiler_trace(tmp_path, uuid8):
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
 
@@ -74,7 +97,7 @@ def test_every_span_of_a_training_step_is_in_the_profiler_trace(tmp_path):
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
 
     try:
-        _train(before_loop=start)
+        _, main = _train(before_loop=start, uuid8=uuid8)
     finally:
         jax.profiler.stop_trace()
     [pb] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
@@ -101,7 +124,11 @@ def test_every_span_of_a_training_step_is_in_the_profiler_trace(tmp_path):
     assert all("step" in st for _, st in loop)
     # keyword arguments come back as the event's stats
     [enq] = [st for n, st in loop if n == "executor.enqueue" and st["step"] == 1]
-    assert re.fullmatch(r"train_[0-9a-f]{8}", enq["module"]) and len(enq["program"]) == 8
+    assert re.fullmatch(r"train_[0-9a-f]{8}", enq["module"]) and same_program(enq["program"], main._uuid[:8])
+    if uuid8 == "01234567":     # the case the rule is for: the id came back as a number, its leading zero gone
+        assert enq["program"] == 1234567 and not isinstance(enq["program"], str)
+    if uuid8 == "12e45678":     # and worse: a float too large to hold, whatever followed the `e`
+        assert enq["program"] == float("inf")
     [placed] = [st for n, st in loop if n == "executor.feed_place" and st["step"] == 1]
     assert placed["bytes"] == sum(v.nbytes for v in FEED.values())
     # the producer's thread: one `reader.stage` a batch, numbered
