@@ -1,6 +1,6 @@
 """Attention under a mask that is a rule over positions, not a tensor, and,
 at the module's end (`selected_attention`), under one that IS a tensor of the
-step: each query's own chosen keys, through the same stock kernels.
+step: each query's own chosen keys, through the same forward kernel and the same one backward kernel.
 
 Three rules.  The SLIDING-WINDOW one (`window_allowed`: key j for query i where
 i - window < j <= i; `fused_attention`'s `mask="sliding_window"`, `window_plan`)
@@ -12,10 +12,11 @@ sequence's start from every query IS the causal rule and takes its plan.  The
 CAUSAL one (`causal_allowed`: key j <= query i over equal
 lengths; `fused_attention`'s `causal` at long keys, `causal_plan`) is the stock
 splash forward kernel under the stock causal mask and nothing round it but the
-queries' scaling.  BACKWARD under these two rules is ONE kernel of our own
+queries' scaling.  BACKWARD under every rule of this module is ONE kernel of our own
 (`ops/attention_backward_kernels.py`, `Plan.backward`): dq, dk and dv from one
 pass over the scores of the (key block, query block) pairs the rule leaves, dq
-summed in VMEM, the cut blocks' mask computed in the kernel.  At 4096 keys 10 of
+summed in VMEM, the cut blocks' mask computed in the kernel under these two
+rules and read from a stored block under the other two (PR 68).  At 4096 keys 10 of
 the square's 16 1024-blocks are visited and 4 of them cut, at 8192 36 of 64 and
 8.  What is said below of the far term's forward kernel, its block maps and
 grouped key/value heads holds for it; nothing of the own-block term does.  The other rule is block-diffusion training's
@@ -37,8 +38,8 @@ CPU tests and goldens), and `block_sparse_attention` splits it into the term
 that has block structure and the one that has none:
 
 * the FAR term, all 2L queries against the L clean keys, through the stock
-  splash-attention kernels (jax.experimental.pallas.ops.tpu.splash_attention;
-  forward, dq and dkv) under the rule's `[2L, L]` rectangle as a mask they can
+  splash-attention forward kernel (jax.experimental.pallas.ops.tpu.splash_attention)
+  and the one backward kernel, under the rule's `[2L, L]` rectangle as a mask they can
   ask for any block of.  The block maps are made from the rule at trace time
   (numpy, a block of the grid at a time): blocks the rule empties are never
   visited nor their keys fetched, blocks it fills skip the mask, and the
@@ -62,9 +63,12 @@ that has block structure and the one that has none:
   exactly 0.  The far term's output leaves its kernel rounded to the
   operands' dtype, so a noised row carries that rounding and the join's.
 * BACKWARD as one `custom_vjp` over the whole op: di = rowsum(do . out) from
-  the joined output, the far term's dq, dk, dv from the stock dq and dkv
-  kernels given the joined `lse` and `di`, the near term's by the same
-  formulas, added to the noised rows of dq in place.
+  the joined output, the far term's dq, dk, dv from the one backward kernel
+  given the joined `lse` and `di` (2L queries against L keys; a step's block of
+  the mask one of the two distinct cut blocks or the block of ones, a byte a
+  pair: a row with no far key reads p = exp(mask_value - lse) = 0 under its
+  finite joined log-sum-exp), the near term's by the same formulas, added to
+  the noised rows of dq in place.
 
 The split is taken where the rule's block is smaller than the kernels' and
 whole blocks of the rule make up a lane tile (`plan_of`: every block length
@@ -176,6 +180,19 @@ MASKS = ("block_diffusion", "sliding_window")
 #: rounded once: against float32 3.34e-3 for the stock fused kernel's 3.49e-3 at
 #: (1, 4, 8192, 128), 8 partials; 2.50e-3 for 2.59e-3 at 64-wide heads; dk and dv
 #: to the sixth digit the stock kernel's (tools/chip_attention_errors.py).
+#:
+#: Since PR 68 backward under BLOCK DIFFUSION's rule is that kernel too, a step's block of the mask read from the
+#: rule's distinct cut blocks (a byte a pair, [3, 1024, 1024] at SDAR's shape with the block of ones that the whole
+#: steps read).  (2, 32 on 4, 8192 queries on 4096 clean keys, 128), far + near, forward + backward of a layer alone,
+#: ms (my chip run, PR 68, call 2; `STORED=1 python3 tools/chip_block_attention.py`):
+#:
+#:   block                                    1024 (20 steps a head)   512 (72)
+#:   ours, dk and dv of a group in VMEM       18.34                    20.17
+#:   ours, summed outside in float32          18.76                    20.39
+#:   ours, an int32 a pair of the mask        18.50                    20.39
+#:   the stock dq and dkv pair                24.62                    26.81
+#:
+#: so 1024-blocks and a byte a pair, 25.5% under the pair; dq is the pair's to the last bit, dk 1.1e-3 of the largest.
 _BLOCKS = (1024, 512, 128)
 _KV_COMPUTE = 512
 
@@ -268,16 +285,22 @@ class Plan(NamedTuple):
         return max(self.mask_block, 128)
 
     @property
+    def stored(self) -> bool:
+        """Are the rule's cut blocks STORED (block diffusion's few distinct
+        ones, the selected rule's whole mask) and not computed from positions?"""
+        return self.rule in ("block_diffusion", "selected")
+
+    @property
     def backward(self) -> str:
         """Which backward form the plan takes, read off the rule, the widths and
-        the length alone.  `"onchip_dq"`: the one kernel of
+        the lengths alone.  `"onchip_dq"`: the one kernel of
         `ops/attention_backward_kernels.py`, dq, dk and dv from one pass over the
-        scores of the blocks the rule leaves, dq summed in VMEM; under the two
-        rules whose cut blocks are computed from positions, wherever a head's
-        whole dq fits the kernel's VMEM.  `"stock_pair"`: the stock dq and dkv
-        kernels, each computing its scores again; under the rules whose cut
-        blocks are STORED (block diffusion's, the selected one: the kernel reads
-        no stored block yet), and where a head's dq does not fit.  The stock
+        scores of the blocks the rule leaves, dq summed in VMEM; under every
+        rule, the cut blocks' mask computed from positions (`causal`,
+        `sliding_window`) or a STORED block a step (`block_diffusion`,
+        `selected`), wherever a head's whole dq and that block fit the kernel's
+        VMEM.  `"stock_pair"`: the stock dq and dkv kernels, each computing its
+        scores again; where they do not fit.  The stock
         FUSED kernel, which the causal rule took until PR 64, is no form any
         more: it writes dq as one partial a block of keys, [L / block, Hq, L, dh]
         rounded to the operands' dtype for XLA to sum, over a dkv grid that is
@@ -285,8 +308,9 @@ class Plan(NamedTuple):
         every shape priced (`_BLOCKS`' and `_WINDOW_BLOCKS`' tables)."""
         from . import attention_backward_kernels as onchip
 
-        computed = self.rule in ("causal", "sliding_window")
-        return "onchip_dq" if computed and onchip.vmem_bytes(self.positions, self.widths, False) <= onchip.VMEM_LIMIT else "stock_pair"
+        held = onchip.vmem_bytes((self.positions, self.positions - self.first_key), self.widths, False,
+                                 self.block if self.stored else 0)
+        return "onchip_dq" if held <= onchip.VMEM_LIMIT else "stock_pair"
 
     @property
     def sizes(self):
@@ -301,7 +325,7 @@ class Plan(NamedTuple):
                                  block_kv_dkv_compute=inner, **dq)
 
 
-def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False) -> Plan:
+def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False, widths=(128, 128)) -> Plan:
     """The own-block term leaves the stock kernels where the rule's block is
     smaller than theirs (the noised quadrant's diagonal blocks are then cut
     blocks mask_block / block full) and whole blocks of the rule make up the
@@ -309,8 +333,8 @@ def plan_of(positions: int, heads: int, mask_block: int, interpret: bool = False
     the largest that divides L, the far keys."""
     far, tile = kernel_block(positions // 2), max(mask_block, 128)
     if far is not None and mask_block < far and tile % mask_block == 0 and far % tile == 0:
-        return Plan(positions, heads, mask_block, far, positions // 2, interpret)
-    return Plan(positions, heads, mask_block, kernel_block(positions), 0, interpret)
+        return Plan(positions, heads, mask_block, far, positions // 2, interpret, widths=tuple(widths))
+    return Plan(positions, heads, mask_block, kernel_block(positions), 0, interpret, widths=tuple(widths))
 
 
 def causal_plan(length: int, heads: int, interpret: bool = False, widths=(128, 128)) -> Plan:
@@ -440,10 +464,22 @@ def _block_map(plan: Plan, which: int):
 
 @functools.lru_cache(maxsize=32)
 def _steps(plan: Plan):
-    """The (key block, query block) pairs the one backward kernel steps through."""
+    """The (key block, query block) pairs the one backward kernel steps through:
+    those the rule leaves; under the selected rule, whose mask is the step's
+    data, those the causal rule leaves or the whole square."""
     from . import attention_backward_kernels as onchip
 
+    if plan.rule == "selected":
+        return onchip.steps_over(plan.positions // plan.block, plan.causal)
     return onchip.steps_of(block_maps(plan)[2])
+
+
+@functools.lru_cache(maxsize=32)
+def _stored_blocks(plan: Plan):
+    """(The distinct cut blocks and one of ones, `mask_of[step]`) of block diffusion's rule."""
+    from . import attention_backward_kernels as onchip
+
+    return onchip.stored_blocks(block_maps(plan)[2], plan.block)
 
 
 def _stock_options(plan: Plan) -> dict:
@@ -476,11 +512,14 @@ def _far_forward(q, k, v, plan: Plan, residuals: bool = True):
 def _far_backward(q, k, v, lse, do, di, plan: Plan):
     """dq, dk, dv of the kernels' term given the log-sum-exp and rowsum(do .
     out) of the WHOLE row (joined, where the own-block term was split off): from
-    the one kernel that keeps dq on the chip, or from the stock dq and dkv
-    kernels (`Plan.backward`)."""
+    the one kernel that keeps dq on the chip, its cut blocks computed from
+    positions or (block diffusion's) read from the rule's stored ones, or from
+    the stock dq and dkv kernels (`Plan.backward`)."""
     if plan.backward == "onchip_dq":
         from . import attention_backward_kernels as onchip
 
+        if plan.stored:
+            return onchip.backward(q, k, v, lse, do, di, _steps(plan), None, plan.block, plan.interpret, stored=_stored_blocks(plan))
         return onchip.backward(q, k, v, lse, do, di, _steps(plan), _stock_options(plan)["mask_function"], plan.block, plan.interpret)
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
@@ -658,6 +697,8 @@ def attention_under(plan: Plan, q, k, v, scale: Optional[float], keep=None):
     _MON.counter("lowering.attention_blocks_cut").inc(int(np.count_nonzero(blocks == 1)))
     _MON.counter("lowering.attention_own_block_terms").inc(int(plan.first_key > 0))
     _MON.counter("lowering.attention_backward_onchip_dq").inc(int(plan.backward == "onchip_dq"))
+    if plan.stored and plan.backward == "onchip_dq":      # the steps a head whose block of the mask is read, not computed
+        _MON.counter("lowering.attention_backward_stored_steps").inc(int(np.count_nonzero(block_maps(plan)[2].block_mask == 1)))
     with jax.named_scope("block_sparse_attention"):
         if scale is not None:
             q = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -667,7 +708,7 @@ def attention_under(plan: Plan, q, k, v, scale: Optional[float], keep=None):
 def block_sparse_attention(q, k, v, mask_block: int, scale: float, interpret: bool = False, keep=None):
     """`attention_under` the block-diffusion rule over 2L positions in blocks
     of `mask_block`."""
-    return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret), q, k, v, scale, keep)
+    return attention_under(plan_of(q.shape[2], q.shape[1], mask_block, interpret, (q.shape[-1], v.shape[-1])), q, k, v, scale, keep)
 
 
 def window_attention(q, k, v, window: int, scale: float, interpret: bool = False, keep=None):
@@ -691,15 +732,28 @@ def causal_attention(q, k, v, scale: float, interpret: bool = False, keep=None):
 
 # -- a mask that is DATA: each query's own chosen keys ------------------------------------------------------------------
 #
-# The same stock kernels once more, their block maps made on the device from the step's own mask (the stock
-# `process_dynamic_mask`): every block of the grid brings its [queries, keys] block of the mask from HBM, one byte a
-# pair, a block no query of which chose a key is skipped (`block_mask` 0: its keys are not fetched), and nothing is
-# computed from positions.  The rows are taken one at a time (`lax.map`): a row's mask is a row's own block maps, which
-# the kernels read from scalar memory.
+# FORWARD the stock kernel once more, its block map made on the device from the step's own mask (the stock
+# `process_dynamic_mask`): every block of the grid brings its [queries, keys] block of the mask from HBM, a
+# block no query of which chose a key is skipped (`block_mask` 0: its keys are not fetched), and nothing is
+# computed from positions.  The rows are taken one at a time (`lax.map`): a row's mask is a row's own block map, which
+# the kernel reads from scalar memory.  BACKWARD the one kernel of `ops/attention_backward_kernels.py` (PR 68), every row
+# in one call over a static grid: a row's mask transposed, [keys, queries] one byte a pair, and the state of each of
+# its steps (`_selected_bwd`).
 
-#: The selected attention's grid block: the largest of these that divides the length.  At 1024 the fused backward
-#: kernel's [keys, queries] block of the stored mask overran the scoped VMEM inside two cells' steps (`_BLOCKS`' table);
-#: dq and dkv are kernels of their own here, as under block diffusion's rule, each over 512 queries or keys a step.
+#: The selected attention's grid block: the largest of these that divides the length.  At 1024 the STOCK fused backward
+#: kernel's [keys, queries] block of the stored mask overran the scoped VMEM inside two cells' steps (`_BLOCKS`' table),
+#: so the stock forward kernel takes 512 queries a step against 1024 keys (`Plan.sizes`); the one backward kernel, with
+#: 64 MiB of its own, takes the whole [1024, 1024] byte block.  (1, 32 on 4, 16384, 128) under the causal rule, ~2048
+#: picks a query (all 136 blocks cut), forward + backward of a layer alone, ms (my chip run, PR 68, call 2; `STORED=1
+#: python3 tools/chip_block_attention.py`):
+#:
+#:   block                                    1024 (136 steps a head)   512 (528)
+#:   ours, dk and dv of a group in VMEM       78.96                     83.34
+#:   ours, summed outside in float32          80.28                     84.75
+#:   ours, an int32 a pair of the mask        81.93                     86.03    (8 MiB of blocks: the group's rows no longer fit)
+#:   the stock dq and dkv pair, a row a loop  109.06                    118.53
+#:
+#: so 1024 and a byte a pair, 27.6% under the pair; dq is the pair's to the last bit, dk 1.7e-3 and dv 7.1e-4 of the largest.
 _SELECTED_BLOCKS = (1024, 512, 256, 128)
 
 
@@ -709,8 +763,8 @@ def selected_block(length: int):
     return next((b for b in _SELECTED_BLOCKS if length % b == 0), None)
 
 
-def selected_plan(length: int, heads: int, causal: bool = False, interpret: bool = False) -> Plan:
-    return Plan(length, heads, 1, selected_block(length), 0, interpret, "selected", causal)
+def selected_plan(length: int, heads: int, causal: bool = False, interpret: bool = False, widths=(128, 128)) -> Plan:
+    return Plan(length, heads, 1, selected_block(length), 0, interpret, "selected", causal, tuple(widths))
 
 
 def _row_mask(picks, plan: Plan):
@@ -763,15 +817,28 @@ def _selected_fwd(q, k, v, picks, plan: Plan, keep):
 
 
 def _selected_bwd(plan: Plan, keep, residuals, cotangents):
-    """The stock dq and dkv kernels a row, each on its own block maps of the
-    row's mask; the picks are whole numbers and take no gradient.  The
-    log-sum-exp is an OUTPUT here (the alignment term reads it): ds = p (dp -
-    di) + p dlse, so its cotangent goes in as di - dlse."""
+    """The picks are whole numbers and take no gradient.  The log-sum-exp is an
+    OUTPUT here (the alignment term reads it): ds = p (dp - di) + p dlse, so its
+    cotangent goes in as di - dlse.  dq, dk and dv from the ONE kernel that keeps
+    dq on the chip (`Plan.backward`), every row in one call: a row's mask
+    transposed, [keys, queries] one byte a pair as the kernel lays its scores, is
+    the only layout of it made for backward, and the state of a row's step (does
+    any query of the block hold a key of the block?) one reduce over it.  Where
+    a head's dq does not fit the kernel's VMEM, the stock dq and dkv kernels a
+    row, each on its own block maps of the row's mask."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
 
     q, k, v, picks, out, lse = residuals
     do, dlse = cotangents
     di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32), do.astype(jnp.float32)) - dlse.astype(jnp.float32)
+    if plan.backward == "onchip_dq":
+        from . import attention_backward_kernels as onchip
+
+        steps, blocks = _steps(plan), plan.positions // plan.block
+        mask = jax.vmap(lambda picks: _row_mask(picks, plan).T)(picks)
+        state = mask.reshape(-1, blocks, plan.block, blocks, plan.block).any((2, 4))[:, steps.kv_block, steps.q_block]
+        stored = (mask.astype(onchip.STORED_DTYPE), state.astype(jnp.int32))
+        return (*onchip.backward(q, k, v, lse, do, di, steps, None, plan.block, plan.interpret, stored=stored), None)
     sizes = plan.sizes
     options = dict(_stock_options(plan), q_layout=sizes.q_layout, k_layout=sizes.k_layout, v_layout=sizes.v_layout)
 
@@ -801,7 +868,10 @@ def selected_attention(q, k, v, picks, scale: Optional[float], causal: bool = Fa
     stored mask, whatever the positions.  A query that holds no key reads the
     stock kernels' finite output under a log-sum-exp of `mask_value`; the
     indexer's choice always holds the query's own position."""
-    plan = selected_plan(q.shape[2], q.shape[1], causal, interpret)
+    plan = selected_plan(q.shape[2], q.shape[1], causal, interpret, (q.shape[-1], v.shape[-1]))
+    onchip = plan.backward == "onchip_dq"
+    _MON.counter("lowering.attention_backward_onchip_dq").inc(int(onchip))
+    _MON.counter("lowering.attention_backward_stored_steps").inc(_steps(plan).q_block.size if onchip else 0)
     with jax.named_scope("selected_attention"):
         if scale is not None:
             q = (q.astype(jnp.float32) * scale).astype(q.dtype)

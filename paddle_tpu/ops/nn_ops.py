@@ -645,13 +645,14 @@ def _fused_attention(ctx, op, ins):
       stock splash-attention kernel with the rule as its mask: blocks the
       rule empties are skipped, forward and backward, the blocks it cuts read
       the few distinct cut blocks (block diffusion) or compute the rule from
-      the positions (a sliding window, `mask_block` its width in keys: its
-      backward is the causal rule's one kernel over the band's blocks), and
-      no mask or score of the whole square is in HBM;
+      the positions (a sliding window, `mask_block` its width in keys), the
+      backward under either the causal rule's one kernel over the blocks the
+      rule leaves, and no mask or score of the whole square is in HBM;
     * `selected`: under a mask that is DATA (the input `Picks`, int32 (B, Lq,
       Lk / 32): bit j of word w of a query set where it holds key 32 w + j;
-      `sparse_index` makes it), the same stock kernels on block maps made on
-      the device from the picks: every block brings its stored block of the
+      `sparse_index` makes it), the same stock forward kernel on a block map
+      made on the device from the picks and the same one backward kernel on
+      the row's byte mask: every block brings its stored block of the
       mask, a block that holds no chosen pair is skipped, and no pair outside
       the picks has weight (with `causal`, none above the diagonal either).
       Counted in `lowering.selected_attention_ops`;

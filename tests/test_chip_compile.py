@@ -278,7 +278,8 @@ CASES = {
         _gmm, [((1000, 256), BF16), ((8, 256, 384), BF16), ((8,), I32)], (0, 1)),
     # SDAR-30B-A3B-Chat's cell: 2 sequences of 8192 positions [noised ; clean], 32 query heads on
     # 4 key/value heads of 128, under the block-diffusion mask: the stock splash kernel with the
-    # mask's rule (ops/masked_attention.py), forward, dq and dkv
+    # mask's rule (ops/masked_attention.py) forward, and backward the ONE kernel that reads a stored block of the mask a
+    # step (PR 68): 8192 queries against the 4096 clean keys, a head's dq and a group's dk and dv rows in VMEM
     "block_sparse_attention_sdar": (
         _block_sparse, [((2, 32, 8192, 128), BF16)] + [((2, 4, 8192, 128), BF16)] * 2, (0, 1, 2)),
     "block_sparse_attention_128_blocks": (  # a length that is whole in the small block only
@@ -331,19 +332,23 @@ def test_kernel_compiles_for_v5e(name, chip):
 def test_no_square_of_the_positions_is_in_the_compiled_attention(chip):
     """Forward and backward at the cell's shape: no array with 8192 x 8192
     elements, mask or scores, in any computation of the compiled program, nor
-    a float32 one of 8192 x 4096 (the far term's scores), and the five kernel
+    a float32 one of 8192 x 4096 (the far term's scores), and the four kernel
     calls under the lowering's scope, where the benchmark's
-    `attention_roofline_share` finds them: the stock kernel's three over the
-    clean keys and the own-block term's two."""
+    `attention_roofline_share` finds them: the stock forward kernel over the
+    clean keys, the ONE backward kernel over them (PR 68: 8192 queries against
+    4096 keys, its three byte blocks of the mask all of it on the device,
+    inside `VMEM_LIMIT` or the compile fails) and the own-block term's two."""
     args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in ((2, 32, 8192, 128), (2, 4, 8192, 128), (2, 4, 8192, 128))]
     text = jax.jit(jax.grad(lambda *a: jnp.sum(_block_sparse(*a).astype(F32)), argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
     assert not re.findall(r"\[[\d,]*8192,8192\]", text)
     assert not re.findall(r"f32\[[\d,]*8192,4096\]", text)
-    assert text.count("tpu_custom_call") == 5
+    assert text.count("tpu_custom_call") == 4
     under_the_scope = re.findall(
-        r'op_name="[^"]*block_sparse_attention[^"]*/(?:splash_mha_)?(fwd|dq|dkv|own_block_join|own_block_backward)[^"/]*/pallas_call"', text)
-    assert set(under_the_scope) == {"fwd", "dq", "dkv", "own_block_join", "own_block_backward"}
+        r'op_name="[^"]*block_sparse_attention[^"]*/(splash_mha_fwd|splash_mha_dq|splash_mha_dkv|attention_dq_dk_dv|own_block_join|own_block_backward)'
+        r'[^"/]*/pallas_call"', text)
+    assert set(under_the_scope) == {"splash_mha_fwd", "attention_dq_dk_dv", "own_block_join", "own_block_backward"}
+    assert re.findall(r"s8\[3,1024,1024\]", text)      # the two distinct cut blocks and the block of ones, keys by queries
 
 
 @pytest.mark.parametrize("q,kv", [((4, 16, 4096, 128), (4, 16, 4096, 128)), ((2, 32, 8192, 64), (2, 8, 8192, 64))],
@@ -392,14 +397,17 @@ def test_no_square_of_the_positions_is_in_the_compiled_window_attention(chip):
 
 
 def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):
-    """The splash kernels on block maps made from the step's own picks
-    (`ops/masked_attention.py: selected_attention`), forward, dq and dkv, at (1,
-    32 on 4, 16384, 128) bf16 with the picks as int32 words: every grid step's
-    stored [512, 1024] block of the mask fits the scoped VMEM beside its
-    operands (the fused backward's [1024, 1024] did not: `_BLOCKS`' table), and
-    no byte mask of the whole square outlives the row it was unpacked for
-    (three block layouts of 268 MB each and the unpacking's own temporaries:
-    under 4 GB)."""
+    """The kernels under a mask that is the step's own picks
+    (`ops/masked_attention.py: selected_attention`) at (1, 32 on 4, 16384, 128)
+    bf16 with the picks as int32 words.  Forward the stock splash kernel on
+    block maps made from the picks, its stored [512, 1024] block of the mask
+    inside the scoped VMEM.  Backward the ONE kernel of
+    `ops/attention_backward_kernels.py` (PR 68): every step's [1024, 1024] byte
+    block of the row's transposed mask beside a head's dq and a group's dk and
+    dv rows, inside `VMEM_LIMIT` (the compile fails where it is overrun), no
+    stock dq or dkv kernel and no block layout of the mask made for them: one
+    byte mask of the square for backward, 268 MB, beside the forward's own."""
+    from paddle_tpu.ops import attention_backward_kernels as onchip
     from paddle_tpu.ops import masked_attention as ma
 
     def gradients(q, k, v, picks):
@@ -412,9 +420,12 @@ def test_the_selected_attentions_kernels_compile_at_keye_vl_2s_shape(chip):
         ((1, 32, 16384, 128), BF16), ((1, 4, 16384, 128), BF16), ((1, 4, 16384, 128), BF16), ((1, 16384, 512), I32))]
     compiled = jax.jit(gradients).lower(*shapes).compile()
     text = compiled.as_text()
-    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"))
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9, compiled.memory_analysis().temp_size_in_bytes
-    assert ma.selected_plan(16384, 32).sizes.block_q == 512 and ma.selected_plan(16384, 32).sizes.block_kv == 1024
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "splash_mha_dq" not in text and "splash_mha_dkv" not in text
+    assert re.findall(r"s8\[1,16384,16384\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9, compiled.memory_analysis().temp_size_in_bytes     # 2.55 GB (the pair: under 4)
+    plan = ma.selected_plan(16384, 32, True)
+    assert plan.sizes.block_q == 512 and plan.sizes.block_kv == 1024 and (plan.block, plan.backward) == (1024, "onchip_dq")
+    assert ma._steps(plan).q_block.size == 136 and onchip.kv_rows_fit((16384, 16384), (128, 128), 8, 1024)
 
 
 @pytest.mark.parametrize("keys", [4096, 16384])

@@ -472,7 +472,9 @@ def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(hos
     print(f"the step's planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
     assert 0.25 * 16.9e9 <= peak <= 15.5e9, f"the step plans {peak / 1e9:.3f} GB"
     text = compiled.as_text()
-    assert all(name in text for name in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")) and "flash_mha" not in text
+    # the stock forward kernel on block maps made from the picks, and the ONE backward kernel on the row's byte mask (PR 68)
+    assert "splash_mha_fwd" in text and "attention_dq_dk_dv" in text and "flash_mha" not in text
+    assert "splash_mha_dq" not in text and "splash_mha_dkv" not in text
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:sparse_index/index_select/", text))) == 4
     assert len(set(re.findall(r"/(sparse_index(?:_\d+)?)/op\d+:index_alignment/", text))) == 4
     # the alignment's gradients are the kernel's in every layer (PR 59), and its target's (PR 62): a call a chunk loop's body,
@@ -486,7 +488,7 @@ def test_keye_vl_2s_step_and_its_eight_row_clone_plan_under_the_chips_memory(hos
     assert max(windows, default=0) < 2048, max(windows)      # no row's statistic is spread as one window over the row
     again = [name for name in _made_again(text) if "/cond/branch_" not in name]
     # (the router's own top-8 is made again with its layer, the same choice bit for bit: ISSUE 54; the INDEXER's never)
-    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name
+    assert again and not [name for name in again if name.endswith("/dot_general") or "splash_mha" in name or "attention_dq_dk_dv" in name
                           or (name.endswith("/top_k") and "moe_router" not in name)
                           or "index_select" in name or "index_alignment" in name]
     clone, _ = _kept_step("keye", "keye-vl-2.0-30b-a3b", "train-dsa-s16384", host.devices, monkeypatch, check_rows=8)

@@ -77,6 +77,19 @@ def test_the_tool_still_runs_at_tiny_sizes_with_its_kernels_interpreted(tool):
     assert not [r for r in readings if r.get("error")], readings
 
 
+def test_chip_block_attentions_stored_masks_rehearse_and_the_one_kernel_is_the_stock_pair_there():
+    """`STORED=1 tools/chip_block_attention.py` (ISSUE 68): both stored rules, each form priced (no time in a rehearsal)
+    and the one backward kernel's dq, dk and dv beside the stock pair's, a bf16 rounding apart at the most (the patches
+    hold while the BACKWARD rule is traced: a form patched inside its forward alone ran the unpatched backward)."""
+    readings = {r["what"]: r for r in rehearsed("chip_block_attention", STORED="1")}
+    assert set(readings) == {f"{rule}_stored{tail}" for rule in ("block_diffusion", "selected") for tail in ("", "_ours_against_the_stock_pair")}
+    for rule in ("block_diffusion", "selected"):
+        assert set(readings[f"{rule}_stored"]["ms"]) == {"ours", "ours_dk_dv_summed_outside", "ours_int32_mask", "stock_pair"}
+        assert not [took for took in readings[f"{rule}_stored"]["ms"].values() if took is not None]      # an error is a string
+        against = readings[f"{rule}_stored_ours_against_the_stock_pair"]
+        assert against["finite"] and max(against["apart"].values()) <= 1e-2
+
+
 def test_chip_kimi_kernels_ops_agree_at_tiny_sizes_with_the_kernels_interpreted():
     """`tools/chip_kimi_kernels.py` has no rehearsal of its own, but its pieces take their sizes as arguments: the op
     through the kernels (interpreted) against the `jax.numpy` form, forward, and the backward it times alone

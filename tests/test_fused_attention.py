@@ -256,6 +256,79 @@ def test_the_one_backward_kernel_agrees_with_dense_float32_under_the_causal_rule
     backward_agrees_with_dense_float32(plan, masked_attention.causal_allowed, hq, hkv, widths)
 
 
+#: the rule, query heads, key/value heads, a group's dk and dv summed in VMEM (`kv_rows`; None: every query head its own
+#: key/value head), the causal rule laid over the picks: the one backward kernel under a STORED mask (ISSUE 68)
+STORED_CASES = [("block_diffusion", 4, 2, True, None), ("block_diffusion", 4, 2, False, None), ("block_diffusion", 7, 1, True, None),
+                ("block_diffusion", 2, 2, None, None), ("selected", 4, 2, True, True), ("selected", 4, 2, False, True),
+                ("selected", 6, 2, True, False), ("selected", 2, 2, None, True)]
+
+
+@pytest.mark.parametrize("rule,hq,hkv,kv_rows,causal", STORED_CASES)
+def test_the_one_backward_kernel_agrees_with_dense_float32_under_a_stored_mask(rule, hq, hkv, kv_rows, causal):
+    """The kernel INTERPRETED, in blocks of 128, as the two ops reach it, dq, dk
+    and dv against float32 dense attention under the rule.  Block diffusion's:
+    the far term a RECTANGLE, 512 queries against the 256 clean keys (two
+    distinct cut blocks and the block of ones), under the joined log-sum-exp,
+    the first noised block's rows with no far key.  The selected rule's: two
+    rows with different picks, a pair of blocks no query chose (its state 0:
+    the step computes nothing), the log-sum-exp an output with a cotangent of
+    its own, the causal rule's triangle of steps or the whole square."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention_backward_kernels as onchip
+    from paddle_tpu.ops import masked_attention
+    from paddle_tpu.ops.sparse_index_ops import pack_bits
+
+    rng = np.random.RandomState(68)
+    width, length = 128, 512 if rule == "block_diffusion" else 384
+    q, weight = (rng.randn(2, hq, length, width).astype("f4") for _ in range(2))
+    k, v = (rng.randn(2, hkv, length, width).astype("f4") for _ in range(2))
+    lse_weight = rng.randn(2, hq, length).astype("f4")
+    at = np.arange(length)
+    if rule == "block_diffusion":
+        allowed = np.broadcast_to(masked_attention.block_diffusion_allowed(at[:, None], at[None, :], length // 2, 4), (2, length, length))
+        plan = masked_attention.plan_of(length, hq, 4, True)
+        assert (plan.block, plan.first_key) == (128, 256) and masked_attention._stored_blocks(plan)[0].shape == (3, 128, 128)
+        assert masked_attention._steps(plan).q_block.size == 6 and sorted(masked_attention._stored_blocks(plan)[1]) == [0, 0, 1, 1, 2, 2]
+
+        def kernel(q, k, v):
+            return jnp.sum(masked_attention.attention_under(plan, q, k, v, width ** -0.5) * weight)
+    else:
+        allowed = (rng.rand(2, length, length) < 0.3) | np.eye(length, dtype=bool)
+        allowed[:, 256:, :128] = False                   # no query of the third block holds a key of the first
+        allowed[1, 128:256, :128] = False                # ... and in the second row none of the second block either
+        picks = pack_bits(jnp.asarray(allowed))
+        allowed = allowed & (at[:, None] >= at[None, :]) if causal else allowed
+        plan = masked_attention.selected_plan(length, hq, causal, True)
+        assert plan.block == 128 and masked_attention._steps(plan).q_block.size == (6 if causal else 9)
+
+        def kernel(q, k, v):
+            out, lse = masked_attention.selected_attention(q, k, v, picks, width ** -0.5, causal, interpret=True)
+            return jnp.sum(out * weight) + jnp.sum(lse * lse_weight)
+    assert plan.backward == "onchip_dq"
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
+        s = jnp.where(allowed[:, None], jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * width ** -0.5, -jnp.inf)
+        out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v, precision="highest")
+        return jnp.sum(out * weight) + (jnp.sum(jax.nn.logsumexp(s, -1) * lse_weight) if rule == "selected" else 0.0)
+
+    onchip.backward.clear_cache()            # the kernel's call is a `jax.jit` of its own: a trace under another patch is not this one's
+    with mock.patch.object(onchip, "kv_rows_fit", lambda *a: bool(kv_rows)):
+        got = jax.grad(kernel, (0, 1, 2))(q, k, v)
+    onchip.backward.clear_cache()
+    want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, "f8"), np.asarray(w, "f8")
+        assert g.shape == w.shape and np.isfinite(g).all()
+        assert np.abs(g - w).max() <= 2e-5 * np.abs(w).max(), (np.abs(g - w).max(), np.abs(w).max())
+    if rule == "block_diffusion":            # the rows with no far key: the own-block term's gradient alone, the far term's exactly 0
+        assert np.abs(np.asarray(got[0])[:, :, :4] - np.asarray(want[0])[:, :, :4]).max() <= 2e-5 * np.abs(np.asarray(want[0])).max()
+
+
 def _backward_jaxpr(plan, q_shape, kv_heads, v_width=None, picks=False):
     """Backward alone under `plan` as a TPU would run it, traced and not run:
     the jaxpr of the op's backward rule on its residuals' shapes."""
@@ -372,7 +445,7 @@ TPU_LAYOUT_CASES = {
     ((1, 512), False, False): ("xla", set()), ((1024, 1024), False, False): ("xla", set()),
     ((2048, 2048), False, False): ("flash", {"flash_attention"}),
     ((2048, 2048), True, False): ("block_causal", {"splash_mha_fwd", "attention_dq_dk_dv"}),   # ONE backward kernel, of our own
-    ((2048, 2048), False, True): ("block_sparse", {"splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"}),
+    ((2048, 2048), False, True): ("block_sparse", {"splash_mha_fwd", "attention_dq_dk_dv"}),   # the same kernel on stored blocks (PR 68)
 }
 
 

@@ -555,7 +555,11 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     ce85b78d...) until PR 53 sent the common pass's `_add_to_tokens` to the
     `token_sum` kernel with the slots no held expert owns left out: two kernel
     calls a layer and one more sort of scalars where two five-branch
-    conditionals of scatter-adds stood; the rare path keeps XLA's form."""
+    conditionals of scatter-adds stood; the rare path keeps XLA's form.  That
+    one stood (sha256 aff8883d...) until PR 68 gave block diffusion's attention
+    the one backward kernel (`ops/attention_backward_kernels.py` on the rule's
+    stored blocks: one call a layer where the stock dq and dkv calls stood);
+    the op listing is what it was."""
     import hashlib
     import re
 
@@ -593,4 +597,4 @@ def test_sdars_step_lowers_to_the_parents_program_but_for_the_rare_path():
     found = (step.module, hashlib.sha256(listing.encode()).hexdigest(), hashlib.sha256(text.encode()).hexdigest())
     print(found)
     assert found == ("train_6de7c714", "bc7cad00c44ec7d55d9ad0458b439478849ada1e810ed87a3ceddfbb31a7734b",
-                     "aff8883d16d19a0c7f09d8a6d91b4aff24e094cd3278bbca9ba7f6b05a9fd51f")
+                     "8b781bff58902adaaf891f9faa2151bc267a227fef0c86edae1ef4145babed1a")

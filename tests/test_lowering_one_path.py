@@ -167,9 +167,11 @@ PARENTS_CELLS = {
     # Kimi Linear's, Phi-4-mini-flash's and Kanana-2's lines re-recorded by PR 64, which means to change these programs:
     # the causal and the window rule's backward is one kernel of `ops/attention_backward_kernels.py`, not the stock
     # fused kernel or the stock pair (the parent's lines were 2c60a2d6...dd4eb, 5d27410c...84a7a, c11c6801...6c118);
-    # SDAR's (block diffusion's rule) and Keye-VL-2.0's (the selected rule) are the parent's still
+    # SDAR's (block diffusion's rule) and Keye-VL-2.0's (the selected rule) re-recorded by PR 68, which means to change these
+    # two programs and no other: their backward is that kernel too, reading a stored block of the mask a step (the parent's
+    # lines were 3a0761cf...b8195 and 89cf2a57...2ea4e; the eleven other one-chip cells' are the parent's, `tools/lowered_hash.py`)
     "sdar-30b-a3b-chat.train-blockdiff-s4096": ("train_6de7c714",
-        "3a0761cf88eedd3d35378d5928192cfe3234650ff0046226fe0d6bb9c78b8195"),
+        "b81ea9561a73e8504db346466d90b2b3e94301dce33ddd20e7ea20f1886700d7"),
     "kimi-linear-48b-a3b.train-kda-s4096": ("train_f85463d4",
         "f90a348c6f9460dfea90bb984d9dd4d084abf11d37de553a04e55d0bbe4d49a2"),
     "phi-4-mini-flash-reasoning.train-sambay-s8192": ("train_d4522e44",
@@ -177,9 +179,9 @@ PARENTS_CELLS = {
     "kanana-2-30b-a3b.train-mla-s16384": ("train_9752db24",
         "9d96882e36a8fbccc6318cac72f400183a5635f2f44212ed1b17e268251d1a0f"),
     # re-recorded by PR 62, which means to change this program (`index_alignment`'s target: `ops/alignment_target_kernels.py`),
-    # as PR 59 did for the op's gradients; the parent's line was bd1e3b97...545f0, and the four above are the parent's still
+    # as PR 59 did for the op's gradients; and by PR 68 (the selected rule's backward: above)
     "keye-vl-2.0-30b-a3b.train-dsa-s16384": ("train_21d207fd",
-        "89cf2a5715956162615cb03e3226f7bfe673601c7be3b465bf505928e582ea4e"),
+        "f105ab5b53b0a0c46b14e368c929da67450df3d99cdfdb4ba98f51125af47184"),
 }
 
 

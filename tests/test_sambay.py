@@ -175,15 +175,28 @@ def test_the_window_plans_backward_is_one_kernel_and_holds_no_partial_dq():
     assert not re.findall(r"\[8,(?:1,)?8,8192,128\]", text)
 
 
-@pytest.mark.parametrize("rule", ["block_diffusion", "selected"])
+@pytest.mark.parametrize("rule", ["block_diffusion", "selected", "selected_too_long"])
 def test_a_stored_masks_plan_still_lowers_to_the_stock_pair(rule):
-    """The rules whose cut blocks are STORED keep the stock dq and dkv kernels,
-    each a call of its own, and no kernel of ours (ISSUE 64 leaves them to an
-    issue of their own)."""
+    """No longer (ISSUE 68, the issue of their own that ISSUE 64 left them to):
+    under either rule whose cut blocks are STORED the backward is ONE
+    `pallas_call`, the kernel that reads a stored block of the mask a step and
+    keeps dq on the chip, no stock dq or dkv kernel, and under the selected
+    rule no loop over the rows.  The stock pair is still what a length takes
+    whose accumulators overrun the kernel's VMEM: 65536 positions of 128-wide
+    heads are 64 MiB of dq alone."""
+    if rule == "selected_too_long":
+        plan = masked_attention.selected_plan(65536, 4)
+        assert plan.backward == "stock_pair"
+        text = _backward_jaxpr(plan, (1, 4, 65536, 128), 2, picks=True)
+        assert "name=splash_mha_dq" in text and "name=splash_mha_dkv" in text and "attention_dq_dk_dv" not in text
+        return
     plan = masked_attention.selected_plan(1024, 4) if rule == "selected" else masked_attention.plan_of(1024, 4, 4)
-    assert plan.backward == "stock_pair"
+    assert plan.backward == "onchip_dq" and plan.stored
     text = _backward_jaxpr(plan, (1, 4, 1024, 128), 2, picks=rule == "selected")
-    assert "name=splash_mha_dq" in text and "name=splash_mha_dkv" in text and "attention_dq_dk_dv" not in text
+    assert text.count("name=attention_dq_dk_dv") == 1 and "splash_mha_dq" not in text and "splash_mha_dkv" not in text
+    # the own-block term's kernel beside it under block diffusion's rule, and nothing else
+    assert text.count("pallas_call[") == (1 if rule == "selected" else 2) and ("own_block_backward" in text) == (rule != "selected")
+    assert "while[" not in text
 
 
 def test_a_window_as_long_as_the_sequence_is_the_causal_attention():

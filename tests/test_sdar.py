@@ -155,8 +155,10 @@ def test_the_kernels_block_map_skips_what_the_rule_empties():
     20 of the rectangle's 32 1024-blocks are visited, 8 of them cut (a clean
     block's diagonal, as the noised rows and as the clean rows see it: two
     distinct blocks are all of the mask that reaches the device), a row of the
-    grid holds 4 at the most and all heads share one map; dq's 512 x 1024 grid
-    visits 40 with 16 cut.  The whole square in blocks of 512, which is what a
+    grid holds 4 at the most and all heads share one map; no dq map is made
+    (PR 68: backward is the one kernel that walks the dkv map, 20 steps a head,
+    8 of them reading one of the two stored blocks and 12 the block of ones).
+    The whole square in blocks of 512, which is what a
     rule's block as large as the kernels' still takes: 80 of 256 visited, 56
     of them whole, three distinct cut blocks (a diagonal block of each
     quadrant)."""
@@ -171,8 +173,15 @@ def test_the_kernels_block_map_skips_what_the_rule_empties():
     assert ((blocks > 0).sum(), (blocks == 1).sum(), (blocks == 2).sum()) == (20, 8, 12)
     assert np.asarray(forward.data_next).max() < 4096 // 1024  # no block over a noised key: there are none to fetch
     assert np.asarray(forward.partial_mask_blocks).shape == (2, 1024, 1024)
-    assert ((np.asarray(dq.block_mask) > 0).sum(), (np.asarray(dq.block_mask) == 1).sum()) == (40, 16)
+    assert dq is None and plan.backward == "onchip_dq"
     assert np.asarray(dkv.block_mask).shape == (1, 8, 4) and (np.asarray(dkv.block_mask) > 0).sum() == 20
+    steps, (stored, mask_of) = masked_attention._steps(plan), masked_attention._stored_blocks(plan)
+    assert steps.q_block.size == mask_of.size == 20 and stored.shape == (3, 1024, 1024) and stored.dtype == np.int8
+    assert (mask_of < 2).sum() == 8 and stored[2].all() and not stored[:2].all(axis=(1, 2)).any()
+    at = np.arange(1024)
+    for q_block, kv_block, named in zip(steps.q_block, steps.kv_block, mask_of):      # each step's block IS the rule's, keys by queries
+        rule = masked_attention.block_diffusion_allowed((1024 * q_block + at)[None, :], (4096 + 1024 * kv_block + at)[:, None], 4096, 4)
+        assert (stored[named].astype(bool) == rule).all()
     whole = masked_attention.block_maps(plan._replace(first_key=0))[0].block_mask
     assert ((whole > 0).sum(), (whole == 1).sum()) == (24, 12)  # what the split took off: the noised quadrant's diagonal
 
@@ -257,7 +266,9 @@ def test_the_lowering_counts_the_block_sparse_attention_and_names_its_kernels():
         text = str(jax.make_jaxpr(jax.grad(attention, (0, 1, 2)))(*args))
         counted = monitor.MONITOR.counter_values()
         assert counted["lowering.attention_block_sparse"] == 1 and not counted.get("lowering.attention_xla")
-        assert all(f"splash_mha_{phase}" in text for phase in ("fwd", "dq", "dkv"))  # the stock kernel's three calls
+        # the stock forward kernel and the ONE backward kernel that reads a stored block of the mask (PR 68)
+        assert "splash_mha_fwd" in text and "name=attention_dq_dk_dv" in text and "splash_mha_dq" not in text and "splash_mha_dkv" not in text
+        assert counted["lowering.attention_backward_onchip_dq"] == 1 and counted["lowering.attention_backward_stored_steps"] == 2   # both of the far term's blocks are cut
         assert "own_block_join" in text and "own_block_backward" in text  # and the own-block term's two
         assert not re.findall(r"\[(?:\d+,)*256,256\]", text)  # no array of the whole square
     finally:

@@ -33,8 +33,18 @@ sequence), and its full layer's causal rule at (1, 48 on 8, 16384, 128), groups
 of SIX: the one backward kernel with a group's dk and dv summed in VMEM against
 outside, beside the stock fused backward.
 
+STORED=1 (PR 68) prices the ONE backward kernel under the two rules whose mask
+is STORED and stops: block diffusion's far + near at SDAR's (2, 32 on 4, 8192
+queries against the 4096 clean keys, 128) and the selected rule under the
+causal one at Keye-VL-2.0's (1, 32 on 4, 16384, 128) with about 2048 picks a
+query; forward + backward of a layer alone, ours against the stock dq and dkv
+pair, at grid blocks of 1024 and 512, with a byte or an int32 a pair of the
+mask, a group's dk and dv summed in VMEM or outside, and ours against the pair
+in dq, dk and dv.
+
 A microbenchmark: a time here is a kernel's alone, not the cell's.
 """
+import contextlib
 import json
 import os
 import sys
@@ -63,10 +73,20 @@ def gradients(fn):
     return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 1, 2)))
 
 
+def patched(fn):
+    """`fn.patches` entered: what a form patches has to hold while its BACKWARD rule is traced too, which `jax.grad` does
+    after the forward has returned (a patch inside the forward alone never reached `Plan.backward` or `kv_rows_fit`)."""
+    stack = contextlib.ExitStack()
+    for patch in getattr(fn, "patches", ()):
+        stack.enter_context(patch)
+    return stack
+
+
 def ms(fn, *args, runs=5):
     """Forward + backward of sum(fn), the median of `runs` after one that compiles."""
     step = gradients(fn)
-    jax.block_until_ready(step(*args))
+    with patched(fn):
+        jax.block_until_ready(step(*args))
     times = []
     for _ in range(runs):
         t = time.perf_counter()
@@ -88,8 +108,8 @@ def onchip(plan, kv_rows=None):
     head's whole rows do not fit its VMEM (None: as the kernel takes it from
     the shapes)."""
     def attend(q, k, v):
-        with mock.patch.object(onchip_kernels, "kv_rows_fit", (lambda *a: kv_rows) if kv_rows is not None else onchip_kernels.kv_rows_fit):
-            return ma.attention_under(plan, q, k, v, q.shape[-1] ** -0.5)
+        return ma.attention_under(plan, q, k, v, q.shape[-1] ** -0.5)
+    attend.patches = [mock.patch.object(onchip_kernels, "kv_rows_fit", lambda *a: kv_rows)] if kv_rows is not None else []
     onchip_kernels.backward.clear_cache()      # the kernel's call is a `jax.jit` of its own: a trace under another patch is not this one's
     return attend
 
@@ -235,6 +255,55 @@ if os.environ.get("WINDOW") in ("1", "4096", "512x128"):
     report("window_against_dense_float32", q=sq, window=window,
            apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))),
            finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in results[1]))
+    sys.exit(0)
+if os.environ.get("STORED") == "1":
+    # The ONE backward kernel reading a STORED block of the mask a step (PR 68) against the stock dq and dkv pair, forward +
+    # backward of a layer alone: block diffusion's far + near at SDAR's (2, 32 on 4, 8192 against 4096 clean keys, 128) and
+    # the selected rule under the causal one at Keye-VL-2.0's (1, 32 on 4, 16384, 128), about 2048 picks a query; by the
+    # grid's block, by the mask's bytes a pair, and a group's dk and dv summed in VMEM or outside.
+    from paddle_tpu.ops import sparse_index_ops as sio
+
+    def form(plan, picks=None, kv_rows=None, pair=False, mask_dtype=jnp.int8):
+        """Forward + backward under `plan` as the op calls it (`picks`: the selected rule), with the backward's form
+        (`pair`: the stock dq and dkv kernels), its dk and dv (`kv_rows`, None: by the shapes) and the stored mask's dtype set."""
+        def attend(q, k, v):
+            if picks is None:
+                return ma.attention_under(plan, q, k, v, scale)
+            out, lse = ma._selected((q.astype(jnp.float32) * scale).astype(q.dtype), k, v, picks, plan, None)
+            return out.astype(jnp.float32) + lse[..., None]      # the log-sum-exp is an output: its cotangent counts
+        attend.patches = [mock.patch.object(ma.Plan, "backward", property(lambda self: "stock_pair"))] if pair else [
+            mock.patch.object(onchip_kernels, "STORED_DTYPE", mask_dtype)] + (
+            [mock.patch.object(onchip_kernels, "kv_rows_fit", lambda *a: kv_rows)] if kv_rows is not None else [])
+        onchip_kernels.backward.clear_cache()
+        ma._stored_blocks.cache_clear()
+        ma.block_maps.cache_clear()          # the dq map is made where the plan's backward is the pair, and only there
+        return attend
+
+    def priced(what, plan, qkv, picks=None, **fields):
+        forms = {"ours": {}, "ours_dk_dv_summed_outside": dict(kv_rows=False), "ours_int32_mask": dict(mask_dtype=jnp.int32),
+                 "stock_pair": dict(pair=True)}
+        blocks = (plan.block,) if DRY else (1024, 512)
+        for b in blocks:
+            at = plan._replace(block=b)
+            took = {name: try_ms(form(at, picks, **how), *qkv) for name, how in forms.items()}
+            report(what, grid_block=b, steps_a_head=int(ma._steps(at).q_block.size), ms=took, **fields)
+        def gradients_of(**how):
+            attend = form(plan, picks, **how)
+            with patched(attend):
+                return gradients(attend)(*qkv)
+
+        ours, pair = gradients_of(), gradients_of(pair=True)
+        report(what + "_ours_against_the_stock_pair", apart=dict(zip(("dq", "dk", "dv"), (apart(a, b) for a, b in zip(ours, pair)))),
+               finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in ours))
+
+    priced("block_diffusion_stored", ma.plan_of(POSITIONS, Q[1], BLOCK, DRY, (Q[-1], KV[-1])), (q, k, v), q=Q, kv=KV)
+    sq, skv = ((2, 4, 256, 128), (2, 2, 256, 128)) if DRY else ((1, 32, 16384, 128), (1, 4, 16384, 128))
+    length, topk = sq[2], 32 if DRY else 2048
+    at = jnp.arange(length)
+    chosen = jax.random.uniform(jax.random.PRNGKey(5), (sq[0], length, length)) * (at[:, None] + 1) < topk      # ~topk of a query's keys so far
+    picks = sio.pack_bits(chosen | (at[:, None] == at[None, :]))
+    priced("selected_stored", ma.selected_plan(length, sq[1], True, DRY, (sq[-1], skv[-1])), operands(sq, skv, seed=6), picks, q=sq, kv=skv,
+           picks_a_query=topk)
     sys.exit(0)
 for cq, ckv, v_width in (((1, 4, 256, 128), (1, 4, 256, 128), 128), ((1, 8, 256, 64), (1, 2, 256, 64), 64),
                          ((1, 2, 256, 192), (1, 2, 256, 192), 128)) if DRY else (
