@@ -428,7 +428,9 @@ def count_layer_forms(ops: List[Operator]) -> None:
     turns, the pairing, a table of frequencies, a factor: 2 where stretched
     half-rotary layers stand beside plain ones); and
     `lowering.query_heads_by_layer.<i>`, the query heads of the i-th
-    `fused_attention` op, a counter a layer so that the list can be read."""
+    `fused_attention` op, a counter a layer so that the list can be read; and
+    `lowering.scalar_decay_scans`, the `kda` ops whose log decay `G` is ONE
+    number a head a token ([b, T, H]: Gated DeltaNet) and not one a channel."""
     if not any(op.type == "backward" for op in ops):
         return
     made_by = {name: op for op in ops for name in op.output_arg_names}
@@ -449,6 +451,8 @@ def count_layer_forms(ops: List[Operator]) -> None:
     _MON.counter("lowering.gated_attention_layers").inc(
         sum(op.type == "sigmoid" and bool(re.search(r"(^|/)attention_gate(_\d+)?(/|$)", op.attrs.get("op_namescope") or ""))
             for op in ops))
+    _MON.counter("lowering.scalar_decay_scans").inc(
+        sum(op.type == "kda" and len(op.block.var(op.input("G")[0]).shape) == 3 for op in ops))
     _MON.counter("lowering.rotary_tables").inc(len({
         tuple(op.attr(n, None) for n in ("theta", "interleave", "rotary_dim", "inv_freq", "scale"))
         for op in ops if op.type == "rotary_embedding"}))

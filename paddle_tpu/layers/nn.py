@@ -931,7 +931,7 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
         router_attr=None, gate_attr=None, up_attr=None, down_attr=None, held=None, name=None,
         scoring="softmax", bias_attr=None, routed_scaling_factor=1.0, norm_eps=0.0,
         shared_experts=0, shared_attrs=None, activation="silu", gated=True, latent_size=None, latent_attrs=None,
-        shared_width=None, router_input=None):
+        shared_width=None, router_input=None, shared_gate_attr=None):
     """A layer of routed experts over (..., d): a float32 router picks
     `top_k` of `num_experts` experts of width `expert_width` for every token;
     their outputs are summed, weighted by the router's scores (renormalised
@@ -980,10 +980,15 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
     unweighted (`shared_attrs` = the ParamAttrs of its gate, up and down
     matrices; `mul` ops under the scope `shared_expert`).  It is outside the
     held path: where several chips split the routed experts each computes the
-    shared one alike."""
+    shared one alike.  `shared_gate_attr` (a `ParamAttr`) gives the shared expert
+    a sigmoid gate of its own (Qwen3-Next's): its output times sigmoid(x w_s),
+    w_s [d, 1], ONE number a token, the projection read in float32 at the
+    highest precision as the router's is, the product float32 and rounded once
+    (the scope `moe_shared_gate`, inside `shared_expert`)."""
     import contextlib
 
     from ..core.program import name_scope
+    from .tensor import cast
 
     helper = LayerHelper("moe", name=name)
     if activation not in ("silu", "relu", "relu2"):
@@ -1018,7 +1023,13 @@ def moe(input, num_experts, expert_width, top_k, norm_topk_prob=False,
                 hidden = square(hidden)
             if gated:
                 hidden = elementwise_mul(hidden, project(hidden_in, width, up_a))
-            out = elementwise_add(out, project(hidden, int(hidden_in.shape[-1]), down_a))
+            shared = project(hidden, int(hidden_in.shape[-1]), down_a)
+            if shared_gate_attr is not None:
+                with name_scope("moe_shared_gate"):
+                    gate = sigmoid(fc(cast(hidden_in, "float32"), 1, num_flatten_dims=len(lead), param_attr=shared_gate_attr,
+                                      bias_attr=False, precision="highest"))
+                    shared = cast(elementwise_mul(cast(shared, "float32"), gate), shared.dtype)
+            out = elementwise_add(out, shared)
     return _keep_lod(hidden_in, out), balance, z_loss
 
 
@@ -1148,12 +1159,14 @@ def kda_gate(input, num_heads, a_log_attr=None, dt_bias_attr=None, name=None):
 
 
 def kda(q, k, v, g, beta, name=None):
-    """Kimi Delta Attention's recurrence over the sequence (`ops/
+    """A gated delta rule's recurrence over the sequence (`ops/
     linear_attention_ops.py`): q, k (b, T, H, K), v (b, T, H, V), the float32
-    log decay g (b, T, H, K) a channel and the step beta (b, T, H); a head
-    keeps a float32 [K, V] state, S_t = (I - beta_t k_t k_t^T) Diag(exp g_t)
-    S_{t-1} + beta_t k_t v_t^T, and returns o_t = S_t^T q_t, (b, T, H, V) in
-    v's dtype.  Computed 64 tokens a chunk; T is a whole number of chunks (or
+    log decay g, a decay a channel (b, T, H, K) (Kimi Delta Attention) or a
+    head (b, T, H) (Gated DeltaNet: g's rank says which), and the step beta (b,
+    T, H); a head keeps a float32 [K, V] state, S_t = (I - beta_t k_t k_t^T)
+    Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T, and returns o_t = S_t^T q_t, (b, T,
+    H, V) in v's dtype.  q and k may have fewer heads than v, a divisor of H:
+    value head h reads key head h div (H / key heads).  Computed 64 tokens a chunk; T is a whole number of chunks (or
     at most one).  The state starts at zero with every row: sequences are
     whole.  The op's `Stats` (mean decay, mean step, largest |S| at the end)
     are published a logged step by `train_loop` as a `kind="kda_state"` record."""

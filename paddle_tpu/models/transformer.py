@@ -68,12 +68,18 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
     head's leading features turn, a table of frequencies where they are not
     theta's own (`yarn_frequencies`), a factor on cos and sin.
 
-    `head_gate` gates each head's output before the out projection (the
-    head-wise gated attention of Qiu et al. 2025, arXiv:2505.06708): g =
-    sigmoid(x Wg), Wg [d_model, n_heads], ONE number a head a token, read from
-    the layer's own (normed) input in float32; the attention's output, in the
-    layout the attention left it, is multiplied by it in float32 and rounded
-    once.  The projection, the sigmoid and the product stand in the scope
+    `head_gate` (True or "head") gates each head's output before the out
+    projection (the head-wise gated attention of Qiu et al. 2025,
+    arXiv:2505.06708): g = sigmoid(x Wg), Wg [d_model, n_heads], ONE number a
+    head a token, read from the layer's own (normed) input in float32; the
+    attention's output, in the layout the attention left it, is multiplied by it
+    in float32 and rounded once.  `head_gate="feature"` is the same paper's
+    gate a FEATURE (Qwen3-Next's): the gate's columns ride in the query
+    projection, x Wq [d_model, n_heads x 2 head_dim], a head's head_dim query
+    features and then its head_dim gate features; the attention's output, heads
+    merged, is multiplied by sigmoid of them in float32 and rounded once, n_heads
+    x head_dim numbers a token and no matrix of its own.  Either form's sigmoid
+    and product (and the form a head's projection) stand in the scope
     `attention_gate`.
 
     `n_kv_heads` (a divisor of `n_heads`) gives keys and values fewer heads
@@ -121,7 +127,16 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
         return layers.fc(t, width, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
                          bias_attr=_attr(f"{prefix}.{name}.b") if proj_bias else False)
 
-    q = project(x, "q", n_heads * d_head)
+    if head_gate not in (False, True, "head", "feature"):
+        raise ValueError(f"multi_head_attention: head_gate={head_gate!r}; True or \"head\" (a number a head), or \"feature\"")
+    feature_gate = None
+    if head_gate == "feature":   # [q | gate] a head in one projection: the query's features go on as ever
+        both = layers.reshape(project(x, "q", n_heads * 2 * d_head), [0, 0, n_heads, 2 * d_head])
+        q = layers.reshape(layers.slice(both, axes=[3], starts=[0], ends=[d_head]), [0, 0, n_heads * d_head])
+        with name_scope("attention_gate"):
+            feature_gate = layers.slice(both, axes=[3], starts=[d_head], ends=[2 * d_head])
+    else:
+        q = project(x, "q", n_heads * d_head)
     if kept_kv is None:
         k, v = project(kv_in, "k", n_kv_heads * d_head), project(kv_in, "v", n_kv_heads * d_head)
     if qk_norm_eps is not None and not qk_norm_per_head:
@@ -219,11 +234,14 @@ def multi_head_attention(x, seq_len, d_model, n_heads, prefix, dropout_prob=0.1,
             attn = layers.dropout(attn, dropout_prob, is_test=is_test,
                                   dropout_implementation="upscale_in_train")
         ctx = layers.matmul(attn, v)  # (B, H, L, dh)
-    if head_gate:   # in the layout the attention left: the one transpose that follows feeds the out projection as before
-        with name_scope("attention_gate"):
+    if head_gate and feature_gate is None:   # in the layout the attention left: the one transpose that follows feeds
+        with name_scope("attention_gate"):   # the out projection as before
             ctx = _head_gate(x, ctx, n_heads, f"{prefix}.gate.w", heads_major)
     if heads_major:
         ctx = layers.transpose(ctx, [0, 2, 1, 3])
+    if feature_gate is not None:             # in the projections' own layout, (B, L, H, dh): the gate is never transposed
+        with name_scope("attention_gate"):
+            ctx = _feature_gate(ctx, feature_gate)
     ctx = layers.reshape(ctx, [0, 0, n_heads * d_head])
     return project(ctx, "out")
 
@@ -239,6 +257,14 @@ def _head_gate(x, ctx, n_heads, name, heads_major):
                                     bias_attr=False, precision="highest"))
     gate = (layers.reshape(layers.transpose(gate, [0, 2, 1]), [0, n_heads, 0, 1]) if heads_major
             else layers.reshape(gate, [0, 0, n_heads, 1]))
+    return layers.cast(layers.elementwise_mul(layers.cast(ctx, "float32"), gate), ctx.dtype)
+
+
+def _feature_gate(ctx, gate):
+    """ctx (B, L, H, dh) times sigmoid(gate), a number a FEATURE a token, the
+    gate as the query projection made it (B, L, H, dh): the sigmoid and the
+    product in float32, rounded once to ctx's dtype, as `_head_gate`'s are."""
+    gate = layers.sigmoid(layers.cast(gate, "float32"))
     return layers.cast(layers.elementwise_mul(layers.cast(ctx, "float32"), gate), ctx.dtype)
 
 
@@ -316,6 +342,33 @@ def latent_attention(x, d_model, n_heads, prefix, rank, nope_dim, rope_dim, v_di
         return project(layers.reshape(ctx, [0, 0, n_heads * v_dim]), "out", d_model)
 
 
+def _delta_rule_parts(prefix, conv_kernel, head_dim):
+    """What the two delta-rule operators (`kimi_delta_attention`,
+    `gated_delta_net`) share round the op `kda`: `project` (a matrix without a
+    bias unless asked), `mixed` (the taps and the SiLU of `short_conv`'s plain
+    mode, then the heads), `unit` (the L2 norm a head times a scale) and
+    `decay_attrs` (how A_log and dt_bias are drawn: a decay of 0.2 to 0.999 a
+    token)."""
+    def project(t, name, out, bias=False):
+        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
+                         bias_attr=ParamAttr(name=f"{prefix}.{name}.b", initializer=ConstantInitializer(0.0))
+                         if bias else False)
+
+    def mixed(t, name, heads):   # taps, SiLU, heads
+        t = layers.short_conv(t, conv_kernel, gated=False, activation="silu", filter_attr=_attr(f"{prefix}.{name}_conv.w"))
+        return layers.reshape(t, [0, 0, heads, head_dim]) if heads else t
+
+    def unit(t, scale):   # t / sqrt(sum t^2 + 1e-6) . scale = rms(t; 1e-6 / head_dim) . head_dim^-0.5 . scale
+        t = layers.rms_norm(t, begin_norm_axis=3, epsilon=1e-6 / head_dim, param_attr=False)
+        return layers.scale(t, scale=scale * head_dim ** -0.5)
+
+    decay_attrs = dict(
+        a_log_attr=ParamAttr(name=f"{prefix}.a_log", initializer=UniformInitializer(0.0, float(np.log(16.0)))),
+        dt_bias_attr=ParamAttr(name=f"{prefix}.dt_bias",
+                               initializer=UniformInitializer(float(np.log(1e-3)), float(np.log(1e-1)))))
+    return project, mixed, unit, decay_attrs
+
+
 def kimi_delta_attention(x, d_model, n_heads, head_dim, prefix, conv_kernel=4, norm_eps=1e-5):
     """The Kimi-Delta-Attention operator (Kimi Linear, arXiv:2510.26692) round
     the op `kda`: q, k and v are each a projection to n_heads x head_dim, a
@@ -327,34 +380,57 @@ def kimi_delta_attention(x, d_model, n_heads, head_dim, prefix, conv_kernel=4, n
     RMS-normed a head (one gain of head_dim), gated by sigmoid of a second
     low-rank pair (a bias on its second matrix) and projected back."""
     width = n_heads * head_dim
-
-    def project(t, name, out, bias=False):
-        return layers.fc(t, out, num_flatten_dims=2, param_attr=_attr(f"{prefix}.{name}.w"),
-                         bias_attr=ParamAttr(name=f"{prefix}.{name}.b", initializer=ConstantInitializer(0.0))
-                         if bias else False)
-
-    def mixed(name):   # projection, taps, SiLU, heads
-        t = layers.short_conv(project(x, name, width), conv_kernel, gated=False, activation="silu",
-                              filter_attr=_attr(f"{prefix}.{name}_conv.w"))
-        return layers.reshape(t, [0, 0, n_heads, head_dim])
-
-    def unit(t, scale):   # t / sqrt(sum t^2 + 1e-6) . scale = rms(t; 1e-6 / head_dim) . head_dim^-0.5 . scale
-        t = layers.rms_norm(t, begin_norm_axis=3, epsilon=1e-6 / head_dim, param_attr=False)
-        return layers.scale(t, scale=scale * head_dim ** -0.5)
+    project, mixed, unit, decay_attrs = _delta_rule_parts(prefix, conv_kernel, head_dim)
 
     with name_scope("kda"):
-        q, k, v = unit(mixed("q"), head_dim ** -0.5), unit(mixed("k"), 1.0), mixed("v")
-        g = layers.kda_gate(
-            project(project(x, "f_a", head_dim), "f_b", width), n_heads,
-            a_log_attr=ParamAttr(name=f"{prefix}.a_log", initializer=UniformInitializer(0.0, float(np.log(16.0)))),
-            dt_bias_attr=ParamAttr(name=f"{prefix}.dt_bias",
-                                   initializer=UniformInitializer(float(np.log(1e-3)), float(np.log(1e-1)))))
+        def taken(name):
+            return mixed(project(x, name, width), name, n_heads)
+
+        q, k, v = unit(taken("q"), head_dim ** -0.5), unit(taken("k"), 1.0), taken("v")
+        g = layers.kda_gate(project(project(x, "f_a", head_dim), "f_b", width), n_heads, **decay_attrs)
         beta = layers.sigmoid(layers.cast(project(x, "b", n_heads), "float32"))
         o = layers.rms_norm(layers.kda(q, k, v, g, beta), begin_norm_axis=3, epsilon=norm_eps,
                             param_attr=_attr_ones(f"{prefix}.o_norm.w"))
         gate = layers.sigmoid(project(project(x, "g_a", head_dim), "g_b", width, bias=True))
         o = layers.elementwise_mul(o, layers.reshape(gate, [0, 0, n_heads, head_dim]))
         return project(layers.reshape(o, [0, 0, width]), "out", d_model)
+
+
+def gated_delta_net(x, d_model, key_heads, value_heads, head_dim, prefix, conv_kernel=4, norm_eps=1e-6):
+    """The Gated DeltaNet operator (Yang et al. 2024, arXiv:2412.06464, as
+    Qwen3-Next has it) round the op `kda` with a decay of ONE number a head:
+    [q | k | v | z] = x Wqkvz, `key_heads` heads of `head_dim` for q and for k,
+    `value_heads` (a multiple of them) for v and for z; [b | alpha] = x Wba, a
+    number a value head each.  The columns of [q | k | v] pass ONE depthwise
+    causal convolution of `conv_kernel` taps and a SiLU; q and k are
+    L2-normalised a head (eps 1e-6 on the sum of squares) and q scaled by
+    head_dim^-0.5; beta = sigmoid(b) and g = -exp(A_log[h]) . softplus(alpha +
+    dt_bias[h]) in float32 (`kda_gate` at a width of one a head); value head h
+    reads key head h div (value_heads / key_heads) (`layers.kda`).  The
+    recurrence's output is RMS-normed a head (one gain of head_dim), multiplied
+    by silu(z) in float32, rounded once and projected back.  The whole operator
+    stands in the scope `gated_delta_net`; the op keeps `kda_chunk_scan` inside."""
+    keys, values = key_heads * head_dim, value_heads * head_dim
+    project, mixed, unit, decay_attrs = _delta_rule_parts(prefix, conv_kernel, head_dim)
+
+    def part(t, lo, hi, heads):
+        return layers.reshape(layers.slice(t, axes=[2], starts=[lo], ends=[hi]), [0, 0, heads, (hi - lo) // heads])
+
+    with name_scope("gated_delta_net"):
+        qkvz = project(x, "qkvz", 2 * keys + 2 * values)
+        taken = mixed(layers.slice(qkvz, axes=[2], starts=[0], ends=[2 * keys + values]), "qkv", None)
+        q, k = unit(part(taken, 0, keys, key_heads), head_dim ** -0.5), unit(part(taken, keys, 2 * keys, key_heads), 1.0)
+        v = part(taken, 2 * keys, 2 * keys + values, value_heads)
+        ba = project(x, "ba", 2 * value_heads)
+        beta = layers.sigmoid(layers.cast(layers.slice(ba, axes=[2], starts=[0], ends=[value_heads]), "float32"))
+        g = layers.kda_gate(layers.slice(ba, axes=[2], starts=[value_heads], ends=[2 * value_heads]), value_heads, **decay_attrs)
+        o = layers.rms_norm(layers.kda(q, k, v, layers.reshape(g, [0, 0, value_heads]), beta), begin_norm_axis=3,
+                            epsilon=norm_eps, param_attr=_attr_ones(f"{prefix}.o_norm.w"))
+        # the gate's product in the projections' own layout, [b, T, H . 128]: z is never split into heads (a head's 128
+        # lanes of 4096 and a [32, 128] tile are two layouts on the chip: 2.1 GB of float32 copies an 8-row clone, PR 69)
+        o, z = layers.reshape(o, [0, 0, values]), layers.slice(qkvz, axes=[2], starts=[2 * keys + values], ends=[2 * keys + 2 * values])
+        o = layers.cast(layers.elementwise_mul(layers.cast(o, "float32"), layers.swish(layers.cast(z, "float32"))), o.dtype)
+        return project(o, "out", d_model)
 
 
 def mamba_mixer(x, d_model, prefix, expand=2, state=16, dt_rank=None, conv_kernel=4, norm_eps=1e-6,
@@ -499,7 +575,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     attention.  `operator="conv"` puts a gated short convolution of
     `conv_kernel` taps (`layers.short_conv`) where the attention stands,
     `operator="kda"` a Kimi-Delta-Attention operator (`kimi_delta_attention`;
-    `operator_args` = dict(n_heads=, head_dim=)) and
+    `operator_args` = dict(n_heads=, head_dim=)), `operator="gated_delta_net"` a
+    Gated DeltaNet operator (`gated_delta_net`; `operator_args` =
+    dict(key_heads=, value_heads=, head_dim=)) and
     `operator="latent_attention"` attention over latent keys and values
     (`latent_attention`; `operator_args` = dict(rank=, nope_dim=, rope_dim=,
     v_dim=), with `rope=True` the layer's rotary embedding on `positions` at
@@ -541,7 +619,8 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     `shared_experts`: how many shared experts every token passes beside the
     routed ones; `activation`, `gated`, `latent_size`, `shared_width`: the
     experts' form, the latent they live in and the shared expert's own width;
-    `router_ahead`: the router reads the LAYER's input x, before the input norm
+    `shared_gate`: the shared expert's output times a sigmoid gate of its own,
+    one number a token; `router_ahead`: the router reads the LAYER's input x, before the input norm
     and the operator, and not the normed h the experts read, and its op stands
     ahead of the operator's: `layers.moe(router_input=)`); its two auxiliary
     losses are appended to `aux_losses` as (load balance, router z).
@@ -578,6 +657,7 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
                 shared_experts=moe.get("shared_experts", 0),
                 shared_attrs=tuple(_attr(f"{prefix}.moe.shared.{n}.w") for n in ("gate", "up", "down")),
                 **{n: moe[n] for n in ("activation", "gated", "latent_size", "shared_width") if n in moe},
+                **({"shared_gate_attr": _attr(f"{prefix}.moe.shared_gate.w")} if moe.get("shared_gate") else {}),
                 **({"latent_attrs": tuple(_attr(f"{prefix}.moe.latent_{n}.w") for n in ("in", "out"))}
                    if moe.get("latent_size") else {}))
             aux_losses.append((balance, z_loss))
@@ -606,6 +686,9 @@ def encoder_layer(x, seq_len, d_model, n_heads, d_ff, prefix, dropout_prob=0.1, 
     elif operator == "kda":
         attn_out = kimi_delta_attention(operator_in, d_model, prefix=f"{prefix}.kda", conv_kernel=conv_kernel,
                                         norm_eps=norm_eps, **operator_args)
+    elif operator == "gated_delta_net":
+        attn_out = gated_delta_net(operator_in, d_model, prefix=f"{prefix}.gdn", conv_kernel=conv_kernel,
+                                   norm_eps=norm_eps, **operator_args)
     elif operator == "latent_attention":
         latent = dict(operator_args)
         attn_out = latent_attention(operator_in, d_model, n_heads, f"{prefix}.attn", norm_eps=norm_eps,
@@ -765,6 +848,9 @@ def build_causal_lm(
     mamba2=None,
     expert_form=None,
     attention_gate=False,
+    linear_key_heads=None,
+    linear_value_heads=None,
+    linear_head_dim=None,
 ):
     """Decoder-only language model with routed experts in every layer: the
     OLMoE-1B-7B block at its defaults (Muennighoff et al. 2024,
@@ -921,6 +1007,20 @@ def build_causal_lm(
     head on its output (`multi_head_attention(head_gate=)`, the scope
     `attention_gate`).
 
+    A hybrid of Gated DeltaNet and gated softmax attention (Qwen3-Next:
+    Qwen/Qwen3-Next-80B-A3B-Instruct) is arguments too.  `layer_types` may hold
+    "gated_delta_net" (a delta rule whose decay is ONE number a head:
+    `gated_delta_net`, of `linear_key_heads` key heads feeding
+    `linear_value_heads` value heads, all `linear_head_dim` wide, one convolution
+    of `conv_kernel` taps over [q | k | v], a SiLU-gated norm; the op is `kda`,
+    its decay's rank says which rule).  `attention_gate="feature"` is the gate a
+    FEATURE: the gate's columns ride in the query projection and the merged
+    heads are multiplied by their sigmoid (True or "head" stays the gate a head).
+    `expert_form` may hold `shared_gate=True`: the shared expert's output times
+    sigmoid(m w_s), a gate of its own, one number a token (`layers.moe`, the
+    scope `moe_shared_gate`).  A head 256 wide of which a quarter turns is
+    `head_dim` and `rope_theta=dict(theta=, rotary_dim=)` as above.
+
     A looped (weight-shared) decoder is arguments as well.  `num_dense_layers`
     equal to the depth makes every layer dense: no router, and the auxiliary
     terms and their fetches are left out.  `post_norm` is `encoder_layer`'s
@@ -944,16 +1044,23 @@ def build_causal_lm(
         raise ValueError(f"build_causal_lm: n_layers={n_layers} beside {len(layer_types)} layer_types; "
                          "layer_types alone states the depth")
     kinds = list(layer_types) if layer_types is not None else ["full_attention"] * (16 if n_layers is None else n_layers)
-    operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "latent_attention": "latent_attention",
+    operators = {"full_attention": "attention", "conv": "conv", "kda": "kda", "gated_delta_net": "gated_delta_net",
+                 "latent_attention": "latent_attention",
                  "mamba": "mamba", "sliding_attention": "attention", "gmu": "gmu", "cross_attention": "cross_attention",
                  "sparse_attention": "attention", "mamba2": "mamba2", "feed_forward": None}
     operator_args = {"kda": dict(n_heads=kda_heads, head_dim=kda_head_dim), "latent_attention": latent, "mamba": mamba,
-                     "mamba2": mamba2}
+                     "mamba2": mamba2,
+                     "gated_delta_net": dict(key_heads=linear_key_heads, value_heads=linear_value_heads,
+                                             head_dim=linear_head_dim)}
     unknown = sorted(set(kinds) - set(operators))
     if unknown:
         raise ValueError(f"build_causal_lm: layer_types holds {unknown}; a layer is full_attention or conv, "
                          "kda or latent_attention, mamba, sliding_attention, gmu or cross_attention, or sparse_attention, "
-                         "mamba2 or feed_forward")
+                         "mamba2 or feed_forward, or gated_delta_net")
+    if "gated_delta_net" in kinds and not (linear_key_heads and linear_value_heads and linear_head_dim
+                                           and linear_value_heads % linear_key_heads == 0):
+        raise ValueError("build_causal_lm: a gated_delta_net layer needs linear_key_heads, linear_value_heads (a multiple "
+                         "of them) and linear_head_dim")
     if (("kda" in kinds and not (kda_heads and kda_head_dim)) or ("latent_attention" in kinds and not latent)
             or ("mamba" in kinds and not mamba) or ("mamba2" in kinds and not mamba2)):
         raise ValueError("build_causal_lm: a kda layer needs kda_heads and kda_head_dim, a latent_attention layer "
@@ -1050,7 +1157,7 @@ def build_causal_lm(
                                       keep=kept if i in (memory_layer, kv_layer) else None, kept=kept,
                                       sparse_index=sparse_index if kind == "sparse_attention" else None,
                                       index_losses=pending,
-                                      head_gate=attention_gate and operators[kind] in ("attention", "cross_attention"))
+                                      head_gate=attention_gate if operators[kind] in ("attention", "cross_attention") else False)
                 index_terms.extend(make() for make in pending)
             return x
 

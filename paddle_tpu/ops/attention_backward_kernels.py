@@ -69,6 +69,7 @@ block are `masked_attention._BLOCKS`' and `_WINDOW_BLOCKS`'):
   (1, 40 on 20, 8192, 64), window 512      7.20   11.71          8.42
   (1, 64 on 8, 16384, 128), window 512    18.39   50.24         22.61      (my chip run, PR 65, call 1)
   (1, 48 on 8, 16384, 128), causal        74.06   82.95                    (groups of SIX: `rem(head, 6)`)
+  (1, 16 on 2, 16384, 256), causal        48.37   52.92         61.88      (my chip run, PR 69: 256-wide heads, groups of EIGHT)
 
 Under a STORED mask (my chip run, PR 68, call 2; `STORED=1 python3
 tools/chip_block_attention.py`; ours, dk and dv of a group summed in VMEM |
@@ -91,7 +92,14 @@ float32 and summed by XLA: 24.23 | 25.04 at 28 on 4 x 16384 (0.94 GB a layer
 written and read back), 29.74 | 30.56 at 32 on 8 x 8192, 7.20 | 7.35 at 40 on
 20, 18.39 | 18.33 at 64 on 8 x 16384 under a window of 512 and 74.06 | 74.03 at
 48 on 8 under the causal rule (PR 65: level, where a band 512 wide or groups of six
-leave the sum outside little to move): in VMEM wherever the rows fit.  The call is a `jax.jit` of its own so that a
+leave the sum outside little to move): in VMEM wherever the rows fit.  At 256-wide heads and 16384 keys they do
+NOT: a head's dq and its output block hold 32 MiB (`vmem_bytes` 46 with the blocks beside them), a key/value head's
+dk and dv rows would hold 64 more (110 MiB: the chip's compiler refuses it at every grid block, `RESOURCE_EXHAUSTED`,
+my chip run, PR 69), so dk and dv of a group of eight go out in float32 a query head, 0.54 GB a layer, and are summed
+outside: 48.37 ms forward and backward at 1024-blocks (15.38 of it forward), 51.51 at 512, 66.95 at 256, against the
+stock fused backward's 52.92 and the stock pair's 61.88 at 1024 (`WIDE=256 python3 tools/chip_block_attention.py`);
+a kernel that holds a key/value head's rows and a query BLOCK's dq, or half a head's rows, is not written
+(ROADMAP.md, R).  The call is a `jax.jit` of its own so that a
 model's layers share one lowering (`setup_s` is end to end).
 """
 from __future__ import annotations

@@ -3,7 +3,8 @@ has the recurrence and the chunked form's equations; `_kda_path` there sends
 the op here).
 
 A grid step is a (row, group of heads, chunk), the chunks in order: it reads
-its heads' 64 tokens of q, k, v [C, 128], the log decay g [C, 128] and beta [C]
+its heads' 64 tokens of q, k, v [C, 128], the log decay g [C, 128] (or [C], one
+number a head: below) and beta [C]
 from the op's own `[b, T, H, .]` arrays (a 128-wide head is a whole lane tile
 of `[b, T, H . 128]`: no transpose in HBM), makes in VMEM the cumulative decay
 G, the decayed Grams M and P, T = (I + beta M)^-1, W, U, Kend and from them Phi,
@@ -25,6 +26,20 @@ head from chunk to chunk in VMEM scratch (the chunk axis is "arbitrary"):
     written out by hand (a dozen products; T's transpose is a product with
     T^T; the decay's gradient from the Grams is x . dx - k . dk); dq, dk, dv,
     dg, dbeta out.
+
+The decay is a channel's or a head's, and q and k may have fewer heads than
+v (Gated DeltaNet: ISSUE 69).  A decay a head comes as g `[b, T, H]`, a head's
+column of the block that beta's comes from; its cumulative sum G is a column
+`[C, 1]`, every exponential of it one number a row, and it factors OUT of the
+Grams: M = (k k^T) . D, P = (q k^T) . D, D[r, i] = exp(G[r] - G[i]) on and
+under the diagonal (`_Chunks._scalar_grams`: one product a KEY head and a
+`[C, C]` matrix a value head; no block of 16 rows, no key carried back, no
+`lax.cond`, no exponent positive whatever the decay), and its gradient goes out
+as the head's row `[1, C]` a chunk, as beta's does.  Where `shared` value heads
+read one key head (value head h the key head h div shared), a grid step's
+blocks of q and k hold its group's key heads alone (`heads / shared` of them:
+the index map, nothing repeated in HBM) and a key head's dq and dk are its value
+heads' summed in float32 before they are rounded and written.
 
 Precision is the op's: float32 operands in VMEM, every product at the
 precision `linear_attention_ops` states (`HIGHEST`: Mosaic's float32
@@ -58,7 +73,13 @@ _TN = (((0,), (0,)), ((), ()))
 #: interleave, and a DMA's rows are 4 x 256 bytes long.  TPU v5e, (1, 4096, 32,
 #: 128), ms forward | backward of the op alone at 1, 2, 4 heads: 6.44 | 15.92,
 #: 4.70 | 11.98, 4.07 | 10.70; at 8 the transposed kernel overruns its VMEM (my
-#: chip run, PR 44; `HEADS=1 python3 tools/chip_kimi_kernels.py`).
+#: chip run, PR 44; `HEADS=1 python3 tools/chip_kimi_kernels.py`).  At Qwen3-Next's
+#: (1, 16384, 32, 128) with 16 key heads and a decay a HEAD (`ONLY=gdn` of the
+#: tool, my chip run, PR 69), forward | backward at 2, 4, 8 value heads a step:
+#: 16.09 | 20.88, 14.19 | 19.08, 13.76 | 18.63 (the scalar form's terms are small
+#: enough for 8 to fit; 3% is not worth a second constant), against 16.45 | 25.67
+#: for the same decay written out over the channels and q and k repeated to 32
+#: heads in HBM through the decay-a-channel form at 4: 19% less both ways.
 _HEADS = 4
 
 
@@ -96,35 +117,29 @@ class _Chunks:
     block, `seams` = (the products' precision, the cumulative decay's function,
     the carried state's); `T`: the heads' (I + beta M)^-1 where forward kept them."""
 
-    def __init__(self, heads, sub, safe, seams, T=None):
+    def __init__(self, heads, sub, safe, seams, T=None, scalar=False, shared=1):
         self.precision, cumulative_fn, self.carried = seams
         self.heads = range(len(heads))
         self.q, self.k, self.v, _, self.beta = (list(t) for t in zip(*heads))
         C, K = self.k[0].shape
-        self.C, self.K, self.sub, self.n = C, K, sub, C // sub
+        self.C, self.K, self.sub, self.n, self.scalar, self.shared = C, K, sub, C // sub, scalar, shared
         row, col = self.row, self.col = _iota((C, C), 0), _iota((C, C), 1)
         self.lower, self.strict, self.eye = row >= col, row > col, row == col
         self.own = (row // sub) == (col // sub)                   # pairs inside one block
         self.at_row = row[:, :1]
         self.G = [cumulative_fn(g, self.lower) for _, _, _, g, _ in heads]
+        if scalar:      # one decay a head: G a column [C, 1], and every term below that reads it spreads it over the channels
+            self.G = [G[:, :1] for G in self.G]
         self.from_start = [jnp.exp(G) for G in self.G]
-        self.last = [G[C - 1:C] for G in self.G]
-        self.to_end = [jnp.exp(last - G) for last, G in zip(self.last, self.G)]
+        self.to_end = [jnp.exp(G[C - 1:C] - G) for G in self.G]
+        # the last row's, a row [1, K] either way (Mosaic spreads a [1, 1] over one axis, not over both)
+        self.last = [jnp.broadcast_to(G[C - 1:C], (1, K)) for G in self.G]
         self.k_end = [k * e for k, e in zip(self.k, self.to_end)]
-        # a block's rows decayed from its first row, and every key carried to that row (back, for the block's own)
-        self.leave, self.back, self.strong = [], [], []
-        for G in self.G:
-            firsts = [G[a * sub:a * sub + 1] for a in range(self.n)]
-            self.leave.append([jnp.exp(G[self.rows_of(a)] - firsts[a]) for a in range(self.n)])
-            self.back.append([jnp.exp(jnp.minimum(firsts[a] - G, safe)) for a in range(self.n)])
-            inside = jnp.concatenate([firsts[a] - G[(a + 1) * sub - 1:(a + 1) * sub] for a in range(self.n)], axis=0)
-            self.strong.append(jnp.max(inside) > safe)
-        # block a's rows of k over those of q, decayed from its first row [2 sub, K]; every key carried to that row [C, K]
-        self.near = [[jnp.concatenate([k[self.rows_of(a)] * leave[a], q[self.rows_of(a)] * leave[a]], axis=0)
-                      for a in range(self.n)] for q, k, leave in zip(self.q, self.k, self.leave)]
-        self.keys_at = [[k * back[a] for a in range(self.n)] for k, back in zip(self.k, self.back)]
-        self.any_strong = functools.reduce(jnp.logical_or, self.strong)
-        grams = self._grams()
+        if scalar:
+            grams = self._scalar_grams()
+        else:
+            self._blocks(safe)
+            grams = self._grams()
         self.M = [jnp.where(self.strict, M, 0.0) for M, _ in grams]
         self.P = [jnp.where(self.lower, P, 0.0) for _, P in grams]
         self.T = self._unit_lower_inverses([beta * M for beta, M in zip(self.beta, self.M)]) if T is None else T
@@ -137,6 +152,36 @@ class _Chunks:
         self.B = [e[:, K:] for e in ends]
         self.q_eff = [q * s - r[:, :K] for q, s, r in zip(self.q, self.from_start, reads)]
         self.own_out = [r[:, K:] for r in reads]
+
+    def _blocks(self, safe):
+        """A decay a channel: a block's rows decayed from its first row, and every key carried to that row (back, for the
+        block's own)."""
+        sub = self.sub
+        self.leave, self.back, self.strong = [], [], []
+        for G in self.G:
+            firsts = [G[a * sub:a * sub + 1] for a in range(self.n)]
+            self.leave.append([jnp.exp(G[self.rows_of(a)] - firsts[a]) for a in range(self.n)])
+            self.back.append([jnp.exp(jnp.minimum(firsts[a] - G, safe)) for a in range(self.n)])
+            inside = jnp.concatenate([firsts[a] - G[(a + 1) * sub - 1:(a + 1) * sub] for a in range(self.n)], axis=0)
+            self.strong.append(jnp.max(inside) > safe)
+        # block a's rows of k over those of q, decayed from its first row [2 sub, K]; every key carried to that row [C, K]
+        self.near = [[jnp.concatenate([k[self.rows_of(a)] * leave[a], q[self.rows_of(a)] * leave[a]], axis=0)
+                      for a in range(self.n)] for q, k, leave in zip(self.q, self.k, self.leave)]
+        self.keys_at = [[k * back[a] for a in range(self.n)] for k, back in zip(self.k, self.back)]
+        self.any_strong = functools.reduce(jnp.logical_or, self.strong)
+
+    def _scalar_grams(self):
+        """A decay a head factors OUT of the Grams: M = (k k^T) . D and P = (q k^T) . D with D[r, i] = exp(G[r] - G[i])
+        on and under the diagonal (no exponent positive), one product a KEY head (the value heads that share it take
+        the same k k^T and q k^T) and a [C, C] matrix a value head: no block, no key carried back, no `lax.cond`."""
+        self.decay, grams = [], []
+        for h in self.heads:
+            across = jnp.sum(jnp.where(self.eye, self.G[h], 0.0), axis=0, keepdims=True)      # G as a row [1, C]
+            self.decay.append(jnp.where(self.lower, jnp.exp(jnp.where(self.lower, self.G[h] - across, 0.0)), 0.0))
+            if h % self.shared == 0:
+                both = self.dot(jnp.concatenate([self.k[h], self.q[h]], axis=0), self.k[h], _NT)   # [2 C, C]
+            grams.append((both[:self.C] * self.decay[h], both[self.C:] * self.decay[h]))
+        return grams
 
     def dot(self, a, b, dims=_NN):
         return jax.lax.dot_general(a, b, dims, precision=self.precision, preferred_element_type=F32)
@@ -220,18 +265,22 @@ class _Chunks:
             d_Rw, d_Ru = d_R[:, :K], d_R[:, K:]
             d_last = (jnp.sum(jnp.where(self.eye_K, d_phi[h], 0.0), axis=0, keepdims=True) * jnp.exp(self.last[h])
                       + jnp.sum(d_k_end * self.k_end[h], axis=0, keepdims=True))
-            # the Grams' transpose, rows' side (k under M, q under P) and keys' side apart for the decay's gradient;
-            # the block's own pairs are left to the loop below where they came from it
-            far = jnp.where(self.own, 1.0 - self.strong[h].astype(F32), 1.0)
-            rows_k, rows_q, keys = [], [], jnp.zeros_like(k)
-            for a in range(self.n):
-                d_strip = jnp.concatenate([d_M[self.rows_of(a)], d_P[self.rows_of(a)]], axis=0) * jnp.concatenate(
-                    [far[self.rows_of(a)]] * 2, axis=0)                                       # [2 sub, C]
-                d_near = self.dot(d_strip, self.keys_at[h][a])
-                rows_k.append(d_near[:sub] * self.leave[h][a])
-                rows_q.append(d_near[sub:] * self.leave[h][a])
-                keys = keys + self.dot(d_strip, self.near[h][a], _TN) * self.back[h][a]
-            grams += [jnp.concatenate(rows_k, axis=0), jnp.concatenate(rows_q, axis=0), keys]
+            # the Grams' transpose, rows' side (k under M, q under P) and keys' side apart for the decay's gradient
+            if self.scalar:
+                d_strip = jnp.concatenate([d_M * self.decay[h], d_P * self.decay[h]], axis=0)  # [2 C, C]
+                d_near = self.dot(d_strip, k)
+                grams += [d_near[:self.C], d_near[self.C:], self.dot(d_strip, jnp.concatenate([k, q], axis=0), _TN)]
+            else:   # the block's own pairs are left to the loop below where they came from it
+                far = jnp.where(self.own, 1.0 - self.strong[h].astype(F32), 1.0)
+                rows_k, rows_q, keys = [], [], jnp.zeros_like(k)
+                for a in range(self.n):
+                    d_strip = jnp.concatenate([d_M[self.rows_of(a)], d_P[self.rows_of(a)]], axis=0) * jnp.concatenate(
+                        [far[self.rows_of(a)]] * 2, axis=0)                                   # [2 sub, C]
+                    d_near = self.dot(d_strip, self.keys_at[h][a])
+                    rows_k.append(d_near[:sub] * self.leave[h][a])
+                    rows_q.append(d_near[sub:] * self.leave[h][a])
+                    keys = keys + self.dot(d_strip, self.near[h][a], _TN) * self.back[h][a]
+                grams += [jnp.concatenate(rows_k, axis=0), jnp.concatenate(rows_q, axis=0), keys]
             found.append((d_M, d_P, d_k_end, d_last, d_A, d_Rw, d_Ru))
 
         def by_differences(*grams):
@@ -251,7 +300,8 @@ class _Chunks:
 
             return jax.lax.fori_loop(0, self.C, key, grams)
 
-        grams = jax.lax.cond(self.any_strong, by_differences, lambda *grams: grams, *grams)
+        if not self.scalar:
+            grams = jax.lax.cond(self.any_strong, by_differences, lambda *grams: grams, *grams)
         out = []
         for h in self.heads:
             q, k, v, beta = self.q[h], self.k[h], self.v[h], self.beta[h]
@@ -259,7 +309,10 @@ class _Chunks:
             _, _, d_k_end, d_last, d_A, d_Rw, d_Ru = found[h]
             d_G = ((d_qe[h] * q + d_Rw * beta * k) * self.from_start[h] - d_k_end * self.k_end[h]
                    + k * rows_k + q * rows_q - k * keys + jnp.where(self.at_row == self.C - 1, d_last, 0.0))
-            d_g = _sums(self.lower, d_G, _TN)                                                 # the sum from each token on
+            if self.scalar:     # the channels' sum, then the sum from each token on, as the head's ROW [1, C]: float32 sums
+                d_g = jnp.sum(jnp.where(self.lower, jnp.sum(d_G, axis=1, keepdims=True), 0.0), axis=0, keepdims=True)
+            else:
+                d_g = _sums(self.lower, d_G, _TN)                                             # the sum from each token on
             d_q = d_qe[h] * self.from_start[h] + rows_q
             d_k = d_Rw * beta * self.from_start[h] + d_k_end * self.to_end[h] + rows_k + keys
             d_beta = (jnp.sum(d_A * self.M[h], axis=1, keepdims=True)
@@ -282,21 +335,38 @@ def _halves_stacked(t):
     return jnp.concatenate([t[:, :half], t[:, half:]], axis=0)
 
 
-def _heads_of(refs, heads, widths, group):
-    """A list, a head of the grid step's group, of float32 (q, k, v, g [C, .],
-    beta [C, 1]) from the step's blocks: q, k, v, g `[1, C, heads . width]` of
-    `[b, T, H . width]`, beta `[1, C, H]`."""
-    *wide, beta_ref = refs
-    beta = beta_ref[0].astype(F32)
-    lane = _iota(beta.shape, 1)
-    return [tuple(r[0, :, j * w:(j + 1) * w].astype(F32) for r, w in zip(wide, widths))
-            + (jnp.sum(jnp.where(lane == group * heads + j, beta, 0.0), axis=1, keepdims=True),) for j in range(heads)]
+def _heads_of(refs, heads, widths, group, scalar, shared):
+    """A list, a VALUE head of the grid step's group, of float32 (q, k, v, g [C,
+    .], beta [C, 1]) from the step's blocks: v `[1, C, heads . width]` of `[b, T,
+    H . width]`, q and k `[1, C, heads / shared . width]` (`shared` value heads
+    read one key head: head j of the group reads the block's key head j div
+    shared), beta `[1, C, H]`; g as v where the decay is a channel's, as beta
+    where it is ONE number a head (`[1, C, H]`: the head's column spread over
+    the channels' width for the cumulative sum's product)."""
+    q_ref, k_ref, v_ref, g_ref, beta_ref = refs
+    k_width, v_width = widths
+
+    def column(ref, j):     # [C, 1], the head's lane of a [1, C, H] block
+        block = ref[0].astype(F32)
+        return jnp.sum(jnp.where(_iota(block.shape, 1) == group * heads + j, block, 0.0), axis=1, keepdims=True)
+
+    def lanes(ref, j, width):
+        return ref[0, :, j * width:(j + 1) * width].astype(F32)
+
+    return [(lanes(q_ref, j // shared, k_width), lanes(k_ref, j // shared, k_width), lanes(v_ref, j, v_width),
+             jnp.broadcast_to(column(g_ref, j), (g_ref.shape[1], k_width)) if scalar else lanes(g_ref, j, k_width),
+             column(beta_ref, j)) for j in range(heads)]
 
 
-def _scan_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, final_ref, *rest):
+def _step_chunks(heads, sub, safe, seams, scalar, shared, refs, T=None):
+    """(`_Chunks` of a grid step's value heads, K, V) from its five input blocks."""
+    K, V = refs[0].shape[-1] * shared // heads, refs[2].shape[-1] // heads
+    return _Chunks(_heads_of(refs, heads, (K, V), pl.program_id(1), scalar, shared), sub, safe, seams, T, scalar, shared), K, V
+
+
+def _scan_kernel(heads, sub, safe, seams, scalar, shared, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, final_ref, *rest):
     """`rest`: the blocks of the chunk's start states and T where they are kept, and the state's scratch."""
-    K, V = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
-    chunks = _Chunks(_heads_of((q_ref, k_ref, v_ref, g_ref, beta_ref), heads, (K, K, V, K), pl.program_id(1)), sub, safe, seams)
+    chunks, K, V = _step_chunks(heads, sub, safe, seams, scalar, shared, (q_ref, k_ref, v_ref, g_ref, beta_ref))
     *kept, state = rest
 
     @pl.when(pl.program_id(2) == 0)
@@ -316,11 +386,10 @@ def _scan_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, 
         final_ref[0] = state[...]
 
 
-def _transposed_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta_ref, d_o_ref, starts_ref, t_ref,
-                       dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, after):
-    K, V = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
-    chunks = _Chunks(_heads_of((q_ref, k_ref, v_ref, g_ref, beta_ref), heads, (K, K, V, K), pl.program_id(1)), sub, safe, seams,
-                     T=[_halves_stacked(t_ref[0, 0, h]) for h in range(heads)])
+def _transposed_kernel(heads, sub, safe, seams, scalar, shared, q_ref, k_ref, v_ref, g_ref, beta_ref, d_o_ref, starts_ref,
+                       t_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, after):
+    chunks, K, V = _step_chunks(heads, sub, safe, seams, scalar, shared, (q_ref, k_ref, v_ref, g_ref, beta_ref),
+                                T=[_halves_stacked(t_ref[0, 0, h]) for h in range(heads)])
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -333,11 +402,17 @@ def _transposed_kernel(heads, sub, safe, seams, q_ref, k_ref, v_ref, g_ref, beta
     d_qe = [chunks.dot(d, s, _NT) for d, s in zip(d_own, starts)]
     C = d_own[0].shape[0]
     diagonal = _iota((C, C), 0) == _iota((C, C), 1)
-    for h, (d_q, d_k, d_v, d_g, d_beta) in enumerate(chunks.transposed(d_phi, ends, d_qe, d_own)):
-        dq_ref[0, :, h * K:(h + 1) * K] = d_q.astype(dq_ref.dtype)
-        dk_ref[0, :, h * K:(h + 1) * K] = d_k.astype(dk_ref.dtype)
+    found = chunks.transposed(d_phi, ends, d_qe, d_own)
+    for j in range(heads // shared):    # a key head's gradient: its value heads' summed in float32, rounded once
+        mine = found[j * shared:(j + 1) * shared]
+        dq_ref[0, :, j * K:(j + 1) * K] = sum(d_q for d_q, *_ in mine).astype(dq_ref.dtype)
+        dk_ref[0, :, j * K:(j + 1) * K] = sum(d_k for _, d_k, *_ in mine).astype(dk_ref.dtype)
+    for h, (_, _, d_v, d_g, d_beta) in enumerate(found):
         dv_ref[0, :, h * V:(h + 1) * V] = d_v.astype(dv_ref.dtype)
-        dg_ref[0, :, h * K:(h + 1) * K] = d_g
+        if scalar:      # as beta's: the (head, chunk)'s row [1, C]
+            dg_ref[0, 0, 0, h:h + 1, :] = d_g
+        else:
+            dg_ref[0, :, h * K:(h + 1) * K] = d_g
         # beta's gradient as the (head, chunk)'s row [1, C]: the column laid on the diagonal and summed down
         dbeta_ref[0, 0, 0, h:h + 1, :] = jnp.sum(jnp.where(diagonal, d_beta, 0.0), axis=0, keepdims=True)
         after[h] = chunks.dot(chunks.phi[h], ends[h], _TN) + chunks.dot(chunks.q_eff[h], d_own[h], _TN)
@@ -348,8 +423,10 @@ def _flat(t):
     return t.reshape(t.shape[0], t.shape[1], -1)
 
 
-def _heads_a_step(H):
-    return next(g for g in (_HEADS, 2, 1) if H % g == 0)
+def heads_a_step(H, shared=1):
+    """Value heads a grid step, of `H` of which `shared` read one key head: a
+    whole number of key heads; None where no count of (`_HEADS`, 2, 1) is."""
+    return next((g for g in (_HEADS, 2, 1) if H % g == 0 and g % shared == 0), None)
 
 
 def _cost(b, T, H, K, V, chunk, times, state_bytes):
@@ -366,29 +443,34 @@ _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
 def scan(q, k, v, g, beta, chunk, sub, safe, seams, keep, interpret):
     """(o [b, T, H, V] in v's dtype, the state after the last token [b, H, K, V]
-    float32) of q, k [b, T, H, K], v [b, T, H, V], g [b, T, H, K] float32 and
+    float32) of v [b, T, H, V], q, k [b, T, H / shared, K] (`shared` value
+    heads read one key head, head h the key head h div shared: the index map
+    hands a group its key heads, nothing is repeated in HBM), the float32 log
+    decay g [b, T, H, K] a channel or [b, T, H] a head (its rank says which) and
     beta [b, T, H]; with `keep` the state every chunk starts from [n, b, H, K,
     V] and T [n, b, H, C / 2, 2 C] (`_halves_side_by_side`), float32, after
     them (the same kernel with two more output blocks a grid step: o and the
     final state are the plain call's)."""
-    (b, T, H, K), V = k.shape, v.shape[-1]
-    n, heads = T // chunk, _heads_a_step(H)
+    (b, T, key_heads, K), (H, V) = k.shape, v.shape[2:]
+    scalar, shared = g.ndim == 3, H // key_heads
+    n, heads = T // chunk, heads_a_step(H, shared)
 
-    def tokens(width):      # [b, T, H . width]: the chunk's rows, the group's lanes
+    def tokens(width, heads=heads):      # [b, T, H . width]: the chunk's rows, the group's lanes
         return pl.BlockSpec((1, chunk, heads * width), lambda i, h, c: (i, c, h))
 
+    a_head = pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, c, 0))
     out_specs = [tokens(V), pl.BlockSpec((1, heads, K, V), lambda i, h, c: (i, h, 0, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, T, H * V), v.dtype), jax.ShapeDtypeStruct((b, H, K, V), F32)]
     for rows, width in ((K, V), (chunk // 2, 2 * chunk)) if keep else ():
         out_specs.append(pl.BlockSpec((1, 1, heads, rows, width), lambda i, h, c: (c, i, h, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((n, b, H, rows, width), F32))
     o, *rest = pl.pallas_call(
-        functools.partial(_scan_kernel, heads, sub, safe, seams), grid=(b, H // heads, n),
-        in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, c, 0))],
+        functools.partial(_scan_kernel, heads, sub, safe, seams, scalar, shared), grid=(b, H // heads, n),
+        in_specs=[tokens(K, heads // shared), tokens(K, heads // shared), tokens(V), a_head if scalar else tokens(K), a_head],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=[pltpu.VMEM((heads, K, V), F32)],
         compiler_params=_SEMANTICS, cost_estimate=_cost(b, T, H, K, V, chunk, 1, 4 * b * H * (K * V + n * keep * (K * V + chunk * chunk))),
         name="kda_scan", interpret=interpret,
-    )(_flat(q), _flat(k), _flat(v), _flat(g), beta)
+    )(_flat(q), _flat(k), _flat(v), g if scalar else _flat(g), beta)
     return (o.reshape(v.shape), *rest)
 
 
@@ -398,26 +480,33 @@ def scan_transposed(q, k, v, g, beta, d_o, starts, inverses, chunk, sub, safe, s
     dtypes, dg and dbeta float32) of d o [b, T, H, V] and what `scan(keep=True)`
     kept from forward, the chunks' start states [n, b, H, K, V] and T
     [n, b, H, C / 2, 2 C], beside the inputs."""
-    (b, T, H, K), V = k.shape, v.shape[-1]
-    n, heads = T // chunk, _heads_a_step(H)
+    (b, T, key_heads, K), (H, V) = k.shape, v.shape[2:]
+    scalar, shared = g.ndim == 3, H // key_heads
+    n, heads = T // chunk, heads_a_step(H, shared)
 
-    def tokens(width):      # the chunks in reverse order
+    def tokens(width, heads=heads):      # the chunks in reverse order
         return pl.BlockSpec((1, chunk, heads * width), lambda i, h, c: (i, n - 1 - c, h))
 
     def kept(rows, width):
         return pl.BlockSpec((1, 1, heads, rows, width), lambda i, h, c: (n - 1 - c, i, h, 0, 0))
 
+    a_head = pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, n - 1 - c, 0))
+    rows = pl.BlockSpec((1, 1, 1, heads, chunk), lambda i, h, c: (i, n - 1 - c, h, 0, 0))   # a head's row [1, C] a chunk
+    a_row = jax.ShapeDtypeStruct((b, n, H // heads, heads, chunk), F32)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_transposed_kernel, heads, sub, safe, seams), grid=(b, H // heads, n),
-        in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), pl.BlockSpec((1, chunk, H), lambda i, h, c: (i, n - 1 - c, 0)),
+        functools.partial(_transposed_kernel, heads, sub, safe, seams, scalar, shared), grid=(b, H // heads, n),
+        in_specs=[tokens(K, heads // shared), tokens(K, heads // shared), tokens(V), a_head if scalar else tokens(K), a_head,
                   tokens(V), kept(K, V), kept(chunk // 2, 2 * chunk)],
-        out_specs=[tokens(K), tokens(K), tokens(V), tokens(K),
-                   pl.BlockSpec((1, 1, 1, heads, chunk), lambda i, h, c: (i, n - 1 - c, h, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(_flat(t).shape, t.dtype) for t in (q, k, v, g)]
-        + [jax.ShapeDtypeStruct((b, n, H // heads, heads, chunk), F32)],
+        out_specs=[tokens(K, heads // shared), tokens(K, heads // shared), tokens(V), rows if scalar else tokens(K), rows],
+        out_shape=[jax.ShapeDtypeStruct(_flat(t).shape, t.dtype) for t in (q, k, v)]
+        + [a_row if scalar else jax.ShapeDtypeStruct(_flat(g).shape, g.dtype), a_row],
         scratch_shapes=[pltpu.VMEM((heads, K, V), F32)], compiler_params=_SEMANTICS,
         cost_estimate=_cost(b, T, H, K, V, chunk, 3, 4 * b * H * n * (K * V + chunk * chunk)), name="kda_scan_transposed",
         interpret=interpret,
-    )(_flat(q), _flat(k), _flat(v), _flat(g), beta, _flat(d_o), starts, inverses)
-    dbeta = dbeta.reshape(b, n, H, chunk).swapaxes(2, 3).reshape(b, T, H)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), dg.reshape(g.shape), dbeta
+    )(_flat(q), _flat(k), _flat(v), g if scalar else _flat(g), beta, _flat(d_o), starts, inverses)
+
+    def by_token(t):    # [b, n, H / heads, heads, C] -> [b, T, H]
+        return t.reshape(b, n, H, chunk).swapaxes(2, 3).reshape(b, T, H)
+
+    dbeta = by_token(dbeta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), by_token(dg) if scalar else dg.reshape(g.shape), dbeta

@@ -1,16 +1,29 @@
 """Kimi Delta Attention (KDA): a linear-attention recurrence over the sequence,
 a gated delta rule with a decay for every key channel (Kimi Linear,
-arXiv:2510.26692), computed chunk by chunk.
+arXiv:2510.26692) or ONE a head (Gated DeltaNet, arXiv:2412.06464), computed
+chunk by chunk.
 
 A head keeps a float32 state S in R^{K x V} (keys x values), S_0 = 0:
 
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T,    o_t = S_t^T q_t
 
 with alpha_t = exp(g_t) in (0, 1)^K the channels' decay and beta_t in (0, 1)
-the step.  `kda` takes q, k, v [b, T, H, K|V], the LOG decay g [b, T, H, K]
-(float32) and beta [b, T, H]; the convolutions, norms, gates and projections
-round it are ops of the program.  `kda_gate` makes g from the decay's
-projection: g = -exp(A_log[h]) . softplus(x + dt_bias), float32.
+the step.  `kda` takes q, k, v [b, T, H, K|V], the LOG decay g (float32), a
+decay a channel [b, T, H, K] or a head [b, T, H] (alpha_t the same number in
+every channel: G's rank says which, no attribute does), and beta [b, T, H];
+q and k may have FEWER heads than v, a divisor of them: value head h reads key
+head h div (H / key heads), as grouped attention's query heads read theirs.
+The convolutions, norms, gates and projections round it are ops of the program.
+`kda_gate` makes g from the decay's projection: g = -exp(A_log[h]) . softplus(x
++ dt_bias), float32.
+
+A decay a head factors out of the decayed Grams below, M = (k k^T) . exp(G[r] -
+G[i]): the kernels compute it so (`kda_kernels._Chunks._scalar_grams`: one
+product a KEY head, a [C, C] matrix of exponentials a value head, no block and
+no `lax.cond`) and read g and write its gradient as [b, T, H], and a group's key
+heads come through the index map: neither g's 128 copies nor q's and k's
+repeats are in HBM.  The `jax.numpy` form, the CPU's, writes both out
+(`_per_channel`) and runs the one form below; backward sums them back.
 
 The chunked form (`_KDA_CHUNK` tokens a chunk, G the cumulative log decay
 inside the chunk, inclusive):
@@ -60,10 +73,12 @@ import numpy as np
 
 from ..core import analysis as _A
 from ..core import resource_plan as _RP
-from ..core.registry import register_op, set_step_stats
+from jax.ad_checkpoint import checkpoint_name
+
+from ..core.registry import register_op, set_kept, set_step_stats
 from ..monitor import MONITOR as _MON
 from . import kda_kernels
-from .common import counted_rules, first
+from .common import counted_rules, first, kept_residuals, operand_of, residuals_name
 
 #: Tokens a chunk (the published kernels') and a block of its rows for the
 #: decayed Grams (`_decayed_grams`), and the largest decay inside a block, in
@@ -264,6 +279,26 @@ def _kernel_seams():
     return _KDA_PRECISION, kda_kernels.cumulative, kda_kernels.carried
 
 
+def _per_channel(q, k, v, g):
+    """(q, k, g) as the `jax.numpy` form computes them: q and k repeated to v's
+    heads (value head h reads key head h div (H / key heads)) and a decay of
+    one number a head written out over the key's channels.  Nothing where the
+    op was handed a head of keys a head of values and a decay a channel."""
+    shared = v.shape[2] // k.shape[2]
+    if shared > 1:
+        q, k = jnp.repeat(q, shared, axis=2), jnp.repeat(k, shared, axis=2)
+    return q, k, jnp.broadcast_to(g[..., None], k.shape) if g.ndim == 3 else g
+
+
+def _as_handed(d_q, d_k, d_g, k, g):
+    """`_per_channel`'s transpose: a key head's gradient summed over the value
+    heads that read it, a head's decay's over the channels (float32 sums)."""
+    if d_k.shape != k.shape:
+        b, T, key_heads, K = k.shape
+        d_q, d_k = (jnp.sum(t.astype(jnp.float32).reshape(b, T, key_heads, -1, K), axis=3).astype(t.dtype) for t in (d_q, d_k))
+    return d_q, d_k, jnp.sum(d_g, axis=-1) if g.ndim == 3 else d_g
+
+
 def _kda_path(platform, mesh, q, v, chunk):
     """How the op is lowered: "kernels" (`ops/kda_kernels.py`: a group of heads'
     chunk in VMEM, the state carried there, forward and transposed) on the TPU
@@ -273,10 +308,17 @@ def _kda_path(platform, mesh, q, v, chunk):
     "xla", this module's `jax.numpy` form: the CPU's path and what the tests
     hold the kernels to.  TPU v5e, (1, 4096, 32, 128), forward | backward of the
     op alone: PERF.md, PR 44.  A 64-wide head stays "xla" until someone prices a
-    kernel for it."""
+    kernel for it.  A decay a head and fewer key heads than value heads take
+    the kernels too where a grid step's value heads are whole key heads
+    (`kda_kernels.heads_a_step`: 16 feeding 32 are two key heads a step of
+    four): TPU v5e, (1, 16384, 32, 128) with 16 key heads, forward | backward of
+    the op alone 14.19 | 19.08 ms against 16.45 | 25.67 with the decay written
+    out over the channels and the keys repeated (`kda_kernels._HEADS`' comment;
+    PERF.md, PR 69); eight value heads on one key head take "xla"."""
     one_device = mesh is None or mesh.size == 1
     whole = q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0 and chunk == _KDA_CHUNK and q.shape[1] % chunk == 0
-    return "kernels" if platform == "tpu" and one_device and whole else "xla"
+    grouped = kda_kernels.heads_a_step(v.shape[2], v.shape[2] // q.shape[2]) is not None
+    return "kernels" if platform == "tpu" and one_device and whole and grouped else "xla"
 
 
 def _chunked_kda(q, k, v, g, beta, chunk, sub, kernels, keep=False):
@@ -286,22 +328,29 @@ def _chunked_kda(q, k, v, g, beta, chunk, sub, kernels, keep=False):
         if kernels:
             return kda_kernels.scan(q, k, v, g, beta[..., 0], chunk, sub, _KDA_SAFE, _kernel_seams(), keep,
                                     kernels == "interpret")
+        q, k, g = _per_channel(q, k, v, g)
         return _over_rows(functools.partial(_row_forward, chunk=chunk, sub=sub), q, k, v, g, beta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def chunked_kda(q, k, v, g, beta, chunk=_KDA_CHUNK, sub=_KDA_SUB, kernels=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def chunked_kda(q, k, v, g, beta, chunk=_KDA_CHUNK, sub=_KDA_SUB, kernels=None, keep=None):
     """(o [b, T, H, V] in v's dtype, the state after the last token [b, H, K, V]
-    float32) of the recurrence above over q, k [b, T, H, K], v [b, T, H, V], the
-    log decay g [b, T, H, K] and beta [b, T, H, 1], `chunk` tokens at a time,
+    float32) of the recurrence above over q, k [b, T, H or a divisor of it, K],
+    v [b, T, H, V], the log decay g [b, T, H, K] a channel or [b, T, H] a head
+    and beta [b, T, H, 1], `chunk` tokens at a time,
     a chunk's rows in blocks of `sub` (`_blocks_of`).  `kernels`: None for the
     `jax.numpy` form, "tpu" for the Pallas kernels of `ops/kda_kernels.py`
     (`_kda_path` says when), "interpret" for those interpreted (the tests').
-    The final state is for statistics: backward takes no cotangent for it."""
+    The final state is for statistics: backward takes no cotangent for it.
+    `keep` (the kernels' path) names what only the forward kernel makes and
+    backward reads, the output, the chunks' start states and T: a
+    `jax.checkpoint` round the op whose policy saves the name
+    (`core/lowering.py: plan_kept`, a layer's `recompute_scope`) then runs no
+    second forward kernel."""
     return _chunked_kda(q, k, v, g, beta, chunk, sub, kernels)
 
 
-def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub, kernels):
+def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub, kernels, keep=None):
     """The op where it is differentiated: the kernels keep the chunks' start
     states and T beside the five inputs (the `jax.numpy` form the inputs alone)."""
     inputs = (q, k, v, g, beta)
@@ -309,14 +358,21 @@ def _chunked_kda_fwd(q, k, v, g, beta, chunk, sub, kernels):
         return _chunked_kda(*inputs, chunk, sub, None), (inputs, ())
     _MON.counter("lowering.kda_starts_kept").inc()
     o, final, *kept = _chunked_kda(*inputs, chunk, sub, kernels, keep=True)
+    if keep:
+        o, kept = checkpoint_name(o, keep), [checkpoint_name(t, keep) for t in kept]
     return (o, final), (inputs, tuple(kept))
 
 
-def _chunked_kda_bwd(chunk, sub, kernels, residuals, cotangents):
+def _chunked_kda_bwd(chunk, sub, kernels, keep, residuals, cotangents):
     inputs, kept = residuals
     with jax.named_scope("kda_chunk_scan"):
         if not kernels:
-            return _over_rows(functools.partial(_row_backward, chunk=chunk, sub=sub), *inputs, cotangents[0])
+            q, k, v, g, beta = inputs
+            wide_q, wide_k, wide_g = _per_channel(q, k, v, g)
+            d_q, d_k, d_v, d_g, d_beta = _over_rows(functools.partial(_row_backward, chunk=chunk, sub=sub),
+                                                    wide_q, wide_k, v, wide_g, beta, cotangents[0])
+            d_q, d_k, d_g = _as_handed(d_q, d_k, d_g, k, g)
+            return d_q, d_k, d_v, d_g, d_beta
         # the chunks in reverse, each from the start state and with the T that forward kept
         _MON.counter("lowering.kda_kernel_transposed_calls").inc()
         *d_inputs, d_beta = kda_kernels.scan_transposed(*inputs[:4], inputs[4][..., 0], cotangents[0], *kept, chunk, sub,
@@ -329,8 +385,11 @@ chunked_kda.defvjp(*counted_rules("kda", _chunked_kda_fwd, _chunked_kda_bwd))
 
 @register_op("kda")
 def _kda(ctx, op, ins):
-    """The chunked recurrence over Q, K [b, T, H, K], V [b, T, H, V], G (the
-    float32 log decay, [b, T, H, K]) and Beta [b, T, H].  `Stats` [3] is the
+    """The chunked recurrence over Q, K [b, T, H or a divisor of it, K] (value
+    head h reads key head h div (H / key heads)), V [b, T, H, V], G (the float32
+    log decay, [b, T, H, K] a channel or [b, T, H] a head) and Beta [b, T, H].
+    `lowering.scalar_decay_scans` (core/lowering.py: `count_layer_forms`) counts a step's ops whose decay is a head's.
+    `Stats` [3] is the
     step's health, read on logged steps: the mean decay exp(G), the mean
     step beta and the largest |S| of the state after the last token."""
     q, k, v, g, beta = (first(ins, s) for s in ("Q", "K", "V", "G", "Beta"))
@@ -350,7 +409,8 @@ def _kda(ctx, op, ins):
         # against the recurrence on its FETCHED q, k, v, g, beta where the same kernels on those arrays read 5.9e-4
         # (my chip runs, PR 44: `correct` false by `KDA_RTOL` for arithmetic that was sound).
         q, k, v, g, beta = jax.lax.optimization_barrier((q, k, v, g, beta))
-    out, final = chunked_kda(q, k, v, g, beta[..., None], chunk, _blocks_of(chunk), kernels)
+    out, final = chunked_kda(q, k, v, g, beta[..., None], chunk, _blocks_of(chunk), kernels,
+                             kept_residuals(ctx, op) if kernels else None)
     stats = jnp.stack([jnp.mean(jnp.exp(g)), jnp.mean(beta.astype(jnp.float32)), jnp.max(jnp.abs(final))])
     return {"Out": out, "Stats": jax.lax.stop_gradient(stats)}
 
@@ -387,12 +447,13 @@ def _infer_kda(ctx):
     q, k, v, g, beta = (ctx.in_shape(s) for s in ("Q", "K", "V", "G", "Beta"))
     if q is None or k is None or v is None:
         return
-    if len(q) != 4 or tuple(k) != tuple(q) or len(v) != 4 or tuple(v[:3]) != tuple(q[:3]):
-        ctx.fail(f"Q and K must be (b, T, H, K) and V (b, T, H, V), got {q}, {k}, {v}")
-    if g is not None and tuple(g) != tuple(k):
-        ctx.fail(f"G holds one log decay for each of K's {tuple(k)} channels, got {g}")
-    if beta is not None and tuple(beta) != tuple(q[:3]):
-        ctx.fail(f"Beta must be (b, T, H) = {tuple(q[:3])}, got {beta}")
+    if (len(q) != 4 or tuple(k) != tuple(q) or len(v) != 4 or tuple(v[:2]) != tuple(q[:2])
+            or (_A.DYN not in (v[2], q[2]) and v[2] % q[2])):
+        ctx.fail(f"Q and K must be (b, T, H or a divisor of it, K) and V (b, T, H, V), got {q}, {k}, {v}")
+    if g is not None and tuple(g) not in (tuple(v[:3]) + (k[3],), tuple(v[:3])):
+        ctx.fail(f"G holds one log decay for each of the {v[2]} heads' {k[3]} channels or for each head, got {g}")
+    if beta is not None and tuple(beta) != tuple(v[:3]):
+        ctx.fail(f"Beta must be (b, T, H) = {tuple(v[:3])}, got {beta}")
     T = q[1]
     if T != _A.DYN and T > _KDA_CHUNK and T % _KDA_CHUNK:
         ctx.fail(f"{T} positions are no whole number of chunks of {_KDA_CHUNK}")
@@ -409,6 +470,23 @@ def _infer_kda_gate(ctx):
     ctx.set_out("Out", tuple(xs[:-1]) + (a_log[0], xs[-1] // a_log[0]), "float32")
 
 
+def _kept_kda(ctx, op, shapes):
+    """Where the op takes the kernels: its output, the float32 state every chunk
+    starts from [chunks, b, H, K, V] and T [chunks, b, H, C / 2, 2 C] (what only
+    the forward kernel makes and the transposed one reads: 0.8 GB a layer at
+    (1, 16384, 32, 128), where making them again is a second forward kernel,
+    13 ms a layer of Qwen3-Next's step: PERF.md, PR 69).  The `jax.numpy` form
+    offers nothing: it keeps its inputs alone and makes the rest again."""
+    q, v = (operand_of(shapes, op.input(slot)[0]) for slot in ("Q", "V"))
+    chunk = min(_KDA_CHUNK, q.shape[1])
+    if _kda_path(ctx.platform, ctx.mesh, q, v, chunk) != "kernels":
+        return None
+    batch, tokens, heads, width = v.shape
+    chunks = tokens // chunk
+    return residuals_name(op), shapes.nbytes(op.output("Out")[0]) + 4 * chunks * batch * heads * (q.shape[-1] * width + chunk * chunk)
+
+
+set_kept("kda", _kept_kda)
 _A.register_rule(["kda"], _infer_kda)
 _A.register_rule(["kda_gate"], _infer_kda_gate)
 
@@ -431,7 +509,7 @@ def _cost_kda(ctx):
     q, v = ctx.in_shape("Q"), ctx.in_shape("V")
     if q is None or v is None or len(q) != 4:
         return float(ctx.out_elems_total()), ctx.io_bytes()
-    return kda_chunk_flops(q[0] * q[1], q[2], q[3], v[3], min(_KDA_CHUNK, q[1])), ctx.io_bytes()
+    return kda_chunk_flops(v[0] * v[1], v[2], q[3], v[3], min(_KDA_CHUNK, q[1])), ctx.io_bytes()
 
 
 _RP.register_cost(["kda"], _cost_kda)

@@ -173,6 +173,9 @@ MASKS = ("block_diffusion", "sliding_window")
 #:   (1, 28 on 4, 16384, 128), SmallThinker's full layer
 #:   fused, dq on the chip                                44.82 | 45.73
 #:   stock fused backward                                 49.15
+#:   (1, 16 on 2, 16384, 256), Qwen3-Next's full layer (my chip run, PR 69: dk and dv outside; in VMEM they do not fit)
+#:   fused, dq on the chip                                48.37           51.51           (66.95 at 256-blocks)
+#:   stock fused backward | the pair                      52.92 | 61.88   60.30 | 67.64
 #:
 #: so ours at every shape, 1024-blocks, a block's keys ONE pass (the kernel has
 #: no inner loop: 0.5 to 2% over 512 keys a pass now that the VMEM is ours), a

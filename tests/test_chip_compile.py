@@ -313,6 +313,18 @@ CASES = {
         _window, [((1, 64, 16384, 128), BF16)] + [((1, 8, 16384, 128), BF16)] * 2, (0, 1, 2)),
     "block_causal_attention_laguna": (
         _block_causal, [((1, 48, 16384, 128), BF16)] + [((1, 8, 16384, 128), BF16)] * 2, (0, 1, 2)),
+    # Qwen3-Next-80B-A3B's cell (PR 69): a Gated DeltaNet layer's scan, one sequence of 16384 positions, 16 key heads of
+    # 128 feeding 32 value heads of 128, the log decay ONE float32 a head a token ([b, T, H]: the kernels' scalar form,
+    # the key heads through the index map), forward and transposed; and its full layer's causal attention, 16 query
+    # heads on 2 key/value heads of 256: the widest head compiled here, groups of EIGHT
+    "kda_scan_qwen3_next": (
+        _kda, [((1, 16384, 16, 128), BF16)] * 2 + [((1, 16384, 32, 128), BF16), ((1, 16384, 32), F32),
+                                                    ((1, 16384, 32, 1), F32)], ()),
+    "kda_scan_transposed_qwen3_next": (
+        _backward(_kda, (0, 1, 2, 3, 4)),
+        [((1, 16384, 16, 128), BF16)] * 2 + [((1, 16384, 32, 128), BF16), ((1, 16384, 32), F32), ((1, 16384, 32, 1), F32)], ()),
+    "block_causal_attention_qwen3_next": (
+        _block_causal, [((1, 16, 16384, 256), BF16)] + [((1, 2, 16384, 256), BF16)] * 2, (0, 1, 2)),
 }
 
 

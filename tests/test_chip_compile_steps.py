@@ -540,3 +540,48 @@ def test_nemotron3_supers_step_and_its_check_rows_on_the_2x2_host_leave_room(hos
     beside = _planned_peak(clone) + moments
     print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
     assert beside <= 16.9e9, beside
+
+
+@pytest.mark.slow   # two compiles, ~90 and ~75 s on every core: run by name (`-m slow -k qwen3_next`); PERF.md, PR 69, has their readings
+def test_qwen3_nexts_step_and_its_eight_row_clone_plan_under_the_chips_memory(host, monkeypatch):
+    """`qwen3-next-80b-a3b-instruct.train-gdn-s16384`'s whole step at the
+    published widths and 16384 tokens, compiled for the described v5e with what
+    `plan_kept` chooses at the chip's memory limit: every candidate of the four
+    segments (41 values, 4.96 GB: the three scans' outputs, chunks' start states
+    and T among them, 0.8 GB a layer, so that no scan is made again), planned
+    over 25% of the chip and under 12.5 GB (11.94; 9.70 with the scans made
+    again); the three Gated DeltaNet layers took the scan's kernels, forward
+    and transposed, at a decay [1, 16384, 32] float32 (no [.., 128]
+    copy of it) and 16 key heads; the full layer the causal splash forward and
+    the ONE backward kernel at 16 heads of 256 on 2; the scopes
+    `gated_delta_net` (three, numbered), `attention_gate` and `moe_shared_gate`
+    stand in the compiled step.  The 8-row `for_test` clone of the reference
+    check plans with the optimizer's two moments beside it under the 16.9 GB the
+    chip's runtime gives (12.54 + 3.39: the gated norm's product in the
+    projections' own layout took 2.4 GB off it; ISSUE 69)."""
+    compiled, counted = _kept_step("qwen3_next", "qwen3-next-80b-a3b-instruct", "train-gdn-s16384", host.devices, monkeypatch)
+    assert counted["segments"] == counted["sparse_segments"] == 4 and counted["kept_values"] == 41
+    assert counted["kept_bytes"] == counted["candidates_bytes"] > 4.9e9
+    peak = _planned_peak(compiled)
+    print(f"the step's planned peak {peak / 1e9:.3f} GB")     # shown by `-s`
+    assert 0.25 * 16.9e9 <= peak <= 12.5e9, f"the step plans {peak / 1e9:.3f} GB"
+    text = compiled.as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    scans = [n for n in names if n.endswith("/kda_scan/pallas_call") or n.endswith("/kda_scan_transposed/pallas_call")]
+    assert {re.search(r"/(gated_delta_net(?:_\d+)?)/", n).group(1) for n in scans} == {"gated_delta_net", "gated_delta_net_1", "gated_delta_net_2"}
+    assert len({re.search(r"/(gated_delta_net(?:_\d+)?)/", n).group(1) for n in scans if "kda_scan_transposed" in n}) == 3
+    assert not [n for n in scans if "rematted_computation" in n]                  # no scan is made again: its residuals are kept
+    assert re.findall(r"f32\[1,16384,32\]", text)            # the decay as the kernels read it: a number a head a token
+    assert re.findall(r"bf16\[1,16384,2048\]", text)          # q and k of 16 key heads: never repeated to 32
+    assert "splash_mha_fwd" in text and "flash_mha" not in text
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*/attention_dq_dk_dv["/]', text)) == 1
+    assert re.findall(r"bf16\[1,16,16384,256\]", text)
+    assert any("/attention_gate" in n for n in names) and any("/moe_shared_gate/" in n for n in names)
+    again = [name for name in _made_again(text) if "/cond/branch_" not in name]
+    assert again and not [name for name in again if "splash_mha" in name or "attention_dq_dk_dv" in name or "/expert_gemm/" in name
+                          or "kda_scan" in name]
+    clone, _ = _kept_step("qwen3_next", "qwen3-next-80b-a3b-instruct", "train-gdn-s16384", host.devices, monkeypatch, check_rows=8)
+    moments = 2 * 4 * 424_340_544
+    beside = _planned_peak(clone) + moments
+    print(f"the 8-row clone's planned peak {_planned_peak(clone) / 1e9:.3f} GB, {beside / 1e9:.3f} with the moments")
+    assert beside <= 16.9e9, f"the clone plans {_planned_peak(clone) / 1e9:.3f} GB beside {moments / 1e9:.3f} GB of moments"

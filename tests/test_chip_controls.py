@@ -12,6 +12,7 @@ REHEARSED = {
     "chip_lfm2_controls": ("1",),
     "chip_nemotron_controls": ("1", "scan_wrong_group"),      # the sound run and a fault in the program
     "chip_phi4flash_controls": ("1",),
+    "chip_qwen3_next_controls": ("1", "no_delta_correction", "a_heads_mean_for_the_feature_gate"),   # a fault in the reference, a lowering wrapped by its scope
     "chip_smallthinker_controls": ("1", "window_of_17"),      # the sound run and a program built again
 }
 

@@ -122,6 +122,42 @@ def test_the_rule_takes_the_kernels_on_one_tpu_at_whole_lane_tiles_and_nowhere_e
     assert not re.search(r"environ|getenv|FLAGS|\.attr\(", inspect.getsource(lao._kda_path) + inspect.getsource(lao._kda))
 
 
+@pytest.mark.parametrize("key_heads,heads,scalar", [(2, 4, True), (4, 4, True), (2, 4, False), (1, 2, True)],
+                         ids=["16_on_32s_kind", "a_decay_a_head_alone", "fewer_key_heads_alone", "two_value_heads_a_key_head"])
+def test_a_decay_a_head_and_fewer_key_heads_through_the_kernels_are_the_jax_numpy_form(key_heads, heads, scalar):
+    """ISSUE 69: g [b, T, H] (one decay a head: the kernels take it out of the
+    chunk's Grams and read and write it as a head's row) and q, k of fewer heads
+    than v (a group's key heads come through the index map; a key head's
+    gradient is its value heads' summed in the kernel): output, state and every
+    gradient against the `jax.numpy` form, which writes both out, and the
+    gradients in the shapes the op was handed."""
+    q, k, v, g, beta = scan_inputs(11, 2, 128, heads, 8, 8, 0.3)
+    q, k = q[:, :, :key_heads], k[:, :, :key_heads]
+    g = g[..., 0] if scalar else g
+    weigh = jnp.asarray(np.random.RandomState(1).randn(*v.shape).astype("f4"))
+
+    def through(kernels):
+        op = lambda *a: lao.chunked_kda(*a[:4], a[4][..., None], 64, 16, kernels)
+        return op(q, k, v, g, beta), jax.grad(lambda *a: jnp.sum(op(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    (out, state), grads = through("interpret")
+    (plain, plain_state), plain_grads = through(None)
+    assert [t.shape for t in grads] == [t.shape for t in (q, k, v, g, beta)]
+    for mine, theirs in ((out, plain), (state, plain_state)) + tuple(zip(grads, plain_grads)):
+        assert np.isfinite(np.asarray(mine)).all()
+        agree(mine, theirs, tol=3e-5)
+
+
+def test_the_rule_takes_the_kernels_where_a_grid_steps_value_heads_are_whole_key_heads():
+    """16 key heads feeding 32 value heads: four value heads a grid step read two key heads.  Eight value heads on one
+    key head leave no step of (4, 2, 1) heads whole key heads: the `jax.numpy` form."""
+    shape = lambda heads: jax.ShapeDtypeStruct((1, 4096, heads, 128), jnp.bfloat16)     # noqa: E731
+    assert lao._kda_path("tpu", None, shape(16), shape(32), 64) == "kernels" and kda_kernels.heads_a_step(32, 2) == 4
+    assert lao._kda_path("tpu", None, shape(8), shape(32), 64) == "kernels" and kda_kernels.heads_a_step(32, 4) == 4
+    assert lao._kda_path("tpu", None, shape(4), shape(32), 64) == "xla" and kda_kernels.heads_a_step(32, 8) is None
+    assert kda_kernels.heads_a_step(6, 2) == 2 and kda_kernels.heads_a_step(3, 1) == 1
+
+
 KEPT_CASES = [KERNEL_CASES[1], KERNEL_CASES[3], KERNEL_CASES[5], KERNEL_CASES[7], KERNEL_CASES[8], "a_channel_dies"]
 
 
@@ -200,7 +236,7 @@ def test_the_gradient_is_two_kernel_calls_and_the_plain_op_one_with_two_outputs(
     calls = pallas_calls(grad.jaxpr)
     assert [name for name, _ in calls] == ["kda_scan", "kda_scan_transposed"] and "kda_scan_starts" not in str(grad)
     assert calls[0][1] == [o, final, (n, rows, heads, 128, 128), (n, rows, heads, 32, 128)]
-    assert calls[1][1] == [o, o, o, o, (rows, n, -(-heads // kda_kernels._heads_a_step(heads)), kda_kernels._heads_a_step(heads), 64)]
+    assert calls[1][1] == [o, o, o, o, (rows, n, -(-heads // kda_kernels.heads_a_step(heads)), kda_kernels.heads_a_step(heads), 64)]
 
 
 def kda_lowered(platform):

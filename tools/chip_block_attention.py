@@ -184,6 +184,60 @@ class StoredCausal(mask_lib.Mask):
         return hash((type(self).__name__, self.shape))
 
 
+if os.environ.get("WIDE") == "256":
+    # Qwen3-Next's full layer (PR 69): the causal rule at 16 query heads on 2 key/value heads of 256, 16384 keys: the widest
+    # head the kernels have run.  A head's float32 dq and its output block hold 32 MiB of the backward kernel's VMEM, a
+    # key/value head's dk and dv rows would hold 64 more (`kv_rows_fit` says no): dk and dv of a group of EIGHT query heads
+    # go out in float32 a query head and are summed outside.  The op's own call; the grid's block at 256, 512 and 1024 with
+    # dk and dv outside and (refused where it overruns the VMEM) in it; the stock splash pair and the stock fused backward.
+    fq, fkv = ((1, 8, 512, 256), (1, 2, 512, 256)) if DRY else ((1, 16, 16384, 256), (1, 2, 16384, 256))
+    fqkv = operands(fq, fkv, seed=5)
+    length, group = fq[2], fq[1] // fkv[1]
+    causal = lambda q, k, v: ma.causal_attention(q, k, v, q.shape[-1] ** -0.5, interpret=DRY)  # noqa: E731
+    full = ma.causal_plan(length, fq[1], DRY, (fq[-1], fkv[-1]))
+
+    def forward_ms(fn, runs=5):
+        step = jax.jit(fn)
+        jax.block_until_ready(step(*fqkv))
+        times = []
+        for _ in range(runs):
+            t = time.perf_counter()
+            jax.block_until_ready(step(*fqkv))
+            times.append(1e3 * (time.perf_counter() - t))
+        return None if DRY else float(np.median(times))
+
+    report("causal_256_as_the_op_calls_it", q=fq, kv=fkv, grid_block=full.block, taken_backward=full.backward,
+           dk_dv_summed_in_vmem=onchip_kernels.kv_rows_fit((length, length), full.widths, group),
+           dq_vmem_mib=onchip_kernels.vmem_bytes((length, length), full.widths, False) / 2 ** 20,
+           with_kv_rows_vmem_mib=onchip_kernels.vmem_bytes((length, length), full.widths, True) / 2 ** 20,
+           forward_ms=forward_ms(causal), forward_backward_ms=try_ms(causal, *fqkv))
+    for b in (128, 256) if DRY else (256, 512, 1024):
+        at_block = lambda q, k, v, b=b: ma.attention_under(full._replace(block=b), q, k, v, q.shape[-1] ** -0.5)  # noqa: E731
+        for kv_rows in (False, True):
+            report("causal_256_fused_dq_on_the_chip", q=fq, grid_block=b, dk_dv_summed_in_vmem=kv_rows, forward_ms=forward_ms(at_block),
+                   steps_a_head=int(ma._steps(full._replace(block=b)).q_block.size), ms=try_ms(onchip(full._replace(block=b), kv_rows), *fqkv))
+    if not DRY:
+        for b, compute, fused in ((1024, 512, True), (512, 512, True), (1024, 512, False), (512, 512, False)):
+            report("causal_256_splash", q=fq, cut_blocks="computed", grid_block=b, block_kv_compute=compute, fused_backward=fused,
+                   ms=try_ms(splash_with(mask_lib.CausalMask((length, length)), fq[1], sizes_of(b, b, compute, fused=fused)), *fqkv))
+    sq, skv = ((1, 8, 512, 256), (1, 2, 512, 256)) if DRY else ((1, 16, 2048, 256), (1, 2, 2048, 256))
+    sqkv = operands(sq, skv, seed=6)
+
+    def dense(q, k, v):   # float32 scores of the whole square under the rule
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
+        at = jnp.arange(q.shape[2])
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * q.shape[-1] ** -0.5
+        p = jax.nn.softmax(jnp.where(ma.causal_allowed(at[:, None], at[None, :]), s, -jnp.inf), -1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+    results = [(jax.jit(f)(*sqkv), *gradients(f)(*sqkv)) for f in (dense, causal)]
+    report("causal_256_against_dense_float32", q=sq,
+           apart=dict(zip(("out", "dq", "dk", "dv"), (apart(a, b) for a, b in zip(results[1], results[0])))),
+           finite=all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in results[1]))
+    sys.exit(0)
+
+
 if os.environ.get("WINDOW") in ("1", "4096", "512x128"):
     wide = os.environ["WINDOW"] == "4096"
     narrow_band = os.environ["WINDOW"] == "512x128"     # Laguna-XS.2's: Phi's window on SmallThinker's kind of heads

@@ -14,6 +14,15 @@
     keys, 128-wide values, 32 heads, 4096 keys): the stock splash kernels with
     the widths as they are, with q and k padded to 256 by zeros, and XLA's.
 
+  * ONLY=gdn: the op at Qwen3-Next's Gated DeltaNet shape, (1, 16384, 32, 128) with
+    16 key heads and a decay of ONE number a head: as the op takes it (g [b, T,
+    H], the key heads through the kernels' index map, the decay taken out of
+    the chunk's Grams) at 4, 2 and 8 value heads a grid step (two share a key head: no fewer than 2), against the same
+    decay written out over the 128 channels and q and k repeated to 32 heads
+    in HBM (the decay-a-channel kernels, what the op ran before PR 69), forward |
+    forward that keeps | backward | both, how far the two forms lie apart and
+    the scalar form from the token-by-token recurrence.
+
 Prints one JSON line a reading.  ONLY=kda or ONLY=attention runs one half;
 ONLY=profile writes the own device time by HLO instruction (the kernels, the
 state's `while`s, what is left in XLA's fusions) of the op's three programs,
@@ -111,7 +120,7 @@ def kept_of(kernels):
 def backward_of(kernels):
     """The op's backward alone: the five gradients of the inputs, what forward made for it and d o."""
     def backward(q, k, v, g, beta, kept, d_o):
-        return lao._chunked_kda_bwd(lao._KDA_CHUNK, lao._KDA_SUB, kernels, ((q, k, v, g, beta[..., None]), kept), (d_o, None))
+        return lao._chunked_kda_bwd(lao._KDA_CHUNK, lao._KDA_SUB, kernels, None, ((q, k, v, g, beta[..., None]), kept), (d_o, None))
     return backward
 
 
@@ -187,6 +196,49 @@ def kda():
         found = errors(out, hard)
         say(reading="kda_op_by_differences", form=form, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms, error=found["error"],
             error_unrounded=found["error_unrounded"])
+
+
+def gdn_inputs(seed, b=1, T=16384, key_heads=16, H=32, K=128):
+    """q, k (`key_heads`), v, beta as `kda_inputs` draws them, and a log decay of one number a head a token."""
+    q, k, v, _, beta = kda_inputs(seed, b, T, H, K)
+    r = np.random.RandomState(seed + 1)
+    g = -(r.uniform(1, 16, (1, 1, H)) * np.exp(r.uniform(np.log(1e-3), np.log(1e-1), (b, T, H))))
+    return q[:, :, :key_heads], k[:, :, :key_heads], v, jnp.asarray(g, jnp.float32), beta
+
+
+def gdn(T=16384, key_heads=16, H=32, K=128, kernels="tpu"):
+    from benchmark.models import qwen3_next
+
+    args = gdn_inputs(int(os.environ.get("SEED", "1")), 1, T, key_heads, H, K)
+    q, k, v, g, beta = args
+    written_out = (jnp.repeat(q, H // key_heads, 2), jnp.repeat(k, H // key_heads, 2), v, jnp.broadcast_to(g[..., None], v.shape[:3] + (K,)), beta)
+    weigh = cotangent_of(args)
+    heads_a_step = kda_kernels._HEADS
+    rms = lambda t: float(np.sqrt(np.mean(np.square(np.asarray(t, "f4")))))
+    outs = {}
+    for form, operands, heads in [("a_decay_a_head", args, h) for h in (heads_a_step, 2, 8)] + [("written_out_over_channels", written_out, heads_a_step)]:
+        kda_kernels._HEADS = heads      # read when a kernel is traced
+        jax.clear_caches()
+        op = op_of(kernels)
+        fwd_ms, out = timed(jax.jit(op), *operands)
+        kept_ms, (_, kept) = timed(jax.jit(kept_of(kernels)), *operands)
+        bwd_ms, _ = timed(jax.jit(backward_of(kernels)), *operands, kept, weigh)
+        both_ms, grads = timed(jax.jit(both_of(op)), *operands)
+        say(reading="gdn_op", form=form, shape=(1, T, H, K), key_heads=key_heads, heads_a_grid_step=heads, fwd_ms=fwd_ms, fwd_kept_ms=kept_ms,
+            bwd_ms=bwd_ms, fwd_bwd_ms=both_ms, kept_mb=sum(t.nbytes for t in kept) / 1e6,
+            operands_mb=sum(t.nbytes for t in operands) / 1e6)
+        outs.setdefault(form, (out, grads))
+    kda_kernels._HEADS = heads_a_step
+    jax.clear_caches()
+    (mine, mine_grads), (theirs, their_grads) = outs["a_decay_a_head"], outs["written_out_over_channels"]
+    share = H // key_heads
+    summed = [their_grads[0].astype(jnp.float32).reshape(1, T, key_heads, share, K).sum(3), their_grads[1].astype(jnp.float32).reshape(1, T, key_heads, share, K).sum(3),
+              their_grads[2], their_grads[3].sum(-1), their_grads[4]]
+    say(reading="gdn_forms_apart", out=rms(mine.astype(jnp.float32) - theirs.astype(jnp.float32)) / rms(theirs),
+        **{"d" + name: rms(a.astype(jnp.float32) - b.astype(jnp.float32)) / max(rms(b), 1e-30) for name, a, b in zip("q k v g beta".split(), mine_grads, summed)})
+    want = np.asarray(jax.jit(qwen3_next.recurrence)(*(t[0] for t in written_out[:3]), g[0], beta[0]))
+    rounded = np.asarray(jnp.asarray(want, jnp.bfloat16).astype(jnp.float32))
+    say(reading="gdn_against_the_recurrence", **{form: rms(np.asarray(out[0].astype(jnp.float32)) - rounded) / rms(want) for form, (out, _) in outs.items()})
 
 
 def attention():
@@ -316,6 +368,8 @@ if __name__ == "__main__":
         profile()
     if only == "terms":
         terms()
+    if only == "gdn":
+        gdn()
     if only in (None, "kda"):
         kda()
     if only in (None, "attention"):
